@@ -17,9 +17,11 @@ import jax
 from megatron_llm_tpu.config import parse_args
 from megatron_llm_tpu.models.families import validate_family
 from megatron_llm_tpu.training import pretrain
+from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
 
 def main():
+    enable_compilation_cache()
     cfg = parse_args(n_devices=len(jax.devices()))
     validate_family(cfg)
     if cfg.checkpoint.use_checkpoint_args and cfg.checkpoint.load:

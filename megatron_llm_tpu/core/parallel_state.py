@@ -131,12 +131,6 @@ def build_mesh_from_config(cfg, devices=None) -> Mesh:
 def set_global_mesh(mesh: Mesh) -> None:
     global _GLOBAL_MESH
     _GLOBAL_MESH = mesh
-    # Layouts that reach partial-manual shard_map code (pp/cp) must compile
-    # under the shardy partitioner on jax 0.4.37 (parallel/compat.py);
-    # dp/ep/tp-only meshes stay on GSPMD (bitwise-stable pjit lowering).
-    from megatron_llm_tpu.parallel import compat
-
-    compat.enable_partitioner_for(mesh)
 
 
 def get_global_mesh() -> Mesh:
@@ -172,18 +166,14 @@ def target_platform() -> str:
 
 @contextlib.contextmanager
 def global_mesh(mesh: Mesh):
-    from megatron_llm_tpu.parallel import compat
-
     global _GLOBAL_MESH
     prev = _GLOBAL_MESH
-    prev_partitioner = compat.enable_partitioner_for(mesh)
     set_global_mesh(mesh)
     try:
         with mesh:
             yield mesh
     finally:
         _GLOBAL_MESH = prev
-        compat.restore_partitioner(prev_partitioner)
 
 
 def _axis_size(mesh: Mesh, axis: str) -> int:
@@ -216,14 +206,43 @@ def named_sharding(*spec, mesh: Optional[Mesh] = None) -> NamedSharding:
     return NamedSharding(mesh or get_global_mesh(), P(*spec))
 
 
+def placement_report(mesh: Mesh, **trees) -> str:
+    """One log line: on how many of the mesh's local devices each named
+    pytree has an addressable shard, and every such device's bytes in use
+    and peak (where the backend reports them; XLA:CPU does not).
+
+    Raises when a tree leaves a mesh device without a shard, or a device
+    reports zero bytes in use: the layout asked for is then not the layout
+    on the chips (a mesh built over the first device only, a pool left on
+    one chip)."""
+    devices = list(mesh.local_devices)
+    parts = []
+    for name, tree in trees.items():
+        held = {s.device for leaf in jax.tree_util.tree_leaves(tree)
+                for s in leaf.addressable_shards}
+        missing = [d.id for d in devices if d not in held]
+        if missing:
+            raise RuntimeError(
+                f"{name} has no shard on mesh devices {missing}")
+        parts.append(f"{name} on {len(devices)}/{len(devices)} devices")
+    for d in devices:
+        stats = d.memory_stats()
+        if stats is None:
+            continue
+        if not stats["bytes_in_use"]:
+            raise RuntimeError(f"device {d.id} reports 0 bytes in use")
+        parts.append(
+            f"device {d.id} in_use {stats['bytes_in_use'] / 2**30:.2f} GiB "
+            f"peak {stats['peak_bytes_in_use'] / 2**30:.2f} GiB")
+    return "placement: " + "; ".join(parts)
+
+
 # Inside shard_map, pipeline stage index is the device's coordinate on the pp
 # axis (analog of get_pipeline_model_parallel_rank, parallel_state.py:311-320).
 
 def pipeline_stage_index() -> jax.Array:
     """Current pp-stage index; only valid inside shard_map over PP_AXIS."""
-    from megatron_llm_tpu.parallel import compat
-
-    return compat.axis_index(PP_AXIS)
+    return jax.lax.axis_index(PP_AXIS)
 
 
 def is_pipeline_first_stage() -> jax.Array:
@@ -231,6 +250,4 @@ def is_pipeline_first_stage() -> jax.Array:
 
 
 def is_pipeline_last_stage() -> jax.Array:
-    from megatron_llm_tpu.parallel import compat
-
-    return pipeline_stage_index() == compat.axis_size(PP_AXIS) - 1
+    return pipeline_stage_index() == jax.lax.axis_size(PP_AXIS) - 1

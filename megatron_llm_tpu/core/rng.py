@@ -57,10 +57,9 @@ def fold_layer(key: jax.Array, layer_index) -> jax.Array:
 def fold_model_parallel(key: jax.Array, axis_name: str = "tp") -> jax.Array:
     """Diverge randomness across TP ranks inside a shard_map region
     (semantics of get_cuda_rng_tracker().fork(), random.py:121-141)."""
-    from megatron_llm_tpu.parallel import compat
-
     return jax.random.fold_in(
-        jax.random.fold_in(key, _MODEL_PARALLEL_TAG), compat.axis_index(axis_name)
+        jax.random.fold_in(key, _MODEL_PARALLEL_TAG),
+        jax.lax.axis_index(axis_name),
     )
 
 

@@ -4,11 +4,16 @@ Reference: megatron/data/dataset_utils.py:82 ``compile_helper`` — the
 reference also builds its C++ helper lazily at first use (via make).  The
 Python callers keep vectorized numpy fallbacks, so the native library is an
 optimization, never a requirement.
+
+The binary is keyed by the source: ``_helpers_<sha256 of helpers.cpp>.so``.
+``*.so`` is git-ignored, so a copied working tree may carry a binary no
+commit describes; one built from another ``helpers.cpp`` is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
@@ -16,15 +21,17 @@ from typing import Optional, Tuple
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "_helpers.so")
+with open(os.path.join(_DIR, "helpers.cpp"), "rb") as _src:
+    _SO = os.path.join(
+        _DIR, f"_helpers_{hashlib.sha256(_src.read()).hexdigest()[:16]}.so")
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
 
 def _compile() -> bool:
     try:
-        subprocess.run(["make", "-C", _DIR], check=True, capture_output=True,
-                       timeout=120)
+        subprocess.run(["make", "-C", _DIR, os.path.basename(_SO)],
+                       check=True, capture_output=True, timeout=120)
         return os.path.isfile(_SO)
     except (subprocess.SubprocessError, OSError):
         return False

@@ -56,12 +56,12 @@ class InferenceEngine:
         logical length, token budget on the (bucket-padded) size that runs."""
         max_pos = self.cfg.model.max_position_embeddings
         if samples_length > max_pos:
-            raise ValueError(
+            raise gen.InvalidRequest(
                 "Length of prompt + tokens_to_generate longer than allowed")
         budget = self.cfg.inference.max_tokens_to_oom
         run_tokens = (run_length or samples_length) * batch_size
         if run_tokens > budget:
-            raise ValueError(
+            raise gen.InvalidRequest(
                 f"Too many tokens.  {run_tokens} is greater than {budget}")
 
     # -- generate ----------------------------------------------------------
@@ -183,7 +183,8 @@ class InferenceEngine:
     ):
         """api.beam_search_and_post_process analog (api.py:152-201)."""
         if len(prompts) != 1:
-            raise ValueError("beam search supports exactly one prompt")
+            raise gen.InvalidRequest(
+                "beam search supports exactly one prompt")
         tok = self.tokenizer
         stop_token = tok.eod if stop_token is None else stop_token
         tokens, lengths, samples_length = tokenize_prompts_and_batch(

@@ -96,6 +96,7 @@ import dataclasses
 import os
 import threading
 import time
+import traceback
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -721,13 +722,6 @@ class ContinuousBatchingEngine:
             assert cfg.model.num_layers % self._pp == 0, (
                 f"num_layers {cfg.model.num_layers} not divisible by "
                 f"pp {self._pp}")
-            # ppermute inside a partial-manual region crashes the GSPMD
-            # partitioner on jax 0.4.37 — hold the shardy flag for the
-            # engine's lifetime (it participates in jit trace keys, so
-            # flat-mesh executables are never reused; compat.py story).
-            from megatron_llm_tpu.parallel import compat as compat_mod
-
-            compat_mod.enable_partitioner_for(mesh)
         if mesh is not None:
             from megatron_llm_tpu.parallel.tp import param_shardings
 
@@ -946,6 +940,8 @@ class ContinuousBatchingEngine:
         # wall time the last device dispatch call returned (driver-thread
         # only; reads/writes serialize under _drive_lock)
         self._last_dispatch_end: Optional[float] = None
+        # steps that raised in the scheduler loop (:meth:`_fail_all`)
+        self.failures = 0
         # tick/cache telemetry for the decode bench
         self.ticks = 0
         self.ticked_tokens = 0
@@ -1005,6 +1001,10 @@ class ContinuousBatchingEngine:
             "mlt_engine_requests_total", help="generations submitted")
         self._m_ticks = reg.counter(
             "mlt_engine_ticks_total", help="fused decode ticks run")
+        self._m_failures = reg.counter(
+            "mlt_engine_failures_total",
+            help="engine steps that raised (lowering, compile or runtime "
+                 "failure); every request in flight answered 500")
         self._m_tokens = reg.counter(
             "mlt_engine_ticked_tokens_total",
             help="slot-steps advanced (tokens sampled) across ticks")
@@ -1548,16 +1548,17 @@ class ContinuousBatchingEngine:
                **kw) -> EngineRequest:
         """Enqueue a generation; returns the request future.
 
-        Raises ValueError for requests that can never fit (the legacy
-        engine's request-size guard, generation/api._check_limits) and
-        :class:`EngineOverloaded` when the queue is at capacity."""
+        Raises :class:`gen.InvalidRequest` (a ValueError) for requests
+        that can never fit (the legacy engine's request-size guard,
+        generation/api._check_limits) and :class:`EngineOverloaded` when
+        the queue is at capacity."""
         prompt = [int(t) for t in prompt]
         if len(prompt) < 1:
-            raise ValueError("prompt must contain at least one token")
+            raise gen.InvalidRequest("prompt must contain at least one token")
         if max_new_tokens < 1:
-            raise ValueError("max_new_tokens must be >= 1")
+            raise gen.InvalidRequest("max_new_tokens must be >= 1")
         if len(prompt) + max_new_tokens > self.max_seq:
-            raise ValueError(
+            raise gen.InvalidRequest(
                 "Length of prompt + tokens_to_generate longer than allowed")
         req = EngineRequest(prompt=prompt, max_new_tokens=max_new_tokens, **kw)
         req._t_submit = time.monotonic()
@@ -3228,9 +3229,34 @@ class ContinuousBatchingEngine:
                 if self._stopping:
                     break
             with self._drive_lock:
-                self.step()
+                try:
+                    self.step()
+                except Exception as e:  # noqa: BLE001 — boundary: the
+                    # scheduler thread must outlive a failed step, or every
+                    # waiter hangs until its timeout with no answer
+                    traceback.print_exc()
+                    self._fail_all(e)
         with self._drive_lock:
             self._drain_pipeline()
+
+    def _fail_all(self, e: Exception) -> None:
+        """A step raised (a program failed to lower, compile or run):
+        nothing in flight can be trusted, so every queued and resident
+        request fails with the step's error — each waiter gets its answer
+        (a 500 from the server) now.  Counted in ``engine_failures``."""
+        with self._lock:
+            self.failures += 1
+            self._inflight.clear()
+            self._pipe_state = None
+            pending = list(self._queue) + [
+                r for r in self._slots if r is not None]
+            self._queue.clear()
+            self._prefill_q.clear()
+            for req in pending:
+                self._fail_locked(req, e)
+            self._publish_queued_locked()
+        if obs_registry.publishing():
+            self._m_failures.inc()
 
     # -- server-facing API (api.InferenceEngine surface) -------------------
 

@@ -38,6 +38,15 @@ from megatron_llm_tpu.models.language_model import (
 
 BUCKET = 64
 
+
+class InvalidRequest(ValueError):
+    """A request that can never be served as sent (empty prompt, longer
+    than the model allows, too many tokens).  The only error the server
+    answers with a 400: any other exception out of an engine — a
+    lowering, compile or runtime failure — is the server's fault and a
+    500 (generation/server.py)."""
+
+
 # GPT-2 BPE newline conventions used by the reference's stop_on_eol /
 # stop_on_double_eol options (generation.py:241-251).
 GPT2_EOL = 198
@@ -377,7 +386,7 @@ def beam_search(
     S = int(tokens.shape[1])
     horizon = S if samples_length is None else min(int(samples_length), S)
     if prompt_length >= horizon:
-        raise ValueError("context length + tokens_to_generate too large")
+        raise InvalidRequest("context length + tokens_to_generate too large")
 
     beam_hyp = BeamHypotheses(beam_size, length_penalty)
     tokens = jnp.broadcast_to(jnp.asarray(tokens, jnp.int32), (beam_size, S))

@@ -32,6 +32,7 @@ from typing import Optional
 from urllib.parse import parse_qs
 
 from megatron_llm_tpu.generation.engine import EngineOverloaded
+from megatron_llm_tpu.generation.generation import InvalidRequest
 from megatron_llm_tpu.generation.scheduling import RequestShed
 from megatron_llm_tpu.observability import trace as obs_trace
 from megatron_llm_tpu.serving.streaming import SSE_CONTENT_TYPE, sse_encode
@@ -293,7 +294,7 @@ class MegatronServer:
                 # load shed) — retryable load feedback, not a client error
                 return 503, {"error": str(rs), "shed": True,
                              "retry_after": getattr(rs, "retry_after", 1.0)}
-            except (ValueError, AssertionError) as ve:
+            except InvalidRequest as ve:
                 return 400, {"error": str(ve.args[0] if ve.args else ve)}
             except Exception as e:  # engine failure must still answer the client
                 import traceback
@@ -343,7 +344,7 @@ class MegatronServer:
         except RequestShed as rs:
             return 503, {"error": str(rs), "shed": True,
                          "retry_after": getattr(rs, "retry_after", 1.0)}
-        except (ValueError, AssertionError) as ve:
+        except InvalidRequest as ve:
             return 400, {"error": str(ve.args[0] if ve.args else ve)}
         except Exception as e:
             import traceback
@@ -442,7 +443,7 @@ class MegatronServer:
         except RequestShed as rs:
             return 503, {"error": str(rs), "shed": True,
                          "retry_after": getattr(rs, "retry_after", 1.0)}
-        except (ValueError, AssertionError) as ve:
+        except InvalidRequest as ve:
             return 400, {"error": str(ve.args[0] if ve.args else ve)}
         first = q.next_event(timeout=600.0)
         if first is None:
@@ -705,6 +706,7 @@ class MegatronServer:
                     prefix_hit_tokens=eng.prefix_hit_tokens,
                     prefix_miss_tokens=eng.prefix_miss_tokens,
                     ticks=eng.ticks,
+                    engine_failures=eng.failures,
                     page_size=eng.page_size,
                     # quantized paged KV (ISSUE 13): storage mode + byte
                     # budget, so the router can route capacity-aware in

@@ -16,7 +16,6 @@ from typing import Optional
 
 __all__ = [
     "PEAK_BF16_FLOPS_BY_KIND",
-    "PEAK_BF16_FLOPS_SUBSTR",
     "device_peak_flops",
     "flops_per_step",
     "flops_per_token",
@@ -34,28 +33,22 @@ PEAK_BF16_FLOPS_BY_KIND = {
     "TPU v6 lite": 918e12,  # Trillium
     "TPU v6e": 918e12,
 }
-PEAK_BF16_FLOPS_SUBSTR = {
-    # substring fallback on normalized device_kind (live-device probing)
-    "v5litepod": 197e12,
-    "v5lite": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v6e": 918e12,
-}
 
 
 def device_peak_flops(device_kind: str) -> Optional[float]:
-    """Peak dense bf16 FLOP/s for a device-kind string, or None when the
-    kind is unknown (CPU hosts: an 'MFU' over a nominal CPU peak is not a
-    measurement — callers report 0/None instead)."""
-    if device_kind in PEAK_BF16_FLOPS_BY_KIND:  # exact kind first (v5p is
-        return PEAK_BF16_FLOPS_BY_KIND[device_kind]  # "TPU v5", no substr)
-    kind = device_kind.lower().replace(" ", "")
-    for key, val in PEAK_BF16_FLOPS_SUBSTR.items():
-        if key in kind:
-            return val
-    return None
+    """Peak dense bf16 FLOP/s for an exact ``device_kind`` string.
+
+    None on an explicit CPU run (an 'MFU' over a nominal CPU peak is not a
+    measurement — callers report 0/None instead).  Any other kind missing
+    from the table is an error: a measuring path does not divide by a
+    guess."""
+    if device_kind == "cpu":
+        return None
+    if device_kind not in PEAK_BF16_FLOPS_BY_KIND:
+        raise ValueError(
+            f"no peak FLOP/s known for device_kind {device_kind!r}: add it "
+            "to PEAK_BF16_FLOPS_BY_KIND with its source")
+    return PEAK_BF16_FLOPS_BY_KIND[device_kind]
 
 
 def param_count(cfg) -> int:
@@ -95,9 +88,10 @@ def mfu(cfg, tokens_per_sec: float,
         n_devices: int = 1) -> Optional[float]:
     """Model flops utilization (fraction) at a measured token rate.
 
-    ``peak`` wins when given; otherwise it is looked up from
-    ``device_kind``.  Returns None when no peak is known (CPU) — the
-    callers publish 0.0 / omit the field rather than a made-up number."""
+    ``peak`` (per device) wins when given; otherwise it is looked up from
+    ``device_kind``.  ``n_devices`` is every device the token rate was
+    produced on.  Returns None on a CPU run — the callers publish 0.0 /
+    omit the field rather than a made-up number."""
     if peak is None and device_kind is not None:
         peak = device_peak_flops(device_kind)
     if not peak or tokens_per_sec <= 0:

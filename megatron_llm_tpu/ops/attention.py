@@ -18,7 +18,7 @@ GQA). TPU-native differences:
 
 from __future__ import annotations
 
-from functools import partial
+import functools
 from typing import Optional
 
 import jax
@@ -27,25 +27,39 @@ import jax.numpy as jnp
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _flash_available() -> bool:
-    try:
-        from megatron_llm_tpu.ops.pallas import flash_attention  # noqa: F401
+@functools.lru_cache(maxsize=None)
+def announce_path(op: str, path: str, why: str = "") -> None:
+    """Say once, while a program is being traced, which implementation an
+    attention op resolved to — so a log shows whether a compiled program
+    holds the Pallas kernel or a jnp/XLA substitute, and why."""
+    print(f"[attention] {op}: {path}" + (f" ({why})" if why else ""),
+          flush=True)
 
-        return True
-    except ImportError:
-        return False
+
+def kernel_region(mesh):
+    """(mesh, axis_names) to shard_map a Pallas call over.
+
+    pallas_call is opaque to the GSPMD partitioner, so under a mesh the
+    kernel must be mapped explicitly.  Called from inside an enclosing
+    shard_map (the pipeline engines manualize pp/cp), the inner shard_map
+    must bind the CONTEXT abstract mesh — passing the concrete global mesh
+    raises a mesh-mismatch — and manualize every axis not already manual:
+    Mosaic kernels reject being left under ANY auto axis, even size-1."""
+    from megatron_llm_tpu.parallel import compat
+
+    abstract = compat.get_abstract_mesh()
+    if not abstract.empty and abstract.manual_axes:
+        return abstract, set(abstract.axis_names) - set(abstract.manual_axes)
+    return mesh, set(mesh.axis_names)
 
 
 def _flash_sharded(q, k, v, segment_ids, scale, sliding_window, block_q,
                    block_kv, causal=True):
     """Run the Pallas kernel, wrapped in shard_map when a non-trivial mesh is
-    active.
-
-    pallas_call is opaque to the GSPMD partitioner, so under pjit the kernel
-    must be mapped explicitly: batch over dp, heads over tp (attention is
-    embarrassingly parallel over both — the same decomposition the reference
-    gets from per-rank processes). Sequence stays whole here; context
-    parallelism (ring attention) shards it separately in parallel/ring.
+    active: batch over dp, heads over tp (attention is embarrassingly
+    parallel over both — the same decomposition the reference gets from
+    per-rank processes). Sequence stays whole here; context parallelism
+    (ring attention) shards it separately in parallel/ring.
     """
     from megatron_llm_tpu.core import parallel_state as ps
     from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
@@ -61,24 +75,9 @@ def _flash_sharded(q, k, v, segment_ids, scale, sliding_window, block_q,
 
     from jax.sharding import PartitionSpec as P
 
-    from megatron_llm_tpu.parallel import compat
     from megatron_llm_tpu.parallel.compat import shard_map
 
-    # Nested-manual composition: called from inside an enclosing shard_map
-    # (the pipeline engine manualizes pp/cp), the inner shard_map must bind
-    # the CONTEXT abstract mesh — passing the concrete global mesh raises a
-    # mesh-mismatch. The specs below reference only dp/ep/tp, which remain
-    # Auto in that context (same pattern as parallel/ring.cp_is_manual).
-    # Manualize every axis not already manual in the enclosing context:
-    # Mosaic kernels reject being left under ANY auto axis (even size-1),
-    # and an enclosing pipeline shard_map has already manualized pp/cp.
-    abstract = compat.get_abstract_mesh()
-    if abstract is not None and not abstract.empty and abstract.manual_axes:
-        mesh = abstract
-        names = set(mesh.axis_names) - set(mesh.manual_axes)
-    else:
-        names = set(mesh.axis_names)
-
+    mesh, names = kernel_region(mesh)
     qs = P(ps.DATA_AXES, None, ps.TP_AXIS, None)
     kvs = P(ps.DATA_AXES, None, ps.TP_AXIS, None)
     segs = P(ps.DATA_AXES, None)
@@ -194,10 +193,6 @@ def attention(
 
     from megatron_llm_tpu.core import parallel_state as ps
 
-    # compile-TARGET platform, not the host backend: AOT lowering for a TPU
-    # topology on a CPU host must still pick the flash kernel
-    on_tpu = ps.target_platform() == "tpu"
-
     cp = (
         ps.get_context_parallel_world_size()
         if ps.mesh_is_initialized()
@@ -210,36 +205,34 @@ def attention(
         )
         from megatron_llm_tpu.parallel.ring import ring_attention
 
+        announce_path("dense", "ring", f"cp={cp}")
         return ring_attention(
             q, k, v, segment_ids=segment_ids, token_idx=token_idx,
             causal=causal, sliding_window=sliding_window, scale=scale,
             zigzag=zigzag,
         )
-    flash_ok = (
-        use_flash
-        and bias is None
-        and dropout_rate == 0.0
-        # bidirectional (BERT / T5 encoder) runs the kernel with causal
-        # masking off — full or segment-gated attention
-        and token_idx is None  # kernel masks by storage order only
-        and on_tpu
-        and sq >= 128
-        and q.shape[-1] in (64, 128, 256)
-        and _flash_available()
-        # Round-4 note: pp x dp>1 x tp>1 used to fall back to xla_attention
-        # here — an XLA scatter-partitioner CHECK crash that turned out to
-        # be the EMBEDDING-grad scatter-add inside the pipeline tick loop,
-        # not the nested flash shard_map itself. Fixed at the root by the
-        # matmul-backward embedding under pp
-        # (models/language_model.py:_take_rows_matmul_bwd,
-        # tools/flash_nested_repro.py) — flash now dispatches at every
-        # sharding incl. the tp8 x pp8 x dp4 north star.
+    # compile-TARGET platform, not the host backend: AOT lowering for a TPU
+    # topology on a CPU host must still pick the flash kernel.  Bidirectional
+    # (BERT / T5 encoder) runs the kernel with causal masking off.
+    target = ps.target_platform()
+    refusal = (
+        "use_flash_attn is off" if not use_flash
+        else "explicit bias" if bias is not None
+        else "attention dropout" if dropout_rate != 0.0
+        # the kernel masks by storage order only
+        else "permuted token order" if token_idx is not None
+        else f"target platform is {target}" if target != "tpu"
+        else f"seq {sq} < 128" if sq < 128
+        else f"head_dim {q.shape[-1]}" if q.shape[-1] not in (64, 128, 256)
+        else None
     )
-    if flash_ok:
+    if refusal is None:
+        announce_path("dense", "pallas")
         return _flash_sharded(
             q, k, v, segment_ids, scale, sliding_window, block_q, block_kv,
             causal=causal,
         )
+    announce_path("dense", "xla", refusal)
     if bias is None:
         seg_q = seg_kv = segment_ids
         bias = make_attention_bias(

@@ -21,8 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from megatron_llm_tpu.parallel.compat import axis_index as _axis_index
-
 
 def softmax_cross_entropy(
     logits: jax.Array,
@@ -127,7 +125,7 @@ def vocab_parallel_cross_entropy(
     """
     logits_shard = logits_shard.astype(jnp.float32)
     vp = logits_shard.shape[-1]
-    rank = _axis_index(axis_name)
+    rank = jax.lax.axis_index(axis_name)
     vocab_start = rank * vp
 
     # stop_gradient BEFORE pmax: the max shift is gradient-free anyway and
@@ -163,7 +161,7 @@ def vocab_parallel_max_indices(
     """Global argmax over vocab-sharded logits (cross_entropy.py:146-175),
     used by the accuracy metric. Returns global vocab ids."""
     vp = logits_shard.shape[-1]
-    rank = _axis_index(axis_name)
+    rank = jax.lax.axis_index(axis_name)
     local_max = jnp.max(logits_shard, axis=-1)
     local_idx = jnp.argmax(logits_shard, axis=-1) + rank * vp
     # combine (max, idx) across ranks: pick idx of the global max

@@ -2,8 +2,10 @@
 
 Replaces the reference's fused CUDA LayerNorm (model/fused_layer_norm.py:26-61,
 layer_norm_cuda_kernel.cu) and pure-torch RMSNorm (fused_layer_norm.py:125-139).
-On TPU, XLA fuses these elementwise chains well; a Pallas fused RMSNorm kernel
-(ops/pallas/rmsnorm.py) is used on TPU for the hot path when enabled.
+On TPU, XLA fuses these elementwise chains well, and this jnp form is what the
+model path runs.  A Pallas fused RMSNorm kernel exists (ops/pallas/rmsnorm.py)
+but is NOT wired in: only tools/tpu_kernel_check.py and tools/mfu_sweep.py
+call it, the latter to decide whether it should be.
 
 Math matches the reference: internal computation in fp32, cast back to the
 input dtype (RMSNorm: ``x * rsqrt(mean(x^2) + eps) * w``).

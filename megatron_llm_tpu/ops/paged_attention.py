@@ -8,16 +8,21 @@ batch of sequences at heterogeneous positions — the Ragged-Paged-Attention
 decomposition (PAPERS.md): a single fused program per tick regardless of the
 per-sequence context lengths.
 
-Two implementations with identical numerics:
+Two implementations with the same fp32-softmax numerics:
 
 * ``ops/pallas/paged_attention.py`` — the TPU kernel: the block table is a
   scalar-prefetch operand, so each grid step DMAs exactly one page from the
   HBM pool into VMEM (no [b, max_seq] gather ever materializes) and the
   online-softmax accumulator carries across pages.
-* the jnp fallback below — gathers the block-tabled pages into a dense
-  [b, max_seq] view and reuses :func:`ops.attention.xla_attention`.  It is
-  bitwise-identical to the dense-cache decode path on the same context (the
-  parity contract tier-1 enforces on CPU, tests/test_paged_engine.py).
+* the jnp path below — gathers the block-tabled pages into a dense
+  [b, max_seq] view and reuses :func:`ops.attention.xla_attention`.  It
+  matches the dense-cache decode path on the same context (the parity
+  contract tier-1 enforces on CPU, tests/test_paged_engine.py) and is the
+  reference tools/tpu_kernel_check.py holds the compiled kernel to.
+
+Which one a traced program took is printed once per distinct reason
+(:func:`ops.attention.announce_path`); :func:`_kernel_refusal` is the
+whole rule.
 
 Page 0 of the pool is reserved as the *null page*: the engine never
 allocates it, inactive slots' block tables point at it, and writes routed
@@ -33,6 +38,7 @@ import jax.numpy as jnp
 
 from megatron_llm_tpu.ops import attention as attn_ops
 from megatron_llm_tpu.ops import kv_quant
+from megatron_llm_tpu.ops.pallas import paged_attention as pallas_paged
 
 
 class PagedState(NamedTuple):
@@ -107,13 +113,10 @@ def paged_attention_decode(
     b, _, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if use_kernel and _kernel_ok(q, k_pool):
-        from megatron_llm_tpu.ops.pallas.paged_attention import (
-            paged_decode_kernel,
-        )
-
-        return paged_decode_kernel(
-            q, k_pool, v_pool, block_tables, positions,
+    if _take_kernel("paged_decode", use_kernel, k_pool):
+        return _run_kernel(
+            pallas_paged.paged_decode_kernel, q, k_pool, v_pool,
+            (block_tables, positions),
             scale=scale, sliding_window=sliding_window,
         )
 
@@ -169,13 +172,10 @@ def paged_attention_ragged(
     b, _, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if use_kernel and _kernel_ok(q, k_pool):
-        from megatron_llm_tpu.ops.pallas.paged_attention import (
-            paged_ragged_kernel,
-        )
-
-        return paged_ragged_kernel(
-            q, k_pool, v_pool, tables, table_index, positions, horizons,
+    if _take_kernel("paged_ragged", use_kernel, k_pool):
+        return _run_kernel(
+            pallas_paged.paged_ragged_kernel, q, k_pool, v_pool,
+            (tables, table_index, positions, horizons),
             scale=scale, sliding_window=sliding_window,
         )
 
@@ -246,13 +246,10 @@ def paged_attention_prefill(
     b, s, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if use_kernel and _kernel_ok(q, k_pool):
-        from megatron_llm_tpu.ops.pallas.paged_attention import (
-            paged_prefill_kernel,
-        )
-
-        return paged_prefill_kernel(
-            q, k_pool, v_pool, block_tables, start,
+    if _take_kernel("paged_prefill", use_kernel, k_pool):
+        return _run_kernel(
+            pallas_paged.paged_prefill_kernel, q, k_pool, v_pool,
+            (block_tables, start),
             scale=scale, sliding_window=sliding_window,
         )
 
@@ -268,19 +265,59 @@ def paged_attention_prefill(
         q, k_all, v_all, bias=bias[:, None, :, :], scale=scale)
 
 
-def _kernel_ok(q: jax.Array, k_pool) -> bool:
-    """Kernel dispatch predicate — mirrors ops/attention.attention: TPU
-    compile target, supported head_dim, lane-aligned page."""
+def _kernel_refusal(k_pool) -> Optional[str]:
+    """Why the Pallas kernel cannot serve this call (None: it can).
+
+    The kernel reads one ``(page, d)`` block per grid step out of the
+    pool's ``[P, page, nkv*d]`` view, so Mosaic needs the lane extent ``d``
+    to be a multiple of 128 unless the view has a single head (the block is
+    then the whole last dim): Mistral/Mixtral/Llama (d=128) and d=256 take
+    the kernel, Falcon-40B (8 kv heads of 64) does not, Falcon-7B (one kv
+    head of 64) does.  Pages need 8 sublane rows; bf16, int8 and fp8 pools
+    all lower from 8 rows up (packed dtypes are unpacked after the DMA).
+    """
     from megatron_llm_tpu.core.parallel_state import target_platform
 
-    d = q.shape[-1]
-    page_size = kv_quant.page_size_of(k_pool)
-    try:
-        from megatron_llm_tpu.ops.pallas import paged_attention  # noqa: F401
-    except ImportError:
-        return False
-    return (
-        target_platform() == "tpu"
-        and d in (64, 128, 256)
-        and page_size % 8 == 0
-    )
+    arr = k_pool.q if kv_quant.is_quantized(k_pool) else k_pool
+    page_size, nkv, d = arr.shape[-3:]
+    target = target_platform()
+    if target != "tpu":
+        return f"target platform is {target}"
+    if d % 128 and nkv > 1:
+        return f"head_dim {d} is not a multiple of 128 lanes"
+    if page_size % 8:
+        return f"page_size {page_size} is not a multiple of 8 sublanes"
+    return None
+
+
+def _take_kernel(op: str, use_kernel: bool, k_pool) -> bool:
+    refusal = ("use_flash_attn is off" if not use_kernel
+               else _kernel_refusal(k_pool))
+    attn_ops.announce_path(op, "jnp" if refusal else "pallas", refusal or "")
+    return refusal is None
+
+
+def _run_kernel(kernel, q, k_pool, v_pool, tables, **kw):
+    """Call a Pallas paged kernel; under a tp > 1 mesh, shard_map it over
+    the heads (q heads and the pool's kv heads split the same way the qkv
+    column-parallel rule splits them; block tables and positions are
+    replicated) — pallas_call is opaque to the GSPMD partitioner."""
+    from megatron_llm_tpu.core import parallel_state as ps
+
+    if (not ps.mesh_is_initialized()
+            or ps.get_tensor_model_parallel_world_size() == 1):
+        return kernel(q, k_pool, v_pool, *tables, **kw)
+
+    from jax.sharding import PartitionSpec as P
+
+    from megatron_llm_tpu.parallel.compat import shard_map
+
+    mesh, names = attn_ops.kernel_region(ps.get_global_mesh())
+    heads = P(None, None, ps.TP_AXIS, None)
+    pool = (kv_quant.QuantPagedKV(q=heads, scale=P(None, ps.TP_AXIS))
+            if kv_quant.is_quantized(k_pool) else heads)
+    return shard_map(
+        lambda q_, k_, v_, *t: kernel(q_, k_, v_, *t, **kw),
+        mesh=mesh, in_specs=(heads, pool, pool) + (P(),) * len(tables),
+        out_specs=heads, axis_names=names, check_vma=False,
+    )(q, k_pool, v_pool, *tables)

@@ -1,37 +1,36 @@
-"""Pallas TPU paged-attention kernels (Ragged Paged Attention style).
+"""Pallas TPU paged-attention kernel (Ragged Paged Attention style).
 
-Two kernels over the same layout: one DECODE step for a batch of sequences
-whose KV lives in a shared page pool, and one PREFILL CHUNK (s query rows
-of one sequence against its block-tabled prefix — the prefix-cache engine's
-prefill-against-block-table mode, ISSUE 5).  The dense-cache decode
-attention reads a contiguous [b, max_seq] cache; here the block table is a
-*scalar-prefetch* operand (pltpu.PrefetchScalarGridSpec), so the BlockSpec
-index map resolves ``page_id = block_table[seq, j]`` before the grid step
-runs and the pipeline DMAs exactly that page from the HBM pool into VMEM —
-the [b, max_pages*page_size] gather of the jnp fallback
-(ops/paged_attention.py) never materializes.
+One kernel body serves the engine's three call shapes over the same page
+pool: a DECODE step (one query row per sequence), a PREFILL CHUNK (s query
+rows of one sequence against its block-tabled prefix) and a RAGGED tick
+(decode, verify and prefill rows flattened to single-token rows, each with
+its own table index and kv horizon).  The block table is a *scalar-prefetch*
+operand (pltpu.PrefetchScalarGridSpec), so the BlockSpec index map resolves
+``page_id = tables[table_index[row], j]`` before the grid step runs and the
+pipeline DMAs exactly that page from the HBM pool into VMEM — the
+[b, max_pages*page_size] gather of the jnp path (ops/paged_attention.py)
+never materializes.
 
-Grid ``(b, n_kv_heads, max_pages_per_seq)``, pages innermost: on TPU the
+Grid ``(rows, n_kv_heads, max_pages_per_seq)``, pages innermost: on TPU the
 grid is a sequential loop, so the online-softmax state (running max m,
 normalizer l, fp32 accumulator) lives in VMEM scratch and carries across
-page iterations of one (sequence, kv-head) pair — the same blockwise
-scheme as ops/pallas/flash_attention.py, with pages playing the role of KV
-blocks.  Pages past a row's context (``j*page_size > pos``) are skipped
-with @pl.when; GQA is native (q grouped [b, nkv, group, d], no K/V
-expansion).
+page iterations of one (row, kv-head) pair — the same blockwise scheme as
+ops/pallas/flash_attention.py, with pages playing the role of KV blocks.
+Pages past a row's context are skipped with @pl.when; GQA is native (q
+grouped [b, nkv, rows*group, d], no K/V expansion).
 
-Numerics match the fallback: fp32 logits/softmax/accumulator, outputs cast
-to the query dtype.
+Layout rule (Mosaic): the last two dims of a block must be (8k, 128k) or
+the array's own.  The pool ``[P, page, nkv, d]`` is therefore read through
+its contiguous view ``[P, page, nkv*d]`` with a ``(page, d)`` block at lane
+offset ``h*d`` — legal when ``d % 128 == 0`` (or ``nkv == 1``), see
+ops/paged_attention._kernel_ok.  Per-page scales ``[P, nkv]`` ride as a
+``(1, nkv)`` SMEM block of ``[P, 1, nkv]``; the step reads scalar ``h``.
 
-Quantized pools (ops/kv_quant.QuantPagedKV, ``--kv_dtype int8/fp8``): the
-page blocks arrive in their storage dtype and each grid step additionally
-receives that (page, kv-head)'s scale as a ``[1, 1]`` block — the
-int8/fp8 -> fp32 cast and the scale multiply happen right after the page
-DMA, inside the same step that consumes the page, so HBM traffic is the
-quantized bytes (the whole point: ~2x the pages per chip at the same
-bandwidth).  The online-softmax math is unchanged — dequantized pages
-enter the identical fp32 score/accumulate pipeline, matching the jnp
-fallback's dequantize-at-gather numerics.
+Numerics match the jnp path: fp32 logits/softmax/accumulator, outputs cast
+to the query dtype.  Quantized pools (ops/kv_quant.QuantPagedKV) arrive in
+their storage dtype; the int8/fp8 -> fp32 cast and the scale multiply
+happen inside the step that consumes the page, so HBM traffic is the
+quantized bytes.
 """
 
 from __future__ import annotations
@@ -49,126 +48,44 @@ from megatron_llm_tpu.ops import kv_quant
 NEG_INF = -1e30
 
 
-def _split_quant(k_pool, v_pool):
-    """(k_arr, v_arr, k_scale, v_scale) — scales are None for plain
-    pools.  The wrappers pass scales as extra [1, 1]-blocked operands so
-    the kernels dequantize in-register after the page DMA."""
-    if kv_quant.is_quantized(k_pool):
-        return k_pool.q, v_pool.q, k_pool.scale, v_pool.scale
-    return k_pool, v_pool, None, None
-
-
-def _decode_kernel(
-    # scalar prefetch
-    bt_ref,      # [b, max_pages] int32 block tables
-    pos_ref,     # [b] int32 query positions
+def _paged_kernel(
+    # scalar prefetch — all traced data, so one compiled launch serves any
+    # tick composition
+    tbl_ref,     # [T, max_pages] int32 block tables
+    idx_ref,     # [b] int32 row -> table
+    pos_ref,     # [b] int32 position of the row's first query
+    hor_ref,     # [b] int32 kv horizon in tokens (0 = dead row)
     # tensor refs: q, k-page, v-page [, k-scale, v-scale], out + scratch
-    # (quantized pools add two [1, 1] scale blocks — see _split_quant)
-    *refs,
-    scale: float,
-    page_size: int,
-    sliding_window: Optional[int],
-    quantized: bool = False,
-):
-    if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
-        ks_ref = vs_ref = None
-    i = pl.program_id(0)
-    j = pl.program_id(2)
-    first = j * page_size
-    pos = pos_ref[i]
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    run = first <= pos
-    if sliding_window is not None:
-        # page entirely below the window -> nothing to accumulate
-        run = jnp.logical_and(run, first + page_size > pos - sliding_window + 1)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # [g, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page, d]
-        if quantized:
-            # dequant fused into the page step: the DMA moved int8/fp8,
-            # the cast+scale happen here in-register
-            k = k * ks_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [g, page]
-        kv_pos = first + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        mask = kv_pos <= pos
-        if sliding_window is not None:
-            mask = jnp.logical_and(mask, pos - kv_pos < sliding_window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_s[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)
-        # fully-masked-so-far guard (flash_attention.py:_fwd_kernel): without
-        # it exp(NEG_INF - NEG_INF) = 1 would poison the accumulator
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur[:, None]))
-        l_s[:, 0] = alpha * l_s[:, 0] + jnp.sum(p, axis=1)
-        m_s[:, 0] = m_cur
-        v = v_ref[0, :, 0, :].astype(jnp.float32)      # [page, d]
-        if quantized:
-            v = v * vs_ref[0, 0]
-        acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        l = l_s[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[:] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-def _prefill_kernel(
-    # scalar prefetch
-    bt_ref,      # [b, kv_pages] int32 block tables (chunk horizon)
-    pos_ref,     # [b] int32 position of the chunk's first query
-    # tensor refs: q [1,1,s*g,d], k/v pages [, k/v scales], out + scratch
     *refs,
     scale: float,
     page_size: int,
     group: int,
     sliding_window: Optional[int],
-    quantized: bool = False,
+    quantized: bool,
 ):
-    """Chunked-prefill sibling of :func:`_decode_kernel`: same grid layout
-    and online-softmax page loop, but ``s*group`` query rows per
-    (sequence, kv-head) pair, each at its own position ``pos0 + row//group``
-    — the causal mask is per ROW, not per sequence.  Pages past the LAST
-    query's position are skipped; rows whose own position is below a page
-    mask it off inside the page step."""
+    """``rows = s*group`` query rows per (sequence, kv-head) pair, row ``r``
+    at position ``pos0 + r // group`` — the causal mask is per ROW.  Decode
+    and ragged calls have ``s == 1``."""
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
-        ks_ref = vs_ref = None
     i = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     first = j * page_size
     pos0 = pos_ref[i]
-    rows = q_ref.shape[2]
-    s_chunk = rows // group
-    last_pos = pos0 + s_chunk - 1
+    rows = q_ref.shape[0]
+    last_pos = pos0 + rows // group - 1
 
     @pl.when(j == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
 
-    run = first <= last_pos
+    # first < hor kills dead rows (horizon 0): they touch no page at all
+    run = jnp.logical_and(first <= last_pos, first < hor_ref[i])
     if sliding_window is not None:
         # page entirely below every query row's window -> skip
         run = jnp.logical_and(
@@ -176,10 +93,10 @@ def _prefill_kernel(
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale    # [rows, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [page, d]
+        q = q_ref[...].astype(jnp.float32) * scale          # [rows, d]
+        k = k_ref[...].astype(jnp.float32)                  # [page, d]
         if quantized:
-            k = k * ks_ref[0, 0]
+            k = k * ks_ref[0, h]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [rows, page]
@@ -192,112 +109,90 @@ def _prefill_kernel(
             mask = jnp.logical_and(mask, q_pos - kv_pos < sliding_window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_s[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_s[...]                                   # [rows, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur[:, None]))
-        l_s[:, 0] = alpha * l_s[:, 0] + jnp.sum(p, axis=1)
-        m_s[:, 0] = m_cur
-        v = v_ref[0, :, 0, :].astype(jnp.float32)       # [page, d]
+        # fully-masked-so-far guard (flash_attention.py:_fwd_kernel): without
+        # it exp(NEG_INF - NEG_INF) = 1 would poison the accumulator
+        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur))
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_s[...] = m_cur
+        v = v_ref[...].astype(jnp.float32)                  # [page, d]
         if quantized:
-            v = v * vs_ref[0, 0]
-        acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
+            v = v * vs_ref[0, h]
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        l = l_s[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[:] / l_safe[:, None]).astype(o_ref.dtype)
-
-
-def _ragged_kernel(
-    # scalar prefetch — the per-row ragged metadata (ISSUE 11): the
-    # tick's UNIQUE block tables, each row's table index, query position
-    # and bucketed kv horizon all arrive as data-carried prefetch
-    # operands, so ONE compiled launch serves any tick composition
-    # (decode slots, verify blocks, prefill chunks)
-    tbl_ref,     # [T, max_pages] int32 unique block tables
-    idx_ref,     # [R] int32 row -> table
-    pos_ref,     # [R] int32 query positions
-    hor_ref,     # [R] int32 kv horizons (tokens, 0 = dead row)
-    # tensor refs: q, k-page, v-page [, k-scale, v-scale], out + scratch
-    *refs,
-    scale: float,
-    page_size: int,
-    sliding_window: Optional[int],
-    quantized: bool = False,
-):
-    """Ragged sibling of :func:`_decode_kernel`: one query row per grid
-    step, same online-softmax page walk, but the page loop is bounded by
-    the row's own data-carried horizon — a dead row (horizon 0, the fixed
-    batch's padding) touches no page at all, and the accumulated work per
-    row scales with that row's context, not the widest row's."""
-    if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
-        ks_ref = vs_ref = None
-    i = pl.program_id(0)
-    j = pl.program_id(2)
-    first = j * page_size
-    pos = pos_ref[i]
-    hor = hor_ref[i]
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
-    # first <= pos gives bitwise the decode kernel's page set for live
-    # rows (hor >= pos + 1 by construction); first < hor kills dead rows
-    run = jnp.logical_and(first <= pos, first < hor)
-    if sliding_window is not None:
-        run = jnp.logical_and(run, first + page_size > pos - sliding_window + 1)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # [g, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page, d]
-        if quantized:
-            k = k * ks_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [g, page]
-        kv_pos = first + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        mask = kv_pos <= pos
-        if sliding_window is not None:
-            mask = jnp.logical_and(mask, pos - kv_pos < sliding_window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_s[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur[:, None]))
-        l_s[:, 0] = alpha * l_s[:, 0] + jnp.sum(p, axis=1)
-        m_s[:, 0] = m_cur
-        v = v_ref[0, :, 0, :].astype(jnp.float32)      # [page, d]
-        if quantized:
-            v = v * vs_ref[0, 0]
-        acc_s[:] = acc_s[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        l = l_s[:, 0]
+        l = l_s[...]
         # dead rows never ran a page: l == 0 -> exact zeros out
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_s[:] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_s[...] / l_safe).astype(o_ref.dtype)
+
+
+def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
+                *, group, scale, sliding_window, interpret):
+    """``qg`` [b, nkv, rows, d] kv-head-major query rows -> same shape."""
+    quantized = kv_quant.is_quantized(k_pool)
+    k_arr, v_arr = (k_pool.q, v_pool.q) if quantized else (k_pool, v_pool)
+    b, nkv, rows, d = qg.shape
+    num_pages, page_size, _, _ = k_arr.shape
+
+    def page_map(i, h, j, tbl, idx, pos, hor):
+        return (tbl[idx[i], j], 0, h)
+
+    def row_map(i, h, j, tbl, idx, pos, hor):
+        return (i, h, 0, 0)
+
+    page_spec = pl.BlockSpec((None, page_size, d), page_map)
+    row_spec = pl.BlockSpec((None, None, rows, d), row_map)
+    in_specs = [row_spec, page_spec, page_spec]
+    operands = [qg,
+                k_arr.reshape(num_pages, page_size, nkv * d),
+                v_arr.reshape(num_pages, page_size, nkv * d)]
+    if quantized:
+        scale_spec = pl.BlockSpec(
+            (None, 1, nkv),
+            lambda i, h, j, tbl, idx, pos, hor: (tbl[idx[i], j], 0, 0),
+            memory_space=pltpu.SMEM)
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_pool.scale.reshape(num_pages, 1, nkv),
+                     v_pool.scale.reshape(num_pages, 1, nkv)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, nkv, tables.shape[1]),
+        in_specs=in_specs,
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _paged_kernel, scale=scale, page_size=page_size, group=group,
+        sliding_window=sliding_window, quantized=quantized,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        interpret=interpret,
+        name="paged_attention",
+    )(tables.astype(jnp.int32), table_index.astype(jnp.int32),
+      positions.astype(jnp.int32), horizons.astype(jnp.int32), *operands)
+
+
+def _nkv(k_pool) -> int:
+    return (k_pool.q if kv_quant.is_quantized(k_pool) else k_pool).shape[2]
 
 
 def paged_ragged_kernel(
     q: jax.Array,             # [R, 1, n_heads, d]
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    k_pool,                   # [num_pages, page_size, n_kv_heads, d]
+    v_pool,
     tables: jax.Array,        # [T, max_pages_per_seq] int32 unique tables
     table_index: jax.Array,   # [R] int32 row -> table
     positions: jax.Array,     # [R] int32
@@ -307,75 +202,21 @@ def paged_ragged_kernel(
     sliding_window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch wrapper; returns [R, 1, n_heads, d] in q's dtype.
-
-    ONE launch for a whole ragged tick: every row of a mixed decode /
-    spec-verify / prefill batch is a grid step over its own block table
-    — resolved as ``tables[table_index[row], page]`` in the BlockSpec
-    index map, with (position, horizon) scalar-prefetched alongside.
-    All four operands are traced data — composition changes re-dispatch
-    the same executable, never recompile."""
-    k_arr, v_arr, k_scale, v_scale = _split_quant(k_pool, v_pool)
-    quantized = k_scale is not None
+    """ONE launch for a whole ragged tick; returns [R, 1, n_heads, d]."""
     b, _, n, d = q.shape
-    num_pages, page_size, nkv, _ = k_arr.shape
-    assert n % nkv == 0
+    nkv = _nkv(k_pool)
     g = n // nkv
-    max_pages = tables.shape[1]
-
-    qg = q.reshape(b, nkv, g, d)
-    grid = (b, nkv, max_pages)
-
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, page_size=page_size,
-        sliding_window=sliding_window, quantized=quantized,
-    )
-    page_spec = pl.BlockSpec((1, page_size, 1, d),
-                             lambda i, h, j, tbl, idx, pos, hor:
-                             (tbl[idx[i], j], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda i, h, j, tbl, idx, pos, hor: (i, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [qg, k_arr, v_arr]
-    if quantized:
-        # per-(page, head) dequant scale rides the page DMA as a [1, 1]
-        # block — the cast+multiply fuse into the page step
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda i, h, j, tbl, idx, pos, hor:
-                                  (tbl[idx[i], j], h))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda i, h, j, tbl, idx, pos, hor:
-                               (i, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), table_index.astype(jnp.int32),
-      positions.astype(jnp.int32), horizons.astype(jnp.int32),
-      *operands)
+    out = _paged_call(
+        q.reshape(b, nkv, g, d), k_pool, v_pool, tables, table_index,
+        positions, horizons, group=g, scale=scale,
+        sliding_window=sliding_window, interpret=interpret)
     return out.reshape(b, 1, n, d)
 
 
 def paged_prefill_kernel(
     q: jax.Array,             # [b, s, n_heads, d]
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    k_pool,
+    v_pool,
     block_tables: jax.Array,  # [b, kv_pages] int32 (chunk horizon)
     start: jax.Array,         # [b] int32 — position of q[:, 0]
     *,
@@ -383,66 +224,25 @@ def paged_prefill_kernel(
     sliding_window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch wrapper; returns [b, s, n_heads, d] in q's dtype."""
-    k_arr, v_arr, k_scale, v_scale = _split_quant(k_pool, v_pool)
-    quantized = k_scale is not None
+    """One prefill chunk; returns [b, s, n_heads, d]."""
     b, s, n, d = q.shape
-    num_pages, page_size, nkv, _ = k_arr.shape
-    assert n % nkv == 0
+    nkv = _nkv(k_pool)
     g = n // nkv
-    kv_pages = block_tables.shape[1]
-
-    # kv-head-major query rows: [b, nkv, s*g, d] so one grid step sees all
-    # of a kv head's query rows for the chunk
+    # kv-head-major query rows: one grid step sees all of a kv head's
+    # query rows for the chunk
     qg = q.reshape(b, s, nkv, g, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, nkv, s * g, d)
-    grid = (b, nkv, kv_pages)
-
-    kernel = functools.partial(
-        _prefill_kernel, scale=scale, page_size=page_size, group=g,
-        sliding_window=sliding_window, quantized=quantized,
-    )
-    page_spec = pl.BlockSpec((1, page_size, 1, d),
-                             lambda i, h, j, bt, pos: (bt[i, j], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, s * g, d),
-                     lambda i, h, j, bt, pos: (i, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [qg, k_arr, v_arr]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda i, h, j, bt, pos: (bt[i, j], h))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, s * g, d),
-                               lambda i, h, j, bt, pos: (i, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((s * g, 1), jnp.float32),
-            pltpu.VMEM((s * g, 1), jnp.float32),
-            pltpu.VMEM((s * g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, s * g, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), start.astype(jnp.int32),
-      *operands)
+    out = _paged_call(
+        qg.reshape(b, nkv, s * g, d), k_pool, v_pool, block_tables,
+        jnp.arange(b, dtype=jnp.int32), start, start + s, group=g,
+        scale=scale, sliding_window=sliding_window, interpret=interpret)
     return out.reshape(b, nkv, s, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, s, n, d)
 
 
 def paged_decode_kernel(
     q: jax.Array,             # [b, 1, n_heads, d]
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    k_pool,
+    v_pool,
     block_tables: jax.Array,  # [b, max_pages_per_seq] int32
     positions: jax.Array,     # [b] int32
     *,
@@ -450,53 +250,9 @@ def paged_decode_kernel(
     sliding_window: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Dispatch wrapper; returns [b, 1, n_heads, d] in q's dtype."""
-    k_arr, v_arr, k_scale, v_scale = _split_quant(k_pool, v_pool)
-    quantized = k_scale is not None
-    b, _, n, d = q.shape
-    num_pages, page_size, nkv, _ = k_arr.shape
-    assert n % nkv == 0
-    g = n // nkv
-    max_pages = block_tables.shape[1]
-
-    qg = q.reshape(b, nkv, g, d)
-    grid = (b, nkv, max_pages)
-
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, page_size=page_size,
-        sliding_window=sliding_window, quantized=quantized,
-    )
-    page_spec = pl.BlockSpec((1, page_size, 1, d),
-                             lambda i, h, j, bt, pos: (bt[i, j], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda i, h, j, bt, pos: (i, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [qg, k_arr, v_arr]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda i, h, j, bt, pos: (bt[i, j], h))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda i, h, j, bt, pos: (i, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      *operands)
-    return out.reshape(b, 1, n, d)
+    """One decode step; returns [b, 1, n_heads, d]."""
+    b = q.shape[0]
+    return paged_ragged_kernel(
+        q, k_pool, v_pool, block_tables, jnp.arange(b, dtype=jnp.int32),
+        positions, positions + 1, scale=scale,
+        sliding_window=sliding_window, interpret=interpret)

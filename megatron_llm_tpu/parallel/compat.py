@@ -1,354 +1,41 @@
-"""shard_map compatibility layer — the ONLY module allowed to import jax's
-shard_map directly (tools/linter.py enforces this).
+"""shard_map entry point — the ONLY module that spells jax's shard_map and
+its context-mesh accessor (tools/graftcheck/rules/shardmap.py and
+tools/linter.py enforce this), so the conventions the parallel/ and ops/
+code relies on are stated once:
 
-The parallel/ and ops/ code is written against the modern shard_map API:
-
-* ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., axis_names={...},
-  check_vma=False)`` — partial-manual regions declared by ``axis_names``;
-* ``jax.sharding.get_abstract_mesh()`` — the tracing-context mesh, whose
-  ``manual_axes`` tell a nested region which axes an enclosing shard_map has
-  already manualized (ops/attention._flash_sharded, parallel/ring.cp_is_manual).
-
-The pinned jax 0.4.37 has neither: only ``jax.experimental.shard_map`` with
-the older ``auto=``/``check_rep=`` spelling, and no context-mesh accessor.
-This module bridges the gap:
-
-* :func:`shard_map` accepts the modern signature and translates —
-  ``axis_names`` becomes its complement ``auto``, ``check_vma`` becomes
-  ``check_rep``, and an abstract-mesh argument (ours or jax's) resolves to
-  the concrete mesh it wraps.
-* :func:`get_abstract_mesh` emulates the context accessor with a
-  thread-local stack pushed while a compat shard_map body is being traced.
-* :func:`axis_index` works around ``lax.axis_index`` lowering to a
-  ``PartitionId`` op that XLA's SPMD lowering rejects inside PARTIAL-manual
-  regions on 0.4.37 (UNIMPLEMENTED under both GSPMD and shardy): for every
-  partial-manual region, :func:`shard_map` appends one hidden
-  ``jnp.arange(size)`` input per manual axis, sharded ``P(axis)``, so each
-  shard receives its own coordinate as data; :func:`axis_index` returns
-  that carried value when available and falls back to ``lax.axis_index``
-  (full-manual regions, or modern jax) otherwise.
-
-Partitioner note: 0.4.37's default GSPMD partitioner hard-crashes (CHECK
-failure in spmd_partitioner.cc:512) on ``ppermute`` inside partial-manual
-regions — the exact shape of the pipeline engine. The shardy partitioner
-handles every composition this repo uses — but globally flipping it
-perturbs reduction order in the plain pjit TP path (a bitwise-parity
-regression in tests/test_tensor_parallel.py), so the flip is scoped:
-:func:`mesh_needs_shardy` says whether a mesh layout reaches partial-manual
-code (pp > 1 or cp > 1), and ``parallel_state.set_global_mesh`` /
-``global_mesh`` call :func:`enable_partitioner_for` to flip (and restore)
-``jax_use_shardy_partitioner`` accordingly. Meshes that only use dp/ep/tp
-stay on GSPMD and keep today's bitwise behavior. ``MLT_NO_SHARDY=1`` opts
-out entirely for debugging.
-
-Residual-sharding patch: 0.4.37's ``_shard_map_partial_eval`` names vjp
-residuals over ALL mesh axes, which is rejected when the shard_map nests
-inside an enclosing manual region (the axes already manual cannot appear in
-a GSPMD spec). Fixed upstream in later jax; here
-:func:`_patch_partial_eval_residuals` subtracts the enclosing compat
-region's manual axes, which restores the exact upstream behavior for the
-compositions this repo uses (inner regions bind every remaining axis).
+* ``shard_map(f, mesh=..., in_specs=..., out_specs=..., axis_names={...},
+  check_vma=False)`` — partial-manual regions are declared by
+  ``axis_names`` (the axes THIS region manualizes; default every axis of
+  ``mesh``), the rest stay auto (GSPMD-partitioned);
+* ``get_abstract_mesh()`` — the tracing-context mesh, whose ``manual_axes``
+  tell a nested region which axes an enclosing shard_map has already
+  manualized (ops/attention.kernel_region, parallel/ring.cp_is_manual).  A
+  nested region passes that abstract mesh as its ``mesh=`` — the concrete
+  global mesh raises a mesh-mismatch there.
 """
 
 from __future__ import annotations
 
-import os
-import threading
-from typing import Any, Optional
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = [
-    "shard_map",
-    "get_abstract_mesh",
-    "axis_index",
-    "axis_size",
-    "mesh_needs_shardy",
-    "enable_partitioner_for",
-    "HAS_NATIVE_SHARD_MAP",
-]
-
-# Modern jax exposes the new API at the top level; 0.4.37 does not.
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-if not HAS_NATIVE_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-
-def mesh_needs_shardy(mesh) -> bool:
-    """True when this mesh layout reaches partial-manual shard_map code
-    (the pipeline engine and the ring-attention paths): on 0.4.37 those
-    must compile under the shardy partitioner (see module docstring)."""
-    if HAS_NATIVE_SHARD_MAP or os.environ.get("MLT_NO_SHARDY"):
-        return False
-    shape = getattr(mesh, "shape", {})
-    return shape.get("pp", 1) > 1 or shape.get("cp", 1) > 1
-
-
-def enable_partitioner_for(mesh) -> bool:
-    """Flip ``jax_use_shardy_partitioner`` if ``mesh`` needs it. Returns the
-    PREVIOUS flag value so ``parallel_state.global_mesh`` can restore it
-    (the flag participates in jit trace keys, so flipping is safe — cached
-    executables for the other partitioner are simply not reused)."""
-    prev = bool(jax.config.jax_use_shardy_partitioner)
-    if mesh_needs_shardy(mesh) and not prev:
-        jax.config.update("jax_use_shardy_partitioner", True)
-    return prev
-
-
-def restore_partitioner(prev: bool) -> None:
-    if bool(jax.config.jax_use_shardy_partitioner) != prev:
-        jax.config.update("jax_use_shardy_partitioner", prev)
-
-
-# ---------------------------------------------------------------------------
-# Context-mesh emulation
-# ---------------------------------------------------------------------------
-
-
-class CompatAbstractMesh:
-    """Duck-type of ``jax.sharding.AbstractMesh`` for the legacy path.
-
-    Carries the concrete mesh plus the axes manualized by the enclosing
-    compat shard_map regions; also usable as the ``mesh=`` argument of a
-    nested :func:`shard_map` (the modern nested-manual idiom).
-    """
-
-    def __init__(self, mesh: Optional[Mesh], manual_axes, index_vals=None):
-        self._mesh = mesh
-        self.manual_axes = frozenset(manual_axes)
-        # axis name -> per-shard coordinate scalar (partial-manual regions)
-        self._axis_index_vals = dict(index_vals or {})
-
-    @property
-    def empty(self) -> bool:
-        return self._mesh is None
-
-    @property
-    def axis_names(self):
-        return self._mesh.axis_names if self._mesh is not None else ()
-
-    @property
-    def shape(self):
-        return self._mesh.shape if self._mesh is not None else {}
-
-    def __repr__(self):
-        return (f"CompatAbstractMesh({self._mesh!r}, "
-                f"manual_axes={sorted(self.manual_axes)})")
-
-
-_EMPTY_MESH = CompatAbstractMesh(None, ())
-
-
-class _TraceContext(threading.local):
-    def __init__(self):
-        self.stack = []
-
-
-_trace_ctx = _TraceContext()
+__all__ = ["shard_map", "get_abstract_mesh"]
 
 
 def get_abstract_mesh():
-    """The mesh of the innermost shard_map region being traced (modern:
-    jax.sharding.get_abstract_mesh; legacy: the compat-tracked context).
-    ``.empty`` is True outside any region."""
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.sharding.get_abstract_mesh()
-    return _trace_ctx.stack[-1] if _trace_ctx.stack else _EMPTY_MESH
+    """The mesh of the innermost shard_map region being traced; ``.empty``
+    is True outside any region."""
+    return jax.sharding.get_abstract_mesh()
 
 
-def axis_index(name: str) -> jax.Array:
-    """``lax.axis_index`` that also works inside legacy partial-manual
-    regions (see module docstring). Identical semantics otherwise."""
-    if not HAS_NATIVE_SHARD_MAP and _trace_ctx.stack:
-        carried = _trace_ctx.stack[-1]._axis_index_vals.get(name)
-        if carried is not None:
-            return carried
-    return jax.lax.axis_index(name)
-
-
-def axis_size(name: str) -> int:
-    """``lax.axis_size`` (modern) — on 0.4.37 resolved from the compat
-    tracing context, falling back to ``psum(1, name)`` (which jax folds to
-    a constant) for regions bound by non-compat machinery."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    if _trace_ctx.stack:
-        top = _trace_ctx.stack[-1]
-        if name in top.shape:
-            return top.shape[name]
-    return jax.lax.psum(1, name)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-
-def _resolve_mesh(mesh: Any) -> Mesh:
-    if isinstance(mesh, CompatAbstractMesh):
-        assert mesh._mesh is not None, "shard_map over an empty mesh"
-        return mesh._mesh
-    return mesh
-
-
-def shard_map(
-    f,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    axis_names=None,
-    check_vma: Optional[bool] = None,
-    check_rep: Optional[bool] = None,
-):
-    """Modern-signature shard_map resolved against the running jax.
-
-    ``axis_names`` — axes THIS region manualizes (default: every axis of
-    ``mesh``); the rest stay auto (GSPMD-partitioned). ``mesh`` may be a
-    concrete Mesh, a modern AbstractMesh, or the CompatAbstractMesh from
-    :func:`get_abstract_mesh` when nesting inside an enclosing region.
-    ``check_vma`` (modern) / ``check_rep`` (legacy) are aliases.
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None or check_rep is not None:
-            kwargs["check_vma"] = bool(
-                check_vma if check_vma is not None else check_rep
-            )
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-    concrete = _resolve_mesh(mesh)
-    all_names = frozenset(concrete.axis_names)
-    manual = frozenset(axis_names) if axis_names is not None else all_names
-    unknown = manual - all_names
-    assert not unknown, f"axis_names {unknown} not in mesh {all_names}"
-    # Legacy spelling: ``auto`` is the complement of what this region binds.
-    # Axes an ENCLOSING region already manualized also belong in auto —
-    # 0.4.37 resolves them from the tracing context (verified by the nested
-    # compositions in tests/test_flash_sharded.py).
-    auto = all_names - manual
-    rep = check_vma if check_vma is not None else check_rep
-    # Partial-manual + replication checking is unsupported on 0.4.37; every
-    # caller passes False anyway.
-    rep = bool(rep) if rep is not None and not auto else False
-
-    outer = _trace_ctx.stack[-1] if _trace_ctx.stack else None
-    outer_manual = outer.manual_axes if outer is not None else frozenset()
-    outer_vals = outer._axis_index_vals if outer is not None else {}
-    region_manual = manual | outer_manual
-
-    # Hidden data-carried axis coordinates for partial-manual regions (the
-    # lax.axis_index workaround): one [size]-arange per newly-manual axis,
-    # sharded over that axis, so each shard's slice holds its coordinate.
-    partial = bool(auto)
-    idx_axes = tuple(sorted(manual)) if partial else ()
-
-    # NB: PartitionSpec subclasses tuple on 0.4.37 — test it first, or a
-    # bare spec would be exploded into its axis entries.
-    if isinstance(in_specs, P) or not isinstance(in_specs, (tuple, list)):
-        in_specs = (in_specs,)
-    full_in_specs = tuple(in_specs) + tuple(P(ax) for ax in idx_axes)
-
-    def wrapped(*args):
-        vals = dict(outer_vals)
-        if idx_axes:
-            n = len(idx_axes)
-            idx_args = args[-n:]
-            args = args[:-n]
-            vals.update({
-                ax: idx_args[i][0] for i, ax in enumerate(idx_axes)
-            })
-        ctx = CompatAbstractMesh(concrete, region_manual, vals)
-        _trace_ctx.stack.append(ctx)
-        try:
-            return f(*args)
-        finally:
-            _trace_ctx.stack.pop()
-
-    mapped = _legacy_shard_map(
-        wrapped, concrete, in_specs=full_in_specs, out_specs=out_specs,
-        check_rep=rep, auto=frozenset(auto),
-    )
-
-    def call(*args):
-        if idx_axes:
-            extra = tuple(
-                jnp.arange(concrete.shape[ax], dtype=jnp.int32)
-                for ax in idx_axes
-            )
-            return mapped(*args, *extra)
-        return mapped(*args)
-
-    return call
-
-
-# ---------------------------------------------------------------------------
-# 0.4.37 residual-sharding patch (see module docstring)
-# ---------------------------------------------------------------------------
-
-
-def _patch_partial_eval_residuals() -> None:
-    """0.4.37 names vjp/remat residuals ``{0: all_mesh_axes}`` — i.e. the
-    stacked-shards dim sharded over EVERY axis, including the region's auto
-    axes. For partial-manual regions that emits illegal shardings (manual
-    axes trailing free axes in the sdy annotation; outright rejected when
-    the region nests inside another manual region). Upstream later fixed
-    residual names to cover only the region's manual axes; this reproduces
-    that by threading each region's ``auto`` set through a contextvar into
-    ``_all_mesh_names_except_spmd``."""
-    import contextvars
-
-    from jax.experimental import shard_map as _sm_mod
-
-    cur_auto = contextvars.ContextVar("mlt_shard_map_auto",
-                                      default=frozenset())
-
-    orig_helper = _sm_mod._all_mesh_names_except_spmd
-
-    def helper(mesh, trace=None):
-        auto = cur_auto.get()
-        return tuple(n for n in orig_helper(mesh, trace) if n not in auto)
-
-    _sm_mod._all_mesh_names_except_spmd = helper
-
-    orig_pe = _sm_mod._shard_map_partial_eval
-
-    def pe_wrap(trace, shard_map_p, f, tracers, mesh, in_names,
-                out_names_thunk, check_rep, rewrite, auto):
-        token = cur_auto.set(frozenset(auto))
-        try:
-            return orig_pe(trace, shard_map_p, f, tracers, mesh, in_names,
-                           out_names_thunk, check_rep, rewrite, auto)
-        finally:
-            cur_auto.reset(token)
-
-    _sm_mod._shard_map_partial_eval = pe_wrap
-    # process_shard_map captured the original function object — rebind it.
-    _sm_mod.pe.JaxprTrace.process_shard_map = pe_wrap
-
-    orig_pcp = _sm_mod._pe_custom_params
-
-    def pcp_wrap(unks_in, inst_in, kept_outs_known, kept_outs_staged,
-                 in_fwd, out_fwd, which, params_known, params_staged):
-        token = cur_auto.set(
-            frozenset(params_known.get("auto", frozenset()))
-        )
-        try:
-            return orig_pcp(unks_in, inst_in, kept_outs_known,
-                            kept_outs_staged, in_fwd, out_fwd, which,
-                            params_known, params_staged)
-        finally:
-            cur_auto.reset(token)
-
-    _sm_mod._pe_custom_params = pcp_wrap
-
-
-if not HAS_NATIVE_SHARD_MAP:
-    _patch_partial_eval_residuals()
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
+              check_vma: Optional[bool] = None):
+    """``jax.shard_map`` with the keyword-only spelling every caller uses."""
+    kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kwargs["check_vma"] = bool(check_vma)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)

@@ -66,11 +66,9 @@ greedy tokens identical, per-token log-probs <= 5e-6 (bench_tp.py
 overlap arm + tests/test_tp_overlap.py), while ``--tp_overlap off``
 stays pinned bitwise.
 
-jax 0.4.37 note: the region is FULL-manual (``axis_names`` = every mesh
-axis) because partial-manual + ``ppermute`` hard-crashes the GSPMD
-partitioner (spmd_partitioner.cc:512 — the compat.py story).  That is
-also why overlap is gated to pp == cp == 1 meshes: pipeline/ring-
-attention code owns its own manual regions and the two must not nest.
+The region is FULL-manual (``axis_names`` = every mesh axis), and overlap
+is gated to pp == cp == 1 meshes: pipeline/ring-attention code owns its
+own manual regions and the two must not nest.
 """
 
 from __future__ import annotations
@@ -349,7 +347,7 @@ def row_parallel(cfg, p, x, fallback: Callable[[Any, Any], Any]):
         # holding seq chunk r fully reduced — the reduce-scatter result
         # the SP residual stream wants, no gather needed.
         wl = wl.astype(xl.dtype)
-        r = compat.axis_index(TP_AXIS)
+        r = jax.lax.axis_index(TP_AXIS)
         s_c = s // tp
 
         def chunk(c):
@@ -366,7 +364,7 @@ def row_parallel(cfg, p, x, fallback: Callable[[Any, Any], Any]):
         # then a tiled all_gather restores the replicated activation —
         # together, the all-reduce, pipelined against its own GEMM.
         wl = wl.astype(xl.dtype)
-        r = compat.axis_index(TP_AXIS)
+        r = jax.lax.axis_index(TP_AXIS)
         bl = xl.shape[0]
         rows = bl * s
         xf = xl.reshape(rows, xl.shape[-1])
@@ -424,7 +422,7 @@ def column_parallel(cfg, p, x, fallback: Callable[[Any, Any], Any]):
         # GEMM the chunk in hand while ppermute brings in the next; each
         # arriving chunk lands at its own seq offset.
         wl2 = wl.reshape(wl.shape[0], -1).astype(xl.dtype)
-        r = compat.axis_index(TP_AXIS)
+        r = jax.lax.axis_index(TP_AXIS)
         bl, s_c, _ = xl.shape
         y = jnp.zeros((bl, s_c * tp, wl2.shape[-1]), xl.dtype)
         buf = xl
@@ -512,7 +510,7 @@ def vocab_parallel(cfg, w, x, fallback: Callable[[Any, Any], Any]):
     def body(xl, wl):
         # xl [R, s, h] replicated, wl [h, V/tp] this rank's column shard.
         wl = wl.astype(xl.dtype)
-        r = compat.axis_index(TP_AXIS)
+        r = jax.lax.axis_index(TP_AXIS)
         rows = R * s
         xf = xl.reshape(rows, h)
         # y4[o, j] = owner o's sub-chunk j — assembled as blocks arrive.

@@ -62,12 +62,12 @@ def _stage_body(cfg, layers_local, x, aux, token_idx, dropout_key,
     the tick scan; the 1F1B schedules fold them into the per-stage vjp's
     aux output (see _1f1b_setup's aux_scalar).
     """
-    stage = compat.axis_index(PP_AXIS)
+    stage = jax.lax.axis_index(PP_AXIS)
     if dropout_key is not None and cfg.parallel.context_parallel_size > 1:
         # distinct dropout streams per cp seq-chunk (analog of the reference's
         # per-TP-rank RNG fork inside parallel regions, random.py:144-172)
         dropout_key = jax.random.fold_in(
-            dropout_key, compat.axis_index(CP_AXIS)
+            dropout_key, jax.lax.axis_index(CP_AXIS)
         )
     layers_per_stage = jax.tree_util.tree_leaves(layers_local)[0].shape[0]
     if layer_offset is None:
@@ -177,7 +177,7 @@ def pipeline_apply(cfg, mesh, stacked_layers, hidden_mb: jax.Array,
     layers_chunked = jax.tree.map(chunked, stacked_layers)
 
     def body(layers_local, hidden_mb, aux_mb, token_idx_local, mb_keys_local):
-        stage = compat.axis_index(PP_AXIS)
+        stage = jax.lax.axis_index(PP_AXIS)
         perm = [(i, (i + 1) % pp) for i in range(pp)]
         layers_local = jax.tree.map(lambda a: a[:, 0], layers_local)  # [v, Lc, ...]
 
@@ -436,7 +436,7 @@ def _1f1b_setup(cfg, batch, num_micro, dropout_key, embed_fn, head_loss_fn,
                      cfg.model.layernorm_epsilon, cfg.model.use_rms_norm)
             w = lm.head_weight(cfg, outer_p).astype(h.dtype)
             vc = w.shape[1] // pp_
-            rank = compat.axis_index(PP_AXIS)
+            rank = jax.lax.axis_index(PP_AXIS)
             wc = jax.lax.dynamic_slice_in_dim(w, rank * vc, vc, axis=1)
             per_token = vocab_parallel_cross_entropy(
                 h @ wc, lbl, axis_name=PP_AXIS)
@@ -593,7 +593,7 @@ def pipeline_1f1b_loss_and_grads(
 
     def body(layers_local, outer_p, tokens, labels, loss_mask, aux_mb,
              token_idx_local, embed_keys, layer_keys):
-        stage = compat.axis_index(PP_AXIS)
+        stage = jax.lax.axis_index(PP_AXIS)
         last = pp - 1
         perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
         perm_bwd = [(i, (i - 1) % pp) for i in range(pp)]
@@ -823,7 +823,7 @@ def pipeline_1f1b_interleaved_loss_and_grads(
 
     def body(layers_local, outer_p, tokens, labels, loss_mask, aux_mb,
              token_idx_local, embed_keys, layer_keys):
-        stage = compat.axis_index(PP_AXIS)
+        stage = jax.lax.axis_index(PP_AXIS)
         last = pp - 1
         perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
         perm_bwd = [(i, (i - 1) % pp) for i in range(pp)]

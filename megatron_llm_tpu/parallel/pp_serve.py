@@ -43,12 +43,6 @@ builders wrap their bodies in :func:`activate`, and
 through :func:`pipelined_transformer` when a context is live and the call
 carries paged K/V.  ``serve_params`` returns None on pp==1 meshes, so an
 inert ``--pp 1`` engine traces byte-for-byte today's program.
-
-jax 0.4.37 note: ``ppermute`` inside a partial-manual region crashes the
-GSPMD partitioner (spmd_partitioner.cc:512) — pp>1 engines flip to the
-shardy partitioner via ``compat.enable_partitioner_for`` (the flag
-participates in jit trace keys, so tp-only executables are never reused;
-see ``_mesh_statics``).
 """
 
 from __future__ import annotations
@@ -173,7 +167,7 @@ def pipelined_transformer(cfg, ctx: ServeParams, stacked_layers, hidden, *,
                                         meta_mb, rope))
 
     def body(layers_local, pools_local, x_mb, p_mb, kvp_mb, meta, rp):
-        stage = compat.axis_index(PP_AXIS)
+        stage = jax.lax.axis_index(PP_AXIS)
         n_local = jax.tree_util.tree_leaves(layers_local)[0].shape[0]
         perm = [(i, (i + 1) % pp) for i in range(pp)]
 

@@ -142,7 +142,7 @@ def make_quantized_dp_grad_fn(cfg, mesh: Mesh, loss_fn: Callable,
         from megatron_llm_tpu.models.language_model import make_rope_cache
 
         rope = make_rope_cache(cfg)
-        rank = compat.axis_index(DP_AXIS)
+        rank = jax.lax.axis_index(DP_AXIS)
 
         def scaled(p, mb, k):
             with jax.named_scope(fwd_scope):

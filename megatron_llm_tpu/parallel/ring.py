@@ -187,13 +187,7 @@ def _ring_case_index(src, i, causal):
 
 
 def _flash_shapes_ok(s: int, d: int) -> bool:
-    if d not in (64, 128, 256) or s < 128 or s % 128 != 0:
-        return False
-    try:
-        from megatron_llm_tpu.ops.pallas import flash_attention  # noqa: F401
-    except ImportError:
-        return False
-    return True
+    return d in (64, 128, 256) and s >= 128 and s % 128 == 0
 
 
 def _merge_chunk(acc, m_run, l_run, out_t, lse_t):
@@ -219,7 +213,7 @@ def _flash_ring_fwd_impl(qh, kh, vh, sq3, skv3, i, scale, causal, bq, bkv,
     ppermute does not have that problem — it stays inside."""
     from megatron_llm_tpu.ops.pallas.flash_attention import _fwd
 
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     b, n, s, d = qh.shape
     perm = _ring_perm(cp)
 
@@ -287,7 +281,7 @@ def _flash_ring_bwd(scale, causal, bq, bkv, interpret, axis_name,
     from megatron_llm_tpu.ops.pallas.flash_attention import _bwd
 
     qh, kh, vh, sq3, skv3, i, out, lse = residuals
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     perm = _ring_perm(cp)
     # delta = rowsum(do * o) is loop-invariant — computed ONCE here (XLA
     # cannot CSE across scan iterations; recomputing it per ring step would
@@ -380,7 +374,7 @@ def _flash_ring_zz_fwd_impl(qh, kh, vh, sq3, skv3, i, scale, causal, bq,
     from megatron_llm_tpu.ops.pallas.flash_attention import _fwd
 
     assert causal, "striped ring is causal-only (see module note)"
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     b, n, s, d = qh.shape
     c = s // 2
     perm = _ring_perm(cp)
@@ -460,7 +454,7 @@ def _flash_ring_zz_bwd(scale, causal, bq, bkv, interpret, axis_name,
     from megatron_llm_tpu.ops.pallas.flash_attention import _bwd
 
     qh, kh, vh, sq3, skv3, i, out, lse = residuals
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     b, n, s, d = qh.shape
     nkv = kh.shape[1]
     c = s // 2
@@ -577,7 +571,7 @@ def _ring_attention_flash(q, k, v, seg_q, seg_kv, *, axis_name, scale,
     # the cp coordinate is computed HERE — where the caller's context binds
     # cp — and passed in: lax.axis_index emitted inside the nested
     # shard_map would double-bind the axis (sdy verifier error)
-    i = compat.axis_index(axis_name)
+    i = jax.lax.axis_index(axis_name)
     if not auto:
         return _ring_attention_flash_core(q, k, v, seg_q, seg_kv, i, **kw)
     qs = P(ps.DATA_AXES, None, ps.TP_AXIS, None)
@@ -616,7 +610,7 @@ def _ring_attention_local(
     causal: bool,
     sliding_window: Optional[int],
 ) -> jax.Array:
-    cp = compat.axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     b, sq, n, d = q.shape
     nkv = k.shape[2]
     g = n // nkv
@@ -720,7 +714,7 @@ def _local_indices(token_idx: Optional[jax.Array], s_local: int, axis_name: str)
     """Global token indices of this device's chunk (contiguous by default)."""
     if token_idx is not None:
         return token_idx
-    return compat.axis_index(axis_name) * s_local + jnp.arange(s_local)
+    return jax.lax.axis_index(axis_name) * s_local + jnp.arange(s_local)
 
 
 # ---------------------------------------------------------------------------
@@ -767,22 +761,32 @@ def _dispatch_local(q, k, v, seg, tok, *, axis_name, scale, causal,
     rather than the dispatcher inspecting it.
     """
     from megatron_llm_tpu.core.parallel_state import target_platform
+    from megatron_llm_tpu.ops.attention import announce_path
 
     if target_platform() == "tpu" and sliding_window is None:
         if tok is None and _flash_shapes_ok(q.shape[1], q.shape[-1]):
+            announce_path("ring", "pallas", "contiguous chunks")
             return _ring_attention_flash(
                 q, k, v, seg, seg, axis_name=axis_name, scale=scale,
                 causal=causal, interpret=False)
         if (tok is not None and not causal
                 and _flash_shapes_ok(q.shape[1], q.shape[-1])):
+            announce_path("ring", "pallas", "permuted, non-causal")
             return _ring_attention_flash(
                 q, k, v, seg, seg, axis_name=axis_name, scale=scale,
                 causal=False, interpret=False)
         if (tok is not None and causal and zigzag and q.shape[1] % 2 == 0
                 and _flash_shapes_ok(q.shape[1] // 2, q.shape[-1])):
+            announce_path("ring", "pallas", "striped zigzag")
             return _ring_attention_flash(
                 q, k, v, seg, seg, axis_name=axis_name, scale=scale,
                 causal=True, interpret=False, striped=True)
+    announce_path(
+        "ring", "jnp",
+        f"target platform is {target_platform()}"
+        if target_platform() != "tpu"
+        else "sliding window" if sliding_window is not None
+        else "undeclared token permutation or off-tile shape")
     idx = _local_indices(tok, q.shape[1], axis_name)
     return _ring_attention_local(
         q, k, v, idx, idx, seg, seg,

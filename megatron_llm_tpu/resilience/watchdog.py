@@ -2,8 +2,8 @@
 
 The failure mode this targets is the worst one operationally: the process
 is alive, the loop is not advancing, and nothing ever prints — a wedged
-device tunnel, a deadlocked collective, a data loader blocked on a dead
-filesystem.  (PR 1's PJRT topology probe hang is the house example.)  A
+device, a deadlocked collective, a data loader blocked on a dead
+filesystem.  A
 supervisor cannot restart what never exits, so the watchdog's job is to
 *exit*, loudly:
 

@@ -36,7 +36,12 @@ from megatron_llm_tpu.checkpointing import (
     load_checkpoint,
     save_checkpoint,
 )
-from megatron_llm_tpu.core.parallel_state import build_mesh_from_config, global_mesh
+from megatron_llm_tpu.core.parallel_state import (
+    build_mesh_from_config,
+    get_global_mesh,
+    global_mesh,
+    placement_report,
+)
 from megatron_llm_tpu.core import rng as rng_mod
 from megatron_llm_tpu.data.batch_utils import get_ltor_batch
 from megatron_llm_tpu.models import init_model_params
@@ -71,13 +76,6 @@ def model_flops_per_token(cfg) -> float:
     accounting in observability/flops.py (kept here for the tools that
     import it from the driver)."""
     return flops_mod.flops_per_token(cfg)
-
-
-def _device_kind() -> str:
-    try:
-        return getattr(jax.devices()[0], "device_kind", "cpu")
-    except Exception:
-        return "cpu"
 
 
 def _train_valid_test_num_samples(cfg):
@@ -349,7 +347,9 @@ def training_log(cfg, metrics, iteration, step_time, writer, timers,
                   help="training throughput over the last interval").set(tps)
         reg.gauge("mlt_step_time_seconds",
                   help="mean step time over the last interval").set(step_time)
-        frac = flops_mod.mfu(cfg, tps, device_kind=_device_kind())
+        frac = flops_mod.mfu(cfg, tps,
+                             device_kind=jax.devices()[0].device_kind,
+                             n_devices=get_global_mesh().size)
         reg.gauge("mlt_steady_mfu",
                   help="model flops utilization over the last interval "
                        "(0 when no device peak is known)").set(frac or 0.0)
@@ -942,6 +942,9 @@ def pretrain(
 
                 signal_mod.signal(signal_mod.SIGUSR2, prev_usr2)
 
+        # after the last step: the allocator's view of the whole run
+        print0(placement_report(mesh, params=params, opt_state=opt_state),
+               flush=True)
         steady_sps = None
         if steady_t0 is not None and steady_steps > 0:
             steady_sps = steady_steps / max(steady_end - steady_t0, 1e-9)
@@ -951,8 +954,9 @@ def pretrain(
             # dict and the registry: the Megatron-style MFU signal
             steady_tps = (steady_sps * t.global_batch_size
                           * cfg.data.seq_length)
-            steady_mfu_val = flops_mod.mfu(cfg, steady_tps,
-                                           device_kind=_device_kind())
+            steady_mfu_val = flops_mod.mfu(
+                cfg, steady_tps, device_kind=jax.devices()[0].device_kind,
+                n_devices=mesh.size)
             if registry_mod.publishing():
                 reg = registry_mod.get_registry()
                 reg.gauge("mlt_tokens_per_sec").set(steady_tps)

@@ -371,11 +371,16 @@ def make_jitted_train_step(cfg, mesh: Mesh, params: Any,
     """
     if optimizer is None:
         optimizer = get_optimizer(cfg, params)
-    if opt_state is None:
-        opt_state = optimizer.init(params)
-
     p_shard = param_shardings(mesh, params)
-    o_shard = opt_state_shardings(cfg, mesh, params, opt_state)
+    if opt_state is None:
+        o_shard = opt_state_shardings(
+            cfg, mesh, params, jax.eval_shape(optimizer.init, params))
+        # born under its sharding: an eagerly initialized state is typed
+        # without the mesh, so the second step — fed the first step's
+        # mesh-typed outputs — would retrace and compile the program again
+        opt_state = jax.jit(optimizer.init, out_shardings=o_shard)(params)
+    else:
+        o_shard = opt_state_shardings(cfg, mesh, params, opt_state)
     cp = cfg.parallel.context_parallel_size > 1
     b_shard = NamedSharding(mesh, data_spec(cp))
     scalar = NamedSharding(mesh, P())

@@ -15,6 +15,7 @@ import jax
 from megatron_llm_tpu.config import parse_args
 from megatron_llm_tpu.models.bert import bert_loss_from_batch, init_bert_params
 from megatron_llm_tpu.training import pretrain
+from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
 
 def _special_ids(tokenizer, vocab_size: int):
@@ -78,6 +79,7 @@ def main():
     argv = sys.argv[1:]
     if "--model_name" not in argv:
         argv = ["--model_name", "bert"] + argv
+    enable_compilation_cache()
     cfg = parse_args(argv, n_devices=len(jax.devices()))
     from megatron_llm_tpu.models.bert import bert_pipeline_hooks
 

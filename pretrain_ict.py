@@ -24,6 +24,7 @@ from megatron_llm_tpu.retrieval.biencoder import (
     init_biencoder_params,
 )
 from megatron_llm_tpu.training import pretrain
+from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
 
 def _special_ids(tokenizer, vocab_size: int):
@@ -81,6 +82,7 @@ def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     if "--model_name" not in argv:
         argv = ["--model_name", "bert"] + argv
+    enable_compilation_cache()
     cfg = parse_args(argv, n_devices=len(jax.devices()))
     # ICT trains the towers at retriever_seq_length
     cfg.data.seq_length = cfg.retriever.retriever_seq_length

@@ -16,6 +16,7 @@ import jax
 from megatron_llm_tpu.config import parse_args
 from megatron_llm_tpu.models.t5 import init_t5_params, t5_loss_from_batch
 from megatron_llm_tpu.training import pretrain
+from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
 
 def extend_vocab_for_t5(cfg) -> None:
@@ -92,6 +93,7 @@ def main():
     argv = sys.argv[1:]
     if "--model_name" not in argv:
         argv = ["--model_name", "t5"] + argv
+    enable_compilation_cache()
     cfg = parse_args(argv, n_devices=len(jax.devices()))
     if cfg.model.vocab_size is None:
         from megatron_llm_tpu.tokenizer.tokenizer import build_tokenizer

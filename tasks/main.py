@@ -16,6 +16,7 @@ sys.path.append(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
 from megatron_llm_tpu.config import parse_args
+from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
 
 def get_tasks_args(parser):
@@ -298,6 +299,7 @@ def main():
                 "--task MSDP-EVAL-F1 requires --guess_file and --answer_file")
         return evaluate_f1(extra.guess_file, extra.answer_file)
 
+    enable_compilation_cache()
     cfg = parse_args(rest, n_devices=len(jax.devices()))
 
     if extra.task in ("WIKITEXT103", "LAMBADA"):

@@ -260,3 +260,31 @@ def test_server_queue_overflow_returns_503_with_retry_after():
             assert "queue full" in payload["error"]
     finally:
         srv.stop()
+
+
+def test_engine_failure_is_a_500_and_counted(batching_server):
+    """A program that fails to lower, compile or run is the server's
+    fault: a ValueError out of the engine's step (what a Pallas lowering
+    refusal raises) answers 500 — never the 400 reserved for requests
+    that can never be served — promptly, is counted in /health, and the
+    scheduler thread survives to serve the next request."""
+    url, engine = batching_server
+
+    def refused(pre_rows):
+        raise ValueError("The Pallas TPU lowering currently requires ...")
+
+    real = engine._ragged_tick
+    engine._ragged_tick = refused
+    try:
+        status, body = _put(url, {"prompts": ["hello"],
+                                  "tokens_to_generate": 4, "top_k": 1})
+    finally:
+        engine._ragged_tick = real
+    assert status == 500 and "Pallas TPU lowering" in body
+    with urllib.request.urlopen(url + "/health") as resp:
+        assert json.loads(resp.read())["engine_failures"] == 1
+    status, _ = _put(url, {"prompts": ["hello"], "tokens_to_generate": 4,
+                           "top_k": 1})
+    assert status == 200
+    status, body = _put(url, {"prompts": ["x"], "tokens_to_generate": 10 ** 6})
+    assert status == 400 and "longer than allowed" in body

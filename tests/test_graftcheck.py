@@ -10,8 +10,8 @@ Four layers:
     regression it exists to prevent (id-keyed cached_jit from PR 1, the
     direct shard_map import that cost 8 tests, a device-syncing
     instrument, unguarded shared state, pinned-key reuse);
-(c) the CLI contract — JSON schema, exit codes 0/1/2 (the tpu_watch
-    predicate distinguishes analyzer crashes from findings), and the
+(c) the CLI contract — JSON schema, exit codes 0/1/2 (a caller can tell
+    an analyzer crash from findings), and the
     tools/linter.py shim's legacy surface;
 (d) the full-repo sweep — zero non-baselined findings on this tree,
     every baseline entry explained, no stale entries, under the 30 s
@@ -76,7 +76,7 @@ FIXTURES = {
     ),
     "no-direct-shard-map": (
         f"from jax import {_SM}\n",
-        f'msg = "jax.{_SM} is unavailable on 0.4.37"\n'
+        f'msg = "jax.{_SM} belongs in parallel/compat.py"\n'
         f"from megatron_llm_tpu.parallel.compat import {_SM}\n",
     ),
     "sync-in-jit": (
@@ -305,8 +305,8 @@ def test_ragged_metadata_in_cached_jit_statics_flagged():
 
 
 def test_historic_direct_shard_map_import():
-    """The 8-failure jax-0.4.37 gap: every direct spelling is caught,
-    and compat.py itself is exempt."""
+    """Every direct spelling is caught, and compat.py itself is
+    exempt."""
     spellings = [
         f"from jax import {_SM}\n",
         f"import jax.experimental.{_SM}\n",
@@ -453,7 +453,7 @@ def test_docstring_prose_never_false_positives():
     fs = findings_for(obs, path="megatron_llm_tpu/observability/doc.py")
     assert not [f for f in fs if f.rule == "obs-no-sync"], fs
     sm = (
-        f'"""jax.{_SM} is unavailable on the pinned 0.4.37; use\n'
+        f'"""jax.{_SM} is spelled in one module only; use\n'
         "parallel/compat.py instead.\n"
         '"""\n'
         f'SPELLING = "jax.experimental.{_SM}"\n'
@@ -567,25 +567,6 @@ def test_update_baseline_roundtrip(tmp_path, capsys):
     doc2 = json.loads(bl.read_text())
     assert doc2["entries"][0]["reason"] == "legacy comment, tracked elsewhere"
     capsys.readouterr()
-
-
-def test_tpu_watch_job_registered():
-    """The graftcheck job is in the watch queue, bounded, with a
-    predicate that reads the one-line JSON: an analyzer crash (rc 2, no
-    summary) is 'not captured' (retried), findings are captured."""
-    from tools.tpu_watch import JOBS, _graftcheck_ran
-
-    by_name = {name: (cmd, bounded, pred)
-               for name, cmd, bounded, pred in JOBS}
-    assert "graftcheck" in by_name
-    cmd, bounded, pred = by_name["graftcheck"]
-    assert bounded, "graftcheck has no internal watchdog — needs timeout"
-    assert "--json" in cmd and "tools.graftcheck" in " ".join(cmd)
-    assert pred is _graftcheck_ran
-    assert pred('{"graftcheck": 1, "exit": 0}')
-    assert pred('noise\n{"graftcheck": 1, "exit": 1}')
-    assert not pred("Traceback (most recent call last):\n  boom\n")
-    assert not pred("")
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,8 @@
 RoPE position-interpolation scaling, long-seq masking, full remat, and the
 ring-attention row-blocked online softmax — exercised end to end in a
 train step at a CPU-tractable scaled-down width/seq. The full 32K e2e run
-is bench.py --seq 32768 --rope_scaling 8 (tools/tpu_watch.py job
-``bench_32k``); the AOT proof at real width is
+is bench.py --seq 32768 --rope_scaling 8 (on a TPU host); the AOT proof
+at real width is
 tools/aot_scale_check.py::codellama_34b_32k.
 """
 

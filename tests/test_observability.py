@@ -319,10 +319,20 @@ def test_peak_tables_single_source():
     import bench
 
     assert bench.PEAK_BF16_FLOPS_BY_KIND is flops_mod.PEAK_BF16_FLOPS_BY_KIND
-    assert bench.peak_flops  # still callable with its cpu-nominal fallback
+    assert bench.peak_flops() is None  # this process is pinned to the CPU
     assert flops_mod.device_peak_flops("TPU v5") == 459e12
-    assert flops_mod.device_peak_flops("TPU v5e somethingnew") == 197e12
     assert flops_mod.device_peak_flops("cpu") is None
+    # exact device_kind only: a measuring path does not divide by a guess
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        flops_mod.device_peak_flops("TPU v5e somethingnew")
+    # every device the rate was produced on shares the bill
+    from megatron_llm_tpu.models import make_config
+
+    cfg = make_config("llama2", num_layers=2, hidden_size=64,
+                      num_attention_heads=4, vocab_size=128, seq_length=32)
+    one = flops_mod.mfu(cfg, 1e4, device_kind="TPU v5 lite")
+    assert flops_mod.mfu(cfg, 1e4, device_kind="TPU v5 lite",
+                         n_devices=4) == pytest.approx(one / 4)
 
 
 # ---------------------------------------------------------------------------

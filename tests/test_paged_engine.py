@@ -75,27 +75,25 @@ def toy_model():
 # ---------------------------------------------------------------------------
 
 
-def test_paged_kernel_interpret_matches_fallback():
-    """The Pallas decode kernel (interpret mode) == the jnp gather fallback,
-    with and without a sliding window."""
-    from megatron_llm_tpu.ops.pallas.paged_attention import paged_decode_kernel
+@pytest.mark.parametrize("case", [
+    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32),
+    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, window=9),
+    dict(n=4, nkv=4, d=128, page=16, kv_dtype="int8"),
+    dict(n=4, nkv=1, d=64, page=8, kv_dtype="fp8", window=20),
+], ids=lambda c: "-".join(f"{k}{getattr(v, '__name__', v)}"
+                          for k, v in c.items()))
+def test_paged_kernels_interpret_match_jnp_path(case):
+    """The Pallas decode / prefill / ragged kernel (interpret mode) == the
+    jnp gather path, plain and quantized pools, with and without a sliding
+    window — the scenarios tools/tpu_kernel_check.py compiles on the chip."""
+    from tools.tpu_kernel_check import max_err, paged_case
 
-    rng = np.random.default_rng(0)
-    b, n, nkv, d = 3, 4, 2, 64
-    P, page, maxp = 9, 8, 4
-    q = jnp.asarray(rng.normal(size=(b, 1, n, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, nkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, nkv, d)), jnp.float32)
-    bt = jnp.asarray(rng.integers(1, P, size=(b, maxp)), jnp.int32)
-    pos = jnp.asarray([5, 17, 30], jnp.int32)
-
-    for sw in (None, 9):
-        ref = paged_attention_decode(q, kp, vp, bt, pos,
-                                     sliding_window=sw, use_kernel=False)
-        ker = paged_decode_kernel(q, kp, vp, bt, pos, scale=1.0 / d ** 0.5,
-                                  sliding_window=sw, interpret=True)
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
-                                   atol=2e-6, rtol=2e-6)
+    # fp32 inputs: both sides are fp32 end to end and differ by reduction
+    # order only; bf16 inputs (the quantized cases) round the output to
+    # bf16, so one output ulp (2^-7 at |x| < 2) is the bound
+    tol = 1e-5 if case.get("dtype") == jnp.float32 else 2e-2
+    for name, (pallas_fn, jnp_fn) in paged_case(0, **case).items():
+        assert max_err(pallas_fn(True), jnp_fn()) < tol, name
 
 
 def test_dense_vs_paged_model_forward_bitwise(toy_model):

@@ -33,21 +33,10 @@ import pytest
 from megatron_llm_tpu.core import parallel_state as ps
 from megatron_llm_tpu.generation.engine import ContinuousBatchingEngine
 from megatron_llm_tpu.models import init_model_params, make_config
-from megatron_llm_tpu.parallel import compat as compat_mod
 from megatron_llm_tpu.parallel import overlap as ovl_mod
 from megatron_llm_tpu.parallel import pp_serve as pp_serve_mod
 
 VOCAB = 512  # divisible by tp^2 for tp in {1, 2, 4} (vocab-ring columns)
-
-
-@pytest.fixture(autouse=True)
-def _restore_partitioner():
-    """pp>1 engines flip jax_use_shardy_partitioner and hold it for their
-    lifetime (parallel/compat.py) — restore after each test so this file
-    leaks no partitioner state into the rest of the suite."""
-    prev = bool(jax.config.jax_use_shardy_partitioner)
-    yield
-    compat_mod.restore_partitioner(prev)
 
 
 def _toy_cfg(num_layers=4, tp=1, vocab_ring=False):

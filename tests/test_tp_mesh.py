@@ -2,9 +2,9 @@
 
 Runs on the conftest 8-virtual-CPU-device mesh. Gates:
 
-* the compat shim (parallel/compat.py) resolves modern shard_map semantics
-  on the pinned jax — partial-manual regions, nesting, and the
-  data-carried ``axis_index`` workaround;
+* the shard_map entry point (parallel/compat.py) composes the way the
+  pipeline engines use it — a partial-manual region with a nested inner
+  region binding the remaining axes;
 * parallel/tp.py's param/batch rules land on real arrays (qkv
   column-parallel, fc2/dense row-parallel, vocab-parallel embedding) and
   degrade gracefully on a single-chip mesh;
@@ -63,14 +63,14 @@ def toy_model():
 
 
 # ---------------------------------------------------------------------------
-# compat shim
+# shard_map entry point
 # ---------------------------------------------------------------------------
 
 
-def test_compat_partial_manual_axis_index_and_nesting(eight_devices):
-    """Partial-manual region: ppermute works, compat.axis_index returns the
-    data-carried coordinate, a nested inner region binds the remaining
-    axes, and grads flow through the whole sandwich."""
+def test_partial_manual_axis_index_and_nesting(eight_devices):
+    """Partial-manual region: ppermute works, axis_index returns the
+    stage coordinate, a nested inner region binds the remaining axes,
+    and grads flow through the whole sandwich."""
     mesh = ps.build_mesh(tensor_model_parallel_size=2,
                          pipeline_model_parallel_size=2,
                          data_parallel_size=2, devices=eight_devices)
@@ -83,7 +83,7 @@ def test_compat_partial_manual_axis_index_and_nesting(eight_devices):
         am = compat.get_abstract_mesh()
         assert not am.empty
         assert set(am.manual_axes) == {ps.PP_AXIS, ps.CP_AXIS}
-        stage = compat.axis_index(ps.PP_AXIS)
+        stage = jax.lax.axis_index(ps.PP_AXIS)
         auto = set(am.axis_names) - set(am.manual_axes)
         inner = compat.shard_map(
             inner_fn, mesh=am, in_specs=(P(None, ps.TP_AXIS),),
@@ -99,7 +99,7 @@ def test_compat_partial_manual_axis_index_and_nesting(eight_devices):
         out = jax.jit(fn)(x)
         grads = jax.jit(jax.grad(lambda a: fn(a).sum()))(x)
     # the inner psum over tp sums the two column shards of x^2; each pp
-    # stage adds its (data-carried) stage index; out stacks the stages
+    # stage adds its stage index; out stacks the stages
     xsq = np.asarray(x * x)
     col_sum = xsq[:, :2] + xsq[:, 2:]
     expect = np.concatenate([col_sum + s for s in (0.0, 1.0)], 0)
@@ -107,17 +107,6 @@ def test_compat_partial_manual_axis_index_and_nesting(eight_devices):
     # loss = sum over both stages of sum(x^2)  =>  d/dx = 2 * 2x
     np.testing.assert_allclose(np.asarray(grads), 4.0 * np.asarray(x),
                                rtol=1e-6)
-
-
-def test_compat_axis_index_outside_region_falls_back(eight_devices):
-    """Full-manual region: compat.axis_index == lax.axis_index."""
-    mesh = ps.build_mesh(data_parallel_size=8, devices=eight_devices)
-    fn = compat.shard_map(
-        lambda: compat.axis_index(ps.DP_AXIS)[None],
-        mesh=mesh, in_specs=(), out_specs=P(ps.DP_AXIS), check_vma=False)
-    with ps.global_mesh(mesh):
-        out = jax.jit(fn)()
-    np.testing.assert_array_equal(np.asarray(out), np.arange(8))
 
 
 # ---------------------------------------------------------------------------
@@ -362,7 +351,7 @@ def test_engine_health_reports_mesh(toy_model, eight_devices):
 
 
 # ---------------------------------------------------------------------------
-# linter: the 0.4.37 gap cannot regress in
+# linter: one shard_map call site
 # ---------------------------------------------------------------------------
 
 
@@ -385,7 +374,7 @@ def test_linter_forbids_direct_shard_map(tmp_path, capsys):
 
     # comments/docstring prose is allowed
     ok = tmp_path / "prose.py"
-    ok.write_text("# jax.shard" + "_map is unavailable on 0.4.37\nx = 1\n")
+    ok.write_text("# jax.shard" + "_map lives in compat.py\nx = 1\n")
     assert lint_file(str(ok)) == 0
 
     # compat.py itself is exempt

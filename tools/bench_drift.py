@@ -1,7 +1,7 @@
 """Bench-trajectory drift detector — prints ONE JSON line for the driver.
 
 This tool is the trajectory-level check over the committed CPU-sanity
-bench rounds: it loads every ``BENCH_r*.json`` capture (the tpu_watch
+bench rounds: it loads every ``BENCH_r*.json`` capture (the
 round records, ``{"n": .., "parsed": {..}}``), orders them by round,
 computes per-metric drift — step time, compile time, tokens/sec —
 against the earliest round, and emits a one-line JSON verdict with
@@ -24,9 +24,8 @@ tripped threshold means bisect-the-code — after first checking, as
 round 5 teaches, what else was running on the host.
 
 Exit codes follow the graftcheck convention: 0 = no drift, 1 = drift
-detected (the verdict line IS the evidence), 2 = internal error.  The
-tpu_watch predicate treats any parseable verdict line as captured —
-drift is a finding to act on, not a reason to re-run.
+detected (the verdict line IS the evidence), 2 = internal error — drift
+is a finding to act on, not a reason to re-run.
 """
 
 from __future__ import annotations

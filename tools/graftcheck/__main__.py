@@ -1,6 +1,5 @@
 """``python -m tools.graftcheck`` entry point (also works when invoked
-from anywhere — the repo root is put on sys.path the way tpu_watch.py
-does it)."""
+from anywhere — the repo root is put on sys.path first)."""
 
 import os
 import sys

@@ -1,9 +1,8 @@
 """graftcheck core: file model, rule protocol, baseline, runner, CLI.
 
 The analyzer exists because this repo's expensive failures are *static*
-properties: a direct jax shard_map import compiles on modern jax and
-breaks the pinned 0.4.37 container (the 8-test regression of PR 6's
-prehistory); a compiled-program cache keyed on ``id()`` serves a stale
+properties: a shard_map spelled outside the one module that owns the
+convention (parallel/compat.py); a compiled-program cache keyed on ``id()`` serves a stale
 executable after GC recycles the id (PR 1); an instrument that syncs the
 device destroys the PR-2/PR-4 overlap it measures; and unguarded shared
 state races exactly once a quarter, in production.  A regex line scanner
@@ -37,8 +36,8 @@ Vocabulary:
   line-number drift.  Baselined findings don't fail the run; every entry
   carries a human reason.
 
-Exit codes (the tools/resilience_smoke.py convention, so the tpu_watch
-predicate can tell an analyzer crash from real findings):
+Exit codes (the tools/resilience_smoke.py convention, so a caller can
+tell an analyzer crash from real findings):
 
 * 0 — clean (no findings outside the baseline)
 * 1 — findings

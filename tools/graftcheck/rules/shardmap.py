@@ -1,13 +1,10 @@
-"""no-direct-shard-map: the pinned jax 0.4.37 has no top-level
-shard_map.
+"""no-direct-shard-map: one module owns jax's shard_map spellings.
 
-Every module must import shard_map / get_abstract_mesh / axis_index from
+Every module must import shard_map / get_abstract_mesh from
 ``megatron_llm_tpu/parallel/compat.py`` — the one module allowed to touch
-jax's own spellings (it translates the modern API onto 0.4.37's
-experimental module with its different kwargs, partitioner quirks and
-residual-naming bug).  A direct import compiles fine on newer jax and
-breaks the pinned container, which is exactly how the original 8-failure
-gap regressed in.
+jax's own spellings, so the repo's conventions for partial-manual regions
+and nesting are stated (and can change) in one place.  The retired
+``jax.experimental`` module stays forbidden too.
 
 The AST port fixes the regex scanner's blind spot: a *string literal* or
 docstring that discusses the forbidden spellings is prose, not an
@@ -31,12 +28,12 @@ from tools.graftcheck.core import FileContext, Finding, Rule, qualname
 _SM = "shard_map"
 _JAX_SM = "jax." + _SM                          # the modern-API spelling
 _JAX_EXP = "jax.experimental"
-_JAX_EXP_SM = _JAX_EXP + "." + _SM              # the 0.4.37 module
+_JAX_EXP_SM = _JAX_EXP + "." + _SM              # the retired module
 _JAX_GAM = "jax.sharding." + "get_abstract_mesh"
 
 _MSG = ("direct jax shard_map import/use — go through "
-        "megatron_llm_tpu/parallel/compat.py (jax 0.4.37 has no "
-        + _JAX_SM + "; see that module)")
+        "megatron_llm_tpu/parallel/compat.py (the one " + _JAX_SM
+        + " call site; see that module)")
 
 
 def _is_compat(path: str) -> bool:

@@ -90,7 +90,9 @@ def main():
     from megatron_llm_tpu.generation.server import MegatronServer
     from megatron_llm_tpu.models import init_model_params
     from megatron_llm_tpu.tokenizer import build_tokenizer
+    from megatron_llm_tpu.utils.platform import enable_compilation_cache
 
+    enable_compilation_cache()
     cfg = parse_args(
         ["--model_name", args.model_name] + extra
         + (["--tokenizer_type", args.tokenizer_type] if args.tokenizer_type else [])
@@ -100,47 +102,63 @@ def main():
     if cfg.model.vocab_size is None:
         cfg.model.vocab_size = tokenizer.vocab_size
 
+    # --tp N (--tensor_model_parallel_size) shards the engine over a named
+    # mesh: params by the parallel/tp.py rules, the KV pool over the heads
+    # dim — one engine then serves a model larger than a single chip's HBM.
+    # tp=1 keeps the single-chip engine unchanged.  --pp N
+    # (--pipeline_model_parallel_size) additionally runs the tick as pp
+    # pipeline stages (parallel/pp_serve.py): each stage holds L/pp layers
+    # of params AND pool — tp*pp chips per replica.  --pp 1 builds no mesh
+    # axis work at all (byte-for-byte the flat engine).
+    mesh = None
+    if not args.legacy_engine and (
+            cfg.parallel.tensor_model_parallel_size > 1
+            or cfg.parallel.pipeline_model_parallel_size > 1):
+        from megatron_llm_tpu.core.parallel_state import (
+            build_mesh, set_global_mesh,
+        )
+
+        mesh = build_mesh(
+            tensor_model_parallel_size=(
+                cfg.parallel.tensor_model_parallel_size),
+            pipeline_model_parallel_size=(
+                cfg.parallel.pipeline_model_parallel_size),
+            data_parallel_size=1,
+        )
+        set_global_mesh(mesh)
+        print(f"engine mesh: {dict(mesh.shape)}", flush=True)
+
+    # params are born under their shardings (one jitted init, or a sharded
+    # load): a full copy on the first device would cap the servable model
+    # at one chip's HBM however many chips the mesh has
     key = jax.random.PRNGKey(cfg.training.seed)
+    template = jax.eval_shape(lambda k: init_model_params(cfg, k), key)
+    shardings = None
+    if mesh is not None:
+        from megatron_llm_tpu.parallel.tp import param_shardings
+
+        shardings = param_shardings(mesh, template)
     if args.random_init:
-        params = init_model_params(cfg, key)
+        params = jax.jit(lambda k: init_model_params(cfg, k),
+                         out_shardings=shardings)(key)
     else:
         if not args.load:
             ap.error("--load is required unless --random_init")
         from megatron_llm_tpu.checkpointing import load_checkpoint
 
-        template = jax.eval_shape(
-            lambda k: init_model_params(cfg, k), key)
-        params, _, _, _, _ = load_checkpoint(cfg, args.load, template)
+        params, _, _, _, _ = load_checkpoint(
+            cfg, args.load, template, param_shardings=shardings)
 
     if args.legacy_engine:
         engine = InferenceEngine(cfg, params, tokenizer)
     else:
-        # --tp N (--tensor_model_parallel_size) shards the engine over a
-        # named mesh: params by the parallel/tp.py rules, the KV pool over
-        # the heads dim — one engine then serves a model larger than a
-        # single chip's HBM. tp=1 keeps the single-chip engine unchanged.
-        # --pp N (--pipeline_model_parallel_size) additionally runs the
-        # tick as pp pipeline stages (parallel/pp_serve.py): each stage
-        # holds L/pp layers of params AND pool, multiplying the servable
-        # model size again — tp*pp chips per replica. --pp 1 builds no
-        # mesh axis work at all (byte-for-byte the flat engine).
-        mesh = None
-        if (cfg.parallel.tensor_model_parallel_size > 1
-                or cfg.parallel.pipeline_model_parallel_size > 1):
-            from megatron_llm_tpu.core.parallel_state import (
-                build_mesh, set_global_mesh,
-            )
-
-            mesh = build_mesh(
-                tensor_model_parallel_size=(
-                    cfg.parallel.tensor_model_parallel_size),
-                pipeline_model_parallel_size=(
-                    cfg.parallel.pipeline_model_parallel_size),
-                data_parallel_size=1,
-            )
-            set_global_mesh(mesh)
-            print(f"engine mesh: {dict(mesh.shape)}", flush=True)
         engine = ContinuousBatchingEngine(cfg, params, tokenizer, mesh=mesh)
+        if mesh is not None:
+            from megatron_llm_tpu.core.parallel_state import placement_report
+
+            print(placement_report(
+                mesh, params=engine.params,
+                kv_pool=(engine.pool.k, engine.pool.v)), flush=True)
     server = MegatronServer(engine, register_url=args.register_url,
                             register_interval_s=args.register_interval,
                             advertise_url=args.advertise_url,

@@ -1,16 +1,29 @@
-"""Validate + profile the Pallas kernels on real TPU hardware.
+"""Validate the Pallas kernels, compiled, on a TPU.
 
 The CPU test suite runs every kernel in interpret mode
-(tests/test_flash_attention.py); this tool is the hardware half of the
-reference's fused-kernel test discipline (fused_kernels/tests/
-test_fused_kernels.py): compiled-vs-interpret numerics, block-size timing
-sweeps, and a long-sequence (32K) memory-fit check.
+(tests/test_flash_attention.py, tests/test_paged_engine.py); this tool is
+the hardware half of the reference's fused-kernel test discipline
+(fused_kernels/tests/test_fused_kernels.py).  Each compiled kernel is held
+to the repo's jnp implementation of the same op on the same inputs:
 
-Usage (on a TPU host):
+* flash attention fwd + bwd against ``ops.attention.xla_attention``;
+* the paged decode / prefill / ragged kernels, on plain and quantized
+  pools, against the gather path of ``ops.paged_attention``;
+* RMSNorm against ``ops.norms.rms_norm``.
+
+Tolerance: inputs are bf16 and both sides accumulate in fp32, so outputs
+(|x| of order 1) may differ by a few bf16 ulps of the output and of the
+probabilities fed to the second matmul: 0.05 absolute, the bound the flash
+check has always used.  Quantized pools share one dequantized value per
+element on both sides, so the same bound holds.
+
+Usage (through the chip tool; off-TPU it exits 2):
     python tools/tpu_kernel_check.py [--quick]
 
-Prints one PASS/FAIL line per check and a timing table; exit code 0 iff all
-checks pass.
+``--quick`` is numerics at the preset geometries only (chip_smoke.py's
+kernel phase).  The full run adds the page-size/dtype matrix, block-size
+timing sweeps and a long-sequence (32K) memory-fit check.  Prints one
+PASS/FAIL line per check; exit code 0 iff all checks pass.
 """
 
 from __future__ import annotations
@@ -49,22 +62,29 @@ def max_err(a, b):
     return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
 
 
-def numerics_checks():
-    """Compiled TPU kernel vs interpret-mode ground truth, fwd + bwd."""
+TOL = 0.05  # module docstring
+
+
+def flash_numerics(quick: bool):
+    """Compiled flash kernel vs the XLA attention path, fwd + bwd."""
+    from megatron_llm_tpu.ops.attention import make_attention_bias, xla_attention
     from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
 
     cases = [
         # name, b, s, n, nkv, d, window, segmented, causal
-        ("causal", 2, 1024, 8, 8, 128, None, False, True),
         ("gqa4", 2, 1024, 8, 2, 128, None, False, True),
-        ("sliding256", 1, 2048, 4, 4, 128, 256, False, True),
-        ("segments", 1, 1024, 4, 4, 128, None, True, True),
         ("gqa_sliding", 1, 2048, 8, 2, 128, 512, False, True),
-        ("d256", 1, 2048, 4, 4, 256, None, False, True),  # VMEM cap path
-        # bidirectional dispatch (BERT / pipelined T5 encoder)
-        ("bidir", 2, 1024, 8, 8, 128, None, False, False),
-        ("bidir_segments", 1, 1024, 4, 4, 128, None, True, False),
+        ("segments", 1, 1024, 4, 4, 128, None, True, True),
     ]
+    if not quick:
+        cases += [
+            ("causal", 2, 1024, 8, 8, 128, None, False, True),
+            ("sliding256", 1, 2048, 4, 4, 128, 256, False, True),
+            ("d256", 1, 2048, 4, 4, 256, None, False, True),  # VMEM cap path
+            # bidirectional dispatch (BERT / pipelined T5 encoder)
+            ("bidir", 2, 1024, 8, 8, 128, None, False, False),
+            ("bidir_segments", 1, 1024, 4, 4, 128, None, True, False),
+        ]
     for name, b, s, n, nkv, d, window, segmented, causal in cases:
         q, k, v = rand_qkv(jax.random.PRNGKey(17), b, s, n, nkv, d)
         seg = None
@@ -72,39 +92,149 @@ def numerics_checks():
             seg = (jnp.arange(s)[None, :] >= s // 3).astype(jnp.int32)
             seg = jnp.broadcast_to(seg, (b, s))
 
-        def f(q, k, v, interpret):
+        def kernel(q, k, v):
             out = flash_attention(q, k, v, causal=causal, sliding_window=window,
-                                  segment_ids=seg, interpret=interpret)
+                                  segment_ids=seg)
             return (out.astype(jnp.float32) * 0.01).sum(), out
 
-        (_, out_t), grads_t = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
-            q, k, v, None)  # None = compiled on TPU, interpret on CPU
-        (_, out_i), grads_i = jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
-            q, k, v, True)
+        def reference(q, k, v):
+            bias = make_attention_bias(
+                s, causal=causal, sliding_window=window,
+                segment_ids_q=seg, segment_ids_kv=seg)
+            out = xla_attention(q, k, v, bias=bias)
+            return (out.astype(jnp.float32) * 0.01).sum(), out
 
-        e_out = max_err(out_t, out_i)
-        # bf16 inputs, fp32 internals: interpret and MXU differ by bf16 ulp
-        check(f"flash fwd {name}", e_out < 0.05, f"max_err={e_out:.2e}")
-        for gname, gt, gi in zip("dq dk dv".split(), grads_t, grads_i):
-            e = max_err(gt, gi)
-            check(f"flash bwd {name} {gname}", e < 0.05, f"max_err={e:.2e}")
+        def grads(f):
+            # one program per case: the check exists to compile each one
+            return jax.jit(jax.value_and_grad(  # graftcheck: noqa[recompile-hazard]
+                f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+        (_, out_k), grads_k = grads(kernel)
+        (_, out_r), grads_r = grads(reference)
+        e_out = max_err(out_k, out_r)
+        check(f"flash fwd {name}", e_out < TOL, f"max_err={e_out:.2e}")
+        for gname, gk, gr in zip("dq dk dv".split(), grads_k, grads_r):
+            e = max_err(gk, gr)
+            check(f"flash bwd {name} {gname}", e < TOL, f"max_err={e:.2e}")
+
+
+def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
+               kv_dtype: str = "bf16", window=None, dtype=jnp.bfloat16):
+    """One random paged-attention scenario and its three call shapes.
+
+    Returns ``{name: (pallas_fn, jnp_fn)}`` — thunks over the same pool and
+    block tables: ``pallas_fn(interpret)`` calls the kernel wrapper directly,
+    ``jnp_fn()`` the gather path.  Shared with tests/test_paged_engine.py,
+    which runs the kernels in interpret mode on the CPU.
+    """
+    import numpy as np
+
+    from megatron_llm_tpu.ops import kv_quant
+    from megatron_llm_tpu.ops import paged_attention as pa
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    rng = np.random.default_rng(seed)
+    num_pages, maxp, b, s = 40, 12, 4, 2 * page
+    vals = [jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
+            for _ in range(2)]
+    if kv_dtype == "bf16":
+        kp, vp = vals
+    else:
+        kp, vp = (kv_quant.quantize_pages(x, kv_dtype) for x in vals)
+    scale = 1.0 / d ** 0.5
+    kw = dict(scale=scale, sliding_window=window)
+    # page ids never repeat within a table; page 0 stays the null page
+    bt = jnp.asarray(np.stack([rng.permutation(num_pages - 1)[:maxp] + 1
+                               for _ in range(b)]), jnp.int32)
+    limit = maxp * page
+    pos = jnp.asarray([0, page - 1, page, limit - 1][:b], jnp.int32)
+    q1 = jnp.asarray(rng.normal(size=(b, 1, n, d)), dtype)
+
+    # prefill: one chunk of s rows starting mid-sequence, page-aligned
+    start = jnp.asarray([3 * page], jnp.int32)
+    qs = jnp.asarray(rng.normal(size=(1, s, n, d)), dtype)
+    bt1 = bt[:1]
+
+    # ragged: 3 tables (null + two live), rows mix a decode row, a dead
+    # row (horizon 0, null table) and a run of consecutive prefill rows
+    tables = jnp.concatenate([jnp.zeros((1, maxp), jnp.int32), bt[:2]])
+    r_pos = np.array([limit - 2, 0] + list(range(page + 1, page + 7)), np.int32)
+    r_idx = np.array([1, 0] + [2] * 6, np.int32)
+    r_hor = np.where(r_idx > 0, (r_pos // 64 + 1) * 64, 0).astype(np.int32)
+    live = np.flatnonzero(r_idx)
+    r_pos, r_idx, r_hor = (jnp.asarray(a) for a in (r_pos, r_idx, r_hor))
+    qr = jnp.asarray(rng.normal(size=(r_pos.shape[0], 1, n, d)), dtype)
+
+    return {
+        "decode": (
+            lambda interpret=False: pk.paged_decode_kernel(
+                q1, kp, vp, bt, pos, interpret=interpret, **kw),
+            lambda: pa.paged_attention_decode(
+                q1, kp, vp, bt, pos, use_kernel=False, **kw)),
+        "prefill": (
+            lambda interpret=False: pk.paged_prefill_kernel(
+                qs, kp, vp, bt1, start, interpret=interpret, **kw),
+            lambda: pa.paged_attention_prefill(
+                qs, kp, vp, bt1, start, use_kernel=False, **kw)),
+        # live rows only: a dead row is exact zeros from the kernel and
+        # null-page garbage from the gather path, by design
+        "ragged": (
+            lambda interpret=False: pk.paged_ragged_kernel(
+                qr, kp, vp, tables, r_idx, r_pos, r_hor,
+                interpret=interpret, **kw)[live],
+            lambda: pa.paged_attention_ragged(
+                qr, kp, vp, tables, r_idx, r_pos, r_hor,
+                use_kernel=False, **kw)[live]),
+    }
+
+
+def paged_numerics(quick: bool):
+    """Compiled paged kernels vs the jnp gather path."""
+    # the preset head geometries: Mistral/Mixtral/Llama-3 32q/8kv x 128,
+    # Llama-2 32/32 x 128, at the engine's default page size
+    cases = [dict(n=32, nkv=8, d=128, page=16, kv_dtype=kvd, window=w)
+             for kvd, w in (("bf16", None), ("bf16", 24), ("int8", None))]
+    cases += [dict(n=32, nkv=32, d=128, page=16, kv_dtype=kvd)
+              for kvd in ("bf16", "int8")]
+    if not quick:
+        cases += [dict(n=32, nkv=8, d=128, page=page, kv_dtype=kvd)
+                  for page in (8, 32, 128) for kvd in ("bf16", "int8", "fp8")]
+        cases += [dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8"),
+                  dict(n=8, nkv=2, d=256, page=16),
+                  dict(n=8, nkv=2, d=256, page=16, kv_dtype="int8"),
+                  # Falcon-7B: one kv head of 64
+                  dict(n=8, nkv=1, d=64, page=16),
+                  dict(n=8, nkv=1, d=64, page=16, kv_dtype="int8")]
+    for i, case in enumerate(cases):
+        tag = " ".join(f"{k}={v}" for k, v in case.items())
+        for name, (pallas_fn, jnp_fn) in paged_case(i, **case).items():
+            try:
+                e = max_err(pallas_fn(), jnp_fn())
+                check(f"paged {name} {tag}", e < TOL, f"max_err={e:.2e}")
+            except Exception as exc:  # a compiler refusal is a FAIL line
+                check(f"paged {name} {tag}", False,
+                      f"{type(exc).__name__}: {str(exc)[:300]}")
 
 
 def rmsnorm_check():
+    from megatron_llm_tpu.ops.norms import rms_norm
     from megatron_llm_tpu.ops.pallas.rmsnorm import fused_rms_norm
 
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 1024, 2048), jnp.bfloat16)
     w = jax.random.normal(jax.random.PRNGKey(4), (2048,), jnp.float32) * 0.1 + 1.0
 
-    def f(x, w, interpret):
-        y = fused_rms_norm(x, w, interpret=interpret)
-        return (y.astype(jnp.float32) * 0.01).sum(), y
+    def grads(norm):
+        def f(x, w):
+            y = norm(x, w)
+            return (y.astype(jnp.float32) * 0.01).sum(), y
 
-    (_, y_t), g_t = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(x, w, None)
-    (_, y_i), g_i = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(x, w, True)
-    check("rmsnorm fwd", max_err(y_t, y_i) < 0.05, f"max_err={max_err(y_t, y_i):.2e}")
-    check("rmsnorm bwd dx", max_err(g_t[0], g_i[0]) < 0.05)
-    check("rmsnorm bwd dw", max_err(g_t[1], g_i[1]) < 0.5)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(x, w)
+
+    (_, y_k), g_k = grads(fused_rms_norm)
+    (_, y_r), g_r = grads(rms_norm)
+    check("rmsnorm fwd", max_err(y_k, y_r) < TOL, f"max_err={max_err(y_k, y_r):.2e}")
+    check("rmsnorm bwd dx", max_err(g_k[0], g_r[0]) < TOL)
+    check("rmsnorm bwd dw", max_err(g_k[1], g_r[1]) < 0.5)
 
 
 def time_fn(f, *args, reps=5):
@@ -125,13 +255,13 @@ def attention_flops(b, s, n, d, causal=True):
     return f / 2 if causal else f
 
 
-def block_sweep(quick: bool):
-    """Flash fwd+bwd timing vs block sizes and vs the XLA fallback."""
+def block_sweep():
+    """Flash fwd+bwd timing vs block sizes and vs the XLA path."""
     from megatron_llm_tpu.ops.attention import make_attention_bias, xla_attention
     from megatron_llm_tpu.ops.pallas.flash_attention import flash_attention
 
     b, n, nkv, d = 4, 16, 16, 128
-    seqs = [1024, 4096] if quick else [1024, 2048, 4096, 8192]
+    seqs = [1024, 2048, 4096, 8192]
     blocks = [(256, 256), (512, 512), (512, 1024), (1024, 512), (1024, 1024)]
     print("\n-- fwd+bwd step time (ms) --")
     print(f"{'seq':>6} {'xla':>8}", *[f"bq{a}/bk{c}".rjust(12) for a, c in blocks])
@@ -227,18 +357,26 @@ def long_context_fit():
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--quick", action="store_true")
+    ap.add_argument("--quick", action="store_true",
+                    help="numerics at the preset geometries only")
     args = ap.parse_args()
 
-    backend = jax.default_backend()
-    print(f"backend: {backend} ({jax.devices()[0].device_kind})")
-    if backend == "cpu":
-        print("not on TPU — numerics-only (interpret==compiled trivially); "
-              "run on a TPU host for the real check")
-    numerics_checks()
-    rmsnorm_check()
-    if backend != "cpu":
-        block_sweep(args.quick)
+    from megatron_llm_tpu.utils.platform import enable_compilation_cache
+
+    enable_compilation_cache()
+    dev = jax.devices()[0]
+    print(f"backend: {dev.platform} ({dev.device_kind}) x{len(jax.devices())}",
+          flush=True)
+    if dev.platform != "tpu":
+        # interpret mode against itself proves nothing about Mosaic
+        print("FAIL not on a TPU: this check compiles the kernels for the "
+              "device; the CPU half is tests/ in interpret mode")
+        sys.exit(2)
+    flash_numerics(args.quick)
+    paged_numerics(args.quick)
+    if not args.quick:
+        rmsnorm_check()
+        block_sweep()
         long_context_fit()
     print(f"\n{len(FAILURES)} failures" + (f": {FAILURES}" if FAILURES else ""))
     sys.exit(1 if FAILURES else 0)
