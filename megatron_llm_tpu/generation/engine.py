@@ -117,6 +117,10 @@ from megatron_llm_tpu.generation.scheduling import (
 from megatron_llm_tpu.observability import flight as obs_flight
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.observability import trace as obs_trace
+from megatron_llm_tpu.observability.profiler import (
+    ProfileTrigger,
+    profile_dir,
+)
 from megatron_llm_tpu.generation.tokenization import detokenize_generations
 from megatron_llm_tpu.models.language_model import (
     _compute_dtype,
@@ -992,6 +996,14 @@ class ContinuousBatchingEngine:
         self.flight = obs_flight.FlightRecorder(
             capacity=n_rec, events_per_request=n_ev, enabled=n_rec > 0)
         obs_flight.set_recorder(self.flight)
+        # on-demand jax.profiler windows of the live engine (GET
+        # /profile?ticks=N on the serving port): armed from a handler
+        # thread, started and stopped by the scheduler loop at step
+        # boundaries; a tick is the trigger's "step".  Same flags and
+        # layout as the trainer's (--profile_dir, --profile_max_captures)
+        self.profile_trigger = ProfileTrigger(
+            os.path.join(profile_dir(cfg.logging), "ondemand"),
+            max_captures=cfg.logging.profile_max_captures)
         # label sets ever published — guarded by _lock
         self._queued_prios: Set[int] = set()
         # registry instruments, resolved once (observability/registry.py):
@@ -1045,9 +1057,13 @@ class ContinuousBatchingEngine:
         self._m_shed = reg.counter(
             "mlt_engine_shed_total",
             help="queued requests shed (unmeetable deadline / load)")
+        # every *_seconds histogram here shares one geometric ladder: a
+        # median or a tail read off it is within +-15%
+        lat = obs_registry.LATENCY_BUCKETS
         self._m_ttft = reg.histogram(
             "mlt_engine_ttft_seconds",
-            help="submit-to-first-token latency of retired requests")
+            help="submit-to-first-token latency of retired requests",
+            buckets=lat)
         self._m_miss_ttft = reg.counter(
             "mlt_engine_deadline_miss_total",
             help="retired requests that missed a declared deadline",
@@ -1063,25 +1079,53 @@ class ContinuousBatchingEngine:
         self._m_queue_wait = reg.histogram(
             "mlt_engine_queue_wait_seconds",
             help="submit-to-admission wait of retired requests (flight-"
-                 "recorder queued-phase bucket)")
+                 "recorder queued-phase bucket)", buckets=lat)
         self._m_prefill_compute = reg.histogram(
             "mlt_engine_prefill_compute_seconds",
             help="prefill-phase seconds of retired requests (admission "
-                 "to decode activation)")
+                 "to decode activation)", buckets=lat)
         self._m_preempted_s = reg.histogram(
             "mlt_engine_preempted_seconds",
             help="seconds retired requests spent preempted (observed "
-                 "only for requests that were preempted at least once)")
+                 "only for requests that were preempted at least once)",
+            buckets=lat)
         # pipelined-dispatch telemetry (ISSUE 17): the host gap is the
         # wall time between one tick launch returning and the next being
         # dispatched — scheduling + emission fetch + apply, THE overhead
-        # --tick_pipeline_depth amortizes across a chain
+        # --tick_pipeline_depth amortizes across a chain.  The fetch is a
+        # wait for the device, so this is NOT host work: the phase
+        # histogram below is
         self._m_host_gap = reg.histogram(
             "mlt_engine_host_gap_seconds",
-            help="host time between consecutive tick-program dispatches "
-                 "(fetch + apply + scheduling; pipelining amortizes it)",
-            buckets=[1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
-                     0.1, 0.3])
+            help="wall time between consecutive tick-program dispatches; "
+                 "INCLUDES the fetch's wait for the device tick to end, "
+                 "so it is about one tick long when the device is busy "
+                 "(pipelining amortizes it). For host work read "
+                 "mlt_engine_tick_phase_seconds (admit, plan, launch, "
+                 "apply)",
+            buckets=lat)
+        # where one ragged tick's wall time goes, phase by phase, at the
+        # boundaries of the engine-* spans (one observation a tick each;
+        # the ragged path only, which is every tick by default)
+        self._m_phase = {
+            ph: reg.histogram(
+                "mlt_engine_tick_phase_seconds",
+                help="wall seconds of one ragged tick by phase: admit "
+                     "(queue to slots), plan (prefill packing, paging, "
+                     "slot-state upload), launch (argument conversion + "
+                     "dispatch), fetch (waiting for the device and the "
+                     "result's way back), apply (tokens to requests and "
+                     "streams, retirement, gauges, and the wait to get "
+                     "the interpreter lock back from the handler threads "
+                     "it woke)",
+                labels={"phase": ph}, buckets=lat)
+            for ph in ("admit", "plan", "launch", "fetch", "apply")}
+        self._m_tick_kind = {
+            k: reg.counter(
+                "mlt_engine_tick_kind_total",
+                help="ragged ticks by kind: prefill = the launch carried "
+                     "prompt rows (a larger program), decode = it did not",
+                labels={"kind": k}) for k in ("decode", "prefill")}
         self._m_inflight = reg.gauge(
             "mlt_engine_inflight_ticks",
             help="device ticks launched but not yet applied "
@@ -2466,15 +2510,17 @@ class ContinuousBatchingEngine:
         prefill, preemption fallout — drains the pipeline and runs this
         depth-0 path for that step.  Speculative engines always step at
         depth 0 (adaptive k_eff needs per-tick acceptance)."""
-        if self.pipeline_depth and not self.spec_k:
-            n = self._step_pipelined()
-            if n is not None:
-                return n
-        with obs_trace.span("engine-admit"):
-            self._admit()
-        if self.ragged:
-            return self._step_ragged()
-        return self._step_legacy()
+        with obs_trace.span("engine-step", tick=self.ticks):
+            if self.pipeline_depth and not self.spec_k:
+                n = self._step_pipelined()
+                if n is not None:
+                    return n
+            t_admit = time.monotonic()
+            with obs_trace.span("engine-admit"):
+                self._admit()
+            if self.ragged:
+                return self._step_ragged(time.monotonic() - t_admit)
+            return self._step_legacy()
 
     def _prefill_budget_tokens(self) -> int:  # holds _lock
         """The policy's per-tick prefill budget, validated as TOKENS
@@ -3068,117 +3114,151 @@ class ContinuousBatchingEngine:
                                       (len(seq) - 1) // ps)
                 self._activate_or_handoff(req, req._slot)
 
-    def _step_ragged(self) -> int:
+    def _step_ragged(self, admit_s: float) -> int:
         """One fused ragged tick: decode slots + verify blocks + packed
         prefill-chunk rows, ONE compiled attention launch
         (generation/ragged.py).  return_log_probs prompts are the one
         carve-out — their teacher-forced chunk keeps the legacy program
-        (counted honestly in the launch telemetry)."""
-        with self._lock:
-            pre0 = self.prefill_tokens_computed
-            (spans, pre_tok, pre_pos, pre_tables, pre_index, pre_hor,
-             lp_live) = self._plan_ragged_prefill()
-        did_lp = 1 if lp_live and self._advance_prefill(
-            only_log_probs=True) else 0
-        with self._lock:
-            active = [i for i, r in enumerate(self._slots)
-                      if r is not None and r._phase == "decode"]
-            if active:
-                k_eff = self._prepare_decode_locked(active)
-            else:
-                k_eff = np.zeros((self.max_slots,), np.int32)
-            if not active and not spans:
-                self._note_launches_locked(
-                    did_lp, self.prefill_tokens_computed - pre0)
-                if obs_registry.publishing():
-                    self._m_active.set(0)
-                    self._m_free_pages.set(self.pool.num_free)
-                    self._m_pages_cached.set(
-                        len(self.cache) if self.cache else 0)
-                self._publish_queued_locked()
-                return did_lp
-            self.peak_active_slots = max(self.peak_active_slots,
-                                         len(active))
-            bt, pos, toks, keys, steps, temp, tk, tp = \
-                self._dev_state_locked()
+        (counted honestly in the launch telemetry).
 
-        n_pre = sum(end - start for _, start, end in spans)
-        # live prefill rows bucketed to chunk multiples: the program's one
-        # shape knob (a dead-row-free decode tick at 0; composition within
-        # a bucket is pure data)
-        n_bucket = (min(self.prefill_rows,
-                        _bucket_up(n_pre, self.prefill_chunk))
-                    if n_pre else 0)
+        The tick's phases are spans under the caller's ``engine-step``
+        (plan, launch and fetch inside ``engine-ragged-tick``, apply) and
+        one observation each of ``mlt_engine_tick_phase_seconds``, with
+        ``admit_s`` (the caller's admission) the fifth; a step that
+        launches nothing observes nothing."""
+        t_plan = time.monotonic()
+        with obs_trace.span("engine-plan"):
+            with self._lock:
+                pre0 = self.prefill_tokens_computed
+                (spans, pre_tok, pre_pos, pre_tables, pre_index, pre_hor,
+                 lp_live) = self._plan_ragged_prefill()
+            did_lp = 1 if lp_live and self._advance_prefill(
+                only_log_probs=True) else 0
+            with self._lock:
+                active = [i for i, r in enumerate(self._slots)
+                          if r is not None and r._phase == "decode"]
+                if active:
+                    k_eff = self._prepare_decode_locked(active)
+                else:
+                    k_eff = np.zeros((self.max_slots,), np.int32)
+                if not active and not spans:
+                    self._note_launches_locked(
+                        did_lp, self.prefill_tokens_computed - pre0)
+                    if obs_registry.publishing():
+                        self._m_active.set(0)
+                        self._m_free_pages.set(self.pool.num_free)
+                        self._m_pages_cached.set(
+                            len(self.cache) if self.cache else 0)
+                    self._publish_queued_locked()
+                    return did_lp
+                self.peak_active_slots = max(self.peak_active_slots,
+                                             len(active))
+                bt, pos, toks, keys, steps, temp, tk, tp = \
+                    self._dev_state_locked()
+
+            n_pre = sum(end - start for _, start, end in spans)
+            # live prefill rows bucketed to chunk multiples: the program's
+            # one shape knob (a dead-row-free decode tick at 0; composition
+            # within a bucket is pure data)
+            n_bucket = (min(self.prefill_rows,
+                            _bucket_up(n_pre, self.prefill_chunk))
+                        if n_pre else 0)
         t_tick = time.monotonic()
         gap = (None if self._last_dispatch_end is None
                else t_tick - self._last_dispatch_end)
         with obs_trace.span("engine-ragged-tick", active=len(active),
                             prefill_tokens=n_pre, launches=1,
-                            k=self.spec_k, tp=self._tp,
-                            host_gap_ms=(None if gap is None
-                                         else round(gap * 1e3, 4))), \
+                            k=self.spec_k, tp=self._tp), \
                 self._overlap_span(), self._pp_span():
-            pre_args = () if not n_bucket else (
-                self._asarray(pre_tok[:n_bucket]),
-                self._asarray(pre_pos[:n_bucket]),
-                self._asarray(pre_tables),
-                self._asarray(pre_index[:n_bucket]),
-                self._asarray(pre_hor[:n_bucket]))
-            tick_fn = self._ragged_tick(n_bucket)
-            if self.spec_k:
-                (self.pool.k, self.pool.v, self.pool.draft_k,
-                 self.pool.draft_v, emit, emit_lp, acc, cnt,
-                 new_pos, next_tok, new_steps) = tick_fn(
-                    self.params, self.draft_params,
-                    self.pool.k, self.pool.v,
-                    self.pool.draft_k, self.pool.draft_v,
-                    bt, pos, toks, keys, steps, temp, tk, tp,
-                    self._asarray(k_eff), *pre_args)
-                self._last_dispatch_end = time.monotonic()
-                # ONE batched host sync for the tick's emissions
-                emit_np, lp_np, acc_np, m_np = jax.device_get(
-                    (emit, emit_lp, acc, cnt))
-            else:
-                (self.pool.k, self.pool.v, next_tok, logp,
-                 new_pos, new_steps) = tick_fn(
-                    self.params, self.pool.k, self.pool.v,
-                    bt, pos, toks, keys, steps, temp, tk, tp,
-                    *pre_args)
-                self._last_dispatch_end = time.monotonic()
-                next_np, logp_np = jax.device_get((next_tok, logp))
-        self._note_host_gap(gap)
+            with obs_trace.span("engine-launch", prefill_rows=n_bucket,
+                                prefill_tokens=n_pre,
+                                decode_rows=len(active)):
+                pre_args = () if not n_bucket else (
+                    self._asarray(pre_tok[:n_bucket]),
+                    self._asarray(pre_pos[:n_bucket]),
+                    self._asarray(pre_tables),
+                    self._asarray(pre_index[:n_bucket]),
+                    self._asarray(pre_hor[:n_bucket]))
+                tick_fn = self._ragged_tick(n_bucket)
+                if self.spec_k:
+                    (self.pool.k, self.pool.v, self.pool.draft_k,
+                     self.pool.draft_v, emit, emit_lp, acc, cnt,
+                     new_pos, next_tok, new_steps) = tick_fn(
+                        self.params, self.draft_params,
+                        self.pool.k, self.pool.v,
+                        self.pool.draft_k, self.pool.draft_v,
+                        bt, pos, toks, keys, steps, temp, tk, tp,
+                        self._asarray(k_eff), *pre_args)
+                else:
+                    (self.pool.k, self.pool.v, next_tok, logp,
+                     new_pos, new_steps) = tick_fn(
+                        self.params, self.pool.k, self.pool.v,
+                        bt, pos, toks, keys, steps, temp, tk, tp,
+                        *pre_args)
+                t_launched = self._last_dispatch_end = time.monotonic()
+            with obs_trace.span("engine-fetch"):
+                # ONE batched host sync for the tick's emissions: the wait
+                # for the device tick to end, and the result's way back
+                if self.spec_k:
+                    emit_np, lp_np, acc_np, m_np = jax.device_get(
+                        (emit, emit_lp, acc, cnt))
+                else:
+                    next_np, logp_np = jax.device_get((next_tok, logp))
 
         now = time.monotonic()
-        with self._lock:
-            dt = now - t_tick  # feeds Retry-After/shed drain estimates
-            self._ema_tick_s = (dt if self._ema_tick_s is None
-                                else 0.8 * self._ema_tick_s + 0.2 * dt)
-            if not self._dirty:
-                # steady state: the tick already advanced the device mirror
-                self._dev_state = (bt, new_pos, next_tok, keys, new_steps,
-                                   temp, tk, tp)
-            self.ticks += 1
+        with obs_trace.span("engine-apply"):
+            with self._lock:
+                dt = now - t_tick  # feeds Retry-After/shed drain estimates
+                self._ema_tick_s = (dt if self._ema_tick_s is None
+                                    else 0.8 * self._ema_tick_s + 0.2 * dt)
+                if not self._dirty:
+                    # steady state: the tick already advanced the device mirror
+                    self._dev_state = (bt, new_pos, next_tok, keys, new_steps,
+                                       temp, tk, tp)
+                self.ticks += 1
+                if self.spec_k:
+                    emitted = self._apply_spec_locked(
+                        active, k_eff, emit_np, lp_np, acc_np, m_np, now)
+                else:
+                    emitted = self._apply_plain_locked(
+                        active, next_np, logp_np, now)
+                self._apply_ragged_prefill_locked(
+                    spans, tick_s=dt, work_rows=n_pre + len(active))
+                self.ticked_tokens += emitted
+                self._note_launches_locked(
+                    1 + did_lp, self.prefill_tokens_computed - pre0)
+                if obs_registry.publishing():
+                    self._m_ticks.inc()
+                    self._m_tokens.inc(emitted)
+                    self._m_active.set(
+                        sum(r is not None and r._phase == "decode"
+                            for r in self._slots))
+                    self._m_free_pages.set(self.pool.num_free)
+                    self._m_pages_cached.set(
+                        len(self.cache) if self.cache else 0)
+                self._publish_queued_locked()
+            # This tick's device handles are dropped here, inside the span
+            # and outside the lock.  Freeing a device array can release the
+            # interpreter lock, and the wait to get it back from the
+            # handler threads that apply has just woken (one a stream, each
+            # writing its chunk) is the largest single piece of host time
+            # between two launches: it is apply's doing, so it is counted
+            # as apply, in the span and in the phase histogram alike.
+            del pre_args, bt, pos, toks, keys, steps, temp, tk, tp
+            del next_tok, new_pos, new_steps
             if self.spec_k:
-                emitted = self._apply_spec_locked(
-                    active, k_eff, emit_np, lp_np, acc_np, m_np, now)
+                del emit, emit_lp, acc, cnt
             else:
-                emitted = self._apply_plain_locked(
-                    active, next_np, logp_np, now)
-            self._apply_ragged_prefill_locked(
-                spans, tick_s=dt, work_rows=n_pre + len(active))
-            self.ticked_tokens += emitted
-            self._note_launches_locked(
-                1 + did_lp, self.prefill_tokens_computed - pre0)
-            if obs_registry.publishing():
-                self._m_ticks.inc()
-                self._m_tokens.inc(emitted)
-                self._m_active.set(
-                    sum(r is not None and r._phase == "decode"
-                        for r in self._slots))
-                self._m_free_pages.set(self.pool.num_free)
-                self._m_pages_cached.set(
-                    len(self.cache) if self.cache else 0)
-            self._publish_queued_locked()
+                del logp
+        t_applied = time.monotonic()
+        self._note_host_gap(gap)
+        if obs_registry.publishing():
+            for ph, sec in (("admit", admit_s), ("plan", t_tick - t_plan),
+                            ("launch", t_launched - t_tick),
+                            ("fetch", now - t_launched),
+                            ("apply", t_applied - now)):
+                self._m_phase[ph].observe(sec)
+            self._m_tick_kind["prefill" if n_bucket else "decode"].inc()
         return len(active) + (1 if spans else 0) + did_lp
 
     def run_until_idle(self) -> None:
@@ -3216,21 +3296,38 @@ class ContinuousBatchingEngine:
             self._thread.join(timeout=30)
             self._thread = None
 
+    def _idle_locked(self) -> bool:  # holds _lock
+        # an in-flight chained launch keeps the loop stepping: its apply
+        # may retire rows (and must not be stranded when every slot
+        # empties before it lands)
+        return (not self._queue and not self._inflight
+                and all(r is None for r in self._slots))
+
     def _loop(self) -> None:
         while True:
             with self._work:
-                # an in-flight chained launch keeps the loop stepping:
-                # its apply may retire rows (and must not be stranded
-                # when every slot empties before it lands)
-                while (not self._stopping and not self._queue
-                       and all(r is None for r in self._slots)
-                       and not self._inflight):
-                    self._work.wait()
+                # a live /profile window does not sleep through idleness:
+                # the loop comes out to close it (below) and waits then
+                while (not self._stopping and self._idle_locked()
+                       and not self.profile_trigger.active):
+                    # "no work" in a capture, told from "host slow"
+                    with obs_trace.span("engine-wait"):
+                        self._work.wait()
                 if self._stopping:
                     break
+                out_of_work = self._idle_locked()
             with self._drive_lock:
+                if out_of_work:
+                    # the window asked for N ticks and the traffic ended
+                    # first: it ends here, not N ticks into the next burst
+                    self.profile_trigger.close()
+                    continue
+                # GET /profile?ticks=N (generation/server.py): a capture
+                # brackets whole steps, started and stopped here
+                self.profile_trigger.maybe_start(self.ticks)
                 try:
-                    self.step()
+                    if self.step():
+                        self.profile_trigger.step_done()
                 except Exception as e:  # noqa: BLE001 — boundary: the
                     # scheduler thread must outlive a failed step, or every
                     # waiter hangs until its timeout with no answer
@@ -3238,6 +3335,7 @@ class ContinuousBatchingEngine:
                     self._fail_all(e)
         with self._drive_lock:
             self._drain_pipeline()
+            self.profile_trigger.close()
 
     def _fail_all(self, e: Exception) -> None:
         """A step raised (a program failed to lower, compile or run):

@@ -35,9 +35,13 @@ from megatron_llm_tpu.generation.engine import EngineOverloaded
 from megatron_llm_tpu.generation.generation import InvalidRequest
 from megatron_llm_tpu.generation.scheduling import RequestShed
 from megatron_llm_tpu.observability import trace as obs_trace
+from megatron_llm_tpu.observability.compiles import install_compile_counter
 from megatron_llm_tpu.serving.streaming import SSE_CONTENT_TYPE, sse_encode
 
 _STATIC_DIR = Path(__file__).parent / "static"
+# the longest window GET /profile?ticks=N may ask for: the endpoint is on
+# the public port and a capture's host buffers grow with every tick
+MAX_PROFILE_TICKS = 1000
 
 
 def _validate(payload: dict):
@@ -541,12 +545,14 @@ class MegatronServer:
                 data = (json.dumps(body) if content_type == "application/json"
                         else body).encode()
                 self._begin(code, content_type, headers, length=len(data))
-                self.wfile.write(data)
+                with obs_trace.span("serve-write"):
+                    self.wfile.write(data)
 
             def _send_chunk(self, data: bytes):
                 """One streamed body write, flushed to the socket."""
-                self.wfile.write(data)
-                self.wfile.flush()
+                with obs_trace.span("serve-write"):
+                    self.wfile.write(data)
+                    self.wfile.flush()
 
             def do_PUT(self):
                 if self.path.rstrip("/") != "/api":
@@ -637,6 +643,8 @@ class MegatronServer:
                     return self._send(
                         200, server.metrics_text(),
                         "text/plain; version=0.0.4; charset=utf-8")
+                if path == "/profile":
+                    return self._send(*server.profile(parse_qs(query)))
                 if path == "/debug/requests":
                     # recent flight records (observability/flight.py):
                     # ?n= caps the count, ?trace_id= filters.  Schema:
@@ -782,6 +790,28 @@ class MegatronServer:
             "requests": recs,
         }
 
+    def profile(self, qs: dict):
+        """``GET /profile?ticks=N``: arm a ``jax.profiler`` window of the
+        live engine's next N ticks (observability/profiler.py; the
+        engine's loop starts and stops it at step boundaries, output
+        under ``--profile_dir``, at most ``--profile_max_captures`` a
+        process).  The capture holds the engine-* and serve-* spans on
+        the device planes' clock.  N is held to ``MAX_PROFILE_TICKS``,
+        and a window that outlives the traffic ends when the engine goes
+        idle.  Returns (status, body): 409 while one is pending or active
+        or the budget is spent, 503 on an engine with no scheduler loop."""
+        trig = getattr(self.engine, "profile_trigger", None)
+        if trig is None:
+            return 503, {"error": "this engine takes no profile"}
+        try:
+            ticks = int(qs["ticks"][0]) if "ticks" in qs else None
+        except ValueError:
+            return 400, {"error": "ticks must be an integer"}
+        if ticks is not None:
+            ticks = min(ticks, MAX_PROFILE_TICKS)
+        res = trig.request(ticks)
+        return (200 if res.get("accepted") else 409), res
+
     def metrics_text(self) -> str:
         """Prometheus text for GET /metrics: refresh the engine-occupancy
         gauges from live engine state (scrape-time pull — the engine also
@@ -850,6 +880,7 @@ class MegatronServer:
         port — with ``port=0`` the OS picks a free one, which is how local
         fleets (tests, bench_decode --mode router) avoid port races.  Call
         ``serve()`` afterwards to block."""
+        install_compile_counter()  # mlt_jit_compiles_total on /metrics
         self._httpd = ThreadingHTTPServer((host, port), self._make_handler())
         return self._httpd.server_address[1]
 

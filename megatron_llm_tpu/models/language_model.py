@@ -308,21 +308,24 @@ def model_forward(
     if not logits_postprocess:
         return ret(hidden)
 
-    if labels is not None and cfg.model.ce_vocab_chunks:
-        # head matmul fused into a vocab-chunked CE: the [b, s, vocab] fp32
-        # logits are never materialized (large-vocab memory lever)
-        loss = chunked_softmax_cross_entropy_from_hidden(
-            hidden, head_weight(cfg, params).astype(hidden.dtype), labels,
-            cfg.model.ce_vocab_chunks,
-        )
+    # one name for what follows the final norm, in the trainer's step and
+    # the engine's tick alike: a device trace prices the head (+ loss)
+    with jax.named_scope("lm_head_loss"):
+        if labels is not None and cfg.model.ce_vocab_chunks:
+            # head matmul fused into a vocab-chunked CE: the [b, s, vocab]
+            # fp32 logits are never materialized (large-vocab memory lever)
+            loss = chunked_softmax_cross_entropy_from_hidden(
+                hidden, head_weight(cfg, params).astype(hidden.dtype),
+                labels, cfg.model.ce_vocab_chunks,
+            )
+            return ret(loss)
+
+        logits = compute_logits(cfg, params, hidden)
+        if labels is None:
+            return ret(logits)
+
+        loss = softmax_cross_entropy(logits, labels)  # fp32 per-token
         return ret(loss)
-
-    logits = compute_logits(cfg, params, hidden)
-    if labels is None:
-        return ret(logits)
-
-    loss = softmax_cross_entropy(logits, labels)  # fp32 per-token
-    return ret(loss)
 
 
 def loss_from_batch(cfg, params, batch: Dict[str, jax.Array], *,

@@ -179,6 +179,7 @@ def split_qkv(
     return q, k, v
 
 
+@jax.named_scope("attention")  # a region a device trace can price
 def attention_sublayer(
     cfg,
     p: Params,
@@ -371,6 +372,7 @@ def ffn_sublayer(cfg, p: Params, x: jax.Array):
     return mlp_sublayer(cfg, p["mlp"], x), moe_mod.zero_aux()
 
 
+@jax.named_scope("mlp")
 def mlp_sublayer(cfg, p: Params, x: jax.Array) -> jax.Array:
     """ParallelMLP analog (transformer.py:77-142): fc1 -> activation -> fc2.
 

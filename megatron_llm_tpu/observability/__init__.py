@@ -5,13 +5,17 @@ async training loop (training.py), the continuous-batching engine
 (generation/engine.py) and the resilience subsystem visible while they
 run:
 
-* ``trace``    — sync-free host span tracer -> Chrome/Perfetto JSON;
+* ``trace``    — sync-free host spans: always a ``jax.profiler``
+  annotation (any capture, the device planes' clock), and a bounded ring
+  -> Chrome/Perfetto JSON when one is configured;
 * ``registry`` — process-wide counters/gauges/histograms -> Prometheus
   text;
 * ``exporter`` — HTTP ``/metrics`` + ``/profile`` endpoint
   (``--metrics_port``);
-* ``profiler`` — on-demand ``jax.profiler`` windows (SIGUSR2 or
-  ``/profile?steps=N``);
+* ``profiler`` — on-demand ``jax.profiler`` windows (SIGUSR2,
+  ``/profile?steps=N`` on the trainer, ``/profile?ticks=N`` on the
+  generation server);
+* ``compiles`` — the program's own count of XLA compilations;
 * ``flops``    — config-derived flops/MFU math shared by driver, bench
   and registry;
 * ``flight``   — per-request flight recorder: bounded event logs with an

@@ -24,7 +24,15 @@ import signal
 import threading
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["ProfileTrigger", "install_sigusr2"]
+__all__ = ["ProfileTrigger", "install_sigusr2", "profile_dir"]
+
+
+def profile_dir(logging_cfg) -> str:
+    """Where a process's profiles go: ``--profile_dir``, else ``profile``
+    under ``--tensorboard_dir`` (or the working directory).  On-demand
+    windows land in its ``ondemand`` subdirectory."""
+    return logging_cfg.profile_dir or os.path.join(
+        logging_cfg.tensorboard_dir or ".", "profile")
 
 
 def _jax_start(logdir: str) -> None:

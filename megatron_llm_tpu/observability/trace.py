@@ -1,4 +1,13 @@
-"""Sync-free host-side span tracer with a bounded ring buffer.
+"""Sync-free host-side spans: one ``span()``, two sinks.
+
+Every ``span(name, **args)`` enters a ``jax.profiler.TraceAnnotation``,
+so it lands in any ``jax.profiler`` capture (``--profile``, the on-demand
+``/profile`` windows, a benchmark's traced run) on plane ``/host:CPU``,
+one line per thread, on the same clock as the device planes — a device
+idle gap can be laid over the phase of the program that was open.  While
+no capture runs the annotation costs an atomic read.  When a ring is
+configured (``configure()``: ``--trace_dir``, the watchdog's hang
+report) the same span is also recorded there, as before.
 
 The async training loop (training.py) and the decode engine
 (generation/engine.py) deliberately keep the host off the device's
@@ -20,9 +29,10 @@ Usage::
     trace.instant("step", iteration=i)
     trace.get_tracer().dump("trace_000010.json")   # Chrome trace JSON
 
-When no tracer is configured (the default), ``span()`` returns a shared
-null context and ``instant()`` is a no-op — the disabled cost is one
-global read and one ``is None`` check.
+When no tracer is configured (the default), ``span()`` returns the bare
+profiler annotation (under a microsecond with no capture running) and
+``instant()``, which is ring-only, is a no-op.  ``jax`` is imported at
+the first span, not with this module.
 
 The dump format is the Chrome/Perfetto ``traceEvents`` JSON (load it at
 https://ui.perfetto.dev or chrome://tracing): complete ``"X"`` events
@@ -66,17 +76,20 @@ _NULL = _NullContext()
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, inside
+    the profiler annotation ``ann`` when one is given."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], ann=_NULL):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -84,6 +97,7 @@ class _Span:
         t1 = time.perf_counter()
         self._tracer._record("X", self._name, self._t0, t1 - self._t0,
                              self._args)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -246,13 +260,23 @@ def get_tracer() -> Optional[SpanTracer]:
     return _TRACER
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, resolved at first use
+
+
 def span(name: str, **args) -> Any:
-    """Module-level span against the process-wide tracer (no-op context
-    when none is configured) — what the instrumented hot paths call."""
+    """What the instrumented hot paths call: a profiler annotation always
+    (visible in any ``jax.profiler`` capture), and a ring record as well
+    when a process-wide tracer is configured."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    ann = _ANNOTATION(name, **args)
     t = _TRACER
     if t is None or not t.enabled:
-        return _NULL
-    return _Span(t, name, args or None)
+        return ann
+    return _Span(t, name, args or None, ann)
 
 
 def instant(name: str, **args) -> None:

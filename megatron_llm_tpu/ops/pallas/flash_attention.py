@@ -202,6 +202,7 @@ def _fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return out, lse
 
@@ -375,6 +376,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*args)
 
     # ---- dk, dv ----
@@ -425,6 +427,7 @@ def _bwd(
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*args)
 
     dsq = dskv = None

@@ -395,10 +395,10 @@ def pretrain(
     # docs/guide/observability.md): span tracer, metrics endpoint,
     # on-demand profiler.  All host-side and sync-free — the async loop's
     # overlap (and its bitwise loss guarantee) survives instrumentation.
+    from megatron_llm_tpu.observability import profiler as profiler_mod
+
     obs = cfg.logging
-    profile_dir = obs.profile_dir or os.path.join(
-        obs.tensorboard_dir or ".", "profile"
-    )
+    profile_dir = profiler_mod.profile_dir(obs)
     tracer = None
     if obs.trace_dir:
         os.makedirs(obs.trace_dir, exist_ok=True)
@@ -406,16 +406,14 @@ def pretrain(
         print0(f"observability: span tracing -> {obs.trace_dir} "
                f"(window {obs.trace_steps} steps, ring "
                f"{obs.trace_buffer_events} events)")
-    from megatron_llm_tpu.observability.profiler import (
-        ProfileTrigger,
-        install_sigusr2,
-    )
+    from megatron_llm_tpu.observability.compiles import install_compile_counter
 
-    profile_trigger = ProfileTrigger(
+    install_compile_counter()  # mlt_jit_compiles_total on /metrics
+    profile_trigger = profiler_mod.ProfileTrigger(
         os.path.join(profile_dir, "ondemand"),
         max_captures=obs.profile_max_captures,
     )
-    prev_usr2 = install_sigusr2(profile_trigger)
+    prev_usr2 = profiler_mod.install_sigusr2(profile_trigger)
     exporter = None
     if obs.metrics_port is not None:
         from megatron_llm_tpu.observability.exporter import MetricsExporter

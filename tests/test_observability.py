@@ -87,15 +87,22 @@ def test_chrome_trace_json_valid(tmp_path):
 
 
 def test_module_level_span_noop_when_unconfigured():
+    """One span(), two sinks: with no ring configured a span is the bare
+    profiler annotation (visible to any jax.profiler capture, nothing
+    recorded in-process); with a ring it is recorded there as well."""
+    from jax.profiler import TraceAnnotation
+
     trace_mod.disable()
-    with trace_mod.span("x") as s:
-        assert s is None  # shared null context
-    trace_mod.instant("y")  # must not raise
+    assert trace_mod.get_tracer() is None
+    with trace_mod.span("x", tick=3) as s:
+        assert isinstance(trace_mod.span("x"), TraceAnnotation)
+        assert s is not trace_mod.span("x")  # no shared state
+    trace_mod.instant("y")  # ring-only: a no-op without a ring
     t = trace_mod.configure(capacity=32)
     try:
-        with trace_mod.span("x"):
+        with trace_mod.span("x", tick=4):
             pass
-        assert len(t) == 1
+        assert [(e[1], e[5]) for e in t.snapshot()] == [("x", {"tick": 4})]
     finally:
         trace_mod.disable()
 
@@ -745,3 +752,425 @@ def test_observability_overhead_gate(tmp_path):
                                       trace_dir=str(tmp_path / "t"))
     overhead_pct = cost["instrument_cost_us_per_step"] / step_us * 100.0
     assert overhead_pct < bo.GATE_OVERHEAD_PCT, (cost, step_us)
+
+
+# ---------------------------------------------------------------------------
+# (i) program spans on the profiler's clock (ISSUE 24): the engine step as
+# a tree of spans in a jax.profiler capture and in the ring, the counters
+# at the same boundaries, the bucket ladder, the compile counter, /profile
+# ---------------------------------------------------------------------------
+
+PHASES = ("admit", "plan", "launch", "fetch", "apply")
+
+
+def _capture_spans(path, names):
+    """[(name, line index, start_ns, end_ns, stats)] of the events called
+    one of ``names`` on plane /host:CPU of an xplane file."""
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name in names:
+                        out.append((e.name, i, e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+    return out
+
+
+def _counts(reg):
+    out = {ph: reg.histogram("mlt_engine_tick_phase_seconds",
+                             labels={"phase": ph}).snapshot()[2]
+           for ph in PHASES}
+    out["ticks"] = reg.counter("mlt_engine_ticks_total").value
+    for k in ("decode", "prefill"):
+        out[k] = reg.counter("mlt_engine_tick_kind_total",
+                             labels={"kind": k}).value
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced_serving_run(tmp_path_factory):
+    """Two streamed requests of three ticks each through the real server,
+    under a jax.profiler capture, with a ring configured too."""
+    import glob
+    import http.client
+    import time
+
+    import jax
+
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.generation.server import MegatronServer
+    from megatron_llm_tpu.models import init_model_params, make_config
+    from tests.test_generation import VOCAB, ToyTokenizer
+
+    cfg = make_config(
+        "llama2", num_layers=2, hidden_size=64, num_attention_heads=4,
+        num_attention_heads_kv=2, ffn_hidden_size=128, seq_length=128,
+        max_position_embeddings=256, vocab_size=VOCAB,
+        params_dtype="float32", use_flash_attn=False,
+    )
+    cfg.inference.max_batch_slots = 4
+    params = init_model_params(cfg, jax.random.PRNGKey(0))
+    engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer())
+    srv = MegatronServer(engine)
+    port = srv.start_background(port=0)
+
+    def ask(prompt="trace me"):
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        conn.request("PUT", "/api", body=json.dumps(
+            {"prompts": [prompt], "tokens_to_generate": 3, "top_k": 1,
+             "stream": True}).encode(),
+            headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        body = resp.read()
+        conn.close()
+        assert resp.status == 200, body
+
+    logdir = str(tmp_path_factory.mktemp("capture"))
+    old = trace_mod.get_tracer()
+    reg = registry_mod.get_registry()
+    try:
+        ask()  # compile outside the capture
+        ring = trace_mod.configure(capacity=4096)
+        # the client has its last event before the scheduler thread has
+        # left that step (the phase histogram is fed at its very end);
+        # the loop holds _drive_lock over a whole step, so under it the
+        # counters are those of whole steps
+        with engine._drive_lock:
+            before = _counts(reg)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # the program's spans, not every call
+        jax.profiler.start_trace(logdir, profiler_options=opts)
+        try:
+            ask()
+            ask("and me too")  # the loop's wait between the two is inside
+            # the client has its last event before the scheduler thread
+            # has closed that step's spans: let it reach its idle wait, or
+            # the capture misses spans the ring still gets
+            time.sleep(0.3)
+        finally:
+            jax.profiler.stop_trace()
+        with engine._drive_lock:
+            after = _counts(reg)
+        ring_events = ring.snapshot()
+    finally:
+        trace_mod._TRACER = old
+        srv.stop()
+    (path,) = glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    names = {"engine-step", "engine-admit", "engine-plan",
+             "engine-ragged-tick", "engine-launch", "engine-fetch",
+             "engine-apply", "engine-wait", "serve-write",
+             "serve-api-stream"}
+    return {"capture": _capture_spans(path, names), "ring": ring_events,
+            "page_size": engine.page_size,
+            "delta": {k: after[k] - before[k] for k in after}}
+
+
+def _children(spans, parent):
+    name, line, s, e, _ = parent
+    return [c for c in spans
+            if c[1] == line and c is not parent and s <= c[2] and c[3] <= e]
+
+
+def test_capture_holds_the_engine_step_tree(traced_serving_run):
+    spans = traced_serving_run["capture"]
+    steps = [x for x in spans if x[0] == "engine-step"]
+    ticked = [x for x in steps
+              if any(c[0] == "engine-launch" for c in _children(spans, x))]
+    assert len(ticked) >= 3, [x[0] for x in spans]
+    assert len({x[1] for x in steps}) == 1, "one scheduler thread"
+    ticks = [x[4]["tick"] for x in ticked]
+    assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+    for step in ticked:
+        kids = sorted(_children(spans, step), key=lambda c: c[2])
+        order = [c[0] for c in kids]
+        assert order == ["engine-admit", "engine-plan", "engine-ragged-tick",
+                         "engine-launch", "engine-fetch", "engine-apply"]
+        by = {c[0]: c for c in kids}
+        tick = by["engine-ragged-tick"]
+        for inner in ("engine-launch", "engine-fetch"):
+            assert tick[2] <= by[inner][2] and by[inner][3] <= tick[3]
+        # siblings in order, none overlapping
+        seq = [by[n] for n in ("engine-admit", "engine-plan",
+                               "engine-launch", "engine-fetch",
+                               "engine-apply")]
+        assert all(a[3] <= b[2] for a, b in zip(seq, seq[1:]))
+    launches = [c for x in ticked for c in _children(spans, x)
+                if c[0] == "engine-launch"]
+    # each prompt rides one tick as a bucketed prefill chunk; the other
+    # ticks are decode-only, one live row
+    pre = [c[4] for c in launches if c[4]["prefill_rows"] > 0]
+    page = traced_serving_run["page_size"]  # prompts fill whole pages
+    assert [a["prefill_tokens"] for a in pre] == [page, page]
+    assert all(a["prefill_rows"] >= a["prefill_tokens"] for a in pre)
+    dec = [c[4] for c in launches if c[4]["prefill_rows"] == 0]
+    assert len(dec) >= 4 and all(
+        a["decode_rows"] == 1 and a["prefill_tokens"] == 0 for a in dec)
+
+
+def test_capture_holds_handler_thread_writes(traced_serving_run):
+    spans = traced_serving_run["capture"]
+    sched = {x[1] for x in spans if x[0] == "engine-step"}
+    writes = [x for x in spans if x[0] == "serve-write"]
+    assert len(writes) >= 3  # one a streamed token at least
+    assert all(x[1] not in sched for x in writes)
+    # each write lies inside its request's handler span, same thread
+    apis = [x for x in spans if x[0] == "serve-api-stream"]
+    assert apis and all(
+        any(a[1] == w[1] and a[2] <= w[2] and w[3] <= a[3] for a in apis)
+        for w in writes)
+    # the loop's idle wait is a span too: no work is told from host slow
+    assert any(x[0] == "engine-wait" and x[1] in sched for x in spans)
+
+
+def test_ring_holds_the_same_spans(traced_serving_run):
+    from collections import Counter
+
+    ring = Counter(e[1] for e in traced_serving_run["ring"] if e[0] == "X")
+    cap = Counter(x[0] for x in traced_serving_run["capture"])
+    for name in ("engine-step", "engine-admit", "engine-plan",
+                 "engine-ragged-tick", "engine-launch", "engine-fetch",
+                 "engine-apply", "serve-write", "serve-api-stream"):
+        assert ring[name] == cap[name] > 0, (name, ring[name], cap[name])
+    launch = [e for e in traced_serving_run["ring"]
+              if e[1] == "engine-launch"]
+    assert set(launch[0][5]) == {"prefill_rows", "prefill_tokens",
+                                 "decode_rows"}
+
+
+def test_phase_and_kind_counters_add_up_to_ticks(traced_serving_run):
+    d = traced_serving_run["delta"]
+    assert d["ticks"] >= 3
+    for ph in PHASES:
+        assert d[ph] == d["ticks"], (ph, d)
+    assert d["decode"] + d["prefill"] == d["ticks"]
+    assert d["prefill"] == 2 and d["decode"] >= 4  # one prompt a request
+
+
+def test_latency_ladder_quantiles_within_15_percent():
+    import random
+
+    ladder = registry_mod.LATENCY_BUCKETS
+    assert len(ladder) == 49
+    assert ladder[0] == pytest.approx(1e-4) and ladder[-1] == pytest.approx(1e2)
+    assert all(b / a == pytest.approx(10 ** 0.125)
+               for a, b in zip(ladder, ladder[1:]))
+    rng = random.Random(24)
+    # a latency-shaped sample: lognormal around 30 ms with a long tail
+    xs = sorted(rng.lognormvariate(-3.5, 1.2) for _ in range(1000))
+    h = registry_mod.Histogram(ladder)
+    for x in xs:
+        h.observe(x)
+    cum, total, count = h.snapshot()
+    assert count == 1000 and total == pytest.approx(sum(xs))
+    for q in (0.1, 0.5, 0.9, 0.99):
+        exact = xs[int(q * 1000) - 1]
+        assert h.quantile(q) == pytest.approx(exact, rel=0.15), q
+    assert registry_mod.Histogram(ladder).quantile(0.5) is None
+
+
+def test_engine_seconds_histograms_share_the_ladder(traced_serving_run):
+    """Once an engine has been built (the fixture's), every
+    mlt_engine_*_seconds histogram on /metrics has the ladder's 49 bounds
+    and +Inf."""
+    import re
+
+    text = registry_mod.get_registry().render()
+    seen = {}
+    for m in re.finditer(
+            r'^(mlt_engine_\w+_seconds)_bucket\{(.*?)le="([^"]+)"\} ',
+            text, re.M):
+        seen.setdefault((m.group(1), m.group(2)), []).append(m.group(3))
+    names = {k[0] for k in seen}
+    assert {"mlt_engine_ttft_seconds", "mlt_engine_queue_wait_seconds",
+            "mlt_engine_prefill_compute_seconds",
+            "mlt_engine_preempted_seconds", "mlt_engine_host_gap_seconds",
+            "mlt_engine_tick_phase_seconds"} <= names
+    for key, les in seen.items():
+        assert len(les) == 50 and les[-1] == "+Inf", key
+    assert "INCLUDES the fetch" in text  # host_gap's help says what it is
+
+
+def test_compile_counter_counts_new_shapes_only():
+    import jax
+    import jax.numpy as jnp
+
+    from megatron_llm_tpu.observability.compiles import (
+        install_compile_counter,
+    )
+
+    install_compile_counter()
+    install_compile_counter()  # a second call must not count double
+    reg = registry_mod.get_registry()
+    n = reg.counter("mlt_jit_compiles_total")
+    sec = reg.counter("mlt_jit_compile_seconds_total")
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0 + 1.0)
+    a, b = np.ones((7, 13), np.float32), np.ones((11, 5), np.float32)
+    n0, s0 = n.value, sec.value
+    f(a).block_until_ready()
+    assert n.value == n0 + 1 and sec.value > s0
+    f(a).block_until_ready()                 # a repeat: no compile
+    assert n.value == n0 + 1
+    f(b).block_until_ready()                 # a new shape: one more
+    assert n.value == n0 + 2
+    text = reg.render()
+    assert "mlt_jit_compiles_total" in text
+    assert "mlt_jit_compile_seconds_total" in text
+
+
+@pytest.mark.parametrize("ring,capture,cap_us", [
+    (False, False, 5.0),    # the bare annotation's atomic read
+    (True, False, 25.0),    # + a ring record
+    (False, True, 25.0),    # + an event in a live jax.profiler capture
+    (True, True, 25.0),
+], ids=["off", "ring", "capture", "ring+capture"])
+def test_span_cost(ring, capture, cap_us, tmp_path):
+    """One span in each of its four states; best of five rounds, generous
+    caps for a shared CPU.  `-s` prints the figure (PERF.md section 6 has
+    the chip machine's)."""
+    import time
+
+    import jax
+
+    trace_mod.disable()
+    if capture:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # the span's bill, not the tracer's
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    if ring:
+        trace_mod.configure(capacity=65536)
+    try:
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for i in range(2000):
+                with trace_mod.span("engine-launch", prefill_rows=0,
+                                    decode_rows=i):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / 2000)
+    finally:
+        trace_mod.disable()
+        if capture:
+            jax.profiler.stop_trace()
+    print(f"span cost, ring={ring} capture={capture}: {best * 1e6:.2f} us")
+    assert best < cap_us * 1e-6, f"{best * 1e6:.2f} us a span"
+
+
+def _profiled_server(tmp_path):
+    """(engine, server, base url, calls): a toy engine behind the real
+    server, its ProfileTrigger's start and stop injected to record
+    (what, dir, the engine's tick count) in ``calls``."""
+    import jax
+
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.generation.server import MegatronServer
+    from megatron_llm_tpu.models import init_model_params, make_config
+    from tests.test_generation import VOCAB, ToyTokenizer
+
+    cfg = make_config(
+        "llama2", num_layers=2, hidden_size=64, num_attention_heads=4,
+        num_attention_heads_kv=2, ffn_hidden_size=128, seq_length=128,
+        max_position_embeddings=256, vocab_size=VOCAB,
+        params_dtype="float32", use_flash_attn=False,
+    )
+    cfg.inference.max_batch_slots = 4
+    cfg.logging.profile_dir = str(tmp_path)
+    cfg.logging.profile_max_captures = 2
+    params = init_model_params(cfg, jax.random.PRNGKey(0))
+    engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer())
+    assert engine.profile_trigger.out_dir == os.path.join(
+        str(tmp_path), "ondemand")
+    assert engine.profile_trigger.max_captures == 2
+    calls = []
+    engine.profile_trigger = ProfileTrigger(
+        engine.profile_trigger.out_dir, max_captures=2,
+        start_fn=lambda d: calls.append(("start", d, engine.ticks)),
+        stop_fn=lambda: calls.append(("stop", None, engine.ticks)))
+    srv = MegatronServer(engine)
+    port = srv.start_background(port=0)
+    return engine, srv, f"http://127.0.0.1:{port}", calls
+
+
+def test_serving_profile_endpoint_brackets_ticks(tmp_path):
+    """GET /profile?ticks=N on the serving port arms a capture that the
+    engine's loop starts at its next step and stops N ticks later; the
+    budget and the single-flight rule are ProfileTrigger's own."""
+    from megatron_llm_tpu.generation.server import MegatronServer
+
+    engine, srv, base, calls = _profiled_server(tmp_path)
+    try:
+        code, body, _ = _get(base + "/profile?ticks=zero")
+        assert code == 400
+        code, body, _ = _get(base + "/profile?ticks=2")
+        assert code == 200 and json.loads(body)["steps"] == 2
+        code, body, _ = _get(base + "/profile?ticks=2")
+        assert code == 409  # one at a time
+        assert calls == []  # idle: nothing starts until a step runs
+        req = engine.submit([5, 6, 7], 5, use_eod_for_termination=False)
+        req.result(timeout=120)
+        (start, stop) = calls
+        assert start[0] == "start" and "ondemand_000" in start[1]
+        assert start[1].startswith(str(tmp_path))
+        assert stop[0] == "stop" and stop[2] - start[2] == 2
+    finally:
+        srv.stop()
+    # a legacy engine has no scheduler loop to bracket ticks: 503
+    class _NoLoop:
+        pass
+    assert MegatronServer(_NoLoop()).profile({})[0] == 503
+
+
+def test_serving_profile_window_is_clamped(tmp_path):
+    """The endpoint is on the public port: no request holds a capture
+    open for more than MAX_PROFILE_TICKS ticks."""
+    from megatron_llm_tpu.generation import server as server_mod
+
+    engine, srv, base, calls = _profiled_server(tmp_path)
+    try:
+        code, body, _ = _get(base + f"/profile?ticks={10 ** 12}")
+        assert code == 200
+        assert json.loads(body)["steps"] == server_mod.MAX_PROFILE_TICKS
+        assert engine.profile_trigger.pending and calls == []
+    finally:
+        srv.stop()
+    assert calls == []  # armed, never started: nothing to stop
+
+
+def test_serving_profile_window_ends_when_engine_goes_idle(tmp_path):
+    """A window longer than the traffic stops when the loop runs out of
+    work, not N ticks into whatever comes next; the next request is free
+    to arm another."""
+    import time
+
+    engine, srv, base, calls = _profiled_server(tmp_path)
+    try:
+        assert _get(base + "/profile?ticks=500")[0] == 200
+        engine.submit([5, 6, 7], 3,
+                      use_eod_for_termination=False).result(timeout=120)
+        deadline = time.monotonic() + 30
+        while len(calls) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        (start, stop) = calls
+        assert start[0] == "start" and stop[0] == "stop"
+        assert 1 <= stop[2] - start[2] < 500
+        assert not engine.profile_trigger.active
+        # the loop went back to its wait: a second window is accepted
+        assert _get(base + "/profile?ticks=1")[0] == 200
+        engine.submit([5, 6, 7], 2,
+                      use_eod_for_termination=False).result(timeout=120)
+        deadline = time.monotonic() + 30
+        while len(calls) < 4 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert [c[0] for c in calls] == ["start", "stop"] * 2
+        assert calls[3][2] - calls[2][2] == 1
+    finally:
+        srv.stop()

@@ -260,6 +260,59 @@ def test_mixed_tick_single_launch_and_span(models):
         "a ragged-tick span claimed more than one launch")
 
 
+def test_tick_phase_spans_and_kind_counters_agree(models):
+    """ISSUE 24: every ragged tick is launch + fetch inside
+    ``engine-ragged-tick``, between a plan and an apply; the launch span
+    says which kind of tick it was, and the kind counters count the same
+    ticks the spans show."""
+    from megatron_llm_tpu.observability import registry as obs_registry
+    from megatron_llm_tpu.observability import trace as obs_trace
+
+    reg = obs_registry.get_registry()
+
+    def kinds():
+        return {k: reg.counter("mlt_engine_tick_kind_total",
+                               labels={"kind": k}).value
+                for k in ("decode", "prefill")}
+
+    old = obs_trace.get_tracer()
+    tracer = obs_trace.configure(capacity=8192)
+    try:
+        eng = _engine(models, ragged=True)
+        before, ticks0 = kinds(), eng.ticks
+        _run(eng, _mixed_jobs(n_new=6))
+        after = kinds()
+    finally:
+        obs_trace._TRACER = old
+    events = [e for e in tracer.snapshot() if e[0] == "X"]
+    by = {}
+    for e in events:
+        by.setdefault(e[1], []).append(e)
+    n = eng.ticks - ticks0
+    assert n > 0
+    for name in ("engine-ragged-tick", "engine-launch", "engine-fetch",
+                 "engine-apply"):
+        assert len(by[name]) == n, (name, len(by[name]), n)
+    # an idle step plans and launches nothing: plans >= ticks, steps too
+    assert len(by["engine-plan"]) >= n and len(by["engine-step"]) >= n
+    assert len(by["engine-admit"]) == len(by["engine-step"])
+    assert sorted({e[5]["tick"] for e in by["engine-step"]})[:n] == list(
+        range(ticks0, ticks0 + n))
+    launches = [e[5] for e in by["engine-launch"]]
+    pre = [a for a in launches if a["prefill_rows"] > 0]
+    dec = [a for a in launches if a["prefill_rows"] == 0]
+    assert pre and dec, "the workload mixes prefill-carrying and decode ticks"
+    assert all(a["prefill_rows"] % eng.prefill_chunk == 0
+               and 0 < a["prefill_tokens"] <= a["prefill_rows"] for a in pre)
+    assert all(a["prefill_tokens"] == 0 for a in dec)
+    assert after["prefill"] - before["prefill"] == len(pre)
+    assert after["decode"] - before["decode"] == len(dec)
+    # the tick span no longer repeats the host-gap histogram (read by
+    # nothing), and keeps the arguments the guides name
+    assert all(set(e[5]) == {"active", "prefill_tokens", "launches", "k",
+                             "tp"} for e in by["engine-ragged-tick"])
+
+
 def test_legacy_mixed_tick_multi_launch(models):
     """The counter is honest: the legacy split path really does dispatch
     more than one program on a mixed tick (the thing ragged removes)."""
