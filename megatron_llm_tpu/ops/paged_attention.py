@@ -11,9 +11,11 @@ per-sequence context lengths.
 Two implementations with the same fp32-softmax numerics:
 
 * ``ops/pallas/paged_attention.py`` — the TPU kernel: the block table is a
-  scalar-prefetch operand, so each grid step DMAs exactly one page from the
-  HBM pool into VMEM (no [b, max_seq] gather ever materializes) and the
-  online-softmax accumulator carries across pages.
+  scalar-prefetch operand and the pool stays in HBM; one program per row
+  walks that row's context in blocks of several pages, copying the next
+  block's pages into VMEM while the current one is scored (no [b, max_seq]
+  gather ever materializes; slots past the context are never looked up)
+  and the online-softmax accumulator carries across blocks.
 * the jnp path below — gathers the block-tabled pages into a dense
   [b, max_seq] view and reuses :func:`ops.attention.xla_attention`.  It
   matches the dense-cache decode path on the same context (the parity
@@ -268,13 +270,14 @@ def paged_attention_prefill(
 def _kernel_refusal(k_pool) -> Optional[str]:
     """Why the Pallas kernel cannot serve this call (None: it can).
 
-    The kernel reads one ``(page, d)`` block per grid step out of the
-    pool's ``[P, page, nkv*d]`` view, so Mosaic needs the lane extent ``d``
-    to be a multiple of 128 unless the view has a single head (the block is
-    then the whole last dim): Mistral/Mixtral/Llama (d=128) and d=256 take
-    the kernel, Falcon-40B (8 kv heads of 64) does not, Falcon-7B (one kv
-    head of 64) does.  Pages need 8 sublane rows; bf16, int8 and fp8 pools
-    all lower from 8 rows up (packed dtypes are unpacked after the DMA).
+    The kernel copies whole pages out of the pool's ``[P, page, nkv*d]``
+    view and slices a head's ``d`` lanes out of the copy, so Mosaic needs
+    the lane extent ``d`` to be a multiple of 128 unless the view has a
+    single head (its row is then padded to whole 128-lane rows):
+    Mistral/Mixtral/Llama (d=128) and d=256 take the kernel, Falcon-40B (8
+    kv heads of 64) does not, Falcon-7B (one kv head of 64) does.  Pages
+    need 8 sublane rows; bf16, int8 and fp8 pools all lower from 8 rows up
+    (packed dtypes are unpacked after the copy).
     """
     from megatron_llm_tpu.core.parallel_state import target_platform
 

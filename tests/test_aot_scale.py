@@ -208,17 +208,18 @@ def _compiles_with_kernel(fn, *args, **jit_kw):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
-@pytest.mark.parametrize("heads", [(32, 8), (32, 32)],
-                         ids=["gqa32q8kv", "mha32q32kv"])
+@pytest.mark.parametrize("heads", [(32, 8, 128), (32, 32, 128), (71, 1, 64)],
+                         ids=["gqa32q8kv", "mha32q32kv", "mqa71q1kv64"])
 def test_aot_paged_kernels_compile(heads, kv_dtype):
     """Decode, prefill-chunk and ragged kernels lower and compile for a
-    v5e at the preset head geometries (heads of 128, default page size),
-    on plain and quantized pools."""
+    v5e at the preset head geometries (Mistral's and Llama-2's heads of
+    128, Falcon-7B's one kv head of 64; default page size), on plain and
+    quantized pools."""
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
     from megatron_llm_tpu.ops import paged_attention as pa
 
-    n, nkv = heads
-    d, page, pages, maxp, b = 128, 16, 64, 8, 8
+    n, nkv, d = heads
+    page, pages, maxp, b = 16, 64, 8, 8
     mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
     repl = NamedSharding(mesh, P())
 

@@ -80,6 +80,33 @@ def toy_model():
     dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, window=9),
     dict(n=4, nkv=4, d=128, page=16, kv_dtype="int8"),
     dict(n=4, nkv=1, d=64, page=8, kv_dtype="fp8", window=20),
+    # the page walk (tools/tpu_kernel_check.py WALK_CASES): a context of
+    # three 128-token blocks that ends inside the third, a horizon that is
+    # no multiple of a block, a window that opens inside a block, rows
+    # sharing a table beside dead rows (every ragged scenario)
+    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=24,
+         context=300),
+    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=24,
+         context=300, window=50),
+    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, max_pages=48,
+         context=300, window=150),
+    dict(n=4, nkv=2, d=128, page=128, dtype=jnp.float32, max_pages=3,
+         context=300),
+    # 128 slots wide, 40 tokens of context, the tail names a NaN page:
+    # nothing of it reaches the output, which is finite and the gather
+    # path's
+    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=128,
+         context=40, poison_tail=True),
+    dict(n=4, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=128,
+         context=40, poison_tail=True),
+    # Falcon-7B (71 query heads on one kv head of 64: two tokens a
+    # 128-lane row) and Mistral-7B (32/8 x 128) head geometries
+    dict(n=71, nkv=1, d=64, page=16, max_pages=24, context=300),
+    dict(n=71, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=24,
+         context=300, window=100),
+    dict(n=32, nkv=8, d=128, page=16, max_pages=24, context=300),
+    dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8", max_pages=24,
+         context=300, window=50),
 ], ids=lambda c: "-".join(f"{k}{getattr(v, '__name__', v)}"
                           for k, v in c.items()))
 def test_paged_kernels_interpret_match_jnp_path(case):

@@ -18,12 +18,14 @@ check has always used.  Quantized pools share one dequantized value per
 element on both sides, so the same bound holds.
 
 Usage (through the chip tool; off-TPU it exits 2):
-    python tools/tpu_kernel_check.py [--quick]
+    python tools/tpu_kernel_check.py [--quick | --time]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
-kernel phase).  The full run adds the page-size/dtype matrix, block-size
-timing sweeps and a long-sequence (32K) memory-fit check.  Prints one
-PASS/FAIL line per check; exit code 0 iff all checks pass.
+kernel phase).  ``--time`` prints the paged kernel's ms a call at the two
+tick shapes the chip has seen, beside what its KV bytes need at the HBM
+peak, and checks nothing.  The full run adds the page-size/dtype matrix,
+block-size timing sweeps and a long-sequence (32K) memory-fit check.
+Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
 """
 
 from __future__ import annotations
@@ -119,13 +121,20 @@ def flash_numerics(quick: bool):
 
 
 def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
-               kv_dtype: str = "bf16", window=None, dtype=jnp.bfloat16):
+               kv_dtype: str = "bf16", window=None, dtype=jnp.bfloat16,
+               max_pages: int = 12, context=None, poison_tail: bool = False):
     """One random paged-attention scenario and its three call shapes.
 
     Returns ``{name: (pallas_fn, jnp_fn)}`` — thunks over the same pool and
     block tables: ``pallas_fn(interpret)`` calls the kernel wrapper directly,
     ``jnp_fn()`` the gather path.  Shared with tests/test_paged_engine.py,
     which runs the kernels in interpret mode on the CPU.
+
+    Tables are ``max_pages`` slots wide and the longest context is
+    ``context`` tokens (default: the whole table).  ``poison_tail`` points
+    every slot past a table's context at a page that is NaN in the pool
+    the kernel reads and finite in the one the gather path reads: a walk
+    that lets the tail reach a row's output shows as a NaN.
     """
     import numpy as np
 
@@ -134,32 +143,52 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     from megatron_llm_tpu.ops.pallas import paged_attention as pk
 
     rng = np.random.default_rng(seed)
-    num_pages, maxp, b, s = 40, 12, 4, 2 * page
+    num_pages, b, s = max(40, max_pages + 2), 4, 2 * page
+    poison = num_pages - 1
     vals = [jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
             for _ in range(2)]
     if kv_dtype == "bf16":
-        kp, vp = vals
+        pools = vals
+        bad = [x.at[poison].set(jnp.nan) for x in vals]
     else:
-        kp, vp = (kv_quant.quantize_pages(x, kv_dtype) for x in vals)
+        pools = [kv_quant.quantize_pages(x, kv_dtype) for x in vals]
+        bad = [x._replace(scale=x.scale.at[poison].set(jnp.nan))
+               for x in pools]
+    kp, vp = pools
+    kpk, vpk = bad if poison_tail else pools
     scale = 1.0 / d ** 0.5
     kw = dict(scale=scale, sliding_window=window)
-    # page ids never repeat within a table; page 0 stays the null page
-    bt = jnp.asarray(np.stack([rng.permutation(num_pages - 1)[:maxp] + 1
-                               for _ in range(b)]), jnp.int32)
-    limit = maxp * page
-    pos = jnp.asarray([0, page - 1, page, limit - 1][:b], jnp.int32)
+    limit = context or max_pages * page
+
+    def table(ctx):
+        """Page ids never repeat within a table; page 0 stays the null
+        page; slots past ``ctx`` tokens name the poison page if asked."""
+        ids = rng.permutation(num_pages - 2)[:max_pages] + 1
+        if poison_tail:
+            ids = np.where(np.arange(max_pages) * page < ctx, ids, poison)
+        return ids
+
+    pos = np.asarray([0, page - 1, page, limit - 1], np.int32)
+    bt = jnp.asarray(np.stack([table(p + 1) for p in pos]), jnp.int32)
+    pos = jnp.asarray(pos)
     q1 = jnp.asarray(rng.normal(size=(b, 1, n, d)), dtype)
 
-    # prefill: one chunk of s rows starting mid-sequence, page-aligned
-    start = jnp.asarray([3 * page], jnp.int32)
+    # prefill: one chunk of s rows starting mid-sequence, page-aligned; at
+    # the end of the context when one is given
+    start = 3 * page if context is None else max(limit - s, 0) // page * page
+    bt1 = jnp.asarray(table(start + s)[None], jnp.int32)
+    start = jnp.asarray([start], jnp.int32)
     qs = jnp.asarray(rng.normal(size=(1, s, n, d)), dtype)
-    bt1 = bt[:1]
 
-    # ragged: 3 tables (null + two live), rows mix a decode row, a dead
-    # row (horizon 0, null table) and a run of consecutive prefill rows
-    tables = jnp.concatenate([jnp.zeros((1, maxp), jnp.int32), bt[:2]])
-    r_pos = np.array([limit - 2, 0] + list(range(page + 1, page + 7)), np.int32)
-    r_idx = np.array([1, 0] + [2] * 6, np.int32)
+    # ragged: 3 tables (null + two live); two decode rows share table 1, a
+    # run of consecutive prefill rows shares table 2, dead rows (horizon
+    # 0, null table) lie between them
+    run = list(range(page + 1, page + 7))
+    r_pos = np.array([limit - 2, 0] + run + [0, 0, limit // 2], np.int32)
+    r_idx = np.array([1, 0] + [2] * 6 + [0, 0, 1], np.int32)
+    tables = jnp.asarray(np.stack(
+        [np.zeros(max_pages, np.int64), table(limit - 1), table(run[-1] + 1)]),
+        jnp.int32)
     r_hor = np.where(r_idx > 0, (r_pos // 64 + 1) * 64, 0).astype(np.int32)
     live = np.flatnonzero(r_idx)
     r_pos, r_idx, r_hor = (jnp.asarray(a) for a in (r_pos, r_idx, r_hor))
@@ -168,19 +197,19 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     return {
         "decode": (
             lambda interpret=False: pk.paged_decode_kernel(
-                q1, kp, vp, bt, pos, interpret=interpret, **kw),
+                q1, kpk, vpk, bt, pos, interpret=interpret, **kw),
             lambda: pa.paged_attention_decode(
                 q1, kp, vp, bt, pos, use_kernel=False, **kw)),
         "prefill": (
             lambda interpret=False: pk.paged_prefill_kernel(
-                qs, kp, vp, bt1, start, interpret=interpret, **kw),
+                qs, kpk, vpk, bt1, start, interpret=interpret, **kw),
             lambda: pa.paged_attention_prefill(
                 qs, kp, vp, bt1, start, use_kernel=False, **kw)),
         # live rows only: a dead row is exact zeros from the kernel and
         # null-page garbage from the gather path, by design
         "ragged": (
             lambda interpret=False: pk.paged_ragged_kernel(
-                qr, kp, vp, tables, r_idx, r_pos, r_hor,
+                qr, kpk, vpk, tables, r_idx, r_pos, r_hor,
                 interpret=interpret, **kw)[live],
             lambda: pa.paged_attention_ragged(
                 qr, kp, vp, tables, r_idx, r_pos, r_hor,
@@ -188,23 +217,94 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     }
 
 
+# the head geometries of the benchmark's serving configurations
+FALCON = dict(n=71, nkv=1, d=64, page=16)
+MISTRAL = dict(n=32, nkv=8, d=128, page=16)
+
+# what the page walk can get wrong, beyond the preset scenario: a context
+# of three compute blocks that ends inside the third (and a horizon that
+# is no multiple of a block), a window that opens in the middle of a
+# block, and a wide table whose unused tail must not reach the output
+WALK_CASES = [
+    dict(max_pages=24, context=300),
+    dict(max_pages=24, context=300, window=50),
+    dict(max_pages=128, context=40, poison_tail=True),
+]
+
+
+def tick_case(seed: int, name: str, width=None):
+    """A ragged tick as the chip has seen it (PERF.md section 5): the
+    arguments of ``paged_ragged_kernel`` and the KV bytes the tick needs
+    (each table's keys once, K and V).
+
+    ``falcon``: 128 slots + a 64-row prefill chunk = 192 rows over 128 page
+    slots; 49 decode rows at contexts 300-700 and the chunk are live, 79
+    rows dead.  ``mistral``: 32 slots + 64 chunk rows = 96 rows over 256
+    page slots, 8 kv heads; 24 decode rows at 256-1536, window 4096.
+    ``width`` overrides the table width (same contexts).
+    """
+    import numpy as np
+
+    geo, slots, live, lo, hi, chunk_at, slots_wide, window = {
+        "falcon": (FALCON, 128, 49, 300, 700, 192, 128, None),
+        "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 4096),
+    }[name]
+    n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
+    width = width or slots_wide
+    rng = np.random.default_rng(seed)
+    num_pages = slots * slots_wide + 1
+    kp, vp = (jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)),
+                          jnp.bfloat16) for _ in range(2))
+    chunk = 64
+    pos = np.zeros(slots + chunk, np.int64)
+    idx = np.full(slots + chunk, slots + 1)
+    rows = rng.permutation(slots)[:live]
+    pos[rows] = rng.integers(lo, hi, size=live)
+    idx[rows] = rows
+    pos[slots:] = chunk_at + np.arange(chunk)
+    idx[slots:] = slots
+    # one table a slot, the chunk's, the null table; pages drawn at random
+    # over the pool as a long-running engine leaves them (drawn last: the
+    # contexts do not depend on the width)
+    tables = rng.integers(1, num_pages, size=(slots + 2, width))
+    tables[-1] = 0
+    hor = np.where(idx <= slots, (pos // 64 + 1) * 64, 0)
+    q = jnp.asarray(rng.normal(size=(slots + chunk, 1, n, d)), jnp.bfloat16)
+    visible = pos[rows] + 1
+    if window:
+        visible = np.minimum(visible, window)
+    keys = visible.sum() + chunk_at + chunk
+    args = (q, kp, vp) + tuple(
+        jnp.asarray(a, jnp.int32) for a in (tables, idx, pos, hor))
+    kw = dict(scale=1.0 / d ** 0.5, sliding_window=window)
+    return args, kw, np.flatnonzero(hor), int(keys) * 2 * nkv * d * 2
+
+
 def paged_numerics(quick: bool):
     """Compiled paged kernels vs the jnp gather path."""
+    from megatron_llm_tpu.ops import paged_attention as pa
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
     # the preset head geometries: Mistral/Mixtral/Llama-3 32q/8kv x 128,
     # Llama-2 32/32 x 128, at the engine's default page size
-    cases = [dict(n=32, nkv=8, d=128, page=16, kv_dtype=kvd, window=w)
+    cases = [dict(MISTRAL, kv_dtype=kvd, window=w)
              for kvd, w in (("bf16", None), ("bf16", 24), ("int8", None))]
     cases += [dict(n=32, nkv=32, d=128, page=16, kv_dtype=kvd)
               for kvd in ("bf16", "int8")]
+    cases += [dict(geo, **walk) for geo in (FALCON, MISTRAL)
+              for walk in WALK_CASES]
     if not quick:
         cases += [dict(n=32, nkv=8, d=128, page=page, kv_dtype=kvd)
                   for page in (8, 32, 128) for kvd in ("bf16", "int8", "fp8")]
-        cases += [dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8"),
+        cases += [dict(MISTRAL, kv_dtype="fp8"),
                   dict(n=8, nkv=2, d=256, page=16),
                   dict(n=8, nkv=2, d=256, page=16, kv_dtype="int8"),
                   # Falcon-7B: one kv head of 64
                   dict(n=8, nkv=1, d=64, page=16),
                   dict(n=8, nkv=1, d=64, page=16, kv_dtype="int8")]
+        cases += [dict(geo, kv_dtype=kvd, **walk)
+                  for geo in (FALCON, MISTRAL) for kvd in ("int8", "fp8")
+                  for walk in WALK_CASES]
     for i, case in enumerate(cases):
         tag = " ".join(f"{k}={v}" for k, v in case.items())
         for name, (pallas_fn, jnp_fn) in paged_case(i, **case).items():
@@ -214,6 +314,81 @@ def paged_numerics(quick: bool):
             except Exception as exc:  # a compiler refusal is a FAIL line
                 check(f"paged {name} {tag}", False,
                       f"{type(exc).__name__}: {str(exc)[:300]}")
+    for name in ("falcon", "mistral"):
+        args, kw, live, _ = tick_case(7, name)
+        q, kp, vp, tables, idx, pos, _ = args
+        try:
+            # a ragged row is the decode step at its position over its own
+            # table; the ragged gather path scores every row against every
+            # table, which at these shapes does not fit the chip
+            e = max_err(
+                pk.paged_ragged_kernel(*args, **kw)[live],
+                pa.paged_attention_decode(
+                    q[live], kp, vp, tables[idx[live]], pos[live],
+                    use_kernel=False, **kw))
+            check(f"paged tick {name}", e < TOL, f"max_err={e:.2e}")
+        except Exception as exc:
+            check(f"paged tick {name}", False,
+                  f"{type(exc).__name__}: {str(exc)[:300]}")
+
+
+def kernel_seconds(f, *args, kernel: str):
+    """Device seconds of each execution of the Pallas kernel ``kernel`` in
+    one traced call of ``f`` (plane ``/device:TPU:0``, line ``XLA Ops``:
+    an event is named by its instruction's text)."""
+    import glob
+    import tempfile
+
+    jax.block_until_ready(f(*args))  # compiled and warm before the trace
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            jax.block_until_ready(f(*args))
+        path = sorted(glob.glob(os.path.join(
+            d, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+        return [ev.duration_ns / 1e9
+                for plane in data.planes if plane.name == "/device:TPU:0"
+                for line in plane.lines if line.name == "XLA Ops"
+                for ev in line.events
+                if ev.name.lstrip("%").startswith(kernel)
+                and "custom-call" in ev.name]
+
+
+def paged_timing():
+    """ms a call of the paged kernel alone at the two tick shapes, beside
+    the least time the chip's HBM (819 GB/s, TPU v5e) allows for the KV
+    bytes the tick needs.  One program makes 24 calls, as a tick's layers
+    do; the kernel's own device time is read from a profiler trace of it
+    (the host's clock around the program is printed beside it, and holds
+    whatever copy XLA puts in front of the kernel)."""
+    import statistics
+
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    calls = 24
+    for name, width in (("falcon", 128), ("falcon", 256), ("mistral", 256)):
+        args, kw, _, need = tick_case(7, name, width)
+
+        def layers(q, *rest):
+            def layer(i, acc):
+                out = pk.paged_ragged_kernel(
+                    q + i.astype(q.dtype), *rest, **kw)
+                return acc + out.astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, calls, layer, jnp.zeros(q.shape, jnp.float32))
+
+        # one program per shape: timing each is the point
+        f = jax.jit(layers)  # graftcheck: noqa[recompile-hazard]
+        t = statistics.median(
+            kernel_seconds(f, *args, kernel="paged_attention"))
+        host = time_fn(f, *args) / calls
+        least = need / 819e9
+        print(f"TIME paged tick {name} rows={args[0].shape[0]} "
+              f"page_slots={width}: {t * 1e3:.3f} ms a call on the device "
+              f"({host * 1e3:.3f} by the host's clock over {calls} calls), "
+              f"least {least * 1e3:.4f} ms for {need / 1e6:.2f} MB of K "
+              f"and V ({100 * least / t:.2f}% of the bandwidth roofline)",
+              flush=True)
 
 
 def rmsnorm_check():
@@ -359,6 +534,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="numerics at the preset geometries only")
+    ap.add_argument("--time", action="store_true",
+                    help="time the paged kernel alone at the tick shapes "
+                         "the chip has seen, and nothing else")
     args = ap.parse_args()
 
     from megatron_llm_tpu.utils.platform import enable_compilation_cache
@@ -372,6 +550,9 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
+    if args.time:
+        paged_timing()
+        return
     flash_numerics(args.quick)
     paged_numerics(args.quick)
     if not args.quick:
