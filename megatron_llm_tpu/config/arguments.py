@@ -492,9 +492,11 @@ class InferenceConfig:
     # row batch (bitwise-identical output to the legacy split dispatch;
     # 0 restores the split decode-tick + per-chunk programs).  Requires
     # chunked prefill; prefill_chunk=0 implies the legacy path.
-    # --prefill_budget is the compiled prefill-row capacity of the ragged
-    # tick in TOKENS per tick (0 = one chunk's worth, the legacy pacing);
-    # the SchedulerPolicy's token-level prefill_budget is capped by it.
+    # --prefill_budget is the prompt TOKENS a tick may prefill: the
+    # compiled prefill-row capacity of the ragged tick and the cap on the
+    # SchedulerPolicy's token-level prefill_budget on either dispatch.
+    # 0 = --max_batch_slots rounded up to whole chunks, which for an
+    # engine of at most one chunk of slots is the legacy one chunk a tick.
     ragged_tick: bool = True
     prefill_budget: int = 0
     # per-request flight recorder (observability/flight.py, ISSUE 12):

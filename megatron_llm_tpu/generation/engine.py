@@ -834,10 +834,21 @@ class ContinuousBatchingEngine:
             and self.prefill_chunk)
         budget_cap = (prefill_budget if prefill_budget is not None
                       else getattr(inf, "prefill_budget", 0))
+        # prompt tokens a tick may prefill, on either dispatch; the
+        # policy's token budget is capped here.  Nobody set it: the decode
+        # width in whole chunks, so a wide engine fills its slots at the
+        # pace it empties them (the tick streams the weights once whatever
+        # its rows) and an engine of at most one chunk of slots keeps the
+        # one-chunk-a-tick interleave.
+        if budget_cap:
+            self._prefill_cap = max(self.prefill_chunk, int(budget_cap))
+        else:
+            self._prefill_cap = (
+                _bucket_up(self.max_slots, self.prefill_chunk)
+                if self.prefill_chunk else 0)
         # compiled prefill-row capacity of the ragged tick (a geometry
-        # static, like max_slots); the policy's token budget is capped here
-        self.prefill_rows = (max(self.prefill_chunk, int(budget_cap or 0))
-                             if self.ragged else 0)
+        # static, like max_slots)
+        self.prefill_rows = self._prefill_cap if self.ragged else 0
         # distinct prefilling requests packable into one tick — the
         # compressed-table capacity of the ragged program (one table row
         # per request; rows of a request share it)
@@ -1051,6 +1062,11 @@ class ContinuousBatchingEngine:
                  "prefill_budget control; observed on ticks that prefill)",
             buckets=[16.0, 32.0, 64.0, 128.0, 192.0, 256.0, 512.0,
                      1024.0])
+        self._m_multi_chunk = reg.counter(
+            "mlt_engine_prefill_multi_chunk_ticks_total",
+            help="ticks that prefilled more prompt tokens than one "
+                 "prefill_chunk (over mlt_engine_tick_kind_total"
+                 "{kind=\"prefill\"}: how often the pacing packs a tick)")
         self._m_preempt = reg.counter(
             "mlt_engine_preemptions_total",
             help="decoding requests preempted by page release")
@@ -2525,7 +2541,9 @@ class ContinuousBatchingEngine:
     def _prefill_budget_tokens(self) -> int:  # holds _lock
         """The policy's per-tick prefill budget, validated as TOKENS
         (ISSUE 11: the unit is pinned — a chunk-count return is a policy
-        bug) and floored to one chunk so prefill always advances."""
+        bug), floored to one chunk so prefill always advances and capped
+        at the engine's geometry (``_prefill_cap``) so that neither
+        dispatch stalls its decode rows behind a backlog of prompts."""
         budget = self.policy.prefill_budget(
             [r for r in self._prefill_q if r._phase == "prefill"],
             self._sched_state(time.monotonic()))
@@ -2533,7 +2551,7 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"prefill_budget must be a non-negative int of TOKENS, "
                 f"got {budget!r}")
-        return max(budget, self.prefill_chunk)
+        return min(max(budget, self.prefill_chunk), self._prefill_cap)
 
     def _prepare_decode_locked(self, active) -> np.ndarray:  # holds _lock
         """On-demand paging + per-slot speculation depth for the decode
@@ -2604,6 +2622,8 @@ class ContinuousBatchingEngine:
                 self._m_launches.inc(n)
             if prefill_tokens:
                 self._m_prefill_per_tick.observe(prefill_tokens)
+            if prefill_tokens > self.prefill_chunk:
+                self._m_multi_chunk.inc()
 
     # -- pipelined multi-tick dispatch (ISSUE 17) --------------------------
 
@@ -3044,7 +3064,7 @@ class ContinuousBatchingEngine:
         if not live:
             return (spans, pre_tok, pre_pos, pre_tables, pre_index,
                     pre_hor, lp_live)
-        budget = min(self._prefill_budget_tokens(), Rp)
+        budget = self._prefill_budget_tokens()  # at most Rp
         order = self.policy.prefill_order(
             live, self._sched_state(time.monotonic()))
         used = 0

@@ -121,13 +121,23 @@ class SchedulerPolicy:
         The unit is TOKENS, not chunks (ISSUE 11 pinned the ambiguity):
         the engine floors the budget to at least one chunk
         (``state.prefill_chunk``) so prefill always advances, and caps it
-        at its compiled prefill-row capacity; a budget of N tokens may
-        therefore admit MULTIPLE chunks from MULTIPLE prefilling requests
-        into one tick (tests/test_ragged_tick.py pins the regression).
-        The default — exactly one chunk's worth — matches the pre-policy
-        one-chunk-per-tick interleave, so decode never stalls behind
-        prefill.  Negative returns are a policy bug and raise."""
-        return max(state.prefill_chunk, 1)
+        at its prefill capacity (``--prefill_budget``; unset, the decode
+        width in whole chunks); a budget of N tokens may therefore admit
+        MULTIPLE chunks from MULTIPLE prefilling requests into one tick
+        (tests/test_ragged_tick.py pins the regression).
+        The default spends that capacity while prompts wait: it asks for
+        the rows ``prefilling`` still needs, each prompt counted to the
+        end of its last chunk (an upper bound: the engine packs the rows
+        there are).  Waiting prompts hold their slots, so the slots an
+        engine has fill as fast as its geometry lets them; an engine of
+        at most one chunk of slots is capped at one chunk a tick, the
+        pre-policy interleave.  Negative returns are a policy bug and
+        raise."""
+        chunk = max(state.prefill_chunk, 1)
+        need = sum(
+            -(-(len(r.prompt) + len(r.generated)) // chunk) * chunk
+            - r._fill_pos for r in prefilling)
+        return max(need, chunk)
 
     # ---- shedding ------------------------------------------------------
 

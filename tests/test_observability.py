@@ -563,15 +563,11 @@ def test_loss_bitwise_identical_with_observability(tmp_path):
         on["last_metrics"]["lm loss"])
 
 
-def test_generation_server_metrics_endpoint():
-    """ISSUE 4 acceptance: /metrics on the generation server serves
-    Prometheus text including engine slot occupancy."""
+def _toy_serving_model():
     import jax
 
-    from megatron_llm_tpu.generation import ContinuousBatchingEngine
-    from megatron_llm_tpu.generation.server import MegatronServer
     from megatron_llm_tpu.models import init_model_params, make_config
-    from tests.test_generation import VOCAB, ToyTokenizer
+    from tests.test_generation import VOCAB
 
     cfg = make_config(
         "llama2", num_layers=2, hidden_size=64, num_attention_heads=4,
@@ -579,8 +575,59 @@ def test_generation_server_metrics_endpoint():
         max_position_embeddings=256, vocab_size=VOCAB,
         params_dtype="float32", use_flash_attn=False,
     )
+    return cfg, init_model_params(cfg, jax.random.PRNGKey(0))
+
+
+def test_multi_chunk_tick_counter_counts_only_packed_ticks():
+    """ISSUE 28: mlt_engine_prefill_multi_chunk_ticks_total counts the
+    ticks that prefilled more than one chunk, no others, and is served."""
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.generation.server import MegatronServer
+    from megatron_llm_tpu.observability import registry as obs_registry
+    from tests.test_generation import ToyTokenizer
+
+    cfg, params = _toy_serving_model()
+    # two chunks of slots: the default capacity is two chunks a tick
+    engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer(),
+                                      max_slots=32, prefill_chunk=16)
+    reg = obs_registry.get_registry()
+
+    def read():
+        return (reg.counter(
+            "mlt_engine_prefill_multi_chunk_ticks_total").value,
+            reg.counter("mlt_engine_tick_kind_total",
+                        labels={"kind": "prefill"}).value)
+
+    def run(n_prompt):
+        req = engine.submit([2 + j % 60 for j in range(n_prompt)], 3,
+                            top_k=1, termination_id=10 ** 9)
+        engine.run_until_idle()
+        req.result(timeout=120)
+
+    multi0, pre0 = read()
+    run(10)                   # one short chunk: a prefill tick, not packed
+    assert read() == (multi0, pre0 + 1)
+    run(40)                   # 48 rows: a tick of two chunks, then one
+    multi1, pre1 = read()
+    assert (multi1, pre1) == (multi0 + 1, pre0 + 3)
+    srv = MegatronServer(engine)
+    port = srv.start_background(port=0)
+    try:
+        _, body, _ = _get(f"http://127.0.0.1:{port}/metrics")
+    finally:
+        srv.stop()
+    assert f"mlt_engine_prefill_multi_chunk_ticks_total {multi1:g}" in body
+
+
+def test_generation_server_metrics_endpoint():
+    """ISSUE 4 acceptance: /metrics on the generation server serves
+    Prometheus text including engine slot occupancy."""
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.generation.server import MegatronServer
+    from tests.test_generation import ToyTokenizer
+
+    cfg, params = _toy_serving_model()
     cfg.inference.max_batch_slots = 4
-    params = init_model_params(cfg, jax.random.PRNGKey(0))
     engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer())
     srv = MegatronServer(engine)
     port = srv.start_background(port=0)

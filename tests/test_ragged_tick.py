@@ -429,6 +429,107 @@ def test_budget_floor_keeps_prefill_alive(models):
 
 
 # ---------------------------------------------------------------------------
+# 4b. the default pacing: capacity from the decode width, spent while
+#     prompts wait (ISSUE 28)
+# ---------------------------------------------------------------------------
+
+
+def _wide(models, **kw):
+    """A decode width of two chunks: the smallest engine whose default
+    prefill capacity is more than one chunk."""
+    return _engine(models, max_slots=32, prefill_chunk=16, **kw)
+
+
+def _waiting_prompts():
+    # page-bucketed to 48 + 64 + 32 + 64 = 208 rows: six ticks of two
+    # chunks and one of one, so every bucket of the wide engine is used
+    return [([2 + (i * 7 + j) % 60 for j in range(n)], 6,
+             dict(top_k=1, termination_id=10 ** 9))
+            for i, n in enumerate((40, 50, 30, 60))]
+
+
+def _step_until_idle(eng, jobs):
+    """Results, and per step the prompt tokens prefilled and the programs
+    launched."""
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
+    per_step = []
+    while not all(r.finished for r in reqs):
+        before = eng.prefill_tokens_computed
+        eng.step()
+        per_step.append((eng.prefill_tokens_computed - before,
+                         eng.last_tick_launches))
+    return [r.result(timeout=120) for r in reqs], per_step
+
+
+def test_default_capacity_is_the_decode_width_in_chunks(models):
+    """Nobody set a budget: the ragged tick's prompt-row capacity is
+    max_slots in whole chunks, the default policy spends it while prompts
+    wait, and the executable bound holds and is reached."""
+    eng = _wide(models, ragged=True)
+    assert eng.prefill_rows == eng.max_slots == 2 * eng.prefill_chunk
+    _, per_step = _step_until_idle(eng, _waiting_prompts())
+    prefilled = [n for n, _ in per_step if n]
+    assert prefilled == [32] * 6 + [16], prefilled
+    assert all(launches == 1 for _, launches in per_step)
+    assert sorted(eng._ragged_fns) == [0, 16, 32]
+    assert len(eng._ragged_fns) == 1 + eng.prefill_rows // eng.prefill_chunk
+    for fn in eng._ragged_fns.values():
+        assert fn._cache_size() == 1
+    # a capacity that is not a whole number of chunks rounds up
+    assert _engine(models, max_slots=20, prefill_chunk=16).prefill_rows == 32
+
+
+def _launch_sequence(eng, jobs):
+    """(prefill rows, prompt tokens, decode rows) of every launch."""
+    from megatron_llm_tpu.observability import trace as obs_trace
+
+    old = obs_trace.get_tracer()
+    tracer = obs_trace.configure(capacity=8192)
+    try:
+        out = _run(eng, jobs)
+    finally:
+        obs_trace._TRACER = old
+    return out, [(e[5]["prefill_rows"], e[5]["prefill_tokens"],
+                  e[5]["decode_rows"]) for e in tracer.snapshot()
+                 if e[0] == "X" and e[1] == "engine-launch"]
+
+
+@pytest.mark.parametrize("slots", [4, 64])
+def test_narrow_engine_keeps_one_chunk_a_tick(models, slots):
+    """max_slots <= prefill_chunk: the default is the explicit one-chunk
+    budget, launch for launch."""
+    default = _engine(models, max_slots=slots, ragged=True)
+    pinned = _engine(models, max_slots=slots, ragged=True,
+                     prefill_budget=default.prefill_chunk)
+    assert default.prefill_rows == pinned.prefill_rows == 64
+    got, seq = _launch_sequence(default, _mixed_jobs(n_new=6))
+    want, want_seq = _launch_sequence(pinned, _mixed_jobs(n_new=6))
+    assert seq == want_seq
+    assert any(rows for rows, _, _ in seq)
+    assert_same_generations(want, got)
+
+
+@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "legacy"])
+def test_default_pacing_is_lossless(models, ragged):
+    """The wide default against an explicit one-chunk budget: the same
+    tokens and log-probs (tests/parity.py's contract) in fewer ticks, on
+    either dispatch; the legacy dispatch launches at most capacity /
+    chunk chunk programs a tick however many prompts wait."""
+    jobs = _waiting_prompts() + _mixed_jobs(n_new=6)
+    packed = _wide(models, ragged=ragged)
+    got, per_step = _step_until_idle(packed, jobs)
+    paced = _wide(models, ragged=ragged, prefill_budget=16)
+    want, paced_steps = _step_until_idle(paced, jobs)
+    assert_same_generations(want, got)
+    assert max(n for n, _ in paced_steps) == 16
+    assert max(n for n, _ in per_step) == 32
+    assert packed.ticks < paced.ticks
+    if not ragged:
+        assert packed.prefill_rows == 0  # no ragged program: the cap alone
+        assert max(launches for _, launches in per_step) == 3
+
+
+# ---------------------------------------------------------------------------
 # 5. telemetry surface
 # ---------------------------------------------------------------------------
 
