@@ -43,17 +43,6 @@ Two modes:
   decode tok/s speedup at concurrency 1 — the latency-bound shape
   speculative decoding exists for.  Gate: >= 1.3x.
 
-* ``--mode mixed`` (ISSUE 11): the ragged-paged-attention headline — long
-  prefills arriving under a saturated speculative decode batch, identical
-  traffic through the RAGGED single-launch tick and the legacy split
-  dispatch (decode/spec tick + one program per prefill chunk).  Rows
-  report attention-program launches per tick, TTFT of the long-prompt
-  requests (prefill-scheduling-bound), decode tok/s and tokens/tick for
-  both arms; the in-bench losslessness assert pins ragged tokens ==
-  legacy tokens.  Headline: launches-per-tick reduction (dispatch is the
-  cost ragged removes; the TTFT/tok-s deltas ride along).  Gate: >= 1.5x
-  launch reduction with TTFT and tok/s no worse.
-
 * ``--mode capacity`` (ISSUE 13): concurrent-user capacity at a FIXED
   pool byte budget, ``--kv_dtype int8`` vs ``bf16``.  The budget is what
   a bf16 pool of the reference size occupies; each arm gets as many
@@ -124,7 +113,6 @@ METRIC_PREFIX = "engine_prefix_prefill_reduction_llama470m_c8_1chip"
 METRIC_SLO = "engine_slo_hi_p99_ttft_speedup_llama470m_1chip"
 METRIC_SPEC = "engine_spec_decode_speedup_llama470m_c1_1chip"
 METRIC_ROUTER = "router_prefix_affinity_ttft_speedup_llama470m_2rep_1chip"
-METRIC_MIXED = "engine_ragged_launch_reduction_llama470m_mixed_1chip"
 METRIC_PIPELINE = "engine_pipeline_decode_speedup_llama470m_c8_1chip"
 METRIC_STREAMING = "serving_stream_first_token_speedup_llama470m_c8_2rep_1chip"
 METRIC_DISAGG = "serving_disagg_decode_p99_tpot_speedup_llama470m_2rep_1chip"
@@ -681,115 +669,6 @@ def bench_spec(cfg, params, draft, levels, prompt, gen, vocab,
         "step_time_s": round(
             headline["on"]["wall_s"] / max(headline["on"]["ticks"], 1), 6),
         "rows": rows,
-    }
-
-
-def bench_mixed(cfg, params, draft, slots, n_short, n_long, prompt_long,
-                gen_short, gen_long, vocab, spec_k: int, budget: int,
-                reps: int) -> dict:
-    """Ragged vs legacy split dispatch on a mixed workload: ``n_short``
-    tiny-prompt/long-generation requests saturate the decode slots while
-    ``n_long`` long-prompt requests chunk-prefill underneath them, spec
-    on — every steady tick carries decode + verify + prefill work.  Both
-    arms run identical traffic; emitted tokens are asserted equal.
-
-    The legacy arm runs the historical split dispatch it represents:
-    one prefill chunk interleaved per tick (separate compiled program
-    per chunk) — the scheduling constraint the ragged tick exists to
-    remove.  The ragged arm packs ``budget`` prompt tokens (multiple
-    chunks, multiple requests) into its ONE launch per tick."""
-    import numpy as np
-
-    from megatron_llm_tpu.generation.scheduling import get_policy
-
-    shorts = _requests(n_short, 8, gen_long, vocab, seed=5)
-    longs = _requests(n_long, prompt_long, gen_short, vocab, seed=7)
-
-    class _BudgetFcfs(get_policy("fcfs")):
-        name = "fcfs_budget"
-
-        def prefill_budget(self, prefilling, state):
-            return budget
-
-    def run(ragged: bool) -> dict:
-        best = None
-        for _ in range(max(reps, 1) + 1):  # first rep warms the compiles
-            ekw = dict(ragged=ragged, spec_k=spec_k, spec_draft=draft,
-                       spec_adaptive=False)
-            if ragged:
-                ekw.update(prefill_budget=budget,
-                           sched_policy=_BudgetFcfs())
-            eng = make_engine(
-                cfg, params, max_slots=slots,
-                max_seq=max(8 + gen_long, prompt_long + gen_short),
-                **ekw)
-            jobs = ([(p, gen_long, dict(GREEDY_KW)) for p in shorts]
-                    + [(p, gen_short, dict(GREEDY_KW)) for p in longs])
-            t0 = time.perf_counter()
-            reqs = run_workload(eng, jobs)
-            wall = time.perf_counter() - t0
-            if best is None or wall < best[0]:
-                best = (wall, eng, reqs)
-        wall, eng, reqs = best
-        long_reqs = reqs[n_short:]
-        ttft_ms = sorted(1e3 * r.ttft for r in long_reqs)
-        total_gen = n_short * gen_long + n_long * gen_short
-        row = {
-            "ragged": ragged,
-            "wall_s": round(wall, 4),
-            "decode_tok_s": round(total_gen / wall, 1),
-            "ticks": eng.ticks,
-            "launches": eng.tick_launches,
-            "launches_per_tick": round(
-                eng.tick_launches / max(eng.ticks, 1), 3),
-            "tok_per_tick": round(
-                eng.ticked_tokens / max(eng.ticks, 1), 3),
-            "long_ttft_mean_ms": round(float(np.mean(ttft_ms)), 2),
-            "long_ttft_p50_ms": round(_percentile(ttft_ms, 50), 2),
-            "long_ttft_p99_ms": round(_percentile(ttft_ms, 99), 2),
-            "_tokens": [r.generated for r in reqs],
-        }
-        return row
-
-    t0 = time.perf_counter()
-    run(False)
-    run(True)
-    compile_s = time.perf_counter() - t0
-
-    legacy = run(False)
-    ragged = run(True)
-    assert ragged.pop("_tokens") == legacy.pop("_tokens"), (
-        "ragged dispatch emitted different tokens than the legacy split "
-        "path — bitwise parity violated")
-    launch_reduction = round(
-        legacy["launches_per_tick"] / max(ragged["launches_per_tick"],
-                                          1e-9), 2)
-    ttft_speedup = round(
-        legacy["long_ttft_mean_ms"] / max(ragged["long_ttft_mean_ms"],
-                                          1e-9), 2)
-    tok_s_speedup = round(
-        ragged["decode_tok_s"] / max(legacy["decode_tok_s"], 1e-9), 2)
-    return {
-        "slots": slots,
-        "n_short": n_short,
-        "n_long": n_long,
-        "prompt_long": prompt_long,
-        "gen_short": gen_short,
-        "gen_long": gen_long,
-        "spec_k": spec_k,
-        "prefill_budget": budget,
-        "launch_reduction": launch_reduction,
-        "ttft_speedup": ttft_speedup,
-        "tok_s_speedup": tok_s_speedup,
-        # the deterministic claim is dispatch; the timing deltas must not
-        # regress (CPU single-core walls are noisy — see repo memory)
-        "speedup_ok": (launch_reduction >= 1.5
-                       and ragged["launches_per_tick"] <= 1.001
-                       and ttft_speedup >= 0.95 and tok_s_speedup >= 0.95),
-        "compile_time_s": round(compile_s, 1),
-        "step_time_s": round(
-            ragged["wall_s"] / max(ragged["ticks"], 1), 6),
-        "rows": [legacy, ragged],
     }
 
 
@@ -1560,7 +1439,6 @@ def _run(args, finished):
     slo_mode = args.mode == "slo"
     spec_mode = args.mode == "spec"
     router_mode = args.mode == "router"
-    mixed_mode = args.mode == "mixed"
     cap_mode = args.mode == "capacity"
     pipe_mode = args.mode == "pipeline"
     stream_mode = args.mode == "streaming"
@@ -1569,9 +1447,6 @@ def _run(args, finished):
     pipe_depths = (0, 1, 2, 8)
     burst = 12  # admission-arm clients (streaming mode section 2)
     draft_layers = 2
-    # mixed-mode workload shape (TPU defaults; CPU sanity overrides below)
-    mx = dict(slots=8, n_short=6, n_long=4, prompt_long=256,
-              gen_short=16, gen_long=128, budget=256)
     # capacity-mode workload shape (ISSUE 13): ref_slots sizes the fixed
     # byte budget (a bf16 pool for that many concurrent sequences),
     # n_requests over-subscribes it so the peak is pool-bound, and the
@@ -1611,13 +1486,6 @@ def _run(args, finished):
             # the target must out-depth the 1-layer draft by enough that
             # drafting is visibly cheaper than verifying
             layers, args.gen, draft_layers = 4, 48, 1
-        if mixed_mode:
-            # small enough for tier-1 time, long enough that the decode
-            # batch is still saturated while the long prompts prefill
-            # (every steady tick then mixes decode + verify + prefill)
-            layers, draft_layers = 2, 1
-            mx = dict(slots=3, n_short=2, n_long=2, prompt_long=160,
-                      gen_short=6, gen_long=40, budget=192)
         if pipe_mode:
             # host-bound shape: this mode measures ORCHESTRATION
             # amortization, so the model must be small enough that host
@@ -1677,8 +1545,6 @@ def _run(args, finished):
     seq_need = max(args.prompt + args.gen,
                    args.shared + args.tail + args.gen,
                    args.prompt + args.gen_lo,
-                   mx["prompt_long"] + mx["gen_short"],
-                   8 + mx["gen_long"],
                    cap["shared"] + cap["tail"] + cap["gen_cache"],
                    dg["prompt_long"] + max(dg["gen_short"],
                                            dg["gen_long"]) + 1)
@@ -1730,7 +1596,7 @@ def _run(args, finished):
             c = levels[-1]
             row = bench_shared_prefix(cfg, params, c, args.shared,
                                       args.tail, args.gen, vocab)
-        elif spec_mode or mixed_mode:
+        elif spec_mode:
             from megatron_llm_tpu.generation import DraftModel
             from megatron_llm_tpu.generation.speculative import (
                 extend_params_identity,
@@ -1749,17 +1615,9 @@ def _run(args, finished):
             dparams = init_model_params(dcfg, jax.random.PRNGKey(1))
             params = extend_params_identity(dcfg, dparams, cfg,
                                             jax.random.PRNGKey(0))
-            if mixed_mode:
-                row = bench_mixed(cfg, params, DraftModel(dcfg, dparams),
-                                  mx["slots"], mx["n_short"], mx["n_long"],
-                                  mx["prompt_long"], mx["gen_short"],
-                                  mx["gen_long"], vocab,
-                                  min(args.spec_k, 2), mx["budget"],
-                                  args.reps)
-            else:
-                row = bench_spec(cfg, params, DraftModel(dcfg, dparams),
-                                 levels, args.prompt, args.gen, vocab,
-                                 args.spec_k, args.reps)
+            row = bench_spec(cfg, params, DraftModel(dcfg, dparams),
+                             levels, args.prompt, args.gen, vocab,
+                             args.spec_k, args.reps)
         elif slo_mode:
             row = bench_slo(cfg, params, args.slots, args.n_hi, args.n_lo,
                             args.prompt, args.gen, args.gen_lo, vocab,
@@ -1861,28 +1719,6 @@ def _run(args, finished):
             "device_kind": getattr(jax.devices()[0], "device_kind", "?"),
         }
         tag = "engine_decode_capacity"
-    elif mixed_mode:
-        result = {
-            "metric": METRIC_MIXED,
-            "value": row["launch_reduction"],
-            "unit": "x",
-            "launch_reduction": row["launch_reduction"],
-            "speedup_ok": row["speedup_ok"],
-            "ttft_speedup": row["ttft_speedup"],
-            "tok_s_speedup": row["tok_s_speedup"],
-            "spec_k": row["spec_k"],
-            "prefill_budget": row["prefill_budget"],
-            "compile_time_s": row["compile_time_s"],
-            "step_time_s": row["step_time_s"],
-            "n_params": n_params,
-            "rows": row["rows"],
-            "workload": {k: row[k] for k in
-                         ("slots", "n_short", "n_long", "prompt_long",
-                          "gen_short", "gen_long")},
-            "backend": jax.devices()[0].platform,
-            "device_kind": getattr(jax.devices()[0], "device_kind", "?"),
-        }
-        tag = "engine_decode_mixed"
     elif spec_mode:
         result = {
             "metric": METRIC_SPEC,
@@ -2001,7 +1837,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode",
                     choices=("occupancy", "shared_prefix", "slo", "spec",
-                             "router", "mixed", "capacity", "pipeline",
+                             "router", "capacity", "pipeline",
                              "streaming", "disagg", "pp"),
                     default="occupancy")
     ap.add_argument("--concurrency", default="1,4,8",
@@ -2041,13 +1877,13 @@ def main():
         args.concurrency = "1,2,4,8"
     metric = {"shared_prefix": METRIC_PREFIX, "slo": METRIC_SLO,
               "spec": METRIC_SPEC, "router": METRIC_ROUTER,
-              "mixed": METRIC_MIXED, "pipeline": METRIC_PIPELINE,
+              "pipeline": METRIC_PIPELINE,
               "capacity": METRIC_CAPACITY,
               "streaming": METRIC_STREAMING,
               "disagg": METRIC_DISAGG,
               "pp": METRIC_PP}.get(args.mode, METRIC)
     unit = ("x" if args.mode in ("shared_prefix", "slo", "spec", "router",
-                                 "mixed", "capacity", "pipeline",
+                                 "capacity", "pipeline",
                                  "streaming", "disagg", "pp")
             else "tok/s")
     finished = threading.Event()

@@ -426,6 +426,18 @@ class LoggingConfig:
     metrics: List[str] = field(default_factory=list)
 
 
+def check_prefill_chunk(prefill_chunk: int, page_size: int) -> None:
+    """The one check of ``--prefill_chunk``, where the value enters
+    (:meth:`Config.finalize` and the engine's constructor): prompt rows
+    join the tick in chunks on a grid of whole pages, so there is no
+    unchunked mode."""
+    if (not isinstance(prefill_chunk, int) or prefill_chunk <= 0
+            or prefill_chunk % page_size):
+        raise ValueError(
+            f"prefill_chunk must be a positive whole number of pages "
+            f"(page_size {page_size}), got {prefill_chunk!r}")
+
+
 @dataclass
 class InferenceConfig:
     """Text-generation server/sampling defaults."""
@@ -454,10 +466,10 @@ class InferenceConfig:
     kv_dtype: str = "bf16"
     # prefix cache + chunked prefill (ISSUE 5): shared refcounted prompt
     # pages with copy-on-write, prefill split into --prefill_chunk-token
-    # chunks interleaved one per decode tick (0 = monolithic PR-1 prefill,
-    # which also disables the cache — it needs the block-table prefill
-    # path); --page_watermark is extra free+evictable slack admission keeps
-    # beyond the worst-case commitment of in-flight requests;
+    # chunks packed into the decode tick (a positive whole number of
+    # pages, check_prefill_chunk); --page_watermark is extra
+    # free+evictable slack admission keeps beyond the worst-case
+    # commitment of in-flight requests;
     # --max_queued_requests bounds the submit queue (overflow -> 503 with
     # Retry-After on the server, 0 = unbounded)
     prefix_cache: bool = True
@@ -486,18 +498,14 @@ class InferenceConfig:
     spec_k: int = 0
     spec_draft: Optional[str] = None
     spec_adaptive: bool = True
-    # ragged paged attention (generation/ragged.py, ISSUE 11):
-    # --ragged_tick fuses every tick's decode slots, speculative-verify
-    # blocks and prefill-chunk rows into ONE compiled launch over a ragged
-    # row batch (bitwise-identical output to the legacy split dispatch;
-    # 0 restores the split decode-tick + per-chunk programs).  Requires
-    # chunked prefill; prefill_chunk=0 implies the legacy path.
+    # ragged paged attention (generation/ragged.py, ISSUE 11): every
+    # tick's decode slots, speculative-verify blocks and prefill-chunk
+    # rows are ONE compiled launch over a ragged row batch.
     # --prefill_budget is the prompt TOKENS a tick may prefill: the
-    # compiled prefill-row capacity of the ragged tick and the cap on the
-    # SchedulerPolicy's token-level prefill_budget on either dispatch.
+    # compiled prefill-row capacity of the tick and the cap on the
+    # SchedulerPolicy's token-level prefill_budget.
     # 0 = --max_batch_slots rounded up to whole chunks, which for an
-    # engine of at most one chunk of slots is the legacy one chunk a tick.
-    ragged_tick: bool = True
+    # engine of at most one chunk of slots is one chunk a tick.
     prefill_budget: int = 0
     # per-request flight recorder (observability/flight.py, ISSUE 12):
     # --flight_records bounds how many retired request records the
@@ -563,6 +571,8 @@ class Config:
         """
         self.model.finalize()
         self.parallel.finalize(n_devices)
+        check_prefill_chunk(self.inference.prefill_chunk,
+                            self.inference.page_size)
         t = self.training
         if t.global_batch_size is None:
             dp = self.parallel.data_parallel_size or 1

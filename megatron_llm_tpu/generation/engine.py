@@ -34,30 +34,29 @@ resident, keep its batch full, and never compute the same prefix twice.
   write K/V through the block table and attend through it too
   (ops/paged_attention.paged_attention_prefill — the prefix-length-aware
   prefill-against-block-table mode, Pallas kernel on TPU).  The scheduler
-  interleaves ONE chunk per decode tick instead of stalling the whole batch
-  for a monolithic prompt, so queued requests' time-to-first-token stops
+  packs chunk rows into the decode tick instead of stalling the whole batch
+  for a whole prompt, so queued requests' time-to-first-token stops
   scaling with the longest admitted prompt.  Chunk boundaries are aligned
   to absolute-position multiples of ``prefill_chunk`` and the attended page
   horizon is bucketed per chunk, so the K/V bits a chunk produces depend
   only on (tokens, absolute positions) — a cache hit replays bitwise the
   pages a cold prefill would compute (the cache-on/off parity contract,
-  tests/test_prefix_cache.py).  ``prefill_chunk=0`` restores the PR 1
-  monolithic dense prefill (and disables the prefix cache, which needs the
-  block-table prefill path).
+  tests/test_prefix_cache.py).
 
-* **Slots + fixed shapes**: the decode tick runs ``max_slots`` rows every
+* **Slots + fixed shapes**: the tick runs ``max_slots`` decode rows every
   time, active or not.  Block tables, positions, per-slot sampling params
-  and per-slot PRNG keys are *traced* inputs, so the tick compiles ONCE;
-  prefill compiles once per (chunk rows, page horizon) pair.  Slots mid
-  prefill keep their device block-table row at the null page, so tick
-  writes from not-yet-active rows land in garbage that is never attended.
+  and per-slot PRNG keys are *traced* inputs, so the tick compiles once
+  per bucketed count of prompt rows it carries (generation/ragged.py).
+  Slots mid prefill keep their device block-table row at the null page,
+  so tick writes from not-yet-active rows land in garbage that is never
+  attended.
 
-* **Decode tick**: one fused jitted step — embed [slots, 1] tokens, write
-  each row's K/V into its current page, paged attention over block tables
-  (Pallas kernel on TPU, jnp gather fallback elsewhere —
-  ops/paged_attention.py), per-slot sampling (sampling.sample_per_slot),
-  token log-probs.  Pool buffers are donated, so the cache updates in
-  place.
+* **The tick**: one fused jitted step (generation/ragged.py) — embed the
+  decode rows' tokens and the packed prompt rows, write each row's K/V
+  into its current page, paged attention over block tables (Pallas kernel
+  on TPU, jnp gather fallback elsewhere — ops/paged_attention.py),
+  per-slot sampling (sampling.sample_per_slot), token log-probs.  Pool
+  buffers are donated, so the cache updates in place.
 
 * **Scheduling control plane** (generation/scheduling/): every scheduling
   DECISION — admission order, the per-tick prefill-chunk budget,
@@ -105,9 +104,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from megatron_llm_tpu.config.arguments import check_prefill_chunk
 from megatron_llm_tpu.core.parallel_state import PP_AXIS, TP_AXIS
 from megatron_llm_tpu.generation import generation as gen
-from megatron_llm_tpu.generation.sampling import sample_per_slot
 from megatron_llm_tpu.generation.scheduling import (
     RequestShed,
     SchedulerPolicy,
@@ -673,7 +672,6 @@ class ContinuousBatchingEngine:
                  spec_k: Optional[int] = None,
                  spec_draft=None,
                  spec_adaptive: Optional[bool] = None,
-                 ragged: Optional[bool] = None,
                  prefill_budget: Optional[int] = None,
                  flight_records: Optional[int] = None,
                  flight_events: Optional[int] = None,
@@ -757,9 +755,7 @@ class ContinuousBatchingEngine:
             "scatter whole pages")
         self.prefill_chunk = (prefill_chunk if prefill_chunk is not None
                               else getattr(inf, "prefill_chunk", gen.BUCKET))
-        if self.prefill_chunk:
-            assert self.prefill_chunk % self.page_size == 0, (
-                "prefill_chunk must be a whole number of pages")
+        check_prefill_chunk(self.prefill_chunk, self.page_size)
         use_cache = (prefix_cache if prefix_cache is not None
                      else getattr(inf, "prefix_cache", True))
         self.page_watermark = (page_watermark if page_watermark is not None
@@ -806,10 +802,6 @@ class ContinuousBatchingEngine:
             if draft is None:
                 raise ValueError(
                     "spec_k > 0 requires a draft model (--spec_draft)")
-            assert self.prefill_chunk, (
-                "speculative decoding requires chunked prefill "
-                "(prefill_chunk > 0): draft K/V is populated through the "
-                "block-table prefill path")
             if isinstance(draft, str):
                 draft = resolve_draft(draft, cfg)
             elif isinstance(draft, tuple):
@@ -822,38 +814,28 @@ class ContinuousBatchingEngine:
                 draft_params = jax.device_put(
                     draft_params, param_shardings(mesh, draft_params))
             self.draft_cfg, self.draft_params = draft.cfg, draft_params
-        # ragged tick (generation/ragged.py, ISSUE 11): ONE compiled
-        # launch per tick carries the decode slots, the speculative-verify
-        # blocks AND up to prefill_rows prefill-chunk rows — bitwise-
-        # identical output to the legacy split dispatch, minus its per-tick
-        # program launches.  Needs the block-table prefill path, so
-        # prefill_chunk=0 (monolithic) implies the legacy dispatch.
-        self.ragged = bool(
-            (ragged if ragged is not None
-             else getattr(inf, "ragged_tick", True))
-            and self.prefill_chunk)
         budget_cap = (prefill_budget if prefill_budget is not None
                       else getattr(inf, "prefill_budget", 0))
-        # prompt tokens a tick may prefill, on either dispatch; the
-        # policy's token budget is capped here.  Nobody set it: the decode
-        # width in whole chunks, so a wide engine fills its slots at the
-        # pace it empties them (the tick streams the weights once whatever
-        # its rows) and an engine of at most one chunk of slots keeps the
+        # prompt tokens a tick may prefill; the policy's token budget is
+        # capped here.  Nobody set it: the decode width in whole chunks,
+        # so a wide engine fills its slots at the pace it empties them
+        # (the tick streams the weights once whatever its rows) and an
+        # engine of at most one chunk of slots keeps the
         # one-chunk-a-tick interleave.
         if budget_cap:
             self._prefill_cap = max(self.prefill_chunk, int(budget_cap))
         else:
-            self._prefill_cap = (
-                _bucket_up(self.max_slots, self.prefill_chunk)
-                if self.prefill_chunk else 0)
+            self._prefill_cap = _bucket_up(self.max_slots,
+                                           self.prefill_chunk)
         # compiled prefill-row capacity of the ragged tick (a geometry
-        # static, like max_slots)
-        self.prefill_rows = self._prefill_cap if self.ragged else 0
+        # static, like max_slots): ONE compiled launch per tick carries
+        # the decode slots, the speculative-verify blocks AND up to
+        # prefill_rows prefill-chunk rows (generation/ragged.py)
+        self.prefill_rows = self._prefill_cap
         # distinct prefilling requests packable into one tick — the
         # compressed-table capacity of the ragged program (one table row
         # per request; rows of a request share it)
-        self._pre_tables_cap = (self.prefill_rows // self.prefill_chunk + 1
-                                if self.ragged else 0)
+        self._pre_tables_cap = self.prefill_rows // self.prefill_chunk + 1
         self.pages_per_seq = -(-self.max_seq // self.page_size)
         num_pages = (num_pages or inf.kv_pool_pages
                      or self.max_slots * self.pages_per_seq + 1)
@@ -873,25 +855,15 @@ class ContinuousBatchingEngine:
         self.pipeline_depth = max(0, int(
             tick_pipeline_depth if tick_pipeline_depth is not None
             else getattr(inf, "tick_pipeline_depth", 0)))
-        if self._pp > 1:
-            # the monolithic dense prefill (init_kv_caches + cache_index)
-            # has no stage decomposition — pp serving requires the
-            # block-table chunked prefill path
-            assert self.prefill_chunk, (
-                "pipeline-parallel serving requires chunked prefill "
-                "(prefill_chunk > 0)")
-            if self.draft_cfg is not None:
-                assert self.draft_cfg.model.num_layers % self._pp == 0, (
-                    f"draft num_layers {self.draft_cfg.model.num_layers} "
-                    f"not divisible by pp {self._pp}")
+        if self._pp > 1 and self.draft_cfg is not None:
+            assert self.draft_cfg.model.num_layers % self._pp == 0, (
+                f"draft num_layers {self.draft_cfg.model.num_layers} "
+                f"not divisible by pp {self._pp}")
         self.pool = PagedKVPool(cfg, num_pages, self.page_size, mesh=mesh,
                                 draft_cfg=self.draft_cfg,
                                 kv_dtype=self.kv_dtype)
-        # the prefix cache needs the block-table prefill path: a monolithic
-        # dense prefill recomputes and rewrites the whole prompt, shared
-        # pages included
         self.cache = (PrefixCache(self.pool, self.page_size)
-                      if use_cache and self.prefill_chunk else None)
+                      if use_cache else None)
 
         # host-side slot state + scheduler queues: every attribute marked
         # "guarded by _lock" below is shared between submitter threads,
@@ -927,13 +899,12 @@ class ContinuousBatchingEngine:
         self._thread: Optional[threading.Thread] = None
         self._stopping = False  # guarded by _lock
 
-        self._tick_fn = None
-        self._spec_tick_fn = None
         # ragged tick executables keyed by bucketed live-prefill-row
         # count — bounded at 1 + prefill_rows // prefill_chunk entries
         self._ragged_fns: Dict[int, object] = {}
-        self._prefill_fns: Dict[Tuple[int, bool], object] = {}
-        self._chunk_fns: Dict[Tuple[int, int, bool], object] = {}
+        # the return_log_probs carve-out's chunk executables, keyed by
+        # (rows, page horizon)
+        self._chunk_fns: Dict[Tuple[int, int], object] = {}
         self._copy_fn = None
         # device mirror of the per-slot arrays; rebuilt from the host copies
         # whenever admission/retirement changes the slot layout
@@ -960,11 +931,11 @@ class ContinuousBatchingEngine:
         # tick/cache telemetry for the decode bench
         self.ticks = 0
         self.ticked_tokens = 0
-        # attention-program launches in the tick phase (ISSUE 11): ragged
-        # ticks dispatch ONE compiled program per tick; the legacy split
-        # path dispatches the decode/spec tick plus one program per
-        # prefill chunk.  last_tick_launches is the most recent step's
-        # count — the single-launch claim tests assert on.
+        # attention-program launches in the tick phase (ISSUE 11): ONE
+        # compiled program per tick, plus the scoring chunk of a
+        # return_log_probs prompt when one is prefilling.
+        # last_tick_launches is the most recent step's count — the
+        # single-launch claim tests assert on.
         self.tick_launches = 0
         self.last_tick_launches = 0
         # capacity telemetry (ISSUE 13): the high-water mark of
@@ -1051,11 +1022,12 @@ class ContinuousBatchingEngine:
             help="copy-on-write page copies (shared page would be written)")
         self._m_prefill_tokens = reg.counter(
             "mlt_engine_prefill_tokens_total",
-            help="token rows pushed through prefill (chunked or monolithic)")
+            help="token rows pushed through prefill")
         self._m_launches = reg.counter(
             "mlt_engine_tick_launches_total",
-            help="attention-program launches in the tick phase (ragged "
-                 "mode: exactly one per non-idle tick)")
+            help="attention-program launches in the tick phase (one per "
+                 "non-idle tick, plus a return_log_probs prompt's scoring "
+                 "chunk)")
         self._m_prefill_per_tick = reg.histogram(
             "mlt_engine_prefill_tokens_per_tick",
             help="prompt tokens prefilled per tick (token-level "
@@ -1121,8 +1093,7 @@ class ContinuousBatchingEngine:
                  "apply)",
             buckets=lat)
         # where one ragged tick's wall time goes, phase by phase, at the
-        # boundaries of the engine-* spans (one observation a tick each;
-        # the ragged path only, which is every tick by default)
+        # boundaries of the engine-* spans (one observation a tick each)
         self._m_phase = {
             ph: reg.histogram(
                 "mlt_engine_tick_phase_seconds",
@@ -1302,84 +1273,8 @@ class ContinuousBatchingEngine:
 
     # -- compiled programs -------------------------------------------------
 
-    def _tick(self):
-        """The fused decode-tick program, compiled once per (config, engine
-        geometry) — shared ACROSS engine instances via the fingerprint-keyed
-        generation cache, so rebuilding an engine never recompiles."""
-        if self._tick_fn is not None:
-            return self._tick_fn
-        cfg = self.cfg
-        m = cfg.model
-
-        # scope name carries the tp degree: the row-parallel all-reduces
-        # GSPMD inserts under a tp>1 mesh inherit it in HLO op metadata,
-        # so device profiles attribute them to the decode forward
-        scope = ("decode-fwd" if self._tp == 1
-                 else f"decode-fwd-tp{self._tp}")
-        from megatron_llm_tpu.parallel import overlap as tp_overlap_mod
-        from megatron_llm_tpu.parallel import pp_serve as pp_serve_mod
-
-        ovl = self._overlap
-        ppc = self._ppc
-
-        def tick(params, pool_k, pool_v, block_tables, positions, tokens,
-                 req_keys, steps, temperature, top_k, top_p):
-            rope = make_rope_cache(cfg)
-            with jax.named_scope(scope), tp_overlap_mod.activate(ovl), \
-                    pp_serve_mod.activate(ppc):
-                logits, (pool_k, pool_v) = model_forward(
-                    cfg, params, tokens[:, None],
-                    position_ids=positions[:, None],
-                    rope_cache=rope, kv_caches=(pool_k, pool_v),
-                    paged=PagedState(block_tables, positions),
-                )
-            last = logits[:, -1]
-            keys = jax.vmap(jax.random.fold_in)(req_keys, steps)
-            next_tok = sample_per_slot(
-                keys, last, top_k=top_k, top_p=top_p,
-                temperature=temperature, vocab_size=m.vocab_size)
-            logp = gen._gather_token_log_probs(last, next_tok)
-            # advance the device-resident slot state in-program so steady
-            # ticks need no host->device uploads (step() re-uploads from the
-            # host copy only after admit/retire dirties the layout)
-            return (pool_k, pool_v, next_tok, logp,
-                    positions + 1, steps + 1)
-
-        statics = ("engine_tick", self.max_slots, self.pages_per_seq,
-                   self.page_size, self.pool.num_pages,
-                   self.pool.kv_statics, self._mesh_statics)
-        self._tick_fn = gen.cached_jit(
-            self.cfg, "engine_tick", statics, lambda: tick,
-            donate_argnums=(1, 2))
-        return self._tick_fn
-
-    def _spec_tick(self):
-        """The fused draft-k-then-verify tick for the LEGACY split
-        dispatch: the ragged builder at prefill-row capacity 0 — one
-        compiled program drafts ``spec_k`` tokens per slot, verifies all
-        k+1 positions in a single flattened-batch target forward, and
-        applies the lossless acceptance rule.  Cache key carries the
-        DRAFT config fingerprint too — engines speculating with different
-        drafts must not share executables."""
-        if self._spec_tick_fn is not None:
-            return self._spec_tick_fn
-        from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
-
-        statics = ("engine_spec_tick", self.max_slots, self.pages_per_seq,
-                   self.page_size, self.pool.num_pages,
-                   self.pool.kv_statics, self.spec_k,
-                   gen.config_fingerprint(self.draft_cfg),
-                   self.pool.draft_kv_statics, self._mesh_statics)
-        self._spec_tick_fn = gen.cached_jit(
-            self.cfg, "engine_spec_tick", statics,
-            lambda: make_ragged_tick_fn(self.cfg, self.draft_cfg,
-                                        self.spec_k, 0, tp=self._tp,
-                                        mesh=self.mesh),
-            donate_argnums=(2, 3, 4, 5))
-        return self._spec_tick_fn
-
     def _ragged_tick(self, pre_rows: int):
-        """THE ragged-mode tick (generation/ragged.py): decode slots,
+        """THE tick (generation/ragged.py): decode slots,
         verify blocks and ``pre_rows`` prefill-chunk rows in ONE compiled
         launch.  Every piece of tick composition — which slots decode,
         per-slot speculation depth, which prompt positions prefill, their
@@ -1389,9 +1284,9 @@ class ContinuousBatchingEngine:
         ``pre_rows``, the live prefill-row count bucketed to
         ``prefill_chunk`` multiples: a BOUNDED set of at most
         ``1 + prefill_rows // prefill_chunk`` executables (0 rows = the
-        pure decode/verify tick, byte-identical shape to the legacy tick),
-        so a decode-heavy tick never pays for dead prefill rows and tick
-        composition changes re-dispatch, never recompile
+        pure decode/verify tick), so a decode-heavy tick never pays for
+        dead prefill rows and tick composition changes re-dispatch, never
+        recompile
         (tests/test_ragged_tick.py pins the bound)."""
         fn = self._ragged_fns.get(pre_rows)
         if fn is not None:
@@ -1449,63 +1344,15 @@ class ContinuousBatchingEngine:
             donate_argnums=(1, 2))
         return self._chained_fn
 
-    def _prefill(self, s_pre: int, with_log_probs: bool):
-        """Monolithic dense prefill (the ``prefill_chunk=0`` legacy path):
-        one dense-cache forward over the bucketed prompt, scattered into the
-        request's pages as whole pages."""
-        key = (s_pre, with_log_probs)
-        fn = self._prefill_fns.get(key)
-        if fn is not None:
-            return fn
-        cfg = self.cfg
-        L = cfg.model.num_layers
-        nkv, d = cfg.model.num_attention_heads_kv, cfg.model.kv_channels
-        page = self.page_size
-        npg = s_pre // page
-        # the dense scratch cache always computes in the compute dtype;
-        # quantized pools quantize whole pages at the scatter (bf16 pools:
-        # pool dtype == compute dtype, the original expression bitwise)
-        cache_dtype = self.pool.compute_dtype
-
-        from megatron_llm_tpu.parallel import overlap as tp_overlap_mod
-
-        ovl = self._overlap
-
-        def prefill(params, tokens, pool_k, pool_v, page_ids):
-            caches = gen.init_kv_caches(cfg, 1, s_pre, cache_dtype)
-            with tp_overlap_mod.activate(ovl):
-                out, (ck, cv) = model_forward(
-                    cfg, params, tokens,
-                    position_ids=jnp.arange(s_pre)[None, :],
-                    rope_cache=make_rope_cache(cfg),
-                    kv_caches=caches, cache_index=jnp.int32(0),
-                    logits_postprocess=with_log_probs,
-                )
-            pages_k = ck.reshape(L, npg, page, nkv, d)
-            pages_v = cv.reshape(L, npg, page, nkv, d)
-            pool_k = kv_quant.scatter_whole_pages(pool_k, page_ids, pages_k)
-            pool_v = kv_quant.scatter_whole_pages(pool_v, page_ids, pages_v)
-            if with_log_probs:
-                # teacher-forced prompt log-probs (api logprobs contract)
-                lp = gen._gather_token_log_probs(out[:, :-1], tokens[:, 1:])
-                return pool_k, pool_v, lp[0]
-            return pool_k, pool_v
-
-        statics = (s_pre, with_log_probs, self.page_size,
-                   self.pool.num_pages, self.pool.kv_statics,
-                   self._mesh_statics)
-        fn = gen.cached_jit(self.cfg, "engine_prefill", statics,
-                            lambda: prefill, donate_argnums=(2, 3))
-        self._prefill_fns[key] = fn
-        return fn
-
-    def _chunk_prefill(self, rows: int, kv_pages: int, with_log_probs: bool):
-        """One prefill CHUNK: feed ``rows`` prompt tokens at positions
+    def _score_chunk(self, rows: int, kv_pages: int):
+        """One teacher-forced prefill CHUNK of a ``return_log_probs``
+        prompt: feed ``rows`` prompt tokens at positions
         ``start..start+rows-1`` through the block table (write K/V into the
-        owned pages, attend over the first ``kv_pages`` pages).  Compiled
-        per (rows, page horizon) — both page-aligned and horizon bucketed,
-        so a server sees a handful of shapes."""
-        key = (rows, kv_pages, with_log_probs)
+        owned pages, attend over the first ``kv_pages`` pages) and return
+        each position's log-prob of its target token.  Compiled per (rows,
+        page horizon) — both page-aligned and horizon bucketed, so a
+        server sees a handful of shapes."""
+        key = (rows, kv_pages)
         fn = self._chunk_fns.get(key)
         if fn is not None:
             return fn
@@ -1525,12 +1372,10 @@ class ContinuousBatchingEngine:
                     rope_cache=make_rope_cache(cfg),
                     kv_caches=(pool_k, pool_v),
                     paged=PagedState(bt, start),
-                    logits_postprocess=with_log_probs,
+                    logits_postprocess=True,
                 )
-            if with_log_probs:
-                lp = gen._gather_token_log_probs(out, targets)
-                return pool_k, pool_v, lp[0]
-            return pool_k, pool_v
+            lp = gen._gather_token_log_probs(out, targets)
+            return pool_k, pool_v, lp[0]
 
         def chunk_spec(params, draft_params, tokens, start, bt,
                        pool_k, pool_v, draft_k, draft_v, targets):
@@ -1550,7 +1395,9 @@ class ContinuousBatchingEngine:
                 )
             return res[:2] + (draft_k, draft_v) + res[2:]
 
-        statics = ("engine_prefill_chunk", rows, kv_pages, with_log_probs,
+        # the True is the key's log-prob flag from when the program had a
+        # variant without scores: kept so the compile cache's entries hold
+        statics = ("engine_prefill_chunk", rows, kv_pages, True,
                    self.page_size, self.pool.num_pages,
                    self.pool.kv_statics, self._mesh_statics)
         if self.spec_k:
@@ -1771,7 +1618,6 @@ class ContinuousBatchingEngine:
             ema_retire_s=self._ema_retire_s,
             free_slots=sum(r is None for r in self._slots),
             queue_depth=len(self._queue),
-            can_preempt=bool(self.prefill_chunk),
             prefill_chunk=self.prefill_chunk,
             ttft_ema_s=self._ema_ttft_s,
         )
@@ -1785,14 +1631,13 @@ class ContinuousBatchingEngine:
         ``barrier_admission``), which queued requests to shed outright,
         and which decoding victim to preempt when the best candidate
         can't get a slot or its page budget.  The engine owns the
-        MECHANISMS: chunked mode reserves only the uncovered prompt
-        suffix (plus the first decode page) and books the worst-case rest
-        in the commitment ledger; monolithic mode reserves the full
-        budget up front (PR 1 semantics).  Planning (trie match, budget
-        check, allocation, slot assignment) happens under ``_lock``; only
-        the device work (COW copy / monolithic prefill) runs outside it,
-        with every owned page ref tracked in ``req._pages`` throughout so
-        a failure path releases exactly what is held."""
+        MECHANISMS: it reserves only the uncovered prompt suffix (plus
+        the first decode page) and books the worst-case rest in the
+        commitment ledger.  Planning (trie match, budget check,
+        allocation, slot assignment) happens under ``_lock``; only the
+        device work (the COW copy) runs outside it, with every owned page
+        ref tracked in ``req._pages`` throughout so a failure path
+        releases exactly what is held."""
         while True:
             with self._lock:
                 if not self._queue:
@@ -1817,9 +1662,7 @@ class ContinuousBatchingEngine:
                     slot = None
                 if slot is not None:
                     for cand in order:
-                        p = (self._plan_chunked(cand, slot)
-                             if self.prefill_chunk
-                             else self._plan_monolithic(cand, slot))
+                        p = self._plan_chunked(cand, slot)
                         if p is not None:
                             req, plan = cand, p
                             break
@@ -1831,7 +1674,7 @@ class ContinuousBatchingEngine:
                     # back to the pool (prefix-covered ones stay in the
                     # trie) and it re-queues for a cached-page resume
                     victim = None
-                    if order and state.can_preempt:
+                    if order:
                         decoding = [r for r in self._slots
                                     if r is not None
                                     and r._phase == "decode"
@@ -1845,10 +1688,7 @@ class ContinuousBatchingEngine:
                 self._queue.remove(req)
                 self._publish_queued_locked()
             try:
-                if self.prefill_chunk:
-                    self._place_chunked(req, plan)
-                else:
-                    self._place_monolithic(req)
+                self._place_chunked(req, plan)
             except Exception as e:  # noqa: BLE001 — surface to the waiter
                 self._fail(req, e)
 
@@ -1915,7 +1755,7 @@ class ContinuousBatchingEngine:
         driven preemption runs the same ``_preempt_locked`` path during
         admission).  False if the request isn't currently decoding."""
         with self._lock:
-            if req._phase != "decode" or not self.prefill_chunk:
+            if req._phase != "decode":
                 return False
             self._preempt_locked(req)
             return True
@@ -1946,7 +1786,7 @@ class ContinuousBatchingEngine:
                 "retry_after_s": round(self._drain_eta(len(self._queue)), 3),
             }
 
-    # ---- chunked admission ----
+    # ---- admission ----
 
     def _plan_chunked(self, req: EngineRequest,
                       slot: int) -> Optional[dict]:  # holds _lock
@@ -2040,54 +1880,6 @@ class ContinuousBatchingEngine:
             else:
                 req._phase = "prefill"
                 self._prefill_q.append(req)
-
-    # ---- monolithic admission (prefill_chunk=0, PR 1 semantics) ----
-
-    def _plan_monolithic(self, req: EngineRequest,
-                         slot: int) -> Optional[dict]:  # holds _lock
-        pages = self.pool.alloc(self._max_pages_for(req))
-        if pages is None:
-            return None
-        req._pages = pages
-        req._max_pages = len(pages)
-        req._slot = slot
-        self._slots[slot] = req
-        req._flight.set_phase("prefill", kind="admit", slot=slot,
-                              pages=len(pages))
-        return {"pages": pages}
-
-    def _place_monolithic(self, req: EngineRequest) -> None:
-        """Prefill the whole prompt into the request's pages and activate
-        the slot."""
-        pages = req._pages
-        prompt_len = len(req.prompt)
-        s_pre = min(_bucket_up(prompt_len), _bucket_up(self.max_seq))
-        tokens = np.zeros((1, s_pre), np.int32)
-        tokens[0, :prompt_len] = req.prompt
-        # pages for the bucket-padded tail beyond the request's budget route
-        # to the null page; decode overwrites in-budget positions one by one
-        page_ids = np.full((s_pre // self.page_size,), NULL_PAGE, np.int32)
-        n = min(len(pages), len(page_ids))
-        page_ids[:n] = pages[:n]
-
-        out = self._prefill(s_pre, req.return_log_probs)(
-            self.params, self._asarray(tokens), self.pool.k, self.pool.v,
-            self._asarray(page_ids))
-        if req.return_log_probs:
-            self.pool.k, self.pool.v, prompt_lp = out
-            req.prompt_log_probs = [
-                float(x) for x in np.asarray(prompt_lp)[: prompt_len - 1]]
-        else:
-            self.pool.k, self.pool.v = out
-
-        with self._lock:
-            req._fill_pos = prompt_len
-            self.prefix_miss_tokens += prompt_len
-            self.prefill_tokens_computed += s_pre
-            if obs_registry.publishing():
-                self._m_miss_tokens.inc(prompt_len)
-                self._m_prefill_tokens.inc(s_pre)
-            self._activate_or_handoff(req, req._slot)
 
     # ---- shared lifecycle tail ----
 
@@ -2401,23 +2193,19 @@ class ContinuousBatchingEngine:
 
     # -- chunked prefill scheduling ---------------------------------------
 
-    def _advance_prefill(self, only_log_probs: bool = False) -> bool:
-        """Run ONE prefill chunk for the policy's chosen prefilling
-        request (fcfs: the oldest).  Returns True if a chunk ran — the
-        policy's token budget bounds how many run back to back, so decode
-        slots keep ticking while long prompts fill in the gaps.
+    def _advance_scored_prefill(self) -> bool:
+        """Run ONE scoring chunk for the policy's chosen prefilling
+        ``return_log_probs`` request (fcfs: the oldest).  Returns True if
+        a chunk ran: one a tick, beside the fused tick.
 
-        ``only_log_probs`` is the ragged-mode carve-out: teacher-forced
-        prompt log-probs need every chunk position's logits from the
-        s>1 prefill program (their bits are pinned by the api scoring
-        contract), so ``return_log_probs`` prompts keep this legacy chunk
-        path even when everything else rides the fused ragged tick."""
+        The carve-out from the ragged tick: teacher-forced prompt
+        log-probs need every chunk position's logits from the s>1 prefill
+        program (their bits are pinned by the api scoring contract), so
+        ``return_log_probs`` prompts prefill through this chunk program
+        while everything else rides the fused tick."""
         with self._lock:
-            live = [r for r in self._prefill_q if r._phase == "prefill"]
-            if len(live) != len(self._prefill_q):  # failed/cancelled
-                self._prefill_q = deque(live)
-            if only_log_probs:
-                live = [r for r in live if r.return_log_probs]
+            live = [r for r in self._prefill_q
+                    if r._phase == "prefill" and r.return_log_probs]
             if not live:
                 return False
             req = self.policy.prefill_order(
@@ -2446,8 +2234,7 @@ class ContinuousBatchingEngine:
             bt[0, :n_bt] = req._pages[:n_bt]
             targets = np.zeros((1, rows), np.int32)
             n_lp = max(0, min(rows, prompt_len - 1 - start))
-            if req.return_log_probs and n_lp:
-                targets[0, :n_lp] = seq[start + 1:start + 1 + n_lp]
+            targets[0, :n_lp] = seq[start + 1:start + 1 + n_lp]
 
         t_chunk = time.monotonic()
         try:
@@ -2455,8 +2242,7 @@ class ContinuousBatchingEngine:
                                 rows=rows, tp=self._tp,
                                 trace_id=req.trace_id):
                 if self.spec_k:
-                    out = self._chunk_prefill(rows, kv_pages,
-                                              req.return_log_probs)(
+                    out = self._score_chunk(rows, kv_pages)(
                         self.params, self.draft_params,
                         self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
@@ -2467,20 +2253,16 @@ class ContinuousBatchingEngine:
                      self.pool.draft_v) = out[:4]
                     out = (self.pool.k, self.pool.v) + out[4:]
                 else:
-                    out = self._chunk_prefill(rows, kv_pages,
-                                              req.return_log_probs)(
+                    out = self._score_chunk(rows, kv_pages)(
                         self.params, self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
                         self._asarray(bt),
                         self.pool.k, self.pool.v, self._asarray(targets))
-            if req.return_log_probs:
-                self.pool.k, self.pool.v, lp = out
-                if req.prompt_log_probs is None:
-                    req.prompt_log_probs = []
-                req.prompt_log_probs.extend(
-                    float(x) for x in np.asarray(lp)[:n_lp])
-            else:
-                self.pool.k, self.pool.v = out
+            self.pool.k, self.pool.v, lp = out
+            if req.prompt_log_probs is None:
+                req.prompt_log_probs = []
+            req.prompt_log_probs.extend(
+                float(x) for x in np.asarray(lp)[:n_lp])
         except Exception as e:  # noqa: BLE001 — surface to the waiter
             self._fail(req, e)
             return True
@@ -2515,10 +2297,8 @@ class ContinuousBatchingEngine:
         a time (:meth:`run_until_idle` / the background loop serialize
         via ``_drive_lock``).
 
-        Ragged mode (the default): the whole tick — decode slots, verify
-        blocks, prefill-chunk rows — is ONE compiled launch
-        (:meth:`_step_ragged`).  Legacy split mode dispatches the
-        decode/spec tick plus one program per prefill chunk.
+        The whole tick — decode slots, verify blocks, prefill-chunk rows
+        — is ONE compiled launch (:meth:`_step_ragged`).
 
         Pipelined mode (``--tick_pipeline_depth N``, ISSUE 17): steady-
         state steps chain N ticks per launch and apply results at a one-
@@ -2534,16 +2314,14 @@ class ContinuousBatchingEngine:
             t_admit = time.monotonic()
             with obs_trace.span("engine-admit"):
                 self._admit()
-            if self.ragged:
-                return self._step_ragged(time.monotonic() - t_admit)
-            return self._step_legacy()
+            return self._step_ragged(time.monotonic() - t_admit)
 
     def _prefill_budget_tokens(self) -> int:  # holds _lock
         """The policy's per-tick prefill budget, validated as TOKENS
         (ISSUE 11: the unit is pinned — a chunk-count return is a policy
         bug), floored to one chunk so prefill always advances and capped
-        at the engine's geometry (``_prefill_cap``) so that neither
-        dispatch stalls its decode rows behind a backlog of prompts."""
+        at the engine's geometry (``_prefill_cap``) so that the tick never
+        stalls its decode rows behind a backlog of prompts."""
         budget = self.policy.prefill_budget(
             [r for r in self._prefill_q if r._phase == "prefill"],
             self._sched_state(time.monotonic()))
@@ -2929,103 +2707,6 @@ class ContinuousBatchingEngine:
             self._apply_oldest()
         return len(active)
 
-    def _step_legacy(self) -> int:
-        with self._lock:
-            budget = self._prefill_budget_tokens()
-            pre0 = self.prefill_tokens_computed
-        did_prefill = 0
-        for _ in range(max(1, budget // max(self.prefill_chunk, 1))):
-            if not self._advance_prefill():
-                break
-            did_prefill += 1
-        with self._lock:
-            active = [i for i, r in enumerate(self._slots)
-                      if r is not None and r._phase == "decode"]
-            if not active:
-                self._note_launches_locked(
-                    did_prefill, self.prefill_tokens_computed - pre0)
-                if obs_registry.publishing():
-                    self._m_active.set(0)
-                    self._m_free_pages.set(self.pool.num_free)
-                    self._m_pages_cached.set(
-                        len(self.cache) if self.cache else 0)
-                self._publish_queued_locked()
-                return did_prefill
-            k_eff = self._prepare_decode_locked(active)
-            if not active:
-                self._note_launches_locked(
-                    did_prefill, self.prefill_tokens_computed - pre0)
-                return did_prefill
-            self.peak_active_slots = max(self.peak_active_slots,
-                                         len(active))
-            bt, pos, toks, keys, steps, temp, tk, tp = \
-                self._dev_state_locked()
-
-        t_tick = time.monotonic()
-        gap = (None if self._last_dispatch_end is None
-               else t_tick - self._last_dispatch_end)
-        gap_ms = None if gap is None else round(gap * 1e3, 4)
-        if self.spec_k:
-            with obs_trace.span("engine-spec-tick", active=len(active),
-                                k=self.spec_k, tp=self._tp,
-                                host_gap_ms=gap_ms), \
-                    self._overlap_span(), self._pp_span():
-                (self.pool.k, self.pool.v, self.pool.draft_k,
-                 self.pool.draft_v, emit, emit_lp, acc, cnt,
-                 new_pos, next_tok, new_steps) = self._spec_tick()(
-                    self.params, self.draft_params,
-                    self.pool.k, self.pool.v,
-                    self.pool.draft_k, self.pool.draft_v,
-                    bt, pos, toks, keys, steps, temp, tk, tp,
-                    self._asarray(k_eff))
-                self._last_dispatch_end = time.monotonic()
-                # ONE batched host sync for the tick's emissions
-                emit_np, lp_np, acc_np, m_np = jax.device_get(
-                    (emit, emit_lp, acc, cnt))
-        else:
-            with obs_trace.span("engine-tick", active=len(active),
-                                tp=self._tp, host_gap_ms=gap_ms), \
-                    self._overlap_span(), self._pp_span():
-                (self.pool.k, self.pool.v, next_tok, logp,
-                 new_pos, new_steps) = self._tick()(
-                    self.params, self.pool.k, self.pool.v,
-                    bt, pos, toks, keys, steps, temp, tk, tp)
-                self._last_dispatch_end = time.monotonic()
-                next_np, logp_np = jax.device_get((next_tok, logp))
-        self._note_host_gap(gap)
-
-        now = time.monotonic()
-        with self._lock:
-            dt = now - t_tick  # feeds Retry-After/shed drain estimates
-            self._ema_tick_s = (dt if self._ema_tick_s is None
-                                else 0.8 * self._ema_tick_s + 0.2 * dt)
-            if not self._dirty:
-                # steady state: the tick already advanced the device mirror
-                self._dev_state = (bt, new_pos, next_tok, keys, new_steps,
-                                   temp, tk, tp)
-            self.ticks += 1
-            if self.spec_k:
-                emitted = self._apply_spec_locked(
-                    active, k_eff, emit_np, lp_np, acc_np, m_np, now)
-            else:
-                emitted = self._apply_plain_locked(
-                    active, next_np, logp_np, now)
-            self.ticked_tokens += emitted
-            self._note_launches_locked(
-                did_prefill + 1, self.prefill_tokens_computed - pre0)
-            if obs_registry.publishing():
-                self._m_ticks.inc()
-                self._m_tokens.inc(emitted)
-            if obs_registry.publishing():
-                self._m_active.set(
-                    sum(r is not None and r._phase == "decode"
-                        for r in self._slots))
-                self._m_free_pages.set(self.pool.num_free)
-                self._m_pages_cached.set(
-                    len(self.cache) if self.cache else 0)
-            self._publish_queued_locked()
-        return len(active) + did_prefill
-
     # -- the ragged tick (ISSUE 11) ----------------------------------------
 
     def _plan_ragged_prefill(self):  # holds _lock
@@ -3039,15 +2720,14 @@ class ContinuousBatchingEngine:
         request may attend K/V a same-tick earlier chunk writes
         (write-then-attend holds across the whole ragged batch).  Row
         bits depend only on (token, position, horizon bucket), so ANY
-        packing produces the bitwise output the one-chunk-per-tick
-        legacy interleave produces.
+        packing produces the output one chunk a tick produces.
 
         Returns ``(spans, pre_tok, pre_pos, pre_tables, pre_index,
         pre_hor, lp_live)`` where spans is ``[(req, start, end), ...]``,
         ``pre_tables``/``pre_index`` are the COMPRESSED block tables (one
         table per packed request, ``-1`` index = dead row), and
         ``lp_live`` flags return_log_probs prompts that must take the
-        legacy teacher-forced chunk path instead."""
+        teacher-forced scoring chunk instead."""
         Rp = self.prefill_rows
         pre_tok = np.zeros((Rp,), np.int32)
         pre_pos = np.zeros((Rp,), np.int32)
@@ -3107,11 +2787,11 @@ class ContinuousBatchingEngine:
         """Advance the packed requests' fill frontiers; a request whose
         bucketed prompt completed inserts its full pages into the prefix
         trie (refeed page excluded — shared pages immutable from birth)
-        and activates into decode, exactly like _advance_prefill's
-        completion tail.  ``tick_s``/``work_rows`` attribute the fused
-        launch's wall time to each request's flight record
-        proportionally to its rows — an estimate by construction (the
-        launch is ONE program), documented as such."""
+        and activates into decode, exactly like
+        _advance_scored_prefill's completion tail.  ``tick_s``/``work_rows``
+        attribute the fused launch's wall time to each request's flight
+        record proportionally to its rows — an estimate by construction
+        (the launch is ONE program), documented as such."""
         ps = self.page_size
         for req, start, end in spans:
             if req._phase != "prefill":  # failed mid-step (defensive)
@@ -3138,7 +2818,7 @@ class ContinuousBatchingEngine:
         """One fused ragged tick: decode slots + verify blocks + packed
         prefill-chunk rows, ONE compiled attention launch
         (generation/ragged.py).  return_log_probs prompts are the one
-        carve-out — their teacher-forced chunk keeps the legacy program
+        carve-out — their teacher-forced chunk is a program of its own
         (counted honestly in the launch telemetry).
 
         The tick's phases are spans under the caller's ``engine-step``
@@ -3152,8 +2832,7 @@ class ContinuousBatchingEngine:
                 pre0 = self.prefill_tokens_computed
                 (spans, pre_tok, pre_pos, pre_tables, pre_index, pre_hor,
                  lp_live) = self._plan_ragged_prefill()
-            did_lp = 1 if lp_live and self._advance_prefill(
-                only_log_probs=True) else 0
+            did_lp = 1 if lp_live and self._advance_scored_prefill() else 0
             with self._lock:
                 active = [i for i, r in enumerate(self._slots)
                           if r is not None and r._phase == "decode"]

@@ -3,12 +3,11 @@ runs a whole tick's heterogeneous work — decode slots, speculative-verify
 blocks, and prefill chunks — as a single flattened row batch (ISSUE 11,
 PAPERS.md "Ragged Paged Attention").
 
-The legacy split dispatch compiles up to three shapes of the same
-computation per tick: the decode batch (``engine._tick``), one program per
-prefill-chunk geometry (``engine._chunk_prefill``), and the speculative
-verify.  Here the tick is ONE ragged batch of single-token rows; each row
-carries its own data-carried ``(token, position, block-table row, kv
-horizon)``:
+A split dispatch would compile up to three shapes of the same
+computation per tick: the decode batch, one program per prefill-chunk
+geometry, and the speculative verify.  Here the tick is ONE ragged batch
+of single-token rows; each row carries its own data-carried ``(token,
+position, block-table row, kv horizon)``:
 
 * a **decode slot** contributes 1 row (span 1) at its own position;
 * a **speculative-verify block** contributes ``spec_k + 1`` consecutive
@@ -20,20 +19,20 @@ horizon)``:
 
 Every op in the forward is then structurally an s=1 paged decode over a
 larger batch, and per-row bits are BATCH-SIZE INVARIANT (the PR 9 key
-numerics fact) — so decode rows are bitwise the legacy decode tick, verify
-rows are bitwise the legacy flattened verify, and prefill rows are bitwise
-the legacy chunk rows (masked attention is invariant to query-row
-partitioning when kv horizons stay on the BUCKET(64) grid — the PR 5
-contract).  That is what makes ragged output — tokens AND log-probs,
-greedy AND sampled, cache on/off — bitwise-identical to the legacy split
-path (tests/test_ragged_tick.py).
+numerics fact) — so a decode row, a verify row and a prefill row compute
+what a decode tick, a flattened verify and a chunk program of their own
+would (masked attention is invariant to query-row partitioning when kv
+horizons stay on the BUCKET(64) grid — the PR 5 contract).  That is what
+makes a request's output — tokens AND log-probs, greedy AND sampled,
+cache on/off — independent of what else the tick carries: the same as
+the request served alone, and for greedy rows the dense single-stream
+path's (tests/test_ragged_tick.py, tests/parity.py).
 
 ``prefill_rows`` is the COMPILED prefill-row capacity (a static, like
-``max_slots``); which rows are live each tick is pure data.  With
-``prefill_rows=0`` the builders reduce exactly to the legacy programs:
-``make_ragged_tick_fn(cfg, None, 0, 0)`` is the decode tick and
-``make_ragged_tick_fn(cfg, draft_cfg, k, 0)`` is byte-for-byte the
-flattened spec verify this module absorbed from ``speculative/verify.py``.
+``max_slots``); which rows are live each tick is pure data.
+``make_ragged_tick_fn(cfg, None, 0, 0)`` is the pure decode tick and
+``make_ragged_tick_fn(cfg, draft_cfg, k, 0)`` the flattened spec verify
+this module absorbed from ``speculative/verify.py``.
 
 Write-then-attend causality holds across the whole ragged batch: all R
 rows' K/V lands first (each row a distinct (page, offset) — different

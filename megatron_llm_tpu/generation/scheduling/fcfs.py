@@ -5,7 +5,7 @@ pressure (``barrier_admission``), prefill feeds the oldest prefilling
 request one chunk per tick, nothing is ever preempted or shed.  This is
 the default policy and MUST stay bitwise-equivalent to the inlined
 scheduler it replaced: tests/test_scheduler.py locks tokens and log-probs
-against the monolithic reference, and the PR 5 parity suites
+against the dense single-stream reference, and the PR 5 parity suites
 (tests/test_prefix_cache.py) run through it unchanged.
 """
 

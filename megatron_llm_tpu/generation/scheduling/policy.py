@@ -61,8 +61,7 @@ class SchedulerState:
     ema_retire_s: Optional[float]    # EMA interval between retirements
     free_slots: int
     queue_depth: int
-    can_preempt: bool                # chunked mode + policy allows it
-    prefill_chunk: int = 0           # engine chunk size in tokens (0 = off)
+    prefill_chunk: int = 0           # engine chunk size in tokens
     # measured submit-to-first-token EMA (ISSUE 12, flight-recorder
     # derived): the REAL first-token latency of recent requests —
     # includes queue + prefill, unlike the tick/retire EMAs.  None until

@@ -56,7 +56,7 @@ class PriorityPolicy(SchedulerPolicy):
 
     def preempt_victim(self, candidate, decoding: Sequence,
                        state: SchedulerState) -> Optional[object]:
-        if not (self.preemption and state.can_preempt):
+        if not self.preemption:
             return None
         cand_eff = self.effective(candidate, state.now)
         victims = [r for r in decoding

@@ -88,7 +88,7 @@ class SloPolicy(SchedulerPolicy):
 
     def preempt_victim(self, candidate, decoding: Sequence,
                        state: SchedulerState) -> Optional[object]:
-        if not (self.preemption and state.can_preempt):
+        if not self.preemption:
             return None
         cand_dl = ttft_deadline(candidate)
         if cand_dl is math.inf:

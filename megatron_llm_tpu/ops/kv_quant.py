@@ -152,8 +152,7 @@ def _cast_q(x32: jax.Array, qdtype) -> jax.Array:
 
 def quantize_pages(vals: jax.Array, kv_dtype: str) -> QuantPagedKV:
     """Whole-page quantization of ``vals`` [..., page, nkv, d]: the
-    monolithic-prefill scatter path, and the single-shot form the error
-    bound is stated against."""
+    single-shot form the error bound is stated against."""
     qmax = _QMAX[kv_dtype]
     v32 = vals.astype(jnp.float32)
     scale = jnp.max(jnp.abs(v32), axis=(-3, -1)) / qmax  # [..., nkv]
@@ -235,21 +234,6 @@ def _quant_write_rows(pool: QuantPagedKV, page_ids: jax.Array,
     tok_q = _cast_q(v32 / den[page_ids][..., None], qdtype)
     q = q.at[page_ids, offs].set(tok_q)
     return QuantPagedKV(q=q, scale=new_scale)
-
-
-def scatter_whole_pages(pool: PagedKV, page_ids: jax.Array,
-                        pages: jax.Array) -> PagedKV:
-    """Replace whole pages: ``pages`` is [..., n, page, nkv, d] computed
-    content for ``page_ids`` [n] — the monolithic-prefill path.  Plain
-    pools keep the original ``.at[:, page_ids].set`` expression; quantized
-    pools quantize each page in one shot (the tight error bound)."""
-    if not is_quantized(pool):
-        return pool.at[:, page_ids].set(pages.astype(pool.dtype))
-    qp = quantize_pages(pages, "int8" if pool.q.dtype == jnp.int8 else "fp8")
-    return QuantPagedKV(
-        q=pool.q.at[:, page_ids].set(qp.q),
-        scale=pool.scale.at[:, page_ids].set(qp.scale),
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -230,53 +230,6 @@ def test_committed_router_evidence_is_valid():
     assert "error" not in rec
 
 
-def test_mixed_bench_cpu_contract():
-    """bench_decode.py --mode mixed (ISSUE 11) reuses the off-TPU
-    contract: headline 0, the ragged-vs-legacy comparison rides under
-    cpu_sanity with budget fields populated, TPU evidence goes to its
-    own tagged file."""
-    line = bench.cpu_contract_line({
-        "metric": "engine_ragged_launch_reduction_llama470m_mixed_1chip",
-        "value": 2.1, "unit": "x", "backend": "cpu",
-        "speedup_ok": True, "ttft_speedup": 1.12, "tok_s_speedup": 1.05,
-        "compile_time_s": 50.0, "step_time_s": 0.03,
-        "rows": [{"ragged": False, "launches_per_tick": 2.1,
-                  "long_ttft_mean_ms": 900.0, "decode_tok_s": 40.0},
-                 {"ragged": True, "launches_per_tick": 1.0,
-                  "long_ttft_mean_ms": 800.0, "decode_tok_s": 42.0}],
-    }, tag="engine_decode_mixed")
-    assert line["value"] == 0.0 and line["unit"] == "x"
-    assert line["cpu_sanity"]["speedup_ok"] is True
-    assert line["budgets"]["compile_time_s"]["value"] == 50.0
-    assert "error" not in line
-
-
-def test_committed_mixed_evidence_is_valid():
-    """The committed CPU-sanity evidence (BENCH_decode_mixed_cpu_sanity
-    .json) satisfies the acceptance bar: headline 0 off-TPU, the ragged
-    arm runs exactly ONE attention launch per tick with >= 1.5x fewer
-    launches than the legacy split dispatch, TTFT/tok-s no worse, and
-    budgets populated without violations."""
-    from pathlib import Path
-
-    path = (Path(__file__).parent.parent
-            / "BENCH_decode_mixed_cpu_sanity.json")
-    rec = json.loads(path.read_text())
-    assert rec["value"] == 0.0 and rec["backend"] == "cpu"
-    sanity = rec["cpu_sanity"]
-    assert sanity["speedup_ok"] is True
-    assert sanity["launch_reduction"] >= 1.5
-    by = {r["ragged"]: r for r in sanity["rows"]}
-    assert set(by) == {True, False}
-    assert by[True]["launches_per_tick"] <= 1.001
-    assert (by[False]["launches_per_tick"]
-            >= 1.5 * by[True]["launches_per_tick"])
-    assert sanity["ttft_speedup"] >= 0.95
-    assert sanity["tok_s_speedup"] >= 0.95
-    assert "compile_time_s" in rec["budgets"]
-    assert "error" not in rec
-
-
 # ---------------------------------------------------------------------------
 # ISSUE 13: quantized-KV capacity bench
 # ---------------------------------------------------------------------------

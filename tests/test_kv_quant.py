@@ -37,6 +37,8 @@ from megatron_llm_tpu.generation.engine import ContinuousBatchingEngine
 from megatron_llm_tpu.models import init_model_params, make_config
 from megatron_llm_tpu.ops import kv_quant
 
+from tests.parity import dense_greedy
+
 # accuracy gates, measured on the CPU-sanity shapes below and documented
 # in docs/guide/quantization.md ("Accuracy gates"): greedy agreement is
 # asserted exactly on the short horizon; log-prob deltas on the long
@@ -319,19 +321,31 @@ def test_tp4_agreement_int8(models):
         assert ts == tm
 
 
-def test_legacy_split_dispatch_int8(models):
-    """The non-ragged (legacy split) tick and the monolithic prefill path
-    also run quantized: ragged-off agrees with ragged-on, and
-    prefill_chunk=0 (monolithic, cache off) still matches bf16 greedy."""
-    prompts = _prompts(2, 37)
-    ragged = _decode(_engine(models, "int8"), prompts)
-    legacy = _decode(_engine(models, "int8", ragged=False), prompts)
-    for (tr, lr), (tl, ll) in zip(ragged, legacy):
-        assert tr == tl
-    mono16 = _decode(_engine(models, "bf16", prefill_chunk=0), prompts)
-    mono8 = _decode(_engine(models, "int8", prefill_chunk=0), prompts)
-    for (tb, _), (tq, _) in zip(mono16, mono8):
-        assert tb == tq
+def test_int8_ragged_tick_matches_dense_greedy(models):
+    """int8 pages under the ragged tick — a multi-chunk prompt prefilling
+    beside decoding rows — still give the greedy tokens of the dense
+    single-stream path, which has no pages to quantize; log-probs stay
+    inside the documented int8 gate."""
+    prompts = _prompts(2, 37) + _prompts(1, 100, seed=5)
+    eng = _engine(models, "int8")
+    early = [eng.submit(p, 12, **GREEDY) for p in prompts[:2]]
+    for _ in range(3):
+        eng.step()
+    late = eng.submit(prompts[2], 12, **GREEDY)
+    mixed = False
+    while not late.finished:
+        mixed |= late._phase == "prefill" and any(
+            r._phase == "decode" for r in early)
+        eng.step()
+    eng.run_until_idle()
+    assert mixed, "no tick carried prompt rows beside decode rows"
+    for p, req in zip(prompts, early + [late]):
+        toks, lps = req.result(timeout=120)
+        ref_toks, ref_lp = dense_greedy(models["cfg"], models["params"],
+                                        p, 12)
+        assert toks == ref_toks
+        delta = np.max(np.abs(np.asarray(lps) - ref_lp[len(p) - 1:]))
+        assert delta < LOGPROB_GATE, delta
 
 
 # ---------------------------------------------------------------------------

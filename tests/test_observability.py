@@ -756,12 +756,17 @@ def test_instrument_cost_microbench():
     """The per-step instrument bill, measured deterministically: replay
     one driver iteration's full instrumentation (spans, timer mirrors,
     gauges, trigger checks, amortized window dump) and time it alone.
-    Tens of µs — far inside 3% of any real step."""
+    Tens of µs — far inside 3% of any real step.
+
+    The least of five rounds: load on a shared host (the suite runs six
+    workers on these cores) only ever adds to a timing, so the minimum is
+    the code's own cost and one quiet round is enough to read it."""
     import bench_observability as bo
 
-    cost = bo.measure_instrument_cost(steps=500)
+    costs = [bo.measure_instrument_cost(steps=500)
+             ["instrument_cost_us_per_step"] for _ in range(5)]
     # generous cap: even a 10ms CPU micro-step keeps 300µs/step inside 3%
-    assert cost["instrument_cost_us_per_step"] < 300.0, cost
+    assert min(costs) < 300.0, costs
 
 
 @pytest.mark.slow

@@ -4,7 +4,7 @@ parallel/pp_serve.py + the vocab_ring slots of parallel/overlap.py).
 The parity contract the acceptance criteria name:
 
 * engine greedy decode at pp=2/4 emits the SAME tokens as the flat
-  (no-mesh) engine — ragged AND legacy AND chained/pipelined tick,
+  (no-mesh) engine — the ragged AND the chained/pipelined tick,
   prefix cache on/off, speculative decoding on/off — with per-token
   log-probs within 5e-6 (microbatched stage scan: same GEMMs, but XLA
   may tile the per-stage programs differently → tolerance on log-probs,
@@ -103,16 +103,13 @@ def test_engine_pp_token_identity(eight_devices, pp):
 
 
 def test_engine_pp_tick_modes(eight_devices, toy_params):
-    """pp=2 parity holds on the legacy tick, the chained/pipelined tick
-    (tick_pipeline_depth=2), and with the prefix cache off."""
+    """pp=2 parity holds on the chained/pipelined tick
+    (tick_pipeline_depth=2) and with the prefix cache off."""
     cfg = _toy_cfg()
     params = toy_params
-    _, b_legacy = _run_engine(cfg, params, None, ragged=False)
     _, b_chain = _run_engine(cfg, params, None, tick_pipeline_depth=2)
     _, b_nocache = _run_engine(cfg, params, None, prefix_cache=False)
     mesh = _pp_mesh(eight_devices, 2)
-    _, p = _run_engine(copy.deepcopy(cfg), params, mesh, ragged=False)
-    _check(b_legacy, p, "pp2 legacy tick")
     _, p = _run_engine(copy.deepcopy(cfg), params, mesh,
                        tick_pipeline_depth=2)
     _check(b_chain, p, "pp2 chained tick")
@@ -188,11 +185,6 @@ def test_serve_params_gating(eight_devices):
         ContinuousBatchingEngine(bad, init_model_params(
             bad, jax.random.PRNGKey(0)), None, max_slots=4, num_pages=64,
             page_size=16, mesh=mesh2)
-    # monolithic dense prefill has no stage decomposition
-    with pytest.raises(AssertionError):
-        ContinuousBatchingEngine(copy.deepcopy(cfg), params, None,
-                                 max_slots=4, num_pages=64, page_size=16,
-                                 prefill_chunk=0, mesh=mesh2)
 
 
 def test_inert_flags_degrade_bitwise(eight_devices, toy_params):

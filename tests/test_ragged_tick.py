@@ -2,12 +2,14 @@
 
 Gates:
 
-1. **Parity matrix** — the ragged single-launch tick emits the same
-   tokens, and log-probs within a few fp32 ulps (tests/parity.py says
-   why not bit for bit), as the legacy split dispatch (decode tick +
-   per-chunk prefill programs + flattened spec verify) across:
-   decode-only, prefill-heavy, mixed, speculative (greedy and sampled),
-   cache on/off, preemption/resume, and tp=4 (token identity).
+1. **Parity matrix** — what a request gets from the single-launch tick
+   does not depend on what else the tick carries: greedy jobs emit the
+   dense single-stream path's tokens and log-probs (the anchor), and
+   every job, sampled ones too, the tokens and log-probs (within a few
+   fp32 ulps) of the same engine serving it alone (tests/parity.py says
+   why these two and not a second dispatch) across: decode-only,
+   prefill-heavy, mixed, speculative (greedy and sampled), cache on/off,
+   preemption/resume, and tp=4 (token identity).
 2. **One launch per tick** — a mixed prefill+decode+spec tick dispatches
    exactly ONE compiled attention program, asserted via the engine's
    launch counter AND the ``engine-ragged-tick`` trace span (launches
@@ -20,6 +22,10 @@ Gates:
    into one tick; negative/typed-wrong budgets raise.
 5. Telemetry: ``mlt_engine_tick_launches_total`` /
    ``mlt_engine_prefill_tokens_per_tick`` reach ``/metrics``.
+6. **One dispatch** — the ragged tick is the only tick program an engine
+   compiles (plus the scoring chunk of a ``return_log_probs`` prompt), and
+   ``prefill_chunk`` is a positive whole number of pages wherever it
+   enters.
 """
 
 import numpy as np
@@ -30,7 +36,14 @@ import jax
 from megatron_llm_tpu.generation import ContinuousBatchingEngine, DraftModel
 from megatron_llm_tpu.generation.scheduling import SchedulerPolicy
 
-from tests.parity import assert_logprobs_close, assert_same_generations
+from tests.parity import (
+    assert_greedy_match_dense,
+    assert_logprobs_close,
+    assert_same_generations,
+    generations,
+    run_jobs,
+    serve_alone,
+)
 
 VOCAB = 67
 
@@ -85,9 +98,23 @@ def _mixed_jobs(n_new=10):
 
 
 def _run(eng, jobs):
-    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
-    eng.run_until_idle()
-    return [r.result(timeout=120) for r in reqs]
+    return generations(run_jobs(eng, jobs))
+
+
+def _assert_parity(models, jobs, n_greedy, **kw):
+    """Both references of tests/parity.py for ``jobs`` served together by
+    one engine built with ``kw``; the requests served together, and
+    alone."""
+    def make():
+        return _engine(models, **kw)
+
+    reqs = run_jobs(make(), jobs)
+    assert assert_greedy_match_dense(
+        models["cfg"], models["params"], jobs, reqs) == n_greedy
+    alone = serve_alone(make, jobs)
+    assert_same_generations(generations(alone), generations(reqs),
+                            "mixed vs alone")
+    return reqs, alone
 
 
 # ---------------------------------------------------------------------------
@@ -97,43 +124,38 @@ def _run(eng, jobs):
 
 @pytest.mark.parametrize("cache", [True, False])
 def test_parity_mixed(models, cache):
-    legacy = _run(_engine(models, ragged=False, prefix_cache=cache),
-                  _mixed_jobs())
-    ragged = _run(_engine(models, ragged=True, prefix_cache=cache),
-                  _mixed_jobs())
-    assert_same_generations(legacy, ragged)
+    _assert_parity(models, _mixed_jobs(), 6, prefix_cache=cache)
 
 
 def test_parity_decode_only(models):
     jobs = [([5, 9, 2 + i], 16, dict(top_k=1, termination_id=10 ** 9))
             for i in range(4)]
-    assert_same_generations(_run(_engine(models, ragged=False), jobs),
-                    _run(_engine(models, ragged=True), jobs))
+    _assert_parity(models, jobs, 4)
 
 
 def test_parity_prefill_heavy(models):
     # prompts far longer than a chunk: most ticks are prefill-dominated
     jobs = [([2 + (i * 7 + j) % 60 for j in range(110 + 5 * i)], 6,
              dict(top_k=1, termination_id=10 ** 9)) for i in range(3)]
-    assert_same_generations(_run(_engine(models, ragged=False), jobs),
-                    _run(_engine(models, ragged=True), jobs))
+    _assert_parity(models, jobs, 3)
 
 
 @pytest.mark.parametrize("cache", [True, False])
 def test_parity_spec(models, cache):
-    kw = dict(spec_k=3, spec_draft=models["draft"], spec_adaptive=False,
-              prefix_cache=cache)
-    legacy = _run(_engine(models, ragged=False, **kw), _mixed_jobs())
-    ragged = _run(_engine(models, ragged=True, **kw), _mixed_jobs())
-    assert_same_generations(legacy, ragged)
+    """Speculation is lossless: its greedy jobs are the dense greedy
+    stream, its sampled jobs those of the same speculating engine serving
+    the request alone."""
+    _assert_parity(models, _mixed_jobs(), 6, spec_k=3,
+                   spec_draft=models["draft"], spec_adaptive=False,
+                   prefix_cache=cache)
 
 
 def test_parity_spec_vs_nonspec_through_ragged(models):
     """The PR 9 losslessness contract survives the ragged rebuild:
     greedy spec rows through the ragged tick == plain ragged decode."""
     jobs = [j for j in _mixed_jobs() if "temperature" not in j[2]]
-    plain = _run(_engine(models, ragged=True), jobs)
-    spec = _run(_engine(models, ragged=True, spec_k=3,
+    plain = _run(_engine(models), jobs)
+    spec = _run(_engine(models, spec_k=3,
                         spec_draft=models["draft"], spec_adaptive=False),
                 jobs)
     assert_same_generations(plain, spec)
@@ -141,12 +163,14 @@ def test_parity_spec_vs_nonspec_through_ragged(models):
 
 def test_parity_preemption_resume(models):
     """A mid-decode preemption + trie resume under the ragged tick is
-    the legacy path's resume (and the uninterrupted stream)."""
-    def run(ragged, preempt_at):
-        eng = _engine(models, ragged=ragged, sched_policy="fcfs")
-        long = [2 + (j * 7) % 60 for j in range(48)]
-        req = eng.submit(long, 14, top_k=1, termination_id=10 ** 9)
-        other = eng.submit([5, 9, 2], 6, top_k=1, termination_id=10 ** 9)
+    the uninterrupted stream, which is the dense greedy stream."""
+    long = [2 + (j * 7) % 60 for j in range(48)]
+    jobs = [(long, 14, dict(top_k=1, termination_id=10 ** 9)),
+            ([5, 9, 2], 6, dict(top_k=1, termination_id=10 ** 9))]
+
+    def run(preempt_at):
+        eng = _engine(models, sched_policy="fcfs")
+        req, other = [eng.submit(p, n, **kw) for p, n, kw in jobs]
         steps = 0
         while not req.finished:
             eng.step()
@@ -154,12 +178,14 @@ def test_parity_preemption_resume(models):
             if steps == preempt_at and req._phase == "decode":
                 assert eng.preempt(req)
         eng.run_until_idle()
-        return [req.result(timeout=120), other.result(timeout=120)]
+        assert eng.preemptions == (preempt_at < 10 ** 9)
+        return [req, other]
 
-    base = run(True, 10 ** 9)   # never preempted
+    base = run(10 ** 9)   # never preempted
+    assert assert_greedy_match_dense(
+        models["cfg"], models["params"], jobs, base) == 2
     for cut in (3, 6):
-        assert_same_generations(base, run(True, cut))
-        assert_same_generations(run(False, cut), run(True, cut))
+        assert_same_generations(generations(base), generations(run(cut)))
 
 
 def test_parity_tp4_token_identity(models, eight_devices):
@@ -177,35 +203,28 @@ def test_parity_tp4_token_identity(models, eight_devices):
     tpm = {"cfg": cfg, "params": params}
 
     jobs = _mixed_jobs(n_new=6)[:4]
-    base = _run(_engine(tpm, ragged=True), jobs)
+    base = _run(_engine(tpm), jobs)
     mesh = ps.build_mesh(tensor_model_parallel_size=4,
                          data_parallel_size=1, devices=eight_devices[:4])
-    tp = _run(_engine(tpm, ragged=True, mesh=mesh), jobs)
+    tp = _run(_engine(tpm, mesh=mesh), jobs)
     for (t0, l0), (t1, l1) in zip(base, tp):
         assert t0 == t1  # tokens bitwise across tp
         np.testing.assert_allclose(l0, l1, atol=1e-5)
 
 
 def test_parity_return_log_probs(models):
-    """return_log_probs prompts take the legacy teacher-forced chunk
-    carve-out in ragged mode: prompt AND generation log-probs agree."""
+    """return_log_probs prompts take the teacher-forced scoring-chunk
+    carve-out beside the tick: prompt AND generation log-probs are the
+    dense scorer's, and those of the request served alone."""
     jobs = [([2 + (j * 7) % 60 for j in range(40)], 8,
              dict(top_k=1, termination_id=10 ** 9, return_log_probs=True)),
             ([5, 9, 2], 8, dict(top_k=1, termination_id=10 ** 9))]
-
-    def run(ragged):
-        eng = _engine(models, ragged=ragged)
-        reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
-        eng.run_until_idle()
-        return [(r.result(timeout=120), r.prompt_log_probs) for r in reqs]
-
-    legacy, ragged = run(False), run(True)
-    for ((t0, l0), p0), ((t1, l1), p1) in zip(legacy, ragged):
-        assert t0 == t1
-        assert_logprobs_close(l0, l1)
-        assert (p0 is None) == (p1 is None)
-        if p0 is not None:
-            assert_logprobs_close(p0, p1, "teacher-forced prompt scores")
+    reqs, alone = _assert_parity(models, jobs, 2)
+    assert reqs[1].prompt_log_probs is None
+    assert len(reqs[0].prompt_log_probs) == 39
+    assert_logprobs_close(alone[0].prompt_log_probs,
+                          reqs[0].prompt_log_probs,
+                          "teacher-forced prompt scores")
 
 
 # ---------------------------------------------------------------------------
@@ -221,7 +240,7 @@ def test_mixed_tick_single_launch_and_span(models):
     old = obs_trace.get_tracer()
     tracer = obs_trace.configure(capacity=4096)
     try:
-        eng = _engine(models, ragged=True, spec_k=2,
+        eng = _engine(models, spec_k=2,
                       spec_draft=models["draft"], spec_adaptive=False)
         # saturate decode first
         short = [eng.submit([5 + i, 9, 2], 24, top_k=1,
@@ -278,7 +297,7 @@ def test_tick_phase_spans_and_kind_counters_agree(models):
     old = obs_trace.get_tracer()
     tracer = obs_trace.configure(capacity=8192)
     try:
-        eng = _engine(models, ragged=True)
+        eng = _engine(models)
         before, ticks0 = kinds(), eng.ticks
         _run(eng, _mixed_jobs(n_new=6))
         after = kinds()
@@ -313,27 +332,6 @@ def test_tick_phase_spans_and_kind_counters_agree(models):
                              "tp"} for e in by["engine-ragged-tick"])
 
 
-def test_legacy_mixed_tick_multi_launch(models):
-    """The counter is honest: the legacy split path really does dispatch
-    more than one program on a mixed tick (the thing ragged removes)."""
-    eng = _engine(models, ragged=False)
-    short = [eng.submit([5 + i, 9, 2], 24, top_k=1,
-                        termination_id=10 ** 9) for i in range(3)]
-    for _ in range(3):
-        eng.step()
-    long = eng.submit([2 + (j * 7) % 60 for j in range(90)], 4,
-                      top_k=1, termination_id=10 ** 9)
-    seen = 0
-    for _ in range(4):
-        eng.step()
-        if long._phase == "prefill":
-            seen = max(seen, eng.last_tick_launches)
-    assert seen >= 2, "legacy mixed tick should be >= 2 launches"
-    eng.run_until_idle()
-    for r in short + [long]:
-        r.result(timeout=120)
-
-
 def test_composition_changes_reuse_bounded_executables(models):
     """The recompile-hazard gate: all-decode, mixed, multi-request
     prefill, spec depths, drained — every composition re-dispatches a
@@ -341,7 +339,7 @@ def test_composition_changes_reuse_bounded_executables(models):
     most 1 + prefill_rows/prefill_chunk) and none of them ever
     re-traces: span/horizon/block-table metadata is data-carried, never
     static."""
-    eng = _engine(models, ragged=True, spec_k=2,
+    eng = _engine(models, spec_k=2,
                   spec_draft=models["draft"], spec_adaptive=False)
     _run(eng, _mixed_jobs())            # mixed compositions
     _run(eng, _mixed_jobs(n_new=4)[:2])  # different mix
@@ -354,7 +352,7 @@ def test_composition_changes_reuse_bounded_executables(models):
         assert fn._cache_size() == 1, (
             "a ragged executable re-traced on a composition change")
 
-    eng2 = _engine(models, ragged=True)
+    eng2 = _engine(models)
     _run(eng2, _mixed_jobs())
     assert len(eng2._ragged_fns) <= 1 + (eng2.prefill_rows
                                          // eng2.prefill_chunk)
@@ -382,8 +380,8 @@ class _TokenBudget(SchedulerPolicy):
 def test_budget_admits_multiple_chunks_multiple_requests(models):
     """ISSUE 11 regression: prefill_budget is TOKENS — a 192-token budget
     packs 3 chunks spanning TWO requests into one tick."""
-    eng = _engine(models, max_seq=256, ragged=True,
-                  sched_policy=_TokenBudget(192), prefill_budget=192)
+    eng = _engine(models, max_seq=256, sched_policy=_TokenBudget(192),
+                  prefill_budget=192)
     r1 = eng.submit([2 + (j % 60) for j in range(150)], 4,
                     top_k=1, termination_id=10 ** 9)
     r2 = eng.submit([3 + (j % 60) for j in range(100)], 4,
@@ -396,7 +394,7 @@ def test_budget_admits_multiple_chunks_multiple_requests(models):
     eng.run_until_idle()
     got = [r1.result(timeout=60), r2.result(timeout=60)]
     # aggressive packing is still bitwise the default pacing
-    base = _run(_engine(models, max_seq=256, ragged=True),
+    base = _run(_engine(models, max_seq=256),
                 [([2 + (j % 60) for j in range(150)], 4,
                   dict(top_k=1, termination_id=10 ** 9)),
                  ([3 + (j % 60) for j in range(100)], 4,
@@ -406,12 +404,12 @@ def test_budget_admits_multiple_chunks_multiple_requests(models):
 
 def test_budget_validated_as_tokens(models):
     """Negative or non-int budgets are policy bugs and raise."""
-    eng = _engine(models, ragged=True, sched_policy=_TokenBudget(-1))
+    eng = _engine(models, sched_policy=_TokenBudget(-1))
     eng.submit([2 + (j % 60) for j in range(80)], 2,
                top_k=1, termination_id=10 ** 9)
     with pytest.raises(ValueError, match="TOKENS"):
         eng.step()
-    eng2 = _engine(models, ragged=True, sched_policy=_TokenBudget(2.5))
+    eng2 = _engine(models, sched_policy=_TokenBudget(2.5))
     eng2.submit([2 + (j % 60) for j in range(80)], 2,
                 top_k=1, termination_id=10 ** 9)
     with pytest.raises(ValueError, match="TOKENS"):
@@ -421,7 +419,7 @@ def test_budget_validated_as_tokens(models):
 def test_budget_floor_keeps_prefill_alive(models):
     """A zero budget still advances one chunk per tick (liveness — the
     legacy `max(1, ...)` guarantee, now in token units)."""
-    eng = _engine(models, ragged=True, sched_policy=_TokenBudget(0))
+    eng = _engine(models, sched_policy=_TokenBudget(0))
     req = eng.submit([2 + (j % 60) for j in range(80)], 2,
                      top_k=1, termination_id=10 ** 9)
     eng.run_until_idle()
@@ -449,8 +447,8 @@ def _waiting_prompts():
 
 
 def _step_until_idle(eng, jobs):
-    """Results, and per step the prompt tokens prefilled and the programs
-    launched."""
+    """The finished requests, and per step the prompt tokens prefilled and
+    the programs launched."""
     reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
     per_step = []
     while not all(r.finished for r in reqs):
@@ -458,14 +456,14 @@ def _step_until_idle(eng, jobs):
         eng.step()
         per_step.append((eng.prefill_tokens_computed - before,
                          eng.last_tick_launches))
-    return [r.result(timeout=120) for r in reqs], per_step
+    return reqs, per_step
 
 
 def test_default_capacity_is_the_decode_width_in_chunks(models):
     """Nobody set a budget: the ragged tick's prompt-row capacity is
     max_slots in whole chunks, the default policy spends it while prompts
     wait, and the executable bound holds and is reached."""
-    eng = _wide(models, ragged=True)
+    eng = _wide(models)
     assert eng.prefill_rows == eng.max_slots == 2 * eng.prefill_chunk
     _, per_step = _step_until_idle(eng, _waiting_prompts())
     prefilled = [n for n, _ in per_step if n]
@@ -498,9 +496,8 @@ def _launch_sequence(eng, jobs):
 def test_narrow_engine_keeps_one_chunk_a_tick(models, slots):
     """max_slots <= prefill_chunk: the default is the explicit one-chunk
     budget, launch for launch."""
-    default = _engine(models, max_slots=slots, ragged=True)
-    pinned = _engine(models, max_slots=slots, ragged=True,
-                     prefill_budget=default.prefill_chunk)
+    default = _engine(models, max_slots=slots)
+    pinned = _engine(models, max_slots=slots, prefill_budget=default.prefill_chunk)
     assert default.prefill_rows == pinned.prefill_rows == 64
     got, seq = _launch_sequence(default, _mixed_jobs(n_new=6))
     want, want_seq = _launch_sequence(pinned, _mixed_jobs(n_new=6))
@@ -509,24 +506,41 @@ def test_narrow_engine_keeps_one_chunk_a_tick(models, slots):
     assert_same_generations(want, got)
 
 
-@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "legacy"])
-def test_default_pacing_is_lossless(models, ragged):
+def test_default_pacing_is_lossless(models):
     """The wide default against an explicit one-chunk budget: the same
-    tokens and log-probs (tests/parity.py's contract) in fewer ticks, on
-    either dispatch; the legacy dispatch launches at most capacity /
-    chunk chunk programs a tick however many prompts wait."""
+    tokens and log-probs (tests/parity.py's contract) in fewer ticks."""
     jobs = _waiting_prompts() + _mixed_jobs(n_new=6)
-    packed = _wide(models, ragged=ragged)
+    packed = _wide(models)
     got, per_step = _step_until_idle(packed, jobs)
-    paced = _wide(models, ragged=ragged, prefill_budget=16)
+    paced = _wide(models, prefill_budget=16)
     want, paced_steps = _step_until_idle(paced, jobs)
-    assert_same_generations(want, got)
+    assert_same_generations(generations(want), generations(got))
     assert max(n for n, _ in paced_steps) == 16
     assert max(n for n, _ in per_step) == 32
     assert packed.ticks < paced.ticks
-    if not ragged:
-        assert packed.prefill_rows == 0  # no ragged program: the cap alone
-        assert max(launches for _, launches in per_step) == 3
+
+
+def test_scored_prompt_among_waiting_prompts_on_the_wide_engine(models):
+    """The carve-out under the wide default pacing: a return_log_probs
+    prompt waiting among plain prompts costs at most one scoring chunk
+    beside the one tick program, a tick, and gets the tokens and prompt
+    scores of the request served alone."""
+    scored = ([2 + (j * 5) % 60 for j in range(50)], 6,
+              dict(top_k=1, termination_id=10 ** 9, return_log_probs=True))
+    jobs = _waiting_prompts()[:2] + [scored] + _waiting_prompts()[2:]
+    eng = _wide(models)
+    reqs, per_step = _step_until_idle(eng, jobs)
+    launches = [n for _, n in per_step]
+    # 50 tokens page-bucketed to 64 = four chunks of 16, one a tick, while
+    # the tick beside it still packs two chunks of the waiting prompts
+    assert max(launches) == 2 and launches.count(2) == 4, launches
+    assert max(n for n, _ in per_step) == 48
+    assert {rows for rows, _ in eng._chunk_fns} == {16}
+    alone, = serve_alone(lambda: _wide(models), [scored])
+    assert_same_generations(generations([alone]), generations([reqs[2]]))
+    assert len(reqs[2].prompt_log_probs) == 49
+    assert_logprobs_close(alone.prompt_log_probs, reqs[2].prompt_log_probs,
+                          "teacher-forced prompt scores")
 
 
 # ---------------------------------------------------------------------------
@@ -539,11 +553,71 @@ def test_launch_metrics_on_scrape(models):
 
     reg = obs_registry.get_registry()
     before = reg.counter("mlt_engine_tick_launches_total").value
-    eng = _engine(models, ragged=True)
+    eng = _engine(models)
     _run(eng, _mixed_jobs(n_new=4)[:3])
     text = reg.render()
     assert "mlt_engine_tick_launches_total" in text
     assert "mlt_engine_prefill_tokens_per_tick" in text
     assert reg.counter("mlt_engine_tick_launches_total").value > before
-    # ragged mode: launches == non-idle ticks
+    # launches == non-idle ticks
     assert eng.tick_launches == eng.ticks, (eng.tick_launches, eng.ticks)
+
+
+# ---------------------------------------------------------------------------
+# 6. one dispatch
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("scored", [False, True],
+                         ids=["plain", "with_log_probs"])
+def test_engine_compiles_the_ragged_tick_and_nothing_else(models, scored):
+    """After a mixed run an engine has compiled ``engine_ragged_tick``
+    programs (and the page copy of copy-on-write) and no other tick or
+    prefill program of its own; ``engine_prefill_chunk`` only when a
+    ``return_log_probs`` prompt was served."""
+    from megatron_llm_tpu.generation import generation as gen
+
+    # a geometry no other test builds: whatever this engine compiles is
+    # new to the process-wide program cache
+    eng = _engine(models, max_slots=5, num_pages=83 + scored)
+    jobs = _mixed_jobs(n_new=4)
+    if scored:
+        jobs[3][2]["return_log_probs"] = True
+    before = set(gen._JIT_CACHE)
+    reqs = run_jobs(eng, jobs)
+    names = sorted({k[1] for k in set(gen._JIT_CACHE) - before})
+    want = ["engine_copy_page"] + (["engine_prefill_chunk"] if scored
+                                   else []) + ["engine_ragged_tick"]
+    assert names == want, names
+    assert eng.cow_copies >= 1 and len(eng._ragged_fns) == 2
+    assert bool(eng._chunk_fns) == scored
+    assert (reqs[3].prompt_log_probs is not None) == scored
+
+
+@pytest.mark.parametrize("chunk", [0, 24, -16])
+def test_prefill_chunk_must_be_whole_pages_constructor(models, chunk):
+    with pytest.raises(ValueError,
+                       match="positive whole number of pages"):
+        _engine(models, prefill_chunk=chunk)
+
+
+def test_prefill_chunk_must_be_whole_pages_server_arguments(monkeypatch):
+    """The server's argument parsing refuses the same values with the
+    same message, before it builds anything."""
+    import importlib.util
+    from pathlib import Path
+
+    spec = importlib.util.spec_from_file_location(
+        "run_text_generation_server",
+        Path(__file__).parent.parent / "tools"
+        / "run_text_generation_server.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    for chunk in ("0", "24"):
+        monkeypatch.setattr("sys.argv", [
+            "run_text_generation_server.py", "--random_init",
+            "--tokenizer_type", "NullTokenizer", "--vocab_size", "128",
+            "--prefill_chunk", chunk])
+        with pytest.raises(ValueError,
+                           match="positive whole number of pages"):
+            tool.main()

@@ -1,7 +1,7 @@
 """Serving control plane tests (ISSUE 7, generation/scheduling/).
 
 Gates: (1) the fcfs policy — the default — is the pre-policy engine,
-token-for-token: same tokens AND log-probs as the PR 1 monolithic
+token-for-token: same tokens AND log-probs as the dense single-stream
 reference, strict submission-order admission, nothing preempted or shed;
 (2) preemption-by-page-release resumes BITWISE through the prefix cache
 (tokens + log-probs, greedy and sampled, any cut point); (3) the
@@ -40,7 +40,14 @@ from megatron_llm_tpu.generation.server import MegatronServer
 from megatron_llm_tpu.models import init_model_params, make_config
 from megatron_llm_tpu.observability import registry as obs_registry
 
-from tests.parity import assert_logprobs_close, assert_same_generations
+from tests.parity import (
+    assert_greedy_match_dense,
+    assert_logprobs_close,
+    assert_same_generations,
+    generations,
+    run_jobs,
+    serve_alone,
+)
 
 VOCAB = 67
 GKW = dict(top_k=1, termination_id=10 ** 9)
@@ -88,26 +95,27 @@ def test_policy_registry():
 
 
 def test_fcfs_bitwise_parity_vs_monolithic_reference(toy_model):
-    """Default engine (fcfs policy, chunked+cached) == the PR 1
-    monolithic prefill engine on tokens AND log-probs — the policy
-    extraction changed nothing.  Mirrors the pre-refactor parity contract
-    (tests/test_prefix_cache.py), now through the policy layer."""
+    """Default engine (fcfs policy, chunked+cached) against the two
+    references of tests/parity.py, neither of which has a scheduler: its
+    greedy jobs are the dense single-stream path's on tokens AND
+    log-probs, and every job (the sampled one too) is what the same
+    engine gives the request alone — queueing behind two slots under the
+    policy layer changed nothing."""
     cfg, params = toy_model
     jobs = [(_prompt(n, n), 10, dict(seed=n, **GKW)) for n in (3, 20, 40)]
     jobs.append((_prompt(24, 5), 10,
                  dict(temperature=0.8, top_p=0.9, seed=7,
                       termination_id=10 ** 9)))
 
-    mono = _engine(cfg, params, prefill_chunk=0)
-    ref = [mono.submit(p, g, **kw) for p, g, kw in jobs]
-    res_ref = _drain(mono, ref)
+    def make():
+        return _engine(cfg, params, sched_policy="fcfs")
 
-    fcfs = _engine(cfg, params, sched_policy="fcfs")
+    fcfs = make()
     assert isinstance(fcfs.policy, FcfsPolicy)
-    got = [fcfs.submit(p, g, **kw) for p, g, kw in jobs]
-    res_got = _drain(fcfs, got)
-
-    assert_same_generations(res_ref, res_got)
+    got = run_jobs(fcfs, jobs)
+    assert assert_greedy_match_dense(cfg, params, jobs, got) == 3
+    assert_same_generations(generations(serve_alone(make, jobs)),
+                            generations(got))
     assert fcfs.preemptions == 0 and fcfs.shed_requests == 0
 
 
@@ -264,7 +272,6 @@ def _state(now=100.0, **kw):
     kw.setdefault("ema_retire_s", None)
     kw.setdefault("free_slots", 0)
     kw.setdefault("queue_depth", 0)
-    kw.setdefault("can_preempt", True)
     return SchedulerState(now=now, **kw)
 
 

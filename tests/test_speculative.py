@@ -301,9 +301,6 @@ def test_spec_requires_draft_and_chunked_prefill(models):
     cfg, params = models["cfg"], models["params"]
     with pytest.raises(ValueError, match="draft"):
         _engine(cfg, params, spec_k=2)
-    with pytest.raises(AssertionError, match="chunked prefill"):
-        _engine(cfg, params, spec_k=2, spec_draft=models["draft"],
-                prefill_chunk=0)
 
 
 def test_draft_compat_rejected(models):

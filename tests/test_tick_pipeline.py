@@ -71,7 +71,6 @@ def models():
 def _engine(models, depth, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("max_seq", 128)
-    kw.setdefault("ragged", True)
     return ContinuousBatchingEngine(models["cfg"], models["params"], None,
                                     tick_pipeline_depth=depth, **kw)
 

@@ -7,7 +7,7 @@ The parity matrix the acceptance criteria name:
   tolerance, NOT bitwise — the overlap.py docstring documents why),
   with the ring mechanism machine-asserted in the compiled HLO
   (ppermute chain + ``forward-tp{N}-overlap`` scope metadata);
-* engine greedy-token identity at tp=4, ragged AND legacy tick, with
+* engine greedy-token identity at tp=4 on the ragged tick, with
   per-token log-probs within 5e-6 and the overlap span in a trace dump;
 * int8 wire chunks vs the f32 ring (bounded by the per-hop rounding
   analysis) and vs the plain path;
@@ -141,12 +141,11 @@ def test_quantized_wire_bounded_vs_f32_ring(eight_devices):
     assert float(np.max(np.abs(y32 - np.asarray(x @ w)))) < 1e-4
 
 
-def _run_engine(cfg, params, mesh, ragged=True, n_req=3, tokens=8):
+def _run_engine(cfg, params, mesh, n_req=3, tokens=8):
     from megatron_llm_tpu.generation.engine import ContinuousBatchingEngine
 
     eng = ContinuousBatchingEngine(cfg, params, None, max_slots=4,
-                                   num_pages=64, page_size=16,
-                                   ragged=ragged, mesh=mesh)
+                                   num_pages=64, page_size=16, mesh=mesh)
     prompts = [[2 + (7 * i + j) % (VOCAB - 2) for j in range(13)]
                for i in range(n_req)]
     reqs = [eng.submit(p, tokens, temperature=1.0, top_k=0, top_p=0.0,
@@ -155,10 +154,9 @@ def _run_engine(cfg, params, mesh, ragged=True, n_req=3, tokens=8):
     return eng, [(r.result()[0], list(r.log_probs)) for r in reqs]
 
 
-@pytest.mark.parametrize("ragged", [True, False])
-def test_engine_tp4_token_identity(eight_devices, ragged):
-    """Engine greedy decode at tp=4: ring emits the SAME tokens as off
-    (both tick modes); per-token log-probs within 5e-6."""
+def test_engine_tp4_token_identity(eight_devices):
+    """Engine greedy decode at tp=4: ring emits the SAME tokens as off;
+    per-token log-probs within 5e-6."""
     cfg = _toy_cfg(1)
     params = init_model_params(cfg, jax.random.PRNGKey(0))
     mesh = ps.build_mesh(tensor_model_parallel_size=4,
@@ -166,11 +164,11 @@ def test_engine_tp4_token_identity(eight_devices, ragged):
     c_off = copy.deepcopy(cfg)
     c_ring = copy.deepcopy(cfg)
     c_ring.parallel.tp_overlap = "ring"
-    _, off = _run_engine(c_off, params, mesh, ragged=ragged)
+    _, off = _run_engine(c_off, params, mesh)
     from megatron_llm_tpu.observability import trace as obs_trace
 
     tracer = obs_trace.configure()
-    eng, ring = _run_engine(c_ring, params, mesh, ragged=ragged)
+    eng, ring = _run_engine(c_ring, params, mesh)
     for (t0, l0), (t1, l1) in zip(off, ring):
         assert t0 == t1
         np.testing.assert_allclose(l0, l1, atol=5e-6)
@@ -266,7 +264,7 @@ def test_cached_jit_keys_never_cross_overlap_modes(eight_devices):
     assert ("tp_overlap", "ring") == e_ring._mesh_statics[-2:]
     assert e_off._mesh_statics != e_ring._mesh_statics
     # and the compiled tick programs are distinct cache entries
-    assert e_off._tick() is not e_ring._tick()
+    assert e_off._ragged_tick(0) is not e_ring._ragged_tick(0)
     # a no-mesh engine also never collides with a ring engine even under
     # an overlap-requesting cfg (the inert-flag case)
     e_none = ContinuousBatchingEngine(c_ring, params, None, max_slots=4,

@@ -10,8 +10,9 @@ SPMD needs none of that).
 Default engine is the continuous-batching paged-KV engine
 (generation/engine.py): concurrent requests share fused decode ticks, a
 refcounted prefix cache reuses shared-prompt KV pages (``--prefix_cache``),
-and prefill runs in schedulable chunks interleaved with decode
-(``--prefill_chunk``, 0 = monolithic).  ``--legacy_engine`` serves the
+and prefill runs in schedulable chunks packed into the decode tick
+(``--prefill_chunk``, a positive whole number of pages).
+``--legacy_engine`` serves the
 dense one-request-at-a-time path instead.  Engine geometry and
 backpressure come from ``cfg.inference`` (--max_batch_slots, --page_size,
 --page_watermark, --max_queued_requests: overflow answers a structured 503
