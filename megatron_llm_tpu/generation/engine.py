@@ -97,7 +97,8 @@ import threading
 import time
 import traceback
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Dict, List, NamedTuple, Optional, Sequence, Set,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +131,15 @@ from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
 
 NULL_PAGE = 0
+
+
+def _request_key(seed: int) -> np.ndarray:
+    """The two words of ``jax.random.PRNGKey(seed)`` (threefry), worked out
+    on the host.  As a device program the seeding would queue behind the
+    tick in flight, and the apply that activates the request would wait
+    for that tick to end (tests/test_tick_lag.py pins the equality)."""
+    hi = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, seed & 0xFFFFFFFF], np.uint32)
 
 
 def _bucket_up(n: int, bucket: int = gen.BUCKET) -> int:
@@ -348,7 +358,9 @@ class PagedKVPool:
         """``n`` fresh pages at refcount 1, or None if free + evictable
         can't satisfy the request.  Evicts cached-idle pages (LRU,
         leaf-first) only when the free list alone runs short."""
-        if n > self.num_available:
+        # the free list first: counting the evictable pages walks every
+        # cached page, and the tick's page grants come here once a row
+        if n > len(self._free) and n > self.num_available:
             return None
         if n > len(self._free) and self.evict_hook is not None:
             self._free.extend(self.evict_hook(n - len(self._free)))
@@ -656,6 +668,31 @@ class EngineRequest:
         return self._t_done - self._t_submit
 
 
+class _Launched(NamedTuple):
+    """A launch the host has not applied yet (an entry of
+    ``ContinuousBatchingEngine._inflight``): the slots it ran, the requests
+    those slots held then, its device tokens and log-probs, its launch
+    time.  A row is dropped at apply when its slot no longer holds its
+    request as launched (retired, preempted or failed meanwhile:
+    ``_row_live``).  One ragged tick (``chain`` 0: arrays ``[b]``) or a
+    chained launch of ``chain`` decode ticks (``[chain, b]``); the fields
+    after ``chain`` are the ragged tick's own."""
+
+    active: List[int]
+    reqs: List[EngineRequest]
+    toks: object
+    logps: object
+    t0: float
+    epochs: List[int]  # each request's ``_preemptions`` at the launch
+    chain: int = 0
+    no: int = 0        # the tick's number: ``tick=`` of its spans
+    spans: Sequence = ()  # the prompt chunks it packed: (req, start, end)
+    n_bucket: int = 0  # its compiled prompt-row capacity (0: decode-only)
+    # a verify tick's (accepted, counts, k_eff); ``toks``/``logps`` then
+    # hold the emitted blocks ``[b, K+1]``
+    spec: Optional[Tuple] = None
+
+
 class ContinuousBatchingEngine:
     """Shared-tick decode over a prefix-cached paged pool."""
 
@@ -910,14 +947,21 @@ class ContinuousBatchingEngine:
         # whenever admission/retirement changes the slot layout
         self._dev_state: Optional[Tuple] = None  # guarded by _lock
         self._dirty = True  # guarded by _lock
-        # pipelined dispatch state (ISSUE 17).  _inflight holds launched-
-        # but-unapplied chained launches as (active slots, request
-        # identities, device tokens [C,b], device log-probs [C,b],
-        # launch time); _pipe_state is the device-resident
+        # launched-but-unapplied work, oldest first (:class:`_Launched`):
+        # at most one ragged tick between two steps (the step launches
+        # the next tick before it applies this one), or the chained
+        # launches of --tick_pipeline_depth (ISSUE 17), never both kinds
+        # at once.  _pipe_state is the device-resident
         # (term_ids, stop_modes, done, remaining) carry the next chain
         # consumes — None means the next launch must rebuild it from the
         # (then-current) host mirrors — guarded by _lock
         self._inflight: deque = deque()
+        # the ``carried`` operand of a tick that takes no token from a
+        # tick in flight
+        self._no_carry = self._asarray(
+            np.zeros((self.max_slots,), np.bool_))
+        # when the last ragged tick's results reached the host
+        self._last_fetch_t = 0.0  # guarded by _lock
         self._pipe_state: Optional[Tuple] = None  # guarded by _lock
         self._chained_fn = None
         # inter-launch host-gap samples for the pipeline bench (bounded;
@@ -1085,15 +1129,18 @@ class ContinuousBatchingEngine:
         # histogram below is
         self._m_host_gap = reg.histogram(
             "mlt_engine_host_gap_seconds",
-            help="wall time between consecutive tick-program dispatches; "
-                 "INCLUDES the fetch's wait for the device tick to end, "
-                 "so it is about one tick long when the device is busy "
-                 "(pipelining amortizes it). For host work read "
-                 "mlt_engine_tick_phase_seconds (admit, plan, launch, "
-                 "apply)",
+            help="wall time between consecutive tick-program dispatches: "
+                 "one whole scheduler cycle less the dispatch itself. It "
+                 "INCLUDES the fetch's wait for the device, so it is "
+                 "about one tick long while the host's work (read "
+                 "mlt_engine_tick_phase_seconds: admit, plan, launch, "
+                 "apply) fits beside the running tick, and longer once "
+                 "it does not",
             buckets=lat)
         # where one ragged tick's wall time goes, phase by phase, at the
-        # boundaries of the engine-* spans (one observation a tick each)
+        # boundaries of the engine-* spans (one observation a tick each:
+        # admit, plan and launch when it is dispatched, fetch and apply
+        # when its results land, a step later while ticks follow on)
         self._m_phase = {
             ph: reg.histogram(
                 "mlt_engine_tick_phase_seconds",
@@ -1104,9 +1151,19 @@ class ContinuousBatchingEngine:
                      "result's way back), apply (tokens to requests and "
                      "streams, retirement, gauges, and the wait to get "
                      "the interpreter lock back from the handler threads "
-                     "it woke)",
+                     "it woke). Fetch and apply of a tick run after the "
+                     "next tick's launch, beside it on the device",
                 labels={"phase": ph}, buckets=lat)
             for ph in ("admit", "plan", "launch", "fetch", "apply")}
+        self._m_apply_lag = {
+            lag: reg.counter(
+                "mlt_engine_tick_apply_lag_total",
+                help="ragged ticks by how they were applied: 1 = the next "
+                     "tick had been dispatched first (the host worked "
+                     "beside the device), 0 = nothing was queued behind "
+                     "it (speculative engines, a scoring chunk, the last "
+                     "tick before idle)",
+                labels={"lag": lag}) for lag in ("0", "1")}
         self._m_tick_kind = {
             k: reg.counter(
                 "mlt_engine_tick_kind_total",
@@ -1115,8 +1172,9 @@ class ContinuousBatchingEngine:
                 labels={"kind": k}) for k in ("decode", "prefill")}
         self._m_inflight = reg.gauge(
             "mlt_engine_inflight_ticks",
-            help="device ticks launched but not yet applied "
-                 "(--tick_pipeline_depth chains in flight)")
+            help="device ticks launched but not yet applied (the "
+                 "ragged tick running beside the host, or "
+                 "--tick_pipeline_depth chains in flight)")
         # token streaming (ISSUE 18, serving/streaming/): live
         # subscriptions + incremental events shed by slow consumers
         # (drop-to-terminal — the terminal event is never shed)
@@ -1896,7 +1954,7 @@ class ContinuousBatchingEngine:
             seed = req.seed
             if seed is None:
                 seed = int.from_bytes(os.urandom(4), "little")
-            req._key = np.asarray(jax.random.PRNGKey(seed), np.uint32)
+            req._key = _request_key(seed)
         bt = np.full((self.pages_per_seq,), NULL_PAGE, np.int32)
         bt[: len(req._pages)] = req._pages
         self._block_tables[slot] = bt
@@ -2143,32 +2201,6 @@ class ContinuousBatchingEngine:
         self.spec_ticks += 1
         return emitted
 
-    def _apply_plain_locked(self, active, next_np, logp_np,
-                            now) -> int:  # holds _lock
-        """Fold one non-speculative tick's sampled tokens into the slots;
-        returns tokens emitted (== len(active))."""
-        for i in active:
-            req = self._slots[i]
-            tok = int(next_np[i])
-            req.generated.append(tok)
-            req.log_probs.append(float(logp_np[i]))
-            req._step += 1
-            if req._step == 1:
-                req._t_first = now
-                req._flight.mark_first_token(now)
-                self._note_ttft_locked(now - req._t_submit)
-            self._stream_emit_locked(req, (tok,), (req.log_probs[-1],))
-            self._positions[i] += 1
-            self._tokens[i] = tok
-            self._steps[i] += 1
-            done = (self._stopped_by_token(req, tok)
-                    or len(req.generated) >= req.max_new_tokens
-                    or len(req.prompt) + len(req.generated)
-                    >= self.max_seq)
-            if done:
-                self._retire(i)
-        return len(active)
-
     def spec_stats(self) -> dict:
         """Speculative-decoding snapshot for ``/health`` and the spec
         bench (generation/server.py, bench_decode.py --mode spec)."""
@@ -2331,10 +2363,12 @@ class ContinuousBatchingEngine:
                 f"got {budget!r}")
         return min(max(budget, self.prefill_chunk), self._prefill_cap)
 
-    def _prepare_decode_locked(self, active) -> np.ndarray:  # holds _lock
+    def _prepare_decode_locked(self, active,
+                               ahead) -> np.ndarray:  # holds _lock
         """On-demand paging + per-slot speculation depth for the decode
         rows of this tick; mutates ``active`` in place when a row must be
-        failed.
+        failed.  ``ahead[i]`` is 1 for a row the tick in flight is still
+        sampling for: this tick feeds it one position past the host's.
 
         A row crossing into a page it doesn't own yet gets one allocated
         now (commitment ledger guarantees this can't fail while the slot
@@ -2355,9 +2389,9 @@ class ContinuousBatchingEngine:
                     k_i = min(k_i, max(1, int(round(
                         req._spec_ema * self.spec_k))))
                 k_eff[i] = max(k_i, 0)
-            p0 = int(self._positions[i]) // self.page_size
-            p1 = (int(self._positions[i]) + int(k_eff[i])) \
-                // self.page_size
+            pos = int(self._positions[i]) + int(ahead[i])
+            p0 = pos // self.page_size
+            p1 = (pos + int(k_eff[i])) // self.page_size
             for idx in range(p0, min(p1, self.pages_per_seq - 1) + 1):
                 if self._block_tables[i][idx] != NULL_PAGE:
                     continue
@@ -2374,18 +2408,25 @@ class ContinuousBatchingEngine:
                 self._dirty = True
         return k_eff
 
-    def _dev_state_locked(self) -> Tuple:  # holds _lock
+    def _dev_state_locked(self, ahead=0, spent=()) -> Tuple:  # holds _lock
         """The device mirror of the per-slot arrays, re-uploaded from the
-        host copies only when admission/retirement dirtied the layout."""
+        host copies only when admission/retirement dirtied the layout.
+        The host copies stand where the last APPLIED tick left them; with
+        a tick in flight, the rows it runs (``ahead``, 0 or 1 a slot) are
+        uploaded one position and one step on, and the rows whose budget
+        it spends (``spent``) with a null table: a dead row, which writes
+        the null page and never runs past its granted pages.  Snapshots
+        go up, never the mirrors themselves: those move again before the
+        tick that reads the upload has run, and a backend may alias host
+        memory (XLA:CPU does)."""
         if self._dirty:
-            self._dev_state = (self._asarray(self._block_tables),
-                               self._asarray(self._positions),
-                               self._asarray(self._tokens),
-                               self._asarray(self._keys),
-                               self._asarray(self._steps),
-                               self._asarray(self._temperature),
-                               self._asarray(self._top_k),
-                               self._asarray(self._top_p))
+            bt = self._block_tables.copy()
+            bt[list(spent)] = NULL_PAGE
+            self._dev_state = tuple(self._asarray(a) for a in (
+                bt, self._positions + ahead, self._tokens.copy(),
+                self._keys.copy(), self._steps + ahead,
+                self._temperature.copy(), self._top_k.copy(),
+                self._top_p.copy()))
             self._dirty = False
         return self._dev_state
 
@@ -2473,21 +2514,23 @@ class ContinuousBatchingEngine:
                 changed = True
         return changed
 
-    def _apply_chain_locked(self, active, reqs, toks_np, logps_np,
+    def _apply_chain_locked(self, rec: _Launched, toks_np, logps_np,
                             now) -> int:  # holds _lock
-        """Fold one in-flight chain's results into the slots — the spec
-        apply's block shape over the chain axis: each surviving row
-        appends its whole column up to the first stop in ONE pass, so
+        """Fold one in-flight launch's sampled tokens into the slots: a
+        chain's ``[chain, b]``, or one ragged tick's as a chain of one.
+        The spec apply's block shape over the chain axis: each surviving
+        row appends its whole column up to the first stop in ONE pass, so
         host apply cost is per CHAIN, not per tick (the pipelined mode's
         other half: chains amortize dispatch, this amortizes apply).
-        Bit-for-bit the per-tick ``_apply_plain_locked`` fold: same stop
-        rules in the same order, rows discarded when their slot no
-        longer holds the launched request."""
+        A row is discarded when its slot no longer holds the launched
+        request (:meth:`_row_live`); ``_positions`` / ``_steps`` /
+        ``_tokens`` move here, so they always stand where the last
+        applied launch left them."""
         chain = toks_np.shape[0]
         emitted = 0
-        for i, req in zip(active, reqs):
-            if self._slots[i] is not req or req._phase != "decode":
-                continue  # retired / preempted / failed at the boundary
+        for k, (i, req) in enumerate(zip(rec.active, rec.reqs)):
+            if not self._row_live(rec, k):
+                continue  # retired / preempted / failed since the launch
             col = toks_np[:, i].tolist()
             room = min(req.max_new_tokens - len(req.generated),
                        self.max_seq - len(req.prompt)
@@ -2531,7 +2574,8 @@ class ContinuousBatchingEngine:
         return emitted
 
     def _apply_oldest(self) -> int:
-        """Fetch and fold the OLDEST in-flight chain: ONE batched
+        """Fetch and fold the OLDEST in-flight launch (a ragged tick goes
+        to :meth:`_apply_tick`); for a chain: ONE batched
         ``jax.device_get`` for all of its ticks' tokens and log-probs
         (the drain point), then per-tick application under the host's
         own stop rules — the lag boundary where admission/stop/
@@ -2543,17 +2587,20 @@ class ContinuousBatchingEngine:
         with self._lock:
             if not self._inflight:
                 return 0
-            active, reqs, ctoks, clogps, t0 = self._inflight.popleft()
-        toks_np, logps_np = jax.device_get((ctoks, clogps))
+            ragged = not self._inflight[0].chain
+            if not ragged:
+                rec = self._inflight.popleft()
+        if ragged:
+            return self._apply_tick() or 0
+        toks_np, logps_np = jax.device_get((rec.toks, rec.logps))
         now = time.monotonic()
         emitted = 0
         with self._lock:
             chain = toks_np.shape[0]
-            dt = (now - t0) / max(chain, 1)
+            dt = (now - rec.t0) / max(chain, 1)
             self._ema_tick_s = (dt if self._ema_tick_s is None
                                 else 0.8 * self._ema_tick_s + 0.2 * dt)
-            emitted = self._apply_chain_locked(active, reqs, toks_np,
-                                               logps_np, now)
+            emitted = self._apply_chain_locked(rec, toks_np, logps_np, now)
             self.ticks += chain
             self.ticked_tokens += emitted
             if obs_registry.publishing():
@@ -2571,10 +2618,10 @@ class ContinuousBatchingEngine:
         return emitted
 
     def _drain_pipeline(self) -> int:
-        """Apply every in-flight chain and invalidate the device-resident
-        pipeline carry — the boundary synchronization point: after this
-        the host mirrors are exact and depth-0 stepping (admission,
-        prefill, preemption) may run.  Returns tokens emitted."""
+        """Apply everything in flight (chains, or the ragged step's lagged
+        tick) and invalidate the device-resident pipeline carry — the
+        boundary synchronization point: after this the host mirrors are
+        exact.  Returns tokens emitted."""
         emitted = 0
         while True:
             with self._lock:
@@ -2587,6 +2634,16 @@ class ContinuousBatchingEngine:
             if obs_registry.publishing():
                 self._m_inflight.set(0)
         return emitted
+
+    def _steady_rows_locked(self) -> List[int]:  # holds _lock
+        """The decoding slots if nothing but decoding is going on (no
+        queue, no prefill, no handoff), else none: the state a chain may
+        be launched from."""
+        if (self._queue or self._prefill_q
+                or any(r is not None and r._phase != "decode"
+                       for r in self._slots)):
+            return []
+        return [i for i, r in enumerate(self._slots) if r is not None]
 
     def _step_pipelined(self) -> Optional[int]:
         """One pipelined driver step (``--tick_pipeline_depth N > 0``):
@@ -2607,12 +2664,18 @@ class ContinuousBatchingEngine:
         bitwise after preemption; and per-row bits are batch-composition
         invariant, so freezing one row never changes another's tokens."""
         with self._lock:
-            steady = (not self._queue and not self._prefill_q
-                      and all(r is None or r._phase == "decode"
-                              for r in self._slots))
-            active = [i for i, r in enumerate(self._slots)
-                      if r is not None and r._phase == "decode"]
-        if not steady or not active:
+            active = self._steady_rows_locked()
+            lagged = bool(self._inflight) and not self._inflight[0].chain
+        if active and lagged:
+            # the ragged step's tick in flight lands first: a chain is
+            # built on exact host mirrors, and what it retires or
+            # activates decides whether the state is steady at all
+            self._apply_oldest()
+            with self._lock:
+                active = self._steady_rows_locked()
+        elif not active and lagged:
+            return None  # the ragged step keeps its own lag
+        if not active:
             self._drain_pipeline()
             return None
         C = self.pipeline_depth
@@ -2696,7 +2759,9 @@ class ContinuousBatchingEngine:
             self._dev_state = (bt, new_pos, new_tok, keys, new_steps,
                                temp, tk, tp)
             self._pipe_state = (term_d, mode_d, new_done, new_rem)
-            self._inflight.append((active, reqs, ctoks, clogps, t0))
+            self._inflight.append(_Launched(
+                active, reqs, ctoks, clogps, t0,
+                [r._preemptions for r in reqs], chain=C))
             depth_now = len(self._inflight)
             self._note_launches_locked(1, 0)
             if obs_registry.publishing():
@@ -2781,31 +2846,41 @@ class ContinuousBatchingEngine:
         return (spans, pre_tok, pre_pos, pre_tables, pre_index,
                 pre_hor, lp_live)
 
-    def _apply_ragged_prefill_locked(self, spans, tick_s: float = 0.0,
-                                     work_rows: int = 0
-                                     ) -> None:  # holds _lock
-        """Advance the packed requests' fill frontiers; a request whose
-        bucketed prompt completed inserts its full pages into the prefix
-        trie (refeed page excluded — shared pages immutable from birth)
-        and activates into decode, exactly like
-        _advance_scored_prefill's completion tail.  ``tick_s``/``work_rows``
-        attribute the fused launch's wall time to each request's flight
-        record proportionally to its rows — an estimate by construction
-        (the launch is ONE program), documented as such."""
+    def _advance_fill_locked(self, spans) -> None:  # holds _lock
+        """The half of a tick's prompt rows the host knows at dispatch:
+        the packed requests' fill frontiers move to the planned ends, so
+        the next plan packs the chunks after them."""
         ps = self.page_size
         for req, start, end in spans:
-            if req._phase != "prefill":  # failed mid-step (defensive)
-                continue
             req._fill_pos = end
             rows = end - start
             self.prefill_tokens_computed += rows
             req._flight.event("prefill_chunk", start=start, end=end,
                               rows=rows,
                               fill_end=_bucket_up(len(req.seq_tokens), ps))
-            if work_rows > 0:
-                req._flight.add_prefill_compute(tick_s * rows / work_rows)
             if obs_registry.publishing():
                 self._m_prefill_tokens.inc(rows)
+
+    def _finish_prefill_locked(self, spans, tick_s: float,
+                               work_rows: int) -> None:  # holds _lock
+        """The half that waits for the tick's results: a request whose
+        bucketed prompt the tick completed inserts its full pages into the
+        prefix trie (refeed page excluded — shared pages immutable from
+        birth) and activates into decode or parks for handoff, exactly
+        like _advance_scored_prefill's completion tail.  So the trie and
+        an export only ever hold pages of a tick that has been fetched; a
+        request waits one tick between its last chunk and its first
+        decode row while ticks follow on.  ``tick_s``/``work_rows``
+        attribute the fused launch's wall time to each request's flight
+        record proportionally to its rows — an estimate by construction
+        (the launch is ONE program), documented as such."""
+        ps = self.page_size
+        for req, start, end in spans:
+            if req._phase != "prefill":  # failed since the launch
+                continue
+            if work_rows > 0:
+                req._flight.add_prefill_compute(
+                    tick_s * (end - start) / work_rows)
             seq = req.seq_tokens
             if end >= _bucket_up(len(seq), ps):
                 self._prefill_q.remove(req)
@@ -2821,11 +2896,39 @@ class ContinuousBatchingEngine:
         carve-out — their teacher-forced chunk is a program of its own
         (counted honestly in the launch telemetry).
 
-        The tick's phases are spans under the caller's ``engine-step``
-        (plan, launch and fetch inside ``engine-ragged-tick``, apply) and
-        one observation each of ``mlt_engine_tick_phase_seconds``, with
-        ``admit_s`` (the caller's admission) the fifth; a step that
-        launches nothing observes nothing."""
+        The step runs one tick ahead of the host: it plans and DISPATCHES
+        the next tick first, then fetches and applies the tick that was
+        in flight (:meth:`_apply_tick`) while the device runs the new
+        one, so retire, admit, activate and preempt land one tick late
+        and the device does not wait for them.  Lossless because:
+
+        * everything the next tick needs but the continuing rows' tokens
+          is known before the last tick's results are: a row it runs
+          stands one position and one step on (``ahead``; the host
+          mirrors keep the APPLIED state), page crossings follow from
+          that, and a prompt's fill frontier moves when its chunk is
+          dispatched (:meth:`_advance_fill_locked`);
+        * those tokens go device to device: the tick takes the in-flight
+          tick's ``next_tok`` for the rows it carries (``carried``) and
+          the uploaded host token for the rest;
+        * a row whose budget (``max_new_tokens``, ``max_seq``) the tick
+          in flight spends is launched dead (``spent``); a row that a
+          stop token ends runs one overrun row in the tick already
+          launched, dropped at apply (:meth:`_row_live`), its write in
+          the request's own tail page or the null page; pages a retire
+          or a preemption releases are re-used only by ticks dispatched
+          later, which the device runs later; a preempted victim draws
+          its dropped tokens again, bit for bit (``fold_in(key, step)``);
+        * what cannot be predicted keeps lag 0, by what the engine sees:
+          a speculative tick (its positions move by the accepted count)
+          and a step that ran a scoring chunk are applied at once.
+
+        The phases are spans under the caller's ``engine-step``: plan,
+        launch (inside ``engine-ragged-tick``) of this tick, then fetch
+        and apply of the tick before, each with its ``tick=``; and one
+        observation each of ``mlt_engine_tick_phase_seconds`` a tick,
+        with ``admit_s`` (the caller's admission) the fifth; a step that
+        launches nothing observes nothing for a launch."""
         t_plan = time.monotonic()
         with obs_trace.span("engine-plan"):
             with self._lock:
@@ -2834,13 +2937,28 @@ class ContinuousBatchingEngine:
                  lp_live) = self._plan_ragged_prefill()
             did_lp = 1 if lp_live and self._advance_scored_prefill() else 0
             with self._lock:
-                active = [i for i, r in enumerate(self._slots)
-                          if r is not None and r._phase == "decode"]
+                prev = self._inflight[-1] if self._inflight else None
+                ahead = np.zeros((self.max_slots,), np.int32)
+                if prev is not None:
+                    for k, i in enumerate(prev.active):
+                        if self._row_live(prev, k):
+                            ahead[i] = 1
+                active, spent = [], []
+                for i, r in enumerate(self._slots):
+                    if r is None or r._phase != "decode":
+                        continue
+                    n = len(r.generated) + 1
+                    if ahead[i] and (n >= r.max_new_tokens
+                                     or len(r.prompt) + n >= self.max_seq):
+                        spent.append(i)
+                    else:
+                        active.append(i)
                 if active:
-                    k_eff = self._prepare_decode_locked(active)
+                    k_eff = self._prepare_decode_locked(active, ahead)
                 else:
                     k_eff = np.zeros((self.max_slots,), np.int32)
-                if not active and not spans:
+                idle = not active and not spans
+                if idle:
                     self._note_launches_locked(
                         did_lp, self.prefill_tokens_computed - pre0)
                     if obs_registry.publishing():
@@ -2849,11 +2967,23 @@ class ContinuousBatchingEngine:
                         self._m_pages_cached.set(
                             len(self.cache) if self.cache else 0)
                     self._publish_queued_locked()
-                    return did_lp
-                self.peak_active_slots = max(self.peak_active_slots,
-                                             len(active))
-                bt, pos, toks, keys, steps, temp, tk, tp = \
-                    self._dev_state_locked()
+                else:
+                    no = self.ticks + len(self._inflight)
+                    reqs = [self._slots[i] for i in active]
+                    epochs = [r._preemptions for r in reqs]
+                    self.peak_active_slots = max(self.peak_active_slots,
+                                                 len(active))
+                    if spent:
+                        self._dirty = True
+                    # a re-upload holds the tokens of the last APPLIED
+                    # tick: the rows the tick in flight runs take its
+                    # output instead, on the device
+                    carry = ((prev.toks, self._asarray(ahead > 0))
+                             if prev is not None and self._dirty else None)
+                    bt, pos, toks, keys, steps, temp, tk, tp = \
+                        self._dev_state_locked(ahead, spent)
+                    if carry is None:
+                        carry = (toks, self._no_carry)
 
             n_pre = sum(end - start for _, start, end in spans)
             # live prefill rows bucketed to chunk multiples: the program's
@@ -2862,6 +2992,9 @@ class ContinuousBatchingEngine:
             n_bucket = (min(self.prefill_rows,
                             _bucket_up(n_pre, self.prefill_chunk))
                         if n_pre else 0)
+        if idle:
+            # nothing to dispatch: what is in flight lands now
+            return did_lp + (self._apply_tick() is not None)
         t_tick = time.monotonic()
         gap = (None if self._last_dispatch_end is None
                else t_tick - self._last_dispatch_end)
@@ -2869,7 +3002,8 @@ class ContinuousBatchingEngine:
                             prefill_tokens=n_pre, launches=1,
                             k=self.spec_k, tp=self._tp), \
                 self._overlap_span(), self._pp_span():
-            with obs_trace.span("engine-launch", prefill_rows=n_bucket,
+            with obs_trace.span("engine-launch", tick=no,
+                                prefill_rows=n_bucket,
                                 prefill_tokens=n_pre,
                                 decode_rows=len(active)):
                 pre_args = () if not n_bucket else (
@@ -2881,54 +3015,111 @@ class ContinuousBatchingEngine:
                 tick_fn = self._ragged_tick(n_bucket)
                 if self.spec_k:
                     (self.pool.k, self.pool.v, self.pool.draft_k,
-                     self.pool.draft_v, emit, emit_lp, acc, cnt,
+                     self.pool.draft_v, out_tok, out_lp, acc, cnt,
                      new_pos, next_tok, new_steps) = tick_fn(
                         self.params, self.draft_params,
                         self.pool.k, self.pool.v,
                         self.pool.draft_k, self.pool.draft_v,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         self._asarray(k_eff), *pre_args)
+                    spec = (acc, cnt, k_eff)
+                    del acc, cnt
                 else:
-                    (self.pool.k, self.pool.v, next_tok, logp,
+                    (self.pool.k, self.pool.v, next_tok, out_lp,
                      new_pos, new_steps) = tick_fn(
                         self.params, self.pool.k, self.pool.v,
                         bt, pos, toks, keys, steps, temp, tk, tp,
-                        *pre_args)
-                t_launched = self._last_dispatch_end = time.monotonic()
-            with obs_trace.span("engine-fetch"):
-                # ONE batched host sync for the tick's emissions: the wait
-                # for the device tick to end, and the result's way back
-                if self.spec_k:
-                    emit_np, lp_np, acc_np, m_np = jax.device_get(
-                        (emit, emit_lp, acc, cnt))
-                else:
-                    next_np, logp_np = jax.device_get((next_tok, logp))
+                        *carry, *pre_args)
+                    out_tok, spec = next_tok, None
+                self._last_dispatch_end = time.monotonic()
+                with self._lock:
+                    self._inflight.append(_Launched(
+                        active, reqs, out_tok, out_lp, t_tick, epochs,
+                        no=no, spans=spans, n_bucket=n_bucket, spec=spec))
+                    self._advance_fill_locked(spans)
+                    if not self._dirty:
+                        # steady state: the tick advanced the device mirror
+                        self._dev_state = (bt, new_pos, next_tok, keys,
+                                           new_steps, temp, tk, tp)
+                    self._note_launches_locked(
+                        1 + did_lp, self.prefill_tokens_computed - pre0)
+                    if obs_registry.publishing():
+                        self._m_inflight.set(len(self._inflight))
+                # the launch's handles are dropped here, outside the lock:
+                # freeing a device array can release the interpreter lock
+                del pre_args, bt, pos, toks, keys, steps, temp, tk, tp
+                del carry, prev, out_tok, out_lp, next_tok, new_pos
+                del new_steps, spec
+        t_launched = time.monotonic()
+        self._note_host_gap(gap)
+        if obs_registry.publishing():
+            for ph, sec in (("admit", admit_s), ("plan", t_tick - t_plan),
+                            ("launch", t_launched - t_tick)):
+                self._m_phase[ph].observe(sec)
+        # the tick before lands while the device runs this one; this one
+        # too where the host cannot know its outcome's shape beforehand
+        lag = 0 if self.spec_k or did_lp else 1
+        while self._apply_tick(keep=lag) is not None:
+            pass
+        return len(active) + (1 if spans else 0) + did_lp
 
+    def _row_live(self, rec: _Launched, k: int) -> bool:  # holds _lock
+        """Whether row ``k`` of a launch in flight is still its request's:
+        the slot holds that request, decoding, and not preempted since
+        (a victim can be back in its old slot before the launch is
+        applied; it then draws the dropped tokens again)."""
+        req = rec.reqs[k]
+        return (self._slots[rec.active[k]] is req
+                and req._phase == "decode"
+                and req._preemptions == rec.epochs[k])
+
+    def _apply_tick(self, keep: int = 0) -> Optional[int]:
+        """Fetch and fold the oldest ragged tick in flight, unless no more
+        than ``keep`` are (then None): ONE batched ``jax.device_get`` for
+        its emissions — the wait for that tick to end on the device, and
+        the result's way back — then tokens to requests and streams, stop
+        rules, retirement, prefill completion, gauges.  Returns tokens
+        emitted."""
+        with self._lock:
+            if len(self._inflight) <= keep:
+                return None
+            rec = self._inflight.popleft()
+            lagged = bool(self._inflight)
+        t_fetch = time.monotonic()
+        with obs_trace.span("engine-fetch", tick=rec.no):
+            handles = (rec.toks, rec.logps) + (
+                rec.spec[:2] if rec.spec else ())
+            fetched = jax.device_get(handles)
         now = time.monotonic()
-        with obs_trace.span("engine-apply"):
+        with obs_trace.span("engine-apply", tick=rec.no):
             with self._lock:
-                dt = now - t_tick  # feeds Retry-After/shed drain estimates
+                # one tick of device time: from the end of the fetch
+                # before (when the device took this tick up) or, with
+                # nothing in flight then, from this tick's launch.
+                # Feeds Retry-After/shed drain estimates
+                dt = now - max(rec.t0, self._last_fetch_t)
+                self._last_fetch_t = now
                 self._ema_tick_s = (dt if self._ema_tick_s is None
                                     else 0.8 * self._ema_tick_s + 0.2 * dt)
-                if not self._dirty:
-                    # steady state: the tick already advanced the device mirror
-                    self._dev_state = (bt, new_pos, next_tok, keys, new_steps,
-                                       temp, tk, tp)
                 self.ticks += 1
-                if self.spec_k:
+                if rec.spec:
                     emitted = self._apply_spec_locked(
-                        active, k_eff, emit_np, lp_np, acc_np, m_np, now)
+                        rec.active, rec.spec[2], *fetched, now)
                 else:
-                    emitted = self._apply_plain_locked(
-                        active, next_np, logp_np, now)
-                self._apply_ragged_prefill_locked(
-                    spans, tick_s=dt, work_rows=n_pre + len(active))
+                    emitted = self._apply_chain_locked(
+                        rec, fetched[0][None], fetched[1][None], now)
+                self._finish_prefill_locked(
+                    rec.spans, dt,
+                    sum(end - start for _, start, end in rec.spans)
+                    + len(rec.active))
                 self.ticked_tokens += emitted
-                self._note_launches_locked(
-                    1 + did_lp, self.prefill_tokens_computed - pre0)
                 if obs_registry.publishing():
                     self._m_ticks.inc()
                     self._m_tokens.inc(emitted)
+                    self._m_apply_lag["1" if lagged else "0"].inc()
+                    self._m_tick_kind[
+                        "prefill" if rec.n_bucket else "decode"].inc()
+                    self._m_inflight.set(len(self._inflight))
                     self._m_active.set(
                         sum(r is not None and r._phase == "decode"
                             for r in self._slots))
@@ -2941,24 +3132,13 @@ class ContinuousBatchingEngine:
             # interpreter lock, and the wait to get it back from the
             # handler threads that apply has just woken (one a stream, each
             # writing its chunk) is the largest single piece of host time
-            # between two launches: it is apply's doing, so it is counted
-            # as apply, in the span and in the phase histogram alike.
-            del pre_args, bt, pos, toks, keys, steps, temp, tk, tp
-            del next_tok, new_pos, new_steps
-            if self.spec_k:
-                del emit, emit_lp, acc, cnt
-            else:
-                del logp
-        t_applied = time.monotonic()
-        self._note_host_gap(gap)
+            # in a step: it is apply's doing, so it is counted as apply, in
+            # the span and in the phase histogram alike.
+            del rec, handles
         if obs_registry.publishing():
-            for ph, sec in (("admit", admit_s), ("plan", t_tick - t_plan),
-                            ("launch", t_launched - t_tick),
-                            ("fetch", now - t_launched),
-                            ("apply", t_applied - now)):
-                self._m_phase[ph].observe(sec)
-            self._m_tick_kind["prefill" if n_bucket else "decode"].inc()
-        return len(active) + (1 if spans else 0) + did_lp
+            self._m_phase["fetch"].observe(now - t_fetch)
+            self._m_phase["apply"].observe(time.monotonic() - now)
+        return emitted
 
     def run_until_idle(self) -> None:
         """Drive ticks on the calling thread until queue and slots drain.
@@ -3025,7 +3205,11 @@ class ContinuousBatchingEngine:
                 # brackets whole steps, started and stopped here
                 self.profile_trigger.maybe_start(self.ticks)
                 try:
-                    if self.step():
+                    applied = self.ticks
+                    self.step()
+                    # a window of N ticks closes when N have landed (a
+                    # step lands the tick before the one it launches)
+                    for _ in range(self.ticks - applied):
                         self.profile_trigger.step_done()
                 except Exception as e:  # noqa: BLE001 — boundary: the
                     # scheduler thread must outlive a failed step, or every

@@ -103,9 +103,17 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
     and ``spec_k == 0`` (no draft args, plain per-slot sampling)::
 
         (params, pool_k, pool_v, block_tables, positions, tokens,
-         req_keys, steps, temperature, top_k, top_p
+         req_keys, steps, temperature, top_k, top_p, carry_tok, carried
          [, pre_tok, pre_pos, pre_tables, pre_index, pre_hor])
         -> (pool_k, pool_v, next_tok, logp, new_pos, new_steps)
+
+    ``carry_tok`` / ``carried`` (``[b]`` int32 / bool) feed a row its
+    token device to device: the engine launches this tick before it has
+    fetched the one in flight, whose ``next_tok`` is the input of every
+    row that tick was sampling for (``carried``); the uploaded ``tokens``
+    hold the rest.  The speculative tick has no such operands: its
+    positions advance by the accepted count, which only the fetch tells
+    the host, so it is never launched ahead.
 
     The ``pre_*`` operands exist iff ``prefill_rows > 0``: ``pre_tok`` /
     ``pre_pos`` / ``pre_hor`` are ``[prefill_rows]``; block tables come
@@ -275,11 +283,13 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     def tick(params, pool_k, pool_v, block_tables, positions, tokens,
              req_keys, steps, temperature, top_k, top_p,
+             carry_tok, carried,
              pre_tok=None, pre_pos=None, pre_tables=None,
              pre_index=None, pre_hor=None):
         b = tokens.shape[0]
         W = block_tables.shape[1]
         null_tbl = jnp.zeros((1, W), block_tables.dtype)
+        tokens = jnp.where(carried, carry_tok, tokens)
         idx = 1 + jnp.arange(b, dtype=jnp.int32)
         hor = row_horizons(positions)
         if prefill_rows:

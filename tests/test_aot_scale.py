@@ -306,7 +306,8 @@ def test_aot_engine_programs_compile(tp):
             S((slots,), jnp.int32), S((slots,), jnp.int32),
             S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
             S((slots,), jnp.float32), S((slots,), jnp.int32),
-            S((slots,), jnp.float32), S((pre,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), S((pre,), jnp.int32),
             S((pre,), jnp.int32), S((2, width), jnp.int32),
             S((pre,), jnp.int32), S((pre,), jnp.int32),
             donate_argnums=(1, 2))

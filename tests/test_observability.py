@@ -940,24 +940,39 @@ def test_capture_holds_the_engine_step_tree(traced_serving_run):
               if any(c[0] == "engine-launch" for c in _children(spans, x))]
     assert len(ticked) >= 3, [x[0] for x in spans]
     assert len({x[1] for x in steps}) == 1, "one scheduler thread"
+    # a step names the tick it lands (the ticks applied before it began)
+    # and launches the one after: it runs one tick ahead of the host
     ticks = [x[4]["tick"] for x in ticked]
-    assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+    assert ticks == sorted(ticks)
+    lagged = 0
     for step in ticked:
         kids = sorted(_children(spans, step), key=lambda c: c[2])
         order = [c[0] for c in kids]
-        assert order == ["engine-admit", "engine-plan", "engine-ragged-tick",
-                         "engine-launch", "engine-fetch", "engine-apply"]
-        by = {c[0]: c for c in kids}
-        tick = by["engine-ragged-tick"]
-        for inner in ("engine-launch", "engine-fetch"):
-            assert tick[2] <= by[inner][2] and by[inner][3] <= tick[3]
+        assert order[:4] == ["engine-admit", "engine-plan",
+                             "engine-ragged-tick", "engine-launch"]
+        # then nothing (no tick was in flight) or the fetch and apply of
+        # the tick before, beside the one just launched
+        assert order[4:] in ([], ["engine-fetch", "engine-apply"]), order
+        tick, launch = kids[2], kids[3]
+        assert tick[2] <= launch[2] and launch[3] <= tick[3]
+        assert launch[4]["tick"] == step[4]["tick"] + len(order[4:]) // 2
+        for landed in kids[4:]:
+            lagged += landed[0] == "engine-fetch"
+            assert landed[2] >= tick[3]
+            assert landed[4]["tick"] == launch[4]["tick"] - 1
         # siblings in order, none overlapping
-        seq = [by[n] for n in ("engine-admit", "engine-plan",
-                               "engine-launch", "engine-fetch",
-                               "engine-apply")]
+        seq = kids[:2] + kids[3:]
         assert all(a[3] <= b[2] for a, b in zip(seq, seq[1:]))
+    assert lagged >= 3, "no tick was launched before the last was fetched"
     launches = [c for x in ticked for c in _children(spans, x)
                 if c[0] == "engine-launch"]
+    numbers = [c[4]["tick"] for c in launches]
+    assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    # a step that launches nothing lands what is in flight, fetch then apply
+    landing = [x for x in steps if x not in ticked and _children(spans, x)]
+    assert landing and all(
+        [c[0] for c in sorted(_children(spans, x), key=lambda c: c[2])][-2:]
+        == ["engine-fetch", "engine-apply"] for x in landing)
     # each prompt rides one tick as a bucketed prefill chunk; the other
     # ticks are decode-only, one live row
     pre = [c[4] for c in launches if c[4]["prefill_rows"] > 0]
@@ -995,7 +1010,7 @@ def test_ring_holds_the_same_spans(traced_serving_run):
         assert ring[name] == cap[name] > 0, (name, ring[name], cap[name])
     launch = [e for e in traced_serving_run["ring"]
               if e[1] == "engine-launch"]
-    assert set(launch[0][5]) == {"prefill_rows", "prefill_tokens",
+    assert set(launch[0][5]) == {"tick", "prefill_rows", "prefill_tokens",
                                  "decode_rows"}
 
 

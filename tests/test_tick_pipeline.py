@@ -185,12 +185,21 @@ def test_parity_preempt_mid_pipeline(models):
         return ([req.result(timeout=120), other.result(timeout=120)],
                 preempted_in_flight)
 
+    from tests.parity import assert_same_generations
+
     base, _ = run(0, 10 ** 9)  # never preempted
     in_flight_seen = 0
     for depth in (0, 1, 2):
         for cut in (3, 5):
             got, inflight = run(depth, cut)
-            _assert_bitwise(base, got, f"depth {depth} preempt@{cut}")
+            # tokens bit for bit; log-probs to the ulps of tests/parity.py:
+            # the resume's re-prefill puts the OTHER request's row into a
+            # prompt-carrying tick, another program than the base run's
+            # decode-only tick for the same token (which token that is
+            # follows the preemption's tick, so bit equality was a
+            # coincidence of the cut)
+            assert_same_generations(base, got,
+                                    f"depth {depth} preempt@{cut}")
             in_flight_seen += inflight
     assert in_flight_seen, (
         "no preemption ever landed with a chain in flight — the lag "
