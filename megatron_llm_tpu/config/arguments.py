@@ -116,8 +116,73 @@ class ModelConfig:
     # Switch-style load-balance aux loss and ST-MoE router z-loss weights
     moe_aux_loss_coeff: float = 0.01
     moe_z_loss_coeff: float = 0.0
+    # the router's options, data of the family (models/moe.py `route`):
+    # how a token's scores are made ('softmax' over the experts, Mixtral;
+    # 'sigmoid' of each logit, the DeepSeek-V3 lineage) ...
+    moe_score_func: str = "softmax"
+    # ... whether a learned per-expert bias is added to the scores for the
+    # top-k SELECTION only, never to the weights (`e_score_correction_bias`
+    # of `topk_method: noaux_tc`; the leaf `moe/router/bias`) ...
+    moe_selection_bias: bool = False
+    # ... and what multiplies the (renormalised) weights of the chosen
+    moe_routed_scaling_factor: float = 1.0
+    # width of one expert's FFN; None = ffn_hidden_size (Mixtral).  With a
+    # value, ffn_hidden_size stays the width of the dense layers' MLP
+    moe_ffn_hidden_size: Optional[int] = None
+    # shared experts: a dense MLP of moe_shared_experts x the expert width
+    # that every token takes, added to the routed sum
+    moe_shared_experts: int = 0
+    # dense layers that run BEFORE the `num_layers` layers of the scanned
+    # stack (DeepSeek's `first_k_dense_replace`: a published
+    # `num_hidden_layers` is dense_prefix_layers + num_layers).  They are a
+    # stack of their own, `params["dense_layers"]`, so that the scanned
+    # stack keeps one parameter shape; 0 = a uniform model, unchanged
+    dense_prefix_layers: int = 0
+    # --- latent attention (MLA, the DeepSeek-V2/V3 lineage) ---
+    # 'mha' (MHA/GQA/MQA over kv_channels) | 'mla': low-rank queries
+    # (q_lora_rank, with a norm), a joint latent + rope-key down-projection
+    # (kv_lora_rank + qk_rope_head_dim values a token: ALL the cache holds),
+    # decoupled RoPE on qk_rope_head_dim of the qk_nope_head_dim +
+    # qk_rope_head_dim query dims, values of v_head_dim
+    attention_type: str = "mha"
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+
+    @property
+    def depth(self) -> int:
+        """Layers a token passes: the dense prefix and the scanned stack
+        (the KV pool's layer axis)."""
+        return self.dense_prefix_layers + self.num_layers
+
+    @property
+    def mla(self) -> bool:
+        return self.attention_type == "mla"
+
+    @property
+    def latent_cache_width(self) -> int:
+        """Values one cached token of an MLA layer holds: the normed
+        latent and the rotated shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     def finalize(self) -> None:
+        assert self.attention_type in ("mha", "mla"), (
+            f"unknown attention_type {self.attention_type!r}")
+        if self.mla:
+            missing = [k for k in ("q_lora_rank", "kv_lora_rank",
+                                   "qk_nope_head_dim", "qk_rope_head_dim",
+                                   "v_head_dim") if not getattr(self, k)]
+            assert not missing, f"attention_type 'mla' needs {missing}"
+            assert self.qk_rope_head_dim % 2 == 0, (
+                "qk_rope_head_dim must be even (RoPE rotates pairs)")
+            assert self.sliding_window_size is None, (
+                "latent attention has no sliding window")
+            # the rotary table is made for this width; K/V heads do not
+            # exist (one latent row serves every query head)
+            self.kv_channels = self.qk_rope_head_dim
+            self.num_attention_heads_kv = 1
         if self.kv_channels is None:
             assert self.hidden_size % self.num_attention_heads == 0, (
                 f"hidden_size {self.hidden_size} not divisible by "
@@ -626,6 +691,9 @@ class Config:
             assert self.model.moe_router_type in ("topk", "expert_choice"), (
                 f"unknown moe_router_type {self.model.moe_router_type!r}"
             )
+            assert self.model.moe_score_func in ("softmax", "sigmoid"), (
+                f"unknown moe_score_func {self.model.moe_score_func!r}"
+            )
             if self.model.moe_router_type == "expert_choice":
                 # EC routing compares tokens across positions within a
                 # routing group, leaking future-token information into the
@@ -651,7 +719,7 @@ class Config:
                 )
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
-                "mistral", "mixtral",
+                "mistral", "mixtral", "joyai",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -661,6 +729,14 @@ class Config:
             assert self.parallel.expert_parallel_size == 1, (
                 "expert_parallel_size > 1 requires num_experts (MoE)"
             )
+            assert not self.model.moe_shared_experts, (
+                "moe_shared_experts requires num_experts (MoE)")
+        if self.model.dense_prefix_layers:
+            assert self.model.num_experts is not None, (
+                "dense_prefix_layers precede expert layers: set num_experts")
+            assert self.parallel.pipeline_model_parallel_size == 1, (
+                "a dense prefix is a stack of its own; pipeline stages "
+                "cut one uniform stack")
         return self
 
 
@@ -767,6 +843,25 @@ ARCH_DEFAULTS = {
         moe_router_topk=2,
         rope_theta=1_000_000.0,
     ),
+    # JoyAI-LLM-Flash (beyond-reference; the DeepSeek-V3 block): latent
+    # attention, one leading dense layer, then layers of 256 routed experts
+    # top-8 by a bias-corrected sigmoid router plus one shared expert
+    "joyai": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=32_000_000.0,
+        attention_type="mla",
+        moe_score_func="sigmoid",
+        moe_selection_bias=True,
+        moe_normalize_gates=True,
+        moe_routed_scaling_factor=2.5,
+        moe_shared_experts=1,
+        dense_prefix_layers=1,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -814,6 +909,16 @@ MODEL_SIZES = {
                          num_attention_heads_kv=8, ffn_hidden_size=14336,
                          max_position_embeddings=32768, num_experts=8,
                          moe_router_topk=2),
+    # 40 published layers = 1 dense + 39 of experts (dense_prefix_layers
+    # comes from the family); no MTP layer on the serving path
+    "joyai-llm-flash": dict(num_layers=39, hidden_size=2048,
+                            num_attention_heads=32, ffn_hidden_size=7168,
+                            max_position_embeddings=131072,
+                            q_lora_rank=1536, kv_lora_rank=512,
+                            qk_nope_head_dim=128, qk_rope_head_dim=64,
+                            v_head_dim=128, num_experts=256,
+                            moe_router_topk=8, moe_ffn_hidden_size=768,
+                            vocab_size=129280),
 }
 
 
@@ -922,7 +1027,7 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     )
     parser.add_argument("--model_name", type=str, default=None,
                         help="gpt|llama|llama2|codellama|llama3|falcon|"
-                             "mistral|mixtral|qwen2|bert|t5 or a canonical "
+                             "mistral|mixtral|qwen2|joyai|bert|t5 or a canonical "
                              "size like llama2-7b / llama3-8b")
     seen = set()
     for group_name, group_cls in _GROUPS.items():

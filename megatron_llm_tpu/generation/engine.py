@@ -162,6 +162,43 @@ class EngineOverloaded(RuntimeError):
         self.info = info or {}
 
 
+def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
+                        draft: bool = False, pipeline_depth: int = 0,
+                        handoff: bool = False) -> None:
+    """What does not carry a one-leaf latent pool (MLA) yet says so at
+    start-up, in a sentence, instead of failing inside a compile.  The
+    prefix trie, copy-on-write, preemption and chunked prefill DO carry it:
+    they move page ids, and a page copy maps over whatever leaves exist."""
+    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
+    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
+    why = None
+    if kv_dtype != "bf16":
+        why = (f"--kv_dtype {kv_dtype}: page scales are kept per KV head, "
+               "and a latent row has none")
+    elif tp > 1:
+        why = (f"tensor-parallel serving (tp {tp}): the pool shards over "
+               "KV heads, and a latent row has none")
+    elif pp > 1:
+        why = (f"pipeline-parallel serving (pp {pp}): its stages scan "
+               "per-layer K/V slices, and the latent pool is one array that "
+               "every layer updates in place")
+    elif draft:
+        why = ("--spec_k: the verify tick and the draft cache are built "
+               "for a K/V pair")
+    elif pipeline_depth:
+        why = ("--tick_pipeline_depth: the chained tick carries a K/V "
+               "pair")
+    elif handoff:
+        why = ("the cross-replica KV handoff: its wire format names a K "
+               "and a V leaf")
+    if why:
+        raise ValueError(
+            "latent attention (attention_type 'mla') keeps ONE latent row a "
+            f"token in a pool without a value leaf, which {why} does not "
+            "carry yet. Serve this model on one chip with --kv_dtype bf16, "
+            "--spec_k 0 and --tick_pipeline_depth 0.")
+
+
 class PagedKVPool:
     """Device page pool + host refcounting allocator.
 
@@ -202,8 +239,24 @@ class PagedKVPool:
         # per-head scales, ops/kv_quant.py) for ~2x pages per chip.
         self.kv_dtype = kv_dtype
         self.compute_dtype = dtype
-        shape = (m.num_layers, num_pages, page_size,
-                 m.num_attention_heads_kv, m.kv_channels)
+        # latent attention (MLA): ONE leaf, a row of [normed latent |
+        # rotated rope key] a token and layer, no KV-head axis and NO value
+        # pool (``v`` is None; the kernel reads the key row as the value).
+        # The row is stored in whole 128-lane rows (576 -> 640 values):
+        # that is what the array's tiles in HBM hold whatever its logical
+        # width, and the kernel's page copies move whole lanes, so a
+        # narrower leaf would be padded by a copy of the layer's pool
+        # before every call.  The lanes past ``latent_cache_width`` are
+        # zeros nobody reads (64 of 640 at 576: 11% of the leaf).
+        self.latent = bool(m.mla)
+        if self.latent:
+            refuse_latent_cache(kv_dtype=kv_dtype, mesh=mesh,
+                                draft=draft_cfg is not None)
+            width = -(-m.latent_cache_width // 128) * 128
+            shape = (m.depth, num_pages, page_size, width)
+        else:
+            shape = (m.depth, num_pages, page_size,
+                     m.num_attention_heads_kv, m.kv_channels)
 
         def _make(shp):
             return kv_quant.make_pool(shp, kv_dtype, dtype)
@@ -248,7 +301,7 @@ class PagedKVPool:
                                 if mesh is not None else None)
             self._scale_sharding = self.kv_sharding
             self.k = _make(shape)
-            self.v = _make(shape)
+            self.v = None if self.latent else _make(shape)
         self.draft_cfg = draft_cfg
         self.draft_k = self.draft_v = None
         if draft_cfg is not None:
@@ -304,6 +357,8 @@ class PagedKVPool:
         if kv_quant.is_quantized(self.k):
             return ("kv", self.kv_dtype, str(self.k.q.dtype),
                     str(self.k.scale.dtype))
+        if self.latent:
+            return ("kv", "latent", str(self.k.dtype), self.k.shape[-1])
         return ("kv", self.kv_dtype, str(self.k.dtype))
 
     @property
@@ -319,7 +374,9 @@ class PagedKVPool:
         """Device bytes of the KV value storage, target + draft caches —
         the fixed budget the capacity bench holds constant while the
         kv_dtype varies (published as ``mlt_engine_kv_pool_bytes``)."""
-        n = kv_quant.pool_nbytes(self.k) + kv_quant.pool_nbytes(self.v)
+        n = kv_quant.pool_nbytes(self.k)
+        if self.v is not None:       # a latent pool has no value leaf
+            n += kv_quant.pool_nbytes(self.v)
         if self.draft_k is not None:
             n += (kv_quant.pool_nbytes(self.draft_k)
                   + kv_quant.pool_nbytes(self.draft_v))
@@ -334,7 +391,9 @@ class PagedKVPool:
 
     def kv_scale_bytes(self) -> int:
         """Per-page scale overhead bytes (0 for bf16)."""
-        n = kv_quant.scale_nbytes(self.k) + kv_quant.scale_nbytes(self.v)
+        n = kv_quant.scale_nbytes(self.k)
+        if self.v is not None:
+            n += kv_quant.scale_nbytes(self.v)
         if self.draft_k is not None:
             n += (kv_quant.scale_nbytes(self.draft_k)
                   + kv_quant.scale_nbytes(self.draft_v))
@@ -461,7 +520,8 @@ class PagedKVPool:
             return pool.at[:, ids].set(jnp.asarray(leaves[name]))
 
         self.k = _install(self.k, "k")
-        self.v = _install(self.v, "v")
+        if self.v is not None:
+            self.v = _install(self.v, "v")
         if self.draft_k is not None:
             self.draft_k = _install(self.draft_k, "draft_k")
             self.draft_v = _install(self.draft_v, "draft_v")
@@ -691,6 +751,9 @@ class _Launched(NamedTuple):
     # a verify tick's (accepted, counts, k_eff); ``toks``/``logps`` then
     # hold the emitted blocks ``[b, K+1]``
     spec: Optional[Tuple] = None
+    # an expert model's tick: what its router did, ``[2]`` on the device
+    # (assignments, distinct experts touched; generation/ragged.py)
+    moe: object = None
 
 
 class ContinuousBatchingEngine:
@@ -717,6 +780,16 @@ class ContinuousBatchingEngine:
                  mesh: Optional[Mesh] = None):
         inf = cfg.inference
         self.cfg = cfg
+        if cfg.model.mla:
+            # before anything is placed or resolved: a sentence, not a
+            # sharding error from the middle of start-up
+            pick = lambda given, name: (  # noqa: E731
+                given if given is not None else getattr(inf, name))
+            refuse_latent_cache(
+                kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
+                draft=bool(pick(spec_k, "spec_k")),
+                pipeline_depth=int(pick(tick_pipeline_depth,
+                                        "tick_pipeline_depth")))
         if inf.int8_weights:
             # same decode-weight quantization contract as api.InferenceEngine
             from megatron_llm_tpu.ops.quant import quantize_layer_weights_int8
@@ -987,6 +1060,10 @@ class ContinuousBatchingEngine:
         # number the fixed-pool-bytes capacity bench and /health report
         self.peak_active_slots = 0  # guarded by _lock
         self.prefill_tokens_computed = 0  # rows pushed through prefill
+        # what the router of an expert model did, summed over ticks and
+        # expert layers (mlt_engine_moe_*; zero for dense models)
+        self.moe_assignments = 0
+        self.moe_experts_touched = 0
         self.prefix_hit_tokens = 0
         self.prefix_miss_tokens = 0
         self.cow_copies = 0
@@ -1083,6 +1160,15 @@ class ContinuousBatchingEngine:
             help="ticks that prefilled more prompt tokens than one "
                  "prefill_chunk (over mlt_engine_tick_kind_total"
                  "{kind=\"prefill\"}: how often the pacing packs a tick)")
+        self._m_moe_assignments = reg.counter(
+            "mlt_engine_moe_assignments_total",
+            help="router assignments (rows x topk, every row a tick ran, "
+                 "summed over the expert layers); 0 for dense models")
+        self._m_moe_touched = reg.counter(
+            "mlt_engine_moe_experts_touched_total",
+            help="distinct experts that received a row, summed over ticks "
+                 "and expert layers: with the assignments, the rows an "
+                 "expert's GEMM ran on")
         self._m_preempt = reg.counter(
             "mlt_engine_preemptions_total",
             help="decoding requests preempted by page release")
@@ -1421,17 +1507,19 @@ class ContinuousBatchingEngine:
 
         ovl = self._overlap
         ppc = self._ppc
+        latent = self.pool.latent    # one pool leaf, pool_v is None
 
         def chunk(params, tokens, start, bt, pool_k, pool_v, targets):
             with tp_overlap_mod.activate(ovl), pp_serve_mod.activate(ppc):
-                out, (pool_k, pool_v) = model_forward(
+                out, pools = model_forward(
                     cfg, params, tokens,
                     position_ids=start[:, None] + jnp.arange(rows)[None, :],
                     rope_cache=make_rope_cache(cfg),
-                    kv_caches=(pool_k, pool_v),
+                    kv_caches=pool_k if latent else (pool_k, pool_v),
                     paged=PagedState(bt, start),
                     logits_postprocess=True,
                 )
+            pool_k, pool_v = (pools, None) if latent else pools
             lp = gen._gather_token_log_probs(out, targets)
             return pool_k, pool_v, lp[0]
 
@@ -3013,6 +3101,7 @@ class ContinuousBatchingEngine:
                     self._asarray(pre_index[:n_bucket]),
                     self._asarray(pre_hor[:n_bucket]))
                 tick_fn = self._ragged_tick(n_bucket)
+                moe = ()
                 if self.spec_k:
                     (self.pool.k, self.pool.v, self.pool.draft_k,
                      self.pool.draft_v, out_tok, out_lp, acc, cnt,
@@ -3026,7 +3115,7 @@ class ContinuousBatchingEngine:
                     del acc, cnt
                 else:
                     (self.pool.k, self.pool.v, next_tok, out_lp,
-                     new_pos, new_steps) = tick_fn(
+                     new_pos, new_steps, *moe) = tick_fn(
                         self.params, self.pool.k, self.pool.v,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         *carry, *pre_args)
@@ -3035,7 +3124,8 @@ class ContinuousBatchingEngine:
                 with self._lock:
                     self._inflight.append(_Launched(
                         active, reqs, out_tok, out_lp, t_tick, epochs,
-                        no=no, spans=spans, n_bucket=n_bucket, spec=spec))
+                        no=no, spans=spans, n_bucket=n_bucket, spec=spec,
+                        moe=moe[0] if moe else None))
                     self._advance_fill_locked(spans)
                     if not self._dirty:
                         # steady state: the tick advanced the device mirror
@@ -3049,7 +3139,7 @@ class ContinuousBatchingEngine:
                 # freeing a device array can release the interpreter lock
                 del pre_args, bt, pos, toks, keys, steps, temp, tk, tp
                 del carry, prev, out_tok, out_lp, next_tok, new_pos
-                del new_steps, spec
+                del new_steps, spec, moe
         t_launched = time.monotonic()
         self._note_host_gap(gap)
         if obs_registry.publishing():
@@ -3089,7 +3179,22 @@ class ContinuousBatchingEngine:
         with obs_trace.span("engine-fetch", tick=rec.no):
             handles = (rec.toks, rec.logps) + (
                 rec.spec[:2] if rec.spec else ())
+            if rec.moe is not None:      # rides the same fetch
+                handles += (rec.moe,)
             fetched = jax.device_get(handles)
+            if rec.moe is not None:
+                *fetched, moe_stats = fetched
+                rows, touched = int(moe_stats[0]), int(moe_stats[1])
+                self.moe_assignments += rows
+                self.moe_experts_touched += touched
+                # the same two numbers where a capture can lay them beside
+                # this tick's device time (engine-launch has its ``tick=``)
+                with obs_trace.span("engine-moe", tick=rec.no,
+                                    assignments=rows, touched=touched):
+                    pass
+                if obs_registry.publishing():
+                    self._m_moe_assignments.inc(rows)
+                    self._m_moe_touched.inc(touched)
         now = time.monotonic()
         with obs_trace.span("engine-apply", tick=rec.no):
             with self._lock:
@@ -3399,6 +3504,8 @@ class ContinuousBatchingEngine:
 
         Returns ``(blob, info)`` — ``info`` has ``tokens`` / ``pages``
         / ``bytes`` / ``hit_tokens`` for the migration receipt."""
+        if self.pool.latent:
+            refuse_latent_cache(handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         tok = self.tokenizer
@@ -3450,6 +3557,8 @@ class ContinuousBatchingEngine:
         parked in the prefix cache (e.g. a preempted request's finished
         pages).  Returns ``(blob, n_pages)``; ``n_pages`` may be 0 when
         nothing is cached."""
+        if self.pool.latent:
+            refuse_latent_cache(handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         if self.cache is None:
@@ -3481,6 +3590,8 @@ class ContinuousBatchingEngine:
         so COW/refcount/eviction invariants hold unchanged.  Raises
         :class:`EngineOverloaded` (→ 503 + Retry-After) when the pool
         cannot hold the pages.  Returns the import receipt."""
+        if self.pool.latent:
+            refuse_latent_cache(handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         payload = wire.decode_pages(blob)

@@ -105,7 +105,15 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
         (params, pool_k, pool_v, block_tables, positions, tokens,
          req_keys, steps, temperature, top_k, top_p, carry_tok, carried
          [, pre_tok, pre_pos, pre_tables, pre_index, pre_hor])
-        -> (pool_k, pool_v, next_tok, logp, new_pos, new_steps)
+        -> (pool_k, pool_v, next_tok, logp, new_pos, new_steps
+            [, moe_stats])
+
+    ``moe_stats`` exists iff the model has experts: ``[2]`` float32, the
+    router's assignments (rows x topk, every row the program ran, dead
+    padding rows too: the grouped GEMM runs them) and the distinct experts
+    that received a row, both summed over the expert layers.  It rides
+    the tick's one fetch to the engine's ``mlt_engine_moe_*`` counters.
+    A latent-attention model's ``pool_v`` is None.
 
     ``carry_tok`` / ``carried`` (``[b]`` int32 / bool) feed a row its
     token device to device: the engine launches this tick before it has
@@ -145,19 +153,25 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
          else ("decode-fwd" if tp == 1 else f"decode-fwd-tp{tp}"))
     scope_d = "draft-fwd" if tp == 1 else f"draft-fwd-tp{tp}"
 
+    moe = cfg.model.num_experts is not None
+    latent = cfg.model.mla     # ONE pool leaf; pool_v is None throughout
+
     def target_forward(params, pool_k, pool_v, tbl, idx, pos, tok, hor):
         """ONE target forward over the full ragged batch — the single
         attention launch of the tick.  ``tbl`` is the tick's compressed
-        unique-table set, ``idx`` each row's table."""
+        unique-table set, ``idx`` each row's table.  Returns the router's
+        aux vector as well (models/moe.py)."""
         with jax.named_scope(scope_t):
-            logits, (pool_k, pool_v) = model_forward(
+            logits, pools, aux = model_forward(
                 cfg, params, tok[:, None],
                 position_ids=pos[:, None],
                 rope_cache=make_rope_cache(cfg),
-                kv_caches=(pool_k, pool_v),
+                kv_caches=pool_k if latent else (pool_k, pool_v),
                 paged=PagedState(tbl, pos, hor, idx),
+                return_aux=True,
             )
-        return logits[:, 0], pool_k, pool_v
+        pool_k, pool_v = (pools, None) if latent else pools
+        return logits[:, 0], pool_k, pool_v, aux
 
     def spec_tick(params, draft_params, pool_k, pool_v, draft_k, draft_v,
                   block_tables, positions, tokens, req_keys, steps,
@@ -249,7 +263,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_tok, all_pos, all_idx, all_hor = (
                 flat_tok, flat_pos, flat_idx, flat_hor)
             all_tbl = jnp.concatenate([null_tbl, block_tables])
-        out, pool_k, pool_v = target_forward(
+        out, pool_k, pool_v, _ = target_forward(
             params, pool_k, pool_v, all_tbl, all_idx, all_pos, all_tok,
             all_hor)
         t_logits = out[: b * S].reshape(b, S, -1)      # [b, K+1, v_padded]
@@ -303,7 +317,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_tok, all_pos, all_idx, all_hor = (
                 tokens, positions, idx, hor)
             all_tbl = jnp.concatenate([null_tbl, block_tables])
-        out, pool_k, pool_v = target_forward(
+        out, pool_k, pool_v, aux = target_forward(
             params, pool_k, pool_v, all_tbl, all_idx, all_pos, all_tok,
             all_hor)
         last = out[:b]
@@ -312,8 +326,8 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             keys, last, top_k=top_k, top_p=top_p,
             temperature=temperature, vocab_size=cfg.model.vocab_size)
         logp = gen._gather_token_log_probs(last, next_tok)
-        return (pool_k, pool_v, next_tok, logp,
-                positions + 1, steps + 1)
+        res = (pool_k, pool_v, next_tok, logp, positions + 1, steps + 1)
+        return res + (aux[2:],) if moe else res
 
     base_fn = spec_tick if K else tick
     if ovl is None and ppc is None:

@@ -222,6 +222,12 @@ class MegatronServer:
             raise ValueError(
                 f"role must be 'unified', 'prefill' or 'decode', got {role!r}")
         self.role = role
+        if role != "unified" and getattr(
+                getattr(engine, "pool", None), "latent", False):
+            # a prefill or decode role exists to push and take KV pages
+            from megatron_llm_tpu.generation.engine import refuse_latent_cache
+
+            refuse_latent_cache(handoff=True)
 
     def handle_request(self, payload, trace_id: str = ""):
         """Core PUT /api logic; returns (status_code, response dict).

@@ -44,6 +44,18 @@ def validate_family(cfg: Config) -> Config:
         _check(m.use_rms_norm and m.glu_activation == "swiglu",
                "mixtral uses the llama block")
         _check(not m.use_bias, "mixtral has no biases")
+    elif name == "joyai":
+        _check(m.mla, "joyai requires attention_type 'mla'")
+        _check(m.num_experts is not None and m.num_experts > 1,
+               "joyai requires num_experts > 1")
+        _check(m.moe_score_func == "sigmoid" and m.moe_selection_bias,
+               "joyai routes by bias-corrected sigmoid scores")
+        _check(m.use_rms_norm and m.glu_activation == "swiglu",
+               "joyai uses RMSNorm and SwiGLU")
+        _check(not m.use_bias and not m.parallel_attn,
+               "joyai is a sequential block without biases")
+        _check(m.position_embedding_type == "rotary",
+               "joyai requires rotary embeddings")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

@@ -18,8 +18,10 @@ import jax
 import jax.numpy as jnp
 
 from megatron_llm_tpu.core import rng as rng_mod
+from megatron_llm_tpu.models.moe import zero_aux
 from megatron_llm_tpu.models.transformer import (
     init_stacked_layers,
+    layer_stacks,
     transformer_forward,
 )
 from megatron_llm_tpu.ops.cross_entropy import (
@@ -63,6 +65,12 @@ def init_model_params(cfg, key: jax.Array) -> Params:
         "layers": init_stacked_layers(cfg, k_layers),
         "final_norm": init_norm_params(h, m.use_rms_norm),
     }
+    if m.dense_prefix_layers:
+        # the leading dense layers: a stack of their own, so that the
+        # scanned stack keeps one parameter shape
+        params["dense_layers"] = init_stacked_layers(
+            cfg, jax.random.fold_in(k_layers, 1), m.dense_prefix_layers,
+            dense_ffn=True)
     if m.position_embedding_type == "absolute":
         params["embedding"]["position_embeddings"] = m.init_method_std * (
             jax.random.normal(k_pos, (m.max_position_embeddings, h), jnp.float32)
@@ -264,7 +272,7 @@ def model_forward(
 
     With ``labels``: returns per-token fp32 loss [b, s] (masked mean is the
     caller's job, matching the reference loss_func split). Without: logits.
-    Returns (output, new_kv_caches), or (output, new_kv_caches, moe_aux[2])
+    Returns (output, new_kv_caches), or (output, new_kv_caches, moe_aux[AUX_LEN])
     when ``return_aux`` (MoE router losses, models/moe.py).
     """
     hidden = embed_tokens(cfg, params, tokens, position_ids)
@@ -288,16 +296,22 @@ def model_forward(
             rope=rope_cache, position_ids=position_ids,
             kv_caches=kv_caches, paged=paged,
         )
-        moe_aux = jnp.zeros((2,), jnp.float32)
+        moe_aux = zero_aux()
     else:
-        hidden, new_caches, moe_aux = transformer_forward(
-            cfg, params["layers"], hidden,
-            rope=rope_cache, position_ids=position_ids, segment_ids=segment_ids,
-            token_idx=token_idx,
-            dropout_key=dropout_key, deterministic=deterministic,
-            kv_caches=kv_caches, cache_index=cache_index, paged=paged,
-            sp_constraint=sp_constraint,
-        )
+        new_caches, moe_aux = kv_caches, None
+        for stack, first_layer in layer_stacks(cfg, params):
+            # a dense prefix exists with latent attention only here, whose
+            # pool is one array handed from stack to stack
+            assert first_layer == 0 or kv_caches is None or cfg.model.mla
+            hidden, new_caches, aux = transformer_forward(
+                cfg, stack, hidden,
+                rope=rope_cache, position_ids=position_ids,
+                segment_ids=segment_ids, token_idx=token_idx,
+                dropout_key=dropout_key, deterministic=deterministic,
+                kv_caches=new_caches, cache_index=cache_index, paged=paged,
+                sp_constraint=sp_constraint, layer_offset=first_layer,
+            )
+            moe_aux = aux if moe_aux is None else moe_aux + aux
 
     hidden = norm(hidden, params["final_norm"], cfg.model.layernorm_epsilon,
                   cfg.model.use_rms_norm)

@@ -19,21 +19,45 @@ adds the capability TPU-first, in the GShard/Switch/Mixtral lineage:
   expert-sharded [G:dp, E:ep, C, h], and XLA emits the all-to-all over the
   ICI ring. TP composes: the per-expert FFN hidden axis shards over ``tp``
   exactly like the dense MLP (column- then row-parallel, parallel/tp.py).
-* **Capacity-based token dropping**: each expert processes at most
+* **Capacity-based token dropping** (the ``ep > 1`` and expert-choice
+  path only): each expert processes at most
   C = ceil(topk * T * capacity_factor / E) tokens per group; overflow tokens
   fall through to the residual stream (their combine weight is zero), which
   keeps every shape static for XLA.
+* **Dropless dispatch** (:func:`dropless_experts`; token-choice routing at
+  ``ep == 1``, trainer and serving engine alike): the ``T x topk``
+  assignments are sorted by expert, the sorted rows go through ONE grouped
+  GEMM for fc1 and one for fc2 (:func:`grouped_matmul`, group sizes = rows
+  an expert), and the weighted results are gathered back to their tokens.
+  ``T x topk`` is static, so every shape is; no token is dropped at any
+  load, no ``[G, T, E, C]`` one-hot exists (at 256 experts that tensor
+  would be the layer), and ``moe_capacity_factor`` / ``moe_group_size`` do
+  not apply.
+* **The router's options are data of the family** (:func:`route`):
+  softmax or sigmoid scores, a selection bias that picks the top-k and is
+  not part of the weight, renormalisation, a scaling factor; and a
+  **shared expert** every token takes (a dense MLP of the expert width).
 
 Parameter schema (per layer; stacked on a leading layer axis under scan):
 
-    {'router':  {'kernel': [h, E]}                        # fp32, replicated
-     'experts': {'fc1': {'kernel': [E, h, 2, ffn] | [E, h, ffn], 'bias'?},
-                 'fc2': {'kernel': [E, ffn, h], 'bias'?}}}
+    {'router':  {'kernel': [h, E], 'bias'?: [E]}          # fp32, replicated
+     'experts': {'fc1': {'kernel': [E, 2, h, ffn] | [E, h, ffn], 'bias'?},
+                 'fc2': {'kernel': [E, ffn, h], 'bias'?}},
+     'shared'?: {'fc1': {'kernel': [h, 2, S*ffn]}, 'fc2': {'kernel': [S*ffn, h]}}}
+
+``router.bias`` is the selection bias (``e_score_correction_bias``); ``ffn``
+is the expert width (``moe_ffn_hidden_size``, default ``ffn_hidden_size``).
+A GLU expert's fc1 holds its value half at ``[:, 0]`` and its gated half at
+``[:, 1]``, each a whole ``[h, ffn]`` matrix: the dense MLP's ``[h, 2, ffn]``
+with the chunk axis moved out, so that ``[E * 2, h, ffn]`` is a view of the
+stack and the grouped kernel reads it where it lies (as ``[E, h, 2, ffn]``
+the leaf is tiled in pairs and had to be laid out anew before every call:
+PERF.md, PR 31).  tp still shards the last axis.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,16 +85,28 @@ def moe_capacity_expert_choice(cfg, tokens_per_group: int) -> int:
     return min(max(cap, m.moe_min_capacity), tokens_per_group)
 
 
+# the per-layer aux vector: (load-balance loss, router z-loss) for the
+# trained loss, then what the router did, for the serving engine's counters
+# (generation/ragged.py): assignments made (rows x topk) and distinct
+# experts that received at least one row
+AUX_LEN = 4
+
+
+def expert_width(cfg) -> int:
+    m = cfg.model
+    return m.moe_ffn_hidden_size or m.ffn_hidden_size
+
+
 def init_moe_params(cfg, key: jax.Array) -> Params:
     m = cfg.model
-    h, f, e = m.hidden_size, m.ffn_hidden_size, m.num_experts
+    h, f, e = m.hidden_size, expert_width(cfg), m.num_experts
     glu = m.glu_activation is not None
     std = m.init_method_std
     out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
     kr, k1, k2 = jax.random.split(key, 3)
     # per-expert independent init: one key per expert, same distribution as
     # the dense MLP (transformer.init_layer_params)
-    fc1_shape = (e, h, 2, f) if glu else (e, h, f)
+    fc1_shape = (e, 2, h, f) if glu else (e, h, f)
     p: Params = {
         "router": {"kernel": std * jax.random.normal(kr, (h, e), jnp.float32)},
         "experts": {
@@ -82,6 +118,25 @@ def init_moe_params(cfg, key: jax.Array) -> Params:
         p["experts"]["fc1"]["bias"] = jnp.zeros((e, 2, f) if glu else (e, f),
                                                 jnp.float32)
         p["experts"]["fc2"]["bias"] = jnp.zeros((e, h), jnp.float32)
+    if m.moe_selection_bias:
+        # a trained checkpoint holds what the balance update left there: it
+        # moves by a fixed step while an expert is over- or under-loaded, so
+        # the values are small beside the scores (std 0.02 against a sigmoid
+        # score's ~0.2) and yet decide the top-k, whose neighbours lie
+        # ~0.003 apart.  Zeros would make every test and benchmark blind to
+        # a program that forgets the bias; large values would skew which
+        # experts a tick touches, which a balanced model does not
+        p["router"]["bias"] = 0.02 * jax.random.normal(
+            jax.random.fold_in(kr, 1), (e,), jnp.float32)
+    if m.moe_shared_experts:
+        fs = f * m.moe_shared_experts
+        ks1, ks2 = jax.random.split(jax.random.fold_in(key, 1))
+        p["shared"] = {
+            "fc1": {"kernel": std * jax.random.normal(
+                ks1, (h, 2, fs) if glu else (h, fs), jnp.float32)},
+            "fc2": {"kernel": out_std * jax.random.normal(
+                ks2, (fs, h), jnp.float32)},
+        }
     return p
 
 
@@ -115,6 +170,185 @@ def _router_z_loss(router_logits: jax.Array) -> jax.Array:
     return jnp.mean(jax.nn.logsumexp(router_logits, axis=-1) ** 2)
 
 
+def _aux(balance, z, rows_per_expert: jax.Array) -> jax.Array:
+    """The layer's aux vector [AUX_LEN] from its two losses and the rows
+    each expert received."""
+    return jnp.stack([
+        balance.astype(jnp.float32), z.astype(jnp.float32),
+        rows_per_expert.sum().astype(jnp.float32),
+        (rows_per_expert > 0).sum().astype(jnp.float32)])
+
+
+@jax.named_scope("router")
+def route(cfg, p_router: Params, x: jax.Array
+          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Token-choice routing of ``x`` [T, h], in float32 whatever the
+    compute dtype.  The family's options (config/arguments.py):
+
+    * ``moe_score_func``: ``softmax`` over the experts or ``sigmoid`` of
+      each logit;
+    * ``moe_selection_bias``: ``router.bias`` is added to the scores to
+      PICK the top-k (``e_score_correction_bias``); the weights are the
+      scores without it;
+    * ``moe_normalize_gates``: the chosen weights are divided by their sum
+      (+ 1e-20, as the DeepSeek-V3 modelling code has it);
+    * ``moe_routed_scaling_factor`` multiplies them.
+
+    Returns (experts [T, K] int32, weights [T, K] float32, rows an expert
+    received [E] float32, aux [AUX_LEN])."""
+    m = cfg.model
+    e_, k_ = m.num_experts, m.moe_router_topk
+    logits = x.astype(jnp.float32) @ p_router["kernel"].astype(jnp.float32)
+    if m.moe_score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    pick = scores
+    if "bias" in p_router:
+        pick = scores + p_router["bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(pick, k_)                        # [T, K]
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if m.moe_normalize_gates:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    w = w * m.moe_routed_scaling_factor
+    # rows an expert received: compares, no scatter ([T*K, E] booleans)
+    counts = (idx.reshape(-1, 1) == jnp.arange(e_)[None, :]).sum(
+        0).astype(jnp.float32)
+    # load-balance loss (Switch eq. 4 generalised to top-k): share of the
+    # assignments an expert received x its mean (normalised) score, x E
+    probs = scores / scores.sum(-1, keepdims=True)
+    balance = e_ * jnp.sum(counts / counts.sum() * probs.mean(0))
+    return idx, w, counts, _aux(balance, _router_z_loss(logits), counts)
+
+
+class StackedExperts(NamedTuple):
+    """``experts`` of every layer of a stack (leaves ``[L, E, ...]``) and
+    the layer whose experts are meant: what the serving tick hands the
+    dispatch instead of a scanned slice (models/transformer.py
+    ``transformer_forward``)."""
+
+    stack: Params
+    layer: jax.Array
+
+
+_GMM_ROWS = 128            # rows of one tile of the grouped kernel
+_GMM_WEIGHT_TILE = 3 << 20  # bytes of one expert-weight tile in VMEM
+
+
+def grouped_matmul(rows: jax.Array, kernel: jax.Array, counts: jax.Array,
+                   layer: Optional[jax.Array] = None,
+                   half: Optional[int] = None) -> jax.Array:
+    """``rows[start_e : start_e + counts[e]] @ W[e]`` for every expert
+    ``e``: ``rows`` [m, k] sorted by expert, ``counts`` [E] rows an expert.
+    ``kernel`` holds the ``W[e]`` [k, n] on its last two axes and, before
+    them, the expert axis, with a layer axis in front where ``layer`` says
+    which layer is meant (the serving tick's whole stack) and a GLU chunk
+    axis behind where ``half`` says which half: ``[(L,) E, (2,) k, n]``.
+
+    On a TPU target this is jax's grouped-matmul Pallas kernel (megablox
+    ``gmm``, differentiable), which visits (row tile, group) pairs and
+    streams each touched group's weights once, in tiles as large as fast
+    memory takes (the whole k, then as much of n as 3 MB holds): at the
+    serving tick's few rows an expert the layer is weight-streaming, and
+    small tiles pay a grid step per tile (PERF.md, PR 31: 4.1 ms a layer
+    against ``jax.lax.ragged_dot``'s 8.7 at 256 experts x 8 rows).  The
+    kernel is handed the WHOLE leaf as ``[groups, k, n]``, a view, with
+    rows for the groups meant and none for the others: a slice of the leaf
+    would be a copy of it.  Elsewhere (the CPU tests), and for widths off
+    the 128-lane grid, ``jax.lax.ragged_dot`` on the slice: the same sums."""
+    from megatron_llm_tpu.core.parallel_state import target_platform
+
+    m, (k, n) = rows.shape[0], kernel.shape[-2:]
+    pick = (() if layer is None else (layer,)) + (slice(None),) + (
+        () if half is None else (half,))
+    if target_platform() != "tpu" or k % 128 or n % 128:
+        return jax.lax.ragged_dot(rows, kernel[pick], counts)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    sizes = jnp.zeros(kernel.shape[:-2], counts.dtype).at[pick].set(counts)
+    pad = -m % _GMM_ROWS
+    if pad:   # whole row tiles; the padding rows belong to no group
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    tk = min(k, 2048)
+    tn = min(n, max(128, _GMM_WEIGHT_TILE // (tk * rows.dtype.itemsize)
+                    // 128 * 128))
+    out = gmm(rows, kernel.reshape(-1, k, n).astype(rows.dtype),
+              sizes.reshape(-1), preferred_element_type=rows.dtype,
+              tiling=(_GMM_ROWS, tk, tn))
+    return out[:m] if pad else out
+
+
+def _grouped_linear(p_lin: Params, rows: jax.Array, counts: jax.Array,
+                    row_expert: jax.Array, dt,
+                    layer: Optional[jax.Array] = None,
+                    half: Optional[int] = None) -> jax.Array:
+    """``rows`` [R, k] sorted by expert through that expert's kernel: one
+    grouped GEMM over the sorted rows, then the expert's int8 channel scale
+    and bias where the leaf has them.  ``layer`` / ``half`` as
+    :func:`grouped_matmul`."""
+    kernel, scale = _expert_kernel(p_lin, dt)
+    y = grouped_matmul(rows, kernel, counts, layer, half)
+
+    def per_row(leaf):          # [(L,) E, (2,) n] -> [R, n]
+        leaf = leaf if layer is None else leaf[layer]
+        leaf = leaf if half is None else leaf[:, half]
+        return leaf.astype(dt)[row_expert]
+
+    if scale is not None:   # int8 per-channel scale
+        y = y * per_row(scale)
+    if "bias" in p_lin:
+        y = y + per_row(p_lin["bias"])
+    return y
+
+
+def dropless_experts(cfg, experts, x: jax.Array, idx: jax.Array,
+                     w: jax.Array, counts: jax.Array) -> jax.Array:
+    """sum_k w[t, k] * E_{idx[t, k]}(x[t]) for ``x`` [T, h], with no
+    capacity: sort the T*K assignments by expert, run the sorted rows
+    through one grouped GEMM for each half of fc1 and one for fc2, and
+    gather each token's K results back (a gather through the inverse
+    permutation, not a scatter-add: deterministic, and no scatter on the
+    TPU).  ``experts`` is a layer's subtree or :class:`StackedExperts`."""
+    m = cfg.model
+    t_, k_ = idx.shape
+    dt = x.dtype
+    layer = None
+    if isinstance(experts, StackedExperts):
+        experts, layer = experts
+    with jax.named_scope("dispatch"):
+        flat = idx.reshape(t_ * k_)
+        order = jnp.argsort(flat)                 # stable: rows by expert
+        row_expert = flat[order]
+        rows = x[order // k_]                     # [T*K, h]
+        counts = counts.astype(jnp.int32)
+    with jax.named_scope("expert_gemm"):
+        def linear(name, rows, half=None):
+            return _grouped_linear(experts[name], rows, counts, row_expert,
+                                   dt, layer, half)
+
+        if m.glu_activation is not None:
+            act = GLU_BASE_ACTIVATIONS[m.glu_activation]
+            inter = linear("fc1", rows, 0) * act(linear("fc1", rows, 1))
+        else:
+            inter = get_mlp_activation(None, m.activation)(
+                linear("fc1", rows))
+        out = linear("fc2", inter)                            # [T*K, h]
+    with jax.named_scope("combine"):
+        back = out[jnp.argsort(order)].reshape(t_, k_, -1)
+        return jnp.einsum("tkh,tk->th", back.astype(jnp.float32),
+                          w).astype(dt)
+
+
+def use_dropless(cfg) -> bool:
+    """Token-choice routing on one expert shard takes the dropless
+    dispatch.  What keeps the capacity einsums: ``ep > 1`` (their
+    sharding constraints are what makes XLA emit the data<->expert
+    all-to-all; a grouped GEMM over an expert-sharded stack has no such
+    rule yet) and expert-choice routing (capacity is its definition)."""
+    return (cfg.model.moe_router_type == "topk"
+            and cfg.parallel.expert_parallel_size == 1)
+
+
 def route_expert_choice(
     cfg, router_logits: jax.Array, capacity: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -144,7 +378,8 @@ def route_expert_choice(
     # for expert_choice, so this never enters the training loss.
     covered = dispatch.any(axis=(2, 3))  # [G, T]
     dropped = 1.0 - covered.mean().astype(jnp.float32)
-    aux = jnp.stack([dropped, _router_z_loss(router_logits)])
+    aux = _aux(dropped, _router_z_loss(router_logits),
+               dispatch.sum((0, 1, 3)).astype(jnp.float32))
     return combine, dispatch, aux
 
 
@@ -184,7 +419,7 @@ def route_tokens(
     frac_tokens = mask.sum(2).mean((0, 1)) / k_    # [E]
     frac_probs = probs.mean((0, 1))                # [E]
     balance = e_ * jnp.sum(frac_tokens * frac_probs)
-    aux = jnp.stack([balance, _router_z_loss(router_logits)])
+    aux = _aux(balance, _router_z_loss(router_logits), mask.sum((0, 1, 2)))
 
     gate_kept = gate * fits.astype(gate.dtype)                  # [G, T, K]
     slot = jax.nn.one_hot(pos_tk, capacity, dtype=jnp.float32)  # [G, T, K, C]
@@ -202,6 +437,23 @@ def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """
     m = cfg.model
     b, s, h = x.shape
+    if use_dropless(cfg):
+        with jax.named_scope("moe"):
+            xt = x.reshape(b * s, h)
+            idx, w, counts, aux = route(cfg, p["router"], xt)
+            out = dropless_experts(cfg, p["experts"], xt, idx, w, counts)
+            if "shared" in p:
+                from megatron_llm_tpu.models.transformer import mlp_sublayer
+
+                with jax.named_scope("shared_expert"):
+                    out = out + mlp_sublayer(cfg, p["shared"], xt)
+            return out.reshape(b, s, h), aux
+    assert "shared" not in p and "bias" not in p["router"] and (
+        m.moe_score_func == "softmax"
+        and m.moe_routed_scaling_factor == 1.0), (
+        "the capacity dispatch (ep > 1, expert_choice) knows the softmax "
+        "top-k router only: no shared expert, selection bias, sigmoid "
+        "scores or scaling factor")
     # GShard grouping: route fixed-size chunks of the sequence independently
     # so dispatch/combine stay O(group * capacity), not O(seq^2) — at 32K seq
     # an ungrouped [s, E, C~s] one-hot would be gigabytes per sample.
@@ -232,7 +484,7 @@ def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     fc1, s1 = _expert_kernel(experts["fc1"], dt)
     glu = m.glu_activation is not None
     # [g,e,c,(2,)f]; the bias broadcast [1,e,1,(2,)f] covers both layouts
-    y = jnp.einsum("gech,ehuf->gecuf" if glu else "gech,ehf->gecf", xe, fc1)
+    y = jnp.einsum("gech,euhf->gecuf" if glu else "gech,ehf->gecf", xe, fc1)
     if s1 is not None:  # int8 per-channel scale (same broadcast as bias)
         y = y * s1.astype(dt)[None, :, None]
     if "bias" in experts["fc1"]:
@@ -255,8 +507,8 @@ def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def zero_aux() -> jax.Array:
-    """Aux-loss placeholder for dense layers (keeps scan carries uniform)."""
-    return jnp.zeros((2,), jnp.float32)
+    """Aux placeholder for dense layers (keeps scan carries uniform)."""
+    return jnp.zeros((AUX_LEN,), jnp.float32)
 
 
 def aux_loss_coeffs(cfg) -> Tuple[float, float]:

@@ -27,12 +27,24 @@ Layer params schema (one layer; stacked on axis 0 when scanned):
      'mlp_norm':    {...},    # Falcon-40B parallel_layernorm only
      'mlp':         {'fc1': {'kernel': [h, ffn*(2 if glu else 1)], 'bias'?},
                      'fc2': {'kernel': [ffn, h],                   'bias'?}}}
+
+Latent attention (``attention_type == 'mla'``, :func:`mla_sublayer`) has
+its own ``'attention'`` subtree, n heads of nope + rope query dims:
+
+    {'q_down':  {'kernel': [h, q_lora_rank]},   'q_norm':  {'scale'},
+     'q_up':    {'kernel': [q_lora_rank, n*(nope+rope)]},
+     'kv_down': {'kernel': [h, kv_lora_rank + rope]}, 'kv_norm': {'scale'},
+     'kv_up':   {'kernel': [kv_lora_rank, n, nope + v]},
+     'dense':   {'kernel': [n*v, h]}}
+
+A model with ``dense_prefix_layers`` holds two stacks: ``dense_layers``
+(the prefix, dense MLP) and ``layers`` (the scanned expert layers).
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +67,10 @@ def _normal(key, shape, std, dtype=jnp.float32):
     return std * jax.random.normal(key, shape, dtype=dtype)
 
 
-def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False) -> Params:
+def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
+                      dense_ffn: bool = False) -> Params:
+    """One layer of the scanned stack; ``dense_ffn`` makes a layer of the
+    dense prefix instead (a dense MLP whatever ``num_experts`` says)."""
     m = cfg.model
     h = m.hidden_size
     d = m.kv_channels
@@ -70,12 +85,12 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False) -> Par
     k = jax.random.split(key, 7)
     p: Params = {
         "input_norm": init_norm_params(h, m.use_rms_norm),
-        "attention": {
+        "attention": _init_mla_params(cfg, k[0], k[1], out_std) if m.mla else {
             "qkv": {"kernel": _normal(k[0], (h, (n + 2 * nkv) * d), std)},
             "dense": {"kernel": _normal(k[1], (n * d, h), out_std)},
         },
     }
-    if m.num_experts is not None:
+    if m.num_experts is not None and not dense_ffn:
         # MoE layer: router + expert FFN stack replaces the dense MLP
         # (beyond-reference — see models/moe.py)
         from megatron_llm_tpu.models.moe import init_moe_params
@@ -108,6 +123,8 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False) -> Par
             p["cross_attention"]["q"]["bias"] = jnp.zeros((n * d,), jnp.float32)
             p["cross_attention"]["kv"]["bias"] = jnp.zeros((2 * nkv * d,), jnp.float32)
             p["cross_attention"]["dense"]["bias"] = jnp.zeros((h,), jnp.float32)
+    assert not (m.mla and (m.use_bias or m.add_qkv_bias)), (
+        "latent attention has no biases")
     if m.use_bias or m.add_qkv_bias:
         # add_qkv_bias: Qwen2-style QKV-only bias (dense/mlp stay bias-free)
         p["attention"]["qkv"]["bias"] = jnp.zeros(((n + 2 * nkv) * d,), jnp.float32)
@@ -119,13 +136,36 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False) -> Par
     return p
 
 
+def _init_mla_params(cfg, k_down: jax.Array, k_out: jax.Array,
+                     out_std: float) -> Params:
+    m = cfg.model
+    h, n, std = m.hidden_size, m.num_attention_heads, m.init_method_std
+    nope, rope, v = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    kq, kkv, kqu, kkvu = jax.random.split(k_down, 4)
+    return {
+        "q_down": {"kernel": _normal(kq, (h, m.q_lora_rank), std)},
+        "q_norm": init_norm_params(m.q_lora_rank, True),
+        "q_up": {"kernel": _normal(kqu, (m.q_lora_rank, n * (nope + rope)),
+                                   std)},
+        "kv_down": {"kernel": _normal(kkv, (h, m.kv_lora_rank + rope), std)},
+        "kv_norm": init_norm_params(m.kv_lora_rank, True),
+        # per head [k_nope | v]: the up-projection the absorbed form folds
+        # into the query (its first nope columns) and the output (the rest)
+        "kv_up": {"kernel": _normal(kkvu, (m.kv_lora_rank, n, nope + v),
+                                    std)},
+        "dense": {"kernel": _normal(k_out, (n * v, h), out_std)},
+    }
+
+
 def init_stacked_layers(cfg, key: jax.Array, num_layers: Optional[int] = None,
-                        cross_attention: bool = False) -> Params:
+                        cross_attention: bool = False,
+                        dense_ffn: bool = False) -> Params:
     """Stack per-layer params on axis 0 (for lax.scan / per-stage pipelines)."""
     L = num_layers if num_layers is not None else cfg.model.num_layers
     keys = jax.random.split(key, L)
     return jax.vmap(
-        lambda kk: init_layer_params(cfg, kk, cross_attention=cross_attention)
+        lambda kk: init_layer_params(cfg, kk, cross_attention=cross_attention,
+                                     dense_ffn=dense_ffn)
     )(keys)
 
 
@@ -333,6 +373,145 @@ def attention_sublayer(
     return out, new_cache
 
 
+class LatentCache(NamedTuple):
+    """The whole latent pool ``[layers, pages, page, width]`` and the layer
+    this sublayer writes and reads: the pool rides the layer scan's carry
+    and every layer updates its own slice in place (a scan over stacked
+    slices copies the whole pool out and back each tick)."""
+
+    pool: jax.Array
+    layer: jax.Array
+
+
+@jax.named_scope("attention")
+def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
+                 segment_ids, kv_cache=None, paged=None):
+    """Multi-head latent attention (DeepSeek-V2/V3; JoyAI-LLM-Flash).
+
+    Per token: ``c_q = RMSNorm(x W_dq)``; per head ``[q_nope | q_rope] =
+    c_q W_uq``; ``[c_kv | k_rope] = x W_dkv``, ``c_kv <- RMSNorm(c_kv)``;
+    RoPE on each head's ``q_rope`` and on ``k_rope``, which all heads
+    share.  Two forms of the same numbers:
+
+    * **expanded** (no cache: the trainer, the dense forward):
+      ``[k_nope_h | v_h] = c_kv W_ukv`` per head, keys ``[k_nope_h |
+      k_rope]``, plain causal attention with qk width nope + rope and v
+      width ``v_head_dim`` on the XLA path (the flash kernel is built for
+      equal widths and is not asked);
+    * **absorbed** (``paged``: every row of the engine's tick):
+      ``q~_h = q_nope_h W_uk_h^T``, scores ``(q~_h . c_kv + q_rope_h .
+      k_rope) / sqrt(nope + rope)`` against the cached row itself, ``u_h =
+      softmax_h c_kv``, ``out_h = u_h W_uv_h``.  The cache holds ``[c_kv |
+      k_rope]`` after norm and RoPE and nothing else: ONE key of
+      ``latent_cache_width`` values a token that every head reads, whose
+      first ``kv_lora_rank`` values are also the value (the paged kernel's
+      single-KV-head path, ``ops/paged_attention.py``).
+
+    Returns (output [b, s, h], the updated pool or None).
+    """
+    from megatron_llm_tpu.parallel.tp import (
+        apply_column_parallel,
+        apply_row_parallel,
+    )
+
+    m = cfg.model
+    b, s, _ = x.shape
+    n, r = m.num_attention_heads, m.kv_lora_rank
+    nope, rd, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    eps = m.layernorm_epsilon
+    linear = _linear_impl(cfg)
+    scale = 1.0 / ((nope + rd) ** 0.5)
+
+    with jax.named_scope("mla"):
+        c_q = norm(linear(p["q_down"], x), p["q_norm"], eps, True)
+        q = apply_column_parallel(cfg, p["q_up"], c_q, linear)
+        q = q.reshape(b, s, n, nope + rd)
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        ckv = linear(p["kv_down"], x)
+        c_kv = norm(ckv[..., :r], p["kv_norm"], eps, True)
+        k_rope = ckv[..., None, r:]                       # [b, s, 1, rd]
+        cos, sin = rope
+        q_rope = apply_rotary_emb(q_rope, cos, sin, position_ids)
+        k_rope = apply_rotary_emb(k_rope, cos, sin, position_ids)
+        w_ukv = p["kv_up"]["kernel"].astype(x.dtype)      # [r, n, nope + vd]
+
+        new_pool = None
+        if paged is not None:
+            ctx, new_pool = _mla_paged(
+                cfg, q_nope, q_rope, c_kv, k_rope[:, :, 0], w_ukv, kv_cache,
+                paged, scale)
+        else:
+            assert kv_cache is None, (
+                "latent attention decodes through the paged pool only: the "
+                "dense incremental cache holds K/V heads")
+            kv = jnp.einsum("bsr,rnd->bsnd", c_kv, w_ukv)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (b, s, n, rd))], axis=-1)
+            qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+            # unequal qk / v widths: attention() keeps this off the flash
+            # kernel and off the ring, and says so
+            ctx = attn_ops.attention(
+                qf, k, kv[..., nope:], causal=True, segment_ids=segment_ids,
+                scale=scale, use_flash=cfg.training.use_flash_attn)
+    from jax.ad_checkpoint import checkpoint_name
+
+    ctx = checkpoint_name(ctx, "attn_out")
+    out = apply_row_parallel(cfg, p["dense"], ctx.reshape(b, s, n * vd),
+                             linear)
+    return out, new_pool
+
+
+def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LatentCache,
+               paged, scale):
+    """The absorbed form against the latent pool.  Every fed token is one
+    row at its own position, whatever the call's shape (a tick's ``[R, 1]``
+    rows, a scoring chunk's ``[b, s]``): write the rows' ``[c_kv | k_rope]``
+    through the block table into this layer's slice of the pool, then one
+    ragged paged attention with the pool as key AND value."""
+    from megatron_llm_tpu.ops.paged_attention import paged_attention_ragged
+
+    m = cfg.model
+    b, s, n, nope = q_nope.shape
+    r = m.kv_lora_rank
+    pool, layer = cache
+    n_layers, n_pages, page_size, width = pool.shape
+    rows = b * s
+    pos = (paged.positions[:, None] + jnp.arange(s)[None, :]).reshape(rows)
+    if paged.table_index is not None:
+        tables, index = paged.block_tables, paged.table_index
+        assert s == 1
+    else:
+        tables = paged.block_tables
+        index = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
+    # this layer's pages of the flat pool: one table offset, no slice
+    tables = tables + layer * n_pages
+    flat = pool.reshape(n_layers * n_pages, page_size, 1, width)
+    row_tables = tables[index]                                  # [rows, W]
+    # clip: as the K/V pair's write (attention_sublayer), stray rows land
+    # in pages that are never attended
+    page_slot = jnp.clip(pos // page_size, 0, row_tables.shape[1] - 1)
+    page_ids = jnp.take_along_axis(row_tables, page_slot[:, None], axis=1)
+    latent = jnp.concatenate([c_kv, k_rope], axis=-1).reshape(rows, 1, 1, -1)
+    pad = width - latent.shape[-1]          # whole 128-lane rows (the pool)
+    if pad:
+        latent = jnp.pad(latent, ((0, 0),) * 3 + ((0, pad),))
+    flat = flat.at[page_ids, (pos % page_size)[:, None]].set(
+        latent.astype(flat.dtype))
+    q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_ukv[..., :nope])
+    q_abs = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(rows, 1, n, -1)
+    if pad:
+        q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
+    horizons = paged.horizons if paged.horizons is not None else pos + 1
+    u = paged_attention_ragged(
+        q_abs, flat, None, tables, index, pos, horizons, scale=scale,
+        use_kernel=cfg.training.use_flash_attn)
+    ctx = jnp.einsum("bsnr,rnd->bsnd", u[..., :r].reshape(b, s, n, r),
+                     w_ukv[..., nope:])
+    return ctx, flat.reshape(pool.shape)
+
+
+
 def cross_attention_sublayer(
     cfg,
     p: Params,
@@ -440,11 +619,19 @@ def block_forward(
     _sp = sp_constraint if sp_constraint is not None else (lambda t: t)
 
     ln1 = norm(hidden, p["input_norm"], eps, m.use_rms_norm)
-    attn_out, new_cache = attention_sublayer(
-        cfg, p["attention"], ln1, rope, position_ids, segment_ids,
-        dk_attn, deterministic, kv_cache, cache_index, token_idx=token_idx,
-        attn_bias=attn_bias, paged=paged,
-    )
+    if m.mla:
+        assert token_idx is None and attn_bias is None and (
+            deterministic or not m.attention_dropout), (
+            "latent attention: no cp token order, bias or attention dropout")
+        attn_out, new_cache = mla_sublayer(
+            cfg, p["attention"], ln1, rope, position_ids, segment_ids,
+            kv_cache=kv_cache, paged=paged)
+    else:
+        attn_out, new_cache = attention_sublayer(
+            cfg, p["attention"], ln1, rope, position_ids, segment_ids,
+            dk_attn, deterministic, kv_cache, cache_index,
+            token_idx=token_idx, attn_bias=attn_bias, paged=paged,
+        )
 
     if m.parallel_attn:
         assert "cross_attention" not in p, (
@@ -540,10 +727,39 @@ def transformer_forward(
     loss pair [2] (load-balance, z), zeros for dense models.
     """
     num_layers = jax.tree_util.tree_leaves(stacked_layers)[0].shape[0]
-    rates = _lima_rates(cfg, cfg.model.num_layers)
+    rates = _lima_rates(cfg, cfg.model.depth)
+    # a latent pool (MLA through the engine) is ONE array over all layers
+    # and rides the carry: each layer updates its own slice in place
+    # (LatentCache); K/V pairs are scanned as stacked per-layer slices
+    latent = paged is not None and cfg.model.mla
+    pool = kv_caches if latent else None
+    if latent:
+        kv_caches = None
+    # the serving tick does not scan the expert weights: a scanned slice
+    # that feeds the grouped kernel is a copy of the layer's experts (2.4 GB
+    # a layer at 256 x 2048 x 768; 74% of the tick's device time when it
+    # was one, PERF.md PR 31), so the whole stack stays outside the scan and
+    # the layer names its own experts (models/moe.StackedExperts).
+    # The trainer scans them: a closed-over stack would take a stack-sized
+    # gradient from every layer
+    from megatron_llm_tpu.models import moe as moe_mod
 
-    def one_layer(carry_hidden, xs):
+    all_experts = None
+    if (paged is not None and "moe" in stacked_layers
+            and moe_mod.use_dropless(cfg)):
+        all_experts = stacked_layers["moe"]["experts"]
+        stacked_layers = {**stacked_layers, "moe": {
+            k: v for k, v in stacked_layers["moe"].items() if k != "experts"}}
+
+    def one_layer(carry, xs):
+        carry_hidden, pool = carry
         layer_params, layer_idx, cache = xs
+        if latent:
+            cache = LatentCache(pool, layer_idx)
+        if all_experts is not None:
+            layer_params = {**layer_params, "moe": {
+                **layer_params["moe"], "experts": moe_mod.StackedExperts(
+                    all_experts, layer_idx - layer_offset)}}
         dk = None if dropout_key is None else rng_mod.fold_layer(dropout_key, layer_idx)
         rate = rates[layer_idx]
         out, new_cache, aux = block_forward(
@@ -557,7 +773,9 @@ def transformer_forward(
             kv_cache=cache, cache_index=cache_index, paged=paged,
             sp_constraint=sp_constraint,
         )
-        return out, (new_cache, aux)
+        if latent:
+            pool, new_cache = new_cache, None
+        return (out, pool), (new_cache, aux)
 
     layer_ids = jnp.arange(num_layers) + layer_offset
 
@@ -570,21 +788,37 @@ def transformer_forward(
         body = one_layer
         if granularity is not None:
             body = jax.checkpoint(one_layer, policy=policy, prevent_cse=False)
-        hidden, (new_caches, aux_stack) = jax.lax.scan(
-            body, hidden, (stacked_layers, layer_ids, kv_caches)
+        (hidden, pool), (new_caches, aux_stack) = jax.lax.scan(
+            body, (hidden, pool), (stacked_layers, layer_ids, kv_caches)
         )
-        return hidden, new_caches, aux_stack.sum(0)
+        return hidden, pool if latent else new_caches, aux_stack.sum(0)
     else:
+        from megatron_llm_tpu.models.moe import zero_aux
+
         new_caches = []
-        aux_total = jnp.zeros((2,), jnp.float32)
+        aux_total = zero_aux()
         for i in range(num_layers):
             layer_p = jax.tree.map(lambda a: a[i], stacked_layers)
             cache = None if kv_caches is None else jax.tree.map(lambda a: a[i], kv_caches)
-            hidden, (nc, aux) = one_layer(hidden, (layer_p, layer_ids[i], cache))
+            (hidden, pool), (nc, aux) = one_layer(
+                (hidden, pool), (layer_p, layer_ids[i], cache))
             new_caches.append(nc)
             aux_total = aux_total + aux
-        if kv_caches is not None:
+        if latent:
+            new_caches = pool
+        elif kv_caches is not None:
             new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
         else:
             new_caches = None
         return hidden, new_caches, aux_total
+
+
+def layer_stacks(cfg, params: Params):
+    """(stacked layers, first absolute layer) of each stack a token passes,
+    in order: the dense prefix where the model has one, then the scanned
+    stack.  A uniform model has the one stack it always had."""
+    stacks = []
+    if "dense_layers" in params:
+        stacks.append((params["dense_layers"], 0))
+    stacks.append((params["layers"], cfg.model.dense_prefix_layers))
+    return stacks

@@ -164,7 +164,7 @@ def xla_attention(
         probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
     probs = probs.astype(v.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, sq, n, d)
+    return out.reshape(b, sq, n, v.shape[-1])   # v may be narrower (MLA)
 
 
 def attention(
@@ -199,6 +199,9 @@ def attention(
         else 1
     )
     if cp > 1:
+        assert q.shape[-1] == v.shape[-1], (
+            "ring attention needs equal qk and v widths (latent attention "
+            "has no context-parallel form)")
         assert bias is None and dropout_rate == 0.0, (
             "context parallelism supports structural masking only "
             "(causal/sliding-window/segment), no bias or attention dropout"
@@ -224,6 +227,9 @@ def attention(
         else f"target platform is {target}" if target != "tpu"
         else f"seq {sq} < 128" if sq < 128
         else f"head_dim {q.shape[-1]}" if q.shape[-1] not in (64, 128, 256)
+        # the kernel's blocks and its backward assume one head width
+        else (f"qk width {q.shape[-1]} differs from v width {v.shape[-1]}")
+        if q.shape[-1] != v.shape[-1]
         else None
     )
     if refusal is None:

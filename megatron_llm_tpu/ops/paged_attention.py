@@ -89,6 +89,8 @@ def paged_gather_kv(k_pool, v_pool, block_tables: jax.Array,
     b = block_tables.shape[0]
     nkv, d = k_pool.shape[-2], k_pool.shape[-1]
     k_all = k_pool[block_tables].reshape(b, -1, nkv, d)
+    if v_pool is None:       # a latent pool: the key row is the value too
+        return k_all, k_all
     v_all = v_pool[block_tables].reshape(b, -1, nkv, d)
     return k_all, v_all
 
@@ -136,7 +138,7 @@ def paged_attention_decode(
 def paged_attention_ragged(
     q: jax.Array,             # [R, 1, n_heads, d] — one query row per entry
     k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    v_pool: Optional[jax.Array],  # the same; None = k_pool is the value too
     tables: jax.Array,        # [T, max_pages_per_seq] int32 — UNIQUE tables
     table_index: jax.Array,   # [R] int32 — each row's table
     positions: jax.Array,     # [R] int32 — each row's own position
@@ -169,6 +171,11 @@ def paged_attention_ragged(
     ``horizons`` bounds the page walk in the Pallas kernel (a dead row —
     horizon 0 — skips every page); the fallback's mask ``kv_pos <=
     positions`` subsumes them.
+
+    ``v_pool=None`` is the latent pool of MLA (models/transformer.py
+    ``_mla_paged``): one shared key row a token whose own values are the
+    value, so the output has the key's width and the caller keeps the
+    leading ``kv_lora_rank`` values.  The kernel then copies each page once.
     """
     assert q.ndim == 4 and q.shape[1] == 1, "ragged rows are [R, 1, n, d]"
     b, _, n, d = q.shape

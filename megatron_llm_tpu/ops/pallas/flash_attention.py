@@ -552,6 +552,11 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention over [batch, seq, heads, head_dim] inputs."""
     b, sq, n, d = q.shape
+    if not (k.shape[-1] == v.shape[-1] == d):
+        raise ValueError(
+            f"flash_attention needs one head width for q, k and v; got "
+            f"{d}, {k.shape[-1]}, {v.shape[-1]} (latent attention's expanded "
+            f"form takes the XLA path: ops/attention.attention)")
     auto_q, auto_kv = pick_blocks(sq, k.shape[1], d)
     if block_q is None:
         block_q = auto_q

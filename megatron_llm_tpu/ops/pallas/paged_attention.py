@@ -73,11 +73,16 @@ def _paged_kernel(
     group: int,
     sliding_window: Optional[int],
     quantized: bool,
+    shared_kv: bool = False,
 ):
     """One program per sequence: ``rows = s*group`` query rows per kv head,
     row ``r`` at position ``pos0 + r // group`` — the causal mask is per ROW.
-    Decode and ragged calls have ``s == 1``."""
-    if quantized:
+    Decode and ragged calls have ``s == 1``.  ``shared_kv`` (a latent pool):
+    the key pages are the value pages, copied once."""
+    if shared_kv:
+        q_ref, k_hbm, o_ref, k_buf, sem, m_s, l_s, acc_s = refs
+        v_hbm, v_buf = None, k_buf
+    elif quantized:
         (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, sem, m_s, l_s, acc_s, ks_buf, vs_buf) = refs
     else:
@@ -108,7 +113,9 @@ def _paged_kernel(
             def _page():
                 # a wait needs the copy's shape only, not its source
                 pid = page_id(blk, j) if start else 0
-                copies = [(k_hbm.at[pid], k_buf, 0), (v_hbm.at[pid], v_buf, 1)]
+                copies = [(k_hbm.at[pid], k_buf, 0)]
+                if not shared_kv:
+                    copies += [(v_hbm.at[pid], v_buf, 1)]
                 if quantized:
                     scale_rows = pl.ds(pid * nkv // 128, 2)
                     copies += [(ks_hbm.at[scale_rows], ks_buf, 2),
@@ -222,8 +229,13 @@ def _pages_per_step(page_size: int, row_bytes: int) -> int:
 
 def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
                 *, group, scale, sliding_window, interpret):
-    """``qg`` [b, nkv, rows, d] kv-head-major query rows -> same shape."""
+    """``qg`` [b, nkv, rows, d] kv-head-major query rows -> same shape.
+    ``v_pool=None``: the key pool is the value pool (a latent pool)."""
     quantized = kv_quant.is_quantized(k_pool)
+    shared_kv = v_pool is None
+    assert not (shared_kv and quantized), "a latent pool is not quantized"
+    if shared_kv:
+        v_pool = k_pool
     k_arr, v_arr = (k_pool.q, v_pool.q) if quantized else (k_pool, v_pool)
     b, nkv, rows, d = qg.shape
     num_pages, page_size, _, _ = k_arr.shape
@@ -246,11 +258,10 @@ def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
     row_spec = pl.BlockSpec((None, nkv, rows, d),
                             lambda i, tbl, idx, pos, hor: (i, 0, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [row_spec, hbm_spec, hbm_spec]
-    operands = [qg, view(k_arr), view(v_arr)]
-    scratch = [
-        pltpu.VMEM(buf_shape, k_arr.dtype),
-        pltpu.VMEM(buf_shape, v_arr.dtype),
+    in_specs = [row_spec, hbm_spec] + [hbm_spec] * (not shared_kv)
+    operands = [qg, view(k_arr)] + [view(v_arr)] * (not shared_kv)
+    scratch = [pltpu.VMEM(buf_shape, k_arr.dtype)] + [
+        pltpu.VMEM(buf_shape, v_arr.dtype)] * (not shared_kv) + [
         pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)),
         pltpu.VMEM((nkv, rows, 1), jnp.float32),
         pltpu.VMEM((nkv, rows, 1), jnp.float32),
@@ -270,6 +281,7 @@ def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
     kernel = functools.partial(
         _paged_kernel, scale=scale, group=group,
         sliding_window=sliding_window, quantized=quantized,
+        shared_kv=shared_kv,
     )
 
     # VMEM, every last dim padded to 128 lanes: the q and out blocks (two

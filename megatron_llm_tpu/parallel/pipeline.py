@@ -223,8 +223,10 @@ def pipeline_apply(cfg, mesh, stacked_layers, hidden_mb: jax.Array,
             nxt = jax.lax.ppermute(out, PP_AXIS, perm)
             return (nxt, out_buf, aux_acc), None
 
+        from megatron_llm_tpu.models.moe import zero_aux
+
         init = (jnp.zeros_like(hidden_mb[0]), jnp.zeros_like(hidden_mb),
-                jnp.zeros((2,), jnp.float32))
+                zero_aux())
         (_, out_buf, aux_acc), _ = jax.lax.scan(tick, init, jnp.arange(T))
         # broadcast last-stage results to every stage (psum of one-hot data);
         # transpose of this psum routes dLoss back to the last stage only.

@@ -88,7 +88,7 @@ def _spec_for_path(path: tuple, ndim: int, stacked: bool) -> P:
         # (column/row-parallel per expert, exactly the dense fc1/fc2 rule).
         if "fc1" in names:
             if names[-1] in ("kernel", "kernel_q"):
-                # [E, h, 2, ffn] (GLU) or [E, h, ffn]
+                # [E, 2, h, ffn] (GLU) or [E, h, ffn]
                 return (spec(EP_AXIS, None, None, TP_AXIS)
                         if ndim == 4 + len(lead) else spec(EP_AXIS, None, TP_AXIS))
             # bias [E, 2, ffn] or [E, ffn]

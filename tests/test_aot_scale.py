@@ -323,3 +323,47 @@ def test_aot_engine_programs_compile(tp):
         _compiles_with_kernel(
             chunk, params, S((1, rows), jnp.int32), S((1,), jnp.int32),
             S((1, 8), jnp.int32), pool, pool, donate_argnums=(4, 5))
+
+
+def test_aot_latent_tick_compiles_at_published_widths():
+    """The ragged tick of JoyAI-LLM-Flash at its published widths (one
+    dense + one expert layer, abstract parameters) compiles for one v5e:
+    Mosaic takes the paged kernel with ONE shared latent leaf of 640 lanes
+    (576 values) as key and value, and jax's grouped-matmul kernel with the
+    whole expert stack as its operand; the program's temporaries stay far
+    under one layer's experts (2.4 GB), i.e. nothing copies a stack."""
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    cfg = make_config("joyai-llm-flash", num_layers=1,
+                      params_dtype="bfloat16", seq_length=1024)
+    m = cfg.model
+    slots, page, pre = 16, 16, 64
+    width = cfg.data.seq_length // page
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    pool = S((m.depth, slots * width + 1, page, 640), jnp.bfloat16)
+    with global_mesh(mesh):
+        params = jax.eval_shape(
+            functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda a: S(a.shape, jnp.bfloat16), params)
+        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        lowered = jax.jit(tick, donate_argnums=(1, 2)).lower(
+            params, pool, None, S((slots, width), jnp.int32),
+            S((slots,), jnp.int32), S((slots,), jnp.int32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), S((pre,), jnp.int32),
+            S((pre,), jnp.int32), S((2, width), jnp.int32),
+            S((pre,), jnp.int32), S((pre,), jnp.int32))
+        text = lowered.as_text()
+        assert "paged_attention" in text and "gmm" in text
+        stats = lowered.compile().memory_analysis()
+    assert stats.temp_size_in_bytes < 1 << 30

@@ -128,6 +128,9 @@ def test_single_expert_equals_dense_mlp():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64), jnp.float32)
     out, aux = moe_sublayer(cfg, p, x)
     dense_p = jax.tree.map(lambda a: a[0], p["experts"])  # strip expert axis
+    # an expert's fc1 is [2, h, ffn] (each half a whole matrix), the dense
+    # MLP's [h, 2, ffn]
+    dense_p["fc1"]["kernel"] = dense_p["fc1"]["kernel"].transpose(1, 0, 2)
     want = mlp_sublayer(cfg, dense_p, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

@@ -135,8 +135,7 @@ def convert_llama_state(state: Dict[str, Any], cfg) -> Dict[str, Any]:
                 "fc1": {
                     "kernel": stack(
                         lambda i: np.stack([
-                            np.stack([EW(i, e, "w3").T, EW(i, e, "w1").T],
-                                     axis=1)
+                            np.stack([EW(i, e, "w3").T, EW(i, e, "w1").T])
                             for e in range(E)
                         ])
                     )

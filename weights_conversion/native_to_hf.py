@@ -61,12 +61,12 @@ def to_hf_llama_state(params: Dict[str, Any], cfg, vocab_size: int) -> Dict[str,
             state[f"{pre}.block_sparse_moe.gate.weight"] = (
                 np.ascontiguousarray(get("moe", "router", "kernel").T)
             )
-            fc1 = get("moe", "experts", "fc1", "kernel")  # [E, h, 2, ffn]
+            fc1 = get("moe", "experts", "fc1", "kernel")  # [E, 2, h, ffn]
             fc2 = get("moe", "experts", "fc2", "kernel")  # [E, ffn, h]
             for e in range(m.num_experts):
                 epre = f"{pre}.block_sparse_moe.experts.{e}"
-                state[f"{epre}.w3.weight"] = np.ascontiguousarray(fc1[e, :, 0, :].T)
-                state[f"{epre}.w1.weight"] = np.ascontiguousarray(fc1[e, :, 1, :].T)
+                state[f"{epre}.w3.weight"] = np.ascontiguousarray(fc1[e, 0].T)
+                state[f"{epre}.w1.weight"] = np.ascontiguousarray(fc1[e, 1].T)
                 state[f"{epre}.w2.weight"] = np.ascontiguousarray(fc2[e].T)
         else:
             fc1 = get("mlp", "fc1", "kernel")  # [h, 2, ffn]
