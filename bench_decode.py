@@ -1391,7 +1391,7 @@ def bench_pp(cfg, params, pps, concurrency: int, prompt: int, gen: int,
             toks = np.full((eng.max_slots,), 2, np.int32)
             ppc, acfg = eng._ppc, eng.cfg
 
-            def tickish(p, pk, pv):
+            def tickish(p, pool_kv):
                 import jax.numpy as jnp
 
                 rope = make_rope_cache(acfg)
@@ -1399,13 +1399,13 @@ def bench_pp(cfg, params, pps, concurrency: int, prompt: int, gen: int,
                     logits, _ = model_forward(
                         acfg, p, jnp.asarray(toks)[:, None],
                         position_ids=jnp.asarray(pos)[:, None],
-                        rope_cache=rope, kv_caches=(pk, pv),
+                        rope_cache=rope, kv_caches=pool_kv,
                         paged=PagedState(jnp.asarray(bt),
                                          jnp.asarray(pos)))
                 return logits
 
             hlo = jax.jit(tickish).lower(
-                eng.params, eng.pool.k, eng.pool.v).compile().as_text()
+                eng.params, eng.pool.kv).compile().as_text()
     mechanism_ok = (pp_serve_mod.STAGE_PERMUTE_SCOPE in hlo
                     and "collective-permute" in hlo)
     ratios = {f"pp{pp}": round(

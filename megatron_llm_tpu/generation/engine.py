@@ -179,22 +179,22 @@ def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
         why = (f"tensor-parallel serving (tp {tp}): the pool shards over "
                "KV heads, and a latent row has none")
     elif pp > 1:
-        why = (f"pipeline-parallel serving (pp {pp}): its stages scan "
-               "per-layer K/V slices, and the latent pool is one array that "
-               "every layer updates in place")
+        why = (f"pipeline-parallel serving (pp {pp}): its stages split "
+               "one scanned stack, and this family's dense prefix layers "
+               "come before it")
     elif draft:
         why = ("--spec_k: the verify tick and the draft cache are built "
-               "for a K/V pair")
+               "for a K/V pool")
     elif pipeline_depth:
-        why = ("--tick_pipeline_depth: the chained tick carries a K/V "
-               "pair")
+        why = ("--tick_pipeline_depth: the chained tick is not tested "
+               "with a latent pool")
     elif handoff:
         why = ("the cross-replica KV handoff: its wire format names a K "
                "and a V leaf")
     if why:
         raise ValueError(
             "latent attention (attention_type 'mla') keeps ONE latent row a "
-            f"token in a pool without a value leaf, which {why} does not "
+            f"token, key and value at once, which {why} does not "
             "carry yet. Serve this model on one chip with --kv_dtype bf16, "
             "--spec_k 0 and --tick_pipeline_depth 0.")
 
@@ -202,10 +202,19 @@ def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
 class PagedKVPool:
     """Device page pool + host refcounting allocator.
 
-    The device arrays are plain stacked pytrees ``[L, P, page, nkv, d]``
-    (scanned over L exactly like the dense cache); the allocator is
-    host-side python — alloc/release happen at request admission/retirement
-    and page-boundary crossings, far below tick frequency.
+    The device array is ONE leaf ``[L, P, page, row]`` (``kv``) whose row
+    ``ops/kv_quant.py`` owns and derives from ``(nkv, d, dtype)``: a head's
+    key and value side by side, so a page is one copy for the kernel and a
+    token one scatter for the write, in whole 128-lane rows (Falcon-7B's
+    head of 64 included); a latent model's row is its padded latent.  The
+    pool keeps that ONE layout from the write to the kernel: every tick
+    program carries it through the layer scan and updates it in place
+    (models/transformer.py ``LayerPool``).  Code off the tick's hot path
+    reads the logical ``(page, offset, head, d)`` view through kv_quant
+    (:meth:`logical_kv`, the handoff's export / import).  The allocator
+    is host-side python — alloc/release happen at request
+    admission/retirement and page-boundary crossings, far below tick
+    frequency.
 
     Page states (disjoint, tests/test_prefix_cache.py invariants):
 
@@ -218,7 +227,7 @@ class PagedKVPool:
       outruns the free list.
 
     With ``draft_cfg`` (speculative decoding, generation/speculative/),
-    the pool carries a SECOND pair of device arrays shaped by the draft
+    the pool carries a SECOND leaf (``draft_kv``) shaped by the draft
     model — same ``num_pages``, same page ids.  A page id then addresses
     both models' K/V for the same token positions: one block table, one
     refcount, one commitment ledger and one prefix trie govern both
@@ -239,35 +248,34 @@ class PagedKVPool:
         # per-head scales, ops/kv_quant.py) for ~2x pages per chip.
         self.kv_dtype = kv_dtype
         self.compute_dtype = dtype
-        # latent attention (MLA): ONE leaf, a row of [normed latent |
-        # rotated rope key] a token and layer, no KV-head axis and NO value
-        # pool (``v`` is None; the kernel reads the key row as the value).
-        # The row is stored in whole 128-lane rows (576 -> 640 values):
-        # that is what the array's tiles in HBM hold whatever its logical
-        # width, and the kernel's page copies move whole lanes, so a
-        # narrower leaf would be padded by a copy of the layer's pool
-        # before every call.  The lanes past ``latent_cache_width`` are
-        # zeros nobody reads (64 of 640 at 576: 11% of the leaf).
+        # latent attention (MLA): a row of [normed latent | rotated rope
+        # key] a token and layer, ONE storage head that the kernel reads as
+        # key and as value, stored in whole 128-lane rows (576 -> 640
+        # values; the lanes past ``latent_cache_width`` are zeros nobody
+        # reads, 11% of the leaf).  Every other model: the K/V row
         self.latent = bool(m.mla)
         if self.latent:
             refuse_latent_cache(kv_dtype=kv_dtype, mesh=mesh,
                                 draft=draft_cfg is not None)
-            width = -(-m.latent_cache_width // 128) * 128
-            shape = (m.depth, num_pages, page_size, width)
+            # the logical view's head width: one head, the whole row
+            self.head_dim = -(-m.latent_cache_width // 128) * 128
+            kv = kv_quant.make_pool(
+                (m.depth, num_pages, page_size, 1, self.head_dim), kv_dtype,
+                dtype)
         else:
-            shape = (m.depth, num_pages, page_size,
-                     m.num_attention_heads_kv, m.kv_channels)
+            self.head_dim = m.kv_channels
+            kv = kv_quant.make_kv_pool(
+                m.depth, num_pages, page_size, m.num_attention_heads_kv,
+                self.head_dim, kv_dtype, dtype)
 
-        def _make(shp):
-            return kv_quant.make_pool(shp, kv_dtype, dtype)
-
-        # Tensor parallelism shards the pool over the KV-heads dim (each tp
-        # rank attends its own heads — the same decomposition as the qkv
-        # column-parallel rule in parallel/tp.py). Block tables and the
+        # Tensor parallelism shards the pool's row over its KV heads (each
+        # tp rank attends its own heads — the same decomposition as the qkv
+        # column-parallel rule in parallel/tp.py; the row is head-major, so
+        # a head's key|value pair stays on its shard). Block tables and the
         # allocator below stay host-side and apply to every shard alike;
         # tp=1 (or no mesh) degrades to a single-device replicated pool.
-        # Quantized pools shard the scale leaf over the same heads dim
-        # ([L, P, nkv] -> tp on nkv), so a page's values and its scales
+        # Quantized pools shard the scale leaf over the same heads
+        # ([L, P, 2*nkv] -> tp), so a page's values and its scales
         # always live on the same shard.
         # Pipeline parallelism (ISSUE 20) additionally shards the pool
         # over the LAYER dim: each pp stage holds only its own L/pp
@@ -291,28 +299,23 @@ class PagedKVPool:
             layer_ax = PP_AXIS if pp > 1 else None
             heads_ax = TP_AXIS if tp > 1 else None
             self.kv_sharding = NamedSharding(
-                mesh, P(layer_ax, None, None, heads_ax, None))
+                mesh, P(layer_ax, None, None, heads_ax))
             self._scale_sharding = NamedSharding(
                 mesh, P(layer_ax, None, heads_ax))
-            self.k = self._place(_make(shape))
-            self.v = self._place(_make(shape))
+            self.kv = self._place(kv)
         else:
             self.kv_sharding = (NamedSharding(mesh, P())
                                 if mesh is not None else None)
             self._scale_sharding = self.kv_sharding
-            self.k = _make(shape)
-            self.v = None if self.latent else _make(shape)
+            self.kv = kv
         self.draft_cfg = draft_cfg
-        self.draft_k = self.draft_v = None
+        self.draft_kv = None
         if draft_cfg is not None:
             dm = draft_cfg.model
-            ddtype = _compute_dtype(draft_cfg)
-            dshape = (dm.num_layers, num_pages, page_size,
-                      dm.num_attention_heads_kv, dm.kv_channels)
-
-            def _make_d(shp):
-                return kv_quant.make_pool(shp, kv_dtype, ddtype)
-
+            draft_kv = kv_quant.make_kv_pool(
+                dm.num_layers, num_pages, page_size,
+                dm.num_attention_heads_kv, dm.kv_channels, kv_dtype,
+                _compute_dtype(draft_cfg))
             if pp > 1:
                 assert dm.num_layers % pp == 0, (
                     f"draft num_layers {dm.num_layers} not divisible by "
@@ -322,11 +325,8 @@ class PagedKVPool:
                     assert dm.num_attention_heads_kv % tp == 0, (
                         f"draft kv heads {dm.num_attention_heads_kv} not "
                         f"divisible by tp {tp}")
-                self.draft_k = self._place(_make_d(dshape))
-                self.draft_v = self._place(_make_d(dshape))
-            else:
-                self.draft_k = _make_d(dshape)
-                self.draft_v = _make_d(dshape)
+                draft_kv = self._place(draft_kv)
+            self.draft_kv = draft_kv
         self.num_pages = num_pages
         self.page_size = page_size
         self.refcounts = np.zeros((num_pages,), np.int32)
@@ -339,8 +339,7 @@ class PagedKVPool:
 
     def _place(self, pool):
         """device_put a pool (plain array or QuantPagedKV) under the tp
-        sharding — values over the heads dim, scales over their heads
-        dim."""
+        sharding — a row over its heads, scales over their heads dim."""
         if kv_quant.is_quantized(pool):
             return jax.device_put(pool, kv_quant.QuantPagedKV(
                 q=self.kv_sharding, scale=self._scale_sharding))
@@ -352,34 +351,52 @@ class PagedKVPool:
         (ISSUE 13): kv-quantization mode, storage dtype AND scale dtype —
         an int8 engine must never reuse a bf16 executable (and vice
         versa), and a future scale-dtype change re-keys too.  Replaces
-        the old ``str(pool.k.dtype)`` key entry, which could not tell a
-        container apart from its storage array."""
-        if kv_quant.is_quantized(self.k):
-            return ("kv", self.kv_dtype, str(self.k.q.dtype),
-                    str(self.k.scale.dtype))
+        a pool-dtype key entry, which could not tell a container apart
+        from its storage array."""
+        if kv_quant.is_quantized(self.kv):
+            return ("kv", self.kv_dtype, str(self.kv.q.dtype),
+                    str(self.kv.scale.dtype))
         if self.latent:
-            return ("kv", "latent", str(self.k.dtype), self.k.shape[-1])
-        return ("kv", self.kv_dtype, str(self.k.dtype))
+            return ("kv", "latent", str(self.kv.dtype), self.kv.shape[-1])
+        return ("kv", self.kv_dtype, str(self.kv.dtype))
 
     @property
     def draft_kv_statics(self) -> Tuple:
-        if self.draft_k is None:
+        if self.draft_kv is None:
             return ("draft_kv", None)
-        if kv_quant.is_quantized(self.draft_k):
-            return ("draft_kv", self.kv_dtype, str(self.draft_k.q.dtype),
-                    str(self.draft_k.scale.dtype))
-        return ("draft_kv", self.kv_dtype, str(self.draft_k.dtype))
+        if kv_quant.is_quantized(self.draft_kv):
+            return ("draft_kv", self.kv_dtype, str(self.draft_kv.q.dtype),
+                    str(self.draft_kv.scale.dtype))
+        return ("draft_kv", self.kv_dtype, str(self.draft_kv.dtype))
+
+    def _pools(self) -> List[Tuple[str, object, int]]:
+        """(wire prefix, pool, head_dim) of every cache this pool holds."""
+        pools = [("", self.kv, self.head_dim)]
+        if self.draft_kv is not None:
+            pools.append(("draft_", self.draft_kv,
+                          self.draft_cfg.model.kv_channels))
+        return pools
+
+    def logical_kv(self, pages: Sequence[int], draft: bool = False):
+        """Host copies of ``pages`` in the logical view, whatever the
+        physical row: (keys, values), each ``[L, n, page, nkv, d]``
+        (dequantized where the pool is quantized; a latent pool's row is
+        both).  For tests, tools and debugging — not the tick."""
+        _, pool, d = self._pools()[int(draft)]
+        ids = np.asarray(list(pages), np.int32)
+        got = jax.tree.map(lambda a: a[:, ids], pool)
+        heads = np.asarray(
+            kv_quant.dequantize_pages(got, jnp.float32)
+            if kv_quant.is_quantized(got) else kv_quant.heads_view(got, d))
+        return (heads, heads) if self.latent else kv_quant.split_kv(heads)
 
     def kv_pool_bytes(self) -> int:
         """Device bytes of the KV value storage, target + draft caches —
         the fixed budget the capacity bench holds constant while the
         kv_dtype varies (published as ``mlt_engine_kv_pool_bytes``)."""
-        n = kv_quant.pool_nbytes(self.k)
-        if self.v is not None:       # a latent pool has no value leaf
-            n += kv_quant.pool_nbytes(self.v)
-        if self.draft_k is not None:
-            n += (kv_quant.pool_nbytes(self.draft_k)
-                  + kv_quant.pool_nbytes(self.draft_v))
+        n = kv_quant.pool_nbytes(self.kv)
+        if self.draft_kv is not None:
+            n += kv_quant.pool_nbytes(self.draft_kv)
         return n
 
     def kv_stage_bytes(self) -> int:
@@ -391,12 +408,9 @@ class PagedKVPool:
 
     def kv_scale_bytes(self) -> int:
         """Per-page scale overhead bytes (0 for bf16)."""
-        n = kv_quant.scale_nbytes(self.k)
-        if self.v is not None:
-            n += kv_quant.scale_nbytes(self.v)
-        if self.draft_k is not None:
-            n += (kv_quant.scale_nbytes(self.draft_k)
-                  + kv_quant.scale_nbytes(self.draft_v))
+        n = kv_quant.scale_nbytes(self.kv)
+        if self.draft_kv is not None:
+            n += kv_quant.scale_nbytes(self.draft_kv)
         return n
 
     @property
@@ -449,82 +463,73 @@ class PagedKVPool:
 
     # ---- cross-replica page transfer (ISSUE 19, serving/handoff/) ----
 
-    def _leaf_items(self) -> List[Tuple[str, object]]:
-        """(wire name, device array) pairs of every storage leaf, in
-        wire order: plain pools contribute one leaf per cache, quantized
-        pools their value bytes AND per-page scale rows, draft caches
-        (speculation) ride along under their own names — exactly the
-        set a receiving pool must install for a migrated page to be
-        bit-identical to a locally prefilled one."""
-        items: List[Tuple[str, object]] = []
-        for name, pool in (("k", self.k), ("v", self.v),
-                           ("draft_k", self.draft_k),
-                           ("draft_v", self.draft_v)):
-            if pool is None:
-                continue
-            if kv_quant.is_quantized(pool):
-                items.append((name + ".q", pool.q))
-                items.append((name + ".scale", pool.scale))
-            else:
-                items.append((name, pool))
-        return items
-
     def export_pages(self, pages: Sequence[int]) -> Dict[str, np.ndarray]:
         """Gather ``pages`` from every storage leaf to the host: ONE
-        batched ``device_get`` over all leaves (k/v values, scale rows,
-        draft caches), so a multi-page export pays one transfer sync.
-        The caller must hold page refs on ``pages`` and serialize
-        against tick dispatch (the engine's ``_drive_lock``) — ticks
-        rebind the pool arrays with donated buffers."""
+        batched ``device_get`` over all leaves (values, scale rows, draft
+        cache), so a multi-page export pays one transfer sync.  What
+        comes back are the wire's LOGICAL leaves (ops/kv_quant.kv_to_leaves:
+        ``k``, ``v``, for a quantized pool ``k.q`` / ``k.scale`` / ``v.q`` /
+        ``v.scale``, a speculating pool's ``draft_*`` beside them), bytes
+        verbatim — exactly the set a receiving pool must install for a
+        migrated page to be bit-identical to a locally prefilled one;
+        the physical row never leaves this pool.  The caller must hold
+        page refs on ``pages`` and serialize against tick dispatch (the
+        engine's ``_drive_lock``) — ticks rebind the pool arrays with
+        donated buffers."""
+        assert not self.latent, "the handoff carries K/V pools"
         ids = np.asarray(list(pages), np.int32)
-        names, gathers = [], []
-        for name, arr in self._leaf_items():
-            names.append(name)
-            gathers.append(arr[:, ids])
-        host = jax.device_get(gathers)
-        return dict(zip(names, host))
+        pools = self._pools()
+        host = jax.device_get(
+            [jax.tree.map(lambda a: a[:, ids], pool) for _, pool, _ in pools])
+        leaves: Dict[str, np.ndarray] = {}
+        for (prefix, _, d), got in zip(pools, host):
+            leaves.update(kv_quant.kv_to_leaves(got, d, prefix))
+        return leaves
 
     def import_pages(self, pages: Sequence[int],
                      leaves: Dict[str, np.ndarray]) -> None:
         """Install exported leaf bytes into freshly allocated ``pages``
-        VERBATIM — quantized leaves set ``q`` and ``scale`` directly,
+        VERBATIM — quantized leaves set values and scales directly,
         never re-quantizing, so the imported page is byte-identical to
         the sender's (tests/test_handoff.py round-trip).  Leaf names,
-        dtypes and shapes must match this pool exactly (a bf16 pool
-        cannot install an int8 export; a speculating sender's draft
-        leaves need a speculating receiver).  Caller serializes against
-        tick dispatch, same as :meth:`export_pages`."""
+        dtypes and shapes must match this pool's logical leaves exactly
+        (a bf16 pool cannot install an int8 export; a speculating
+        sender's draft leaves need a speculating receiver).  Caller
+        serializes against tick dispatch, same as :meth:`export_pages`."""
         ids = np.asarray(list(pages), np.int32)
-        mine = dict(self._leaf_items())
-        if sorted(mine) != sorted(leaves):
+        quant = kv_quant.is_quantized(self.kv)
+        want: Dict[str, Tuple] = {}
+        for prefix, pool, d in self._pools():
+            arr = kv_quant.values_of(pool)
+            lead = (arr.shape[0], len(ids))
+            heads = arr.shape[-1] // (2 * d)
+            for side in "kv":
+                name = prefix + side + (".q" if quant else "")
+                want[name] = (arr.dtype, lead + (arr.shape[2], heads, d))
+                if quant:
+                    want[prefix + side + ".scale"] = (
+                        pool.scale.dtype, lead + (heads,))
+        if sorted(want) != sorted(leaves):
             raise ValueError(
                 f"handoff leaves {sorted(leaves)} do not match this "
-                f"pool's storage leaves {sorted(mine)} "
+                f"pool's storage leaves {sorted(want)} "
                 f"(kv_dtype={self.kv_dtype!r}, "
-                f"draft={'yes' if self.draft_k is not None else 'no'})")
-        for name, arr in mine.items():
+                f"draft={'yes' if self.draft_kv is not None else 'no'})")
+        for name, (dtype, shape) in want.items():
             val = leaves[name]
-            want_shape = arr.shape[:1] + (len(ids),) + arr.shape[2:]
-            if tuple(val.shape) != want_shape or val.dtype != arr.dtype:
+            if tuple(val.shape) != shape or val.dtype != dtype:
                 raise ValueError(
                     f"handoff leaf {name!r} is {val.dtype}{val.shape}, "
-                    f"pool needs {arr.dtype}{want_shape}")
+                    f"pool needs {dtype}{shape}")
 
-        def _install(pool, name):
-            if kv_quant.is_quantized(pool):
-                return kv_quant.QuantPagedKV(
-                    q=pool.q.at[:, ids].set(
-                        jnp.asarray(leaves[name + ".q"])),
-                    scale=pool.scale.at[:, ids].set(
-                        jnp.asarray(leaves[name + ".scale"])))
-            return pool.at[:, ids].set(jnp.asarray(leaves[name]))
+        def _install(pool, prefix):
+            rows = kv_quant.kv_from_leaves(leaves, quant, prefix)
+            return jax.tree.map(
+                lambda a, r: a.at[:, ids].set(jnp.asarray(r)), pool, rows)
 
-        self.k = _install(self.k, "k")
-        if self.v is not None:
-            self.v = _install(self.v, "v")
-        if self.draft_k is not None:
-            self.draft_k = _install(self.draft_k, "draft_k")
-            self.draft_v = _install(self.draft_v, "draft_v")
+        self.kv = _install(self.kv, "")
+        if self.draft_kv is not None:
+            self.draft_kv = _install(self.draft_kv, "draft_")
 
 
 class _TrieNode:
@@ -1449,7 +1454,7 @@ class ContinuousBatchingEngine:
                 lambda: make_ragged_tick_fn(
                     self.cfg, self.draft_cfg, self.spec_k,
                     pre_rows, tp=self._tp, mesh=self.mesh),
-                donate_argnums=(2, 3, 4, 5))
+                donate_argnums=(2, 3))
         else:
             statics = ("engine_ragged_tick", self.max_slots,
                        self.pages_per_seq, self.page_size,
@@ -1461,7 +1466,7 @@ class ContinuousBatchingEngine:
                 lambda: make_ragged_tick_fn(
                     self.cfg, None, 0, pre_rows, tp=self._tp,
                     mesh=self.mesh),
-                donate_argnums=(1, 2))
+                donate_argnums=(1,))
         self._ragged_fns[pre_rows] = fn
         return fn
 
@@ -1485,7 +1490,7 @@ class ContinuousBatchingEngine:
             self.cfg, "engine_chained_tick", statics,
             lambda: make_chained_tick_fn(self.cfg, self.pipeline_depth,
                                          tp=self._tp, mesh=self.mesh),
-            donate_argnums=(1, 2))
+            donate_argnums=(1,))
         return self._chained_fn
 
     def _score_chunk(self, rows: int, kv_pages: int):
@@ -1507,39 +1512,37 @@ class ContinuousBatchingEngine:
 
         ovl = self._overlap
         ppc = self._ppc
-        latent = self.pool.latent    # one pool leaf, pool_v is None
 
-        def chunk(params, tokens, start, bt, pool_k, pool_v, targets):
+        def chunk(params, tokens, start, bt, pool_kv, targets):
             with tp_overlap_mod.activate(ovl), pp_serve_mod.activate(ppc):
-                out, pools = model_forward(
+                out, pool_kv = model_forward(
                     cfg, params, tokens,
                     position_ids=start[:, None] + jnp.arange(rows)[None, :],
                     rope_cache=make_rope_cache(cfg),
-                    kv_caches=pool_k if latent else (pool_k, pool_v),
+                    kv_caches=pool_kv,
                     paged=PagedState(bt, start),
                     logits_postprocess=True,
                 )
-            pool_k, pool_v = (pools, None) if latent else pools
             lp = gen._gather_token_log_probs(out, targets)
-            return pool_k, pool_v, lp[0]
+            return pool_kv, lp[0]
 
         def chunk_spec(params, draft_params, tokens, start, bt,
-                       pool_k, pool_v, draft_k, draft_v, targets):
+                       pool_kv, draft_kv, targets):
             # target chunk plus the DRAFT model's chunk through the same
             # block table: a speculating engine keeps both caches filled
             # for every prefilled page, so trie-matched pages (prefix hits,
             # preemption resume) carry valid draft K/V too
-            res = chunk(params, tokens, start, bt, pool_k, pool_v, targets)
+            pool_kv, lp = chunk(params, tokens, start, bt, pool_kv, targets)
             with tp_overlap_mod.activate(ovl), pp_serve_mod.activate(ppc):
-                _, (draft_k, draft_v) = model_forward(
+                _, draft_kv = model_forward(
                     draft_cfg, draft_params, tokens,
                     position_ids=start[:, None] + jnp.arange(rows)[None, :],
                     rope_cache=make_rope_cache(draft_cfg),
-                    kv_caches=(draft_k, draft_v),
+                    kv_caches=draft_kv,
                     paged=PagedState(bt, start),
                     logits_postprocess=False,
                 )
-            return res[:2] + (draft_k, draft_v) + res[2:]
+            return pool_kv, draft_kv, lp
 
         # the True is the key's log-prob flag from when the program had a
         # variant without scores: kept so the compile cache's entries hold
@@ -1550,10 +1553,10 @@ class ContinuousBatchingEngine:
             statics += ("spec", gen.config_fingerprint(draft_cfg))
             fn = gen.cached_jit(self.cfg, "engine_prefill_chunk", statics,
                                 lambda: chunk_spec,
-                                donate_argnums=(5, 6, 7, 8))
+                                donate_argnums=(5, 6))
         else:
             fn = gen.cached_jit(self.cfg, "engine_prefill_chunk", statics,
-                                lambda: chunk, donate_argnums=(4, 5))
+                                lambda: chunk, donate_argnums=(4,))
         self._chunk_fns[key] = fn
         return fn
 
@@ -1563,24 +1566,19 @@ class ContinuousBatchingEngine:
         if self._copy_fn is not None:
             return self._copy_fn
 
-        def copy(pool_k, pool_v, src, dst):
+        def copy(pool_kv, src, dst):
+            # a page id is the same page in every layer, whatever the row:
             # tree-mapped so quantized pools clone the page's scale row
-            # together with its values (plain pools: one leaf, the
-            # original expression bitwise) — a COW page is byte-identical
+            # together with its values — a COW page is byte-identical
             # to its source in BOTH leaves, so the refeed rewrite sees
             # exactly the shared page's quantization state
-            pool_k = jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]), pool_k)
-            pool_v = jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]), pool_v)
-            return pool_k, pool_v
+            return jax.tree.map(
+                lambda a: a.at[:, dst].set(a[:, src]), pool_kv)
 
-        def copy_spec(pool_k, pool_v, draft_k, draft_v, src, dst):
+        def copy_spec(pool_kv, draft_kv, src, dst):
             # COW must clone the page in BOTH caches: the refeed tick
             # rewrites the draft K/V at the same position too
-            pool_k, pool_v = copy(pool_k, pool_v, src, dst)
-            draft_k, draft_v = copy(draft_k, draft_v, src, dst)
-            return pool_k, pool_v, draft_k, draft_v
+            return copy(pool_kv, src, dst), copy(draft_kv, src, dst)
 
         statics = ("engine_copy_page", self.pool.num_pages, self.page_size,
                    self.pool.kv_statics, self._mesh_statics)
@@ -1588,11 +1586,11 @@ class ContinuousBatchingEngine:
             statics += ("spec", gen.config_fingerprint(self.draft_cfg))
             self._copy_fn = gen.cached_jit(
                 self.cfg, "engine_copy_page", statics, lambda: copy_spec,
-                donate_argnums=(0, 1, 2, 3))
+                donate_argnums=(0, 1))
         else:
             self._copy_fn = gen.cached_jit(
                 self.cfg, "engine_copy_page", statics, lambda: copy,
-                donate_argnums=(0, 1))
+                donate_argnums=(0,))
         return self._copy_fn
 
     # -- request lifecycle -------------------------------------------------
@@ -2001,14 +1999,13 @@ class ContinuousBatchingEngine:
             # device copy OUTSIDE the lock (driver thread; serialized with
             # ticks via _drive_lock), then drop our ref on the shared page
             if self.spec_k:
-                (self.pool.k, self.pool.v, self.pool.draft_k,
-                 self.pool.draft_v) = self._copy_page()(
-                    self.pool.k, self.pool.v, self.pool.draft_k,
-                    self.pool.draft_v, self._asarray(np.int32(src)),
+                self.pool.kv, self.pool.draft_kv = self._copy_page()(
+                    self.pool.kv, self.pool.draft_kv,
+                    self._asarray(np.int32(src)),
                     self._asarray(np.int32(dst)))
             else:
-                self.pool.k, self.pool.v = self._copy_page()(
-                    self.pool.k, self.pool.v, self._asarray(np.int32(src)),
+                self.pool.kv = self._copy_page()(
+                    self.pool.kv, self._asarray(np.int32(src)),
                     self._asarray(np.int32(dst)))
         with self._lock:
             if cow:
@@ -2362,23 +2359,19 @@ class ContinuousBatchingEngine:
                                 rows=rows, tp=self._tp,
                                 trace_id=req.trace_id):
                 if self.spec_k:
-                    out = self._score_chunk(rows, kv_pages)(
+                    (self.pool.kv, self.pool.draft_kv,
+                     lp) = self._score_chunk(rows, kv_pages)(
                         self.params, self.draft_params,
                         self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
-                        self._asarray(bt), self.pool.k, self.pool.v,
-                        self.pool.draft_k, self.pool.draft_v,
-                        self._asarray(targets))
-                    (self.pool.k, self.pool.v, self.pool.draft_k,
-                     self.pool.draft_v) = out[:4]
-                    out = (self.pool.k, self.pool.v) + out[4:]
+                        self._asarray(bt), self.pool.kv,
+                        self.pool.draft_kv, self._asarray(targets))
                 else:
-                    out = self._score_chunk(rows, kv_pages)(
+                    self.pool.kv, lp = self._score_chunk(rows, kv_pages)(
                         self.params, self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
                         self._asarray(bt),
-                        self.pool.k, self.pool.v, self._asarray(targets))
-            self.pool.k, self.pool.v, lp = out
+                        self.pool.kv, self._asarray(targets))
             if req.prompt_log_probs is None:
                 req.prompt_log_probs = []
             req.prompt_log_probs.extend(
@@ -2836,9 +2829,9 @@ class ContinuousBatchingEngine:
                             host_gap_ms=(None if gap is None
                                          else round(gap * 1e3, 4))), \
                 self._overlap_span(), self._pp_span():
-            (self.pool.k, self.pool.v, ctoks, clogps, new_pos, new_tok,
+            (self.pool.kv, ctoks, clogps, new_pos, new_tok,
              new_steps, new_done, new_rem) = self._chained_tick()(
-                self.params, self.pool.k, self.pool.v, bt, pos, toks,
+                self.params, self.pool.kv, bt, pos, toks,
                 keys, steps, temp, tk, tp, term_d, mode_d, done_d,
                 rem_d)
             self._last_dispatch_end = time.monotonic()
@@ -3103,20 +3096,19 @@ class ContinuousBatchingEngine:
                 tick_fn = self._ragged_tick(n_bucket)
                 moe = ()
                 if self.spec_k:
-                    (self.pool.k, self.pool.v, self.pool.draft_k,
-                     self.pool.draft_v, out_tok, out_lp, acc, cnt,
+                    (self.pool.kv, self.pool.draft_kv,
+                     out_tok, out_lp, acc, cnt,
                      new_pos, next_tok, new_steps) = tick_fn(
                         self.params, self.draft_params,
-                        self.pool.k, self.pool.v,
-                        self.pool.draft_k, self.pool.draft_v,
+                        self.pool.kv, self.pool.draft_kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         self._asarray(k_eff), *pre_args)
                     spec = (acc, cnt, k_eff)
                     del acc, cnt
                 else:
-                    (self.pool.k, self.pool.v, next_tok, out_lp,
+                    (self.pool.kv, next_tok, out_lp,
                      new_pos, new_steps, *moe) = tick_fn(
-                        self.params, self.pool.k, self.pool.v,
+                        self.params, self.pool.kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         *carry, *pre_args)
                     out_tok, spec = next_tok, None

@@ -92,28 +92,31 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     Returned signature, ``spec_k >= 1`` (draft model present)::
 
-        (params, draft_params, pool_k, pool_v, draft_k, draft_v,
+        (params, draft_params, pool_kv, draft_kv,
          block_tables, positions, tokens, req_keys, steps,
          temperature, top_k, top_p, k_eff
          [, pre_tok, pre_pos, pre_tables, pre_index, pre_hor])
-        -> (pool_k, pool_v, draft_k, draft_v,
+        -> (pool_kv, draft_kv,
             emit [b, K+1], emit_logp [b, K+1], accepted [b], counts [b],
             new_pos, new_tok, new_steps)
 
     and ``spec_k == 0`` (no draft args, plain per-slot sampling)::
 
-        (params, pool_k, pool_v, block_tables, positions, tokens,
+        (params, pool_kv, block_tables, positions, tokens,
          req_keys, steps, temperature, top_k, top_p, carry_tok, carried
          [, pre_tok, pre_pos, pre_tables, pre_index, pre_hor])
-        -> (pool_k, pool_v, next_tok, logp, new_pos, new_steps
+        -> (pool_kv, next_tok, logp, new_pos, new_steps
             [, moe_stats])
+
+    ``pool_kv`` is the paged pool, ONE leaf over all layers whose row
+    ops/kv_quant.py owns (K/V or latent); the engine donates it and every
+    layer updates its pages in place.
 
     ``moe_stats`` exists iff the model has experts: ``[2]`` float32, the
     router's assignments (rows x topk, every row the program ran, dead
     padding rows too: the grouped GEMM runs them) and the distinct experts
     that received a row, both summed over the expert layers.  It rides
     the tick's one fetch to the engine's ``mlt_engine_moe_*`` counters.
-    A latent-attention model's ``pool_v`` is None.
 
     ``carry_tok`` / ``carried`` (``[b]`` int32 / bool) feed a row its
     token device to device: the engine launches this tick before it has
@@ -154,26 +157,24 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
     scope_d = "draft-fwd" if tp == 1 else f"draft-fwd-tp{tp}"
 
     moe = cfg.model.num_experts is not None
-    latent = cfg.model.mla     # ONE pool leaf; pool_v is None throughout
 
-    def target_forward(params, pool_k, pool_v, tbl, idx, pos, tok, hor):
+    def target_forward(params, pool_kv, tbl, idx, pos, tok, hor):
         """ONE target forward over the full ragged batch — the single
         attention launch of the tick.  ``tbl`` is the tick's compressed
         unique-table set, ``idx`` each row's table.  Returns the router's
         aux vector as well (models/moe.py)."""
         with jax.named_scope(scope_t):
-            logits, pools, aux = model_forward(
+            logits, pool_kv, aux = model_forward(
                 cfg, params, tok[:, None],
                 position_ids=pos[:, None],
                 rope_cache=make_rope_cache(cfg),
-                kv_caches=pool_k if latent else (pool_k, pool_v),
+                kv_caches=pool_kv,
                 paged=PagedState(tbl, pos, hor, idx),
                 return_aux=True,
             )
-        pool_k, pool_v = (pools, None) if latent else pools
-        return logits[:, 0], pool_k, pool_v, aux
+        return logits[:, 0], pool_kv, aux
 
-    def spec_tick(params, draft_params, pool_k, pool_v, draft_k, draft_v,
+    def spec_tick(params, draft_params, pool_kv, draft_kv,
                   block_tables, positions, tokens, req_keys, steps,
                   temperature, top_k, top_p, k_eff,
                   pre_tok=None, pre_pos=None, pre_tables=None,
@@ -191,10 +192,10 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
         if prefill_rows:
             d_idx = jnp.where(pre_index >= 0, 1 + pre_index, 0)
             with jax.named_scope(scope_d):
-                _, (draft_k, draft_v) = model_forward(
+                _, draft_kv = model_forward(
                     draft_cfg, draft_params, pre_tok[:, None],
                     position_ids=pre_pos[:, None], rope_cache=rope_d,
-                    kv_caches=(draft_k, draft_v),
+                    kv_caches=draft_kv,
                     paged=PagedState(
                         jnp.concatenate([null_tbl, pre_tables]),
                         pre_pos, pre_hor, d_idx))
@@ -206,17 +207,17 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
         # all-accepted-plus-bonus tick leaves a permanent hole in the
         # draft cache at d_K's position (the PR 9 acceptance-decay bug).
         def draft_step(carry, j):
-            tok, dk, dv = carry
+            tok, dkv = carry
             pos_j = positions + j
             # rows past their own depth write to the NULL page: a clipped
             # write at the end of the sequence budget would otherwise land
             # inside the row's LAST real page and corrupt live KV
             bt_j = jnp.where((j <= k_eff)[:, None], block_tables, 0)
             with jax.named_scope(scope_d):
-                logits, (dk, dv) = model_forward(
+                logits, dkv = model_forward(
                     draft_cfg, draft_params, tok[:, None],
                     position_ids=pos_j[:, None], rope_cache=rope_d,
-                    kv_caches=(dk, dv),
+                    kv_caches=dkv,
                     paged=PagedState(bt_j, pos_j))
             filt, greedy = filtered_logits_per_slot(
                 logits[:, -1], top_k=top_k, top_p=top_p,
@@ -226,10 +227,10 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             drawn = jax.vmap(lambda k_, row: jax.random.categorical(k_, row))(
                 keys_j, filt)
             nxt = jnp.where(greedy_row, greedy, drawn).astype(jnp.int32)
-            return (nxt, dk, dv), (nxt, filt)
+            return (nxt, dkv), (nxt, filt)
 
-        (_, draft_k, draft_v), (draft_seq, q_seq) = jax.lax.scan(
-            draft_step, (tokens, draft_k, draft_v), jnp.arange(K + 1))
+        (_, draft_kv), (draft_seq, q_seq) = jax.lax.scan(
+            draft_step, (tokens, draft_kv), jnp.arange(K + 1))
         draft_toks = jnp.moveaxis(draft_seq[:K], 0, 1)   # [b, K]
         q_filt = jnp.moveaxis(q_seq[:K], 0, 1)           # [b, K, v]
 
@@ -263,9 +264,8 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_tok, all_pos, all_idx, all_hor = (
                 flat_tok, flat_pos, flat_idx, flat_hor)
             all_tbl = jnp.concatenate([null_tbl, block_tables])
-        out, pool_k, pool_v, _ = target_forward(
-            params, pool_k, pool_v, all_tbl, all_idx, all_pos, all_tok,
-            all_hor)
+        out, pool_kv, _ = target_forward(
+            params, pool_kv, all_tbl, all_idx, all_pos, all_tok, all_hor)
         t_logits = out[: b * S].reshape(b, S, -1)      # [b, K+1, v_padded]
 
         rep = lambda x: jnp.repeat(x, S, axis=0)  # noqa: E731
@@ -292,10 +292,10 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
         new_steps = steps + counts
         new_tok = jnp.take_along_axis(
             emit, (counts - 1)[:, None], axis=1)[:, 0]
-        return (pool_k, pool_v, draft_k, draft_v, emit, emit_logp,
+        return (pool_kv, draft_kv, emit, emit_logp,
                 accepted, counts, new_pos, new_tok, new_steps)
 
-    def tick(params, pool_k, pool_v, block_tables, positions, tokens,
+    def tick(params, pool_kv, block_tables, positions, tokens,
              req_keys, steps, temperature, top_k, top_p,
              carry_tok, carried,
              pre_tok=None, pre_pos=None, pre_tables=None,
@@ -317,16 +317,15 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_tok, all_pos, all_idx, all_hor = (
                 tokens, positions, idx, hor)
             all_tbl = jnp.concatenate([null_tbl, block_tables])
-        out, pool_k, pool_v, aux = target_forward(
-            params, pool_k, pool_v, all_tbl, all_idx, all_pos, all_tok,
-            all_hor)
+        out, pool_kv, aux = target_forward(
+            params, pool_kv, all_tbl, all_idx, all_pos, all_tok, all_hor)
         last = out[:b]
         keys = jax.vmap(jax.random.fold_in)(req_keys, steps)
         next_tok = sample_per_slot(
             keys, last, top_k=top_k, top_p=top_p,
             temperature=temperature, vocab_size=cfg.model.vocab_size)
         logp = gen._gather_token_log_probs(last, next_tok)
-        res = (pool_k, pool_v, next_tok, logp, positions + 1, steps + 1)
+        res = (pool_kv, next_tok, logp, positions + 1, steps + 1)
         return res + (aux[2:],) if moe else res
 
     base_fn = spec_tick if K else tick
@@ -378,10 +377,10 @@ def make_chained_tick_fn(cfg, chain: int, *, tp: int = 1, mesh=None):
 
     Signature::
 
-        (params, pool_k, pool_v, block_tables, positions, tokens,
+        (params, pool_kv, block_tables, positions, tokens,
          req_keys, steps, temperature, top_k, top_p,
          term_ids, stop_modes, done, remaining)
-        -> (pool_k, pool_v, toks [chain, b], logps [chain, b],
+        -> (pool_kv, toks [chain, b], logps [chain, b],
             new_pos, new_tok, new_steps, new_done, new_remaining)
 
     The final carry is the NEXT launch's input — consecutive chains hand
@@ -397,18 +396,18 @@ def make_chained_tick_fn(cfg, chain: int, *, tp: int = 1, mesh=None):
     vocab = cfg.model.vocab_size
     scope_t = "decode-fwd" if tp == 1 else f"decode-fwd-tp{tp}"
 
-    def target_forward(params, pool_k, pool_v, tbl, idx, pos, tok, hor):
+    def target_forward(params, pool_kv, tbl, idx, pos, tok, hor):
         with jax.named_scope(scope_t):
-            logits, (pool_k, pool_v) = model_forward(
+            logits, pool_kv = model_forward(
                 cfg, params, tok[:, None],
                 position_ids=pos[:, None],
                 rope_cache=make_rope_cache(cfg),
-                kv_caches=(pool_k, pool_v),
+                kv_caches=pool_kv,
                 paged=PagedState(tbl, pos, hor, idx),
             )
-        return logits[:, 0], pool_k, pool_v
+        return logits[:, 0], pool_kv
 
-    def chained(params, pool_k, pool_v, block_tables, positions, tokens,
+    def chained(params, pool_kv, block_tables, positions, tokens,
                 req_keys, steps, temperature, top_k, top_p,
                 term_ids, stop_modes, done, remaining):
         b = tokens.shape[0]
@@ -418,13 +417,13 @@ def make_chained_tick_fn(cfg, chain: int, *, tp: int = 1, mesh=None):
         live_idx = 1 + jnp.arange(b, dtype=jnp.int32)
 
         def body(carry, _):
-            pool_k, pool_v, pos, tok, stp, dn, rem = carry
+            pool_kv, pos, tok, stp, dn, rem = carry
             # frozen rows null-route (reads garbage, writes page 0) —
             # exactly how dead prefill rows are already handled
             idx = jnp.where(dn, 0, live_idx)
             hor = row_horizons(pos)
-            out, pk, pv = target_forward(
-                params, pool_k, pool_v, all_tbl, idx, pos, tok, hor)
+            out, pool_kv = target_forward(
+                params, pool_kv, all_tbl, idx, pos, tok, hor)
             keys = jax.vmap(jax.random.fold_in)(req_keys, stp)
             next_tok = sample_per_slot(
                 keys, out, top_k=top_k, top_p=top_p,
@@ -446,15 +445,13 @@ def make_chained_tick_fn(cfg, chain: int, *, tp: int = 1, mesh=None):
             pos2 = jnp.where(dn, pos, pos + 1)
             tok2 = jnp.where(dn, tok, next_tok)
             stp2 = jnp.where(dn, stp, stp + 1)
-            return (pk, pv, pos2, tok2, stp2, dn2, rem2), (next_tok, logp)
+            return (pool_kv, pos2, tok2, stp2, dn2, rem2), (next_tok, logp)
 
-        carry0 = (pool_k, pool_v, positions, tokens, steps, done,
-                  remaining)
+        carry0 = (pool_kv, positions, tokens, steps, done, remaining)
         carry, (toks, logps) = jax.lax.scan(
             body, carry0, None, length=chain)
-        (pool_k, pool_v, new_pos, new_tok, new_steps, new_done,
-         new_rem) = carry
-        return (pool_k, pool_v, toks, logps, new_pos, new_tok,
+        pool_kv, new_pos, new_tok, new_steps, new_done, new_rem = carry
+        return (pool_kv, toks, logps, new_pos, new_tok,
                 new_steps, new_done, new_rem)
 
     if ovl is None and ppc is None:

@@ -265,8 +265,9 @@ def model_forward(
 ):
     """GPTModel.forward analog (gpt_model.py:45-124).
 
-    ``paged`` (ops/paged_attention.PagedState): ``kv_caches`` is the stacked
-    [L, num_pages, page_size, nkv, d] page pool instead of a dense cache, and
+    ``paged`` (ops/paged_attention.PagedState): ``kv_caches`` is the paged
+    pool, one leaf [L, num_pages, page_size, row] (ops/kv_quant.py owns the
+    row) that every layer updates in place, instead of a dense cache, and
     every batch row decodes one token at its own ``paged.positions`` entry
     (the serving engine's fused tick, generation/engine.py).
 
@@ -300,9 +301,9 @@ def model_forward(
     else:
         new_caches, moe_aux = kv_caches, None
         for stack, first_layer in layer_stacks(cfg, params):
-            # a dense prefix exists with latent attention only here, whose
-            # pool is one array handed from stack to stack
-            assert first_layer == 0 or kv_caches is None or cfg.model.mla
+            # a paged pool is one leaf over all layers, handed from stack
+            # to stack; a dense cache is a stack's own
+            assert first_layer == 0 or kv_caches is None or paged is not None
             hidden, new_caches, aux = transformer_forward(
                 cfg, stack, hidden,
                 rope=rope_cache, position_ids=position_ids,

@@ -238,9 +238,10 @@ def attention_sublayer(
     """ParallelAttention analog (transformer.py:280-657).
 
     ``paged`` (ops/paged_attention.PagedState) switches the incremental-decode
-    branch to the block-table page pool: ``kv_cache`` is then the per-layer
-    [num_pages, page_size, nkv, d] pair and each row writes/attends at its own
-    position — the continuous-batching engine's fused tick.
+    branch to the block-table page pool: ``kv_cache`` is then a
+    :class:`LayerPool` — the whole layered pool and this layer's index —
+    and each row writes/attends at its own position in this layer's pages,
+    in place: the continuous-batching engine's fused tick.
 
     Returns (output [b, s, h], new_kv_cache).
     """
@@ -285,8 +286,8 @@ def attention_sublayer(
             paged_attention_ragged,
         )
 
-        pk, pv = kv_cache
-        page_size = kv_quant.page_size_of(pk)
+        pool, layer = kv_cache
+        page_size = kv_quant.page_size_of(pool)
         pos = paged.positions
         # ragged compressed tables (ISSUE 11): block_tables holds the
         # tick's UNIQUE tables and table_index maps rows onto them; the
@@ -303,34 +304,35 @@ def attention_sublayer(
                              row_tables.shape[1] - 1)
         page_ids = jnp.take_along_axis(row_tables, page_slot, axis=1)
         offs = wpos % page_size
-        # plain pools: the original scatter, byte for byte; quantized
-        # pools (--kv_dtype int8/fp8): page-granular quantizing write
-        # with per-page, per-head scales (ops/kv_quant.paged_write)
-        pk = kv_quant.paged_write(pk, page_ids, offs, k)
-        pv = kv_quant.paged_write(pv, page_ids, offs, v)
-        new_cache = (pk, pv)
+        # ONE scatter of whole rows, a head's key and value side by side
+        # (ops/kv_quant.py owns the row), into this layer's pages of the
+        # flat pool; quantized pools (--kv_dtype int8/fp8): page-granular
+        # quantizing write with per-page, per-head scales
+        pool = kv_quant.paged_write(
+            pool, page_ids, offs, kv_quant.pack_kv(k, v), layer)
+        new_cache = pool
         if s == 1 and paged.horizons is not None:
             # ragged tick (ISSUE 11): one launch for a mixed
             # decode/verify/prefill row batch; each row carries its own
             # data-carried kv horizon (0 = dead padding row) and an index
             # into the tick's unique block tables
             ctx = paged_attention_ragged(
-                q, pk, pv, paged.block_tables, paged.table_index, pos,
+                q, pool, paged.block_tables, paged.table_index, pos,
                 paged.horizons,
                 scale=scale, sliding_window=m.sliding_window_size,
-                use_kernel=cfg.training.use_flash_attn,
+                use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
         elif s == 1:
             ctx = paged_attention_decode(
-                q, pk, pv, paged.block_tables, pos, scale=scale,
+                q, pool, paged.block_tables, pos, scale=scale,
                 sliding_window=m.sliding_window_size,
-                use_kernel=cfg.training.use_flash_attn,
+                use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
         else:
             ctx = paged_attention_prefill(
-                q, pk, pv, paged.block_tables, pos, scale=scale,
+                q, pool, paged.block_tables, pos, scale=scale,
                 sliding_window=m.sliding_window_size,
-                use_kernel=cfg.training.use_flash_attn,
+                use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
     elif kv_cache is not None:
         # Incremental decode: write current k/v at cache_index, attend to the
@@ -373,13 +375,14 @@ def attention_sublayer(
     return out, new_cache
 
 
-class LatentCache(NamedTuple):
-    """The whole latent pool ``[layers, pages, page, width]`` and the layer
-    this sublayer writes and reads: the pool rides the layer scan's carry
-    and every layer updates its own slice in place (a scan over stacked
-    slices copies the whole pool out and back each tick)."""
+class LayerPool(NamedTuple):
+    """The whole paged pool ``[layers, pages, page, row]`` (a K/V pool or a
+    latent one; ops/kv_quant.py owns the row) and the layer of it this
+    sublayer writes and reads: the pool rides the layer scan's carry and
+    every layer updates its own pages in place (a scan over stacked slices
+    copies the whole pool out and back each tick)."""
 
-    pool: jax.Array
+    pool: Any
     layer: jax.Array
 
 
@@ -462,20 +465,21 @@ def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
     return out, new_pool
 
 
-def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LatentCache,
+def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LayerPool,
                paged, scale):
     """The absorbed form against the latent pool.  Every fed token is one
     row at its own position, whatever the call's shape (a tick's ``[R, 1]``
     rows, a scoring chunk's ``[b, s]``): write the rows' ``[c_kv | k_rope]``
     through the block table into this layer's slice of the pool, then one
     ragged paged attention with the pool as key AND value."""
+    from megatron_llm_tpu.ops import kv_quant
     from megatron_llm_tpu.ops.paged_attention import paged_attention_ragged
 
     m = cfg.model
     b, s, n, nope = q_nope.shape
     r = m.kv_lora_rank
     pool, layer = cache
-    n_layers, n_pages, page_size, width = pool.shape
+    page_size, width = pool.shape[2:]
     rows = b * s
     pos = (paged.positions[:, None] + jnp.arange(s)[None, :]).reshape(rows)
     if paged.table_index is not None:
@@ -484,9 +488,6 @@ def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LatentCache,
     else:
         tables = paged.block_tables
         index = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-    # this layer's pages of the flat pool: one table offset, no slice
-    tables = tables + layer * n_pages
-    flat = pool.reshape(n_layers * n_pages, page_size, 1, width)
     row_tables = tables[index]                                  # [rows, W]
     # clip: as the K/V pair's write (attention_sublayer), stray rows land
     # in pages that are never attended
@@ -496,19 +497,20 @@ def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LatentCache,
     pad = width - latent.shape[-1]          # whole 128-lane rows (the pool)
     if pad:
         latent = jnp.pad(latent, ((0, 0),) * 3 + ((0, pad),))
-    flat = flat.at[page_ids, (pos % page_size)[:, None]].set(
-        latent.astype(flat.dtype))
+    # this layer's pages of the flat pool, in place: no slice of a layer
+    pool = kv_quant.paged_write(
+        pool, page_ids, (pos % page_size)[:, None], latent, layer)
     q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_ukv[..., :nope])
     q_abs = jnp.concatenate([q_lat, q_rope], axis=-1).reshape(rows, 1, n, -1)
     if pad:
         q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
     horizons = paged.horizons if paged.horizons is not None else pos + 1
     u = paged_attention_ragged(
-        q_abs, flat, None, tables, index, pos, horizons, scale=scale,
-        use_kernel=cfg.training.use_flash_attn)
+        q_abs, pool, tables, index, pos, horizons, scale=scale,
+        use_kernel=cfg.training.use_flash_attn, layer=layer, latent=True)
     ctx = jnp.einsum("bsnr,rnd->bsnd", u[..., :r].reshape(b, s, n, r),
                      w_ukv[..., nope:])
-    return ctx, flat.reshape(pool.shape)
+    return ctx, pool
 
 
 
@@ -712,11 +714,12 @@ def transformer_forward(
     enc_bias=None,
     dropout_key=None,
     deterministic: bool = True,
-    kv_caches=None,        # stacked [L, ...] pair, or None
+    kv_caches=None,        # dense: stacked [L, ...] pair; paged: the pool
     cache_index=None,
     paged=None,
     sp_constraint=None,
     layer_offset: int = 0,
+    pool_first_layer=0,
 ):
     """Run the stacked layers (ParallelTransformer, transformer.py:974-1347).
 
@@ -725,15 +728,20 @@ def transformer_forward(
     per-layer inspection).
     Returns (hidden, new_kv_caches, aux) — ``aux`` is the summed MoE router
     loss pair [2] (load-balance, z), zeros for dense models.
+
+    ``paged``: ``kv_caches`` is a paged pool, ONE leaf over all its layers
+    (ops/kv_quant.py: K/V or latent), whose layer 0 is the model's layer
+    ``pool_first_layer`` (a pipeline stage hands its own slice).  It rides
+    the scan's CARRY and every layer writes and reads its own pages of it
+    in place (:class:`LayerPool`); with the engine's donated buffers no
+    copy of the pool or of a layer's slice exists in the tick.  The dense
+    incremental cache (``cache_index``) is a stacked pair scanned per layer.
     """
     num_layers = jax.tree_util.tree_leaves(stacked_layers)[0].shape[0]
     rates = _lima_rates(cfg, cfg.model.depth)
-    # a latent pool (MLA through the engine) is ONE array over all layers
-    # and rides the carry: each layer updates its own slice in place
-    # (LatentCache); K/V pairs are scanned as stacked per-layer slices
-    latent = paged is not None and cfg.model.mla
-    pool = kv_caches if latent else None
-    if latent:
+    in_carry = paged is not None and kv_caches is not None
+    pool = kv_caches if in_carry else None
+    if in_carry:
         kv_caches = None
     # the serving tick does not scan the expert weights: a scanned slice
     # that feeds the grouped kernel is a copy of the layer's experts (2.4 GB
@@ -754,8 +762,8 @@ def transformer_forward(
     def one_layer(carry, xs):
         carry_hidden, pool = carry
         layer_params, layer_idx, cache = xs
-        if latent:
-            cache = LatentCache(pool, layer_idx)
+        if in_carry:
+            cache = LayerPool(pool, layer_idx - pool_first_layer)
         if all_experts is not None:
             layer_params = {**layer_params, "moe": {
                 **layer_params["moe"], "experts": moe_mod.StackedExperts(
@@ -773,7 +781,7 @@ def transformer_forward(
             kv_cache=cache, cache_index=cache_index, paged=paged,
             sp_constraint=sp_constraint,
         )
-        if latent:
+        if in_carry:
             pool, new_cache = new_cache, None
         return (out, pool), (new_cache, aux)
 
@@ -791,7 +799,7 @@ def transformer_forward(
         (hidden, pool), (new_caches, aux_stack) = jax.lax.scan(
             body, (hidden, pool), (stacked_layers, layer_ids, kv_caches)
         )
-        return hidden, pool if latent else new_caches, aux_stack.sum(0)
+        return hidden, pool if in_carry else new_caches, aux_stack.sum(0)
     else:
         from megatron_llm_tpu.models.moe import zero_aux
 
@@ -804,7 +812,7 @@ def transformer_forward(
                 (hidden, pool), (layer_p, layer_ids[i], cache))
             new_caches.append(nc)
             aux_total = aux_total + aux
-        if latent:
+        if in_carry:
             new_caches = pool
         elif kv_caches is not None:
             new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)

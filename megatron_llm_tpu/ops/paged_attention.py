@@ -1,11 +1,15 @@
 """Paged KV-cache decode attention: block-table gather + masked softmax.
 
 The serving engine (generation/engine.py) stores the KV cache as a pool of
-fixed-size pages ``[num_pages, page_size, n_kv_heads, head_dim]`` shared by
-all in-flight sequences; each sequence owns an ordered list of page ids (its
-*block table*).  This module computes one decode step of attention for a
-batch of sequences at heterogeneous positions — the Ragged-Paged-Attention
-decomposition (PAPERS.md): a single fused program per tick regardless of the
+fixed-size pages shared by all in-flight sequences, ONE leaf ``[layers,
+num_pages, page_size, row]`` whose row ops/kv_quant.py owns (a head's key
+and value side by side; a latent row for MLA); each sequence owns an
+ordered list of page ids (its *block table*).  Every function here takes
+that leaf — one layer's ``[num_pages, page_size, row]``, or the layered
+pool with ``layer=`` naming the caller's — and reads it in place.  This
+module computes one decode step of attention for a batch of sequences at
+heterogeneous positions — the Ragged-Paged-Attention decomposition
+(PAPERS.md): a single fused program per tick regardless of the
 per-sequence context lengths.
 
 Two implementations with the same fp32-softmax numerics:
@@ -75,36 +79,31 @@ class PagedState(NamedTuple):
     table_index: Optional[jax.Array] = None  # [R] int32 into block_tables
 
 
-def paged_gather_kv(k_pool, v_pool, block_tables: jax.Array,
-                    dtype=None):
-    """Dense [b, max_pages*page_size, nkv, d] view of each row's pages.
+def paged_gather_kv(pool, block_tables: jax.Array, d: int, dtype=None,
+                    layer=None, latent: bool = False):
+    """Dense (keys, values), each ``[b, max_pages*page_size, nkv, d]``, of
+    each row's pages: the logical view of the pool's row.
 
     The fallback's materialized gather — the tensor the Pallas kernel
     exists to avoid.  Quantized pools (ops/kv_quant.QuantPagedKV)
     dequantize at the gather, into ``dtype`` (the query/compute dtype);
-    plain pools return the original gather bitwise."""
-    if kv_quant.is_quantized(k_pool):
-        return (kv_quant.dequant_gather(k_pool, block_tables, dtype),
-                kv_quant.dequant_gather(v_pool, block_tables, dtype))
-    b = block_tables.shape[0]
-    nkv, d = k_pool.shape[-2], k_pool.shape[-1]
-    k_all = k_pool[block_tables].reshape(b, -1, nkv, d)
-    if v_pool is None:       # a latent pool: the key row is the value too
-        return k_all, k_all
-    v_all = v_pool[block_tables].reshape(b, -1, nkv, d)
-    return k_all, v_all
+    plain pools return the gathered values bitwise.  A latent pool's key
+    row is its value too."""
+    heads = kv_quant.dequant_gather(pool, block_tables, d, dtype, layer)
+    return (heads, heads) if latent else kv_quant.split_kv(heads)
 
 
 def paged_attention_decode(
     q: jax.Array,             # [b, 1, n_heads, d] — queries at `positions`
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    pool,                     # [num_pages, page_size, row] (kv_quant's row)
     block_tables: jax.Array,  # [b, max_pages_per_seq] int32 page ids
     positions: jax.Array,     # [b] int32 — q's position; attends to <= it
     *,
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     use_kernel: bool = True,
+    layer=None,
+    latent: bool = False,
 ) -> jax.Array:
     """One decode step of paged attention; returns [b, 1, n_heads, d].
 
@@ -117,14 +116,15 @@ def paged_attention_decode(
     b, _, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if _take_kernel("paged_decode", use_kernel, k_pool):
+    if _take_kernel("paged_decode", use_kernel, pool, d, latent):
         return _run_kernel(
-            pallas_paged.paged_decode_kernel, q, k_pool, v_pool,
+            pallas_paged.paged_decode_kernel, q, pool, layer,
             (block_tables, positions),
-            scale=scale, sliding_window=sliding_window,
+            scale=scale, sliding_window=sliding_window, latent=latent,
         )
 
-    k_all, v_all = paged_gather_kv(k_pool, v_pool, block_tables, q.dtype)
+    k_all, v_all = paged_gather_kv(pool, block_tables, d, q.dtype, layer,
+                                   latent)
     kv_len = k_all.shape[1]
     kv_pos = jnp.arange(kv_len)[None, :]
     allowed = kv_pos <= positions[:, None]
@@ -137,8 +137,7 @@ def paged_attention_decode(
 
 def paged_attention_ragged(
     q: jax.Array,             # [R, 1, n_heads, d] — one query row per entry
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: Optional[jax.Array],  # the same; None = k_pool is the value too
+    pool,                     # [num_pages, page_size, row] (kv_quant's row)
     tables: jax.Array,        # [T, max_pages_per_seq] int32 — UNIQUE tables
     table_index: jax.Array,   # [R] int32 — each row's table
     positions: jax.Array,     # [R] int32 — each row's own position
@@ -147,6 +146,8 @@ def paged_attention_ragged(
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     use_kernel: bool = True,
+    layer=None,
+    latent: bool = False,
 ) -> jax.Array:
     """One RAGGED batch of paged attention; returns [R, 1, n_heads, d].
 
@@ -172,20 +173,20 @@ def paged_attention_ragged(
     horizon 0 — skips every page); the fallback's mask ``kv_pos <=
     positions`` subsumes them.
 
-    ``v_pool=None`` is the latent pool of MLA (models/transformer.py
+    ``latent`` is the latent pool of MLA (models/transformer.py
     ``_mla_paged``): one shared key row a token whose own values are the
     value, so the output has the key's width and the caller keeps the
-    leading ``kv_lora_rank`` values.  The kernel then copies each page once.
+    leading ``kv_lora_rank`` values.
     """
     assert q.ndim == 4 and q.shape[1] == 1, "ragged rows are [R, 1, n, d]"
     b, _, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if _take_kernel("paged_ragged", use_kernel, k_pool):
+    if _take_kernel("paged_ragged", use_kernel, pool, d, latent):
         return _run_kernel(
-            pallas_paged.paged_ragged_kernel, q, k_pool, v_pool,
+            pallas_paged.paged_ragged_kernel, q, pool, layer,
             (tables, table_index, positions, horizons),
-            scale=scale, sliding_window=sliding_window,
+            scale=scale, sliding_window=sliding_window, latent=latent,
         )
 
     # fallback: gather each UNIQUE table's pages once, batch the score
@@ -196,11 +197,10 @@ def paged_attention_ragged(
     # xla_attention decode fallback (same contractions, same per-(row,
     # table) reduction order; only the batching layout moves)
     T = tables.shape[0]
-    nkv = (k_pool.q if kv_quant.is_quantized(k_pool) else k_pool).shape[2]
-    g = n // nkv
     # [T, kv, nkv, d]
-    k_all, v_all = paged_gather_kv(k_pool, v_pool, tables, q.dtype)
-    kv_len = k_all.shape[1]
+    k_all, v_all = paged_gather_kv(pool, tables, d, q.dtype, layer, latent)
+    kv_len, nkv = k_all.shape[1:3]
+    g = n // nkv
     qg = q.reshape(b, 1, nkv, g, d)
     # [R, T, nkv, g, 1, kv] — the decode fallback's "bqhgd,bkhd->bhgqk"
     # with the table dim batched
@@ -226,14 +226,15 @@ def paged_attention_ragged(
 
 def paged_attention_prefill(
     q: jax.Array,             # [b, s, n_heads, d] — chunk queries
-    k_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
-    v_pool: jax.Array,        # [num_pages, page_size, n_kv_heads, d]
+    pool,                     # [num_pages, page_size, row] (kv_quant's row)
     block_tables: jax.Array,  # [b, kv_pages] int32 — pages covering the chunk
     start: jax.Array,         # [b] int32 — position of q[:, 0]
     *,
     scale: Optional[float] = None,
     sliding_window: Optional[int] = None,
     use_kernel: bool = True,
+    layer=None,
+    latent: bool = False,
 ) -> jax.Array:
     """One prefill CHUNK of paged attention; returns [b, s, n_heads, d].
 
@@ -255,14 +256,15 @@ def paged_attention_prefill(
     b, s, n, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    if _take_kernel("paged_prefill", use_kernel, k_pool):
+    if _take_kernel("paged_prefill", use_kernel, pool, d, latent):
         return _run_kernel(
-            pallas_paged.paged_prefill_kernel, q, k_pool, v_pool,
+            pallas_paged.paged_prefill_kernel, q, pool, layer,
             (block_tables, start),
-            scale=scale, sliding_window=sliding_window,
+            scale=scale, sliding_window=sliding_window, latent=latent,
         )
 
-    k_all, v_all = paged_gather_kv(k_pool, v_pool, block_tables, q.dtype)
+    k_all, v_all = paged_gather_kv(pool, block_tables, d, q.dtype, layer,
+                                   latent)
     kv_len = k_all.shape[1]
     q_pos = start[:, None, None] + jnp.arange(s)[None, :, None]  # [b, s, 1]
     kv_pos = jnp.arange(kv_len)[None, None, :]
@@ -274,49 +276,56 @@ def paged_attention_prefill(
         q, k_all, v_all, bias=bias[:, None, :, :], scale=scale)
 
 
-def _kernel_refusal(k_pool) -> Optional[str]:
+def _kernel_refusal(pool, d: int, latent: bool = False) -> Optional[str]:
     """Why the Pallas kernel cannot serve this call (None: it can).
 
-    The kernel copies whole pages out of the pool's ``[P, page, nkv*d]``
-    view and slices a head's ``d`` lanes out of the copy, so Mosaic needs
-    the lane extent ``d`` to be a multiple of 128 unless the view has a
-    single head (its row is then padded to whole 128-lane rows):
-    Mistral/Mixtral/Llama (d=128) and d=256 take the kernel, Falcon-40B (8
-    kv heads of 64) does not, Falcon-7B (one kv head of 64) does.  Pages
-    need 8 sublane rows; bf16, int8 and fp8 pools all lower from 8 rows up
-    (packed dtypes are unpacked after the copy).
+    The kernel copies whole pages of the pool's stored row (ops/kv_quant.py:
+    a head's key and value side by side) and slices a head's lanes out of
+    the copy in whole 128-lane groups, so Mosaic needs a head's PAIR, ``2 *
+    d`` lanes, to be a multiple of 128: Mistral/Mixtral/Llama (d=128) and
+    d=256 read key and value as two aligned slices, Falcon-7B (one kv head
+    of 64) and Falcon-40B (8 of 64) read the 128-lane pair as one operand
+    (ops/pallas/paged_attention._head_lanes); a head of 32 or 96 does not
+    take the kernel.  A latent row is one head and has to be whole lanes
+    itself.  Pages need 8 sublane rows; bf16, int8 and fp8 pools all lower
+    from 8 rows up (packed dtypes are unpacked after the copy).
     """
     from megatron_llm_tpu.core.parallel_state import target_platform
 
-    arr = k_pool.q if kv_quant.is_quantized(k_pool) else k_pool
-    page_size, nkv, d = arr.shape[-3:]
+    page_size, row = kv_quant.values_of(pool).shape[-2:]
     target = target_platform()
     if target != "tpu":
         return f"target platform is {target}"
-    if d % 128 and nkv > 1:
-        return f"head_dim {d} is not a multiple of 128 lanes"
+    if row % 128 if latent else (2 * d) % 128:
+        return (f"latent row of {row} values" if latent else
+                f"head_dim {d}: a head's key|value pair of {2 * d} values"
+                ) + " is not a multiple of 128 lanes"
     if page_size % 8:
         return f"page_size {page_size} is not a multiple of 8 sublanes"
     return None
 
 
-def _take_kernel(op: str, use_kernel: bool, k_pool) -> bool:
+def _take_kernel(op: str, use_kernel: bool, pool, d: int,
+                 latent: bool) -> bool:
     refusal = ("use_flash_attn is off" if not use_kernel
-               else _kernel_refusal(k_pool))
+               else _kernel_refusal(pool, d, latent))
     attn_ops.announce_path(op, "jnp" if refusal else "pallas", refusal or "")
     return refusal is None
 
 
-def _run_kernel(kernel, q, k_pool, v_pool, tables, **kw):
-    """Call a Pallas paged kernel; under a tp > 1 mesh, shard_map it over
-    the heads (q heads and the pool's kv heads split the same way the qkv
-    column-parallel rule splits them; block tables and positions are
-    replicated) — pallas_call is opaque to the GSPMD partitioner."""
+def _run_kernel(kernel, q, pool, layer, tables, **kw):
+    """Call a Pallas paged kernel on the flat view of the (layered) pool;
+    under a tp > 1 mesh, shard_map it over the heads (q heads and the
+    pool row's kv heads split the same way the qkv column-parallel rule
+    splits them — a head's key|value pair stays on one shard; block tables
+    and positions are replicated) — pallas_call is opaque to the GSPMD
+    partitioner."""
     from megatron_llm_tpu.core import parallel_state as ps
 
+    flat, base = kv_quant.layer_view(pool, layer)
     if (not ps.mesh_is_initialized()
             or ps.get_tensor_model_parallel_world_size() == 1):
-        return kernel(q, k_pool, v_pool, *tables, **kw)
+        return kernel(q, flat, *tables, page_base=base, **kw)
 
     from jax.sharding import PartitionSpec as P
 
@@ -324,10 +333,12 @@ def _run_kernel(kernel, q, k_pool, v_pool, tables, **kw):
 
     mesh, names = attn_ops.kernel_region(ps.get_global_mesh())
     heads = P(None, None, ps.TP_AXIS, None)
-    pool = (kv_quant.QuantPagedKV(q=heads, scale=P(None, ps.TP_AXIS))
-            if kv_quant.is_quantized(k_pool) else heads)
+    rows = P(None, None, ps.TP_AXIS)
+    spec = (kv_quant.QuantPagedKV(q=rows, scale=P(None, ps.TP_AXIS))
+            if kv_quant.is_quantized(flat) else rows)
     return shard_map(
-        lambda q_, k_, v_, *t: kernel(q_, k_, v_, *t, **kw),
-        mesh=mesh, in_specs=(heads, pool, pool) + (P(),) * len(tables),
+        lambda q_, pool_, base_, *t: kernel(
+            q_, pool_, *t, page_base=base_, **kw),
+        mesh=mesh, in_specs=(heads, spec, P()) + (P(),) * len(tables),
         out_specs=heads, axis_names=names, check_vma=False,
-    )(q, k_pool, v_pool, *tables)
+    )(q, flat, jnp.asarray(base, jnp.int32), *tables)

@@ -27,14 +27,21 @@ VMEM scratch across the steps of one row — the blockwise scheme of
 ops/pallas/flash_attention.py with blocks of pages as KV blocks.  GQA is
 native (q grouped [b, nkv, rows*group, d], no K/V expansion).
 
-Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, so
-the pool ``[P, page, nkv, d]`` is read through its contiguous view ``[P,
-page, nkv*d]`` and a head's ``d`` lanes are sliced out of the copy in
-VMEM — legal when ``d % 128 == 0``; a single head narrower than that
-(Falcon-7B: one of 64) has its row padded to 128 lanes first, which is what
-its tiles in HBM hold anyway (ops/paged_attention._kernel_refusal is the
-whole rule).  Per-page scales ``[P, nkv]`` are read as 128-lane rows of
-their flat view into SMEM, one copy per fetched page.
+Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, and
+the pool is STORED in such rows (ops/kv_quant.py owns the row): ``[pages,
+page, H*d]``, for a K/V pool a head's key and value side by side
+(``H = 2*nkv``), every layer's pages in one flat array.  The kernel reads
+it as it lies — no pad, no slice of a layer in front of the call: ONE copy
+a page brings keys and values, ``page_base`` (a scalar-prefetch operand)
+is the calling layer's first page.  In VMEM a head's lanes are sliced out
+of the copy in whole 128-lane groups: with ``d % 128 == 0`` the key's
+``d`` lanes and the value's next to them; with ``d = 64`` the head's
+128-lane PAIR is read as key and as value at once — the query is zero on
+the value's lanes, the output's value lanes are kept — which costs a
+128-wide MXU nothing (``_head_lanes``; ops/paged_attention._kernel_refusal
+is the whole rule).  A latent pool (``latent``) is one head whose row is
+key and value.  Per-page scales ``[P, H]`` (the calling layer's) are read
+as 128-lane rows of their flat view into SMEM, one copy per fetched page.
 
 Numerics match the jnp path: fp32 logits/softmax/accumulator, outputs cast
 to the query dtype.  Quantized pools (ops/kv_quant.QuantPagedKV) arrive in
@@ -67,29 +74,28 @@ def _paged_kernel(
     idx_ref,     # [b] int32 row -> table
     pos_ref,     # [b] int32 position of the row's first query
     hor_ref,     # [b] int32 kv horizon in tokens (0 = dead row)
-    # q block, the pools in HBM [, their scales], out block, then scratch
+    base_ref,    # [1] int32 first page of the calling layer in the pool
+    # q block, the pool in HBM [, its scales], out block, then scratch
     *refs,
     scale: float,
     group: int,
     sliding_window: Optional[int],
     quantized: bool,
-    shared_kv: bool = False,
+    paired: bool,
 ):
     """One program per sequence: ``rows = s*group`` query rows per kv head,
     row ``r`` at position ``pos0 + r // group`` — the causal mask is per ROW.
-    Decode and ragged calls have ``s == 1``.  ``shared_kv`` (a latent pool):
-    the key pages are the value pages, copied once."""
-    if shared_kv:
-        q_ref, k_hbm, o_ref, k_buf, sem, m_s, l_s, acc_s = refs
-        v_hbm, v_buf = None, k_buf
-    elif quantized:
-        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-         k_buf, v_buf, sem, m_s, l_s, acc_s, ks_buf, vs_buf) = refs
+    Decode and ragged calls have ``s == 1``.  ``paired``: a head's ``w``
+    key lanes are followed by its ``w`` value lanes; otherwise its ``w``
+    lanes are key and value at once (a latent row; a K|V pair of 64s)."""
+    if quantized:
+        (q_ref, kv_hbm, s_hbm, o_ref,
+         kv_buf, sem, m_s, l_s, acc_s, s_buf) = refs
     else:
-        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, m_s, l_s, acc_s = refs
+        q_ref, kv_hbm, o_ref, kv_buf, sem, m_s, l_s, acc_s = refs
     i = pl.program_id(0)
-    nkv, rows, d = q_ref.shape
-    _, pps, page, _ = k_buf.shape
+    nkv, rows, w = q_ref.shape
+    _, pps, page, _ = kv_buf.shape
     bk = pps * page
     tbl = idx_ref[i]
     pos0 = pos_ref[i]
@@ -100,6 +106,8 @@ def _paged_kernel(
                 else jnp.maximum(pos0 - sliding_window + 1, 0))
     blk0 = kv_start // bk
     blk1 = (kv_end + bk - 1) // bk
+    # storage heads a page's scale row holds: a key and a value per head
+    n_scales = 2 * nkv
 
     def page_id(blk, j):
         # clamped: a block's last slots may lie past the table's width
@@ -113,28 +121,25 @@ def _paged_kernel(
             def _page():
                 # a wait needs the copy's shape only, not its source
                 pid = page_id(blk, j) if start else 0
-                copies = [(k_hbm.at[pid], k_buf, 0)]
-                if not shared_kv:
-                    copies += [(v_hbm.at[pid], v_buf, 1)]
+                copies = [(kv_hbm.at[pid + base_ref[0]], kv_buf, 0)]
                 if quantized:
-                    scale_rows = pl.ds(pid * nkv // 128, 2)
-                    copies += [(ks_hbm.at[scale_rows], ks_buf, 2),
-                               (vs_hbm.at[scale_rows], vs_buf, 3)]
+                    scale_rows = pl.ds(pid * n_scales // 128, 2)
+                    copies += [(s_hbm.at[scale_rows], s_buf, 1)]
                 for src, dst, s in copies:
                     cp = pltpu.make_async_copy(
                         src, dst.at[slot, j], sem.at[s, slot])
                     cp.start() if start else cp.wait()
 
-    def page_scales(buf, slot, at, h, live):
-        """[1, bk] row of head ``h``'s per-page scales, 0 where not live;
-        ``at[j]`` is where page j's heads start in its two rows of ``buf``
-        (_scale_rows)."""
+    def page_scales(slot, at, h, live):
+        """[1, bk] row of storage head ``h``'s per-page scales, 0 where
+        not live; ``at[j]`` is where page j's heads start in its two rows
+        of ``s_buf`` (_scale_rows)."""
         col_page = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page
         vec = jnp.zeros((1, bk), jnp.float32)
         for j in range(pps):
             vec = jnp.where(
                 col_page == j,
-                buf[slot, j, (at[j] + h) // 128, (at[j] + h) % 128], vec)
+                s_buf[slot, j, (at[j] + h) // 128, (at[j] + h) % 128], vec)
         return jnp.where(live, vec, 0.0)
 
     def block(blk, _):
@@ -159,18 +164,21 @@ def _paged_kernel(
         live_row = first + jax.lax.broadcasted_iota(
             jnp.int32, (bk, 1), 0) < kv_end
         if quantized:
-            scales_at = [page_id(blk, j) * nkv % 128 for j in range(pps)]
+            scales_at = [page_id(blk, j) * n_scales % 128
+                         for j in range(pps)]
         for h in range(nkv):
-            lanes = pl.ds(h * d, d)
-            q = q_ref[h].astype(jnp.float32) * scale            # [rows, d]
-            k = k_buf[slot, :, :, lanes].astype(jnp.float32).reshape(bk, d)
+            k_lanes = pl.ds((2 * h if paired else h) * w, w)
+            v_lanes = pl.ds((2 * h + 1) * w, w) if paired else k_lanes
+            q = q_ref[h].astype(jnp.float32) * scale            # [rows, w]
+            k = kv_buf[slot, :, :, k_lanes].astype(jnp.float32).reshape(
+                bk, w)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)             # [rows, bk]
             if quantized:
                 # q . (k * scale) == (q . k) * scale: dequantize the
                 # scores' columns, not the page
-                s = s * page_scales(ks_buf, slot, scales_at, h, live_col)
+                s = s * page_scales(slot, scales_at, 2 * h, live_col)
             s = jnp.where(mask, s, NEG_INF)
 
             m_prev = m_s[h]                                     # [rows, 1]
@@ -182,12 +190,13 @@ def _paged_kernel(
             p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur))
             l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=1, keepdims=True)
             m_s[h] = m_cur
-            v = v_buf[slot, :, :, lanes].astype(jnp.float32).reshape(bk, d)
+            v = k if not paired else kv_buf[
+                slot, :, :, v_lanes].astype(jnp.float32).reshape(bk, w)
             # rows of pages not fetched hold whatever the buffer held:
             # 0 * NaN would reach the accumulator
             v = jnp.where(live_row, v, 0.0)
             if quantized:
-                p = p * page_scales(vs_buf, slot, scales_at, h, live_col)
+                p = p * page_scales(slot, scales_at, 2 * h + 1, live_col)
             acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -209,8 +218,8 @@ def _paged_kernel(
 
 
 def _scale_rows(scale):
-    """Per-page scales ``[P, nkv]`` as rows of 128 lanes, ``(page, head)``
-    at flat index ``page * nkv + head``: a copy out of HBM moves whole
+    """Per-page scales ``[P, H]`` as rows of 128 lanes, ``(page, head)``
+    at flat index ``page * H + head``: a copy out of HBM moves whole
     128-lane rows, and a page's heads may straddle two (hence the spare
     row at the end)."""
     flat = scale.reshape(-1)
@@ -220,59 +229,67 @@ def _scale_rows(scale):
 
 def _pages_per_step(page_size: int, row_bytes: int) -> int:
     """Pages of one compute block: at least 128 KV tokens, so that a step
-    is one full-width score matmul, and at least 64 KiB of K, so that the
-    step's fixed cost (a copy and a wait per page, the loop) is spread
-    over enough of them — one kv head of 64 then takes 256 tokens a step,
-    8 kv heads of 128 take 128 (256 KiB)."""
+    is one full-width score matmul, and at least 64 KiB of rows, so that
+    the step's fixed cost (a copy and a wait per page, the loop) is spread
+    over enough of them — one K|V pair of 64s then takes 256 tokens a
+    step, 8 pairs of 128 take 128 (512 KiB)."""
     return max(1, max(128, (64 << 10) // row_bytes) // page_size)
 
 
-def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
-                *, group, scale, sliding_window, interpret):
+def _head_lanes(d: int, row: int, latent: bool):
+    """How a query head of width ``d`` reads its lanes of a pool row:
+    ``(w, paired)`` — ``w`` lanes a matmul operand takes, and whether the
+    key's ``w`` lanes are followed by the value's (else the same ``w``
+    lanes are both).  A latent row is one head, whole.  A K/V row gives a
+    head ``2*d`` lanes, key then value: two aligned slices where ``d`` is
+    whole 128-lane groups, else the pair as one operand (``d = 64``)."""
+    if latent:
+        return row, False
+    return (d, True) if d % 128 == 0 else (2 * d, False)
+
+
+def _paged_call(qg, pool, tables, table_index, positions, horizons,
+                page_base, *, group, scale, sliding_window, latent,
+                interpret):
     """``qg`` [b, nkv, rows, d] kv-head-major query rows -> same shape.
-    ``v_pool=None``: the key pool is the value pool (a latent pool)."""
-    quantized = kv_quant.is_quantized(k_pool)
-    shared_kv = v_pool is None
-    assert not (shared_kv and quantized), "a latent pool is not quantized"
-    if shared_kv:
-        v_pool = k_pool
-    k_arr, v_arr = (k_pool.q, v_pool.q) if quantized else (k_pool, v_pool)
+    ``pool`` is the flat ``[pages, page, H*d]`` pool of every layer
+    (ops/kv_quant.layer_view; quantized: with the calling layer's ``[P,
+    H]`` scales) and ``page_base`` the calling layer's first page in it."""
+    quantized = kv_quant.is_quantized(pool)
+    assert not (latent and quantized), "a latent pool is not quantized"
+    arr = kv_quant.values_of(pool)
     b, nkv, rows, d = qg.shape
-    num_pages, page_size, _, _ = k_arr.shape
+    _, page_size, row = arr.shape
+    w, paired = _head_lanes(d, row, latent)
+    if w != d:
+        # the pair read whole: the query is zero on the value's lanes,
+        # so the scores see the key alone
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, w - d),))
 
     def lanes(n):
         return pl.cdiv(n, 128) * 128
 
-    # a copy out of HBM moves whole 128-lane rows: a narrower page row (one
-    # kv head of 64) is padded to 128 lanes, which is what its tiles in HBM
-    # hold anyway
-    width = lanes(nkv * d)
-    pps = _pages_per_step(page_size, width * k_arr.dtype.itemsize)
+    pps = _pages_per_step(page_size, row * arr.dtype.itemsize)
+    buf_shape = (2, pps, page_size, row)
 
-    def view(pool):
-        flat = pool.reshape(num_pages, page_size, nkv * d)
-        return jnp.pad(flat, ((0, 0), (0, 0), (0, width - nkv * d)))
-
-    buf_shape = (2, pps, page_size, width)
-
-    row_spec = pl.BlockSpec((None, nkv, rows, d),
-                            lambda i, tbl, idx, pos, hor: (i, 0, 0, 0))
+    row_spec = pl.BlockSpec((None, nkv, rows, w),
+                            lambda i, tbl, idx, pos, hor, base: (i, 0, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [row_spec, hbm_spec] + [hbm_spec] * (not shared_kv)
-    operands = [qg, view(k_arr)] + [view(v_arr)] * (not shared_kv)
-    scratch = [pltpu.VMEM(buf_shape, k_arr.dtype)] + [
-        pltpu.VMEM(buf_shape, v_arr.dtype)] * (not shared_kv) + [
-        pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)),
+    in_specs = [row_spec, hbm_spec]
+    operands = [qg, arr]
+    scratch = [
+        pltpu.VMEM(buf_shape, arr.dtype),
+        pltpu.SemaphoreType.DMA((2 if quantized else 1, 2)),
         pltpu.VMEM((nkv, rows, 1), jnp.float32),
         pltpu.VMEM((nkv, rows, 1), jnp.float32),
-        pltpu.VMEM((nkv, rows, d), jnp.float32),
+        pltpu.VMEM((nkv, rows, w), jnp.float32),
     ]
     if quantized:
-        in_specs += [hbm_spec, hbm_spec]
-        operands += [_scale_rows(k_pool.scale), _scale_rows(v_pool.scale)]
-        scratch += [pltpu.SMEM((2, pps, 2, 128), jnp.float32)] * 2
+        in_specs += [hbm_spec]
+        operands += [_scale_rows(pool.scale)]
+        scratch += [pltpu.SMEM((2, pps, 2, 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(b,),
         in_specs=in_specs,
         out_specs=row_spec,
@@ -280,21 +297,20 @@ def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, group=group,
-        sliding_window=sliding_window, quantized=quantized,
-        shared_kv=shared_kv,
+        sliding_window=sliding_window, quantized=quantized, paired=paired,
     )
 
     # VMEM, every last dim padded to 128 lanes: the q and out blocks (two
-    # of each, the pipeline's), the softmax state, both halves of the K
-    # and V buffers, and a step's [rows, block] fp32 temporaries (scores,
+    # of each, the pipeline's), the softmax state, both halves of the page
+    # buffer, and a step's [rows, block] fp32 temporaries (scores,
     # probabilities, masks).  A ragged tick needs 1.1 MiB at Mistral's
     # widths and 0.9 at Falcon's; Falcon's 64-row chunk (4544 rows a kv
     # head) 38 MiB, over Mosaic's default of 16 — so the limit is stated
-    vmem = (4 * nkv * rows * lanes(d) * qg.dtype.itemsize
-            + nkv * rows * (2 * 128 + lanes(d)) * 4
-            + 2 * math.prod(buf_shape) * k_arr.dtype.itemsize
+    vmem = (4 * nkv * rows * lanes(w) * qg.dtype.itemsize
+            + nkv * rows * (2 * 128 + lanes(w)) * 4
+            + math.prod(buf_shape) * arr.dtype.itemsize
             + 6 * rows * lanes(pps * page_size) * 4)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
@@ -303,17 +319,19 @@ def _paged_call(qg, k_pool, v_pool, tables, table_index, positions, horizons,
         interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), table_index.astype(jnp.int32),
-      positions.astype(jnp.int32), horizons.astype(jnp.int32), *operands)
+      positions.astype(jnp.int32), horizons.astype(jnp.int32),
+      jnp.asarray(page_base, jnp.int32).reshape(1), *operands)
+    # the pair read whole: its value lanes are the output
+    return out if w == d or latent else out[..., d:]
 
 
-def _nkv(k_pool) -> int:
-    return (k_pool.q if kv_quant.is_quantized(k_pool) else k_pool).shape[2]
+def _nkv(pool, d: int, latent: bool) -> int:
+    return 1 if latent else kv_quant.row_width(pool) // (2 * d)
 
 
 def paged_ragged_kernel(
     q: jax.Array,             # [R, 1, n_heads, d]
-    k_pool,                   # [num_pages, page_size, n_kv_heads, d]
-    v_pool,
+    pool,                     # [pages, page_size, H*d] (kv_quant's row)
     tables: jax.Array,        # [T, max_pages_per_seq] int32 unique tables
     table_index: jax.Array,   # [R] int32 row -> table
     positions: jax.Array,     # [R] int32
@@ -321,59 +339,65 @@ def paged_ragged_kernel(
     *,
     scale: float,
     sliding_window: Optional[int] = None,
+    latent: bool = False,
+    page_base=0,
     interpret: bool = False,
 ) -> jax.Array:
     """ONE launch for a whole ragged tick; returns [R, 1, n_heads, d]."""
     b, _, n, d = q.shape
-    nkv = _nkv(k_pool)
+    nkv = _nkv(pool, d, latent)
     g = n // nkv
     out = _paged_call(
-        q.reshape(b, nkv, g, d), k_pool, v_pool, tables, table_index,
-        positions, horizons, group=g, scale=scale,
-        sliding_window=sliding_window, interpret=interpret)
+        q.reshape(b, nkv, g, d), pool, tables, table_index,
+        positions, horizons, page_base, group=g, scale=scale,
+        sliding_window=sliding_window, latent=latent, interpret=interpret)
     return out.reshape(b, 1, n, d)
 
 
 def paged_prefill_kernel(
     q: jax.Array,             # [b, s, n_heads, d]
-    k_pool,
-    v_pool,
+    pool,
     block_tables: jax.Array,  # [b, kv_pages] int32 (chunk horizon)
     start: jax.Array,         # [b] int32 — position of q[:, 0]
     *,
     scale: float,
     sliding_window: Optional[int] = None,
+    latent: bool = False,
+    page_base=0,
     interpret: bool = False,
 ) -> jax.Array:
     """One prefill chunk; returns [b, s, n_heads, d]."""
     b, s, n, d = q.shape
-    nkv = _nkv(k_pool)
+    nkv = _nkv(pool, d, latent)
     g = n // nkv
     # kv-head-major query rows: one grid step sees all of a kv head's
     # query rows for the chunk
     qg = q.reshape(b, s, nkv, g, d).transpose(0, 2, 1, 3, 4)
     out = _paged_call(
-        qg.reshape(b, nkv, s * g, d), k_pool, v_pool, block_tables,
-        jnp.arange(b, dtype=jnp.int32), start, start + s, group=g,
-        scale=scale, sliding_window=sliding_window, interpret=interpret)
+        qg.reshape(b, nkv, s * g, d), pool, block_tables,
+        jnp.arange(b, dtype=jnp.int32), start, start + s, page_base,
+        group=g, scale=scale, sliding_window=sliding_window, latent=latent,
+        interpret=interpret)
     return out.reshape(b, nkv, s, g, d).transpose(0, 2, 1, 3, 4).reshape(
         b, s, n, d)
 
 
 def paged_decode_kernel(
     q: jax.Array,             # [b, 1, n_heads, d]
-    k_pool,
-    v_pool,
+    pool,
     block_tables: jax.Array,  # [b, max_pages_per_seq] int32
     positions: jax.Array,     # [b] int32
     *,
     scale: float,
     sliding_window: Optional[int] = None,
+    latent: bool = False,
+    page_base=0,
     interpret: bool = False,
 ) -> jax.Array:
     """One decode step; returns [b, 1, n_heads, d]."""
     b = q.shape[0]
     return paged_ragged_kernel(
-        q, k_pool, v_pool, block_tables, jnp.arange(b, dtype=jnp.int32),
+        q, pool, block_tables, jnp.arange(b, dtype=jnp.int32),
         positions, positions + 1, scale=scale,
-        sliding_window=sliding_window, interpret=interpret)
+        sliding_window=sliding_window, latent=latent, page_base=page_base,
+        interpret=interpret)

@@ -6,7 +6,7 @@ so a served model had to fit one host's chips.  This module extends the
 engine's forward across pipeline stages:
 
 * **Layer placement**: the stacked ``[L, ...]`` layer params and the paged
-  K/V pools (``[L, pages, page, nkv, d]``) are sharded ``P(pp)`` on the
+  K/V pool (``[L, pages, page, row]``, ops/kv_quant.py) are sharded ``P(pp)`` on the
   layer dim — each stage holds ``L/pp`` layers and ONLY its own layers'
   K/V pages (the servable-model-size multiplier: per-stage pool bytes are
   ``1/pp`` of the tp-only pool).  Block tables, the page trie, the
@@ -131,8 +131,10 @@ def pipelined_transformer(cfg, ctx: ServeParams, stacked_layers, hidden, *,
     """Run the layer stack as a pp-stage pipeline over microbatched rows.
 
     Args mirror the ``transformer_forward`` call in model_forward;
-    ``kv_caches`` is the stacked paged pool pair (``[L, ...]`` leaves,
-    sharded ``P(pp)`` on the layer dim by ``PagedKVPool``).  Returns
+    ``kv_caches`` is the paged pool (``[L, ...]`` leaves, sharded
+    ``P(pp)`` on the layer dim by ``PagedKVPool``); a stage hands its own
+    ``L/pp`` layers of it to the stack, which carries them through its
+    scan and writes them in place (``pool_first_layer``).  Returns
     ``(hidden, new_kv_caches)`` — MoE aux is not plumbed (serving is
     deterministic inference; the engine discards it).
     """
@@ -201,6 +203,7 @@ def pipelined_transformer(cfg, ctx: ServeParams, stacked_layers, hidden, *,
                     rope=rp, position_ids=take(p_mb),
                     kv_caches=pools_, paged=pg_,
                     layer_offset=stage * n_local,
+                    pool_first_layer=stage * n_local,
                 )
                 return out_, pools_
 

@@ -24,10 +24,11 @@ per leaf   u64 byte length, then the leaf's raw C-order bytes, in
 
 ``tokens`` is the page-aligned token prefix the pages hold (length ==
 ``n_pages * page_size``) — the receiving :class:`PrefixCache` keys its
-trie nodes on exactly these ids.  Leaf names are the pool attributes
-(``k``/``v``/``draft_k``/``draft_v``), with ``.q`` / ``.scale``
-suffixes for quantized containers; every leaf's page axis is axis 1
-(``[L, n_pages, ...]``).
+trie nodes on exactly these ids.  Leaves are LOGICAL, whatever row the
+pool stores (ops/kv_quant.kv_to_leaves): ``k``/``v``/``draft_k``/
+``draft_v``, each ``[L, n_pages, page, nkv, d]``, with ``.q`` /
+``.scale`` (``[L, n_pages, nkv]``) suffixes for quantized pools; every
+leaf's page axis is axis 1.
 """
 
 from __future__ import annotations

@@ -189,15 +189,19 @@ def test_aot_pp_dp_tp_flash_no_partitioner_crash():
 
 
 def _abstract_pool(shape, kv_dtype, sharding, scale_sharding):
+    """The K/V pool of logical ``shape`` = [..., pages, page, nkv, d] in
+    the row ops/kv_quant.py gives it, abstract: what make_kv_pool builds."""
     from megatron_llm_tpu.ops import kv_quant
 
-    if kv_dtype == "bf16":
-        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
-    return kv_quant.QuantPagedKV(
-        q=jax.ShapeDtypeStruct(shape, kv_quant.storage_dtype(kv_dtype),
-                               sharding=sharding),
-        scale=jax.ShapeDtypeStruct(shape[:-3] + (shape[-2],), jnp.float32,
-                                   sharding=scale_sharding))
+    *lead, page, nkv, d = shape
+    made = jax.eval_shape(lambda: kv_quant.make_pool(
+        (*lead, page, 2 * nkv, d), kv_dtype, jnp.bfloat16))
+    assert kv_quant.row_width(made) == 2 * nkv * d
+    shardings = (kv_quant.QuantPagedKV(q=sharding, scale=scale_sharding)
+                 if kv_quant.is_quantized(made) else sharding)
+    return jax.tree.map(
+        lambda a, s_: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s_),
+        made, shardings)
 
 
 def _compiles_with_kernel(fn, *args, **jit_kw):
@@ -208,13 +212,15 @@ def _compiles_with_kernel(fn, *args, **jit_kw):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
-@pytest.mark.parametrize("heads", [(32, 8, 128), (32, 32, 128), (71, 1, 64)],
-                         ids=["gqa32q8kv", "mha32q32kv", "mqa71q1kv64"])
+@pytest.mark.parametrize(
+    "heads", [(32, 8, 128), (32, 32, 128), (71, 1, 64), (128, 8, 64)],
+    ids=["gqa32q8kv", "mha32q32kv", "mqa71q1kv64", "gqa128q8kv64"])
 def test_aot_paged_kernels_compile(heads, kv_dtype):
     """Decode, prefill-chunk and ragged kernels lower and compile for a
     v5e at the preset head geometries (Mistral's and Llama-2's heads of
-    128, Falcon-7B's one kv head of 64; default page size), on plain and
-    quantized pools."""
+    128, Falcon-7B's one kv head of 64 and Falcon-40B's eight; default page
+    size), on plain and quantized pools, reading the pool's row as it is
+    stored."""
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
     from megatron_llm_tpu.ops import paged_attention as pa
 
@@ -228,36 +234,45 @@ def test_aot_paged_kernels_compile(heads, kv_dtype):
 
     pool = _abstract_pool((pages, page, nkv, d), kv_dtype, repl, repl)
     with global_mesh(mesh):
-        assert pa._kernel_refusal(pool) is None
+        assert pa._kernel_refusal(pool, d) is None
         _compiles_with_kernel(
             pa.paged_attention_decode, S((b, 1, n, d), jnp.bfloat16), pool,
-            pool, S((b, maxp), jnp.int32), S((b,), jnp.int32))
+            S((b, maxp), jnp.int32), S((b,), jnp.int32))
         _compiles_with_kernel(
             pa.paged_attention_prefill, S((1, 64, n, d), jnp.bfloat16), pool,
-            pool, S((1, maxp), jnp.int32), S((1,), jnp.int32))
+            S((1, maxp), jnp.int32), S((1,), jnp.int32))
         _compiles_with_kernel(
             pa.paged_attention_ragged, S((72, 1, n, d), jnp.bfloat16), pool,
-            pool, S((11, maxp), jnp.int32), S((72,), jnp.int32),
+            S((11, maxp), jnp.int32), S((72,), jnp.int32),
             S((72,), jnp.int32), S((72,), jnp.int32))
 
 
 def test_paged_kernel_refusal_rule():
-    """_kernel_refusal is the whole dispatch rule: lanes (head_dim % 128
-    unless one kv head), sublanes (page % 8), TPU target."""
+    """_kernel_refusal is the whole dispatch rule: lanes (a head's
+    key|value pair, 2 * head_dim, in whole 128-lane groups; a latent row
+    whole lanes itself), sublanes (page % 8), TPU target."""
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
     from megatron_llm_tpu.ops import paged_attention as pa
 
-    def pool(page, nkv, d):
-        return jax.ShapeDtypeStruct((9, page, nkv, d), jnp.bfloat16)
+    def refusal(page, nkv, d):
+        return pa._kernel_refusal(
+            _abstract_pool((9, page, nkv, d), "bf16", None, None), d)
 
-    assert "cpu" in pa._kernel_refusal(pool(16, 8, 128))
+    assert "cpu" in refusal(16, 8, 128)
     mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
     with global_mesh(mesh):
-        assert pa._kernel_refusal(pool(16, 8, 128)) is None
-        assert pa._kernel_refusal(pool(16, 2, 256)) is None
-        assert pa._kernel_refusal(pool(16, 1, 64)) is None   # Falcon-7B
-        assert "128 lanes" in pa._kernel_refusal(pool(16, 8, 64))
-        assert "8 sublanes" in pa._kernel_refusal(pool(12, 8, 128))
+        assert refusal(16, 8, 128) is None
+        assert refusal(16, 2, 256) is None
+        assert refusal(16, 1, 64) is None   # Falcon-7B
+        # Falcon-40B: refused while a head's 64 lanes were sliced alone
+        assert refusal(16, 8, 64) is None
+        assert "128 lanes" in refusal(16, 8, 96)
+        assert "128 lanes" in refusal(16, 1, 32)
+        assert "8 sublanes" in refusal(12, 8, 128)
+        latent = jax.ShapeDtypeStruct((9, 16, 640), jnp.bfloat16)
+        assert pa._kernel_refusal(latent, 640, latent=True) is None
+        assert "128 lanes" in pa._kernel_refusal(
+            jax.ShapeDtypeStruct((9, 16, 576), jnp.bfloat16), 576, True)
 
 
 @pytest.mark.parametrize("tp", [pytest.param(1, marks=pytest.mark.slow), 4])
@@ -302,7 +317,7 @@ def test_aot_engine_programs_compile(tp):
             params, param_shardings(mesh, params))
         tick = make_ragged_tick_fn(cfg, None, 0, pre, tp=tp, mesh=mesh)
         _compiles_with_kernel(
-            tick, params, pool, pool, S((slots, width), jnp.int32),
+            tick, params, pool, S((slots, width), jnp.int32),
             S((slots,), jnp.int32), S((slots,), jnp.int32),
             S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
             S((slots,), jnp.float32), S((slots,), jnp.int32),
@@ -310,19 +325,19 @@ def test_aot_engine_programs_compile(tp):
             S((slots,), jnp.bool_), S((pre,), jnp.int32),
             S((pre,), jnp.int32), S((2, width), jnp.int32),
             S((pre,), jnp.int32), S((pre,), jnp.int32),
-            donate_argnums=(1, 2))
+            donate_argnums=(1,))
 
-        def chunk(params, tokens, start, bt, pool_k, pool_v):
+        def chunk(params, tokens, start, bt, pool_kv):
             # the body of ContinuousBatchingEngine._chunk_prefill
             return model_forward(
                 cfg, params, tokens,
                 position_ids=start[:, None] + jnp.arange(rows)[None, :],
-                rope_cache=make_rope_cache(cfg), kv_caches=(pool_k, pool_v),
+                rope_cache=make_rope_cache(cfg), kv_caches=pool_kv,
                 paged=PagedState(bt, start), logits_postprocess=True)
 
         _compiles_with_kernel(
             chunk, params, S((1, rows), jnp.int32), S((1,), jnp.int32),
-            S((1, 8), jnp.int32), pool, pool, donate_argnums=(4, 5))
+            S((1, 8), jnp.int32), pool, donate_argnums=(4,))
 
 
 def test_aot_latent_tick_compiles_at_published_widths():
@@ -354,8 +369,8 @@ def test_aot_latent_tick_compiles_at_published_widths():
         params = jax.tree.map(
             lambda a: S(a.shape, jnp.bfloat16), params)
         tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
-        lowered = jax.jit(tick, donate_argnums=(1, 2)).lower(
-            params, pool, None, S((slots, width), jnp.int32),
+        lowered = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, pool, S((slots, width), jnp.int32),
             S((slots,), jnp.int32), S((slots,), jnp.int32),
             S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
             S((slots,), jnp.float32), S((slots,), jnp.int32),
@@ -367,3 +382,87 @@ def test_aot_latent_tick_compiles_at_published_widths():
         assert "paged_attention" in text and "gmm" in text
         stats = lowered.compile().memory_analysis()
     assert stats.temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("model,vocab,heads,slots", [
+    ("falcon-7b", 65024, (71, 1, 64), 128),
+    ("mistral-7b", 32000, (32, 8, 128), 32)],
+    ids=["falcon7b_mqa64", "mistral7b_gqa128"])
+def test_aot_tick_keeps_the_pool_in_one_layout(model, vocab, heads, slots):
+    """The ragged tick at published widths (2 layers, abstract bf16
+    parameters, a pool of a few hundred MB) compiles for one v5e with the
+    Pallas kernel in it and touches the pool in place: the compiled program
+    holds no pad, copy, slice or transpose whose result has the pool's, a
+    layer slice's or a layer's K (or V) slice's shape, and its temporaries
+    do not grow with the pool — a pool four times the size adds well under
+    ONE layer's K slice of the growth (the tied head's transposed
+    embedding, the scanned weights' slices and the sampler's vocabulary
+    rows are temporaries of any pool).  CPU interpret mode cannot see any
+    of this: it accepts every layout and plans no buffers."""
+    import re
+
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    n, nkv, d = heads
+    row = 2 * nkv * d
+    page, pre = 16, 128
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    def compiled_tick(seq_length):
+        cfg = make_config(model, num_layers=2, params_dtype="bfloat16",
+                          vocab_size=vocab, seq_length=seq_length)
+        m = cfg.model
+        assert (m.num_attention_heads, m.num_attention_heads_kv,
+                m.kv_channels) == heads
+        width = seq_length // page
+        pages = slots * width + 1
+        pool = _abstract_pool((m.num_layers, pages, page, nkv, d), "bf16",
+                              repl, None)
+        assert pool.shape == (m.num_layers, pages, page, row)
+        with global_mesh(mesh):
+            params = jax.eval_shape(functools.partial(
+                init_model_params, cfg), jax.random.PRNGKey(0))
+            params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), params)
+            tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+            lowered = jax.jit(tick, donate_argnums=(1,)).lower(
+                params, pool, S((slots, width), jnp.int32),
+                S((slots,), jnp.int32), S((slots,), jnp.int32),
+                S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+                S((slots,), jnp.float32), S((slots,), jnp.int32),
+                S((slots,), jnp.float32), S((slots,), jnp.int32),
+                S((slots,), jnp.bool_), S((pre,), jnp.int32),
+                S((pre,), jnp.int32), S((pre // page + 1, width), jnp.int32),
+                S((pre,), jnp.int32), S((pre,), jnp.int32))
+            assert "paged_attention" in lowered.as_text()
+            return pages, lowered.compile()
+
+    pages, compiled = compiled_tick(2048)
+    assert row % 128 == 0 and pages * page * row * 2 * 2 > 100 << 20
+    pool_shapes = {
+        f"2,{pages},{page},{row}",          # the pool
+        f"{2 * pages},{page},{row}",        # its flat view
+        f"{pages},{page},{row}",            # a layer's slice
+        f"{pages},{page},{nkv * d}",        # a layer's K (or V)
+        f"{pages},{page},{nkv},{d}",
+    }
+    moved = re.compile(
+        r"= bf16\[([0-9,]+)\][^ ]* (pad|copy|dynamic-slice|transpose|"
+        r"concatenate)\(")
+    bad = [mt.group(0) for mt in moved.finditer(compiled.as_text())
+           if mt.group(1) in pool_shapes]
+    assert not bad, bad
+    # the scatter that replaces them writes the flat view in place
+    assert re.search(rf"bf16\[{2 * pages},{page},{row}\][^ ]* scatter\(",
+                     compiled.as_text())
+
+    pages4, compiled4 = compiled_tick(8192)
+    k_slice_growth = (pages4 - pages) * page * nkv * d * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    temp4 = compiled4.memory_analysis().temp_size_in_bytes
+    assert temp4 - temp < k_slice_growth // 8, (temp, temp4, k_slice_growth)

@@ -30,7 +30,7 @@ from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
     model_forward,
 )
-from megatron_llm_tpu.models.transformer import LatentCache, mla_sublayer
+from megatron_llm_tpu.models.transformer import LayerPool, mla_sublayer
 from megatron_llm_tpu.ops.paged_attention import PagedState
 
 import parity
@@ -131,7 +131,7 @@ def test_absorbed_equals_expanded(model):
     rows = x[0][:, None, :]                                  # [s, 1, h]
     got, new_pool = mla_sublayer(
         cfg, p, rows, rope, jnp.arange(s)[:, None], None,
-        kv_cache=LatentCache(pool, jnp.asarray(1)),
+        kv_cache=LayerPool(pool, jnp.asarray(1)),
         paged=PagedState(table, jnp.arange(s, dtype=jnp.int32),
                          jnp.full((s,), 64, jnp.int32),
                          jnp.zeros((s,), jnp.int32)))
@@ -158,7 +158,7 @@ def test_engine_matches_reference_through_the_latent_pool(model):
     probes (benchmark/lib/check.py)."""
     cfg, params = model
     eng = ContinuousBatchingEngine(cfg, params, prefill_chunk=32)
-    assert eng.pool.v is None and eng.pool.k.shape == (3, 65, 16, 128)
+    assert eng.pool.latent and eng.pool.kv.shape == (3, 65, 16, 128)
     # 128 lanes hold the 40 values of a latent row: ONE leaf, no value pool
     assert eng.pool.kv_pool_bytes() == 3 * 65 * 16 * 128 * 4
     jobs = [(PROMPT_A, 12), (PROMPT_B, 12)]

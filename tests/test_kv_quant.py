@@ -224,6 +224,74 @@ def test_bf16_pool_is_plain_array():
     assert kv_quant.pool_nbytes(q) * 4 == kv_quant.pool_nbytes(pool)
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("nkv,d", [(1, 64), (8, 64), (8, 128)],
+                         ids=["mqa1x64", "gqa8x64", "gqa8x128"])
+def test_row_round_trip_through_the_owner(nkv, d, kv_dtype):
+    """ops/kv_quant.py owns the pool's physical row: a write of logical
+    ``(page, offset, head, d)`` keys and values into one layer of the
+    layered pool reads back through every logical reader (the gather, the
+    handoff's wire leaves and their inverse) — exactly for a plain pool,
+    within the page bound for a quantized one — in whole 128-lane rows,
+    with the other layer untouched."""
+    from megatron_llm_tpu.ops.paged_attention import paged_gather_kv
+
+    rng = np.random.default_rng(nkv * d)
+    layers, pages, page = 2, 6, 8
+    pool = kv_quant.make_kv_pool(layers, pages, page, nkv, d, kv_dtype,
+                                 jnp.float32)
+    assert kv_quant.row_width(pool) == 2 * nkv * d
+    assert kv_quant.row_width(pool) % 128 == 0
+    assert kv_quant.page_size_of(pool) == page
+    assert kv_quant.values_of(pool).shape == (layers, pages, page,
+                                              2 * nkv * d)
+    # two whole pages (3 and 5) and half of page 1, one call
+    n_tok = 2 * page + page // 2
+    page_ids = np.concatenate([np.full(page, 3), np.full(page, 5),
+                               np.full(page // 2, 1)])[None]
+    offs = np.concatenate([np.arange(page), np.arange(page),
+                           np.arange(page // 2)])[None]
+    k = rng.normal(0, 2.0, (1, n_tok, nkv, d)).astype(np.float32)
+    v = rng.normal(0, 2.0, (1, n_tok, nkv, d)).astype(np.float32)
+    layer = jnp.int32(1)
+    pool = jax.jit(kv_quant.paged_write)(
+        pool, jnp.asarray(page_ids), jnp.asarray(offs),
+        kv_quant.pack_kv(jnp.asarray(k), jnp.asarray(v)), layer)
+
+    tables = jnp.asarray([[3, 5, 1]])
+    got_k, got_v = paged_gather_kv(pool, tables, d, jnp.float32, layer)
+    assert got_k.shape == got_v.shape == (1, 3 * page, nkv, d)
+    tol = 0.0 if kv_dtype == "bf16" else max(
+        kv_quant.kv_error_bound(jnp.asarray(x), kv_dtype) for x in (k, v))
+    if kv_dtype == "fp8":   # relative rounding: the format's worst step
+        tol = max(tol, float(np.abs(np.concatenate([k, v])).max()) * 2 ** -3)
+    np.testing.assert_allclose(np.asarray(got_k)[0, :n_tok], k[0], atol=tol)
+    np.testing.assert_allclose(np.asarray(got_v)[0, :n_tok], v[0], atol=tol)
+    # layer 0 was not touched
+    z_k, z_v = paged_gather_kv(pool, tables, d, jnp.float32, jnp.int32(0))
+    assert not np.asarray(z_k).any() and not np.asarray(z_v).any()
+
+    # the wire's logical leaves and back: the stored bytes verbatim
+    ids = np.asarray([3, 5, 1])
+    host = jax.device_get(jax.tree.map(lambda a: a[:, ids], pool))
+    leaves = kv_quant.kv_to_leaves(host, d)
+    quant = kv_dtype != "bf16"
+    sfx = ".q" if quant else ""
+    assert leaves["k" + sfx].shape == (layers, 3, page, nkv, d)
+    assert leaves["v" + sfx].shape == (layers, 3, page, nkv, d)
+    if quant:
+        assert leaves["k.scale"].shape == (layers, 3, nkv)
+        logical_k = (leaves["k.q"].astype(np.float32)
+                     * leaves["k.scale"][..., None, :, None])
+    else:
+        logical_k = leaves["k"]
+    np.testing.assert_allclose(
+        logical_k[1].reshape(3 * page, nkv, d)[:n_tok], k[0], atol=tol)
+    back = kv_quant.kv_from_leaves(leaves, quant)
+    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(host)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ---------------------------------------------------------------------------
 # engine accuracy gates
 # ---------------------------------------------------------------------------
@@ -314,8 +382,8 @@ def test_tp4_agreement_int8(models):
                       devices=jax.devices()[:4])
     with global_mesh(mesh):
         eng = _engine(models, "int8", max_slots=2, mesh=mesh)
-        assert eng.pool.k.q.sharding.spec[3] == "tp"
-        assert eng.pool.k.scale.sharding.spec[2] == "tp"
+        assert eng.pool.kv.q.sharding.spec[3] == "tp"
+        assert eng.pool.kv.scale.sharding.spec[2] == "tp"
         sharded = _decode(eng, prompts, gen_len=10)
     for (ts, _), (tm, _) in zip(single, sharded):
         assert ts == tm
@@ -471,7 +539,7 @@ def test_kv_dtype_flag_flows_from_config(models):
     eng = ContinuousBatchingEngine(cfg, models["params"], max_slots=2,
                                    max_seq=128)
     assert eng.kv_dtype == "int8"
-    assert kv_quant.is_quantized(eng.pool.k)
+    assert kv_quant.is_quantized(eng.pool.kv)
     with pytest.raises(AssertionError):
         _engine(models, "int4")
 

@@ -99,8 +99,13 @@ def toy_model():
          context=40, poison_tail=True),
     dict(n=4, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=128,
          context=40, poison_tail=True),
-    # Falcon-7B (71 query heads on one kv head of 64: two tokens a
-    # 128-lane row) and Mistral-7B (32/8 x 128) head geometries
+    # Falcon-40B: 8 kv heads of 64, each head's key|value pair one
+    # 128-lane operand
+    dict(n=16, nkv=8, d=64, page=16, max_pages=24, context=300),
+    dict(n=16, nkv=8, d=64, page=16, kv_dtype="int8", max_pages=24,
+         context=300, window=100),
+    # Falcon-7B (71 query heads on one kv head of 64: its key|value pair
+    # is the 128-lane row) and Mistral-7B (32/8 x 128) head geometries
     dict(n=71, nkv=1, d=64, page=16, max_pages=24, context=300),
     dict(n=71, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=24,
          context=300, window=100),
@@ -142,16 +147,16 @@ def test_dense_vs_paged_model_forward_bitwise(toy_model):
         rope_cache=rope, kv_caches=caches, cache_index=jnp.int32(0))
 
     # interleave the two rows' pages so the block tables are non-trivial
+    from megatron_llm_tpu.ops import kv_quant
+
     P = 1 + b * maxp
-    pool_k = jnp.zeros((L, P, page, nkv, d), jnp.float32)
-    pool_v = jnp.zeros((L, P, page, nkv, d), jnp.float32)
+    pool = kv_quant.make_kv_pool(L, P, page, nkv, d, "bf16", jnp.float32)
     bt = np.asarray([[1 + 2 * j for j in range(maxp)],
                      [2 + 2 * j for j in range(maxp)]], np.int32)
-    ck, cv = caches
-    pool_k = pool_k.at[:, bt.reshape(-1)].set(
-        ck.reshape(L, b, maxp, page, nkv, d).reshape(L, -1, page, nkv, d))
-    pool_v = pool_v.at[:, bt.reshape(-1)].set(
-        cv.reshape(L, b, maxp, page, nkv, d).reshape(L, -1, page, nkv, d))
+    ck, cv = (c.reshape(L, b * maxp, page, nkv, d) for c in caches)
+    # the dense cache's tokens, in the pool's own row
+    pool = pool.at[:, bt.reshape(-1)].set(
+        kv_quant.pack_kv(ck, cv).reshape(L, b * maxp, page, -1))
     bt = jnp.asarray(bt)
 
     tok = jnp.argmax(logits_d[:, -1, :VOCAB], -1).astype(jnp.int32)
@@ -161,14 +166,70 @@ def test_dense_vs_paged_model_forward_bitwise(toy_model):
             cfg, params, tok[:, None],
             position_ids=jnp.full((b, 1), pos, jnp.int32),
             rope_cache=rope, kv_caches=caches, cache_index=jnp.int32(pos))
-        lp, (pool_k, pool_v) = model_forward(
+        lp, pool = model_forward(
             cfg, params, tok[:, None],
             position_ids=jnp.full((b, 1), pos, jnp.int32),
-            rope_cache=rope, kv_caches=(pool_k, pool_v),
+            rope_cache=rope, kv_caches=pool,
             paged=PagedState(bt, jnp.full((b,), pos, jnp.int32)))
         assert bool(jnp.all(ld == lp)), f"logits diverged at position {pos}"
         tok = jnp.argmax(ld[:, -1, :VOCAB], -1).astype(jnp.int32)
         pos += 1
+
+
+@pytest.mark.parametrize("family,n,nkv,d", [
+    ("llama2", 4, 2, 16), ("falcon", 2, 1, 64), ("llama2", 4, 4, 128)],
+    ids=["gqa2x16", "mqa1x64", "mha4x128"])
+def test_pool_logical_view_is_the_dense_cache(family, n, nkv, d):
+    """Whatever the physical row, the pool's LOGICAL view ``(layer, page,
+    offset, head, d)`` holds what the dense incremental cache holds for the
+    same tokens: a chunk prefilled through a scattered block table reads
+    back through ops/kv_quant (the owner of the row) and through
+    ``PagedKVPool.logical_kv`` as the dense cache's keys and values."""
+    from megatron_llm_tpu.generation.engine import PagedKVPool
+    from megatron_llm_tpu.ops import kv_quant
+
+    cfg = make_config(
+        family, num_layers=2, hidden_size=n * d, num_attention_heads=n,
+        num_attention_heads_kv=nkv, ffn_hidden_size=64, seq_length=64,
+        max_position_embeddings=64, vocab_size=VOCAB, hidden_dropout=0.0,
+        attention_dropout=0.0, params_dtype="float32", use_flash_attn=False)
+    params = init_model_params(cfg, jax.random.PRNGKey(1))
+    rope = make_rope_cache(cfg)
+    page, s = 8, 24
+    tokens = jnp.asarray(
+        np.random.RandomState(3).randint(2, VOCAB, (1, s)), jnp.int32)
+    _, (ck, cv) = model_forward(
+        cfg, params, tokens, position_ids=jnp.arange(s)[None],
+        rope_cache=rope, kv_caches=init_kv_caches(cfg, 1, s, jnp.float32),
+        cache_index=jnp.int32(0))
+
+    pool = PagedKVPool(cfg, num_pages=9, page_size=page)
+    L = cfg.model.num_layers
+    assert pool.kv.shape == (L, 9, page, 2 * nkv * d)   # the physical row
+    table = [7, 2, 5]
+    _, pool.kv = model_forward(
+        cfg, params, tokens, position_ids=jnp.arange(s)[None],
+        rope_cache=rope, kv_caches=pool.kv,
+        paged=PagedState(jnp.asarray([table], jnp.int32),
+                         jnp.zeros((1,), jnp.int32)))
+    lk, lv = pool.logical_kv(table)
+    assert lk.shape == lv.shape == (L, 3, page, nkv, d)
+    np.testing.assert_allclose(
+        lk.reshape(L, s, nkv, d), np.asarray(ck)[:, 0], rtol=0, atol=1e-6)
+    np.testing.assert_allclose(
+        lv.reshape(L, s, nkv, d), np.asarray(cv)[:, 0], rtol=0, atol=1e-6)
+    # the same through the owner module's gather, a layer at a time
+    for layer in range(L):
+        heads = kv_quant.dequant_gather(
+            pool.kv, jnp.asarray([table]), d, layer=jnp.int32(layer))
+        gk, gv = kv_quant.split_kv(heads)
+        np.testing.assert_array_equal(np.asarray(gk)[0], lk[layer].reshape(
+            s, nkv, d))
+        np.testing.assert_array_equal(np.asarray(gv)[0], lv[layer].reshape(
+            s, nkv, d))
+    # pages nobody wrote stay zero
+    zk, zv = pool.logical_kv([1, 3, 4, 6, 8])
+    assert not zk.any() and not zv.any()
 
 
 # ---------------------------------------------------------------------------

@@ -346,17 +346,17 @@ def test_pp_observables(eight_devices, toy_params):
     pos = np.zeros((eng.max_slots,), np.int32)
     toks = np.full((eng.max_slots,), 2, np.int32)
 
-    def tickish(params, pk, pv):
+    def tickish(params, pool_kv):
         rope = make_rope_cache(cfg)
         with pp_serve_mod.activate(eng._ppc):
             logits, _ = model_forward(
                 cfg, params, jnp.asarray(toks)[:, None],
                 position_ids=jnp.asarray(pos)[:, None], rope_cache=rope,
-                kv_caches=(pk, pv),
+                kv_caches=pool_kv,
                 paged=PagedState(jnp.asarray(bt), jnp.asarray(pos)))
         return logits
 
     hlo = jax.jit(tickish).lower(
-        eng.params, eng.pool.k, eng.pool.v).compile().as_text()
+        eng.params, eng.pool.kv).compile().as_text()
     assert pp_serve_mod.STAGE_PERMUTE_SCOPE in hlo, "stage scope missing"
     assert "collective-permute" in hlo

@@ -213,7 +213,7 @@ def test_cow_never_mutates_shared_page(toy_model):
                              dict(top_k=1, termination_id=10 ** 9))])
     cached_pages = sorted(eng.pool.cached)
     assert len(cached_pages) == 3
-    before = {p: np.asarray(eng.pool.k[:, p]).copy() for p in cached_pages}
+    before = {p: np.asarray(eng.pool.kv[:, p]).copy() for p in cached_pages}
 
     # a page-aligned PREFIX of the cached prompt is fully covered: its
     # refeed tick would write the last shared page -> COW
@@ -228,7 +228,7 @@ def test_cow_never_mutates_shared_page(toy_model):
     assert t2 == t1  # identical greedy continuation off the copied page
     for p in cached_pages:
         np.testing.assert_array_equal(
-            before[p], np.asarray(eng.pool.k[:, p]),
+            before[p], np.asarray(eng.pool.kv[:, p]),
             err_msg=f"shared page {p} mutated")
     _assert_page_states(eng)
 

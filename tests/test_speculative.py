@@ -287,10 +287,16 @@ def test_spec_pool_shares_page_ids_and_drains(models):
     eng = _engine(cfg, params, spec_k=3, spec_draft=models["draft"],
                   prefix_cache=False)
     pool = eng.pool
-    assert pool.draft_k is not None
-    # one page-id space: draft arrays have the same page axis
-    assert pool.draft_k.shape[1] == pool.k.shape[1]
-    assert pool.draft_k.shape[0] == models["draft"].cfg.model.num_layers
+    assert pool.draft_kv is not None
+    # one page-id space: the draft leaf has the same page axis
+    assert pool.draft_kv.shape[1] == pool.kv.shape[1]
+    assert pool.draft_kv.shape[0] == models["draft"].cfg.model.num_layers
+    # the logical view of a page, whatever the row: [L, n, page, nkv, d]
+    dm = models["draft"].cfg.model
+    dk, dv = pool.logical_kv([1, 2], draft=True)
+    assert dk.shape == dv.shape == (
+        dm.num_layers, 2, pool.page_size, dm.num_attention_heads_kv,
+        dm.kv_channels)
     _run(eng, _greedy_jobs())
     assert np.all(pool.refcounts == 0)
     assert pool.num_free == pool.num_pages - 1  # cache off: all pages back

@@ -303,11 +303,14 @@ def test_engine_tp4_decode_parity(toy_model, eight_devices):
                          data_parallel_size=1, devices=eight_devices[:4])
     eng4, tp = _run_engine(cfg, params, mesh)
 
-    # pool really shards over the heads dim
-    spec = eng4.pool.k.sharding.spec
+    # pool really shards over the heads: the row is head-major, a shard
+    # holds whole heads (key and value of each)
+    spec = eng4.pool.kv.sharding.spec
     assert tuple(spec)[3] == ps.TP_AXIS, spec
-    shard = eng4.pool.k.sharding.shard_shape(eng4.pool.k.shape)
-    assert shard[3] == eng4.pool.k.shape[3] // 4
+    shard = eng4.pool.kv.sharding.shard_shape(eng4.pool.kv.shape)
+    m = cfg.model
+    assert shard[3] == eng4.pool.kv.shape[3] // 4 == (
+        m.num_attention_heads_kv // 4 * 2 * m.kv_channels)
     # block tables stay host-side numpy
     assert isinstance(eng4._block_tables, np.ndarray)
 

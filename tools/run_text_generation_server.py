@@ -159,7 +159,7 @@ def main():
 
             print(placement_report(
                 mesh, params=engine.params,
-                kv_pool=(engine.pool.k, engine.pool.v)), flush=True)
+                kv_pool=engine.pool.kv), flush=True)
     server = MegatronServer(engine, register_url=args.register_url,
                             register_interval_s=args.register_interval,
                             advertise_url=args.advertise_url,

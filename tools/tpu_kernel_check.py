@@ -145,17 +145,17 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     rng = np.random.default_rng(seed)
     num_pages, b, s = max(40, max_pages + 2), 4, 2 * page
     poison = num_pages - 1
-    vals = [jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
-            for _ in range(2)]
+    # keys and values in the pool's own row (ops/kv_quant.py)
+    heads = kv_quant.pack_kv(*(
+        jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
+        for _ in range(2)))
     if kv_dtype == "bf16":
-        pools = vals
-        bad = [x.at[poison].set(jnp.nan) for x in vals]
+        pool = heads.reshape(num_pages, page, -1)
+        bad = pool.at[poison].set(jnp.nan)
     else:
-        pools = [kv_quant.quantize_pages(x, kv_dtype) for x in vals]
-        bad = [x._replace(scale=x.scale.at[poison].set(jnp.nan))
-               for x in pools]
-    kp, vp = pools
-    kpk, vpk = bad if poison_tail else pools
+        pool = kv_quant.quantize_pages(heads, kv_dtype)
+        bad = pool._replace(scale=pool.scale.at[poison].set(jnp.nan))
+    pool_k = bad if poison_tail else pool
     scale = 1.0 / d ** 0.5
     kw = dict(scale=scale, sliding_window=window)
     limit = context or max_pages * page
@@ -197,22 +197,22 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     return {
         "decode": (
             lambda interpret=False: pk.paged_decode_kernel(
-                q1, kpk, vpk, bt, pos, interpret=interpret, **kw),
+                q1, pool_k, bt, pos, interpret=interpret, **kw),
             lambda: pa.paged_attention_decode(
-                q1, kp, vp, bt, pos, use_kernel=False, **kw)),
+                q1, pool, bt, pos, use_kernel=False, **kw)),
         "prefill": (
             lambda interpret=False: pk.paged_prefill_kernel(
-                qs, kpk, vpk, bt1, start, interpret=interpret, **kw),
+                qs, pool_k, bt1, start, interpret=interpret, **kw),
             lambda: pa.paged_attention_prefill(
-                qs, kp, vp, bt1, start, use_kernel=False, **kw)),
+                qs, pool, bt1, start, use_kernel=False, **kw)),
         # live rows only: a dead row is exact zeros from the kernel and
         # null-page garbage from the gather path, by design
         "ragged": (
             lambda interpret=False: pk.paged_ragged_kernel(
-                qr, kpk, vpk, tables, r_idx, r_pos, r_hor,
+                qr, pool_k, tables, r_idx, r_pos, r_hor,
                 interpret=interpret, **kw)[live],
             lambda: pa.paged_attention_ragged(
-                qr, kp, vp, tables, r_idx, r_pos, r_hor,
+                qr, pool, tables, r_idx, r_pos, r_hor,
                 use_kernel=False, **kw)[live]),
     }
 
@@ -245,6 +245,8 @@ def tick_case(seed: int, name: str, width=None):
     """
     import numpy as np
 
+    from megatron_llm_tpu.ops import kv_quant
+
     geo, slots, live, lo, hi, chunk_at, slots_wide, window = {
         "falcon": (FALCON, 128, 49, 300, 700, 192, 128, None),
         "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 4096),
@@ -253,8 +255,9 @@ def tick_case(seed: int, name: str, width=None):
     width = width or slots_wide
     rng = np.random.default_rng(seed)
     num_pages = slots * slots_wide + 1
-    kp, vp = (jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)),
-                          jnp.bfloat16) for _ in range(2))
+    pool = kv_quant.pack_kv(*(
+        jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), jnp.bfloat16)
+        for _ in range(2))).reshape(num_pages, page, -1)
     chunk = 64
     pos = np.zeros(slots + chunk, np.int64)
     idx = np.full(slots + chunk, slots + 1)
@@ -274,7 +277,7 @@ def tick_case(seed: int, name: str, width=None):
     if window:
         visible = np.minimum(visible, window)
     keys = visible.sum() + chunk_at + chunk
-    args = (q, kp, vp) + tuple(
+    args = (q, pool) + tuple(
         jnp.asarray(a, jnp.int32) for a in (tables, idx, pos, hor))
     kw = dict(scale=1.0 / d ** 0.5, sliding_window=window)
     return args, kw, np.flatnonzero(hor), int(keys) * 2 * nkv * d * 2
@@ -293,6 +296,10 @@ def paged_numerics(quick: bool):
               for kvd in ("bf16", "int8")]
     cases += [dict(geo, **walk) for geo in (FALCON, MISTRAL)
               for walk in WALK_CASES]
+    # Falcon-40B: 8 kv heads of 64, a head's 128-lane key|value pair read
+    # as one operand
+    cases += [dict(n=16, nkv=8, d=64, page=16, kv_dtype=kvd)
+              for kvd in ("bf16", "int8")]
     if not quick:
         cases += [dict(n=32, nkv=8, d=128, page=page, kv_dtype=kvd)
                   for page in (8, 32, 128) for kvd in ("bf16", "int8", "fp8")]
@@ -316,7 +323,7 @@ def paged_numerics(quick: bool):
                       f"{type(exc).__name__}: {str(exc)[:300]}")
     for name in ("falcon", "mistral"):
         args, kw, live, _ = tick_case(7, name)
-        q, kp, vp, tables, idx, pos, _ = args
+        q, pool, tables, idx, pos, _ = args
         try:
             # a ragged row is the decode step at its position over its own
             # table; the ragged gather path scores every row against every
@@ -324,7 +331,7 @@ def paged_numerics(quick: bool):
             e = max_err(
                 pk.paged_ragged_kernel(*args, **kw)[live],
                 pa.paged_attention_decode(
-                    q[live], kp, vp, tables[idx[live]], pos[live],
+                    q[live], pool, tables[idx[live]], pos[live],
                     use_kernel=False, **kw))
             check(f"paged tick {name}", e < TOL, f"max_err={e:.2e}")
         except Exception as exc:
