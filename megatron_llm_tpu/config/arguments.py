@@ -65,6 +65,16 @@ class ModelConfig:
     parallel_layernorm: bool = False
     # Mistral sliding-window attention size (None = full causal).
     sliding_window_size: Optional[int] = None
+    # The layer PATTERN, one period of it (models/transformer.py owns what
+    # a kind of layer does): entry j says whether the layers l with
+    # l % period == j attend inside `sliding_window_size` (1) or to the whole
+    # causal prefix (0), and whether they rotate q and k (1) or carry no
+    # position signal (0).  The published `sliding_window_layout` /
+    # `rope_layout` of the SmallThinker family, cut to their first period.
+    # None = every layer alike (window where sliding_window_size is set,
+    # rotation where position_embedding_type is 'rotary'): a period of one
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
     tie_embed_logits: bool = False  # share input embedding and output head
     apply_query_key_layer_scaling: bool = False
     attention_softmax_in_fp32: bool = True
@@ -132,6 +142,19 @@ class ModelConfig:
     # shared experts: a dense MLP of moe_shared_experts x the expert width
     # that every token takes, added to the routed sum
     moe_shared_experts: int = 0
+    # what the router reads: 'post_attention' (the norm before the expert
+    # layer, Mixtral and the DeepSeek lineage) or 'layer_input' (the normed
+    # input of the layer, what the attention reads too: SmallThinker's
+    # router placed before attention)
+    moe_router_input: str = "post_attention"
+    # the chip's share of an expert-parallel layer: the router keeps its
+    # `num_experts` outputs and its top-k, this program HOLDS
+    # `moe_experts_held` of the experts, from `moe_first_held_expert` on,
+    # and computes the part of the layer's output those give; what the
+    # absent experts would add is left out (no code stands in for the
+    # other chips).  None = all of them
+    moe_experts_held: Optional[int] = None
+    moe_first_held_expert: int = 0
     # dense layers that run BEFORE the `num_layers` layers of the scanned
     # stack (DeepSeek's `first_k_dense_replace`: a published
     # `num_hidden_layers` is dense_prefix_layers + num_layers).  They are a
@@ -162,6 +185,16 @@ class ModelConfig:
         return self.attention_type == "mla"
 
     @property
+    def layer_period(self) -> int:
+        """Layers in one period of the layer pattern (1 = uniform)."""
+        return len(self.sliding_window_layout or self.rope_layout or (0,))
+
+    @property
+    def experts_held(self) -> int:
+        """Experts whose weights this program holds (all, unless told)."""
+        return self.moe_experts_held or self.num_experts
+
+    @property
     def latent_cache_width(self) -> int:
         """Values one cached token of an MLA layer holds: the normed
         latent and the rotated shared rope key."""
@@ -183,6 +216,32 @@ class ModelConfig:
             # exist (one latent row serves every query head)
             self.kv_channels = self.qk_rope_head_dim
             self.num_attention_heads_kv = 1
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None:
+                layout = tuple(int(v) for v in layout)
+                assert layout and set(layout) <= {0, 1}, (
+                    f"{name} is one period of 0/1 entries, got {layout}")
+                setattr(self, name, layout)
+        if self.sliding_window_layout or self.rope_layout:
+            assert (self.sliding_window_layout and self.rope_layout
+                    and len(self.sliding_window_layout)
+                    == len(self.rope_layout)), (
+                "a layer pattern gives sliding_window_layout and "
+                "rope_layout, one period each, of the same length")
+            assert self.num_layers % self.layer_period == 0, (
+                f"num_layers {self.num_layers} is not a whole number of "
+                f"periods of the layer pattern ({self.layer_period} layers)")
+            assert not any(self.sliding_window_layout) or (
+                self.sliding_window_size), (
+                "sliding_window_layout marks window layers: set "
+                "sliding_window_size")
+            assert not any(self.rope_layout) or (
+                self.position_embedding_type == "rotary"), (
+                "rope_layout marks rotating layers: position_embedding_type "
+                "must be 'rotary'")
+            assert not self.mla and not self.bidirectional, (
+                "a layer pattern is written for causal K/V-head attention")
         if self.kv_channels is None:
             assert self.hidden_size % self.num_attention_heads == 0, (
                 f"hidden_size {self.hidden_size} not divisible by "
@@ -694,6 +753,29 @@ class Config:
             assert self.model.moe_score_func in ("softmax", "sigmoid"), (
                 f"unknown moe_score_func {self.model.moe_score_func!r}"
             )
+            assert self.model.moe_router_input in (
+                "post_attention", "layer_input"), (
+                f"unknown moe_router_input {self.model.moe_router_input!r}")
+            assert not (self.model.moe_router_input == "layer_input"
+                        and self.model.parallel_attn), (
+                "moe_router_input 'layer_input' is for the sequential "
+                "block: a parallel block's experts read the layer input "
+                "already")
+            if self.model.moe_experts_held is not None:
+                m = self.model
+                assert (0 < m.moe_experts_held and 0 <= m.moe_first_held_expert
+                        and m.moe_first_held_expert + m.moe_experts_held
+                        <= m.num_experts), (
+                    f"held experts {m.moe_first_held_expert} .. "
+                    f"{m.moe_first_held_expert + m.moe_experts_held} lie "
+                    f"outside the router's {m.num_experts}")
+                assert (m.moe_router_type == "topk" and ep == 1), (
+                    "moe_experts_held is one chip's share of an "
+                    "expert-parallel layer on the dropless dispatch: "
+                    "expert_parallel_size 1 and moe_router_type 'topk'")
+                assert not m.moe_shared_experts, (
+                    "moe_experts_held with a shared expert: every chip "
+                    "would add the shared expert's output again")
             if self.model.moe_router_type == "expert_choice":
                 # EC routing compares tokens across positions within a
                 # routing group, leaking future-token information into the
@@ -719,7 +801,7 @@ class Config:
                 )
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
-                "mistral", "mixtral", "joyai",
+                "mistral", "mixtral", "joyai", "smallthinker",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -737,6 +819,20 @@ class Config:
             assert self.parallel.pipeline_model_parallel_size == 1, (
                 "a dense prefix is a stack of its own; pipeline stages "
                 "cut one uniform stack")
+        period = self.model.layer_period
+        if period > 1:
+            pp = self.parallel.pipeline_model_parallel_size
+            vpp = self.parallel.virtual_pipeline_model_parallel_size or 1
+            assert self.model.num_layers % (pp * vpp * period) == 0, (
+                f"a pipeline stage holds whole periods of the layer "
+                f"pattern: num_layers {self.model.num_layers} over "
+                f"{pp * vpp} stages is not a multiple of {period}")
+            assert self.parallel.context_parallel_size == 1, (
+                "a layer pattern with context parallelism is not written: "
+                "the ring has one window for every layer")
+            assert not self.model.dense_prefix_layers, (
+                "a layer pattern counts its periods from layer 0: no "
+                "dense prefix")
         return self
 
 
@@ -862,6 +958,26 @@ ARCH_DEFAULTS = {
         moe_shared_experts=1,
         dense_prefix_layers=1,
     ),
+    # SmallThinker (beyond-reference): RMSNorm blocks of GQA attention and
+    # ReGLU experts with no shared expert and no dense layer; one layer in
+    # four attends to everything and carries no position signal, the other
+    # three rotate and see 4096 keys; the router reads the layer's normed
+    # input (placed before attention), softmax over the chosen six
+    "smallthinker": dict(
+        use_rms_norm=True,
+        glu_activation="reglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=1_500_000.0,
+        sliding_window_size=4096,
+        sliding_window_layout=(0, 1, 1, 1),
+        rope_layout=(0, 1, 1, 1),
+        moe_router_input="layer_input",
+        moe_score_func="softmax",
+        moe_normalize_gates=True,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -919,6 +1035,14 @@ MODEL_SIZES = {
                             v_head_dim=128, num_experts=256,
                             moe_router_topk=8, moe_ffn_hidden_size=768,
                             vocab_size=129280),
+    # 52 layers = 13 periods of (full NoPE, window, window, window)
+    "smallthinker-21b-a3b": dict(num_layers=52, hidden_size=2560,
+                                 num_attention_heads=28,
+                                 num_attention_heads_kv=4, kv_channels=128,
+                                 max_position_embeddings=16384,
+                                 num_experts=64, moe_router_topk=6,
+                                 moe_ffn_hidden_size=768,
+                                 ffn_hidden_size=768, vocab_size=151936),
 }
 
 
@@ -1027,8 +1151,9 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     )
     parser.add_argument("--model_name", type=str, default=None,
                         help="gpt|llama|llama2|codellama|llama3|falcon|"
-                             "mistral|mixtral|qwen2|joyai|bert|t5 or a canonical "
-                             "size like llama2-7b / llama3-8b")
+                             "mistral|mixtral|qwen2|joyai|smallthinker|bert|t5 "
+                             "or a canonical size like llama2-7b / "
+                             "llama3-8b")
     seen = set()
     for group_name, group_cls in _GROUPS.items():
         group = parser.add_argument_group(group_name)

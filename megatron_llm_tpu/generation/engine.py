@@ -162,6 +162,30 @@ class EngineOverloaded(RuntimeError):
         self.info = info or {}
 
 
+def refuse_layer_pattern(cfg) -> None:
+    """The engine does not serve a patterned stack or a share of the experts
+    yet, and says so at start-up instead of running them wrong.  The tick's
+    layers would take their kinds from models/transformer.py as the trainer
+    does; what is not written is around them: the pool has ONE page class
+    (a window layer would keep every page of a sequence where
+    ceil(window / page) + 1 serve it), the prefix trie, preemption and the
+    handoff count pages without a layer class, and nothing here tests the
+    paged kernel under two masks in one tick."""
+    m = cfg.model
+    if m.layer_period > 1:
+        raise ValueError(
+            f"a layer pattern (sliding_window_layout {m.sliding_window_layout}"
+            f", rope_layout {m.rope_layout}) is not served by the "
+            "continuous-batching engine yet: its paged pool has one page "
+            "class for every layer.  Train it (finetune.py) or run the dense "
+            "forward; serving it is ROADMAP R3b")
+    if m.num_experts is not None and m.experts_held < m.num_experts:
+        raise ValueError(
+            f"moe_experts_held {m.moe_experts_held} of {m.num_experts}: a "
+            "share of an expert-parallel layer is not a model to serve; the "
+            "other chips' experts and their exchange are not here")
+
+
 def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
                         draft: bool = False, pipeline_depth: int = 0,
                         handoff: bool = False) -> None:
@@ -785,6 +809,7 @@ class ContinuousBatchingEngine:
                  mesh: Optional[Mesh] = None):
         inf = cfg.inference
         self.cfg = cfg
+        refuse_layer_pattern(cfg)
         if cfg.model.mla:
             # before anything is placed or resolved: a sentence, not a
             # sharding error from the middle of start-up

@@ -326,7 +326,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             temperature=temperature, vocab_size=cfg.model.vocab_size)
         logp = gen._gather_token_log_probs(last, next_tok)
         res = (pool_kv, next_tok, logp, positions + 1, steps + 1)
-        return res + (aux[2:],) if moe else res
+        return res + (aux[2:4],) if moe else res
 
     base_fn = spec_tick if K else tick
     if ovl is None and ppc is None:

@@ -56,6 +56,22 @@ def validate_family(cfg: Config) -> Config:
                "joyai is a sequential block without biases")
         _check(m.position_embedding_type == "rotary",
                "joyai requires rotary embeddings")
+    elif name == "smallthinker":
+        _check(m.layer_period > 1 and 0 in m.sliding_window_layout
+               and 1 in m.sliding_window_layout,
+               "smallthinker mixes full and window layers: give "
+               "sliding_window_layout and rope_layout")
+        _check(m.num_experts is not None and m.num_experts > 1
+               and not m.moe_shared_experts,
+               "smallthinker requires num_experts > 1 and no shared expert")
+        _check(m.moe_router_input == "layer_input"
+               and m.moe_score_func == "softmax" and m.moe_normalize_gates,
+               "smallthinker's router reads the layer input and weighs the "
+               "chosen experts by a softmax over them")
+        _check(m.use_rms_norm and m.glu_activation == "reglu",
+               "smallthinker uses RMSNorm and ReGLU")
+        _check(not m.use_bias and not m.parallel_attn,
+               "smallthinker is a sequential block without biases")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

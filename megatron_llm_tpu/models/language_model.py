@@ -378,6 +378,13 @@ def loss_from_batch(cfg, params, batch: Dict[str, jax.Array], *,
         c_bal, c_z = aux_loss_coeffs(cfg)
         total = loss + c_bal * balance + c_z * z
         metrics["moe aux loss"] = balance
+        # what the routers did, summed over the expert layers: assignments
+        # made, those whose expert is held here and ran, and held ones that
+        # found the row buffer full (models/moe.py; the trainer's
+        # `train-moe` span carries them)
+        metrics["moe assignments"] = out[2][2]
+        metrics["moe held"] = out[2][4]
+        metrics["moe dropped"] = out[2][5]
         if c_z:
             metrics["router z loss"] = z
         return total, metrics

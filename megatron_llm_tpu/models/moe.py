@@ -33,6 +33,17 @@ adds the capability TPU-first, in the GShard/Switch/Mixtral lineage:
   load, no ``[G, T, E, C]`` one-hot exists (at 256 experts that tensor
   would be the layer), and ``moe_capacity_factor`` / ``moe_group_size`` do
   not apply.
+* **A layer told which experts it holds** (``moe_experts_held``,
+  ``moe_first_held_expert``: one chip's share of an expert-parallel layer
+  whose other chips are not here).  The router keeps its ``num_experts``
+  outputs and its top-k and the losses count all of them; the expert stacks
+  hold the held experts only; the dispatch keeps the assignments whose
+  expert is held and drops the rest BEFORE the gather, so absent experts
+  cost no rows, no GEMM and no multiplied zeros; the output is the held
+  experts' part of the layer's sum.  The row buffer is static:
+  ``moe_capacity_factor`` x the held share of the ``T x topk`` assignments
+  (at most all of them), and an assignment that finds it full is dropped
+  and COUNTED (``aux[5]``), never silently.
 * **The router's options are data of the family** (:func:`route`):
   softmax or sigmoid scores, a selection bias that picks the top-k and is
   not part of the weight, renormalisation, a scaling factor; and a
@@ -57,6 +68,7 @@ PERF.md, PR 31).  tp still shards the last axis.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -87,9 +99,11 @@ def moe_capacity_expert_choice(cfg, tokens_per_group: int) -> int:
 
 # the per-layer aux vector: (load-balance loss, router z-loss) for the
 # trained loss, then what the router did, for the serving engine's counters
-# (generation/ragged.py): assignments made (rows x topk) and distinct
-# experts that received at least one row
-AUX_LEN = 4
+# (generation/ragged.py) and the trainer's `train-moe` span: assignments
+# made (rows x topk), distinct experts that received at least one row,
+# assignments whose expert is held here and ran, and held assignments
+# dropped because the row buffer was full
+AUX_LEN = 6
 
 
 def expert_width(cfg) -> int:
@@ -99,7 +113,8 @@ def expert_width(cfg) -> int:
 
 def init_moe_params(cfg, key: jax.Array) -> Params:
     m = cfg.model
-    h, f, e = m.hidden_size, expert_width(cfg), m.num_experts
+    # the router keeps every output; the stacks hold the held experts
+    h, f, e = m.hidden_size, expert_width(cfg), m.experts_held
     glu = m.glu_activation is not None
     std = m.init_method_std
     out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
@@ -108,7 +123,8 @@ def init_moe_params(cfg, key: jax.Array) -> Params:
     # the dense MLP (transformer.init_layer_params)
     fc1_shape = (e, 2, h, f) if glu else (e, h, f)
     p: Params = {
-        "router": {"kernel": std * jax.random.normal(kr, (h, e), jnp.float32)},
+        "router": {"kernel": std * jax.random.normal(
+            kr, (h, m.num_experts), jnp.float32)},
         "experts": {
             "fc1": {"kernel": std * jax.random.normal(k1, fc1_shape, jnp.float32)},
             "fc2": {"kernel": out_std * jax.random.normal(k2, (e, f, h), jnp.float32)},
@@ -127,7 +143,7 @@ def init_moe_params(cfg, key: jax.Array) -> Params:
         # a program that forgets the bias; large values would skew which
         # experts a tick touches, which a balanced model does not
         p["router"]["bias"] = 0.02 * jax.random.normal(
-            jax.random.fold_in(kr, 1), (e,), jnp.float32)
+            jax.random.fold_in(kr, 1), (m.num_experts,), jnp.float32)
     if m.moe_shared_experts:
         fs = f * m.moe_shared_experts
         ks1, ks2 = jax.random.split(jax.random.fold_in(key, 1))
@@ -173,10 +189,12 @@ def _router_z_loss(router_logits: jax.Array) -> jax.Array:
 def _aux(balance, z, rows_per_expert: jax.Array) -> jax.Array:
     """The layer's aux vector [AUX_LEN] from its two losses and the rows
     each expert received."""
+    total = rows_per_expert.sum().astype(jnp.float32)
+    # held and dropped: the dispatch fills them in where it keeps a share
     return jnp.stack([
-        balance.astype(jnp.float32), z.astype(jnp.float32),
-        rows_per_expert.sum().astype(jnp.float32),
-        (rows_per_expert > 0).sum().astype(jnp.float32)])
+        balance.astype(jnp.float32), z.astype(jnp.float32), total,
+        (rows_per_expert > 0).sum().astype(jnp.float32), total,
+        jnp.zeros((), jnp.float32)])
 
 
 @jax.named_scope("router")
@@ -231,27 +249,91 @@ class StackedExperts(NamedTuple):
     layer: jax.Array
 
 
-_GMM_ROWS = 128            # rows of one tile of the grouped kernel
+_GMM_ROWS = 128            # rows of one tile of the grouped kernel ...
+_GMM_ROWS_MANY = 512       # ... and where an expert has at least as many
 _GMM_WEIGHT_TILE = 3 << 20  # bytes of one expert-weight tile in VMEM
+_GMM_GRAD_TILE = 1 << 19   # elements of one weight-gradient tile (float32)
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``dim`` (itself one) and
+    is at most ``cap``; ``dim`` where it fits."""
+    if dim <= cap:
+        return dim
+    return max(t for t in range(128, cap + 1, 128) if dim % t == 0)
+
+
+def _gmm_tiles(tm: int, k: int, n: int, itemsize: int):
+    """Tiles of ``[m, k] @ [k, n]``: the whole k if 2048 hold it, then as
+    much of n as the weight tile's bytes allow."""
+    tk = _tile(k, 2048)
+    return tm, tk, _tile(n, max(128, _GMM_WEIGHT_TILE // (tk * itemsize)
+                                // 128 * 128))
+
+
+def _row_tile(m: int, groups: int) -> int:
+    """512 rows a tile where the groups average at least that, else 128."""
+    return _GMM_ROWS_MANY if m % _GMM_ROWS_MANY == 0 and (
+        m // groups >= _GMM_ROWS_MANY) else _GMM_ROWS
+
+
+@jax.custom_vjp
+def _gmm(rows, kernel, sizes):
+    """megablox ``gmm`` with tiles chosen per problem in the backward too
+    (jax's own vjp reuses the forward's tiles on the transposed shapes)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (m, k), n = rows.shape, kernel.shape[-1]
+    tm = _row_tile(m, kernel.shape[0])
+    return gmm(rows, kernel, sizes, preferred_element_type=rows.dtype,
+               tiling=_gmm_tiles(tm, k, n, rows.dtype.itemsize))
+
+
+def _gmm_fwd(rows, kernel, sizes):
+    return _gmm(rows, kernel, sizes), (rows, kernel, sizes)
+
+
+def _gmm_bwd(res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    rows, kernel, sizes = res
+    (m, k), n = rows.shape, kernel.shape[-1]
+    tm = _row_tile(m, kernel.shape[0])
+    d_rows = gmm(g, kernel, sizes, preferred_element_type=rows.dtype,
+                 tiling=_gmm_tiles(tm, n, k, rows.dtype.itemsize),
+                 transpose_rhs=True)
+    tk = _tile(k, 1024)
+    d_kernel = tgmm(rows.swapaxes(0, 1), g, sizes,
+                    preferred_element_type=kernel.dtype,
+                    tiling=(tm, tk, _tile(n, max(128, _GMM_GRAD_TILE // tk
+                                                 // 128 * 128))),
+                    num_actual_groups=kernel.shape[0])
+    return d_rows, d_kernel, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(rows: jax.Array, kernel: jax.Array, counts: jax.Array,
                    layer: Optional[jax.Array] = None,
                    half: Optional[int] = None) -> jax.Array:
     """``rows[start_e : start_e + counts[e]] @ W[e]`` for every expert
-    ``e``: ``rows`` [m, k] sorted by expert, ``counts`` [E] rows an expert.
+    ``e``: ``rows`` [m, k] sorted by expert, ``counts`` [E] rows an expert
+    (rows behind the last expert's belong to none and come back undefined).
     ``kernel`` holds the ``W[e]`` [k, n] on its last two axes and, before
     them, the expert axis, with a layer axis in front where ``layer`` says
     which layer is meant (the serving tick's whole stack) and a GLU chunk
     axis behind where ``half`` says which half: ``[(L,) E, (2,) k, n]``.
 
     On a TPU target this is jax's grouped-matmul Pallas kernel (megablox
-    ``gmm``, differentiable), which visits (row tile, group) pairs and
-    streams each touched group's weights once, in tiles as large as fast
-    memory takes (the whole k, then as much of n as 3 MB holds): at the
-    serving tick's few rows an expert the layer is weight-streaming, and
-    small tiles pay a grid step per tile (PERF.md, PR 31: 4.1 ms a layer
-    against ``jax.lax.ragged_dot``'s 8.7 at 256 experts x 8 rows).  The
+    ``gmm``; ``tgmm`` for the weights' gradient), which visits (row tile,
+    group) pairs and streams each touched group's weights once a row tile,
+    in tiles as large as fast memory takes (the whole k, then as much of n
+    as 3 MB holds): at the serving tick's few rows an expert the layer is
+    weight-streaming, and small tiles pay a grid step per tile (PERF.md,
+    PR 31: 4.1 ms a layer against ``jax.lax.ragged_dot``'s 8.7 at 256
+    experts x 8 rows); at the trainer's hundreds of rows an expert a row
+    tile is 512, so that the weights are streamed a quarter as often.  The
     kernel is handed the WHOLE leaf as ``[groups, k, n]``, a view, with
     rows for the groups meant and none for the others: a slice of the leaf
     would be a copy of it.  Elsewhere (the CPU tests), and for widths off
@@ -263,18 +345,13 @@ def grouped_matmul(rows: jax.Array, kernel: jax.Array, counts: jax.Array,
         () if half is None else (half,))
     if target_platform() != "tpu" or k % 128 or n % 128:
         return jax.lax.ragged_dot(rows, kernel[pick], counts)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     sizes = jnp.zeros(kernel.shape[:-2], counts.dtype).at[pick].set(counts)
     pad = -m % _GMM_ROWS
     if pad:   # whole row tiles; the padding rows belong to no group
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    tk = min(k, 2048)
-    tn = min(n, max(128, _GMM_WEIGHT_TILE // (tk * rows.dtype.itemsize)
-                    // 128 * 128))
-    out = gmm(rows, kernel.reshape(-1, k, n).astype(rows.dtype),
-              sizes.reshape(-1), preferred_element_type=rows.dtype,
-              tiling=(_GMM_ROWS, tk, tn))
+    out = _gmm(rows, kernel.reshape(-1, k, n).astype(rows.dtype),
+               sizes.reshape(-1))
     return out[:m] if pad else out
 
 
@@ -301,26 +378,135 @@ def _grouped_linear(p_lin: Params, rows: jax.Array, counts: jax.Array,
     return y
 
 
+def held_rows(cfg, assignments: int) -> int:
+    """Rows of the dispatch's buffer for ``assignments`` (tokens x topk)
+    router choices: all of them where every expert is held; for a share,
+    ``moe_capacity_factor`` x the held experts' expected part, in whole
+    row tiles of the grouped kernel, and never more than all."""
+    m = cfg.model
+    if m.experts_held == m.num_experts:
+        return assignments
+    rows = math.ceil(assignments * m.experts_held * m.moe_capacity_factor
+                     / m.num_experts)
+    return min(assignments, -(-rows // _GMM_ROWS_MANY) * _GMM_ROWS_MANY)
+
+
+# The dispatch and the combine as a pair of transposes, each a GATHER in
+# both directions (a scatter-add of tens of thousands of rows is the one
+# thing the TPU does a row at a time).  ``order`` [R]: the flat assignment
+# (token * K + slot) behind buffer row r; ``live`` [R]: the row holds an
+# assignment; ``inv`` [T, K]: the buffer row of an assignment; ``valid``
+# [T, K]: the assignment has one.  Where every expert is held every row is
+# live and every assignment has one: both masks are None and cost nothing.
+
+
+def _keep(mask, x):
+    """``x`` where ``mask`` (broadcast over the trailing axis), else 0."""
+    if mask is None:
+        return x
+    return jnp.where(mask if mask.ndim == x.ndim else mask[..., None], x, 0)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, live, inv, valid):
+    """rows[r] = x[token of row r], zero where the row is not live."""
+    k_ = inv.shape[1]
+    return _keep(live, x[order // k_])
+
+
+def _dispatch_fwd(x, order, live, inv, valid):
+    return _dispatch(x, order, live, inv, valid), (inv, valid)
+
+
+def _dispatch_bwd(res, g):
+    inv, valid = res
+    back = _keep(valid, g[inv])                               # [T, K, h]
+    dx = back.astype(jnp.float32).sum(1).astype(g.dtype)
+    return dx, None, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, w, order, live, inv, valid):
+    """y[t] = sum_k w[t, k] * out[row of (t, k)] over the assignments that
+    have a row, accumulated in float32."""
+    back = _keep(valid, out[inv])                             # [T, K, h]
+    return jnp.einsum("tkh,tk->th", back.astype(jnp.float32),
+                      w).astype(out.dtype)
+
+
+def _combine_fwd(out, w, order, live, inv, valid):
+    return (_combine(out, w, order, live, inv, valid),
+            (out, w, order, live, inv, valid))
+
+
+def _combine_bwd(res, dy):
+    out, w, order, live, inv, valid = res
+    k_ = inv.shape[1]
+    g = _keep(live, dy[order // k_]).astype(jnp.float32)
+    w_row = _keep(live, w.reshape(-1)[order])                 # [R]
+    d_out = (g * w_row[:, None]).astype(out.dtype)
+    # a row's weight gradient, made where the row lies and carried back
+    dw_row = _keep(live, (g * out.astype(jnp.float32)).sum(-1))
+    dw = _keep(valid, dw_row[inv]).astype(w.dtype)
+    return d_out, dw, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def dropless_experts(cfg, experts, x: jax.Array, idx: jax.Array,
-                     w: jax.Array, counts: jax.Array) -> jax.Array:
-    """sum_k w[t, k] * E_{idx[t, k]}(x[t]) for ``x`` [T, h], with no
-    capacity: sort the T*K assignments by expert, run the sorted rows
-    through one grouped GEMM for each half of fc1 and one for fc2, and
-    gather each token's K results back (a gather through the inverse
-    permutation, not a scatter-add: deterministic, and no scatter on the
-    TPU).  ``experts`` is a layer's subtree or :class:`StackedExperts`."""
+                     w: jax.Array, counts: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """sum_k w[t, k] * E_{idx[t, k]}(x[t]) over the HELD experts, for ``x``
+    [T, h], with no capacity where all are held: sort the T*K assignments
+    by expert, run the sorted rows through one grouped GEMM for each half
+    of fc1 and one for fc2, and gather each token's results back (a gather
+    through the inverse permutation, not a scatter-add: deterministic, and
+    no scatter on the TPU, forward or backward).  A share
+    (``moe_experts_held``): an absent expert's assignments sort behind
+    every held one and get no row; the buffer is :func:`held_rows` long.
+    ``experts`` is a layer's subtree or :class:`StackedExperts`; ``counts``
+    [num_experts] the rows the router gave each expert.
+
+    Returns (out [T, h], assignments that ran, held assignments dropped
+    for want of a row: zero where all experts are held)."""
     m = cfg.model
     t_, k_ = idx.shape
     dt = x.dtype
+    held, first = m.experts_held, m.moe_first_held_expert
+    n_rows = held_rows(cfg, t_ * k_)
     layer = None
     if isinstance(experts, StackedExperts):
         experts, layer = experts
     with jax.named_scope("dispatch"):
         flat = idx.reshape(t_ * k_)
+        share = held < m.num_experts
+        if share:   # an absent expert's assignments sort behind the held
+            flat = flat - first
+            flat = jnp.where((flat >= 0) & (flat < held), flat, held)
         order = jnp.argsort(flat)                 # stable: rows by expert
-        row_expert = flat[order]
-        rows = x[order // k_]                     # [T*K, h]
+        inv = jnp.argsort(order).reshape(t_, k_)  # an assignment's row
         counts = counts.astype(jnp.int32)
+        ran, dropped = counts.sum().astype(jnp.float32), jnp.float32(0)
+        live = valid = None
+        row_expert = flat[order[:n_rows]]
+        if share:
+            order = order[:n_rows]
+            live = row_expert < held
+            valid = (flat.reshape(t_, k_) < held) & (inv < n_rows)
+            inv = jnp.minimum(inv, n_rows - 1)
+            row_expert = jnp.minimum(row_expert, held - 1)
+            # rows an expert runs: what the router gave it, as far as the
+            # buffer reaches
+            given = counts[first:first + held]
+            start = jnp.cumsum(given) - given
+            counts = jnp.clip(n_rows - start, 0, given)
+            ran = counts.sum().astype(jnp.float32)
+            dropped = given.sum().astype(jnp.float32) - ran
+        rows = _dispatch(x, order, live, inv, valid)          # [R, h]
     with jax.named_scope("expert_gemm"):
         def linear(name, rows, half=None):
             return _grouped_linear(experts[name], rows, counts, row_expert,
@@ -332,11 +518,9 @@ def dropless_experts(cfg, experts, x: jax.Array, idx: jax.Array,
         else:
             inter = get_mlp_activation(None, m.activation)(
                 linear("fc1", rows))
-        out = linear("fc2", inter)                            # [T*K, h]
+        out = linear("fc2", inter)                            # [R, h]
     with jax.named_scope("combine"):
-        back = out[jnp.argsort(order)].reshape(t_, k_, -1)
-        return jnp.einsum("tkh,tk->th", back.astype(jnp.float32),
-                          w).astype(dt)
+        return _combine(out, w, order, live, inv, valid), ran, dropped
 
 
 def use_dropless(cfg) -> bool:
@@ -428,9 +612,13 @@ def route_tokens(
     return combine, dispatch, aux
 
 
-def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def moe_sublayer(cfg, p: Params, x: jax.Array,
+                 router_x: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
     """MoE FFN over [b, s, h]; tokens route in per-sequence-chunk groups of
-    ``moe_group_size``. Returns (out, aux[2]).
+    ``moe_group_size``. Returns (out, aux[AUX_LEN]).  ``router_x``
+    [b, s, h] is what the router reads where that is not ``x``
+    (``moe_router_input``: the dropless dispatch only).
 
     Replaces mlp_sublayer (transformer.py) on MoE layers; the dense path's
     GLU chunk-2 convention (glu_activations.py:14-16) is preserved per expert.
@@ -440,8 +628,13 @@ def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     if use_dropless(cfg):
         with jax.named_scope("moe"):
             xt = x.reshape(b * s, h)
-            idx, w, counts, aux = route(cfg, p["router"], xt)
-            out = dropless_experts(cfg, p["experts"], xt, idx, w, counts)
+            idx, w, counts, aux = route(
+                cfg, p["router"],
+                xt if router_x is None else router_x.reshape(b * s, h))
+            out, ran, dropped = dropless_experts(
+                cfg, p["experts"], xt, idx, w, counts)
+            if m.experts_held < m.num_experts:
+                aux = aux.at[4].set(ran).at[5].set(dropped)
             if "shared" in p:
                 from megatron_llm_tpu.models.transformer import mlp_sublayer
 
@@ -449,11 +642,11 @@ def moe_sublayer(cfg, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
                     out = out + mlp_sublayer(cfg, p["shared"], xt)
             return out.reshape(b, s, h), aux
     assert "shared" not in p and "bias" not in p["router"] and (
-        m.moe_score_func == "softmax"
+        m.moe_score_func == "softmax" and router_x is None
         and m.moe_routed_scaling_factor == 1.0), (
         "the capacity dispatch (ep > 1, expert_choice) knows the softmax "
         "top-k router only: no shared expert, selection bias, sigmoid "
-        "scores or scaling factor")
+        "scores, scaling factor or router before the attention")
     # GShard grouping: route fixed-size chunks of the sequence independently
     # so dispatch/combine stay O(group * capacity), not O(seq^2) — at 32K seq
     # an ungrouped [s, E, C~s] one-hot would be gigabytes per sample.

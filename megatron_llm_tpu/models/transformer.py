@@ -39,10 +39,19 @@ its own ``'attention'`` subtree, n heads of nope + rope query dims:
 
 A model with ``dense_prefix_layers`` holds two stacks: ``dense_layers``
 (the prefix, dense MLP) and ``layers`` (the scanned expert layers).
+
+The LAYER PATTERN is owned here (:class:`LayerKind`, :func:`layer_kinds`):
+every layer has the same leaves, so the tree stays the uniform ``[L, ...]``
+stack, and what differs between kinds of layer (the keys a query may see,
+whether q and k rotate) is static data of the layer's place in its period.
+:func:`transformer_forward` scans over PERIODS with the period's layers
+unrolled in the body, so each kernel gets its static window and nothing
+chooses between kernels at run time; a uniform model is a period of one.
 """
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -219,6 +228,40 @@ def split_qkv(
     return q, k, v
 
 
+class LayerKind(NamedTuple):
+    """What a kind of layer does in its attention: ``window`` keys a causal
+    query may see (None = all of them) and whether q and k ``rotate``."""
+
+    window: Optional[int]
+    rotate: bool
+
+    @property
+    def scope(self) -> str:
+        """The named scope of this kind's attention in a patterned stack
+        (``attention/global``, ``attention/window``)."""
+        return "global" if self.window is None else "window"
+
+
+def layer_kinds(cfg) -> Tuple[LayerKind, ...]:
+    """One period of the layer pattern: layer ``l`` is of kind
+    ``layer_kinds(cfg)[l % period]``.  A uniform model has one kind: its
+    window if it has one, rotation wherever it is handed a rope table."""
+    m = cfg.model
+    if m.sliding_window_layout is None:
+        return (LayerKind(m.sliding_window_size, True),)
+    return tuple(
+        LayerKind(m.sliding_window_size if w else None, bool(r))
+        for w, r in zip(m.sliding_window_layout, m.rope_layout))
+
+
+def _uniform_kind(cfg) -> LayerKind:
+    kinds = layer_kinds(cfg)
+    assert len(kinds) == 1, (
+        "a layer of a patterned stack must be told its kind "
+        "(transformer_forward does; block_forward(kind=...))")
+    return kinds[0]
+
+
 @jax.named_scope("attention")  # a region a device trace can price
 def attention_sublayer(
     cfg,
@@ -234,8 +277,12 @@ def attention_sublayer(
     token_idx: Optional[jax.Array] = None,
     attn_bias: Optional[jax.Array] = None,
     paged=None,
+    kind: Optional[LayerKind] = None,
 ):
     """ParallelAttention analog (transformer.py:280-657).
+
+    ``kind`` is the layer's kind (its window, whether it rotates); None
+    means the one kind of a uniform model.
 
     ``paged`` (ops/paged_attention.PagedState) switches the incremental-decode
     branch to the block-table page pool: ``kv_cache`` is then a
@@ -245,6 +292,22 @@ def attention_sublayer(
 
     Returns (output [b, s, h], new_kv_cache).
     """
+    m = cfg.model
+    b, s, _ = x.shape
+    n, nkv, d = m.num_attention_heads, m.num_attention_heads_kv, m.kv_channels
+    kind = kind or _uniform_kind(cfg)
+    # a patterned stack names its kinds, so that a device trace can tell
+    # the window layers' kernels from the global layers'
+    with (jax.named_scope(kind.scope) if m.layer_period > 1
+          else contextlib.nullcontext()):
+        return _attention(cfg, p, x, rope, position_ids, segment_ids,
+                          dropout_key, deterministic, kv_cache, cache_index,
+                          token_idx, attn_bias, paged, kind)
+
+
+def _attention(cfg, p, x, rope, position_ids, segment_ids, dropout_key,
+               deterministic, kv_cache, cache_index, token_idx, attn_bias,
+               paged, kind: LayerKind):
     m = cfg.model
     b, s, _ = x.shape
     n, nkv, d = m.num_attention_heads, m.num_attention_heads_kv, m.kv_channels
@@ -258,7 +321,7 @@ def attention_sublayer(
     qkv = apply_column_parallel(cfg, p["qkv"], x, linear)
     q, k, v = split_qkv(qkv, n, nkv, d)
 
-    if rope is not None:
+    if rope is not None and kind.rotate:
         cos, sin = rope
         q = apply_rotary_emb(q, cos, sin, position_ids)
         k = apply_rotary_emb(k, cos, sin, position_ids)
@@ -319,19 +382,19 @@ def attention_sublayer(
             ctx = paged_attention_ragged(
                 q, pool, paged.block_tables, paged.table_index, pos,
                 paged.horizons,
-                scale=scale, sliding_window=m.sliding_window_size,
+                scale=scale, sliding_window=kind.window,
                 use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
         elif s == 1:
             ctx = paged_attention_decode(
                 q, pool, paged.block_tables, pos, scale=scale,
-                sliding_window=m.sliding_window_size,
+                sliding_window=kind.window,
                 use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
         else:
             ctx = paged_attention_prefill(
                 q, pool, paged.block_tables, pos, scale=scale,
-                sliding_window=m.sliding_window_size,
+                sliding_window=kind.window,
                 use_kernel=cfg.training.use_flash_attn, layer=layer,
             )
     elif kv_cache is not None:
@@ -346,15 +409,15 @@ def attention_sublayer(
         q_pos = cache_index + jnp.arange(s)[:, None]
         kv_pos = jnp.arange(kv_len)[None, :]
         allowed = q_pos >= kv_pos
-        if m.sliding_window_size is not None:
-            allowed &= q_pos - kv_pos < m.sliding_window_size
+        if kind.window is not None:
+            allowed &= q_pos - kv_pos < kind.window
         bias = jnp.where(allowed, 0.0, attn_ops.NEG_INF).astype(jnp.float32)[None, None]
         ctx = attn_ops.xla_attention(q, ck, cv, bias=bias, scale=scale)
     else:
         ctx = attn_ops.attention(
             q, k, v,
             causal=not m.bidirectional,
-            sliding_window=m.sliding_window_size,
+            sliding_window=kind.window,
             segment_ids=segment_ids,
             token_idx=token_idx,
             bias=attn_bias,
@@ -543,13 +606,20 @@ def cross_attention_sublayer(
     return linear(p["dense"], ctx.reshape(b, sq, n * d))
 
 
-def ffn_sublayer(cfg, p: Params, x: jax.Array):
-    """Dense MLP or MoE, depending on the layer params. Returns (out, aux[2])
-    where aux is the (load-balance, z) router loss pair (zeros for dense)."""
+def ffn_sublayer(cfg, p: Params, x: jax.Array,
+                 layer_input: Optional[jax.Array] = None):
+    """Dense MLP or MoE, depending on the layer params. Returns (out,
+    aux[AUX_LEN]): the router's losses and counts (zeros for dense).
+    ``layer_input`` is the layer's normed input, which the router reads
+    instead of ``x`` where the family places it before the attention
+    (``moe_router_input``)."""
     from megatron_llm_tpu.models import moe as moe_mod
 
     if "moe" in p:
-        return moe_mod.moe_sublayer(cfg, p["moe"], x)
+        before = cfg.model.moe_router_input == "layer_input"
+        assert layer_input is not None or not before
+        return moe_mod.moe_sublayer(
+            cfg, p["moe"], x, router_x=layer_input if before else None)
     return mlp_sublayer(cfg, p["mlp"], x), moe_mod.zero_aux()
 
 
@@ -603,8 +673,10 @@ def block_forward(
     cache_index=None,
     paged=None,
     sp_constraint=None,
+    kind: Optional[LayerKind] = None,
 ):
     """One transformer layer (ParallelTransformerLayer, transformer.py:659-894).
+    ``kind``: the layer's kind in a patterned stack (None = uniform).
 
     Pre-LN residual block; ``parallel_attn`` runs attention and MLP from the
     same normed input and sums both into the residual (Falcon,
@@ -632,7 +704,7 @@ def block_forward(
         attn_out, new_cache = attention_sublayer(
             cfg, p["attention"], ln1, rope, position_ids, segment_ids,
             dk_attn, deterministic, kv_cache, cache_index,
-            token_idx=token_idx, attn_bias=attn_bias, paged=paged,
+            token_idx=token_idx, attn_bias=attn_bias, paged=paged, kind=kind,
         )
 
     if m.parallel_attn:
@@ -661,7 +733,7 @@ def block_forward(
             )
             resid = _sp(resid)
         ln2 = norm(resid, p["post_norm"], eps, m.use_rms_norm)
-        mlp_out, aux = ffn_sublayer(cfg, p, ln2)
+        mlp_out, aux = ffn_sublayer(cfg, p, ln2, layer_input=ln1)
         out = resid + rng_mod.dropout(dk_h2, rate, mlp_out, deterministic or dk_h2 is None)
         out = _sp(out)
     return out, new_cache, aux
@@ -759,7 +831,7 @@ def transformer_forward(
         stacked_layers = {**stacked_layers, "moe": {
             k: v for k, v in stacked_layers["moe"].items() if k != "experts"}}
 
-    def one_layer(carry, xs):
+    def one_layer(carry, xs, kind):
         carry_hidden, pool = carry
         layer_params, layer_idx, cache = xs
         if in_carry:
@@ -779,12 +851,20 @@ def transformer_forward(
             dropout_key=dk, deterministic=deterministic,
             hidden_dropout_rate=rate,
             kv_cache=cache, cache_index=cache_index, paged=paged,
-            sp_constraint=sp_constraint,
+            sp_constraint=sp_constraint, kind=kind,
         )
         if in_carry:
             pool, new_cache = new_cache, None
         return (out, pool), (new_cache, aux)
 
+    # the pattern: layer l is of kind kinds[l % period].  A stack handed to
+    # this function is whole periods from a period's first layer on (a
+    # pipeline stage's slice too: Config.finalize), so a layer's place in
+    # its period is its place in the stack, whatever layer_offset is
+    kinds = layer_kinds(cfg)
+    period = len(kinds)
+    assert num_layers % period == 0, (
+        f"a stack of {num_layers} layers is not whole periods of {period}")
     layer_ids = jnp.arange(num_layers) + layer_offset
 
     if cfg.training.scan_layers:
@@ -793,12 +873,36 @@ def transformer_forward(
             "full" if granularity == "full" else cfg.training.remat_policy
             if granularity else "none"
         )
-        body = one_layer
+        bodies = [partial(one_layer, kind=k) for k in kinds]
         if granularity is not None:
-            body = jax.checkpoint(one_layer, policy=policy, prevent_cse=False)
+            # a checkpoint a LAYER, not a period: what the backward holds
+            # at once is one layer's internals, as in a uniform stack
+            bodies = [jax.checkpoint(b, policy=policy, prevent_cse=False)
+                      for b in bodies]
+        if period == 1:
+            body = bodies[0]
+            xs = (stacked_layers, layer_ids, kv_caches)
+        else:
+            # scan over periods, the period's layers unrolled in the body:
+            # a layer's kind is static there
+            def body(carry, xs):
+                outs = []
+                for j, layer_body in enumerate(bodies):
+                    carry, out = layer_body(
+                        carry, jax.tree.map(lambda a: a[j], xs))
+                    outs.append(out)
+                return carry, jax.tree.map(lambda *o: jnp.stack(o), *outs)
+
+            xs = jax.tree.map(
+                lambda a: a.reshape(num_layers // period, period,
+                                    *a.shape[1:]),
+                (stacked_layers, layer_ids, kv_caches))
         (hidden, pool), (new_caches, aux_stack) = jax.lax.scan(
-            body, (hidden, pool), (stacked_layers, layer_ids, kv_caches)
-        )
+            body, (hidden, pool), xs)
+        if period > 1:
+            new_caches, aux_stack = jax.tree.map(
+                lambda a: a.reshape(num_layers, *a.shape[2:]),
+                (new_caches, aux_stack))
         return hidden, pool if in_carry else new_caches, aux_stack.sum(0)
     else:
         from megatron_llm_tpu.models.moe import zero_aux
@@ -809,7 +913,8 @@ def transformer_forward(
             layer_p = jax.tree.map(lambda a: a[i], stacked_layers)
             cache = None if kv_caches is None else jax.tree.map(lambda a: a[i], kv_caches)
             (hidden, pool), (nc, aux) = one_layer(
-                (hidden, pool), (layer_p, layer_ids[i], cache))
+                (hidden, pool), (layer_p, layer_ids[i], cache),
+                kinds[i % period])
             new_caches.append(nc)
             aux_total = aux_total + aux
         if in_carry:

@@ -628,6 +628,16 @@ def pretrain(
             for (it, _), host in zip(entries, hosts):
                 loss_series.append((it, float(host.get("lm loss", np.nan))))
                 metrics = host
+                if "moe assignments" in host:
+                    # what the step's routers did, where a capture can lay
+                    # it beside the step's device time (zero length: the
+                    # numbers came with the drain above)
+                    with trace_mod.span(
+                            "train-moe", step=it,
+                            assignments=int(host["moe assignments"]),
+                            held=int(host["moe held"]),
+                            dropped=int(host["moe dropped"])):
+                        pass
             return metrics
 
         prefetcher = None

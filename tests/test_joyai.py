@@ -247,15 +247,16 @@ def test_dropless_dispatch_equals_per_token_loop(load):
     w = jnp.asarray(rng.uniform(0.1, 1.0, size=idx.shape), jnp.float32)
     idx = jnp.asarray(idx, jnp.int32)
     counts = (idx.reshape(-1, 1) == jnp.arange(16)[None]).sum(0)
-    got = moe.dropless_experts(cfg, experts, x, idx, w,
-                               counts.astype(jnp.float32))
+    got, ran, dropped = moe.dropless_experts(cfg, experts, x, idx, w,
+                                             counts.astype(jnp.float32))
+    assert (float(ran), float(dropped)) == (idx.size, 0)   # all held
     np.testing.assert_allclose(
         np.asarray(got), _per_token_loop(cfg, experts, x, idx, w),
         rtol=0, atol=ATOL)
     # the serving tick's form: every layer's experts and the layer meant
     stack = jax.tree.map(lambda a: jnp.stack([a * 0 + 3.0, a, a * 0 - 1.0]),
                          experts)
-    stacked = moe.dropless_experts(
+    stacked, _, _ = moe.dropless_experts(
         cfg, moe.StackedExperts(stack, jnp.asarray(1)), x, idx, w,
         counts.astype(jnp.float32))
     np.testing.assert_array_equal(np.asarray(stacked), np.asarray(got))
