@@ -1265,9 +1265,10 @@ class ContinuousBatchingEngine:
                      "slot-state upload), launch (argument conversion + "
                      "dispatch), fetch (waiting for the device and the "
                      "result's way back), apply (tokens to requests and "
-                     "streams, retirement, gauges, and the wait to get "
-                     "the interpreter lock back from the handler threads "
-                     "it woke). Fetch and apply of a tick run after the "
+                     "stream queues, retirement, gauges, and the wait "
+                     "to get the interpreter lock back from the handler "
+                     "threads it woke: a request's first token, its "
+                     "end). Fetch and apply of a tick run after the "
                      "next tick's launch, beside it on the device",
                 labels={"phase": ph}, buckets=lat)
             for ph in ("admit", "plan", "launch", "fetch", "apply")}
@@ -1295,6 +1296,12 @@ class ContinuousBatchingEngine:
         # subscriptions + incremental events shed by slow consumers
         # (drop-to-terminal — the terminal event is never shed)
         self._stream_subs = 0  # live submit_stream queues — guarded by _lock
+        # an apply put tokens into some stream's queue and the stream
+        # writer has not been kicked for them yet
+        self._stream_dirty = False  # guarded by _lock
+        # the server's stream writer (serving/streaming/writer.py): called
+        # once an applied tick, outside _lock; must never block
+        self.stream_kick = None
         self._m_stream_subs = reg.gauge(
             "mlt_engine_stream_subscribers",
             help="live submit_stream subscriptions (emission queues "
@@ -1715,8 +1722,34 @@ class ContinuousBatchingEngine:
         if q is None or not tokens:
             return
         shed = q.publish_tokens(tokens, log_probs)
-        if shed and obs_registry.publishing():
+        if not shed:
+            self._stream_dirty = True
+        elif obs_registry.publishing():
             self._m_stream_dropped.inc(shed)
+
+    def _streams_dirty_locked(self) -> bool:  # holds _lock
+        """Whether the stream writer is owed a kick for tokens an apply
+        put into stream queues (the debt is the caller's from here: it
+        kicks after leaving the lock)."""
+        dirty, self._stream_dirty = self._stream_dirty, False
+        return dirty
+
+    def _kick_streams(self) -> None:
+        """Tell the stream writer, ONCE for an applied tick, that stream
+        queues hold new tokens.  Outside ``_lock``; an ``Event.set``.
+
+        Called where the scheduler thread is about to wait for the device
+        (the fetch of the NEXT tick), not at the end of the apply: the
+        writer's pass is a hundred short sends, each of which gives the
+        interpreter up and asks for it back, and beside admit, plan and
+        launch each of those hand-overs is the scheduler's time (PERF.md
+        section 6, PR 34: 1.7 ms a tick at 100 streams); beside the fetch
+        the scheduler does not want the interpreter.  A frame so leaves up
+        to one host cycle after its tick was applied; a stream's first
+        and last frames are its handler's and wait for nothing."""
+        kick = self.stream_kick
+        if kick is not None:
+            kick()
 
     def _stream_finish_locked(self, req: EngineRequest, kind: str,
                               **data) -> None:  # holds _lock
@@ -2696,8 +2729,11 @@ class ContinuousBatchingEngine:
             ragged = not self._inflight[0].chain
             if not ragged:
                 rec = self._inflight.popleft()
+                dirty = self._streams_dirty_locked()
         if ragged:
             return self._apply_tick() or 0
+        if dirty:
+            self._kick_streams()
         toks_np, logps_np = jax.device_get((rec.toks, rec.logps))
         now = time.monotonic()
         emitted = 0
@@ -3192,8 +3228,11 @@ class ContinuousBatchingEngine:
                 return None
             rec = self._inflight.popleft()
             lagged = bool(self._inflight)
+            dirty = self._streams_dirty_locked()
         t_fetch = time.monotonic()
         with obs_trace.span("engine-fetch", tick=rec.no):
+            if dirty:
+                self._kick_streams()
             handles = (rec.toks, rec.logps) + (
                 rec.spec[:2] if rec.spec else ())
             if rec.moe is not None:      # rides the same fetch
@@ -3252,10 +3291,10 @@ class ContinuousBatchingEngine:
             # This tick's device handles are dropped here, inside the span
             # and outside the lock.  Freeing a device array can release the
             # interpreter lock, and the wait to get it back from the
-            # handler threads that apply has just woken (one a stream, each
-            # writing its chunk) is the largest single piece of host time
-            # in a step: it is apply's doing, so it is counted as apply, in
-            # the span and in the phase histogram alike.
+            # handler threads apply has just woken (a request's first
+            # token, its end; no longer one a streamed token) is apply's
+            # doing, so it is counted as apply, in the span and in the
+            # phase histogram alike.
             del rec, handles
         if obs_registry.publishing():
             self._m_phase["fetch"].observe(now - t_fetch)

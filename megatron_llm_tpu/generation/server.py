@@ -36,7 +36,13 @@ from megatron_llm_tpu.generation.generation import InvalidRequest
 from megatron_llm_tpu.generation.scheduling import RequestShed
 from megatron_llm_tpu.observability import trace as obs_trace
 from megatron_llm_tpu.observability.compiles import install_compile_counter
-from megatron_llm_tpu.serving.streaming import SSE_CONTENT_TYPE, sse_encode
+from megatron_llm_tpu.observability import registry as obs_registry
+from megatron_llm_tpu.serving.streaming import (
+    SSE_CONTENT_TYPE,
+    StreamWriter,
+    sse_encode,
+    token_frame,
+)
 
 _STATIC_DIR = Path(__file__).parent / "static"
 # the longest window GET /profile?ticks=N may ask for: the endpoint is on
@@ -228,6 +234,25 @@ class MegatronServer:
             from megatron_llm_tpu.generation.engine import refuse_latent_cache
 
             refuse_latent_cache(handoff=True)
+        # token streaming: ONE thread writes every open stream's
+        # incremental frames, kicked by the engine once an applied tick
+        # (serving/streaming/writer.py); started and joined with the
+        # server.  A handler thread writes its stream's first frame and
+        # its terminal frames, and is woken for nothing else
+        tok = getattr(engine, "tokenizer", None)
+        self._detokenize = None if tok is None else tok.detokenize
+        self._stream_writer = StreamWriter(self._detokenize)
+        reg = obs_registry.get_registry()
+        self._m_stream_requests = reg.counter(
+            "mlt_server_stream_requests_total",
+            help="streamed requests whose response body was begun")
+        self._m_stream_wakeups = reg.counter(
+            "mlt_server_stream_handler_wakeups_total",
+            help="times a streamed request's handler thread came back "
+                 "from a blocking wait on its queue (over "
+                 "mlt_server_stream_requests_total: 2 a request, the "
+                 "first token and the terminal event, however many "
+                 "tokens it streamed)")
 
     def handle_request(self, payload, trace_id: str = ""):
         """Core PUT /api logic; returns (status_code, response dict).
@@ -421,7 +446,15 @@ class MegatronServer:
         The response headers (trace id + ``X-MLT-TTFT-S``) are sent at
         the moment the FIRST token event arrives — the stamp and the
         first flushed byte describe the same instant, which is the
-        property the streaming bench gates on."""
+        property the streaming bench gates on.
+
+        This thread writes the first frame and the terminal frames
+        (``dropped`` / ``done`` / ``error``).  Every frame between is the
+        stream writer's: the socket is attached to it after the first
+        frame and this thread parks until the stream has ended, then
+        takes the socket back, sends what the writer had not (the rest
+        of a frame a full socket buffer cut, events still queued) and the
+        terminal.  It wakes twice a request, not once a token."""
         params, err = _validate(payload)
         if err is None:
             err = _validate_stream(params)
@@ -472,22 +505,29 @@ class MegatronServer:
         ttft = req.ttft
         if ttft is not None:
             headers["X-MLT-TTFT-S"] = str(round(ttft, 6))
-        tok = getattr(eng, "tokenizer", None)
         try:
             handler._begin(200, SSE_CONTENT_TYPE, headers)
-            ev = first
-            flushed_first = False
-            while True:
-                if ev.kind == "token":
-                    frame = {"tokens": ev.tokens, "logprobs": ev.log_probs}
-                    if tok is not None:
-                        frame["text"] = tok.detokenize(ev.tokens)
-                    handler._send_chunk(sse_encode("token", frame))
-                    if not flushed_first:
-                        # flight-record event: the instant the first
-                        # token actually left for the client
-                        flushed_first = True
-                        req._flight.event("first_byte_flushed")
+            if first.kind == "token":
+                handler._send_chunk(token_frame([first], self._detokenize))
+                # flight-record event: the instant the first token
+                # actually left for the client
+                req._flight.event("first_byte_flushed")
+                attached = self._stream_writer.attach(q, handler.connection)
+                ended = q.wait_terminal(gap_timeout=600.0)
+                rest = self._stream_writer.detach(attached)
+                if rest:
+                    handler._send_chunk(rest)
+                if not ended:
+                    handler._send_chunk(sse_encode("error", {
+                        "error": "stream stalled (no event within 600s)"}))
+                    return None
+                events = q.iter_events(timeout=0.0)
+            else:
+                events = [first]
+            # the stream has ended: nothing below waits
+            for ev in events:
+                if ev.kind == "token":  # applied since the writer's last pass
+                    handler._send_chunk(token_frame([ev], self._detokenize))
                 elif ev.kind == "done":
                     if ev.data.get("dropped_events"):
                         # honest drop-to-terminal: the incremental
@@ -504,23 +544,21 @@ class MegatronServer:
                         if timing is not None:
                             body["timing"] = timing
                     handler._send_chunk(sse_encode("done", body))
-                    return None
                 else:  # terminal error after bytes were written:
                     # structured SSE error frame, never silent truncation
                     data = dict(ev.data)
                     data.setdefault("error", "generation failed")
                     handler._send_chunk(sse_encode("error", data))
-                    return None
-                ev = q.next_event(timeout=600.0)
-                if ev is None:
-                    handler._send_chunk(sse_encode("error", {
-                        "error": "stream stalled (no event within 600s)"}))
-                    return None
+            return None
         except (BrokenPipeError, ConnectionError, OSError):
             # client went away mid-stream: shed future publishes and let
             # the generation finish on its own (it may be shared work)
             q.abandon()
             return None
+        finally:
+            if obs_registry.publishing():
+                self._m_stream_requests.inc()
+                self._m_stream_wakeups.inc(q.wakeups)
 
     def _make_handler(server):  # noqa: N805 — `server` is the enclosing object
         class Handler(BaseHTTPRequestHandler):
@@ -843,6 +881,8 @@ class MegatronServer:
 
     def _start_engine(self):
         if self.batching and hasattr(self.engine, "start"):
+            self._stream_writer.start()
+            self.engine.stream_kick = self._stream_writer.kick
             self.engine.start()  # background scheduler drives shared ticks
 
     # ---- elastic discovery (ISSUE 18) -----------------------------------
@@ -924,3 +964,5 @@ class MegatronServer:
             self._httpd = None
         if self.batching and hasattr(self.engine, "stop"):
             self.engine.stop()
+            self.engine.stream_kick = None
+        self._stream_writer.stop()

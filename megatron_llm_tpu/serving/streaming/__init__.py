@@ -31,5 +31,7 @@ from megatron_llm_tpu.serving.streaming.events import (  # noqa: F401
     parse_sse,
     sse_encode,
     sse_scan_terminal,
+    token_frame,
 )
 from megatron_llm_tpu.serving.streaming.queue import StreamQueue  # noqa: F401
+from megatron_llm_tpu.serving.streaming.writer import StreamWriter  # noqa: F401

@@ -4,8 +4,10 @@ The wire format is plain Server-Sent Events (one ``event:`` line, one
 ``data:`` line holding a JSON object, a blank line):
 
 * ``event: token`` — ``{"tokens": [...], "text": "...", "logprobs":
-  [...]}``: one freshly-applied token batch (chained dispatch retires
-  several per flush, so a single event may carry several tokens).
+  [...]}``: the tokens of one stream applied since its last frame, in
+  order: usually one tick's, several when a chained dispatch retires
+  several per flush or when the stream writer found the socket full at
+  an earlier tick (``token_frame`` joins the queued events).
 * ``event: dropped`` — ``{"dropped_events": n}``: the consumer fell
   behind the bounded emission queue and *incremental* events were shed;
   the terminal ``done`` body is still complete (drop-to-terminal).
@@ -22,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Dict, Iterable, Iterator, List, Tuple
+from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 
 __all__ = [
     "SSE_CONTENT_TYPE",
@@ -31,6 +33,7 @@ __all__ = [
     "parse_sse",
     "sse_encode",
     "sse_scan_terminal",
+    "token_frame",
 ]
 
 SSE_CONTENT_TYPE = "text/event-stream"
@@ -62,6 +65,22 @@ class StreamEvent:
 def sse_encode(event: str, data: dict) -> bytes:
     """One SSE frame: ``event:`` + single-line JSON ``data:`` + blank."""
     return (f"event: {event}\ndata: {json.dumps(data)}\n\n").encode()
+
+
+def token_frame(events: Iterable[StreamEvent],
+                detokenize: Optional[Callable[[List[int]], str]] = None
+                ) -> bytes:
+    """THE ``token`` frame of one stream: the tokens and log-probs of
+    ``events`` joined in order (and their text, given a detokenizer)."""
+    tokens: List[int] = []
+    log_probs: List[float] = []
+    for ev in events:
+        tokens += ev.tokens
+        log_probs += ev.log_probs
+    frame = {"tokens": tokens, "logprobs": log_probs}
+    if detokenize is not None:
+        frame["text"] = detokenize(tokens)
+    return sse_encode("token", frame)
 
 
 def sse_scan_terminal(tail: bytes, chunk: bytes) -> Tuple[bool, bytes]:

@@ -13,6 +13,14 @@ bounded queue loses *incremental* token events (counted, reported in the
 terminal event's ``dropped_events``), but the terminal event is always
 accepted — the tick loop never waits on a slow HTTP client, and the
 client always learns how the request ended.
+
+A publish wakes a thread only if one is blocked in ``next_event`` (an
+in-process consumer, or an HTTP handler waiting for its first token).
+A served stream's later events are taken, without a wake-up a stream, by
+the one stream writer (``serving/streaming/writer.py``: ``take_tokens``
+once a tick) while the handler is parked in ``wait_terminal``.  One
+consumer reads a queue at a time; both read through the same ordering
+rule (``_pop_locked``) and the same bound.
 """
 
 from __future__ import annotations
@@ -20,7 +28,7 @@ from __future__ import annotations
 import collections
 import threading
 import time
-from typing import Iterator, Optional, Sequence
+from typing import Iterator, List, Optional, Sequence
 
 from megatron_llm_tpu.serving.streaming.events import StreamEvent
 
@@ -28,7 +36,7 @@ __all__ = ["StreamQueue"]
 
 
 class StreamQueue:
-    """Bounded single-producer single-consumer event queue."""
+    """Bounded single-producer event queue, one consumer at a time."""
 
     def __init__(self, maxsize: int = 256):
         assert maxsize >= 1
@@ -40,6 +48,9 @@ class StreamQueue:
         self._terminal_taken = False  # guarded by _lock
         self._dropped = 0  # incremental events shed — guarded by _lock
         self._abandoned = False  # consumer gone — guarded by _lock
+        self._readers = 0  # threads blocked in next_event — guarded by _lock
+        self._published = 0  # publish_tokens calls so far — guarded by _lock
+        self._wakeups = 0  # returns from a blocking wait — guarded by _lock
 
     # ---- publisher side (engine, holding its own _lock) -----------------
     # Method names are deliberately unique repo-wide (not `put`/`close`):
@@ -54,13 +65,17 @@ class StreamQueue:
         with self._ready:
             if self._terminal is not None:
                 return 1  # post-terminal publish: late, count as shed
+            self._published += 1
             if self._abandoned or len(self._events) >= self.maxsize:
                 self._dropped += 1
                 return 1
             self._events.append(StreamEvent(
                 "token", tokens=list(tokens),
                 log_probs=list(log_probs or [])))
-            self._ready.notify()
+            if self._readers:
+                # nobody is woken for a queue the stream writer drains:
+                # its handler parks in wait_terminal, not in next_event
+                self._ready.notify_all()
             return 0
 
     def publish_terminal(self, event: StreamEvent) -> None:
@@ -74,7 +89,20 @@ class StreamQueue:
                 self._terminal = event
             self._ready.notify_all()
 
-    # ---- consumer side (HTTP handler thread / bench client) -------------
+    # ---- consumer side (HTTP handler, stream writer, bench client) ------
+
+    def _pop_locked(self) -> Optional[StreamEvent]:  # holds _lock
+        """THE ordering rule of every consumer: queued incremental events
+        oldest first, then the terminal event exactly once; None while
+        there is nothing to take (and for good once the queue is dry)."""
+        if self._abandoned:
+            return None  # abandon() dries the consumer
+        if self._events:
+            return self._events.popleft()
+        if self._terminal is not None and not self._terminal_taken:
+            self._terminal_taken = True
+            return self._terminal
+        return None
 
     def next_event(self, timeout: Optional[float] = None
                    ) -> Optional[StreamEvent]:
@@ -84,21 +112,49 @@ class StreamQueue:
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._ready:
             while True:
-                if self._abandoned:
-                    return None  # abandon() wakes and dries the consumer
-                if self._events:
-                    return self._events.popleft()
-                if self._terminal is not None:
-                    if self._terminal_taken:
-                        return None
-                    self._terminal_taken = True
-                    return self._terminal
+                ev = self._pop_locked()
+                if ev is not None or self._abandoned or self._terminal_taken:
+                    return ev
+                remaining = None
                 if deadline is not None:
                     remaining = deadline - time.monotonic()
-                    if remaining <= 0 or not self._ready.wait(remaining):
+                    if remaining <= 0:
                         return None
-                else:
-                    self._ready.wait()
+                self._readers += 1
+                try:
+                    woken = self._ready.wait(remaining)
+                finally:
+                    self._readers -= 1
+                self._wakeups += 1
+                if not woken:
+                    return None
+
+    def take_tokens(self) -> List[StreamEvent]:
+        """Every queued incremental event, oldest first, without blocking;
+        never the terminal event (the stream writer's drain: what it
+        takes in one call it may send as one frame)."""
+        with self._lock:
+            taken = []
+            while self._events:
+                taken.append(self._pop_locked())
+            return taken
+
+    def wait_terminal(self, gap_timeout: Optional[float] = None) -> bool:
+        """Park until the stream has ended: the terminal event is in (take
+        it with ``next_event``, after whatever is still queued) or the
+        queue was abandoned.  Incremental publishes do not wake the
+        caller.  False if ``gap_timeout`` passed with no publish at all:
+        a stalled generation."""
+        with self._ready:
+            seen = self._published
+            while self._terminal is None and not self._abandoned:
+                woken = self._ready.wait(gap_timeout)
+                self._wakeups += 1
+                if not woken:
+                    if self._published == seen:
+                        return False
+                    seen = self._published
+            return True
 
     def iter_events(self, timeout: Optional[float] = None
                     ) -> Iterator[StreamEvent]:
@@ -124,3 +180,10 @@ class StreamQueue:
     def dropped(self) -> int:
         with self._lock:
             return self._dropped
+
+    @property
+    def wakeups(self) -> int:
+        """How often a consumer thread came back from a blocking wait on
+        this queue (``next_event``, ``wait_terminal``)."""
+        with self._lock:
+            return self._wakeups

@@ -158,25 +158,11 @@ def test_admission_deadline_timeout_returns_none():
 def fleet():
     """Two continuous-batching replicas with identical weights behind real
     MegatronServers, mirroring tests/test_router.py's fixture."""
-    import jax
-
-    from megatron_llm_tpu.generation import ContinuousBatchingEngine
     from megatron_llm_tpu.generation.server import MegatronServer
-    from megatron_llm_tpu.models import init_model_params, make_config
-    from tests.test_generation import VOCAB, ToyTokenizer
 
-    cfg = make_config(
-        "llama2", num_layers=2, hidden_size=64, num_attention_heads=4,
-        num_attention_heads_kv=2, ffn_hidden_size=128, seq_length=128,
-        max_position_embeddings=256, vocab_size=VOCAB,
-        hidden_dropout=0.0, attention_dropout=0.0,
-        params_dtype="float32", use_flash_attn=False,
-    )
-    params = init_model_params(cfg, jax.random.PRNGKey(0))
     servers, urls = [], []
     for _ in range(2):
-        engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer(),
-                                          max_slots=4, max_seq=128)
+        engine = _toy_engine(max_slots=4)
         srv = MegatronServer(engine)
         port = srv.start_background(port=0)
         servers.append(srv)
@@ -618,3 +604,420 @@ def test_run_router_allows_empty_fleet_only_with_registration(monkeypatch):
              "--port", "0"])
     assert seen["registration"] is True
     assert seen["admission"] is not None and seen["admission"].depth == 8
+
+
+# ---------------------------------------------------------------------------
+# One stream writer (ISSUE 34, serving/streaming/writer.py): a tick's
+# frames leave from ONE thread; a handler thread writes its stream's first
+# frame and its terminal frames and is woken for nothing else
+# ---------------------------------------------------------------------------
+
+
+def _toy_engine(max_slots=8, tokenizer=None):
+    import jax
+
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.models import init_model_params, make_config
+    from tests.test_generation import VOCAB, ToyTokenizer
+
+    cfg = make_config(
+        "llama2", num_layers=2, hidden_size=64, num_attention_heads=4,
+        num_attention_heads_kv=2, ffn_hidden_size=128, seq_length=128,
+        max_position_embeddings=256, vocab_size=VOCAB,
+        hidden_dropout=0.0, attention_dropout=0.0,
+        params_dtype="float32", use_flash_attn=False,
+    )
+    params = init_model_params(cfg, jax.random.PRNGKey(0))
+    return ContinuousBatchingEngine(cfg, params, tokenizer or ToyTokenizer(),
+                                    max_slots=max_slots, max_seq=128)
+
+
+@pytest.fixture(scope="module")
+def writer_server():
+    """One replica of 8 slots whose frames are fat (64 characters a
+    token), so a reader that stops fills its socket within a request."""
+    from megatron_llm_tpu.generation.server import MegatronServer
+    from tests.test_generation import ToyTokenizer
+
+    class FatTokenizer(ToyTokenizer):
+        def detokenize(self, ids):
+            return "".join(chr(97 + (i % 26)) * 64 for i in ids if i >= 2)
+
+    srv = MegatronServer(_toy_engine(tokenizer=FatTokenizer()))
+    port = srv.start_background(port=0)
+    yield srv, f"http://127.0.0.1:{port}"
+    srv.stop()
+
+
+def _counter(name):
+    from megatron_llm_tpu.observability.registry import get_registry
+
+    return get_registry().counter(name).value
+
+
+def _stream_raw(base, payload, timeout=120):
+    """The response body of one streamed PUT, byte for byte."""
+    u = urlparse(base)
+    conn = http.client.HTTPConnection(u.hostname, u.port, timeout=timeout)
+    conn.request("PUT", "/api", body=json.dumps(payload).encode(),
+                 headers={"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    raw = resp.read()
+    conn.close()
+    return resp.status, raw
+
+
+def _streamed(frames):
+    """(token ids, log-probs, text) of a stream's token frames, joined."""
+    toks, lps, text = [], [], ""
+    for event, data in frames:
+        if event == "token":
+            toks += data["tokens"]
+            lps += data["logprobs"]
+            text += data["text"]
+    return toks, lps, text
+
+
+def _open_stream(base, payload, rcvbuf=None):
+    """Send a streamed PUT on a raw socket and read up to the first token
+    frame; returns (socket, bytes read so far).  The caller decides
+    whether anybody ever reads the rest."""
+    u = urlparse(base)
+    s = socket.socket()
+    if rcvbuf is not None:
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    s.settimeout(60)
+    s.connect((u.hostname, u.port))
+    body = json.dumps(payload).encode()
+    s.sendall(b"PUT /api HTTP/1.1\r\nHost: x\r\nContent-Type: "
+              b"application/json\r\nContent-Length: %d\r\n\r\n%s"
+              % (len(body), body))
+    got = b""
+    while b"event: token\n" not in got:
+        chunk = s.recv(256)
+        assert chunk, got
+        got += chunk
+    return s, got
+
+
+def _wait(cond, seconds=60.0):
+    deadline = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < deadline, "condition not met in time"
+        time.sleep(0.02)
+
+
+@pytest.fixture(params=[None, 1e-5], ids=["switch-default", "switch-10us"])
+def switch_interval(request):
+    """The second case hands the interpreter over every 10 us: the
+    writer, 8 handler threads and the scheduler interleave at every
+    bytecode, where a token lost or reordered in the hand-over of a
+    socket between a handler and the writer would show."""
+    import sys
+
+    old = sys.getswitchinterval()
+    if request.param is not None:
+        sys.setswitchinterval(request.param)
+    try:
+        yield
+    finally:
+        sys.setswitchinterval(old)
+
+
+def test_writer_eight_streams_match_their_buffered_responses(
+        writer_server, switch_interval):
+    """(a) 8 concurrent streams through a live server: each stream's
+    frames carry exactly the tokens and log-probs of the buffered
+    response for the same seed, in order, and its done body is that
+    response."""
+    srv, url = writer_server
+    eng = srv.engine
+    prompts = [f"writer stream number {i}" for i in range(8)]
+    gen = dict(tokens_to_generate=24, top_k=1, logprobs=True, random_seed=11)
+    want = {}
+    for p in prompts:
+        code, body = _put(url + "/api", {"prompts": [p], **gen})
+        assert code == 200
+        body.pop("timing")
+        _, _, lps, toks = eng.generate_and_post_process(
+            [p], tokens_to_generate=24, return_output_log_probs=True,
+            top_k_sampling=1, random_seed=11)
+        want[p] = (body, toks[0][-24:], lps[0][-24:])
+    got = {}
+
+    def one(p):
+        got[p] = _stream_put(url, {"prompts": [p], **gen, "stream": True})
+
+    threads = [threading.Thread(target=one, args=(p,)) for p in prompts]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    for p in prompts:
+        code, _, frames, _ = got[p]
+        body, toks, lps = want[p]
+        assert code == 200
+        assert [e for e, _ in frames if e != "token"] == ["done"]
+        s_toks, s_lps, s_text = _streamed(frames)
+        assert s_toks == list(toks)
+        assert s_lps == pytest.approx(list(lps), abs=1e-6)
+        done = frames[-1][1]
+        assert done.pop("timing", None) is not None
+        assert done == body, "streaming changed the response"
+        assert done["logprobs"][0][-24:] == s_lps, "frames != own done body"
+        assert done["text"][0].endswith(s_text)
+
+
+def test_writer_stalled_reader_delays_nobody_and_drops_to_terminal(
+        writer_server, monkeypatch):
+    """(b) A client that stops reading stalls only itself: the streams
+    opened after it run to their end while it sleeps.  Its own events
+    wait in its bounded queue, and past the bound it gets ``dropped`` +
+    a complete ``done`` whose count is the engine counter's."""
+    srv, url = writer_server
+    eng = srv.engine
+    slow = "the reader that stops"
+    real = eng.submit_stream_request
+
+    def bounded(prompt, *a, **kw):
+        if prompt == slow:
+            kw["stream_events"] = 4
+        return real(prompt, *a, **kw)
+
+    monkeypatch.setattr(eng, "submit_stream_request", bounded)
+    gen = dict(tokens_to_generate=100, top_k=1, logprobs=True, random_seed=5)
+    _, _, _, ref = eng.generate_and_post_process(
+        [slow], tokens_to_generate=100, top_k_sampling=1, random_seed=5)
+    ref = list(ref[0][-100:])
+    dropped0 = _counter("mlt_engine_stream_dropped_events_total")
+    deferred0 = _counter("mlt_server_stream_deferred_sends_total")
+    # accepted sockets inherit the listener's buffer size: the smallest
+    # the kernel gives, for the stalled connection alone
+    lsock = srv._httpd.socket
+    sndbuf = lsock.getsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF)
+    lsock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1)
+    try:
+        stalled, head = _open_stream(
+            url, {"prompts": [slow], **gen, "stream": True}, rcvbuf=1)
+    finally:
+        lsock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, sndbuf)
+    try:
+        others = {}
+
+        def one(i):
+            others[i] = _stream_put(url, {
+                "prompts": [f"a reader that reads {i}"], **gen,
+                "stream": True})
+
+        t0 = time.monotonic()
+        threads = [threading.Thread(target=one, args=(i,)) for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        took = time.monotonic() - t0
+        assert took < 30.0, f"streams behind a stalled reader took {took}s"
+        for i in range(3):
+            code, _, frames, _ = others[i]
+            assert code == 200 and frames[-1][0] == "done"
+            assert len(_streamed(frames)[0]) == 100
+        # the stalled request's generation ends too, on the engine's time
+        _wait(lambda: srv.health()["active_slots"] == 0)
+        dropped = _counter("mlt_engine_stream_dropped_events_total") - dropped0
+        assert dropped >= 1, "the socket never filled: nothing was tested"
+        assert _counter("mlt_server_stream_deferred_sends_total") > deferred0
+        # now it reads on: everything queued for it arrives, in order
+        raw = head
+        while True:
+            chunk = stalled.recv(65536)
+            if not chunk:
+                break
+            raw += chunk
+    finally:
+        stalled.close()
+    frames = parse_sse(raw.split(b"\r\n\r\n", 1)[1])
+    kinds = [e for e, _ in frames]
+    assert kinds[-2:] == ["dropped", "done"] and kinds.count("done") == 1
+    assert frames[-2][1]["dropped_events"] == dropped
+    toks, lps, _ = _streamed(frames)
+    assert len(toks) == len(lps) == 100 - dropped  # one token an event
+    it = iter(ref)
+    assert all(t in it for t in toks), "frames out of order"
+    done = frames[-1][1]
+    assert len(done["logprobs"][0]) == len(done["segments"][0]) - 1
+    assert len(done["segments"][0]) >= 100
+
+
+def test_writer_survives_a_client_that_disconnects(writer_server):
+    """(c) A client that goes away mid-stream abandons its queue; the
+    writer drops the connection and goes on serving."""
+    srv, url = writer_server
+    gen = dict(tokens_to_generate=100, top_k=1, random_seed=3)
+    s, _ = _open_stream(url, {"prompts": ["gone before the end"], **gen,
+                              "stream": True})
+    s.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                 b"\x01\x00\x00\x00\x00\x00\x00\x00")  # close with a reset
+    s.close()
+    _wait(lambda: srv.health()["active_slots"] == 0)
+    _wait(lambda: not srv._stream_writer._streams)
+    assert srv._stream_writer._thread.is_alive()
+    code, raw = _stream_raw(url, {"prompts": ["still served"], **gen,
+                                  "stream": True})
+    frames = parse_sse(raw)
+    assert code == 200 and frames[-1][0] == "done"
+    assert len(_streamed(frames)[0]) == 100
+
+
+@pytest.mark.parametrize("n_tokens", [1, 2, 40])
+def test_writer_terminal_frame_is_the_last_bytes(writer_server, n_tokens):
+    """(d) Whoever wrote the frames before it, ``done`` is the end of the
+    body: also for a request that ends on the tick of its first token."""
+    _, url = writer_server
+    code, raw = _stream_raw(url, {
+        "prompts": ["how it ends"], "tokens_to_generate": n_tokens,
+        "top_k": 1, "logprobs": True, "stream": True})
+    assert code == 200
+    frames = parse_sse(raw)
+    assert [e for e, _ in frames if e != "token"] == ["done"]
+    assert frames[-1][0] == "done"
+    assert raw.endswith(sse_encode("done", frames[-1][1]))
+    assert len(_streamed(frames)[0]) == n_tokens
+
+
+def test_writer_engagement_counters(writer_server):
+    """(e) A streamed request wakes its handler thread twice, however
+    many tokens it streams, and one writer pass sends several streams'
+    frames."""
+    _, url = writer_server
+    names = ("mlt_server_stream_requests_total",
+             "mlt_server_stream_handler_wakeups_total",
+             "mlt_server_stream_frames_total",
+             "mlt_server_stream_writer_passes_total")
+    before = {n: _counter(n) for n in names}
+    out = {}
+
+    def one(i):
+        out[i] = _stream_put(url, {
+            "prompts": [f"counted stream {i}"], "tokens_to_generate": 60,
+            "top_k": 1, "stream": True})
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert all(out[i][2][-1][0] == "done" for i in range(6))
+    d = {n: _counter(n) - before[n] for n in names}
+    assert d["mlt_server_stream_requests_total"] == 6
+    # the first token and the terminal event (one fewer where an event was
+    # in before its handler came to wait); a wake-up a token would be 360
+    assert 6 < d["mlt_server_stream_handler_wakeups_total"] <= 12
+    assert d["mlt_server_stream_writer_passes_total"] >= 1
+    # every token but a stream's first (and what its handler sent at the
+    # end) went through the writer, several streams a pass
+    assert d["mlt_server_stream_frames_total"] >= 6 * 40 / 2
+    assert (d["mlt_server_stream_frames_total"]
+            > 1.5 * d["mlt_server_stream_writer_passes_total"])
+
+
+def test_server_stop_joins_the_stream_writer():
+    """(f) The writer lives exactly as long as the server serves."""
+    from megatron_llm_tpu.generation.server import MegatronServer
+
+    def writers():
+        return [t for t in threading.enumerate()
+                if t.name == "stream-writer" and t.is_alive()]
+
+    n0 = len(writers())
+    eng = _toy_engine(max_slots=2)
+    srv = MegatronServer(eng)
+    assert len(writers()) == n0, "no thread before the server starts"
+    port = srv.start_background(port=0)
+    assert len(writers()) == n0 + 1 and eng.stream_kick is not None
+    code, raw = _stream_raw(f"http://127.0.0.1:{port}", {
+        "prompts": ["one stream"], "tokens_to_generate": 8, "top_k": 1,
+        "stream": True})
+    assert code == 200 and parse_sse(raw)[-1][0] == "done"
+    srv.stop()
+    assert len(writers()) == n0 and eng.stream_kick is None
+
+
+def test_scheduler_never_sends_and_a_deaf_fleet_of_sockets_costs_it_nothing():
+    """(g) Nothing on the scheduler's path touches a socket: with every
+    attached connection refusing every byte, the thread that drives
+    ``step()`` runs the requests to their end, every send was the
+    writer thread's, and the events wait in (and are shed from) their
+    own bounded queues."""
+    from megatron_llm_tpu.serving.streaming import StreamWriter
+
+    class DeafSocket:
+        def __init__(self):
+            self.senders = set()
+
+        def send(self, data, flags=0):
+            self.senders.add(threading.current_thread().name)
+            assert flags & socket.MSG_DONTWAIT, "a send that could block"
+            raise BlockingIOError
+
+    eng = _toy_engine(max_slots=4)
+    writer = StreamWriter()
+    writer.start()
+    eng.stream_kick = writer.kick
+    deferred0 = _counter("mlt_server_stream_deferred_sends_total")
+    try:
+        socks, queues, attached = [], [], []
+        for i in range(4):
+            _, q = eng.submit_stream([3 + i, 4, 5, 6], 40, top_k=1,
+                                     termination_id=10 ** 9,
+                                     stream_events=8)
+            socks.append(DeafSocket())
+            queues.append(q)
+            attached.append(writer.attach(q, socks[-1]))
+        driver = threading.Thread(target=eng.run_until_idle,
+                                  name="the-scheduler")
+        driver.start()
+        driver.join(timeout=120)
+        assert not driver.is_alive(), "step() waited on a socket"
+        _wait(lambda: _counter("mlt_server_stream_deferred_sends_total")
+              > deferred0)
+    finally:
+        writer.stop()
+    for sock, q, stream in zip(socks, queues, attached):
+        assert sock.senders == {"stream-writer"}
+        assert q.wait_terminal(gap_timeout=1.0)
+        evs = list(q.iter_events(timeout=0.0))
+        assert evs[-1].kind == "done"
+        # one frame is in the writer's hands (built, never taken by the
+        # socket); what came after it waited in the queue up to its bound
+        # and was shed past it: no token is lost uncounted
+        (_, frame), = parse_sse(writer.detach(stream))
+        held = sum(len(e.tokens) for e in evs[:-1])
+        assert len(evs) - 1 <= 8
+        assert evs[-1].data["dropped_events"] == q.dropped >= 1
+        assert len(frame["tokens"]) + held + q.dropped == 40
+
+
+def test_stream_queue_drain_and_park_share_the_ordering():
+    """``take_tokens`` (the writer's drain) and ``next_event`` read one
+    order; a parked ``wait_terminal`` is not woken by tokens."""
+    q = StreamQueue(maxsize=8)
+    q.publish_tokens([1], [0.1])
+    q.publish_tokens([2, 3], [0.2, 0.3])
+    assert [e.tokens for e in q.take_tokens()] == [[1], [2, 3]]
+    assert q.take_tokens() == []
+    ended = []
+    parked = threading.Thread(
+        target=lambda: ended.append(q.wait_terminal(gap_timeout=30.0)))
+    parked.start()
+    for t in range(5):
+        q.publish_tokens([10 + t], [0.0])
+    time.sleep(0.1)
+    assert q.wakeups == 0 and not ended, "a token woke the parked handler"
+    q.publish_terminal(StreamEvent("done", data={}))
+    parked.join(timeout=10)
+    assert ended == [True] and q.wakeups == 1
+    assert [e.tokens for e in q.take_tokens()] == [[10 + t] for t in range(5)]
+    assert q.take_tokens() == [], "the terminal is never the writer's"
+    assert q.next_event(timeout=0.0).kind == "done"
+    # a generation that publishes nothing for a whole gap has stalled
+    assert StreamQueue().wait_terminal(gap_timeout=0.05) is False
