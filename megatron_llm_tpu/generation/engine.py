@@ -360,6 +360,30 @@ class PagedKVPool:
         self.evict_hook = None  # PrefixCache.evict: (n) -> freed page list
         # page 0 reserved as the null page (never allocated)
         self._free: deque = deque(range(1, num_pages))
+        # a grant had to evict since the engine's step last looked: the
+        # step that launches the next tick counts it dry and clears this
+        self.reclaimed = False
+        # the slow path counts and times itself (an ``alloc`` the free
+        # list serves reads no clock): pages by where they came from, and
+        # the calling thread's wall seconds in the two scans, whoever
+        # calls (a tick's page grant, admission's budget check, /health)
+        reg = obs_registry.get_registry()
+        self._m_alloc = {
+            src: reg.counter(
+                "mlt_engine_pool_alloc_pages_total",
+                help="KV pool pages granted: free = off the free list, "
+                     "evict = a cached-idle page the prefix cache had to "
+                     "give up first (the pool had run dry)",
+                labels={"source": src}) for src in ("free", "evict")}
+        self._m_scan = {
+            what: reg.counter(
+                "mlt_engine_pool_scan_seconds_total",
+                help="wall seconds the calling thread spent in the pool's "
+                     "two scans: evictable = counting the cached pages no "
+                     "request references (every alloc past the free list, "
+                     "every admission check, every /health answer), evict "
+                     "= the prefix cache picking and unlinking victims",
+                labels={"what": what}) for what in ("evictable", "evict")}
 
     def _place(self, pool):
         """device_put a pool (plain array or QuantPagedKV) under the tp
@@ -443,8 +467,13 @@ class PagedKVPool:
 
     @property
     def num_evictable(self) -> int:
-        """Cached pages no request references — reclaimable on demand."""
-        return sum(1 for p in self.cached if self.refcounts[p] == 0)
+        """Cached pages no request references — reclaimable on demand.
+        A walk over every cached page, timed for its caller."""
+        t0 = time.perf_counter()
+        n = sum(1 for p in self.cached if self.refcounts[p] == 0)
+        if obs_registry.publishing():
+            self._m_scan["evictable"].inc(time.perf_counter() - t0)
+        return n
 
     @property
     def num_available(self) -> int:
@@ -457,17 +486,41 @@ class PagedKVPool:
         leaf-first) only when the free list alone runs short."""
         # the free list first: counting the evictable pages walks every
         # cached page, and the tick's page grants come here once a row
-        if n > len(self._free) and n > self.num_available:
-            return None
-        if n > len(self._free) and self.evict_hook is not None:
-            self._free.extend(self.evict_hook(n - len(self._free)))
+        evicted = 0
         if n > len(self._free):
-            return None
+            evicted = self._reclaim(n)
+            if n > len(self._free):
+                return None
         pages = [self._free.popleft() for _ in range(n)]
         for p in pages:
             assert self.refcounts[p] == 0 and p not in self.cached
             self.refcounts[p] = 1
+        if obs_registry.publishing():
+            self._m_alloc["free"].inc(n - evicted)
+            if evicted:
+                self._m_alloc["evict"].inc(evicted)
         return pages
+
+    def _reclaim(self, n: int) -> int:
+        """``alloc``'s slow path: the free list is short of ``n`` pages.
+        Evicts the shortfall in cached-idle pages onto it if there are as
+        many, and returns how many it evicted.  One ``pool-reclaim`` span
+        a call (inside the caller's ``engine-admit`` or ``engine-plan``)
+        with the eviction's zero-length ``pool-evict`` inside, so a
+        device idle gap that is an eviction is named in a capture."""
+        short = n - len(self._free)
+        with obs_trace.span("pool-reclaim", want=n, free=len(self._free),
+                            cached=len(self.cached)):
+            if short > self.num_evictable or self.evict_hook is None:
+                return 0
+            t0 = time.perf_counter()
+            freed = self.evict_hook(short)
+            if obs_registry.publishing():
+                self._m_scan["evict"].inc(time.perf_counter() - t0)
+            self._free.extend(freed)
+            if freed:
+                self.reclaimed = True
+            return len(freed)
 
     def incref(self, pages: Sequence[int]) -> None:
         for p in pages:
@@ -589,6 +642,15 @@ class PrefixCache:
         self._nodes: Dict[int, _TrieNode] = {}  # page id -> node
         self._clock = 0
         pool.evict_hook = self.evict
+        reg = obs_registry.get_registry()
+        self._m_evicted = reg.counter(
+            "mlt_engine_prefix_evicted_pages_total",
+            help="cached-idle pages the prefix cache gave up to a grant")
+        self._m_scanned = reg.counter(
+            "mlt_engine_prefix_evict_scanned_nodes_total",
+            help="trie nodes looked at to pick eviction victims (a whole "
+                 "pass over the trie a victim); over the evicted pages: "
+                 "the work one eviction costs")
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -640,8 +702,10 @@ class PrefixCache:
         """Reclaim up to ``n`` cached-idle pages, least-recently-used
         leaves first (removing a leaf may expose its parent next round)."""
         freed: List[int] = []
+        scanned = 0
         while len(freed) < n:
             victim = None
+            scanned += len(self._nodes)  # a pass looks at every node
             for node in self._nodes.values():
                 if node.children or self.pool.refcounts[node.page] != 0:
                     continue
@@ -653,6 +717,14 @@ class PrefixCache:
             del self._nodes[victim.page]
             self.pool.cached.discard(victim.page)
             freed.append(victim.page)
+        # what the call did, as one zero-length event inside the pool's
+        # ``pool-reclaim``: the numbers are known only now
+        with obs_trace.span("pool-evict", evicted=len(freed),
+                            scanned=scanned):
+            pass
+        if obs_registry.publishing():
+            self._m_evicted.inc(len(freed))
+            self._m_scanned.inc(scanned)
         return freed
 
 
@@ -1272,6 +1344,42 @@ class ContinuousBatchingEngine:
                      "next tick's launch, beside it on the device",
                 labels={"phase": ph}, buckets=lat)
             for ph in ("admit", "plan", "launch", "fetch", "apply")}
+        # the scheduler thread's own CPU clock (time.thread_time, a system
+        # call: four reads a tick) over the same host work: wall minus CPU
+        # is the time the thread stood still inside its own work
+        self._m_host_cpu = {
+            side: reg.histogram(
+                "mlt_engine_tick_host_cpu_seconds",
+                help="CPU seconds of the scheduler thread in one ragged "
+                     "tick's host work: dispatch = admit + plan + launch "
+                     "(one stretch on the thread), apply = apply. The "
+                     "sums of mlt_engine_tick_phase_seconds over those "
+                     "four phases less these: waiting for the "
+                     "interpreter behind the stream writer and the "
+                     "handler threads, for the engine's lock, or inside "
+                     "a blocking upload. fetch is a wait by design and "
+                     "has no CPU reading",
+                labels={"side": side}, buckets=lat)
+            for side in ("dispatch", "apply")}
+        # plan's three jobs, one observation a launched tick each; what
+        # plan holds beyond their sum is its two waits for the engine's
+        # lock and the row count of the launch
+        self._m_plan_part = {
+            part: reg.histogram(
+                "mlt_engine_plan_part_seconds",
+                help="wall seconds of one ragged tick's plan phase by "
+                     "part: prefill (packing prompt rows, a scoring "
+                     "chunk), pages (the decode rows' page grants; an "
+                     "eviction once the pool is dry), upload (the "
+                     "slot-state arrays to the device, when dirty)",
+                labels={"part": part}, buckets=lat)
+            for part in ("prefill", "pages", "upload")}
+        self._m_dry_ticks = reg.counter(
+            "mlt_engine_pool_dry_ticks_total",
+            help="ragged ticks during whose admit or plan a page grant "
+                 "had to evict (the pool's free list had run dry); over "
+                 "mlt_engine_ticks_total the share of ticks that paid "
+                 "for an eviction")
         self._m_apply_lag = {
             lag: reg.counter(
                 "mlt_engine_tick_apply_lag_total",
@@ -2482,10 +2590,10 @@ class ContinuousBatchingEngine:
                 n = self._step_pipelined()
                 if n is not None:
                     return n
-            t_admit = time.monotonic()
+            t_admit, c_admit = time.monotonic(), time.thread_time()
             with obs_trace.span("engine-admit"):
                 self._admit()
-            return self._step_ragged(time.monotonic() - t_admit)
+            return self._step_ragged(time.monotonic() - t_admit, c_admit)
 
     def _prefill_budget_tokens(self) -> int:  # holds _lock
         """The policy's per-tick prefill budget, validated as TOKENS
@@ -3031,7 +3139,7 @@ class ContinuousBatchingEngine:
                                       (len(seq) - 1) // ps)
                 self._activate_or_handoff(req, req._slot)
 
-    def _step_ragged(self, admit_s: float) -> int:
+    def _step_ragged(self, admit_s: float, c_admit: float) -> int:
         """One fused ragged tick: decode slots + verify blocks + packed
         prefill-chunk rows, ONE compiled attention launch
         (generation/ragged.py).  return_log_probs prompts are the one
@@ -3070,35 +3178,49 @@ class ContinuousBatchingEngine:
         and apply of the tick before, each with its ``tick=``; and one
         observation each of ``mlt_engine_tick_phase_seconds`` a tick,
         with ``admit_s`` (the caller's admission) the fifth; a step that
-        launches nothing observes nothing for a launch."""
+        launches nothing observes nothing for a launch.  Plan's three
+        jobs are spans of their own under ``engine-plan``
+        (``plan-prefill``, ``plan-pages``, ``plan-upload``) with
+        ``mlt_engine_plan_part_seconds`` beside them.  ``c_admit`` is the
+        thread's CPU clock where the caller's admission began: admit,
+        plan and launch are one stretch on the thread, read again where
+        the launch ends (``mlt_engine_tick_host_cpu_seconds``, side
+        ``dispatch``; :meth:`_apply_tick` reads ``apply``'s pair)."""
         t_plan = time.monotonic()
         with obs_trace.span("engine-plan"):
+            with obs_trace.span("plan-prefill"):
+                with self._lock:
+                    pre0 = self.prefill_tokens_computed
+                    (spans, pre_tok, pre_pos, pre_tables, pre_index,
+                     pre_hor, lp_live) = self._plan_ragged_prefill()
+                did_lp = (1 if lp_live and self._advance_scored_prefill()
+                          else 0)
+            prefill_s = time.monotonic() - t_plan
             with self._lock:
-                pre0 = self.prefill_tokens_computed
-                (spans, pre_tok, pre_pos, pre_tables, pre_index, pre_hor,
-                 lp_live) = self._plan_ragged_prefill()
-            did_lp = 1 if lp_live and self._advance_scored_prefill() else 0
-            with self._lock:
-                prev = self._inflight[-1] if self._inflight else None
-                ahead = np.zeros((self.max_slots,), np.int32)
-                if prev is not None:
-                    for k, i in enumerate(prev.active):
-                        if self._row_live(prev, k):
-                            ahead[i] = 1
-                active, spent = [], []
-                for i, r in enumerate(self._slots):
-                    if r is None or r._phase != "decode":
-                        continue
-                    n = len(r.generated) + 1
-                    if ahead[i] and (n >= r.max_new_tokens
-                                     or len(r.prompt) + n >= self.max_seq):
-                        spent.append(i)
+                t_pages = time.monotonic()
+                with obs_trace.span("plan-pages"):
+                    prev = self._inflight[-1] if self._inflight else None
+                    ahead = np.zeros((self.max_slots,), np.int32)
+                    if prev is not None:
+                        for k, i in enumerate(prev.active):
+                            if self._row_live(prev, k):
+                                ahead[i] = 1
+                    active, spent = [], []
+                    for i, r in enumerate(self._slots):
+                        if r is None or r._phase != "decode":
+                            continue
+                        n = len(r.generated) + 1
+                        if ahead[i] and (
+                                n >= r.max_new_tokens
+                                or len(r.prompt) + n >= self.max_seq):
+                            spent.append(i)
+                        else:
+                            active.append(i)
+                    if active:
+                        k_eff = self._prepare_decode_locked(active, ahead)
                     else:
-                        active.append(i)
-                if active:
-                    k_eff = self._prepare_decode_locked(active, ahead)
-                else:
-                    k_eff = np.zeros((self.max_slots,), np.int32)
+                        k_eff = np.zeros((self.max_slots,), np.int32)
+                pages_s = time.monotonic() - t_pages
                 idle = not active and not spans
                 if idle:
                     self._note_launches_locked(
@@ -3117,15 +3239,19 @@ class ContinuousBatchingEngine:
                                                  len(active))
                     if spent:
                         self._dirty = True
-                    # a re-upload holds the tokens of the last APPLIED
-                    # tick: the rows the tick in flight runs take its
-                    # output instead, on the device
-                    carry = ((prev.toks, self._asarray(ahead > 0))
-                             if prev is not None and self._dirty else None)
-                    bt, pos, toks, keys, steps, temp, tk, tp = \
-                        self._dev_state_locked(ahead, spent)
-                    if carry is None:
-                        carry = (toks, self._no_carry)
+                    t_upload = time.monotonic()
+                    with obs_trace.span("plan-upload"):
+                        # a re-upload holds the tokens of the last APPLIED
+                        # tick: the rows the tick in flight runs take its
+                        # output instead, on the device
+                        carry = ((prev.toks, self._asarray(ahead > 0))
+                                 if prev is not None and self._dirty
+                                 else None)
+                        bt, pos, toks, keys, steps, temp, tk, tp = \
+                            self._dev_state_locked(ahead, spent)
+                        if carry is None:
+                            carry = (toks, self._no_carry)
+                    upload_s = time.monotonic() - t_upload
 
             n_pre = sum(end - start for _, start, end in spans)
             # live prefill rows bucketed to chunk multiples: the program's
@@ -3193,12 +3319,19 @@ class ContinuousBatchingEngine:
                 del pre_args, bt, pos, toks, keys, steps, temp, tk, tp
                 del carry, prev, out_tok, out_lp, next_tok, new_pos
                 del new_steps, spec, moe
-        t_launched = time.monotonic()
+        t_launched, c_launched = time.monotonic(), time.thread_time()
         self._note_host_gap(gap)
+        dry, self.pool.reclaimed = self.pool.reclaimed, False
         if obs_registry.publishing():
             for ph, sec in (("admit", admit_s), ("plan", t_tick - t_plan),
                             ("launch", t_launched - t_tick)):
                 self._m_phase[ph].observe(sec)
+            self._m_host_cpu["dispatch"].observe(c_launched - c_admit)
+            for part, sec in (("prefill", prefill_s), ("pages", pages_s),
+                              ("upload", upload_s)):
+                self._m_plan_part[part].observe(sec)
+            if dry:
+                self._m_dry_ticks.inc()
         # the tick before lands while the device runs this one; this one
         # too where the host cannot know its outcome's shape beforehand
         lag = 0 if self.spec_k or did_lp else 1
@@ -3251,7 +3384,7 @@ class ContinuousBatchingEngine:
                 if obs_registry.publishing():
                     self._m_moe_assignments.inc(rows)
                     self._m_moe_touched.inc(touched)
-        now = time.monotonic()
+        now, c_apply = time.monotonic(), time.thread_time()
         with obs_trace.span("engine-apply", tick=rec.no):
             with self._lock:
                 # one tick of device time: from the end of the fetch
@@ -3297,8 +3430,10 @@ class ContinuousBatchingEngine:
             # phase histogram alike.
             del rec, handles
         if obs_registry.publishing():
+            t_end, c_end = time.monotonic(), time.thread_time()
             self._m_phase["fetch"].observe(now - t_fetch)
-            self._m_phase["apply"].observe(time.monotonic() - now)
+            self._m_phase["apply"].observe(t_end - now)
+            self._m_host_cpu["apply"].observe(c_end - c_apply)
         return emitted
 
     def run_until_idle(self) -> None:
@@ -3667,44 +3802,43 @@ class ContinuousBatchingEngine:
             if n == 0:
                 return {"pages": 0, "installed": 0, "deduped": 0,
                         "tokens": 0}
-            with obs_trace.span("kv-import", pages=n, trace_id=trace_id):
-                with self._drive_lock:
+            with self._drive_lock:
+                with self._lock:
+                    matched = self.cache.match(payload.tokens, n)
+                    covered = len(matched)
+                    fresh = (self.pool.alloc(n - covered)
+                             if covered < n else [])
+                    if fresh is None:
+                        self.pool.release(matched)
+                        raise EngineOverloaded(
+                            f"KV pool cannot hold {n - covered} "
+                            f"pushed pages",
+                            retry_after=self._drain_eta(
+                                len(self._queue)),
+                            info=self._overload_info())
+                try:
+                    if fresh:
+                        # device upload outside _lock: the fresh
+                        # pages are refcount-1 and unshared, and
+                        # _drive_lock serializes vs tick dispatch
+                        self.pool.import_pages(fresh, {
+                            name: arr[:, covered:]
+                            for name, arr in payload.leaves.items()})
+                except Exception:
                     with self._lock:
-                        matched = self.cache.match(payload.tokens, n)
-                        covered = len(matched)
-                        fresh = (self.pool.alloc(n - covered)
-                                 if covered < n else [])
-                        if fresh is None:
-                            self.pool.release(matched)
-                            raise EngineOverloaded(
-                                f"KV pool cannot hold {n - covered} "
-                                f"pushed pages",
-                                retry_after=self._drain_eta(
-                                    len(self._queue)),
-                                info=self._overload_info())
-                    try:
-                        if fresh:
-                            # device upload outside _lock: the fresh
-                            # pages are refcount-1 and unshared, and
-                            # _drive_lock serializes vs tick dispatch
-                            self.pool.import_pages(fresh, {
-                                name: arr[:, covered:]
-                                for name, arr in payload.leaves.items()})
-                    except Exception:
-                        with self._lock:
-                            self.pool.release(matched)
-                            self.pool.release(fresh)
-                        raise
-                    with self._lock:
-                        installed = self.cache.insert(
-                            payload.tokens, matched + fresh, n)
-                        # inserted pages go cached-idle; duplicates
-                        # (trie incumbents won the position) go free
                         self.pool.release(matched)
                         self.pool.release(fresh)
-                        if obs_registry.publishing():
-                            self._m_kv_import_pages.inc(installed)
-                            self._m_kv_import_bytes.inc(len(blob))
+                    raise
+                with self._lock:
+                    installed = self.cache.insert(
+                        payload.tokens, matched + fresh, n)
+                    # inserted pages go cached-idle; duplicates
+                    # (trie incumbents won the position) go free
+                    self.pool.release(matched)
+                    self.pool.release(fresh)
+                    if obs_registry.publishing():
+                        self._m_kv_import_pages.inc(installed)
+                        self._m_kv_import_bytes.inc(len(blob))
             receipt = {"pages": n, "installed": installed,
                        "deduped": n - installed,
                        "tokens": len(payload.tokens)}

@@ -659,10 +659,8 @@ class MegatronServer:
                     trace_id = (self.headers.get("X-MLT-Trace-Id", "").strip()
                                 or uuid.uuid4().hex)
                     try:
-                        with obs_trace.span("serve-kv-push",
-                                            trace_id=trace_id):
-                            code, body = server.kv_push(
-                                blob, trace_id=trace_id)
+                        code, body = server.kv_push(
+                            blob, trace_id=trace_id)
                     except Exception as e:
                         code, body = 500, {
                             "error":

@@ -270,9 +270,6 @@ class ForwardingProxy:
         headers, i.e. its first token exists) returns an open
         :class:`StreamHandle` instead of a read body.  From that point
         ``pump_stream`` owns the never-retry-mid-body rule."""
-        from megatron_llm_tpu.observability.trace import span
-
-        trace_id = (headers or {}).get("X-MLT-Trace-Id", "")
         excluded: set = set()
         attempts = failovers = retries = 0
         saturated: List[Tuple[str, float]] = []
@@ -285,10 +282,8 @@ class ForwardingProxy:
                 if url in excluded:
                     continue
                 attempts += 1
-                with span("router-forward-stream", url=url,
-                          trace_id=trace_id):
-                    kind, status, payload, ra, resp = self._connect(
-                        url, body, headers)
+                kind, status, payload, ra, resp = self._connect(
+                    url, body, headers)
                 if kind == "accepted":
                     try:
                         ttft = float(resp.headers.get("X-MLT-TTFT-S"))

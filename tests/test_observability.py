@@ -813,6 +813,8 @@ def test_observability_overhead_gate(tmp_path):
 # ---------------------------------------------------------------------------
 
 PHASES = ("admit", "plan", "launch", "fetch", "apply")
+PLAN_PARTS = ("prefill", "pages", "upload")
+CPU_SIDES = ("dispatch", "apply")
 
 
 def _capture_spans(path, names):
@@ -845,6 +847,17 @@ def _counts(reg):
     for k in ("decode", "prefill"):
         out[k] = reg.counter("mlt_engine_tick_kind_total",
                              labels={"kind": k}).value
+    for side in CPU_SIDES:
+        out["cpu:" + side] = reg.histogram(
+            "mlt_engine_tick_host_cpu_seconds",
+            labels={"side": side}).snapshot()[2]
+    for part in PLAN_PARTS:
+        _, out["sum:" + part], out["part:" + part] = reg.histogram(
+            "mlt_engine_plan_part_seconds",
+            labels={"part": part}).snapshot()
+    out["sum:plan"] = reg.histogram(
+        "mlt_engine_tick_phase_seconds",
+        labels={"phase": "plan"}).snapshot()[1]
     return out
 
 
@@ -1028,6 +1041,44 @@ def test_phase_and_kind_counters_add_up_to_ticks(traced_serving_run):
     assert d["prefill"] == 2 and d["decode"] >= 4  # one prompt a request
 
 
+def test_plan_parts_and_host_cpu_observe_once_a_tick(traced_serving_run):
+    d = traced_serving_run["delta"]
+    for part in PLAN_PARTS:
+        assert d["part:" + part] == d["ticks"], (part, d)
+    for side in CPU_SIDES:
+        assert d["cpu:" + side] == d["ticks"], (side, d)
+    # the parts lie inside the phase: what is left is its waits for the
+    # engine's lock
+    parts = sum(d["sum:" + part] for part in PLAN_PARTS)
+    assert 0 < parts <= d["sum:plan"]
+
+
+def test_ring_nests_plans_three_parts_in_engine_plan(traced_serving_run):
+    """A ring dump holds ``plan-prefill``, ``plan-pages`` and (a step that
+    launches) ``plan-upload`` inside their step's ``engine-plan``, in
+    that order, none overlapping."""
+    ring = [e for e in traced_serving_run["ring"] if e[0] == "X"]
+    plans = [e for e in ring if e[1] == "engine-plan"]
+    launches = [e for e in ring if e[1] == "engine-launch"]
+    assert plans and len({e[4] for e in plans}) == 1
+    launched = 0
+    for plan in plans:
+        t0, t1 = plan[2], plan[2] + plan[3]
+        kids = sorted((e for e in ring if e[4] == plan[4]
+                       and e[1].startswith("plan-")
+                       and t0 <= e[2] and e[2] + e[3] <= t1),
+                      key=lambda e: e[2])
+        names = [e[1] for e in kids]
+        assert names in (["plan-prefill", "plan-pages", "plan-upload"],
+                         ["plan-prefill", "plan-pages"]), names
+        assert all(a[2] + a[3] <= b[2] for a, b in zip(kids, kids[1:]))
+        launched += len(names) == 3
+    assert launched == len(launches) >= 3
+    # every plan-* span of the run has an engine-plan around it
+    assert sum(e[1].startswith("plan-") for e in ring) == \
+        2 * len(plans) + launched
+
+
 def test_latency_ladder_quantiles_within_15_percent():
     import random
 
@@ -1066,7 +1117,9 @@ def test_engine_seconds_histograms_share_the_ladder(traced_serving_run):
     assert {"mlt_engine_ttft_seconds", "mlt_engine_queue_wait_seconds",
             "mlt_engine_prefill_compute_seconds",
             "mlt_engine_preempted_seconds", "mlt_engine_host_gap_seconds",
-            "mlt_engine_tick_phase_seconds"} <= names
+            "mlt_engine_tick_phase_seconds",
+            "mlt_engine_tick_host_cpu_seconds",
+            "mlt_engine_plan_part_seconds"} <= names
     for key, les in seen.items():
         assert len(les) == 50 and les[-1] == "+Inf", key
     assert "INCLUDES the fetch" in text  # host_gap's help says what it is

@@ -304,6 +304,136 @@ def test_eviction_under_pressure_admits_instead_of_starving(toy_model):
     _assert_page_states(eng)
 
 
+HOST_PHASES = {"dispatch": ("admit", "plan", "launch"), "apply": ("apply",)}
+
+
+def _engine_counters():
+    from megatron_llm_tpu.observability import registry as registry_mod
+
+    reg = registry_mod.get_registry()
+    out = {"ticks": reg.counter("mlt_engine_ticks_total").value,
+           "dry": reg.counter("mlt_engine_pool_dry_ticks_total").value,
+           "evicted": reg.counter(
+               "mlt_engine_prefix_evicted_pages_total").value,
+           "by_evict": reg.counter("mlt_engine_pool_alloc_pages_total",
+                                   labels={"source": "evict"}).value}
+    for ph in ("admit", "plan", "launch", "fetch", "apply"):
+        _, out[ph], out[ph + "_n"] = reg.histogram(
+            "mlt_engine_tick_phase_seconds", labels={"phase": ph}).snapshot()
+    for side in HOST_PHASES:
+        _, out["cpu_" + side], out["cpu_" + side + "_n"] = reg.histogram(
+            "mlt_engine_tick_host_cpu_seconds",
+            labels={"side": side}).snapshot()
+    return out
+
+
+def test_dry_tick_is_counted_by_the_step_and_the_flag_cleared(toy_model):
+    """A tick during whose admit or plan a grant had to evict is counted
+    dry, once, by the step that launches it; the pool's flag is the
+    step's to clear; a run that evicts nothing counts none."""
+    cfg, params = toy_model
+    eng = _engine(cfg, params, max_slots=2, page_size=16, num_pages=10,
+                  prefix_cache=True)
+    c0 = _engine_counters()
+    prompt64 = [2 + (j * 7) % 60 for j in range(64)]
+    _run(eng, [(prompt64, 4, dict(top_k=1, termination_id=10 ** 9))])
+    c1 = _engine_counters()
+    assert c1["ticks"] > c0["ticks"] and c1["dry"] == c0["dry"]
+    assert c1["evicted"] == c0["evicted"] and not eng.pool.reclaimed
+    prompt = [11 + (j * 13) % 50 for j in range(80)]
+    _run(eng, [(prompt, 30, dict(top_k=1, termination_id=10 ** 9))])
+    c2 = _engine_counters()
+    evicted = c2["evicted"] - c1["evicted"]
+    assert evicted >= 1 and c2["by_evict"] - c1["by_evict"] == evicted
+    dry = c2["dry"] - c1["dry"]
+    # at least the tick that admitted the request; never more than a tick
+    # a victim, never more than the ticks run
+    assert 1 <= dry <= min(evicted, c2["ticks"] - c1["ticks"])
+    assert not eng.pool.reclaimed, "the launching step clears the flag"
+    _assert_page_states(eng)
+
+
+def test_host_cpu_is_the_scheduler_threads_own(toy_model, monkeypatch):
+    """mlt_engine_tick_host_cpu_seconds reads the driving thread's CPU
+    clock: a side spent asleep reads near 0 however busy another thread
+    is meanwhile, and no side's CPU exceeds the wall time of its phases."""
+    import threading
+    import time
+
+    cfg, params = toy_model
+    eng = _engine(cfg, params, max_slots=2, page_size=16, num_pages=16)
+    _run(eng, [([5, 6, 7, 8], 3, dict(top_k=1, termination_id=10 ** 9))])
+    admit = eng._admit
+
+    def sleepy_admit():
+        time.sleep(0.05)                  # off the CPU, inside dispatch
+        admit()
+
+    monkeypatch.setattr(eng, "_admit", sleepy_admit)
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(2000))
+
+    other = threading.Thread(target=spin, daemon=True)
+    other.start()
+    c0 = _engine_counters()
+    try:
+        _run(eng, [([9, 10, 11, 12], 6,
+                    dict(top_k=1, termination_id=10 ** 9))])
+    finally:
+        stop.set()
+        other.join(timeout=30)
+    assert not other.is_alive()
+    c1 = _engine_counters()
+    d = {k: c1[k] - c0[k] for k in c1}
+    n = d["admit_n"]
+    assert n >= 5 and d["cpu_dispatch_n"] == n
+    wall = {side: sum(d[ph] for ph in phases)
+            for side, phases in HOST_PHASES.items()}
+    assert wall["dispatch"] >= 0.05 * n
+    assert d["cpu_dispatch"] < 0.2 * wall["dispatch"], d
+    for side in HOST_PHASES:
+        assert d["cpu_" + side + "_n"] == d["apply_n"] > 0
+        # thread_time ticks coarser than the wall clock: a tick of room
+        assert d["cpu_" + side] <= wall[side] + 0.011 * n, (side, d)
+
+
+def test_four_cpu_clock_reads_a_tick(toy_model, monkeypatch):
+    """time.thread_time is a system call (5.6 to 23.8 us on the chip's
+    host): a launched tick reads it at most four times, the start of
+    admit, the end of launch, and the two ends of apply."""
+    import time
+    import types
+
+    from megatron_llm_tpu.generation import engine as engine_mod
+
+    cfg, params = toy_model
+    eng = _engine(cfg, params, max_slots=2, page_size=16, num_pages=16)
+    reads = []
+
+    def counting_thread_time():
+        reads.append(1)
+        return time.thread_time()
+
+    clock = types.SimpleNamespace(**{
+        k: getattr(time, k) for k in dir(time) if not k.startswith("_")})
+    clock.thread_time = counting_thread_time
+    monkeypatch.setattr(engine_mod, "time", clock)
+    steps = []
+    admit = eng._admit
+    monkeypatch.setattr(eng, "_admit", lambda: (steps.append(1), admit())[1])
+    c0 = _engine_counters()
+    _run(eng, [([5, 6, 7, 8], 12, dict(top_k=1, termination_id=10 ** 9))])
+    c1 = _engine_counters()
+    ticks = int(c1["ticks"] - c0["ticks"])
+    assert 12 <= ticks <= len(steps)
+    # one read a step (admit's start: all that a step with nothing to
+    # launch reads) and three more a launched tick
+    assert len(reads) == len(steps) + 3 * ticks, (len(reads), len(steps))
+
+
 def test_lru_leaf_first_eviction_order(toy_model):
     """Direct pool+trie unit test: eviction takes refcount-0 LEAVES in LRU
     order and never touches referenced pages."""
