@@ -92,6 +92,8 @@ a caller loop (:meth:`run_until_idle`).
 from __future__ import annotations
 
 import dataclasses
+import heapq
+import itertools
 import os
 import threading
 import time
@@ -250,6 +252,13 @@ class PagedKVPool:
       ``evict_hook`` (PrefixCache.evict, LRU leaf-first) when ``alloc``
       outruns the free list.
 
+    How many pages are cached-idle is KEPT, not walked: the count changes
+    only where a page crosses a boundary (``incref`` of a cached page
+    0 -> 1, ``release`` of one 1 -> 0, ``set_cached`` as a page enters or
+    leaves ``cached``), so ``num_evictable`` and ``num_available`` are
+    reads.  Pages that ``release`` leaves cached-idle are handed to
+    ``idle_hook`` (PrefixCache.note_idle), which keeps the eviction order.
+
     With ``draft_cfg`` (speculative decoding, generation/speculative/),
     the pool carries a SECOND leaf (``draft_kv``) shaped by the draft
     model — same ``num_pages``, same page ids.  A page id then addresses
@@ -354,10 +363,14 @@ class PagedKVPool:
         self.num_pages = num_pages
         self.page_size = page_size
         self.refcounts = np.zeros((num_pages,), np.int32)
-        # pages owned by the prefix cache (trie nodes); maintained by
-        # PrefixCache, read here for release/eviction accounting
+        # pages owned by the prefix cache (trie nodes); PrefixCache adds
+        # and removes them through ``set_cached``, which keeps the count
+        # of those at refcount 0 beside the set
         self.cached: Set[int] = set()
+        self._idle_cached = 0
         self.evict_hook = None  # PrefixCache.evict: (n) -> freed page list
+        # PrefixCache.note_idle: (pages a release left cached-idle) -> None
+        self.idle_hook = None
         # page 0 reserved as the null page (never allocated)
         self._free: deque = deque(range(1, num_pages))
         # a grant had to evict since the engine's step last looked: the
@@ -365,8 +378,9 @@ class PagedKVPool:
         self.reclaimed = False
         # the slow path counts and times itself (an ``alloc`` the free
         # list serves reads no clock): pages by where they came from, and
-        # the calling thread's wall seconds in the two scans, whoever
-        # calls (a tick's page grant, admission's budget check, /health)
+        # the calling thread's wall seconds picking victims.  Counting the
+        # evictable pages is a read since the count is kept; its series
+        # stays exported, at 0, for the readers that sum both
         reg = obs_registry.get_registry()
         self._m_alloc = {
             src: reg.counter(
@@ -379,10 +393,10 @@ class PagedKVPool:
             what: reg.counter(
                 "mlt_engine_pool_scan_seconds_total",
                 help="wall seconds the calling thread spent in the pool's "
-                     "two scans: evictable = counting the cached pages no "
-                     "request references (every alloc past the free list, "
-                     "every admission check, every /health answer), evict "
-                     "= the prefix cache picking and unlinking victims",
+                     "slow path: evict = the prefix cache picking and "
+                     "unlinking victims; evictable = counting the cached "
+                     "pages no request references, 0 since that count is "
+                     "kept as references change and no longer walked",
                 labels={"what": what}) for what in ("evictable", "evict")}
 
     def _place(self, pool):
@@ -468,12 +482,20 @@ class PagedKVPool:
     @property
     def num_evictable(self) -> int:
         """Cached pages no request references — reclaimable on demand.
-        A walk over every cached page, timed for its caller."""
-        t0 = time.perf_counter()
-        n = sum(1 for p in self.cached if self.refcounts[p] == 0)
-        if obs_registry.publishing():
-            self._m_scan["evictable"].inc(time.perf_counter() - t0)
-        return n
+        A read: the count is kept where references and ``cached`` change
+        (what a walk ``sum(refcounts[p] == 0 for p in cached)`` would
+        give, tests/test_prefix_cache.py holds the two equal)."""
+        return self._idle_cached
+
+    def set_cached(self, page: int, cached: bool) -> None:
+        """The prefix cache registers ``page`` (a node now owns it) or
+        gives it up (evicted; the caller puts it on the free list)."""
+        if cached:
+            self.cached.add(page)
+        else:
+            self.cached.remove(page)
+        if self.refcounts[page] == 0:
+            self._idle_cached += 1 if cached else -1
 
     @property
     def num_available(self) -> int:
@@ -484,8 +506,8 @@ class PagedKVPool:
         """``n`` fresh pages at refcount 1, or None if free + evictable
         can't satisfy the request.  Evicts cached-idle pages (LRU,
         leaf-first) only when the free list alone runs short."""
-        # the free list first: counting the evictable pages walks every
-        # cached page, and the tick's page grants come here once a row
+        # the free list first: the tick's page grants come here once a
+        # row, and this path reads no clock and opens no span
         evicted = 0
         if n > len(self._free):
             evicted = self._reclaim(n)
@@ -504,7 +526,10 @@ class PagedKVPool:
     def _reclaim(self, n: int) -> int:
         """``alloc``'s slow path: the free list is short of ``n`` pages.
         Evicts the shortfall in cached-idle pages onto it if there are as
-        many, and returns how many it evicted.  One ``pool-reclaim`` span
+        many (a read of the kept count: a grant that cannot be served
+        evicts nothing and reads no clock), and returns how many it
+        evicted.  What it costs is what its victims cost, one or two heap
+        entries each, not the size of the trie.  One ``pool-reclaim`` span
         a call (inside the caller's ``engine-admit`` or ``engine-plan``)
         with the eviction's zero-length ``pool-evict`` inside, so a
         device idle gap that is an eviction is named in a capture."""
@@ -525,18 +550,25 @@ class PagedKVPool:
     def incref(self, pages: Sequence[int]) -> None:
         for p in pages:
             assert p != NULL_PAGE
+            if self.refcounts[p] == 0 and p in self.cached:
+                self._idle_cached -= 1
             self.refcounts[p] += 1
 
     def release(self, pages: Sequence[int]) -> None:
         """Drop one reference per page.  Unreferenced pages return to the
         free list unless the prefix cache still holds them (those stay
         cached-idle until matched again or evicted)."""
+        idle = []
         for p in pages:
             assert p != NULL_PAGE, "null page is never allocated"
             self.refcounts[p] -= 1
             assert self.refcounts[p] >= 0, f"page {p} over-released"
-            if self.refcounts[p] == 0 and p not in self.cached:
-                self._free.append(p)
+            if self.refcounts[p] == 0:
+                (idle if p in self.cached else self._free).append(p)
+        if idle:
+            self._idle_cached += len(idle)
+            if self.idle_hook is not None:
+                self.idle_hook(idle)
 
     # ---- cross-replica page transfer (ISSUE 19, serving/handoff/) ----
 
@@ -632,7 +664,26 @@ class PrefixCache:
     construction, matched ALL its ancestors too, a refcount-0 node's
     descendants are also refcount-0 — so eviction can always proceed
     leaf-first through cached-idle subtrees, and ``PagedKVPool.num_evictable``
-    (a flat count) is exactly the number of reclaimable pages.
+    (the pool's kept count of cached pages at refcount 0) is exactly the
+    number of reclaimable pages.
+
+    Eviction takes the idle LEAF (no child, refcount 0) with the lowest
+    ``last_use``.  Those leaves are kept in that order in a heap
+    (``_idle``), entered where a node becomes one: the pool releases its
+    page's last reference while it has no child (``note_idle``), its last
+    child is evicted while it is idle, or ``insert`` ends on it
+    unreferenced.  Nothing is taken out when a node stops being one
+    (``match`` references it, ``insert`` hangs a child on it or stamps it
+    anew): an entry is checked where it is popped and dropped if its node
+    is gone, has a child, is referenced or was stamped since, so ``evict``
+    looks at one or two entries a victim whatever the trie holds.  Order
+    of entry is not order of use, hence a heap and no queue.  Every
+    ``match`` / ``insert`` stamps one root path with a fresh clock value
+    and only the deepest node of a path can be childless, so no two idle
+    leaves share a ``last_use`` and the order is total.  A pool that
+    never runs dry never pops: the heap is rebuilt from the trie when it
+    outgrows ``2 * len(self) + 64`` entries, which keeps it O(nodes) at
+    an amortised constant a push.
     """
 
     def __init__(self, pool: PagedKVPool, page_size: int):
@@ -641,16 +692,27 @@ class PrefixCache:
         self.root = _TrieNode(None, NULL_PAGE, None)
         self._nodes: Dict[int, _TrieNode] = {}  # page id -> node
         self._clock = 0
+        # (last_use, entry number, node): the number only keeps two
+        # entries of one node and stamp from comparing nodes
+        self._idle: List[Tuple[int, int, _TrieNode]] = []
+        self._entry = itertools.count()
         pool.evict_hook = self.evict
+        pool.idle_hook = self.note_idle
         reg = obs_registry.get_registry()
         self._m_evicted = reg.counter(
             "mlt_engine_prefix_evicted_pages_total",
             help="cached-idle pages the prefix cache gave up to a grant")
         self._m_scanned = reg.counter(
             "mlt_engine_prefix_evict_scanned_nodes_total",
-            help="trie nodes looked at to pick eviction victims (a whole "
-                 "pass over the trie a victim); over the evicted pages: "
-                 "the work one eviction costs")
+            help="entries of the idle-leaf order looked at to pick "
+                 "eviction victims, stale ones included; over the evicted "
+                 "pages: the work one eviction costs (1-2, whatever the "
+                 "trie holds)")
+        self._m_rebuilds = reg.counter(
+            "mlt_engine_prefix_idle_rebuilds_total",
+            help="times the idle-leaf order outgrew twice the trie and "
+                 "was rebuilt from it (stale entries of a pool that "
+                 "rarely evicts)")
 
     def __len__(self) -> int:
         return len(self._nodes)
@@ -692,31 +754,63 @@ class PrefixCache:
                 child = _TrieNode(key, p, node)
                 node.children[key] = child
                 self._nodes[p] = child
-                self.pool.cached.add(p)
+                self.pool.set_cached(p, True)
                 added += 1
             child.last_use = self._clock
             node = child
+        # the walk's end is the one node it may leave an idle leaf under a
+        # stamp the heap has not seen: a new page nobody references, or
+        # an incumbent's that its request's duplicate did not replace
+        if node is not self.root:
+            self._push_if_idle_leaf(node)
         return added
+
+    def note_idle(self, pages: Sequence[int]) -> None:
+        """The pool's ``idle_hook``: a release left ``pages`` cached at
+        refcount 0.  Of a retired request's chain only the deepest page
+        is a leaf."""
+        for p in pages:
+            self._push_if_idle_leaf(self._nodes[p])
+
+    def _push_if_idle_leaf(self, node: _TrieNode) -> None:
+        if node.children or self.pool.refcounts[node.page] != 0:
+            return
+        heapq.heappush(self._idle,
+                       (node.last_use, next(self._entry), node))
+        if len(self._idle) > 2 * len(self._nodes) + 64:
+            self._rebuild_idle()
+
+    def _rebuild_idle(self) -> None:
+        """Drop the stale entries: the heap anew from the trie's idle
+        leaves, in place (``evict`` may be popping from this list)."""
+        self._idle[:] = [
+            (n.last_use, next(self._entry), n) for n in self._nodes.values()
+            if not n.children and self.pool.refcounts[n.page] == 0]
+        heapq.heapify(self._idle)
+        if obs_registry.publishing():
+            self._m_rebuilds.inc()
 
     def evict(self, n: int) -> List[int]:
         """Reclaim up to ``n`` cached-idle pages, least-recently-used
-        leaves first (removing a leaf may expose its parent next round)."""
+        leaves first (removing a leaf may expose its parent, which
+        competes from then on under its own ``last_use``)."""
         freed: List[int] = []
         scanned = 0
-        while len(freed) < n:
-            victim = None
-            scanned += len(self._nodes)  # a pass looks at every node
-            for node in self._nodes.values():
-                if node.children or self.pool.refcounts[node.page] != 0:
-                    continue
-                if victim is None or node.last_use < victim.last_use:
-                    victim = node
-            if victim is None:
-                break
-            del victim.parent.children[victim.key]
-            del self._nodes[victim.page]
-            self.pool.cached.discard(victim.page)
+        idle, nodes, refcounts = self._idle, self._nodes, self.pool.refcounts
+        while len(freed) < n and idle:
+            last_use, _, victim = heapq.heappop(idle)
+            scanned += 1
+            if (nodes.get(victim.page) is not victim or victim.children
+                    or refcounts[victim.page] != 0
+                    or victim.last_use != last_use):
+                continue  # stale: it stopped being this idle leaf
+            parent = victim.parent
+            del parent.children[victim.key]
+            del nodes[victim.page]
+            self.pool.set_cached(victim.page, False)
             freed.append(victim.page)
+            if parent is not self.root:
+                self._push_if_idle_leaf(parent)
         # what the call did, as one zero-length event inside the pool's
         # ``pool-reclaim``: the numbers are known only now
         with obs_trace.span("pool-evict", evicted=len(freed),

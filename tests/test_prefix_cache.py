@@ -466,6 +466,184 @@ def test_lru_leaf_first_eviction_order(toy_model):
     assert int(pool.refcounts.sum()) == 0
 
 
+def _scan_victims(cache, pool, n):
+    """The reference: the victim scan as it stood before the idle leaves
+    were kept in order (a whole pass over the trie a victim, the lowest
+    ``last_use`` among childless unreferenced nodes, first in ``_nodes``
+    order on a tie), run on the live trie without unlinking anything."""
+    gone, out = set(), []
+    while len(out) < n:
+        victim, stamps = None, set()
+        for node in cache._nodes.values():
+            if id(node) in gone or pool.refcounts[node.page] != 0:
+                continue
+            if any(id(c) not in gone for c in node.children.values()):
+                continue
+            assert node.last_use not in stamps, "two idle leaves, one stamp"
+            stamps.add(node.last_use)
+            if victim is None or node.last_use < victim.last_use:
+                victim = node
+        if victim is None:
+            break
+        gone.add(id(victim))
+        out.append(victim.page)
+    return out
+
+
+def _assert_pool_and_trie(pool, cache):
+    """(b) the kept count equals the walk's; (c) free, referenced and
+    cached-idle are disjoint and cover the pool; the idle order's bound."""
+    walk = sum(1 for p in pool.cached if pool.refcounts[p] == 0)
+    assert pool.num_evictable == walk
+    assert pool.num_available == pool.num_free + walk
+    free = set(pool._free)
+    assert len(free) == pool.num_free and NULL_PAGE not in free
+    referenced = {p for p in range(1, pool.num_pages) if pool.refcounts[p]}
+    idle = {p for p in pool.cached if pool.refcounts[p] == 0}
+    assert not free & referenced and not free & pool.cached
+    assert len(free) + len(referenced) + len(idle) == pool.num_pages - 1
+    assert set(cache._nodes) == pool.cached
+    assert len(cache._idle) <= 2 * len(cache) + 64
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_eviction_equals_the_reference_scan_over_random_steps(toy_model, seed):
+    """A pool and a trie through a few thousand random steps: chains that
+    share prefixes admitted (match, alloc past the free list, insert),
+    retired in shuffled order, touched, referenced bare, and evicted in
+    part.  Every eviction's victims are the reference scan's, page for
+    page; the kept count is the walk's; the page states stay disjoint."""
+    cfg, _ = toy_model
+    ps, n_pages = 2, 72
+    pool = PagedKVPool(cfg, num_pages=n_pages, page_size=ps)
+    cache = PrefixCache(pool, page_size=ps)
+    rng = np.random.default_rng(seed)
+    held = []                       # page lists some "request" references
+    evictions = victims = 0
+
+    def chain():
+        # 6 families x a few branch points: prefixes are shared, tails differ
+        fam, depth = int(rng.integers(0, 6)), int(rng.integers(1, 7))
+        toks = []
+        for d in range(depth):
+            branch = int(rng.integers(0, 2 if d < 3 else 4))
+            toks += [100 * fam + 10 * d + branch] * ps
+        return toks, depth
+
+    for step in range(2500):
+        op = rng.choice(["admit", "retire", "touch", "evict", "bare"],
+                        p=[0.36, 0.32, 0.14, 0.1, 0.08])
+        if op == "admit":
+            toks, depth = chain()
+            matched = cache.match(toks, int(rng.integers(0, depth + 1)))
+            need = depth - len(matched) + int(rng.integers(0, 3))
+            short = need - pool.num_free
+            # a grant the count cannot cover evicts nothing; one it can
+            # takes the scan's victims, which fall short of the count
+            # where an idle page's child is still referenced (a request
+            # whose duplicate pages stayed private holds the child alone)
+            walk = sum(1 for p in pool.cached if pool.refcounts[p] == 0)
+            want = _scan_victims(cache, pool, short) if (
+                0 < short <= walk) else []
+            cached_before = set(pool.cached)
+            fresh = pool.alloc(need)
+            assert cached_before - pool.cached == set(want), (step, want)
+            if want:
+                evictions, victims = evictions + 1, victims + len(want)
+            if fresh is None:
+                assert short > len(want)
+                assert list(pool._free)[pool.num_free - len(want):] == want
+                pool.release(matched)
+            else:
+                assert fresh[need - len(want):] == want, (step, want, fresh)
+                pages = matched + fresh
+                cache.insert(toks, pages, int(rng.integers(0, depth + 1)))
+                held.append(pages)
+        elif op == "retire" and held:
+            pool.release(held.pop(int(rng.integers(0, len(held)))))
+        elif op == "touch":
+            toks, depth = chain()
+            pool.release(cache.match(toks, depth))
+        elif op == "evict":
+            k = int(rng.integers(1, 6))
+            want = _scan_victims(cache, pool, k)
+            got = cache.evict(k)
+            assert got == want, (step, want, got)
+            pool._free.extend(got)  # evicted pages belong to the caller
+        elif op == "bare" and pool.cached:
+            # references that stamp nothing (the handoff, parking): on a
+            # page and, as every holder does, on all its ancestors
+            p = sorted(pool.cached)[int(rng.integers(0, len(pool.cached)))]
+            node, path = cache._nodes[p], []
+            while node is not cache.root:
+                path.append(node.page)
+                node = node.parent
+            pool.incref(path)
+            held.append(path)
+        _assert_pool_and_trie(pool, cache)
+    assert evictions > 50 and victims > evictions, "the pool never ran dry"
+    for pages in held:
+        pool.release(pages)
+    _assert_pool_and_trie(pool, cache)
+    # nothing is referenced: the whole trie unwinds, still in the order
+    want = _scan_victims(cache, pool, n_pages)
+    assert cache.evict(n_pages) == want
+    assert len(cache) == 0 and not pool.cached and not cache._idle
+    assert int(pool.refcounts.sum()) == 0
+
+
+def test_order_of_use_not_order_of_retirement_decides(toy_model):
+    """A matches at clock 5, B on another path at clock 6, B retires
+    first: B's leaf became idle before A's, and A's still goes first."""
+    cfg, _ = toy_model
+    pool = PagedKVPool(cfg, num_pages=12, page_size=4)
+    cache = PrefixCache(pool, page_size=4)
+    toks_a, toks_b = list(range(100, 108)), list(range(200, 208))
+    a, b = pool.alloc(2), pool.alloc(2)
+    cache.insert(toks_a, a, 2)
+    cache.insert(toks_b, b, 2)
+    pool.release(a)
+    pool.release(b)
+    got_a = cache.match(toks_a, 2)       # A is used first ...
+    got_b = cache.match(toks_b, 2)       # ... B after it
+    pool.release(got_b)                  # B retires first
+    pool.release(got_a)
+    assert cache.evict(1) == [a[1]], "the least recently USED leaf goes"
+    assert cache.evict(3) == [a[0], b[1], b[0]]
+
+
+def test_idle_order_stays_bounded_without_any_eviction(toy_model):
+    """A pool that never runs dry never pops: 10,000 match / release
+    rounds leave one stale entry each, and the rebuild keeps the heap
+    under its bound; what is left afterwards still evicts in order."""
+    from megatron_llm_tpu.observability import registry as registry_mod
+
+    cfg, _ = toy_model
+    pool = PagedKVPool(cfg, num_pages=40, page_size=2)
+    cache = PrefixCache(pool, page_size=2)
+    chains = []
+    for c in range(6):
+        pages = pool.alloc(4)
+        toks = [10 * c + d for d in range(4) for _ in range(2)]
+        cache.insert(toks, pages, 4)
+        pool.release(pages)
+        chains.append((toks, pages))
+    rebuilds = registry_mod.get_registry().counter(
+        "mlt_engine_prefix_idle_rebuilds_total")
+    before, peak = rebuilds.value, 0
+    rng = np.random.default_rng(0)
+    for _ in range(10_000):
+        toks, pages = chains[int(rng.integers(0, 6))]
+        assert cache.match(toks, 4) == pages
+        pool.release(pages)
+        peak = max(peak, len(cache._idle))
+    assert peak <= 2 * len(cache) + 64 == 112
+    assert rebuilds.value - before >= 10_000 // 112
+    assert pool.num_evictable == 24 and pool.num_free == 15
+    want = _scan_victims(cache, pool, 24)
+    assert cache.evict(24) == want and len(want) == 24 and not cache._idle
+
+
 def test_queue_overflow_raises_engine_overloaded(toy_model):
     cfg, params = toy_model
     eng = _engine(cfg, params, max_queue=2)
