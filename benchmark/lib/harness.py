@@ -185,6 +185,23 @@ def _plain(v):
     return v.item() if hasattr(v, "item") else v
 
 
+def compared(cell, checks: Dict) -> Dict[str, Dict]:
+    """Each number that ``correct`` compares beside its limit, by short
+    plain names: what the last lines of a run's standard error and the last
+    key of its result line show."""
+    tol = cell.config.get("tolerance", {})
+    pairs = (("compiles_in_window", "compiles_in_window", 0),
+             ("reference_mean", "reference_mean_abs_diff", tol.get("mean_abs_nats")),
+             ("reference_median", "reference_median_abs_diff",
+              tol.get("median_abs_nats")),
+             ("reference_max", "reference_max_abs_diff", tol.get("max_abs_nats")),
+             ("engine_failures", "engine_failures", 0),
+             ("prefix_hit_share", "prefix_hit_share", checks.get("min_hit_share")))
+    return {name: {"value": checks[key], "limit": limit}
+            for name, key, limit in pairs
+            if checks.get(key) is not None and limit is not None}
+
+
 def result_line(cell, args, run: Run) -> Dict:
     """The contract's one JSON object.  ``--trace 0``: the cell's
     end-to-end metrics; ``--trace 1``: its per-layer metrics."""
@@ -209,4 +226,5 @@ def result_line(cell, args, run: Run) -> Dict:
         device["window_s"] = run.trace.window_s
         line["breakdown"] = {"device_ops": run.trace.top_ops(10),
                              "idle_gaps": run.trace.labelled_gaps(10)}
+    line["compared"] = compared(cell, line["checks"])      # last, by design
     return line

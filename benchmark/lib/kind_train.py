@@ -14,6 +14,16 @@ answers with StopIteration.  Steps completed in the window = draws between
 the two stamps; both edges are draw instants, so no step is cut.  pretrain's
 own ``tokens_per_sec`` (first retire to last fetch) is printed beside it as
 a cross-check and is not the metric.
+
+``correct`` rests on: no compile inside the window, every loss finite, and
+the reference comparison.  How evenly the steps came is recorded beside
+them (``window_steady``: no draw gap of three times the median or more;
+the longest gap, the step it followed and the median, in ms) and decides
+nothing: a program that stalls completes fewer steps, which
+``train_tokens_per_s`` counts over every second of the window, and one late
+step on a shared host is a far-off run to repeat, not a wrong output
+(PERF.md 7i: three PRs refused by it that had touched no training code).
+In a ``--trace 1`` run the longest gap is the profiler's own start.
 """
 
 from __future__ import annotations
@@ -24,6 +34,27 @@ from typing import Dict
 
 from benchmark.lib import check as check_mod
 from benchmark.lib import harness, traffic as traffic_mod
+
+
+def judge(run_: harness.Run, gaps, losses, ref: Dict) -> None:
+    """Fill ``run_.checks`` and ``run_.correct`` from the window's draw
+    gaps (seconds), the trainer's losses and the reference comparison."""
+    import numpy as np
+
+    finite = bool(losses) and all(math.isfinite(v) for v in losses)
+    gaps = np.asarray(gaps, np.float64)
+    late = int(gaps.argmax()) if len(gaps) else None
+    median = float(np.median(gaps)) if len(gaps) else None
+    run_.checks = {
+        "compiles_in_window": run_.compiles_in_window, "losses_finite": finite,
+        "window_steady": late is not None and bool(gaps[late] < 3.0 * median),
+        "longest_gap_ms": None if late is None else float(gaps[late]) * 1e3,
+        "longest_gap_after_step": late,
+        "median_gap_ms": None if late is None else median * 1e3,
+        "last_loss": losses[-1] if losses else None, **ref}
+    # a window that completed no step measured nothing
+    run_.correct = bool(run_.compiles_in_window == 0 and finite
+                        and late is not None and ref["reference_ok"])
 
 
 def run(cell, args, clock) -> harness.Run:
@@ -123,17 +154,10 @@ def run(cell, args, clock) -> harness.Run:
     finite = bool(losses) and all(math.isfinite(v) for v in losses)
     run_.attempted = steps
     run_.failed = 0 if finite else sum(not math.isfinite(v) for v in losses) or steps
-    # a compile, a stall or a starved pipeline shows as a long draw gap
-    steady = bool(len(gaps)) and (prof is not None or
-                                  gaps.max() < 3.0 * float(np.median(gaps)))
     ref = check_mod.train_against_reference(
         cell, cfg, result["params"], result["mesh"], args.seed,
         rows=global_batch, positions=min(int(mix.get("probe_positions", 512)), seq))
-    run_.checks = {"compiles_in_window": run_.compiles_in_window,
-                   "losses_finite": finite, "window_steady": steady,
-                   "last_loss": losses[-1] if losses else None, **ref}
-    run_.correct = (run_.compiles_in_window == 0 and finite and steady
-                    and ref["reference_ok"])
+    judge(run_, gaps, losses, ref)
     if prof:
         prof.reduce()
     return run_

@@ -12,7 +12,19 @@ Order of a run: weights -> engine, server -> the probe prompts streamed
 through the HTTP API (they warm up every tick shape and the copy-on-write
 page copy, and their emitted tokens' log-probs are what ``correct``
 compares: lib/check.py) -> the reference on prompts + emitted tokens ->
-ramp -> the measured window -> drain -> reduce.
+priming, where the mix asks for it -> ramp -> the measured window -> drain
+-> reduce.
+
+Priming.  A mix whose ``shared_prefix`` holds a ``prime`` group (``together``:
+how many prefixes are sent at a time; ``min_hit_share`` with its reason)
+has every prefix of the run's own plan streamed once for one token before
+the client starts, so that the window opens on a warm prefix cache, as a
+deployment's warm-up does; the time is part of ``setup_s``.  Such a run is
+``correct`` only if the prefix cache then served, inside the window, at
+least ``min_hit_share`` of the prefix tokens that the window's requests
+carried (``mlt_engine_prefix_hit_tokens_total``): a prefix evicted before
+its requests came is the configuration's pool being too small, and the
+run says so.  Nothing is retried.
 """
 
 from __future__ import annotations
@@ -26,11 +38,12 @@ import sys
 import threading
 import time
 import urllib.request
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from benchmark.lib import check as check_mod
 from benchmark.lib import client as client_mod
 from benchmark.lib import harness, readers, stats
+from benchmark.lib import traffic as traffic_mod
 
 
 def init_weights(cfg, key, dtype_name: str, shardings=None):
@@ -84,91 +97,179 @@ def _hit_tokens(url: str) -> float:
         "mlt_engine_prefix_hit_tokens_total", 0.0)
 
 
+def _stream_all(port: int, jobs: List[Dict], n_out: int, sampling: Dict,
+                together: int) -> None:
+    """Stream ``jobs`` (each a dict with ``name`` and ``prompt``) through the
+    HTTP API, ``together`` at a time, and leave each its ``tokens`` and
+    ``logprobs``; a refusal or an error ends the run."""
+    def ask(job):
+        got = client_mod.stream_request("127.0.0.1", port, job["prompt"], n_out,
+                                        sampling, 600.0)
+        if got["status"] != 200 or got["error"]:
+            job["failure"] = f"{got['status']} {got['error']}"
+        job.update(tokens=got["tokens"], logprobs=got["logprobs"])
+
+    for i in range(0, len(jobs), together):
+        threads = [threading.Thread(target=ask, args=(j,))
+                   for j in jobs[i:i + together]]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=600)
+    for job in jobs:
+        if "tokens" not in job or job.get("failure"):
+            raise RuntimeError(f"request {job['name']} failed: "
+                               f"{job.get('failure', 'no answer')}")
+
+
+def prime(url: str, port: int, mix: Dict, seed: int, seconds: float,
+          vocab: int) -> Dict:
+    """Stream every shared prefix of this run's plan once for one token (see
+    the module's docstring).  The plan is the one the client child derives:
+    same mix, seed, seconds and vocabulary."""
+    spec = (mix.get("shared_prefix") or {}).get("prime")
+    if not spec:
+        return {}
+    plan = traffic_mod.request_plan(mix, seed, seconds, vocab)
+    jobs = [{"name": f"prefix {i}", "prompt": ids}
+            for i, ids in enumerate(plan["prefixes"])]
+    t = time.monotonic()
+    _stream_all(port, jobs, 1, mix.get("sampling", {}), int(spec["together"]))
+    out = {"primed_prefixes": len(jobs),
+           "primed_tokens": sum(len(j["prompt"]) for j in jobs),
+           "prime_s": time.monotonic() - t}
+    print(f"benchmark: primed {out['primed_prefixes']} shared prefixes, "
+          f"{out['primed_tokens']} tokens, in {out['prime_s']:.2f} s", flush=True)
+    return out
+
+
+def prefix_hit_share(url: str, port: int, mix: Dict, run_: harness.Run,
+                     seed: int, vocab: int) -> Dict:
+    """Of the prefix tokens that the requests sent inside the window carried,
+    the share the prefix cache served inside the window.  Where that is
+    under the mix's ``min_hit_share`` each prefix is asked for once more,
+    alone, and those the cache no longer holds are printed."""
+    spec = (mix.get("shared_prefix") or {}).get("prime")
+    if not spec:
+        return {}
+    n_prefix = int(mix["shared_prefix"]["tokens"])
+    carried = sum(min(n_prefix, s["n_prompt"]) for s in run_.all_samples
+                  if s.get("prefix") is not None and s.get("sent_t") is not None
+                  and run_.t_open <= s["sent_t"] <= run_.t_close)
+    hit = run_.counters.get("mlt_engine_prefix_hit_tokens_total", 0.0)
+    share = hit / carried if carried else None
+    ok = share is not None and share >= float(spec["min_hit_share"])
+    out = {"prefix_tokens_carried": carried, "prefix_hit_share": share,
+           "min_hit_share": float(spec["min_hit_share"]), "prefixes_hit": ok}
+    if not ok:
+        prefixes = traffic_mod.request_plan(mix, seed, run_.seconds,
+                                            vocab)["prefixes"]
+        page = run_.engine["page_size"]
+        gone = []
+        for i, ids in enumerate(prefixes):
+            before = _hit_tokens(url)
+            _stream_all(port, [{"name": f"prefix {i}", "prompt": ids}], 1,
+                        mix.get("sampling", {}), 1)
+            if _hit_tokens(url) - before < (len(ids) - 1) // page * page:
+                gone.append(i)
+        out["prefixes_gone_after_window"] = gone
+        print(f"benchmark: the prefix cache served {hit:.0f} of the {carried} "
+              f"prefix tokens that the window's requests carried (the mix "
+              f"wants a share of {spec['min_hit_share']}); asked again one by "
+              f"one after the drain, the cache no longer held prefixes {gone} "
+              f"of {len(prefixes)}: the pool does not keep what this mix "
+              f"shares", flush=True)
+    return out
+
+
 def _sleep_until(t: float) -> None:
     d = t - time.monotonic()
     if d > 0:
         time.sleep(d)
 
 
-def run(cell, args, clock) -> harness.Run:
-    import jax
-    import numpy as np
+class Served:
+    """The system under test as one serving run holds it: weights, engine
+    and HTTP server on a local port, with the run's mix and vocabulary."""
 
-    from megatron_llm_tpu.config.arguments import parse_args
-    from megatron_llm_tpu.generation import ContinuousBatchingEngine
-    from megatron_llm_tpu.generation.server import MegatronServer
-    from megatron_llm_tpu.models import init_model_params
-    from megatron_llm_tpu.tokenizer import build_tokenizer
+    def __init__(self, cell, args):
+        import jax
+
+        from megatron_llm_tpu.config.arguments import parse_args
+        from megatron_llm_tpu.generation import ContinuousBatchingEngine
+        from megatron_llm_tpu.generation.server import MegatronServer
+        from megatron_llm_tpu.models import init_model_params
+        from megatron_llm_tpu.tokenizer import build_tokenizer
+
+        mix = dict(cell.traffic)
+        flags: Dict = {"seed": int(args.seed) % (2 ** 31 - 1)}
+        if args.rehearsal:
+            flags.update(cell.config.get("rehearsal", {}).get("flags", {}))
+            cell.model.update(cell.config.get("rehearsal", {}).get("model", {}))
+            mix.update(mix.get("rehearsal", {}))
+        if args.rate is not None:
+            mix["rate_per_s"] = args.rate
+        cfg = parse_args(cell.flags(flags))
+        tokenizer = build_tokenizer(cfg)
+
+        # as the server CLI: a mesh only when the layout asks for one
+        mesh = shardings = None
+        par = cfg.parallel
+        if par.tensor_model_parallel_size > 1 or par.pipeline_model_parallel_size > 1:
+            from megatron_llm_tpu.core.parallel_state import build_mesh, set_global_mesh
+            from megatron_llm_tpu.parallel.tp import param_shardings
+
+            mesh = build_mesh(
+                tensor_model_parallel_size=par.tensor_model_parallel_size,
+                pipeline_model_parallel_size=par.pipeline_model_parallel_size,
+                data_parallel_size=1)
+            set_global_mesh(mesh)
+            shardings = param_shardings(mesh, jax.eval_shape(
+                lambda k: init_model_params(cfg, k), jax.random.PRNGKey(0)))
+        key = jax.random.PRNGKey(cfg.training.seed)
+        self.mix, self.vocab = mix, cfg.model.vocab_size
+        self.params = init_weights(
+            cfg, key, cell.config.get("weights_dtype", "bfloat16"), shardings)
+        self.engine = ContinuousBatchingEngine(cfg, self.params, tokenizer, mesh=mesh)
+        self.server = MegatronServer(self.engine)
+        self.port = self.server.start_background("127.0.0.1", 0)
+        self.url = f"http://127.0.0.1:{self.port}"
+
+    def stream_probes(self, seed: int):
+        """The probes of lib/check.py streamed through the HTTP API, each
+        left with its ``tokens`` and ``logprobs``; and how many of their
+        prompt tokens the prefix cache served."""
+        mix = self.mix
+        probes = check_mod.serve_probes(
+            seed, self.vocab, tuple(mix.get("probe_lengths", (192, 256))),
+            self.engine.page_size)
+        hits_before = _hit_tokens(self.url)
+        for group, together in (
+                ([p for p in probes if p["after"] is None], len(probes)),
+                ([p for p in probes if p["after"] is not None], 1)):
+            _stream_all(self.port, group, check_mod.PROBE_TOKENS,
+                        mix.get("sampling", {}), together)
+        return probes, _hit_tokens(self.url) - hits_before
+
+
+def run(cell, args, clock) -> harness.Run:
+    import numpy as np
 
     run_ = harness.Run(cell, args, clock)
     run_.stamp_device()
     compiles = harness.CompileCounter()
-    mix = dict(cell.traffic)
-    flags: Dict = {"seed": int(args.seed) % (2 ** 31 - 1)}
-    if args.rehearsal:
-        flags.update(cell.config.get("rehearsal", {}).get("flags", {}))
-        cell.model.update(cell.config.get("rehearsal", {}).get("model", {}))
-        mix.update(mix.get("rehearsal", {}))
-    if args.rate is not None:
-        mix["rate_per_s"] = args.rate
-    cfg = parse_args(cell.flags(flags))
-    tokenizer = build_tokenizer(cfg)
-    vocab = cfg.model.vocab_size
-
-    # as the server CLI: a mesh only when the layout asks for one
-    mesh = shardings = None
-    par = cfg.parallel
-    if par.tensor_model_parallel_size > 1 or par.pipeline_model_parallel_size > 1:
-        from megatron_llm_tpu.core.parallel_state import build_mesh, set_global_mesh
-        from megatron_llm_tpu.parallel.tp import param_shardings
-
-        mesh = build_mesh(
-            tensor_model_parallel_size=par.tensor_model_parallel_size,
-            pipeline_model_parallel_size=par.pipeline_model_parallel_size,
-            data_parallel_size=1)
-        set_global_mesh(mesh)
-        shardings = param_shardings(mesh, jax.eval_shape(
-            lambda k: init_model_params(cfg, k), jax.random.PRNGKey(0)))
-    key = jax.random.PRNGKey(cfg.training.seed)
-    params = init_weights(cfg, key, cell.config.get("weights_dtype", "bfloat16"),
-                          shardings)
-
-    engine = ContinuousBatchingEngine(cfg, params, tokenizer, mesh=mesh)
+    served = Served(cell, args)
+    mix, vocab, params, engine = served.mix, served.vocab, served.params, served.engine
+    server, port, url = served.server, served.port, served.url
     run_.engine = {"max_slots": engine.max_slots, "page_size": engine.page_size,
                    "prefill_chunk": engine.prefill_chunk,
                    "max_seq": engine.max_seq}
-    server = MegatronServer(engine)
-    port = server.start_background("127.0.0.1", 0)
-    url = f"http://127.0.0.1:{port}"
     child: Optional[subprocess.Popen] = None
     prof = harness.Profiler(run_) if args.trace else None
     try:
-        sampling = mix.get("sampling", {})
-        probes = check_mod.serve_probes(
-            args.seed, vocab, tuple(mix.get("probe_lengths", (192, 256))),
-            engine.page_size)
-        hits_before = _hit_tokens(url)
-
-        def ask(probe):
-            s = client_mod.stream_request(
-                "127.0.0.1", port, probe["prompt"], check_mod.PROBE_TOKENS,
-                sampling, 600.0)
-            if s["status"] != 200 or s["error"]:
-                raise RuntimeError(f"probe {probe['name']} failed: "
-                                   f"{s['status']} {s['error']}")
-            probe.update(tokens=s["tokens"], logprobs=s["logprobs"])
-
-        together = [threading.Thread(target=ask, args=(p,))
-                    for p in probes if p["after"] is None]
-        for th in together:
-            th.start()
-        for th in together:
-            th.join(timeout=600)
-        for p in probes:
-            if p["after"] is not None:
-                ask(p)
-        probe_hits = _hit_tokens(url) - hits_before
+        probes, probe_hits = served.stream_probes(args.seed)
         ref = check_mod.serve_against_reference(cell, params, probes)
+        primed = prime(url, port, mix, args.seed, run_.seconds, vocab)
 
         out = harness.out_dir(cell, "client")
         samples_path = os.path.join(out, "samples.json")
@@ -210,6 +311,7 @@ def run(cell, args, clock) -> harness.Run:
             raise RuntimeError(f"the load generator exited {child.returncode}")
         with open(samples_path) as f:
             run_.all_samples = json.load(f)["samples"]
+        hits = prefix_hit_share(url, port, mix, run_, args.seed, vocab)
         health = json.loads(_get(url + "/health"))
     finally:
         if child is not None and child.poll() is None:
@@ -228,11 +330,13 @@ def run(cell, args, clock) -> harness.Run:
                    "engine_failures": health.get("engine_failures"),
                    "probe_prefix_hit_tokens": probe_hits if cached else None,
                    "prefix_hit_tokens": run_.counters.get(
-                       "mlt_engine_prefix_hit_tokens_total"), **ref}
+                       "mlt_engine_prefix_hit_tokens_total"),
+                   **primed, **hits, **ref}
     run_.correct = (run_.compiles_in_window == 0 and finite
                     and ref["reference_ok"] and run_.attempted > 0
                     and health.get("engine_failures") == 0
-                    and (probe_hits > 0 or not cached))
+                    and (probe_hits > 0 or not cached)
+                    and hits.get("prefixes_hit", True))
     if prof:
         prof.reduce()
     return run_

@@ -68,7 +68,13 @@ def request_plan(mix: Dict, seed: int, seconds: float, vocab: int) -> Dict:
     [[ids]...]}``.  A request is ``{"id", "due_s" (open loop: seconds
     after the ramp starts; closed loop: None), "prompt": [ids], "n_out",
     "prefix" (index or None)}``.  Open loop covers ramp + window; closed
-    loop is a list long enough that clients never run out."""
+    loop is a list long enough that clients never run out.
+
+    Two limits: ``prompt_len.max`` clamps the BODY a request draws, and
+    ``prompt_max`` cuts the whole prompt, shared prefix and body together
+    (a mix sets it to what its engine's ``engine_max_seq`` leaves beside
+    the longest output).  A mix without ``prompt_max`` cuts the whole
+    prompt at ``prompt_len.max`` too, as every mix did before the key."""
     base = random.Random(int(mix.get("draw_seed", 0)))
     order = random.Random(int(seed) & SEED_MASK)
     horizon = float(mix.get("ramp_s", 0.0)) + float(seconds)
@@ -105,7 +111,7 @@ def request_plan(mix: Dict, seed: int, seconds: float, vocab: int) -> Dict:
 
     prefixes = [_tokens(order, int(shared["tokens"]), vocab)
                 for _ in range(int(shared["count"]))] if shared else []
-    cap = int(mix["prompt_len"].get("max", 1 << 30))
+    cap = int(mix.get("prompt_max", mix["prompt_len"].get("max", 1 << 30)))
     requests = []
     for i, ((n_prompt, n_out, prefix), due) in enumerate(zip(sizes, dues)):
         body = _tokens(order, n_prompt, vocab)

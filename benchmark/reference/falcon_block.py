@@ -27,13 +27,22 @@ def block(layer: Dict, x, model: Dict):
     return x + attn + mlp
 
 
-def logits(params: Dict, tokens, model: Dict):
+def stack(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> the final norm's output [b, s, h] float32."""
     with jax.default_matmul_precision("highest"):
-        emb = params["embedding"]["word_embeddings"]
-        x = emb.astype(c.F32)[tokens]
+        x = params["embedding"]["word_embeddings"].astype(c.F32)[tokens]
         x = c.run_layers(block, params, x, model)
-        x = c.layer_norm(x, params["final_norm"]["scale"].astype(c.F32),
-                         params["final_norm"]["bias"].astype(c.F32),
-                         model["layer_norm_epsilon"])
-        out = jax.jit(lambda a, w: a @ w.astype(c.F32).T)(x, emb)
-    return out
+        return c.layer_norm(x, params["final_norm"]["scale"].astype(c.F32),
+                            params["final_norm"]["bias"].astype(c.F32),
+                            model["layer_norm_epsilon"])
+
+
+def head(params: Dict, hidden, model: Dict):
+    """hidden [..., h] -> logits [..., vocab]: the embedding, tied."""
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda a, w: a @ w.astype(c.F32).T)(
+            hidden, params["embedding"]["word_embeddings"])
+
+
+def logits(params: Dict, tokens, model: Dict):
+    return head(params, stack(params, tokens, model), model)

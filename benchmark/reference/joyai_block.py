@@ -65,10 +65,16 @@ def mla(p: Dict, x, model: Dict):
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, n, rd))], -1)
     v = kv[..., nope:]
-    scores = jnp.einsum("bqnd,bknd->bnqk", q, k) / jnp.sqrt(c.F32(nope + rd))
-    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    ctx = jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(b, s, n * vd)
+
+    def attend(qb, start):
+        size = qb.shape[1]
+        scores = jnp.einsum("bqnd,bknd->bnqk", qb, k) / jnp.sqrt(c.F32(nope + rd))
+        causal = c.causal_mask(start, size, s, None)
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        return jnp.einsum("bnqk,bknd->bqnd", probs, v).reshape(b, size, n * vd)
+
+    size = c.query_block(s, n)     # past a block of positions: a block at a time
+    ctx = attend(q, 0) if size >= s else c.in_query_blocks(attend, q, size)
     return ctx @ p["dense"]["kernel"]
 
 
@@ -141,18 +147,28 @@ def _run_stack(layers: Dict, x, model: Dict):
 
     step = jax.jit(step)
     for i in range(depth):
-        x = step(jax.tree.map(lambda a: a[i], layers), x)
+        # a layer's slice of the expert stacks is a 2.4 GB copy at the
+        # published widths: wait for the layer before the next is sliced, or
+        # the host runs ahead and several copies are live at once
+        x = jax.block_until_ready(step(jax.tree.map(lambda a: a[i], layers), x))
     return x
 
 
-def logits(params: Dict, tokens, model: Dict):
-    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+def stack(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> the final norm's output [b, s, h] float32."""
     with jax.default_matmul_precision("highest"):
         x = params["embedding"]["word_embeddings"].astype(c.F32)[tokens]
         x = _run_stack(params["dense_layers"], x, model)
         x = _run_stack(params["layers"], x, model)
-        x = c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
-                       model["rms_norm_eps"])
-        out = jax.jit(lambda a, w: a @ w.astype(c.F32))(
-            x, params["lm_head"]["kernel"])
-    return out
+        return c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
+                          model["rms_norm_eps"])
+
+
+def head(params: Dict, hidden, model: Dict):
+    """hidden [..., h] -> logits [..., vocab] float32: untied."""
+    return c.project(hidden, params["lm_head"]["kernel"])
+
+
+def logits(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+    return head(params, stack(params, tokens, model), model)

@@ -27,14 +27,22 @@ def block(layer: Dict, x, model: Dict):
     return x + (up * jax.nn.silu(gate)) @ layer["mlp"]["fc2"]["kernel"]
 
 
-def logits(params: Dict, tokens, model: Dict):
-    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+def stack(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> the final norm's output [b, s, h] float32."""
     with jax.default_matmul_precision("highest"):
         x = params["embedding"]["word_embeddings"].astype(c.F32)[tokens]
         x = c.run_layers(block, params, x, model)
-        x = c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
-                       model["rms_norm_eps"])
-        head = (params["embedding"]["word_embeddings"].T
-                if model.get("tie_word_embeddings") else params["lm_head"]["kernel"])
-        out = jax.jit(lambda a, w: a @ w.astype(c.F32))(x, head)
-    return out
+        return c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
+                          model["rms_norm_eps"])
+
+
+def head(params: Dict, hidden, model: Dict):
+    """hidden [..., h] -> logits [..., vocab] float32."""
+    return c.project(hidden, params["embedding"]["word_embeddings"].T
+                     if model.get("tie_word_embeddings")
+                     else params["lm_head"]["kernel"])
+
+
+def logits(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+    return head(params, stack(params, tokens, model), model)

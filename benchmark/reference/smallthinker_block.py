@@ -38,46 +38,21 @@ modelling code, none of which changes a result:
 * the family's "secondary experts" (on-device sparsity predictors) have no
   key in the config and take no part in the forward pass.
 
-Memory.  Attention runs a block of ``QUERY_BLOCK`` queries at a time and the
-experts a block of ``EXPERT_BLOCK`` at a time, so that thousands of positions
+Memory.  Attention runs a block of queries at a time (``common.causal_attention``)
+and the experts a block of ``EXPERT_BLOCK`` at a time, so that thousands of positions
 fit beside a training state; a layer is cast to float32 when it runs.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from benchmark.reference import common as c
 
-QUERY_BLOCK = 512
 EXPERT_BLOCK = 4
-
-
-def blocked_attention(q, k, v, window: Optional[int]):
-    """``common.causal_attention`` a block of queries at a time:
-    q [b, s, n, d], k / v [b, s, nkv, d] -> [b, s, n * d]."""
-    b, s, n, d = q.shape
-    nkv = k.shape[2]
-    size = QUERY_BLOCK if s % QUERY_BLOCK == 0 else s
-    kpos = jnp.arange(s)[None, :]
-
-    def one_block(start):
-        qb = jax.lax.dynamic_slice_in_dim(q, start, size, axis=1)
-        qb = qb.reshape(b, size, nkv, n // nkv, d)
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", qb, k) / jnp.sqrt(c.F32(d))
-        qpos = start + jnp.arange(size)[:, None]
-        ok = qpos >= kpos
-        if window:
-            ok &= (qpos - kpos) < window
-        p = jax.nn.softmax(jnp.where(ok[None, None, None], scores, -jnp.inf),
-                           axis=-1)
-        return jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(b, size, n * d)
-
-    out = jax.lax.map(one_block, jnp.arange(0, s, size))    # [blocks, b, ..]
-    return out.transpose(1, 0, 2, 3).reshape(b, s, n * d)
 
 
 def router_weights(logits, k: int):
@@ -126,7 +101,7 @@ def block(layer: Dict, x, model: Dict, place: int):
         q, k = c.rope(q, theta), c.rope(k, theta)
     window = (model["sliding_window_size"]
               if model["sliding_window_layout"][place] else None)
-    x1 = x + blocked_attention(q, k, v, window) @ layer["attention"]["dense"]["kernel"]
+    x1 = x + c.causal_attention(q, k, v, window) @ layer["attention"]["dense"]["kernel"]
     m = c.rms_norm(x1, layer["post_norm"]["scale"], eps)
     w = router_weights(r, model["moe_num_active_primary_experts"])
     first = int(model.get("first_held_expert", 0))
@@ -154,13 +129,20 @@ def run_layers(params: Dict, x, model: Dict):
     return x
 
 
-def logits(params: Dict, tokens, model: Dict):
-    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+def stack(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> the final norm's output [b, s, h] float32."""
     with jax.default_matmul_precision("highest"):
         x = params["embedding"]["word_embeddings"].astype(c.F32)[tokens]
         x = run_layers(params, x, model)
-        x = c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
-                       model["rms_norm_eps"])
-        out = jax.jit(lambda a, w: a @ w.astype(c.F32))(
-            x, params["lm_head"]["kernel"])
-    return out
+        return c.rms_norm(x, params["final_norm"]["scale"].astype(c.F32),
+                          model["rms_norm_eps"])
+
+
+def head(params: Dict, hidden, model: Dict):
+    """hidden [..., h] -> logits [..., vocab] float32: untied."""
+    return c.project(hidden, params["lm_head"]["kernel"])
+
+
+def logits(params: Dict, tokens, model: Dict):
+    """tokens [b, s] int32 -> logits [b, s, vocab] float32."""
+    return head(params, stack(params, tokens, model), model)

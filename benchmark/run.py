@@ -74,6 +74,9 @@ def main() -> int:
     runner = cells.load_kind(cell.traffic["kind"])
     result = runner.run(cell, args, clock)
     line = harness.result_line(cell, args, result)
+    for name, pair in line["compared"].items():     # the last lines of stderr
+        print(f"benchmark: compared {name} {pair['value']} limit {pair['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

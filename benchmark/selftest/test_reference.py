@@ -73,6 +73,29 @@ def test_compare_rule(workload):
     assert not check.compare(cell, a, b)["reference_ok"]
 
 
+def test_compare_takes_the_statistics_a_configuration_limits():
+    """SmallThinker limits the mean and the median and not the largest
+    difference (no upper reading: PERF.md section 4): the median alone
+    fails a run, one far-off token alone does not, and it is still read."""
+    from benchmark.lib import cells, check
+
+    cell = cells.Cell("smallthinker_train_16k")
+    tol = cell.config["tolerance"]
+    assert "max_abs_nats" not in tol and tol["median_abs_nats"] < tol["mean_abs_nats"]
+    a = np.linspace(-11, -10, 100)
+    shift = np.full(100, 1.1 * tol["median_abs_nats"])
+    shift[:20] = 0.0                      # mean 0.88 x its limit's median
+    assert shift.mean() < tol["mean_abs_nats"]
+    out = check.compare(cell, a, a + shift)
+    assert not out["reference_ok"]
+    assert out["reference_median_abs_diff"] == pytest.approx(1.1 * tol["median_abs_nats"])
+    b = a.copy()
+    b[3] += 0.9
+    out = check.compare(cell, a, b)
+    assert out["reference_ok"] and out["reference_max_abs_diff"] == pytest.approx(0.9)
+    assert not check.compare(cell, a, a + 1.1 * tol["mean_abs_nats"])["reference_ok"]
+
+
 def test_serving_probes_reach_the_prefix_cache():
     from benchmark.lib import check
 
