@@ -51,6 +51,9 @@ class ModelConfig:
     make_vocab_size_divisible_by: int = 128
     layernorm_epsilon: float = 1e-5
     use_rms_norm: bool = True
+    # LayerNorm's additive bias (use_rms_norm false); false = scale only,
+    # the Cohere block's norm
+    norm_bias: bool = True
     # GLU activation: None | 'swiglu' | 'geglu' | 'reglu' | 'liglu'
     glu_activation: Optional[str] = "swiglu"
     # plain activation when glu_activation is None: 'gelu' | 'relu' | 'squared_relu'
@@ -142,6 +145,10 @@ class ModelConfig:
     # shared experts: a dense MLP of moe_shared_experts x the expert width
     # that every token takes, added to the routed sum
     moe_shared_experts: int = 0
+    # how the shared experts' outputs join: 'sum' (the DeepSeek lineage) or
+    # 'average' (their sum over their number: Cohere's
+    # shared_expert_combination_strategy)
+    moe_shared_combination: str = "sum"
     # what the router reads: 'post_attention' (the norm before the expert
     # layer, Mixtral and the DeepSeek lineage) or 'layer_input' (the normed
     # input of the layer, what the attention reads too: SmallThinker's
@@ -580,6 +587,11 @@ class InferenceConfig:
     max_batch_slots: int = 8
     page_size: int = 16
     kv_pool_pages: Optional[int] = None
+    # pages of the WINDOW page class of a patterned model's pool (the
+    # layers that see `sliding_window_size` keys; kv_pool_pages then sizes
+    # the full-attention layers' class).  None = what every slot at its
+    # cap needs
+    kv_window_pool_pages: Optional[int] = None
     engine_max_seq: Optional[int] = None
     # quantized paged KV cache (ISSUE 13, ops/kv_quant.py): --kv_dtype
     # bf16|int8|fp8 picks the pool storage.  bf16 (default) is today's
@@ -753,6 +765,9 @@ class Config:
             assert self.model.moe_score_func in ("softmax", "sigmoid"), (
                 f"unknown moe_score_func {self.model.moe_score_func!r}"
             )
+            assert self.model.moe_shared_combination in ("sum", "average"), (
+                "unknown moe_shared_combination "
+                f"{self.model.moe_shared_combination!r}")
             assert self.model.moe_router_input in (
                 "post_attention", "layer_input"), (
                 f"unknown moe_router_input {self.model.moe_router_input!r}")
@@ -773,9 +788,6 @@ class Config:
                     "moe_experts_held is one chip's share of an "
                     "expert-parallel layer on the dropless dispatch: "
                     "expert_parallel_size 1 and moe_router_type 'topk'")
-                assert not m.moe_shared_experts, (
-                    "moe_experts_held with a shared expert: every chip "
-                    "would add the shared expert's output again")
             if self.model.moe_router_type == "expert_choice":
                 # EC routing compares tokens across positions within a
                 # routing group, leaking future-token information into the
@@ -801,7 +813,7 @@ class Config:
                 )
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
-                "mistral", "mixtral", "joyai", "smallthinker",
+                "mistral", "mixtral", "joyai", "smallthinker", "commanda",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -978,6 +990,33 @@ ARCH_DEFAULTS = {
         moe_score_func="softmax",
         moe_normalize_gates=True,
     ),
+    # Command A+ (beyond-reference; Cohere's cohere2_moe): a PARALLEL block
+    # (attention and experts both read one bias-free LayerNorm of the
+    # layer's input) of GQA attention and SwiGLU experts; three layers in
+    # four rotate (interleaved pairs) and see 4096 keys, the fourth sees
+    # all and carries no position signal; a sigmoid router with no
+    # selection bias whose chosen weights are normalised; four shared
+    # experts whose AVERAGE joins the routed sum; tied head
+    "commanda": dict(
+        use_rms_norm=False,
+        norm_bias=False,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=True,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-5,
+        rope_theta=50_000.0,
+        parallel_attn=True,
+        sliding_window_size=4096,
+        sliding_window_layout=(1, 1, 1, 0),
+        rope_layout=(1, 1, 1, 0),
+        moe_score_func="sigmoid",
+        moe_selection_bias=False,
+        moe_normalize_gates=True,
+        moe_routed_scaling_factor=1.0,
+        moe_shared_experts=4,
+        moe_shared_combination="average",
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1043,6 +1082,13 @@ MODEL_SIZES = {
                                  num_experts=64, moe_router_topk=6,
                                  moe_ffn_hidden_size=768,
                                  ffn_hidden_size=768, vocab_size=151936),
+    # 32 layers = 8 periods of (window, window, window, full NoPE)
+    "commanda-plus": dict(num_layers=32, hidden_size=4096,
+                          num_attention_heads=128, num_attention_heads_kv=8,
+                          kv_channels=128, max_position_embeddings=200000,
+                          num_experts=128, moe_router_topk=8,
+                          moe_ffn_hidden_size=4096, ffn_hidden_size=4096,
+                          vocab_size=262144),
 }
 
 

@@ -164,28 +164,63 @@ class EngineOverloaded(RuntimeError):
         self.info = info or {}
 
 
-def refuse_layer_pattern(cfg) -> None:
-    """The engine does not serve a patterned stack or a share of the experts
-    yet, and says so at start-up instead of running them wrong.  The tick's
-    layers would take their kinds from models/transformer.py as the trainer
-    does; what is not written is around them: the pool has ONE page class
-    (a window layer would keep every page of a sequence where
-    ceil(window / page) + 1 serve it), the prefix trie, preemption and the
-    handoff count pages without a layer class, and nothing here tests the
-    paged kernel under two masks in one tick."""
+def refuse_layer_pattern(cfg, *, kv_dtype: str = "bf16", mesh=None,
+                         draft: bool = False, pipeline_depth: int = 0,
+                         handoff: bool = False) -> None:
+    """A patterned stack (window and full layers mixed) is served on a pool
+    with one page class a cache need (models/transformer.py
+    ``pool_classes``: a window layer's class gives a page back once its
+    sequence's window has moved past it), and a share of the experts
+    (``moe_experts_held``) as the part of each layer's sum that the held
+    experts give.  What does not carry two page classes, or a share, yet
+    says so at start-up, in a sentence, instead of failing inside a
+    compile.  Chunked prefill, the prefix cache, copy-on-write and
+    preemption DO carry both."""
+    from megatron_llm_tpu.models.transformer import pool_classes
+
     m = cfg.model
-    if m.layer_period > 1:
+    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
+    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
+    if m.num_experts is not None and m.experts_held < m.num_experts and (
+            tp > 1 or pp > 1):
+        raise ValueError(
+            f"moe_experts_held {m.moe_experts_held} of {m.num_experts} is "
+            "one chip's share of an expert-parallel layer, its attention "
+            f"data-parallel: serve it on one chip, not on a tp {tp} x pp "
+            f"{pp} mesh (the other chips' experts and the exchange with "
+            "them are not here)")
+    classes = pool_classes(cfg)
+    if len(classes) == 1:
+        return
+    why = None
+    if len(classes) > 2 or classes[0].window is not None or m.mla or (
+            m.dense_prefix_layers):
+        why = ("this pattern (more than one window size, no full layer, "
+               "latent attention or a dense prefix): the pool knows a full "
+               "class and ONE window class of K/V rows over one stack")
+    elif kv_dtype != "bf16":
+        why = (f"--kv_dtype {kv_dtype}: a page's scales are set by the "
+               "page's first write, and no test holds them through a "
+               "window class's release and re-grant")
+    elif tp > 1 or pp > 1:
+        why = (f"tensor- or pipeline-parallel serving (tp {tp}, pp {pp}): "
+               "the pool's shardings and the stage pipeline name one leaf")
+    elif draft:
+        why = ("--spec_k: the verify tick and the draft cache are built "
+               "for one block table a sequence")
+    elif pipeline_depth:
+        why = ("--tick_pipeline_depth: the chained tick grants pages ahead "
+               "for one block table a sequence and never slides a window")
+    elif handoff:
+        why = ("the cross-replica KV handoff: its wire format names one "
+               "page list a sequence")
+    if why:
         raise ValueError(
             f"a layer pattern (sliding_window_layout {m.sliding_window_layout}"
-            f", rope_layout {m.rope_layout}) is not served by the "
-            "continuous-batching engine yet: its paged pool has one page "
-            "class for every layer.  Train it (finetune.py) or run the dense "
-            "forward; serving it is ROADMAP R3b")
-    if m.num_experts is not None and m.experts_held < m.num_experts:
-        raise ValueError(
-            f"moe_experts_held {m.moe_experts_held} of {m.num_experts}: a "
-            "share of an expert-parallel layer is not a model to serve; the "
-            "other chips' experts and their exchange are not here")
+            ") keeps its window layers' and its full layers' keys in two "
+            f"page classes, which {why} does not carry yet. Serve this "
+            "model on one chip with --kv_dtype bf16, --spec_k 0 and "
+            "--tick_pipeline_depth 0.")
 
 
 def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
@@ -270,8 +305,15 @@ class PagedKVPool:
 
     def __init__(self, cfg, num_pages: int, page_size: int, dtype=None,
                  mesh: Optional[Mesh] = None, draft_cfg=None,
-                 kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16", layers: Optional[int] = None,
+                 page_class: Optional[str] = None):
         m = cfg.model
+        # one page class of a patterned model's pool (``page_class`` names
+        # it in the counters' ``class=`` label; ``layers``: how many of the
+        # model's layers keep their keys here).  None: the one pool of a
+        # uniform model, every layer's, its counters unlabelled as ever
+        self.page_class = page_class
+        layers = m.depth if layers is None else layers
         dtype = dtype or _compute_dtype(cfg)
         assert kv_dtype in kv_quant.KV_DTYPES, (
             f"kv_dtype must be one of {kv_quant.KV_DTYPES}, got {kv_dtype!r}")
@@ -298,7 +340,7 @@ class PagedKVPool:
         else:
             self.head_dim = m.kv_channels
             kv = kv_quant.make_kv_pool(
-                m.depth, num_pages, page_size, m.num_attention_heads_kv,
+                layers, num_pages, page_size, m.num_attention_heads_kv,
                 self.head_dim, kv_dtype, dtype)
 
         # Tensor parallelism shards the pool's row over its KV heads (each
@@ -382,13 +424,27 @@ class PagedKVPool:
         # evictable pages is a read since the count is kept; its series
         # stays exported, at 0, for the readers that sum both
         reg = obs_registry.get_registry()
+        cls = {} if page_class is None else {"class": page_class}
         self._m_alloc = {
             src: reg.counter(
                 "mlt_engine_pool_alloc_pages_total",
                 help="KV pool pages granted: free = off the free list, "
                      "evict = a cached-idle page the prefix cache had to "
-                     "give up first (the pool had run dry)",
-                labels={"source": src}) for src in ("free", "evict")}
+                     "give up first (the pool had run dry); a patterned "
+                     "model's series carry class= (full, window)",
+                labels={**cls, "source": src}) for src in ("free", "evict")}
+        # the three states a page is in (the null page in none), set by
+        # the engine where a tick is applied
+        self._m_pages = {
+            state: reg.gauge(
+                "mlt_engine_pool_pages",
+                help="pool pages by state: referenced (a live sequence's "
+                     "table names it), cached_idle (only the prefix cache "
+                     "does), free; class = the page class (full: every "
+                     "key kept, the one class of a uniform model; window: "
+                     "a patterned model's window layers')",
+                labels={"class": page_class or "full", "state": state})
+            for state in ("referenced", "cached_idle", "free")}
         self._m_scan = {
             what: reg.counter(
                 "mlt_engine_pool_scan_seconds_total",
@@ -479,6 +535,14 @@ class PagedKVPool:
     def num_free(self) -> int:
         return len(self._free)
 
+    def publish_states(self) -> None:
+        """The ``mlt_engine_pool_pages{class=,state=}`` gauges: reads of
+        kept counts."""
+        free, idle = len(self._free), self._idle_cached
+        self._m_pages["free"].set(free)
+        self._m_pages["cached_idle"].set(idle)
+        self._m_pages["referenced"].set(self.num_pages - 1 - free - idle)
+
     @property
     def num_evictable(self) -> int:
         """Cached pages no request references — reclaimable on demand.
@@ -546,6 +610,11 @@ class PagedKVPool:
             if freed:
                 self.reclaimed = True
             return len(freed)
+
+    def free_evicted(self, pages: Sequence[int]) -> None:
+        """Pages the prefix cache gave up outside this pool's own
+        ``_reclaim`` (a node evicted for the other class) go free."""
+        self._free.extend(pages)
 
     def incref(self, pages: Sequence[int]) -> None:
         for p in pages:
@@ -642,11 +711,16 @@ class PagedKVPool:
 
 
 class _TrieNode:
-    __slots__ = ("key", "page", "parent", "children", "last_use")
+    __slots__ = ("key", "page", "wpage", "parent", "children", "last_use",
+                 "depth")
 
     def __init__(self, key, page, parent):
         self.key = key
         self.page = page
+        self.depth = 0 if parent is None else parent.depth + 1
+        # the block's page in the window class of a patterned model's pool
+        # (NULL_PAGE: none, or evicted while the full class's page stays)
+        self.wpage = NULL_PAGE
         self.parent = parent
         self.children: Dict[Tuple[int, ...], "_TrieNode"] = {}
         self.last_use = 0
@@ -684,10 +758,30 @@ class PrefixCache:
     never runs dry never pops: the heap is rebuilt from the trie when it
     outgrows ``2 * len(self) + 64`` entries, which keeps it O(nodes) at
     an amortised constant a push.
+
+    **Two page classes** (``wpool``: a patterned model's window class, its
+    layers seeing ``window`` keys).  A node names its block's page in each
+    class, and the window class's may be gone while the full class's stays:
+    a sequence gives a window page back once its window has moved past it
+    (registered ones go cached-idle in their class), and the window pool
+    evicts its idle pages in use order WHATEVER their place in the trie
+    (``evict_window``: a heap of its own, the shallower of two pages of one
+    stamp first, since a match needs the pages before its end).  A window
+    page referenced means its node's full page referenced (a sequence
+    holds every full page of its context), so evicting a node frees both.
+    ``match_classes`` returns the longest page-aligned length whose full
+    pages are all present AND whose window pages cover the ``window`` keys
+    before its end; a longer match that the window class cannot serve is
+    shortened to that (recomputing a window layer's keys needs the layers
+    below at those positions, so a gap cannot be filled in).
     """
 
-    def __init__(self, pool: PagedKVPool, page_size: int):
+    def __init__(self, pool: PagedKVPool, page_size: int,
+                 wpool: Optional[PagedKVPool] = None,
+                 window: Optional[int] = None):
         self.pool = pool
+        self.wpool = wpool
+        self.window = window
         self.page_size = page_size
         self.root = _TrieNode(None, NULL_PAGE, None)
         self._nodes: Dict[int, _TrieNode] = {}  # page id -> node
@@ -699,6 +793,24 @@ class PrefixCache:
         pool.evict_hook = self.evict
         pool.idle_hook = self.note_idle
         reg = obs_registry.get_registry()
+        if wpool is not None:
+            self._wnodes: Dict[int, _TrieNode] = {}  # window page -> node
+            # (last_use, depth, entry number, node)
+            self._widle: List[Tuple[int, int, int, _TrieNode]] = []
+            wpool.evict_hook = self.evict_window
+            wpool.idle_hook = self.note_widle
+            self._m_wevicted = reg.counter(
+                "mlt_engine_prefix_window_evicted_pages_total",
+                help="cached-idle pages of the WINDOW class the prefix "
+                     "cache gave up to a grant (their nodes keep the full "
+                     "class's page); equals mlt_engine_pool_alloc_pages_"
+                     "total{class=\"window\",source=\"evict\"}")
+            self._m_shortened = reg.counter(
+                "mlt_engine_prefix_match_shortened_total",
+                help="prefix matches cut short of the full class's pages "
+                     "because the window class no longer held the pages "
+                     "before the match's end",
+                labels={"by": "window"})
         self._m_evicted = reg.counter(
             "mlt_engine_prefix_evicted_pages_total",
             help="cached-idle pages the prefix cache gave up to a grant")
@@ -736,12 +848,51 @@ class PrefixCache:
         self.pool.incref(pages)
         return pages
 
+    def window_first(self, n_pages: int) -> int:
+        """The first block whose window-class page a sequence still needs
+        once ``n_pages`` whole pages of it are cached: the one holding the
+        oldest key the query at the last cached position can see."""
+        return max(0, n_pages * self.page_size - self.window) // self.page_size
+
+    def match_classes(self, tokens: Sequence[int], max_pages: int
+                      ) -> Tuple[List[int], List[int]]:
+        """``match`` for a pool with a window class: (full-class pages,
+        window-class pages), one pool ref each, the second list as long as
+        the first with ``NULL_PAGE`` for the blocks the window has left
+        behind (a sequence's window table keeps the block's place)."""
+        self._clock += 1
+        node, path, run, runs = self.root, [], 0, []
+        for i in range(max_pages):
+            child = node.children.get(self._key(tokens, i))
+            if child is None:
+                break
+            run = run + 1 if child.wpage != NULL_PAGE else 0
+            path.append(child)
+            runs.append(run)
+            node = child
+        m = len(path)
+        while m and runs[m - 1] < m - self.window_first(m):
+            m -= 1
+        if m < len(path) and obs_registry.publishing():
+            self._m_shortened.inc()
+        first = self.window_first(m)
+        for nd in path[:m]:
+            nd.last_use = self._clock
+        pages = [nd.page for nd in path[:m]]
+        wpages = [nd.wpage for nd in path[first:m]]
+        self.pool.incref(pages)
+        self.wpool.incref(wpages)
+        return pages, [NULL_PAGE] * first + wpages
+
     def insert(self, tokens: Sequence[int], pages: Sequence[int],
-               n_pages: int) -> int:
+               n_pages: int, wpages: Optional[Sequence[int]] = None) -> int:
         """Register the first ``n_pages`` full pages of a prefilled prompt;
         pages already cached at a position keep the incumbent (the
-        request's duplicate page simply stays private).  Returns the number
-        of pages newly cached."""
+        request's duplicate page simply stays private).  ``wpages``: the
+        same blocks' window-class pages (``NULL_PAGE`` where the sequence
+        gave one back); a node without one takes the sequence's where the
+        sequence holds the node's full page too, or can replace it.
+        Returns the number of pages newly cached."""
         self._clock += 1
         node, added = self.root, 0
         for i in range(n_pages):
@@ -756,6 +907,29 @@ class PrefixCache:
                 self._nodes[p] = child
                 self.pool.set_cached(p, True)
                 added += 1
+            if wpages is not None and child.wpage == NULL_PAGE and (
+                    i < len(wpages) and wpages[i] != NULL_PAGE
+                    and wpages[i] not in self._wnodes):
+                if child.page != pages[i]:
+                    # an incumbent that lost its window page: a prefix
+                    # nobody can match past.  Where nobody holds its full
+                    # page either, the sequence's own pair of pages takes
+                    # its place (a window page referenced means its node's
+                    # full page referenced); else the pair stays private
+                    if (self.pool.refcounts[child.page] != 0
+                            or pages[i] in self._nodes):
+                        child.last_use = self._clock
+                        node = child
+                        continue
+                    del self._nodes[child.page]
+                    self.pool.set_cached(child.page, False)
+                    self.pool.free_evicted([child.page])
+                    child.page = pages[i]
+                    self._nodes[child.page] = child
+                    self.pool.set_cached(child.page, True)
+                child.wpage = wpages[i]
+                self._wnodes[child.wpage] = child
+                self.wpool.set_cached(child.wpage, True)
             child.last_use = self._clock
             node = child
         # the walk's end is the one node it may leave an idle leaf under a
@@ -809,6 +983,11 @@ class PrefixCache:
             del nodes[victim.page]
             self.pool.set_cached(victim.page, False)
             freed.append(victim.page)
+            if victim.wpage != NULL_PAGE:
+                # idle too: whoever held it held the full page
+                self._drop_wpage(victim)
+                self.wpool.free_evicted([victim.wpage])
+                victim.wpage = NULL_PAGE
             if parent is not self.root:
                 self._push_if_idle_leaf(parent)
         # what the call did, as one zero-length event inside the pool's
@@ -819,6 +998,58 @@ class PrefixCache:
         if obs_registry.publishing():
             self._m_evicted.inc(len(freed))
             self._m_scanned.inc(scanned)
+        return freed
+
+    # ---- the window class ----
+
+    def _drop_wpage(self, node: _TrieNode) -> None:
+        assert self.wpool.refcounts[node.wpage] == 0
+        del self._wnodes[node.wpage]
+        self.wpool.set_cached(node.wpage, False)
+
+    def note_widle(self, pages: Sequence[int]) -> None:
+        """The window pool's ``idle_hook``: a release (a window that moved
+        on, a retirement) left ``pages`` cached at refcount 0; every one
+        is evictable, a leaf or not."""
+        for p in pages:
+            node = self._wnodes[p]
+            heapq.heappush(self._widle, (node.last_use, node.depth,
+                                         next(self._entry), node))
+        if len(self._widle) > 2 * len(self._wnodes) + 64:
+            self._widle[:] = [
+                (n.last_use, n.depth, next(self._entry), n)
+                for p, n in self._wnodes.items()
+                if self.wpool.refcounts[p] == 0]
+            heapq.heapify(self._widle)
+
+    def evict_window(self, n: int) -> List[int]:
+        """Reclaim up to ``n`` cached-idle pages of the window class, least
+        recently used first; their nodes stay, with the full class's page.
+        An entry whose node was stamped since (a match that took the full
+        page and no longer needed this one) goes back under its new stamp;
+        one whose page is gone or referenced is dropped."""
+        freed: List[int] = []
+        scanned = 0
+        idle, refcounts = self._widle, self.wpool.refcounts
+        while len(freed) < n and idle:
+            last_use, _, _, node = heapq.heappop(idle)
+            scanned += 1
+            wp = node.wpage
+            if (wp == NULL_PAGE or self._wnodes.get(wp) is not node
+                    or refcounts[wp] != 0):
+                continue
+            if node.last_use != last_use:
+                heapq.heappush(idle, (node.last_use, node.depth,
+                                      next(self._entry), node))
+                continue
+            self._drop_wpage(node)
+            node.wpage = NULL_PAGE
+            freed.append(wp)
+        with obs_trace.span("pool-evict", evicted=len(freed),
+                            scanned=scanned, page_class="window"):
+            pass
+        if obs_registry.publishing():
+            self._m_wevicted.inc(len(freed))
         return freed
 
 
@@ -864,6 +1095,16 @@ class EngineRequest:
     _done: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False)
     _pages: List[int] = dataclasses.field(default_factory=list, repr=False)
+    # a patterned model's WINDOW page class: the block's page by its place
+    # (as ``_pages``), NULL_PAGE where the window has moved past it
+    # (``_wfirst`` blocks so far); blocks below ``_wkeep`` are the prefix
+    # cache's, the rest this request's own, ``_wprivate`` of them live and
+    # never more than ``_wmax`` (what the ledger holds for it)
+    _wpages: List[int] = dataclasses.field(default_factory=list, repr=False)
+    _wfirst: int = dataclasses.field(default=0, repr=False)
+    _wkeep: int = dataclasses.field(default=0, repr=False)
+    _wprivate: int = dataclasses.field(default=0, repr=False)
+    _wmax: int = dataclasses.field(default=0, repr=False)
     _step: int = 0  # decode ticks taken (== len(generated))
     # scheduler state: queued -> prefill -> decode -> finished
     _phase: str = dataclasses.field(default="queued", repr=False)
@@ -975,12 +1216,16 @@ class ContinuousBatchingEngine:
                  mesh: Optional[Mesh] = None):
         inf = cfg.inference
         self.cfg = cfg
-        refuse_layer_pattern(cfg)
+        pick = lambda given, name: (  # noqa: E731
+            given if given is not None else getattr(inf, name))
+        refuse_layer_pattern(
+            cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
+            draft=bool(pick(spec_k, "spec_k")),
+            pipeline_depth=int(pick(tick_pipeline_depth,
+                                    "tick_pipeline_depth")))
         if cfg.model.mla:
             # before anything is placed or resolved: a sentence, not a
             # sharding error from the middle of start-up
-            pick = lambda given, name: (  # noqa: E731
-                given if given is not None else getattr(inf, name))
             refuse_latent_cache(
                 kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
                 draft=bool(pick(spec_k, "spec_k")),
@@ -1165,10 +1410,40 @@ class ContinuousBatchingEngine:
             assert self.draft_cfg.model.num_layers % self._pp == 0, (
                 f"draft num_layers {self.draft_cfg.model.num_layers} "
                 f"not divisible by pp {self._pp}")
-        self.pool = PagedKVPool(cfg, num_pages, self.page_size, mesh=mesh,
-                                draft_cfg=self.draft_cfg,
-                                kv_dtype=self.kv_dtype)
-        self.cache = (PrefixCache(self.pool, self.page_size)
+        # page classes (models/transformer.py ``pool_classes``): a uniform
+        # model has ONE, today's pool, tables and tick; a patterned model
+        # a full class (``pool``) and a window class (``wpool``), each with
+        # its own leaf, free list, reference counts and block tables
+        from megatron_llm_tpu.models.transformer import pool_classes
+
+        classes = pool_classes(cfg)
+        self.wpool: Optional[PagedKVPool] = None
+        self._window = 0
+        if len(classes) == 2:
+            periods = cfg.model.num_layers // cfg.model.layer_period
+            full, win = classes
+            self._window = int(win.window)
+            # pages of the window class one sequence holds at most: the
+            # window's own (ceil(window / page), + 1 where it starts inside
+            # a page), the one its next position opens, and while it
+            # prefills the pages of one tick's rows of it
+            self.window_pages_cap = (-(-self._window // self.page_size) + 2
+                                     + self.prefill_rows // self.page_size)
+            self.pool = PagedKVPool(
+                cfg, num_pages, self.page_size, kv_dtype=self.kv_dtype,
+                layers=periods * len(full.places), page_class=full.name)
+            self.wpool = PagedKVPool(
+                cfg, (inf.kv_window_pool_pages
+                      or self.max_slots * min(self.pages_per_seq,
+                                              self.window_pages_cap) + 1),
+                self.page_size, kv_dtype=self.kv_dtype,
+                layers=periods * len(win.places), page_class=win.name)
+        else:
+            self.pool = PagedKVPool(cfg, num_pages, self.page_size,
+                                    mesh=mesh, draft_cfg=self.draft_cfg,
+                                    kv_dtype=self.kv_dtype)
+        self.cache = (PrefixCache(self.pool, self.page_size, self.wpool,
+                                  self._window or None)
                       if use_cache else None)
 
         # host-side slot state + scheduler queues: every attribute marked
@@ -1179,6 +1454,10 @@ class ContinuousBatchingEngine:
         s = self.max_slots
         # guarded by _lock
         self._block_tables = np.zeros((s, self.pages_per_seq), np.int32)
+        # the window class's tables, a block at its own place as above and
+        # NULL_PAGE behind a sequence's first live page — guarded by _lock
+        self._wtables = (np.zeros((s, self.pages_per_seq), np.int32)
+                         if self.wpool is not None else None)
         self._positions = np.zeros((s,), np.int32)    # guarded by _lock
         self._tokens = np.zeros((s,), np.int32)       # guarded by _lock
         self._temperature = np.ones((s,), np.float32)  # guarded by _lock
@@ -1197,6 +1476,10 @@ class ContinuousBatchingEngine:
         # free + evictable >= committed (+ watermark) so decode-time allocs
         # can never deadlock an in-flight slot — guarded by _lock
         self._committed = 0
+        # the same ledger for the window class: what admitted requests may
+        # still take of it beyond the pages of their own they hold
+        self._wcommitted = 0  # guarded by _lock
+        self.window_pages_released = 0
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         # serializes device-driving (step) across caller threads; state
@@ -1260,6 +1543,9 @@ class ContinuousBatchingEngine:
         # expert layers (mlt_engine_moe_*; zero for dense models)
         self.moe_assignments = 0
         self.moe_experts_touched = 0
+        # ... and of those, what fell to the experts this chip holds
+        self.moe_held_assignments = 0
+        self.moe_held_experts_touched = 0
         self.prefix_hit_tokens = 0
         self.prefix_miss_tokens = 0
         self.cow_copies = 0
@@ -1473,7 +1759,43 @@ class ContinuousBatchingEngine:
             help="ragged ticks during whose admit or plan a page grant "
                  "had to evict (the pool's free list had run dry); over "
                  "mlt_engine_ticks_total the share of ticks that paid "
-                 "for an eviction")
+                 "for an eviction. A patterned model also counts them by "
+                 "page class (class=)")
+        self._pools = [self.pool] + (
+            [self.wpool] if self.wpool is not None else [])
+        self._m_dry_class = {
+            pl.page_class: reg.counter(
+                "mlt_engine_pool_dry_ticks_total",
+                help="ragged ticks during whose admit or plan a page grant "
+                     "of this page class had to evict",
+                labels={"class": pl.page_class})
+            for pl in self._pools if pl.page_class is not None}
+        self._m_seq_pages = {
+            (pl.page_class or "full"): reg.counter(
+                "mlt_engine_seq_pages_sum",
+                help="pages of this class that the decoding sequences "
+                     "held, summed over sequences and applied ticks; over "
+                     "mlt_engine_seq_ticks_total the mean pages a live "
+                     "sequence holds, whole window",
+                labels={"class": pl.page_class or "full"})
+            for pl in self._pools}
+        self._m_seq_ticks = reg.counter(
+            "mlt_engine_seq_ticks_total",
+            help="decoding sequences summed over applied ticks (the "
+                 "divisor of mlt_engine_seq_pages_sum)")
+        self._m_slid = reg.counter(
+            "mlt_engine_window_pages_released_total",
+            help="window-class pages a LIVE sequence gave back because "
+                 "its window moved past them (0 for a uniform model)")
+        self._m_moe_held = reg.counter(
+            "mlt_engine_moe_held_assignments_total",
+            help="router assignments whose expert this chip holds and "
+                 "ran (moe_experts_held; all of them where every expert "
+                 "is held)")
+        self._m_moe_held_touched = reg.counter(
+            "mlt_engine_moe_held_experts_touched_total",
+            help="distinct HELD experts that received a row, summed over "
+                 "ticks and expert layers")
         self._m_apply_lag = {
             lag: reg.counter(
                 "mlt_engine_tick_apply_lag_total",
@@ -1637,6 +1959,29 @@ class ContinuousBatchingEngine:
                               stages=self._pp, tp=self._tp)
 
     @property
+    def _class_statics(self) -> Tuple:
+        """Nothing for a uniform model (its programs' keys stay as they
+        were); a patterned model's window class's geometry."""
+        if self.wpool is None:
+            return ()
+        return ("window_class", self.wpool.num_pages, self._window)
+
+    @property
+    def _kv(self):
+        """What a tick program takes and returns as ``pool_kv``: the
+        pool's leaf, or for a patterned model the (full, window) pair."""
+        if self.wpool is None:
+            return self.pool.kv
+        return (self.pool.kv, self.wpool.kv)
+
+    @_kv.setter
+    def _kv(self, kv) -> None:
+        if self.wpool is None:
+            self.pool.kv = kv
+        else:
+            self.pool.kv, self.wpool.kv = kv
+
+    @property
     def _mesh_statics(self) -> Tuple:
         """Compiled-program cache key extension: engines on different mesh
         layouts must not share executables (gen.cached_jit is process-wide).
@@ -1694,7 +2039,7 @@ class ContinuousBatchingEngine:
                        self.pages_per_seq, self.page_size,
                        self.pool.num_pages, self.pool.kv_statics,
                        0, pre_rows, self._pre_tables_cap,
-                       self._mesh_statics)
+                       self._mesh_statics) + self._class_statics
             fn = gen.cached_jit(
                 self.cfg, "engine_ragged_tick", statics,
                 lambda: make_ragged_tick_fn(
@@ -1845,6 +2190,11 @@ class ContinuousBatchingEngine:
         if len(prompt) + max_new_tokens > self.max_seq:
             raise gen.InvalidRequest(
                 "Length of prompt + tokens_to_generate longer than allowed")
+        if self.wpool is not None and kw.get("return_log_probs"):
+            raise gen.InvalidRequest(
+                "return_log_probs (prompt scoring) is not served for a "
+                "layer pattern: the scoring chunk walks one block table a "
+                "sequence, and this model's pool has two page classes")
         req = EngineRequest(prompt=prompt, max_new_tokens=max_new_tokens, **kw)
         req._t_submit = time.monotonic()
         # flight record + enqueue event (observability/flight.py): a
@@ -2111,24 +2461,17 @@ class ContinuousBatchingEngine:
             # every page fully covered by seq[:-1] is finished K/V the
             # resume's refeed tick will never write — safe to share
             self.cache.insert(seq, victim._pages,
-                              (len(seq) - 1) // self.page_size)
-        self._slots[slot] = None
-        self._block_tables[slot] = NULL_PAGE
-        self._positions[slot] = 0
-        self._tokens[slot] = 0
-        self._top_k[slot] = 1
-        self._top_p[slot] = 0.0
-        self._temperature[slot] = 1.0
-        pages, victim._pages = victim._pages, []
-        self._committed -= max(0, victim._max_pages - len(pages))
-        self.pool.release(pages)
+                              (len(seq) - 1) // self.page_size,
+                              victim._wpages or None)
+        self._clear_slot_locked(slot)
+        pages = self._release_pages_locked(victim)
         victim._phase = "queued"
         victim._slot = -1
         victim._fill_pos = 0
         victim._preemptions += 1
         victim._flight.note_preemption()
         victim._flight.set_phase("preempted", step=victim._step,
-                                 pages_released=len(pages))
+                                 pages_released=pages)
         self.preemptions += 1
         self._queue.append(victim)  # position is policy-ordered anyway
         if obs_registry.publishing():
@@ -2205,11 +2548,17 @@ class ContinuousBatchingEngine:
         prompt_len = len(seq)
         max_total = self._max_pages_for(req)
         matched: List[int] = []
+        wmatched: List[int] = []
+        classed = self.wpool is not None
         if self.cache is not None and not req.return_log_probs:
             # log-prob requests recompute the whole prompt (the teacher-
             # forced scores need every position's logits), so they take no
             # shared pages — their pages still feed the cache afterwards
-            matched = self.cache.match(seq, prompt_len // ps)
+            if classed:
+                matched, wmatched = self.cache.match_classes(
+                    seq, prompt_len // ps)
+            else:
+                matched = self.cache.match(seq, prompt_len // ps)
         covered = len(matched) * ps
         # full page-aligned match: the first tick re-feeds the last prompt
         # token and would WRITE the final shared page -> copy-on-write
@@ -2221,15 +2570,38 @@ class ContinuousBatchingEngine:
         extra = 1 if max_total > held_core else 0  # first decode page
         need_now = (1 if cow else 0) + suffix_pages + extra
         remaining = max_total - held_core - extra
-        if (self.pool.num_available - need_now
-                < self._committed + remaining + self.page_watermark):
+        # the window class: the copy-on-write page now, the prompt's pages
+        # as its chunks are planned, a decode page as a row crosses into
+        # it; never more of its own than the cap, nor than its blocks
+        # behind the shared ones.  A grant that one class refuses is
+        # refused whole
+        wneed = 1 if classed and cow else 0
+        wmax = min(self.window_pages_cap, max_total - n_keep) if classed else 0
+
+        def undo():
             self.pool.release(matched)
+            if classed:
+                self.wpool.release([p for p in wmatched if p != NULL_PAGE])
+
+        if (self.pool.num_available - need_now
+                < self._committed + remaining + self.page_watermark) or (
+                classed and self.wpool.num_available - wneed
+                < self._wcommitted + wmax - wneed + self.page_watermark):
+            undo()
             return None
         fresh = self.pool.alloc(need_now)
-        if fresh is None:  # unreachable given the check; stay safe
-            self.pool.release(matched)
+        wfresh = self.wpool.alloc(wneed) if classed else []
+        if fresh is None or wfresh is None:  # unreachable given the check
+            self.pool.release(fresh or [])
+            undo()
             return None
         self._committed += remaining
+        if classed:
+            self._wcommitted += wmax - wneed
+            req._wpages = wmatched + wfresh
+            req._wfirst = self.cache.window_first(len(matched)) \
+                if matched else 0
+            req._wkeep, req._wprivate, req._wmax = n_keep, wneed, wmax
         # every ref this request owns lives in _pages from here on, so any
         # failure path releases exactly the right set; the COW page swap
         # reorders the list after the device copy lands
@@ -2249,7 +2621,7 @@ class ContinuousBatchingEngine:
             self._m_hit_tokens.inc(covered)
             self._m_miss_tokens.inc(prompt_len - covered)
         return {"matched": matched, "fresh": fresh, "cow": cow,
-                "n_keep": n_keep}
+                "n_keep": n_keep, "wmatched": wmatched, "wfresh": wfresh}
 
     def _place_chunked(self, req: EngineRequest, plan: dict) -> None:
         matched, fresh = plan["matched"], plan["fresh"]
@@ -2267,12 +2639,20 @@ class ContinuousBatchingEngine:
                 self.pool.kv = self._copy_page()(
                     self.pool.kv, self._asarray(np.int32(src)),
                     self._asarray(np.int32(dst)))
+            if self.wpool is not None:
+                self.wpool.kv = self._copy_page()(
+                    self.wpool.kv,
+                    self._asarray(np.int32(plan["wmatched"][-1])),
+                    self._asarray(np.int32(plan["wfresh"][0])))
         with self._lock:
             if cow:
                 # block-table order: kept shared pages, the private COW
                 # copy, then the first decode page
                 req._pages = matched[:n_keep] + [fresh[0]] + fresh[1:]
                 self.pool.release([matched[-1]])
+                if self.wpool is not None:
+                    req._wpages = plan["wmatched"][:n_keep] + plan["wfresh"]
+                    self.wpool.release([plan["wmatched"][-1]])
                 self.cow_copies += 1
                 if obs_registry.publishing():
                     self._m_cow.inc()
@@ -2303,6 +2683,10 @@ class ContinuousBatchingEngine:
         bt = np.full((self.pages_per_seq,), NULL_PAGE, np.int32)
         bt[: len(req._pages)] = req._pages
         self._block_tables[slot] = bt
+        if self.wpool is not None:
+            self._slide_locked(req, len(seq) - 1)
+            self._wtables[slot] = NULL_PAGE
+            self._wtables[slot][: len(req._wpages)] = req._wpages
         self._positions[slot] = len(seq) - 1
         self._tokens[slot] = seq[-1]
         self._temperature[slot] = req.temperature
@@ -2333,14 +2717,7 @@ class ContinuousBatchingEngine:
         flight record enters the ``handoff`` phase bucket here, so the
         migrated request's latency decomposition still provably sums
         (PR 12 invariant across the hop)."""
-        self._slots[slot] = None
-        self._block_tables[slot] = NULL_PAGE
-        self._positions[slot] = 0
-        self._tokens[slot] = 0
-        self._top_k[slot] = 1
-        self._top_p[slot] = 0.0
-        self._temperature[slot] = 1.0
-        self._dirty = True
+        self._clear_slot_locked(slot)
         # a handoff request never decodes: its worst-case decode-page
         # commitment returns to the ledger now
         self._committed -= max(0, req._max_pages - len(req._pages))
@@ -2358,15 +2735,91 @@ class ContinuousBatchingEngine:
         request (or a second export) still hits them."""
         if req._phase != "handoff":
             return  # failed/shed earlier; _fail/_shed already cleaned up
-        pages, req._pages = req._pages, []
-        self._committed -= max(0, req._max_pages - len(pages))
-        self.pool.release(pages)
+        self._release_pages_locked(req)
         req._phase = "finished"
         req.finished = True
         req._t_done = time.monotonic()
         req._flight.finish("handoff", **args)
         self.flight.close(req._flight)
         req._done.set()
+
+    def _clear_slot_locked(self, slot: int) -> None:  # holds _lock
+        """An emptied slot: a dead row (null tables, greedy, position 0)."""
+        self._slots[slot] = None
+        self._block_tables[slot] = NULL_PAGE
+        if self._wtables is not None:
+            self._wtables[slot] = NULL_PAGE
+        self._positions[slot] = 0
+        self._tokens[slot] = 0
+        self._top_k[slot] = 1
+        self._top_p[slot] = 0.0
+        self._temperature[slot] = 1.0
+        self._dirty = True
+
+    def _release_pages_locked(self, req: EngineRequest) -> int:  # holds _lock
+        """Give back every page ``req`` holds, in each class, and what the
+        ledgers still held for it; returns how many pages those were."""
+        pages, req._pages = req._pages, []
+        self._committed -= max(0, req._max_pages - len(pages))
+        self.pool.release(pages)
+        if self.wpool is None:
+            return len(pages)
+        wpages = [p for p in req._wpages if p != NULL_PAGE]
+        self._wcommitted -= max(0, req._wmax - req._wprivate)
+        self.wpool.release(wpages)
+        req._wpages, req._wfirst, req._wkeep = [], 0, 0
+        req._wprivate = req._wmax = 0
+        return len(pages) + len(wpages)
+
+    def _slide_locked(self, req: EngineRequest,
+                      qpos: int) -> int:  # holds _lock
+        """The window class's release on slide: no query of ``req`` at
+        position ``qpos`` or later can see a key of the blocks before the
+        one holding key ``qpos - window + 1``, so their pages go back
+        (those the prefix cache registered stay cached-idle in their
+        class), and the ledger holds a page again for each of the
+        request's own.  A tick already launched may still read them: it
+        runs before any tick that writes what a later grant makes of
+        them.  Returns the pages released."""
+        ps = self.page_size
+        first = min(max(0, qpos - self._window + 1) // ps, len(req._wpages))
+        lo = req._wfirst
+        if first <= lo:
+            return 0
+        gone, own = [], 0
+        for i in range(lo, first):
+            p = req._wpages[i]
+            if p != NULL_PAGE:
+                gone.append(p)
+                own += i >= req._wkeep
+                req._wpages[i] = NULL_PAGE
+        req._wfirst = first
+        req._wprivate -= own
+        self._wcommitted += own
+        self.wpool.release(gone)
+        if req._slot >= 0 and req._phase == "decode":
+            # the host's mirror only: the rows' queries start behind these
+            # blocks, and the next upload that anything else asks for
+            # carries the nulls
+            self._wtables[req._slot][lo:first] = NULL_PAGE
+        self.window_pages_released += len(gone)
+        if gone and obs_registry.publishing():
+            self._m_slid.inc(len(gone))
+        return len(gone)
+
+    def _grant_window_locked(self, req: EngineRequest,
+                             last_block: int) -> bool:  # holds _lock
+        """Window-class pages for every block up to ``last_block`` that
+        ``req`` has none for yet (its own, off the ledger); False where the
+        pool cannot (ledger-unreachable)."""
+        while len(req._wpages) <= last_block:
+            got = self.wpool.alloc(1)
+            if got is None:
+                return False
+            req._wpages.append(got[0])
+            req._wprivate += 1
+            self._wcommitted -= 1
+        return True
 
     def _fail(self, req: EngineRequest, e: Exception) -> None:
         with self._lock:
@@ -2378,10 +2831,10 @@ class ContinuousBatchingEngine:
                 and self._slots[req._slot] is req:
             self._slots[req._slot] = None
             self._block_tables[req._slot] = NULL_PAGE
+            if self._wtables is not None:
+                self._wtables[req._slot] = NULL_PAGE
             self._dirty = True
-        pages, req._pages = req._pages, []
-        self._committed -= max(0, req._max_pages - len(pages))
-        self.pool.release(pages)
+        self._release_pages_locked(req)
         req._phase = "finished"
         req.error = f"{type(e).__name__}: {e}"
         req.finished = True
@@ -2392,18 +2845,9 @@ class ContinuousBatchingEngine:
 
     def _retire(self, slot: int) -> None:  # holds _lock
         req = self._slots[slot]
-        self._slots[slot] = None
-        self._block_tables[slot] = NULL_PAGE
-        self._positions[slot] = 0
-        self._tokens[slot] = 0
-        self._top_k[slot] = 1
-        self._top_p[slot] = 0.0
-        self._temperature[slot] = 1.0
-        pages, req._pages = req._pages, []
+        self._clear_slot_locked(slot)
         # early termination returns its unneeded worst-case commitment
-        self._committed -= max(0, req._max_pages - len(pages))
-        self.pool.release(pages)
-        self._dirty = True
+        self._release_pages_locked(req)
         req._phase = "finished"
         req.finished = True
         # drain-rate EMA (feeds Retry-After + slo shed predictions) and
@@ -2747,6 +3191,19 @@ class ContinuousBatchingEngine:
                 req._pages.append(got[0])
                 self._committed -= 1
                 self._dirty = True
+            if self.wpool is not None and i in active and (
+                    len(req._wpages) <= min(p1, self.pages_per_seq - 1)):
+                n_had = len(req._wpages)
+                if not self._grant_window_locked(
+                        req, min(p1, self.pages_per_seq - 1)):
+                    self._fail_locked(req, RuntimeError(
+                        "window-class KV pool exhausted for an in-flight "
+                        "slot — commitment ledger violated"))
+                    active.remove(i)
+                    continue
+                self._wtables[i][n_had:len(req._wpages)] = \
+                    req._wpages[n_had:]
+                self._dirty = True
         return k_eff
 
     def _dev_state_locked(self, ahead=0, spent=()) -> Tuple:  # holds _lock
@@ -2763,11 +3220,17 @@ class ContinuousBatchingEngine:
         if self._dirty:
             bt = self._block_tables.copy()
             bt[list(spent)] = NULL_PAGE
-            self._dev_state = tuple(self._asarray(a) for a in (
-                bt, self._positions + ahead, self._tokens.copy(),
-                self._keys.copy(), self._steps + ahead,
-                self._temperature.copy(), self._top_k.copy(),
-                self._top_p.copy()))
+            if self._wtables is not None:
+                wbt = self._wtables.copy()
+                wbt[list(spent)] = NULL_PAGE
+                bt = (bt, wbt)
+            # the tables alone may be a pair (one a page class)
+            self._dev_state = (jax.tree.map(self._asarray, bt),) + tuple(
+                self._asarray(a) for a in (
+                    self._positions + ahead, self._tokens.copy(),
+                    self._keys.copy(), self._steps + ahead,
+                    self._temperature.copy(), self._top_k.copy(),
+                    self._top_p.copy()))
             self._dirty = False
         return self._dev_state
 
@@ -3134,9 +3597,15 @@ class ContinuousBatchingEngine:
         Returns ``(spans, pre_tok, pre_pos, pre_tables, pre_index,
         pre_hor, lp_live)`` where spans is ``[(req, start, end), ...]``,
         ``pre_tables``/``pre_index`` are the COMPRESSED block tables (one
-        table per packed request, ``-1`` index = dead row), and
+        table per packed request, ``-1`` index = dead row; a patterned
+        model's ``pre_tables`` is the (full, window) pair), and
         ``lp_live`` flags return_log_probs prompts that must take the
-        teacher-forced scoring chunk instead."""
+        teacher-forced scoring chunk instead.
+
+        The window class's pages of a prompt are granted HERE, for the rows
+        a tick packs and no further, after the window has been slid up to
+        the first of them: a prompt of any length holds the window's pages
+        and one tick's rows' (``window_pages_cap``)."""
         Rp = self.prefill_rows
         pre_tok = np.zeros((Rp,), np.int32)
         pre_pos = np.zeros((Rp,), np.int32)
@@ -3144,6 +3613,10 @@ class ContinuousBatchingEngine:
                              NULL_PAGE, np.int32)
         pre_index = np.full((Rp,), -1, np.int32)
         pre_hor = np.zeros((Rp,), np.int32)
+        pre_wtables = None
+        if self.wpool is not None:
+            pre_wtables = np.full_like(pre_tables, NULL_PAGE)
+            pre_tables = (pre_tables, pre_wtables)
         spans: List[Tuple[EngineRequest, int, int]] = []
         live = [r for r in self._prefill_q if r._phase == "prefill"]
         if len(live) != len(self._prefill_q):  # failed/cancelled
@@ -3169,7 +3642,20 @@ class ContinuousBatchingEngine:
             pos = req._fill_pos
             if pos >= fill_end or used >= budget:
                 continue
-            pre_tables[n_req, : len(req._pages)] = req._pages
+            if pre_wtables is None:
+                pre_tables[n_req, : len(req._pages)] = req._pages
+            else:
+                pre_tables[0][n_req, : len(req._pages)] = req._pages
+                self._slide_locked(req, pos)
+                # the rows this request gets are known before they are
+                # packed: chunk ends do not move what the budget leaves
+                last = min(fill_end, pos + (budget - used)) - 1
+                if not self._grant_window_locked(req, last // ps):
+                    self._fail_locked(req, RuntimeError(
+                        "window-class KV pool exhausted for an admitted "
+                        "prompt — commitment ledger violated"))
+                    continue
+                pre_wtables[n_req, : len(req._wpages)] = req._wpages
             while pos < fill_end and used < budget:
                 # absolute-grid chunk boundary (first/last may be short);
                 # a budget cut mid-chunk is fine — the next tick's chunk
@@ -3230,7 +3716,8 @@ class ContinuousBatchingEngine:
                 self._prefill_q.remove(req)
                 if self.cache is not None:
                     self.cache.insert(seq, req._pages,
-                                      (len(seq) - 1) // ps)
+                                      (len(seq) - 1) // ps,
+                                      req._wpages or None)
                 self._activate_or_handoff(req, req._slot)
 
     def _step_ragged(self, admit_s: float, c_admit: float) -> int:
@@ -3371,7 +3858,7 @@ class ContinuousBatchingEngine:
                 pre_args = () if not n_bucket else (
                     self._asarray(pre_tok[:n_bucket]),
                     self._asarray(pre_pos[:n_bucket]),
-                    self._asarray(pre_tables),
+                    jax.tree.map(self._asarray, pre_tables),
                     self._asarray(pre_index[:n_bucket]),
                     self._asarray(pre_hor[:n_bucket]))
                 tick_fn = self._ragged_tick(n_bucket)
@@ -3387,9 +3874,9 @@ class ContinuousBatchingEngine:
                     spec = (acc, cnt, k_eff)
                     del acc, cnt
                 else:
-                    (self.pool.kv, next_tok, out_lp,
+                    (self._kv, next_tok, out_lp,
                      new_pos, new_steps, *moe) = tick_fn(
-                        self.params, self.pool.kv,
+                        self.params, self._kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         *carry, *pre_args)
                     out_tok, spec = next_tok, None
@@ -3415,7 +3902,12 @@ class ContinuousBatchingEngine:
                 del new_steps, spec, moe
         t_launched, c_launched = time.monotonic(), time.thread_time()
         self._note_host_gap(gap)
-        dry, self.pool.reclaimed = self.pool.reclaimed, False
+        dry = False
+        for pl in self._pools:
+            if pl.reclaimed:
+                dry, pl.reclaimed = True, False
+                if pl.page_class is not None and obs_registry.publishing():
+                    self._m_dry_class[pl.page_class].inc()
         if obs_registry.publishing():
             for ph, sec in (("admit", admit_s), ("plan", t_tick - t_plan),
                             ("launch", t_launched - t_tick)):
@@ -3468,16 +3960,26 @@ class ContinuousBatchingEngine:
             if rec.moe is not None:
                 *fetched, moe_stats = fetched
                 rows, touched = int(moe_stats[0]), int(moe_stats[1])
+                # a share of the experts (moe_experts_held) also says what
+                # of that was its own; all of it where every expert is held
+                held, held_touched = ((int(moe_stats[2]), int(moe_stats[4]))
+                                      if len(moe_stats) > 2
+                                      else (rows, touched))
                 self.moe_assignments += rows
                 self.moe_experts_touched += touched
-                # the same two numbers where a capture can lay them beside
+                self.moe_held_assignments += held
+                self.moe_held_experts_touched += held_touched
+                # the same numbers where a capture can lay them beside
                 # this tick's device time (engine-launch has its ``tick=``)
                 with obs_trace.span("engine-moe", tick=rec.no,
-                                    assignments=rows, touched=touched):
+                                    assignments=rows, touched=touched,
+                                    held=held, held_touched=held_touched):
                     pass
                 if obs_registry.publishing():
                     self._m_moe_assignments.inc(rows)
                     self._m_moe_touched.inc(touched)
+                    self._m_moe_held.inc(held)
+                    self._m_moe_held_touched.inc(held_touched)
         now, c_apply = time.monotonic(), time.thread_time()
         with obs_trace.span("engine-apply", tick=rec.no):
             with self._lock:
@@ -3501,6 +4003,7 @@ class ContinuousBatchingEngine:
                     sum(end - start for _, start, end in rec.spans)
                     + len(rec.active))
                 self.ticked_tokens += emitted
+                self._note_seq_pages_locked()
                 if obs_registry.publishing():
                     self._m_ticks.inc()
                     self._m_tokens.inc(emitted)
@@ -3514,6 +4017,8 @@ class ContinuousBatchingEngine:
                     self._m_free_pages.set(self.pool.num_free)
                     self._m_pages_cached.set(
                         len(self.cache) if self.cache else 0)
+                    for pl in self._pools:
+                        pl.publish_states()
                 self._publish_queued_locked()
             # This tick's device handles are dropped here, inside the span
             # and outside the lock.  Freeing a device array can release the
@@ -3529,6 +4034,28 @@ class ContinuousBatchingEngine:
             self._m_phase["apply"].observe(t_end - now)
             self._m_host_cpu["apply"].observe(c_end - c_apply)
         return emitted
+
+    def _note_seq_pages_locked(self) -> None:  # holds _lock
+        """Once an applied tick: slide the decoding sequences' windows up
+        to the position their next query stands at (the tick in flight
+        feeds the token this apply has just appended), and add the pages
+        they then hold, a class, to ``mlt_engine_seq_pages_sum``."""
+        live = [r for r in self._slots
+                if r is not None and r._phase == "decode"]
+        if self.wpool is not None:
+            released = sum(
+                self._slide_locked(r, len(r.prompt) + len(r.generated) - 1)
+                for r in live)
+            if released:
+                with obs_trace.span("pool-slide", released=released):
+                    pass
+        if live and obs_registry.publishing():
+            self._m_seq_ticks.inc(len(live))
+            self._m_seq_pages[self.pool.page_class or "full"].inc(
+                sum(len(r._pages) for r in live))
+            if self.wpool is not None:
+                self._m_seq_pages[self.wpool.page_class].inc(
+                    sum(len(r._wpages) - r._wfirst for r in live))
 
     def run_until_idle(self) -> None:
         """Drive ticks on the calling thread until queue and slots drain.
@@ -3791,6 +4318,7 @@ class ContinuousBatchingEngine:
         / ``bytes`` / ``hit_tokens`` for the migration receipt."""
         if self.pool.latent:
             refuse_latent_cache(handoff=True)
+        refuse_layer_pattern(self.cfg, handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         tok = self.tokenizer
@@ -3844,6 +4372,7 @@ class ContinuousBatchingEngine:
         nothing is cached."""
         if self.pool.latent:
             refuse_latent_cache(handoff=True)
+        refuse_layer_pattern(self.cfg, handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         if self.cache is None:
@@ -3877,6 +4406,7 @@ class ContinuousBatchingEngine:
         cannot hold the pages.  Returns the import receipt."""
         if self.pool.latent:
             refuse_latent_cache(handoff=True)
+        refuse_layer_pattern(self.cfg, handoff=True)
         from megatron_llm_tpu.serving.handoff import wire
 
         payload = wire.decode_pages(blob)

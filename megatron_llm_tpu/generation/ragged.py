@@ -110,13 +110,19 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     ``pool_kv`` is the paged pool, ONE leaf over all layers whose row
     ops/kv_quant.py owns (K/V or latent); the engine donates it and every
-    layer updates its pages in place.
+    layer updates its pages in place.  A patterned model (the spec-0 tick
+    only) has one leaf a page class, ``pool_kv`` the tuple of them, and
+    ``block_tables`` / ``pre_tables`` tuples of tables beside it
+    (models/transformer.py ``pool_classes``): a layer reads its class's.
 
     ``moe_stats`` exists iff the model has experts: ``[2]`` float32, the
     router's assignments (rows x topk, every row the program ran, dead
     padding rows too: the grouped GEMM runs them) and the distinct experts
     that received a row, both summed over the expert layers.  It rides
     the tick's one fetch to the engine's ``mlt_engine_moe_*`` counters.
+    Where the program holds a share of the experts (``moe_experts_held``)
+    it is ``[5]``: then the held assignments that ran, those dropped for
+    want of a row, and the distinct held experts that received one.
 
     ``carry_tok`` / ``carried`` (``[b]`` int32 / bool) feed a row its
     token device to device: the engine launches this tick before it has
@@ -157,6 +163,9 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
     scope_d = "draft-fwd" if tp == 1 else f"draft-fwd-tp{tp}"
 
     moe = cfg.model.num_experts is not None
+    # what of the router's aux vector rides the fetch (models/moe.py)
+    moe_stats = slice(2, 7) if moe and (
+        cfg.model.experts_held < cfg.model.num_experts) else slice(2, 4)
 
     def target_forward(params, pool_kv, tbl, idx, pos, tok, hor):
         """ONE target forward over the full ragged batch — the single
@@ -301,8 +310,11 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
              pre_tok=None, pre_pos=None, pre_tables=None,
              pre_index=None, pre_hor=None):
         b = tokens.shape[0]
-        W = block_tables.shape[1]
-        null_tbl = jnp.zeros((1, W), block_tables.dtype)
+
+        def with_null(*tables):     # a class's tables behind its null table
+            null_tbl = jnp.zeros((1, tables[0].shape[1]), tables[0].dtype)
+            return jnp.concatenate([null_tbl, *tables])
+
         tokens = jnp.where(carried, carry_tok, tokens)
         idx = 1 + jnp.arange(b, dtype=jnp.int32)
         hor = row_horizons(positions)
@@ -311,12 +323,12 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_pos = jnp.concatenate([positions, pre_pos])
             all_idx = jnp.concatenate(
                 [idx, jnp.where(pre_index >= 0, 1 + b + pre_index, 0)])
-            all_tbl = jnp.concatenate([null_tbl, block_tables, pre_tables])
+            all_tbl = jax.tree.map(with_null, block_tables, pre_tables)
             all_hor = jnp.concatenate([hor, pre_hor])
         else:
             all_tok, all_pos, all_idx, all_hor = (
                 tokens, positions, idx, hor)
-            all_tbl = jnp.concatenate([null_tbl, block_tables])
+            all_tbl = jax.tree.map(with_null, block_tables)
         out, pool_kv, aux = target_forward(
             params, pool_kv, all_tbl, all_idx, all_pos, all_tok, all_hor)
         last = out[:b]
@@ -326,7 +338,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             temperature=temperature, vocab_size=cfg.model.vocab_size)
         logp = gen._gather_token_log_probs(last, next_tok)
         res = (pool_kv, next_tok, logp, positions + 1, steps + 1)
-        return res + (aux[2:4],) if moe else res
+        return res + (aux[moe_stats],) if moe else res
 
     base_fn = spec_tick if K else tick
     if ovl is None and ppc is None:

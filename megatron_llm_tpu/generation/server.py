@@ -234,6 +234,12 @@ class MegatronServer:
             from megatron_llm_tpu.generation.engine import refuse_latent_cache
 
             refuse_latent_cache(handoff=True)
+        if role != "unified" and getattr(engine, "wpool", None) is not None:
+            from megatron_llm_tpu.generation.engine import (
+                refuse_layer_pattern,
+            )
+
+            refuse_layer_pattern(engine.cfg, handoff=True)
         # token streaming: ONE thread writes every open stream's
         # incremental frames, kicked by the engine once an applied tick
         # (serving/streaming/writer.py); started and joined with the

@@ -72,6 +72,27 @@ def validate_family(cfg: Config) -> Config:
                "smallthinker uses RMSNorm and ReGLU")
         _check(not m.use_bias and not m.parallel_attn,
                "smallthinker is a sequential block without biases")
+    elif name == "commanda":
+        _check(m.layer_period > 1 and 0 in m.sliding_window_layout
+               and 1 in m.sliding_window_layout,
+               "commanda mixes window and full layers: give "
+               "sliding_window_layout and rope_layout")
+        _check(m.parallel_attn and not m.parallel_layernorm,
+               "commanda is a parallel block behind ONE norm")
+        _check(not m.use_rms_norm and not m.norm_bias and not m.use_bias,
+               "commanda uses bias-free LayerNorm and no biases")
+        _check(m.glu_activation == "swiglu", "commanda uses SwiGLU experts")
+        _check(m.num_experts is not None and m.num_experts > 1,
+               "commanda requires num_experts > 1")
+        _check(m.moe_score_func == "sigmoid" and not m.moe_selection_bias
+               and m.moe_normalize_gates
+               and m.moe_routed_scaling_factor == 1.0,
+               "commanda routes by plain sigmoid scores, normalised over "
+               "the chosen, unscaled")
+        _check(m.moe_shared_experts > 0
+               and m.moe_shared_combination == "average",
+               "commanda averages its shared experts")
+        _check(m.tie_embed_logits, "commanda ties its head to the embedding")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

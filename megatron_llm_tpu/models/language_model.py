@@ -63,7 +63,7 @@ def init_model_params(cfg, key: jax.Array) -> Params:
             * jax.random.normal(k_emb, (v, h), jnp.float32)
         },
         "layers": init_stacked_layers(cfg, k_layers),
-        "final_norm": init_norm_params(h, m.use_rms_norm),
+        "final_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias),
     }
     if m.dense_prefix_layers:
         # the leading dense layers: a stack of their own, so that the

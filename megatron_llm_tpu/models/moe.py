@@ -46,8 +46,12 @@ adds the capability TPU-first, in the GShard/Switch/Mixtral lineage:
   and COUNTED (``aux[5]``), never silently.
 * **The router's options are data of the family** (:func:`route`):
   softmax or sigmoid scores, a selection bias that picks the top-k and is
-  not part of the weight, renormalisation, a scaling factor; and a
-  **shared expert** every token takes (a dense MLP of the expert width).
+  not part of the weight, renormalisation, a scaling factor; and
+  **shared experts** every token takes (one dense MLP of their summed
+  widths, whose output is their sum, or their average where the family
+  says ``moe_shared_combination`` 'average').  A share of the experts and
+  shared experts go together where attention is data-parallel: each chip
+  computes the shared experts for its own tokens, once.
 
 Parameter schema (per layer; stacked on a leading layer axis under scan):
 
@@ -101,9 +105,10 @@ def moe_capacity_expert_choice(cfg, tokens_per_group: int) -> int:
 # trained loss, then what the router did, for the serving engine's counters
 # (generation/ragged.py) and the trainer's `train-moe` span: assignments
 # made (rows x topk), distinct experts that received at least one row,
-# assignments whose expert is held here and ran, and held assignments
-# dropped because the row buffer was full
-AUX_LEN = 6
+# assignments whose expert is held here and ran, held assignments dropped
+# because the row buffer was full, and distinct HELD experts that received
+# at least one row
+AUX_LEN = 7
 
 
 def expert_width(cfg) -> int:
@@ -194,7 +199,8 @@ def _aux(balance, z, rows_per_expert: jax.Array) -> jax.Array:
     return jnp.stack([
         balance.astype(jnp.float32), z.astype(jnp.float32), total,
         (rows_per_expert > 0).sum().astype(jnp.float32), total,
-        jnp.zeros((), jnp.float32)])
+        jnp.zeros((), jnp.float32),
+        (rows_per_expert > 0).sum().astype(jnp.float32)])
 
 
 @jax.named_scope("router")
@@ -634,12 +640,20 @@ def moe_sublayer(cfg, p: Params, x: jax.Array,
             out, ran, dropped = dropless_experts(
                 cfg, p["experts"], xt, idx, w, counts)
             if m.experts_held < m.num_experts:
-                aux = aux.at[4].set(ran).at[5].set(dropped)
+                first = m.moe_first_held_expert
+                aux = aux.at[4].set(ran).at[5].set(dropped).at[6].set(
+                    (counts[first:first + m.experts_held] > 0).sum()
+                    .astype(jnp.float32))
             if "shared" in p:
                 from megatron_llm_tpu.models.transformer import mlp_sublayer
 
                 with jax.named_scope("shared_expert"):
-                    out = out + mlp_sublayer(cfg, p["shared"], xt)
+                    # the S shared experts are one MLP of S x the width:
+                    # its output is their sum
+                    shared = mlp_sublayer(cfg, p["shared"], xt)
+                    if m.moe_shared_combination == "average":
+                        shared = shared * (1.0 / m.moe_shared_experts)
+                    out = out + shared
             return out.reshape(b, s, h), aux
     assert "shared" not in p and "bias" not in p["router"] and (
         m.moe_score_func == "softmax" and router_x is None

@@ -93,7 +93,7 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
 
     k = jax.random.split(key, 7)
     p: Params = {
-        "input_norm": init_norm_params(h, m.use_rms_norm),
+        "input_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias),
         "attention": _init_mla_params(cfg, k[0], k[1], out_std) if m.mla else {
             "qkv": {"kernel": _normal(k[0], (h, (n + 2 * nkv) * d), std)},
             "dense": {"kernel": _normal(k[1], (n * d, h), out_std)},
@@ -115,9 +115,9 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
             "fc2": {"kernel": _normal(k[3], (ffn, h), out_std)},
         }
     if not m.parallel_attn:
-        p["post_norm"] = init_norm_params(h, m.use_rms_norm)
+        p["post_norm"] = init_norm_params(h, m.use_rms_norm, bias=m.norm_bias)
     if m.parallel_layernorm:
-        p["mlp_norm"] = init_norm_params(h, m.use_rms_norm)
+        p["mlp_norm"] = init_norm_params(h, m.use_rms_norm, bias=m.norm_bias)
     if cross_attention:
         # T5 decoder inter-attention (reference t5_model.py via
         # ParallelAttention attn_type=cross, transformer.py:280): separate Q
@@ -252,6 +252,34 @@ def layer_kinds(cfg) -> Tuple[LayerKind, ...]:
     return tuple(
         LayerKind(m.sliding_window_size if w else None, bool(r))
         for w, r in zip(m.sliding_window_layout, m.rope_layout))
+
+
+class PoolClass(NamedTuple):
+    """One page class of the paged pool: the layers of a period that need
+    the same keys kept (``window`` of them; None = every key) and so share
+    pages, a leaf and a block table."""
+
+    window: Optional[int]
+    places: Tuple[int, ...]   # places in the period, in order
+
+    @property
+    def name(self) -> str:
+        return "full" if self.window is None else "window"
+
+
+def pool_classes(cfg) -> Tuple[PoolClass, ...]:
+    """The page classes of the serving pool: the distinct cache needs of
+    :func:`layer_kinds`, the class that keeps every key first.  A model
+    whose layers all need the same keys has ONE class, which keeps every
+    page a sequence wrote (a uniform window's pool does not slide)."""
+    kinds = layer_kinds(cfg)
+    windows = sorted({k.window for k in kinds},
+                     key=lambda w: (w is not None, w))
+    if len(windows) == 1:
+        return (PoolClass(None, tuple(range(len(kinds)))),)
+    return tuple(
+        PoolClass(w, tuple(j for j, k in enumerate(kinds) if k.window == w))
+        for w in windows)
 
 
 def _uniform_kind(cfg) -> LayerKind:
@@ -831,10 +859,26 @@ def transformer_forward(
         stacked_layers = {**stacked_layers, "moe": {
             k: v for k, v in stacked_layers["moe"].items() if k != "experts"}}
 
-    def one_layer(carry, xs, kind):
+    # a layer's page class, its rank among the class's layers of a period
+    # and how many those are, by its place in the period
+    # (told by the TABLES: a quantized pool is a tuple of its own)
+    classed = in_carry and paged is not None and isinstance(
+        paged.block_tables, tuple)
+    class_at = {j: (c, cls.places.index(j), len(cls.places))
+                for c, cls in enumerate(pool_classes(cfg))
+                for j in cls.places} if classed else None
+
+    def one_layer(carry, xs, kind, place=0):
         carry_hidden, pool = carry
         layer_params, layer_idx, cache = xs
-        if in_carry:
+        layer_paged = paged
+        if classed:
+            c, rank, per_period = class_at[place]
+            cache = LayerPool(
+                pool[c], (layer_idx - pool_first_layer)
+                // len(class_at) * per_period + rank)
+            layer_paged = paged._replace(block_tables=paged.block_tables[c])
+        elif in_carry:
             cache = LayerPool(pool, layer_idx - pool_first_layer)
         if all_experts is not None:
             layer_params = {**layer_params, "moe": {
@@ -850,10 +894,13 @@ def transformer_forward(
             encoder_hidden=encoder_hidden, enc_bias=enc_bias,
             dropout_key=dk, deterministic=deterministic,
             hidden_dropout_rate=rate,
-            kv_cache=cache, cache_index=cache_index, paged=paged,
+            kv_cache=cache, cache_index=cache_index, paged=layer_paged,
             sp_constraint=sp_constraint, kind=kind,
         )
-        if in_carry:
+        if classed:
+            pool = pool[:c] + (new_cache,) + pool[c + 1:]
+            new_cache = None
+        elif in_carry:
             pool, new_cache = new_cache, None
         return (out, pool), (new_cache, aux)
 
@@ -873,7 +920,8 @@ def transformer_forward(
             "full" if granularity == "full" else cfg.training.remat_policy
             if granularity else "none"
         )
-        bodies = [partial(one_layer, kind=k) for k in kinds]
+        bodies = [partial(one_layer, kind=k, place=j)
+                  for j, k in enumerate(kinds)]
         if granularity is not None:
             # a checkpoint a LAYER, not a period: what the backward holds
             # at once is one layer's internals, as in a uniform stack
@@ -914,7 +962,7 @@ def transformer_forward(
             cache = None if kv_caches is None else jax.tree.map(lambda a: a[i], kv_caches)
             (hidden, pool), (nc, aux) = one_layer(
                 (hidden, pool), (layer_p, layer_ids[i], cache),
-                kinds[i % period])
+                kinds[i % period], i % period)
             new_caches.append(nc)
             aux_total = aux_total + aux
         if in_carry:

@@ -48,8 +48,10 @@ def norm(x, params: dict, eps: float, use_rms: bool) -> jax.Array:
     return layer_norm(x, params["scale"], params.get("bias"), eps)
 
 
-def init_norm_params(hidden_size: int, use_rms: bool, dtype=jnp.float32) -> dict:
+def init_norm_params(hidden_size: int, use_rms: bool, dtype=jnp.float32,
+                     bias: bool = True) -> dict:
+    """``bias``: a LayerNorm's additive bias (``norm_bias``; RMSNorm has none)."""
     p = {"scale": jnp.ones((hidden_size,), dtype=dtype)}
-    if not use_rms:
+    if not use_rms and bias:
         p["bias"] = jnp.zeros((hidden_size,), dtype=dtype)
     return p

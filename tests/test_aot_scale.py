@@ -384,6 +384,65 @@ def test_aot_latent_tick_compiles_at_published_widths():
     assert stats.temp_size_in_bytes < 1 << 30
 
 
+def test_aot_two_class_tick_compiles_at_published_widths():
+    """The ragged tick of Command A+ at its published widths (one period:
+    three window layers and the full one; 16 of 128 experts held; abstract
+    parameters) compiles for one v5e with the pool as TWO leaves, one a
+    page class, each behind its own block tables: Mosaic takes the paged
+    kernel under both masks (128 query heads on 8 KV heads of 128) in one
+    program, and the grouped kernel takes the held experts' stack where
+    it lies."""
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    cfg = make_config("commanda-plus", num_layers=4, vocab_size=32768,
+                      moe_experts_held=16, moe_capacity_factor=8.0,
+                      params_dtype="bfloat16", seq_length=8192)
+    slots, page, pre = 16, 16, 64
+    width = cfg.data.seq_length // page
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    row = 2 * 8 * 128
+    pools = (S((1, slots * width + 1, page, row), jnp.bfloat16),
+             S((3, slots * 262 + 1, page, row), jnp.bfloat16))
+    tables = lambda n: (S((n, width), jnp.int32),) * 2  # noqa: E731
+    with global_mesh(mesh):
+        params = jax.eval_shape(
+            functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda a: S(a.shape, jnp.bfloat16), params)
+        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        lowered = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, pools, tables(slots),
+            S((slots,), jnp.int32), S((slots,), jnp.int32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), S((pre,), jnp.int32),
+            S((pre,), jnp.int32), tables(2),
+            S((pre,), jnp.int32), S((pre,), jnp.int32))
+        text = lowered.as_text()
+        assert "paged_attention" in text and "gmm" in text
+        compiled = lowered.compile()
+        stats = compiled.memory_analysis()
+    # the scopes a device trace tells the two masks' kernels apart by
+    hlo = compiled.as_text()
+    assert "attention/window" in hlo and "attention/global" in hlo
+    # 1.8 GB as compiled: XLA lays every layer's QKV and shared-expert
+    # weights out anew (transposed) before their projections, 0.42 GB a
+    # layer, all four at once (PERF.md section 5, PR 39; the other families'
+    # ticks do the same at their widths).  No copy of a layer's HELD
+    # experts (1.6 GB) on top of that: the grouped kernel reads the stack
+    assert stats.temp_size_in_bytes < 2 << 30
+    out = compiled.output_shardings
+    assert len(jax.tree.leaves(out)) == 2 + 5     # two leaves, four rows, moe
+
+
 @pytest.mark.parametrize("model,vocab,heads,slots", [
     ("falcon-7b", 65024, (71, 1, 64), 128),
     ("mistral-7b", 32000, (32, 8, 128), 32)],

@@ -372,3 +372,42 @@ def test_flash_kernel_refuses_unequal_widths():
     q = jnp.zeros((1, 128, 4, 192), jnp.float32)
     with pytest.raises(ValueError, match="one head width"):
         flash_attention(q, q, jnp.zeros((1, 128, 4, 128), jnp.float32))
+
+
+def test_long_probes_compared_at_the_emitted_positions(model, monkeypatch):
+    """PERF.md 7(p)(1), the tier-1 case ISSUE 38 asked for here and a
+    `benchmark` PR could not add: probes longer than one block of the
+    reference's attention (blocks of 32 queries; 72 + 32 and 88 + 32 tokens)
+    served through the engine, two of them out of the prefix cache, and
+    compared as a run's ``correct`` compares them: the reference's stack on
+    prompt + emitted tokens, its head on the emitted positions only
+    (``benchmark/lib/check.py`` ``emitted_reference``)."""
+    import types
+
+    from benchmark.lib import check
+
+    monkeypatch.setattr(ref_common, "QUERY_BLOCK", 32)
+    cfg, params = model
+    eng = ContinuousBatchingEngine(cfg, params, prefill_chunk=32)
+    probes = check.serve_probes(11, VOCAB, (72, 88), eng.page_size)
+    assert [p["name"] for p in probes] == ["alone", "first", "whole_hit",
+                                           "part_hit"]
+    for group in (probes[:2], probes[2:3], probes[3:]):
+        reqs = [eng.submit(p["prompt"], check.PROBE_TOKENS, top_k=1,
+                           termination_id=NEVER) for p in group]
+        eng.run_until_idle()
+        for p, req in zip(group, reqs):
+            tokens, lps = req.result(timeout=120)
+            p["tokens"], p["logprobs"] = tokens[len(p["prompt"]):], lps
+    assert eng.prefix_hit_tokens >= 80 + 64 and eng.cow_copies == 1
+    cell = types.SimpleNamespace(config={"reference": "joyai_block"},
+                                 model=MODEL)
+    want = check.emitted_reference(cell, params, probes)
+    got = [lp for p in probes for lp in p["logprobs"]]
+    assert len(got) == 4 * check.PROBE_TOKENS
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+    # blocks change no number: the plain form reads the same
+    monkeypatch.setattr(ref_common, "QUERY_BLOCK", 512)
+    whole = check.emitted_reference(cell, params, probes[:1])
+    np.testing.assert_allclose(whole, want[:check.PROBE_TOKENS], rtol=0,
+                               atol=1e-5)

@@ -112,6 +112,13 @@ def toy_model():
     dict(n=32, nkv=8, d=128, page=16, max_pages=24, context=300),
     dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8", max_pages=24,
          context=300, window=50),
+    # Command A+ (128/8 x 128) under its window with the tables of a
+    # window page class: the slots behind the window name the null page,
+    # so the first live page is not the table's first; and the same
+    # geometry with no window (its full layers), the two masks of one tick
+    dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300,
+         window=100, slid_head=True),
+    dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300),
 ], ids=lambda c: "-".join(f"{k}{getattr(v, '__name__', v)}"
                           for k, v in c.items()))
 def test_paged_kernels_interpret_match_jnp_path(case):

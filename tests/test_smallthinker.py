@@ -453,20 +453,36 @@ def test_a_patterned_layer_must_be_told_its_kind(model):
                           rope=make_rope_cache(cfg))
 
 
-@pytest.mark.parametrize("case, sentence", [
-    ("pattern", "is not served by the continuous-batching engine yet"),
-    ("share", "a share of an expert-parallel layer is not a model to serve"),
-])
-def test_engine_refuses_at_start_up(model, case, sentence):
-    """The serving engine must not run the pattern wrong silently: its pool
-    has one page class, so it says so before anything is placed."""
+@pytest.mark.parametrize("case", ["pattern", "share"])
+def test_engine_serves_what_it_refused(model, case):
+    """Since PR 39 the engine serves a pattern (two page classes: its full
+    layer first here, where Command A+ has it last) and a share of the
+    experts; where this test stood, it held the engine to refusing both.
+    Compared with the plain reference (the pattern) and with the dense
+    forward on the same share (mixtral has no reference of its own)."""
     cfg, params = model
     if case == "share":
         cfg = make_config(
             "mixtral", num_layers=2, hidden_size=64, num_attention_heads=4,
             num_attention_heads_kv=2, ffn_hidden_size=32, num_experts=8,
-            moe_experts_held=2, vocab_size=VOCAB, params_dtype="float32",
-            use_flash_attn=False)
+            moe_experts_held=2, moe_capacity_factor=8.0, vocab_size=VOCAB,
+            params_dtype="float32", use_flash_attn=False)
         params = init_model_params(cfg, jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match=sentence):
-        ContinuousBatchingEngine(cfg, params, max_slots=2, max_seq=64)
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=2, max_seq=128,
+                                   page_size=8, prefill_chunk=16)
+    assert (eng.wpool is not None) == (case == "pattern")
+    prompt = [int(t) for t in np.random.default_rng(2).integers(1, VOCAB, 90)]
+    req = eng.submit(prompt, 16, top_k=1, termination_id=10 ** 9)
+    eng.run_until_idle()
+    toks, lps = req.result(timeout=120)
+    seq = jnp.asarray([toks], jnp.int32)
+    logits = (ref.logits(params, seq, MODEL) if case == "pattern"
+              else model_forward(cfg, params, seq)[0])
+    logp = jax.nn.log_softmax(logits[0, len(prompt) - 1:-1], axis=-1)
+    want = np.asarray(jnp.take_along_axis(
+        logp, seq[0, len(prompt):, None], axis=-1))[:, 0]
+    np.testing.assert_allclose(np.asarray(lps), want, rtol=0, atol=1e-4)
+    if case == "pattern":
+        assert eng.window_pages_released > 0      # 90 tokens, window 32
+    else:
+        assert 0 < eng.moe_held_assignments < eng.moe_assignments

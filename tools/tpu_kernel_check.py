@@ -122,7 +122,8 @@ def flash_numerics(quick: bool):
 
 def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
                kv_dtype: str = "bf16", window=None, dtype=jnp.bfloat16,
-               max_pages: int = 12, context=None, poison_tail: bool = False):
+               max_pages: int = 12, context=None, poison_tail: bool = False,
+               slid_head: bool = False):
     """One random paged-attention scenario and its three call shapes.
 
     Returns ``{name: (pallas_fn, jnp_fn)}`` — thunks over the same pool and
@@ -134,7 +135,12 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     ``context`` tokens (default: the whole table).  ``poison_tail`` points
     every slot past a table's context at a page that is NaN in the pool
     the kernel reads and finite in the one the gather path reads: a walk
-    that lets the tail reach a row's output shows as a NaN.
+    that lets the tail reach a row's output shows as a NaN.  ``slid_head``
+    (with ``window``): the tables the KERNEL reads name the null page for
+    every slot wholly behind the window of the earliest query that reads
+    them, as a window page class's tables do once a sequence's window has
+    moved on (generation/engine.py); the gather path keeps the whole
+    tables, so what lies behind a window must not reach the output.
     """
     import numpy as np
 
@@ -168,15 +174,28 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             ids = np.where(np.arange(max_pages) * page < ctx, ids, poison)
         return ids
 
+    def slid(ids, first_pos):
+        """``ids`` as the kernel reads them: NULL where every token of the
+        slot is older than ``first_pos - window + 1``."""
+        if not (slid_head and window):
+            return ids
+        dead = (np.arange(max_pages) + 1) * page <= first_pos - window + 1
+        return np.where(dead, 0, ids)
+
     pos = np.asarray([0, page - 1, page, limit - 1], np.int32)
-    bt = jnp.asarray(np.stack([table(p + 1) for p in pos]), jnp.int32)
+    bt = np.stack([table(p + 1) for p in pos])
+    bt_k = jnp.asarray(np.stack([slid(t, p) for t, p in zip(bt, pos)]),
+                       jnp.int32)
+    bt = jnp.asarray(bt, jnp.int32)
     pos = jnp.asarray(pos)
     q1 = jnp.asarray(rng.normal(size=(b, 1, n, d)), dtype)
 
     # prefill: one chunk of s rows starting mid-sequence, page-aligned; at
     # the end of the context when one is given
     start = 3 * page if context is None else max(limit - s, 0) // page * page
-    bt1 = jnp.asarray(table(start + s)[None], jnp.int32)
+    bt1 = table(start + s)
+    bt1_k = jnp.asarray(slid(bt1, start)[None], jnp.int32)
+    bt1 = jnp.asarray(bt1[None], jnp.int32)
     start = jnp.asarray([start], jnp.int32)
     qs = jnp.asarray(rng.normal(size=(1, s, n, d)), dtype)
 
@@ -186,9 +205,12 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     run = list(range(page + 1, page + 7))
     r_pos = np.array([limit - 2, 0] + run + [0, 0, limit // 2], np.int32)
     r_idx = np.array([1, 0] + [2] * 6 + [0, 0, 1], np.int32)
-    tables = jnp.asarray(np.stack(
-        [np.zeros(max_pages, np.int64), table(limit - 1), table(run[-1] + 1)]),
+    tables = np.stack(
+        [np.zeros(max_pages, np.int64), table(limit - 1), table(run[-1] + 1)])
+    tables_k = jnp.asarray(np.stack(
+        [tables[0], slid(tables[1], limit // 2), slid(tables[2], run[0])]),
         jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
     r_hor = np.where(r_idx > 0, (r_pos // 64 + 1) * 64, 0).astype(np.int32)
     live = np.flatnonzero(r_idx)
     r_pos, r_idx, r_hor = (jnp.asarray(a) for a in (r_pos, r_idx, r_hor))
@@ -197,19 +219,19 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     return {
         "decode": (
             lambda interpret=False: pk.paged_decode_kernel(
-                q1, pool_k, bt, pos, interpret=interpret, **kw),
+                q1, pool_k, bt_k, pos, interpret=interpret, **kw),
             lambda: pa.paged_attention_decode(
                 q1, pool, bt, pos, use_kernel=False, **kw)),
         "prefill": (
             lambda interpret=False: pk.paged_prefill_kernel(
-                qs, pool_k, bt1, start, interpret=interpret, **kw),
+                qs, pool_k, bt1_k, start, interpret=interpret, **kw),
             lambda: pa.paged_attention_prefill(
                 qs, pool, bt1, start, use_kernel=False, **kw)),
         # live rows only: a dead row is exact zeros from the kernel and
         # null-page garbage from the gather path, by design
         "ragged": (
             lambda interpret=False: pk.paged_ragged_kernel(
-                qr, pool_k, tables, r_idx, r_pos, r_hor,
+                qr, pool_k, tables_k, r_idx, r_pos, r_hor,
                 interpret=interpret, **kw)[live],
             lambda: pa.paged_attention_ragged(
                 qr, pool, tables, r_idx, r_pos, r_hor,
@@ -229,6 +251,9 @@ WALK_CASES = [
     dict(max_pages=24, context=300),
     dict(max_pages=24, context=300, window=50),
     dict(max_pages=128, context=40, poison_tail=True),
+    # a window class's tables: the slots behind the window name the null
+    # page, and the first live page is not the table's first
+    dict(max_pages=24, context=300, window=100, slid_head=True),
 ]
 
 
