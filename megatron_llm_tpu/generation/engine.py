@@ -131,6 +131,7 @@ from megatron_llm_tpu.models.language_model import (
 )
 from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
+from megatron_llm_tpu.ops.pallas.paged_attention import tile_runs
 
 NULL_PAGE = 0
 
@@ -1761,6 +1762,17 @@ class ContinuousBatchingEngine:
                  "mlt_engine_ticks_total the share of ticks that paid "
                  "for an eviction. A patterned model also counts them by "
                  "page class (class=)")
+        self._m_paged_rows = reg.counter(
+            "mlt_engine_paged_rows_total",
+            help="live rows (decode, verify, prompt) of the launched "
+                 "ragged ticks")
+        self._m_paged_walks = reg.counter(
+            "mlt_engine_paged_walks_total",
+            help="page walks those rows cost the paged kernel a layer: a "
+                 "tile of 8 consecutive rows of one sequence at consecutive "
+                 "positions is walked once, any other row on its own "
+                 "(ops/pallas/paged_attention.tile_runs); rows over walks "
+                 "is how often the shared walk engages")
         self._pools = [self.pool] + (
             [self.wpool] if self.wpool is not None else [])
         self._m_dry_class = {
@@ -3918,6 +3930,23 @@ class ContinuousBatchingEngine:
                 self._m_plan_part[part].observe(sec)
             if dry:
                 self._m_dry_ticks.inc()
+            # the tick's rows as the program lays them out, by the kernel's
+            # own rule: a slot's verify rows and a request's prompt rows
+            # stand at consecutive positions of one table, and consecutive
+            # is all the rule reads of a position
+            at = np.arange(self.spec_k + 1)
+            on = np.zeros((self.max_slots, at.size), bool)
+            on[active] = at <= k_eff[active, None]
+            slot = 1 + np.arange(self.max_slots)[:, None]
+            pre = pre_index[:n_bucket]
+            shared, live = tile_runs(
+                np.concatenate([(on * slot).ravel(),
+                                np.where(pre >= 0, 1 + slot.size + pre, 0)]),
+                np.concatenate([(on * at).ravel(), pre_pos[:n_bucket]]),
+                np.concatenate([(on * (at + 1)).ravel(),
+                                pre_hor[:n_bucket]]))
+            self._m_paged_rows.inc(int(live.sum()))
+            self._m_paged_walks.inc(int(np.where(shared, 1, live).sum()))
         # the tick before lands while the device runs this one; this one
         # too where the host cannot know its outcome's shape beforehand
         lag = 0 if self.spec_k or did_lp else 1

@@ -15,11 +15,13 @@ per-sequence context lengths.
 Two implementations with the same fp32-softmax numerics:
 
 * ``ops/pallas/paged_attention.py`` — the TPU kernel: the block table is a
-  scalar-prefetch operand and the pool stays in HBM; one program per row
-  walks that row's context in blocks of several pages, copying the next
+  scalar-prefetch operand and the pool stays in HBM; one program per tile
+  of 8 rows walks a context in blocks of several pages, copying the next
   block's pages into VMEM while the current one is scored (no [b, max_seq]
   gather ever materializes; slots past the context are never looked up)
-  and the online-softmax accumulator carries across blocks.
+  and the online-softmax accumulator carries across blocks.  A tile of
+  consecutive rows of one sequence shares ONE walk and one matmul; any
+  other row walks alone.
 * the jnp path below — gathers the block-tabled pages into a dense
   [b, max_seq] view and reuses :func:`ops.attention.xla_attention`.  It
   matches the dense-cache decode path on the same context (the parity
@@ -156,9 +158,14 @@ def paged_attention_ragged(
     blocks (span k+1) and prefill chunks (span = chunk rows) — is flattened
     into R single-token rows, each carrying its own (position, kv horizon,
     block table).  Block tables arrive COMPRESSED: rows of one span share
-    one entry of ``tables`` and ``table_index`` names it, so a 64-row
-    chunk walks its pages once, not 64 times.  One launch serves any mix;
-    the composition lives entirely in the data-carried metadata, so
+    one entry of ``tables`` and ``table_index`` names it.  The fallback
+    gathers each table's pages once, so a 64-row chunk reads its pages
+    once, not 64 times; the kernel reads the same fact off the rows
+    (ops/pallas/paged_attention.tile_runs): 8 consecutive rows of one
+    table at consecutive positions share ONE page walk and one matmul a kv
+    head, so a 64-row chunk is walked 8 times, and a row with no such
+    neighbours (every decode row) once for itself.  One launch serves any
+    mix; the composition lives entirely in the data-carried metadata, so
     changing it never recompiles.
 
     Numerics contract (tests/test_ragged_tick.py): row ``i`` computes the

@@ -1,31 +1,46 @@
 """Pallas TPU paged-attention kernel (Ragged Paged Attention style).
 
-One kernel body serves the engine's three call shapes over the same page
-pool: a DECODE step (one query row per sequence), a PREFILL CHUNK (s query
-rows of one sequence against its block-tabled prefix) and a RAGGED tick
-(decode, verify and prefill rows flattened to single-token rows, each with
-its own table index and kv horizon).  The [b, max_pages*page_size] gather
-of the jnp path (ops/paged_attention.py) never materializes.
+One kernel, one call shape: a RAGGED batch of single-token rows (decode,
+verify and prefill rows, each with its own table index, position and kv
+horizon).  The engine's tick is that batch; a DECODE step (one row per
+sequence) and a PREFILL CHUNK (s rows of one sequence at consecutive
+positions) are ragged batches too, and are launched as such.  The [b,
+max_pages*page_size] gather of the jnp path (ops/paged_attention.py) never
+materializes.
 
-The grid runs over rows only; the pools stay in HBM and the block tables,
-table indices, positions and horizons are *scalar-prefetch* operands in
-SMEM.  Inside one program a loop walks that row's context in COMPUTE
-BLOCKS of several pages (_pages_per_step: 128 tokens or more), from the
-first block the sliding window still sees to the one that holds
-``min(horizon, last position + 1)``: the trip count is data, so table
-slots past a row's context are never looked up, a dead row (horizon 0)
-runs zero trips and writes zeros, and the cost of a call does not depend on
-the table's width (``engine_max_seq``).  Each step starts the copies of
-the NEXT block's pages (``page_id = tables[table_index[row], j]``, one copy
-a page, all kv heads of the page at once) into the other half of a
-double-buffered VMEM scratch, waits for the current half, and does one
-score matmul and one online-softmax update per kv head for the whole
-block.  The last block of a row is partial: pages past the context are not
-fetched, their columns are masked and their value rows zeroed.  The
-softmax state (running max m, normalizer l, fp32 accumulator) lives in
-VMEM scratch across the steps of one row — the blockwise scheme of
-ops/pallas/flash_attention.py with blocks of pages as KV blocks.  GQA is
-native (q grouped [b, nkv, rows*group, d], no K/V expansion).
+The grid runs over TILES of ``TILE`` (8) consecutive rows; the pools stay
+in HBM and the block tables, table indices, positions and horizons are
+*scalar-prefetch* operands in SMEM.  A PAGE WALK is a loop over a context
+in COMPUTE BLOCKS of several pages (_pages_per_step: 128 tokens or more),
+from the first block the sliding window still sees to the one that holds
+the last visible key: the trip count is data, so table slots past a
+context are never looked up, a dead row (horizon 0) runs zero trips and
+writes zeros, and the cost of a call does not depend on the table's width
+(``engine_max_seq``).  Each step starts the copies of the NEXT block's
+pages (``page_id = tables[table_index[row], j]``, one copy a page, all kv
+heads of the page at once) into the other half of a double-buffered VMEM
+scratch, waits for the current half, and does one score matmul and one
+online-softmax update per kv head for the whole block.  The last block of
+a walk is partial: pages past the context are not fetched, their columns
+are masked and their value rows zeroed.  The softmax state (running max m,
+normalizer l, fp32 accumulator) of the tile's rows lives in VMEM scratch —
+the blockwise scheme of ops/pallas/flash_attention.py with blocks of pages
+as KV blocks.  GQA is native (q grouped [tiles, nkv, TILE*group, d], no
+K/V expansion).
+
+WHO SHARES A WALK is read from the call's own data (:func:`tile_runs`, the
+one rule; the engine counts by it too): a tile whose rows are ONE RUN — all
+live, on one table, at consecutive positions: a prompt chunk's rows, a
+verify block that fills a tile — is walked ONCE, from its first row's
+window to its last row's position, with one score matmul and one value
+matmul a kv head for all ``TILE * group`` query rows, the causal (and
+window) mask and the softmax state per row.  A block outside one row's mask
+leaves that row's state as it was (alpha 1, p 0), so each row's result is
+what a walk of its own gives.  Any other tile — decode rows, a table each;
+a tile where one request's rows end and the next one's begin — walks its
+live rows one after another inside the program, ``group`` query rows a
+matmul: a row alone costs what it cost when the grid ran over rows.  Which
+of the two a tile takes is data, so a tick's composition never recompiles.
 
 Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, and
 the pool is STORED in such rows (ops/kv_quant.py owns the row): ``[pages,
@@ -59,6 +74,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -67,73 +83,71 @@ from megatron_llm_tpu.ops import kv_quant
 NEG_INF = -1e30
 
 
+# Query rows a program: the sublane count of a 32-bit tile, so that a row's
+# ``group`` query heads (padded to whole tiles) start on one.  It divides
+# the engine's slot counts and its prompt-row buckets, so a tick's prompt
+# rows fill whole tiles and none holds decode and prompt rows at once.
+TILE = 8
+
+
+def tile_runs(table_index, positions, horizons):
+    """THE grouping rule, a pure function of the call's data: which tiles
+    of ``TILE`` consecutive rows the kernel serves by one page walk.
+
+    ``[R]`` arrays (numpy, on the host; traced, in front of the kernel) ->
+    ``(shared, live)``, both ``[ceil(R / TILE)]``: ``shared[i]`` says that
+    tile ``i`` is ONE RUN — every row live (its horizon past its position),
+    all on one table, at consecutive positions — and ``live[i]`` counts its
+    rows with a horizon.  A run costs one walk, any other tile one walk a
+    live row: ``where(shared, 1, live)``.  Rows past the last whole tile
+    count as dead ones (the wrapper pads with them)."""
+    xp = jnp if any(isinstance(a, jax.Array)
+                    for a in (table_index, positions, horizons)) else np
+    pad = (0, -table_index.shape[0] % TILE)
+    idx, pos, hor = (xp.pad(a, pad).reshape(-1, TILE)
+                     for a in (table_index, positions, horizons))
+    run = ((hor > pos) & (idx == idx[:, :1])
+           & (pos - pos[:, :1] == np.arange(TILE)))
+    return run.all(axis=1), (hor > 0).sum(axis=1)
+
+
 def _paged_kernel(
     # scalar prefetch — all traced data, so one compiled launch serves any
     # tick composition
     tbl_ref,     # [T, max_pages] int32 block tables
     idx_ref,     # [b] int32 row -> table
-    pos_ref,     # [b] int32 position of the row's first query
+    pos_ref,     # [b] int32 the row's position
     hor_ref,     # [b] int32 kv horizon in tokens (0 = dead row)
+    run_ref,     # [b / TILE] int32: the tile is one run (tile_runs)
     base_ref,    # [1] int32 first page of the calling layer in the pool
     # q block, the pool in HBM [, its scales], out block, then scratch
     *refs,
-    scale: float,
     group: int,
     sliding_window: Optional[int],
     quantized: bool,
     paired: bool,
 ):
-    """One program per sequence: ``rows = s*group`` query rows per kv head,
-    row ``r`` at position ``pos0 + r // group`` — the causal mask is per ROW.
-    Decode and ragged calls have ``s == 1``.  ``paired``: a head's ``w``
-    key lanes are followed by its ``w`` value lanes; otherwise its ``w``
-    lanes are key and value at once (a latent row; a K|V pair of 64s)."""
+    """One program per TILE of rows: ``group`` query rows a kv head and
+    row (the heads of its group, padded to whole sublane tiles), scaled
+    and in float32.  ``paired``: a head's ``w`` key lanes are followed by
+    its ``w`` value lanes; otherwise its ``w`` lanes are key and value at
+    once (a latent row; a K|V pair of 64s)."""
     if quantized:
         (q_ref, kv_hbm, s_hbm, o_ref,
          kv_buf, sem, m_s, l_s, acc_s, s_buf) = refs
     else:
         q_ref, kv_hbm, o_ref, kv_buf, sem, m_s, l_s, acc_s = refs
     i = pl.program_id(0)
-    nkv, rows, w = q_ref.shape
+    nkv, _, w = q_ref.shape
     _, pps, page, _ = kv_buf.shape
     bk = pps * page
-    tbl = idx_ref[i]
-    pos0 = pos_ref[i]
-    # keys [kv_start, kv_end) are all any row of this program can see; a
-    # dead row (horizon 0) has none
-    kv_end = jnp.minimum(hor_ref[i], pos0 + rows // group)
-    kv_start = (0 if sliding_window is None
-                else jnp.maximum(pos0 - sliding_window + 1, 0))
-    blk0 = kv_start // bk
-    blk1 = (kv_end + bk - 1) // bk
     # storage heads a page's scale row holds: a key and a value per head
     n_scales = 2 * nkv
 
-    def page_id(blk, j):
-        # clamped: a block's last slots may lie past the table's width
-        return tbl_ref[tbl, jnp.minimum(blk * pps + j, tbl_ref.shape[1] - 1)]
-
-    def pages_of(blk, slot, start: bool):
-        """Start (or wait for) the copies of block ``blk``'s pages into
-        half ``slot``.  Pages past ``kv_end`` are never looked up."""
-        for j in range(pps):
-            @pl.when((blk * pps + j) * page < kv_end)
-            def _page():
-                # a wait needs the copy's shape only, not its source
-                pid = page_id(blk, j) if start else 0
-                copies = [(kv_hbm.at[pid + base_ref[0]], kv_buf, 0)]
-                if quantized:
-                    scale_rows = pl.ds(pid * n_scales // 128, 2)
-                    copies += [(s_hbm.at[scale_rows], s_buf, 1)]
-                for src, dst, s in copies:
-                    cp = pltpu.make_async_copy(
-                        src, dst.at[slot, j], sem.at[s, slot])
-                    cp.start() if start else cp.wait()
-
     def page_scales(slot, at, h, live):
-        """[1, bk] row of storage head ``h``'s per-page scales, 0 where
-        not live; ``at[j]`` is where page j's heads start in its two rows
-        of ``s_buf`` (_scale_rows)."""
+        """[1, bk] row of storage head ``h``'s per-page scales, 0 where not
+        live; ``at[j]`` is where page j's heads start in its two rows of
+        ``s_buf`` (_scale_rows)."""
         col_page = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page
         vec = jnp.zeros((1, bk), jnp.float32)
         for j in range(pps):
@@ -142,79 +156,139 @@ def _paged_kernel(
                 s_buf[slot, j, (at[j] + h) // 128, (at[j] + h) % 128], vec)
         return jnp.where(live, vec, 0.0)
 
-    def block(blk, _):
-        slot = blk % 2
+    def walk(at, rows: int, tbl, pos0, kv_end):
+        """ONE page walk for the ``rows`` query rows a kv head from ``at``
+        on: row ``r`` of them stands at position ``pos0 + r // group`` of
+        table ``tbl`` — the causal (and window) mask is per ROW.  Keys
+        ``[kv_start, kv_end)`` are all any of them can see; a block that
+        lies outside one row's mask leaves that row's state as it was."""
+        kv_start = (0 if sliding_window is None
+                    else jnp.maximum(pos0 - sliding_window + 1, 0))
+        blk0 = kv_start // bk
+        blk1 = (kv_end + bk - 1) // bk
+        rows_at = pl.ds(at, rows)
 
-        @pl.when(blk + 1 < blk1)
-        def _prefetch():
-            pages_of(blk + 1, 1 - slot, True)
+        def page_id(blk, j):
+            # clamped: a block's last slots may lie past the table's width
+            return tbl_ref[tbl, jnp.minimum(blk * pps + j,
+                                            tbl_ref.shape[1] - 1)]
 
-        pages_of(blk, slot, False)
-        first = blk * bk
-        q_pos = pos0
-        if rows > group:
-            q_pos += jax.lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0) // group
-        kv_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        # kv_end also covers the pages of this block that were not fetched
-        mask = kv_pos <= jnp.minimum(q_pos, kv_end - 1)
-        if sliding_window is not None:
-            mask = jnp.logical_and(mask, q_pos - kv_pos < sliding_window)
-        live_col = kv_pos < kv_end
-        live_row = first + jax.lax.broadcasted_iota(
-            jnp.int32, (bk, 1), 0) < kv_end
-        if quantized:
-            scales_at = [page_id(blk, j) * n_scales % 128
-                         for j in range(pps)]
-        for h in range(nkv):
-            k_lanes = pl.ds((2 * h if paired else h) * w, w)
-            v_lanes = pl.ds((2 * h + 1) * w, w) if paired else k_lanes
-            q = q_ref[h].astype(jnp.float32) * scale            # [rows, w]
-            k = kv_buf[slot, :, :, k_lanes].astype(jnp.float32).reshape(
-                bk, w)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)             # [rows, bk]
+        def pages_of(blk, slot, start: bool):
+            """Start (or wait for) the copies of block ``blk``'s pages into
+            half ``slot``.  Pages past ``kv_end`` are never looked up."""
+            def page_j(j, _):
+                @pl.when((blk * pps + j) * page < kv_end)
+                def _page():
+                    # a wait needs the copy's shape only, not its source
+                    pid = page_id(blk, j) if start else 0
+                    copies = [(kv_hbm.at[pid + base_ref[0]], kv_buf, 0)]
+                    if quantized:
+                        scale_rows = pl.ds(pid * n_scales // 128, 2)
+                        copies += [(s_hbm.at[scale_rows], s_buf, 1)]
+                    for src, dst, s in copies:
+                        cp = pltpu.make_async_copy(
+                            src, dst.at[slot, j], sem.at[s, slot])
+                        cp.start() if start else cp.wait()
+
+            # unrolled where it is lowered (a real loop over the pages
+            # costs the decode rows 10-34% on the chip), traced once
+            jax.lax.fori_loop(0, pps, page_j, None, unroll=True)
+
+        def block(blk, _):
+            slot = blk % 2
+
+            @pl.when(blk + 1 < blk1)
+            def _prefetch():
+                pages_of(blk + 1, 1 - slot, True)
+
+            pages_of(blk, slot, False)
+            first = blk * bk
+            q_pos = pos0
+            if rows > group:
+                q_pos += jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, 1), 0) // group
+            kv_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            # kv_end also covers the pages of this block that were not
+            # fetched
+            mask = kv_pos <= jnp.minimum(q_pos, kv_end - 1)
+            if sliding_window is not None:
+                mask = jnp.logical_and(mask, q_pos - kv_pos < sliding_window)
+            live_col = kv_pos < kv_end
+            live_row = first + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0) < kv_end
             if quantized:
-                # q . (k * scale) == (q . k) * scale: dequantize the
-                # scores' columns, not the page
-                s = s * page_scales(slot, scales_at, 2 * h, live_col)
-            s = jnp.where(mask, s, NEG_INF)
+                scales_at = [page_id(blk, j) * n_scales % 128
+                             for j in range(pps)]
+            for h in range(nkv):
+                k_lanes = pl.ds((2 * h if paired else h) * w, w)
+                v_lanes = pl.ds((2 * h + 1) * w, w) if paired else k_lanes
+                q = q_ref[h, rows_at, :]                        # [rows, w]
+                k = kv_buf[slot, :, :, k_lanes].astype(jnp.float32).reshape(
+                    bk, w)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [rows, bk]
+                if quantized:
+                    # q . (k * scale) == (q . k) * scale: dequantize the
+                    # scores' columns, not the page
+                    s = s * page_scales(slot, scales_at, 2 * h, live_col)
+                s = jnp.where(mask, s, NEG_INF)
 
-            m_prev = m_s[h]                                     # [rows, 1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_cur)
-            # fully-masked-so-far guard (flash_attention.py:_fwd_kernel):
-            # without it exp(NEG_INF - NEG_INF) = 1 would poison the
-            # accumulator
-            p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur))
-            l_s[h] = alpha * l_s[h] + jnp.sum(p, axis=1, keepdims=True)
-            m_s[h] = m_cur
-            v = k if not paired else kv_buf[
-                slot, :, :, v_lanes].astype(jnp.float32).reshape(bk, w)
-            # rows of pages not fetched hold whatever the buffer held:
-            # 0 * NaN would reach the accumulator
-            v = jnp.where(live_row, v, 0.0)
-            if quantized:
-                p = p * page_scales(slot, scales_at, 2 * h + 1, live_col)
-            acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                m_prev = m_s[h, rows_at, :]                     # [rows, 1]
+                m_cur = jnp.maximum(
+                    m_prev, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_cur)
+                # fully-masked-so-far guard (flash_attention.py:
+                # _fwd_kernel): without it exp(NEG_INF - NEG_INF) = 1 would
+                # poison the accumulator.  It is also what lets a run walk
+                # blocks that only some of its rows see: the others' alpha
+                # is 1 and their p is 0
+                p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur))
+                l_s[h, rows_at, :] = (alpha * l_s[h, rows_at, :]
+                                      + jnp.sum(p, axis=1, keepdims=True))
+                m_s[h, rows_at, :] = m_cur
+                v = k if not paired else kv_buf[
+                    slot, :, :, v_lanes].astype(jnp.float32).reshape(bk, w)
+                # rows of pages not fetched hold whatever the buffer held:
+                # 0 * NaN would reach the accumulator
+                v = jnp.where(live_row, v, 0.0)
+                if quantized:
+                    p = p * page_scales(slot, scales_at, 2 * h + 1, live_col)
+                acc_s[h, rows_at, :] = (
+                    acc_s[h, rows_at, :] * alpha + jax.lax.dot_general(
+                        p, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
 
-    @pl.when(blk1 > blk0)
-    def _walk():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-        pages_of(blk0, blk0 % 2, True)
-        jax.lax.fori_loop(blk0, blk1, block, None)
-        l = l_s[...]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_s[...] / l_safe).astype(o_ref.dtype)
+        @pl.when(blk1 > blk0)
+        def _walk():
+            pages_of(blk0, blk0 % 2, True)
+            jax.lax.fori_loop(blk0, blk1, block, None)
 
-    @pl.when(blk1 <= blk0)
-    def _dead():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    # a row no walk reaches (horizon 0) keeps this state: it writes zeros
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    row0 = i * TILE
+
+    @pl.when(run_ref[i] != 0)
+    def _run():
+        # the tile is one run: one walk, one matmul a kv head for all its
+        # rows, from its first row's window to its last row's position
+        walk(0, TILE * group, idx_ref[row0], pos_ref[row0],
+             pos_ref[row0] + TILE)
+
+    @pl.when(run_ref[i] == 0)
+    def _rows():
+        def row(t, _):
+            r = row0 + t
+            walk(pl.multiple_of(t * group, 8), group, idx_ref[r], pos_ref[r],
+                 jnp.minimum(hor_ref[r], pos_ref[r] + 1))
+
+        jax.lax.fori_loop(0, TILE, row, None)
+
+    l = l_s[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[...] = (acc_s[...] / l_safe).astype(o_ref.dtype)
 
 
 def _scale_rows(scale):
@@ -248,35 +322,49 @@ def _head_lanes(d: int, row: int, latent: bool):
     return (d, True) if d % 128 == 0 else (2 * d, False)
 
 
-def _paged_call(qg, pool, tables, table_index, positions, horizons,
-                page_base, *, group, scale, sliding_window, latent,
-                interpret):
-    """``qg`` [b, nkv, rows, d] kv-head-major query rows -> same shape.
+def _paged_call(q, pool, tables, table_index, positions, horizons,
+                page_base, *, scale, sliding_window, latent, interpret):
+    """``q`` [R, n_heads, d], one query row a ragged row -> same shape.
     ``pool`` is the flat ``[pages, page, H*d]`` pool of every layer
     (ops/kv_quant.layer_view; quantized: with the calling layer's ``[P,
     H]`` scales) and ``page_base`` the calling layer's first page in it."""
     quantized = kv_quant.is_quantized(pool)
     assert not (latent and quantized), "a latent pool is not quantized"
     arr = kv_quant.values_of(pool)
-    b, nkv, rows, d = qg.shape
+    r, n, d = q.shape
     _, page_size, row = arr.shape
+    nkv = 1 if latent else row // (2 * d)
+    g = n // nkv
     w, paired = _head_lanes(d, row, latent)
-    if w != d:
-        # the pair read whole: the query is zero on the value's lanes,
-        # so the scores see the key alone
-        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, w - d),))
+    # a row's group in whole sublane tiles of float32, so that a row of a
+    # tile is sliced out of the block where a tile starts; rows in whole
+    # TILEs (dead ones behind); the pair read whole (w != d): the query is
+    # zero on the value's lanes, so the scores see the key alone
+    gp = pl.cdiv(g, 8) * 8
+    tiles = pl.cdiv(r, TILE)
+    dead = (0, tiles * TILE - r)
+    table_index, positions, horizons = (
+        jnp.pad(a.astype(jnp.int32), dead)
+        for a in (table_index, positions, horizons))
+    run, _ = tile_runs(table_index, positions, horizons)
+    # kv-head-major query rows, scaled here: one program sees all of a kv
+    # head's query rows of its tile as ONE matmul operand
+    qg = jnp.pad((q.astype(jnp.float32) * scale).reshape(r, nkv, g, d),
+                 (dead, (0, 0), (0, gp - g), (0, w - d)))
+    qg = qg.reshape(tiles, TILE, nkv, gp, w).transpose(0, 2, 1, 3, 4)
 
     def lanes(n):
         return pl.cdiv(n, 128) * 128
 
     pps = _pages_per_step(page_size, row * arr.dtype.itemsize)
     buf_shape = (2, pps, page_size, row)
+    rows = TILE * gp
 
-    row_spec = pl.BlockSpec((None, nkv, rows, w),
-                            lambda i, tbl, idx, pos, hor, base: (i, 0, 0, 0))
+    tile_spec = pl.BlockSpec((None, nkv, rows, w),
+                             lambda i, *prefetch: (i, 0, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [row_spec, hbm_spec]
-    operands = [qg, arr]
+    in_specs = [tile_spec, hbm_spec]
+    operands = [qg.reshape(tiles, nkv, rows, w), arr]
     scratch = [
         pltpu.VMEM(buf_shape, arr.dtype),
         pltpu.SemaphoreType.DMA((2 if quantized else 1, 2)),
@@ -289,44 +377,46 @@ def _paged_call(qg, pool, tables, table_index, positions, horizons,
         operands += [_scale_rows(pool.scale)]
         scratch += [pltpu.SMEM((2, pps, 2, 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(b,),
+        num_scalar_prefetch=6,
+        grid=(tiles,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=tile_spec,
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
-        _paged_kernel, scale=scale, group=group,
-        sliding_window=sliding_window, quantized=quantized, paired=paired,
+        _paged_kernel, group=gp, sliding_window=sliding_window,
+        quantized=quantized, paired=paired,
     )
 
-    # VMEM, every last dim padded to 128 lanes: the q and out blocks (two
-    # of each, the pipeline's), the softmax state, both halves of the page
-    # buffer, and a step's [rows, block] fp32 temporaries (scores,
-    # probabilities, masks).  A ragged tick needs 1.1 MiB at Mistral's
-    # widths and 0.9 at Falcon's; Falcon's 64-row chunk (4544 rows a kv
-    # head) 38 MiB, over Mosaic's default of 16 — so the limit is stated
-    vmem = (4 * nkv * rows * lanes(w) * qg.dtype.itemsize
+    # VMEM, every last dim padded to 128 lanes: the float32 q and the out
+    # blocks (two of each, the pipeline's), the softmax state of the whole
+    # tile, both halves of the page buffer, and a step's [rows, block] fp32
+    # temporaries (scores, probabilities, masks) at a run's TILE * group
+    # rows.  A tile of 8 needs 4.4 MiB at Command A+'s widths (128 rows a
+    # kv head x 8), 5.2 at Falcon's (576 rows, blocks of 256 tokens), 3.8
+    # at the latent row's and 2.7 at Mistral's; a whole 64-row chunk a
+    # program would need 28 at Command A+'s and 41 at Falcon's, over
+    # Mosaic's default of 16 — the tile is what keeps every geometry under
+    # it, and the limit is stated all the same
+    vmem = (2 * nkv * rows * lanes(w) * (4 + q.dtype.itemsize)
             + nkv * rows * (2 * 128 + lanes(w)) * 4
             + math.prod(buf_shape) * arr.dtype.itemsize
             + 6 * rows * lanes(pps * page_size) * 4)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles, nkv, rows, w), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(2 * vmem, 16 << 20)),
         interpret=interpret,
         name="paged_attention",
-    )(tables.astype(jnp.int32), table_index.astype(jnp.int32),
-      positions.astype(jnp.int32), horizons.astype(jnp.int32),
-      jnp.asarray(page_base, jnp.int32).reshape(1), *operands)
+    )(tables.astype(jnp.int32), table_index, positions, horizons,
+      run.astype(jnp.int32), jnp.asarray(page_base, jnp.int32).reshape(1),
+      *operands)
+    out = out.reshape(tiles, nkv, TILE, gp, w).transpose(0, 2, 1, 3, 4)
     # the pair read whole: its value lanes are the output
-    return out if w == d or latent else out[..., d:]
-
-
-def _nkv(pool, d: int, latent: bool) -> int:
-    return 1 if latent else kv_quant.row_width(pool) // (2 * d)
+    return out.reshape(tiles * TILE, nkv, gp, w)[
+        :r, :, :g, (0 if w == d or latent else d):].reshape(r, n, -1)
 
 
 def paged_ragged_kernel(
@@ -344,14 +434,10 @@ def paged_ragged_kernel(
     interpret: bool = False,
 ) -> jax.Array:
     """ONE launch for a whole ragged tick; returns [R, 1, n_heads, d]."""
-    b, _, n, d = q.shape
-    nkv = _nkv(pool, d, latent)
-    g = n // nkv
-    out = _paged_call(
-        q.reshape(b, nkv, g, d), pool, tables, table_index,
-        positions, horizons, page_base, group=g, scale=scale,
-        sliding_window=sliding_window, latent=latent, interpret=interpret)
-    return out.reshape(b, 1, n, d)
+    return _paged_call(
+        q[:, 0], pool, tables, table_index, positions, horizons, page_base,
+        scale=scale, sliding_window=sliding_window, latent=latent,
+        interpret=interpret)[:, None]
 
 
 def paged_prefill_kernel(
@@ -366,20 +452,16 @@ def paged_prefill_kernel(
     page_base=0,
     interpret: bool = False,
 ) -> jax.Array:
-    """One prefill chunk; returns [b, s, n_heads, d]."""
+    """One prefill chunk; returns [b, s, n_heads, d].  A chunk is ``s``
+    ragged rows of one table at consecutive positions: runs."""
     b, s, n, d = q.shape
-    nkv = _nkv(pool, d, latent)
-    g = n // nkv
-    # kv-head-major query rows: one grid step sees all of a kv head's
-    # query rows for the chunk
-    qg = q.reshape(b, s, nkv, g, d).transpose(0, 2, 1, 3, 4)
-    out = _paged_call(
-        qg.reshape(b, nkv, s * g, d), pool, block_tables,
-        jnp.arange(b, dtype=jnp.int32), start, start + s, page_base,
-        group=g, scale=scale, sliding_window=sliding_window, latent=latent,
-        interpret=interpret)
-    return out.reshape(b, nkv, s, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, s, n, d)
+    positions = (start[:, None] + jnp.arange(s, dtype=jnp.int32)).reshape(-1)
+    return paged_ragged_kernel(
+        q.reshape(b * s, 1, n, d), pool, block_tables,
+        jnp.repeat(jnp.arange(b, dtype=jnp.int32), s), positions,
+        jnp.repeat(start + s, s), scale=scale,
+        sliding_window=sliding_window, latent=latent, page_base=page_base,
+        interpret=interpret).reshape(b, s, n, d)
 
 
 def paged_decode_kernel(

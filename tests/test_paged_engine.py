@@ -7,6 +7,7 @@ sampling is a function of (request seed, step) alone, not slot placement;
 (4) the compiled-program cache keys on config CONTENT, not object identity.
 """
 
+import functools
 import threading
 
 import jax
@@ -41,6 +42,7 @@ from megatron_llm_tpu.ops.paged_attention import (
     PagedState,
     paged_attention_decode,
 )
+from tools import tpu_kernel_check as kernel_check
 
 VOCAB = 67
 
@@ -133,6 +135,139 @@ def test_paged_kernels_interpret_match_jnp_path(case):
     tol = 1e-5 if case.get("dtype") == jnp.float32 else 2e-2
     for name, (pallas_fn, jnp_fn) in paged_case(0, **case).items():
         assert max_err(pallas_fn(True), jnp_fn()) < tol, name
+
+
+# the serving configurations' head geometries (tools/tpu_kernel_check.py),
+# the latent row of MLA (one head of 640 lanes, 32 query heads) and one
+# quantized pool
+RUN_GEOMETRIES = {
+    "falcon": kernel_check.FALCON,
+    "mistral": kernel_check.MISTRAL,
+    "commanda": kernel_check.COMMANDA,
+    "latent": kernel_check.LATENT,
+    "mistral-int8": dict(kernel_check.MISTRAL, kv_dtype="int8"),
+}
+RUN_SCENARIOS = {"tiles": False, "inside": False, "blocks": False,
+                 "verify": False, "window": True, "window_inside": True}
+
+
+@functools.lru_cache(maxsize=None)
+def _run_outputs(geometry: str, window: bool, fp32: bool = False):
+    """The kernel's and the gather path's outputs of one ``run_case`` call
+    (and at float32 the one-row walk's), made once for the scenarios that
+    share the call."""
+    pallas_fn, jnp_fn, scenarios = kernel_check.run_case(
+        3, window=window, **RUN_GEOMETRIES[geometry],
+        **(dict(dtype=jnp.float32) if fp32 else {}))
+    return (pallas_fn(True), jnp_fn(),
+            pallas_fn(True, spread=True) if fp32 else None, scenarios)
+
+
+@pytest.mark.parametrize("scenario", RUN_SCENARIOS)
+@pytest.mark.parametrize("geometry", RUN_GEOMETRIES)
+def test_paged_kernel_shared_walk_matches_jnp_path(geometry, scenario):
+    """A run of consecutive rows of one sequence, walked once (interpret
+    mode), == the gather path, row for row: a run that fills whole tiles,
+    one inside a tile beside another request's row and dead rows, one
+    across a compute-block boundary with two horizons, a verify block among
+    decode rows, and under a window with slid tables a run whose first rows
+    see a page its last rows do not."""
+    out, ref, _, scenarios = _run_outputs(geometry, RUN_SCENARIOS[scenario])
+    rows = scenarios[scenario]
+    assert kernel_check.max_err(out[rows], ref[rows]) < 2e-2
+    dead = np.setdiff1d(np.arange(out.shape[0]),
+                        np.concatenate(list(scenarios.values())))
+    assert not np.asarray(out[dead]).any(), "a dead row writes zeros"
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize(
+    "geometry", [g for g in RUN_GEOMETRIES if "int8" not in g])
+def test_paged_kernel_shared_walk_is_the_one_row_walk(geometry, window):
+    """At float32 a row's result from the shared walk is what the one-row
+    walk gives (every row launched in a tile of its own): the same keys in
+    the same blocks, so reduction order within a matmul is all that
+    differs; and both are the gather path's."""
+    out, ref, alone, scenarios = _run_outputs(geometry, window, fp32=True)
+    live = np.concatenate(list(scenarios.values()))
+    assert kernel_check.max_err(out[live], alone[live]) < 1e-6
+    assert kernel_check.max_err(out[live], ref[live]) < 1e-5
+
+
+@pytest.mark.parametrize("rows,shared,live", [
+    # (table, position, horizon) a row; a tile of 8 rows of one table at
+    # consecutive positions is one run, whatever their horizons' buckets
+    ([(3, 60 + i, 64 if i < 4 else 128) for i in range(8)], [True], [8]),
+    # decode rows: a table each
+    ([(1 + i, 500, 512) for i in range(8)], [False], [8]),
+    # one request's rows end and the next one's begin inside the tile
+    ([(2, 16 + i, 64) for i in range(5)] + [(3, 0, 64), (3, 1, 64),
+                                            (3, 2, 64)], [False], [8]),
+    # a dead row (table 0, horizon 0) breaks a run; it costs no walk
+    ([(2, 16 + i, 64) for i in range(7)] + [(0, 0, 0)], [False], [7]),
+    # a gap in the positions, and a horizon that cuts below the position
+    ([(2, i if i < 4 else i + 1, 64) for i in range(8)], [False], [8]),
+    ([(2, 64 + i, 128 if i else 64) for i in range(8)], [False], [8]),
+    # 64 slots' worth: two tiles of decode rows, three dead, then a
+    # 16-row chunk = two runs; 11 rows more fill a tile and start one
+    ([(1 + i, 99, 128) for i in range(13)] + [(0, 0, 0)] * 3
+     + [(40, 128 + i, 192) for i in range(16)]
+     + [(41, 7 + i, 64) for i in range(11)],
+     [False, False, True, True, True, False], [8, 5, 8, 8, 8, 3]),
+])
+def test_tile_runs_rule(rows, shared, live):
+    """The grouping rule by hand: which tiles are one run, and how many
+    walks the rest cost; the same from numpy and from traced arrays."""
+    from megatron_llm_tpu.ops.pallas.paged_attention import TILE, tile_runs
+
+    assert TILE == 8
+    cols = [np.array(c, np.int32) for c in zip(*rows)]
+    for arrays in (cols, [jnp.asarray(c) for c in cols]):
+        got_shared, got_live = tile_runs(*arrays)
+        assert np.asarray(got_shared).tolist() == shared
+        assert np.asarray(got_live).tolist() == live
+    assert isinstance(tile_runs(*cols)[0], np.ndarray)
+
+
+def test_engine_counts_rows_and_walks_by_the_kernels_rule(toy_model):
+    """mlt_engine_paged_rows_total / _walks_total: a launched tick's live
+    rows and the page walks they cost under ``tile_runs``, from the plan
+    alone.  8 slots, so a prompt's rows start on a tile: a prompt of 40
+    tokens fills 48 rows (whole pages) = 6 runs of 8; every decode row is
+    a walk of its own."""
+    from megatron_llm_tpu.observability import registry as registry_mod
+
+    reg = registry_mod.get_registry()
+
+    def read():
+        return (reg.counter("mlt_engine_paged_rows_total").value,
+                reg.counter("mlt_engine_paged_walks_total").value,
+                reg.counter("mlt_engine_ticks_total").value)
+
+    cfg, params = toy_model
+    eng = ContinuousBatchingEngine(cfg, params, ToyTokenizer(),
+                                   max_slots=8, max_seq=128)
+    rows0, walks0, ticks0 = read()
+    req = eng.submit([5 + i % 50 for i in range(40)], 6, top_k=1,
+                     termination_id=10 ** 9)
+    eng.run_until_idle()
+    req.result(timeout=5)
+    rows, walks, ticks = (b - a for a, b in zip((rows0, walks0, ticks0),
+                                                read()))
+    decode_rows = rows - 48
+    assert 0 < decode_rows <= ticks
+    assert walks == 6 + decode_rows
+    # a second prompt beside a decoding one, 20 tokens = 32 rows: 4 runs
+    rows0, walks0, _ = read()
+    eng.submit([7] * 3, 8, top_k=1, termination_id=10 ** 9)
+    eng.step(), eng.step(), eng.step()
+    rows1, walks1, _ = read()
+    eng.submit([9 + i % 40 for i in range(20)], 2, top_k=1,
+               termination_id=10 ** 9)
+    eng.run_until_idle()
+    rows2, walks2, _ = read()
+    assert (rows2 - rows1) - (walks2 - walks1) == 32 - 4
+    assert rows1 - rows0 == walks1 - walks0 + 16 - 2   # a page of 3 tokens
 
 
 def test_dense_vs_paged_model_forward_bitwise(toy_model):

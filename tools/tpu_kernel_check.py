@@ -21,16 +21,18 @@ Usage (through the chip tool; off-TPU it exits 2):
     python tools/tpu_kernel_check.py [--quick | --time]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
-kernel phase).  ``--time`` prints the paged kernel's ms a call at the two
-tick shapes the chip has seen, beside what its KV bytes need at the HBM
-peak, and checks nothing.  The full run adds the page-size/dtype matrix,
-block-size timing sweeps and a long-sequence (32K) memory-fit check.
+kernel phase).  ``--time`` prints the paged kernel's ms a call at the
+tick shapes the chip has seen (``TICKS``), whole and with the chunk's rows
+dead, beside what its KV bytes need at the HBM peak, and checks nothing.
+The full run adds the page-size/dtype matrix, block-size timing sweeps
+and a long-sequence (32K) memory-fit check.
 Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import sys
 import time
@@ -239,6 +241,124 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     }
 
 
+def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
+             kv_dtype: str = "bf16", dtype=jnp.bfloat16,
+             latent: bool = False, window: bool = False):
+    """One ragged call whose rows are the RUNS the kernel serves by one
+    page walk, and what stands in their way (ops/pallas/paged_attention.py
+    ``tile_runs``; ``T`` rows a tile).
+
+    Returns ``(pallas_fn, jnp_fn, scenarios)``: ``pallas_fn(interpret,
+    spread)`` calls the kernel wrapper — ``spread``: every row the first of
+    a tile of its own with dead rows behind it, which is the one-row walk —
+    ``jnp_fn()`` the gather path, both on every row of the call, and
+    ``scenarios`` names the live rows of each:
+
+    * ``tiles``: a run that fills two whole tiles;
+    * ``inside``: a run that starts and ends inside a tile, another
+      request's row and dead rows beside it;
+    * ``blocks``: a run of two tiles whose first crosses a compute-block
+      boundary, its rows' horizons on both sides of it;
+    * ``verify``: a run of three rows among decode rows of other tables;
+
+    and with ``window`` (a call of its own: the window is static), under a
+    window of two and a half pages, every table slid as a window page
+    class's is (the slots behind its first query's window name the null
+    page):
+
+    * ``window``: a run whose first rows see a page that its last rows no
+      longer see, a second tile of the run behind it;
+    * ``window_inside``: a short run, a decode row and dead rows in a tile.
+    """
+    import numpy as np
+
+    from megatron_llm_tpu.ops import kv_quant
+    from megatron_llm_tpu.ops import paged_attention as pa
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    rng = np.random.default_rng(seed)
+    T = pk.TILE
+    row = d if latent else 2 * nkv * d
+    item = 1 if kv_dtype != "bf16" else jnp.dtype(dtype).itemsize
+    bk = pk._pages_per_step(page, row * item) * page
+    w = 2 * page + page // 2 if window else None
+
+    def run(table, first, rows):
+        return [(table, first + i) for i in range(rows)]
+
+    dead = [None]
+    if window:
+        # the run's first window opens in the middle of page 3, its ninth
+        # row's in page 4
+        p0 = 3 * page + page // 2 + w - 1
+        scenes = {
+            "window": run(1, p0, 2 * T),
+            "window_inside": (dead + run(2, 5 * page + 3, 3)
+                              + [(3, 7 * page)] + dead * (T - 5)),
+        }
+    else:
+        scenes = {
+            "tiles": run(1, page, 2 * T),
+            "inside": (dead + run(2, 2 * page + 1, T - 3)
+                       + [(3, 4 * page + 6)] + dead),
+            "blocks": run(4, bk - T // 2, 2 * T),
+            "verify": ([(5, page - 1), (6, 3 * page)] + run(7, 50, 3)
+                       + [(8, 0), (9, bk + 1)] + dead),
+        }
+    rows = [r for scene in scenes.values() for r in scene]
+    at, scenarios = 0, {}
+    for name, scene in scenes.items():
+        scenarios[name] = np.array(
+            [at + i for i, r in enumerate(scene) if r is not None])
+        at += len(scene)
+    idx = np.array([r[0] if r else 0 for r in rows], np.int32)
+    pos = np.array([r[1] if r else 0 for r in rows], np.int32)
+    hor = np.where(idx > 0, (pos // 64 + 1) * 64, 0).astype(np.int32)
+    max_pages = int(pos.max()) // page + 2
+    num_pages = max_pages * (int(idx.max()) + 1) + 1
+    # page ids never repeat; page 0 stays the null page, table 0 the null
+    # table
+    tables = 1 + rng.permutation(num_pages - 1)[
+        :(idx.max() + 1) * max_pages].reshape(-1, max_pages)
+    tables[0] = 0
+    tables_k = tables.copy()
+    if window:
+        first = np.full(tables.shape[0], 1 << 30)
+        np.minimum.at(first, idx, np.where(idx > 0, pos, 1 << 30))
+        tables_k[(np.arange(max_pages) + 1) * page
+                 <= first[:, None] - w + 1] = 0
+    if latent:
+        pool = jnp.asarray(rng.normal(size=(num_pages, page, d)), dtype)
+    else:
+        heads = kv_quant.pack_kv(*(
+            jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
+            for _ in range(2)))
+        pool = (heads.reshape(num_pages, page, -1) if kv_dtype == "bf16"
+                else kv_quant.quantize_pages(heads, kv_dtype))
+    q = jnp.asarray(rng.normal(size=(len(rows), 1, n, d)), dtype)
+    kw = dict(scale=1.0 / d ** 0.5, sliding_window=w, latent=latent)
+
+    def pallas_fn(interpret=False, spread=False):
+        q_, meta = q, (idx, pos, hor)
+        if spread:
+            q_ = jnp.zeros((T * len(rows),) + q.shape[1:], dtype).at[::T].set(q)
+            meta = [np.zeros(T * len(rows), np.int32) for _ in range(3)]
+            for wide, a in zip(meta, (idx, pos, hor)):
+                wide[::T] = a
+        out = pk.paged_ragged_kernel(
+            q_, pool, jnp.asarray(tables_k, jnp.int32),
+            *(jnp.asarray(a) for a in meta), interpret=interpret, **kw)
+        return out[::T] if spread else out
+
+    def jnp_fn():
+        return pa.paged_attention_ragged(
+            q, pool, jnp.asarray(tables, jnp.int32),
+            *(jnp.asarray(a) for a in (idx, pos, hor)),
+            use_kernel=False, **kw)
+
+    return pallas_fn, jnp_fn, scenarios
+
+
 # the head geometries of the benchmark's serving configurations
 FALCON = dict(n=71, nkv=1, d=64, page=16)
 MISTRAL = dict(n=32, nkv=8, d=128, page=16)
@@ -257,7 +377,40 @@ WALK_CASES = [
 ]
 
 
-def tick_case(seed: int, name: str, width=None):
+# Command A+: 128 query heads on 8 kv heads of 128
+COMMANDA = dict(n=128, nkv=8, d=128, page=16)
+# JoyAI-LLM-Flash: 32 query heads on ONE latent row of 640 lanes
+LATENT = dict(n=32, nkv=1, d=640, page=16, latent=True)
+
+# name: geometry, slots, live decode rows, their contexts from .. to, where
+# the chunk starts, table width, pool pages, window
+TICKS = {
+    "falcon": (FALCON, 128, 49, 300, 700, 192, 128, 128 * 128 + 1, None),
+    "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 32 * 256 + 1, 4096),
+    # the agent cell's tick (PERF.md section 5): every row past 16k tokens,
+    # under the full layers' mask and under the window layers'
+    "commanda": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289, None),
+    "commanda_window": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289,
+                        4096),
+}
+
+
+@functools.lru_cache(maxsize=1)
+def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int):
+    """A tick's pool, made once for the cases that share it (a Command A+
+    pool is 0.4 G values)."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops import kv_quant
+
+    rng = np.random.default_rng([seed, num_pages])
+    return kv_quant.pack_kv(*(
+        jnp.asarray(rng.standard_normal(
+            size=(num_pages, page, nkv, d), dtype=np.float32), jnp.bfloat16)
+        for _ in range(2))).reshape(num_pages, page, -1)
+
+
+def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     """A ragged tick as the chip has seen it (PERF.md section 5): the
     arguments of ``paged_ragged_kernel`` and the KV bytes the tick needs
     (each table's keys once, K and V).
@@ -266,42 +419,53 @@ def tick_case(seed: int, name: str, width=None):
     slots; 49 decode rows at contexts 300-700 and the chunk are live, 79
     rows dead.  ``mistral``: 32 slots + 64 chunk rows = 96 rows over 256
     page slots, 8 kv heads; 24 decode rows at 256-1536, window 4096.
-    ``width`` overrides the table width (same contexts).
+    ``commanda``: 64 slots + 64 chunk rows over 1,112 page slots, 8 kv
+    heads of 128; 55 decode rows at 16.4k-17.4k and the chunk at 16.4k;
+    ``commanda_window`` the same under a window of 4,096 with the tables
+    of a window page class (the slots wholly behind a table's window name
+    the null page).  ``width`` overrides the table width (same contexts);
+    ``chunk_live`` false leaves the chunk's rows dead: the decode rows
+    alone.
     """
     import numpy as np
 
-    from megatron_llm_tpu.ops import kv_quant
-
-    geo, slots, live, lo, hi, chunk_at, slots_wide, window = {
-        "falcon": (FALCON, 128, 49, 300, 700, 192, 128, None),
-        "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 4096),
-    }[name]
+    (geo, slots, live, lo, hi, chunk_at, slots_wide, num_pages,
+     window) = TICKS[name]
     n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
     width = width or slots_wide
     rng = np.random.default_rng(seed)
-    num_pages = slots * slots_wide + 1
-    pool = kv_quant.pack_kv(*(
-        jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), jnp.bfloat16)
-        for _ in range(2))).reshape(num_pages, page, -1)
+    pool = _tick_pool(seed, num_pages, page, nkv, d)
     chunk = 64
     pos = np.zeros(slots + chunk, np.int64)
     idx = np.full(slots + chunk, slots + 1)
     rows = rng.permutation(slots)[:live]
     pos[rows] = rng.integers(lo, hi, size=live)
     idx[rows] = rows
-    pos[slots:] = chunk_at + np.arange(chunk)
-    idx[slots:] = slots
+    if chunk_live:
+        pos[slots:] = chunk_at + np.arange(chunk)
+        idx[slots:] = slots
     # one table a slot, the chunk's, the null table; pages drawn at random
     # over the pool as a long-running engine leaves them (drawn last: the
     # contexts do not depend on the width)
     tables = rng.integers(1, num_pages, size=(slots + 2, width))
     tables[-1] = 0
+    if window:
+        # a table's first query this tick: a decode row's own position,
+        # the chunk's first row
+        first = np.zeros(slots + 2, np.int64)
+        first[rows] = pos[rows]
+        first[slots] = chunk_at
+        behind = ((np.arange(width) + 1) * page
+                  <= first[:, None] - window + 1)
+        tables[behind] = 0
     hor = np.where(idx <= slots, (pos // 64 + 1) * 64, 0)
     q = jnp.asarray(rng.normal(size=(slots + chunk, 1, n, d)), jnp.bfloat16)
     visible = pos[rows] + 1
+    keys = chunk_at + chunk if chunk_live else 0
     if window:
         visible = np.minimum(visible, window)
-    keys = visible.sum() + chunk_at + chunk
+        keys = min(keys, window - 1 + chunk)
+    keys += visible.sum()
     args = (q, pool) + tuple(
         jnp.asarray(a, jnp.int32) for a in (tables, idx, pos, hor))
     kw = dict(scale=1.0 / d ** 0.5, sliding_window=window)
@@ -346,18 +510,41 @@ def paged_numerics(quick: bool):
             except Exception as exc:  # a compiler refusal is a FAIL line
                 check(f"paged {name} {tag}", False,
                       f"{type(exc).__name__}: {str(exc)[:300]}")
-    for name in ("falcon", "mistral"):
+    # the shared walk (run_case): every scenario's live rows against the
+    # gather path, at the serving configurations' geometries, the latent
+    # row's and on quantized pools
+    runs = [FALCON, MISTRAL, COMMANDA, LATENT, dict(MISTRAL, kv_dtype="int8")]
+    if not quick:
+        runs += [dict(FALCON, kv_dtype="int8"), dict(COMMANDA, kv_dtype="fp8")]
+    for i, case in enumerate(runs):
+        tag = " ".join(f"{k}={v}" for k, v in case.items())
+        for window in (False, True):
+            try:
+                pallas_fn, jnp_fn, scenarios = run_case(
+                    i, window=window, **case)
+                out, ref = pallas_fn(), jnp_fn()
+                for name, rows in scenarios.items():
+                    e = max_err(out[rows], ref[rows])
+                    check(f"paged run {name} {tag}", e < TOL,
+                          f"max_err={e:.2e}")
+            except Exception as exc:
+                check(f"paged run window={window} {tag}", False,
+                      f"{type(exc).__name__}: {str(exc)[:300]}")
+    for name in TICKS:
         args, kw, live, _ = tick_case(7, name)
         q, pool, tables, idx, pos, _ = args
         try:
             # a ragged row is the decode step at its position over its own
             # table; the ragged gather path scores every row against every
-            # table, which at these shapes does not fit the chip
-            e = max_err(
-                pk.paged_ragged_kernel(*args, **kw)[live],
-                pa.paged_attention_decode(
-                    q[live], pool, tables[idx[live]], pos[live],
+            # table, which at these shapes does not fit the chip, and at
+            # 17k tokens of context nor does the decode path's gather for
+            # every row at once
+            out = pk.paged_ragged_kernel(*args, **kw)
+            e = max(
+                max_err(out[rows], pa.paged_attention_decode(
+                    q[rows], pool, tables[idx[rows]], pos[rows],
                     use_kernel=False, **kw))
+                for rows in (live[i:i + 16] for i in range(0, len(live), 16)))
             check(f"paged tick {name}", e < TOL, f"max_err={e:.2e}")
         except Exception as exc:
             check(f"paged tick {name}", False,
@@ -387,7 +574,7 @@ def kernel_seconds(f, *args, kernel: str):
 
 
 def paged_timing():
-    """ms a call of the paged kernel alone at the two tick shapes, beside
+    """ms a call of the paged kernel alone at the tick shapes, beside
     the least time the chip's HBM (819 GB/s, TPU v5e) allows for the KV
     bytes the tick needs.  One program makes 24 calls, as a tick's layers
     do; the kernel's own device time is read from a profiler trace of it
@@ -398,8 +585,15 @@ def paged_timing():
     from megatron_llm_tpu.ops.pallas import paged_attention as pk
 
     calls = 24
-    for name, width in (("falcon", 128), ("falcon", 256), ("mistral", 256)):
-        args, kw, _, need = tick_case(7, name, width)
+    # every tick whole (Falcon's at two table widths), then its decode
+    # rows alone
+    cases = [(name, width, True) for name in TICKS
+             for width in ((128, 256) if name == "falcon" else (None,))]
+    cases += [(name, None, False) for name in TICKS]
+    for name, width, chunk_live in sorted(cases, key=lambda c: c[0]):
+        args, kw, _, need = tick_case(7, name, width, chunk_live)
+        width = args[2].shape[1]
+        name += "" if chunk_live else " (chunk dead)"
 
         def layers(q, *rest):
             def layer(i, acc):
