@@ -174,6 +174,12 @@ class ModelConfig:
     # (kv_lora_rank + qk_rope_head_dim values a token: ALL the cache holds),
     # decoupled RoPE on qk_rope_head_dim of the qk_nope_head_dim +
     # qk_rope_head_dim query dims, values of v_head_dim
+    # | 'retention': gated degree-2 power retention over the same q / k / v
+    # heads (ops/retention.py): no softmax, no key or value kept, a
+    # sequence's past is a float32 state of constant size a KV head; a
+    # per-head RMSNorm on q and k before RoPE and a log-sigmoid gate a KV
+    # head from the layer's normed input come with it (the degree, the
+    # feature layout and the state's dtype are the mechanism's, not flags)
     attention_type: str = "mha"
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
@@ -192,6 +198,10 @@ class ModelConfig:
         return self.attention_type == "mla"
 
     @property
+    def retention(self) -> bool:
+        return self.attention_type == "retention"
+
+    @property
     def layer_period(self) -> int:
         """Layers in one period of the layer pattern (1 = uniform)."""
         return len(self.sliding_window_layout or self.rope_layout or (0,))
@@ -208,8 +218,14 @@ class ModelConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     def finalize(self) -> None:
-        assert self.attention_type in ("mha", "mla"), (
+        assert self.attention_type in ("mha", "mla", "retention"), (
             f"unknown attention_type {self.attention_type!r}")
+        if self.retention:
+            assert (self.sliding_window_size is None
+                    and not self.sliding_window_layout
+                    and not self.bidirectional), (
+                "power retention is causal and has no window or pattern: "
+                "its state is the whole past")
         if self.mla:
             missing = [k for k in ("q_lora_rank", "kv_lora_rank",
                                    "qk_nope_head_dim", "qk_rope_head_dim",
@@ -1017,6 +1033,20 @@ ARCH_DEFAULTS = {
         moe_shared_experts=4,
         moe_shared_combination="average",
     ),
+    # Brumby (beyond-reference; manifestai's `brumby`): the Qwen3 block
+    # (RMSNorm, SwiGLU, GQA widths, per-head RMSNorm on q and k, RoPE at
+    # theta 1e6, untied head) whose every layer replaces softmax attention
+    # by gated degree-2 power retention (ops/retention.py)
+    "brumby": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=1_000_000.0,
+        attention_type="retention",
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1082,6 +1112,10 @@ MODEL_SIZES = {
                                  num_experts=64, moe_router_topk=6,
                                  moe_ffn_hidden_size=768,
                                  ffn_hidden_size=768, vocab_size=151936),
+    "brumby-14b": dict(num_layers=40, hidden_size=5120,
+                       num_attention_heads=40, num_attention_heads_kv=8,
+                       kv_channels=128, ffn_hidden_size=17408,
+                       max_position_embeddings=32768, vocab_size=151936),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,

@@ -83,6 +83,16 @@ resident, keep its batch full, and never compute the same prefix twice.
   refcounts, the commitment ledger, the prefix trie, COW and
   preemption-by-page-release all govern both models unchanged.
 
+* **A state in place of pages** (:class:`StatePool`): a model whose
+  layers keep a constant-size recurrent state a sequence (power retention,
+  ops/retention.py) holds ONE state slot from admission to its end, where
+  a paged model holds a growing page list: the allocator, the tables (one
+  entry wide), slots, chunked prefill, the tick and retirement are the
+  same code; prefill stops before a prompt's last token (:meth:`_fill_end`),
+  preemption drops the state and the resume prefills again, and the prefix
+  cache is off.  What pages alone carry refuses in a sentence
+  (:func:`refuse_state_cache`).
+
 Threading: ``submit`` may be called from any thread (e.g. concurrent HTTP
 handlers — generation/server.py); device work happens on whichever thread
 drives :meth:`step`, either the built-in background loop (:meth:`start`) or
@@ -261,6 +271,55 @@ def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
             "--spec_k 0 and --tick_pipeline_depth 0.")
 
 
+def refuse_state_cache(cfg, *, kv_dtype: str = "bf16", mesh=None,
+                       draft: bool = False, pipeline_depth: int = 0,
+                       handoff: bool = False,
+                       log_probs: bool = False) -> None:
+    """Power retention keeps ONE float32 state a sequence, of constant
+    size, in a state slot (:class:`StatePool`): no key, no value, no page.
+    What is written for pages says so at start-up (or at the request), in
+    a sentence, instead of failing inside a compile.  Admission, chunked
+    prefill, preemption (the state is dropped and the tokens are prefilled
+    again) and retirement DO carry it; the prefix cache is off for it, not
+    refused: a trie of pages has nothing to hold."""
+    m = cfg.model
+    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
+    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
+    why = None
+    if m.sliding_window_layout or m.dense_prefix_layers or m.mla:
+        why = ("a stack that mixes it with a page class (a layer pattern, "
+               "a dense prefix, latent attention): the engine serves one "
+               "kind of per-sequence memory a model")
+    elif kv_dtype != "bf16":
+        why = (f"--kv_dtype {kv_dtype}: the state is a float32 sum that is "
+               "decayed and added to at every token, and storing it lower "
+               "is a different result")
+    elif tp > 1:
+        why = (f"tensor-parallel serving (tp {tp}): the state pool is not "
+               "sharded over its KV heads")
+    elif pp > 1:
+        why = (f"pipeline-parallel serving (pp {pp}): the stage pipeline "
+               "hands a paged leaf from stage to stage")
+    elif draft:
+        why = ("--spec_k: a rejected draft token would have to roll the "
+               "state back, and nothing keeps the state before it")
+    elif pipeline_depth:
+        why = ("--tick_pipeline_depth: the chained tick freezes a finished "
+               "row by a null block table, and is not tested with a state")
+    elif handoff:
+        why = ("the cross-replica KV handoff: its wire format names pages "
+               "of keys and values")
+    elif log_probs:
+        why = ("return_log_probs (prompt scoring): the scoring chunk feeds "
+               "many tokens a row through a block table")
+    if why:
+        raise ValueError(
+            "power retention (attention_type 'retention') keeps a "
+            f"constant-size recurrent state a sequence, which {why} does "
+            "not carry yet. Serve this model on one chip with --kv_dtype "
+            "bf16, --spec_k 0 and --tick_pipeline_depth 0.")
+
+
 class PagedKVPool:
     """Device page pool + host refcounting allocator.
 
@@ -330,7 +389,18 @@ class PagedKVPool:
         # values; the lanes past ``latent_cache_width`` are zeros nobody
         # reads, 11% of the leaf).  Every other model: the K/V row
         self.latent = bool(m.mla)
-        if self.latent:
+        # power retention (:class:`StatePool`): a "page" is a sequence's
+        # whole state, float32 whatever the activations are
+        self.state = bool(m.retention)
+        if self.state:
+            from megatron_llm_tpu.ops import retention as ret_ops
+
+            refuse_state_cache(cfg, kv_dtype=kv_dtype, mesh=mesh,
+                               draft=draft_cfg is not None)
+            self.head_dim = m.kv_channels
+            kv = ret_ops.zero_state((layers, num_pages),
+                                    m.num_attention_heads_kv, self.head_dim)
+        elif self.latent:
             refuse_latent_cache(kv_dtype=kv_dtype, mesh=mesh,
                                 draft=draft_cfg is not None)
             # the logical view's head width: one head, the whole row
@@ -709,6 +779,37 @@ class PagedKVPool:
         self.kv = _install(self.kv, "")
         if self.draft_kv is not None:
             self.draft_kv = _install(self.draft_kv, "draft_")
+
+
+class StatePool(PagedKVPool):
+    """The pool of a model that keeps a recurrent STATE and no keys (power
+    retention, ops/retention.py): ``kv`` is ``ops/retention.State``, leaves
+    ``s [layers, slots + 1, nkv, d, D]`` and ``z [layers, slots + 1, nkv,
+    1, D]`` in float32, indexed by STATE SLOT.  The allocator is the page
+    pool's, a slot standing where a page stood: a sequence holds exactly
+    ONE from admission to its end, whatever its length, so with as many
+    slots as the engine has decode slots nothing ever runs dry, nothing is
+    granted while a sequence decodes, and nothing is shared, cached or
+    evicted.  Slot 0 is the null slot, as page 0 is the null page: a dead
+    row's table names it and the tick touches no state for it.  A slot is
+    not cleared when it changes hands: the first row of a sequence stands
+    at position 0, and the tick's program takes a zero state for the run
+    that starts there whatever the slot held (``ops/retention.tick_runs``:
+    no launch of its own)."""
+
+    def __init__(self, cfg, slots: int, page_size: int):
+        assert cfg.model.retention
+        super().__init__(cfg, slots + 1, page_size, page_class=None)
+
+    @property
+    def kv_statics(self) -> Tuple:
+        return ("kv", "state", str(self.kv.s.dtype), self.kv.s.shape[-1])
+
+    def kv_pool_bytes(self) -> int:
+        return sum(a.size * a.dtype.itemsize for a in self.kv)
+
+    def kv_scale_bytes(self) -> int:
+        return 0
 
 
 class _TrieNode:
@@ -1224,6 +1325,15 @@ class ContinuousBatchingEngine:
             draft=bool(pick(spec_k, "spec_k")),
             pipeline_depth=int(pick(tick_pipeline_depth,
                                     "tick_pipeline_depth")))
+        # a constant-size state a sequence (power retention) in place of
+        # pages: :class:`StatePool`
+        self.state = bool(cfg.model.retention)
+        if self.state:
+            refuse_state_cache(
+                cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
+                draft=bool(pick(spec_k, "spec_k")),
+                pipeline_depth=int(pick(tick_pipeline_depth,
+                                        "tick_pipeline_depth")))
         if cfg.model.mla:
             # before anything is placed or resolved: a sentence, not a
             # sharding error from the middle of start-up
@@ -1388,7 +1498,9 @@ class ContinuousBatchingEngine:
         # compressed-table capacity of the ragged program (one table row
         # per request; rows of a request share it)
         self._pre_tables_cap = self.prefill_rows // self.prefill_chunk + 1
-        self.pages_per_seq = -(-self.max_seq // self.page_size)
+        # a state class's "table" is one entry wide: the sequence's slot
+        self.pages_per_seq = (1 if self.state
+                              else -(-self.max_seq // self.page_size))
         num_pages = (num_pages or inf.kv_pool_pages
                      or self.max_slots * self.pages_per_seq + 1)
         # quantized paged KV (ISSUE 13, ops/kv_quant.py): int8/fp8 pages
@@ -1439,6 +1551,13 @@ class ContinuousBatchingEngine:
                                               self.window_pages_cap) + 1),
                 self.page_size, kv_dtype=self.kv_dtype,
                 layers=periods * len(win.places), page_class=win.name)
+        elif classes[0].state:
+            self.pool = StatePool(cfg, self.max_slots, self.page_size)
+            if use_cache:
+                print("[engine] the prefix cache is off for a state pool: "
+                      "a sequence's past is one recurrent state, and a "
+                      "trie of pages has nothing to hold", flush=True)
+            use_cache = False
         else:
             self.pool = PagedKVPool(cfg, num_pages, self.page_size,
                                     mesh=mesh, draft_cfg=self.draft_cfg,
@@ -1773,6 +1892,35 @@ class ContinuousBatchingEngine:
                  "positions is walked once, any other row on its own "
                  "(ops/pallas/paged_attention.tile_runs); rows over walks "
                  "is how often the shared walk engages")
+        # the state class's own (zero for a paged model): what the tick's
+        # state sweep ran on, counted on the host from each tick's plan
+        self.state_recomputed_tokens = 0
+        self._m_state = {
+            "rows": reg.counter(
+                "mlt_engine_state_rows_total",
+                help="live rows (decode and prompt) of the launched ticks "
+                     "of a state-class model (power retention)"),
+            "touches": reg.counter(
+                "mlt_engine_state_touches_total",
+                help="state reads and writes those rows cost a layer and "
+                     "KV head: ONE a run (a sequence's consecutive rows of "
+                     "one tick), so rows over touches is what a prompt run "
+                     "amortises; 1.0 for decode-only ticks"),
+            "resets": reg.counter(
+                "mlt_engine_state_resets_total",
+                help="runs that started a sequence (position 0): the "
+                     "slot's state taken as zero inside the tick's own "
+                     "program"),
+            "recomputed_tokens": reg.counter(
+                "mlt_engine_state_recomputed_tokens_total",
+                help="tokens prefilled again because a preemption dropped "
+                     "their state (a page pool resumes from the prefix "
+                     "cache instead)")}
+        reg.gauge("mlt_engine_state_pool_bytes",
+                  help="device bytes of the recurrent-state pool (float32 "
+                       "S and z of every layer, KV head and slot, the "
+                       "null slot included; 0 for a paged model)"
+                  ).set(self.pool.kv_pool_bytes() if self.state else 0)
         self._pools = [self.pool] + (
             [self.wpool] if self.wpool is not None else [])
         self._m_dry_class = {
@@ -2202,6 +2350,11 @@ class ContinuousBatchingEngine:
         if len(prompt) + max_new_tokens > self.max_seq:
             raise gen.InvalidRequest(
                 "Length of prompt + tokens_to_generate longer than allowed")
+        if self.state and kw.get("return_log_probs"):
+            try:
+                refuse_state_cache(self.cfg, log_probs=True)
+            except ValueError as e:
+                raise gen.InvalidRequest(str(e)) from None
         if self.wpool is not None and kw.get("return_log_probs"):
             raise gen.InvalidRequest(
                 "return_log_probs (prompt scoring) is not served for a "
@@ -2373,8 +2526,20 @@ class ContinuousBatchingEngine:
                       ).set(by_prio.get(prio, 0))
 
     def _max_pages_for(self, req: EngineRequest) -> int:
+        if self.state:
+            return 1      # its state slot, whatever its length
         total = min(len(req.prompt) + req.max_new_tokens, self.max_seq)
         return -(-total // self.page_size)
+
+    def _fill_end(self, prompt_len: int) -> int:
+        """Where prefill stops.  Pages: the prompt bucketed up to whole
+        pages (the padding's keys are never attended, and the first decode
+        row writes the last token's again, the same bits).  A state cannot
+        take a token twice: prefill ends BEFORE the last token, which the
+        first decode row feeds."""
+        if self.state:
+            return prompt_len - 1
+        return _bucket_up(prompt_len, self.page_size)
 
     def _sched_state(self, now: float) -> SchedulerState:  # holds _lock
         """Read-only snapshot for policy decisions (under _lock)."""
@@ -2576,8 +2741,8 @@ class ContinuousBatchingEngine:
         # token and would WRITE the final shared page -> copy-on-write
         cow = bool(matched) and covered == prompt_len
         n_keep = len(matched) - (1 if cow else 0)
-        fill_end = _bucket_up(prompt_len, ps)
-        suffix_pages = (fill_end - covered) // ps
+        fill_end = self._fill_end(prompt_len)
+        suffix_pages = 1 if self.state else (fill_end - covered) // ps
         held_core = n_keep + (1 if cow else 0) + suffix_pages
         extra = 1 if max_total > held_core else 0  # first decode page
         need_now = (1 if cow else 0) + suffix_pages + extra
@@ -2625,6 +2790,12 @@ class ContinuousBatchingEngine:
         self._slots[slot] = req
         self.prefix_hit_tokens += covered
         self.prefix_miss_tokens += prompt_len - covered
+        if self.state and req._preemptions:
+            # the preemption dropped the state: its tokens go through
+            # prefill again (a page pool takes them back out of the trie)
+            self.state_recomputed_tokens += fill_end
+            if obs_registry.publishing():
+                self._m_state["recomputed_tokens"].inc(fill_end)
         req._flight.note_hit_tokens(covered)
         req._flight.set_phase(
             "prefill", kind="resume" if req._preemptions else "admit",
@@ -2668,8 +2839,9 @@ class ContinuousBatchingEngine:
                 self.cow_copies += 1
                 if obs_registry.publishing():
                     self._m_cow.inc()
-            if req._fill_pos >= len(req.seq_tokens):
-                # fully served from cache: straight to decode (or, for
+            if req._fill_pos >= self._fill_end(len(req.seq_tokens)):
+                # fully served from cache (or a state's one-token prompt:
+                # nothing to prefill): straight to decode (or, for
                 # a prefill_only request, straight to handoff)
                 self._activate_or_handoff(req, req._slot)
             else:
@@ -3650,7 +3822,7 @@ class ContinuousBatchingEngine:
                 break  # table slots exhausted; the rest wait a tick
             seq = req.seq_tokens  # resumed requests re-prefill their tail
             prompt_len = len(seq)
-            fill_end = _bucket_up(prompt_len, ps)
+            fill_end = self._fill_end(prompt_len)
             pos = req._fill_pos
             if pos >= fill_end or used >= budget:
                 continue
@@ -3692,14 +3864,13 @@ class ContinuousBatchingEngine:
         """The half of a tick's prompt rows the host knows at dispatch:
         the packed requests' fill frontiers move to the planned ends, so
         the next plan packs the chunks after them."""
-        ps = self.page_size
         for req, start, end in spans:
             req._fill_pos = end
             rows = end - start
             self.prefill_tokens_computed += rows
             req._flight.event("prefill_chunk", start=start, end=end,
                               rows=rows,
-                              fill_end=_bucket_up(len(req.seq_tokens), ps))
+                              fill_end=self._fill_end(len(req.seq_tokens)))
             if obs_registry.publishing():
                 self._m_prefill_tokens.inc(rows)
 
@@ -3724,7 +3895,7 @@ class ContinuousBatchingEngine:
                 req._flight.add_prefill_compute(
                     tick_s * (end - start) / work_rows)
             seq = req.seq_tokens
-            if end >= _bucket_up(len(seq), ps):
+            if end >= self._fill_end(len(seq)):
                 self._prefill_q.remove(req)
                 if self.cache is not None:
                     self.cache.insert(seq, req._pages,
@@ -3828,6 +3999,11 @@ class ContinuousBatchingEngine:
                     no = self.ticks + len(self._inflight)
                     reqs = [self._slots[i] for i in active]
                     epochs = [r._preemptions for r in reqs]
+                    # decode rows at position 0 (a one-token prompt's
+                    # first): runs that start a sequence, as a prompt's
+                    # first chunk is (the state class's reset counter)
+                    starts = int(((self._positions + ahead)[active]
+                                  == 0).sum()) if self.state else 0
                     self.peak_active_slots = max(self.peak_active_slots,
                                                  len(active))
                     if spent:
@@ -3930,6 +4106,17 @@ class ContinuousBatchingEngine:
                 self._m_plan_part[part].observe(sec)
             if dry:
                 self._m_dry_ticks.inc()
+        if self.state and obs_registry.publishing():
+            # the state sweep's rows and runs, by the program's own rule
+            # (ops/retention.tick_runs): a decode row is a run of one, a
+            # request's prompt rows of one tick are one run however many
+            # chunks they fill, and a run at position 0 starts on zero
+            runs = {id(r): start for r, start, _ in reversed(spans)}
+            self._m_state["rows"].inc(len(active) + n_pre)
+            self._m_state["touches"].inc(len(active) + len(runs))
+            self._m_state["resets"].inc(
+                sum(start == 0 for start in runs.values()) + starts)
+        elif obs_registry.publishing():
             # the tick's rows as the program lays them out, by the kernel's
             # own rule: a slot's verify rows and a request's prompt rows
             # stand at consecutive positions of one table, and consecutive
@@ -4325,6 +4512,15 @@ class ContinuousBatchingEngine:
 
     # -- cross-replica KV handoff (ISSUE 19, serving/handoff/) -------------
 
+    def _refuse_handoff(self) -> None:
+        """The handoff's wire format names pages of keys and values: a
+        state, a latent row or a layer pattern says so in a sentence."""
+        if self.state:
+            refuse_state_cache(self.cfg, handoff=True)
+        if self.pool.latent:
+            refuse_latent_cache(handoff=True)
+        refuse_layer_pattern(self.cfg, handoff=True)
+
     def prefill_and_export(self, prompt, *, add_BOS: bool = False,
                            trace_id: str = "", timeout_s: float = 600.0):
         """Prefill ``prompt`` (str — tokenized exactly like
@@ -4345,9 +4541,7 @@ class ContinuousBatchingEngine:
 
         Returns ``(blob, info)`` — ``info`` has ``tokens`` / ``pages``
         / ``bytes`` / ``hit_tokens`` for the migration receipt."""
-        if self.pool.latent:
-            refuse_latent_cache(handoff=True)
-        refuse_layer_pattern(self.cfg, handoff=True)
+        self._refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         tok = self.tokenizer
@@ -4399,9 +4593,7 @@ class ContinuousBatchingEngine:
         parked in the prefix cache (e.g. a preempted request's finished
         pages).  Returns ``(blob, n_pages)``; ``n_pages`` may be 0 when
         nothing is cached."""
-        if self.pool.latent:
-            refuse_latent_cache(handoff=True)
-        refuse_layer_pattern(self.cfg, handoff=True)
+        self._refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         if self.cache is None:
@@ -4433,9 +4625,7 @@ class ContinuousBatchingEngine:
         so COW/refcount/eviction invariants hold unchanged.  Raises
         :class:`EngineOverloaded` (→ 503 + Retry-After) when the pool
         cannot hold the pages.  Returns the import receipt."""
-        if self.pool.latent:
-            refuse_latent_cache(handoff=True)
-        refuse_layer_pattern(self.cfg, handoff=True)
+        self._refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         payload = wire.decode_pages(blob)

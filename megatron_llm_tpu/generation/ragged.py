@@ -43,6 +43,22 @@ row of the SAME tick (its own chunk's prefix, or an earlier chunk of the
 same request packed into the same tick) — the property that lets the
 token-level prefill budget run multiple chunks per tick in one launch.
 
+A model that keeps a recurrent STATE a sequence and no keys (power
+retention, ops/retention.py) runs the same tick on another contract: a
+state cannot be addressed by position, so nothing "lands first".  A row's
+table is one entry wide and names its state SLOT (0: a dead row), and a
+RUN of rows (consecutive rows of one slot at consecutive positions:
+``ops/retention.tick_runs``, read from the ``table_index`` / ``positions``
+the tick already carries) reads the slot's state once, gives its rows
+their outputs in order, and writes the state once; a run at position 0
+starts on a zero state.  Two requests' runs are independent; a request's
+own rows of one tick are ONE run however many chunks they fill.  What a
+request's output is independent of is restated for this class: the same
+chunking of its prompt gives the same bits whatever else the ticks carry;
+ANOTHER chunking (the budget cut its prompt at other rows) sums the
+state's part and the run's part in another order, and its log-probs agree
+to float32 rounding (~1e-5), not bitwise.
+
 Key discipline is unchanged from speculative/verify.py: every random draw
 derives from ``base = fold_in(request_key, steps)`` fanned out through
 disjoint DRAFT/ACCEPT/EMIT streams; no key is ever consumed twice.

@@ -234,6 +234,10 @@ class MegatronServer:
             from megatron_llm_tpu.generation.engine import refuse_latent_cache
 
             refuse_latent_cache(handoff=True)
+        if role != "unified" and getattr(engine, "state", False):
+            from megatron_llm_tpu.generation.engine import refuse_state_cache
+
+            refuse_state_cache(engine.cfg, handoff=True)
         if role != "unified" and getattr(engine, "wpool", None) is not None:
             from megatron_llm_tpu.generation.engine import (
                 refuse_layer_pattern,

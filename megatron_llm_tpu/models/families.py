@@ -93,6 +93,19 @@ def validate_family(cfg: Config) -> Config:
                and m.moe_shared_combination == "average",
                "commanda averages its shared experts")
         _check(m.tie_embed_logits, "commanda ties its head to the embedding")
+    elif name == "brumby":
+        _check(m.retention, "brumby requires attention_type 'retention'")
+        _check(m.use_rms_norm and m.glu_activation == "swiglu",
+               "brumby uses RMSNorm and SwiGLU")
+        _check(not m.use_bias and not m.add_qkv_bias and not m.parallel_attn,
+               "brumby is a sequential block without biases (its gate's "
+               "bias is the layer's own)")
+        _check(m.position_embedding_type == "rotary",
+               "brumby keeps RoPE on q and k")
+        _check(m.num_experts is None and not m.tie_embed_logits,
+               "brumby is dense with an untied head")
+        _check(m.kv_channels % 8 == 0,
+               "brumby's feature map tiles a head in blocks of 8")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

@@ -37,6 +37,11 @@ its own ``'attention'`` subtree, n heads of nope + rope query dims:
      'kv_up':   {'kernel': [kv_lora_rank, n, nope + v]},
      'dense':   {'kernel': [n*v, h]}}
 
+Power retention (``attention_type == 'retention'``,
+:func:`retention_sublayer`) keeps the fused ``qkv`` and ``dense`` and adds
+``q_norm`` / ``k_norm`` (``{'scale': [d]}``, one RMSNorm a head, before
+RoPE) and ``gate`` (``{'kernel': [h, nkv], 'bias': [nkv]}``).
+
 A model with ``dense_prefix_layers`` holds two stacks: ``dense_layers``
 (the prefix, dense MLP) and ``layers`` (the scanned expert layers).
 
@@ -99,6 +104,8 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
             "dense": {"kernel": _normal(k[1], (n * d, h), out_std)},
         },
     }
+    if m.retention:
+        p["attention"].update(_init_retention_params(cfg, k[4]))
     if m.num_experts is not None and not dense_ffn:
         # MoE layer: router + expert FFN stack replaces the dense MLP
         # (beyond-reference — see models/moe.py)
@@ -163,6 +170,32 @@ def _init_mla_params(cfg, k_down: jax.Array, k_out: jax.Array,
         "kv_up": {"kernel": _normal(kkvu, (m.kv_lora_rank, n, nope + v),
                                     std)},
         "dense": {"kernel": _normal(k_out, (n * v, h), out_std)},
+    }
+
+
+# a key this many tokens back keeps between these shares of its weight
+# under the gate's bias alone (log sigmoid(b) ~ -exp(-b)): what a freshly
+# initialised retention layer remembers
+GATE_HORIZON = 2048
+GATE_KEEPS = (0.1, 0.9)
+
+
+def _init_retention_params(cfg, key: jax.Array) -> Params:
+    """The head norms and the gate.  The gate's bias is DRAWN so that the
+    gates sit near 1: with a zero bias a random gate halves the state at
+    every token, nothing older than ~20 tokens weighs anything, and a
+    fault in carrying the state across ticks would change no output."""
+    m = cfg.model
+    d, nkv = m.kv_channels, m.num_attention_heads_kv
+    k_w, k_b = jax.random.split(key)
+    lo, hi = (-jnp.log(-jnp.log(keep) / GATE_HORIZON) for keep in GATE_KEEPS)
+    return {
+        "q_norm": init_norm_params(d, True),
+        "k_norm": init_norm_params(d, True),
+        "gate": {"kernel": _normal(k_w, (m.hidden_size, nkv),
+                                   m.init_method_std),
+                 "bias": jax.random.uniform(k_b, (nkv,), jnp.float32,
+                                            lo, hi)},
     }
 
 
@@ -257,13 +290,18 @@ def layer_kinds(cfg) -> Tuple[LayerKind, ...]:
 class PoolClass(NamedTuple):
     """One page class of the paged pool: the layers of a period that need
     the same keys kept (``window`` of them; None = every key) and so share
-    pages, a leaf and a block table."""
+    pages, a leaf and a block table.  ``state``: the class keeps no keys
+    but one recurrent state a sequence (power retention), its leaf indexed
+    by state slot and its table one entry wide."""
 
     window: Optional[int]
     places: Tuple[int, ...]   # places in the period, in order
+    state: bool = False
 
     @property
     def name(self) -> str:
+        if self.state:
+            return "state"
         return "full" if self.window is None else "window"
 
 
@@ -276,7 +314,8 @@ def pool_classes(cfg) -> Tuple[PoolClass, ...]:
     windows = sorted({k.window for k in kinds},
                      key=lambda w: (w is not None, w))
     if len(windows) == 1:
-        return (PoolClass(None, tuple(range(len(kinds)))),)
+        return (PoolClass(None, tuple(range(len(kinds))),
+                          bool(cfg.model.retention)),)
     return tuple(
         PoolClass(w, tuple(j for j, k in enumerate(kinds) if k.window == w))
         for w in windows)
@@ -605,6 +644,92 @@ def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LayerPool,
 
 
 
+@jax.named_scope("attention")
+def retention_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
+                       kv_cache=None, paged=None):
+    """Gated degree-2 power retention (ops/retention.py) in place of
+    softmax attention: ``q, k <- RoPE(RMSNorm_d(.))`` a head, ``l =
+    log sigmoid(x W_g + b_g)`` a KV head, then
+
+    * no cache (the trainer, the dense forward): the chunked form from a
+      zero state, differentiable;
+    * ``paged`` (every row of the engine's tick): ``kv_cache`` is a
+      :class:`LayerPool` over the STATE pool (``ops/retention.State``
+      leaves ``[layers, slots + 1, nkv, ...]``, float32), a row's table
+      holds its state SLOT (0: a dead row), and the tick's rows sweep the
+      pool once: a run of rows of one sequence reads its slot's state
+      once and writes it once (the Pallas kernel on a TPU target, else
+      ``retention_tick``).
+
+    Returns (output [b, s, h], the updated pool or None)."""
+    from megatron_llm_tpu.ops import retention as ret
+    from megatron_llm_tpu.parallel.tp import (
+        apply_column_parallel,
+        apply_row_parallel,
+    )
+
+    m = cfg.model
+    b, s, _ = x.shape
+    n, nkv, d = m.num_attention_heads, m.num_attention_heads_kv, m.kv_channels
+    linear = _linear_impl(cfg)
+    with jax.named_scope("retention"):
+        q, k, v = split_qkv(
+            apply_column_parallel(cfg, p["qkv"], x, linear), n, nkv, d)
+        eps = m.layernorm_epsilon
+        q = norm(q.astype(jnp.float32), p["q_norm"], eps, True)
+        k = norm(k.astype(jnp.float32), p["k_norm"], eps, True)
+        cos, sin = rope
+        q = apply_rotary_emb(q, cos, sin, position_ids)
+        k = apply_rotary_emb(k, cos, sin, position_ids)
+        log_decay = jax.nn.log_sigmoid(
+            x.astype(jnp.float32) @ p["gate"]["kernel"].astype(jnp.float32)
+            + p["gate"]["bias"].astype(jnp.float32))           # [b, s, nkv]
+        new_pool = None
+        if paged is not None:
+            assert s == 1 and paged.table_index is not None, (
+                "power retention is served by the ragged tick alone: one "
+                "row a token, its state slot in its table")
+            pool, layer = kv_cache
+            slots = paged.block_tables[paged.table_index, 0]
+            ctx, new_pool = _retention_sweep(cfg)(
+                q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], pool, slots,
+                paged.positions, layer)
+            ctx = ctx[:, None]
+        else:
+            assert kv_cache is None, (
+                "power retention decodes through the engine's state pool "
+                "only: the dense incremental cache holds K/V heads")
+            ctx = ret.retention_chunked(q, k, v, log_decay)
+    from jax.ad_checkpoint import checkpoint_name
+
+    ctx = checkpoint_name(ctx.astype(x.dtype), "attn_out")
+    out = apply_row_parallel(cfg, p["dense"], ctx.reshape(b, s, n * d),
+                             linear)
+    return out, new_pool
+
+
+def _retention_sweep(cfg):
+    """The tick's state sweep: the Pallas kernel where the program is
+    compiled for a TPU and the feature rows are whole lanes, else the
+    ``jnp`` form; said once while tracing, as the attention paths are."""
+    from megatron_llm_tpu.core.parallel_state import target_platform
+    from megatron_llm_tpu.ops import retention as ret
+    from megatron_llm_tpu.ops.pallas.retention import retention_sweep
+
+    target = target_platform()
+    refusal = None
+    if not cfg.training.use_flash_attn:
+        refusal = "use_flash_attn is off"
+    elif target != "tpu":
+        refusal = f"target platform is {target}"
+    elif ret.feature_dim(cfg.model.kv_channels) % 128:
+        refusal = (f"head_dim {cfg.model.kv_channels}: its feature rows "
+                   "are not whole 128-lane groups")
+    attn_ops.announce_path("retention_tick", "jnp" if refusal else "pallas",
+                           refusal or "")
+    return retention_sweep if refusal is None else ret.retention_tick
+
+
 def cross_attention_sublayer(
     cfg,
     p: Params,
@@ -727,6 +852,15 @@ def block_forward(
             "latent attention: no cp token order, bias or attention dropout")
         attn_out, new_cache = mla_sublayer(
             cfg, p["attention"], ln1, rope, position_ids, segment_ids,
+            kv_cache=kv_cache, paged=paged)
+    elif m.retention:
+        assert token_idx is None and attn_bias is None and (
+            segment_ids is None) and (
+            deterministic or not m.attention_dropout), (
+            "power retention: no cp token order, bias, packed segments or "
+            "attention dropout")
+        attn_out, new_cache = retention_sublayer(
+            cfg, p["attention"], ln1, rope, position_ids,
             kv_cache=kv_cache, paged=paged)
     else:
         attn_out, new_cache = attention_sublayer(

@@ -384,6 +384,55 @@ def test_aot_latent_tick_compiles_at_published_widths():
     assert stats.temp_size_in_bytes < 1 << 30
 
 
+def test_aot_state_tick_compiles_at_published_widths():
+    """The ragged tick of Brumby-14B at its published widths (one layer of
+    the cell's four, the whole 151,936-row vocabulary, 40 slots, abstract
+    parameters) compiles for one v5e: Mosaic takes the state sweep's kernel
+    with a KV head's whole float32 state ``[128, 8704]`` as its block, the
+    pool (two leaves, slot-indexed) is updated in place, and the program's
+    temporaries are the feature rows and the logits, not a copy of the
+    pool."""
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+    from megatron_llm_tpu.ops import retention as ret
+
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    cfg = make_config("brumby-14b", num_layers=1, params_dtype="bfloat16",
+                      seq_length=6144)
+    m = cfg.model
+    slots, pre = 40, 64
+    big_d = ret.feature_dim(m.kv_channels)
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    lead = (m.num_layers, slots + 1, m.num_attention_heads_kv)
+    pool = ret.State(S((*lead, m.kv_channels, big_d), jnp.float32),
+                     S((*lead, 1, big_d), jnp.float32))
+    pool_bytes = sum(np.prod(a.shape) * 4 for a in pool)
+    with global_mesh(mesh):
+        params = jax.eval_shape(
+            functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda a: S(a.shape, jnp.bfloat16), params)
+        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        lowered = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, pool, S((slots, 1), jnp.int32),
+            S((slots,), jnp.int32), S((slots,), jnp.int32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), S((pre,), jnp.int32),
+            S((pre,), jnp.int32), S((2, 1), jnp.int32),
+            S((pre,), jnp.int32), S((pre,), jnp.int32))
+        assert "retention_sweep" in lowered.as_text()
+        stats = lowered.compile().memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes      # in place
+    assert stats.temp_size_in_bytes < pool_bytes // 2   # and never copied
+
+
 def test_aot_two_class_tick_compiles_at_published_widths():
     """The ragged tick of Command A+ at its published widths (one period:
     three window layers and the full one; 16 of 128 experts held; abstract

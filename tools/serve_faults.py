@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""What a patterned serving cell's reference comparison reads and what it is
-known to fail, at one seed and with no measured window.
+"""What a serving cell's reference comparison reads and what it is known to
+fail, at one seed and with no measured window.
 
     chiprun -- python tools/serve_faults.py --workload commanda_plus_agent_16k --seed N
+    chiprun -- python tools/serve_faults.py --workload brumby14b_longgen_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -20,7 +21,9 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   with it).  The faults are the three choices the reference module exposes
   (``benchmark/reference/commanda_block.py``): window layers that see every
   key, rotation on the full layer, the shared experts summed and not
-  averaged.
+  averaged; and the three of ``benchmark/reference/brumby_block.py``: the
+  gate ignored (no decay), degree 1, the normaliser dropped.  A fault is
+  tried where the cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
 readings and the others over a dozen seeds; an entry other than ``program``
@@ -44,6 +47,10 @@ FAULTS = {
     "window_layers_see_every_key": ("window_of", lambda model, kind: None),
     "rotation_on_the_full_layer": ("rotates", lambda model, kind: True),
     "shared_experts_summed": ("shared_scale", lambda model: 1.0),
+    "gate_ignored": ("log_decay", lambda gate, u: 0.0 * (
+        u @ gate["kernel"].astype(u.dtype))),
+    "degree_one": ("degree", lambda model: 1),
+    "normaliser_dropped": ("normalised", lambda model: False),
 }
 
 

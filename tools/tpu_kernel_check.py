@@ -18,12 +18,17 @@ check has always used.  Quantized pools share one dequantized value per
 element on both sides, so the same bound holds.
 
 Usage (through the chip tool; off-TPU it exits 2):
-    python tools/tpu_kernel_check.py [--quick | --time]
+    python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
 kernel phase).  ``--time`` prints the paged kernel's ms a call at the
 tick shapes the chip has seen (``TICKS``), whole and with the chunk's rows
 dead, beside what its KV bytes need at the HBM peak, and checks nothing.
+``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
+to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
+decode rows and one 64-row prompt run) on a small pool, and with ``--time``
+prints the kernel's ms a call and GB/s at the cell's pool size, apart from
+a whole tick.
 The full run adds the page-size/dtype matrix, block-size timing sweeps
 and a long-sequence (32K) memory-fit check.
 Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
@@ -617,6 +622,94 @@ def paged_timing():
               flush=True)
 
 
+BRUMBY_TICKS = {
+    # name: (decode rows, rows of one prompt run behind them)
+    "40 decode rows": (40, 0),
+    "39 decode rows + one 64-row run": (39, 64),
+}
+
+
+def _brumby_tick(seed: int, decode: int, run: int, slots: int, layers: int):
+    """A Brumby-14B tick's operands (40 query / 8 KV heads of 128): every
+    decode row its own slot at its own position, the prompt run in the
+    last slot from position 128 on, on a pool of noise."""
+    from megatron_llm_tpu.ops import retention as ret
+
+    n, nkv, d = 40, 8, 128
+    rows = decode + run
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    norm = lambda t: t * jax.lax.rsqrt(                     # noqa: E731
+        jnp.mean(t * t, -1, keepdims=True))
+    q = norm(jax.random.normal(ks[0], (rows, n, d)))
+    k = norm(jax.random.normal(ks[1], (rows, nkv, d)))
+    v = jax.random.normal(ks[2], (rows, nkv, d))
+    ld = jax.nn.log_sigmoid(jax.random.normal(ks[3], (rows, nkv)) + 8.0)
+    slot = jnp.concatenate([1 + jnp.arange(decode),
+                            jnp.full((run,), slots)]).astype(jnp.int32)
+    pos = jnp.concatenate([100 + 7 * jnp.arange(decode),
+                           128 + jnp.arange(run)]).astype(jnp.int32)
+    big_d = ret.feature_dim(d)
+    pool = ret.State(
+        jax.random.normal(ks[4], (layers, slots + 1, nkv, d, big_d)),
+        100.0 + jax.random.uniform(ks[5], (layers, slots + 1, nkv, 1, big_d)))
+    return (q, k, v, ld), pool, (slot, pos)
+
+
+def brumby_check(timed: bool):
+    """The state sweep compiled against ``ops/retention.retention_tick`` on
+    the same operands; ``timed``: its device time a call at the cell's pool
+    (40 slots, 4 layers), and the bytes it moved over that."""
+    import statistics
+
+    from benchmark.lib import flops_retention
+    from megatron_llm_tpu.ops import retention as ret
+    from megatron_llm_tpu.ops.pallas import retention as rk
+
+    jnp_form = jax.jit(
+        lambda r, p, a: ret.retention_tick(*r, p, *a, layer=0))
+    kernel = jax.jit(
+        lambda r, p, a: rk.retention_sweep(*r, p, *a, jnp.int32(0)))
+    for name, (decode, run) in BRUMBY_TICKS.items():
+        rows, pool, at = _brumby_tick(11, decode, run, slots=40, layers=1)
+        want_y, want = jnp_form(rows, pool, at)
+        got_y, got = kernel(rows, pool, at)
+        err = max_err(got_y, want_y)
+        live = sorted(set(int(s) for s in at[0]))
+        s_err = max(max_err(a[0, live], b[0, live])
+                    / float(jnp.abs(b[0, live]).max())
+                    for a, b in zip(got, want))
+        check(f"brumby sweep {name}", err < 2e-3 and s_err < 1e-4,
+              f"max |dy| {err:.2e}, state rel {s_err:.2e}")
+        if not timed:
+            continue
+        calls = 4
+        rows, pool, at = _brumby_tick(11, decode, run, slots=40, layers=calls)
+
+        def layers(r, p, a):
+            def layer(i, carry):
+                acc, p = carry
+                y, p = rk.retention_sweep(*r, p, *a, i)
+                return acc + y, p
+            return jax.lax.fori_loop(
+                0, calls, layer, (jnp.zeros(r[0].shape, jnp.float32), p))
+
+        f = jax.jit(layers)  # graftcheck: noqa[recompile-hazard]
+        t = statistics.median(
+            kernel_seconds(f, rows, pool, at, kernel="retention_sweep"))
+        runs = decode + (1 if run else 0)
+        # a run reads and writes its slot's state once: as stored (the
+        # tiled layout), and what the minimal symmetric one would need
+        moved = runs * 2 * sum(a.nbytes // (a.shape[0] * a.shape[1]) for a in pool)
+        need = runs * 2 * flops_retention.state_bytes(
+            {"head_dim": 128, "num_key_value_heads": 8})
+        print(f"TIME brumby sweep {name}: {t * 1e3:.3f} ms a call on the "
+              f"device, {runs} runs of {decode + run} rows, "
+              f"{moved / 1e9:.3f} GB of state moved ({moved / t / 1e9:.1f} "
+              f"GB/s), {need / 1e9:.3f} GB needed in the minimal layout "
+              f"({100 * need / 819e9 / t:.2f}% of the bandwidth roofline)",
+              flush=True)
+
+
 def rmsnorm_check():
     from megatron_llm_tpu.ops.norms import rms_norm
     from megatron_llm_tpu.ops.pallas.rmsnorm import fused_rms_norm
@@ -763,6 +856,10 @@ def main():
     ap.add_argument("--time", action="store_true",
                     help="time the paged kernel alone at the tick shapes "
                          "the chip has seen, and nothing else")
+    ap.add_argument("--brumby", action="store_true",
+                    help="the retention state sweep at the Brumby-14B tick "
+                         "shapes against its jnp form (with --time: its "
+                         "ms a call and GB/s), and nothing else")
     args = ap.parse_args()
 
     from megatron_llm_tpu.utils.platform import enable_compilation_cache
@@ -776,6 +873,11 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
+    if args.brumby:
+        brumby_check(args.time)
+        print(f"\n{len(FAILURES)} failures"
+              + (f": {FAILURES}" if FAILURES else ""))
+        sys.exit(1 if FAILURES else 0)
     if args.time:
         paged_timing()
         return
