@@ -216,7 +216,124 @@ def init_stacked_layers(cfg, key: jax.Array, num_layers: Optional[int] = None,
 # ---------------------------------------------------------------------------
 
 
+class StackedLinear(NamedTuple):
+    """A dense projection of every layer of a stack (its ``kernel`` ``[L,
+    in, out]`` or GLU ``[L, in, 2, out]``, or the int8 pair, and its
+    ``bias``) and the layer whose projection is meant: what the serving
+    tick hands :func:`_linear` instead of a scanned slice
+    (``transformer_forward``)."""
+
+    stack: Params
+    layer: jax.Array
+
+
+# the projections every sublayer runs through ``_linear``: the leaves the
+# serving tick reads from the stack (the others' kernels -- a router, a
+# retention gate, MLA's ``kv_up`` -- are read by name and ride the scan)
+TICK_LINEARS = ("qkv", "dense", "fc1", "fc2", "q_down", "q_up", "kv_down")
+
+
+def _is_linear(node) -> bool:
+    return isinstance(node, dict) and ("kernel" in node or "kernel_q" in node)
+
+
+def _take_linears(tree: Params) -> Tuple[Params, Params]:
+    """(the dense projections of a stacked-layer tree, at their places;
+    the tree without them).  The routed experts' GEMMs are grouped ones
+    with a stack of their own (models/moe.StackedExperts)."""
+    taken, rest = {}, {}
+    for name, node in tree.items():
+        if name in TICK_LINEARS and _is_linear(node):
+            taken[name] = node
+        elif isinstance(node, dict) and name != "experts":
+            sub, left = _take_linears(node)
+            if sub:
+                taken[name] = sub
+            rest[name] = left
+        else:
+            rest[name] = node
+    return taken, rest
+
+
+def _put_linears(layer_params: Params, linears: Params,
+                 layer: jax.Array) -> Params:
+    """A layer's params with every projection of ``linears`` at its place
+    as ``StackedLinear(stack, layer)``."""
+    out = dict(layer_params)
+    for name, node in linears.items():
+        if _is_linear(node):
+            out[name] = StackedLinear(node, layer)
+        else:
+            out[name] = _put_linears(layer_params.get(name, {}), node, layer)
+    return out
+
+
+def _reads_pairs(x: jax.Array, leaf: jax.Array) -> bool:
+    """Whether a GLU ``fc1`` stack goes to the kernel that reads it in
+    place (ops/pallas/stacked_linear.py); said once while tracing, as the
+    attention paths are."""
+    from megatron_llm_tpu.core import parallel_state
+    from megatron_llm_tpu.ops.pallas import stacked_linear
+
+    target = parallel_state.target_platform()
+    why = stacked_linear.refusal(x, leaf)
+    if target != "tpu":
+        why = f"target platform is {target}"
+    elif (parallel_state.mesh_is_initialized()
+          and parallel_state.get_global_mesh().size > 1):
+        # a pallas_call is one device's program: GSPMD cannot partition it
+        # over a tp-sharded stack, so a mesh keeps XLA's slice and re-layout
+        why = "the stack is sharded over a mesh"
+    attn_ops.announce_path("glu_fc1_stack", "xla" if why else "pallas",
+                           why or "")
+    return why is None
+
+
+def _stacked_linear(p: StackedLinear, x: jax.Array) -> jax.Array:
+    """``_linear`` against layer ``p.layer`` of a stack, the weights read
+    where they lie (PERF.md section 6, PR 42: what the compiled tick does
+    with each form).  One rule, two readings of the operand by its shape:
+
+    * ``[L, in, out]``: XLA's own dot fuses the ``dynamic-slice`` of the
+      stack into its read; what made it copy the slice out first (and
+      transposed) was the consumer's reshape of the OUTPUT (the q/k/v
+      split), which it folds into the dot as extra weight dimensions that
+      the stored tiling does not have.  The barrier keeps the split behind
+      the GEMM: the rows' product is materialised (a few hundred rows), the
+      weight is not.
+    * GLU ``[L, in, 2, out]``: the stored tiling (pairs packed in a word,
+      the contraction axis outside the tile) is no GEMM operand, so XLA
+      writes the layer out again in one that is; the pair kernel reads the
+      stack and splits the words in registers.  Off the TPU, under a mesh,
+      for int8 leaves and odd widths: the slice and XLA's re-layout, as
+      before."""
+    stack, layer = p
+    quant = "kernel_q" in stack
+    leaf = stack["kernel_q" if quant else "kernel"]
+
+    def at(a):
+        return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+    if leaf.ndim == 4 and not quant and _reads_pairs(x, leaf):
+        from megatron_llm_tpu.ops.pallas.stacked_linear import glu_stack_matmul
+
+        y = glu_stack_matmul(x.reshape(-1, x.shape[-1]), leaf, layer)
+        y = y.reshape(*x.shape[:-1], *leaf.shape[2:])
+    else:
+        kernel = at(leaf).astype(x.dtype)
+        y = jax.lax.optimization_barrier(
+            x @ kernel.reshape(kernel.shape[0], -1))
+        y = y.reshape(*y.shape[:-1], *kernel.shape[1:])
+    if quant:
+        y = y * at(stack["kernel_scale"]).astype(y.dtype)
+    if "bias" in stack:
+        y = y + at(stack["bias"]).astype(x.dtype)
+    return y
+
+
 def _linear(p: Params, x: jax.Array) -> jax.Array:
+    if isinstance(p, StackedLinear):
+        return _stacked_linear(p, x)
     # weight-only int8 support (the shared quantized-leaf contract,
     # ops/quant.py:resolve_kernel): HBM reads int8, the convert fuses into
     # the GEMM; the per-channel scale applies to the output (after the GLU
@@ -970,6 +1087,17 @@ def transformer_forward(
     in place (:class:`LayerPool`); with the engine's donated buffers no
     copy of the pool or of a layer's slice exists in the tick.  The dense
     incremental cache (``cache_index``) is a stacked pair scanned per layer.
+
+    Which leaves ride the scan.  Without ``paged`` (the trainer, the dense
+    forward, the dense incremental cache): every leaf of ``stacked_layers``
+    is a scanned operand, a patterned stack's as whole periods.  With
+    ``paged`` (every tick of the engine: ragged, verify, decode, chained,
+    the draft model's): the norms, a router, a retention gate and head
+    norms and MLA's ``kv_up`` ride the scan; the routed experts
+    (``StackedExperts``) and the dense projections (``TICK_LINEARS``, as
+    ``StackedLinear``) are read from their stacks, which the scan's body
+    closes over, by the layer's index: the program holds no copy of a
+    layer's weights (``tools/tick_hlo_copies.py`` prints what it holds).
     """
     num_layers = jax.tree_util.tree_leaves(stacked_layers)[0].shape[0]
     rates = _lima_rates(cfg, cfg.model.depth)
@@ -992,6 +1120,23 @@ def transformer_forward(
         all_experts = stacked_layers["moe"]["experts"]
         stacked_layers = {**stacked_layers, "moe": {
             k: v for k, v in stacked_layers["moe"].items() if k != "experts"}}
+    # nor the dense projections (TICK_LINEARS: qkv, dense, fc1, fc2, a
+    # shared expert's, MLA's down and up): a scanned slice of a GLU fc1 is
+    # written out twice before its GEMM reads it, and a patterned stack's
+    # ``a[j]`` slices a period before it slices a layer (PERF.md section 6,
+    # PR 42: 27% of the Brumby tick's device time, 19% of Falcon's).  The
+    # stack stays outside the scan, the layer names its own projection
+    # (``StackedLinear``) and ``_linear`` reads it in place.  Kept as they
+    # were: the trainer and every path without ``paged`` (a stack-sized
+    # gradient a layer, as above); fp8 linears (ops/fp8.py casts a layer's
+    # kernel as it multiplies); the tp overlap rings (parallel/overlap.py
+    # hands ``p["kernel"]`` to a shard_map of its own)
+    from megatron_llm_tpu.parallel import overlap as tp_overlap_mod
+
+    linears = None
+    if (paged is not None and _linear_impl(cfg) is _linear
+            and tp_overlap_mod.current() is None):
+        linears, stacked_layers = _take_linears(stacked_layers)
 
     # a layer's page class, its rank among the class's layers of a period
     # and how many those are, by its place in the period
@@ -1018,6 +1163,9 @@ def transformer_forward(
             layer_params = {**layer_params, "moe": {
                 **layer_params["moe"], "experts": moe_mod.StackedExperts(
                     all_experts, layer_idx - layer_offset)}}
+        if linears:
+            layer_params = _put_linears(layer_params, linears,
+                                        layer_idx - layer_offset)
         dk = None if dropout_key is None else rng_mod.fold_layer(dropout_key, layer_idx)
         rate = rates[layer_idx]
         out, new_cache, aux = block_forward(

@@ -204,6 +204,20 @@ def _abstract_pool(shape, kv_dtype, sharding, scale_sharding):
         made, shardings)
 
 
+def _weights_moved(compiled, min_bytes):
+    """Names of the compiled program's copies and slice-only fusions of at
+    least ``min_bytes`` (tools/tick_hlo_copies.py reads the text)."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import tick_hlo_copies
+
+    return [r.instr.name for r in tick_hlo_copies.moved(
+        tick_hlo_copies.parse_hlo(compiled.as_text()), min_bytes)]
+
+
 def _compiles_with_kernel(fn, *args, **jit_kw):
     lowered = jax.jit(fn, **jit_kw).lower(*args)
     assert "tpu_custom_call" in lowered.as_text(), (
@@ -428,9 +442,15 @@ def test_aot_state_tick_compiles_at_published_widths():
             S((pre,), jnp.int32), S((2, 1), jnp.int32),
             S((pre,), jnp.int32), S((pre,), jnp.int32))
         assert "retention_sweep" in lowered.as_text()
-        stats = lowered.compile().memory_analysis()
+        assert "glu_stack_matmul" in lowered.as_text()
+        compiled = lowered.compile()
+        stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= pool_bytes      # in place
     assert stats.temp_size_in_bytes < pool_bytes // 2   # and never copied
+    # nor is a weight (PR 42): no copy or slice-only fusion the size of a
+    # layer's smallest projection, where the parent wrote fc1 out twice
+    # (340 MiB each) and QKV once transposed (70 MiB) a layer
+    assert not _weights_moved(compiled, 32 << 20)
 
 
 def test_aot_two_class_tick_compiles_at_published_widths():
@@ -482,12 +502,15 @@ def test_aot_two_class_tick_compiles_at_published_widths():
     # the scopes a device trace tells the two masks' kernels apart by
     hlo = compiled.as_text()
     assert "attention/window" in hlo and "attention/global" in hlo
-    # 1.8 GB as compiled: XLA lays every layer's QKV and shared-expert
-    # weights out anew (transposed) before their projections, 0.42 GB a
-    # layer, all four at once (PERF.md section 5, PR 39; the other families'
-    # ticks do the same at their widths).  No copy of a layer's HELD
-    # experts (1.6 GB) on top of that: the grouped kernel reads the stack
-    assert stats.temp_size_in_bytes < 2 << 30
+    # 0.2 GB as compiled.  Until PR 42 it was 1.8: XLA laid every layer's
+    # QKV and shared-expert weights out anew before their projections, 0.42
+    # GB a layer, all four at once (PERF.md section 6, PR 42).  Now the
+    # tick closes over the dense stacks as over the held experts' (1.6 GB a
+    # layer): QKV's dot reads its slice fused, the shared experts' GLU fc1
+    # goes to the pair kernel, the grouped kernel reads the experts' stack
+    assert "glu_stack_matmul" in text
+    assert stats.temp_size_in_bytes < 1 << 29
+    assert not _weights_moved(compiled, 32 << 20)
     out = compiled.output_shardings
     assert len(jax.tree.leaves(out)) == 2 + 5     # two leaves, four rows, moe
 
@@ -503,10 +526,10 @@ def test_aot_tick_keeps_the_pool_in_one_layout(model, vocab, heads, slots):
     holds no pad, copy, slice or transpose whose result has the pool's, a
     layer slice's or a layer's K (or V) slice's shape, and its temporaries
     do not grow with the pool — a pool four times the size adds well under
-    ONE layer's K slice of the growth (the tied head's transposed
-    embedding, the scanned weights' slices and the sampler's vocabulary
-    rows are temporaries of any pool).  CPU interpret mode cannot see any
-    of this: it accepts every layout and plans no buffers."""
+    ONE layer's K slice of the growth (the tied embedding's transposed copy
+    and the sampler's vocabulary rows are temporaries of any pool).  CPU
+    interpret mode cannot see any of this: it accepts every layout and plans
+    no buffers."""
     import re
 
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh

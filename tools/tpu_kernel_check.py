@@ -18,7 +18,8 @@ check has always used.  Quantized pools share one dequantized value per
 element on both sides, so the same bound holds.
 
 Usage (through the chip tool; off-TPU it exits 2):
-    python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]]
+    python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]
+                                      | --glu_stack [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
 kernel phase).  ``--time`` prints the paged kernel's ms a call at the
@@ -28,7 +29,9 @@ dead, beside what its KV bytes need at the HBM peak, and checks nothing.
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
 prints the kernel's ms a call and GB/s at the cell's pool size, apart from
-a whole tick.
+a whole tick.  ``--glu_stack`` holds the GLU ``fc1`` kernel that reads its
+stack in place (``ops/pallas/stacked_linear.py``) to XLA's product of the
+layer's slice at the serving cells' widths; ``--time`` prints both.
 The full run adds the page-size/dtype matrix, block-size timing sweeps
 and a long-sequence (32K) memory-fit check.
 Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
@@ -710,6 +713,52 @@ def brumby_check(timed: bool):
               flush=True)
 
 
+# (rows, h, ffn, layers): the GLU fc1 of the Brumby cell's decode tick and
+# of its widest tick, Command A+'s four shared experts, JoyAI's dense layer
+GLU_STACKS = ((40, 5120, 17408, 2), (104, 5120, 17408, 2),
+              (128, 4096, 16384, 2), (256, 2048, 7168, 1))
+
+
+def glu_stack_check(timed: bool):
+    """``ops/pallas/stacked_linear.glu_stack_matmul`` (a GLU fc1 read from
+    its stack in place) against XLA's product with the layer's slice, at
+    the serving cells' widths: the CPU's interpret mode knows nothing of
+    the tiling the kernel rests on (which half of a word is the value)."""
+    from megatron_llm_tpu.ops.pallas.stacked_linear import glu_stack_matmul
+
+    for rows, h, ffn, layers in GLU_STACKS:
+        kx, kw = jax.random.split(jax.random.PRNGKey(rows + ffn))
+        x = jax.random.normal(kx, (rows, h), jnp.bfloat16)
+        stack = (0.02 * jax.random.normal(
+            kw, (layers, h, 2, ffn), jnp.float32)).astype(jnp.bfloat16)
+        layer = jnp.int32(layers - 1)
+
+        @jax.jit
+        def plain(x, stack, layer):
+            kernel = jax.lax.dynamic_index_in_dim(stack, layer, 0, False)
+            y = jnp.dot(x, kernel.reshape(h, 2 * ffn),
+                        preferred_element_type=jnp.float32)
+            return y.reshape(rows, 2, ffn)
+
+        got, want = glu_stack_matmul(x, stack, layer), plain(x, stack, layer)
+        name = f"glu_stack rows={rows} h={h} ffn={ffn}"
+        err = max_err(got, want)
+        crossed = max_err(got[:, ::-1], want)
+        check(name, err < TOL and crossed > 10 * TOL,
+              f"max_err={err:.2e}, halves swapped {crossed:.2e}, "
+              f"|y| up to {float(jnp.max(jnp.abs(want))):.2f}")
+        if timed:
+            (t,) = kernel_seconds(glu_stack_matmul, x, stack, layer,
+                                  kernel="glu_stack_matmul")
+            need = h * 2 * ffn * 2
+            print(f"TIME {name}: {t * 1e3:.3f} ms a call on the device, "
+                  f"{need / 1e9:.3f} GB of weights "
+                  f"({100 * need / 819e9 / t:.1f}% of the bandwidth "
+                  f"roofline); XLA with its slice and re-layout "
+                  f"{time_fn(plain, x, stack, layer) * 1e3:.3f} ms by the "
+                  "host's clock", flush=True)
+
+
 def rmsnorm_check():
     from megatron_llm_tpu.ops.norms import rms_norm
     from megatron_llm_tpu.ops.pallas.rmsnorm import fused_rms_norm
@@ -856,6 +905,10 @@ def main():
     ap.add_argument("--time", action="store_true",
                     help="time the paged kernel alone at the tick shapes "
                          "the chip has seen, and nothing else")
+    ap.add_argument("--glu_stack", action="store_true",
+                    help="the GLU fc1 kernel that reads its stack in place "
+                         "against XLA's product of the slice (with --time: "
+                         "its ms a call), and nothing else")
     ap.add_argument("--brumby", action="store_true",
                     help="the retention state sweep at the Brumby-14B tick "
                          "shapes against its jnp form (with --time: its "
@@ -873,8 +926,8 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
-    if args.brumby:
-        brumby_check(args.time)
+    if args.brumby or args.glu_stack:
+        (brumby_check if args.brumby else glu_stack_check)(args.time)
         print(f"\n{len(FAILURES)} failures"
               + (f": {FAILURES}" if FAILURES else ""))
         sys.exit(1 if FAILURES else 0)
@@ -884,6 +937,7 @@ def main():
     flash_numerics(args.quick)
     paged_numerics(args.quick)
     if not args.quick:
+        glu_stack_check(False)
         rmsnorm_check()
         block_sweep()
         long_context_fit()
