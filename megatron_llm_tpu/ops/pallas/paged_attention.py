@@ -59,11 +59,24 @@ key and value.  Per-page scales ``[P, H]`` (the calling layer's) are read
 as 128-lane rows of their flat view into SMEM, one copy per fetched page.
 
 Numerics match the jnp path: fp32 logits/softmax/accumulator, outputs cast
-to the query dtype.  Quantized pools (ops/kv_quant.QuantPagedKV) arrive in
-their storage dtype; the int8/fp8 -> fp32 cast and the scale multiply (on
-the scores' and probabilities' columns: ``q . (k*s) == (q . k)*s``) happen
-inside the step that consumes the page, so HBM traffic is the quantized
-bytes.
+to the query dtype.  The OPERANDS of the two matmuls follow what the call
+observes (``_operand_dtype``; no flag): a bf16 query on pages whose values
+bf16 holds exactly (bf16, int8, fp8) multiplies bf16 by bf16 — a product of
+two bf16 values is exact in float32 and the MXU accumulates in float32, so
+the scores are what float32 operands give, in ONE pass over a key tile that
+is read as it lies in the page buffer; ``scale`` multiplies the float32
+scores.  The probabilities stay float32 in VALUE: ``p = p_hi + p_lo``, each
+half a bf16 (``_weighted_values``; 16 bits of mantissa, relative error
+2^-17 where a plain cast to bf16 has 2^-9), two MXU passes of the rows over
+one value tile.  A float32 query, or a float32 pool, keeps float32
+operands: rounding either would change the numbers, and that path is the
+tests' exactness oracle (in interpret mode; on the chip Mosaic's float32
+matmul at default precision rounds its operands to bf16 inside the MXU,
+so there the bf16 path is the more exact of the two).  Quantized pools
+(ops/kv_quant.QuantPagedKV) arrive in their storage dtype; the cast to the
+operand dtype and the scale multiply (on the scores' and probabilities'
+columns: ``q . (k*s) == (q . k)*s``) happen inside the step that consumes
+the page, so HBM traffic is the quantized bytes.
 """
 
 from __future__ import annotations
@@ -111,6 +124,56 @@ def tile_runs(table_index, positions, horizons):
     return run.all(axis=1), (hor > 0).sum(axis=1)
 
 
+def _operand_dtype(q_dtype, page_dtype, quantized: bool):
+    """The dtype both matmuls take their operands in, read off the call: a
+    bf16 query on pages whose values bf16 holds exactly (bf16 pages; an
+    int8 or fp8 page, 8 bits of mantissa or fewer) multiplies bf16 by bf16,
+    which rounds nothing — the products are exact in float32 and are summed
+    in float32.  Any other pairing (a float32 query, a float32 pool) keeps
+    float32 operands: rounding one of them would change the result."""
+    exact = quantized or page_dtype == jnp.bfloat16
+    return jnp.dtype(jnp.bfloat16 if q_dtype == jnp.bfloat16 and exact
+                     else jnp.float32)
+
+
+# Rows of one value matmul from which the probabilities' two halves go
+# through the MXU stacked; measured on a v5e (PERF.md section 6, PR 45)
+STACK_ROWS = 64
+
+
+def _weighted_values(p, v):
+    """``p @ v`` for float32 probabilities ``p [rows, bk]`` and a value tile
+    ``v [bk, w]`` in the operands' dtype, accumulated in float32.  Float32
+    values: the product as it is.  bf16 values: ``p`` is NOT rounded to
+    bf16 — it is split into two bf16 halves whose sum carries 16 bits of
+    its mantissa (``p_hi`` the nearest bf16, ``p_lo`` the nearest bf16 of
+    what that left; ``p - p_hi`` is exact in float32), and ``p_hi @ v +
+    p_lo @ v`` differs from the float32 product by 2^-17 of it, under the
+    float32 sum's own order effects at these lengths.  Two MXU passes of
+    the rows over one value tile: a row's group alone (8-32 rows) as two
+    matmuls with the same right-hand side, a run's ``TILE * group`` rows
+    or a wide group (``STACK_ROWS`` and more) with the halves stacked along
+    the rows of one — which of the two forms Mosaic schedules better was
+    measured, not reasoned."""
+    dims = (((1,), (0,)), ((), ()))
+
+    def dot(a):
+        return jax.lax.dot_general(
+            a, v, dims, preferred_element_type=jnp.float32)
+
+    if v.dtype == jnp.float32:
+        return dot(p)
+    rows = p.shape[0]
+    p_hi = p.astype(v.dtype)
+    hi32 = p_hi.astype(jnp.float32)
+    p_lo = p - hi32
+    if rows < STACK_ROWS:
+        return dot(p_hi) + dot(p_lo.astype(v.dtype))
+    # stacked in float32, where 8 rows are a whole sublane tile
+    both = dot(jnp.concatenate([hi32, p_lo], axis=0).astype(v.dtype))
+    return both[:rows] + both[rows:]
+
+
 def _paged_kernel(
     # scalar prefetch — all traced data, so one compiled launch serves any
     # tick composition
@@ -123,15 +186,20 @@ def _paged_kernel(
     # q block, the pool in HBM [, its scales], out block, then scratch
     *refs,
     group: int,
+    scale: float,
+    operand,
     sliding_window: Optional[int],
     quantized: bool,
     paired: bool,
 ):
     """One program per TILE of rows: ``group`` query rows a kv head and
-    row (the heads of its group, padded to whole sublane tiles), scaled
-    and in float32.  ``paired``: a head's ``w`` key lanes are followed by
-    its ``w`` value lanes; otherwise its ``w`` lanes are key and value at
-    once (a latent row; a K|V pair of 64s)."""
+    row (the heads of its group, padded to whole sublane tiles), UNSCALED
+    and in float32 — an exact copy of a bf16 query, whose rows are sliced
+    where a 32-bit tile starts and cast to ``operand``, the dtype both
+    matmuls take their operands in (``_operand_dtype``), beside the matmul.
+    ``paired``: a head's ``w`` key lanes are followed by its ``w`` value
+    lanes; otherwise its ``w`` lanes are key and value at once (a latent
+    row; a K|V pair of 64s)."""
     if quantized:
         (q_ref, kv_hbm, s_hbm, o_ref,
          kv_buf, sem, m_s, l_s, acc_s, s_buf) = refs
@@ -222,12 +290,14 @@ def _paged_kernel(
             for h in range(nkv):
                 k_lanes = pl.ds((2 * h if paired else h) * w, w)
                 v_lanes = pl.ds((2 * h + 1) * w, w) if paired else k_lanes
-                q = q_ref[h, rows_at, :]                        # [rows, w]
-                k = kv_buf[slot, :, :, k_lanes].astype(jnp.float32).reshape(
+                q = q_ref[h, rows_at, :].astype(operand)        # [rows, w]
+                # the tile as it lies: a cast only where the page is not in
+                # the operands' dtype (a quantized page; float32 operands)
+                k = kv_buf[slot, :, :, k_lanes].astype(operand).reshape(
                     bk, w)
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)         # [rows, bk]
+                    preferred_element_type=jnp.float32) * scale  # [rows, bk]
                 if quantized:
                     # q . (k * scale) == (q . k) * scale: dequantize the
                     # scores' columns, not the page
@@ -248,16 +318,17 @@ def _paged_kernel(
                                       + jnp.sum(p, axis=1, keepdims=True))
                 m_s[h, rows_at, :] = m_cur
                 v = k if not paired else kv_buf[
-                    slot, :, :, v_lanes].astype(jnp.float32).reshape(bk, w)
-                # rows of pages not fetched hold whatever the buffer held:
-                # 0 * NaN would reach the accumulator
-                v = jnp.where(live_row, v, 0.0)
+                    slot, :, :, v_lanes].astype(operand).reshape(bk, w)
+                # rows past kv_end (pages not fetched, the rest of the last
+                # page) hold whatever the buffer held: 0 * NaN would reach
+                # the accumulator.  A select on the tile as the matmul takes
+                # it; behind a branch that only a walk's last block takes
+                # it is no cheaper (PERF.md section 6, PR 45)
+                v = jnp.where(live_row, v, jnp.zeros_like(v))
                 if quantized:
                     p = p * page_scales(slot, scales_at, 2 * h + 1, live_col)
                 acc_s[h, rows_at, :] = (
-                    acc_s[h, rows_at, :] * alpha + jax.lax.dot_general(
-                        p, v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+                    acc_s[h, rows_at, :] * alpha + _weighted_values(p, v))
 
         @pl.when(blk1 > blk0)
         def _walk():
@@ -347,9 +418,12 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         jnp.pad(a.astype(jnp.int32), dead)
         for a in (table_index, positions, horizons))
     run, _ = tile_runs(table_index, positions, horizons)
-    # kv-head-major query rows, scaled here: one program sees all of a kv
-    # head's query rows of its tile as ONE matmul operand
-    qg = jnp.pad((q.astype(jnp.float32) * scale).reshape(r, nkv, g, d),
+    # kv-head-major query rows, unscaled (the kernel scales the float32
+    # scores) and in float32, which holds a bf16 query exactly: one program
+    # sees all of a kv head's query rows of its tile as ONE matmul operand.
+    # A bf16 block would want a row's group in tiles of 16 rows: measured,
+    # the padded rows cost more than this copy (PERF.md section 6, PR 45)
+    qg = jnp.pad(q.astype(jnp.float32).reshape(r, nkv, g, d),
                  (dead, (0, 0), (0, gp - g), (0, w - d)))
     qg = qg.reshape(tiles, TILE, nkv, gp, w).transpose(0, 2, 1, 3, 4)
 
@@ -384,24 +458,26 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
-        _paged_kernel, group=gp, sliding_window=sliding_window,
-        quantized=quantized, paired=paired,
+        _paged_kernel, group=gp, scale=scale,
+        operand=_operand_dtype(q.dtype, arr.dtype, quantized),
+        sliding_window=sliding_window, quantized=quantized, paired=paired,
     )
 
     # VMEM, every last dim padded to 128 lanes: the float32 q and the out
     # blocks (two of each, the pipeline's), the softmax state of the whole
     # tile, both halves of the page buffer, and a step's [rows, block] fp32
-    # temporaries (scores, probabilities, masks) at a run's TILE * group
-    # rows.  A tile of 8 needs 4.4 MiB at Command A+'s widths (128 rows a
-    # kv head x 8), 5.2 at Falcon's (576 rows, blocks of 256 tokens), 3.8
-    # at the latent row's and 2.7 at Mistral's; a whole 64-row chunk a
-    # program would need 28 at Command A+'s and 41 at Falcon's, over
-    # Mosaic's default of 16 — the tile is what keeps every geometry under
-    # it, and the limit is stated all the same
+    # temporaries (scores, probabilities, masks, and the probabilities' two
+    # bf16 halves: one more) at a run's TILE * group rows.  A tile of 8
+    # needs 4.4 MiB at Command A+'s widths (128 rows a kv head x 8), 5.8 at
+    # Falcon's (576 rows, blocks of 256 tokens), 3.9 at the latent row's and
+    # 2.7 at Mistral's; a whole 64-row chunk a program would need 29 at
+    # Command A+'s and 45 at Falcon's, over Mosaic's default of 16 — the
+    # tile is what keeps every geometry under it, and the limit is stated
+    # all the same
     vmem = (2 * nkv * rows * lanes(w) * (4 + q.dtype.itemsize)
             + nkv * rows * (2 * 128 + lanes(w)) * 4
             + math.prod(buf_shape) * arr.dtype.itemsize
-            + 6 * rows * lanes(pps * page_size) * 4)
+            + 7 * rows * lanes(pps * page_size) * 4)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

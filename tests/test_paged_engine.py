@@ -77,64 +77,9 @@ def toy_model():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", [
-    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32),
-    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, window=9),
-    dict(n=4, nkv=4, d=128, page=16, kv_dtype="int8"),
-    dict(n=4, nkv=1, d=64, page=8, kv_dtype="fp8", window=20),
-    # the page walk (tools/tpu_kernel_check.py WALK_CASES): a context of
-    # three 128-token blocks that ends inside the third, a horizon that is
-    # no multiple of a block, a window that opens inside a block, rows
-    # sharing a table beside dead rows (every ragged scenario)
-    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=24,
-         context=300),
-    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=24,
-         context=300, window=50),
-    dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, max_pages=48,
-         context=300, window=150),
-    dict(n=4, nkv=2, d=128, page=128, dtype=jnp.float32, max_pages=3,
-         context=300),
-    # 128 slots wide, 40 tokens of context, the tail names a NaN page:
-    # nothing of it reaches the output, which is finite and the gather
-    # path's
-    dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=128,
-         context=40, poison_tail=True),
-    dict(n=4, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=128,
-         context=40, poison_tail=True),
-    # Falcon-40B: 8 kv heads of 64, each head's key|value pair one
-    # 128-lane operand
-    dict(n=16, nkv=8, d=64, page=16, max_pages=24, context=300),
-    dict(n=16, nkv=8, d=64, page=16, kv_dtype="int8", max_pages=24,
-         context=300, window=100),
-    # Falcon-7B (71 query heads on one kv head of 64: its key|value pair
-    # is the 128-lane row) and Mistral-7B (32/8 x 128) head geometries
-    dict(n=71, nkv=1, d=64, page=16, max_pages=24, context=300),
-    dict(n=71, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=24,
-         context=300, window=100),
-    dict(n=32, nkv=8, d=128, page=16, max_pages=24, context=300),
-    dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8", max_pages=24,
-         context=300, window=50),
-    # Command A+ (128/8 x 128) under its window with the tables of a
-    # window page class: the slots behind the window name the null page,
-    # so the first live page is not the table's first; and the same
-    # geometry with no window (its full layers), the two masks of one tick
-    dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300,
-         window=100, slid_head=True),
-    dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300),
-], ids=lambda c: "-".join(f"{k}{getattr(v, '__name__', v)}"
-                          for k, v in c.items()))
-def test_paged_kernels_interpret_match_jnp_path(case):
-    """The Pallas decode / prefill / ragged kernel (interpret mode) == the
-    jnp gather path, plain and quantized pools, with and without a sliding
-    window — the scenarios tools/tpu_kernel_check.py compiles on the chip."""
-    from tools.tpu_kernel_check import max_err, paged_case
-
-    # fp32 inputs: both sides are fp32 end to end and differ by reduction
-    # order only; bf16 inputs (the quantized cases) round the output to
-    # bf16, so one output ulp (2^-7 at |x| < 2) is the bound
-    tol = 1e-5 if case.get("dtype") == jnp.float32 else 2e-2
-    for name, (pallas_fn, jnp_fn) in paged_case(0, **case).items():
-        assert max_err(pallas_fn(True), jnp_fn()) < tol, name
+# the kernels at their smallest cases, against the gather path one call at a
+# time: tests/test_paged_kernel_cases.py (a file of its own, so that another
+# worker of the tier-1 run takes it: it is half of what this file took)
 
 
 # the serving configurations' head geometries (tools/tpu_kernel_check.py),
@@ -149,6 +94,11 @@ RUN_GEOMETRIES = {
 }
 RUN_SCENARIOS = {"tiles": False, "inside": False, "blocks": False,
                  "verify": False, "window": True, "window_inside": True}
+# the bf16 operands' precision test holds both storage dtypes of a
+# quantized pool: int8 on the paired layout (above) and fp8 on the pair of
+# 64s read whole
+GEOMETRIES = {**RUN_GEOMETRIES,
+              "falcon-fp8": dict(kernel_check.FALCON, kv_dtype="fp8")}
 
 
 @functools.lru_cache(maxsize=None)
@@ -157,7 +107,7 @@ def _run_outputs(geometry: str, window: bool, fp32: bool = False):
     (and at float32 the one-row walk's), made once for the scenarios that
     share the call."""
     pallas_fn, jnp_fn, scenarios = kernel_check.run_case(
-        3, window=window, **RUN_GEOMETRIES[geometry],
+        3, window=window, **GEOMETRIES[geometry],
         **(dict(dtype=jnp.float32) if fp32 else {}))
     return (pallas_fn(True), jnp_fn(),
             pallas_fn(True, spread=True) if fp32 else None, scenarios)
@@ -192,6 +142,79 @@ def test_paged_kernel_shared_walk_is_the_one_row_walk(geometry, window):
     live = np.concatenate(list(scenarios.values()))
     assert kernel_check.max_err(out[live], alone[live]) < 1e-6
     assert kernel_check.max_err(out[live], ref[live]) < 1e-5
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_paged_kernel_bf16_operands_round_nothing(geometry, window):
+    """THE GUARD OF THE KERNEL'S PRECISION.  On bf16 queries and bf16 pages
+    both matmuls take bf16 operands (one MXU pass for the scores, two for
+    the values).  That must change no number: the output is what a float32
+    computation on the SAME bf16 values gives, rounded ONCE to bf16 — under
+    1% of the elements differ (the order of float32 sums at a rounding
+    boundary), none by more than one bf16 unit in the last place.  In
+    interpret mode on the CPU a bf16 dot with float32 accumulation is
+    exact, so what this measures is the probabilities' split into two bf16
+    halves: a plain ``p.astype(bfloat16)`` in front of the value matmul
+    passes the 2e-2 of the tests above and FAILS here (tens of percent of
+    the elements move: the test below).  The int8 and fp8 pools are held to
+    the same rule against their dequantized float32 form: a quantized value
+    is exact in bf16, and the per-page scales multiply the float32 scores
+    and the float32 probabilities (before their split)."""
+    out, _, _, scenarios = _run_outputs(geometry, window)
+    _, jnp_fn, _ = kernel_check.run_case(
+        3, window=window, **GEOMETRIES[geometry])
+    assert out.dtype == jnp.bfloat16
+    live = np.concatenate(list(scenarios.values()))
+    differ, ulps = kernel_check.bf16_ulps(
+        out[live], jnp_fn(exact=True)[live])
+    assert differ < 0.01 and ulps <= 1.0, (differ, ulps)
+
+
+@pytest.mark.parametrize("rows", [16, 128], ids=["two-matmuls", "stacked"])
+def test_bf16_ulps_tells_a_plain_cast_of_the_probabilities(rows):
+    """The measure itself: softmax(s) @ v in float32 against the same with
+    the probabilities split into two bf16 halves (the kernel's
+    ``_weighted_values``, in both of its forms) and with a plain cast."""
+    from megatron_llm_tpu.ops.pallas.paged_attention import (
+        STACK_ROWS,
+        _weighted_values,
+    )
+
+    assert 16 < STACK_ROWS <= 128
+    rng = np.random.default_rng(0)
+    s = jnp.asarray(rng.normal(size=(rows, 512)) * 2, jnp.float32)
+    v = jnp.asarray(rng.normal(size=(512, 128)), jnp.bfloat16)
+    p = jax.nn.softmax(s, axis=-1)
+    exact = p @ v.astype(jnp.float32)
+    differ, ulps = kernel_check.bf16_ulps(
+        _weighted_values(p, v).astype(jnp.bfloat16), exact)
+    assert differ < 0.01 and ulps <= 1.0, (differ, ulps)
+    cast = jnp.dot(p.astype(jnp.bfloat16), v,
+                   preferred_element_type=jnp.float32)
+    differ, _ = kernel_check.bf16_ulps(cast.astype(jnp.bfloat16), exact)
+    assert differ > 0.1, differ
+    # float32 values: the product as it is
+    assert np.array_equal(_weighted_values(p, v.astype(jnp.float32)),
+                          jnp.dot(p, v.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("q_dtype,page_dtype,quantized,operand", [
+    (jnp.bfloat16, jnp.bfloat16, False, jnp.bfloat16),
+    (jnp.bfloat16, jnp.int8, True, jnp.bfloat16),
+    (jnp.bfloat16, jnp.float8_e4m3fn, True, jnp.bfloat16),
+    (jnp.float32, jnp.bfloat16, False, jnp.float32),
+    (jnp.float32, jnp.float32, False, jnp.float32),
+    (jnp.float32, jnp.int8, True, jnp.float32),
+    (jnp.bfloat16, jnp.float32, False, jnp.float32),
+])
+def test_paged_kernel_operand_dtype_follows_the_call(
+        q_dtype, page_dtype, quantized, operand):
+    """What decides the matmuls' operands is the dtypes the call observes:
+    bf16 only where it rounds nothing; a float32 query keeps float32."""
+    from megatron_llm_tpu.ops.pallas.paged_attention import _operand_dtype
+
+    assert _operand_dtype(q_dtype, page_dtype, quantized) == operand
 
 
 @pytest.mark.parametrize("rows,shared,live", [

@@ -24,7 +24,8 @@ Usage (through the chip tool; off-TPU it exits 2):
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
 kernel phase).  ``--time`` prints the paged kernel's ms a call at the
 tick shapes the chip has seen (``TICKS``), whole and with the chunk's rows
-dead, beside what its KV bytes need at the HBM peak, and checks nothing.
+dead, beside the dtype its two matmuls take their operands in and what
+its KV bytes need at the HBM peak, and checks nothing.
 ``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
@@ -75,6 +76,30 @@ def max_err(a, b):
 
 
 TOL = 0.05  # module docstring
+
+
+def bf16_ulps(out, exact):
+    """How far a bf16 output lies from a float32 result ROUNDED ONCE to
+    bf16: ``(share of the elements that differ at all, largest difference
+    in bf16 units in the last place of the larger of the two)``.  A kernel
+    that keeps float32 through its softmax and accumulator differs from
+    such a reference only where the order of the float32 sums carries a
+    value across a rounding boundary: a fraction of a percent of the
+    elements, each by one unit.  One that rounds its probabilities to bf16
+    moves tens of percent (tests/test_paged_engine.py pins both).  An
+    element under 2^-10 of the largest is held to the unit of that size:
+    where a sum cancels, the float32 additions' own rounding (2^-24 of the
+    terms) is more than the unit of what is left."""
+    import numpy as np
+
+    a = np.asarray(out.astype(jnp.float32))
+    b = np.asarray(exact.astype(jnp.bfloat16).astype(jnp.float32))
+    size = np.maximum(np.maximum(np.abs(a), np.abs(b)),
+                      np.abs(b).max() * 2.0 ** -10)
+    # |x| = m * 2^e with m in [0.5, 1): 8 bits of mantissa end at 2^(e-8)
+    _, e = np.frexp(size)
+    diff = np.abs(a - b)
+    return float((diff > 0).mean()), float((diff / np.ldexp(1.0, e - 8)).max())
 
 
 def flash_numerics(quick: bool):
@@ -138,8 +163,9 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
 
     Returns ``{name: (pallas_fn, jnp_fn)}`` — thunks over the same pool and
     block tables: ``pallas_fn(interpret)`` calls the kernel wrapper directly,
-    ``jnp_fn()`` the gather path.  Shared with tests/test_paged_engine.py,
-    which runs the kernels in interpret mode on the CPU.
+    ``jnp_fn()`` the gather path.  Shared with
+    tests/test_paged_kernel_cases.py, which runs the kernels in interpret
+    mode on the CPU.
 
     Tables are ``max_pages`` slots wide and the longest context is
     ``context`` tokens (default: the whole table).  ``poison_tail`` points
@@ -259,8 +285,11 @@ def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     Returns ``(pallas_fn, jnp_fn, scenarios)``: ``pallas_fn(interpret,
     spread)`` calls the kernel wrapper — ``spread``: every row the first of
     a tile of its own with dead rows behind it, which is the one-row walk —
-    ``jnp_fn()`` the gather path, both on every row of the call, and
-    ``scenarios`` names the live rows of each:
+    ``jnp_fn(exact)`` the gather path (``exact``: in float32 throughout, on
+    float32 copies of the same query and page values, a quantized pool
+    dequantized into float32: what ``bf16_ulps`` compares a bf16 output
+    with), both on every row of the call, and ``scenarios`` names the live
+    rows of each:
 
     * ``tiles``: a run that fills two whole tiles;
     * ``inside``: a run that starts and ends inside a tile, another
@@ -358,11 +387,18 @@ def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             *(jnp.asarray(a) for a in meta), interpret=interpret, **kw)
         return out[::T] if spread else out
 
-    def jnp_fn():
-        return pa.paged_attention_ragged(
-            q, pool, jnp.asarray(tables, jnp.int32),
-            *(jnp.asarray(a) for a in (idx, pos, hor)),
-            use_kernel=False, **kw)
+    def jnp_fn(exact=False):
+        q_, pool_ = q, pool
+        if exact:
+            q_ = q.astype(jnp.float32)
+            if not kv_quant.is_quantized(pool):
+                pool_ = pool.astype(jnp.float32)
+        # on the chip a float32 einsum is one bf16 pass unless told
+        with jax.default_matmul_precision("highest" if exact else "default"):
+            return pa.paged_attention_ragged(
+                q_, pool_, jnp.asarray(tables, jnp.int32),
+                *(jnp.asarray(a) for a in (idx, pos, hor)),
+                use_kernel=False, **kw)
 
     return pallas_fn, jnp_fn, scenarios
 
@@ -400,11 +436,15 @@ TICKS = {
     "commanda": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289, None),
     "commanda_window": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289,
                         4096),
+    # the JoyAI cell's tick: the Falcon cell's traffic on the latent row,
+    # 103 of 128 slots live (`slot_occupancy.batch` 80.8)
+    "joyai": (LATENT, 128, 103, 300, 700, 192, 128, 128 * 128 + 1, None),
 }
 
 
 @functools.lru_cache(maxsize=1)
-def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int):
+def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int,
+               latent: bool = False):
     """A tick's pool, made once for the cases that share it (a Command A+
     pool is 0.4 G values)."""
     import numpy as np
@@ -412,6 +452,9 @@ def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int):
     from megatron_llm_tpu.ops import kv_quant
 
     rng = np.random.default_rng([seed, num_pages])
+    if latent:
+        return jnp.asarray(rng.standard_normal(
+            size=(num_pages, page, d), dtype=np.float32), jnp.bfloat16)
     return kv_quant.pack_kv(*(
         jnp.asarray(rng.standard_normal(
             size=(num_pages, page, nkv, d), dtype=np.float32), jnp.bfloat16)
@@ -431,7 +474,9 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     heads of 128; 55 decode rows at 16.4k-17.4k and the chunk at 16.4k;
     ``commanda_window`` the same under a window of 4,096 with the tables
     of a window page class (the slots wholly behind a table's window name
-    the null page).  ``width`` overrides the table width (same contexts);
+    the null page).  ``joyai``: Falcon's slots and contexts on one latent
+    row of 640 lanes, 103 decode rows live; its bytes are the 576 values of
+    a row read once.  ``width`` overrides the table width (same contexts);
     ``chunk_live`` false leaves the chunk's rows dead: the decode rows
     alone.
     """
@@ -440,9 +485,10 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     (geo, slots, live, lo, hi, chunk_at, slots_wide, num_pages,
      window) = TICKS[name]
     n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
+    latent = geo.get("latent", False)
     width = width or slots_wide
     rng = np.random.default_rng(seed)
-    pool = _tick_pool(seed, num_pages, page, nkv, d)
+    pool = _tick_pool(seed, num_pages, page, nkv, d, latent)
     chunk = 64
     pos = np.zeros(slots + chunk, np.int64)
     idx = np.full(slots + chunk, slots + 1)
@@ -476,12 +522,25 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     keys += visible.sum()
     args = (q, pool) + tuple(
         jnp.asarray(a, jnp.int32) for a in (tables, idx, pos, hor))
-    kw = dict(scale=1.0 / d ** 0.5, sliding_window=window)
-    return args, kw, np.flatnonzero(hor), int(keys) * 2 * nkv * d * 2
+    kw = dict(scale=1.0 / d ** 0.5, sliding_window=window, latent=latent)
+    return args, kw, np.flatnonzero(hor), int(keys) * (
+        576 * 2 if latent else 2 * nkv * d * 2)
+
+
+def check_bf16(name: str, out, exact) -> None:
+    """A PASS/FAIL line by the rule of ``bf16_ulps``: under 1% of a bf16
+    output's elements differ from the float32 result rounded once, none by
+    more than one unit in the last place."""
+    differ, ulps = bf16_ulps(out, exact)
+    check(f"{name} bf16 against float32 rounded once",
+          differ < 0.01 and ulps <= 1.0,
+          f"{100 * differ:.3f}% differ, largest {ulps:.2f} ulp")
 
 
 def paged_numerics(quick: bool):
     """Compiled paged kernels vs the jnp gather path."""
+    import numpy as np
+
     from megatron_llm_tpu.ops import paged_attention as pa
     from megatron_llm_tpu.ops.pallas import paged_attention as pk
 
@@ -535,6 +594,11 @@ def paged_numerics(quick: bool):
                     e = max_err(out[rows], ref[rows])
                     check(f"paged run {name} {tag}", e < TOL,
                           f"max_err={e:.2e}")
+                # the bf16 operands' rule (bf16_ulps), compiled: against
+                # float32 on the same values, rounded once
+                live = np.concatenate(list(scenarios.values()))
+                check_bf16(f"paged run window={window} {tag}", out[live],
+                           jnp_fn(exact=True)[live])
             except Exception as exc:
                 check(f"paged run window={window} {tag}", False,
                       f"{type(exc).__name__}: {str(exc)[:300]}")
@@ -554,6 +618,16 @@ def paged_numerics(quick: bool):
                     use_kernel=False, **kw))
                 for rows in (live[i:i + 16] for i in range(0, len(live), 16)))
             check(f"paged tick {name}", e < TOL, f"max_err={e:.2e}")
+            # the same in float32 throughout (twice the gather: 4 rows)
+            pool32 = pool.astype(jnp.float32)
+            with jax.default_matmul_precision("highest"):
+                exact = jnp.concatenate([
+                    pa.paged_attention_decode(
+                        q[rows].astype(jnp.float32), pool32,
+                        tables[idx[rows]], pos[rows], use_kernel=False, **kw)
+                    for rows in (live[i:i + 4]
+                                 for i in range(0, len(live), 4))])
+            check_bf16(f"paged tick {name}", out[live], exact)
         except Exception as exc:
             check(f"paged tick {name}", False,
                   f"{type(exc).__name__}: {str(exc)[:300]}")
@@ -617,8 +691,11 @@ def paged_timing():
             kernel_seconds(f, *args, kernel="paged_attention"))
         host = time_fn(f, *args) / calls
         least = need / 819e9
+        # what the kernel reads off this call's dtypes
+        operand = pk._operand_dtype(args[0].dtype, args[1].dtype, False)
         print(f"TIME paged tick {name} rows={args[0].shape[0]} "
-              f"page_slots={width}: {t * 1e3:.3f} ms a call on the device "
+              f"page_slots={width} operands={operand}: "
+              f"{t * 1e3:.3f} ms a call on the device "
               f"({host * 1e3:.3f} by the host's clock over {calls} calls), "
               f"least {least * 1e3:.4f} ms for {need / 1e6:.2f} MB of K "
               f"and V ({100 * least / t:.2f}% of the bandwidth roofline)",
