@@ -113,7 +113,6 @@ METRIC_PREFIX = "engine_prefix_prefill_reduction_llama470m_c8_1chip"
 METRIC_SLO = "engine_slo_hi_p99_ttft_speedup_llama470m_1chip"
 METRIC_SPEC = "engine_spec_decode_speedup_llama470m_c1_1chip"
 METRIC_ROUTER = "router_prefix_affinity_ttft_speedup_llama470m_2rep_1chip"
-METRIC_PIPELINE = "engine_pipeline_decode_speedup_llama470m_c8_1chip"
 METRIC_STREAMING = "serving_stream_first_token_speedup_llama470m_c8_2rep_1chip"
 METRIC_DISAGG = "serving_disagg_decode_p99_tpot_speedup_llama470m_2rep_1chip"
 METRIC_PP = "engine_pp_decode_tok_s_ratio_llama470m_c4_eqchip"
@@ -350,87 +349,6 @@ def bench_engine(cfg, params, concurrency: int, prompt: int, gen: int,
         "sequential_s": round(seq_best, 4),
         "sequential_tok_s": round(total_tokens / seq_best, 1),
         "speedup_vs_sequential": round(seq_best / best, 2),
-    }
-
-
-def bench_pipeline(cfg, params, levels, depths, prompt: int, gen: int,
-                   vocab: int, reps: int) -> dict:
-    """Pipelined multi-tick dispatch (ISSUE 17): decode-only throughput
-    and host-gap percentiles per ``--tick_pipeline_depth``, with an
-    in-bench lossless assert (every depth's token streams must be
-    bitwise the depth-0 streams).  ``depths`` sweeps 0/1/2 (the parity
-    grid) plus a deep arm that shows the amortization limit."""
-    import time
-
-    rows = []
-    compile_s = 0.0
-    t_compile = time.perf_counter()
-    for c in levels:
-        prompts = _requests(c, prompt, gen, vocab)
-
-        def run(depth):
-            eng = make_engine(cfg, params, max_slots=max(c, 1),
-                              max_seq=prompt + gen,
-                              tick_pipeline_depth=depth)
-            reqs = run_workload(
-                eng, [(p, gen, dict(GREEDY_KW)) for p in prompts])
-            return eng, [r.result(timeout=600)[0] for r in reqs]
-
-        cells = []
-        base_toks = None
-        for depth in depths:
-            run(depth)  # warm this depth's chain compile
-            if compile_s == 0.0:
-                compile_s = time.perf_counter() - t_compile
-            best, stats, toks = float("inf"), None, None
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                eng, toks = run(depth)
-                dt = time.perf_counter() - t0
-                if dt < best:
-                    best, stats = dt, eng.host_gap_stats()
-            if depth == depths[0]:
-                base_toks = toks
-            elif toks != base_toks:
-                raise RuntimeError(
-                    f"LOSSLESS VIOLATION: depth {depth} tokens diverged "
-                    f"from depth 0 at c={c}")
-            cells.append({
-                "depth": depth,
-                "wall_s": round(best, 4),
-                "tok_s": round(c * gen / best, 1),
-                "dispatches": stats["count"],
-                "host_gap_total_s": stats["total_s"],
-                "host_gap_p50_ms": stats["p50_ms"],
-                "host_gap_p99_ms": stats["p99_ms"],
-            })
-        d0 = cells[0]
-        best_cell = max(cells[1:], key=lambda r: r["tok_s"])
-        rows.append({
-            "concurrency": c,
-            "depths": cells,
-            "speedup_best": round(best_cell["tok_s"] / d0["tok_s"], 2),
-            "best_depth": best_cell["depth"],
-            "host_gap_reduction": round(
-                d0["host_gap_total_s"]
-                / max(best_cell["host_gap_total_s"], 1e-9), 2),
-            "lossless": True,
-        })
-    head = rows[-1]
-    d0 = head["depths"][0]
-    return {
-        "prompt_len": prompt,
-        "gen_len": gen,
-        "depths_swept": list(depths),
-        "speedup_headline": head["speedup_best"],
-        "best_depth": head["best_depth"],
-        "host_gap_reduction": head["host_gap_reduction"],
-        "speedup_ok": head["speedup_best"] >= 1.5
-        and head["host_gap_reduction"] > 1.0,
-        "lossless": all(r["lossless"] for r in rows),
-        "compile_time_s": round(compile_s, 1),
-        "step_time_s": round(d0["wall_s"] / max(d0["dispatches"], 1), 6),
-        "rows": rows,
     }
 
 
@@ -1440,11 +1358,9 @@ def _run(args, finished):
     spec_mode = args.mode == "spec"
     router_mode = args.mode == "router"
     cap_mode = args.mode == "capacity"
-    pipe_mode = args.mode == "pipeline"
     stream_mode = args.mode == "streaming"
     disagg_mode = args.mode == "disagg"
     pp_mode = args.mode == "pp"
-    pipe_depths = (0, 1, 2, 8)
     burst = 12  # admission-arm clients (streaming mode section 2)
     draft_layers = 2
     # capacity-mode workload shape (ISSUE 13): ref_slots sizes the fixed
@@ -1486,16 +1402,6 @@ def _run(args, finished):
             # the target must out-depth the 1-layer draft by enough that
             # drafting is visibly cheaper than verifying
             layers, args.gen, draft_layers = 4, 48, 1
-        if pipe_mode:
-            # host-bound shape: this mode measures ORCHESTRATION
-            # amortization, so the model must be small enough that host
-            # dispatch + apply dominates a tick (the TPU analog is
-            # dispatch latency against a real model's step time); long
-            # decode-only streams keep admission/prefill boundaries to
-            # the first few ticks, and 3 reps de-noise the sub-100ms
-            # walls
-            layers, hidden, heads, ffn, vocab = 1, 32, 2, 64, 128
-            args.prompt, args.gen, args.reps = 16, 96, 3
         if stream_mode:
             # enough decode ticks (gen=24) that a streamed client's first
             # byte lands visibly before the buffered client's only byte;
@@ -1589,9 +1495,6 @@ def _run(args, finished):
             assert pps, "pp mode needs >= 2 devices"
             row = bench_pp(cfg, params, pps, levels[-1], args.prompt,
                            args.gen, vocab, args.reps)
-        elif pipe_mode:
-            row = bench_pipeline(cfg, params, levels, pipe_depths,
-                                 args.prompt, args.gen, vocab, args.reps)
         elif prefix_mode:
             c = levels[-1]
             row = bench_shared_prefix(cfg, params, c, args.shared,
@@ -1758,25 +1661,6 @@ def _run(args, finished):
             "device_kind": getattr(jax.devices()[0], "device_kind", "?"),
         }
         tag = "engine_decode_slo"
-    elif pipe_mode:
-        result = {
-            "metric": METRIC_PIPELINE,
-            "value": row["speedup_headline"],
-            "unit": "x",
-            "speedup_ok": row["speedup_ok"],
-            "lossless": row["lossless"],
-            "best_depth": row["best_depth"],
-            "depths_swept": row["depths_swept"],
-            "host_gap_reduction": row["host_gap_reduction"],
-            "compile_time_s": row["compile_time_s"],
-            "step_time_s": row["step_time_s"],
-            "n_params": n_params,
-            "rows": row["rows"],
-            "workload": {k: row[k] for k in ("prompt_len", "gen_len")},
-            "backend": jax.devices()[0].platform,
-            "device_kind": getattr(jax.devices()[0], "device_kind", "?"),
-        }
-        tag = "engine_decode_pipeline"
     elif pp_mode:
         result = {
             "metric": METRIC_PP.replace(
@@ -1837,8 +1721,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode",
                     choices=("occupancy", "shared_prefix", "slo", "spec",
-                             "router", "capacity", "pipeline",
-                             "streaming", "disagg", "pp"),
+                             "router", "capacity", "streaming", "disagg",
+                             "pp"),
                     default="occupancy")
     ap.add_argument("--concurrency", default="1,4,8",
                     help="comma-separated occupancy levels (requests); "
@@ -1873,18 +1757,16 @@ def main():
     ap.add_argument("--watchdog", type=float, default=1500.0)
     args = ap.parse_args()
 
-    if args.mode in ("spec", "pipeline") and args.concurrency == "1,4,8":
+    if args.mode == "spec" and args.concurrency == "1,4,8":
         args.concurrency = "1,2,4,8"
     metric = {"shared_prefix": METRIC_PREFIX, "slo": METRIC_SLO,
               "spec": METRIC_SPEC, "router": METRIC_ROUTER,
-              "pipeline": METRIC_PIPELINE,
               "capacity": METRIC_CAPACITY,
               "streaming": METRIC_STREAMING,
               "disagg": METRIC_DISAGG,
               "pp": METRIC_PP}.get(args.mode, METRIC)
     unit = ("x" if args.mode in ("shared_prefix", "slo", "spec", "router",
-                                 "capacity", "pipeline",
-                                 "streaming", "disagg", "pp")
+                                 "capacity", "streaming", "disagg", "pp")
             else "tok/s")
     finished = threading.Event()
 

@@ -40,6 +40,7 @@ from bench import (  # noqa: E402
     probe_backend,
 )
 from bench_train_loop import make_provider  # noqa: E402
+from megatron_llm_tpu.observability import trace as trace_mod  # noqa: E402
 
 METRIC = "train_loop_observed_steps_s_1chip"
 GATE_OVERHEAD_PCT = 3.0
@@ -49,7 +50,6 @@ def run_mode(make_cfg, vocab: int, seq: int, iters: int,
              instrumented: bool, trace_dir: str | None = None) -> dict:
     """One full pretrain() run; returns steady-state timing fields."""
     from megatron_llm_tpu.observability import registry as registry_mod
-    from megatron_llm_tpu.observability import trace as trace_mod
     from megatron_llm_tpu.training import pretrain
 
     cfg = make_cfg(iters)
@@ -120,26 +120,54 @@ def run_pair(make_cfg, vocab: int, seq: int, iters: int,
     }
 
 
-def measure_instrument_cost(steps: int = 2000,
-                            trace_dir: str | None = None) -> dict:
-    """Direct per-step cost of the full instrumentation sequence.
-
-    Replays exactly what one driver iteration records — the step mark,
-    the data-wait/dispatch/metric-drain spans, the timer stop mirrors and
-    driver gauges, the profiler-trigger checks, the amortized
+def instrument_step(i: int, tracer, timers, flight, trigger,
+                    trace_dir: str) -> None:
+    """What one driver iteration records, with nothing between: the step
+    mark, the data-wait/dispatch/metric-drain spans, the timer stop
+    mirrors and driver gauges, the profiler-trigger checks, the amortized
     every-10-steps window dump, AND one full flight-recorder request
     lifecycle (ISSUE 12: open, enqueue, admit/decode phase transitions,
     first token, finish, close — what one served request bills the
-    engine's scheduler thread) — and times it in isolation.  This is the
+    engine's scheduler thread)."""
+    trace_mod.instant("step-begin", iteration=i)
+    trigger.maybe_start(i)
+    timers("batch-generator", 1).start()
+    with trace_mod.span("data-wait", iteration=i):
+        pass
+    timers.gauge("data-wait-ms", 1.0)
+    timers("batch-generator").stop()
+    timers("train-step", 0).start()
+    with trace_mod.span("dispatch", iteration=i):
+        pass
+    timers.gauge("in-flight-depth", 2)
+    with trace_mod.span("metric-drain", count=1):
+        pass
+    timers("train-step").stop()
+    trigger.step_done()
+    rec = flight.open(f"cost-{i}", prompt_tokens=64)
+    rec.event("enqueue", queued=1)
+    rec.set_phase("prefill", kind="admit", slot=0, hit_tokens=0)
+    rec.set_phase("decode", pos=63)
+    rec.mark_first_token()
+    rec.finish("ok", tokens=16)
+    flight.close(rec)
+    if i % 10 == 9:  # the driver's N-step window dump, amortized
+        tracer.dump(os.path.join(trace_dir, "w.json"))
+
+
+def measure_instrument_cost(steps: int = 2000,
+                            trace_dir: str | None = None) -> dict:
+    """Direct per-step cost of the full instrumentation sequence
+    (:func:`instrument_step`), timed in isolation.  This is the
     deterministic companion to the wall-clock A/B above: steps/sec pairs
     are the honest end-to-end number but ride a noisy host, while this
-    isolates the instrument bill itself (tests gate on cost vs measured
-    step time; see tests/test_observability.py)."""
+    isolates the instrument bill itself (the slow lane gates on cost vs
+    measured step time; tier-1 counts what a step DOES, which no host's
+    load moves: tests/test_observability.py)."""
     import tempfile
     import time as _time
 
     from megatron_llm_tpu.observability import registry as registry_mod
-    from megatron_llm_tpu.observability import trace as trace_mod
     from megatron_llm_tpu.observability.flight import FlightRecorder
     from megatron_llm_tpu.observability.profiler import ProfileTrigger
     from megatron_llm_tpu.utils.timers import Timers
@@ -156,30 +184,7 @@ def measure_instrument_cost(steps: int = 2000,
     try:
         t0 = _time.perf_counter()
         for i in range(steps):
-            trace_mod.instant("step-begin", iteration=i)
-            trigger.maybe_start(i)
-            timers("batch-generator", 1).start()
-            with trace_mod.span("data-wait", iteration=i):
-                pass
-            timers.gauge("data-wait-ms", 1.0)
-            timers("batch-generator").stop()
-            timers("train-step", 0).start()
-            with trace_mod.span("dispatch", iteration=i):
-                pass
-            timers.gauge("in-flight-depth", 2)
-            with trace_mod.span("metric-drain", count=1):
-                pass
-            timers("train-step").stop()
-            trigger.step_done()
-            rec = flight.open(f"cost-{i}", prompt_tokens=64)
-            rec.event("enqueue", queued=1)
-            rec.set_phase("prefill", kind="admit", slot=0, hit_tokens=0)
-            rec.set_phase("decode", pos=63)
-            rec.mark_first_token()
-            rec.finish("ok", tokens=16)
-            flight.close(rec)
-            if i % 10 == 9:  # the driver's N-step window dump, amortized
-                tracer.dump(os.path.join(trace_dir, "w.json"))
+            instrument_step(i, tracer, timers, flight, trigger, trace_dir)
         cost_us = (_time.perf_counter() - t0) / steps * 1e6
     finally:
         trace_mod.disable()

@@ -666,16 +666,6 @@ class InferenceConfig:
     # record's event log (oldest events drop, with an honest count)
     flight_records: int = 256
     flight_events: int = 64
-    # pipelined multi-tick dispatch (generation/engine.py, ISSUE 17):
-    # --tick_pipeline_depth keeps up to N steady-state decode ticks in
-    # flight per launch — position advance, stop detection and page-
-    # boundary routing run INSIDE the compiled program (a lax.scan chain
-    # over the ragged tick) against a pre-granted page budget, and the
-    # host applies results at a one-launch lag.  0 (default) is today's
-    # one-tick-per-launch driver, byte for byte; any non-steady tick
-    # (admission, prefill, speculation, log-prob requests) degrades that
-    # step to depth 0 automatically.
-    tick_pipeline_depth: int = 0
 
 
 @dataclass

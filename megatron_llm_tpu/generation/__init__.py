@@ -1,18 +1,22 @@
 """Text generation — megatron/text_generation analog, plus the
-continuous-batching serving engine (generation/engine.py)."""
+continuous-batching serving engine (generation/engine.py) over its pools
+(generation/pools.py)."""
 
 from megatron_llm_tpu.generation.api import InferenceEngine
 from megatron_llm_tpu.generation.engine import (
     ContinuousBatchingEngine,
     EngineOverloaded,
     EngineRequest,
-    PagedKVPool,
-    PrefixCache,
 )
 from megatron_llm_tpu.generation.generation import (
     beam_search,
     generate_tokens,
     score_tokens,
+)
+from megatron_llm_tpu.generation.pools import (
+    PagedKVPool,
+    PrefixCache,
+    StatePool,
 )
 from megatron_llm_tpu.generation.sampling import sample, sample_per_slot
 from megatron_llm_tpu.generation.scheduling import (
@@ -32,6 +36,7 @@ __all__ = [
     "PrefixCache",
     "RequestShed",
     "SchedulerPolicy",
+    "StatePool",
     "beam_search",
     "generate_tokens",
     "get_policy",

@@ -7,7 +7,8 @@ is the TPU-serving shape the Ragged-Paged-Attention and Gemma-on-Cloud-TPU
 studies (PAPERS.md) converge on: keep ONE fixed-shape decode program
 resident, keep its batch full, and never compute the same prefix twice.
 
-* **Paged KV pool** (:class:`PagedKVPool`): all in-flight sequences share a
+* **Paged KV pool** (:class:`PagedKVPool`, generation/pools.py, as are the
+  state pool and the prefix trie below): all in-flight sequences share a
   ``[L, num_pages, page_size, nkv, d]`` pool; a sequence owns an ordered
   page list (its block table).  Pages are REFERENCE-COUNTED: several
   sequences may share the pages of a common prompt prefix.  Page 0 is the
@@ -91,7 +92,7 @@ resident, keep its batch full, and never compute the same prefix twice.
   same code; prefill stops before a prompt's last token (:meth:`_fill_end`),
   preemption drops the state and the resume prefills again, and the prefix
   cache is off.  What pages alone carry refuses in a sentence
-  (:func:`refuse_state_cache`).
+  (generation/pools.py ``refuse_unserved``).
 
 Threading: ``submit`` may be called from any thread (e.g. concurrent HTTP
 handlers — generation/server.py); device work happens on whichever thread
@@ -102,8 +103,6 @@ a caller loop (:meth:`run_until_idle`).
 from __future__ import annotations
 
 import dataclasses
-import heapq
-import itertools
 import os
 import threading
 import time
@@ -120,6 +119,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from megatron_llm_tpu.config.arguments import check_prefill_chunk
 from megatron_llm_tpu.core.parallel_state import PP_AXIS, TP_AXIS
 from megatron_llm_tpu.generation import generation as gen
+from megatron_llm_tpu.generation.pools import (  # noqa: F401 — re-exported
+    NULL_PAGE,
+    PagedKVPool,
+    PrefixCache,
+    StatePool,
+    refuse_unserved,
+)
 from megatron_llm_tpu.generation.scheduling import (
     RequestShed,
     SchedulerPolicy,
@@ -135,15 +141,11 @@ from megatron_llm_tpu.observability.profiler import (
 )
 from megatron_llm_tpu.generation.tokenization import detokenize_generations
 from megatron_llm_tpu.models.language_model import (
-    _compute_dtype,
     make_rope_cache,
     model_forward,
 )
-from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
 from megatron_llm_tpu.ops.pallas.paged_attention import tile_runs
-
-NULL_PAGE = 0
 
 
 def _request_key(seed: int) -> np.ndarray:
@@ -173,986 +175,6 @@ class EngineOverloaded(RuntimeError):
         super().__init__(msg)
         self.retry_after = retry_after
         self.info = info or {}
-
-
-def refuse_layer_pattern(cfg, *, kv_dtype: str = "bf16", mesh=None,
-                         draft: bool = False, pipeline_depth: int = 0,
-                         handoff: bool = False) -> None:
-    """A patterned stack (window and full layers mixed) is served on a pool
-    with one page class a cache need (models/transformer.py
-    ``pool_classes``: a window layer's class gives a page back once its
-    sequence's window has moved past it), and a share of the experts
-    (``moe_experts_held``) as the part of each layer's sum that the held
-    experts give.  What does not carry two page classes, or a share, yet
-    says so at start-up, in a sentence, instead of failing inside a
-    compile.  Chunked prefill, the prefix cache, copy-on-write and
-    preemption DO carry both."""
-    from megatron_llm_tpu.models.transformer import pool_classes
-
-    m = cfg.model
-    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
-    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
-    if m.num_experts is not None and m.experts_held < m.num_experts and (
-            tp > 1 or pp > 1):
-        raise ValueError(
-            f"moe_experts_held {m.moe_experts_held} of {m.num_experts} is "
-            "one chip's share of an expert-parallel layer, its attention "
-            f"data-parallel: serve it on one chip, not on a tp {tp} x pp "
-            f"{pp} mesh (the other chips' experts and the exchange with "
-            "them are not here)")
-    classes = pool_classes(cfg)
-    if len(classes) == 1:
-        return
-    why = None
-    if len(classes) > 2 or classes[0].window is not None or m.mla or (
-            m.dense_prefix_layers):
-        why = ("this pattern (more than one window size, no full layer, "
-               "latent attention or a dense prefix): the pool knows a full "
-               "class and ONE window class of K/V rows over one stack")
-    elif kv_dtype != "bf16":
-        why = (f"--kv_dtype {kv_dtype}: a page's scales are set by the "
-               "page's first write, and no test holds them through a "
-               "window class's release and re-grant")
-    elif tp > 1 or pp > 1:
-        why = (f"tensor- or pipeline-parallel serving (tp {tp}, pp {pp}): "
-               "the pool's shardings and the stage pipeline name one leaf")
-    elif draft:
-        why = ("--spec_k: the verify tick and the draft cache are built "
-               "for one block table a sequence")
-    elif pipeline_depth:
-        why = ("--tick_pipeline_depth: the chained tick grants pages ahead "
-               "for one block table a sequence and never slides a window")
-    elif handoff:
-        why = ("the cross-replica KV handoff: its wire format names one "
-               "page list a sequence")
-    if why:
-        raise ValueError(
-            f"a layer pattern (sliding_window_layout {m.sliding_window_layout}"
-            ") keeps its window layers' and its full layers' keys in two "
-            f"page classes, which {why} does not carry yet. Serve this "
-            "model on one chip with --kv_dtype bf16, --spec_k 0 and "
-            "--tick_pipeline_depth 0.")
-
-
-def refuse_latent_cache(*, kv_dtype: str = "bf16", mesh=None,
-                        draft: bool = False, pipeline_depth: int = 0,
-                        handoff: bool = False) -> None:
-    """What does not carry a one-leaf latent pool (MLA) yet says so at
-    start-up, in a sentence, instead of failing inside a compile.  The
-    prefix trie, copy-on-write, preemption and chunked prefill DO carry it:
-    they move page ids, and a page copy maps over whatever leaves exist."""
-    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
-    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
-    why = None
-    if kv_dtype != "bf16":
-        why = (f"--kv_dtype {kv_dtype}: page scales are kept per KV head, "
-               "and a latent row has none")
-    elif tp > 1:
-        why = (f"tensor-parallel serving (tp {tp}): the pool shards over "
-               "KV heads, and a latent row has none")
-    elif pp > 1:
-        why = (f"pipeline-parallel serving (pp {pp}): its stages split "
-               "one scanned stack, and this family's dense prefix layers "
-               "come before it")
-    elif draft:
-        why = ("--spec_k: the verify tick and the draft cache are built "
-               "for a K/V pool")
-    elif pipeline_depth:
-        why = ("--tick_pipeline_depth: the chained tick is not tested "
-               "with a latent pool")
-    elif handoff:
-        why = ("the cross-replica KV handoff: its wire format names a K "
-               "and a V leaf")
-    if why:
-        raise ValueError(
-            "latent attention (attention_type 'mla') keeps ONE latent row a "
-            f"token, key and value at once, which {why} does not "
-            "carry yet. Serve this model on one chip with --kv_dtype bf16, "
-            "--spec_k 0 and --tick_pipeline_depth 0.")
-
-
-def refuse_state_cache(cfg, *, kv_dtype: str = "bf16", mesh=None,
-                       draft: bool = False, pipeline_depth: int = 0,
-                       handoff: bool = False,
-                       log_probs: bool = False) -> None:
-    """Power retention keeps ONE float32 state a sequence, of constant
-    size, in a state slot (:class:`StatePool`): no key, no value, no page.
-    What is written for pages says so at start-up (or at the request), in
-    a sentence, instead of failing inside a compile.  Admission, chunked
-    prefill, preemption (the state is dropped and the tokens are prefilled
-    again) and retirement DO carry it; the prefix cache is off for it, not
-    refused: a trie of pages has nothing to hold."""
-    m = cfg.model
-    tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
-    pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
-    why = None
-    if m.sliding_window_layout or m.dense_prefix_layers or m.mla:
-        why = ("a stack that mixes it with a page class (a layer pattern, "
-               "a dense prefix, latent attention): the engine serves one "
-               "kind of per-sequence memory a model")
-    elif kv_dtype != "bf16":
-        why = (f"--kv_dtype {kv_dtype}: the state is a float32 sum that is "
-               "decayed and added to at every token, and storing it lower "
-               "is a different result")
-    elif tp > 1:
-        why = (f"tensor-parallel serving (tp {tp}): the state pool is not "
-               "sharded over its KV heads")
-    elif pp > 1:
-        why = (f"pipeline-parallel serving (pp {pp}): the stage pipeline "
-               "hands a paged leaf from stage to stage")
-    elif draft:
-        why = ("--spec_k: a rejected draft token would have to roll the "
-               "state back, and nothing keeps the state before it")
-    elif pipeline_depth:
-        why = ("--tick_pipeline_depth: the chained tick freezes a finished "
-               "row by a null block table, and is not tested with a state")
-    elif handoff:
-        why = ("the cross-replica KV handoff: its wire format names pages "
-               "of keys and values")
-    elif log_probs:
-        why = ("return_log_probs (prompt scoring): the scoring chunk feeds "
-               "many tokens a row through a block table")
-    if why:
-        raise ValueError(
-            "power retention (attention_type 'retention') keeps a "
-            f"constant-size recurrent state a sequence, which {why} does "
-            "not carry yet. Serve this model on one chip with --kv_dtype "
-            "bf16, --spec_k 0 and --tick_pipeline_depth 0.")
-
-
-class PagedKVPool:
-    """Device page pool + host refcounting allocator.
-
-    The device array is ONE leaf ``[L, P, page, row]`` (``kv``) whose row
-    ``ops/kv_quant.py`` owns and derives from ``(nkv, d, dtype)``: a head's
-    key and value side by side, so a page is one copy for the kernel and a
-    token one scatter for the write, in whole 128-lane rows (Falcon-7B's
-    head of 64 included); a latent model's row is its padded latent.  The
-    pool keeps that ONE layout from the write to the kernel: every tick
-    program carries it through the layer scan and updates it in place
-    (models/transformer.py ``LayerPool``).  Code off the tick's hot path
-    reads the logical ``(page, offset, head, d)`` view through kv_quant
-    (:meth:`logical_kv`, the handoff's export / import).  The allocator
-    is host-side python — alloc/release happen at request
-    admission/retirement and page-boundary crossings, far below tick
-    frequency.
-
-    Page states (disjoint, tests/test_prefix_cache.py invariants):
-
-    * **free** — on the free list, refcount 0, not cached;
-    * **referenced** — refcount > 0 (held by >= 1 request's block table),
-      possibly ALSO registered in the prefix cache;
-    * **cached-idle** — refcount 0 but registered in the prefix cache
-      (``cached``): reusable by a future match, reclaimable by
-      ``evict_hook`` (PrefixCache.evict, LRU leaf-first) when ``alloc``
-      outruns the free list.
-
-    How many pages are cached-idle is KEPT, not walked: the count changes
-    only where a page crosses a boundary (``incref`` of a cached page
-    0 -> 1, ``release`` of one 1 -> 0, ``set_cached`` as a page enters or
-    leaves ``cached``), so ``num_evictable`` and ``num_available`` are
-    reads.  Pages that ``release`` leaves cached-idle are handed to
-    ``idle_hook`` (PrefixCache.note_idle), which keeps the eviction order.
-
-    With ``draft_cfg`` (speculative decoding, generation/speculative/),
-    the pool carries a SECOND leaf (``draft_kv``) shaped by the draft
-    model — same ``num_pages``, same page ids.  A page id then addresses
-    both models' K/V for the same token positions: one block table, one
-    refcount, one commitment ledger and one prefix trie govern both
-    caches, so admission/preemption accounting stays deadlock-proof with
-    zero new allocator states.
-    """
-
-    def __init__(self, cfg, num_pages: int, page_size: int, dtype=None,
-                 mesh: Optional[Mesh] = None, draft_cfg=None,
-                 kv_dtype: str = "bf16", layers: Optional[int] = None,
-                 page_class: Optional[str] = None):
-        m = cfg.model
-        # one page class of a patterned model's pool (``page_class`` names
-        # it in the counters' ``class=`` label; ``layers``: how many of the
-        # model's layers keep their keys here).  None: the one pool of a
-        # uniform model, every layer's, its counters unlabelled as ever
-        self.page_class = page_class
-        layers = m.depth if layers is None else layers
-        dtype = dtype or _compute_dtype(cfg)
-        assert kv_dtype in kv_quant.KV_DTYPES, (
-            f"kv_dtype must be one of {kv_quant.KV_DTYPES}, got {kv_dtype!r}")
-        # --kv_dtype (ISSUE 13): "bf16" keeps plain compute-dtype arrays —
-        # byte-for-byte today's pool, every bitwise parity suite intact;
-        # int8/fp8 store QuantPagedKV containers (values + per-page,
-        # per-head scales, ops/kv_quant.py) for ~2x pages per chip.
-        self.kv_dtype = kv_dtype
-        self.compute_dtype = dtype
-        # latent attention (MLA): a row of [normed latent | rotated rope
-        # key] a token and layer, ONE storage head that the kernel reads as
-        # key and as value, stored in whole 128-lane rows (576 -> 640
-        # values; the lanes past ``latent_cache_width`` are zeros nobody
-        # reads, 11% of the leaf).  Every other model: the K/V row
-        self.latent = bool(m.mla)
-        # power retention (:class:`StatePool`): a "page" is a sequence's
-        # whole state, float32 whatever the activations are
-        self.state = bool(m.retention)
-        if self.state:
-            from megatron_llm_tpu.ops import retention as ret_ops
-
-            refuse_state_cache(cfg, kv_dtype=kv_dtype, mesh=mesh,
-                               draft=draft_cfg is not None)
-            self.head_dim = m.kv_channels
-            kv = ret_ops.zero_state((layers, num_pages),
-                                    m.num_attention_heads_kv, self.head_dim)
-        elif self.latent:
-            refuse_latent_cache(kv_dtype=kv_dtype, mesh=mesh,
-                                draft=draft_cfg is not None)
-            # the logical view's head width: one head, the whole row
-            self.head_dim = -(-m.latent_cache_width // 128) * 128
-            kv = kv_quant.make_pool(
-                (m.depth, num_pages, page_size, 1, self.head_dim), kv_dtype,
-                dtype)
-        else:
-            self.head_dim = m.kv_channels
-            kv = kv_quant.make_kv_pool(
-                layers, num_pages, page_size, m.num_attention_heads_kv,
-                self.head_dim, kv_dtype, dtype)
-
-        # Tensor parallelism shards the pool's row over its KV heads (each
-        # tp rank attends its own heads — the same decomposition as the qkv
-        # column-parallel rule in parallel/tp.py; the row is head-major, so
-        # a head's key|value pair stays on its shard). Block tables and the
-        # allocator below stay host-side and apply to every shard alike;
-        # tp=1 (or no mesh) degrades to a single-device replicated pool.
-        # Quantized pools shard the scale leaf over the same heads
-        # ([L, P, 2*nkv] -> tp), so a page's values and its scales
-        # always live on the same shard.
-        # Pipeline parallelism (ISSUE 20) additionally shards the pool
-        # over the LAYER dim: each pp stage holds only its own L/pp
-        # layers' pages — per-stage pool bytes are 1/pp of the tp-only
-        # pool (the servable-model-size multiplier).  Page ids address
-        # the same slot of every stage's slice, so block tables, the
-        # trie, the allocator and the commitment ledger below stay
-        # host-side and stage-agnostic, untouched.
-        self.mesh = mesh
-        tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
-        pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
-        self.pp = pp
-        if pp > 1:
-            assert m.num_layers % pp == 0, (
-                f"num_layers {m.num_layers} not divisible by pp {pp}")
-        if tp > 1 or pp > 1:
-            if tp > 1:
-                assert m.num_attention_heads_kv % tp == 0, (
-                    f"kv heads {m.num_attention_heads_kv} not divisible by "
-                    f"tp {tp}")
-            layer_ax = PP_AXIS if pp > 1 else None
-            heads_ax = TP_AXIS if tp > 1 else None
-            self.kv_sharding = NamedSharding(
-                mesh, P(layer_ax, None, None, heads_ax))
-            self._scale_sharding = NamedSharding(
-                mesh, P(layer_ax, None, heads_ax))
-            self.kv = self._place(kv)
-        else:
-            self.kv_sharding = (NamedSharding(mesh, P())
-                                if mesh is not None else None)
-            self._scale_sharding = self.kv_sharding
-            self.kv = kv
-        self.draft_cfg = draft_cfg
-        self.draft_kv = None
-        if draft_cfg is not None:
-            dm = draft_cfg.model
-            draft_kv = kv_quant.make_kv_pool(
-                dm.num_layers, num_pages, page_size,
-                dm.num_attention_heads_kv, dm.kv_channels, kv_dtype,
-                _compute_dtype(draft_cfg))
-            if pp > 1:
-                assert dm.num_layers % pp == 0, (
-                    f"draft num_layers {dm.num_layers} not divisible by "
-                    f"pp {pp}")
-            if tp > 1 or pp > 1:
-                if tp > 1:
-                    assert dm.num_attention_heads_kv % tp == 0, (
-                        f"draft kv heads {dm.num_attention_heads_kv} not "
-                        f"divisible by tp {tp}")
-                draft_kv = self._place(draft_kv)
-            self.draft_kv = draft_kv
-        self.num_pages = num_pages
-        self.page_size = page_size
-        self.refcounts = np.zeros((num_pages,), np.int32)
-        # pages owned by the prefix cache (trie nodes); PrefixCache adds
-        # and removes them through ``set_cached``, which keeps the count
-        # of those at refcount 0 beside the set
-        self.cached: Set[int] = set()
-        self._idle_cached = 0
-        self.evict_hook = None  # PrefixCache.evict: (n) -> freed page list
-        # PrefixCache.note_idle: (pages a release left cached-idle) -> None
-        self.idle_hook = None
-        # page 0 reserved as the null page (never allocated)
-        self._free: deque = deque(range(1, num_pages))
-        # a grant had to evict since the engine's step last looked: the
-        # step that launches the next tick counts it dry and clears this
-        self.reclaimed = False
-        # the slow path counts and times itself (an ``alloc`` the free
-        # list serves reads no clock): pages by where they came from, and
-        # the calling thread's wall seconds picking victims.  Counting the
-        # evictable pages is a read since the count is kept; its series
-        # stays exported, at 0, for the readers that sum both
-        reg = obs_registry.get_registry()
-        cls = {} if page_class is None else {"class": page_class}
-        self._m_alloc = {
-            src: reg.counter(
-                "mlt_engine_pool_alloc_pages_total",
-                help="KV pool pages granted: free = off the free list, "
-                     "evict = a cached-idle page the prefix cache had to "
-                     "give up first (the pool had run dry); a patterned "
-                     "model's series carry class= (full, window)",
-                labels={**cls, "source": src}) for src in ("free", "evict")}
-        # the three states a page is in (the null page in none), set by
-        # the engine where a tick is applied
-        self._m_pages = {
-            state: reg.gauge(
-                "mlt_engine_pool_pages",
-                help="pool pages by state: referenced (a live sequence's "
-                     "table names it), cached_idle (only the prefix cache "
-                     "does), free; class = the page class (full: every "
-                     "key kept, the one class of a uniform model; window: "
-                     "a patterned model's window layers')",
-                labels={"class": page_class or "full", "state": state})
-            for state in ("referenced", "cached_idle", "free")}
-        self._m_scan = {
-            what: reg.counter(
-                "mlt_engine_pool_scan_seconds_total",
-                help="wall seconds the calling thread spent in the pool's "
-                     "slow path: evict = the prefix cache picking and "
-                     "unlinking victims; evictable = counting the cached "
-                     "pages no request references, 0 since that count is "
-                     "kept as references change and no longer walked",
-                labels={"what": what}) for what in ("evictable", "evict")}
-
-    def _place(self, pool):
-        """device_put a pool (plain array or QuantPagedKV) under the tp
-        sharding — a row over its heads, scales over their heads dim."""
-        if kv_quant.is_quantized(pool):
-            return jax.device_put(pool, kv_quant.QuantPagedKV(
-                q=self.kv_sharding, scale=self._scale_sharding))
-        return jax.device_put(pool, self.kv_sharding)
-
-    @property
-    def kv_statics(self) -> Tuple:
-        """Compiled-program cache-key component for the KV storage mode
-        (ISSUE 13): kv-quantization mode, storage dtype AND scale dtype —
-        an int8 engine must never reuse a bf16 executable (and vice
-        versa), and a future scale-dtype change re-keys too.  Replaces
-        a pool-dtype key entry, which could not tell a container apart
-        from its storage array."""
-        if kv_quant.is_quantized(self.kv):
-            return ("kv", self.kv_dtype, str(self.kv.q.dtype),
-                    str(self.kv.scale.dtype))
-        if self.latent:
-            return ("kv", "latent", str(self.kv.dtype), self.kv.shape[-1])
-        return ("kv", self.kv_dtype, str(self.kv.dtype))
-
-    @property
-    def draft_kv_statics(self) -> Tuple:
-        if self.draft_kv is None:
-            return ("draft_kv", None)
-        if kv_quant.is_quantized(self.draft_kv):
-            return ("draft_kv", self.kv_dtype, str(self.draft_kv.q.dtype),
-                    str(self.draft_kv.scale.dtype))
-        return ("draft_kv", self.kv_dtype, str(self.draft_kv.dtype))
-
-    def _pools(self) -> List[Tuple[str, object, int]]:
-        """(wire prefix, pool, head_dim) of every cache this pool holds."""
-        pools = [("", self.kv, self.head_dim)]
-        if self.draft_kv is not None:
-            pools.append(("draft_", self.draft_kv,
-                          self.draft_cfg.model.kv_channels))
-        return pools
-
-    def logical_kv(self, pages: Sequence[int], draft: bool = False):
-        """Host copies of ``pages`` in the logical view, whatever the
-        physical row: (keys, values), each ``[L, n, page, nkv, d]``
-        (dequantized where the pool is quantized; a latent pool's row is
-        both).  For tests, tools and debugging — not the tick."""
-        _, pool, d = self._pools()[int(draft)]
-        ids = np.asarray(list(pages), np.int32)
-        got = jax.tree.map(lambda a: a[:, ids], pool)
-        heads = np.asarray(
-            kv_quant.dequantize_pages(got, jnp.float32)
-            if kv_quant.is_quantized(got) else kv_quant.heads_view(got, d))
-        return (heads, heads) if self.latent else kv_quant.split_kv(heads)
-
-    def kv_pool_bytes(self) -> int:
-        """Device bytes of the KV value storage, target + draft caches —
-        the fixed budget the capacity bench holds constant while the
-        kv_dtype varies (published as ``mlt_engine_kv_pool_bytes``)."""
-        n = kv_quant.pool_nbytes(self.kv)
-        if self.draft_kv is not None:
-            n += kv_quant.pool_nbytes(self.draft_kv)
-        return n
-
-    def kv_stage_bytes(self) -> int:
-        """Per-stage device bytes of the KV value storage: the layer dim
-        is sharded over pp, so each stage holds ``kv_pool_bytes / pp`` —
-        the number a pp=N replica's HBM budget actually pays (published
-        as ``mlt_engine_kv_stage_bytes``; bench --mode pp evidence)."""
-        return self.kv_pool_bytes() // self.pp
-
-    def kv_scale_bytes(self) -> int:
-        """Per-page scale overhead bytes (0 for bf16)."""
-        n = kv_quant.scale_nbytes(self.kv)
-        if self.draft_kv is not None:
-            n += kv_quant.scale_nbytes(self.draft_kv)
-        return n
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    def publish_states(self) -> None:
-        """The ``mlt_engine_pool_pages{class=,state=}`` gauges: reads of
-        kept counts."""
-        free, idle = len(self._free), self._idle_cached
-        self._m_pages["free"].set(free)
-        self._m_pages["cached_idle"].set(idle)
-        self._m_pages["referenced"].set(self.num_pages - 1 - free - idle)
-
-    @property
-    def num_evictable(self) -> int:
-        """Cached pages no request references — reclaimable on demand.
-        A read: the count is kept where references and ``cached`` change
-        (what a walk ``sum(refcounts[p] == 0 for p in cached)`` would
-        give, tests/test_prefix_cache.py holds the two equal)."""
-        return self._idle_cached
-
-    def set_cached(self, page: int, cached: bool) -> None:
-        """The prefix cache registers ``page`` (a node now owns it) or
-        gives it up (evicted; the caller puts it on the free list)."""
-        if cached:
-            self.cached.add(page)
-        else:
-            self.cached.remove(page)
-        if self.refcounts[page] == 0:
-            self._idle_cached += 1 if cached else -1
-
-    @property
-    def num_available(self) -> int:
-        """Pages an ``alloc`` could produce right now (free + evictable)."""
-        return self.num_free + self.num_evictable
-
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """``n`` fresh pages at refcount 1, or None if free + evictable
-        can't satisfy the request.  Evicts cached-idle pages (LRU,
-        leaf-first) only when the free list alone runs short."""
-        # the free list first: the tick's page grants come here once a
-        # row, and this path reads no clock and opens no span
-        evicted = 0
-        if n > len(self._free):
-            evicted = self._reclaim(n)
-            if n > len(self._free):
-                return None
-        pages = [self._free.popleft() for _ in range(n)]
-        for p in pages:
-            assert self.refcounts[p] == 0 and p not in self.cached
-            self.refcounts[p] = 1
-        if obs_registry.publishing():
-            self._m_alloc["free"].inc(n - evicted)
-            if evicted:
-                self._m_alloc["evict"].inc(evicted)
-        return pages
-
-    def _reclaim(self, n: int) -> int:
-        """``alloc``'s slow path: the free list is short of ``n`` pages.
-        Evicts the shortfall in cached-idle pages onto it if there are as
-        many (a read of the kept count: a grant that cannot be served
-        evicts nothing and reads no clock), and returns how many it
-        evicted.  What it costs is what its victims cost, one or two heap
-        entries each, not the size of the trie.  One ``pool-reclaim`` span
-        a call (inside the caller's ``engine-admit`` or ``engine-plan``)
-        with the eviction's zero-length ``pool-evict`` inside, so a
-        device idle gap that is an eviction is named in a capture."""
-        short = n - len(self._free)
-        with obs_trace.span("pool-reclaim", want=n, free=len(self._free),
-                            cached=len(self.cached)):
-            if short > self.num_evictable or self.evict_hook is None:
-                return 0
-            t0 = time.perf_counter()
-            freed = self.evict_hook(short)
-            if obs_registry.publishing():
-                self._m_scan["evict"].inc(time.perf_counter() - t0)
-            self._free.extend(freed)
-            if freed:
-                self.reclaimed = True
-            return len(freed)
-
-    def free_evicted(self, pages: Sequence[int]) -> None:
-        """Pages the prefix cache gave up outside this pool's own
-        ``_reclaim`` (a node evicted for the other class) go free."""
-        self._free.extend(pages)
-
-    def incref(self, pages: Sequence[int]) -> None:
-        for p in pages:
-            assert p != NULL_PAGE
-            if self.refcounts[p] == 0 and p in self.cached:
-                self._idle_cached -= 1
-            self.refcounts[p] += 1
-
-    def release(self, pages: Sequence[int]) -> None:
-        """Drop one reference per page.  Unreferenced pages return to the
-        free list unless the prefix cache still holds them (those stay
-        cached-idle until matched again or evicted)."""
-        idle = []
-        for p in pages:
-            assert p != NULL_PAGE, "null page is never allocated"
-            self.refcounts[p] -= 1
-            assert self.refcounts[p] >= 0, f"page {p} over-released"
-            if self.refcounts[p] == 0:
-                (idle if p in self.cached else self._free).append(p)
-        if idle:
-            self._idle_cached += len(idle)
-            if self.idle_hook is not None:
-                self.idle_hook(idle)
-
-    # ---- cross-replica page transfer (ISSUE 19, serving/handoff/) ----
-
-    def export_pages(self, pages: Sequence[int]) -> Dict[str, np.ndarray]:
-        """Gather ``pages`` from every storage leaf to the host: ONE
-        batched ``device_get`` over all leaves (values, scale rows, draft
-        cache), so a multi-page export pays one transfer sync.  What
-        comes back are the wire's LOGICAL leaves (ops/kv_quant.kv_to_leaves:
-        ``k``, ``v``, for a quantized pool ``k.q`` / ``k.scale`` / ``v.q`` /
-        ``v.scale``, a speculating pool's ``draft_*`` beside them), bytes
-        verbatim — exactly the set a receiving pool must install for a
-        migrated page to be bit-identical to a locally prefilled one;
-        the physical row never leaves this pool.  The caller must hold
-        page refs on ``pages`` and serialize against tick dispatch (the
-        engine's ``_drive_lock``) — ticks rebind the pool arrays with
-        donated buffers."""
-        assert not self.latent, "the handoff carries K/V pools"
-        ids = np.asarray(list(pages), np.int32)
-        pools = self._pools()
-        host = jax.device_get(
-            [jax.tree.map(lambda a: a[:, ids], pool) for _, pool, _ in pools])
-        leaves: Dict[str, np.ndarray] = {}
-        for (prefix, _, d), got in zip(pools, host):
-            leaves.update(kv_quant.kv_to_leaves(got, d, prefix))
-        return leaves
-
-    def import_pages(self, pages: Sequence[int],
-                     leaves: Dict[str, np.ndarray]) -> None:
-        """Install exported leaf bytes into freshly allocated ``pages``
-        VERBATIM — quantized leaves set values and scales directly,
-        never re-quantizing, so the imported page is byte-identical to
-        the sender's (tests/test_handoff.py round-trip).  Leaf names,
-        dtypes and shapes must match this pool's logical leaves exactly
-        (a bf16 pool cannot install an int8 export; a speculating
-        sender's draft leaves need a speculating receiver).  Caller
-        serializes against tick dispatch, same as :meth:`export_pages`."""
-        ids = np.asarray(list(pages), np.int32)
-        quant = kv_quant.is_quantized(self.kv)
-        want: Dict[str, Tuple] = {}
-        for prefix, pool, d in self._pools():
-            arr = kv_quant.values_of(pool)
-            lead = (arr.shape[0], len(ids))
-            heads = arr.shape[-1] // (2 * d)
-            for side in "kv":
-                name = prefix + side + (".q" if quant else "")
-                want[name] = (arr.dtype, lead + (arr.shape[2], heads, d))
-                if quant:
-                    want[prefix + side + ".scale"] = (
-                        pool.scale.dtype, lead + (heads,))
-        if sorted(want) != sorted(leaves):
-            raise ValueError(
-                f"handoff leaves {sorted(leaves)} do not match this "
-                f"pool's storage leaves {sorted(want)} "
-                f"(kv_dtype={self.kv_dtype!r}, "
-                f"draft={'yes' if self.draft_kv is not None else 'no'})")
-        for name, (dtype, shape) in want.items():
-            val = leaves[name]
-            if tuple(val.shape) != shape or val.dtype != dtype:
-                raise ValueError(
-                    f"handoff leaf {name!r} is {val.dtype}{val.shape}, "
-                    f"pool needs {dtype}{shape}")
-
-        def _install(pool, prefix):
-            rows = kv_quant.kv_from_leaves(leaves, quant, prefix)
-            return jax.tree.map(
-                lambda a, r: a.at[:, ids].set(jnp.asarray(r)), pool, rows)
-
-        self.kv = _install(self.kv, "")
-        if self.draft_kv is not None:
-            self.draft_kv = _install(self.draft_kv, "draft_")
-
-
-class StatePool(PagedKVPool):
-    """The pool of a model that keeps a recurrent STATE and no keys (power
-    retention, ops/retention.py): ``kv`` is ``ops/retention.State``, leaves
-    ``s [layers, slots + 1, nkv, d, D]`` and ``z [layers, slots + 1, nkv,
-    1, D]`` in float32, indexed by STATE SLOT.  The allocator is the page
-    pool's, a slot standing where a page stood: a sequence holds exactly
-    ONE from admission to its end, whatever its length, so with as many
-    slots as the engine has decode slots nothing ever runs dry, nothing is
-    granted while a sequence decodes, and nothing is shared, cached or
-    evicted.  Slot 0 is the null slot, as page 0 is the null page: a dead
-    row's table names it and the tick touches no state for it.  A slot is
-    not cleared when it changes hands: the first row of a sequence stands
-    at position 0, and the tick's program takes a zero state for the run
-    that starts there whatever the slot held (``ops/retention.tick_runs``:
-    no launch of its own)."""
-
-    def __init__(self, cfg, slots: int, page_size: int):
-        assert cfg.model.retention
-        super().__init__(cfg, slots + 1, page_size, page_class=None)
-
-    @property
-    def kv_statics(self) -> Tuple:
-        return ("kv", "state", str(self.kv.s.dtype), self.kv.s.shape[-1])
-
-    def kv_pool_bytes(self) -> int:
-        return sum(a.size * a.dtype.itemsize for a in self.kv)
-
-    def kv_scale_bytes(self) -> int:
-        return 0
-
-
-class _TrieNode:
-    __slots__ = ("key", "page", "wpage", "parent", "children", "last_use",
-                 "depth")
-
-    def __init__(self, key, page, parent):
-        self.key = key
-        self.page = page
-        self.depth = 0 if parent is None else parent.depth + 1
-        # the block's page in the window class of a patterned model's pool
-        # (NULL_PAGE: none, or evicted while the full class's page stays)
-        self.wpage = NULL_PAGE
-        self.parent = parent
-        self.children: Dict[Tuple[int, ...], "_TrieNode"] = {}
-        self.last_use = 0
-
-
-class PrefixCache:
-    """Host-side radix/trie over page-aligned token chunks -> pool pages.
-
-    Each node owns one FULL page of prompt K/V, keyed by that page's
-    ``page_size`` token ids; a path from the root spells a prompt prefix.
-    ``match`` walks the trie and takes a pool reference on every matched
-    page (the caller's block table will point at them); ``insert`` registers
-    a freshly prefilled request's full prompt pages so later requests can
-    share them.  Because a request that matches a page has, by
-    construction, matched ALL its ancestors too, a refcount-0 node's
-    descendants are also refcount-0 — so eviction can always proceed
-    leaf-first through cached-idle subtrees, and ``PagedKVPool.num_evictable``
-    (the pool's kept count of cached pages at refcount 0) is exactly the
-    number of reclaimable pages.
-
-    Eviction takes the idle LEAF (no child, refcount 0) with the lowest
-    ``last_use``.  Those leaves are kept in that order in a heap
-    (``_idle``), entered where a node becomes one: the pool releases its
-    page's last reference while it has no child (``note_idle``), its last
-    child is evicted while it is idle, or ``insert`` ends on it
-    unreferenced.  Nothing is taken out when a node stops being one
-    (``match`` references it, ``insert`` hangs a child on it or stamps it
-    anew): an entry is checked where it is popped and dropped if its node
-    is gone, has a child, is referenced or was stamped since, so ``evict``
-    looks at one or two entries a victim whatever the trie holds.  Order
-    of entry is not order of use, hence a heap and no queue.  Every
-    ``match`` / ``insert`` stamps one root path with a fresh clock value
-    and only the deepest node of a path can be childless, so no two idle
-    leaves share a ``last_use`` and the order is total.  A pool that
-    never runs dry never pops: the heap is rebuilt from the trie when it
-    outgrows ``2 * len(self) + 64`` entries, which keeps it O(nodes) at
-    an amortised constant a push.
-
-    **Two page classes** (``wpool``: a patterned model's window class, its
-    layers seeing ``window`` keys).  A node names its block's page in each
-    class, and the window class's may be gone while the full class's stays:
-    a sequence gives a window page back once its window has moved past it
-    (registered ones go cached-idle in their class), and the window pool
-    evicts its idle pages in use order WHATEVER their place in the trie
-    (``evict_window``: a heap of its own, the shallower of two pages of one
-    stamp first, since a match needs the pages before its end).  A window
-    page referenced means its node's full page referenced (a sequence
-    holds every full page of its context), so evicting a node frees both.
-    ``match_classes`` returns the longest page-aligned length whose full
-    pages are all present AND whose window pages cover the ``window`` keys
-    before its end; a longer match that the window class cannot serve is
-    shortened to that (recomputing a window layer's keys needs the layers
-    below at those positions, so a gap cannot be filled in).
-    """
-
-    def __init__(self, pool: PagedKVPool, page_size: int,
-                 wpool: Optional[PagedKVPool] = None,
-                 window: Optional[int] = None):
-        self.pool = pool
-        self.wpool = wpool
-        self.window = window
-        self.page_size = page_size
-        self.root = _TrieNode(None, NULL_PAGE, None)
-        self._nodes: Dict[int, _TrieNode] = {}  # page id -> node
-        self._clock = 0
-        # (last_use, entry number, node): the number only keeps two
-        # entries of one node and stamp from comparing nodes
-        self._idle: List[Tuple[int, int, _TrieNode]] = []
-        self._entry = itertools.count()
-        pool.evict_hook = self.evict
-        pool.idle_hook = self.note_idle
-        reg = obs_registry.get_registry()
-        if wpool is not None:
-            self._wnodes: Dict[int, _TrieNode] = {}  # window page -> node
-            # (last_use, depth, entry number, node)
-            self._widle: List[Tuple[int, int, int, _TrieNode]] = []
-            wpool.evict_hook = self.evict_window
-            wpool.idle_hook = self.note_widle
-            self._m_wevicted = reg.counter(
-                "mlt_engine_prefix_window_evicted_pages_total",
-                help="cached-idle pages of the WINDOW class the prefix "
-                     "cache gave up to a grant (their nodes keep the full "
-                     "class's page); equals mlt_engine_pool_alloc_pages_"
-                     "total{class=\"window\",source=\"evict\"}")
-            self._m_shortened = reg.counter(
-                "mlt_engine_prefix_match_shortened_total",
-                help="prefix matches cut short of the full class's pages "
-                     "because the window class no longer held the pages "
-                     "before the match's end",
-                labels={"by": "window"})
-        self._m_evicted = reg.counter(
-            "mlt_engine_prefix_evicted_pages_total",
-            help="cached-idle pages the prefix cache gave up to a grant")
-        self._m_scanned = reg.counter(
-            "mlt_engine_prefix_evict_scanned_nodes_total",
-            help="entries of the idle-leaf order looked at to pick "
-                 "eviction victims, stale ones included; over the evicted "
-                 "pages: the work one eviction costs (1-2, whatever the "
-                 "trie holds)")
-        self._m_rebuilds = reg.counter(
-            "mlt_engine_prefix_idle_rebuilds_total",
-            help="times the idle-leaf order outgrew twice the trie and "
-                 "was rebuilt from it (stale entries of a pool that "
-                 "rarely evicts)")
-
-    def __len__(self) -> int:
-        return len(self._nodes)
-
-    def _key(self, tokens: Sequence[int], i: int) -> Tuple[int, ...]:
-        ps = self.page_size
-        return tuple(tokens[i * ps:(i + 1) * ps])
-
-    def match(self, tokens: Sequence[int], max_pages: int) -> List[int]:
-        """Longest cached prefix of ``tokens`` in whole pages (capped at
-        ``max_pages``); takes one pool ref per matched page."""
-        self._clock += 1
-        node, pages = self.root, []
-        for i in range(max_pages):
-            child = node.children.get(self._key(tokens, i))
-            if child is None:
-                break
-            child.last_use = self._clock
-            pages.append(child.page)
-            node = child
-        self.pool.incref(pages)
-        return pages
-
-    def window_first(self, n_pages: int) -> int:
-        """The first block whose window-class page a sequence still needs
-        once ``n_pages`` whole pages of it are cached: the one holding the
-        oldest key the query at the last cached position can see."""
-        return max(0, n_pages * self.page_size - self.window) // self.page_size
-
-    def match_classes(self, tokens: Sequence[int], max_pages: int
-                      ) -> Tuple[List[int], List[int]]:
-        """``match`` for a pool with a window class: (full-class pages,
-        window-class pages), one pool ref each, the second list as long as
-        the first with ``NULL_PAGE`` for the blocks the window has left
-        behind (a sequence's window table keeps the block's place)."""
-        self._clock += 1
-        node, path, run, runs = self.root, [], 0, []
-        for i in range(max_pages):
-            child = node.children.get(self._key(tokens, i))
-            if child is None:
-                break
-            run = run + 1 if child.wpage != NULL_PAGE else 0
-            path.append(child)
-            runs.append(run)
-            node = child
-        m = len(path)
-        while m and runs[m - 1] < m - self.window_first(m):
-            m -= 1
-        if m < len(path) and obs_registry.publishing():
-            self._m_shortened.inc()
-        first = self.window_first(m)
-        for nd in path[:m]:
-            nd.last_use = self._clock
-        pages = [nd.page for nd in path[:m]]
-        wpages = [nd.wpage for nd in path[first:m]]
-        self.pool.incref(pages)
-        self.wpool.incref(wpages)
-        return pages, [NULL_PAGE] * first + wpages
-
-    def insert(self, tokens: Sequence[int], pages: Sequence[int],
-               n_pages: int, wpages: Optional[Sequence[int]] = None) -> int:
-        """Register the first ``n_pages`` full pages of a prefilled prompt;
-        pages already cached at a position keep the incumbent (the
-        request's duplicate page simply stays private).  ``wpages``: the
-        same blocks' window-class pages (``NULL_PAGE`` where the sequence
-        gave one back); a node without one takes the sequence's where the
-        sequence holds the node's full page too, or can replace it.
-        Returns the number of pages newly cached."""
-        self._clock += 1
-        node, added = self.root, 0
-        for i in range(n_pages):
-            key = self._key(tokens, i)
-            child = node.children.get(key)
-            if child is None:
-                p = pages[i]
-                if p in self._nodes:  # defensive: one node per page
-                    break
-                child = _TrieNode(key, p, node)
-                node.children[key] = child
-                self._nodes[p] = child
-                self.pool.set_cached(p, True)
-                added += 1
-            if wpages is not None and child.wpage == NULL_PAGE and (
-                    i < len(wpages) and wpages[i] != NULL_PAGE
-                    and wpages[i] not in self._wnodes):
-                if child.page != pages[i]:
-                    # an incumbent that lost its window page: a prefix
-                    # nobody can match past.  Where nobody holds its full
-                    # page either, the sequence's own pair of pages takes
-                    # its place (a window page referenced means its node's
-                    # full page referenced); else the pair stays private
-                    if (self.pool.refcounts[child.page] != 0
-                            or pages[i] in self._nodes):
-                        child.last_use = self._clock
-                        node = child
-                        continue
-                    del self._nodes[child.page]
-                    self.pool.set_cached(child.page, False)
-                    self.pool.free_evicted([child.page])
-                    child.page = pages[i]
-                    self._nodes[child.page] = child
-                    self.pool.set_cached(child.page, True)
-                child.wpage = wpages[i]
-                self._wnodes[child.wpage] = child
-                self.wpool.set_cached(child.wpage, True)
-            child.last_use = self._clock
-            node = child
-        # the walk's end is the one node it may leave an idle leaf under a
-        # stamp the heap has not seen: a new page nobody references, or
-        # an incumbent's that its request's duplicate did not replace
-        if node is not self.root:
-            self._push_if_idle_leaf(node)
-        return added
-
-    def note_idle(self, pages: Sequence[int]) -> None:
-        """The pool's ``idle_hook``: a release left ``pages`` cached at
-        refcount 0.  Of a retired request's chain only the deepest page
-        is a leaf."""
-        for p in pages:
-            self._push_if_idle_leaf(self._nodes[p])
-
-    def _push_if_idle_leaf(self, node: _TrieNode) -> None:
-        if node.children or self.pool.refcounts[node.page] != 0:
-            return
-        heapq.heappush(self._idle,
-                       (node.last_use, next(self._entry), node))
-        if len(self._idle) > 2 * len(self._nodes) + 64:
-            self._rebuild_idle()
-
-    def _rebuild_idle(self) -> None:
-        """Drop the stale entries: the heap anew from the trie's idle
-        leaves, in place (``evict`` may be popping from this list)."""
-        self._idle[:] = [
-            (n.last_use, next(self._entry), n) for n in self._nodes.values()
-            if not n.children and self.pool.refcounts[n.page] == 0]
-        heapq.heapify(self._idle)
-        if obs_registry.publishing():
-            self._m_rebuilds.inc()
-
-    def evict(self, n: int) -> List[int]:
-        """Reclaim up to ``n`` cached-idle pages, least-recently-used
-        leaves first (removing a leaf may expose its parent, which
-        competes from then on under its own ``last_use``)."""
-        freed: List[int] = []
-        scanned = 0
-        idle, nodes, refcounts = self._idle, self._nodes, self.pool.refcounts
-        while len(freed) < n and idle:
-            last_use, _, victim = heapq.heappop(idle)
-            scanned += 1
-            if (nodes.get(victim.page) is not victim or victim.children
-                    or refcounts[victim.page] != 0
-                    or victim.last_use != last_use):
-                continue  # stale: it stopped being this idle leaf
-            parent = victim.parent
-            del parent.children[victim.key]
-            del nodes[victim.page]
-            self.pool.set_cached(victim.page, False)
-            freed.append(victim.page)
-            if victim.wpage != NULL_PAGE:
-                # idle too: whoever held it held the full page
-                self._drop_wpage(victim)
-                self.wpool.free_evicted([victim.wpage])
-                victim.wpage = NULL_PAGE
-            if parent is not self.root:
-                self._push_if_idle_leaf(parent)
-        # what the call did, as one zero-length event inside the pool's
-        # ``pool-reclaim``: the numbers are known only now
-        with obs_trace.span("pool-evict", evicted=len(freed),
-                            scanned=scanned):
-            pass
-        if obs_registry.publishing():
-            self._m_evicted.inc(len(freed))
-            self._m_scanned.inc(scanned)
-        return freed
-
-    # ---- the window class ----
-
-    def _drop_wpage(self, node: _TrieNode) -> None:
-        assert self.wpool.refcounts[node.wpage] == 0
-        del self._wnodes[node.wpage]
-        self.wpool.set_cached(node.wpage, False)
-
-    def note_widle(self, pages: Sequence[int]) -> None:
-        """The window pool's ``idle_hook``: a release (a window that moved
-        on, a retirement) left ``pages`` cached at refcount 0; every one
-        is evictable, a leaf or not."""
-        for p in pages:
-            node = self._wnodes[p]
-            heapq.heappush(self._widle, (node.last_use, node.depth,
-                                         next(self._entry), node))
-        if len(self._widle) > 2 * len(self._wnodes) + 64:
-            self._widle[:] = [
-                (n.last_use, n.depth, next(self._entry), n)
-                for p, n in self._wnodes.items()
-                if self.wpool.refcounts[p] == 0]
-            heapq.heapify(self._widle)
-
-    def evict_window(self, n: int) -> List[int]:
-        """Reclaim up to ``n`` cached-idle pages of the window class, least
-        recently used first; their nodes stay, with the full class's page.
-        An entry whose node was stamped since (a match that took the full
-        page and no longer needed this one) goes back under its new stamp;
-        one whose page is gone or referenced is dropped."""
-        freed: List[int] = []
-        scanned = 0
-        idle, refcounts = self._widle, self.wpool.refcounts
-        while len(freed) < n and idle:
-            last_use, _, _, node = heapq.heappop(idle)
-            scanned += 1
-            wp = node.wpage
-            if (wp == NULL_PAGE or self._wnodes.get(wp) is not node
-                    or refcounts[wp] != 0):
-                continue
-            if node.last_use != last_use:
-                heapq.heappush(idle, (node.last_use, node.depth,
-                                      next(self._entry), node))
-                continue
-            self._drop_wpage(node)
-            node.wpage = NULL_PAGE
-            freed.append(wp)
-        with obs_trace.span("pool-evict", evicted=len(freed),
-                            scanned=scanned, page_class="window"):
-            pass
-        if obs_registry.publishing():
-            self._m_wevicted.inc(len(freed))
-        return freed
 
 
 @dataclasses.dataclass
@@ -1272,9 +294,7 @@ class _Launched(NamedTuple):
     those slots held then, its device tokens and log-probs, its launch
     time.  A row is dropped at apply when its slot no longer holds its
     request as launched (retired, preempted or failed meanwhile:
-    ``_row_live``).  One ragged tick (``chain`` 0: arrays ``[b]``) or a
-    chained launch of ``chain`` decode ticks (``[chain, b]``); the fields
-    after ``chain`` are the ragged tick's own."""
+    ``_row_live``)."""
 
     active: List[int]
     reqs: List[EngineRequest]
@@ -1282,7 +302,6 @@ class _Launched(NamedTuple):
     logps: object
     t0: float
     epochs: List[int]  # each request's ``_preemptions`` at the launch
-    chain: int = 0
     no: int = 0        # the tick's number: ``tick=`` of its spans
     spans: Sequence = ()  # the prompt chunks it packed: (req, start, end)
     n_bucket: int = 0  # its compiled prompt-row capacity (0: decode-only)
@@ -1314,34 +333,18 @@ class ContinuousBatchingEngine:
                  flight_records: Optional[int] = None,
                  flight_events: Optional[int] = None,
                  kv_dtype: Optional[str] = None,
-                 tick_pipeline_depth: Optional[int] = None,
                  mesh: Optional[Mesh] = None):
         inf = cfg.inference
         self.cfg = cfg
         pick = lambda given, name: (  # noqa: E731
             given if given is not None else getattr(inf, name))
-        refuse_layer_pattern(
-            cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
-            draft=bool(pick(spec_k, "spec_k")),
-            pipeline_depth=int(pick(tick_pipeline_depth,
-                                    "tick_pipeline_depth")))
+        # before anything is placed or resolved: a sentence, not a
+        # sharding error from the middle of start-up
+        refuse_unserved(cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
+                        draft=bool(pick(spec_k, "spec_k")))
         # a constant-size state a sequence (power retention) in place of
         # pages: :class:`StatePool`
         self.state = bool(cfg.model.retention)
-        if self.state:
-            refuse_state_cache(
-                cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
-                draft=bool(pick(spec_k, "spec_k")),
-                pipeline_depth=int(pick(tick_pipeline_depth,
-                                        "tick_pipeline_depth")))
-        if cfg.model.mla:
-            # before anything is placed or resolved: a sentence, not a
-            # sharding error from the middle of start-up
-            refuse_latent_cache(
-                kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
-                draft=bool(pick(spec_k, "spec_k")),
-                pipeline_depth=int(pick(tick_pipeline_depth,
-                                        "tick_pipeline_depth")))
         if inf.int8_weights:
             # same decode-weight quantization contract as api.InferenceEngine
             from megatron_llm_tpu.ops.quant import quantize_layer_weights_int8
@@ -1510,15 +513,6 @@ class ContinuousBatchingEngine:
         # one storage discipline for every page.
         self.kv_dtype = (kv_dtype if kv_dtype is not None
                          else getattr(inf, "kv_dtype", "bf16"))
-        # pipelined multi-tick dispatch (ISSUE 17): keep one N-tick
-        # CHAINED launch in flight and apply its results at a one-launch
-        # lag, so per-tick host work (scheduling, emission fetch, apply)
-        # amortizes 1/N.  0 = today's one-tick-per-launch driver, byte
-        # for byte.  Speculative decoding keeps depth-0 stepping — its
-        # adaptive k_eff needs per-tick acceptance counts on the host.
-        self.pipeline_depth = max(0, int(
-            tick_pipeline_depth if tick_pipeline_depth is not None
-            else getattr(inf, "tick_pipeline_depth", 0)))
         if self._pp > 1 and self.draft_cfg is not None:
             assert self.draft_cfg.model.num_layers % self._pp == 0, (
                 f"draft num_layers {self.draft_cfg.model.num_layers} "
@@ -1619,14 +613,9 @@ class ContinuousBatchingEngine:
         # whenever admission/retirement changes the slot layout
         self._dev_state: Optional[Tuple] = None  # guarded by _lock
         self._dirty = True  # guarded by _lock
-        # launched-but-unapplied work, oldest first (:class:`_Launched`):
-        # at most one ragged tick between two steps (the step launches
-        # the next tick before it applies this one), or the chained
-        # launches of --tick_pipeline_depth (ISSUE 17), never both kinds
-        # at once.  _pipe_state is the device-resident
-        # (term_ids, stop_modes, done, remaining) carry the next chain
-        # consumes — None means the next launch must rebuild it from the
-        # (then-current) host mirrors — guarded by _lock
+        # launched-but-unapplied ticks, oldest first (:class:`_Launched`):
+        # at most one between two steps (the step launches the next tick
+        # before it applies this one) — guarded by _lock
         self._inflight: deque = deque()
         # the ``carried`` operand of a tick that takes no token from a
         # tick in flight
@@ -1634,11 +623,6 @@ class ContinuousBatchingEngine:
             np.zeros((self.max_slots,), np.bool_))
         # when the last ragged tick's results reached the host
         self._last_fetch_t = 0.0  # guarded by _lock
-        self._pipe_state: Optional[Tuple] = None  # guarded by _lock
-        self._chained_fn = None
-        # inter-launch host-gap samples for the pipeline bench (bounded;
-        # host_gap_stats() summarizes) — guarded by _lock
-        self._host_gaps: deque = deque(maxlen=4096)
         # wall time the last device dispatch call returned (driver-thread
         # only; reads/writes serialize under _drive_lock)
         self._last_dispatch_end: Optional[float] = None
@@ -1809,12 +793,10 @@ class ContinuousBatchingEngine:
             help="seconds retired requests spent preempted (observed "
                  "only for requests that were preempted at least once)",
             buckets=lat)
-        # pipelined-dispatch telemetry (ISSUE 17): the host gap is the
-        # wall time between one tick launch returning and the next being
-        # dispatched — scheduling + emission fetch + apply, THE overhead
-        # --tick_pipeline_depth amortizes across a chain.  The fetch is a
-        # wait for the device, so this is NOT host work: the phase
-        # histogram below is
+        # the host gap is the wall time between one tick launch returning
+        # and the next being dispatched — scheduling + emission fetch +
+        # apply.  The fetch is a wait for the device, so this is NOT host
+        # work: the phase histogram below is
         self._m_host_gap = reg.histogram(
             "mlt_engine_host_gap_seconds",
             help="wall time between consecutive tick-program dispatches: "
@@ -1974,8 +956,7 @@ class ContinuousBatchingEngine:
         self._m_inflight = reg.gauge(
             "mlt_engine_inflight_ticks",
             help="device ticks launched but not yet applied (the "
-                 "ragged tick running beside the host, or "
-                 "--tick_pipeline_depth chains in flight)")
+                 "ragged tick running beside the host)")
         # token streaming (ISSUE 18, serving/streaming/): live
         # subscriptions + incremental events shed by slow consumers
         # (drop-to-terminal — the terminal event is never shed)
@@ -1994,10 +975,6 @@ class ContinuousBatchingEngine:
             "mlt_engine_stream_dropped_events_total",
             help="incremental stream events shed because a consumer "
                  "fell behind its bounded emission queue")
-        reg.gauge("mlt_engine_tick_pipeline_depth",
-                  help="configured chained-ticks-per-launch depth "
-                       "(--tick_pipeline_depth; 0 = unpipelined)"
-                  ).set(self.pipeline_depth)
         # cross-replica KV handoff (ISSUE 19, serving/handoff/): pages
         # and wire bytes this engine exported (prefill role) / imported
         # (decode role, /admin/kv_push)
@@ -2209,29 +1186,6 @@ class ContinuousBatchingEngine:
         self._ragged_fns[pre_rows] = fn
         return fn
 
-    def _chained_tick(self):
-        """The CHAINED steady-state tick (ISSUE 17,
-        generation/ragged.py:make_chained_tick_fn): ``pipeline_depth``
-        consecutive decode ticks as one compiled program, with position
-        advance, stop detection and the remaining-token budget running
-        device-to-device.  Chain length is a geometry static (one
-        executable per depth); everything else — which rows are live,
-        their stop rules, budgets and tables — is traced data."""
-        if self._chained_fn is not None:
-            return self._chained_fn
-        from megatron_llm_tpu.generation.ragged import make_chained_tick_fn
-
-        statics = ("engine_chained_tick", self.max_slots,
-                   self.pages_per_seq, self.page_size,
-                   self.pool.num_pages, self.pool.kv_statics,
-                   self.pipeline_depth, self._mesh_statics)
-        self._chained_fn = gen.cached_jit(
-            self.cfg, "engine_chained_tick", statics,
-            lambda: make_chained_tick_fn(self.cfg, self.pipeline_depth,
-                                         tp=self._tp, mesh=self.mesh),
-            donate_argnums=(1,))
-        return self._chained_fn
-
     def _score_chunk(self, rows: int, kv_pages: int):
         """One teacher-forced prefill CHUNK of a ``return_log_probs``
         prompt: feed ``rows`` prompt tokens at positions
@@ -2350,16 +1304,11 @@ class ContinuousBatchingEngine:
         if len(prompt) + max_new_tokens > self.max_seq:
             raise gen.InvalidRequest(
                 "Length of prompt + tokens_to_generate longer than allowed")
-        if self.state and kw.get("return_log_probs"):
+        if kw.get("return_log_probs"):
             try:
-                refuse_state_cache(self.cfg, log_probs=True)
+                refuse_unserved(self.cfg, log_probs=True)
             except ValueError as e:
                 raise gen.InvalidRequest(str(e)) from None
-        if self.wpool is not None and kw.get("return_log_probs"):
-            raise gen.InvalidRequest(
-                "return_log_probs (prompt scoring) is not served for a "
-                "layer pattern: the scoring chunk walks one block table a "
-                "sequence, and this model's pool has two page classes")
         req = EngineRequest(prompt=prompt, max_new_tokens=max_new_tokens, **kw)
         req._t_submit = time.monotonic()
         # flight record + enqueue event (observability/flight.py): a
@@ -2415,7 +1364,7 @@ class ContinuousBatchingEngine:
         ``submit`` returns plus the :class:`StreamQueue
         <megatron_llm_tpu.serving.streaming.StreamQueue>` the apply paths
         feed under the engine lock: one ``token`` event per applied batch
-        (chained dispatch retires several tokens per flush), then exactly
+        (a verify tick retires several tokens per flush), then exactly
         one terminal ``done``/``error`` event carrying the flight-record
         timing payload.  ``stream_events`` bounds the queue; a consumer
         that falls behind sheds incremental events (counted in
@@ -3299,19 +2248,8 @@ class ContinuousBatchingEngine:
         via ``_drive_lock``).
 
         The whole tick — decode slots, verify blocks, prefill-chunk rows
-        — is ONE compiled launch (:meth:`_step_ragged`).
-
-        Pipelined mode (``--tick_pipeline_depth N``, ISSUE 17): steady-
-        state steps chain N ticks per launch and apply results at a one-
-        launch lag (:meth:`_step_pipelined`); any boundary — admission,
-        prefill, preemption fallout — drains the pipeline and runs this
-        depth-0 path for that step.  Speculative engines always step at
-        depth 0 (adaptive k_eff needs per-tick acceptance)."""
+        — is ONE compiled launch (:meth:`_step_ragged`)."""
         with obs_trace.span("engine-step", tick=self.ticks):
-            if self.pipeline_depth and not self.spec_k:
-                n = self._step_pipelined()
-                if n is not None:
-                    return n
             t_admit, c_admit = time.monotonic(), time.thread_time()
             with obs_trace.span("engine-admit"):
                 self._admit()
@@ -3432,336 +2370,59 @@ class ContinuousBatchingEngine:
             if prefill_tokens > self.prefill_chunk:
                 self._m_multi_chunk.inc()
 
-    # -- pipelined multi-tick dispatch (ISSUE 17) --------------------------
-
     def _note_host_gap(self, gap: Optional[float]) -> None:
         """Record one inter-launch host gap (scheduling + emission fetch
-        + apply time between device dispatches — the overhead pipelining
-        amortizes; fed to the bench via :meth:`host_gap_stats`)."""
-        if gap is None:
-            return
-        with self._lock:
-            self._host_gaps.append(gap)
-        if obs_registry.publishing():
+        + apply time between device dispatches)."""
+        if gap is not None and obs_registry.publishing():
             self._m_host_gap.observe(gap)
 
-    def host_gap_stats(self) -> dict:
-        """Inter-launch host-gap summary (bench_decode --mode pipeline
-        reports the p50/p99 reduction as depth grows)."""
-        with self._lock:
-            gaps = sorted(self._host_gaps)
-        if not gaps:
-            return {"count": 0, "total_s": 0.0,
-                    "p50_ms": None, "p99_ms": None}
-
-        def q(p: float) -> float:
-            return gaps[min(len(gaps) - 1, int(p * (len(gaps) - 1)))]
-
-        return {"count": len(gaps),
-                "total_s": round(sum(gaps), 4),
-                "p50_ms": round(q(0.50) * 1e3, 4),
-                "p99_ms": round(q(0.99) * 1e3, 4)}
-
-    def _pregrant_locked(self, active,
-                         horizon: int) -> bool:  # holds _lock
-        """Pre-grant every page the next ``horizon`` chained positions
-        may write, per active row: page slots covering the HOST position
-        through ``host position + horizon - 1`` (capped at the row's
-        worst-case budget) are allocated now and debited from the
-        commitment ledger — the in-program position advance then crosses
-        page boundaries without consulting the host, and the device-
-        resident ``remaining`` budget freezes a row before it can outrun
-        its final granted page.  The ledger's admission invariant makes
-        the allocs infallible while the slot is in flight, exactly as
-        for :meth:`_prepare_decode_locked`.  Rows that stop early via a
-        stop token simply retire holding a few unwritten pages — they
-        release with the rest.  Returns True when any block table
-        changed (the launch then re-uploads ONLY the table operand;
-        positions/tokens/steps keep chaining on device)."""
-        changed = False
-        for i in list(active):
-            req = self._slots[i]
-            p0 = int(self._positions[i]) // self.page_size
-            last_pos = min(int(self._positions[i]) + horizon - 1,
-                           req._max_pages * self.page_size - 1)
-            p1 = last_pos // self.page_size
-            for idx in range(p0, min(p1, self.pages_per_seq - 1) + 1):
-                if self._block_tables[i][idx] != NULL_PAGE:
-                    continue
-                got = self.pool.alloc(1)
-                if got is None:  # ledger-unreachable; fail just the row
-                    self._fail_locked(req, RuntimeError(
-                        "KV pool exhausted for an in-flight slot — "
-                        "commitment ledger violated"))
-                    active.remove(i)
-                    changed = True
-                    break
-                self._block_tables[i][idx] = got[0]
-                req._pages.append(got[0])
-                self._committed -= 1
-                changed = True
-        return changed
-
-    def _apply_chain_locked(self, rec: _Launched, toks_np, logps_np,
-                            now) -> int:  # holds _lock
-        """Fold one in-flight launch's sampled tokens into the slots: a
-        chain's ``[chain, b]``, or one ragged tick's as a chain of one.
-        The spec apply's block shape over the chain axis: each surviving
-        row appends its whole column up to the first stop in ONE pass, so
-        host apply cost is per CHAIN, not per tick (the pipelined mode's
-        other half: chains amortize dispatch, this amortizes apply).
-        A row is discarded when its slot no longer holds the launched
-        request (:meth:`_row_live`); ``_positions`` / ``_steps`` /
-        ``_tokens`` move here, so they always stand where the last
-        applied launch left them."""
-        chain = toks_np.shape[0]
+    def _apply_rows_locked(self, rec: _Launched, toks_np, logps_np,
+                           now) -> int:  # holds _lock
+        """Fold one tick's sampled tokens (``[b]``) into the slots.  A row
+        is discarded when its slot no longer holds the launched request
+        (:meth:`_row_live`: retired, preempted or failed since the launch;
+        a preempted victim's discarded token regenerates bitwise on resume
+        because its sampling stream is ``fold_in(key, step)`` replay);
+        ``_positions`` / ``_steps`` / ``_tokens`` move here, so they
+        always stand where the last applied tick left them."""
         emitted = 0
+        toks, logps = toks_np.tolist(), logps_np.tolist()
         for k, (i, req) in enumerate(zip(rec.active, rec.reqs)):
             if not self._row_live(rec, k):
-                continue  # retired / preempted / failed since the launch
-            col = toks_np[:, i].tolist()
+                continue
             room = min(req.max_new_tokens - len(req.generated),
                        self.max_seq - len(req.prompt)
                        - len(req.generated))
-            if (not req.stop_on_eol and not req.stop_on_double_eol
-                    and (not req.use_eod_for_termination
-                         or req.termination_id is None)):
-                # length-limited row: bulk-extend the column
-                took = min(chain, room)
-                done = took == room
-                req.generated.extend(col[:took])
-                req.log_probs.extend(logps_np[:took, i].tolist())
-            else:
-                lcol = logps_np[:, i].tolist()
-                took = 0
-                done = False
-                for t in range(chain):
-                    tok = col[t]
-                    req.generated.append(tok)
-                    req.log_probs.append(lcol[t])
-                    took += 1
-                    done = (self._stopped_by_token(req, tok)
-                            or took >= room)
-                    if done:
-                        break
-            if not took:
+            if room < 1:
                 continue
+            tok = toks[i]
+            req.generated.append(tok)
+            req.log_probs.append(logps[i])
             if req._step == 0:
                 req._t_first = now
                 req._flight.mark_first_token(now)
                 self._note_ttft_locked(now - req._t_submit)
-            self._stream_emit_locked(req, req.generated[-took:],
-                                     req.log_probs[-took:])
-            req._step += took
-            self._positions[i] += took
-            self._tokens[i] = col[took - 1]
-            self._steps[i] += took
-            emitted += took
-            if done:
+            self._stream_emit_locked(req, req.generated[-1:],
+                                     req.log_probs[-1:])
+            req._step += 1
+            self._positions[i] += 1
+            self._tokens[i] = tok
+            self._steps[i] += 1
+            emitted += 1
+            if room == 1 or self._stopped_by_token(req, tok):
                 self._retire(i)
         return emitted
 
-    def _apply_oldest(self) -> int:
-        """Fetch and fold the OLDEST in-flight launch (a ragged tick goes
-        to :meth:`_apply_tick`); for a chain: ONE batched
-        ``jax.device_get`` for all of its ticks' tokens and log-probs
-        (the drain point), then per-tick application under the host's
-        own stop rules — the lag boundary where admission/stop/
-        preemption decisions land.  A row whose slot no longer holds the
-        launched request (retired, preempted or failed meanwhile) is
-        discarded tick by tick; a preempted victim's discarded tokens
-        regenerate bitwise on resume because its sampling stream is
-        ``fold_in(key, step)`` replay.  Returns tokens emitted."""
-        with self._lock:
-            if not self._inflight:
-                return 0
-            ragged = not self._inflight[0].chain
-            if not ragged:
-                rec = self._inflight.popleft()
-                dirty = self._streams_dirty_locked()
-        if ragged:
-            return self._apply_tick() or 0
-        if dirty:
-            self._kick_streams()
-        toks_np, logps_np = jax.device_get((rec.toks, rec.logps))
-        now = time.monotonic()
-        emitted = 0
-        with self._lock:
-            chain = toks_np.shape[0]
-            dt = (now - rec.t0) / max(chain, 1)
-            self._ema_tick_s = (dt if self._ema_tick_s is None
-                                else 0.8 * self._ema_tick_s + 0.2 * dt)
-            emitted = self._apply_chain_locked(rec, toks_np, logps_np, now)
-            self.ticks += chain
-            self.ticked_tokens += emitted
-            if obs_registry.publishing():
-                self._m_ticks.inc(chain)
-                self._m_tokens.inc(emitted)
-                self._m_inflight.set(
-                    self.pipeline_depth * len(self._inflight))
-                self._m_active.set(
-                    sum(r is not None and r._phase == "decode"
-                        for r in self._slots))
-                self._m_free_pages.set(self.pool.num_free)
-                self._m_pages_cached.set(
-                    len(self.cache) if self.cache else 0)
-            self._publish_queued_locked()
-        return emitted
-
-    def _drain_pipeline(self) -> int:
-        """Apply everything in flight (chains, or the ragged step's lagged
-        tick) and invalidate the device-resident pipeline carry — the
-        boundary synchronization point: after this the host mirrors are
-        exact.  Returns tokens emitted."""
+    def _land_inflight(self) -> int:
+        """Apply the tick in flight, if any — the boundary synchronization
+        point (a handoff export, the loop's end): after this the host
+        mirrors are exact.  Returns tokens emitted."""
         emitted = 0
         while True:
-            with self._lock:
-                pending = bool(self._inflight)
-            if not pending:
-                break
-            emitted += self._apply_oldest()
-        with self._lock:
-            self._pipe_state = None
-            if obs_registry.publishing():
-                self._m_inflight.set(0)
-        return emitted
-
-    def _steady_rows_locked(self) -> List[int]:  # holds _lock
-        """The decoding slots if nothing but decoding is going on (no
-        queue, no prefill, no handoff), else none: the state a chain may
-        be launched from."""
-        if (self._queue or self._prefill_q
-                or any(r is not None and r._phase != "decode"
-                       for r in self._slots)):
-            return []
-        return [i for i, r in enumerate(self._slots) if r is not None]
-
-    def _step_pipelined(self) -> Optional[int]:
-        """One pipelined driver step (``--tick_pipeline_depth N > 0``):
-        launch the next N-tick chained program from DEVICE-RESIDENT slot
-        state FIRST, then apply the previous launch's results while the
-        device computes — scheduler decisions land at a one-launch
-        (up-to-N-tick) lag.  Steady state only: any queued admission,
-        live prefill or non-decode slot drains the pipeline and returns
-        None, and the caller falls back to the depth-0 step for that
-        boundary.
-
-        Losslessness rests on three facts: the in-program stop/budget
-        rules mirror the host's apply rules bit for bit, so a row the
-        host retires was already frozen (null-routed) on device from the
-        same tick onward — an in-flight chain never writes a page the
-        host has released; the per-row sampling stream is
-        ``fold_in(key, step)``, so discarded overrun draws replay
-        bitwise after preemption; and per-row bits are batch-composition
-        invariant, so freezing one row never changes another's tokens."""
-        with self._lock:
-            active = self._steady_rows_locked()
-            lagged = bool(self._inflight) and not self._inflight[0].chain
-        if active and lagged:
-            # the ragged step's tick in flight lands first: a chain is
-            # built on exact host mirrors, and what it retires or
-            # activates decides whether the state is steady at all
-            self._apply_oldest()
-            with self._lock:
-                active = self._steady_rows_locked()
-        elif not active and lagged:
-            return None  # the ragged step keeps its own lag
-        if not active:
-            self._drain_pipeline()
-            return None
-        C = self.pipeline_depth
-        with self._lock:
-            # pre-grant pages out to TWO chains past the host's applied
-            # frontier: the launch below starts up to C device ticks
-            # ahead of the host positions (one unapplied chain) and runs
-            # C more
-            n0 = len(active)
-            changed = self._pregrant_locked(active, 2 * C)
-            if len(active) < n0:
-                # a ledger-unreachable alloc failure just mutated slot
-                # state under us — the device carry no longer matches the
-                # host; resynchronize through the depth-0 boundary path
-                active = []
-            if not active:
-                pass
-            elif self._pipe_state is None:
-                # boundary rebuild: the pipeline is drained, host
-                # mirrors are exact — upload the full device state and
-                # the per-row stop rules/budgets fresh
-                if changed:
-                    self._dirty = True
-                (bt, pos, toks, keys, steps, temp, tk,
-                 tp) = self._dev_state_locked()
-                term = np.full((self.max_slots,), -1, np.int32)
-                mode = np.zeros((self.max_slots,), np.int32)
-                rem = np.zeros((self.max_slots,), np.int32)
-                done = np.ones((self.max_slots,), np.bool_)
-                for i in active:
-                    req = self._slots[i]
-                    done[i] = False
-                    rem[i] = min(
-                        req.max_new_tokens - len(req.generated),
-                        self.max_seq - len(req.seq_tokens))
-                    if req.stop_on_double_eol:
-                        mode[i] = 2
-                    elif req.stop_on_eol:
-                        mode[i] = 1
-                    elif (req.use_eod_for_termination
-                          and req.termination_id is not None):
-                        term[i] = req.termination_id
-                self._pipe_state = (
-                    self._asarray(term), self._asarray(mode),
-                    self._asarray(done), self._asarray(rem))
-            else:
-                # steady chain: slot state and the stop/budget carry are
-                # the previous launch's outputs, device-to-device; only
-                # a pre-grant refreshes the (host-owned) table operand
-                (bt, pos, toks, keys, steps, temp, tk,
-                 tp) = self._dev_state
-                if changed:
-                    bt = self._asarray(self._block_tables)
-                    self._dev_state = (bt, pos, toks, keys, steps,
-                                       temp, tk, tp)
-            if active:
-                self.peak_active_slots = max(self.peak_active_slots,
-                                             len(active))
-                term_d, mode_d, done_d, rem_d = self._pipe_state
-                reqs = [self._slots[i] for i in active]
-        if not active:
-            self._drain_pipeline()
-            return None
-
-        t0 = time.monotonic()
-        gap = (None if self._last_dispatch_end is None
-               else t0 - self._last_dispatch_end)
-        with obs_trace.span("engine-chained-tick", active=len(active),
-                            chain=C, tp=self._tp,
-                            host_gap_ms=(None if gap is None
-                                         else round(gap * 1e3, 4))), \
-                self._overlap_span(), self._pp_span():
-            (self.pool.kv, ctoks, clogps, new_pos, new_tok,
-             new_steps, new_done, new_rem) = self._chained_tick()(
-                self.params, self.pool.kv, bt, pos, toks,
-                keys, steps, temp, tk, tp, term_d, mode_d, done_d,
-                rem_d)
-            self._last_dispatch_end = time.monotonic()
-        self._note_host_gap(gap)
-        with self._lock:
-            self._dev_state = (bt, new_pos, new_tok, keys, new_steps,
-                               temp, tk, tp)
-            self._pipe_state = (term_d, mode_d, new_done, new_rem)
-            self._inflight.append(_Launched(
-                active, reqs, ctoks, clogps, t0,
-                [r._preemptions for r in reqs], chain=C))
-            depth_now = len(self._inflight)
-            self._note_launches_locked(1, 0)
-            if obs_registry.publishing():
-                self._m_inflight.set(C * depth_now)
-        if depth_now > 1:
-            # apply the previous launch WHILE the device runs this one —
-            # the overlap the whole mode exists for
-            self._apply_oldest()
-        return len(active)
+            got = self._apply_tick()
+            if got is None:
+                return emitted
+            emitted += got
 
     # -- the ragged tick (ISSUE 11) ----------------------------------------
 
@@ -4212,8 +2873,8 @@ class ContinuousBatchingEngine:
                     emitted = self._apply_spec_locked(
                         rec.active, rec.spec[2], *fetched, now)
                 else:
-                    emitted = self._apply_chain_locked(
-                        rec, fetched[0][None], fetched[1][None], now)
+                    emitted = self._apply_rows_locked(
+                        rec, fetched[0], fetched[1], now)
                 self._finish_prefill_locked(
                     rec.spans, dt,
                     sum(end - start for _, start, end in rec.spans)
@@ -4309,9 +2970,9 @@ class ContinuousBatchingEngine:
             self._thread = None
 
     def _idle_locked(self) -> bool:  # holds _lock
-        # an in-flight chained launch keeps the loop stepping: its apply
-        # may retire rows (and must not be stranded when every slot
-        # empties before it lands)
+        # a tick in flight keeps the loop stepping: its apply may retire
+        # rows (and must not be stranded when every slot empties before
+        # it lands)
         return (not self._queue and not self._inflight
                 and all(r is None for r in self._slots))
 
@@ -4350,7 +3011,7 @@ class ContinuousBatchingEngine:
                     traceback.print_exc()
                     self._fail_all(e)
         with self._drive_lock:
-            self._drain_pipeline()
+            self._land_inflight()
             self.profile_trigger.close()
 
     def _fail_all(self, e: Exception) -> None:
@@ -4361,7 +3022,6 @@ class ContinuousBatchingEngine:
         with self._lock:
             self.failures += 1
             self._inflight.clear()
-            self._pipe_state = None
             pending = list(self._queue) + [
                 r for r in self._slots if r is not None]
             self._queue.clear()
@@ -4512,14 +3172,10 @@ class ContinuousBatchingEngine:
 
     # -- cross-replica KV handoff (ISSUE 19, serving/handoff/) -------------
 
-    def _refuse_handoff(self) -> None:
+    def refuse_handoff(self) -> None:
         """The handoff's wire format names pages of keys and values: a
         state, a latent row or a layer pattern says so in a sentence."""
-        if self.state:
-            refuse_state_cache(self.cfg, handoff=True)
-        if self.pool.latent:
-            refuse_latent_cache(handoff=True)
-        refuse_layer_pattern(self.cfg, handoff=True)
+        refuse_unserved(self.cfg, handoff=True)
 
     def prefill_and_export(self, prompt, *, add_BOS: bool = False,
                            trace_id: str = "", timeout_s: float = 600.0):
@@ -4541,7 +3197,7 @@ class ContinuousBatchingEngine:
 
         Returns ``(blob, info)`` — ``info`` has ``tokens`` / ``pages``
         / ``bytes`` / ``hit_tokens`` for the migration receipt."""
-        self._refuse_handoff()
+        self.refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         tok = self.tokenizer
@@ -4593,7 +3249,7 @@ class ContinuousBatchingEngine:
         parked in the prefix cache (e.g. a preempted request's finished
         pages).  Returns ``(blob, n_pages)``; ``n_pages`` may be 0 when
         nothing is cached."""
-        self._refuse_handoff()
+        self.refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         if self.cache is None:
@@ -4625,7 +3281,7 @@ class ContinuousBatchingEngine:
         so COW/refcount/eviction invariants hold unchanged.  Raises
         :class:`EngineOverloaded` (→ 503 + Retry-After) when the pool
         cannot hold the pages.  Returns the import receipt."""
-        self._refuse_handoff()
+        self.refuse_handoff()
         from megatron_llm_tpu.serving.handoff import wire
 
         payload = wire.decode_pages(blob)

@@ -370,123 +370,3 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     return overlapped
 
-
-def make_chained_tick_fn(cfg, chain: int, *, tp: int = 1, mesh=None):
-    """Build the CHAINED steady-state decode tick (ISSUE 17): ``chain``
-    consecutive non-speculative decode ticks as ONE compiled program — a
-    ``lax.scan`` over the spec-0 ragged tick body, so position advance,
-    sampling, stop-token detection and the remaining-token budget all
-    run device-to-device and the host is consulted once per *chain*
-    instead of once per tick (``--tick_pipeline_depth``).
-
-    Per-tick bits are the depth-0 tick's bits exactly: each scan step is
-    the same forward/sample/gather over the same ``[b]`` row batch, keys
-    derive from the same ``fold_in(req_key, step)`` stream with ``steps``
-    advancing in the carry, and per-row output is batch-composition
-    invariant (the PR 9/PR 11 numerics fact) — so masking a finished
-    row's table never changes a live row's tokens or log-probs.
-
-    In-program stop/freeze discipline (mirrors the host's
-    ``engine._stopped_by_token`` + length limits bit for bit):
-
-    * ``stop_modes[i]``: 0 = stop on ``term_ids[i]`` (−1 = never), 1 =
-      stop on EOL/double-EOL, 2 = stop on double-EOL (consecutive-EOL
-      detection uses the carried input token as ``prev`` — identical to
-      the host's ``generated[-2]`` at apply time);
-    * ``remaining[i]`` is the row's exact token budget (``max_new`` and
-      ``max_seq`` folded together by the host at the chain boundary);
-      it decrements per emitted token and freezes the row at 0 — a row
-      can therefore NEVER advance past its pre-granted final page;
-    * a ``done`` row is frozen: its position/token/step/budget stop
-      advancing and its reads AND writes route to the null table (index
-      0), so an in-flight chain cannot touch pages the host has since
-      released — sampled garbage for frozen rows is discarded at the
-      host's apply boundary.
-
-    Signature::
-
-        (params, pool_kv, block_tables, positions, tokens,
-         req_keys, steps, temperature, top_k, top_p,
-         term_ids, stop_modes, done, remaining)
-        -> (pool_kv, toks [chain, b], logps [chain, b],
-            new_pos, new_tok, new_steps, new_done, new_remaining)
-
-    The final carry is the NEXT launch's input — consecutive chains hand
-    slot state device-to-device; the host re-uploads only at boundaries
-    (admission/preemption/prefill) and when pre-granting pages changes
-    the block-table operand.
-    """
-    from megatron_llm_tpu.parallel import overlap as tp_overlap_mod
-    from megatron_llm_tpu.parallel import pp_serve as pp_serve_mod
-
-    ovl = tp_overlap_mod.overlap_params(cfg, mesh)
-    ppc = pp_serve_mod.serve_params(cfg, mesh)
-    vocab = cfg.model.vocab_size
-    scope_t = "decode-fwd" if tp == 1 else f"decode-fwd-tp{tp}"
-
-    def target_forward(params, pool_kv, tbl, idx, pos, tok, hor):
-        with jax.named_scope(scope_t):
-            logits, pool_kv = model_forward(
-                cfg, params, tok[:, None],
-                position_ids=pos[:, None],
-                rope_cache=make_rope_cache(cfg),
-                kv_caches=pool_kv,
-                paged=PagedState(tbl, pos, hor, idx),
-            )
-        return logits[:, 0], pool_kv
-
-    def chained(params, pool_kv, block_tables, positions, tokens,
-                req_keys, steps, temperature, top_k, top_p,
-                term_ids, stop_modes, done, remaining):
-        b = tokens.shape[0]
-        W = block_tables.shape[1]
-        null_tbl = jnp.zeros((1, W), block_tables.dtype)
-        all_tbl = jnp.concatenate([null_tbl, block_tables])
-        live_idx = 1 + jnp.arange(b, dtype=jnp.int32)
-
-        def body(carry, _):
-            pool_kv, pos, tok, stp, dn, rem = carry
-            # frozen rows null-route (reads garbage, writes page 0) —
-            # exactly how dead prefill rows are already handled
-            idx = jnp.where(dn, 0, live_idx)
-            hor = row_horizons(pos)
-            out, pool_kv = target_forward(
-                params, pool_kv, all_tbl, idx, pos, tok, hor)
-            keys = jax.vmap(jax.random.fold_in)(req_keys, stp)
-            next_tok = sample_per_slot(
-                keys, out, top_k=top_k, top_p=top_p,
-                temperature=temperature, vocab_size=vocab)
-            logp = gen._gather_token_log_probs(out, next_tok)
-            # stop detection AFTER the emit, like the host's apply; the
-            # carried input token is the host's generated[-2] (or the
-            # last prompt token on the first generated position)
-            is_eol = next_tok == gen.GPT2_EOL
-            is_deol = next_tok == gen.GPT2_DOUBLE_EOL
-            stop = jnp.where(
-                stop_modes == 2,
-                is_deol | (is_eol & (tok == gen.GPT2_EOL)),
-                jnp.where(stop_modes == 1, is_eol | is_deol,
-                          (term_ids >= 0) & (next_tok == term_ids)))
-            rem2 = jnp.where(dn, rem, rem - 1)
-            dn2 = dn | stop | (rem2 <= 0)
-            # freeze: done rows stop advancing (their re-draws discard)
-            pos2 = jnp.where(dn, pos, pos + 1)
-            tok2 = jnp.where(dn, tok, next_tok)
-            stp2 = jnp.where(dn, stp, stp + 1)
-            return (pool_kv, pos2, tok2, stp2, dn2, rem2), (next_tok, logp)
-
-        carry0 = (pool_kv, positions, tokens, steps, done, remaining)
-        carry, (toks, logps) = jax.lax.scan(
-            body, carry0, None, length=chain)
-        pool_kv, new_pos, new_tok, new_steps, new_done, new_rem = carry
-        return (pool_kv, toks, logps, new_pos, new_tok,
-                new_steps, new_done, new_rem)
-
-    if ovl is None and ppc is None:
-        return chained
-
-    def overlapped_chain(*args, **kw):
-        with tp_overlap_mod.activate(ovl), pp_serve_mod.activate(ppc):
-            return chained(*args, **kw)
-
-    return overlapped_chain

@@ -228,22 +228,9 @@ class MegatronServer:
             raise ValueError(
                 f"role must be 'unified', 'prefill' or 'decode', got {role!r}")
         self.role = role
-        if role != "unified" and getattr(
-                getattr(engine, "pool", None), "latent", False):
+        if role != "unified" and hasattr(engine, "refuse_handoff"):
             # a prefill or decode role exists to push and take KV pages
-            from megatron_llm_tpu.generation.engine import refuse_latent_cache
-
-            refuse_latent_cache(handoff=True)
-        if role != "unified" and getattr(engine, "state", False):
-            from megatron_llm_tpu.generation.engine import refuse_state_cache
-
-            refuse_state_cache(engine.cfg, handoff=True)
-        if role != "unified" and getattr(engine, "wpool", None) is not None:
-            from megatron_llm_tpu.generation.engine import (
-                refuse_layer_pattern,
-            )
-
-            refuse_layer_pattern(engine.cfg, handoff=True)
+            engine.refuse_handoff()
         # token streaming: ONE thread writes every open stream's
         # incremental frames, kicked by the engine once an applied tick
         # (serving/streaming/writer.py); started and joined with the
@@ -774,11 +761,6 @@ class MegatronServer:
                     kv_dtype=getattr(eng, "kv_dtype", "bf16"),
                     kv_pool_bytes=eng.pool.kv_pool_bytes(),
                     kv_scale_bytes=eng.pool.kv_scale_bytes(),
-                    # pipelined dispatch (ISSUE 17): the chained-ticks-
-                    # per-launch depth this engine runs steady-state
-                    # decode at (0 = unpipelined)
-                    tick_pipeline_depth=getattr(
-                        eng, "pipeline_depth", 0),
                 )
             mesh = getattr(eng, "mesh", None)
             info["mesh"] = ({str(k): int(v) for k, v in dict(mesh.shape).items()}

@@ -22,7 +22,7 @@ The subsystem lives in three pieces:
   are ordinary span-(k+1) entries of the engine's single-launch ragged
   tick, not a special-cased program.
 * the engine integration (generation/engine.py): draft K/V lives in the
-  SAME :class:`~megatron_llm_tpu.generation.engine.PagedKVPool` — every
+  SAME :class:`~megatron_llm_tpu.generation.pools.PagedKVPool` — every
   page id indexes both the target and the draft pools, so one block
   table, one refcount, one commitment ledger and one prefix trie govern
   both models' cache, and preempting a speculating slot releases draft

@@ -10,7 +10,7 @@ it honest:
   residual rejection sampler subtracts the draft distribution from the
   target's over the SAME vocab axis.  ``check_draft_compat`` enforces it.
 * **Shared page geometry.** The draft's K/V lives in the same
-  :class:`~megatron_llm_tpu.generation.engine.PagedKVPool` as the
+  :class:`~megatron_llm_tpu.generation.pools.PagedKVPool` as the
   target's — same page ids, same block tables, same refcounts — so the
   draft only needs a per-layer/head shape of its own, which the pool
   allocates alongside the target arrays.

@@ -4,7 +4,7 @@ Disaggregated prefill/decode serving splits a request across two
 replicas: a prefill-role replica runs chunked prefill, exports the
 prompt's full KV pages (quantized bytes + per-page scale rows + draft
 KV when speculating) and pushes them to a decode-role replica, which
-installs them as a :class:`~megatron_llm_tpu.generation.engine.PrefixCache`
+installs them as a :class:`~megatron_llm_tpu.generation.pools.PrefixCache`
 insert — a migrated prefix is indistinguishable from a locally-cached
 one, so COW / refcount / eviction invariants hold unchanged.
 

@@ -3,8 +3,8 @@
 Three tiers share this package's wire shapes (ISSUE 18):
 
 * **Engine tier** — ``ContinuousBatchingEngine.submit_stream`` attaches a
-  :class:`StreamQueue` to the request at enqueue time; the chained /
-  speculative / depth-0 apply paths publish freshly-retired token batches
+  :class:`StreamQueue` to the request at enqueue time; the tick's and
+  the verify tick's apply paths publish freshly-retired token batches
   into it under the engine lock, and ``_retire``/``_fail_locked``/
   ``_shed_locked`` publish the terminal event (carrying the flight-record
   timing payload).  The queue is bounded and never blocks the publisher:

@@ -5,7 +5,7 @@ The wire format is plain Server-Sent Events (one ``event:`` line, one
 
 * ``event: token`` — ``{"tokens": [...], "text": "...", "logprobs":
   [...]}``: the tokens of one stream applied since its last frame, in
-  order: usually one tick's, several when a chained dispatch retires
+  order: usually one tick's, several when a verify tick retires
   several per flush or when the stream writer found the socket full at
   an earlier tick (``token_frame`` joins the queued events).
 * ``event: dropped`` — ``{"dropped_events": n}``: the consumer fell

@@ -613,66 +613,6 @@ def test_graftcheck_lockorder_evidence_committed():
         assert e["examples"], "every edge needs a source example site"
 
 
-# ---------------------------------------------------------------------------
-# ISSUE 17: pipelined multi-tick dispatch bench
-# ---------------------------------------------------------------------------
-
-
-def test_pipeline_bench_cpu_contract():
-    """bench_decode.py --mode pipeline (ISSUE 17) reuses the off-TPU
-    contract: headline 0, the depth-sweep speedup/host-gap comparison
-    rides under cpu_sanity with budget fields populated, TPU evidence
-    goes to its own tagged file."""
-    line = bench.cpu_contract_line({
-        "metric": "engine_pipeline_decode_speedup_llama470m_c8_1chip",
-        "value": 1.6, "unit": "x", "backend": "cpu",
-        "speedup_ok": True, "lossless": True, "best_depth": 8,
-        "depths_swept": [0, 1, 2, 8], "host_gap_reduction": 3.0,
-        "compile_time_s": 2.0, "step_time_s": 0.001,
-        "rows": [{"concurrency": 8, "speedup_best": 1.6,
-                  "best_depth": 8, "host_gap_reduction": 3.0,
-                  "lossless": True}],
-    }, tag="engine_decode_pipeline")
-    assert line["value"] == 0.0 and line["unit"] == "x"
-    assert line["cpu_sanity"]["speedup_ok"] is True
-    assert line["cpu_sanity"]["lossless"] is True
-    assert line["budgets"]["compile_time_s"]["value"] == 2.0
-    assert "error" not in line
-
-
-def test_committed_pipeline_evidence_is_valid():
-    """The committed CPU-sanity evidence (BENCH_decode_pipeline_cpu_
-    sanity.json) satisfies the acceptance bar: headline 0 off-TPU, the
-    best pipelined arm at the highest concurrency is >= 1.5x depth-0
-    decode tok/s with a measurably reduced host gap, every arm emitted
-    byte-identical tokens to depth 0, and budgets populated without
-    violations."""
-    from pathlib import Path
-
-    path = (Path(__file__).parent.parent
-            / "BENCH_decode_pipeline_cpu_sanity.json")
-    rec = json.loads(path.read_text())
-    assert rec["value"] == 0.0 and rec["backend"] == "cpu"
-    sanity = rec["cpu_sanity"]
-    assert sanity["speedup_ok"] is True
-    assert sanity["lossless"] is True
-    assert 0 in sanity["depths_swept"]
-    assert any(d > 0 for d in sanity["depths_swept"])
-    # headline row = highest concurrency swept
-    top = max(sanity["rows"], key=lambda r: r["concurrency"])
-    assert top["speedup_best"] >= 1.5
-    assert top["host_gap_reduction"] > 1.0
-    assert top["lossless"] is True
-    by_depth = {d["depth"]: d for d in top["depths"]}
-    assert 0 in by_depth and top["best_depth"] in by_depth
-    best = by_depth[top["best_depth"]]
-    # fewer host dispatches and less accumulated host gap than depth 0
-    assert best["dispatches"] < by_depth[0]["dispatches"]
-    assert best["host_gap_total_s"] < by_depth[0]["host_gap_total_s"]
-    assert "compile_time_s" in rec["budgets"]
-    assert "error" not in rec
-
-
 def test_streaming_bench_cpu_contract():
     """bench_decode.py --mode streaming (ISSUE 18) reuses the off-TPU
     contract: headline 0, the streamed-vs-buffered TTFT comparison and

@@ -15,10 +15,10 @@ from benchmark.reference import brumby_block
 from benchmark.reference import common as ref_common
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation import generation as gen
-from megatron_llm_tpu.generation.engine import (
+from megatron_llm_tpu.generation.pools import (
     NULL_PAGE,
     StatePool,
-    refuse_state_cache,
+    refuse_unserved,
 )
 from megatron_llm_tpu.models import init_model_params, make_config
 from megatron_llm_tpu.models.language_model import model_forward
@@ -324,7 +324,6 @@ REFUSED = [
     (dict(kv_dtype="int8"), "--kv_dtype int8"),
     (dict(kv_dtype="fp8"), "--kv_dtype fp8"),
     (dict(draft=True), "--spec_k"),
-    (dict(pipeline_depth=2), "--tick_pipeline_depth"),
     (dict(handoff=True), "cross-replica KV handoff"),
     (dict(log_probs=True), "return_log_probs"),
     (dict(tp=2), "tensor-parallel serving"),
@@ -335,7 +334,7 @@ REFUSED = [
 
 @pytest.mark.parametrize("kw,sentence", REFUSED,
                          ids=[s for _, s in REFUSED])
-def test_refuse_state_cache_says_why(model, kw, sentence):
+def test_refuse_unserved_says_why(model, kw, sentence):
     cfg, params = model
     kw = dict(kw)
     if kw.pop("mixed", False):
@@ -346,15 +345,13 @@ def test_refuse_state_cache_says_why(model, kw, sentence):
     if "pp" in kw:
         kw["mesh"] = _mesh(pipeline_model_parallel_size=kw.pop("pp"))
     with pytest.raises(ValueError, match=sentence) as e:
-        refuse_state_cache(cfg, **kw)
+        refuse_unserved(cfg, **kw)
     assert "constant-size recurrent state" in str(e.value)
-    refuse_state_cache(model[0])                   # one chip, bf16: served
+    refuse_unserved(model[0])                   # one chip, bf16: served
 
 
 def test_engine_refuses_at_start_up_and_at_the_request(model):
     cfg, params = model
-    with pytest.raises(ValueError, match="--tick_pipeline_depth"):
-        engine(cfg, params, tick_pipeline_depth=2)
     with pytest.raises(ValueError, match="--kv_dtype int8"):
         engine(cfg, params, kv_dtype="int8")
     eng = engine(cfg, params, prefix_cache=True)

@@ -17,11 +17,11 @@ from benchmark.reference import commanda_block
 from benchmark.reference import common as ref_common
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation import generation as gen
-from megatron_llm_tpu.generation.engine import (
+from megatron_llm_tpu.generation.pools import (
     NULL_PAGE,
     PagedKVPool,
     PrefixCache,
-    refuse_layer_pattern,
+    refuse_unserved,
 )
 from megatron_llm_tpu.models import init_model_params, make_config, moe
 from megatron_llm_tpu.models.language_model import model_forward
@@ -433,7 +433,6 @@ def _mesh(**axes):
     (dict(kv_dtype="int8"), "--kv_dtype int8"),
     (dict(mesh="tp"), "tensor- or pipeline-parallel serving"),
     (dict(draft=True), "--spec_k"),
-    (dict(pipeline_depth=2), "--tick_pipeline_depth"),
     (dict(handoff=True), "cross-replica KV handoff"),
 ])
 def test_page_class_refusals(whole, kw, sentence):
@@ -441,20 +440,18 @@ def test_page_class_refusals(whole, kw, sentence):
     if kw.get("mesh"):
         kw = dict(mesh=_mesh(tensor_model_parallel_size=2))
     with pytest.raises(ValueError, match="two\\s+page classes") as e:
-        refuse_layer_pattern(cfg, **kw)
+        refuse_unserved(cfg, **kw)
     assert sentence in str(e.value)
-    refuse_layer_pattern(cfg)                 # one chip, bf16: served
+    refuse_unserved(cfg)                 # one chip, bf16: served
 
 
 def test_refusals_at_the_engines_door(whole, share):
     cfg, params = whole
     with pytest.raises(ValueError, match="--kv_dtype int8"):
         engine(cfg, params, kv_dtype="int8")
-    with pytest.raises(ValueError, match="--tick_pipeline_depth"):
-        engine(cfg, params, tick_pipeline_depth=2)
     scfg, sparams = share
     with pytest.raises(ValueError, match="one chip's share"):
-        refuse_layer_pattern(scfg, mesh=_mesh(tensor_model_parallel_size=2))
+        refuse_unserved(scfg, mesh=_mesh(tensor_model_parallel_size=2))
     eng = engine(scfg, sparams)
     with pytest.raises(gen.InvalidRequest, match="return_log_probs"):
         eng.submit([1, 2, 3], 4, return_log_probs=True)

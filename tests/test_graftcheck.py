@@ -965,6 +965,10 @@ def test_lockorder_committed_evidence(repo_sweep):
         "python -m tools.graftcheck --lockorder-out "
         "tools/graftcheck/lockorder.json megatron_llm_tpu tools tasks "
         "tests")
+    # an example names ``file:Class.method``, never a line: a PR that
+    # moves a line of engine.py does not carry this file
+    assert not [x for e in committed["edges"] for x in e["examples"]
+                if x.rpartition(":")[2].isdigit()]
 
 
 def test_contract_extractors_not_vacuous(repo_sweep):
@@ -1058,7 +1062,7 @@ def test_traced_functions_really_analyzed():
     """sync-in-jit resolves the engine's cached_jit builders — the
     programs built in engine.py itself are in the analyzed set (a
     resolution regression would silently stop checking the hot path; the
-    tick's body lives in ragged.py and is reached across files, below)."""
+    tick's body lives in ragged.py and is reached across files, next)."""
     from tools.graftcheck.rules.sync import SyncInJitRule
 
     path = os.path.join(REPO, "megatron_llm_tpu", "generation",
@@ -1067,3 +1071,49 @@ def test_traced_functions_really_analyzed():
     names = {getattr(n, "name", "<lambda>")
              for n in SyncInJitRule()._traced_nodes(ctx)}
     assert {"chunk", "chunk_spec", "copy", "copy_spec"} <= names
+
+
+def test_ragged_builder_in_traced_set():
+    """The builder-factory convention (module-level ``make_*_fn``)
+    reaches the ragged tick's bodies, which live in ragged.py behind a
+    cross-module thunk the per-file resolver cannot follow — the compiled
+    tick really is sync-analyzed."""
+    from tools.graftcheck.rules.sync import SyncInJitRule
+
+    path = os.path.join(REPO, "megatron_llm_tpu", "generation",
+                        "ragged.py")
+    ctx = core.FileContext(path)
+    names = {getattr(n, "name", "<lambda>")
+             for n in SyncInJitRule()._traced_nodes(ctx)}
+    assert {"tick", "spec_tick", "draft_step", "target_forward"} <= names, \
+        names
+    # the factory body itself runs at build time (host side) — exempt
+    assert "make_ragged_tick_fn" not in names
+
+
+def test_builder_factory_sync_flagged():
+    """A tick builder hiding a host sync inside the compiled body is a
+    finding; a jax-free host-side factory (REST client shape) is not
+    traced at all."""
+    bad = (
+        "import jax\n"
+        "import jax.numpy as jnp\n"
+        "import numpy as np\n"
+        "def make_bad_tick_fn(cfg):\n"
+        "    def tick(x):\n"
+        "        return np.asarray(x) + jnp.ones(())\n"
+        "    return tick\n"
+    )
+    hits = [f for f in core.check_file("fixture.py", ALL_RULES, source=bad)
+            if f.rule == "sync-in-jit"]
+    assert len(hits) == 1 and hits[0].line == 6, hits
+    host = (
+        "import requests\n"
+        "def make_api_generate_fn(url):\n"
+        "    def fn(text):\n"
+        "        return float(requests.get(url).elapsed.total_seconds())\n"
+        "    return fn\n"
+    )
+    assert not [f for f in core.check_file("fixture.py", ALL_RULES,
+                                           source=host)
+                if f.rule == "sync-in-jit"]

@@ -24,7 +24,7 @@ import pytest
 from benchmark.reference import common as ref_common
 from benchmark.reference import joyai_block
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
-from megatron_llm_tpu.generation.engine import refuse_latent_cache
+from megatron_llm_tpu.generation.pools import refuse_unserved
 from megatron_llm_tpu.models import init_model_params, make_config, moe
 from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
@@ -333,7 +333,7 @@ def _mesh(**axes):
 @pytest.mark.parametrize("case, sentence", [
     ("int8", "--kv_dtype int8"), ("fp8", "--kv_dtype fp8"),
     ("tp", "tensor-parallel serving"), ("pp", "pipeline-parallel serving"),
-    ("spec", "--spec_k"), ("depth", "--tick_pipeline_depth"),
+    ("spec", "--spec_k"),
     ("handoff_role", "KV handoff"), ("handoff_call", "KV handoff")])
 def test_latent_cache_refusals(model, case, sentence):
     cfg, params = model
@@ -346,13 +346,11 @@ def test_latent_cache_refusals(model, case, sentence):
         kw = dict(mesh=_mesh(pipeline_model_parallel_size=2))
     elif case == "spec":
         kw = dict(spec_k=2, spec_draft="llama2:num_layers=1")
-    elif case == "depth":
-        kw = dict(tick_pipeline_depth=2)
     with pytest.raises(ValueError, match="ONE latent row") as err:
         if case == "pp":
             # (pp cuts one uniform stack: the config refuses a dense prefix
             # before the engine is reached, so ask the pool's rule itself)
-            refuse_latent_cache(mesh=kw["mesh"])
+            refuse_unserved(cfg, mesh=kw["mesh"])
         elif case.startswith("handoff"):
             eng = ContinuousBatchingEngine(cfg, params)
             if case == "handoff_role":

@@ -479,7 +479,7 @@ def test_int8_pool_bytes_half_of_bf16():
     reported scale overhead)."""
     cfg = make_config("llama2", num_layers=2, **{**CFG_KW,
                                                  "params_dtype": "bfloat16"})
-    from megatron_llm_tpu.generation.engine import PagedKVPool
+    from megatron_llm_tpu.generation.pools import PagedKVPool
 
     p16 = PagedKVPool(cfg, 33, 16)
     p8 = PagedKVPool(cfg, 33, 16, kv_dtype="int8")

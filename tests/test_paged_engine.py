@@ -7,7 +7,6 @@ sampling is a function of (request seed, step) alone, not slot placement;
 (4) the compiled-program cache keys on config CONTENT, not object identity.
 """
 
-import functools
 import threading
 
 import jax
@@ -80,95 +79,6 @@ def toy_model():
 # the kernels at their smallest cases, against the gather path one call at a
 # time: tests/test_paged_kernel_cases.py (a file of its own, so that another
 # worker of the tier-1 run takes it: it is half of what this file took)
-
-
-# the serving configurations' head geometries (tools/tpu_kernel_check.py),
-# the latent row of MLA (one head of 640 lanes, 32 query heads) and one
-# quantized pool
-RUN_GEOMETRIES = {
-    "falcon": kernel_check.FALCON,
-    "mistral": kernel_check.MISTRAL,
-    "commanda": kernel_check.COMMANDA,
-    "latent": kernel_check.LATENT,
-    "mistral-int8": dict(kernel_check.MISTRAL, kv_dtype="int8"),
-}
-RUN_SCENARIOS = {"tiles": False, "inside": False, "blocks": False,
-                 "verify": False, "window": True, "window_inside": True}
-# the bf16 operands' precision test holds both storage dtypes of a
-# quantized pool: int8 on the paired layout (above) and fp8 on the pair of
-# 64s read whole
-GEOMETRIES = {**RUN_GEOMETRIES,
-              "falcon-fp8": dict(kernel_check.FALCON, kv_dtype="fp8")}
-
-
-@functools.lru_cache(maxsize=None)
-def _run_outputs(geometry: str, window: bool, fp32: bool = False):
-    """The kernel's and the gather path's outputs of one ``run_case`` call
-    (and at float32 the one-row walk's), made once for the scenarios that
-    share the call."""
-    pallas_fn, jnp_fn, scenarios = kernel_check.run_case(
-        3, window=window, **GEOMETRIES[geometry],
-        **(dict(dtype=jnp.float32) if fp32 else {}))
-    return (pallas_fn(True), jnp_fn(),
-            pallas_fn(True, spread=True) if fp32 else None, scenarios)
-
-
-@pytest.mark.parametrize("scenario", RUN_SCENARIOS)
-@pytest.mark.parametrize("geometry", RUN_GEOMETRIES)
-def test_paged_kernel_shared_walk_matches_jnp_path(geometry, scenario):
-    """A run of consecutive rows of one sequence, walked once (interpret
-    mode), == the gather path, row for row: a run that fills whole tiles,
-    one inside a tile beside another request's row and dead rows, one
-    across a compute-block boundary with two horizons, a verify block among
-    decode rows, and under a window with slid tables a run whose first rows
-    see a page its last rows do not."""
-    out, ref, _, scenarios = _run_outputs(geometry, RUN_SCENARIOS[scenario])
-    rows = scenarios[scenario]
-    assert kernel_check.max_err(out[rows], ref[rows]) < 2e-2
-    dead = np.setdiff1d(np.arange(out.shape[0]),
-                        np.concatenate(list(scenarios.values())))
-    assert not np.asarray(out[dead]).any(), "a dead row writes zeros"
-
-
-@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
-@pytest.mark.parametrize(
-    "geometry", [g for g in RUN_GEOMETRIES if "int8" not in g])
-def test_paged_kernel_shared_walk_is_the_one_row_walk(geometry, window):
-    """At float32 a row's result from the shared walk is what the one-row
-    walk gives (every row launched in a tile of its own): the same keys in
-    the same blocks, so reduction order within a matmul is all that
-    differs; and both are the gather path's."""
-    out, ref, alone, scenarios = _run_outputs(geometry, window, fp32=True)
-    live = np.concatenate(list(scenarios.values()))
-    assert kernel_check.max_err(out[live], alone[live]) < 1e-6
-    assert kernel_check.max_err(out[live], ref[live]) < 1e-5
-
-
-@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
-@pytest.mark.parametrize("geometry", GEOMETRIES)
-def test_paged_kernel_bf16_operands_round_nothing(geometry, window):
-    """THE GUARD OF THE KERNEL'S PRECISION.  On bf16 queries and bf16 pages
-    both matmuls take bf16 operands (one MXU pass for the scores, two for
-    the values).  That must change no number: the output is what a float32
-    computation on the SAME bf16 values gives, rounded ONCE to bf16 — under
-    1% of the elements differ (the order of float32 sums at a rounding
-    boundary), none by more than one bf16 unit in the last place.  In
-    interpret mode on the CPU a bf16 dot with float32 accumulation is
-    exact, so what this measures is the probabilities' split into two bf16
-    halves: a plain ``p.astype(bfloat16)`` in front of the value matmul
-    passes the 2e-2 of the tests above and FAILS here (tens of percent of
-    the elements move: the test below).  The int8 and fp8 pools are held to
-    the same rule against their dequantized float32 form: a quantized value
-    is exact in bf16, and the per-page scales multiply the float32 scores
-    and the float32 probabilities (before their split)."""
-    out, _, _, scenarios = _run_outputs(geometry, window)
-    _, jnp_fn, _ = kernel_check.run_case(
-        3, window=window, **GEOMETRIES[geometry])
-    assert out.dtype == jnp.bfloat16
-    live = np.concatenate(list(scenarios.values()))
-    differ, ulps = kernel_check.bf16_ulps(
-        out[live], jnp_fn(exact=True)[live])
-    assert differ < 0.01 and ulps <= 1.0, (differ, ulps)
 
 
 @pytest.mark.parametrize("rows", [16, 128], ids=["two-matmuls", "stacked"])
@@ -350,7 +260,7 @@ def test_pool_logical_view_is_the_dense_cache(family, n, nkv, d):
     same tokens: a chunk prefilled through a scattered block table reads
     back through ops/kv_quant (the owner of the row) and through
     ``PagedKVPool.logical_kv`` as the dense cache's keys and values."""
-    from megatron_llm_tpu.generation.engine import PagedKVPool
+    from megatron_llm_tpu.generation.pools import PagedKVPool
     from megatron_llm_tpu.ops import kv_quant
 
     cfg = make_config(

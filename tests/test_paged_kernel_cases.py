@@ -2,21 +2,38 @@
 one small case a call: every pool dtype, mask, page size and head geometry
 that tools/tpu_kernel_check.py compiles on the chip.
 
-A file of its own beside tests/test_paged_engine.py (which holds the
-shared-walk scenarios, the bf16 operands' precision guard and the engine):
-the tier-1 run hands a FILE to a worker, and these cases take as long in
-interpret mode as everything else there together.
+A file of its own beside tests/test_paged_kernel_walks.py (the
+shared-walk scenarios and the bf16 operands' precision guard) and
+tests/test_paged_engine.py (the engine): the tier-1 run hands a FILE to a
+worker, and these cases take as long in interpret mode as everything else
+there together.  The int8 / fp8 pools' cases, which take longer than all
+of these, are in tests/test_paged_kernel_walks.py for the same reason.
 """
 
 import jax.numpy as jnp
 import pytest
 
 
+def case_id(case) -> str:
+    return "-".join(f"{k}{getattr(v, '__name__', v)}"
+                    for k, v in case.items())
+
+
+def check_case(case) -> None:
+    """The kernel of every call shape == the gather path on one case."""
+    from tools.tpu_kernel_check import max_err, paged_case
+
+    # fp32 inputs: both sides are fp32 end to end and differ by reduction
+    # order only; bf16 inputs (the quantized cases) round the output to
+    # bf16, so one output ulp (2^-7 at |x| < 2) is the bound
+    tol = 1e-5 if case.get("dtype") == jnp.float32 else 2e-2
+    for name, (pallas_fn, jnp_fn) in paged_case(0, **case).items():
+        assert max_err(pallas_fn(True), jnp_fn()) < tol, name
+
+
 @pytest.mark.parametrize("case", [
     dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32),
     dict(n=4, nkv=2, d=128, page=8, dtype=jnp.float32, window=9),
-    dict(n=4, nkv=4, d=128, page=16, kv_dtype="int8"),
-    dict(n=4, nkv=1, d=64, page=8, kv_dtype="fp8", window=20),
     # the page walk (tools/tpu_kernel_check.py WALK_CASES): a context of
     # three 128-token blocks that ends inside the third, a horizon that is
     # no multiple of a block, a window that opens inside a block, rows
@@ -34,21 +51,13 @@ import pytest
     # path's
     dict(n=4, nkv=2, d=128, page=16, dtype=jnp.float32, max_pages=128,
          context=40, poison_tail=True),
-    dict(n=4, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=128,
-         context=40, poison_tail=True),
     # Falcon-40B: 8 kv heads of 64, each head's key|value pair one
     # 128-lane operand
     dict(n=16, nkv=8, d=64, page=16, max_pages=24, context=300),
-    dict(n=16, nkv=8, d=64, page=16, kv_dtype="int8", max_pages=24,
-         context=300, window=100),
     # Falcon-7B (71 query heads on one kv head of 64: its key|value pair
     # is the 128-lane row) and Mistral-7B (32/8 x 128) head geometries
     dict(n=71, nkv=1, d=64, page=16, max_pages=24, context=300),
-    dict(n=71, nkv=1, d=64, page=16, kv_dtype="int8", max_pages=24,
-         context=300, window=100),
     dict(n=32, nkv=8, d=128, page=16, max_pages=24, context=300),
-    dict(n=32, nkv=8, d=128, page=16, kv_dtype="fp8", max_pages=24,
-         context=300, window=50),
     # Command A+ (128/8 x 128) under its window with the tables of a
     # window page class: the slots behind the window name the null page,
     # so the first live page is not the table's first; and the same
@@ -56,17 +65,9 @@ import pytest
     dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300,
          window=100, slid_head=True),
     dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300),
-], ids=lambda c: "-".join(f"{k}{getattr(v, '__name__', v)}"
-                          for k, v in c.items()))
+], ids=case_id)
 def test_paged_kernels_interpret_match_jnp_path(case):
     """The Pallas decode / prefill / ragged kernel (interpret mode) == the
-    jnp gather path, plain and quantized pools, with and without a sliding
-    window — the scenarios tools/tpu_kernel_check.py compiles on the chip."""
-    from tools.tpu_kernel_check import max_err, paged_case
-
-    # fp32 inputs: both sides are fp32 end to end and differ by reduction
-    # order only; bf16 inputs (the quantized cases) round the output to
-    # bf16, so one output ulp (2^-7 at |x| < 2) is the bound
-    tol = 1e-5 if case.get("dtype") == jnp.float32 else 2e-2
-    for name, (pallas_fn, jnp_fn) in paged_case(0, **case).items():
-        assert max_err(pallas_fn(True), jnp_fn()) < tol, name
+    jnp gather path on plain pools, with and without a sliding window —
+    the scenarios tools/tpu_kernel_check.py compiles on the chip."""
+    check_case(case)

@@ -14,7 +14,7 @@ import warnings
 
 import pytest
 
-from megatron_llm_tpu.generation import engine as engine_mod
+from megatron_llm_tpu.generation import pools as pools_mod
 from megatron_llm_tpu.generation.engine import PagedKVPool, PrefixCache
 from megatron_llm_tpu.models import make_config
 from megatron_llm_tpu.observability import registry as registry_mod
@@ -96,7 +96,7 @@ def test_alloc_past_the_free_list_counts_its_eviction(cfg):
 def test_alloc_off_the_free_list_reads_no_clock(cfg, monkeypatch):
     pool, cache = _dry_pool(cfg)
     before = _read()
-    monkeypatch.setattr(engine_mod, "time", NoClock())
+    monkeypatch.setattr(pools_mod, "time", NoClock())
     got = pool.alloc(13)                  # exactly what is free
     monkeypatch.undo()
     d = _delta(before)
@@ -105,7 +105,7 @@ def test_alloc_off_the_free_list_reads_no_clock(cfg, monkeypatch):
                  "s_evictable": 0, "s_evict": 0}
     assert not pool.reclaimed and len(cache) == 50
     # and the next page is the slow path: it does read the clock
-    monkeypatch.setattr(engine_mod, "time", NoClock())
+    monkeypatch.setattr(pools_mod, "time", NoClock())
     with pytest.raises(AssertionError, match="read time.perf_counter"):
         pool.alloc(1)
 
@@ -114,7 +114,7 @@ def test_alloc_beyond_what_is_available_evicts_nothing(cfg, monkeypatch):
     pool, cache = _dry_pool(cfg)
     before = _read()
     # refused on the kept count: no eviction, and no clock to time one
-    monkeypatch.setattr(engine_mod, "time", NoClock())
+    monkeypatch.setattr(pools_mod, "time", NoClock())
     assert pool.alloc(64) is None         # 13 free + 50 evictable = 63
     monkeypatch.undo()
     d = _delta(before)
@@ -152,7 +152,7 @@ def test_the_count_is_read_without_a_clock_or_a_walk_by_any_caller(
 
     pool.cached = NoWalk(pool.cached)
     before = _read()
-    monkeypatch.setattr(engine_mod, "time", NoClock())
+    monkeypatch.setattr(pools_mod, "time", NoClock())
     seen = [pool.num_available, pool.num_evictable]
     th = threading.Thread(target=lambda: seen.append(pool.num_available))
     th.start()

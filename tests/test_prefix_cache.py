@@ -21,7 +21,7 @@ from megatron_llm_tpu.generation import (
     ContinuousBatchingEngine,
     EngineOverloaded,
 )
-from megatron_llm_tpu.generation.engine import (
+from megatron_llm_tpu.generation.pools import (
     NULL_PAGE,
     PagedKVPool,
     PrefixCache,

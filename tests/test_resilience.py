@@ -500,8 +500,13 @@ def test_supervisor_budget_exhausted(tmp_path):
 def test_supervisor_sigterm_forwarding_no_restart(tmp_path):
     """Graceful preemption: SIGTERM forwards to the child (which exits
     cleanly here) and the supervisor does NOT restart."""
+    # the child says so, in a line, once its handler is installed: the
+    # supervisor hands its stdout on, so the line goes to a file
+    ready = tmp_path / "ready"
     script = ("import signal, sys, time;"
               "signal.signal(signal.SIGTERM, lambda *a: sys.exit(0));"
+              f"print('handler installed', file=open({str(ready)!r}, 'w'),"
+              " flush=True);"
               "time.sleep(60)")
     sup = Supervisor([sys.executable, "-c", script], str(tmp_path / "resil"),
                      policy=RestartPolicy(max_restarts=5, backoff_base=0.05),
@@ -517,7 +522,11 @@ def test_supervisor_sigterm_forwarding_no_restart(tmp_path):
     while sup.child_pid is None and time.time() < deadline:
         time.sleep(0.05)
     assert sup.child_pid is not None
-    time.sleep(0.3)  # let the child install its handler
+    deadline = time.time() + 30     # an interpreter's start on shared cores
+    while time.time() < deadline and not (
+            ready.exists() and ready.read_text().strip()):
+        time.sleep(0.02)
+    assert ready.read_text().strip() == "handler installed"
     sup.request_stop()
     t.join(timeout=15)
     assert not t.is_alive()

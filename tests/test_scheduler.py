@@ -28,7 +28,7 @@ from megatron_llm_tpu.generation import (
     RequestShed,
     get_policy,
 )
-from megatron_llm_tpu.generation.engine import NULL_PAGE
+from megatron_llm_tpu.generation.pools import NULL_PAGE
 from megatron_llm_tpu.generation.scheduling import (
     FcfsPolicy,
     PriorityPolicy,

@@ -24,6 +24,7 @@ fetched tick N, and applies N while the device runs N+1.  What must hold:
    interval now spans.
 """
 
+import collections
 import time
 
 import jax
@@ -31,6 +32,7 @@ import pytest
 
 from megatron_llm_tpu.generation import ContinuousBatchingEngine, DraftModel
 from megatron_llm_tpu.generation import engine as engine_mod
+from megatron_llm_tpu.generation import generation as gen
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.observability import trace as obs_trace
 
@@ -113,24 +115,89 @@ def _backlog_jobs():
 # ---------------------------------------------------------------------------
 
 
-def test_backlog_matches_both_references(models):
+@pytest.fixture(scope="module")
+def backlog_alone(models):
+    """Each job of the backlog served alone: the tight reference of every
+    test that runs the backlog (scheduling keys change no token)."""
+    return generations(serve_alone(lambda: _engine(models), _backlog_jobs()))
+
+
+def _pages_accounted(eng):
+    """Every page is free, referenced or cached idle, whatever is in
+    flight; and no page is granted twice: a page held by several live
+    requests is one the prefix cache shares, each holder a reference."""
+    pool = eng.pool
+    referenced = int((pool.refcounts > 0).sum())
+    assert pool.num_free + referenced + pool.num_evictable == \
+        pool.num_pages - 1
+    holders = collections.Counter(
+        p for r in eng._slots if r is not None for p in r._pages)
+    assert all(pool.refcounts[p] == n for p, n in holders.items()), holders
+    assert referenced == len(holders)
+    private = [p for p, n in holders.items() if p not in pool.cached]
+    assert all(holders[p] == 1 for p in private)
+
+
+def _all_pages_back(eng, models, **kw):
+    """Nothing in flight, and every page free or cached idle."""
+    assert not eng._inflight
+    cached = len(eng.cache) if eng.cache is not None else 0
+    assert eng.pool.num_evictable == cached
+    assert eng.pool.num_free == _engine(models, **kw).pool.num_free - cached
+
+
+@pytest.mark.parametrize("prefix_cache", [True, False],
+                         ids=["cache_on", "cache_off"])
+def test_backlog_matches_both_references(models, backlog_alone,
+                                         prefix_cache):
     jobs = _backlog_jobs()
     before = _lag_counts()
-    eng = _engine(models)
+    eng = _engine(models, prefix_cache=prefix_cache)
     ticks0 = eng.ticks
     reqs = run_jobs(eng, jobs)
     n = eng.ticks - ticks0
     lag = _lag_delta(before)
     assert assert_greedy_match_dense(
         models["cfg"], models["params"], jobs, reqs) == 7
-    alone = serve_alone(lambda: _engine(models), jobs)
-    assert_same_generations(generations(alone), generations(reqs),
+    assert_same_generations(backlog_alone, generations(reqs),
                             "a backlog against each request alone")
     # the mechanism ran: nearly every tick was applied behind its successor
     assert lag["0"] + lag["1"] == n
     assert lag["1"] >= 0.8 * n, (lag, n)
-    assert not eng._inflight and eng.pool.num_free == _engine(
-        models).pool.num_free - len(eng.cache)
+    assert (eng.cache is not None) == prefix_cache
+    _all_pages_back(eng, models)
+
+
+@pytest.mark.parametrize("policy", ["priority", "slo"])
+def test_backlog_under_a_policy_with_a_tick_in_flight(models, backlog_alone,
+                                                      policy):
+    """Admission order, the prefill budget, preemption and shedding are
+    the policy's, and all of them now land with a tick in flight: two
+    slots and a pool of exactly their pages under the backlog (the prefix
+    cache's idle pages are evicted to grant), the pages accounted for at
+    every step, every request still its own alone."""
+    jobs = []
+    for i, (p, n, kw) in enumerate(_backlog_jobs()):
+        key = (dict(priority=i % 3) if policy == "priority"
+               else dict(ttft_deadline_ms=60_000.0 + 10_000 * i))
+        jobs.append((p, n, dict(kw, **key)))
+    before = _lag_counts()
+    eng = _engine(models, max_slots=2, sched_policy=policy)
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
+    busy = 0            # steps that admitted or planned beside a tick
+    assert eng.pool.num_pages == 2 * (128 // PAGE) + 1
+    while not all(r.finished for r in reqs):
+        busy += bool(eng._inflight) and bool(eng._queue or eng._prefill_q)
+        eng.step()
+        _pages_accounted(eng)
+    eng.run_until_idle()
+    assert busy and _lag_delta(before)["1"]
+    assert eng.failures == 0
+    assert assert_greedy_match_dense(
+        models["cfg"], models["params"], jobs, reqs) == 7
+    assert_same_generations(backlog_alone, generations(reqs),
+                            f"the backlog under {policy}")
+    _all_pages_back(eng, models, max_slots=2)
 
 
 def test_a_scoring_chunk_step_applies_at_once(models):
@@ -234,6 +301,83 @@ def test_budget_end_is_launched_dead(models, n_prompt, asked, n_out):
         models["cfg"], models["params"], [other], [oth]) == 1
     assert not eng._inflight
     assert eng.pool.num_free == free0 - len(eng.cache)
+
+
+@pytest.mark.parametrize("mode", ["termination_id", "eol", "double_eol"])
+def test_every_stop_mode_fires_behind_a_launched_tick(models, monkeypatch,
+                                                      mode):
+    """The host alone applies the stop rules, one tick late: whichever rule
+    ends a row, its next tick is already launched, that row is dropped,
+    and the request is the one served alone.  (The toy vocabulary holds no
+    GPT-2 EOL id: the two ids are set to tokens of the stream.)"""
+    prompt = _prompt(20, 4)
+    free = dict(termination_id=10 ** 9)
+    for seed in range(20):
+        kw = dict(temperature=0.9, top_k=7, seed=seed)
+        stream = run_jobs(_engine(models), [
+            (prompt, 12, dict(kw, **free))])[0].generated
+        k = next((i for i in range(3, 10) if stream[i] not in stream[:i]),
+                 None)
+        if k is not None:
+            break
+    if mode == "termination_id":
+        stop_kw = dict(kw, termination_id=stream[k])
+    elif mode == "eol":
+        monkeypatch.setattr(gen, "GPT2_EOL", stream[k])
+        stop_kw = dict(kw, stop_on_eol=True)
+    else:
+        # a lone EOL before the stop: it would end an ``eol`` row only
+        lone = next(t for i, t in enumerate(stream[:k])
+                    if stream[i + 1] != t and (i == 0 or stream[i - 1] != t)
+                    and t != prompt[-1])
+        monkeypatch.setattr(gen, "GPT2_EOL", lone)
+        monkeypatch.setattr(gen, "GPT2_DOUBLE_EOL", stream[k])
+        stop_kw = dict(kw, stop_on_double_eol=True)
+    jobs = [(prompt, 12, stop_kw), (_prompt(18, 30), 14, dict(GREEDY))]
+    eng = _engine(models)
+    reqs = [eng.submit(p, n, **kw) for p, n, kw in jobs]
+    stopper, overrun = reqs[0], False
+    while not all(r.finished for r in reqs):
+        eng.step()
+        if stopper.finished and eng._inflight:
+            overrun |= any(r is stopper for r in eng._inflight[-1].reqs)
+    assert overrun, "the stop never fired behind a launched tick"
+    assert stopper.generated == stream[:k + 1]
+    if mode == "double_eol":
+        assert gen.GPT2_EOL in stopper.generated[:-1]
+    alone = serve_alone(lambda: _engine(models), jobs)
+    assert_same_generations(generations(alone), generations(reqs),
+                            f"a {mode} stop beside a decoding row")
+    _all_pages_back(eng, models)
+
+
+@pytest.mark.parametrize("prefix_cache", [True, False],
+                         ids=["cache_on", "cache_off"])
+def test_a_pool_just_large_enough_never_fails_a_grant(models, prefix_cache):
+    """Four sequences that each end at ``max_seq`` need every page of the
+    pool: the ledger admits them all, every grant made with a tick in
+    flight is served, and the drain leaves every page free or cached
+    idle."""
+    jobs = [(_prompt(40, 40 + i), 88, dict(GREEDY)) for i in range(4)]
+    eng = _engine(models, prefix_cache=prefix_cache)
+    assert eng.pool.num_free == 4 * (128 // PAGE)   # and the null page
+    grants, alloc = [], eng.pool.alloc
+
+    def counted(n):
+        got = alloc(n)
+        grants.append((bool(eng._inflight), got is not None))
+        return got
+
+    eng.pool.alloc = counted
+    reqs = run_jobs(eng, jobs)
+    eng.pool.alloc = alloc
+    assert eng.peak_active_slots == 4 and eng.failures == 0
+    assert all(ok for _, ok in grants)
+    # a page a sequence and boundary crossed while it decodes
+    assert sum(flying for flying, _ in grants) >= 4 * 4
+    assert assert_greedy_match_dense(
+        models["cfg"], models["params"], jobs, reqs) == 4
+    _all_pages_back(eng, models)
 
 
 # ---------------------------------------------------------------------------
@@ -399,8 +543,23 @@ def test_never_idle_with_a_tick_in_flight(models):
     for _ in range(4):  # prefill, its landing, two decode launches
         eng.step()
     assert eng._inflight
-    eng._drain_pipeline()
+    eng._land_inflight()
     assert not eng._inflight
+
+
+def test_nothing_in_flight_after_idle_and_after_stop(models):
+    """``run_until_idle`` and the loop's shutdown land the lagged tick:
+    nothing is left launched, and ``mlt_engine_inflight_ticks`` says so."""
+    gauge = obs_registry.get_registry().gauge("mlt_engine_inflight_ticks")
+    eng = _engine(models)
+    run_jobs(eng, _backlog_jobs()[:3])
+    assert not eng._inflight and gauge.value == 0
+    req, seen = eng.submit_stream(_prompt(9, 3), 100, **GREEDY)
+    eng.start()
+    assert seen.next_event(timeout=60) is not None   # a tick is ahead
+    eng.stop()
+    assert not req.finished and eng._thread is None
+    assert not eng._inflight and gauge.value == 0
 
 
 @pytest.mark.parametrize("seed", [0, 1, 42, 2 ** 31 - 1, 2 ** 31,

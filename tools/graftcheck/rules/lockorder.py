@@ -35,7 +35,8 @@ to a fixed point, and emits the edge ``A -> B`` wherever ``B`` is
 acquired (directly or via a call) while ``A`` is held.  Any strongly
 connected component with more than one node is a potential deadlock and
 is reported as an ``error`` finding.  The full graph — nodes, edges
-with example sites, and the topological order when acyclic — is exposed
+with example sites (``file:Class.method``), and the topological order when
+acyclic — is exposed
 as the ``lockorder`` artifact (``--lockorder-out``, committed as
 ``tools/graftcheck/lockorder.json`` evidence).
 
@@ -306,7 +307,11 @@ class _Graph:
     def __init__(self):
         self._parent: Dict[str, str] = {}
         self._prefer: Set[str] = set()   # annotation-named canonical roots
-        self.edges: Dict[Tuple[str, str], List[str]] = {}
+        # (a, b) -> example sites, ``file:Class.method`` -> first line there
+        # (the artifact names the method only, so that an edit above it
+        # does not change the committed evidence; a finding is anchored
+        # at the line)
+        self.edges: Dict[Tuple[str, str], Dict[str, int]] = {}
         self.alias_members: Dict[str, Set[str]] = {}
 
     # ---- union-find ----
@@ -342,13 +347,12 @@ class _Graph:
     def canon(self, n: str) -> str:
         return self._find(n) if n in self._parent else n
 
-    def add_edge(self, a: str, b: str, example: str) -> None:
+    def add_edge(self, a: str, b: str, example: str, line: int) -> None:
         a, b = self.canon(a), self.canon(b)
         if a == b:
             return
-        self.edges.setdefault((a, b), [])
-        if len(self.edges[(a, b)]) < 3 and example not in self.edges[(a, b)]:
-            self.edges[(a, b)].append(example)
+        sites = self.edges.setdefault((a, b), {})
+        sites[example] = min(line, sites.get(example, line))
 
     # ---- analysis ----
 
@@ -455,8 +459,10 @@ class LockOrderRule(ProjectRule):
 
     # ---- pass 2 ----
 
-    def build_graph(self, project: ProjectContext) -> dict:
-        """The lockorder artifact (also computed by tests directly)."""
+    def build_graph(self, project: ProjectContext) -> Tuple[dict, dict]:
+        """The lockorder artifact, and the first line of each example site
+        it names (``file:Class.method``, not a line: the evidence changes
+        when the lock structure does, not when a line moves)."""
         facts = project.facts_for(self.id)
         # class name -> (relpath, model); later duplicate class names are
         # ignored deterministically (first file in walk order wins)
@@ -541,7 +547,7 @@ class LockOrderRule(ProjectRule):
                     held = [node_of(cname, h) for h in ev["held"]]
                     if not held:
                         continue
-                    site = f"{rel}:{ev['line']}"
+                    site = f"{rel}:{cname}.{mname}"
                     if ev["kind"] == "acquire":
                         acquired = {node_of(cname, ev["lock"])}
                     else:
@@ -549,7 +555,7 @@ class LockOrderRule(ProjectRule):
                         acquired = acquires.get(tgt, set()) if tgt else set()
                     for b in acquired:
                         for a in held:
-                            graph.add_edge(a, b, site)
+                            graph.add_edge(a, b, site, ev["line"])
 
         cycles = graph.cycles()
         return {
@@ -560,14 +566,15 @@ class LockOrderRule(ProjectRule):
                  "aliases": sorted(graph.alias_members.get(n, {n}))}
                 for n in graph.nodes()],
             "edges": [
-                {"from": a, "to": b, "examples": sorted(ex)}
+                {"from": a, "to": b, "examples": sorted(ex)[:3]}
                 for (a, b), ex in sorted(graph.edges.items())],
             "order": graph.topo_order(),
             "cycles": cycles,
-        }
+        }, {site: line for ex in graph.edges.values()
+            for site, line in ex.items()}
 
     def finalize(self, project: ProjectContext) -> Iterable[Finding]:
-        artifact = self.build_graph(project)
+        artifact, line_of = self.build_graph(project)
         project.artifacts["lockorder"] = artifact
         edge_by_from: Dict[str, List[dict]] = {}
         for e in artifact["edges"]:
@@ -583,9 +590,9 @@ class LockOrderRule(ProjectRule):
                                  f"(e.g. {e['examples'][0]})")
                     if site is None:
                         site = e["examples"][0]
-            path, _, line = (site or "unknown:1").rpartition(":")
+            path = (site or "unknown:").rpartition(":")[0]
             yield self.project_finding(
-                path or "unknown", int(line) if line.isdigit() else 1,
+                path or "unknown", line_of.get(site, 1),
                 "potential deadlock: lock-acquisition cycle "
                 + " ; ".join(chain)
                 + " — break the cycle or document a single global order")

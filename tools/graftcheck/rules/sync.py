@@ -154,8 +154,8 @@ class SyncInJitRule(Rule):
                         traced.update(self._resolve(build, defs,
                                                     nested_only=True))
         # builder-factory convention (ISSUE 17): the engine reaches the
-        # ragged/chained tick builders through cross-module thunks
-        # (``build=lambda: make_chained_tick_fn(...)``) that the per-file
+        # ragged tick builder through a cross-module thunk
+        # (``build=lambda: make_ragged_tick_fn(...)``) that the per-file
         # resolver above cannot follow — the thunk body is a Call, not a
         # Name.  Module-level ``make_*_fn`` factories that touch jax are
         # therefore cached_jit builders by convention: the factory body
