@@ -47,6 +47,16 @@ class ModelConfig:
     rope_llama3_low_freq_factor: float = 1.0
     rope_llama3_high_freq_factor: float = 4.0
     rope_llama3_original_max_position: int = 8192
+    # 'yarn' (ops/rope.py yarn_scale_freqs): the band of frequencies between
+    # `beta_fast` and `beta_slow` turns over the original context is blended
+    # from extrapolation to interpolation by `rope_scaling_factor`;
+    # `rope_yarn_mscale_all_dim` > 0 scales the softmax by m^2, m = 0.1 x
+    # mscale_all_dim x ln(factor) + 1 (the DeepSeek-V3 convention; the cos /
+    # sin table stays unscaled: mscale / mscale_all_dim = 1)
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_original_max_position: int = 4096
+    rope_yarn_mscale_all_dim: float = 0.0
     vocab_size: Optional[int] = None  # set from tokenizer
     make_vocab_size_divisible_by: int = 128
     layernorm_epsilon: float = 1e-5
@@ -186,6 +196,33 @@ class ModelConfig:
     qk_nope_head_dim: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
+    # an elementwise sigmoid gate on the attention's output, read from the
+    # layer's normed input (`gated_attention`; the leaf `attention/g_proj`)
+    attention_output_gate: bool = False
+    # --- linear layers: the gated delta rule (ops/gated_delta.py) ---
+    # one period of the scanned stack: entry j says whether the layers l
+    # with l % period == j are LINEAR layers (1: a gated-delta mixer on a
+    # per-sequence recurrent state) or `attention_type` layers (0).  The
+    # dense prefix is counted apart: `dense_prefix_linear` says its layers
+    # are linear ones.  The state's and the conv tail's dtype (float32), the
+    # chunk and the kernel's block are the mechanism's, not flags
+    linear_layout: Optional[Tuple[int, ...]] = None
+    dense_prefix_linear: bool = False
+    linear_num_key_heads: Optional[int] = None
+    linear_num_value_heads: Optional[int] = None
+    linear_key_head_dim: Optional[int] = None
+    linear_value_head_dim: Optional[int] = None
+    linear_conv_kernel_dim: int = 4
+    # --- the GigaChat3.5 block ---
+    # `layernorm_type: pre_post`: a norm after each sublayer too, inside
+    # the residual branch (leaves `attn_out_norm`, `mlp_out_norm`)
+    post_sublayer_norms: bool = False
+    # `norm_type: ZeroCenteredGatedNorm`: the RMSNorm's gain is 2 sigmoid(w)
+    # (1 at w = 0; the leaf is `gate`, not `scale`: ops/norms.py)
+    zero_centered_gated_norm: bool = False
+    # gpt-oss's clamp inside a SwiGLU: SiLU(min(gate, limit)) x clip(value,
+    # -limit, limit), dense MLP and experts alike
+    swiglu_limit: Optional[float] = None
 
     @property
     def depth(self) -> int:
@@ -204,7 +241,19 @@ class ModelConfig:
     @property
     def layer_period(self) -> int:
         """Layers in one period of the layer pattern (1 = uniform)."""
-        return len(self.sliding_window_layout or self.rope_layout or (0,))
+        return len(self.sliding_window_layout or self.rope_layout
+                   or self.linear_layout or (0,))
+
+    @property
+    def delta(self) -> bool:
+        """Whether some layer is a gated-delta (linear) layer."""
+        return bool(self.linear_layout and any(self.linear_layout)) or (
+            self.dense_prefix_linear and self.dense_prefix_layers > 0)
+
+    @property
+    def norm_gain(self) -> str:
+        """How an RMSNorm's leaf becomes its gain (ops/norms.py)."""
+        return "sigmoid2" if self.zero_centered_gated_norm else "scale"
 
     @property
     def experts_held(self) -> int:
@@ -239,7 +288,7 @@ class ModelConfig:
             # exist (one latent row serves every query head)
             self.kv_channels = self.qk_rope_head_dim
             self.num_attention_heads_kv = 1
-        for name in ("sliding_window_layout", "rope_layout"):
+        for name in ("sliding_window_layout", "rope_layout", "linear_layout"):
             layout = getattr(self, name)
             if layout is not None:
                 layout = tuple(int(v) for v in layout)
@@ -265,6 +314,25 @@ class ModelConfig:
                 "must be 'rotary'")
             assert not self.mla and not self.bidirectional, (
                 "a layer pattern is written for causal K/V-head attention")
+        if self.delta:
+            missing = [k for k in ("linear_num_key_heads",
+                                   "linear_num_value_heads",
+                                   "linear_key_head_dim",
+                                   "linear_value_head_dim")
+                       if not getattr(self, k)]
+            assert not missing, f"linear layers need {missing}"
+            assert self.linear_num_value_heads % self.linear_num_key_heads \
+                == 0, "a key head serves a whole number of value heads"
+            assert (self.mla and not self.sliding_window_layout
+                    and not self.rope_layout), (
+                "linear layers stand beside latent attention "
+                "(attention_type 'mla') in a stack with no window pattern")
+            assert self.num_layers % self.layer_period == 0, (
+                f"num_layers {self.num_layers} is not a whole number of "
+                f"periods of linear_layout ({self.layer_period} layers)")
+        # periods of the scanned stack: what a hybrid's mixer stacks are
+        # sized by (models/transformer.py init_mixers)
+        self.scanned_periods = self.num_layers // self.layer_period
         if self.kv_channels is None:
             assert self.hidden_size % self.num_attention_heads == 0, (
                 f"hidden_size {self.hidden_size} not divisible by "
@@ -820,6 +888,7 @@ class Config:
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
                 "mistral", "mixtral", "joyai", "smallthinker", "commanda",
+                "gigachat35",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -848,9 +917,11 @@ class Config:
             assert self.parallel.context_parallel_size == 1, (
                 "a layer pattern with context parallelism is not written: "
                 "the ring has one window for every layer")
-            assert not self.model.dense_prefix_layers, (
-                "a layer pattern counts its periods from layer 0: no "
-                "dense prefix")
+            assert (not self.model.dense_prefix_layers
+                    or self.model.linear_layout), (
+                "a window pattern counts its periods from layer 0: no "
+                "dense prefix (linear_layout counts them from the scanned "
+                "stack's first layer)")
         return self
 
 
@@ -1037,6 +1108,42 @@ ARCH_DEFAULTS = {
         rope_theta=1_000_000.0,
         attention_type="retention",
     ),
+    # GigaChat3.5 (beyond-reference; ai-sage's `gigachat3_5`): a hybrid
+    # stack, three gated-delta (linear) layers to one latent-attention
+    # layer, under the DeepSeek-V3 expert layer.  The scanned stack starts
+    # at the model's first attention layer (its layer 3), so a period reads
+    # latent, linear, linear, linear; the dense prefix (the published 3
+    # layers) is linear layers with a dense SwiGLU.  pre_post norms whose
+    # gain is 2 sigmoid(w), a sigmoid gate on the attention's output, the
+    # clamp of swiglu_limit, YaRN (factor 8 over 32,768) on the 64 rope dims
+    "gigachat35": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=100_000.0,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=8.0,
+        rope_yarn_beta_fast=32.0,
+        rope_yarn_beta_slow=1.0,
+        rope_yarn_original_max_position=32768,
+        rope_yarn_mscale_all_dim=1.0,
+        attention_type="mla",
+        attention_output_gate=True,
+        linear_layout=(0, 1, 1, 1),
+        dense_prefix_linear=True,
+        dense_prefix_layers=3,
+        post_sublayer_norms=True,
+        zero_centered_gated_norm=True,
+        swiglu_limit=10.0,
+        moe_score_func="sigmoid",
+        moe_selection_bias=True,
+        moe_normalize_gates=True,
+        moe_routed_scaling_factor=2.5,
+        moe_shared_experts=1,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1106,6 +1213,25 @@ MODEL_SIZES = {
                        num_attention_heads=40, num_attention_heads_kv=8,
                        kv_channels=128, ffn_hidden_size=17408,
                        max_position_embeddings=32768, vocab_size=151936),
+    # 40 published layers = 3 dense linear layers + 37 of experts; the
+    # scanned stack is whole periods of (latent, linear, linear, linear)
+    # from the model's layer 3 on: 36 of them, the published layer 39 (a
+    # latent layer that would open a tenth period) left to a cut's arithmetic
+    "gigachat35-432b-a28b": dict(num_layers=36, hidden_size=7168,
+                                 num_attention_heads=64,
+                                 ffn_hidden_size=18432,
+                                 max_position_embeddings=262144,
+                                 q_lora_rank=1536, kv_lora_rank=512,
+                                 qk_nope_head_dim=128, qk_rope_head_dim=64,
+                                 v_head_dim=128,
+                                 linear_num_key_heads=32,
+                                 linear_num_value_heads=64,
+                                 linear_key_head_dim=128,
+                                 linear_value_head_dim=128,
+                                 linear_conv_kernel_dim=4,
+                                 num_experts=256, moe_router_topk=8,
+                                 moe_ffn_hidden_size=2048,
+                                 vocab_size=128256),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,
@@ -1221,7 +1347,7 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     )
     parser.add_argument("--model_name", type=str, default=None,
                         help="gpt|llama|llama2|codellama|llama3|falcon|"
-                             "mistral|mixtral|qwen2|joyai|smallthinker|bert|t5 "
+                             "mistral|mixtral|qwen2|joyai|smallthinker|gigachat35|bert|t5 "
                              "or a canonical size like llama2-7b / "
                              "llama3-8b")
     seen = set()

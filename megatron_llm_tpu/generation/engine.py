@@ -94,6 +94,16 @@ resident, keep its batch full, and never compute the same prefix twice.
   cache is off.  What pages alone carry refuses in a sentence
   (generation/pools.py ``refuse_unserved``).
 
+* **A state beside pages** (a hybrid: gated-delta layers and latent
+  attention in one stack): ONE engine holds a :class:`PagedKVPool` for the
+  attention layers' latent rows and a :class:`StatePool` for the linear
+  layers' states.  A request holds pages AND a state slot (``_state``):
+  admission grants both or waits, the tick carries a leaf and a table a
+  class (the state class's one entry wide), prefill stops before the
+  prompt's last token for the whole stack (a state cannot take a token
+  twice, so the pages must not either), retirement and preemption release
+  both, and a resume prefills both again.
+
 Threading: ``submit`` may be called from any thread (e.g. concurrent HTTP
 handlers — generation/server.py); device work happens on whichever thread
 drives :meth:`step`, either the built-in background loop (:meth:`start`) or
@@ -229,6 +239,9 @@ class EngineRequest:
     _wkeep: int = dataclasses.field(default=0, repr=False)
     _wprivate: int = dataclasses.field(default=0, repr=False)
     _wmax: int = dataclasses.field(default=0, repr=False)
+    # a hybrid's state slot, held beside ``_pages`` from admission to the
+    # end (a state-only model's slot IS its one "page")
+    _state: List[int] = dataclasses.field(default_factory=list, repr=False)
     _step: int = 0  # decode ticks taken (== len(generated))
     # scheduler state: queued -> prefill -> decode -> finished
     _phase: str = dataclasses.field(default="queued", repr=False)
@@ -342,9 +355,15 @@ class ContinuousBatchingEngine:
         # sharding error from the middle of start-up
         refuse_unserved(cfg, kv_dtype=pick(kv_dtype, "kv_dtype"), mesh=mesh,
                         draft=bool(pick(spec_k, "spec_k")))
-        # a constant-size state a sequence (power retention) in place of
-        # pages: :class:`StatePool`
-        self.state = bool(cfg.model.retention)
+        # page classes (models/transformer.py ``pool_classes``).  A class
+        # may keep a constant-size state a sequence (:class:`StatePool`):
+        # in place of pages (power retention: the slot is the one "page" a
+        # sequence holds) or beside a page class (a hybrid)
+        from megatron_llm_tpu.models.transformer import pool_classes
+
+        classes = pool_classes(cfg)
+        self.state = any(cls.state for cls in classes)
+        self._state_only = all(cls.state for cls in classes)
         if inf.int8_weights:
             # same decode-weight quantization contract as api.InferenceEngine
             from megatron_llm_tpu.ops.quant import quantize_layer_weights_int8
@@ -502,7 +521,7 @@ class ContinuousBatchingEngine:
         # per request; rows of a request share it)
         self._pre_tables_cap = self.prefill_rows // self.prefill_chunk + 1
         # a state class's "table" is one entry wide: the sequence's slot
-        self.pages_per_seq = (1 if self.state
+        self.pages_per_seq = (1 if self._state_only
                               else -(-self.max_seq // self.page_size))
         num_pages = (num_pages or inf.kv_pool_pages
                      or self.max_slots * self.pages_per_seq + 1)
@@ -517,16 +536,22 @@ class ContinuousBatchingEngine:
             assert self.draft_cfg.model.num_layers % self._pp == 0, (
                 f"draft num_layers {self.draft_cfg.model.num_layers} "
                 f"not divisible by pp {self._pp}")
-        # page classes (models/transformer.py ``pool_classes``): a uniform
-        # model has ONE, today's pool, tables and tick; a patterned model
-        # a full class (``pool``) and a window class (``wpool``), each with
-        # its own leaf, free list, reference counts and block tables
-        from megatron_llm_tpu.models.transformer import pool_classes
-
-        classes = pool_classes(cfg)
+        # a uniform model has ONE class, today's pool, tables and tick; a
+        # patterned model a full class (``pool``) and a window class
+        # (``wpool``); a hybrid a page class (``pool``) and a state class
+        # (``spool``): each with its own leaf, free list, reference counts
+        # and tables
         self.wpool: Optional[PagedKVPool] = None
+        self.spool: Optional[StatePool] = None
         self._window = 0
-        if len(classes) == 2:
+        if self.state and not self._state_only:
+            page, st = classes
+            self.pool = PagedKVPool(
+                cfg, num_pages, self.page_size, kv_dtype=self.kv_dtype,
+                layers=page.layers(cfg), page_class=page.name)
+            self.spool = StatePool(cfg, self.max_slots, self.page_size,
+                                   layers=st.layers(cfg), page_class=st.name)
+        elif len(classes) == 2:
             periods = cfg.model.num_layers // cfg.model.layer_period
             full, win = classes
             self._window = int(win.window)
@@ -545,17 +570,20 @@ class ContinuousBatchingEngine:
                                               self.window_pages_cap) + 1),
                 self.page_size, kv_dtype=self.kv_dtype,
                 layers=periods * len(win.places), page_class=win.name)
-        elif classes[0].state:
-            self.pool = StatePool(cfg, self.max_slots, self.page_size)
-            if use_cache:
-                print("[engine] the prefix cache is off for a state pool: "
-                      "a sequence's past is one recurrent state, and a "
-                      "trie of pages has nothing to hold", flush=True)
-            use_cache = False
+        elif self._state_only:
+            self.pool = self.spool = StatePool(cfg, self.max_slots,
+                                               self.page_size)
         else:
             self.pool = PagedKVPool(cfg, num_pages, self.page_size,
                                     mesh=mesh, draft_cfg=self.draft_cfg,
                                     kv_dtype=self.kv_dtype)
+        if self.state:
+            if use_cache:
+                print("[engine] the prefix cache is off for a state pool: "
+                      "a sequence's past is one recurrent state, which a "
+                      "trie of pages does not hold at a page's boundary",
+                      flush=True)
+            use_cache = False
         self.cache = (PrefixCache(self.pool, self.page_size, self.wpool,
                                   self._window or None)
                       if use_cache else None)
@@ -572,6 +600,10 @@ class ContinuousBatchingEngine:
         # NULL_PAGE behind a sequence's first live page — guarded by _lock
         self._wtables = (np.zeros((s, self.pages_per_seq), np.int32)
                          if self.wpool is not None else None)
+        # a hybrid's state class: a slot's table is its sequence's state
+        # slot, one entry wide — guarded by _lock
+        self._stables = (np.zeros((s, 1), np.int32)
+                         if self.spool not in (None, self.pool) else None)
         self._positions = np.zeros((s,), np.int32)    # guarded by _lock
         self._tokens = np.zeros((s,), np.int32)       # guarded by _lock
         self._temperature = np.ones((s,), np.float32)  # guarded by _lock
@@ -902,9 +934,10 @@ class ContinuousBatchingEngine:
                   help="device bytes of the recurrent-state pool (float32 "
                        "S and z of every layer, KV head and slot, the "
                        "null slot included; 0 for a paged model)"
-                  ).set(self.pool.kv_pool_bytes() if self.state else 0)
-        self._pools = [self.pool] + (
-            [self.wpool] if self.wpool is not None else [])
+                  ).set(self.spool.kv_pool_bytes() if self.state else 0)
+        self._pools = [self.pool] + [
+            pl for pl in (self.wpool, self.spool)
+            if pl not in (None, self.pool)]
         self._m_dry_class = {
             pl.page_class: reg.counter(
                 "mlt_engine_pool_dry_ticks_total",
@@ -1099,6 +1132,9 @@ class ContinuousBatchingEngine:
     def _class_statics(self) -> Tuple:
         """Nothing for a uniform model (its programs' keys stay as they
         were); a patterned model's window class's geometry."""
+        if self.spool not in (None, self.pool):
+            return ("state_class", self.spool.num_pages,
+                    self.spool.kv_statics)
         if self.wpool is None:
             return ()
         return ("window_class", self.wpool.num_pages, self._window)
@@ -1107,16 +1143,17 @@ class ContinuousBatchingEngine:
     def _kv(self):
         """What a tick program takes and returns as ``pool_kv``: the
         pool's leaf, or for a patterned model the (full, window) pair."""
-        if self.wpool is None:
+        if len(self._pools) == 1:
             return self.pool.kv
-        return (self.pool.kv, self.wpool.kv)
+        return tuple(pl.kv for pl in self._pools)
 
     @_kv.setter
     def _kv(self, kv) -> None:
-        if self.wpool is None:
+        if len(self._pools) == 1:
             self.pool.kv = kv
         else:
-            self.pool.kv, self.wpool.kv = kv
+            for pl, leaf in zip(self._pools, kv):
+                pl.kv = leaf
 
     @property
     def _mesh_statics(self) -> Tuple:
@@ -1475,7 +1512,7 @@ class ContinuousBatchingEngine:
                       ).set(by_prio.get(prio, 0))
 
     def _max_pages_for(self, req: EngineRequest) -> int:
-        if self.state:
+        if self._state_only:
             return 1      # its state slot, whatever its length
         total = min(len(req.prompt) + req.max_new_tokens, self.max_seq)
         return -(-total // self.page_size)
@@ -1691,7 +1728,8 @@ class ContinuousBatchingEngine:
         cow = bool(matched) and covered == prompt_len
         n_keep = len(matched) - (1 if cow else 0)
         fill_end = self._fill_end(prompt_len)
-        suffix_pages = 1 if self.state else (fill_end - covered) // ps
+        suffix_pages = (1 if self._state_only
+                        else -(-(fill_end - covered) // ps))
         held_core = n_keep + (1 if cow else 0) + suffix_pages
         extra = 1 if max_total > held_core else 0  # first decode page
         need_now = (1 if cow else 0) + suffix_pages + extra
@@ -1712,7 +1750,8 @@ class ContinuousBatchingEngine:
         if (self.pool.num_available - need_now
                 < self._committed + remaining + self.page_watermark) or (
                 classed and self.wpool.num_available - wneed
-                < self._wcommitted + wmax - wneed + self.page_watermark):
+                < self._wcommitted + wmax - wneed + self.page_watermark) or (
+                self._stables is not None and not self.spool.num_free):
             undo()
             return None
         fresh = self.pool.alloc(need_now)
@@ -1722,6 +1761,9 @@ class ContinuousBatchingEngine:
             undo()
             return None
         self._committed += remaining
+        if self._stables is not None:
+            # the state slot, granted with the pages or not at all
+            req._state = self.spool.alloc(1)
         if classed:
             self._wcommitted += wmax - wneed
             req._wpages = wmatched + wfresh
@@ -1816,6 +1858,8 @@ class ContinuousBatchingEngine:
         bt = np.full((self.pages_per_seq,), NULL_PAGE, np.int32)
         bt[: len(req._pages)] = req._pages
         self._block_tables[slot] = bt
+        if self._stables is not None:
+            self._stables[slot] = req._state
         if self.wpool is not None:
             self._slide_locked(req, len(seq) - 1)
             self._wtables[slot] = NULL_PAGE
@@ -1880,8 +1924,9 @@ class ContinuousBatchingEngine:
         """An emptied slot: a dead row (null tables, greedy, position 0)."""
         self._slots[slot] = None
         self._block_tables[slot] = NULL_PAGE
-        if self._wtables is not None:
-            self._wtables[slot] = NULL_PAGE
+        for tables in (self._wtables, self._stables):
+            if tables is not None:
+                tables[slot] = NULL_PAGE
         self._positions[slot] = 0
         self._tokens[slot] = 0
         self._top_k[slot] = 1
@@ -1895,6 +1940,9 @@ class ContinuousBatchingEngine:
         pages, req._pages = req._pages, []
         self._committed -= max(0, req._max_pages - len(pages))
         self.pool.release(pages)
+        if req._state:
+            self.spool.release(req._state)
+            req._state = []
         if self.wpool is None:
             return len(pages)
         wpages = [p for p in req._wpages if p != NULL_PAGE]
@@ -2342,10 +2390,12 @@ class ContinuousBatchingEngine:
         if self._dirty:
             bt = self._block_tables.copy()
             bt[list(spent)] = NULL_PAGE
-            if self._wtables is not None:
-                wbt = self._wtables.copy()
-                wbt[list(spent)] = NULL_PAGE
-                bt = (bt, wbt)
+            other = (self._wtables if self._wtables is not None
+                     else self._stables)
+            if other is not None:
+                obt = other.copy()
+                obt[list(spent)] = NULL_PAGE
+                bt = (bt, obt)
             # the tables alone may be a pair (one a page class)
             self._dev_state = (jax.tree.map(self._asarray, bt),) + tuple(
                 self._asarray(a) for a in (
@@ -2458,10 +2508,15 @@ class ContinuousBatchingEngine:
                              NULL_PAGE, np.int32)
         pre_index = np.full((Rp,), -1, np.int32)
         pre_hor = np.zeros((Rp,), np.int32)
-        pre_wtables = None
+        pre_wtables = pre_stables = None
+        first_tables = pre_tables
         if self.wpool is not None:
             pre_wtables = np.full_like(pre_tables, NULL_PAGE)
             pre_tables = (pre_tables, pre_wtables)
+        elif self._stables is not None:
+            pre_stables = np.full((self._pre_tables_cap, 1), NULL_PAGE,
+                                  np.int32)
+            pre_tables = (pre_tables, pre_stables)
         spans: List[Tuple[EngineRequest, int, int]] = []
         live = [r for r in self._prefill_q if r._phase == "prefill"]
         if len(live) != len(self._prefill_q):  # failed/cancelled
@@ -2487,10 +2542,10 @@ class ContinuousBatchingEngine:
             pos = req._fill_pos
             if pos >= fill_end or used >= budget:
                 continue
-            if pre_wtables is None:
-                pre_tables[n_req, : len(req._pages)] = req._pages
-            else:
-                pre_tables[0][n_req, : len(req._pages)] = req._pages
+            first_tables[n_req, : len(req._pages)] = req._pages
+            if pre_stables is not None:
+                pre_stables[n_req] = req._state
+            if pre_wtables is not None:
                 self._slide_locked(req, pos)
                 # the rows this request gets are known before they are
                 # packed: chunk ends do not move what the budget leaves
@@ -2777,7 +2832,7 @@ class ContinuousBatchingEngine:
             self._m_state["touches"].inc(len(active) + len(runs))
             self._m_state["resets"].inc(
                 sum(start == 0 for start in runs.values()) + starts)
-        elif obs_registry.publishing():
+        if not self._state_only and obs_registry.publishing():
             # the tick's rows as the program lays them out, by the kernel's
             # own rule: a slot's verify rows and a request's prompt rows
             # stand at consecutive positions of one table, and consecutive
@@ -2933,6 +2988,8 @@ class ContinuousBatchingEngine:
             if self.wpool is not None:
                 self._m_seq_pages[self.wpool.page_class].inc(
                     sum(len(r._wpages) - r._wfirst for r in live))
+            if self._stables is not None:
+                self._m_seq_pages[self.spool.page_class].inc(len(live))
 
     def run_until_idle(self) -> None:
         """Drive ticks on the calling thread until queue and slots drain.
@@ -3113,6 +3170,7 @@ class ContinuousBatchingEngine:
         add_BOS: bool = False,
         stop_on_double_eol: bool = False,
         stop_on_eol: bool = False,
+        use_eod_token_for_early_termination: bool = True,
         random_seed: int = -1,
         priority: int = 1,
         ttft_deadline_ms: Optional[float] = None,
@@ -3138,6 +3196,7 @@ class ContinuousBatchingEngine:
             stream_events=stream_events,
             temperature=temperature, top_k=top_k_sampling,
             top_p=top_p_sampling, termination_id=termination_id,
+            use_eod_for_termination=use_eod_token_for_early_termination,
             stop_on_double_eol=stop_on_double_eol,
             stop_on_eol=stop_on_eol,
             seed=None if random_seed == -1 else random_seed,

@@ -4,8 +4,9 @@ and the prefix trie, below the scheduler (generation/engine.py).
 * :class:`PagedKVPool` — the device page pool and its host-side
   refcounting allocator; one a page class (a patterned model has a full and
   a window class, models/transformer.py ``pool_classes``).
-* :class:`StatePool` — the same allocator over state slots, for a model
-  that keeps a constant-size recurrent state a sequence (power retention).
+* :class:`StatePool` — the same allocator over state slots, for the layers
+  that keep a constant-size recurrent state a sequence (power retention;
+  a hybrid's gated-delta layers, BESIDE its attention layers' page pool).
 * :class:`PrefixCache` — the host-side radix trie over page-aligned token
   chunks, and the eviction order of its idle pages.
 * :func:`refuse_unserved` — what each kind of per-sequence memory does not
@@ -44,11 +45,12 @@ NULL_PAGE = 0
 # ---- what is not carried yet: one table ------------------------------------
 #
 # A row is (kind of per-sequence memory, feature) -> why the feature does
-# not carry that kind yet.  The kinds are what ``cfg`` shows
+# not carry that kind yet.  The kinds are what ``cfg``'s pool classes show
 # (:func:`memory_kind`): ``classes`` (a full and a window page class),
 # ``latent`` (MLA's one-leaf pool), ``state`` (power retention's state
-# slots); ``paged``, one class of K/V pages, carries every feature and has
-# no row.  The features are what a caller of :func:`refuse_unserved` may
+# slots), ``hybrid`` (a state class BESIDE a page class: a sequence holds a
+# slot and pages); ``paged``, one class of K/V pages, carries every
+# feature and has no row.  The features are what a caller of :func:`refuse_unserved` may
 # ask for: ``kv_dtype`` other than bf16, a ``tp`` or ``pp`` mesh, a
 # ``draft`` model (--spec_k), the cross-replica ``handoff``, a request's
 # ``log_probs``.  ``pattern`` is no feature but the kind's own
@@ -68,6 +70,9 @@ KEEPS = {
                "row a token, key and value at once"),
     "state": ("power retention (attention_type 'retention') keeps a "
               "constant-size recurrent state a sequence"),
+    "hybrid": ("a hybrid stack (linear_layout {linear}) keeps a recurrent "
+               "state a sequence for its linear layers beside latent pages "
+               "for its attention layers"),
 }
 
 _CLASSES_MESH = (
@@ -115,9 +120,10 @@ NOT_CARRIED = {
         "the cross-replica KV handoff: its wire format names a K and a V "
         "leaf"),
     ("state", "pattern"): (
-        "a stack that mixes it with a page class (a layer pattern, a dense "
-        "prefix, latent attention): the engine serves one kind of "
-        "per-sequence memory a model"),
+        "a stack that mixes it with a page class (a window pattern, a dense "
+        "prefix, latent attention): power retention's state is served "
+        "alone; the state class BESIDE a page class is the gated-delta "
+        "hybrid's (linear_layout)"),
     ("state", "kv_dtype"): (
         "--kv_dtype {kv_dtype}: the state is a float32 sum that is decayed "
         "and added to at every token, and storing it lower is a different "
@@ -137,17 +143,44 @@ NOT_CARRIED = {
     ("state", "log_probs"): (
         "return_log_probs (prompt scoring): the scoring chunk feeds many "
         "tokens a row through a block table"),
+    ("hybrid", "kv_dtype"): (
+        "--kv_dtype {kv_dtype}: the state is a float32 sum that every "
+        "token corrects, and a latent row has no KV head to keep page "
+        "scales by"),
+    ("hybrid", "tp"): (
+        "tensor-parallel serving (tp {tp}): neither the state pool nor the "
+        "latent row is sharded over heads"),
+    ("hybrid", "pp"): (
+        "pipeline-parallel serving (pp {pp}): the stage pipeline hands ONE "
+        "paged leaf from stage to stage, and the dense prefix is a stack "
+        "before the one it cuts"),
+    ("hybrid", "draft"): (
+        "--spec_k: a rejected draft token would have to roll the state "
+        "back, and nothing keeps the state before it"),
+    ("hybrid", "handoff"): (
+        "the cross-replica KV handoff: its wire format names pages of keys "
+        "and values, and a state slot is neither"),
+    ("hybrid", "log_probs"): (
+        "return_log_probs (prompt scoring): the scoring chunk feeds many "
+        "tokens a row through one block table, and a linear layer takes "
+        "one row a token from its state slot"),
 }
 
 
 def memory_kind(cfg) -> str:
-    """The kind of per-sequence memory ``cfg``'s model is served from."""
-    m = cfg.model
-    if m.retention:
+    """The kind of per-sequence memory ``cfg``'s model is served from, read
+    off its pool classes: every class a state, a state class beside a page
+    class, more than one page class, or one (of latent rows or K/V)."""
+    if cfg.model.retention:
+        return "state"          # whatever else the stack claims to be
+    states = [cls.state for cls in pool_classes(cfg)]
+    if all(states):
         return "state"
-    if len(pool_classes(cfg)) > 1:
+    if any(states):
+        return "hybrid"
+    if len(states) > 1:
         return "classes"
-    return "latent" if m.mla else "paged"
+    return "latent" if cfg.model.mla else "paged"
 
 
 def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
@@ -158,9 +191,9 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
     start-up (or at the request, for ``log_probs``), in a sentence, instead
     of failing inside a compile.  Callers pass what they know; what they
     leave out is not asked for.  Admission, chunked prefill, the prefix
-    trie, copy-on-write and preemption carry every kind (a state pool's
-    prefix cache is off, not refused: a trie of pages has nothing to
-    hold)."""
+    trie, copy-on-write and preemption carry every kind (the prefix cache
+    of a model with a state class is off, not refused: a trie of pages
+    does not hold the state at a page's boundary)."""
     m = cfg.model
     tp = mesh.shape.get(TP_AXIS, 1) if mesh is not None else 1
     pp = mesh.shape.get(PP_AXIS, 1) if mesh is not None else 1
@@ -187,8 +220,10 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
         experts=m.num_experts)
     if kind == "share":
         raise ValueError(why)
+    keeps = KEEPS[kind].format(layout=m.sliding_window_layout,
+                               linear=m.linear_layout)
     raise ValueError(
-        f"{KEEPS[kind].format(layout=m.sliding_window_layout)}, which {why} "
+        f"{keeps}, which {why} "
         "does not carry yet. Serve this model on one chip with --kv_dtype "
         "bf16 and --spec_k 0.")
 
@@ -239,7 +274,8 @@ class PagedKVPool:
     def __init__(self, cfg, num_pages: int, page_size: int, dtype=None,
                  mesh: Optional[Mesh] = None, draft_cfg=None,
                  kv_dtype: str = "bf16", layers: Optional[int] = None,
-                 page_class: Optional[str] = None):
+                 page_class: Optional[str] = None,
+                 state: Optional[bool] = None):
         m = cfg.model
         # one page class of a patterned model's pool (``page_class`` names
         # it in the counters' ``class=`` label; ``layers``: how many of the
@@ -262,12 +298,24 @@ class PagedKVPool:
         # values; the lanes past ``latent_cache_width`` are zeros nobody
         # reads, 11% of the leaf).  Every other model: the K/V row
         self.latent = bool(m.mla)
-        # power retention (:class:`StatePool`): a "page" is a sequence's
-        # whole state, float32 whatever the activations are
-        self.state = bool(m.retention)
+        # :class:`StatePool`: a "page" is a sequence's whole state (power
+        # retention's, or a hybrid's linear layers'), float32 whatever the
+        # activations are
+        self.state = bool(m.retention) if state is None else state
         refuse_unserved(cfg, kv_dtype=kv_dtype, mesh=mesh,
                         draft=draft_cfg is not None)
-        if self.state:
+        if self.state and m.delta:
+            from megatron_llm_tpu.ops import gated_delta as gd_ops
+
+            self.latent = False
+            self.head_dim = m.linear_value_head_dim
+            kv = gd_ops.zero_state(
+                (layers, num_pages), m.linear_num_value_heads,
+                m.linear_key_head_dim, self.head_dim,
+                m.linear_conv_kernel_dim,
+                2 * m.linear_num_key_heads * m.linear_key_head_dim
+                + m.linear_num_value_heads * self.head_dim)
+        elif self.state:
             from megatron_llm_tpu.ops import retention as ret_ops
 
             self.head_dim = m.kv_channels
@@ -277,7 +325,7 @@ class PagedKVPool:
             # the logical view's head width: one head, the whole row
             self.head_dim = -(-m.latent_cache_width // 128) * 128
             kv = kv_quant.make_pool(
-                (m.depth, num_pages, page_size, 1, self.head_dim), kv_dtype,
+                (layers, num_pages, page_size, 1, self.head_dim), kv_dtype,
                 dtype)
         else:
             self.head_dim = m.kv_channels
@@ -653,10 +701,14 @@ class PagedKVPool:
 
 
 class StatePool(PagedKVPool):
-    """The pool of a model that keeps a recurrent STATE and no keys (power
-    retention, ops/retention.py): ``kv`` is ``ops/retention.State``, leaves
-    ``s [layers, slots + 1, nkv, d, D]`` and ``z [layers, slots + 1, nkv,
-    1, D]`` in float32, indexed by STATE SLOT.  The allocator is the page
+    """The pool of the layers that keep a recurrent STATE and no keys:
+    power retention's (ops/retention.py: ``kv`` is ``ops/retention.State``,
+    leaves ``s [layers, slots + 1, nkv, d, D]`` and ``z [layers, slots + 1,
+    nkv, 1, D]``) or a hybrid's gated-delta layers' (ops/gated_delta.py:
+    ``DeltaState``, ``s [layers, slots + 1, hv, dk, dv]`` and the conv's
+    tail ``conv [layers * (slots + 1), (width - 1) * channels]``), in float32,
+    indexed by STATE SLOT; ``layers``: how many of the model's layers keep
+    their state here (all of them, unless told).  The allocator is the page
     pool's, a slot standing where a page stood: a sequence holds exactly
     ONE from admission to its end, whatever its length, so with as many
     slots as the engine has decode slots nothing ever runs dry, nothing is
@@ -668,9 +720,12 @@ class StatePool(PagedKVPool):
     that starts there whatever the slot held (``ops/retention.tick_runs``:
     no launch of its own)."""
 
-    def __init__(self, cfg, slots: int, page_size: int):
-        assert cfg.model.retention
-        super().__init__(cfg, slots + 1, page_size, page_class=None)
+    def __init__(self, cfg, slots: int, page_size: int,
+                 layers: Optional[int] = None,
+                 page_class: Optional[str] = None):
+        assert cfg.model.retention or cfg.model.delta
+        super().__init__(cfg, slots + 1, page_size, layers=layers,
+                         page_class=page_class, state=True)
 
     @property
     def kv_statics(self) -> Tuple:

@@ -114,6 +114,15 @@ def _validate(payload: dict):
             return None, f"{flag} must be a boolean value"
         p[flag] = val
 
+    # false: generate exactly tokens_to_generate tokens, whatever is sampled
+    # (a rollout job's fixed lengths; an end-of-document id among random
+    # weights' greedy tokens is noise)
+    use_eod = payload.get("use_eod_token_for_early_termination", True)
+    if not isinstance(use_eod, bool):
+        return None, ("use_eod_token_for_early_termination must be a "
+                      "boolean value")
+    p["use_eod_token_for_early_termination"] = use_eod
+
     random_seed = payload.get("random_seed", -1)
     if not isinstance(random_seed, int):
         return None, "random_seed must be integer"
@@ -302,6 +311,8 @@ class MegatronServer:
                     add_BOS=params["add_BOS"],
                     stop_on_double_eol=params["stop_on_double_eol"],
                     stop_on_eol=params["stop_on_eol"],
+                    use_eod_token_for_early_termination=params[
+                        "use_eod_token_for_early_termination"],
                     random_seed=params["random_seed"],
                     **kw,
                 )
@@ -471,6 +482,8 @@ class MegatronServer:
                 add_BOS=params["add_BOS"],
                 stop_on_double_eol=params["stop_on_double_eol"],
                 stop_on_eol=params["stop_on_eol"],
+                use_eod_token_for_early_termination=params[
+                    "use_eod_token_for_early_termination"],
                 random_seed=params["random_seed"],
                 priority=params["priority"],
                 ttft_deadline_ms=params["ttft_deadline_ms"],

@@ -106,6 +106,30 @@ def validate_family(cfg: Config) -> Config:
                "brumby is dense with an untied head")
         _check(m.kv_channels % 8 == 0,
                "brumby's feature map tiles a head in blocks of 8")
+    elif name == "gigachat35":
+        _check(m.mla and m.attention_output_gate,
+               "gigachat35 requires attention_type 'mla' with its output "
+               "gate")
+        _check(m.delta and m.linear_layout and 0 in m.linear_layout
+               and 1 in m.linear_layout,
+               "gigachat35 mixes linear and latent-attention layers: give "
+               "linear_layout")
+        _check(m.post_sublayer_norms and m.zero_centered_gated_norm
+               and m.use_rms_norm,
+               "gigachat35 norms before and after each sublayer with a "
+               "sigmoid-gained RMSNorm")
+        _check(m.glu_activation == "swiglu" and m.swiglu_limit,
+               "gigachat35 uses SwiGLU under swiglu_limit")
+        _check(m.num_experts is not None and m.num_experts > 1
+               and m.moe_score_func == "sigmoid" and m.moe_selection_bias,
+               "gigachat35 routes by bias-corrected sigmoid scores")
+        _check(not m.use_bias and not m.parallel_attn
+               and not m.tie_embed_logits,
+               "gigachat35 is a sequential block without biases and an "
+               "untied head")
+        _check(m.position_embedding_type == "rotary"
+               and m.rope_scaling_type == "yarn",
+               "gigachat35 rotates under YaRN")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

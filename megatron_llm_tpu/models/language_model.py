@@ -63,8 +63,14 @@ def init_model_params(cfg, key: jax.Array) -> Params:
             * jax.random.normal(k_emb, (v, h), jnp.float32)
         },
         "layers": init_stacked_layers(cfg, k_layers),
-        "final_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias),
+        "final_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias,
+                                       gain=m.norm_gain),
     }
+    if m.linear_layout is not None:
+        # a hybrid's mixers, a stack a kind beside the uniform stack
+        from megatron_llm_tpu.models.transformer import init_mixers
+
+        params["mixers"] = init_mixers(cfg, jax.random.fold_in(k_layers, 2))
     if m.dense_prefix_layers:
         # the leading dense layers: a stack of their own, so that the
         # scanned stack keeps one parameter shape
@@ -105,6 +111,11 @@ def make_rope_cache(cfg) -> Optional[Tuple[jax.Array, jax.Array]]:
             low_freq_factor=m.rope_llama3_low_freq_factor,
             high_freq_factor=m.rope_llama3_high_freq_factor,
             original_max_position=m.rope_llama3_original_max_position,
+        ),
+        yarn_params=dict(
+            beta_fast=m.rope_yarn_beta_fast,
+            beta_slow=m.rope_yarn_beta_slow,
+            original_max_position=m.rope_yarn_original_max_position,
         ),
     )
 

@@ -78,7 +78,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from megatron_llm_tpu.ops.activations import GLU_BASE_ACTIVATIONS, get_mlp_activation
+from megatron_llm_tpu.ops.activations import get_mlp_activation, glu_product
 
 Params = Dict[str, Any]
 
@@ -519,8 +519,8 @@ def dropless_experts(cfg, experts, x: jax.Array, idx: jax.Array,
                                    dt, layer, half)
 
         if m.glu_activation is not None:
-            act = GLU_BASE_ACTIVATIONS[m.glu_activation]
-            inter = linear("fc1", rows, 0) * act(linear("fc1", rows, 1))
+            inter = glu_product(m.glu_activation, linear("fc1", rows, 0),
+                                linear("fc1", rows, 1), m.swiglu_limit)
         else:
             inter = get_mlp_activation(None, m.activation)(
                 linear("fc1", rows))
@@ -697,8 +697,8 @@ def moe_sublayer(cfg, p: Params, x: jax.Array,
     if "bias" in experts["fc1"]:
         y = y + experts["fc1"]["bias"].astype(dt)[None, :, None]
     if glu:
-        act = GLU_BASE_ACTIVATIONS[m.glu_activation]
-        inter = y[..., 0, :] * act(y[..., 1, :])
+        inter = glu_product(m.glu_activation, y[..., 0, :], y[..., 1, :],
+                            m.swiglu_limit)
     else:
         inter = get_mlp_activation(None, m.activation)(y)
     fc2, s2 = _expert_kernel(experts["fc2"], dt)

@@ -42,6 +42,23 @@ Power retention (``attention_type == 'retention'``,
 ``q_norm`` / ``k_norm`` (``{'scale': [d]}``, one RMSNorm a head, before
 RoPE) and ``gate`` (``{'kernel': [h, nkv], 'bias': [nkv]}``).
 
+The gated delta rule (a LINEAR layer, :func:`delta_sublayer`; the mixer
+of ``linear_layout``'s layers) has its own ``'attention'`` subtree, ``hk``
+key heads of ``dk`` and ``hv`` value heads of ``dv``:
+
+    {'qkvz':  {'kernel': [h, 2*hk*dk + 2*hv*dv]},      # q | k | v | z
+     'ba':    {'kernel': [h, 2*hv]},                   # beta | a
+     'conv':  {'kernel': [width, 2*hk*dk + hv*dv]},    # depthwise, causal
+     'a_log': [hv], 'dt_bias': [hv],
+     'o_norm': {'weight': [dv]},
+     'dense': {'kernel': [hv*dv, h]}}
+
+A HYBRID model (``linear_layout``) keeps the two kinds of mixer apart from
+the scanned stack, whose other leaves stay uniform: ``params["mixers"] =
+{'attention': [layers of that kind, ...], 'delta': [...]}``, each stacked
+over its own layers in order, and :func:`layer_stacks` hands them to the
+stack as its ``'attention'`` subtree.
+
 A model with ``dense_prefix_layers`` holds two stacks: ``dense_layers``
 (the prefix, dense MLP) and ``layers`` (the scanned expert layers).
 
@@ -65,7 +82,7 @@ import jax.numpy as jnp
 
 from megatron_llm_tpu.core import rng as rng_mod
 from megatron_llm_tpu.ops import attention as attn_ops
-from megatron_llm_tpu.ops.activations import GLU_BASE_ACTIVATIONS, get_mlp_activation
+from megatron_llm_tpu.ops.activations import get_mlp_activation, glu_product
 from megatron_llm_tpu.ops.norms import init_norm_params, norm
 from megatron_llm_tpu.ops.rope import apply_rotary_emb
 
@@ -97,13 +114,17 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
     out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
 
     k = jax.random.split(key, 7)
-    p: Params = {
-        "input_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias),
-        "attention": _init_mla_params(cfg, k[0], k[1], out_std) if m.mla else {
-            "qkv": {"kernel": _normal(k[0], (h, (n + 2 * nkv) * d), std)},
-            "dense": {"kernel": _normal(k[1], (n * d, h), out_std)},
-        },
-    }
+    new_norm = partial(init_norm_params, h, m.use_rms_norm, bias=m.norm_bias,
+                       gain=m.norm_gain)
+    p: Params = {"input_norm": new_norm()}
+    if dense_ffn and m.dense_prefix_linear:
+        p["attention"] = init_mixer_params(cfg, k[0], k[1], "delta")
+    elif not m.linear_layout or dense_ffn:
+        p["attention"] = init_mixer_params(cfg, k[0], k[1], "attention")
+    # else: a hybrid's scanned stack, whose mixers are stacks of their own
+    # (``params["mixers"]``, :func:`init_mixers`)
+    if m.post_sublayer_norms:
+        p["attn_out_norm"], p["mlp_out_norm"] = new_norm(), new_norm()
     if m.retention:
         p["attention"].update(_init_retention_params(cfg, k[4]))
     if m.num_experts is not None and not dense_ffn:
@@ -122,9 +143,9 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
             "fc2": {"kernel": _normal(k[3], (ffn, h), out_std)},
         }
     if not m.parallel_attn:
-        p["post_norm"] = init_norm_params(h, m.use_rms_norm, bias=m.norm_bias)
+        p["post_norm"] = new_norm()
     if m.parallel_layernorm:
-        p["mlp_norm"] = init_norm_params(h, m.use_rms_norm, bias=m.norm_bias)
+        p["mlp_norm"] = new_norm()
     if cross_attention:
         # T5 decoder inter-attention (reference t5_model.py via
         # ParallelAttention attn_type=cross, transformer.py:280): separate Q
@@ -152,19 +173,108 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
     return p
 
 
+def init_mixer_params(cfg, k_in: jax.Array, k_out: jax.Array,
+                      mixer: str) -> Params:
+    """One layer's ``'attention'`` subtree: the model's ``attention_type``
+    (``mixer`` 'attention') or the gated delta rule's ('delta')."""
+    m = cfg.model
+    h, std = m.hidden_size, m.init_method_std
+    out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
+    if mixer == "delta":
+        return _init_delta_params(cfg, k_in, k_out, out_std)
+    if m.mla:
+        return _init_mla_params(cfg, k_in, k_out, out_std)
+    n, nkv, d = m.num_attention_heads, m.num_attention_heads_kv, m.kv_channels
+    return {
+        "qkv": {"kernel": _normal(k_in, (h, (n + 2 * nkv) * d), std)},
+        "dense": {"kernel": _normal(k_out, (n * d, h), out_std)},
+    }
+
+
+def init_mixers(cfg, key: jax.Array) -> Params:
+    """A hybrid's mixers of the scanned stack, a stack a kind over that
+    kind's layers in order: ``{'attention': [...], 'delta': [...]}``.  How
+    many follows ``scanned_periods`` (Config.finalize), not ``num_layers``:
+    the count is the model's, whatever a caller that builds the uniform
+    stack a layer at a time makes of a copy's ``num_layers``."""
+    m = cfg.model
+    out = {}
+    for j, mixer in enumerate(("attention", "delta")):
+        count = m.scanned_periods * sum(
+            1 for kind in layer_kinds(cfg) if kind.mixer == mixer)
+        out[mixer] = jax.vmap(
+            lambda kk, mixer=mixer: init_mixer_params(
+                cfg, *jax.random.split(kk), mixer))(
+            jax.random.split(jax.random.fold_in(key, j), count))
+    return out
+
+
+# under the bias alone a linear layer's state loses per token what leaves
+# a key GATE_HORIZON tokens back between GATE_KEEPS of its weight
+DELTA_A_RANGE = (1.0, 16.0)
+# the standard deviation at which q, k and v leave the conv and enter SiLU
+DELTA_CONV_OUT_STD = 0.1
+
+
+def _init_delta_params(cfg, k_in: jax.Array, k_out: jax.Array,
+                       out_std: float) -> Params:
+    """``a_log`` and ``dt_bias`` are DRAWN (as the retention gate's bias
+    is): with plain draws a state forgets within ~20 tokens and no fault
+    in carrying it across ticks would show.
+
+    The conv's filter is DRAWN too, so that q, k and v enter SiLU at a
+    standard deviation of ``DELTA_CONV_OUT_STD``, where SiLU is nearly
+    ``x / 2``.  At a filter of ``1 / sqrt(width)`` they enter at ~1.7 and
+    leave with SiLU's positive mean on every channel: any key then sides
+    with any query, a long state reads back the plain average of its
+    values, and the layer hands EVERY token of every sequence nearly the
+    same vector (a third of the energy of what a router four layers on
+    reads, at the 432B widths).  A router so fed sends a tick's rows to
+    the same experts, which no model whose load was balanced does, and
+    which experts they are follows the seed."""
+    m = cfg.model
+    h, std = m.hidden_size, m.init_method_std
+    hk, hv = m.linear_num_key_heads, m.linear_num_value_heads
+    dk, dv = m.linear_key_head_dim, m.linear_value_head_dim
+    qk, vz = hk * dk, hv * dv
+    k1, k2, k3, k4, k5 = jax.random.split(k_in, 5)
+    lo, hi = (-jnp.log(keep) / GATE_HORIZON for keep in GATE_KEEPS[::-1])
+    rate = jnp.exp(jax.random.uniform(k4, (hv,), jnp.float32,
+                                      jnp.log(lo), jnp.log(hi)))
+    a = jax.random.uniform(k5, (hv,), jnp.float32, *DELTA_A_RANGE)
+    return {
+        "qkvz": {"kernel": _normal(k1, (h, 2 * qk + 2 * vz), std)},
+        "ba": {"kernel": _normal(k2, (h, 2 * hv), std)},
+        # a depthwise filter of ``width`` taps over a normed row through
+        # ``qkvz``, which has a deviation of std * sqrt(h) a channel
+        "conv": {"kernel": _normal(
+            k3, (m.linear_conv_kernel_dim, 2 * qk + vz),
+            DELTA_CONV_OUT_STD
+            / (std * (h * m.linear_conv_kernel_dim) ** 0.5))},
+        "a_log": jnp.log(a),
+        "dt_bias": jnp.log(jnp.expm1(rate / a)),
+        "o_norm": {"weight": jnp.zeros((dv,), jnp.float32)},
+        "dense": {"kernel": _normal(k_out, (vz, h), out_std)},
+    }
+
+
 def _init_mla_params(cfg, k_down: jax.Array, k_out: jax.Array,
                      out_std: float) -> Params:
     m = cfg.model
     h, n, std = m.hidden_size, m.num_attention_heads, m.init_method_std
     nope, rope, v = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     kq, kkv, kqu, kkvu = jax.random.split(k_down, 4)
+    gate = {"g_proj": {"kernel": _normal(jax.random.fold_in(k_down, 4),
+                                         (h, n * v), std)}} \
+        if m.attention_output_gate else {}
     return {
+        **gate,
         "q_down": {"kernel": _normal(kq, (h, m.q_lora_rank), std)},
-        "q_norm": init_norm_params(m.q_lora_rank, True),
+        "q_norm": init_norm_params(m.q_lora_rank, True, gain=m.norm_gain),
         "q_up": {"kernel": _normal(kqu, (m.q_lora_rank, n * (nope + rope)),
                                    std)},
         "kv_down": {"kernel": _normal(kkv, (h, m.kv_lora_rank + rope), std)},
-        "kv_norm": init_norm_params(m.kv_lora_rank, True),
+        "kv_norm": init_norm_params(m.kv_lora_rank, True, gain=m.norm_gain),
         # per head [k_nope | v]: the up-projection the absorbed form folds
         # into the query (its first nope columns) and the output (the rest)
         "kv_up": {"kernel": _normal(kkvu, (m.kv_lora_rank, n, nope + v),
@@ -230,7 +340,8 @@ class StackedLinear(NamedTuple):
 # the projections every sublayer runs through ``_linear``: the leaves the
 # serving tick reads from the stack (the others' kernels -- a router, a
 # retention gate, MLA's ``kv_up`` -- are read by name and ride the scan)
-TICK_LINEARS = ("qkv", "dense", "fc1", "fc2", "q_down", "q_up", "kv_down")
+TICK_LINEARS = ("qkv", "dense", "fc1", "fc2", "q_down", "q_up", "kv_down",
+                "g_proj", "qkvz")
 
 
 def _is_linear(node) -> bool:
@@ -380,10 +491,13 @@ def split_qkv(
 
 class LayerKind(NamedTuple):
     """What a kind of layer does in its attention: ``window`` keys a causal
-    query may see (None = all of them) and whether q and k ``rotate``."""
+    query may see (None = all of them), whether q and k ``rotate``, and its
+    ``mixer``: 'attention' (the model's ``attention_type``) or 'delta' (a
+    linear layer: the gated delta rule on a recurrent state)."""
 
     window: Optional[int]
     rotate: bool
+    mixer: str = "attention"
 
     @property
     def scope(self) -> str:
@@ -393,15 +507,36 @@ class LayerKind(NamedTuple):
 
 
 def layer_kinds(cfg) -> Tuple[LayerKind, ...]:
-    """One period of the layer pattern: layer ``l`` is of kind
-    ``layer_kinds(cfg)[l % period]``.  A uniform model has one kind: its
-    window if it has one, rotation wherever it is handed a rope table."""
+    """One period of the layer pattern: layer ``l`` of the scanned stack is
+    of kind ``layer_kinds(cfg)[l % period]``.  A uniform model has one
+    kind: its window if it has one, rotation wherever it is handed a rope
+    table."""
     m = cfg.model
+    if m.linear_layout is not None:
+        return tuple(LayerKind(None, True, "delta" if lin else "attention")
+                     for lin in m.linear_layout)
     if m.sliding_window_layout is None:
         return (LayerKind(m.sliding_window_size, True),)
     return tuple(
         LayerKind(m.sliding_window_size if w else None, bool(r))
         for w, r in zip(m.sliding_window_layout, m.rope_layout))
+
+
+def stack_kinds(cfg, first_layer: int) -> Tuple[LayerKind, ...]:
+    """One period of the stack that starts at the model's layer
+    ``first_layer``: the dense prefix is its own stack with its own kinds
+    (``dense_prefix_linear``: linear layers with a dense MLP), the scanned
+    stack has :func:`layer_kinds`."""
+    if _linear_prefix(cfg, first_layer):
+        return (LayerKind(None, True, "delta"),)
+    return layer_kinds(cfg)
+
+
+def _linear_prefix(cfg, first_layer: int) -> bool:
+    """Whether the stack that starts at ``first_layer`` is a hybrid's dense
+    prefix of linear layers."""
+    m = cfg.model
+    return m.dense_prefix_linear and first_layer < m.dense_prefix_layers
 
 
 class PoolClass(NamedTuple):
@@ -414,6 +549,7 @@ class PoolClass(NamedTuple):
     window: Optional[int]
     places: Tuple[int, ...]   # places in the period, in order
     state: bool = False
+    prefix: int = 0           # layers of the dense prefix, before those
 
     @property
     def name(self) -> str:
@@ -421,13 +557,28 @@ class PoolClass(NamedTuple):
             return "state"
         return "full" if self.window is None else "window"
 
+    def layers(self, cfg) -> int:
+        """Layers of the model that keep their memory in this class."""
+        m = cfg.model
+        return self.prefix + m.num_layers // m.layer_period * len(self.places)
+
 
 def pool_classes(cfg) -> Tuple[PoolClass, ...]:
     """The page classes of the serving pool: the distinct cache needs of
     :func:`layer_kinds`, the class that keeps every key first.  A model
     whose layers all need the same keys has ONE class, which keeps every
-    page a sequence wrote (a uniform window's pool does not slide)."""
+    page a sequence wrote (a uniform window's pool does not slide).  A
+    hybrid has a page class (its attention layers' latent rows) AND a
+    state class (its linear layers', the dense prefix's first)."""
     kinds = layer_kinds(cfg)
+    m = cfg.model
+    if m.delta:
+        places = lambda mixer: tuple(                  # noqa: E731
+            j for j, k in enumerate(kinds) if k.mixer == mixer)
+        lin = m.dense_prefix_layers * m.dense_prefix_linear
+        return (PoolClass(None, places("attention"),
+                          prefix=m.dense_prefix_layers - lin),
+                PoolClass(None, places("delta"), True, prefix=lin))
     windows = sorted({k.window for k in kinds},
                      key=lambda w: (w is not None, w))
     if len(windows) == 1:
@@ -671,6 +822,11 @@ def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
     eps = m.layernorm_epsilon
     linear = _linear_impl(cfg)
     scale = 1.0 / ((nope + rd) ** 0.5)
+    if m.rope_scaling_type == "yarn":
+        from megatron_llm_tpu.ops.rope import yarn_mscale
+
+        scale *= yarn_mscale(m.rope_scaling_factor,
+                             m.rope_yarn_mscale_all_dim) ** 2
 
     with jax.named_scope("mla"):
         c_q = norm(linear(p["q_down"], x), p["q_norm"], eps, True)
@@ -706,9 +862,14 @@ def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
                 scale=scale, use_flash=cfg.training.use_flash_attn)
     from jax.ad_checkpoint import checkpoint_name
 
-    ctx = checkpoint_name(ctx, "attn_out")
-    out = apply_row_parallel(cfg, p["dense"], ctx.reshape(b, s, n * vd),
-                             linear)
+    ctx = checkpoint_name(ctx, "attn_out").reshape(b, s, n * vd)
+    if m.attention_output_gate:
+        # elementwise, a value a head and channel, read from the layer's
+        # normed input (the G1 form of arXiv:2505.06708)
+        with jax.named_scope("mla"):
+            ctx = ctx * jax.nn.sigmoid(
+                linear(p["g_proj"], x).astype(jnp.float32)).astype(ctx.dtype)
+    out = apply_row_parallel(cfg, p["dense"], ctx, linear)
     return out, new_pool
 
 
@@ -847,6 +1008,110 @@ def _retention_sweep(cfg):
     return retention_sweep if refusal is None else ret.retention_tick
 
 
+@jax.named_scope("attention")
+def delta_sublayer(cfg, p: Params, x: jax.Array, kv_cache=None, paged=None):
+    """The gated delta rule (ops/gated_delta.py) as a layer's mixer:
+    ``[q | k | v] = SiLU(conv(x W_qkv))`` (a causal depthwise convolution),
+    ``z = x W_z``, ``beta = sigmoid(x W_b)``, ``g = -exp(a_log) *
+    softplus(x W_a + dt_bias)`` in float32; q and k L2-normalised a head,
+    q times ``dk^-0.5``; the rule; then a head's output normed and gated
+    by ``z`` (``ops/norms.gated_head_norm``) and projected out.
+
+    * no cache (the dense forward): the convolution from zeros and the
+      chunked form from a zero state, differentiable;
+    * ``paged`` (every row of the engine's tick): ``kv_cache`` is a
+      :class:`LayerPool` over the STATE pool (``ops/gated_delta.DeltaState``
+      leaves ``[layers, slots + 1, ...]``, float32), a row's table holds its
+      state SLOT (0: a dead row), and the tick's rows sweep the pool once:
+      a run of rows of one sequence reads its slot's state and conv tail
+      once and writes them once (the Pallas kernel ``delta_sweep`` on a TPU
+      target, else ``delta_tick``).
+
+    Returns (output [b, s, h], the updated pool or None)."""
+    from megatron_llm_tpu.ops import gated_delta as gd
+    from megatron_llm_tpu.ops.norms import gated_head_norm
+    from megatron_llm_tpu.parallel.tp import apply_row_parallel
+
+    m = cfg.model
+    b, s, _ = x.shape
+    hk, hv = m.linear_num_key_heads, m.linear_num_value_heads
+    dk, dv = m.linear_key_head_dim, m.linear_value_head_dim
+    qk, vz = hk * dk, hv * dv
+    linear = _linear_impl(cfg)
+    f32 = jnp.float32
+    with jax.named_scope("delta"):
+        qkvz = linear(p["qkvz"], x)
+        mixed, z = qkvz[..., :2 * qk + vz], qkvz[..., 2 * qk + vz:]
+        ba = x.astype(f32) @ p["ba"]["kernel"].astype(f32)     # [b, s, 2hv]
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., hv:] + p["dt_bias"].astype(f32))
+        w_conv = p["conv"]["kernel"]
+        new_pool = None
+        if paged is not None:
+            assert s == 1 and paged.table_index is not None, (
+                "a linear layer is served by the ragged tick alone: one "
+                "row a token, its state slot in its table")
+            pool, layer = kv_cache
+            slots = paged.block_tables[paged.table_index, 0]
+            mixed, tails = gd.conv_tick(
+                mixed[:, 0], w_conv, pool.conv, slots, paged.positions,
+                layer * pool.s.shape[1])
+            mixed = mixed[:, None]
+        else:
+            assert kv_cache is None, (
+                "a linear layer decodes through the engine's state pool "
+                "only: the dense incremental cache holds K/V heads")
+            mixed = gd.causal_conv(mixed, w_conv)
+        mixed = jax.nn.silu(mixed)
+        q = gd.l2_normalize(mixed[..., :qk].reshape(b, s, hk, dk)) \
+            * dk ** -0.5
+        k = gd.l2_normalize(mixed[..., qk:2 * qk].reshape(b, s, hk, dk))
+        v = mixed[..., 2 * qk:].reshape(b, s, hv, dv)
+        if paged is not None:
+            o, states = _delta_sweep(cfg)(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], pool.s,
+                slots, paged.positions, layer)
+            o, new_pool = o[:, None], gd.DeltaState(states, tails)
+        else:
+            o = gd.delta_chunked(q, k, v, g, beta)
+        o = gated_head_norm(o, z.reshape(b, s, hv, dv), p["o_norm"]["weight"],
+                            m.layernorm_epsilon)
+    from jax.ad_checkpoint import checkpoint_name
+
+    o = checkpoint_name(o.astype(x.dtype), "attn_out")
+    with jax.named_scope("delta"):
+        out = apply_row_parallel(cfg, p["dense"], o.reshape(b, s, vz), linear)
+    return out, new_pool
+
+
+def _delta_sweep(cfg):
+    """The tick's state sweep of a linear layer: the Pallas kernel where
+    the program is compiled for a TPU and a head's state is whole (8, 128)
+    tiles, else the ``jnp`` form; said once while tracing, as the attention
+    paths are."""
+    from megatron_llm_tpu.core.parallel_state import target_platform
+    from megatron_llm_tpu.ops import gated_delta as gd
+
+    m = cfg.model
+    target = target_platform()
+    refusal = None
+    if not cfg.training.use_flash_attn:
+        refusal = "use_flash_attn is off"
+    elif target != "tpu":
+        refusal = f"target platform is {target}"
+    elif m.linear_key_head_dim % 8 or m.linear_value_head_dim % 128:
+        refusal = (f"a head's state [{m.linear_key_head_dim}, "
+                   f"{m.linear_value_head_dim}] is not whole (8, 128) tiles")
+    attn_ops.announce_path("delta_tick", "jnp" if refusal else "pallas",
+                           refusal or "")
+    if refusal is not None:
+        return gd.delta_tick
+    from megatron_llm_tpu.ops.pallas.gated_delta import delta_sweep
+
+    return delta_sweep
+
+
 def cross_attention_sublayer(
     cfg,
     p: Params,
@@ -909,10 +1174,10 @@ def mlp_sublayer(cfg, p: Params, x: jax.Array) -> jax.Array:
     m = cfg.model
     linear = _linear_impl(cfg)
     if m.glu_activation is not None:
-        act = GLU_BASE_ACTIVATIONS[m.glu_activation]
         # [..., 2, ffn] (both impls restore the axis)
         y = apply_column_parallel(cfg, p["fc1"], x, linear)
-        gated = y[..., 0, :] * act(y[..., 1, :])
+        gated = glu_product(m.glu_activation, y[..., 0, :], y[..., 1, :],
+                            m.swiglu_limit)
         return apply_row_parallel(cfg, p["fc2"], gated, linear)
     act = get_mlp_activation(None, m.activation)
     h = act(apply_column_parallel(cfg, p["fc1"], x, linear))
@@ -963,7 +1228,15 @@ def block_forward(
     _sp = sp_constraint if sp_constraint is not None else (lambda t: t)
 
     ln1 = norm(hidden, p["input_norm"], eps, m.use_rms_norm)
-    if m.mla:
+    if kind is not None and kind.mixer == "delta":
+        assert token_idx is None and attn_bias is None and (
+            segment_ids is None) and (
+            deterministic or not m.attention_dropout), (
+            "a linear layer: no cp token order, bias, packed segments or "
+            "attention dropout")
+        attn_out, new_cache = delta_sublayer(
+            cfg, p["attention"], ln1, kv_cache=kv_cache, paged=paged)
+    elif m.mla:
         assert token_idx is None and attn_bias is None and (
             deterministic or not m.attention_dropout), (
             "latent attention: no cp token order, bias or attention dropout")
@@ -997,6 +1270,8 @@ def block_forward(
             + rng_mod.dropout(dk_h2, rate, mlp_out, deterministic or dk_h2 is None)
         out = _sp(out)
     else:
+        if m.post_sublayer_norms:
+            attn_out = norm(attn_out, p["attn_out_norm"], eps, m.use_rms_norm)
         resid = hidden + rng_mod.dropout(dk_h1, rate, attn_out, deterministic or dk_h1 is None)
         resid = _sp(resid)
         if "cross_attention" in p:
@@ -1013,6 +1288,8 @@ def block_forward(
             resid = _sp(resid)
         ln2 = norm(resid, p["post_norm"], eps, m.use_rms_norm)
         mlp_out, aux = ffn_sublayer(cfg, p, ln2, layer_input=ln1)
+        if m.post_sublayer_norms:
+            mlp_out = norm(mlp_out, p["mlp_out_norm"], eps, m.use_rms_norm)
         out = resid + rng_mod.dropout(dk_h2, rate, mlp_out, deterministic or dk_h2 is None)
         out = _sp(out)
     return out, new_cache, aux
@@ -1099,6 +1376,25 @@ def transformer_forward(
     closes over, by the layer's index: the program holds no copy of a
     layer's weights (``tools/tick_hlo_copies.py`` prints what it holds).
     """
+    # the pattern: layer l of this stack is of kind kinds[l % period].  A
+    # stack handed to this function is whole periods from a period's first
+    # layer on (a pipeline stage's slice too: Config.finalize), so a
+    # layer's place in its period is its place in the stack, whatever
+    # layer_offset is
+    kinds = stack_kinds(cfg, layer_offset)
+    period = len(kinds)
+    # a hybrid's mixers: a stack a kind (``layer_stacks``), each over its
+    # own layers; place j of a period reads layer ``rank[j]`` of its kind's
+    # ``per[mixer]`` layers a period
+    mixers = None
+    rank = [sum(k.mixer == kind.mixer for k in kinds[:j])
+            for j, kind in enumerate(kinds)]
+    per = {kind.mixer: sum(k.mixer == kind.mixer for k in kinds)
+           for kind in kinds}
+    if len(per) > 1:
+        mixers = stacked_layers["attention"]
+        stacked_layers = {k: v for k, v in stacked_layers.items()
+                          if k != "attention"}
     num_layers = jax.tree_util.tree_leaves(stacked_layers)[0].shape[0]
     rates = _lima_rates(cfg, cfg.model.depth)
     in_carry = paged is not None and kv_caches is not None
@@ -1133,29 +1429,40 @@ def transformer_forward(
     # hands ``p["kernel"]`` to a shard_map of its own)
     from megatron_llm_tpu.parallel import overlap as tp_overlap_mod
 
-    linears = None
+    linears = mixer_linears = None
     if (paged is not None and _linear_impl(cfg) is _linear
             and tp_overlap_mod.current() is None):
         linears, stacked_layers = _take_linears(stacked_layers)
+        if mixers is not None:
+            taken = {mx: _take_linears(tree) for mx, tree in mixers.items()}
+            mixer_linears = {mx: t[0] for mx, t in taken.items()}
+            mixers = {mx: t[1] for mx, t in taken.items()}
 
-    # a layer's page class, its rank among the class's layers of a period
-    # and how many those are, by its place in the period
+    # a layer's page class, its rank among the class's layers of a period,
+    # how many those are and how many of the class's layers come before
+    # this stack, by its place in the period
     # (told by the TABLES: a quantized pool is a tuple of its own)
     classed = in_carry and paged is not None and isinstance(
         paged.block_tables, tuple)
-    class_at = {j: (c, cls.places.index(j), len(cls.places))
-                for c, cls in enumerate(pool_classes(cfg))
-                for j in cls.places} if classed else None
+    class_at = None
+    if classed and _linear_prefix(cfg, layer_offset):
+        # the first layers of the state class
+        c = next(i for i, cls in enumerate(pool_classes(cfg)) if cls.state)
+        class_at = {0: (c, 0, 1, 0)}
+    elif classed:
+        class_at = {j: (c, cls.places.index(j), len(cls.places), cls.prefix)
+                    for c, cls in enumerate(pool_classes(cfg))
+                    for j in cls.places}
 
     def one_layer(carry, xs, kind, place=0):
         carry_hidden, pool = carry
         layer_params, layer_idx, cache = xs
         layer_paged = paged
         if classed:
-            c, rank, per_period = class_at[place]
+            c, c_rank, per_period, before = class_at[place]
             cache = LayerPool(
-                pool[c], (layer_idx - pool_first_layer)
-                // len(class_at) * per_period + rank)
+                pool[c], before + (layer_idx - layer_offset)
+                // len(class_at) * per_period + c_rank)
             layer_paged = paged._replace(block_tables=paged.block_tables[c])
         elif in_carry:
             cache = LayerPool(pool, layer_idx - pool_first_layer)
@@ -1166,6 +1473,11 @@ def transformer_forward(
         if linears:
             layer_params = _put_linears(layer_params, linears,
                                         layer_idx - layer_offset)
+        if mixer_linears:
+            layer_params = {**layer_params, "attention": _put_linears(
+                layer_params["attention"], mixer_linears[kind.mixer],
+                (layer_idx - layer_offset) // period * per[kind.mixer]
+                + rank[place])}
         dk = None if dropout_key is None else rng_mod.fold_layer(dropout_key, layer_idx)
         rate = rates[layer_idx]
         out, new_cache, aux = block_forward(
@@ -1186,12 +1498,6 @@ def transformer_forward(
             pool, new_cache = new_cache, None
         return (out, pool), (new_cache, aux)
 
-    # the pattern: layer l is of kind kinds[l % period].  A stack handed to
-    # this function is whole periods from a period's first layer on (a
-    # pipeline stage's slice too: Config.finalize), so a layer's place in
-    # its period is its place in the stack, whatever layer_offset is
-    kinds = layer_kinds(cfg)
-    period = len(kinds)
     assert num_layers % period == 0, (
         f"a stack of {num_layers} layers is not whole periods of {period}")
     layer_ids = jnp.arange(num_layers) + layer_offset
@@ -1217,16 +1523,22 @@ def transformer_forward(
             # a layer's kind is static there
             def body(carry, xs):
                 outs = []
+                *common, mixed = xs
                 for j, layer_body in enumerate(bodies):
-                    carry, out = layer_body(
-                        carry, jax.tree.map(lambda a: a[j], xs))
+                    params_j, ids_j, cache_j = jax.tree.map(
+                        lambda a: a[j], tuple(common))
+                    if mixed is not None:
+                        params_j = {**params_j, "attention": jax.tree.map(
+                            lambda a: a[rank[j]], mixed[kinds[j].mixer])}
+                    carry, out = layer_body(carry, (params_j, ids_j, cache_j))
                     outs.append(out)
                 return carry, jax.tree.map(lambda *o: jnp.stack(o), *outs)
 
+            periods = num_layers // period
             xs = jax.tree.map(
-                lambda a: a.reshape(num_layers // period, period,
+                lambda a: a.reshape(periods, a.shape[0] // periods,
                                     *a.shape[1:]),
-                (stacked_layers, layer_ids, kv_caches))
+                (stacked_layers, layer_ids, kv_caches, mixers))
         (hidden, pool), (new_caches, aux_stack) = jax.lax.scan(
             body, (hidden, pool), xs)
         if period > 1:
@@ -1241,6 +1553,11 @@ def transformer_forward(
         aux_total = zero_aux()
         for i in range(num_layers):
             layer_p = jax.tree.map(lambda a: a[i], stacked_layers)
+            if mixers is not None:
+                mx = kinds[i % period].mixer
+                at = i // period * per[mx] + rank[i % period]
+                layer_p = {**layer_p, "attention": jax.tree.map(
+                    lambda a: a[at], mixers[mx])}
             cache = None if kv_caches is None else jax.tree.map(lambda a: a[i], kv_caches)
             (hidden, pool), (nc, aux) = one_layer(
                 (hidden, pool), (layer_p, layer_ids[i], cache),
@@ -1263,5 +1580,9 @@ def layer_stacks(cfg, params: Params):
     stacks = []
     if "dense_layers" in params:
         stacks.append((params["dense_layers"], 0))
-    stacks.append((params["layers"], cfg.model.dense_prefix_layers))
+    layers = params["layers"]
+    if "mixers" in params:
+        # a hybrid: the scanned stack's mixers, a stack a kind
+        layers = {**layers, "attention": params["mixers"]}
+    stacks.append((layers, cfg.model.dense_prefix_layers))
     return stacks

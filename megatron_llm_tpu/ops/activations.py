@@ -75,6 +75,17 @@ GLU_BASE_ACTIVATIONS: Dict[str, Callable] = {
 }
 
 
+def glu_product(name: str, value: jax.Array, gate: jax.Array,
+                limit: Optional[float] = None) -> jax.Array:
+    """``value * act(gate)`` of the ``[.., 2, ffn]`` fc1 layout; with
+    ``limit`` (gpt-oss's clamp, GigaChat3.5's ``swiglu_limit``) the gate is
+    cut from above and the value on both sides first."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        value = jnp.clip(value, -limit, limit)
+    return value * GLU_BASE_ACTIVATIONS[name](gate)
+
+
 def get_mlp_activation(glu_activation: Optional[str], activation: str = "gelu") -> Callable:
     """Resolve the MLP activation; GLU variants expect a doubled fc1 output."""
     if glu_activation is not None:

@@ -41,16 +41,41 @@ def layer_norm(
     return out.astype(dtype)
 
 
+def sigmoid2_gain(w: jax.Array) -> jax.Array:
+    """``2 sigmoid(w)``: the gain of a ZeroCenteredGatedNorm (GigaChat3.5's
+    ``norm_type`` with ``layernorm_gating_weight`` 2), worth 1 at ``w = 0``
+    and never negative or above 2."""
+    return 2.0 * jax.nn.sigmoid(w.astype(jnp.float32))
+
+
+def gated_head_norm(x: jax.Array, z: jax.Array, weight: jax.Array,
+                    eps: float = 1e-6, gate_scale: float = 2.0) -> jax.Array:
+    """The gated-delta layers' output norm a head
+    (``gated_rmsnorm_sigmoid_zero_centered``): ``x / rms(x) * (1 + w) *
+    gate_scale * sigmoid(z)``, float32 in and out."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    out = xf * jax.lax.rsqrt(var + eps) * (1.0 + weight.astype(jnp.float32))
+    return out * (gate_scale * jax.nn.sigmoid(z.astype(jnp.float32)))
+
+
 def norm(x, params: dict, eps: float, use_rms: bool) -> jax.Array:
-    """Dispatch on norm family given a params dict {'scale': ..., 'bias': ...?}."""
+    """Dispatch on norm family given a params dict {'scale': ..., 'bias': ...?}
+    (or ``{'gate': w}``: an RMSNorm whose gain is :func:`sigmoid2_gain`)."""
+    if "gate" in params:
+        return rms_norm(x, sigmoid2_gain(params["gate"]), eps)
     if use_rms:
         return rms_norm(x, params["scale"], eps)
     return layer_norm(x, params["scale"], params.get("bias"), eps)
 
 
 def init_norm_params(hidden_size: int, use_rms: bool, dtype=jnp.float32,
-                     bias: bool = True) -> dict:
-    """``bias``: a LayerNorm's additive bias (``norm_bias``; RMSNorm has none)."""
+                     bias: bool = True, gain: str = "scale") -> dict:
+    """``bias``: a LayerNorm's additive bias (``norm_bias``; RMSNorm has none).
+    ``gain`` 'sigmoid2': the leaf is ``gate``, zeros (a gain of 1)."""
+    if gain == "sigmoid2":
+        assert use_rms
+        return {"gate": jnp.zeros((hidden_size,), dtype=dtype)}
     p = {"scale": jnp.ones((hidden_size,), dtype=dtype)}
     if not use_rms and bias:
         p["bias"] = jnp.zeros((hidden_size,), dtype=dtype)

@@ -13,6 +13,8 @@ fp32 and applied in fp32 for accuracy, output cast back to input dtype.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -43,6 +45,42 @@ def llama3_scale_freqs(
     return jnp.where(wavelen < high_wavelen, freqs, out)
 
 
+def yarn_scale_freqs(
+    freqs: jax.Array,
+    factor: float,
+    theta: float,
+    beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
+    original_max_position: int = 4096,
+) -> jax.Array:
+    """YaRN (arXiv:2309.00071; HF ``rope_type: "yarn"``, the DeepSeek-V3
+    modelling code's form): a frequency that turns more than ``beta_fast``
+    times over the original context is kept (extrapolation), one that
+    turns fewer than ``beta_slow`` times is divided by ``factor``
+    (interpolation), and the pairs between are blended linearly by their
+    index.  The attention's ``mscale`` is the caller's."""
+    half = freqs.shape[0]
+    dim = 2 * half
+
+    def pair_of(turns):     # the pair whose wavelength makes ``turns`` turns
+        return dim * math.log(original_max_position / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 x mscale x ln(factor) + 1`` (1 where nothing is scaled)."""
+    return 1.0 if factor <= 1.0 or not mscale else (
+        0.1 * mscale * math.log(factor) + 1.0)
+
+
 def precompute_freqs(
     dim: int,
     max_len: int,
@@ -51,6 +89,7 @@ def precompute_freqs(
     scaling_type: str = "linear",
     llama3_params: dict | None = None,
     dtype=jnp.float32,
+    yarn_params: dict | None = None,
 ):
     """Return (cos, sin), each [max_len, dim//2], fp32.
 
@@ -59,16 +98,20 @@ def precompute_freqs(
     instead remaps the frequencies per :func:`llama3_scale_freqs`
     (positions undivided), matching HF Llama-3.1+ checkpoints.
     """
-    if scaling_type not in ("linear", "llama3"):
+    if scaling_type not in ("linear", "llama3", "yarn"):
         # fail-loudly posture (same as hf_to_native's rope_scaling check):
         # an unknown type silently falling back to linear would produce
         # wrong frequencies with no diagnostic
         raise ValueError(f"unknown rope scaling_type {scaling_type!r}; "
-                         "expected 'linear' or 'llama3'")
+                         "expected 'linear', 'llama3' or 'yarn'")
     freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     if scaling_type == "llama3" and scaling_factor != 1.0:
         freqs = llama3_scale_freqs(freqs, scaling_factor,
                                    **(llama3_params or {}))
+        t = jnp.arange(max_len, dtype=jnp.float32)
+    elif scaling_type == "yarn" and scaling_factor != 1.0:
+        freqs = yarn_scale_freqs(freqs, scaling_factor, theta,
+                                 **(yarn_params or {}))
         t = jnp.arange(max_len, dtype=jnp.float32)
     else:
         t = jnp.arange(max_len, dtype=jnp.float32) / scaling_factor

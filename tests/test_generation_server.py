@@ -58,6 +58,13 @@ def test_validation_messages():
         "When doing beam_search, batch size must be 1"
     params, err = _validate({"prompts": ["a"], "tokens_to_generate": 8})
     assert err is None and params["tokens_to_generate"] == 8
+    assert params["use_eod_token_for_early_termination"] is True
+    params, err = _validate({"prompts": ["a"],
+                             "use_eod_token_for_early_termination": False})
+    assert err is None and not params["use_eod_token_for_early_termination"]
+    assert _validate({"prompts": ["a"],
+                      "use_eod_token_for_early_termination": 0})[1] == \
+        "use_eod_token_for_early_termination must be a boolean value"
 
 
 def test_server_generate_roundtrip(server):

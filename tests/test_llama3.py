@@ -90,7 +90,7 @@ def test_precompute_freqs_llama3_vs_linear():
 
 def test_unknown_scaling_type_fails_loudly():
     with pytest.raises(ValueError, match="scaling_type"):
-        precompute_freqs(64, 128, scaling_factor=8.0, scaling_type="yarn")
+        precompute_freqs(64, 128, scaling_factor=8.0, scaling_type="ntk")
 
 
 def test_hf_config_roundtrip():

@@ -1,9 +1,9 @@
 """generation/pools.py: the one table of what a kind of per-sequence memory
 does not carry (``NOT_CARRIED``, ``refuse_unserved``), and the module's
 place under the scheduler.  The pools and the trie it holds are driven by
-tests/test_pool_counters.py and tests/test_prefix_cache.py, the three
-refusing kinds' engines by tests/test_commanda.py, tests/test_joyai.py and
-tests/test_brumby.py.
+tests/test_pool_counters.py and tests/test_prefix_cache.py, the four
+refusing kinds' engines by tests/test_commanda.py, tests/test_joyai.py,
+tests/test_brumby.py and tests/test_gigachat35.py.
 """
 
 import ast
@@ -43,6 +43,14 @@ KINDS = {
         num_experts=4, moe_router_topk=2, moe_ffn_hidden_size=40, **TINY),
     "state": lambda: make_config(
         "brumby", kv_channels=16, ffn_hidden_size=96, **TINY),
+    "hybrid": lambda: make_config(
+        "gigachat35", **{**TINY, "num_layers": 4}, dense_prefix_layers=1,
+        ffn_hidden_size=128, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=16, linear_value_head_dim=16, num_experts=4,
+        moe_router_topk=2, moe_ffn_hidden_size=32,
+        rope_yarn_original_max_position=64),
 }
 
 
@@ -109,7 +117,12 @@ def test_the_table_has_no_row_without_a_case():
     for row in NOT_CARRIED:
         _case(*row)
     kinds = {k for k, _ in NOT_CARRIED}
-    assert kinds == {"share", "classes", "latent", "state"}
+    assert kinds == {"share", "classes", "latent", "state", "hybrid"}
+    # a state class beside a page class is SERVED (tests/test_gigachat35.py):
+    # the row that refused it now says whose that is (the hybrid's, not
+    # power retention's), and the hybrid has a row a feature
+    assert "linear_layout" in NOT_CARRIED["state", "pattern"]
+    assert {f for k, f in NOT_CARRIED if k == "hybrid"} == set(FEATURES)
     assert {f for _, f in NOT_CARRIED} <= set(FEATURES) | {"mesh", "pattern"}
 
 

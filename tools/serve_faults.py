@@ -4,6 +4,7 @@ fail, at one seed and with no measured window.
 
     chiprun -- python tools/serve_faults.py --workload commanda_plus_agent_16k --seed N
     chiprun -- python tools/serve_faults.py --workload brumby14b_longgen_closed --seed N
+    chiprun -- python tools/serve_faults.py --workload gigachat35_reasoning_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -22,8 +23,14 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   (``benchmark/reference/commanda_block.py``): window layers that see every
   key, rotation on the full layer, the shared experts summed and not
   averaged; and the three of ``benchmark/reference/brumby_block.py``: the
-  gate ignored (no decay), degree 1, the normaliser dropped.  A fault is
-  tried where the cell's reference has its choice.
+  gate ignored (no decay), degree 1, the normaliser dropped; and those of
+  ``benchmark/reference/gigachat35_block.py``: a linear layer's decay
+  ignored, ``beta`` = 1, its conv's tail dropped at a tick's boundary (128
+  prompt rows), its output gate's scale 1 for 2 (which the after-norm
+  cancels: it reads as ``program`` does, and says so), and the readings the
+  config leaves to the modelling file flipped one by one (the gain ``1 +
+  w``, no after-norms, no MLA scaling factor, the attention ungated, no
+  clamp).  A fault is tried where the cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
 readings and the others over a dozen seeds; an entry other than ``program``
@@ -51,6 +58,18 @@ FAULTS = {
         u @ gate["kernel"].astype(u.dtype))),
     "degree_one": ("degree", lambda model: 1),
     "normaliser_dropped": ("normalised", lambda model: False),
+    "delta_decay_ignored": ("delta_decay", lambda p, u, hv: 0.0 * (
+        u @ p["ba"]["kernel"])[..., hv:]),
+    "delta_beta_one": ("write_strength", lambda p, u, hv: 1.0 + 0.0 * (
+        u @ p["ba"]["kernel"])[..., :hv]),
+    "conv_tail_dropped_at_a_tick": ("conv_reset_every", lambda model: 128),
+    "delta_gate_scale_one": ("output_gate_scale", lambda model: 1.0),
+    "gain_one_plus_w": ("norm_gain", lambda w, model: 1.0 + w),
+    "no_after_norms": ("post_norms", lambda model: False),
+    "no_mla_scaling_factor": ("softmax_scale", lambda model: (
+        model["qk_nope_head_dim"] + model["qk_rope_head_dim"]) ** -0.5),
+    "attention_ungated": ("attention_gate", lambda p, u: 1.0),
+    "no_swiglu_clamp": ("swiglu_limit", lambda model: None),
 }
 
 
@@ -59,6 +78,8 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--rehearsal", type=int, default=0)
+    ap.add_argument("--no-faults", action="store_true",
+                    help="program and control only: a reading a seed")
     args = ap.parse_args()
     args.rate, args.trace = None, 0
     if args.rehearsal:
@@ -84,9 +105,11 @@ def main() -> int:
         served.server.stop()
     ref = check.reference_module(cell)
     got = [lp for p in probes for lp in p["logprobs"]]
+    in_use = (device.memory_stats() or {}).get("bytes_in_use")
     t = time.monotonic()
     want = check.emitted_reference(cell, served.params, probes)
     line = {"workload": cell.name, "seed": args.seed,
+            "bytes_in_use_before_reference": in_use,
             "probe_lengths": [len(p["prompt"]) for p in probes],
             "probe_prefix_hit_tokens": hits,
             "program": check.compare(cell, got, want),
@@ -98,7 +121,7 @@ def main() -> int:
         low = check.emitted_reference(cell, served.params, probes)
     line["control"] = check.compare(cell, low, want)
     for name, (choice, fault) in FAULTS.items():
-        if not hasattr(ref, choice):
+        if args.no_faults or not hasattr(ref, choice):
             continue
         # the reference jits its layer program anew every call, so the
         # choice is traced in

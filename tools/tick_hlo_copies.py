@@ -298,7 +298,8 @@ def compile_tick(workload: str, prefill_rows: Optional[int] = 0):
            else eng._bucket_up(slots, chunk))
     pre = cap if prefill_rows is None else prefill_rows
     classes = pool_classes(cfg)
-    state = classes[0].state
+    state = all(cls.state for cls in classes)
+    hybrid = not state and any(cls.state for cls in classes)
     width = 1 if state else -(-max_seq // page)
     num_pages = inf.kv_pool_pages or slots * width + 1
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
@@ -308,7 +309,19 @@ def compile_tick(workload: str, prefill_rows: Optional[int] = 0):
         return abstract(jax.eval_shape(lambda: make().kv))
 
     with global_mesh(mesh):
-        if len(classes) == 2:
+        if hybrid:
+            # a page class and a state class (generation/engine.py)
+            paged, st = classes
+            pool = (
+                pool_of(lambda: eng.PagedKVPool(
+                    cfg, num_pages, page, layers=paged.layers(cfg),
+                    page_class=paged.name)),
+                pool_of(lambda: eng.StatePool(
+                    cfg, slots, page, layers=st.layers(cfg),
+                    page_class=st.name)))
+            tables = lambda n: (S((n, width), jnp.int32),  # noqa: E731
+                                S((n, 1), jnp.int32))
+        elif len(classes) == 2:
             periods = m.num_layers // m.layer_period
             full, win = classes
             wcap = -(-int(win.window) // page) + 2 + cap // page
