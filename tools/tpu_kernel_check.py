@@ -22,10 +22,13 @@ Usage (through the chip tool; off-TPU it exits 2):
                                       | --glu_stack [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
-kernel phase).  ``--time`` prints the paged kernel's ms a call at the
-tick shapes the chip has seen (``TICKS``), whole and with the chunk's rows
-dead, beside the dtype its two matmuls take their operands in and what
-its KV bytes need at the HBM peak, and checks nothing.
+kernel phase).  ``--time`` prints each flash kernel's ms a call at the
+train cells' shapes (``FLASH_SHAPES``) beside the blocks its grid walks
+(live of all, cut of the live: ``flash_attention.live_blocks``), then the
+paged kernel's ms a call at the tick shapes the chip has seen (``TICKS``),
+whole and with the chunk's rows dead, beside the dtype its two matmuls
+take their operands in and what its KV bytes need at the HBM peak, and
+checks nothing.
 ``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
@@ -636,7 +639,9 @@ def paged_numerics(quick: bool):
 def kernel_seconds(f, *args, kernel: str):
     """Device seconds of each execution of the Pallas kernel ``kernel`` in
     one traced call of ``f`` (plane ``/device:TPU:0``, line ``XLA Ops``:
-    an event is named by its instruction's text)."""
+    an event is named by its instruction's text, and the instruction by
+    the kernel, bare or inside the transform that made it:
+    ``transpose_jvp_flash_bwd_dq__``)."""
     import glob
     import tempfile
 
@@ -647,12 +652,59 @@ def kernel_seconds(f, *args, kernel: str):
         path = sorted(glob.glob(os.path.join(
             d, "plugins", "profile", "*", "*.xplane.pb")))[-1]
         data = jax.profiler.ProfileData.from_file(path)
-        return [ev.duration_ns / 1e9
-                for plane in data.planes if plane.name == "/device:TPU:0"
-                for line in plane.lines if line.name == "XLA Ops"
-                for ev in line.events
-                if ev.name.lstrip("%").startswith(kernel)
-                and "custom-call" in ev.name]
+        calls = [ev for plane in data.planes if plane.name == "/device:TPU:0"
+                 for line in plane.lines if line.name == "XLA Ops"
+                 for ev in line.events if "custom-call" in ev.name]
+        found = [ev.duration_ns / 1e9 for ev in calls
+                 if kernel in ev.name.split(" = ")[0]]
+        if not found:
+            raise RuntimeError(
+                f"no execution of {kernel} in the trace; its custom calls: "
+                f"{sorted({ev.name.split(' = ')[0] for ev in calls})}")
+        return found
+
+
+FLASH_SHAPES = {
+    # name: (seq, heads, KV heads, head dim, causal, window, block pairs
+    # beside pick_blocks' own)
+    "smallthinker 16k global": (16384, 28, 4, 128, True, None, ((512, 512),)),
+    "smallthinker 16k window": (
+        16384, 28, 4, 128, True, 4096,
+        ((512, 512), (512, 1024), (1024, 512))),
+    "mistral 4k window": (4096, 32, 8, 128, True, 4096, ()),
+    # what pick_blocks' docstring quotes: a window far under a block
+    "8k window 256": (8192, 16, 16, 128, True, 256, ((512, 512), (256, 256))),
+    "bidirectional 2k": (2048, 16, 16, 128, False, None, ()),
+}
+
+
+def flash_timing():
+    """ms a call of flash_fwd, flash_bwd_dq and flash_bwd_dkv (one
+    sequence, bf16, device time from a profiler trace of one gradient)
+    beside the blocks the call's grid walks: live of the rectangle's, cut
+    of the live.  The first block pair of a shape is pick_blocks' own."""
+    from megatron_llm_tpu.ops.pallas import flash_attention as fa
+
+    for name, (s, n, nkv, d, causal, window, more) in FLASH_SHAPES.items():
+        q, k, v = rand_qkv(jax.random.PRNGKey(11), 1, s, n, nkv, d)
+        for bq, bkv in (fa.pick_blocks(s, s, d),) + more:
+            def loss(q, k, v, bq=bq, bkv=bkv):
+                out = fa.flash_attention(
+                    q, k, v, causal=causal, sliding_window=window,
+                    block_q=bq, block_kv=bkv)
+                return (out.astype(jnp.float32) * 0.01).sum()
+
+            # one program per shape and block pair: timing each is the point
+            f = jax.jit(  # graftcheck: noqa[recompile-hazard]
+                jax.grad(loss, argnums=(0, 1, 2)))
+            ms = {kernel: 1e3 * sum(kernel_seconds(f, q, k, v, kernel=kernel))
+                  for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+            blocks = fa.live_blocks(s, s, bq, bkv, causal, window)
+            print(f"TIME flash {name} heads={n}/{nkv} blocks={bq}x{bkv} "
+                  f"live {blocks.live}/{blocks.total} "
+                  f"cut {blocks.cut}/{blocks.live}: "
+                  + ", ".join(f"{k_} {t:.3f} ms" for k_, t in ms.items())
+                  + f", together {sum(ms.values()):.3f} ms", flush=True)
 
 
 def paged_timing():
@@ -980,8 +1032,9 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="numerics at the preset geometries only")
     ap.add_argument("--time", action="store_true",
-                    help="time the paged kernel alone at the tick shapes "
-                         "the chip has seen, and nothing else")
+                    help="time the flash kernels at the train cells' "
+                         "shapes and the paged kernel alone at the tick "
+                         "shapes the chip has seen, and nothing else")
     ap.add_argument("--glu_stack", action="store_true",
                     help="the GLU fc1 kernel that reads its stack in place "
                          "against XLA's product of the slice (with --time: "
@@ -1009,6 +1062,7 @@ def main():
               + (f": {FAILURES}" if FAILURES else ""))
         sys.exit(1 if FAILURES else 0)
     if args.time:
+        flash_timing()
         paged_timing()
         return
     flash_numerics(args.quick)
