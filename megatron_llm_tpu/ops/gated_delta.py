@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from megatron_llm_tpu.ops.retention import tick_runs
 
 CHUNK = 64           # rows of one run of the chunked form
+PUT_ROWS = 128       # rows of the tail pool one step of the tick's write takes
 F32 = jnp.float32
 
 
@@ -102,7 +103,14 @@ def conv_tick(x: jax.Array, w: jax.Array, tails: jax.Array, slots, positions,
     (:class:`DeltaState`), ``base`` the row of this layer's slot 0.  A row takes
     what its run's earlier rows fed and, behind the run's start, its
     slot's tail (zeros where the run starts a sequence); a run's last row
-    leaves the slot its new tail.  Returns (y [R, c] float32, the pool)."""
+    leaves the slot its new tail, and no other row writes anything: rows
+    of the pool that no live run names keep their bits, the layer's null
+    row (``base``: what a dead row names, which nothing reads) among them.
+    The engine hands a slot one run a tick (a sequence is decoding, one
+    row, or filling, whose chunks of one tick are consecutive rows at
+    consecutive positions: ``generation/engine.py`` ``_plan_ragged_prefill``
+    and the decode rows before them); of two runs that named one slot the
+    LATER would leave its tail.  Returns (y [R, c] float32, the pool)."""
     r, c = x.shape
     back = w.shape[0] - 1
     x = x.astype(F32)
@@ -127,9 +135,38 @@ def conv_tick(x: jax.Array, w: jax.Array, tails: jax.Array, slots, positions,
     # a run's last row: the row after it is not its run's
     goes_on = jnp.concatenate([live[1:] & ~first[1:], jnp.zeros((1,), bool)])
     new_tail = jnp.concatenate(prev[:back - 1][::-1] + [x], axis=1)
-    to = jnp.where(live & ~goes_on, slots, 0)               # else: null slot
-    return (jnp.where(live[:, None], y, 0.0),
-            tails.at[base + to].set(new_tail))
+    to = jnp.where(live & ~goes_on, base + slots, -1)       # else: nowhere
+    return jnp.where(live[:, None], y, 0.0), _put_rows(tails, new_tail, to)
+
+
+def _put_rows(pool: jax.Array, new: jax.Array, to: jax.Array) -> jax.Array:
+    """``pool [n, d]`` with row ``to[i]`` set to ``new[i]`` wherever ``to[i]
+    >= 0``, the later of two rows that name one; every other row keeps its
+    bits.  Turned round into gathers: the pool's rows from the lowest named
+    to the highest, ``PUT_ROWS`` at a time, each looking up the row of
+    ``new`` that names it.  ``pool.at[to].set(new)`` says the same, but a
+    scatter of R rows of d values reaches a TPU as a loop of R
+    dynamic-update-slices, one row each, whatever it is told of its indices
+    (unique, sorted, dropped), and the loop takes twenty times what the
+    bytes need."""
+    n, r = pool.shape[0], to.shape[0]
+    b = min(PUT_ROWS, n)
+    rows = jnp.arange(r, dtype=jnp.int32)
+    lowest = jnp.min(jnp.where(to >= 0, to, n))
+    highest = jnp.max(to)                                   # -1: none named
+
+    def block(carry):
+        at, pool = carry
+        at0 = jnp.minimum(at, n - b)            # the last block ends the pool
+        here = at0 + jnp.arange(b, dtype=to.dtype)
+        src = jnp.max(jnp.where(to[None, :] == here[:, None], rows[None, :],
+                                -1), axis=1)                # [b]; -1: keep
+        old = jax.lax.dynamic_slice_in_dim(pool, at0, b)
+        put = jnp.where((src >= 0)[:, None], new[jnp.maximum(src, 0)], old)
+        return at + b, jax.lax.dynamic_update_slice_in_dim(pool, put, at0, 0)
+
+    return jax.lax.while_loop(lambda carry: carry[0] <= highest, block,
+                              (lowest, pool))[1]
 
 
 # ---------------------------------------------------------------------------

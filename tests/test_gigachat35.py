@@ -8,6 +8,9 @@ four layers') for the linear ones.  Everything is compared with the plain
 reference (``benchmark/reference/gigachat35_block.py``: the recurrent form
 a token at a time, the expanded latent attention) on the same weights."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,6 +38,7 @@ from megatron_llm_tpu.models.transformer import (
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.ops import gated_delta as gd
 from megatron_llm_tpu.ops import norms, rope
+from megatron_llm_tpu.ops.retention import tick_runs
 
 # float32 rounding: the program sums a state's part and a run's part (the
 # chunked form, the tick's runs) where the reference walks token by token,
@@ -316,6 +320,133 @@ def test_the_ticks_form_carries_state_and_tail_across_runs_and_slot_reuse():
         np.testing.assert_array_equal(pool.s[1, slot], start.s[1, slot])
         np.testing.assert_array_equal(pool.conv[6 + slot],
                                       start.conv[6 + slot])
+
+
+# ---- the tick's write of the conv tails --------------------------------------
+
+def rowwise_tails(tails, x, slots, positions, base):
+    """What ``conv_tick``'s write left until PR 51, row by row in the tick's
+    order: EVERY row sets a row of the pool to its last three inputs (what
+    its run fed, behind the run's start the slot's tail, zeros where the run
+    starts a sequence), a run's last row its slot's, any other row the
+    layer's null row."""
+    before, tails = np.asarray(tails), np.array(tails)
+    live, first, fresh = (np.asarray(t) for t in tick_runs(slots, positions))
+    c = x.shape[1]
+    for i, slot in enumerate(np.asarray(slots)):
+        if first[i] or not live[i]:
+            ins = [np.zeros(c, np.float32)] * 3 if fresh[i] or not live[i] \
+                else list(before[base + slot].reshape(3, c))
+        ins = ins[1:] + [np.asarray(x[i], np.float32)]
+        ends = live[i] and not (i + 1 < len(live) and live[i + 1]
+                                and not first[i + 1])
+        tails[base + (slot if ends else 0)] = np.concatenate(ins)
+    return tails
+
+
+def _tick(tick, base=6, c=12, seed=9):
+    """(x, slots, positions) of one tick of ``TICKS``' notation."""
+    x = jax.random.normal(jax.random.PRNGKey(seed), (3, 300, c))
+    _, slots, pos, take = _feed(tick, [])
+    return jnp.stack([x[a, t] for a, t in take]), slots, pos
+
+
+def _assert_tails(got, want, nulls):
+    """Bit for bit in every row but the layers' null rows."""
+    rows = np.setdiff1d(np.arange(len(want)), nulls)
+    np.testing.assert_array_equal(np.asarray(got)[rows], want[rows])
+
+
+def test_the_tails_after_each_tick_are_the_rowwise_writes_bit_for_bit():
+    w = jax.random.normal(jax.random.PRNGKey(8), (4, 12))
+    start = jax.random.normal(jax.random.PRNGKey(2), (2 * 6, 3 * 12))
+    tails = start
+    for tick in TICKS:
+        x, slots, pos = _tick(tick)
+        want = rowwise_tails(tails, x, slots, pos, 6)
+        _, tails = gd.conv_tick(x, w, tails, slots, pos, 6)
+        _assert_tails(tails, want, [0, 6])
+        # the null rows, which took the rows that end no run, take nothing
+        np.testing.assert_array_equal(tails[jnp.asarray([0, 6])],
+                                      start[jnp.asarray([0, 6])])
+
+
+R_ROWS = 9
+RUNS = {
+    "every row dead": [(0, 0, 0, R_ROWS)],
+    "a run that ends on the last row": [(0, 0, 0, 3), (3, 0, 4, 10)],
+    "a run of one row, last": [(2, 0, 5, 13), (1, 1, 7, 8)],
+    "runs of one row": [(s, s % 3, 2 * s, 2 * s + 1) for s in (5, 3, 1, 4, 2)],
+    "a run of R rows": [(4, 2, 0, R_ROWS)],
+    "a run of R rows that goes on": [(4, 2, 17, 17 + R_ROWS)],
+    "two runs name one slot: the later": [(3, 0, 0, 4), (1, 1, 5, 6),
+                                          (3, 2, 0, 3)],
+}
+
+
+@pytest.mark.parametrize("name", RUNS)
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_only_a_runs_last_row_writes_and_only_its_layers_row(name, layer):
+    """Three layers of six rows: the rows of the layer named equal the
+    rowwise writes', every other row of the pool, the null rows among
+    them, keeps its bits."""
+    w = jax.random.normal(jax.random.PRNGKey(8), (4, 12))
+    start = jax.random.normal(jax.random.PRNGKey(3), (3 * 6, 3 * 12))
+    x, slots, pos = _tick(RUNS[name], seed=4)
+    want = rowwise_tails(start, x, slots, pos, layer * 6)
+    got = np.asarray(gd.conv_tick(x, w, start, slots, pos, layer * 6)[1])
+    _assert_tails(got, want, [layer * 6])
+    named = layer * 6 + np.unique(np.asarray(slots)[np.asarray(slots) > 0])
+    rest = np.setdiff1d(np.arange(3 * 6), named)
+    np.testing.assert_array_equal(got[rest], np.asarray(start)[rest])
+    assert (got[named] != np.asarray(start)[named]).any(axis=1).all()
+
+
+@pytest.mark.parametrize("rows,per,layers", [(3, 300, 2), (256, 129, 4)])
+def test_the_write_walks_a_pool_larger_than_its_block(rows, per, layers):
+    """``PUT_ROWS`` at a time from the lowest row named to the highest: a
+    layer of 300 rows named at both ends, and the cell's pool."""
+    c = 4
+    rng = np.random.default_rng(rows)
+    slots = np.zeros(rows, np.int32)
+    picked = rng.permutation(np.arange(2, per - 1))[:rows - 3]
+    slots[:len(picked) + 2] = [1, *picked, per - 1]         # the rest: dead
+    slots, pos = jnp.asarray(slots), jnp.full((rows,), 7, jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(5), (rows, c))
+    w = jax.random.normal(jax.random.PRNGKey(6), (4, c))
+    start = jax.random.normal(jax.random.PRNGKey(7), (layers * per, 3 * c))
+    base = (layers - 1) * per
+    want = rowwise_tails(start, x, slots, pos, base)
+    got = jax.jit(gd.conv_tick)(x, w, start, slots, pos, base)[1]
+    _assert_tails(got, want, [base])
+    np.testing.assert_array_equal(got[:base + 1], start[:base + 1])
+
+
+def _wide_scatters(text, rows):
+    """The scatters of a lowered module whose updates hold more than a
+    word a row, by the updates' type."""
+    updates = re.findall(
+        r'"stablehlo\.scatter"\(.*?\}\) : \([^)]*, (tensor<[^>]*>)\) ->', text,
+        flags=re.S)
+    assert len(updates) == text.count("stablehlo.scatter\"(")
+    return [u for u in updates
+            if math.prod(int(d) for d in re.findall(r"(\d+)x", u)) > rows]
+
+
+def test_the_lowered_tick_scatters_no_row_of_tails():
+    """A scatter of ``[R, 3c]`` updates reaches the TPU as a loop of R
+    dynamic-update-slices (10% of the GigaChat cell until PR 51) and reads
+    the same as any other form on a CPU: so the lowered text is read."""
+    rows, c = 256, 8
+    args = (jnp.zeros((rows, c)), jnp.zeros((4, c)),
+            jnp.zeros((4 * 129, 3 * c)), jnp.zeros((rows,), jnp.int32),
+            jnp.zeros((rows,), jnp.int32), jnp.int32(129))
+    assert not _wide_scatters(
+        jax.jit(gd.conv_tick).lower(*args).as_text(), rows)
+    # the probe sees the write it guards against
+    before = jax.jit(lambda t, new, to: t.at[to].set(new)).lower(
+        args[2], jnp.zeros((rows, 3 * c)), args[3]).as_text()
+    assert len(_wide_scatters(before, rows)) == 1
 
 
 # ---- the model against the reference ---------------------------------------

@@ -19,7 +19,8 @@ element on both sides, so the same bound holds.
 
 Usage (through the chip tool; off-TPU it exits 2):
     python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]
-                                      | --glu_stack [--time]]
+                                      | --glu_stack [--time]
+                                      | --conv_tick [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
 kernel phase).  ``--time`` prints each flash kernel's ms a call at the
@@ -36,6 +37,10 @@ prints the kernel's ms a call and GB/s at the cell's pool size, apart from
 a whole tick.  ``--glu_stack`` holds the GLU ``fc1`` kernel that reads its
 stack in place (``ops/pallas/stacked_linear.py``) to XLA's product of the
 layer's slice at the serving cells' widths; ``--time`` prints both.
+``--conv_tick`` holds the conv tails' tick form (``ops/gated_delta.py``
+``conv_tick``, plain ``jnp``: what XLA makes of it for the chip) to
+``causal_conv`` at the GigaChat cell's shape and the pool it leaves to the
+inputs; ``--time`` prints its ms a call beside the write it had until PR 51.
 The full run adds the page-size/dtype matrix, block-size timing sweeps
 and a long-sequence (32K) memory-fit check.
 Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
@@ -636,32 +641,49 @@ def paged_numerics(quick: bool):
                   f"{type(exc).__name__}: {str(exc)[:300]}")
 
 
-def kernel_seconds(f, *args, kernel: str):
-    """Device seconds of each execution of the Pallas kernel ``kernel`` in
-    one traced call of ``f`` (plane ``/device:TPU:0``, line ``XLA Ops``:
-    an event is named by its instruction's text, and the instruction by
-    the kernel, bare or inside the transform that made it:
-    ``transpose_jvp_flash_bwd_dq__``)."""
+def device_events(call):
+    """The device's events of one traced ``call()`` (plane
+    ``/device:TPU:0``, line ``XLA Ops``), made once before the trace so
+    that it is compiled and warm."""
     import glob
     import tempfile
 
-    jax.block_until_ready(f(*args))  # compiled and warm before the trace
+    jax.block_until_ready(call())
     with tempfile.TemporaryDirectory() as d:
         with jax.profiler.trace(d):
-            jax.block_until_ready(f(*args))
+            jax.block_until_ready(call())
         path = sorted(glob.glob(os.path.join(
             d, "plugins", "profile", "*", "*.xplane.pb")))[-1]
         data = jax.profiler.ProfileData.from_file(path)
-        calls = [ev for plane in data.planes if plane.name == "/device:TPU:0"
-                 for line in plane.lines if line.name == "XLA Ops"
-                 for ev in line.events if "custom-call" in ev.name]
-        found = [ev.duration_ns / 1e9 for ev in calls
-                 if kernel in ev.name.split(" = ")[0]]
-        if not found:
-            raise RuntimeError(
-                f"no execution of {kernel} in the trace; its custom calls: "
-                f"{sorted({ev.name.split(' = ')[0] for ev in calls})}")
-        return found
+        return [(ev.name, ev.start_ns, ev.duration_ns)
+                for plane in data.planes if plane.name == "/device:TPU:0"
+                for line in plane.lines if line.name == "XLA Ops"
+                for ev in line.events]
+
+
+def kernel_seconds(f, *args, kernel: str):
+    """Device seconds of each execution of the Pallas kernel ``kernel`` in
+    one traced call of ``f``: an event is named by its instruction's text,
+    and the instruction by the kernel, bare or inside the transform that
+    made it: ``transpose_jvp_flash_bwd_dq__``."""
+    calls = [(name, ns) for name, _, ns in device_events(lambda: f(*args))
+             if "custom-call" in name]
+    found = [ns / 1e9 for name, ns in calls if kernel in name.split(" = ")[0]]
+    if not found:
+        raise RuntimeError(
+            f"no execution of {kernel} in the trace; its custom calls: "
+            f"{sorted({name.split(' = ')[0] for name, _ in calls})}")
+    return found
+
+
+def busy_seconds(call):
+    """Seconds the device was busy in one traced ``call()``: the union of
+    its events (a loop's event holds its body's)."""
+    busy, end = 0, 0
+    for _, start, ns in sorted(device_events(call), key=lambda e: e[1]):
+        busy += max(0, start + ns - max(start, end))
+        end = max(end, start + ns)
+    return busy / 1e9
 
 
 FLASH_SHAPES = {
@@ -840,6 +862,115 @@ def brumby_check(timed: bool):
               f"GB/s), {need / 1e9:.3f} GB needed in the minimal layout "
               f"({100 * need / 819e9 / t:.2f}% of the bandwidth roofline)",
               flush=True)
+
+
+# the GigaChat cell's tick (256 rows on 128 slots and the null one, 4
+# linear layers, 16,384 conv channels): (decode rows, rows of the one
+# prompt run behind them); the rows left are dead
+CONV_CHANNELS = 16384
+CONV_TICKS = {
+    "128 decode rows, 128 dead": (128, 0),
+    "127 decode rows + one 128-row run, 1 dead": (127, 128),
+}
+
+
+def _conv_feed(decode: int, run: int, at: int, rows: int = 256):
+    """Tick ``at`` (0, 1) of a feed: (slots, positions, which input each
+    row takes).  Decode row ``i`` is token ``at`` of sequence ``i``, on a
+    slot drawn without order; the prompt run is tokens ``at * run`` on of
+    one more sequence, input ``2 * 128 + token``."""
+    import numpy as np
+
+    order = 1 + np.random.default_rng(5).permutation(128)
+    slots, pos, take = (np.zeros(rows, np.int32) for _ in range(3))
+    slots[:decode], pos[:decode] = order[:decode], at
+    take[:decode] = 2 * np.arange(decode) + at
+    if run:
+        slots[decode:decode + run] = order[decode]
+        pos[decode:decode + run] = at * run + np.arange(run)
+        take[decode:decode + run] = 2 * 128 + pos[decode:decode + run]
+    return slots, pos, take
+
+
+def conv_tick_check(timed: bool):
+    """``ops/gated_delta.conv_tick`` compiled, at the cell's shape: two
+    ticks of a feed against ``causal_conv`` over the same sequences, and the
+    pool they leave against the inputs themselves, bit for bit; ``timed``:
+    its device time a call with the write it has and with the write it had
+    until PR 51 (a destination for every row, those that end no run the null
+    slot's: ``tests/test_gigachat35.py`` keeps that form row by row), the
+    pool donated as the engine donates it."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops import gated_delta as gd
+
+    c, per, layers, layer = CONV_CHANNELS, 129, 4, 2
+    base = layer * per
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    w = jax.random.normal(ks[0], (4, c), jnp.bfloat16)
+    # inputs 2i, 2i + 1: sequence i's two tokens; from 256 on: the prompt's
+    feed = jax.random.normal(ks[1], (2 * 128 + 2 * 128, c), jnp.bfloat16)
+    pool = jax.random.normal(ks[2], (layers * per, 3 * c), jnp.float32)
+    start = np.asarray(pool)
+    tick = jax.jit(gd.conv_tick, donate_argnums=(2,))
+
+    def tick_until_51(*args):
+        """``conv_tick`` traced with the write it had."""
+        kept_put, gd._put_rows = gd._put_rows, lambda p, new, to: p.at[
+            jnp.where(to >= 0, to, base)].set(new)
+        try:
+            return gd.conv_tick(*args)
+        finally:
+            gd._put_rows = kept_put
+
+    forms = {"now": tick,
+             "until PR 51": jax.jit(tick_until_51, donate_argnums=(2,))}
+    for name, (decode, run) in CONV_TICKS.items():
+        tails = pool + 0.0
+        for at in (0, 1):
+            slots, pos, take = _conv_feed(decode, run, at)
+            x = jnp.where((slots > 0)[:, None], feed[take], 0)
+            y, tails = tick(x, w, tails, slots, pos, base)
+        dense = [feed[:2 * decode].reshape(decode, 2, c)] + (
+            [feed[None, 2 * 128:2 * 128 + 2 * run]] if run else [])
+        want = np.concatenate(
+            [np.asarray(gd.causal_conv(d, w))[:, -(d.shape[1] // 2):].reshape(
+                -1, c) for d in dense])
+        live = np.flatnonzero(slots)
+        err = float(np.abs(np.asarray(y)[live] - want).max())
+        # a slot's tail: its sequence's last three inputs, zeros before it
+        last = np.concatenate([np.asarray(jnp.pad(
+            d.astype(jnp.float32), ((0, 0), (1, 0), (0, 0)))[:, -3:]).reshape(
+                -1, 3 * c) for d in dense])
+        named = base + np.asarray([slots[i] for i in live if i + 1 == len(
+            slots) or slots[i + 1] != slots[i]])
+        got = np.asarray(tails)
+        rest = np.setdiff1d(np.arange(layers * per), named)
+        kept = np.array_equal(got[named], last)
+        apart = np.array_equal(got[rest], start[rest])
+        check(f"conv_tick {name}", err < 1e-4 and kept and apart,
+              f"max |dy| {err:.2e} against causal_conv; {len(named)} named "
+              f"rows hold their sequences' last three inputs: {kept}; the "
+              f"other {len(rest)} rows of the pool as they were: {apart}")
+        if not timed:
+            continue
+        took = {}
+        for form, f in forms.items():
+            held = [pool + 0.0]
+
+            def call(f=f, held=held):
+                out = f(x, w, held.pop(), slots, pos, base)
+                held.append(out[1])
+                return out
+
+            took[form] = busy_seconds(call)
+        print(f"TIME conv_tick {name}: {took['now'] * 1e3:.3f} ms a call on "
+              f"the device, {took['until PR 51'] * 1e3:.3f} ms with a "
+              f"destination for every row (until PR 51); the tails a run "
+              f"leaves, read and written once, are "
+              f"{2 * len(named) * 3 * c * 4 / 1e6:.0f} MB "
+              f"({2 * len(named) * 3 * c * 4 / 819e9 * 1e3:.3f} ms at the "
+              f"HBM peak)", flush=True)
 
 
 # (rows, h, ffn, layers): the GLU fc1 of the Brumby cell's decode tick and
@@ -1039,6 +1170,11 @@ def main():
                     help="the GLU fc1 kernel that reads its stack in place "
                          "against XLA's product of the slice (with --time: "
                          "its ms a call), and nothing else")
+    ap.add_argument("--conv_tick", action="store_true",
+                    help="the conv tails' tick form at the GigaChat cell's "
+                         "shape against causal_conv (with --time: its ms a "
+                         "call beside the write it had until PR 51), and "
+                         "nothing else")
     ap.add_argument("--brumby", action="store_true",
                     help="the retention state sweep at the Brumby-14B tick "
                          "shapes against its jnp form (with --time: its "
@@ -1056,8 +1192,9 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
-    if args.brumby or args.glu_stack:
-        (brumby_check if args.brumby else glu_stack_check)(args.time)
+    if args.brumby or args.glu_stack or args.conv_tick:
+        (brumby_check if args.brumby else glu_stack_check if args.glu_stack
+         else conv_tick_check)(args.time)
         print(f"\n{len(FAILURES)} failures"
               + (f": {FAILURES}" if FAILURES else ""))
         sys.exit(1 if FAILURES else 0)
