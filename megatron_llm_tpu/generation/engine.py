@@ -920,6 +920,14 @@ class ContinuousBatchingEngine:
                      "KV head: ONE a run (a sequence's consecutive rows of "
                      "one tick), so rows over touches is what a prompt run "
                      "amortises; 1.0 for decode-only ticks"),
+            "steps": reg.counter(
+                "mlt_engine_state_steps_total",
+                help="passes over a resident state the sweep made of those "
+                     "rows a layer and block of heads, by the rule of the "
+                     "state class's ops module: one a decode row; a prompt "
+                     "run's tiles where the sweep takes a run in the "
+                     "chunked form (Mamba-2: ops/mamba2.sweep_steps), its "
+                     "rows where it walks them"),
             "resets": reg.counter(
                 "mlt_engine_state_resets_total",
                 help="runs that started a sequence (position 0): the "
@@ -2832,6 +2840,20 @@ class ContinuousBatchingEngine:
             self._m_state["touches"].inc(len(active) + len(runs))
             self._m_state["resets"].inc(
                 sum(start == 0 for start in runs.values()) + starts)
+            steps = len(active) + n_pre
+            if self.spool.sweep_steps is not None:
+                # the tick's rows as the program lays them out: a decode
+                # row a slot, the prompt rows behind them, each request's
+                # at consecutive positions
+                pre = pre_index[:n_bucket]
+                row_slots = np.zeros((self.max_slots,), np.int32)
+                row_slots[active] = 1 + np.asarray(active, np.int32)
+                steps = self.spool.sweep_steps(
+                    np.concatenate([row_slots, np.where(
+                        pre >= 0, 1 + self.max_slots + pre, 0)]),
+                    np.concatenate([np.zeros_like(row_slots),
+                                    pre_pos[:n_bucket]]))
+            self._m_state["steps"].inc(steps)
         if not self._state_only and obs_registry.publishing():
             # the tick's rows as the program lays them out, by the kernel's
             # own rule: a slot's verify rows and a request's prompt rows

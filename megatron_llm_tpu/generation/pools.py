@@ -309,9 +309,14 @@ class PagedKVPool:
         self.state = bool(m.retention) if state is None else state
         refuse_unserved(cfg, kv_dtype=kv_dtype, mesh=mesh,
                         draft=draft_cfg is not None)
+        # a state class whose sweep takes a run several rows a pass names
+        # the host's count of its passes (slots, positions) -> int; None: a
+        # sweep that walks, a pass a live row
+        self.sweep_steps = None
         if self.state and m.mamba:
             from megatron_llm_tpu.ops import mamba2 as mamba_ops
 
+            self.sweep_steps = mamba_ops.sweep_steps
             self.head_dim = m.mamba_head_dim
             kv = mamba_ops.zero_state(
                 (layers, num_pages), m.mamba_num_heads, self.head_dim,

@@ -266,7 +266,7 @@ def mamba_sublayer(cfg, p: Params, x: jax.Array, kv_cache=None, paged=None):
         if paged is not None:
             y, states = _mamba_sweep(cfg)(
                 xs[:, 0], dt[:, 0], log_decay[:, 0], bm[:, 0], cm[:, 0],
-                pool.s, slots, paged.positions, layer)
+                pool.s, slots, paged.positions, jnp.asarray(layer, jnp.int32))
             y, new_pool = y[:, None], mb.MambaState(states, tails)
         else:
             y, _ = mb.mamba_chunked(xs, dt, log_decay, bm, cm)

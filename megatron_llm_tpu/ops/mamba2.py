@@ -29,7 +29,10 @@ of the same numbers:
   chunked form at once: it reads its slot's state once and writes it once,
   and a run at position 0 starts from zero whatever the slot held.  ``jnp``
   throughout: the fallback off the TPU and what the tests hold the kernel
-  (ops/pallas/mamba2.py) to.
+  (ops/pallas/mamba2.py) to.  The kernel takes a run in the same form a
+  TILE of ``SWEEP_TILE`` rows at a time on the state it holds in VMEM
+  (until PR 54 it walked a run's rows, a pass over the state each):
+  :func:`sweep_steps` counts its passes on the host.
 
 The state is float32 whatever the activations are, as is the tail of the
 causal depthwise convolution that feeds x, B and C
@@ -47,10 +50,12 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from megatron_llm_tpu.ops.retention import tick_runs
 
 CHUNK = 128          # rows of one run of the chunked form (``chunk_size``)
+SWEEP_TILE = 32      # rows of one tile of the tick's sweep (the kernel's)
 F32 = jnp.float32
 
 
@@ -189,6 +194,23 @@ def mamba_chunked(x, dt, log_decay, b, c, s0=None, chunk: int = CHUNK):
 # ---------------------------------------------------------------------------
 # The tick: ragged rows against a pool of per-slot states
 # ---------------------------------------------------------------------------
+
+
+def sweep_steps(slots, positions) -> int:
+    """Passes over a state the tick's sweep makes a layer and block of
+    heads, on the host (numpy) by the kernel's own rule
+    (``ops/pallas/mamba2.sweep_plan``): one a SEGMENT, the consecutive rows
+    of one run inside one tile of ``SWEEP_TILE`` rows of the tick's row
+    axis.  A decode row is one; a prompt run of k rows that starts on a
+    tile's first row ``ceil(k / SWEEP_TILE)``, one more where it starts
+    inside a tile."""
+    slots, positions = np.asarray(slots), np.asarray(positions)
+    live = slots > 0
+    goes_on = np.zeros(live.shape, bool)
+    goes_on[1:] = (live[1:] & (slots[1:] == slots[:-1])
+                   & (positions[1:] == positions[:-1] + 1))
+    goes_on &= np.arange(live.size) % SWEEP_TILE != 0
+    return int((live & ~goes_on).sum())
 
 
 def mamba_tick(x, dt, log_decay, b, c, pool: jax.Array, slots, positions,

@@ -708,8 +708,9 @@ def test_aot_sublayer_tick_compiles_at_published_widths_and_depth():
     ``mamba_sweep``, the paged kernel at GQA 32 / 2 and the grouped GEMMs
     at an expert width of 1,856 (off the 128 grid: a cut last tile), both
     pools are updated in place, no weight is laid out anew, and the
-    repeated stretches are SCANNED: the program holds a sweep a place of a
-    stretch's unit (6), not one a Mamba layer (23)."""
+    repeated stretches are SCANNED and the units' six Mamba places share
+    the jitted sweep: the program holds ONE lowered ``mamba_sweep``, not one
+    a place (6) or a Mamba layer (23)."""
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
     from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
     from megatron_llm_tpu.models import init_model_params, make_config
@@ -766,9 +767,12 @@ def test_aot_sublayer_tick_compiles_at_published_widths_and_depth():
             S((pre,), jnp.int32), S((pre,), jnp.int32))
         text = lowered.as_text()
         assert "paged_attention" in text and "gmm" in text
-        # distinct sublayer bodies: a sweep a Mamba place of the units
+        # the units' six Mamba places share ONE lowered kernel (the jitted
+        # sweep, its layer an array): more means the sharing broke, and each
+        # one more is a second of the cell's set-up (PERF.md 7ee)
+        assert sum(u.count("M") for u, _ in units) == 6
         sweeps = text.count('kernel_name = "mamba_sweep"')
-        assert sweeps == sum(u.count("M") for u, _ in units) == 6, sweeps
+        assert sweeps == 1, sweeps
         compiled = lowered.compile()
         stats = compiled.memory_analysis()
     # the scopes and the kernel's name that the cell's readers match

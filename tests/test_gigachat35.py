@@ -642,8 +642,8 @@ def test_metrics_count_the_state_and_the_pages(model):
     obs_registry.set_publishing(True)
     reg = obs_registry.get_registry()
     eng = engine(cfg, params)
-    names = ("state_rows", "state_touches", "state_resets", "paged_rows",
-             "paged_walks")
+    names = ("state_rows", "state_touches", "state_steps", "state_resets",
+             "paged_rows", "paged_walks")
     before = {n: reg.counter(f"mlt_engine_{n}_total").value for n in names}
     a, b = prompts(40, 1, seed=6)
     for p in (a, b):
@@ -659,6 +659,8 @@ def test_metrics_count_the_state_and_the_pages(model):
     # lost to the tick that runs ahead of a stop: at least the live ones
     assert got["state_rows"] >= 39 + 12 and got["state_touches"] >= 3 + 12
     assert got["state_rows"] > got["state_touches"]
+    # the delta sweep walks a run's rows: a pass over the state a live row
+    assert got["state_steps"] == got["state_rows"]
     assert got["state_resets"] == 2          # one run at position 0 each
     # the same rows went through the paged kernel, whose walks are a tile's
     assert got["paged_rows"] == got["state_rows"]

@@ -36,7 +36,6 @@ from megatron_llm_tpu.models.transformer import (
 )
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.ops import mamba2 as mb
-from megatron_llm_tpu.ops.pallas import mamba2 as kernel
 
 # float32 rounding: the program sums a state's part and a run's part (the
 # chunked form, the tick's runs) where the reference walks token by token,
@@ -300,6 +299,8 @@ def test_the_grouped_norm_is_not_the_whole_width_one():
 
 
 # a tick: (slot, first position, rows) runs in order; slot 0 is a dead row
+# (the kernel against these: tests/test_mamba_kernel.py)
+T = mb.SWEEP_TILE
 TICKS = {
     "decode rows, a prompt run from 0, a dead row, a run that goes on":
         [(2, 10, 1), (3, 0, 20), (0, 0, 1), (1, 7, 5)],
@@ -307,6 +308,19 @@ TICKS = {
     "a run of every row, fresh": [(4, 0, 19)],
     "decode rows only": [(s, 3 * s, 1) for s in (4, 2, 3, 1)],
     "a reused slot starts from zero whatever it held": [(1, 0, 3), (2, 5, 2)],
+    # what the sweep's tiles meet (T rows of the tick's row axis a tile) and
+    # a walk of the rows never told apart
+    "a run of a tile's rows": [(1, 4, T)],
+    "a run of a tile's rows and one": [(2, 0, T + 1)],
+    "a run of two tiles' rows and three, from a tile's second row":
+        [(4, 7, 1), (3, 9, 2 * T + 3)],
+    "two runs whose boundary falls inside a tile (the cell's 24 then 40)":
+        [(1, 100, 24), (2, 0, 40)],
+    "a run shorter than a tile after decode rows":
+        [(4, 3, 1), (2, 8, 1), (1, 30, 5)],
+    "a fresh run beside one that goes on in one tile":
+        [(3, 0, 10), (1, 17, 12)],
+    "a dead row between two runs": [(2, 6, 9), (0, 0, 1), (4, 0, 11)],
 }
 
 
@@ -343,28 +357,6 @@ def test_the_ticks_form_is_the_recurrence_a_run(name):
         touched.add(slot)
     rest = [s for s in range(1, 5) if s not in touched]
     np.testing.assert_array_equal(new[1, rest], pool[1, rest])
-    np.testing.assert_array_equal(new[0], pool[0])
-
-
-@pytest.mark.parametrize("heads,groups", [(8, 2), (64, 8), (64, 1), (4, 4)])
-@pytest.mark.parametrize("name", list(TICKS))
-def test_the_sweep_kernel_is_the_ticks_form(name, heads, groups):
-    """``mamba_sweep`` in interpret mode against ``mamba_tick``: outputs,
-    the touched slots' states, and every other slot's bits; a program's
-    block whole groups (8 heads in 2; the published 64 in 8: four groups a
-    block), parts of one group (64 in 1) and a group a head (4 in 4)."""
-    assert kernel.NAME == "mamba_sweep"
-    assert kernel.sweep_blocks(64, 8) == 32 and kernel.sweep_blocks(8, 2) == 8
-    x, dt, ld, b, c, slots, pos = _tick_rows(name, h=heads, p=16, g=groups,
-                                             n=16)
-    pool = jax.random.normal(jax.random.PRNGKey(5), (2, 5, 16, heads * 16))
-    want_y, want = mb.mamba_tick(x, dt, ld, b, c, pool, slots, pos, layer=1)
-    y, new = kernel.mamba_sweep(x, dt, ld, b, c, pool, slots, pos, 1,
-                                interpret=True)
-    np.testing.assert_allclose(y, want_y, rtol=0, atol=2e-4)
-    np.testing.assert_allclose(new[:, 1:], want[:, 1:], rtol=0, atol=2e-4)
-    idle = [s for s in range(1, 5) if s not in np.asarray(slots)]
-    np.testing.assert_array_equal(new[1, idle], pool[1, idle])
     np.testing.assert_array_equal(new[0], pool[0])
 
 
@@ -701,8 +693,8 @@ def test_metrics_count_the_state_the_pages_and_the_experts(model):
     obs_registry.set_publishing(True)
     reg = obs_registry.get_registry()
     eng = engine(cfg, params)
-    names = ("state_rows", "state_touches", "state_resets", "paged_rows",
-             "moe_assignments")
+    names = ("state_rows", "state_touches", "state_steps", "state_resets",
+             "paged_rows", "moe_assignments")
     before = {n: reg.counter(f"mlt_engine_{n}_total").value for n in names}
     for p in prompts(40, 1, seed=6):
         eng.submit(p, 6, top_k=1, termination_id=NEVER)
@@ -713,6 +705,9 @@ def test_metrics_count_the_state_the_pages_and_the_experts(model):
     # lost to the tick that runs ahead of a stop: at least the live ones
     assert got["state_rows"] >= 39 + 12 and got["state_touches"] >= 3 + 12
     assert got["state_rows"] > got["state_touches"]
+    # the Mamba sweep takes a run a tile at a time: a tick's 16 prompt rows
+    # lie in one tile of its row axis, so a run is one pass, as a touch is
+    assert got["state_steps"] == got["state_touches"]
     assert got["state_resets"] == 2          # one run at position 0 each
     assert got["paged_rows"] == got["state_rows"]
     assert got["moe_assignments"] > 0        # the three expert layers' rows

@@ -20,7 +20,8 @@ element on both sides, so the same bound holds.
 Usage (through the chip tool; off-TPU it exits 2):
     python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]
                                       | --glu_stack [--time]
-                                      | --conv_tick [--time]]
+                                      | --conv_tick [--time]
+                                      | --mamba [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
 kernel phase).  ``--time`` prints each flash kernel's ms a call at the
@@ -41,6 +42,12 @@ layer's slice at the serving cells' widths; ``--time`` prints both.
 ``conv_tick``, plain ``jnp``: what XLA makes of it for the chip) to
 ``causal_conv`` at the GigaChat cell's shape and the pool it leaves to the
 inputs; ``--time`` prints its ms a call beside the write it had until PR 51.
+``--mamba`` holds the Mamba-2 state sweep (``ops/pallas/mamba2.py``) to
+``ops/mamba2.mamba_tick`` (float32, ``highest``) at the Nemotron cell's
+widths and tick shapes (32 decode rows; with one 64-row run; with a 24-row
+and a 40-row run), the tiles' plan beside a plan of one-row steps
+(``mamba_walk_plan``: the walk it was until PR 54, on this kernel's grid
+of live steps); ``--time`` prints the ms a call of both.
 The full run adds the page-size/dtype matrix, block-size timing sweeps
 and a long-sequence (32K) memory-fit check.
 Prints one PASS/FAIL line per check; exit code 0 iff all checks pass.
@@ -864,6 +871,140 @@ def brumby_check(timed: bool):
               flush=True)
 
 
+# the Nemotron cell's ticks (32 slots, 64 Mamba heads of 64 in 8 groups on a
+# state of 128): the decode rows, then the prompt runs' rows behind them
+MAMBA_TICKS = {
+    "32 decode rows": (32, ()),
+    "32 decode rows + one 64-row run": (32, (64,)),
+    "32 decode rows + a 24-row and a 40-row run": (32, (24, 40)),
+}
+
+
+def _mamba_tick(seed: int, decode: int, runs, layers: int):
+    """A Nemotron-3-Nano tick's operands: every decode row its own slot at
+    its own position, each prompt run a slot of its own (the first from
+    position 0, the next going on at 128), ``dt`` and ``A`` drawn as the
+    model draws them (``A`` 1 to 16, no clamp on ``dt``), a pool of noise."""
+    from megatron_llm_tpu.ops import mamba2 as mb
+
+    h, p, g, n = 64, 64, 8, 128
+    rows = decode + sum(runs)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    x = jax.random.normal(ks[0], (rows, h, p))
+    b = jax.random.normal(ks[1], (rows, g, n))
+    c = jax.random.normal(ks[2], (rows, g, n))
+    dt, ld = mb.discretize(
+        jax.random.normal(ks[3], (rows, h)),
+        jnp.log(jnp.expm1(jnp.exp(jax.random.uniform(
+            ks[4], (h,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1))))),
+        jnp.log(jax.random.uniform(ks[5], (h,), minval=1.0, maxval=16.0)))
+    slot = [1 + jnp.arange(decode)]
+    pos = [100 + 7 * jnp.arange(decode)]
+    for k, run in enumerate(runs):
+        slot.append(jnp.full((run,), decode + 1 + k))
+        pos.append(128 * k + jnp.arange(run))
+    pool = jax.random.normal(ks[6], (layers, decode + 4, n, h * p))
+    return ((x, dt, ld, b, c), pool,
+            (jnp.concatenate(slot).astype(jnp.int32),
+             jnp.concatenate(pos).astype(jnp.int32)))
+
+
+def mamba_walk_plan(slots, positions):
+    """A plan in ``ops/pallas/mamba2.sweep_plan``'s form with EVERY live row
+    a step of its own: a run walked row by row on its resident state, as
+    the kernel walked it until PR 54 (its one-row step is that kernel's
+    step), but on this kernel's grid, which holds no step for a dead row."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops.mamba2 import SWEEP_TILE
+    from megatron_llm_tpu.ops.retention import tick_runs
+
+    _, first, fresh = (np.asarray(t, np.int32)
+                       for t in tick_runs(slots, positions))
+    slots = np.asarray(slots)
+    rows = np.flatnonzero(slots > 0)
+    at = rows % SWEEP_TILE
+    words = np.zeros((4, slots.size), np.int32)
+    words[:, :rows.size] = [
+        slots[rows], 1 | first[rows] << 1 | fresh[rows] << 2,
+        rows // SWEEP_TILE, at | (at + 1) << 16]
+    return jnp.asarray(words), jnp.int32(max(rows.size, 1))
+
+
+def mamba_check(timed: bool):
+    """The Mamba-2 state sweep compiled, against ``ops/mamba2.mamba_tick``
+    (float32, ``highest``) on the same operands: by the tiles' plan, as the
+    engine runs it since PR 54, and by ``mamba_walk_plan`` (a step a live
+    row: the walk it was), the largest error of the outputs and of the
+    touched states each; ``timed``: the device time a call of each, the
+    kernel alone and with what the caller lays out for it.  The parent's
+    own kernel (a STATIC grid of R steps, dead rows among them) is not in
+    this tree: its ms a call at these shapes are in PERF.md section 6, PR
+    54."""
+    import statistics
+
+    from megatron_llm_tpu.ops import mamba2 as mb
+    from megatron_llm_tpu.ops.pallas import mamba2 as mk
+
+    jnp_form = jax.jit(lambda r, p, a: mb.mamba_tick(*r, p, *a, layer=0))
+    plans = {"tiles": mk.sweep_plan, "row walk": mamba_walk_plan}
+
+    calls = 4
+
+    def sweep(r, p, a, plan, layer):
+        return mk.planned_sweep(*r, p, a[0], layer, plan)
+
+    def layers(r, p, a, plan):       # ``calls`` layers' sweeps in one call
+        def layer(i, carry):
+            acc, p = carry
+            y, p = sweep(r, p, a, plan, i)
+            return acc + y, p
+        return jax.lax.fori_loop(
+            0, calls, layer, (jnp.zeros(r[0].shape, jnp.float32), p))
+
+    sweep, layers = jax.jit(sweep), jax.jit(layers)
+    for name, (decode, runs) in MAMBA_TICKS.items():
+        rows, pool, at = _mamba_tick(13, decode, runs, layers=1)
+        want_y, want = jnp_form(rows, pool, at)
+        live = sorted(set(int(s) for s in at[0]))
+        errs = {}
+        for form, plan in plans.items():
+            got_y, got = sweep(rows, pool, at, plan(*at), jnp.int32(0))
+            rest = [s for s in range(pool.shape[1]) if s not in live and s]
+            errs[form] = (max_err(got_y, want_y),
+                          max_err(got[0, live], want[0, live]),
+                          bool(jnp.array_equal(got[0, rest], pool[0, rest])))
+        (ey, es, kept), (wy, ws, wkept) = errs["tiles"], errs["row walk"]
+        steps = mb.sweep_steps(*at)
+        # held to what the row walk shows on the same operands (both are
+        # float32 sums in another order than the reference's), with room
+        # for the order alone
+        check(f"mamba sweep {name}",
+              ey <= max(2 * wy, 1e-4) and es <= max(2 * ws, 1e-4) and kept
+              and wkept,
+              f"max |dy| {ey:.2e} (row walk {wy:.2e}), max |dS| {es:.2e} "
+              f"(row walk {ws:.2e}) against mamba_tick at highest; |y| to "
+              f"{float(jnp.abs(want_y).max()):.1f}, |S| to "
+              f"{float(jnp.abs(want[0, live]).max()):.1f}; {steps} steps "
+              f"a block for {decode + sum(runs)} rows; the other slots as "
+              f"they were: {kept and wkept}")
+        if not timed:
+            continue
+        rows, pool, at = _mamba_tick(13, decode, runs, layers=calls)
+        took = {}
+        for form, plan in plans.items():
+            f = functools.partial(layers, rows, pool, at, plan(*at))
+            took[form] = (
+                statistics.median(kernel_seconds(f, kernel="mamba_sweep")),
+                busy_seconds(f) / calls)
+        (kt, bt), (kw, bw) = took["tiles"], took["row walk"]
+        print(f"TIME mamba sweep {name}: {kt * 1e3:.4f} ms a call of the "
+              f"kernel on the device ({bt * 1e3:.4f} with the caller's "
+              f"layouts), a step a live row {kw * 1e3:.4f} ({bw * 1e3:.4f}); "
+              f"{steps} steps a block against {decode + sum(runs)}",
+              flush=True)
+
+
 # the GigaChat cell's tick (256 rows on 128 slots and the null one, 4
 # linear layers, 16,384 conv channels): (decode rows, rows of the one
 # prompt run behind them); the rows left are dead
@@ -1175,6 +1316,12 @@ def main():
                          "shape against causal_conv (with --time: its ms a "
                          "call beside the write it had until PR 51), and "
                          "nothing else")
+    ap.add_argument("--mamba", action="store_true",
+                    help="the Mamba-2 state sweep at the Nemotron cell's "
+                         "tick shapes against its jnp form, the tiles' plan "
+                         "beside a plan of one-row steps, the walk it was "
+                         "until PR 54 (with --time: the ms a call of each), "
+                         "and nothing else")
     ap.add_argument("--brumby", action="store_true",
                     help="the retention state sweep at the Brumby-14B tick "
                          "shapes against its jnp form (with --time: its "
@@ -1192,9 +1339,9 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
-    if args.brumby or args.glu_stack or args.conv_tick:
+    if args.brumby or args.glu_stack or args.conv_tick or args.mamba:
         (brumby_check if args.brumby else glu_stack_check if args.glu_stack
-         else conv_tick_check)(args.time)
+         else mamba_check if args.mamba else conv_tick_check)(args.time)
         print(f"\n{len(FAILURES)} failures"
               + (f": {FAILURES}" if FAILURES else ""))
         sys.exit(1 if FAILURES else 0)
