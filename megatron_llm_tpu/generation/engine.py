@@ -142,13 +142,12 @@ from megatron_llm_tpu.generation.scheduling import (
     SchedulerState,
     get_policy,
 )
+from megatron_llm_tpu.observability import compiles as obs_compiles
 from megatron_llm_tpu.observability import flight as obs_flight
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.observability import trace as obs_trace
 from megatron_llm_tpu.observability.profiler import (
-    ProfileTrigger,
-    profile_dir,
-)
+    ProfileTrigger, profile_dir)
 from megatron_llm_tpu.generation.tokenization import detokenize_generations
 from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
@@ -329,6 +328,7 @@ class _Launched(NamedTuple):
 class ContinuousBatchingEngine:
     """Shared-tick decode over a prefix-cached paged pool."""
 
+    @obs_compiles.startup_phase("engine-build")
     def __init__(self, cfg, params, tokenizer=None, *,
                  max_slots: Optional[int] = None,
                  page_size: Optional[int] = None,
@@ -2253,14 +2253,14 @@ class ContinuousBatchingEngine:
                                 trace_id=req.trace_id):
                 if self.spec_k:
                     (self.pool.kv, self.pool.draft_kv,
-                     lp) = self._score_chunk(rows, kv_pages)(
+                     lp) = self._chunk_program(rows, kv_pages)(
                         self.params, self.draft_params,
                         self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
                         self._asarray(bt), self.pool.kv,
                         self.pool.draft_kv, self._asarray(targets))
                 else:
-                    self.pool.kv, lp = self._score_chunk(rows, kv_pages)(
+                    self.pool.kv, lp = self._chunk_program(rows, kv_pages)(
                         self.params, self._asarray(tokens),
                         self._asarray(np.asarray([start], np.int32)),
                         self._asarray(bt),
@@ -2773,7 +2773,7 @@ class ContinuousBatchingEngine:
                     jax.tree.map(self._asarray, pre_tables),
                     self._asarray(pre_index[:n_bucket]),
                     self._asarray(pre_hor[:n_bucket]))
-                tick_fn = self._ragged_tick(n_bucket)
+                tick_fn = self._tick_program(n_bucket)
                 moe = ()
                 if self.spec_k:
                     (self.pool.kv, self.pool.draft_kv,
@@ -2987,7 +2987,29 @@ class ContinuousBatchingEngine:
             self._m_phase["fetch"].observe(now - t_fetch)
             self._m_phase["apply"].observe(t_end - now)
             self._m_host_cpu["apply"].observe(c_end - c_apply)
+        if self.ticks == 1:     # the first tick has landed: start-up is over
+            print(f"[engine] {obs_compiles.summary()}", flush=True)
         return emitted
+
+    # A program's first call traces, lowers and compiles (or loads) it on
+    # the scheduler thread while every open stream waits: a ``tick-program``
+    # start-up phase around that call alone, so a tick whose program exists
+    # pays one dictionary lookup.  The two stand BELOW the launch: the line
+    # of the tick's call there is in the compile-cache key of the cells
+    # whose kernel sits nine frames under it (tools/tick_digest.py,
+    # ``callers``), so nothing above that line is added to.
+
+    def _tick_program(self, pre_rows: int):
+        new = pre_rows not in self._ragged_fns
+        fn = self._ragged_tick(pre_rows)
+        return obs_compiles.startup_phase(
+            "tick-program", rows=pre_rows)(fn) if new else fn
+
+    def _chunk_program(self, rows: int, kv_pages: int):
+        new = (rows, kv_pages) not in self._chunk_fns
+        fn = self._score_chunk(rows, kv_pages)
+        return obs_compiles.startup_phase(
+            "tick-program", rows=rows, kv_pages=kv_pages)(fn) if new else fn
 
     def _note_seq_pages_locked(self) -> None:  # holds _lock
         """Once an applied tick: slide the decoding sequences' windows up

@@ -35,7 +35,10 @@ from megatron_llm_tpu.generation.engine import EngineOverloaded
 from megatron_llm_tpu.generation.generation import InvalidRequest
 from megatron_llm_tpu.generation.scheduling import RequestShed
 from megatron_llm_tpu.observability import trace as obs_trace
-from megatron_llm_tpu.observability.compiles import install_compile_counter
+from megatron_llm_tpu.observability.compiles import (
+    install_compile_counter,
+    startup_phase,
+)
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.serving.streaming import (
     SSE_CONTENT_TYPE,
@@ -929,8 +932,10 @@ class MegatronServer:
         port — with ``port=0`` the OS picks a free one, which is how local
         fleets (tests, bench_decode --mode router) avoid port races.  Call
         ``serve()`` afterwards to block."""
-        install_compile_counter()  # mlt_jit_compiles_total on /metrics
-        self._httpd = ThreadingHTTPServer((host, port), self._make_handler())
+        install_compile_counter()  # mlt_jit_* on /metrics, the compile log
+        with startup_phase("server-bind"):
+            self._httpd = ThreadingHTTPServer((host, port),
+                                              self._make_handler())
         return self._httpd.server_address[1]
 
     def serve(self):

@@ -15,7 +15,9 @@ run:
 * ``profiler`` — on-demand ``jax.profiler`` windows (SIGUSR2,
   ``/profile?steps=N`` on the trainer, ``/profile?ticks=N`` on the
   generation server);
-* ``compiles`` — the program's own count of XLA compilations;
+* ``compiles`` — the program's own record of its XLA compilations (a
+  time-stamped log by function: traced, lowered, compiled cold or
+  loaded from the cache) and of its start-up phases;
 * ``flops``    — config-derived flops/MFU math shared by driver, bench
   and registry;
 * ``flight``   — per-request flight recorder: bounded event logs with an

@@ -52,6 +52,11 @@ from megatron_llm_tpu.observability import flight as flight_mod
 from megatron_llm_tpu.observability import flops as flops_mod
 from megatron_llm_tpu.observability import registry as registry_mod
 from megatron_llm_tpu.observability import trace as trace_mod
+from megatron_llm_tpu.observability.compiles import (
+    install_compile_counter,
+    startup_phase,
+    summary as startup_summary,
+)
 from megatron_llm_tpu.tokenizer.tokenizer import build_tokenizer
 from megatron_llm_tpu.training_step import (
     make_jitted_train_step,
@@ -375,7 +380,8 @@ def pretrain(
     from megatron_llm_tpu.core.distributed import initialize_distributed
 
     initialize_distributed()  # no-op single-host; pod autodetect multi-host
-    mesh = build_mesh_from_config(cfg)
+    with startup_phase("mesh"):
+        mesh = build_mesh_from_config(cfg)
     print0(f"mesh: {dict(mesh.shape)}")
     for _ax, _size in dict(mesh.shape).items():
         registry_mod.get_registry().gauge(
@@ -406,9 +412,7 @@ def pretrain(
         print0(f"observability: span tracing -> {obs.trace_dir} "
                f"(window {obs.trace_steps} steps, ring "
                f"{obs.trace_buffer_events} events)")
-    from megatron_llm_tpu.observability.compiles import install_compile_counter
-
-    install_compile_counter()  # mlt_jit_compiles_total on /metrics
+    install_compile_counter()  # mlt_jit_* on /metrics, the compile log
     profile_trigger = profiler_mod.ProfileTrigger(
         os.path.join(profile_dir, "ondemand"),
         max_captures=obs.profile_max_captures,
@@ -430,12 +434,13 @@ def pretrain(
         shapes = jax.eval_shape(init_fn, key)
         p_shardings = param_shardings(mesh, shapes)
         timers("model-setup", 0).start()
-        params = jax.jit(init_fn, out_shardings=p_shardings)(key)
-        step_fn, optimizer, shardings = make_jitted_train_step(
-            cfg, mesh, params, loss_fn=loss_fn, pipeline_hooks=pipeline_hooks,
-            pipeline_loss=pipeline_loss,
-        )
-        opt_state = shardings["opt_state_value"]
+        with startup_phase("model-setup"):
+            params = jax.jit(init_fn, out_shardings=p_shardings)(key)
+            step_fn, optimizer, shardings = make_jitted_train_step(
+                cfg, mesh, params, loss_fn=loss_fn,
+                pipeline_hooks=pipeline_hooks, pipeline_loss=pipeline_loss,
+            )
+            opt_state = shardings["opt_state_value"]
         timers("model-setup").stop()
         if cfg.parallel.pipeline_model_parallel_size > 1:
             from megatron_llm_tpu.parallel.pipeline import (
@@ -472,10 +477,12 @@ def pretrain(
         if cfg.checkpoint.load:
             try:
                 o_shardings = opt_state_shardings(cfg, mesh, params, opt_state)
-                params, loaded_opt, iteration, consumed_samples, _ = load_checkpoint(
-                    cfg, cfg.checkpoint.load, params, opt_state,
-                    p_shardings, o_shardings,
-                )
+                with startup_phase("checkpoint-load"):
+                    (params, loaded_opt, iteration, consumed_samples,
+                     _) = load_checkpoint(
+                        cfg, cfg.checkpoint.load, params, opt_state,
+                        p_shardings, o_shardings,
+                    )
                 if loaded_opt is not None:
                     opt_state = loaded_opt
                 print0(f"loaded checkpoint from {cfg.checkpoint.load} "
@@ -787,22 +794,34 @@ def pretrain(
                 first_step = False
                 if iteration not in (t.skip_iters or []):
                     # --skip_iters skips the update (training.py:397-399)
-                    with trace_mod.span("dispatch", iteration=iteration):
-                        params, opt_state, metrics_dev = cur_step_fn(
-                            params, opt_state, placed, iteration,
-                        )
-                        in_flight.append((iteration + 1, metrics_dev))
-                    timers.gauge("in-flight-depth", len(in_flight))
                     if warmup_time is None:
-                        # fence the compile step out of throughput so the
-                        # first training_log line is honest
-                        _retire()
-                        warmup_time = time.perf_counter() - dispatch_t0
+                        # the first step is the last phase of start-up:
+                        # fenced (retired inside the phase) so that the
+                        # compile stays out of throughput and the first
+                        # training_log line is honest
+                        with startup_phase("first-step") as first:
+                            with trace_mod.span("dispatch",
+                                                iteration=iteration):
+                                params, opt_state, metrics_dev = cur_step_fn(
+                                    params, opt_state, placed, iteration,
+                                )
+                                in_flight.append(
+                                    (iteration + 1, metrics_dev))
+                            timers.gauge("in-flight-depth", len(in_flight))
+                            _retire()
+                        warmup_time = first.t1 - first.t0
                         first_step = True
                         print0(f"first step (compile + warmup): "
                                f"{warmup_time:.2f}s — excluded from "
                                f"throughput averages", flush=True)
+                        print0(startup_summary(), flush=True)
                     else:
+                        with trace_mod.span("dispatch", iteration=iteration):
+                            params, opt_state, metrics_dev = cur_step_fn(
+                                params, opt_state, placed, iteration,
+                            )
+                            in_flight.append((iteration + 1, metrics_dev))
+                        timers.gauge("in-flight-depth", len(in_flight))
                         while len(in_flight) > depth:
                             _retire(1)
                 timers("train-step").stop()

@@ -41,7 +41,10 @@ def pin_cpu_platform(n_devices: int | None = None) -> None:
 
 def enable_compilation_cache() -> Optional[str]:
     """Place JAX's persistent compilation cache; every entry point calls
-    this before its first compile.  Returns the directory in use.
+    this before its first compile.  Returns the directory in use.  The
+    compile log (observability/compiles.py) starts here, whichever way
+    the cache goes, so the weights' build, an engine's constructor and
+    whatever else follows are in it.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and nothing is
     set in code.  Unset: :data:`REPO_CACHE_DIR` — except on a CPU backend,
@@ -49,6 +52,9 @@ def enable_compilation_cache() -> Optional[str]:
     that mis-load across toolchain updates)."""
     import jax
 
+    from megatron_llm_tpu.observability import compiles
+
+    compiles.install()
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

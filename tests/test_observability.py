@@ -1345,3 +1345,286 @@ def test_serving_profile_window_ends_when_engine_goes_idle(tmp_path):
         assert calls[3][2] - calls[2][2] == 1
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# (k) the compile log and the start-up phases (observability/compiles.py)
+# ---------------------------------------------------------------------------
+
+
+def _fed(marker):
+    """The log's rows whose function name carries ``marker`` (the log is
+    the process's: a test reads its own rows by name, not by position)."""
+    from megatron_llm_tpu.observability import compiles
+
+    return [r for r in compiles.log() if marker in r.fun_name]
+
+
+def _region(event, seconds, fun_name, inside=()):
+    """One timed region as jax fires it (``dispatch.log_elapsed_time``): a
+    scalar as it opens, whatever happens ``inside``, its duration."""
+    import jax.monitoring as mon
+
+    mon.record_scalar(event, 0.0, fun_name=fun_name)
+    for fire in inside:
+        fire()
+    mon.record_event_duration_secs(event, seconds, fun_name=fun_name)
+
+
+def test_compile_log_rows_outcomes_and_counters():
+    import time
+
+    import jax.monitoring as mon
+
+    from megatron_llm_tpu.observability import compiles as c
+
+    c.install()
+    reg = registry_mod.get_registry()
+    names = ("compiles", "compile_seconds", "cache_hits", "cold_compiles",
+             "cold_compile_seconds", "trace_seconds", "lower_seconds")
+    read = lambda: {n: reg.counter(f"mlt_jit_{n}_total").value  # noqa: E731
+                    for n in names}
+    before, t_before = read(), time.monotonic()
+    tracer = trace_mod.configure(capacity=256)
+    try:
+        _region(c.TRACE_EVENT, 0.5, "k1_f")
+        # a helper traced inside the outer trace makes no row; a constant
+        # compiled inside it makes its own and comes off the outer's seconds
+        _region(c.TRACE_EVENT, 1.0, "k1_outer", inside=(
+            lambda: _region(c.TRACE_EVENT, 0.25, "k1_where"),
+            lambda: _region(c.COMPILE_EVENT, 0.125, "jit(k1_const)")))
+        # an event with no opening scalar (fed by hand) is a region too
+        mon.record_event_duration_secs(c.LOWER_EVENT, 0.25,
+                                       fun_name="jit(k1_f)")
+        _region(c.COMPILE_EVENT, 0.0625, "jit(k1_f)", inside=(
+            lambda: mon.record_event(c.CACHE_HIT_EVENT),
+            # the cache's own retrieval time: inside the row's seconds
+            lambda: mon.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.03125)))
+        _region(c.COMPILE_EVENT, 2.0, "jit(k1_f)")
+        mon.record_event_duration_secs("/jax/some/other", 9.0, fun_name="k1_no")
+        mon.record_event("/jax/compilation_cache/cache_misses")
+    finally:
+        trace_mod.disable()
+    rows = _fed("k1_")
+    assert [tuple(r[1:]) for r in rows] == [
+        ("trace", "k1_f", 0.5, None),
+        ("compile", "jit(k1_const)", 0.125, "cold"),
+        ("trace", "k1_outer", 0.875, None),
+        ("lower", "jit(k1_f)", 0.25, None),
+        ("compile", "jit(k1_f)", 0.0625, "hit"),
+        ("compile", "jit(k1_f)", 2.0, "cold")]
+    stamps = [r.t_end for r in rows]
+    assert stamps == sorted(stamps)
+    assert t_before <= stamps[0] and stamps[-1] <= time.monotonic()
+    assert c.installed_at() <= stamps[0]
+    after = read()
+    assert {n: after[n] - before[n] for n in names} == {
+        "compiles": 3, "compile_seconds": 2.1875, "cache_hits": 1,
+        "cold_compiles": 2,
+        "cold_compile_seconds": 2.125, "trace_seconds": 1.375,
+        "lower_seconds": 0.25}
+    assert c.totals(rows) == {"compiles": 3, "hits": 1, "cold": 2,
+                              "compile_s": 2.1875, "trace_s": 1.375,
+                              "lower_s": 0.25}
+    marks = [e[5] for e in tracer.snapshot() if e[1] == "jit-compile"]
+    assert len(marks) == 6 and marks[4] == {
+        "fun": "jit(k1_f)", "stage": "compile", "seconds": 0.0625,
+        "outcome": "hit"}
+
+
+def test_cache_hit_marks_its_own_threads_compile_only():
+    import jax.monitoring as mon
+
+    from megatron_llm_tpu.observability import compiles as c
+
+    c.install()
+    hit_fired, other_done = threading.Event(), threading.Event()
+
+    def loads():       # thread A: a hit, then waits inside its region
+        def wait():
+            hit_fired.set()
+            assert other_done.wait(timeout=30)
+        _region(c.COMPILE_EVENT, 0.5, "jit(k2_a)", inside=(
+            lambda: mon.record_event(c.CACHE_HIT_EVENT), wait))
+
+    def compiles_cold():   # thread B: a whole compile meanwhile
+        assert hit_fired.wait(timeout=30)
+        _region(c.COMPILE_EVENT, 3.0, "jit(k2_b)")
+        other_done.set()
+
+    threads = [threading.Thread(target=f) for f in (loads, compiles_cold)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert [(r.fun_name, r.outcome) for r in _fed("k2_")] == [
+        ("jit(k2_b)", "cold"), ("jit(k2_a)", "hit")]
+
+
+def test_compile_log_is_bounded_and_a_second_install_does_not_double():
+    import jax.monitoring as mon
+    from jax._src import monitoring as mon_src
+
+    from megatron_llm_tpu.observability import compiles as c
+
+    c.install()
+    at = c.installed_at()
+    c.install()
+    c.install_compile_counter()      # the name the server and trainer call
+    assert c.installed_at() == at
+    assert mon_src.get_event_duration_listeners().count(c._on_duration) == 1
+    assert mon_src.get_event_listeners().count(c._on_event) == 1
+    for i in range(c.LOG_ROWS + 7):
+        mon.record_event_duration_secs(c.LOWER_EVENT, 0.001,
+                                       fun_name=f"k3_{i}")
+    log = c.log()
+    assert len(log) == c.LOG_ROWS
+    assert log[-1].fun_name == f"k3_{c.LOG_ROWS + 6}"
+    assert log[0].fun_name == "k3_7"      # the oldest rows dropped
+    log.clear()                           # a copy: the log keeps its rows
+    assert len(c.log()) == c.LOG_ROWS
+
+
+@pytest.mark.parametrize("way", ["environment", "cpu"])
+def test_enable_compilation_cache_installs_the_log_first(way, monkeypatch):
+    """Both early returns of ``enable_compilation_cache`` (a directory
+    from the environment; a CPU backend, which keeps no cache) come after
+    the installation."""
+    from megatron_llm_tpu.observability import compiles as c
+    from megatron_llm_tpu.utils import platform
+
+    calls = []
+    monkeypatch.setattr(c, "install", lambda: calls.append(way))
+    if way == "environment":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nowhere/cache")
+        assert platform.enable_compilation_cache() == "/nowhere/cache"
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert platform.enable_compilation_cache() is None
+    assert calls == [way]
+
+
+def test_persistent_cache_round_trip_reads_cold_then_hit(tmp_path):
+    """A real program through a real cache directory on this backend:
+    compiled, forgotten (``jax.clear_caches``), asked for again."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from megatron_llm_tpu.observability import compiles as c
+
+    c.install()
+    knobs = {"jax_compilation_cache_dir": str(tmp_path),
+             "jax_persistent_cache_min_compile_time_secs": 0,
+             "jax_persistent_cache_min_entry_size_bytes": 0}
+    old = {k: getattr(jax.config, k) for k in knobs}
+
+    def k4_round_trip(x):
+        return jnp.tanh(x) * 5.0 - 2.0
+
+    a = np.ones((3, 17), np.float32)
+    try:
+        for k, v in knobs.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        jax.jit(k4_round_trip)(a).block_until_ready()
+        jax.clear_caches()
+        jax.jit(k4_round_trip)(a).block_until_ready()
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    got = [(r.stage, r.outcome) for r in _fed("k4_round_trip")]
+    if not os.listdir(tmp_path):
+        pytest.skip("this backend wrote no entry to the compilation cache")
+    assert got == [("trace", None), ("lower", None), ("compile", "cold"),
+                   ("trace", None), ("lower", None), ("compile", "hit")]
+
+
+def test_startup_phase_gauge_row_and_ring_span():
+    from megatron_llm_tpu.observability import compiles as c
+
+    tracer = trace_mod.configure(capacity=64)
+    try:
+        with c.startup_phase("k5-phase", rows=3) as ph:
+            pass
+
+        @c.startup_phase("k5-whole-call")
+        def build(x):
+            return x + 1
+
+        assert build(1) == 2 and build(2) == 3
+    finally:
+        trace_mod.disable()
+    assert ph.t0 <= ph.t1
+    mine = [p for p in c.phases() if p[0].startswith("k5-")]
+    assert mine[0] == ("k5-phase", ph.t0, ph.t1, {"rows": 3})
+    # as a decorator: a phase of its own for every call
+    assert [p[0] for p in mine[1:]] == ["k5-whole-call"] * 2
+    assert mine[1][2] <= mine[2][1]
+    gauge = registry_mod.get_registry().gauge(
+        "mlt_startup_phase_seconds", labels={"phase": "k5-phase"})
+    assert gauge.value == ph.t1 - ph.t0
+    spans = [e for e in tracer.snapshot() if e[0] == "X" and e[1] == "startup"]
+    assert [e[5] for e in spans] == [
+        {"phase": "k5-phase", "rows": 3}, {"phase": "k5-whole-call"},
+        {"phase": "k5-whole-call"}]
+
+
+def _summary_lines(text):
+    return [l for l in text.splitlines() if "start-up: " in l]
+
+
+def test_engine_says_its_start_up_once(capsys):
+    import re
+
+    import jax
+
+    from megatron_llm_tpu.generation import ContinuousBatchingEngine
+    from megatron_llm_tpu.models import init_model_params, make_config
+    from megatron_llm_tpu.observability import compiles as c
+    from tests.test_generation import VOCAB, ToyTokenizer
+
+    cfg = make_config(
+        "llama2", num_layers=1, hidden_size=32, num_attention_heads=2,
+        num_attention_heads_kv=2, ffn_hidden_size=64, seq_length=64,
+        max_position_embeddings=128, vocab_size=VOCAB,
+        params_dtype="float32", use_flash_attn=False,
+    )
+    params = init_model_params(cfg, jax.random.PRNGKey(0))
+    engine = ContinuousBatchingEngine(cfg, params, ToyTokenizer(),
+                                      max_slots=2, max_seq=64)
+    for prompt in ([5, 6, 7], [8, 9]):
+        engine.submit(prompt, 3, use_eod_for_termination=False)
+        engine.run_until_idle()
+    assert engine.ticks > 2
+    (line,) = _summary_lines(capsys.readouterr().out)
+    assert line.startswith("[engine] start-up: ")
+    names = [p[0] for p in c.phases()]     # the process's: read the tail
+    last_build = len(names) - 1 - names[::-1].index("engine-build")
+    assert "tick-program" in names[last_build + 1:]
+    assert "engine-build" in line and "tick-program rows=" in line
+    m = re.search(r"compiles (\d+) \((\d+) hit, (\d+) cold\) [\d.]+ s, "
+                  r"trace [\d.]+ s, lower [\d.]+ s, in the log's first", line)
+    assert m and int(m.group(1)) == int(m.group(2)) + int(m.group(3))
+
+
+def test_pretrain_says_its_start_up_once(capsys):
+    from megatron_llm_tpu.observability import compiles as c
+    from megatron_llm_tpu.training import pretrain
+
+    result = pretrain(_tiny_cfg(train_iters=2),
+                      data_iterators_provider=_provider())
+    assert result["iteration"] == 2
+    (line,) = _summary_lines(capsys.readouterr().out)
+    said = line.split(";")[0]
+    order = [said.index(p) for p in ("mesh ", "model-setup ", "first-step ")]
+    assert order == sorted(order) and "checkpoint-load" not in said
+    # one pair of clock stamps: the phase's seconds ARE the warm-up time
+    first = [p for p in c.phases() if p[0] == "first-step"][-1]
+    assert result["warmup_time"] == first[2] - first[1]
+    assert registry_mod.get_registry().gauge(
+        "mlt_startup_phase_seconds",
+        labels={"phase": "first-step"}).value == result["warmup_time"]

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""The sha256 of every serving cell's ragged tick as it is LOWERED for a TPU
-(no chip: a virtual v5e topology, abstract parameters and pools at the
-cell's own flags), Mosaic payloads and all.
+"""The sha256 of every serving cell's ragged tick, and of every train cell's
+step, as it is LOWERED for a TPU (no chip: a virtual v5e topology, abstract
+parameters, pools and optimizer state at the cell's own flags), Mosaic
+payloads and all.
 
     JAX_PLATFORMS=cpu python tools/tick_digest.py [--root DIR] [cell ...]
 
@@ -12,17 +13,36 @@ models/language_model.py, ops/paged_attention.py).  A diagnostic: run the
 tool on the parent (``--root`` a ``git archive`` of it) and on the change AT
 ONE PATH (the payload carries file names too: a symbolic link turned from
 one tree to the other, and ``--root`` the link), and compare the lines.  One
-line a cell and prompt-row shape: ``<cell> rows=<n> <sha256>``.
+line a serving cell and prompt-row shape, ``<cell> rows=<n> <sha256>``, and
+one a train cell, ``<cell> train_step <sha256>`` (``make_jitted_train_step``
+on the batch the cell's mix makes).
 A line that differs costs that cell one cold compile on the change's side
-(PERF.md section 6, PRs 52 and 53)."""
+(PERF.md section 6, PRs 52 and 53).
+
+The tool lowers ``make_ragged_tick_fn`` and ``make_jitted_train_step``
+itself, so no frame of ``generation/engine.py`` or ``training.py`` is on
+its stack: where the program's caller would stand, this file does.  The
+frames are kept from the kernel outwards, so a payload that names THIS file
+is one whose tenth frame is the caller of the tick (a kernel nine frames
+under ``tick``: the retention and the gated-delta sweeps), and in a serving
+process that frame is the engine's call of the tick program.  The last two
+lines say so: ``payload files: ...`` (every source file a payload names) and
+``callers: <cells whose payloads name the tick's caller>; engine.py calls
+tick_fn at <line:col-line:col> ...``.  They too have to be equal on parent
+and change: a line added above that call in ``generation/engine.py`` makes
+those cells' ticks new programs.  No train cell's payload reaches its
+caller."""
 
 from __future__ import annotations
 
 import argparse
+import ast
+import base64
 import functools
 import hashlib
 import json
 import os
+import re
 import sys
 
 
@@ -32,6 +52,62 @@ def lowered_text(tick, *operands) -> str:
     import jax
 
     return jax.jit(tick, donate_argnums=(1,)).lower(*operands).as_text()
+
+
+def payload_files(text: str) -> set:
+    """The source files named inside the Mosaic payloads of a lowered
+    program (``backend_config``'s ``body``: base64 of the kernel's module,
+    locations and all)."""
+    named = set()
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        named.update(m.decode() for m in re.findall(
+            rb"[\w/.\-]+\.py", base64.b64decode(body)))
+    return named
+
+
+def engine_call_sites(root: str) -> list:
+    """Where ``generation/engine.py`` calls a tick program (``tick_fn(...)``),
+    as the location a payload would carry: ``line:col-line:col``."""
+    path = os.path.join(root, "megatron_llm_tpu", "generation", "engine.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    return [f"{n.lineno}:{n.col_offset}-{n.end_lineno}:{n.end_col_offset}"
+            for n in ast.walk(tree) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Name) and n.func.id == "tick_fn"]
+
+
+def train_step_text(cell, mesh) -> str:
+    """A train cell's step as ``training.pretrain`` builds it for the
+    benchmark (``benchmark/lib/kind_train.py``: the mix's sequence length
+    and micro-batch, one chip), lowered on abstract state."""
+    import jax
+    import jax.numpy as jnp
+
+    from megatron_llm_tpu.config.arguments import parse_args
+    from megatron_llm_tpu.core.parallel_state import global_mesh
+    from megatron_llm_tpu.models import init_model_params
+    from megatron_llm_tpu.optimizer.optimizer import get_optimizer
+    from megatron_llm_tpu.training_step import make_jitted_train_step
+
+    mix = cell.traffic
+    seq, mbs = int(mix["seq_length"]), int(mix["micro_batch_size"])
+    gbs = mbs * int(mix.get("micro_batches_per_step", 1))
+    cfg = parse_args(cell.flags(dict(
+        seq_length=seq, micro_batch_size=mbs, global_batch_size=gbs,
+        train_iters=10 ** 9, eval_iters=0, eval_interval=10 ** 9,
+        log_interval=10 ** 9)), n_devices=1)
+    with global_mesh(mesh):
+        params = jax.eval_shape(functools.partial(
+            init_model_params, cfg), jax.random.PRNGKey(0))
+        opt = get_optimizer(cfg, params)
+        opt_state = jax.eval_shape(opt.init, params)
+        step, _, _ = make_jitted_train_step(
+            cfg, mesh, params, optimizer=opt, opt_state=opt_state)
+        batch = {k: jax.ShapeDtypeStruct((gbs, seq), t) for k, t in (
+            ("tokens", jnp.int32), ("labels", jnp.int32),
+            ("loss_mask", jnp.float32), ("position_ids", jnp.int32))}
+        return step.lower(params, opt_state, batch,
+                          jax.ShapeDtypeStruct((), jnp.int32)).as_text()
 
 
 def main() -> int:
@@ -67,12 +143,20 @@ def main() -> int:
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
+    named, reach_caller = set(), []
+    me = os.path.abspath(__file__)
     for entry in bench["workloads"]:
         name = entry["name"]
         if args.cells and name not in args.cells:
             continue
         cell = cells_mod.Cell(name, root)
         if cell.traffic["kind"] == "train":
+            text = train_step_text(cell, mesh)
+            named |= payload_files(text)
+            if me in payload_files(text):
+                reach_caller.append(name)
+            print(f"{name} train_step "
+                  f"{hashlib.sha256(text.encode()).hexdigest()}", flush=True)
             continue
         cfg = parse_args(cell.flags())
         inf = cfg.inference
@@ -114,9 +198,17 @@ def main() -> int:
                     S((slots,), jnp.float32), S((slots,), jnp.int32),
                     S((slots,), jnp.float32), S((slots,), jnp.int32),
                     S((slots,), jnp.bool_), *pre)
+                named |= payload_files(text)
+                if me in payload_files(text) and name not in reach_caller:
+                    reach_caller.append(name)
                 print(f"{name} rows={rows} "
                       f"{hashlib.sha256(text.encode()).hexdigest()}",
                       flush=True)
+    print("payload files: " + " ".join(sorted(
+        os.path.relpath(f, root) if os.path.isabs(f) else f for f in named)),
+        flush=True)
+    print(f"callers: {' '.join(reach_caller) or 'none'}; engine.py calls "
+          f"tick_fn at {' '.join(engine_call_sites(root))}", flush=True)
     return 0
 
 
