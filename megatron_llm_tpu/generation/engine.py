@@ -153,8 +153,13 @@ from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
     model_forward,
 )
+from megatron_llm_tpu.generation.ragged import decode_order
+from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
-from megatron_llm_tpu.ops.pallas.paged_attention import tile_runs
+from megatron_llm_tpu.ops.pallas.paged_attention import (
+    tile_runs,
+    tile_shares,
+)
 
 
 def _request_key(seed: int) -> np.ndarray:
@@ -359,7 +364,10 @@ class ContinuousBatchingEngine:
         # may keep a constant-size state a sequence (:class:`StatePool`):
         # in place of pages (power retention: the slot is the one "page" a
         # sequence holds) or beside a page class (a hybrid)
-        from megatron_llm_tpu.models.transformer import pool_classes
+        from megatron_llm_tpu.models.transformer import (
+            layer_kinds,
+            pool_classes,
+        )
 
         classes = pool_classes(cfg)
         self.state = any(cls.state for cls in classes)
@@ -906,6 +914,20 @@ class ContinuousBatchingEngine:
                  "positions is walked once, any other row on its own "
                  "(ops/pallas/paged_attention.tile_runs); rows over walks "
                  "is how often the shared walk engages")
+        self._m_paged_seen = reg.counter(
+            "mlt_engine_paged_blocks_seen_total",
+            help="compute blocks (the kernel's step: several pages) under "
+                 "the masks of those rows, summed over rows, times the "
+                 "attention layers of the rows' page class")
+        self._m_paged_fetched = reg.counter(
+            "mlt_engine_paged_blocks_fetched_total",
+            help="compute blocks the kernel's page walks fetched for them: "
+                 "a run's blocks once a tile, the blocks that rows of one "
+                 "tile name alike (sequences on one cached prefix, laid "
+                 "side by side by the tick) once a span, every other block "
+                 "once a row (ops/pallas/paged_attention.tile_shares); "
+                 "fetched over seen is the share of its rows' blocks the "
+                 "kernel reads")
         # the state class's own (zero for a paged model): what the tick's
         # state sweep ran on, counted on the host from each tick's plan
         self.state_recomputed_tokens = 0
@@ -946,6 +968,16 @@ class ContinuousBatchingEngine:
         self._pools = [self.pool] + [
             pl for pl in (self.wpool, self.spool)
             if pl not in (None, self.pool)]
+        # what the paged kernel walks of a page class, for the host's
+        # count of its blocks: (attention layers, their window, bytes of
+        # this engine's share of a token's row)
+        self._walked = []
+        for cls, pl in zip(classes, self._pools):
+            if not cls.state:
+                leaf = kv_quant.values_of(pl.kv)
+                self._walked.append((
+                    leaf.shape[0], layer_kinds(cfg)[cls.places[0]].window,
+                    leaf.shape[-1] * leaf.dtype.itemsize // self._tp))
         self._m_dry_class = {
             pl.page_class: reg.counter(
                 "mlt_engine_pool_dry_ticks_total",
@@ -2856,22 +2888,40 @@ class ContinuousBatchingEngine:
             self._m_state["steps"].inc(steps)
         if not self._state_only and obs_registry.publishing():
             # the tick's rows as the program lays them out, by the kernel's
-            # own rule: a slot's verify rows and a request's prompt rows
-            # stand at consecutive positions of one table, and consecutive
-            # is all the rule reads of a position
+            # own rules: the slots in the tick's order (a slot's verify
+            # rows together), a request's prompt rows behind them, on the
+            # tables the launch read
+            with self._lock:
+                null = np.zeros((1, self.pages_per_seq), np.int32)
+                tables = [
+                    np.concatenate([null, mine, packed])
+                    for mine, packed, _ in zip(
+                        (self._block_tables, self._wtables),
+                        pre_tables if isinstance(pre_tables, tuple)
+                        else (pre_tables,), self._walked)]
+                pos = self._positions + ahead
+            tables[0][1 + np.asarray(spent, np.int64)] = NULL_PAGE
+            order = decode_order(tables[0][1:1 + self.max_slots])
             at = np.arange(self.spec_k + 1)
             on = np.zeros((self.max_slots, at.size), bool)
             on[active] = at <= k_eff[active, None]
-            slot = 1 + np.arange(self.max_slots)[:, None]
+            on, pos = on[order], pos[order, None] + at
             pre = pre_index[:n_bucket]
-            shared, live = tile_runs(
-                np.concatenate([(on * slot).ravel(),
-                                np.where(pre >= 0, 1 + slot.size + pre, 0)]),
-                np.concatenate([(on * at).ravel(), pre_pos[:n_bucket]]),
-                np.concatenate([(on * (at + 1)).ravel(),
+            rows = (
+                np.concatenate([(on * (1 + order[:, None])).ravel(), np.where(
+                    pre >= 0, 1 + self.max_slots + pre, 0)]),
+                np.concatenate([(on * pos).ravel(), pre_pos[:n_bucket]]),
+                np.concatenate([(on * _bucket_up(pos + 1)).ravel(),
                                 pre_hor[:n_bucket]]))
+            shared, live = tile_runs(*rows)
             self._m_paged_rows.inc(int(live.sum()))
             self._m_paged_walks.inc(int(np.where(shared, 1, live).sum()))
+            for table, (layers, window, row) in zip(tables, self._walked):
+                seen, fetched = tile_shares(
+                    table, *rows, window=window, page=self.page_size,
+                    row_bytes=row).blocks()
+                self._m_paged_seen.inc(layers * int(seen))
+                self._m_paged_fetched.inc(layers * int(fetched))
         # the tick before lands while the device runs this one; this one
         # too where the host cannot know its outcome's shape beforehand
         lag = 0 if self.spec_k or did_lp else 1

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from megatron_llm_tpu.generation import generation as gen
 from megatron_llm_tpu.generation.sampling import (
@@ -84,7 +85,8 @@ from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
     model_forward,
 )
-from megatron_llm_tpu.ops.paged_attention import PagedState
+from megatron_llm_tpu.models.transformer import layer_kinds, pool_classes
+from megatron_llm_tpu.ops.paged_attention import PagedState, plan_walks
 
 
 def row_horizons(positions: jax.Array) -> jax.Array:
@@ -94,6 +96,23 @@ def row_horizons(positions: jax.Array) -> jax.Array:
     only on (tokens, positions), never on tick composition."""
     b = gen.BUCKET
     return ((positions // b) + 1) * b
+
+
+def decode_order(block_tables):
+    """The order a tick runs its decode slots in: ``[b]`` slot ids, the
+    slots that name the same first page of the class that keeps every key
+    side by side (sequences on one cached prefix name the same pages, and
+    rows that stand in one tile of the paged kernel walk them ONCE:
+    ops/pallas/paged_attention.tile_shares), dead slots (the null table)
+    last, slot order otherwise.  The rows of a tick are independent through
+    the whole forward and everything a slot keeps is addressed by its
+    table, so the order moves no number.  numpy on the host (the engine
+    counts the walks of the tick it planned), traced in the tick."""
+    first = block_tables[:, 0]
+    last = np.iinfo(np.int32).max
+    if isinstance(first, jax.Array):
+        return jnp.argsort(jnp.where(first == 0, last, first), stable=True)
+    return np.argsort(np.where(first == 0, last, first), kind="stable")
 
 
 def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
@@ -178,6 +197,32 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
          else ("decode-fwd" if tp == 1 else f"decode-fwd-tp{tp}"))
     scope_d = "draft-fwd" if tp == 1 else f"draft-fwd-tp{tp}"
 
+    # a model that keeps pages runs its decode slots in decode_order; one
+    # that keeps a state a sequence and no page has nothing to lay side by
+    # side
+    classes = pool_classes(cfg)
+    paged = not all(cls.state for cls in classes)
+    # a page class's attention layers all call the paged kernel on the
+    # tick's tables and rows under one window: which walks it shares is
+    # worked out once a class in front of them (on one chip; a shard of
+    # the heads or a stage's microbatch reads it off its own call)
+    latent = bool(cfg.model.mla)
+    planned = {} if tp > 1 or ppc is not None else {
+        c: layer_kinds(cfg)[cls.places[0]].window
+        for c, cls in enumerate(classes) if not cls.state}
+
+    def with_walks(state, pool_kv):
+        classed = isinstance(state.block_tables, tuple)
+        walks = [None] * len(classes)
+        for c, window in planned.items():
+            walks[c] = plan_walks(
+                pool_kv[c] if classed else pool_kv,
+                state._replace(block_tables=state.block_tables[c])
+                if classed else state,
+                cfg.model.kv_channels, sliding_window=window,
+                latent=latent)
+        return state._replace(walks=tuple(walks) if classed else walks[0])
+
     moe = cfg.model.num_experts is not None
     # what of the router's aux vector rides the fetch (models/moe.py)
     moe_stats = slice(2, 7) if moe and (
@@ -194,7 +239,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
                 position_ids=pos[:, None],
                 rope_cache=make_rope_cache(cfg),
                 kv_caches=pool_kv,
-                paged=PagedState(tbl, pos, hor, idx),
+                paged=with_walks(PagedState(tbl, pos, hor, idx), pool_kv),
                 return_aux=True,
             )
         return logits[:, 0], pool_kv, aux
@@ -277,6 +322,11 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
         slot_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), S)
         flat_idx = jnp.where(live, 1 + slot_ids, 0)
         flat_hor = row_horizons(flat_pos)
+        # the slots' verify blocks in decode_order, a block's rows together
+        order = decode_order(block_tables)
+        flat_tok, flat_pos, flat_idx, flat_hor = (
+            a.reshape(b, S)[order].reshape(b * S)
+            for a in (flat_tok, flat_pos, flat_idx, flat_hor))
         if prefill_rows:
             all_tok = jnp.concatenate([flat_tok, pre_tok])
             all_pos = jnp.concatenate([flat_pos, pre_pos])
@@ -291,7 +341,8 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             all_tbl = jnp.concatenate([null_tbl, block_tables])
         out, pool_kv, _ = target_forward(
             params, pool_kv, all_tbl, all_idx, all_pos, all_tok, all_hor)
-        t_logits = out[: b * S].reshape(b, S, -1)      # [b, K+1, v_padded]
+        # [b, K+1, v_padded], back in slot order
+        t_logits = out[: b * S].reshape(b, S, -1)[jnp.argsort(order)]
 
         rep = lambda x: jnp.repeat(x, S, axis=0)  # noqa: E731
         t_filt_flat, t_greedy_flat = filtered_logits_per_slot(
@@ -331,28 +382,39 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             null_tbl = jnp.zeros((1, tables[0].shape[1]), tables[0].dtype)
             return jnp.concatenate([null_tbl, *tables])
 
-        tokens = jnp.where(carried, carry_tok, tokens)
-        idx = 1 + jnp.arange(b, dtype=jnp.int32)
-        hor = row_horizons(positions)
+        all_tok = jnp.where(carried, carry_tok, tokens)
+        all_pos = positions
+        all_idx = 1 + jnp.arange(b, dtype=jnp.int32)
+        if paged:
+            order = decode_order(jax.tree.leaves(block_tables)[0])
+            all_tok, all_pos, all_idx = (
+                a[order] for a in (all_tok, all_pos, all_idx))
+        all_hor = row_horizons(all_pos)
         if prefill_rows:
-            all_tok = jnp.concatenate([tokens, pre_tok])
-            all_pos = jnp.concatenate([positions, pre_pos])
+            all_tok = jnp.concatenate([all_tok, pre_tok])
+            all_pos = jnp.concatenate([all_pos, pre_pos])
             all_idx = jnp.concatenate(
-                [idx, jnp.where(pre_index >= 0, 1 + b + pre_index, 0)])
+                [all_idx, jnp.where(pre_index >= 0, 1 + b + pre_index, 0)])
             all_tbl = jax.tree.map(with_null, block_tables, pre_tables)
-            all_hor = jnp.concatenate([hor, pre_hor])
+            all_hor = jnp.concatenate([all_hor, pre_hor])
         else:
-            all_tok, all_pos, all_idx, all_hor = (
-                tokens, positions, idx, hor)
             all_tbl = jax.tree.map(with_null, block_tables)
         out, pool_kv, aux = target_forward(
             params, pool_kv, all_tbl, all_idx, all_pos, all_tok, all_hor)
         last = out[:b]
         keys = jax.vmap(jax.random.fold_in)(req_keys, steps)
+        if paged:
+            # a row is sampled where it stands, with its slot's key and
+            # settings; only the [b] results go back to slot order
+            keys, top_k, top_p, temperature = (
+                a[order] for a in (keys, top_k, top_p, temperature))
         next_tok = sample_per_slot(
             keys, last, top_k=top_k, top_p=top_p,
             temperature=temperature, vocab_size=cfg.model.vocab_size)
         logp = gen._gather_token_log_probs(last, next_tok)
+        if paged:
+            next_tok, logp = (jnp.zeros_like(a).at[order].set(a)
+                              for a in (next_tok, logp))
         res = (pool_kv, next_tok, logp, positions + 1, steps + 1)
         return res + (aux[moe_stats],) if moe else res
 

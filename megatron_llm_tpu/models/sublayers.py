@@ -419,7 +419,9 @@ def sublayer_stack_forward(cfg, stacked_layers: Params, hidden: jax.Array, *,
         if in_carry and mx in class_of:
             c = class_of[mx]
             cache = LayerPool(pool[c], rank)
-            layer_paged = paged._replace(block_tables=paged.block_tables[c])
+            layer_paged = paged._replace(
+                block_tables=paged.block_tables[c],
+                walks=paged.walks and paged.walks[c])
         if mx == "mamba":
             out, new = mamba_sublayer(cfg, p, ln, kv_cache=cache,
                                       paged=layer_paged)

@@ -732,6 +732,7 @@ def _attention(cfg, p, x, rope, position_ids, segment_ids, dropout_key,
                 paged.horizons,
                 scale=scale, sliding_window=kind.window,
                 use_kernel=cfg.training.use_flash_attn, layer=layer,
+                walks=paged.walks,
             )
         elif s == 1:
             ctx = paged_attention_decode(
@@ -928,7 +929,8 @@ def _mla_paged(cfg, q_nope, q_rope, c_kv, k_rope, w_ukv, cache: LayerPool,
     horizons = paged.horizons if paged.horizons is not None else pos + 1
     u = paged_attention_ragged(
         q_abs, pool, tables, index, pos, horizons, scale=scale,
-        use_kernel=cfg.training.use_flash_attn, layer=layer, latent=True)
+        use_kernel=cfg.training.use_flash_attn, layer=layer, latent=True,
+        walks=paged.walks if paged.table_index is not None else None)
     ctx = jnp.einsum("bsnr,rnd->bsnd", u[..., :r].reshape(b, s, n, r),
                      w_ukv[..., nope:])
     return ctx, pool
@@ -1485,7 +1487,9 @@ def transformer_forward(
             cache = LayerPool(
                 pool[c], before + (layer_idx - layer_offset)
                 // len(class_at) * per_period + c_rank)
-            layer_paged = paged._replace(block_tables=paged.block_tables[c])
+            layer_paged = paged._replace(
+                block_tables=paged.block_tables[c],
+                walks=paged.walks and paged.walks[c])
         elif in_carry:
             cache = LayerPool(pool, layer_idx - pool_first_layer)
         if all_experts is not None:

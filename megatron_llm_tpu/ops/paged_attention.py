@@ -20,8 +20,10 @@ Two implementations with the same fp32-softmax numerics:
   block's pages into VMEM while the current one is scored (no [b, max_seq]
   gather ever materializes; slots past the context are never looked up)
   and the online-softmax accumulator carries across blocks.  A tile of
-  consecutive rows of one sequence shares ONE walk and one matmul; any
-  other row walks alone.
+  consecutive rows of one sequence shares ONE walk and one matmul; rows
+  of different sequences that stand in one tile share one over the
+  compute blocks in which their tables name the same pages (a cached
+  prefix); any other row, and what is a row's own, walks alone.
 * the jnp path below — gathers the block-tabled pages into a dense
   [b, max_seq] view and reuses :func:`ops.attention.xla_attention`.  It
   matches the dense-cache decode path on the same context (the parity
@@ -79,6 +81,12 @@ class PagedState(NamedTuple):
     # once per row.
     horizons: Optional[jax.Array] = None     # [R] int32 or None
     table_index: Optional[jax.Array] = None  # [R] int32 into block_tables
+    # which of these rows' page walks the Pallas kernel shares
+    # (:func:`plan_walks`), worked out ONCE in front of a tick's layers,
+    # which all call the kernel on these very tables and rows: one a page
+    # class beside ``block_tables`` where those are a tuple.  None: the
+    # kernel reads it off its own call
+    walks: Optional[object] = None
 
 
 def paged_gather_kv(pool, block_tables: jax.Array, d: int, dtype=None,
@@ -93,6 +101,27 @@ def paged_gather_kv(pool, block_tables: jax.Array, d: int, dtype=None,
     row is its value too."""
     heads = kv_quant.dequant_gather(pool, block_tables, d, dtype, layer)
     return (heads, heads) if latent else kv_quant.split_kv(heads)
+
+
+def plan_walks(pool, paged: PagedState, d: int, *,
+               sliding_window: Optional[int] = None, latent: bool = False):
+    """``ops/pallas/paged_attention.tile_shares`` of a ragged ``paged``
+    state on ``pool`` (a page class's leaf, layered or not) under
+    ``sliding_window``: what every layer's kernel call on that class would
+    read off its own arguments, for ``paged.walks``.  None where the call
+    does not take the kernel, or takes it a shard of the heads (a shard's
+    row is not the pool's)."""
+    from megatron_llm_tpu.core import parallel_state as ps
+
+    if (paged.table_index is None or _kernel_refusal(pool, d, latent)
+            or (ps.mesh_is_initialized()
+                and ps.get_tensor_model_parallel_world_size() > 1)):
+        return None
+    values = kv_quant.values_of(pool)
+    return pallas_paged.tile_shares(
+        paged.block_tables, paged.table_index, paged.positions,
+        paged.horizons, window=sliding_window, page=values.shape[-2],
+        row_bytes=values.shape[-1] * values.dtype.itemsize)
 
 
 def paged_attention_decode(
@@ -150,6 +179,7 @@ def paged_attention_ragged(
     use_kernel: bool = True,
     layer=None,
     latent: bool = False,
+    walks=None,
 ) -> jax.Array:
     """One RAGGED batch of paged attention; returns [R, 1, n_heads, d].
 
@@ -163,10 +193,16 @@ def paged_attention_ragged(
     once, not 64 times; the kernel reads the same fact off the rows
     (ops/pallas/paged_attention.tile_runs): 8 consecutive rows of one
     table at consecutive positions share ONE page walk and one matmul a kv
-    head, so a 64-row chunk is walked 8 times, and a row with no such
-    neighbours (every decode row) once for itself.  One launch serves any
-    mix; the composition lives entirely in the data-carried metadata, so
-    changing it never recompiles.
+    head, so a 64-row chunk is walked 8 times.  By its second rule
+    (``tile_shares``) rows of DIFFERENT sequences in one tile whose tables
+    name the same pages over whole compute blocks — decode rows on one
+    cached prefix, which the tick lays side by side
+    (generation/ragged.decode_order) — share one walk of those blocks, and
+    each walks what is its own (the blocks before the last window start
+    among them, its own pages behind the prefix) for itself; a row with no
+    such neighbours walks once for itself.  One launch serves any mix; the
+    composition lives entirely in the data-carried metadata, so changing
+    it never recompiles.
 
     Numerics contract (tests/test_ragged_tick.py): row ``i`` computes the
     s=1 decode attention at ``positions[i]`` over its own table — bitwise
@@ -184,6 +220,9 @@ def paged_attention_ragged(
     ``_mla_paged``): one shared key row a token whose own values are the
     value, so the output has the key's width and the caller keeps the
     leading ``kv_lora_rank`` values.
+
+    ``walks`` is :func:`plan_walks` of these very arguments, where a caller
+    worked it out once for a tick's layers (``PagedState.walks``).
     """
     assert q.ndim == 4 and q.shape[1] == 1, "ragged rows are [R, 1, n, d]"
     b, _, n, d = q.shape
@@ -194,6 +233,7 @@ def paged_attention_ragged(
             pallas_paged.paged_ragged_kernel, q, pool, layer,
             (tables, table_index, positions, horizons),
             scale=scale, sliding_window=sliding_window, latent=latent,
+            shares=walks,
         )
 
     # fallback: gather each UNIQUE table's pages once, batch the score

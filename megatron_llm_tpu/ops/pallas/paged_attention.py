@@ -28,19 +28,30 @@ the blockwise scheme of ops/pallas/flash_attention.py with blocks of pages
 as KV blocks.  GQA is native (q grouped [tiles, nkv, TILE*group, d], no
 K/V expansion).
 
-WHO SHARES A WALK is read from the call's own data (:func:`tile_runs`, the
-one rule; the engine counts by it too): a tile whose rows are ONE RUN — all
+WHO SHARES A WALK is read from the call's own data, by two rules the engine
+counts by too.  :func:`tile_runs`: a tile whose rows are ONE RUN — all
 live, on one table, at consecutive positions: a prompt chunk's rows, a
 verify block that fills a tile — is walked ONCE, from its first row's
 window to its last row's position, with one score matmul and one value
 matmul a kv head for all ``TILE * group`` query rows, the causal (and
 window) mask and the softmax state per row.  A block outside one row's mask
 leaves that row's state as it was (alpha 1, p 0), so each row's result is
-what a walk of its own gives.  Any other tile — decode rows, a table each;
-a tile where one request's rows end and the next one's begin — walks its
-live rows one after another inside the program, ``group`` query rows a
-matmul: a row alone costs what it cost when the grid ran over rows.  Which
-of the two a tile takes is data, so a tick's composition never recompiles.
+what a walk of its own gives.  :func:`tile_shares`: in any other tile, rows
+of DIFFERENT sequences whose tables name the same pages over a range of
+compute blocks — sequences on one cached prefix, which the tick lays side
+by side (generation/ragged.decode_order) — are served that range by ONE
+walk too, the tile's rows in the matmul and the others masked, and each
+row walks what is its own before it (a window's first blocks) and behind it
+(its own pages) alone, ``group`` query rows a matmul: every row meets the
+blocks it met alone, in the same order, with the same arithmetic a row.  A
+tile holds up to two such spans (two prefixes meet in it); a run is one
+span, whole.  Rows that agree with nobody — decode rows on a table each —
+walk one after another inside the program: a row alone costs what it cost
+when the grid ran over rows.  All of it is data (``rows_ref`` /
+``span_ref`` / ``part_ref``: each row's own head and tail, each span's
+range, the parts of its program a tile takes at all), so a
+tick's composition never recompiles, and the kernel holds ONE traced
+one-row walk and ONE traced tile walk whatever a tile's program is.
 
 Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, and
 the pool is STORED in such rows (ops/kv_quant.py owns the row): ``[pages,
@@ -83,7 +94,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +133,150 @@ def tile_runs(table_index, positions, horizons):
     run = ((hor > pos) & (idx == idx[:, :1])
            & (pos - pos[:, :1] == np.arange(TILE)))
     return run.all(axis=1), (hor > 0).sum(axis=1)
+
+
+# From how many live rows, and over how many compute blocks, ONE walk with
+# a tile's ``TILE * group`` query rows in the matmul beats the rows' own
+# walks (``group`` rows a matmul each); timed on a v5e (PERF.md section 6,
+# PR 56), as ``STACK_ROWS`` was
+SHARE_ROWS = 3
+SHARE_BLOCKS = 2
+
+
+class TileShares(NamedTuple):
+    """What :func:`tile_shares` reads off a call: every row's walk in
+    compute blocks (``blk0`` .. ``blk1``, its mask's first and last), the
+    part of it a span's shared walk serves (``lo`` .. ``hi``; empty, at
+    ``blk0``, for a row that walks alone) and a tile's two spans."""
+
+    rows: object     # [R', 4] int32: blk0, lo, hi, blk1 (R' in whole tiles)
+    spans: object    # [tiles, 2, 7]: table, rows from, to, blocks s0, s1,
+    #                  the token its walk fetches up to, whether it is a run
+    parts: object    # [tiles, 3]: whether any row of the tile walks a head
+    #                  of its own, any span has a range, any row a tail
+
+    def blocks(self):
+        """``(seen, fetched)``: the compute blocks under the live rows'
+        masks, summed over rows, and the blocks the walks fetch: each
+        row's own head and tail and each span's range once."""
+        blk0, lo, hi, blk1 = (self.rows[:, k] for k in range(4))
+        own = (lo - blk0).clip(0) + (blk1 - hi).clip(0)
+        shared = (self.spans[..., 4] - self.spans[..., 3]).clip(0)
+        return (blk1 - blk0).clip(0).sum(), own.sum() + shared.sum()
+
+
+def tile_shares(tables, table_index, positions, horizons, *,
+                window: Optional[int], page: int,
+                row_bytes: int) -> TileShares:
+    """THE second grouping rule, as :func:`tile_runs` a pure function of the
+    call's data (numpy on the host, traced in front of the kernel): which
+    COMPUTE BLOCKS (``pps`` pages of ``page`` tokens: ``_pages_per_step``
+    of a token's ``row_bytes``) of a tile's rows one page walk serves for
+    several rows at once.
+
+    A tile that is one run is one span: every row, from its first row's
+    window to its last row's position.  In any other tile a SPAN is a
+    stretch of consecutive rows whose live ones name the same pages — rows
+    of different sequences on one cached prefix, which the tick lays side
+    by side (generation/ragged.decode_order), or rows of one sequence that
+    are no run.  A tile has up to two: the rows that agree with its first
+    live row, then those that agree with the first that does not (two
+    prefixes meet in the tile), told apart in the block after the last
+    first-visible block among the tile's rows, which every span worth a
+    walk holds.  A span's shared range ``[s0, s1)`` is the first stretch
+    of blocks, from the last first-visible block among ITS rows on, in
+    which every live row names the anchor's ``pps`` pages and which lie
+    wholly below every live row's last key (a window class's table names
+    the null page behind a row's window, so rows agree only from the
+    latest start on; whole blocks only, so every row still meets the
+    blocks it met alone, in ascending order: its own head ``[blk0, s0)``,
+    the shared ``[s0, s1)``, its own tail ``[s1, blk1)``).  The range is
+    empty where one walk with the tile's rows would not beat the rows'
+    own: under ``SHARE_ROWS`` live rows or ``SHARE_BLOCKS`` blocks."""
+    xp = jnp if any(isinstance(a, jax.Array) for a in (
+        tables, table_index, positions, horizons)) else np
+
+    def padded(a, axis, size):      # zeros behind, where any are wanted
+        lack = [(0, size - a.shape[axis] if k == axis else 0)
+                for k in range(a.ndim)]
+        return xp.pad(a, lack) if lack[axis][1] else a
+
+    pps = _pages_per_step(page, row_bytes)
+    bk = pps * page
+    rows = -(-table_index.shape[0] // TILE) * TILE
+    idx, pos, hor = (padded(a, 0, rows).reshape(-1, TILE).astype(np.int32)
+                     for a in (table_index, positions, horizons))
+    live = hor > 0
+    kv_end = xp.where(live, xp.minimum(hor, pos + 1), 0)
+    blk0 = (xp.zeros_like(pos) if window is None
+            else xp.maximum(pos - window + 1, 0) // bk)
+    blk1 = (kv_end + bk - 1) // bk
+    run, _ = tile_runs(idx.reshape(-1), pos.reshape(-1), hor.reshape(-1))
+    width = tables.shape[1]
+    nblk = -(-width // pps)
+    named = tables[idx]                        # [tiles, TILE, width]
+    r = np.arange(TILE)
+    blk = np.arange(nblk)
+
+    tile = np.arange(idx.shape[0])
+    # who stands with whom, read in ONE block every span worth a walk holds
+    told = xp.minimum(xp.where(live, blk0, 0).max(axis=1) + 1, nblk - 1)
+    pages = named[tile[:, None, None], r[:, None], xp.minimum(
+        told[:, None] * pps + np.arange(pps), width - 1)[:, None]]
+
+    def stretch(first):
+        """Rows ``[first, end)``: up to the first live row behind ``first``
+        that names other pages than row ``first`` (TILE: none does)."""
+        other = (pages != pages[tile, xp.minimum(first, TILE - 1)][
+            :, None]).any(axis=2)
+        return xp.where(live & other & (r > first[:, None]), r,
+                        TILE).min(axis=1)
+
+    first_a = xp.argmax(live, axis=1)
+    first_b = stretch(first_a)
+    # the two spans side by side, [tiles, 2]: rows [first, end), the row
+    # whose table the walk reads
+    first = xp.stack([first_a, first_b], axis=1)
+    end = xp.stack([first_b, stretch(first_b)], axis=1)
+    anchor = xp.minimum(first, TILE - 1)
+    mine = live[:, None] & (r >= first[..., None]) & (r < end[..., None])
+    # where a row names another page than its span's anchor
+    differ = named != named[tile[:, None], xp.where(
+        r < first_b[:, None], anchor[:, :1], anchor[:, 1:])]
+    # a block is a span's where no live row of it differs in a page (slots
+    # past the table's width lie past every context: never below a last
+    # key) and no last key lies inside it
+    ok = ~padded((differ[:, None] & mine[..., None]).any(axis=2), 2,
+                 nblk * pps).reshape(-1, 2, nblk, pps).any(axis=3)
+    ok &= (blk + 1) * bk <= xp.where(
+        mine, kv_end[:, None], np.iinfo(np.int32).max).min(axis=2)[..., None]
+    start = xp.where(mine, blk0[:, None], 0).max(axis=2)[..., None]
+    s0 = xp.where(ok & (blk >= start), blk, nblk).min(axis=2)
+    s1 = xp.where(~ok & (blk >= s0[..., None]), blk, nblk).min(axis=2)
+    pays = (mine.sum(axis=2) >= SHARE_ROWS) & (s1 - s0 >= SHARE_BLOCKS)
+    s1 = xp.where(pays, s1, s0)
+    shared = mine & pays[..., None]
+    # a row's part of its span's range; none: empty, where its walk starts
+    lo = xp.where(shared[:, 0], s0[:, :1],
+                  xp.where(shared[:, 1], s0[:, 1:], blk0))
+    hi = xp.where(shared[:, 0], s1[:, :1],
+                  xp.where(shared[:, 1], s1[:, 1:], blk0))
+    spans = xp.stack([idx[tile[:, None], anchor], first, end, s0, s1,
+                      s1 * bk, 0 * s0], axis=2)
+    # a run is one span, whole: no row of it walks a block alone
+    whole = xp.stack([idx[:, 0], 0 * first_a, 0 * first_a + TILE,
+                      blk0[:, 0], blk1[:, -1], kv_end[:, -1],
+                      0 * first_a + 1], axis=1)
+    spans = xp.where(run[:, None, None], xp.stack(
+        [whole, 0 * whole], axis=1), spans).astype(np.int32)
+    lo, hi = xp.where(run[:, None], blk0, lo), xp.where(run[:, None], blk1, hi)
+    rows = xp.stack([blk0, lo, hi, blk1], axis=2)
+    # the parts of a tile's program that any of its rows or spans takes
+    parts = xp.stack([(lo > blk0).any(axis=1),
+                      (spans[..., 4] > spans[..., 3]).any(axis=1),
+                      (blk1 > hi).any(axis=1)], axis=1)
+    return TileShares(rows.reshape(-1, 4).astype(np.int32), spans,
+                      parts.astype(np.int32))
 
 
 def _operand_dtype(q_dtype, page_dtype, quantized: bool):
@@ -181,7 +336,9 @@ def _paged_kernel(
     idx_ref,     # [b] int32 row -> table
     pos_ref,     # [b] int32 the row's position
     hor_ref,     # [b] int32 kv horizon in tokens (0 = dead row)
-    run_ref,     # [b / TILE] int32: the tile is one run (tile_runs)
+    rows_ref,    # [b * 4] int32: a row's blk0, lo, hi, blk1 (tile_shares)
+    span_ref,    # [b / TILE * 14] int32: a tile's two spans (tile_shares)
+    part_ref,    # [b / TILE * 3] int32: the parts of its program a tile takes
     base_ref,    # [1] int32 first page of the calling layer in the pool
     # q block, the pool in HBM [, its scales], out block, then scratch
     *refs,
@@ -224,17 +381,17 @@ def _paged_kernel(
                 s_buf[slot, j, (at[j] + h) // 128, (at[j] + h) % 128], vec)
         return jnp.where(live, vec, 0.0)
 
-    def walk(at, rows: int, tbl, pos0, kv_end):
-        """ONE page walk for the ``rows`` query rows a kv head from ``at``
-        on: row ``r`` of them stands at position ``pos0 + r // group`` of
-        table ``tbl`` — the causal (and window) mask is per ROW.  Keys
-        ``[kv_start, kv_end)`` are all any of them can see; a block that
-        lies outside one row's mask leaves that row's state as it was."""
-        kv_start = (0 if sliding_window is None
-                    else jnp.maximum(pos0 - sliding_window + 1, 0))
-        blk0 = kv_start // bk
-        blk1 = (kv_end + bk - 1) // bk
+    def walk(at, rows: int, tbl, blk0, blk1, kv_end, q_pos, q_end):
+        """ONE page walk over compute blocks ``[blk0, blk1)`` of table
+        ``tbl``, keys below ``kv_end`` fetched, for the ``rows`` query rows
+        a kv head from ``at`` on.  ``q_pos`` is their position and
+        ``q_end`` the end of the keys they may see — a scalar each for one
+        row's group, ``[rows, 1]`` for a tile's rows: the causal (and
+        window) mask is per ROW, and a block that lies outside one row's
+        mask (every block, for a row with ``q_end`` 0) leaves that row's
+        state as it was."""
         rows_at = pl.ds(at, rows)
+        last = jnp.minimum(q_pos, q_end - 1)
 
         def page_id(blk, j):
             # clamped: a block's last slots may lie past the table's width
@@ -271,14 +428,10 @@ def _paged_kernel(
 
             pages_of(blk, slot, False)
             first = blk * bk
-            q_pos = pos0
-            if rows > group:
-                q_pos += jax.lax.broadcasted_iota(
-                    jnp.int32, (rows, 1), 0) // group
             kv_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-            # kv_end also covers the pages of this block that were not
-            # fetched
-            mask = kv_pos <= jnp.minimum(q_pos, kv_end - 1)
+            # q_end lies at or below kv_end: the mask also covers the
+            # pages of this block that were not fetched
+            mask = kv_pos <= last
             if sliding_window is not None:
                 mask = jnp.logical_and(mask, q_pos - kv_pos < sliding_window)
             live_col = kv_pos < kv_end
@@ -341,21 +494,63 @@ def _paged_kernel(
     acc_s[...] = jnp.zeros_like(acc_s)
     row0 = i * TILE
 
-    @pl.when(run_ref[i] != 0)
-    def _run():
-        # the tile is one run: one walk, one matmul a kv head for all its
-        # rows, from its first row's window to its last row's position
-        walk(0, TILE * group, idx_ref[row0], pos_ref[row0],
-             pos_ref[row0] + TILE)
+    def row_end(r):
+        return jnp.minimum(hor_ref[r], pos_ref[r] + 1)
 
-    @pl.when(run_ref[i] == 0)
-    def _rows():
+    def span_walk(s, _):
+        """One of the tile's spans (tile_shares): ONE walk, one matmul a kv
+        head for all the tile's rows, the rows outside the span masked."""
+        tbl, first, end, blk0, blk1, kv_end, run = (
+            span_ref[(i * 2 + s) * 7 + k] for k in range(7))
+
+        def of_a_run():
+            # consecutive positions from the first row's on, all live
+            q_pos = pos_ref[row0] + jax.lax.broadcasted_iota(
+                jnp.int32, (TILE * group, 1), 0) // group
+            return q_pos, q_pos + 1
+
+        def of_each_row():
+            t = jax.lax.broadcasted_iota(
+                jnp.int32, (TILE * group, 1), 0) // group
+            q_pos = jnp.zeros_like(t)
+            q_end = jnp.zeros_like(t)
+            for k in range(TILE):
+                inside = jnp.logical_and(first <= k, k < end)
+                q_pos = jnp.where(t == k, pos_ref[row0 + k], q_pos)
+                q_end = jnp.where(
+                    t == k, jnp.where(inside, row_end(row0 + k), 0), q_end)
+            return q_pos, q_end
+
+        @pl.when(blk1 > blk0)
+        def _span():
+            # a run's rows need no look at each row (a chunk's tiles are
+            # most of a prompt-heavy tick's)
+            walk(0, TILE * group, tbl, blk0, blk1, kv_end,
+                 *jax.lax.cond(run != 0, of_a_run, of_each_row))
+
+    def part(ph, _):
+        """The rows' own heads, the spans, then the rows' own tails: a row
+        meets its blocks in ascending order.  A tile with nothing to share
+        has empty heads and spans, and its tails are the rows' whole
+        walks, one after another, ``group`` query rows a matmul."""
         def row(t, _):
             r = row0 + t
-            walk(pl.multiple_of(t * group, 8), group, idx_ref[r], pos_ref[r],
-                 jnp.minimum(hor_ref[r], pos_ref[r] + 1))
+            blk0 = rows_ref[r * 4 + 2 * ph]
+            blk1 = rows_ref[r * 4 + 2 * ph + 1]
+            walk(pl.multiple_of(t * group, 8), group, idx_ref[r], blk0, blk1,
+                 row_end(r), pos_ref[r], row_end(r))
 
-        jax.lax.fori_loop(0, TILE, row, None)
+        # a part no row of the tile takes is not looped over: a tile of
+        # decode rows that share nothing is its rows' tails alone
+        @pl.when(part_ref[i * 3 + 2 * ph] != 0)
+        def _rows():
+            jax.lax.fori_loop(0, TILE, row, None)
+
+        @pl.when(jnp.logical_and(ph == 0, part_ref[i * 3 + 1] != 0))
+        def _spans():
+            jax.lax.fori_loop(0, 2, span_walk, None)
+
+    jax.lax.fori_loop(0, 2, part, None)
 
     l = l_s[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -394,7 +589,8 @@ def _head_lanes(d: int, row: int, latent: bool):
 
 
 def _paged_call(q, pool, tables, table_index, positions, horizons,
-                page_base, *, scale, sliding_window, latent, interpret):
+                page_base, *, scale, sliding_window, latent, interpret,
+                shares=None):
     """``q`` [R, n_heads, d], one query row a ragged row -> same shape.
     ``pool`` is the flat ``[pages, page, H*d]`` pool of every layer
     (ops/kv_quant.layer_view; quantized: with the calling layer's ``[P,
@@ -417,7 +613,6 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     table_index, positions, horizons = (
         jnp.pad(a.astype(jnp.int32), dead)
         for a in (table_index, positions, horizons))
-    run, _ = tile_runs(table_index, positions, horizons)
     # kv-head-major query rows, unscaled (the kernel scales the float32
     # scores) and in float32, which holds a bf16 query exactly: one program
     # sees all of a kv head's query rows of its tile as ONE matmul operand.
@@ -431,6 +626,13 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         return pl.cdiv(n, 128) * 128
 
     pps = _pages_per_step(page_size, row * arr.dtype.itemsize)
+    # who shares a walk: handed in where a caller worked it out once for
+    # several calls on the same rows (a tick's layers), else read here
+    if shares is None:
+        shares = tile_shares(tables, table_index, positions, horizons,
+                             window=sliding_window, page=page_size,
+                             row_bytes=row * arr.dtype.itemsize)
+    assert shares.rows.shape == (tiles * TILE, 4), (shares.rows.shape, r)
     buf_shape = (2, pps, page_size, row)
     rows = TILE * gp
 
@@ -451,7 +653,7 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         operands += [_scale_rows(pool.scale)]
         scratch += [pltpu.SMEM((2, pps, 2, 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=8,
         grid=(tiles,),
         in_specs=in_specs,
         out_specs=tile_spec,
@@ -487,8 +689,9 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         interpret=interpret,
         name="paged_attention",
     )(tables.astype(jnp.int32), table_index, positions, horizons,
-      run.astype(jnp.int32), jnp.asarray(page_base, jnp.int32).reshape(1),
-      *operands)
+      shares.rows.reshape(-1), shares.spans.reshape(-1),
+      shares.parts.reshape(-1),
+      jnp.asarray(page_base, jnp.int32).reshape(1), *operands)
     out = out.reshape(tiles, nkv, TILE, gp, w).transpose(0, 2, 1, 3, 4)
     # the pair read whole: its value lanes are the output
     return out.reshape(tiles * TILE, nkv, gp, w)[
@@ -508,12 +711,16 @@ def paged_ragged_kernel(
     latent: bool = False,
     page_base=0,
     interpret: bool = False,
+    shares: Optional[TileShares] = None,
 ) -> jax.Array:
-    """ONE launch for a whole ragged tick; returns [R, 1, n_heads, d]."""
+    """ONE launch for a whole ragged tick; returns [R, 1, n_heads, d].
+    ``shares``: :func:`tile_shares` of these very tables and rows under
+    this window and this pool's row, worked out once by a caller that
+    makes several such calls (None: worked out here)."""
     return _paged_call(
         q[:, 0], pool, tables, table_index, positions, horizons, page_base,
         scale=scale, sliding_window=sliding_window, latent=latent,
-        interpret=interpret)[:, None]
+        interpret=interpret, shares=shares)[:, None]
 
 
 def paged_prefill_kernel(

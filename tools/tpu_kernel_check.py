@@ -28,9 +28,11 @@ kernel phase).  ``--time`` prints each flash kernel's ms a call at the
 train cells' shapes (``FLASH_SHAPES``) beside the blocks its grid walks
 (live of all, cut of the live: ``flash_attention.live_blocks``), then the
 paged kernel's ms a call at the tick shapes the chip has seen (``TICKS``),
-whole and with the chunk's rows dead, beside the dtype its two matmuls
-take their operands in and what its KV bytes need at the HBM peak, and
-checks nothing.
+whole and with the chunk's rows dead, and at the agent cell's tick with
+its decode rows' tables led by four shared prefixes (``SHARED_TICKS``: in
+slot order and in the order the tick runs them in), beside the dtype its
+two matmuls take their operands in and what its KV bytes need at the HBM
+peak, and checks nothing.
 ``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
@@ -290,6 +292,72 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     }
 
 
+def _ragged_fns(rng, rows: int, idx, pos, hor, tables, tables_k, *,
+                n: int, nkv: int, d: int, page: int, kv_dtype: str, dtype,
+                latent: bool, w):
+    """The two sides of one ragged call for :func:`run_case` and
+    :func:`share_case`: a pool of ``tables.max() + 1`` pages and ``rows``
+    queries drawn from ``rng``; ``pallas_fn(interpret, spread)`` the kernel
+    wrapper on ``tables_k`` — ``spread``: every row the first of a tile of
+    its own with dead rows behind it, which is the one-row walk; "beside":
+    ONE call, the spread rows behind the tiles, both outputs — and
+    ``jnp_fn(exact)`` the gather path on ``tables`` (``exact``: in float32
+    throughout, on float32 copies of the same query and page values, a
+    quantized pool dequantized into float32: what ``bf16_ulps`` compares a
+    bf16 output with), both on every row of the call."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops import kv_quant
+    from megatron_llm_tpu.ops import paged_attention as pa
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    T = pk.TILE
+    num_pages = int(tables.max()) + 1
+    if latent:
+        pool = jnp.asarray(rng.normal(size=(num_pages, page, d)), dtype)
+    else:
+        heads = kv_quant.pack_kv(*(
+            jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
+            for _ in range(2)))
+        pool = (heads.reshape(num_pages, page, -1) if kv_dtype == "bf16"
+                else kv_quant.quantize_pages(heads, kv_dtype))
+    q = jnp.asarray(rng.normal(size=(rows, 1, n, d)), dtype)
+    kw = dict(scale=1.0 / d ** 0.5, sliding_window=w, latent=latent)
+
+    def pallas_fn(interpret=False, spread=False):
+        q_, meta = q, (idx, pos, hor)
+        if spread:
+            q_ = jnp.zeros((T * rows,) + q.shape[1:], dtype).at[::T].set(q)
+            meta = [np.zeros(T * rows, np.int32) for _ in range(3)]
+            for wide, a in zip(meta, (idx, pos, hor)):
+                wide[::T] = a
+        if spread == "beside":
+            q_ = jnp.concatenate([q, q_])
+            meta = [np.concatenate(pair)
+                    for pair in zip((idx, pos, hor), meta)]
+        out = pk.paged_ragged_kernel(
+            q_, pool, jnp.asarray(tables_k, jnp.int32),
+            *(jnp.asarray(a) for a in meta), interpret=interpret, **kw)
+        if spread == "beside":
+            return out[:rows], out[rows::T]
+        return out[::T] if spread else out
+
+    def jnp_fn(exact=False):
+        q_, pool_ = q, pool
+        if exact:
+            q_ = q.astype(jnp.float32)
+            if not kv_quant.is_quantized(pool):
+                pool_ = pool.astype(jnp.float32)
+        # on the chip a float32 einsum is one bf16 pass unless told
+        with jax.default_matmul_precision("highest" if exact else "default"):
+            return pa.paged_attention_ragged(
+                q_, pool_, jnp.asarray(tables, jnp.int32),
+                *(jnp.asarray(a) for a in (idx, pos, hor)),
+                use_kernel=False, **kw)
+
+    return pallas_fn, jnp_fn
+
+
 def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
              kv_dtype: str = "bf16", dtype=jnp.bfloat16,
              latent: bool = False, window: bool = False):
@@ -324,8 +392,6 @@ def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     """
     import numpy as np
 
-    from megatron_llm_tpu.ops import kv_quant
-    from megatron_llm_tpu.ops import paged_attention as pa
     from megatron_llm_tpu.ops.pallas import paged_attention as pk
 
     rng = np.random.default_rng(seed)
@@ -379,43 +445,117 @@ def run_case(seed: int, *, n: int, nkv: int, d: int, page: int,
         np.minimum.at(first, idx, np.where(idx > 0, pos, 1 << 30))
         tables_k[(np.arange(max_pages) + 1) * page
                  <= first[:, None] - w + 1] = 0
-    if latent:
-        pool = jnp.asarray(rng.normal(size=(num_pages, page, d)), dtype)
-    else:
-        heads = kv_quant.pack_kv(*(
-            jnp.asarray(rng.normal(size=(num_pages, page, nkv, d)), dtype)
-            for _ in range(2)))
-        pool = (heads.reshape(num_pages, page, -1) if kv_dtype == "bf16"
-                else kv_quant.quantize_pages(heads, kv_dtype))
-    q = jnp.asarray(rng.normal(size=(len(rows), 1, n, d)), dtype)
-    kw = dict(scale=1.0 / d ** 0.5, sliding_window=w, latent=latent)
-
-    def pallas_fn(interpret=False, spread=False):
-        q_, meta = q, (idx, pos, hor)
-        if spread:
-            q_ = jnp.zeros((T * len(rows),) + q.shape[1:], dtype).at[::T].set(q)
-            meta = [np.zeros(T * len(rows), np.int32) for _ in range(3)]
-            for wide, a in zip(meta, (idx, pos, hor)):
-                wide[::T] = a
-        out = pk.paged_ragged_kernel(
-            q_, pool, jnp.asarray(tables_k, jnp.int32),
-            *(jnp.asarray(a) for a in meta), interpret=interpret, **kw)
-        return out[::T] if spread else out
-
-    def jnp_fn(exact=False):
-        q_, pool_ = q, pool
-        if exact:
-            q_ = q.astype(jnp.float32)
-            if not kv_quant.is_quantized(pool):
-                pool_ = pool.astype(jnp.float32)
-        # on the chip a float32 einsum is one bf16 pass unless told
-        with jax.default_matmul_precision("highest" if exact else "default"):
-            return pa.paged_attention_ragged(
-                q_, pool_, jnp.asarray(tables, jnp.int32),
-                *(jnp.asarray(a) for a in (idx, pos, hor)),
-                use_kernel=False, **kw)
-
+    pallas_fn, jnp_fn = _ragged_fns(
+        rng, len(rows), idx, pos, hor, tables, tables_k, n=n, nkv=nkv, d=d,
+        page=page, kv_dtype=kv_dtype, dtype=dtype, latent=latent, w=w)
     return pallas_fn, jnp_fn, scenarios
+
+
+def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
+               kv_dtype: str = "bf16", dtype=jnp.bfloat16,
+               latent: bool = False, window: bool = False, only=None):
+    """One ragged call whose tiles hold rows of DIFFERENT sequences that
+    name the same leading pages, the blocks the kernel serves by one walk
+    a span (ops/pallas/paged_attention.py ``tile_shares``), and what
+    stands in their way.  Returns ``(pallas_fn, jnp_fn, scenarios, plan)``
+    as :func:`run_case` does, ``plan`` the rule's arguments for the call
+    (numpy: tables as the kernel reads them, rows, window, page, row
+    bytes).  Two prefixes of five compute blocks and two pages; a tile a
+    scenario (``only``: those named), a table a live row unless said:
+
+    * ``one``: eight rows on prefix A, their own pages behind it;
+    * ``two``: five rows on A, then three on B: two spans;
+    * ``dead``: four rows on A, dead rows between and behind them;
+    * ``short``: seven rows on A and one whose context ends inside A's
+      third block: the span shares two blocks;
+    * ``verify``: a verify block (four rows of one table at consecutive
+      positions) and four decode rows, all on A;
+    * ``none``: eight rows that share nothing;
+
+    and with ``window`` (four blocks; every table slid as a window page
+    class's is: the slots wholly behind its first query's window name the
+    null page):
+
+    * ``window``: eight rows on A whose windows open in two different
+      blocks and at different pages of them: four walk two blocks of
+      their own before the span's;
+    * ``window_two``: four rows on A, four on B.
+    """
+    import numpy as np
+
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    rng = np.random.default_rng(seed)
+    T = pk.TILE
+    row = d if latent else 2 * nkv * d
+    item = 1 if kv_dtype != "bf16" else jnp.dtype(dtype).itemsize
+    pps = pk._pages_per_step(page, row * item)
+    bk = pps * page
+    lead = 5 * pps + 2                  # pages of a prefix
+    end = lead * page                   # its tokens
+    w = 4 * bk if window else None
+    dead = None
+    # a row: (prefix, position[, table]); rows that name a table share it
+    if window:
+        scenes = {
+            "window": [("A", end + 3 + 5 * i) for i in range(4)]
+            + [("A", end + bk - 10 + 11 * i) for i in range(4)],
+            "window_two": [("A", end + 7 * i) for i in range(4)]
+            + [("B", end + bk // 2 + 9 * i) for i in range(4)],
+        }
+    else:
+        scenes = {
+            "one": [("A", end + 13 * i) for i in range(T)],
+            "two": [("A", end + 1 + 20 * i) for i in range(5)]
+            + [("B", end + 5 + 30 * i) for i in range(3)],
+            "dead": [("A", end + 2), dead, ("A", end + bk), ("A", end + 40),
+                     dead, ("A", end + 9), dead, dead],
+            "short": [("A", end + 4 * i) for i in range(3)]
+            + [("A", 2 * bk + 5)] + [("A", end + 6 * i) for i in range(4)],
+            "verify": [("A", end + 20 + i, "v") for i in range(4)]
+            + [("A", end + 17 * i) for i in range(4)],
+            "none": [(None, 3 * bk + 11 * i) for i in range(T)],
+        }
+    scenes = {k: v for k, v in scenes.items() if only is None or k in only}
+    rows = [r for scene in scenes.values() for r in scene]
+    at, scenarios = 0, {}
+    for name, scene in scenes.items():
+        scenarios[name] = np.array(
+            [at + i for i, r in enumerate(scene) if r is not None])
+        at += len(scene)
+    # tables: the null table, then one a live row (one a named table)
+    max_pages = (max(r[1] for r in rows if r) // page + 2)
+    table_of, prefix_of = {}, [None]
+    for i, r in enumerate(rows):
+        if r is not None:
+            key = (r[2], r[0]) if len(r) > 2 else i
+            if key not in table_of:
+                table_of[key] = len(prefix_of)
+                prefix_of.append(r[0])
+            rows[i] = (table_of[key], r[1])
+    idx = np.array([r[0] if r else 0 for r in rows], np.int32)
+    pos = np.array([r[1] if r else 0 for r in rows], np.int32)
+    hor = np.where(idx > 0, (pos // 64 + 1) * 64, 0).astype(np.int32)
+    num_pages = 1 + 2 * lead + len(prefix_of) * max_pages
+    ids = 1 + rng.permutation(num_pages - 1)
+    leads = {"A": ids[:lead], "B": ids[lead:2 * lead]}
+    tables = ids[2 * lead:].reshape(len(prefix_of), max_pages).copy()
+    tables[0] = 0
+    for t, prefix in enumerate(prefix_of):
+        if prefix is not None:
+            tables[t, :lead] = leads[prefix]
+    tables_k = tables.copy()
+    if window:
+        first = np.full(tables.shape[0], 1 << 30)
+        np.minimum.at(first, idx, np.where(idx > 0, pos, 1 << 30))
+        tables_k[(np.arange(max_pages) + 1) * page
+                 <= first[:, None] - w + 1] = 0
+    pallas_fn, jnp_fn = _ragged_fns(
+        rng, len(rows), idx, pos, hor, tables, tables_k, n=n, nkv=nkv, d=d,
+        page=page, kv_dtype=kv_dtype, dtype=dtype, latent=latent, w=w)
+    plan = (tables_k, idx, pos, hor,
+            dict(window=w, page=page, row_bytes=row * item))
+    return pallas_fn, jnp_fn, scenarios, plan
 
 
 # the head geometries of the benchmark's serving configurations
@@ -457,6 +597,15 @@ TICKS = {
 }
 
 
+# the agent cell's tick as its prefix cache leaves the tables (PERF.md
+# section 6, PR 56).  name: the tick, the primed prefixes its live decode
+# rows are drawn from (slot by slot at random), the pages of one
+SHARED_TICKS = {
+    "commanda_shared": ("commanda", 4, 1023),
+    "commanda_shared_window": ("commanda_window", 4, 1023),
+}
+
+
 @functools.lru_cache(maxsize=1)
 def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int,
                latent: bool = False):
@@ -476,10 +625,18 @@ def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int,
         for _ in range(2))).reshape(num_pages, page, -1)
 
 
-def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
+def tick_case(seed: int, name: str, width=None, chunk_live: bool = True,
+              ordered: bool = False):
     """A ragged tick as the chip has seen it (PERF.md section 5): the
     arguments of ``paged_ragged_kernel`` and the KV bytes the tick needs
     (each table's keys once, K and V).
+
+    A name of ``SHARED_TICKS`` is that tick with every live decode row's
+    table led by the pages of one of a few primed prefixes, the row's own
+    pages after them: rows in slot order, prefixes at random, as the
+    engine's slots hold them; ``ordered``: the decode rows in the order
+    the tick runs them in (generation/ragged.decode_order: one prefix's
+    rows side by side, dead rows last).
 
     ``falcon``: 128 slots + a 64-row prefill chunk = 192 rows over 128 page
     slots; 49 decode rows at contexts 300-700 and the chunk are live, 79
@@ -497,6 +654,7 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     """
     import numpy as np
 
+    name, prefixes, shared = SHARED_TICKS.get(name, (name, 0, 0))
     (geo, slots, live, lo, hi, chunk_at, slots_wide, num_pages,
      window) = TICKS[name]
     n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
@@ -518,18 +676,28 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True):
     # contexts do not depend on the width)
     tables = rng.integers(1, num_pages, size=(slots + 2, width))
     tables[-1] = 0
+    if prefixes:
+        tables[rows, :shared] = rng.integers(
+            1, num_pages, size=(prefixes, shared))[
+                rng.integers(prefixes, size=live)]
+    if ordered:
+        from megatron_llm_tpu.generation.ragged import decode_order
+
+        order = decode_order(tables[idx[:slots]])
+        pos[:slots], idx[:slots] = pos[order], idx[order]
+    at = np.flatnonzero(idx[:slots] < slots)      # the live decode rows
     if window:
         # a table's first query this tick: a decode row's own position,
         # the chunk's first row
         first = np.zeros(slots + 2, np.int64)
-        first[rows] = pos[rows]
+        first[idx[at]] = pos[at]
         first[slots] = chunk_at
         behind = ((np.arange(width) + 1) * page
                   <= first[:, None] - window + 1)
         tables[behind] = 0
     hor = np.where(idx <= slots, (pos // 64 + 1) * 64, 0)
     q = jnp.asarray(rng.normal(size=(slots + chunk, 1, n, d)), jnp.bfloat16)
-    visible = pos[rows] + 1
+    visible = pos[at] + 1
     keys = chunk_at + chunk if chunk_live else 0
     if window:
         visible = np.minimum(visible, window)
@@ -598,27 +766,30 @@ def paged_numerics(quick: bool):
     runs = [FALCON, MISTRAL, COMMANDA, LATENT, dict(MISTRAL, kv_dtype="int8")]
     if not quick:
         runs += [dict(FALCON, kv_dtype="int8"), dict(COMMANDA, kv_dtype="fp8")]
+    # and the walk that rows of different sequences share (share_case),
+    # the same way
     for i, case in enumerate(runs):
         tag = " ".join(f"{k}={v}" for k, v in case.items())
-        for window in (False, True):
-            try:
-                pallas_fn, jnp_fn, scenarios = run_case(
-                    i, window=window, **case)
-                out, ref = pallas_fn(), jnp_fn()
-                for name, rows in scenarios.items():
-                    e = max_err(out[rows], ref[rows])
-                    check(f"paged run {name} {tag}", e < TOL,
-                          f"max_err={e:.2e}")
-                # the bf16 operands' rule (bf16_ulps), compiled: against
-                # float32 on the same values, rounded once
-                live = np.concatenate(list(scenarios.values()))
-                check_bf16(f"paged run window={window} {tag}", out[live],
-                           jnp_fn(exact=True)[live])
-            except Exception as exc:
-                check(f"paged run window={window} {tag}", False,
-                      f"{type(exc).__name__}: {str(exc)[:300]}")
-    for name in TICKS:
-        args, kw, live, _ = tick_case(7, name)
+        for kind, make in (("run", run_case), ("share", share_case)):
+            for window in (False, True):
+                try:
+                    pallas_fn, jnp_fn, scenarios = make(
+                        i, window=window, **case)[:3]
+                    out, ref = pallas_fn(), jnp_fn()
+                    for name, rows in scenarios.items():
+                        e = max_err(out[rows], ref[rows])
+                        check(f"paged {kind} {name} {tag}", e < TOL,
+                              f"max_err={e:.2e}")
+                    # the bf16 operands' rule (bf16_ulps), compiled:
+                    # against float32 on the same values, rounded once
+                    live = np.concatenate(list(scenarios.values()))
+                    check_bf16(f"paged {kind} window={window} {tag}",
+                               out[live], jnp_fn(exact=True)[live])
+                except Exception as exc:
+                    check(f"paged {kind} window={window} {tag}", False,
+                          f"{type(exc).__name__}: {str(exc)[:300]}")
+    for name in (*TICKS, *SHARED_TICKS):
+        args, kw, live, _ = tick_case(7, name, ordered=name in SHARED_TICKS)
         q, pool, tables, idx, pos, _ = args
         try:
             # a ragged row is the decode step at its position over its own
@@ -750,12 +921,18 @@ def paged_timing():
     calls = 24
     # every tick whole (Falcon's at two table widths), then its decode
     # rows alone
-    cases = [(name, width, True) for name in TICKS
+    cases = [(name, width, True, False) for name in TICKS
              for width in ((128, 256) if name == "falcon" else (None,))]
-    cases += [(name, None, False) for name in TICKS]
-    for name, width, chunk_live in sorted(cases, key=lambda c: c[0]):
-        args, kw, _, need = tick_case(7, name, width, chunk_live)
+    cases += [(name, None, False, False) for name in TICKS]
+    # the shared prefixes' ticks in slot order (no tile agrees) and in the
+    # order the tick runs them in
+    cases += [(name, None, chunk_live, ordered) for name in SHARED_TICKS
+              for chunk_live in (True, False) for ordered in (False, True)]
+    for name, width, chunk_live, ordered in sorted(
+            cases, key=lambda c: c[0]):
+        args, kw, _, need = tick_case(7, name, width, chunk_live, ordered)
         width = args[2].shape[1]
+        name += " (ordered)" if ordered else ""
         name += "" if chunk_live else " (chunk dead)"
 
         def layers(q, *rest):
