@@ -149,6 +149,9 @@ class ModelConfig:
     moe_selection_bias: bool = False
     # ... and what multiplies the (renormalised) weights of the chosen
     moe_routed_scaling_factor: float = 1.0
+    # what `moe_normalize_gates` adds to the chosen scores' sum (DeepSeek-V3's
+    # modelling code: 1e-20; LFM2's: 1e-6)
+    moe_gate_eps: float = 1e-20
     # width of one expert's FFN; None = ffn_hidden_size (Mixtral).  With a
     # value, ffn_hidden_size stays the width of the dense layers' MLP
     moe_ffn_hidden_size: Optional[int] = None
@@ -191,6 +194,9 @@ class ModelConfig:
     # head from the layer's normed input come with it (the degree, the
     # feature layout and the state's dtype are the mechanism's, not flags)
     attention_type: str = "mha"
+    # 'mha' only: one RMSNorm a head on q and on k before the rotation
+    # (leaves `attention/q_norm`, `attention/k_norm` of kv_channels)
+    qk_head_norm: bool = False
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
@@ -223,6 +229,15 @@ class ModelConfig:
     # tail's dtype (float32), the chunk and the kernel's block are the
     # mechanism's, not flags
     sublayer_pattern: Optional[str] = None
+    # two further letters, for a family whose layer is TWO such sublayers
+    # (LiquidAI's LFM2: `lfm2_sublayers` writes its `layer_types` out):
+    # `C` a gated short convolution (a causal depthwise conv of
+    # `short_conv_kernel` taps between two elementwise gates, on a
+    # per-sequence tail of its last inputs and NO recurrent state), `D` a
+    # dense MLP of `ffn_hidden_size`.  With them `*` may rotate
+    # (`position_embedding_type` 'rotary').  The tail's dtype is the
+    # activations' (the family's code keeps its conv cache in the model's)
+    short_conv_kernel: int = 3
     # the Mamba-2 mixer: `mamba_num_heads` heads of `mamba_head_dim` on a
     # state of `ssm_state_size`, B and C shared by the heads of each of
     # `mamba_n_groups` groups, a causal depthwise conv of `mamba_conv_kernel`
@@ -269,6 +284,11 @@ class ModelConfig:
         return bool(self.sublayer_pattern and "M" in self.sublayer_pattern)
 
     @property
+    def short_conv(self) -> bool:
+        """Whether some sublayer is a gated short convolution."""
+        return bool(self.sublayer_pattern and "C" in self.sublayer_pattern)
+
+    @property
     def mamba_conv_channels(self) -> int:
         """Channels of a Mamba-2 layer's convolution: x, B and C."""
         return (self.mamba_num_heads * self.mamba_head_dim
@@ -303,10 +323,12 @@ class ModelConfig:
             f"sublayer_pattern {pattern!r}: '-' is the Nemotron-H family's "
             "dense MLP layer, which no published pattern served here holds "
             "and this stack does not build; its letters are M (Mamba-2), "
-            "E (experts) and * (attention)")
-        assert pattern and set(pattern) <= set("ME*"), (
+            "E (experts) and * (attention), and C (short convolution) and "
+            "D (dense MLP) for a layer of two sublayers")
+        assert pattern and set(pattern) <= set("ME*CD"), (
             f"sublayer_pattern {pattern!r}: a letter a layer, M (Mamba-2), "
-            "E (experts) or * (attention)")
+            "E (experts), * (attention), C (gated short convolution) or D "
+            "(dense MLP)")
         # the pattern IS the depth (a cut gives a shorter string)
         self.num_layers = len(pattern)
         assert ("E" in pattern) == (self.num_experts is not None), (
@@ -319,18 +341,26 @@ class ModelConfig:
             assert not missing, f"Mamba-2 layers need {missing}"
             assert self.mamba_num_heads % self.mamba_n_groups == 0, (
                 "a group of B and C serves a whole number of heads")
+        assert not (self.mamba and self.short_conv), (
+            "a state class holds Mamba-2 states or conv tails, not both: "
+            "no published pattern mixes M and C")
+        assert self.short_conv_kernel >= 2, (
+            "a short convolution has at least two taps")
         assert (self.attention_type == "mha" and not self.linear_layout
                 and not self.sliding_window_layout and not self.rope_layout
                 and not self.dense_prefix_layers and not self.parallel_attn
                 and not self.bidirectional
-                and self.position_embedding_type != "rotary"), (
-            "a stack of one-sublayer layers: causal K/V-head attention "
-            "with no position signal, no second layer pattern, no dense "
-            "prefix and no parallel block")
+                and self.position_embedding_type != "absolute"), (
+            "a stack of one-sublayer layers: causal K/V-head attention, "
+            "rotated or with no position signal, no second layer pattern, "
+            "no dense prefix and no parallel block")
 
     def finalize(self) -> None:
         assert self.attention_type in ("mha", "mla", "retention"), (
             f"unknown attention_type {self.attention_type!r}")
+        assert not self.qk_head_norm or self.attention_type == "mha", (
+            "qk_head_norm is the 'mha' path's: latent attention norms its "
+            "latents and power retention has head norms of its own")
         if self.retention:
             assert (self.sliding_window_size is None
                     and not self.sliding_window_layout
@@ -952,7 +982,7 @@ class Config:
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
                 "mistral", "mixtral", "joyai", "smallthinker", "commanda",
-                "gigachat35", "nemotron_h",
+                "gigachat35", "nemotron_h", "lfm2",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -1230,6 +1260,25 @@ ARCH_DEFAULTS = {
         moe_selection_bias=True,
         moe_normalize_gates=True,
     ),
+    # LiquidAI LFM2 (`lfm2_moe`): a layer is a mixer then a feed-forward, each
+    # behind its own RMSNorm, written as TWO letters of `sublayer_pattern`
+    # (`lfm2_sublayers`): gated short convolutions and QK-normed rotated GQA;
+    # dense SwiGLU in the first layers, SwiGLU experts behind a sigmoid +
+    # bias router after them; head tied to the embedding
+    "lfm2": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=True,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-5,
+        rope_theta=1_000_000.0,
+        qk_head_norm=True,
+        moe_score_func="sigmoid",
+        moe_selection_bias=True,
+        moe_normalize_gates=True,
+        moe_gate_eps=1e-6,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1244,6 +1293,17 @@ ARCH_DEFAULTS = {
         rope_theta=1_000_000.0,
     ),
 }
+
+def lfm2_sublayers(layer_types: str, num_dense_layers: int) -> str:
+    """An LFM2 `layer_types` (a letter a layer: `c` conv, `*` full
+    attention) as a `sublayer_pattern`, two letters a layer: the mixer
+    (`C` or `*`), then the feed-forward (`D` in the first
+    `num_dense_layers` layers, `E` after them)."""
+    assert set(layer_types) <= set("c*"), layer_types
+    return "".join(
+        ("C" if t == "c" else "*") + ("D" if i < num_dense_layers else "E")
+        for i, t in enumerate(layer_types))
+
 
 # Canonical model sizes (hidden/layers/heads/kv-heads/ffn) for convenience.
 MODEL_SIZES = {
@@ -1332,6 +1392,17 @@ MODEL_SIZES = {
         num_experts=128, moe_router_topk=6, moe_ffn_hidden_size=1856,
         ffn_hidden_size=1856, moe_shared_experts=2,
         moe_routed_scaling_factor=2.5, vocab_size=131072),
+    # LFM2-24B-A2B: 40 layers, `conv conv full_attention conv` ten times
+    # over, the first two with a dense SwiGLU of 11,776 and 38 with 64
+    # experts of 1,536, top-4: 80 sublayers (`num_layers` counts those)
+    "lfm2-24b-a2b": dict(
+        sublayer_pattern=lfm2_sublayers("cc*c" * 10, 2),
+        num_layers=80, hidden_size=2048,
+        num_attention_heads=32, num_attention_heads_kv=8, kv_channels=64,
+        max_position_embeddings=128000, short_conv_kernel=3,
+        ffn_hidden_size=11776, num_experts=64, moe_router_topk=4,
+        moe_ffn_hidden_size=1536, moe_routed_scaling_factor=1.0,
+        vocab_size=65536),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,

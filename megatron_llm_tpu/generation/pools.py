@@ -50,7 +50,9 @@ NULL_PAGE = 0
 # (:func:`memory_kind`): ``classes`` (a full and a window page class),
 # ``latent`` (MLA's one-leaf pool), ``state`` (power retention's state
 # slots), ``hybrid`` (a state class BESIDE a page class: a sequence holds a
-# slot and pages); ``paged``, one class of K/V pages, carries every
+# slot and pages), ``tails`` (the same beside a state class of conv tails
+# alone: gated short convolutions, no recurrent state); ``paged``, one
+# class of K/V pages, carries every
 # feature and has no row.  The features are what a caller of :func:`refuse_unserved` may
 # ask for: ``kv_dtype`` other than bf16, a ``tp`` or ``pp`` mesh, a
 # ``draft`` model (--spec_k), the cross-replica ``handoff``, a request's
@@ -74,6 +76,10 @@ KEEPS = {
     "hybrid": ("a hybrid stack ({stack}) keeps a recurrent state a sequence "
                "for its linear layers beside pages of {rows} for its "
                "attention layers"),
+    "tails": ("a stack of gated short convolutions ({stack}) keeps the "
+              "conv's last inputs a sequence (a tail a layer, no recurrent "
+              "state) beside pages of keys and values for its attention "
+              "layers"),
 }
 
 _CLASSES_MESH = (
@@ -166,6 +172,25 @@ NOT_CARRIED = {
         "tokens a row through one block table, and a linear layer takes "
         "one row a token from its state slot, beside latent rows and K/V "
         "pages alike"),
+    ("tails", "kv_dtype"): (
+        "--kv_dtype {kv_dtype}: no test holds a page's scales beside a "
+        "tail slot, and the tail is the conv's inputs as they were fed"),
+    ("tails", "tp"): (
+        "tensor-parallel serving (tp {tp}): the tails are not sharded over "
+        "their channels, and the pool's shardings name one leaf"),
+    ("tails", "pp"): (
+        "pipeline-parallel serving (pp {pp}): the stage pipeline hands ONE "
+        "paged leaf from stage to stage, and this stack keeps two"),
+    ("tails", "draft"): (
+        "--spec_k: a rejected draft token would have to roll the tail "
+        "back, and nothing keeps the tail before it"),
+    ("tails", "handoff"): (
+        "the cross-replica KV handoff: its wire format names pages of keys "
+        "and values, and a tail slot is neither"),
+    ("tails", "log_probs"): (
+        "return_log_probs (prompt scoring): the scoring chunk feeds many "
+        "tokens a row through one block table, and a conv layer takes one "
+        "row a token from its tail slot"),
 }
 
 
@@ -179,7 +204,7 @@ def memory_kind(cfg) -> str:
     if all(states):
         return "state"
     if any(states):
-        return "hybrid"
+        return "tails" if cfg.model.short_conv else "hybrid"
     if len(states) > 1:
         return "classes"
     return "latent" if cfg.model.mla else "paged"
@@ -313,7 +338,14 @@ class PagedKVPool:
         # the host's count of its passes (slots, positions) -> int; None: a
         # sweep that walks, a pass a live row
         self.sweep_steps = None
-        if self.state and m.mamba:
+        if self.state and m.short_conv:
+            from megatron_llm_tpu.ops import gated_delta as gd_ops
+
+            # tails alone, in the activations' dtype (what the conv is fed)
+            self.head_dim = m.hidden_size
+            kv = gd_ops.zero_tails((layers, num_pages), m.short_conv_kernel,
+                                   self.head_dim, dtype)
+        elif self.state and m.mamba:
             from megatron_llm_tpu.ops import mamba2 as mamba_ops
 
             self.sweep_steps = mamba_ops.sweep_steps
@@ -727,7 +759,8 @@ class StatePool(PagedKVPool):
     dv]`` and the conv's tail ``conv [layers * (slots + 1), (width - 1) *
     channels]``) beside latent rows, or Mamba-2 ones (ops/mamba2.py:
     ``MambaState``, ``s [layers, slots + 1, n, h * p]`` and the same tail)
-    beside K/V pages; in float32,
+    beside K/V pages; in float32; or the tails of a stack of gated short
+    convolutions (``ConvTail``: the tail ALONE, in the activations' dtype);
     indexed by STATE SLOT; ``layers``: how many of the model's layers keep
     their state here (all of them, unless told).  The allocator is the page
     pool's, a slot standing where a page stood: a sequence holds exactly
@@ -745,15 +778,17 @@ class StatePool(PagedKVPool):
                  layers: Optional[int] = None,
                  page_class: Optional[str] = None):
         assert (cfg.model.retention or cfg.model.delta
-                or cfg.model.mamba), (
-            "a state pool holds power retention's states or a hybrid's "
-            "linear layers' (gated-delta or Mamba-2)")
+                or cfg.model.mamba or cfg.model.short_conv), (
+            "a state pool holds power retention's states, a hybrid's "
+            "linear layers' (gated-delta or Mamba-2) or short "
+            "convolutions' tails")
         super().__init__(cfg, slots + 1, page_size, layers=layers,
                          page_class=page_class, state=True)
 
     @property
     def kv_statics(self) -> Tuple:
-        return ("kv", "state", str(self.kv.s.dtype), self.kv.s.shape[-1])
+        first = self.kv[0]           # ``s``; a tail-only class's ``conv``
+        return ("kv", "state", str(first.dtype), first.shape[-1])
 
     def kv_pool_bytes(self) -> int:
         return sum(a.size * a.dtype.itemsize for a in self.kv)

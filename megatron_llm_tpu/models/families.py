@@ -131,9 +131,11 @@ def validate_family(cfg: Config) -> Config:
                and m.rope_scaling_type == "yarn",
                "gigachat35 rotates under YaRN")
     elif name == "nemotron_h":
-        _check(bool(m.sublayer_pattern),
+        _check(bool(m.sublayer_pattern)
+               and set(m.sublayer_pattern) <= set("ME*"),
                "nemotron_h is a stack of one-sublayer layers: give "
-               "sublayer_pattern (the published hybrid_override_pattern)")
+               "sublayer_pattern (the published hybrid_override_pattern), "
+               "its letters M, E and *")
         _check(m.use_rms_norm and m.glu_activation is None
                and m.activation == "squared_relu",
                "nemotron_h uses RMSNorm and non-gated relu^2 experts")
@@ -146,6 +148,26 @@ def validate_family(cfg: Config) -> Config:
                "nemotron_h has no biases but the conv's and an untied head")
         _check(m.position_embedding_type != "rotary",
                "nemotron_h's attention carries no position signal")
+    elif name == "lfm2":
+        pattern = m.sublayer_pattern or ""
+        _check(bool(pattern) and set(pattern) <= set("C*DE")
+               and len(pattern) % 2 == 0
+               and set(pattern[0::2]) <= set("C*")
+               and set(pattern[1::2]) <= set("DE"),
+               "lfm2 is a stack of mixer-then-feed-forward layers: give "
+               "sublayer_pattern two letters a layer, C or * then D or E "
+               "(config/arguments.py lfm2_sublayers writes layer_types out)")
+        _check(m.qk_head_norm and m.position_embedding_type == "rotary",
+               "lfm2's attention norms q and k a head, then rotates them")
+        _check(m.use_rms_norm and m.glu_activation == "swiglu",
+               "lfm2 uses RMSNorm and SwiGLU")
+        _check(m.num_experts is None or (
+            m.moe_score_func == "sigmoid" and m.moe_selection_bias
+            and m.moe_normalize_gates and not m.moe_shared_experts),
+               "lfm2 routes by bias-corrected sigmoid scores, normalised "
+               "over the chosen, with no shared expert")
+        _check(not m.use_bias and m.tie_embed_logits,
+               "lfm2 has no biases and ties its head to the embedding")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

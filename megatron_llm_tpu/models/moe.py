@@ -241,7 +241,7 @@ def route(cfg, p_router: Params, x: jax.Array
     _, idx = jax.lax.top_k(pick, k_)                        # [T, K]
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if m.moe_normalize_gates:
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(-1, keepdims=True) + m.moe_gate_eps)
     w = w * m.moe_routed_scaling_factor
     # rows an expert received: compares, no scatter ([T*K, E] booleans)
     counts = (idx.reshape(-1, 1) == jnp.arange(e_)[None, :]).sum(

@@ -140,14 +140,14 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
 
         p["moe"] = init_moe_params(cfg, jax.random.fold_in(k[2], 0))
     else:
-        p["mlp"] = {
-            # GLU fc1 is [h, 2, ffn] (value half at [:,0,:], gated half at
-            # [:,1,:]) so a tp sharding on the ffn axis never splits across
-            # the gate/value boundary — the flat reference layout would force
-            # a resharding at the chunk-2 split under GSPMD.
-            "fc1": {"kernel": _normal(k[2], (h, 2, ffn) if glu else (h, ffn), std)},
-            "fc2": {"kernel": _normal(k[3], (ffn, h), out_std)},
-        }
+        # GLU fc1 is [h, 2, ffn] (value half at [:,0,:], gated half at
+        # [:,1,:]) so a tp sharding on the ffn axis never splits across
+        # the gate/value boundary — the flat reference layout would force
+        # a resharding at the chunk-2 split under GSPMD.  Drawn by
+        # :func:`init_mlp_params` (at the file's end: a one-sublayer
+        # stack's dense MLP is the same subtree, models/sublayers.py);
+        # the call keeps this function's lines where they were
+        p["mlp"] = init_mlp_params(cfg, k[2], k[3])
     if not m.parallel_attn:
         p["post_norm"] = new_norm()
     if m.parallel_layernorm:
@@ -194,7 +194,7 @@ def init_mixer_params(cfg, k_in: jax.Array, k_out: jax.Array,
     return {
         "qkv": {"kernel": _normal(k_in, (h, (n + 2 * nkv) * d), std)},
         "dense": {"kernel": _normal(k_out, (n * d, h), out_std)},
-    }
+        **_init_head_norms(cfg)}
 
 
 def init_mixers(cfg, key: jax.Array) -> Params:
@@ -501,7 +501,7 @@ class LayerKind(NamedTuple):
     ``mixer``: 'attention' (the model's ``attention_type``) or 'delta' (a
     linear layer: the gated delta rule on a recurrent state); in a stack of
     one-sublayer layers (models/sublayers.py) the layer's ONE sublayer:
-    'attention', 'mamba' or 'experts'."""
+    'attention', 'mamba', 'experts', 'conv' (a short convolution), 'mlp'."""
 
     window: Optional[int]
     rotate: bool
@@ -667,7 +667,7 @@ def _attention(cfg, p, x, rope, position_ids, segment_ids, dropout_key,
 
     linear = _linear_impl(cfg)
     qkv = apply_column_parallel(cfg, p["qkv"], x, linear)
-    q, k, v = split_qkv(qkv, n, nkv, d)
+    q, k, v = _head_normed(cfg, p, *split_qkv(qkv, n, nkv, d))
 
     if rope is not None and kind.rotate:
         cos, sin = rope
@@ -1394,7 +1394,7 @@ def transformer_forward(
     if cfg.model.sublayer_pattern:    # layers of ONE sublayer: their own stack
         return _sublayers().sublayer_stack_forward(
             cfg, stacked_layers, hidden, kv_caches=kv_caches, paged=paged,
-            unserved=dict(
+            rope=rope, position_ids=position_ids, unserved=dict(
                 segment_ids=segment_ids, token_idx=token_idx,
                 attn_bias=attn_bias, encoder_hidden=encoder_hidden,
                 enc_bias=enc_bias, cache_index=cache_index,
@@ -1619,3 +1619,32 @@ def _sublayers():
     from megatron_llm_tpu.models import sublayers
 
     return sublayers
+
+
+def _init_head_norms(cfg) -> Params:
+    """``qk_head_norm``: the leaves of one RMSNorm a head on q and on k."""
+    if not cfg.model.qk_head_norm:
+        return {}
+    return {"q_norm": init_norm_params(cfg.model.kv_channels, True),
+            "k_norm": init_norm_params(cfg.model.kv_channels, True)}
+
+
+def _head_normed(cfg, p: Params, q, k, v):
+    """``qk_head_norm``: q and k each under its RMSNorm over a head's
+    ``kv_channels``, BEFORE the rotation (float32 inside, the activations'
+    dtype out: what the pages then hold is the normed, rotated key)."""
+    if not cfg.model.qk_head_norm:
+        return q, k, v
+    eps = cfg.model.layernorm_epsilon
+    return norm(q, p["q_norm"], eps, True), norm(k, p["k_norm"], eps, True), v
+
+
+def init_mlp_params(cfg, k_in: jax.Array, k_out: jax.Array) -> Params:
+    """The ``mlp`` subtree: a dense MLP of ``ffn_hidden_size``, inside a
+    layer (:func:`init_layer_params`) or as a sublayer of its own."""
+    m = cfg.model
+    h, ffn, std = m.hidden_size, m.ffn_hidden_size, m.init_method_std
+    out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
+    glu = m.glu_activation is not None
+    return {"fc1": {"kernel": _normal(k_in, (h, 2, ffn) if glu else (h, ffn), std)},
+            "fc2": {"kernel": _normal(k_out, (ffn, h), out_std)}}

@@ -88,12 +88,13 @@ def zero_state(lead: Tuple[int, ...], hv: int, dk: int, dv: int,
 def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
     """``x [b, s, c]``, ``w [width, c]`` (``w[-1]`` multiplies the current
     input): ``y_t = sum_j w[width - 1 - j] x_{t - j}``, zeros before the
-    sequence.  float32."""
+    sequence, summed from the current input back as :func:`conv_tick`
+    sums.  float32."""
     width = w.shape[0]
     x = x.astype(F32)
     pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     s = x.shape[1]
-    return sum(pad[:, j:j + s] * w[j].astype(F32) for j in range(width))
+    return sum(pad[:, j:j + s] * w[j].astype(F32) for j in range(width)[::-1])
 
 
 def conv_tick(x: jax.Array, w: jax.Array, tails: jax.Array, slots, positions,
@@ -136,7 +137,8 @@ def conv_tick(x: jax.Array, w: jax.Array, tails: jax.Array, slots, positions,
     goes_on = jnp.concatenate([live[1:] & ~first[1:], jnp.zeros((1,), bool)])
     new_tail = jnp.concatenate(prev[:back - 1][::-1] + [x], axis=1)
     to = jnp.where(live & ~goes_on, base + slots, -1)       # else: nowhere
-    return jnp.where(live[:, None], y, 0.0), _put_rows(tails, new_tail, to)
+    return jnp.where(live[:, None], y, 0.0), _put_rows(
+        tails, new_tail.astype(tails.dtype), to)
 
 
 def _put_rows(pool: jax.Array, new: jax.Array, to: jax.Array) -> jax.Array:
@@ -306,3 +308,25 @@ def delta_tick(q, k, v, g, beta, pool: jax.Array, slots, positions,
     pool, o = jax.lax.scan(
         one, pool, (q, k, v, g, beta, slots, live, fresh))
     return o, pool
+
+
+# ---------------------------------------------------------------------------
+# A state that is a conv tail alone (a gated short convolution: LFM2)
+# ---------------------------------------------------------------------------
+
+
+class ConvTail(NamedTuple):
+    """What a sequence keeps of a gated short-convolution layer: ``conv``
+    ``[layers * (slots + 1), (width - 1) * channels]``, the conv's last
+    inputs as :class:`DeltaState` lays them out, and nothing else (no
+    recurrent ``s``).  Its dtype is the activations': the conv's inputs are
+    rounded to it before they are kept or convolved, so the tail holds them
+    exactly."""
+
+    conv: jax.Array
+
+
+def zero_tails(lead: Tuple[int, ...], conv_width: int, channels: int,
+               dtype) -> ConvTail:
+    return ConvTail(jnp.zeros((math.prod(lead),
+                               (conv_width - 1) * channels), dtype))

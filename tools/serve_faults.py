@@ -6,6 +6,7 @@ fail, at one seed and with no measured window.
     chiprun -- python tools/serve_faults.py --workload brumby14b_longgen_closed --seed N
     chiprun -- python tools/serve_faults.py --workload gigachat35_reasoning_closed --seed N
     chiprun -- python tools/serve_faults.py --workload nemotron3_nano_chat_closed --seed N
+    chiprun -- python tools/serve_faults.py --workload lfm2_24b_chat_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -36,7 +37,14 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   reading group 0's B and C, the gated norm over all 4,096 values for the
   groups' 512, the conv's bias dropped, ``relu`` for ``relu^2``, the routed
   scaling factor 1 for 2.5, the shared expert left out, rotary embedding
-  applied.  A fault is tried where the cell's reference has its choice.
+  applied; and the eleven of ``benchmark/reference/lfm2_block.py``: a short
+  conv's taps reversed, the conv reading one input ahead, the gate ``B``
+  dropped, the gate ``C`` dropped, its tail dropped at a tick's boundary
+  (256 prompt rows) and at every row (a decode row is a run of its
+  own), the head norms on q and k left out, the rotary
+  embedding left out, ``expert_bias`` used in the weight, the chosen
+  scores' normalisation dropped, the two dense layers treated as expert
+  layers.  A fault is tried where the cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
 readings and the others over a dozen seeds; an entry other than ``program``
@@ -85,6 +93,17 @@ FAULTS = {
     "scaling_factor_one": ("routed_scale", lambda model: 1.0),
     "shared_expert_left_out": ("shared_weight", lambda model: 0.0),
     "rotary_applied": ("rotates_qk", lambda model: True),
+    "conv_taps_reversed": ("conv_taps", lambda w: w[::-1]),
+    "conv_acausal": ("conv_lookahead", lambda model: 1),
+    "conv_gate_b_dropped": ("in_gate", lambda b, u: u),
+    "conv_gate_c_dropped": ("out_gate", lambda cg, y: y),
+    "conv_tail_dropped_at_a_run": ("conv_restarts_every", lambda model: 256),
+    "conv_tail_never_carried": ("conv_restarts_every", lambda model: 1),
+    "qk_norm_left_out": ("qk_normed", lambda model: False),
+    "rope_left_out": ("rope_applied", lambda model: False),
+    "expert_bias_in_the_weight": ("weighed_scores", lambda s, biased: biased),
+    "gate_normalisation_dropped": ("gates_normalised", lambda model: False),
+    "dense_layers_as_expert_layers": ("dense_layers", lambda model: 0),
 }
 
 
@@ -138,8 +157,8 @@ def main() -> int:
     for name, (choice, fault) in FAULTS.items():
         if args.no_faults or not hasattr(ref, choice):
             continue
-        # the reference jits its layer program anew every call, so the
-        # choice is traced in
+        # the reference traces its layer programs with the choice as it
+        # stands (anew every call, or keyed by it: lfm2_block._traced_with)
         with mock.patch.object(ref, choice, fault):
             faulty = check.emitted_reference(cell, served.params, probes)
         line[name] = check.compare(cell, got, faulty)

@@ -21,6 +21,7 @@ Usage (through the chip tool; off-TPU it exits 2):
     python tools/tpu_kernel_check.py [--quick | --time | --brumby [--time]
                                       | --glu_stack [--time]
                                       | --conv_tick [--time]
+                                      | --short_conv [--time]
                                       | --mamba [--time]]
 
 ``--quick`` is numerics at the preset geometries only (chip_smoke.py's
@@ -1291,6 +1292,76 @@ def conv_tick_check(timed: bool):
               f"HBM peak)", flush=True)
 
 
+def short_conv_check(timed: bool):
+    """``ops/gated_delta.conv_tick`` compiled at the LFM2 cell's shape: 512
+    rows of 2,048 channels (255 decode rows, a dead row, one prompt run of
+    256), a filter of THREE taps, 30 layers' bfloat16 tails of 257 slots;
+    two ticks of a feed against ``causal_conv`` over the same sequences and
+    the tails they leave against the inputs themselves, BIT FOR BIT (the
+    same three products summed in the same order; the tail holds bfloat16
+    inputs exactly); ``timed``: its device time a call, the pool donated as
+    the engine donates it."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops import gated_delta as gd
+
+    c, per, layers, layer, dec, run = 2048, 257, 30, 17, 255, 256
+    base = layer * per
+    ks = jax.random.split(jax.random.PRNGKey(23), 4)
+    w = jax.random.normal(ks[0], (3, c), jnp.bfloat16) * 0.577
+    decode = jax.random.normal(ks[1], (dec, 2, c), jnp.bfloat16)
+    prompt = jax.random.normal(ks[2], (1, 2 * run, c), jnp.bfloat16)
+    pool = jax.random.normal(ks[3], (layers * per, 2 * c), jnp.bfloat16)
+    start = np.asarray(pool.astype(jnp.float32))
+    tick = jax.jit(gd.conv_tick, donate_argnums=(2,))
+    slots = np.concatenate([1 + np.arange(dec), [0], np.full(run, per - 1)])
+    want_d = np.asarray(gd.causal_conv(decode, w))
+    want_p = np.asarray(gd.causal_conv(prompt, w))[0]
+    tails, same = pool + 0, True
+    for at in (0, 1):
+        pos = np.concatenate([np.full(dec + 1, at),
+                              at * run + np.arange(run)])
+        x = jnp.concatenate([decode[:, at], jnp.zeros((1, c), jnp.bfloat16),
+                             prompt[0, at * run:(at + 1) * run]])
+        y, tails = tick(x, w, tails, jnp.asarray(slots, jnp.int32),
+                        jnp.asarray(pos, jnp.int32), base)
+        y = np.asarray(y)
+        same &= (np.array_equal(y[:dec], want_d[:, at])
+                 and not y[dec].any()
+                 and np.array_equal(y[dec + 1:],
+                                    want_p[at * run:(at + 1) * run]))
+    got = np.asarray(tails.astype(jnp.float32))
+    last = np.concatenate([
+        np.asarray(decode.astype(jnp.float32)).reshape(dec, 2 * c),
+        np.asarray(prompt[0, -2:].astype(jnp.float32)).reshape(1, 2 * c)])
+    named = base + np.concatenate([1 + np.arange(dec), [per - 1]])
+    rest = np.setdiff1d(np.arange(layers * per), named)
+    kept = np.array_equal(got[named], last)
+    apart = np.array_equal(got[rest], start[rest])
+    check("short conv tick 255 decode + 256 prompt rows", same and kept
+          and apart and tails.dtype == jnp.bfloat16,
+          f"every live row equals causal_conv's bit for bit: {same}; the "
+          f"{len(named)} named slots hold their sequences' last two inputs: "
+          f"{kept}; the other {len(rest)} rows of the pool as they were: "
+          f"{apart}")
+    if not timed:
+        return
+    held = [pool + 0]
+
+    def call():
+        out = tick(x, w, held.pop(), jnp.asarray(slots, jnp.int32),
+                   jnp.asarray(pos, jnp.int32), base)
+        held.append(out[1])
+        return out
+
+    took = busy_seconds(call)
+    moved = (2 * 512 * c * 2 + 512 * c * 4 + 2 * len(named) * 2 * c * 2)
+    print(f"TIME short conv tick: {took * 1e3:.3f} ms a call on the device "
+          f"(one layer; 30 a tick); its rows in (bf16) and out (f32) and "
+          f"the {len(named)} tails read and written are {moved / 1e6:.1f} MB "
+          f"({moved / 819e9 * 1e3:.3f} ms at the HBM peak)", flush=True)
+
+
 # (rows, h, ffn, layers): the GLU fc1 of the Brumby cell's decode tick and
 # of its widest tick, Command A+'s four shared experts, JoyAI's dense layer
 GLU_STACKS = ((40, 5120, 17408, 2), (104, 5120, 17408, 2),
@@ -1493,6 +1564,11 @@ def main():
                          "shape against causal_conv (with --time: its ms a "
                          "call beside the write it had until PR 51), and "
                          "nothing else")
+    ap.add_argument("--short_conv", action="store_true",
+                    help="the conv tick at the LFM2 cell's shape (512 rows, "
+                         "three taps, 30 layers' bfloat16 tails of 257 "
+                         "slots) against causal_conv, bit for bit (with "
+                         "--time: its ms a call), and nothing else")
     ap.add_argument("--mamba", action="store_true",
                     help="the Mamba-2 state sweep at the Nemotron cell's "
                          "tick shapes against its jnp form, the tiles' plan "
@@ -1516,9 +1592,11 @@ def main():
         print("FAIL not on a TPU: this check compiles the kernels for the "
               "device; the CPU half is tests/ in interpret mode")
         sys.exit(2)
-    if args.brumby or args.glu_stack or args.conv_tick or args.mamba:
+    if (args.brumby or args.glu_stack or args.conv_tick or args.mamba
+            or args.short_conv):
         (brumby_check if args.brumby else glu_stack_check if args.glu_stack
-         else mamba_check if args.mamba else conv_tick_check)(args.time)
+         else mamba_check if args.mamba else short_conv_check
+         if args.short_conv else conv_tick_check)(args.time)
         print(f"\n{len(FAILURES)} failures"
               + (f": {FAILURES}" if FAILURES else ""))
         sys.exit(1 if FAILURES else 0)
