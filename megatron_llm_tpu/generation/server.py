@@ -936,6 +936,10 @@ class MegatronServer:
         with startup_phase("server-bind"):
             self._httpd = ThreadingHTTPServer((host, port),
                                               self._make_handler())
+            # the stdlib listens with a backlog of 5 and the kernel resets
+            # the connection that finds it full: a closed loop of 512
+            # clients lost one request in 631 to that (PERF.md, PR 58)
+            self._httpd.socket.listen(1024)
         return self._httpd.server_address[1]
 
     def serve(self):

@@ -43,7 +43,11 @@ adds the capability TPU-first, in the GShard/Switch/Mixtral lineage:
   experts' part of the layer's sum.  The row buffer is static:
   ``moe_capacity_factor`` x the held share of the ``T x topk`` assignments
   (at most all of them), and an assignment that finds it full is dropped
-  and COUNTED (``aux[5]``), never silently.
+  and COUNTED (``aux[5]``), never silently.  Every call works the WHOLE
+  buffer: a short one for the calls whose held assignments fit it (a
+  ``jax.lax.cond`` over two row counts, equal bits) was built and
+  measured in PR 58 and gave back inside its branch what it saved the
+  dispatch, at 6 s of set-up (PERF.md, section 6).
 * **The router's options are data of the family** (:func:`route`):
   softmax or sigmoid scores, a selection bias that picks the top-k and is
   not part of the weight, renormalisation, a scaling factor; and
@@ -296,28 +300,34 @@ def _row_tile(m: int, groups: int) -> int:
         m // groups >= _GMM_ROWS_MANY) else _GMM_ROWS
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gmm(rows, kernel, sizes, transposed=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gmm(rows, kernel, sizes, first=None, transposed=False):
     """megablox ``gmm`` with tiles chosen per problem in the backward too
     (jax's own vjp reuses the forward's tiles on the transposed shapes).
-    ``transposed``: the ``W[e]`` lie as ``[n, k]``."""
+    ``transposed``: the ``W[e]`` lie as ``[n, k]``.  ``first`` (a scalar,
+    the serving tick's stack): ``sizes`` are the groups ``first, first + 1,
+    ...`` of ``kernel`` and no row belongs to any other."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     (m, k), n = rows.shape, kernel.shape[-2 if transposed else -1]
     tm = _row_tile(m, kernel.shape[0])
+    # the kernel reads group g's weights at ``g - group_offset``
     return gmm(rows, kernel, sizes, preferred_element_type=rows.dtype,
                tiling=_gmm_tiles(tm, k, n, rows.dtype.itemsize),
+               group_offset=None if first is None else -first,
                transpose_rhs=transposed)
 
 
-def _gmm_fwd(rows, kernel, sizes, transposed):
-    return _gmm(rows, kernel, sizes, transposed), (rows, kernel, sizes)
+def _gmm_fwd(rows, kernel, sizes, first, transposed):
+    return _gmm(rows, kernel, sizes, first, transposed), (
+        rows, kernel, sizes, first)
 
 
 def _gmm_bwd(transposed, res, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-    rows, kernel, sizes = res
+    rows, kernel, sizes, first = res
+    assert first is None, "a stack of layers is the serving tick's: no vjp"
     (m, k), n = rows.shape, kernel.shape[-2 if transposed else -1]
     tm = _row_tile(m, kernel.shape[0])
     d_rows = gmm(g, kernel, sizes, preferred_element_type=rows.dtype,
@@ -332,7 +342,7 @@ def _gmm_bwd(transposed, res, g):
                     tiling=(tm, ta, _tile(b, max(128, _GMM_GRAD_TILE // ta
                                                  // 128 * 128))),
                     num_actual_groups=kernel.shape[0])
-    return d_rows, d_kernel, None
+    return d_rows, d_kernel, None, None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
@@ -361,28 +371,37 @@ def grouped_matmul(rows: jax.Array, kernel: jax.Array, counts: jax.Array,
     PR 31: 4.1 ms a layer against ``jax.lax.ragged_dot``'s 8.7 at 256
     experts x 8 rows); at the trainer's hundreds of rows an expert a row
     tile is 512, so that the weights are streamed a quarter as often.  The
-    kernel is handed the WHOLE leaf as ``[groups, k, n]``, a view, with
-    rows for the groups meant and none for the others: a slice of the leaf
-    would be a copy of it.  A width need not be on the 128-lane grid (the
-    kernel cuts its last tile: :func:`_tile`; 1,856 runs so on the chip, PR
-    53) but on a 64 grid and at least 128.  Elsewhere (the CPU tests, tiny
-    widths), ``jax.lax.ragged_dot`` on the slice: the same sums."""
+    kernel is handed the WHOLE leaf as ``[groups, k, n]``, a view (a slice
+    of the leaf would be a copy of it), with the sizes of the meant layer's
+    groups and the place in the leaf where they start.  A width need not
+    be on the 128-lane grid (the kernel cuts its last tile: :func:`_tile`;
+    1,856 runs so on the chip, PR 53) but on a 64 grid and at least 128.
+    Elsewhere (the CPU tests, tiny widths), ``jax.lax.ragged_dot`` on the
+    slice: the same sums."""
     from megatron_llm_tpu.core.parallel_state import target_platform
 
     m, (k, n) = rows.shape[0], kernel.shape[-2:][::-1 if transposed else 1]
-    pick = (() if layer is None else (layer,)) + (slice(None),) + (
-        () if half is None else (half,))
+    within = (slice(None),) + (() if half is None else (half,))
+    pick = (() if layer is None else (layer,)) + within
     if target_platform() != "tpu" or k % 64 or n % 64 or min(k, n) < 128:
         sliced = kernel[pick]
         return jax.lax.ragged_dot(
             rows, sliced.swapaxes(-1, -2) if transposed else sliced, counts)
 
-    sizes = jnp.zeros(kernel.shape[:-2], counts.dtype).at[pick].set(counts)
+    # the kernel lays out its tiles in plain jnp from the groups' sizes (a
+    # cumsum, two searches: a loop of tiny operations before every call),
+    # so it is told of ONE layer's groups and where in the stack they start,
+    # not of every layer's with none but one's non-empty (608 groups for 16
+    # a call, three calls a layer: 6 ms a tick of the LFM2 cell, PR 58)
+    groups = kernel.shape[:-2] if layer is None else kernel.shape[1:-2]
+    sizes = jnp.zeros(groups, counts.dtype).at[within].set(counts)
+    first = None if layer is None else jnp.asarray(layer * sizes.size,
+                                                   jnp.int32)
     pad = -m % _GMM_ROWS
     if pad:   # whole row tiles; the padding rows belong to no group
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     out = _gmm(rows, kernel.reshape(-1, *kernel.shape[-2:]).astype(rows.dtype),
-               sizes.reshape(-1), transposed)
+               sizes.reshape(-1), first, transposed)
     return out[:m] if pad else out
 
 

@@ -597,3 +597,52 @@ def test_mixtral_family_config():
     # finalize rejects ep>1 without MoE
     with pytest.raises(AssertionError):
         make_config("llama2", vocab_size=256, expert_parallel_size=2)
+
+
+# ---------------------------------------------------------------------------
+# the grouped kernel on a stack of layers (the serving tick's form)
+# ---------------------------------------------------------------------------
+
+from unittest import mock  # noqa: E402
+
+from megatron_llm_tpu.models import moe  # noqa: E402
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("form", ["fc2", "glu_half_1", "transposed"])
+def test_stacked_kernel_is_told_of_one_layers_groups(layer, form):
+    """The TPU path in interpret mode: the grouped kernel handed the whole
+    stack ``[L, E, (2,) k, n]`` with ONE layer's group sizes and the place
+    in the stack where they start (megablox reads group ``g``'s weights at
+    ``g - group_offset``) gives what the layer's own slice gives, for an
+    fc2, a GLU half (every second group of the layer) and a transposed
+    fc1; rows behind the last group belong to none."""
+    import functools
+    import importlib
+
+    megablox = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    half = 1 if form == "glu_half_1" else None
+    transposed = form == "transposed"
+    lead = (3, 4, 2) if half is not None else (3, 4)
+    stack = jax.random.normal(jax.random.PRNGKey(0), lead + (128, 256),
+                              jnp.float32)
+    if transposed:
+        stack = stack.swapaxes(-1, -2)
+    rows = jax.random.normal(jax.random.PRNGKey(1), (256, 128), jnp.float32)
+    counts = jnp.asarray([70, 0, 9, 131], jnp.int32)       # 210 of 256 rows
+    want = moe.grouped_matmul(rows, stack, counts, jnp.asarray(layer), half,
+                              transposed)                  # ragged_dot
+    with mock.patch("megatron_llm_tpu.core.parallel_state.target_platform",
+                    lambda: "tpu"), \
+            mock.patch.object(megablox, "gmm", functools.partial(
+                megablox.gmm, interpret=True)):
+        got = jax.jit(lambda l: moe.grouped_matmul(
+            rows, stack, counts, l, half, transposed))(jnp.asarray(layer))
+        # one layer's own leaf: the form the trainer's scanned slice takes
+        alone = moe.grouped_matmul(rows, stack[layer], counts, None, half,
+                                   transposed)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+    np.testing.assert_allclose(np.asarray(got[:210]), np.asarray(want[:210]),
+                               rtol=0, atol=1e-4)
+    assert float(jnp.abs(got[:210]).max()) > 1
