@@ -197,6 +197,14 @@ class ModelConfig:
     # 'mha' only: one RMSNorm a head on q and on k before the rotation
     # (leaves `attention/q_norm`, `attention/k_norm` of kv_channels)
     qk_head_norm: bool = False
+    # generation by diffusion over blocks (SDAR): the attention mask is
+    # BLOCK-causal (a query sees every key up to the end of its own block of
+    # `diffusion_block_length` positions, cut from position 0), and the
+    # engine unmasks a block's positions over several denoising steps from
+    # the row `mask_token_id` of the vocabulary (generation/blocks.py).
+    # None: a causal model, which decodes one token a step
+    diffusion_block_length: Optional[int] = None
+    mask_token_id: Optional[int] = None
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
@@ -361,6 +369,19 @@ class ModelConfig:
         assert not self.qk_head_norm or self.attention_type == "mha", (
             "qk_head_norm is the 'mha' path's: latent attention norms its "
             "latents and power retention has head norms of its own")
+        if self.diffusion_block_length is not None:
+            assert (self.diffusion_block_length > 0
+                    and self.mask_token_id is not None
+                    and 0 <= self.mask_token_id < (self.vocab_size or 1 << 31)), (
+                "generation by diffusion over blocks needs a block length "
+                "and mask_token_id, a row of the vocabulary")
+            assert (self.attention_type == "mha" and not self.bidirectional
+                    and self.sliding_window_size is None
+                    and not self.sliding_window_layout
+                    and not self.sublayer_pattern and not self.linear_layout), (
+                "the block-causal mask is written for one class of K/V "
+                "pages: 'mha' layers with no window, no pattern and no "
+                "state class")
         if self.retention:
             assert (self.sliding_window_size is None
                     and not self.sliding_window_layout
@@ -982,7 +1003,7 @@ class Config:
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
                 "mistral", "mixtral", "joyai", "smallthinker", "commanda",
-                "gigachat35", "nemotron_h", "lfm2",
+                "gigachat35", "nemotron_h", "lfm2", "sdar_moe",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -1279,6 +1300,23 @@ ARCH_DEFAULTS = {
         moe_normalize_gates=True,
         moe_gate_eps=1e-6,
     ),
+    # JetLM SDAR (`sdar_moe`): the Qwen3-MoE block (QK-normed rotated GQA,
+    # SwiGLU experts behind a softmax router normalised over the chosen, no
+    # shared expert, untied head) under a BLOCK-causal mask, generated from
+    # by diffusion over blocks of `diffusion_block_length`
+    "sdar_moe": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=1_000_000.0,
+        qk_head_norm=True,
+        moe_score_func="softmax",
+        moe_normalize_gates=True,
+        diffusion_block_length=4,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1403,6 +1441,15 @@ MODEL_SIZES = {
         ffn_hidden_size=11776, num_experts=64, moe_router_topk=4,
         moe_ffn_hidden_size=1536, moe_routed_scaling_factor=1.0,
         vocab_size=65536),
+    # SDAR-30B-A3B-Chat: 48 layers, every one 128 experts of 768, top-8;
+    # `intermediate_size` 6144 is read by no layer; blocks of 4 (the
+    # report's training block), the mask id the published tokenizer's
+    "sdar-30b-a3b-chat": dict(
+        num_layers=48, hidden_size=2048,
+        num_attention_heads=32, num_attention_heads_kv=4, kv_channels=128,
+        max_position_embeddings=32768, ffn_hidden_size=6144,
+        num_experts=128, moe_router_topk=8, moe_ffn_hidden_size=768,
+        vocab_size=151936, diffusion_block_length=4, mask_token_id=151669),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,
@@ -1413,9 +1460,14 @@ MODEL_SIZES = {
 }
 
 
+# a canonical size whose family is not the name's first word
+SIZE_FAMILIES = {"sdar-30b-a3b-chat": "sdar_moe"}
+
+
 def apply_architecture(cfg: Config, model_name: str, size: Optional[str] = None) -> Config:
     """Apply an architecture flag bundle (and optionally a canonical size)."""
     family = model_name.split("-")[0] if model_name not in ARCH_DEFAULTS else model_name
+    family = SIZE_FAMILIES.get(model_name, family)
     if model_name in MODEL_SIZES and size is None:
         size = model_name
     assert family in ARCH_DEFAULTS, f"unknown model family {family}"

@@ -153,7 +153,7 @@ from megatron_llm_tpu.models.language_model import (
     make_rope_cache,
     model_forward,
 )
-from megatron_llm_tpu.generation.ragged import decode_order
+from megatron_llm_tpu.generation.ragged import block_driver, decode_order
 from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
 from megatron_llm_tpu.ops.pallas.paged_attention import (
@@ -667,7 +667,7 @@ class ContinuousBatchingEngine:
         # only; reads/writes serialize under _drive_lock)
         self._last_dispatch_end: Optional[float] = None
         # steps that raised in the scheduler loop (:meth:`_fail_all`)
-        self.failures = 0
+        self.failures, self._blocks = 0, block_driver(self)
         # tick/cache telemetry for the decode bench
         self.ticks = 0
         self.ticked_tokens = 0
@@ -748,7 +748,7 @@ class ContinuousBatchingEngine:
                  "failure); every request in flight answered 500")
         self._m_tokens = reg.counter(
             "mlt_engine_ticked_tokens_total",
-            help="slot-steps advanced (tokens sampled) across ticks")
+            help="tokens appended to requests (a causal slot-step: one)")
         self._m_active = reg.gauge(
             "mlt_engine_active_slots", help="decode slots occupied")
         self._m_queued = reg.gauge(
@@ -1386,7 +1386,7 @@ class ContinuousBatchingEngine:
                 refuse_unserved(self.cfg, log_probs=True)
             except ValueError as e:
                 raise gen.InvalidRequest(str(e)) from None
-        req = EngineRequest(prompt=prompt, max_new_tokens=max_new_tokens, **kw)
+        req = self._new_request(prompt, max_new_tokens, kw)
         req._t_submit = time.monotonic()
         # flight record + enqueue event (observability/flight.py): a
         # request turned away at the door still leaves a record, so an
@@ -1664,7 +1664,7 @@ class ContinuousBatchingEngine:
             # every page fully covered by seq[:-1] is finished K/V the
             # resume's refeed tick will never write — safe to share
             self.cache.insert(seq, victim._pages,
-                              (len(seq) - 1) // self.page_size,
+                              self._parkable_pages(victim, seq),
                               victim._wpages or None)
         self._clear_slot_locked(slot)
         pages = self._release_pages_locked(victim)
@@ -1765,13 +1765,13 @@ class ContinuousBatchingEngine:
         covered = len(matched) * ps
         # full page-aligned match: the first tick re-feeds the last prompt
         # token and would WRITE the final shared page -> copy-on-write
-        cow = bool(matched) and covered == prompt_len
+        cow = bool(matched) and covered == prompt_len and not self._blocks
         n_keep = len(matched) - (1 if cow else 0)
         fill_end = self._fill_end(prompt_len)
         suffix_pages = (1 if self._state_only
                         else -(-(fill_end - covered) // ps))
         held_core = n_keep + (1 if cow else 0) + suffix_pages
-        extra = 1 if max_total > held_core else 0  # first decode page
+        extra = self._first_pages(max_total - held_core)  # decode pages
         need_now = (1 if cow else 0) + suffix_pages + extra
         remaining = max_total - held_core - extra
         # the window class: the copy-on-write page now, the prompt's pages
@@ -2996,7 +2996,9 @@ class ContinuousBatchingEngine:
                 self._ema_tick_s = (dt if self._ema_tick_s is None
                                     else 0.8 * self._ema_tick_s + 0.2 * dt)
                 self.ticks += 1
-                if rec.spec:
+                if self._blocks is not None:
+                    emitted = self._blocks.apply_locked(rec, *fetched, now)
+                elif rec.spec:
                     emitted = self._apply_spec_locked(
                         rec.active, rec.spec[2], *fetched, now)
                 else:
@@ -3054,6 +3056,36 @@ class ContinuousBatchingEngine:
         fn = self._ragged_tick(pre_rows)
         return obs_compiles.startup_phase(
             "tick-program", rows=pre_rows)(fn) if new else fn
+
+    # What a block model (generation/blocks.py) asks of admission, here for
+    # the same reason; a causal model takes the first branch of each.
+
+    def _new_request(self, prompt, max_new_tokens: int,
+                     kw: dict) -> EngineRequest:
+        if self._blocks is not None:
+            return self._blocks.new_request(prompt, max_new_tokens, kw)
+        for name in ("denoising_steps", "remasking_strategy",
+                     "confidence_threshold"):
+            if name in kw:
+                raise gen.InvalidRequest(
+                    f"{name} is a block model's (diffusion_block_length): "
+                    "this model decodes one token a step")
+        return EngineRequest(prompt=prompt, max_new_tokens=max_new_tokens,
+                             **kw)
+
+    def _first_pages(self, left: int) -> int:
+        """Pages granted at admission beyond the prompt's: the first decode
+        page; every page of a block model's output (its table does not move
+        under a tick in flight)."""
+        return max(left, 0) if self._blocks is not None else int(left > 0)
+
+    def _parkable_pages(self, req: EngineRequest, seq) -> int:
+        """Whole pages of a preempted request's ``seq`` the prefix trie may
+        hold: those ``seq[:-1]`` covers (the resume's refeed tick writes the
+        last token's again); a block model's committed blocks'."""
+        if self._blocks is not None:
+            return self._blocks.parkable_pages(req, seq)
+        return (len(seq) - 1) // self.page_size
 
     def _chunk_program(self, rows: int, kv_pages: int):
         new = (rows, kv_pages) not in self._chunk_fns
@@ -3202,6 +3234,7 @@ class ContinuousBatchingEngine:
         ttft_deadline_ms: Optional[float] = None,
         tpot_deadline_ms: Optional[float] = None,
         trace_id: str = "",
+        **block_kw,
     ):
         """Drop-in for api.generate_and_post_process: tokenize, submit each
         prompt as its own request (all of them share decode ticks), wait,
@@ -3232,6 +3265,7 @@ class ContinuousBatchingEngine:
                 ttft_deadline_ms=ttft_deadline_ms,
                 tpot_deadline_ms=tpot_deadline_ms,
                 trace_id=trace_id,
+                **block_kw,
             ))
         if self._thread is None:
             self.run_until_idle()
@@ -3271,6 +3305,7 @@ class ContinuousBatchingEngine:
         tpot_deadline_ms: Optional[float] = None,
         trace_id: str = "",
         stream_events: int = 256,
+        **block_kw,
     ):
         """``submit_stream`` with ``generate_and_post_process``'s exact
         tokenization and submit kwargs for ONE prompt — the streamed
@@ -3299,6 +3334,7 @@ class ContinuousBatchingEngine:
             ttft_deadline_ms=ttft_deadline_ms,
             tpot_deadline_ms=tpot_deadline_ms,
             trace_id=trace_id,
+            **block_kw,
         )
 
     def finalize_stream_request(self, req: EngineRequest,
@@ -3503,12 +3539,23 @@ class ContinuousBatchingEngine:
         finally:
             self.flight.close(rec)
 
+    def _refuse_static(self, what: str) -> None:
+        if self._blocks is not None:
+            raise ValueError(
+                f"{what} is not written for a model that generates by "
+                "diffusion over blocks (diffusion_block_length "
+                f"{self.cfg.model.diffusion_block_length}): its static "
+                "generation path decodes one token a step under a causal "
+                "mask. Ask the engine for tokens_to_generate >= 1.")
+
     def _legacy(self):
         """A dense-path InferenceEngine view over the SAME (already
         quantized) params — bypasses __init__ so int8 weights are not
         re-quantized."""
         from megatron_llm_tpu.generation.api import InferenceEngine
 
+        self._refuse_static("the dense single-stream path (prompt scoring, "
+                            "beam search)")
         legacy = InferenceEngine.__new__(InferenceEngine)
         legacy.cfg, legacy.params, legacy.tokenizer = (
             self.cfg, self.params, self.tokenizer)

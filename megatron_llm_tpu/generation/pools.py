@@ -51,7 +51,9 @@ NULL_PAGE = 0
 # ``latent`` (MLA's one-leaf pool), ``state`` (power retention's state
 # slots), ``hybrid`` (a state class BESIDE a page class: a sequence holds a
 # slot and pages), ``tails`` (the same beside a state class of conv tails
-# alone: gated short convolutions, no recurrent state); ``paged``, one
+# alone: gated short convolutions, no recurrent state), ``blocks`` (one
+# class of K/V pages under a block-causal mask, generated from by diffusion
+# over blocks: generation/blocks.py); ``paged``, one
 # class of K/V pages, carries every
 # feature and has no row.  The features are what a caller of :func:`refuse_unserved` may
 # ask for: ``kv_dtype`` other than bf16, a ``tp`` or ``pp`` mesh, a
@@ -76,6 +78,9 @@ KEEPS = {
     "hybrid": ("a hybrid stack ({stack}) keeps a recurrent state a sequence "
                "for its linear layers beside pages of {rows} for its "
                "attention layers"),
+    "blocks": ("generation by diffusion over blocks (diffusion_block_length "
+               "{block}) keeps its K/V pages under a block-causal mask and "
+               "a block's ids and known flags a slot on the device"),
     "tails": ("a stack of gated short convolutions ({stack}) keeps the "
               "conv's last inputs a sequence (a tail a layer, no recurrent "
               "state) beside pages of keys and values for its attention "
@@ -172,6 +177,27 @@ NOT_CARRIED = {
         "tokens a row through one block table, and a linear layer takes "
         "one row a token from its state slot, beside latent rows and K/V "
         "pages alike"),
+    ("blocks", "kv_dtype"): (
+        "--kv_dtype {kv_dtype}: a page's scales are set by the page's "
+        "first write, which here is a denoise row's K/V of mask inputs "
+        "that the commit rows then write over; no test holds them"),
+    ("blocks", "tp"): (
+        "tensor-parallel serving (tp {tp}): the block tick works out its "
+        "rows' shared page walks for the whole pool row, not a shard of "
+        "the heads, and no test holds the block state on a mesh"),
+    ("blocks", "pp"): (
+        "pipeline-parallel serving (pp {pp}): the stage pipeline carries "
+        "the causal tick's one mask position a row"),
+    ("blocks", "draft"): (
+        "--spec_k: a draft model proposes the NEXT tokens of a causal "
+        "sequence, and a block's positions are unmasked in no fixed order"),
+    ("blocks", "handoff"): (
+        "the cross-replica KV handoff: it ships a prompt's pages up to its "
+        "last token, and a block model's K/V ends on a block boundary"),
+    ("blocks", "log_probs"): (
+        "return_log_probs (prompt scoring): the scoring chunk scores "
+        "token i + 1 from position i under a causal mask, and this model "
+        "has no such distribution"),
     ("tails", "kv_dtype"): (
         "--kv_dtype {kv_dtype}: no test holds a page's scales beside a "
         "tail slot, and the tail is the conv's inputs as they were fed"),
@@ -207,6 +233,8 @@ def memory_kind(cfg) -> str:
         return "tails" if cfg.model.short_conv else "hybrid"
     if len(states) > 1:
         return "classes"
+    if cfg.model.diffusion_block_length:
+        return "blocks"
     return "latent" if cfg.model.mla else "paged"
 
 
@@ -251,7 +279,8 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
         layout=m.sliding_window_layout,
         stack=(f"sublayer_pattern {m.sublayer_pattern}"
                if m.sublayer_pattern else f"linear_layout {m.linear_layout}"),
-        rows="latent rows" if m.mla else "keys and values")
+        rows="latent rows" if m.mla else "keys and values",
+        block=m.diffusion_block_length)
     raise ValueError(
         f"{keeps}, which {why} "
         "does not carry yet. Serve this model on one chip with --kv_dtype "

@@ -432,3 +432,15 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     return overlapped
 
+
+
+def block_driver(engine):
+    """The engine's driver for a model that generates by diffusion over
+    blocks (generation/blocks.py, whose tick stands in this one's place),
+    None for every other model: here so that the engine's one import of
+    this module finds it, and nothing of it is loaded for a causal model."""
+    if not engine.cfg.model.diffusion_block_length:
+        return None
+    from megatron_llm_tpu.generation.blocks import BlockDriver
+
+    return BlockDriver(engine)

@@ -171,3 +171,22 @@ def sample_per_slot(
     sampled = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
         keys, filtered)
     return jnp.where(top_k == 1, greedy, sampled).astype(jnp.int32)
+
+
+def sample_with_log_prob(
+    keys: jax.Array,         # [b, 2] uint32
+    logits: jax.Array,       # [b, v]
+    *,
+    top_k: jax.Array,
+    top_p: jax.Array,
+    temperature: jax.Array,
+    vocab_size: Optional[int] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`sample_per_slot` and the sample's log-probability under the
+    RAW logits in float32 (what a stream reports; its exponential is the
+    CONFIDENCE a block model's unmasking ranks positions by,
+    generation/blocks.py).  Returns ([b] int32, [b] float32)."""
+    tok = sample_per_slot(keys, logits, top_k=top_k, top_p=top_p,
+                          temperature=temperature, vocab_size=vocab_size)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return tok, jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0]

@@ -133,6 +133,18 @@ def _validate(payload: dict):
         return None, "random_seed must be a positive integer"
     p["random_seed"] = random_seed
 
+    # a block model's unmasking (generation/blocks.py); the engine checks
+    # the values against its block length and refuses them for any other
+    blocks = {}
+    for name, kind in (("denoising_steps", int), ("remasking_strategy", str),
+                       ("confidence_threshold", (int, float))):
+        if name in payload:
+            if not isinstance(payload[name], kind) or isinstance(
+                    payload[name], bool):
+                return None, f"{name} must be a {getattr(kind, '__name__', 'number')}"
+            blocks[name] = payload[name]
+    p["blocks"] = blocks
+
     beam_width = payload.get("beam_width")
     if beam_width is not None:
         if not isinstance(beam_width, int) or beam_width < 1:
@@ -317,7 +329,7 @@ class MegatronServer:
                     use_eod_token_for_early_termination=params[
                         "use_eod_token_for_early_termination"],
                     random_seed=params["random_seed"],
-                    **kw,
+                    **kw, **(params["blocks"] if self.batching else {}),
                 )
                 body = {"text": texts, "segments": segments,
                         "logprobs": logprobs}
@@ -491,7 +503,7 @@ class MegatronServer:
                 priority=params["priority"],
                 ttft_deadline_ms=params["ttft_deadline_ms"],
                 tpot_deadline_ms=params["tpot_deadline_ms"],
-                trace_id=trace_id)
+                trace_id=trace_id, **params["blocks"])
         except EngineOverloaded as eo:
             return 503, {"error": str(eo),
                          "retry_after": getattr(eo, "retry_after", 1.0),

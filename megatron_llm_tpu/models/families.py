@@ -168,6 +168,21 @@ def validate_family(cfg: Config) -> Config:
                "over the chosen, with no shared expert")
         _check(not m.use_bias and m.tie_embed_logits,
                "lfm2 has no biases and ties its head to the embedding")
+    elif name == "sdar_moe":
+        _check(m.diffusion_block_length and m.mask_token_id is not None,
+               "sdar_moe generates by diffusion over blocks: give "
+               "diffusion_block_length and mask_token_id")
+        _check(m.qk_head_norm and m.position_embedding_type == "rotary",
+               "sdar_moe's attention norms q and k a head, then rotates them")
+        _check(m.use_rms_norm and m.glu_activation == "swiglu",
+               "sdar_moe uses RMSNorm and SwiGLU")
+        _check(m.num_experts is not None and m.num_experts > 1
+               and m.moe_score_func == "softmax" and m.moe_normalize_gates
+               and not m.moe_selection_bias and not m.moe_shared_experts,
+               "sdar_moe routes by a softmax over all experts, normalised "
+               "over the chosen, with no shared expert")
+        _check(not m.use_bias and not m.tie_embed_logits,
+               "sdar_moe has no biases and an untied head")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

@@ -706,7 +706,7 @@ def _attention(cfg, p, x, rope, position_ids, segment_ids, dropout_key,
         row_tables = paged.block_tables
         if paged.table_index is not None:
             row_tables = row_tables[paged.table_index]
-        wpos = pos[:, None] + jnp.arange(s)[None, :]       # [b, s]
+        wpos = _write_pos(paged)[:, None] + jnp.arange(s)[None, :]  # [b, s]
         # clip: idle slots' device-side positions keep advancing between
         # engine re-uploads, and a chunk's garbage padding rows may run past
         # the table; clipped lookups resolve to null-page (or
@@ -765,11 +765,11 @@ def _attention(cfg, p, x, rope, position_ids, segment_ids, dropout_key,
     else:
         ctx = attn_ops.attention(
             q, k, v,
-            causal=not m.bidirectional,
+            causal=not m.bidirectional and not m.diffusion_block_length,
             sliding_window=kind.window,
             segment_ids=segment_ids,
             token_idx=token_idx,
-            bias=attn_bias,
+            bias=_block_bias(m, attn_bias, s),
             scale=scale,
             use_flash=cfg.training.use_flash_attn,
             dropout_rate=0.0 if deterministic else m.attention_dropout,
@@ -1648,3 +1648,31 @@ def init_mlp_params(cfg, k_in: jax.Array, k_out: jax.Array) -> Params:
     glu = m.glu_activation is not None
     return {"fc1": {"kernel": _normal(k_in, (h, 2, ffn) if glu else (h, ffn), std)},
             "fc2": {"kernel": _normal(k_out, (ffn, h), out_std)}}
+
+
+def _write_pos(paged):
+    """Where a paged row's K/V lands: its own position.  ``paged.positions``
+    is that too for a causal model; for a block-causal one it is the row's
+    MASK position (its block's last) and the write's comes beside it."""
+    return (paged.positions if paged.write_positions is None
+            else paged.write_positions)
+
+
+def block_mask_position(positions, block: int):
+    """The last position a query at ``positions`` sees under a block-causal
+    mask of ``block``: its own block's last."""
+    return (positions // block + 1) * block - 1
+
+
+def _block_bias(m, attn_bias, s: int):
+    """The dense forward's mask of a block-causal model
+    (``diffusion_block_length``) as an additive bias, blocks cut from
+    position 0; ``attn_bias`` as it came for every other model."""
+    if not m.diffusion_block_length:
+        return attn_bias
+    assert attn_bias is None, "a block-causal model takes no other bias"
+    q_pos = jnp.arange(s)
+    allowed = q_pos[None, :] <= block_mask_position(
+        q_pos, m.diffusion_block_length)[:, None]
+    return jnp.where(allowed, 0.0, attn_ops.NEG_INF).astype(
+        jnp.float32)[None, None]

@@ -81,12 +81,12 @@ class PagedState(NamedTuple):
     # once per row.
     horizons: Optional[jax.Array] = None     # [R] int32 or None
     table_index: Optional[jax.Array] = None  # [R] int32 into block_tables
-    # which of these rows' page walks the Pallas kernel shares
-    # (:func:`plan_walks`), worked out ONCE in front of a tick's layers,
-    # which all call the kernel on these very tables and rows: one a page
-    # class beside ``block_tables`` where those are a tuple.  None: the
-    # kernel reads it off its own call
+    # which walks the Pallas kernel shares (:func:`plan_walks`), worked out
+    # ONCE in front of a tick's layers: one a page class.  None: the kernel
+    # reads it off its own call.  ``write_positions``: where a row's K/V
+    # lands when ``positions`` is its MASK position (a block-causal model)
     walks: Optional[object] = None
+    write_positions: Optional[jax.Array] = None
 
 
 def paged_gather_kv(pool, block_tables: jax.Array, d: int, dtype=None,

@@ -893,3 +893,67 @@ def test_aot_lfm2_tick_compiles_at_published_widths_and_depth():
     # is laid out anew (the sampler's sort over 256 x 65,536 float32 logits
     # moves 64 MiB of ITS temporaries, under a branch greedy rows skip)
     assert not _weights_moved(compiled, 16 << 20, "bf16")
+
+
+def test_aot_block_tick_compiles_at_published_widths_and_depth():
+    """The block tick of SDAR-30B-A3B-Chat as its cell runs it (ALL 48
+    layers, 16 held experts of 128, an eighth of the vocabulary, 32 slots x
+    2 x 4 block rows and 64 prompt rows; abstract parameters) compiles for
+    one v5e in ONE program: the paged kernel at GQA 32 / 4 heads of 128 on
+    rows whose mask position is their block's last, the grouped GEMMs at
+    128 experts of 768, the head on the denoise rows alone; the pool is
+    updated in place and the scopes the cell's readers match are there."""
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.blocks import (
+        BlockState,
+        Unmasking,
+        make_block_tick_fn,
+    )
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    cfg = make_config("sdar-30b-a3b-chat", moe_experts_held=16,
+                      moe_capacity_factor=8.0, vocab_size=18992,
+                      mask_token_id=18991, params_dtype="bfloat16",
+                      seq_length=5152)
+    slots, page, pre, pages, B = 32, 16, 64, 2561, 4
+    width = cfg.data.seq_length // page
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    pool = S((48, pages, page, 2 * 4 * 128), jnp.bfloat16)
+    pool_bytes = np.prod(pool.shape) * 2
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    state = BlockState(S((slots,), i32), S((slots, B), i32),
+                       S((slots, B), flag), S((slots, B), i32),
+                       S((slots,), flag), S((slots,), i32),
+                       S((slots,), flag), S((slots,), i32))
+    un = Unmasking(S((slots,), f32), S((slots,), i32), S((slots,), f32),
+                   S((slots,), i32), S((slots,), i32), S((slots,), f32))
+    with global_mesh(mesh):
+        params = jax.eval_shape(
+            functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
+        params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), params)
+        assert params["layers"]["moe"]["experts"]["fc1"]["kernel"].shape == (
+            48, 16, 2, 2048, 768)
+        assert params["layers"]["moe"]["router"]["kernel"].shape == (
+            48, 2048, 128)
+        assert params["lm_head"]["kernel"].shape == (2048, 19072)
+        lowered = jax.jit(make_block_tick_fn(cfg, pre),
+                          donate_argnums=(1,)).lower(
+            params, pool, S((slots, width), i32), state,
+            S((slots,), flag), state, S((slots, 2), jnp.uint32), un,
+            S((pre,), i32), S((pre,), i32), S((2, width), i32),
+            S((pre,), i32))
+        text = lowered.as_text()
+        assert "paged_attention" in text and "gmm" in text
+        compiled = lowered.compile()
+        stats = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    for scope in ("block_unmask", "block_commit", "/moe/expert_gemm",
+                  "lm_head_loss"):
+        assert scope in hlo, scope
+    assert stats.alias_size_in_bytes >= pool_bytes      # in place
+    assert stats.temp_size_in_bytes < 1 << 30           # and never copied

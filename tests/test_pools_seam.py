@@ -51,6 +51,9 @@ KINDS = {
         linear_key_head_dim=16, linear_value_head_dim=16, num_experts=4,
         moe_router_topk=2, moe_ffn_hidden_size=32,
         rope_yarn_original_max_position=64),
+    "blocks": lambda: make_config(
+        "sdar_moe", **TINY, kv_channels=16, ffn_hidden_size=32, num_experts=4,
+        moe_router_topk=2, moe_ffn_hidden_size=32, mask_token_id=127),
     "tails": lambda: make_config(
         "lfm2", **{k: v for k, v in TINY.items() if k != "num_layers"},
         sublayer_pattern="CD*ECE", kv_channels=16, ffn_hidden_size=96,
@@ -121,13 +124,15 @@ def test_the_table_has_no_row_without_a_case():
     for row in NOT_CARRIED:
         _case(*row)
     kinds = {k for k, _ in NOT_CARRIED}
-    assert kinds == {"share", "classes", "latent", "state", "hybrid", "tails"}
+    assert kinds == {"share", "classes", "latent", "state", "hybrid", "tails",
+                     "blocks"}
     # a state class beside a page class is SERVED (tests/test_gigachat35.py):
     # the row that refused it now says whose that is (the hybrid's, not
     # power retention's), and the hybrid has a row a feature
     assert "linear_layout" in NOT_CARRIED["state", "pattern"]
     assert {f for k, f in NOT_CARRIED if k == "hybrid"} == set(FEATURES)
     assert {f for k, f in NOT_CARRIED if k == "tails"} == set(FEATURES)
+    assert {f for k, f in NOT_CARRIED if k == "blocks"} == set(FEATURES)
     assert {f for _, f in NOT_CARRIED} <= set(FEATURES) | {"mesh", "pattern"}
 
 

@@ -7,6 +7,7 @@ fail, at one seed and with no measured window.
     chiprun -- python tools/serve_faults.py --workload gigachat35_reasoning_closed --seed N
     chiprun -- python tools/serve_faults.py --workload nemotron3_nano_chat_closed --seed N
     chiprun -- python tools/serve_faults.py --workload lfm2_24b_chat_closed --seed N
+    chiprun -- python tools/serve_faults.py --workload sdar30b_chat_blocks_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -44,7 +45,13 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   own), the head norms on q and k left out, the rotary
   embedding left out, ``expert_bias`` used in the weight, the chosen
   scores' normalisation dropped, the two dense layers treated as expert
-  layers.  A fault is tried where the cell's reference has its choice.
+  layers; and those of ``benchmark/reference/sdar_block.py``: a causal
+  mask in the place of the block-causal one, the commit pass left out (later
+  blocks read the K/V of mask inputs), the logits shifted by one, a block's
+  keys read from its final tokens before they are known, the rotary
+  embedding at a row's mask position, and (shared with LFM2's) the head
+  norms and the gates' normalisation left out.  A fault is tried where the
+  cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
 readings and the others over a dozen seeds; an entry other than ``program``
@@ -104,6 +111,13 @@ FAULTS = {
     "expert_bias_in_the_weight": ("weighed_scores", lambda s, biased: biased),
     "gate_normalisation_dropped": ("gates_normalised", lambda model: False),
     "dense_layers_as_expert_layers": ("dense_layers", lambda model: 0),
+    "causal_mask_for_block_causal": ("mask_end", lambda pos, model: pos + 1),
+    "commit_pass_left_out": ("earlier_blocks_clean", lambda model: False),
+    "logits_shifted_by_one": ("logits_shift", lambda model: 1),
+    "block_keys_of_final_tokens": ("own_block_stream", lambda model: False),
+    "rope_at_the_mask_position": ("rope_position", lambda pos, model: (
+        pos // model["diffusion_block_length"] + 1)
+        * model["diffusion_block_length"] - 1),
 }
 
 

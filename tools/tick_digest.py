@@ -110,6 +110,34 @@ def train_step_text(cell, mesh) -> str:
                           jax.ShapeDtypeStruct((), jnp.int32)).as_text()
 
 
+def block_tick_text(cfg, rows: int, params, pool, tables, slots: int,
+                    chunk: int, S) -> str:
+    """The tick of a model that generates by diffusion over blocks
+    (``generation/blocks.py``, in the ragged tick's place), lowered on its
+    own operands."""
+    import jax.numpy as jnp
+
+    from megatron_llm_tpu.generation.blocks import (
+        BlockState,
+        Unmasking,
+        make_block_tick_fn,
+    )
+
+    B = cfg.model.diffusion_block_length
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    state = BlockState(S((slots,), i32), S((slots, B), i32),
+                       S((slots, B), flag), S((slots, B), i32),
+                       S((slots,), flag), S((slots,), i32),
+                       S((slots,), flag), S((slots,), i32))
+    un = Unmasking(S((slots,), f32), S((slots,), i32), S((slots,), f32),
+                   S((slots,), i32), S((slots,), i32), S((slots,), f32))
+    pre = (S((rows,), i32), S((rows,), i32), tables(rows // chunk + 1),
+           S((rows,), i32)) if rows else ()
+    return lowered_text(
+        make_block_tick_fn(cfg, rows), params, pool, tables(slots), state,
+        S((slots,), flag), state, S((slots, 2), jnp.uint32), un, *pre)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
@@ -187,17 +215,21 @@ def main() -> int:
 
             cap = -(-slots // chunk) * chunk
             for rows in (0, cap):
-                tick = make_ragged_tick_fn(cfg, None, 0, rows, mesh=mesh)
-                pre = (S((rows,), jnp.int32), S((rows,), jnp.int32),
-                       tables(rows // chunk + 1), S((rows,), jnp.int32),
-                       S((rows,), jnp.int32)) if rows else ()
-                text = lowered_text(
-                    tick, params, pool, tables(slots),
-                    S((slots,), jnp.int32), S((slots,), jnp.int32),
-                    S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
-                    S((slots,), jnp.float32), S((slots,), jnp.int32),
-                    S((slots,), jnp.float32), S((slots,), jnp.int32),
-                    S((slots,), jnp.bool_), *pre)
+                if cfg.model.diffusion_block_length:
+                    text = block_tick_text(cfg, rows, params, pool,
+                                           tables, slots, chunk, S)
+                else:
+                    tick = make_ragged_tick_fn(cfg, None, 0, rows, mesh=mesh)
+                    pre = (S((rows,), jnp.int32), S((rows,), jnp.int32),
+                           tables(rows // chunk + 1), S((rows,), jnp.int32),
+                           S((rows,), jnp.int32)) if rows else ()
+                    text = lowered_text(
+                        tick, params, pool, tables(slots),
+                        S((slots,), jnp.int32), S((slots,), jnp.int32),
+                        S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+                        S((slots,), jnp.float32), S((slots,), jnp.int32),
+                        S((slots,), jnp.float32), S((slots,), jnp.int32),
+                        S((slots,), jnp.bool_), *pre)
                 named |= payload_files(text)
                 if me in payload_files(text) and name not in reach_caller:
                     reach_caller.append(name)
