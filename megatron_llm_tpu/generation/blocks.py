@@ -25,11 +25,12 @@ commit rides on the next block's first step and costs no tick of its own.
 Every row of a block has ONE mask position (its block's last) and its own
 rotary and write position: ``PagedState.positions`` carries the first,
 ``write_positions`` the second, and the paged kernel and its fallback mask
-as they always did.  The rows of one block name one table and one mask
-position, so the kernel's second grouping rule
-(ops/pallas/paged_attention.tile_shares: rows of one tile that name the same
-pages) serves them the whole compute blocks below their last key by ONE
-walk; what is left is each row's walk of the last, partial compute block.
+as they always did.  A slot's span is one kernel tile and all its rows name
+ONE table, so the kernel's grouping rule
+(ops/pallas/paged_attention.tile_shares: a span of one table's rows is
+walked whole, whatever their positions) serves the denoise rows and the
+commit rows beside them by ONE walk through the last compute block any of
+them sees, each row under its own mask; no row walks a block alone.
 Commit rows skip the head; so do prompt rows.
 
 Whether a position is masked is its KNOWN flag, never ``id ==
@@ -85,7 +86,7 @@ from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.observability import trace as obs_trace
 from megatron_llm_tpu.ops.attention import announce_path
 from megatron_llm_tpu.ops.paged_attention import PagedState, plan_walks
-from megatron_llm_tpu.ops.pallas.paged_attention import tile_runs, tile_shares
+from megatron_llm_tpu.ops.pallas.paged_attention import tile_shares
 
 STRATEGIES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 # the published script's defaults for the checkpoint (`assumed`)
@@ -649,14 +650,13 @@ class BlockDriver:
         hor = np.where(idx > 0, (mpos // gen.BUCKET + 1) * gen.BUCKET, 0)
         rows = (idx.astype(np.int32), (mpos * (idx > 0)).astype(np.int32),
                 hor.astype(np.int32))
-        shared, n_live = tile_runs(*rows)
-        e._m_paged_rows.inc(int(n_live.sum()))
-        e._m_paged_walks.inc(int(np.where(shared, 1, n_live).sum()))
+        e._m_paged_rows.inc(int((idx > 0).sum()))
         table = np.concatenate(
             [np.zeros((1, e.pages_per_seq), np.int32), tables, pre_tables])
-        for layers, window, row in e._walked:
-            seen, fetched = tile_shares(
-                table, *rows, window=window, page=e.page_size,
-                row_bytes=row).blocks()
-            e._m_paged_seen.inc(layers * int(seen))
-            e._m_paged_fetched.inc(layers * int(fetched))
+        (layers, window, row), = e._walked      # one class of K/V pages
+        shares = tile_shares(table, *rows, window=window, page=e.page_size,
+                             row_bytes=row)
+        e._m_paged_walks.inc(int(shares.walks()))
+        seen, fetched = shares.blocks()
+        e._m_paged_seen.inc(layers * int(seen))
+        e._m_paged_fetched.inc(layers * int(fetched))

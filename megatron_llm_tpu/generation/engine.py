@@ -157,7 +157,6 @@ from megatron_llm_tpu.generation.ragged import block_driver, decode_order
 from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
 from megatron_llm_tpu.ops.pallas.paged_attention import (
-    tile_runs,
     tile_shares,
 )
 
@@ -909,11 +908,12 @@ class ContinuousBatchingEngine:
                  "ragged ticks")
         self._m_paged_walks = reg.counter(
             "mlt_engine_paged_walks_total",
-            help="page walks those rows cost the paged kernel a layer: a "
-                 "tile of 8 consecutive rows of one sequence at consecutive "
-                 "positions is walked once, any other row on its own "
-                 "(ops/pallas/paged_attention.tile_runs); rows over walks "
-                 "is how often the shared walk engages")
+            help="page walks those rows cost the paged kernel a layer of "
+                 "the class that keeps every key: rows of one tile of 8 on "
+                 "ONE table (a prompt chunk's, a block's denoise and commit "
+                 "rows) are walked once together, any other row once "
+                 "(ops/pallas/paged_attention.tile_shares, its walks()); "
+                 "rows over walks is how often the shared walk engages")
         self._m_paged_seen = reg.counter(
             "mlt_engine_paged_blocks_seen_total",
             help="compute blocks (the kernel's step: several pages) under "
@@ -922,10 +922,10 @@ class ContinuousBatchingEngine:
         self._m_paged_fetched = reg.counter(
             "mlt_engine_paged_blocks_fetched_total",
             help="compute blocks the kernel's page walks fetched for them: "
-                 "a run's blocks once a tile, the blocks that rows of one "
-                 "tile name alike (sequences on one cached prefix, laid "
-                 "side by side by the tick) once a span, every other block "
-                 "once a row (ops/pallas/paged_attention.tile_shares); "
+                 "blocks of one table's rows once a tile, blocks that rows "
+                 "of several tables name alike (sequences on one cached "
+                 "prefix, laid side by side by the tick) once a span, every "
+                 "other block once a row (paged_attention.tile_shares); "
                  "fetched over seen is the share of its rows' blocks the "
                  "kernel reads")
         # the state class's own (zero for a paged model): what the tick's
@@ -2913,13 +2913,15 @@ class ContinuousBatchingEngine:
                 np.concatenate([(on * pos).ravel(), pre_pos[:n_bucket]]),
                 np.concatenate([(on * _bucket_up(pos + 1)).ravel(),
                                 pre_hor[:n_bucket]]))
-            shared, live = tile_runs(*rows)
-            self._m_paged_rows.inc(int(live.sum()))
-            self._m_paged_walks.inc(int(np.where(shared, 1, live).sum()))
-            for table, (layers, window, row) in zip(tables, self._walked):
-                seen, fetched = tile_shares(
+            self._m_paged_rows.inc(int((rows[2] > 0).sum()))
+            for k, (table, (layers, window, row)) in enumerate(
+                    zip(tables, self._walked)):
+                shares = tile_shares(
                     table, *rows, window=window, page=self.page_size,
-                    row_bytes=row).blocks()
+                    row_bytes=row)
+                if k == 0:
+                    self._m_paged_walks.inc(int(shares.walks()))
+                seen, fetched = shares.blocks()
                 self._m_paged_seen.inc(layers * int(seen))
                 self._m_paged_fetched.inc(layers * int(fetched))
         # the tick before lands while the device runs this one; this one

@@ -19,9 +19,10 @@ Two implementations with the same fp32-softmax numerics:
   of 8 rows walks a context in blocks of several pages, copying the next
   block's pages into VMEM while the current one is scored (no [b, max_seq]
   gather ever materializes; slots past the context are never looked up)
-  and the online-softmax accumulator carries across blocks.  A tile of
-  consecutive rows of one sequence shares ONE walk and one matmul; rows
-  of different sequences that stand in one tile share one over the
+  and the online-softmax accumulator carries across blocks.  The rows of
+  a tile that name ONE table (a prompt chunk's, a diffusion block's) share
+  ONE walk and one matmul, whole, whatever their positions; rows of
+  different sequences that stand in one tile share one over the whole
   compute blocks in which their tables name the same pages (a cached
   prefix); any other row, and what is a row's own, walks alone.
 * the jnp path below — gathers the block-tabled pages into a dense
@@ -191,10 +192,11 @@ def paged_attention_ragged(
     one entry of ``tables`` and ``table_index`` names it.  The fallback
     gathers each table's pages once, so a 64-row chunk reads its pages
     once, not 64 times; the kernel reads the same fact off the rows
-    (ops/pallas/paged_attention.tile_runs): 8 consecutive rows of one
-    table at consecutive positions share ONE page walk and one matmul a kv
-    head, so a 64-row chunk is walked 8 times.  By its second rule
-    (``tile_shares``) rows of DIFFERENT sequences in one tile whose tables
+    (ops/pallas/paged_attention.tile_shares): the rows of a tile of 8 that
+    name ONE table share ONE page walk and one matmul a kv head through
+    the last block any of them sees, whatever their positions, so a 64-row
+    chunk is walked 8 times and a diffusion block's denoise and commit
+    rows once.  Rows of DIFFERENT sequences in one tile whose tables
     name the same pages over whole compute blocks — decode rows on one
     cached prefix, which the tick lays side by side
     (generation/ragged.decode_order) — share one walk of those blocks, and

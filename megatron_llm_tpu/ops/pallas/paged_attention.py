@@ -28,29 +28,35 @@ the blockwise scheme of ops/pallas/flash_attention.py with blocks of pages
 as KV blocks.  GQA is native (q grouped [tiles, nkv, TILE*group, d], no
 K/V expansion).
 
-WHO SHARES A WALK is read from the call's own data, by two rules the engine
-counts by too.  :func:`tile_runs`: a tile whose rows are ONE RUN — all
-live, on one table, at consecutive positions: a prompt chunk's rows, a
-verify block that fills a tile — is walked ONCE, from its first row's
-window to its last row's position, with one score matmul and one value
-matmul a kv head for all ``TILE * group`` query rows, the causal (and
-window) mask and the softmax state per row.  A block outside one row's mask
-leaves that row's state as it was (alpha 1, p 0), so each row's result is
-what a walk of its own gives.  :func:`tile_shares`: in any other tile, rows
-of DIFFERENT sequences whose tables name the same pages over a range of
-compute blocks — sequences on one cached prefix, which the tick lays side
-by side (generation/ragged.decode_order) — are served that range by ONE
-walk too, the tile's rows in the matmul and the others masked, and each
-row walks what is its own before it (a window's first blocks) and behind it
-(its own pages) alone, ``group`` query rows a matmul: every row meets the
-blocks it met alone, in the same order, with the same arithmetic a row.  A
-tile holds up to two such spans (two prefixes meet in it); a run is one
-span, whole.  Rows that agree with nobody — decode rows on a table each —
-walk one after another inside the program: a row alone costs what it cost
-when the grid ran over rows.  All of it is data (``rows_ref`` /
-``span_ref`` / ``part_ref``: each row's own head and tail, each span's
-range, the parts of its program a tile takes at all), so a
-tick's composition never recompiles, and the kernel holds ONE traced
+WHO SHARES A WALK is read from the call's own data, by ONE rule the engine
+counts by too (:func:`tile_shares`; a tile holds up to two SPANS, stretches
+of consecutive rows that agree on their pages).  A span whose live rows all
+name ONE TABLE — a prompt chunk's rows, a verify block, a prompt's last
+rows with dead ones behind, a diffusion block's denoise rows and the commit
+rows of the block before (generation/blocks.py) — names the same pages
+everywhere, whatever the rows' positions, and is walked ONCE and WHOLE:
+from the first block any of its rows sees through the last one any of them
+sees, with one score matmul and one value matmul a kv head for all ``TILE *
+group`` query rows, the causal (and window) mask and the softmax state per
+row.  A block outside one row's mask leaves that row's state as it was
+(alpha 1, p 0), so each row's result is what a walk of its own gives.  A
+RUN (:func:`tile_runs`: a tile's eight rows all live, on one table, at
+consecutive positions) is the special case whose masks come from an iota
+and not from a look at each row.  Rows of DIFFERENT sequences whose tables
+name the same pages over a range of compute blocks — sequences on one
+cached prefix, which the tick lays side by side
+(generation/ragged.decode_order) — are served that range by ONE walk too,
+the tile's rows in the matmul and the others masked, but only the whole
+blocks below every row's last key (behind a prefix each sequence names
+pages of its own), and each row walks what is its own before it (a
+window's first blocks) and behind it (its own pages) alone, ``group``
+query rows a matmul: every row meets the blocks it met alone, in the same
+order, with the same arithmetic a row.  Rows that agree with nobody —
+decode rows on a table each — walk one after another inside the program: a
+row alone costs what it cost when the grid ran over rows.  All of it is
+data (``rows_ref`` / ``span_ref`` / ``part_ref``: each row's own head and
+tail, each span's range, the parts of its program a tile takes at all), so
+a tick's composition never recompiles, and the kernel holds ONE traced
 one-row walk and ONE traced tile walk whatever a tile's program is.
 
 Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, and
@@ -115,16 +121,17 @@ TILE = 8
 
 
 def tile_runs(table_index, positions, horizons):
-    """THE grouping rule, a pure function of the call's data: which tiles
-    of ``TILE`` consecutive rows the kernel serves by one page walk.
+    """Which tiles of ``TILE`` consecutive rows are RUNS, a pure function
+    of the call's data: the special case of :func:`tile_shares`' span of
+    one table whose rows' masks the kernel writes from an iota.
 
     ``[R]`` arrays (numpy, on the host; traced, in front of the kernel) ->
     ``(shared, live)``, both ``[ceil(R / TILE)]``: ``shared[i]`` says that
     tile ``i`` is ONE RUN — every row live (its horizon past its position),
     all on one table, at consecutive positions — and ``live[i]`` counts its
-    rows with a horizon.  A run costs one walk, any other tile one walk a
-    live row: ``where(shared, 1, live)``.  Rows past the last whole tile
-    count as dead ones (the wrapper pads with them)."""
+    rows with a horizon.  What a tile's walks cost is the whole rule's to
+    say (``TileShares.walks``).  Rows past the last whole tile count as
+    dead ones (the wrapper pads with them)."""
     xp = jnp if any(isinstance(a, jax.Array)
                     for a in (table_index, positions, horizons)) else np
     pad = (0, -table_index.shape[0] % TILE)
@@ -147,11 +154,13 @@ class TileShares(NamedTuple):
     """What :func:`tile_shares` reads off a call: every row's walk in
     compute blocks (``blk0`` .. ``blk1``, its mask's first and last), the
     part of it a span's shared walk serves (``lo`` .. ``hi``; empty, at
-    ``blk0``, for a row that walks alone) and a tile's two spans."""
+    ``blk0``, for a row that walks alone; the whole of it for a row of a
+    span of one table) and a tile's two spans."""
 
     rows: object     # [R', 4] int32: blk0, lo, hi, blk1 (R' in whole tiles)
     spans: object    # [tiles, 2, 7]: table, rows from, to, blocks s0, s1,
     #                  the token its walk fetches up to, whether it is a run
+    #                  (all zeros: a span with no range)
     parts: object    # [tiles, 3]: whether any row of the tile walks a head
     #                  of its own, any span has a range, any row a tail
 
@@ -164,35 +173,60 @@ class TileShares(NamedTuple):
         shared = (self.spans[..., 4] - self.spans[..., 3]).clip(0)
         return (blk1 - blk0).clip(0).sum(), own.sum() + shared.sum()
 
+    def walks(self):
+        """The page walks the call costs: one a row that walks a head or a
+        tail of its own, one a span that serves a row's WHOLE walk (a run,
+        a span of one table; a span whose rows each walk a tail besides
+        costs no walk more than they do)."""
+        blk0, lo, hi, blk1 = (
+            self.rows.reshape(-1, TILE, 4)[..., k] for k in range(4))
+        alone = (lo > blk0) | (blk1 > hi)
+        first, end = (self.spans[..., k, None] for k in (1, 2))
+        r = np.arange(TILE)
+        whole = ((blk1 > blk0) & ~alone)[:, None] & (r >= first) & (r < end)
+        return alone.sum() + whole.any(axis=2).sum()
+
 
 def tile_shares(tables, table_index, positions, horizons, *,
                 window: Optional[int], page: int,
                 row_bytes: int) -> TileShares:
-    """THE second grouping rule, as :func:`tile_runs` a pure function of the
-    call's data (numpy on the host, traced in front of the kernel): which
-    COMPUTE BLOCKS (``pps`` pages of ``page`` tokens: ``_pages_per_step``
-    of a token's ``row_bytes``) of a tile's rows one page walk serves for
-    several rows at once.
+    """THE grouping rule, a pure function of the call's data (numpy on the
+    host, traced in front of the kernel): which COMPUTE BLOCKS (``pps``
+    pages of ``page`` tokens: ``_pages_per_step`` of a token's
+    ``row_bytes``) of a tile's rows one page walk serves for several rows
+    at once.
 
-    A tile that is one run is one span: every row, from its first row's
-    window to its last row's position.  In any other tile a SPAN is a
-    stretch of consecutive rows whose live ones name the same pages — rows
-    of different sequences on one cached prefix, which the tick lays side
-    by side (generation/ragged.decode_order), or rows of one sequence that
-    are no run.  A tile has up to two: the rows that agree with its first
-    live row, then those that agree with the first that does not (two
-    prefixes meet in the tile), told apart in the block after the last
-    first-visible block among the tile's rows, which every span worth a
-    walk holds.  A span's shared range ``[s0, s1)`` is the first stretch
-    of blocks, from the last first-visible block among ITS rows on, in
-    which every live row names the anchor's ``pps`` pages and which lie
-    wholly below every live row's last key (a window class's table names
-    the null page behind a row's window, so rows agree only from the
-    latest start on; whole blocks only, so every row still meets the
-    blocks it met alone, in ascending order: its own head ``[blk0, s0)``,
-    the shared ``[s0, s1)``, its own tail ``[s1, blk1)``).  The range is
-    empty where one walk with the tile's rows would not beat the rows'
-    own: under ``SHARE_ROWS`` live rows or ``SHARE_BLOCKS`` blocks."""
+    A SPAN is a stretch of a tile's consecutive rows whose live ones name
+    the same pages.  A tile has up to two: the rows that agree with its
+    first live row, then those that agree with the first that does not
+    (two prefixes meet in the tile), told apart in the block after the
+    last first-visible block among the tile's rows, which every span worth
+    a walk holds.
+
+    A span whose live rows all carry ONE ``table_index`` (a prompt's rows,
+    a verify block, a diffusion block's denoise and commit rows) names the
+    same pages everywhere: its range ``[s0, s1)`` runs from the FIRST
+    first-visible block among its rows through the LAST block any of them
+    sees, its walk fetches keys up to the largest of their last keys, and
+    no row of it walks a block alone; each row's mask (its position, its
+    window, its last key) is the kernel's to apply.  A tile that is one run
+    (:func:`tile_runs`) is such a span, however short.
+
+    A span of SEVERAL tables — rows of different sequences on one cached
+    prefix, which the tick lays side by side
+    (generation/ragged.decode_order) — shares the first stretch of blocks,
+    from the last first-visible block among its rows on, in which every
+    live row names the anchor's ``pps`` pages and which lie wholly below
+    every live row's last key (a window class's table names the null page
+    behind a row's window, so rows agree only from the latest start on;
+    whole blocks only: behind a prefix each sequence names pages of its
+    own); every row still meets the blocks it met alone, in ascending
+    order: its own head ``[blk0, s0)``, the shared ``[s0, s1)``, its own
+    tail ``[s1, blk1)``.
+
+    Either range is empty where one walk with the tile's rows would not
+    beat the rows' own: under ``SHARE_ROWS`` live rows or ``SHARE_BLOCKS``
+    blocks (a run apart)."""
     xp = jnp if any(isinstance(a, jax.Array) for a in (
         tables, table_index, positions, horizons)) else np
 
@@ -240,36 +274,47 @@ def tile_shares(tables, table_index, positions, horizons, *,
     end = xp.stack([first_b, stretch(first_b)], axis=1)
     anchor = xp.minimum(first, TILE - 1)
     mine = live[:, None] & (r >= first[..., None]) & (r < end[..., None])
+    table = idx[tile[:, None], anchor]
+    # a span of ONE table (a run is one): its rows name the same pages
+    # everywhere, whatever their positions
+    one = ~(mine & (idx[:, None] != table[..., None])).any(axis=2)
+
+    def over(a, fill, least: bool):     # of a span's live rows, [tiles, 2]
+        a = xp.where(mine, a[:, None], fill)
+        return a.min(axis=2) if least else a.max(axis=2)
+
     # where a row names another page than its span's anchor
     differ = named != named[tile[:, None], xp.where(
         r < first_b[:, None], anchor[:, :1], anchor[:, 1:])]
     # a block is a span's where no live row of it differs in a page (slots
     # past the table's width lie past every context: never below a last
-    # key) and no last key lies inside it
+    # key) and it lies below ``upto``: of one table the last block any row
+    # sees, of several the block the first last key lies in
     ok = ~padded((differ[:, None] & mine[..., None]).any(axis=2), 2,
                  nblk * pps).reshape(-1, 2, nblk, pps).any(axis=3)
-    ok &= (blk + 1) * bk <= xp.where(
-        mine, kv_end[:, None], np.iinfo(np.int32).max).min(axis=2)[..., None]
-    start = xp.where(mine, blk0[:, None], 0).max(axis=2)[..., None]
-    s0 = xp.where(ok & (blk >= start), blk, nblk).min(axis=2)
+    upto = xp.where(one, over(blk1, 0, False),
+                    over(kv_end, np.iinfo(np.int32).max, True) // bk)
+    ok &= blk < upto[..., None]
+    start = xp.where(one, over(blk0, nblk, True), over(blk0, 0, False))
+    s0 = xp.where(ok & (blk >= start[..., None]), blk, nblk).min(axis=2)
     s1 = xp.where(~ok & (blk >= s0[..., None]), blk, nblk).min(axis=2)
-    pays = (mine.sum(axis=2) >= SHARE_ROWS) & (s1 - s0 >= SHARE_BLOCKS)
-    s1 = xp.where(pays, s1, s0)
+    # a run is walked whole however short it is
+    pays = (mine.sum(axis=2) >= SHARE_ROWS) & (
+        (s1 - s0 >= SHARE_BLOCKS) | run[:, None])
     shared = mine & pays[..., None]
-    # a row's part of its span's range; none: empty, where its walk starts
+    # a row's part of its span's range, inside its own walk; none: empty,
+    # where its walk starts
     lo = xp.where(shared[:, 0], s0[:, :1],
                   xp.where(shared[:, 1], s0[:, 1:], blk0))
     hi = xp.where(shared[:, 0], s1[:, :1],
                   xp.where(shared[:, 1], s1[:, 1:], blk0))
-    spans = xp.stack([idx[tile[:, None], anchor], first, end, s0, s1,
-                      s1 * bk, 0 * s0], axis=2)
-    # a run is one span, whole: no row of it walks a block alone
-    whole = xp.stack([idx[:, 0], 0 * first_a, 0 * first_a + TILE,
-                      blk0[:, 0], blk1[:, -1], kv_end[:, -1],
-                      0 * first_a + 1], axis=1)
-    spans = xp.where(run[:, None, None], xp.stack(
-        [whole, 0 * whole], axis=1), spans).astype(np.int32)
-    lo, hi = xp.where(run[:, None], blk0, lo), xp.where(run[:, None], blk1, hi)
+    lo = xp.minimum(xp.maximum(lo, blk0), blk1)
+    hi = xp.minimum(xp.maximum(hi, lo), blk1)
+    # a span with no range is all zeros
+    spans = xp.where(pays[..., None], xp.stack(
+        [table, first, end, s0, s1,
+         xp.minimum(s1 * bk, over(kv_end, 0, False)),
+         run[:, None] & pays], axis=2), 0).astype(np.int32)
     rows = xp.stack([blk0, lo, hi, blk1], axis=2)
     # the parts of a tile's program that any of its rows or spans takes
     parts = xp.stack([(lo > blk0).any(axis=1),

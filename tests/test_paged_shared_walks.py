@@ -1,9 +1,10 @@
 """Rows of different sequences that name the same leading pages share ONE
-page walk (PR 56): the rule (``ops/pallas/paged_attention.tile_shares``) by
-hand, the kernel's outputs in interpret mode against every row walked
-alone, the tick's order of its decode slots (``generation/ragged.py``
-``decode_order``) against slot order, and the engine's two counters of
-compute blocks.
+page walk (PR 56), and rows of ONE table share their whole walk whatever
+their positions (PR 60): the rule
+(``ops/pallas/paged_attention.tile_shares``) by hand, the kernel's outputs
+in interpret mode against every row walked alone, the tick's order of its
+decode slots (``generation/ragged.py`` ``decode_order``) against slot
+order, and the engine's counters of walks and of compute blocks.
 
 A file of its own (the tier-1 run hands a FILE to a worker); the kernel's
 calls are shared by the scenarios that stand in them (``_outputs``: one
@@ -40,7 +41,8 @@ GEOMETRIES = {
 BK, END = 128, 656      # a compute block's tokens; a prefix's (5 blocks + 2 pages)
 
 # scenario: (window, span A, span B, a row's (blk0, lo, hi, blk1)); a span
-# is (rows from, to, blocks s0, s1), None where its range is empty
+# is (rows from, to, blocks s0, s1[, the token its walk fetches up to: the
+# range's end unless said]), None where its range is empty
 RULE = {
     "one": (False, (0, 8, 0, 5), None, [(0, 0, 5, 6)] * 8),
     "two": (False, (0, 5, 0, 5), (5, 8, 0, 5), [(0, 0, 5, 6)] * 8),
@@ -58,6 +60,29 @@ RULE = {
     "window": (True, (0, 8, 3, 5), None,
                [(1, 3, 5, 6)] * 4 + [(2, 3, 5, 7)] * 4),
     "window_two": (True, (0, 4, 2, 5), (4, 8, 2, 5), [(1, 2, 5, 6)] * 8),
+    # rows of ONE table are walked whole, through the last block any of
+    # them sees: a block's denoise rows behind its dead commit rows; its
+    # commit tick (the commit rows' last key ends the seventh block); a
+    # prompt's last tile; the commit tick under a window that opens in the
+    # third block for four rows and in the fourth for the others
+    "block": (False, (4, 8, 0, 8, 7 * BK + 20), None,
+              [(0, 0, 0, 0)] * 4 + [(0, 0, 8, 8)] * 4),
+    "commit": (False, (0, 8, 0, 8, 7 * BK + 4), None,
+               [(0, 0, 7, 7)] * 4 + [(0, 0, 8, 8)] * 4),
+    "tail": (False, (0, 8, 0, 3, 2 * BK + 105), None,
+             [(0, 0, 3, 3)] * 5 + [(0, 0, 0, 0)] * 3),
+    "window_commit": (True, (0, 8, 2, 8, 7 * BK + 2), None,
+                      [(2, 2, 7, 7)] * 4 + [(3, 3, 8, 8)] * 4),
+}
+# the compute blocks under a scenario's masks, those its walks fetch, and
+# its walks, by hand: a row that walks a block alone is a walk, a span that
+# serves a row's whole walk is one
+COUNTS = {
+    "one": (48, 13, 8), "two": (48, 18, 8), "dead": (25, 10, 4),
+    "short": (45, 31, 8), "verify": (48, 13, 8), "none": (32, 32, 8),
+    "window": (40, 26, 8), "window_two": (40, 22, 8),
+    "block": (32, 8, 1), "commit": (60, 8, 1), "tail": (15, 3, 1),
+    "window_commit": (40, 6, 1),
 }
 
 
@@ -94,8 +119,8 @@ def test_tile_shares_rule(scenario):
             if want is None:
                 assert s1 == s0
             else:
-                assert (first, end, s0, s1) == want
-                assert fetch_end == s1 * BK
+                assert (first, end, s0, s1) == want[:4]
+                assert fetch_end == (want[4:] or (s1 * BK,))[0]
                 # the span's walk reads its first live row's table
                 live = scenarios[scenario] - TILE * at
                 assert table == plan[1][TILE * at + live[live >= first][0]]
@@ -111,6 +136,7 @@ def test_tile_shares_rule(scenario):
     one = tile_shares(*(a[TILE * at:TILE * (at + 1)] if i else a
                         for i, a in enumerate(plan[:4])), **plan[4])
     assert tuple(int(n) for n in one.blocks()) == (seen, alone + spans)
+    assert (seen, alone + spans, int(one.walks())) == COUNTS[scenario]
 
 
 def test_tile_shares_run_is_one_span_and_few_rows_walk_alone():
@@ -185,11 +211,13 @@ def test_shared_walk_is_the_one_row_walk(scenario):
 
 
 @pytest.mark.parametrize("geometry,scenario", [
-    ("pair64", "two"), ("latent", "window_two"), ("int8", "two")])
+    ("pair64", "two"), ("latent", "window_two"), ("int8", "two"),
+    ("pair64", "commit"), ("latent", "window_commit"), ("int8", "block")])
 def test_shared_walk_is_the_one_row_walk_at_every_row_kind(
         geometry, scenario):
-    """Two spans in one tile on the pair of 64s read whole, on a latent row
-    (under a window) and on int8 pages with their scales."""
+    """Two spans in one tile, and one table's rows at two positions, on the
+    pair of 64s read whole, on a latent row (under a window) and on int8
+    pages with their scales."""
     _check(geometry, scenario, only=(scenario,))
 
 
@@ -314,8 +342,9 @@ def _counters():
 def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
     """``mlt_engine_paged_blocks_seen_total`` / ``_fetched_total`` rise by
     what ``tile_shares`` gives for each launched tick's plan times the
-    layers; requests on one primed prefix of two compute blocks and more
-    fetch fewer blocks than their rows see, tick after tick."""
+    layers, ``_rows_total`` / ``_walks_total`` by its live rows and its
+    ``walks()``; requests on one primed prefix of two compute blocks and
+    more fetch fewer blocks than their rows see, tick after tick."""
     from megatron_llm_tpu.generation import engine as engine_mod
 
     cfg, params = models["cfg"], models["params"]
@@ -326,7 +355,8 @@ def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
 
     def spy(*args, **kw):
         shares = tile_shares(*args, **kw)
-        given.append([layers * int(n) for n in shares.blocks()])
+        given.append([layers * int(n) for n in shares.blocks()]
+                     + [int((args[3] > 0).sum()), int(shares.walks())])
         return shares
 
     monkeypatch.setattr(engine_mod, "tile_shares", spy)
@@ -341,8 +371,11 @@ def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
     eng.run_until_idle()
     for r in reqs:
         r.result(timeout=5)
-    seen, fetched = (_counters() - before)[:2]
-    assert [seen, fetched] == np.sum(given[ticks:], axis=0).tolist()
+    seen, fetched, rows, walks = _counters() - before
+    assert [seen, fetched, rows, walks] == np.sum(
+        given[ticks:], axis=0).tolist()
+    # a prompt's rows of one table are walked together, a decode row alone
+    assert 0 < walks < rows
     # four decode rows on one prefix: two blocks walked once, not four
     # times, in every tick that held three of them or more
     decode = [g for g in given[ticks:] if g[0] - g[1] >= 2 * layers * 2]

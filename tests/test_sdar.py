@@ -459,6 +459,45 @@ def test_block_counters_count_rows_steps_and_blocks(model):
                    "slot_ticks": 12, "tokens_unmasked": 12, "committed": 2}
 
 
+def test_block_ticks_count_walks_and_blocks_by_the_kernels_rule(monkeypatch):
+    """A block tick's rows name ONE table, so the kernel walks a slot's
+    span once through its last compute block, and the engine's counters
+    follow the same rule: past one compute block of context (256 tokens
+    on this pool) a denoise tick's four rows see two blocks each and fetch
+    two in ONE walk, and so do a commit tick's eight."""
+    cfg = sdar_cfg(max_position_embeddings=512, seq_length=512)
+    params = drawn(init_model_params(cfg, jax.random.PRNGKey(0)))
+    layers = cfg.model.num_layers
+    obs_registry.set_publishing(True)
+    reg = obs_registry.get_registry()
+    read = lambda: np.array([reg.counter(  # noqa: E731
+        f"mlt_engine_paged_{n}_total").value
+        for n in ("rows", "walks", "blocks_seen", "blocks_fetched")])
+    given, rule = [], blocks_mod.tile_shares
+
+    def spy(table, idx, *rest, **kw):
+        shares = rule(table, idx, *rest, **kw)
+        seen, fetched = (int(n) for n in shares.blocks())
+        given.append((int(idx.max()), int((idx > 0).sum()),
+                      int(shares.walks()), seen, fetched))
+        return shares
+
+    monkeypatch.setattr(blocks_mod, "tile_shares", spy)
+    eng = engine(cfg, params, max_seq=512)
+    before = read()
+    prompt, = prompts(264, seed=4)
+    req = ask(eng, prompt, 8)               # two whole blocks, 4 steps each
+    eng.run_until_idle()
+    assert len(req.generated) == 8
+    counted = np.array([g[1:] for g in given]).sum(axis=0)
+    assert (read() - before).tolist() == (
+        counted * [1, 1, layers, layers]).tolist()
+    # the ticks of block rows alone: seven of four denoise rows, and the
+    # one that commits the first block beside the second's first step
+    ticks = [g[1:] for g in given if 0 < g[0] <= eng.max_slots]
+    assert sorted(ticks) == [(4, 1, 8, 2)] * 7 + [(8, 1, 16, 2)]
+
+
 # ---- what it does not carry -------------------------------------------------
 
 @pytest.mark.parametrize("feature", ["kv_dtype", "draft", "handoff",

@@ -4,7 +4,8 @@ step, as it is LOWERED for a TPU (no chip: a virtual v5e topology, abstract
 parameters, pools and optimizer state at the cell's own flags), Mosaic
 payloads and all.
 
-    JAX_PLATFORMS=cpu python tools/tick_digest.py [--root DIR] [cell ...]
+    JAX_PLATFORMS=cpu python tools/tick_digest.py [--root DIR] [--bodies]
+                                                  [cell ...]
 
 A compile cache finds a program again only if its lowered text is the same,
 and a Pallas kernel's payload in that text carries the source lines of the
@@ -31,7 +32,15 @@ lines say so: ``payload files: ...`` (every source file a payload names) and
 tick_fn at <line:col-line:col> ...``.  They too have to be equal on parent
 and change: a line added above that call in ``generation/engine.py`` makes
 those cells' ticks new programs.  No train cell's payload reaches its
-caller."""
+caller.
+
+``--bodies`` answers another question, whether an edit changed a KERNEL: the
+programs are lowered with no frame in a location
+(``jax_traceback_in_locations_limit`` 0), so a payload is the kernel's module
+and nothing of where it stands or who called it, and every line ends in
+``kernels <sha256, 16 digits, of each distinct payload>``.  Those are equal on
+parent and change where the kernels' bodies are, whatever moved around them
+(PERF.md section 6, PR 60); the lines' own sha256 is then not the cache's."""
 
 from __future__ import annotations
 
@@ -54,15 +63,24 @@ def lowered_text(tick, *operands) -> str:
     return jax.jit(tick, donate_argnums=(1,)).lower(*operands).as_text()
 
 
+def payloads(text: str) -> list:
+    """The Mosaic payloads of a lowered program (``backend_config``'s
+    ``body``: base64 of the kernel's module, locations and all)."""
+    return [base64.b64decode(body) for body in re.findall(
+        r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)]
+
+
 def payload_files(text: str) -> set:
-    """The source files named inside the Mosaic payloads of a lowered
-    program (``backend_config``'s ``body``: base64 of the kernel's module,
-    locations and all)."""
-    named = set()
-    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
-        named.update(m.decode() for m in re.findall(
-            rb"[\w/.\-]+\.py", base64.b64decode(body)))
-    return named
+    """The source files named inside a lowered program's payloads."""
+    return {m.decode() for body in payloads(text)
+            for m in re.findall(rb"[\w/.\-]+\.py", body)}
+
+
+def payload_digests(text: str) -> str:
+    """`` kernels <digest> ...``: the distinct payloads of a lowered
+    program, sixteen digits of each one's sha256."""
+    return " kernels " + " ".join(sorted({
+        hashlib.sha256(body).hexdigest()[:16] for body in payloads(text)}))
 
 
 def engine_call_sites(root: str) -> list:
@@ -142,6 +160,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    ap.add_argument("--bodies", action="store_true",
+                    help="lower with no frame in a location and print "
+                         "each line's distinct kernel payloads' digests")
     ap.add_argument("cells", nargs="*")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
@@ -162,6 +183,9 @@ def main() -> int:
     from megatron_llm_tpu.models import init_model_params
     from megatron_llm_tpu.models.transformer import pool_classes
 
+    if args.bodies:
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+    bodies = payload_digests if args.bodies else lambda text: ""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     topo = topologies.get_topology_desc("v5e:2x2", "tpu")
@@ -184,7 +208,8 @@ def main() -> int:
             if me in payload_files(text):
                 reach_caller.append(name)
             print(f"{name} train_step "
-                  f"{hashlib.sha256(text.encode()).hexdigest()}", flush=True)
+                  f"{hashlib.sha256(text.encode()).hexdigest()}"
+                  f"{bodies(text)}", flush=True)
             continue
         cfg = parse_args(cell.flags())
         inf = cfg.inference
@@ -234,8 +259,8 @@ def main() -> int:
                 if me in payload_files(text) and name not in reach_caller:
                     reach_caller.append(name)
                 print(f"{name} rows={rows} "
-                      f"{hashlib.sha256(text.encode()).hexdigest()}",
-                      flush=True)
+                      f"{hashlib.sha256(text.encode()).hexdigest()}"
+                      f"{bodies(text)}", flush=True)
     print("payload files: " + " ".join(sorted(
         os.path.relpath(f, root) if os.path.isabs(f) else f for f in named)),
         flush=True)

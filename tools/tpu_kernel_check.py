@@ -31,9 +31,11 @@ train cells' shapes (``FLASH_SHAPES``) beside the blocks its grid walks
 paged kernel's ms a call at the tick shapes the chip has seen (``TICKS``),
 whole and with the chunk's rows dead, and at the agent cell's tick with
 its decode rows' tables led by four shared prefixes (``SHARED_TICKS``: in
-slot order and in the order the tick runs them in), beside the dtype its
-two matmuls take their operands in and what its KV bytes need at the HBM
-peak, and checks nothing.
+slot order and in the order the tick runs them in) and at a block model's
+ticks (``BLOCK_TICKS``: the SDAR cell's 32 slots of 4 commit + 4 denoise
+rows, under the rule's plan and under the plan it gave until PR 60),
+beside the dtype its two matmuls take their operands in and what its KV
+bytes need at the HBM peak, and checks nothing.
 ``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
@@ -456,9 +458,10 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
                kv_dtype: str = "bf16", dtype=jnp.bfloat16,
                latent: bool = False, window: bool = False, only=None):
     """One ragged call whose tiles hold rows of DIFFERENT sequences that
-    name the same leading pages, the blocks the kernel serves by one walk
-    a span (ops/pallas/paged_attention.py ``tile_shares``), and what
-    stands in their way.  Returns ``(pallas_fn, jnp_fn, scenarios, plan)``
+    name the same leading pages, or rows of ONE table at any positions:
+    the blocks the kernel serves by one walk a span
+    (ops/pallas/paged_attention.py ``tile_shares``), and what stands in
+    their way.  Returns ``(pallas_fn, jnp_fn, scenarios, plan)``
     as :func:`run_case` does, ``plan`` the rule's arguments for the call
     (numpy: tables as the kernel reads them, rows, window, page, row
     bytes).  Two prefixes of five compute blocks and two pages; a tile a
@@ -473,6 +476,16 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
       positions) and four decode rows, all on A;
     * ``none``: eight rows that share nothing;
 
+    then tiles whose live rows name ONE table, walked whole whatever their
+    positions (a context of eight blocks unless said):
+
+    * ``block``: four dead rows, then four rows at ONE position: a
+      block's denoise rows behind its dead commit rows;
+    * ``commit``: four rows whose last key ends the seventh block, then
+      four at a position in the eighth: a block's commit tick;
+    * ``tail``: five rows at consecutive positions in the third block,
+      three dead rows behind them: a prompt's last tile;
+
     and with ``window`` (four blocks; every table slid as a window page
     class's is: the slots wholly behind its first query's window name the
     null page):
@@ -480,7 +493,9 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     * ``window``: eight rows on A whose windows open in two different
       blocks and at different pages of them: four walk two blocks of
       their own before the span's;
-    * ``window_two``: four rows on A, four on B.
+    * ``window_two``: four rows on A, four on B;
+    * ``window_commit``: ``commit`` with the first four rows' window
+      opening in the third block and the others' in the fourth.
     """
     import numpy as np
 
@@ -503,6 +518,8 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             + [("A", end + bk - 10 + 11 * i) for i in range(4)],
             "window_two": [("A", end + 7 * i) for i in range(4)]
             + [("B", end + bk // 2 + 9 * i) for i in range(4)],
+            "window_commit": [("A", 7 * bk - 3, "c")] * 4
+            + [("A", 7 * bk + 1, "c")] * 4,
         }
     else:
         scenes = {
@@ -516,6 +533,11 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             "verify": [("A", end + 20 + i, "v") for i in range(4)]
             + [("A", end + 17 * i) for i in range(4)],
             "none": [(None, 3 * bk + 11 * i) for i in range(T)],
+            "block": [dead] * 4 + [("A", 7 * bk + 19, "b")] * 4,
+            "commit": [("A", 7 * bk - 1, "c")] * 4
+            + [("A", 7 * bk + 3, "c")] * 4,
+            "tail": [("A", 2 * bk + 100 + i, "t") for i in range(5)]
+            + [dead] * 3,
         }
     scenes = {k: v for k, v in scenes.items() if only is None or k in only}
     rows = [r for scene in scenes.values() for r in scene]
@@ -607,6 +629,80 @@ SHARED_TICKS = {
 }
 
 
+# SDAR-30B-A3B: 32 query heads on 4 kv heads of 128
+SDAR = dict(n=32, nkv=4, d=128, page=16)
+
+# a block model's tick (generation/blocks.py; PERF.md section 6, PR 60): a
+# slot is a tile, the 4 commit rows of the block before (live where the
+# slot commits) and the 4 denoise rows of its block, all on ONE table at
+# one mask position a block.  name: geometry, slots, a block's positions,
+# the blocks' first positions from .. to, the slots in four that commit,
+# table width, pool pages
+BLOCK_TICKS = {
+    name: (SDAR, 32, 4, 700, 1100, commits, 128, 32 * 128 + 1)
+    for name, commits in (("sdar", 1), ("sdar_denoise", 0),
+                          ("sdar_commit", 4))}
+
+
+def block_tick_case(seed: int, name: str):
+    """A tick of ``BLOCK_TICKS`` as :func:`tick_case` returns one: every
+    slot live, its block's first position drawn from the range on the
+    blocks' grid, its denoise rows at the block's last position and its
+    commit rows (where it commits) at the last position of the block
+    before."""
+    import numpy as np
+
+    geo, slots, B, lo, hi, commits, width, num_pages = BLOCK_TICKS[name]
+    n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
+    rng = np.random.default_rng(seed)
+    pool = _tick_pool(seed, num_pages, page, nkv, d)
+    start = B * rng.integers(lo // B, hi // B, size=slots)
+    commit = np.arange(slots) % 4 < commits
+    live = np.concatenate([np.repeat(commit[:, None], B, 1),
+                           np.ones((slots, B), bool)], axis=1).ravel()
+    pos = live * np.concatenate(
+        [np.repeat(start[:, None] - 1, B, 1),
+         np.repeat(start[:, None] + B - 1, B, 1)], axis=1).ravel()
+    idx = np.where(live, np.repeat(np.arange(slots), 2 * B), slots)
+    tables = rng.integers(1, num_pages, size=(slots + 1, width))
+    tables[-1] = 0
+    hor = np.where(live, (pos // 64 + 1) * 64, 0)
+    q = jnp.asarray(rng.normal(size=(slots * 2 * B, 1, n, d)), jnp.bfloat16)
+    args = (q, pool) + tuple(
+        jnp.asarray(a, jnp.int32) for a in (tables, idx, pos, hor))
+    kw = dict(scale=1.0 / d ** 0.5, sliding_window=None, latent=False)
+    return args, kw, np.flatnonzero(hor), int(
+        (start + B).sum()) * 2 * nkv * d * 2
+
+
+def walks_until_pr60(args, kw):
+    """The plan the rule gave a tile of ONE table's rows until PR 60, for
+    ``paged_ragged_kernel(shares=)``: the whole blocks below every live
+    row's last key by one span, then each row's walk of the block its
+    last key lies in, alone.  Made from the rule's plan of today for a
+    tick of ``BLOCK_TICKS`` (every live tile one span from block 0)."""
+    import numpy as np
+
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    tables, idx, pos, hor = (np.asarray(a) for a in args[2:])
+    _, page, row = args[1].shape
+    row *= args[1].dtype.itemsize
+    now = pk.tile_shares(tables, idx, pos, hor, window=kw["sliding_window"],
+                         page=page, row_bytes=row)
+    rows = now.rows.reshape(-1, pk.TILE, 4).copy()
+    spans, parts = now.spans.copy(), now.parts.copy()
+    bk = pk._pages_per_step(page, row) * page
+    kv_end = np.where(hor > 0, np.minimum(hor, pos + 1), 1 << 30)
+    below = kv_end.reshape(-1, pk.TILE).min(axis=1) // bk
+    assert (spans[:, 0, 3] == 0).all() and not spans[:, 1].any()
+    rows[..., 2] = np.minimum(rows[..., 2], below[:, None])
+    spans[:, 0, 4], spans[:, 0, 5] = below, below * bk
+    parts[:, 2] = (rows[..., 3] > rows[..., 2]).any(axis=1)
+    return pk.TileShares(*(jnp.asarray(a) for a in (
+        rows.reshape(-1, 4), spans, parts)))
+
+
 @functools.lru_cache(maxsize=1)
 def _tick_pool(seed: int, num_pages: int, page: int, nkv: int, d: int,
                latent: bool = False):
@@ -651,10 +747,12 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True,
     row of 640 lanes, 103 decode rows live; its bytes are the 576 values of
     a row read once.  ``width`` overrides the table width (same contexts);
     ``chunk_live`` false leaves the chunk's rows dead: the decode rows
-    alone.
+    alone.  A name of ``BLOCK_TICKS``: :func:`block_tick_case`.
     """
     import numpy as np
 
+    if name in BLOCK_TICKS:
+        return block_tick_case(seed, name)
     name, prefixes, shared = SHARED_TICKS.get(name, (name, 0, 0))
     (geo, slots, live, lo, hi, chunk_at, slots_wide, num_pages,
      window) = TICKS[name]
@@ -789,7 +887,7 @@ def paged_numerics(quick: bool):
                 except Exception as exc:
                     check(f"paged {kind} window={window} {tag}", False,
                           f"{type(exc).__name__}: {str(exc)[:300]}")
-    for name in (*TICKS, *SHARED_TICKS):
+    for name in (*TICKS, *SHARED_TICKS, *BLOCK_TICKS):
         args, kw, live, _ = tick_case(7, name, ordered=name in SHARED_TICKS)
         q, pool, tables, idx, pos, _ = args
         try:
@@ -929,23 +1027,31 @@ def paged_timing():
     # order the tick runs them in
     cases += [(name, None, chunk_live, ordered) for name in SHARED_TICKS
               for chunk_live in (True, False) for ordered in (False, True)]
+    # a block model's ticks under the rule's plan and under the plan it
+    # gave until PR 60 (``ordered`` stands for the older plan there)
+    cases += [(name, None, True, old) for name in BLOCK_TICKS
+              for old in (False, True)]
     for name, width, chunk_live, ordered in sorted(
             cases, key=lambda c: c[0]):
         args, kw, _, need = tick_case(7, name, width, chunk_live, ordered)
         width = args[2].shape[1]
-        name += " (ordered)" if ordered else ""
+        old = ordered and name in BLOCK_TICKS
+        plan = walks_until_pr60(args, kw) if old else None
+        name += (" (the plan until PR 60)" if old
+                 else " (ordered)" if ordered else "")
         name += "" if chunk_live else " (chunk dead)"
 
-        def layers(q, *rest):
+        def layers(plan, q, *rest):
             def layer(i, acc):
                 out = pk.paged_ragged_kernel(
-                    q + i.astype(q.dtype), *rest, **kw)
+                    q + i.astype(q.dtype), *rest, shares=plan, **kw)
                 return acc + out.astype(jnp.float32)
             return jax.lax.fori_loop(
                 0, calls, layer, jnp.zeros(q.shape, jnp.float32))
 
         # one program per shape: timing each is the point
-        f = jax.jit(layers)  # graftcheck: noqa[recompile-hazard]
+        f = functools.partial(
+            jax.jit(layers), plan)  # graftcheck: noqa[recompile-hazard]
         t = statistics.median(
             kernel_seconds(f, *args, kernel="paged_attention"))
         host = time_fn(f, *args) / calls
