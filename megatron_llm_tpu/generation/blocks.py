@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from megatron_llm_tpu.generation import generation as gen
+from megatron_llm_tpu.generation.launch import call_tick
 from megatron_llm_tpu.generation.pools import NULL_PAGE
 from megatron_llm_tpu.generation.sampling import sample_with_log_prob
 from megatron_llm_tpu.models.language_model import (
@@ -86,7 +87,6 @@ from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.observability import trace as obs_trace
 from megatron_llm_tpu.ops.attention import announce_path
 from megatron_llm_tpu.ops.paged_attention import PagedState, plan_walks
-from megatron_llm_tpu.ops.pallas.paged_attention import tile_shares
 
 STRATEGIES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 # the published script's defaults for the checkpoint (`assumed`)
@@ -400,7 +400,8 @@ class BlockDriver:
             fill_end, pos = self.fill_end(len(seq)), req._fill_pos
             if pos >= fill_end:
                 continue
-            pre_tables[n_req, : len(req._pages)] = req._pages
+            pages = req._mem[0].pages
+            pre_tables[n_req, : len(pages)] = pages
             while pos < fill_end and used < budget:
                 end = min(fill_end, (pos // chunk + 1) * chunk,
                           pos + (budget - used))
@@ -507,9 +508,10 @@ class BlockDriver:
                     t_upload = time.monotonic()
                     with obs_trace.span("plan-upload"):
                         fresh, up = self._fresh_locked()
+                        tables = e._classes[0].snapshot()
                         if e._dirty or self._up is None or fresh.any():
                             self._up = (
-                                e._asarray(e._block_tables.copy()),
+                                e._asarray(tables),
                                 e._asarray(e._keys.copy()),
                                 jax.tree.map(e._asarray,
                                              self._unmasking_locked()))
@@ -517,7 +519,6 @@ class BlockDriver:
                         bt, keys, un = self._up
                         fresh_args = self._no_fresh if not fresh.any() else (
                             e._asarray(fresh), jax.tree.map(e._asarray, up))
-                        tables = e._block_tables.copy()
                     upload_s = time.monotonic() - t_upload
             n_pre = sum(end - start for _, start, end in spans)
             n_bucket = (min(e.prefill_rows,
@@ -539,9 +540,9 @@ class BlockDriver:
                     e._asarray(pre_tables),
                     e._asarray(pre_index[:n_bucket]))
                 (e._kv, x0, logp, newly, ran, self._state,
-                 *moe) = self._program(n_bucket)(
-                    e.params, e._kv, bt, self._state, *fresh_args, keys, un,
-                    *pre_args)
+                 *moe) = call_tick(
+                    self._program(n_bucket), e.params, e._kv, bt,
+                    self._state, *fresh_args, keys, un, *pre_args)
                 e._last_dispatch_end = time.monotonic()
                 with e._lock:
                     e._inflight.append(_Launched(
@@ -650,13 +651,6 @@ class BlockDriver:
         hor = np.where(idx > 0, (mpos // gen.BUCKET + 1) * gen.BUCKET, 0)
         rows = (idx.astype(np.int32), (mpos * (idx > 0)).astype(np.int32),
                 hor.astype(np.int32))
-        e._m_paged_rows.inc(int((idx > 0).sum()))
-        table = np.concatenate(
-            [np.zeros((1, e.pages_per_seq), np.int32), tables, pre_tables])
-        (layers, window, row), = e._walked      # one class of K/V pages
-        shares = tile_shares(table, *rows, window=window, page=e.page_size,
-                             row_bytes=row)
-        e._m_paged_walks.inc(int(shares.walks()))
-        seen, fetched = shares.blocks()
-        e._m_paged_seen.inc(layers * int(seen))
-        e._m_paged_fetched.inc(layers * int(fetched))
+        e._note_walks([np.concatenate([
+            np.zeros((1, e.pages_per_seq), np.int32), tables, pre_tables])],
+            rows)

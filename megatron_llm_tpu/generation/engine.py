@@ -7,29 +7,38 @@ is the TPU-serving shape the Ragged-Paged-Attention and Gemma-on-Cloud-TPU
 studies (PAPERS.md) converge on: keep ONE fixed-shape decode program
 resident, keep its batch full, and never compute the same prefix twice.
 
-* **Paged KV pool** (:class:`PagedKVPool`, generation/pools.py, as are the
-  state pool and the prefix trie below): all in-flight sequences share a
-  ``[L, num_pages, page_size, nkv, d]`` pool; a sequence owns an ordered
-  page list (its block table).  Pages are REFERENCE-COUNTED: several
-  sequences may share the pages of a common prompt prefix.  Page 0 is the
-  reserved *null page*: idle slots' block tables point at it and their
-  writes land there, never attended.
+What a sequence keeps lives in generation/pools.py, below this file:
 
-* **Prefix cache** (:class:`PrefixCache`): a host-side radix/trie keyed on
-  page-aligned token chunks.  Admission walks the trie, takes a ref on
-  every matched full page, and only prefills the uncovered suffix; when a
-  request's first tick must rewrite a shared page (page-aligned full match)
-  the page is copied first — copy-on-write, shared pages are never mutated.
-  Pages whose refcount drops to zero STAY in the cache until the free list
-  runs dry, then an LRU leaf-first eviction recycles them — pool exhaustion
-  no longer means rejection while reusable pages sit idle.
+* **Paged KV pool and prefix cache** (:class:`PagedKVPool`,
+  :class:`PrefixCache`): all in-flight sequences share one pool of
+  REFERENCE-COUNTED pages, a sequence owning an ordered page list (its
+  block table; page 0 is the *null page* that idle rows write and nobody
+  attends).  A host-side trie over page-aligned token chunks lets
+  admission take a cached prefix's pages and prefill only the suffix; a
+  shared page a request's first tick would rewrite is copied first
+  (copy-on-write), and pages nobody references STAY cached until the free
+  list runs dry, then go LRU leaf-first.
 
-* **On-demand pages**: admission allocates only the prompt-suffix pages
-  (plus the first decode page); decode grabs one page at each page-boundary
-  crossing.  A commitment ledger keeps ``free + evictable`` at least the
-  worst-case remaining demand of every admitted request (plus a
-  ``page_watermark`` slack), so an in-flight slot can never deadlock on the
-  pool — admission defers instead.
+* **One per-sequence memory a class** (:class:`ClassMemory`): a request
+  holds ``_mem``, one :class:`SeqMemory` a class in the order of the
+  engine's ``_classes``.  A uniform model has one; a layer pattern a full
+  and a window class; a model whose layers keep a constant-size recurrent
+  state a sequence (:class:`StatePool`: power retention) ONE state slot
+  from admission to its end, its table one entry wide; a hybrid
+  (gated-delta or Mamba-2 layers beside attention) pages AND a slot,
+  granted together or not at all.  The class owns the host mirror of the
+  tick's table, HOW a sequence's memory is granted, slid and released, and
+  the commitment ledger: admission takes only the prompt-suffix pages
+  (plus the first decode page), decode one page a boundary it crosses, and
+  the ledger keeps ``free + evictable`` at least the worst-case remaining
+  demand of every admitted request (plus ``page_watermark``), so a slot in
+  flight never deadlocks on the pool — admission defers instead.  This
+  file decides WHEN, in loops over ``zip(self._classes, req._mem)`` that
+  name no kind of memory.  With a state class prefill stops before a
+  prompt's last token for the whole stack (a state cannot take a token
+  twice: :meth:`_fill_end`), preemption drops the state and the resume
+  prefills again, and the prefix cache is off.  What a kind of memory does
+  not carry refuses in a sentence (``refuse_unserved``).
 
 * **Chunked prefill**: the uncovered suffix runs in fixed-size chunks that
   write K/V through the block table and attend through it too
@@ -64,7 +73,7 @@ resident, keep its batch full, and never compute the same prefix twice.
   preemption victims, load shedding — delegates to a pluggable
   :class:`~megatron_llm_tpu.generation.scheduling.SchedulerPolicy`
   (``--sched_policy``: ``fcfs`` default / ``priority`` / ``slo``), while
-  the MECHANISMS (pages, slots, the commitment ledger) stay here.
+  the MECHANISMS (slots here, pages and ledgers in the classes) do not.
   Preemption works by page release: the victim's finished KV pages are
   parked in the prefix trie, its pages released, and the request
   re-queued — re-admission matches the pages back out of the trie and
@@ -83,26 +92,6 @@ resident, keep its batch full, and never compute the same prefix twice.
   SAME pool (one page id addresses both caches), so block tables,
   refcounts, the commitment ledger, the prefix trie, COW and
   preemption-by-page-release all govern both models unchanged.
-
-* **A state in place of pages** (:class:`StatePool`): a model whose
-  layers keep a constant-size recurrent state a sequence (power retention,
-  ops/retention.py) holds ONE state slot from admission to its end, where
-  a paged model holds a growing page list: the allocator, the tables (one
-  entry wide), slots, chunked prefill, the tick and retirement are the
-  same code; prefill stops before a prompt's last token (:meth:`_fill_end`),
-  preemption drops the state and the resume prefills again, and the prefix
-  cache is off.  What pages alone carry refuses in a sentence
-  (generation/pools.py ``refuse_unserved``).
-
-* **A state beside pages** (a hybrid: gated-delta layers and latent
-  attention, or Mamba-2 layers and K/V attention, in one stack): ONE engine
-  holds a :class:`PagedKVPool` for the attention layers' rows and a
-  :class:`StatePool` for the others'.  A request holds both (``_state``):
-  admission grants both or waits, the tick carries a leaf and a table a
-  class (the state class's one entry wide), prefill stops before the
-  prompt's last token for the whole stack (a state cannot take a token
-  twice, so the pages must not either), retirement and preemption release
-  both, and a resume prefills both again.
 
 Threading: ``submit`` may be called from any thread (e.g. concurrent HTTP
 handlers — generation/server.py); device work happens on whichever thread
@@ -129,10 +118,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from megatron_llm_tpu.config.arguments import check_prefill_chunk
 from megatron_llm_tpu.core.parallel_state import PP_AXIS, TP_AXIS
 from megatron_llm_tpu.generation import generation as gen
+from megatron_llm_tpu.generation.launch import call_tick
 from megatron_llm_tpu.generation.pools import (  # noqa: F401 — re-exported
     NULL_PAGE,
+    ClassMemory,
     PagedKVPool,
     PrefixCache,
+    SeqMemory,
     StatePool,
     refuse_unserved,
 )
@@ -156,9 +148,7 @@ from megatron_llm_tpu.models.language_model import (
 from megatron_llm_tpu.generation.ragged import block_driver, decode_order
 from megatron_llm_tpu.ops import kv_quant
 from megatron_llm_tpu.ops.paged_attention import PagedState
-from megatron_llm_tpu.ops.pallas.paged_attention import (
-    tile_shares,
-)
+from megatron_llm_tpu.ops.pallas.paged_attention import tile_shares
 
 
 def _request_key(seed: int) -> np.ndarray:
@@ -231,26 +221,15 @@ class EngineRequest:
     shed_retry_after: float = 1.0
     _done: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False)
-    _pages: List[int] = dataclasses.field(default_factory=list, repr=False)
-    # a patterned model's WINDOW page class: the block's page by its place
-    # (as ``_pages``), NULL_PAGE where the window has moved past it
-    # (``_wfirst`` blocks so far); blocks below ``_wkeep`` are the prefix
-    # cache's, the rest this request's own, ``_wprivate`` of them live and
-    # never more than ``_wmax`` (what the ledger holds for it)
-    _wpages: List[int] = dataclasses.field(default_factory=list, repr=False)
-    _wfirst: int = dataclasses.field(default=0, repr=False)
-    _wkeep: int = dataclasses.field(default=0, repr=False)
-    _wprivate: int = dataclasses.field(default=0, repr=False)
-    _wmax: int = dataclasses.field(default=0, repr=False)
-    # a hybrid's state slot, held beside ``_pages`` from admission to the
-    # end (a state-only model's slot IS its one "page")
-    _state: List[int] = dataclasses.field(default_factory=list, repr=False)
+    # what it holds of each class of per-sequence memory, in the order of
+    # the engine's ``_classes``; empty until its first admission
+    _mem: List[SeqMemory] = dataclasses.field(default_factory=list,
+                                              repr=False)
     _step: int = 0  # decode ticks taken (== len(generated))
     # scheduler state: queued -> prefill -> decode -> finished
     _phase: str = dataclasses.field(default="queued", repr=False)
     _slot: int = dataclasses.field(default=-1, repr=False)
     _fill_pos: int = dataclasses.field(default=0, repr=False)
-    _max_pages: int = dataclasses.field(default=0, repr=False)
     _hit_tokens: int = dataclasses.field(default=0, repr=False)
     _t_submit: float = dataclasses.field(default=0.0, repr=False)
     _t_first: float = dataclasses.field(default=0.0, repr=False)
@@ -370,7 +349,7 @@ class ContinuousBatchingEngine:
 
         classes = pool_classes(cfg)
         self.state = any(cls.state for cls in classes)
-        self._state_only = all(cls.state for cls in classes)
+        state_only = all(cls.state for cls in classes)
         if inf.int8_weights:
             # same decode-weight quantization contract as api.InferenceEngine
             from megatron_llm_tpu.ops.quant import quantize_layer_weights_int8
@@ -528,7 +507,7 @@ class ContinuousBatchingEngine:
         # per request; rows of a request share it)
         self._pre_tables_cap = self.prefill_rows // self.prefill_chunk + 1
         # a state class's "table" is one entry wide: the sequence's slot
-        self.pages_per_seq = (1 if self._state_only
+        self.pages_per_seq = (1 if state_only
                               else -(-self.max_seq // self.page_size))
         num_pages = (num_pages or inf.kv_pool_pages
                      or self.max_slots * self.pages_per_seq + 1)
@@ -543,47 +522,48 @@ class ContinuousBatchingEngine:
             assert self.draft_cfg.model.num_layers % self._pp == 0, (
                 f"draft num_layers {self.draft_cfg.model.num_layers} "
                 f"not divisible by pp {self._pp}")
-        # a uniform model has ONE class, today's pool, tables and tick; a
-        # patterned model a full class (``pool``) and a window class
-        # (``wpool``); a hybrid a page class (``pool``) and a state class
-        # (``spool``): each with its own leaf, free list, reference counts
-        # and tables
+        # one pool a class (its leaf, free list and reference counts) and
+        # over it the class's table, ledger and operations on a request's
+        # ``_mem`` (``ClassMemory``).  A uniform model has ONE, with the
+        # mesh, the draft and unlabelled counters; ``pool`` is the first,
+        # ``wpool`` a pattern's window class, ``spool`` the state class
         self.wpool: Optional[PagedKVPool] = None
         self.spool: Optional[StatePool] = None
         self._window = 0
-        if self.state and not self._state_only:
-            page, st = classes
-            self.pool = PagedKVPool(
-                cfg, num_pages, self.page_size, kv_dtype=self.kv_dtype,
-                layers=page.layers(cfg), page_class=page.name)
-            self.spool = StatePool(cfg, self.max_slots, self.page_size,
-                                   layers=st.layers(cfg), page_class=st.name)
-        elif len(classes) == 2:
-            periods = cfg.model.num_layers // cfg.model.layer_period
-            full, win = classes
-            self._window = int(win.window)
-            # pages of the window class one sequence holds at most: the
-            # window's own (ceil(window / page), + 1 where it starts inside
-            # a page), the one its next position opens, and while it
-            # prefills the pages of one tick's rows of it
-            self.window_pages_cap = (-(-self._window // self.page_size) + 2
-                                     + self.prefill_rows // self.page_size)
-            self.pool = PagedKVPool(
-                cfg, num_pages, self.page_size, kv_dtype=self.kv_dtype,
-                layers=periods * len(full.places), page_class=full.name)
-            self.wpool = PagedKVPool(
-                cfg, (inf.kv_window_pool_pages
-                      or self.max_slots * min(self.pages_per_seq,
-                                              self.window_pages_cap) + 1),
-                self.page_size, kv_dtype=self.kv_dtype,
-                layers=periods * len(win.places), page_class=win.name)
-        elif self._state_only:
-            self.pool = self.spool = StatePool(cfg, self.max_slots,
-                                               self.page_size)
-        else:
-            self.pool = PagedKVPool(cfg, num_pages, self.page_size,
-                                    mesh=mesh, draft_cfg=self.draft_cfg,
-                                    kv_dtype=self.kv_dtype)
+        self.window_pages_cap: Optional[int] = None
+        self._pools: List[PagedKVPool] = []
+        self._classes: List[ClassMemory] = []  # guarded by _lock
+        many = len(classes) > 1
+        for cls in classes:
+            named = (dict(layers=cls.layers(cfg), page_class=cls.name)
+                     if many else {})
+            window, cap, pages = 0, None, num_pages
+            if cls.state:
+                pl = self.spool = StatePool(cfg, self.max_slots,
+                                            self.page_size, **named)
+            else:
+                if many and cls.window is not None:
+                    window = self._window = int(cls.window)
+                    # pages one sequence holds of it at most: the window's
+                    # own (ceil(window / page), + 1 where it starts inside
+                    # a page), the one its next position opens, and while
+                    # it prefills the pages of one tick's rows
+                    cap = self.window_pages_cap = (
+                        -(-window // self.page_size) + 2
+                        + self.prefill_rows // self.page_size)
+                    pages = (inf.kv_window_pool_pages or self.max_slots
+                             * min(self.pages_per_seq, cap) + 1)
+                pl = PagedKVPool(
+                    cfg, pages, self.page_size, kv_dtype=self.kv_dtype,
+                    **named, **({} if many else dict(
+                        mesh=mesh, draft_cfg=self.draft_cfg)))
+                if window:
+                    self.wpool = pl
+            self._pools.append(pl)
+            self._classes.append(ClassMemory(
+                pl, self.max_slots, self.pages_per_seq, window=window,
+                cap=cap, watermark=self.page_watermark))
+        self.pool = self._pools[0]
         if self.state:
             if use_cache:
                 print("[engine] the prefix cache is off for a state pool: "
@@ -601,16 +581,6 @@ class ContinuousBatchingEngine:
         # lock-discipline rule enforces the with-blocks / '# holds'
         # annotations (docs/guide/static-analysis.md)
         s = self.max_slots
-        # guarded by _lock
-        self._block_tables = np.zeros((s, self.pages_per_seq), np.int32)
-        # the window class's tables, a block at its own place as above and
-        # NULL_PAGE behind a sequence's first live page — guarded by _lock
-        self._wtables = (np.zeros((s, self.pages_per_seq), np.int32)
-                         if self.wpool is not None else None)
-        # a hybrid's state class: a slot's table is its sequence's state
-        # slot, one entry wide — guarded by _lock
-        self._stables = (np.zeros((s, 1), np.int32)
-                         if self.spool not in (None, self.pool) else None)
         self._positions = np.zeros((s,), np.int32)    # guarded by _lock
         self._tokens = np.zeros((s,), np.int32)       # guarded by _lock
         self._temperature = np.ones((s,), np.float32)  # guarded by _lock
@@ -625,13 +595,6 @@ class ContinuousBatchingEngine:
         self._queue: deque = deque()  # guarded by _lock
         # admitted, prompt not yet filled — guarded by _lock
         self._prefill_q: deque = deque()
-        # worst-case pages admitted-but-not-yet-held; admission keeps
-        # free + evictable >= committed (+ watermark) so decode-time allocs
-        # can never deadlock an in-flight slot — guarded by _lock
-        self._committed = 0
-        # the same ledger for the window class: what admitted requests may
-        # still take of it beyond the pages of their own they hold
-        self._wcommitted = 0  # guarded by _lock
         self.window_pages_released = 0
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -965,9 +928,6 @@ class ContinuousBatchingEngine:
                        "S and z of every layer, KV head and slot, the "
                        "null slot included; 0 for a paged model)"
                   ).set(self.spool.kv_pool_bytes() if self.state else 0)
-        self._pools = [self.pool] + [
-            pl for pl in (self.wpool, self.spool)
-            if pl not in (None, self.pool)]
         # what the paged kernel walks of a page class, for the host's
         # count of its blocks: (attention layers, their window, bytes of
         # this engine's share of a token's row)
@@ -985,15 +945,15 @@ class ContinuousBatchingEngine:
                      "of this page class had to evict",
                 labels={"class": pl.page_class})
             for pl in self._pools if pl.page_class is not None}
-        self._m_seq_pages = {
-            (pl.page_class or "full"): reg.counter(
+        self._m_seq_pages = [
+            reg.counter(
                 "mlt_engine_seq_pages_sum",
                 help="pages of this class that the decoding sequences "
                      "held, summed over sequences and applied ticks; over "
                      "mlt_engine_seq_ticks_total the mean pages a live "
                      "sequence holds, whole window",
-                labels={"class": pl.page_class or "full"})
-            for pl in self._pools}
+                labels={"class": cls.name})
+            for cls in self._classes]
         self._m_seq_ticks = reg.counter(
             "mlt_engine_seq_ticks_total",
             help="decoding sequences summed over applied ticks (the "
@@ -1262,6 +1222,23 @@ class ContinuousBatchingEngine:
                 donate_argnums=(1,))
         self._ragged_fns[pre_rows] = fn
         return fn
+
+    # A program's first call traces, lowers and compiles (or loads) it on
+    # the scheduler thread while every open stream waits: a ``tick-program``
+    # start-up phase around that call alone, so a tick whose program exists
+    # pays one dictionary lookup.
+
+    def _tick_program(self, pre_rows: int):
+        new = pre_rows not in self._ragged_fns
+        fn = self._ragged_tick(pre_rows)
+        return obs_compiles.startup_phase(
+            "tick-program", rows=pre_rows)(fn) if new else fn
+
+    def _chunk_program(self, rows: int, kv_pages: int):
+        new = (rows, kv_pages) not in self._chunk_fns
+        fn = self._score_chunk(rows, kv_pages)
+        return obs_compiles.startup_phase(
+            "tick-program", rows=rows, kv_pages=kv_pages)(fn) if new else fn
 
     def _score_chunk(self, rows: int, kv_pages: int):
         """One teacher-forced prefill CHUNK of a ``return_log_probs``
@@ -1551,12 +1528,6 @@ class ContinuousBatchingEngine:
                       labels={"priority": str(prio)}
                       ).set(by_prio.get(prio, 0))
 
-    def _max_pages_for(self, req: EngineRequest) -> int:
-        if self._state_only:
-            return 1      # its state slot, whatever its length
-        total = min(len(req.prompt) + req.max_new_tokens, self.max_seq)
-        return -(-total // self.page_size)
-
     def _fill_end(self, prompt_len: int) -> int:
         """Where prefill stops.  Pages: the prompt bucketed up to whole
         pages (the padding's keys are never attended, and the first decode
@@ -1587,13 +1558,11 @@ class ContinuousBatchingEngine:
         (``admission_order``; fcfs = queue head with nothing skipping it,
         ``barrier_admission``), which queued requests to shed outright,
         and which decoding victim to preempt when the best candidate
-        can't get a slot or its page budget.  The engine owns the
-        MECHANISMS: it reserves only the uncovered prompt suffix (plus
-        the first decode page) and books the worst-case rest in the
-        commitment ledger.  Planning (trie match, budget check,
-        allocation, slot assignment) happens under ``_lock``; only the
-        device work (the COW copy) runs outside it, with every owned page
-        ref tracked in ``req._pages`` throughout so a failure path
+        can't get a slot or its page budget.  The MECHANISMS are the
+        classes' (generation/pools.py).  Planning (trie match, budget
+        check, allocation, slot assignment) happens under ``_lock``; only
+        the device work (the COW copy) runs outside it, with every owned
+        page ref tracked in ``req._mem`` throughout so a failure path
         releases exactly what is held."""
         while True:
             with self._lock:
@@ -1612,20 +1581,20 @@ class ContinuousBatchingEngine:
                         return
                 order = self.policy.admission_order(list(self._queue),
                                                     state)
-                req = plan = None
+                req = cow = None
                 try:
                     slot = self._slots.index(None)
                 except ValueError:
                     slot = None
                 if slot is not None:
                     for cand in order:
-                        p = self._plan_chunked(cand, slot)
-                        if p is not None:
-                            req, plan = cand, p
+                        cow = self._plan_chunked(cand, slot)
+                        if cow is not None:
+                            req = cand
                             break
                         if self.policy.barrier_admission:
                             break  # page pressure: head waits, no skips
-                if plan is None:
+                if req is None:
                     # blocked on a slot or on pages: the policy may evict
                     # the lowest-value decoding request — its pages go
                     # back to the pool (prefix-covered ones stay in the
@@ -1645,7 +1614,7 @@ class ContinuousBatchingEngine:
                 self._queue.remove(req)
                 self._publish_queued_locked()
             try:
-                self._place_chunked(req, plan)
+                self._place_chunked(req, cow)
             except Exception as e:  # noqa: BLE001 — surface to the waiter
                 self._fail(req, e)
 
@@ -1660,12 +1629,10 @@ class ContinuousBatchingEngine:
         assert victim._phase == "decode" and victim._slot >= 0
         slot = victim._slot
         seq = victim.seq_tokens
-        if self.cache is not None:
-            # every page fully covered by seq[:-1] is finished K/V the
-            # resume's refeed tick will never write — safe to share
-            self.cache.insert(seq, victim._pages,
-                              self._parkable_pages(victim, seq),
-                              victim._wpages or None)
+        # every page fully covered by seq[:-1] is finished K/V the
+        # resume's refeed tick will never write — safe to share
+        self._cache_insert_locked(victim, seq,
+                                  self._parkable_pages(victim, seq))
         self._clear_slot_locked(slot)
         pages = self._release_pages_locked(victim)
         victim._phase = "queued"
@@ -1739,82 +1706,49 @@ class ContinuousBatchingEngine:
     # ---- admission ----
 
     def _plan_chunked(self, req: EngineRequest,
-                      slot: int) -> Optional[dict]:  # holds _lock
-        """Under _lock: match the prefix cache, check the page budget,
-        allocate the suffix pages, and reserve the slot.  None = can't
-        admit now (matched refs undone).  Works on the request's
+                      slot: int) -> Optional[bool]:  # holds _lock
+        """Under _lock: match the prefix cache, ask every class for its
+        pages (a grant that one class refuses is refused whole), and
+        reserve the slot.  None = can't admit now (matched refs undone);
+        else whether the first tick would write a shared page, which
+        :meth:`_place_chunked` copies first.  Works on the request's
         EFFECTIVE prompt (prompt + generated): a preempted request
         re-admits here and its parked pages match straight back out of
         the trie."""
         ps = self.page_size
         seq = req.seq_tokens
         prompt_len = len(seq)
-        max_total = self._max_pages_for(req)
-        matched: List[int] = []
-        wmatched: List[int] = []
-        classed = self.wpool is not None
+        # pages its tokens fill at most (a class caps that at its own)
+        max_total = -(-min(len(req.prompt) + req.max_new_tokens,
+                           self.max_seq) // ps)
+        lists: List[List[int]] = [[] for _ in self._classes]
         if self.cache is not None and not req.return_log_probs:
             # log-prob requests recompute the whole prompt (the teacher-
             # forced scores need every position's logits), so they take no
             # shared pages — their pages still feed the cache afterwards
-            if classed:
-                matched, wmatched = self.cache.match_classes(
-                    seq, prompt_len // ps)
-            else:
-                matched = self.cache.match(seq, prompt_len // ps)
+            lists = self.cache.match_lists(seq, prompt_len // ps)
+        matched = lists[0]
         covered = len(matched) * ps
         # full page-aligned match: the first tick re-feeds the last prompt
         # token and would WRITE the final shared page -> copy-on-write
         cow = bool(matched) and covered == prompt_len and not self._blocks
         n_keep = len(matched) - (1 if cow else 0)
         fill_end = self._fill_end(prompt_len)
-        suffix_pages = (1 if self._state_only
-                        else -(-(fill_end - covered) // ps))
+        suffix_pages = -(-(fill_end - covered) // ps)
         held_core = n_keep + (1 if cow else 0) + suffix_pages
         extra = self._first_pages(max_total - held_core)  # decode pages
-        need_now = (1 if cow else 0) + suffix_pages + extra
-        remaining = max_total - held_core - extra
-        # the window class: the copy-on-write page now, the prompt's pages
-        # as its chunks are planned, a decode page as a row crosses into
-        # it; never more of its own than the cap, nor than its blocks
-        # behind the shared ones.  A grant that one class refuses is
-        # refused whole
-        wneed = 1 if classed and cow else 0
-        wmax = min(self.window_pages_cap, max_total - n_keep) if classed else 0
-
-        def undo():
-            self.pool.release(matched)
-            if classed:
-                self.wpool.release([p for p in wmatched if p != NULL_PAGE])
-
-        if (self.pool.num_available - need_now
-                < self._committed + remaining + self.page_watermark) or (
-                classed and self.wpool.num_available - wneed
-                < self._wcommitted + wmax - wneed + self.page_watermark) or (
-                self._stables is not None and not self.spool.num_free):
-            undo()
+        # each class grants of these what is its to grant now and books
+        # the rest (``ClassMemory.demand``), all of them or none
+        want = (n_keep, int(cow), suffix_pages + extra, max_total)
+        if not all(cls.can_admit(*want) for cls in self._classes):
+            for cls, got in zip(self._classes, lists):
+                cls.undo(got)
             return None
-        fresh = self.pool.alloc(need_now)
-        wfresh = self.wpool.alloc(wneed) if classed else []
-        if fresh is None or wfresh is None:  # unreachable given the check
-            self.pool.release(fresh or [])
-            undo()
-            return None
-        self._committed += remaining
-        if self._stables is not None:
-            # the state slot, granted with the pages or not at all
-            req._state = self.spool.alloc(1)
-        if classed:
-            self._wcommitted += wmax - wneed
-            req._wpages = wmatched + wfresh
-            req._wfirst = self.cache.window_first(len(matched)) \
-                if matched else 0
-            req._wkeep, req._wprivate, req._wmax = n_keep, wneed, wmax
-        # every ref this request owns lives in _pages from here on, so any
+        # every ref this request owns lives in _mem from here on, so any
         # failure path releases exactly the right set; the COW page swap
-        # reorders the list after the device copy lands
-        req._pages = matched + fresh
-        req._max_pages = max_total
+        # reorders the lists after the device copy lands
+        req._mem = [cls.admit(got, *want)
+                    for cls, got in zip(self._classes, lists)]
         req._fill_pos = prompt_len if cow else covered
         req._hit_tokens = covered
         req._slot = slot
@@ -1830,43 +1764,31 @@ class ContinuousBatchingEngine:
         req._flight.note_hit_tokens(covered)
         req._flight.set_phase(
             "prefill", kind="resume" if req._preemptions else "admit",
-            slot=slot, hit_tokens=covered, pages=len(req._pages))
+            slot=slot, hit_tokens=covered, pages=len(req._mem[0].pages))
         if obs_registry.publishing():
             self._m_hit_tokens.inc(covered)
             self._m_miss_tokens.inc(prompt_len - covered)
-        return {"matched": matched, "fresh": fresh, "cow": cow,
-                "n_keep": n_keep, "wmatched": wmatched, "wfresh": wfresh}
+        return cow
 
-    def _place_chunked(self, req: EngineRequest, plan: dict) -> None:
-        matched, fresh = plan["matched"], plan["fresh"]
-        n_keep, cow = plan["n_keep"], plan["cow"]
+    def _place_chunked(self, req: EngineRequest, cow: bool) -> None:
         if cow:
-            src, dst = matched[-1], fresh[0]
             # device copy OUTSIDE the lock (driver thread; serialized with
-            # ticks via _drive_lock), then drop our ref on the shared page
-            if self.spec_k:
-                self.pool.kv, self.pool.draft_kv = self._copy_page()(
-                    self.pool.kv, self.pool.draft_kv,
-                    self._asarray(np.int32(src)),
-                    self._asarray(np.int32(dst)))
-            else:
-                self.pool.kv = self._copy_page()(
-                    self.pool.kv, self._asarray(np.int32(src)),
-                    self._asarray(np.int32(dst)))
-            if self.wpool is not None:
-                self.wpool.kv = self._copy_page()(
-                    self.wpool.kv,
-                    self._asarray(np.int32(plan["wmatched"][-1])),
-                    self._asarray(np.int32(plan["wfresh"][0])))
+            # ticks via _drive_lock), a class at a time: the shared page at
+            # ``keep`` into the request's own behind it; then drop our ref
+            for pl, mem in zip(self._pools, req._mem):
+                src = self._asarray(np.int32(mem.pages[mem.keep]))
+                dst = self._asarray(np.int32(mem.pages[mem.keep + 1]))
+                if self.spec_k:
+                    pl.kv, pl.draft_kv = self._copy_page()(
+                        pl.kv, pl.draft_kv, src, dst)
+                else:
+                    pl.kv = self._copy_page()(pl.kv, src, dst)
         with self._lock:
             if cow:
                 # block-table order: kept shared pages, the private COW
                 # copy, then the first decode page
-                req._pages = matched[:n_keep] + [fresh[0]] + fresh[1:]
-                self.pool.release([matched[-1]])
-                if self.wpool is not None:
-                    req._wpages = plan["wmatched"][:n_keep] + plan["wfresh"]
-                    self.wpool.release([plan["wmatched"][-1]])
+                for cls, mem in zip(self._classes, req._mem):
+                    cls.drop_shared(mem)
                 self.cow_copies += 1
                 if obs_registry.publishing():
                     self._m_cow.inc()
@@ -1878,6 +1800,11 @@ class ContinuousBatchingEngine:
             else:
                 req._phase = "prefill"
                 self._prefill_q.append(req)
+
+    def _cache_insert_locked(self, req, seq, n_pages: int):  # holds _lock
+        """The first ``n_pages`` whole pages of ``seq`` go to the trie."""
+        if self.cache is not None:
+            self.cache.insert_lists(seq, [m.pages for m in req._mem], n_pages)
 
     # ---- shared lifecycle tail ----
 
@@ -1895,15 +1822,9 @@ class ContinuousBatchingEngine:
             if seed is None:
                 seed = int.from_bytes(os.urandom(4), "little")
             req._key = _request_key(seed)
-        bt = np.full((self.pages_per_seq,), NULL_PAGE, np.int32)
-        bt[: len(req._pages)] = req._pages
-        self._block_tables[slot] = bt
-        if self._stables is not None:
-            self._stables[slot] = req._state
-        if self.wpool is not None:
-            self._slide_locked(req, len(seq) - 1)
-            self._wtables[slot] = NULL_PAGE
-            self._wtables[slot][: len(req._wpages)] = req._wpages
+        self._slide_locked(req, len(seq) - 1)
+        for cls, mem in zip(self._classes, req._mem):
+            cls.install(slot, mem)
         self._positions[slot] = len(seq) - 1
         self._tokens[slot] = seq[-1]
         self._temperature[slot] = req.temperature
@@ -1937,11 +1858,11 @@ class ContinuousBatchingEngine:
         self._clear_slot_locked(slot)
         # a handoff request never decodes: its worst-case decode-page
         # commitment returns to the ledger now
-        self._committed -= max(0, req._max_pages - len(req._pages))
-        req._max_pages = len(req._pages)
+        for cls, mem in zip(self._classes, req._mem):
+            cls.trim(mem)
         req._slot = -1
         req._phase = "handoff"
-        req._flight.set_phase("handoff", pages=len(req._pages))
+        req._flight.set_phase("handoff", pages=len(req._mem[0].pages))
         req._done.set()
 
     def _finish_handoff_locked(self, req: EngineRequest,
@@ -1963,10 +1884,8 @@ class ContinuousBatchingEngine:
     def _clear_slot_locked(self, slot: int) -> None:  # holds _lock
         """An emptied slot: a dead row (null tables, greedy, position 0)."""
         self._slots[slot] = None
-        self._block_tables[slot] = NULL_PAGE
-        for tables in (self._wtables, self._stables):
-            if tables is not None:
-                tables[slot] = NULL_PAGE
+        for cls in self._classes:
+            cls.clear(slot)
         self._positions[slot] = 0
         self._tokens[slot] = 0
         self._top_k[slot] = 1
@@ -1977,70 +1896,26 @@ class ContinuousBatchingEngine:
     def _release_pages_locked(self, req: EngineRequest) -> int:  # holds _lock
         """Give back every page ``req`` holds, in each class, and what the
         ledgers still held for it; returns how many pages those were."""
-        pages, req._pages = req._pages, []
-        self._committed -= max(0, req._max_pages - len(pages))
-        self.pool.release(pages)
-        if req._state:
-            self.spool.release(req._state)
-            req._state = []
-        if self.wpool is None:
-            return len(pages)
-        wpages = [p for p in req._wpages if p != NULL_PAGE]
-        self._wcommitted -= max(0, req._wmax - req._wprivate)
-        self.wpool.release(wpages)
-        req._wpages, req._wfirst, req._wkeep = [], 0, 0
-        req._wprivate = req._wmax = 0
-        return len(pages) + len(wpages)
+        return sum(cls.release(mem)
+                   for cls, mem in zip(self._classes, req._mem))
 
     def _slide_locked(self, req: EngineRequest,
                       qpos: int) -> int:  # holds _lock
-        """The window class's release on slide: no query of ``req`` at
-        position ``qpos`` or later can see a key of the blocks before the
-        one holding key ``qpos - window + 1``, so their pages go back
-        (those the prefix cache registered stay cached-idle in their
-        class), and the ledger holds a page again for each of the
-        request's own.  A tick already launched may still read them: it
-        runs before any tick that writes what a later grant makes of
-        them.  Returns the pages released."""
-        ps = self.page_size
-        first = min(max(0, qpos - self._window + 1) // ps, len(req._wpages))
-        lo = req._wfirst
-        if first <= lo:
-            return 0
-        gone, own = [], 0
-        for i in range(lo, first):
-            p = req._wpages[i]
-            if p != NULL_PAGE:
-                gone.append(p)
-                own += i >= req._wkeep
-                req._wpages[i] = NULL_PAGE
-        req._wfirst = first
-        req._wprivate -= own
-        self._wcommitted += own
-        self.wpool.release(gone)
-        if req._slot >= 0 and req._phase == "decode":
-            # the host's mirror only: the rows' queries start behind these
-            # blocks, and the next upload that anything else asks for
-            # carries the nulls
-            self._wtables[req._slot][lo:first] = NULL_PAGE
-        self.window_pages_released += len(gone)
-        if gone and obs_registry.publishing():
-            self._m_slid.inc(len(gone))
-        return len(gone)
+        """Slide ``req``'s windows up to a query at ``qpos`` (nothing for a
+        class without one); counts and returns the pages that gave back."""
+        gone = sum(cls.slide(mem, qpos)
+                   for cls, mem in zip(self._classes, req._mem))
+        if gone:
+            self.window_pages_released += gone
+            if obs_registry.publishing():
+                self._m_slid.inc(gone)
+        return gone
 
-    def _grant_window_locked(self, req: EngineRequest,
-                             last_block: int) -> bool:  # holds _lock
-        """Window-class pages for every block up to ``last_block`` that
-        ``req`` has none for yet (its own, off the ledger); False where the
-        pool cannot (ledger-unreachable)."""
-        while len(req._wpages) <= last_block:
-            got = self.wpool.alloc(1)
-            if got is None:
-                return False
-            req._wpages.append(got[0])
-            req._wprivate += 1
-            self._wcommitted -= 1
-        return True
+    def _ledger_violated(self, k: int, whom: str):  # holds _lock
+        """A row whose class ``k`` could not grant what its ledger booked."""
+        return RuntimeError(
+            ("" if k == 0 else f"{self._classes[k].name}-class ")
+            + f"KV pool exhausted for {whom} — commitment ledger violated")
 
     def _fail(self, req: EngineRequest, e: Exception) -> None:
         with self._lock:
@@ -2050,11 +1925,7 @@ class ContinuousBatchingEngine:
                      e: Exception) -> None:  # holds _lock
         if 0 <= req._slot < len(self._slots) \
                 and self._slots[req._slot] is req:
-            self._slots[req._slot] = None
-            self._block_tables[req._slot] = NULL_PAGE
-            if self._wtables is not None:
-                self._wtables[req._slot] = NULL_PAGE
-            self._dirty = True
+            self._clear_slot_locked(req._slot)
         self._release_pages_locked(req)
         req._phase = "finished"
         req.error = f"{type(e).__name__}: {e}"
@@ -2272,8 +2143,9 @@ class ContinuousBatchingEngine:
             n_real = min(end, prompt_len) - start
             tokens[0, :n_real] = seq[start:start + n_real]
             bt = np.full((1, kv_pages), NULL_PAGE, np.int32)
-            n_bt = min(len(req._pages), kv_pages)
-            bt[0, :n_bt] = req._pages[:n_bt]
+            pages = req._mem[0].pages
+            n_bt = min(len(pages), kv_pages)
+            bt[0, :n_bt] = pages[:n_bt]
             targets = np.zeros((1, rows), np.int32)
             n_lp = max(0, min(rows, prompt_len - 1 - start))
             targets[0, :n_lp] = seq[start + 1:start + 1 + n_lp]
@@ -2315,13 +2187,11 @@ class ContinuousBatchingEngine:
                 self._m_prefill_tokens.inc(rows)
             if end >= fill_end:
                 self._prefill_q.remove(req)
-                if self.cache is not None:
-                    # cache every page FULLY covered by prompt tokens that
-                    # the refeed tick will never write: (prompt_len-1)//page
-                    # excludes the refeed page, so shared pages are
-                    # immutable from birth
-                    self.cache.insert(seq, req._pages,
-                                      (prompt_len - 1) // ps)
+                # cache every page FULLY covered by prompt tokens that
+                # the refeed tick will never write: (prompt_len-1)//page
+                # excludes the refeed page, so shared pages are
+                # immutable from birth
+                self._cache_insert_locked(req, seq, (prompt_len - 1) // ps)
                 self._activate_or_handoff(req, req._slot)
         return True
 
@@ -2365,10 +2235,11 @@ class ContinuousBatchingEngine:
         failed.  ``ahead[i]`` is 1 for a row the tick in flight is still
         sampling for: this tick feeds it one position past the host's.
 
-        A row crossing into a page it doesn't own yet gets one allocated
-        now (commitment ledger guarantees this can't fail while the slot
-        is in flight).  A speculating slot writes up to k_eff positions
-        past its own, so its horizon covers the whole verify block; k_eff
+        A row crossing into a block it holds no page for gets one of each
+        class now (``ClassMemory.grant``; the commitment ledger guarantees
+        this can't fail while the slot is in flight).  A speculating slot
+        writes up to k_eff positions past its own, so its horizon covers
+        the whole verify block; k_eff
         itself is per-slot and per-tick — capped by --spec_k, the tokens
         the request still owes, and (adaptive mode) the acceptance EMA.
         Writes past a row's k_eff land on the null page or above the
@@ -2384,36 +2255,17 @@ class ContinuousBatchingEngine:
                     k_i = min(k_i, max(1, int(round(
                         req._spec_ema * self.spec_k))))
                 k_eff[i] = max(k_i, 0)
-            pos = int(self._positions[i]) + int(ahead[i])
-            p0 = pos // self.page_size
-            p1 = (pos + int(k_eff[i])) // self.page_size
-            for idx in range(p0, min(p1, self.pages_per_seq - 1) + 1):
-                if self._block_tables[i][idx] != NULL_PAGE:
-                    continue
-                got = self.pool.alloc(1)
+            last = (int(self._positions[i]) + int(ahead[i])
+                    + int(k_eff[i])) // self.page_size
+            for k, (cls, mem) in enumerate(zip(self._classes, req._mem)):
+                got = cls.grant(mem, last)
                 if got is None:  # ledger-unreachable; fail just the row
-                    self._fail_locked(req, RuntimeError(
-                        "KV pool exhausted for an in-flight slot — "
-                        "commitment ledger violated"))
+                    self._fail_locked(req, self._ledger_violated(
+                        k, "an in-flight slot"))
                     active.remove(i)
                     break
-                self._block_tables[i][idx] = got[0]
-                req._pages.append(got[0])
-                self._committed -= 1
-                self._dirty = True
-            if self.wpool is not None and i in active and (
-                    len(req._wpages) <= min(p1, self.pages_per_seq - 1)):
-                n_had = len(req._wpages)
-                if not self._grant_window_locked(
-                        req, min(p1, self.pages_per_seq - 1)):
-                    self._fail_locked(req, RuntimeError(
-                        "window-class KV pool exhausted for an in-flight "
-                        "slot — commitment ledger violated"))
-                    active.remove(i)
-                    continue
-                self._wtables[i][n_had:len(req._wpages)] = \
-                    req._wpages[n_had:]
-                self._dirty = True
+                if got:
+                    self._dirty = True
         return k_eff
 
     def _dev_state_locked(self, ahead=0, spent=()) -> Tuple:  # holds _lock
@@ -2428,16 +2280,10 @@ class ContinuousBatchingEngine:
         tick that reads the upload has run, and a backend may alias host
         memory (XLA:CPU does)."""
         if self._dirty:
-            bt = self._block_tables.copy()
-            bt[list(spent)] = NULL_PAGE
-            other = (self._wtables if self._wtables is not None
-                     else self._stables)
-            if other is not None:
-                obt = other.copy()
-                obt[list(spent)] = NULL_PAGE
-                bt = (bt, obt)
-            # the tables alone may be a pair (one a page class)
-            self._dev_state = (jax.tree.map(self._asarray, bt),) + tuple(
+            # the tables: one a class, a single leaf where there is one
+            bt = tuple(cls.snapshot(spent) for cls in self._classes)
+            self._dev_state = (jax.tree.map(
+                self._asarray, bt if len(bt) > 1 else bt[0]),) + tuple(
                 self._asarray(a) for a in (
                     self._positions + ahead, self._tokens.copy(),
                     self._keys.copy(), self._steps + ahead,
@@ -2532,31 +2378,25 @@ class ContinuousBatchingEngine:
         Returns ``(spans, pre_tok, pre_pos, pre_tables, pre_index,
         pre_hor, lp_live)`` where spans is ``[(req, start, end), ...]``,
         ``pre_tables``/``pre_index`` are the COMPRESSED block tables (one
-        table per packed request, ``-1`` index = dead row; a patterned
-        model's ``pre_tables`` is the (full, window) pair), and
+        table per packed request, ``-1`` index = dead row; ``pre_tables``
+        is one a class), and
         ``lp_live`` flags return_log_probs prompts that must take the
         teacher-forced scoring chunk instead.
 
-        The window class's pages of a prompt are granted HERE, for the rows
+        A window class's pages of a prompt are granted HERE, for the rows
         a tick packs and no further, after the window has been slid up to
         the first of them: a prompt of any length holds the window's pages
-        and one tick's rows' (``window_pages_cap``)."""
+        and one tick's rows' (``window_pages_cap``).  Every other class
+        holds the prompt's since admission, and its grant finds nothing
+        to do."""
         Rp = self.prefill_rows
         pre_tok = np.zeros((Rp,), np.int32)
         pre_pos = np.zeros((Rp,), np.int32)
-        pre_tables = np.full((self._pre_tables_cap, self.pages_per_seq),
-                             NULL_PAGE, np.int32)
+        pre_tables = tuple(
+            np.full((self._pre_tables_cap, cls.width), NULL_PAGE, np.int32)
+            for cls in self._classes)
         pre_index = np.full((Rp,), -1, np.int32)
         pre_hor = np.zeros((Rp,), np.int32)
-        pre_wtables = pre_stables = None
-        first_tables = pre_tables
-        if self.wpool is not None:
-            pre_wtables = np.full_like(pre_tables, NULL_PAGE)
-            pre_tables = (pre_tables, pre_wtables)
-        elif self._stables is not None:
-            pre_stables = np.full((self._pre_tables_cap, 1), NULL_PAGE,
-                                  np.int32)
-            pre_tables = (pre_tables, pre_stables)
         spans: List[Tuple[EngineRequest, int, int]] = []
         live = [r for r in self._prefill_q if r._phase == "prefill"]
         if len(live) != len(self._prefill_q):  # failed/cancelled
@@ -2582,20 +2422,19 @@ class ContinuousBatchingEngine:
             pos = req._fill_pos
             if pos >= fill_end or used >= budget:
                 continue
-            first_tables[n_req, : len(req._pages)] = req._pages
-            if pre_stables is not None:
-                pre_stables[n_req] = req._state
-            if pre_wtables is not None:
-                self._slide_locked(req, pos)
-                # the rows this request gets are known before they are
-                # packed: chunk ends do not move what the budget leaves
-                last = min(fill_end, pos + (budget - used)) - 1
-                if not self._grant_window_locked(req, last // ps):
-                    self._fail_locked(req, RuntimeError(
-                        "window-class KV pool exhausted for an admitted "
-                        "prompt — commitment ledger violated"))
-                    continue
-                pre_wtables[n_req, : len(req._wpages)] = req._wpages
+            self._slide_locked(req, pos)
+            # the rows this request gets are known before they are
+            # packed: chunk ends do not move what the budget leaves
+            last = min(fill_end, pos + (budget - used)) - 1
+            for k, (cls, mem, table) in enumerate(zip(
+                    self._classes, req._mem, pre_tables)):
+                if cls.grant(mem, last // ps) is None:
+                    self._fail_locked(req, self._ledger_violated(
+                        k, "an admitted prompt"))
+                    break
+                table[n_req, : len(mem.pages)] = mem.pages
+            if req._phase != "prefill":     # failed just above
+                continue
             while pos < fill_end and used < budget:
                 # absolute-grid chunk boundary (first/last may be short);
                 # a budget cut mid-chunk is fine — the next tick's chunk
@@ -2653,10 +2492,7 @@ class ContinuousBatchingEngine:
             seq = req.seq_tokens
             if end >= self._fill_end(len(seq)):
                 self._prefill_q.remove(req)
-                if self.cache is not None:
-                    self.cache.insert(seq, req._pages,
-                                      (len(seq) - 1) // ps,
-                                      req._wpages or None)
+                self._cache_insert_locked(req, seq, (len(seq) - 1) // ps)
                 self._activate_or_handoff(req, req._slot)
 
     def _step_ragged(self, admit_s: float, c_admit: float) -> int:
@@ -2802,7 +2638,8 @@ class ContinuousBatchingEngine:
                 pre_args = () if not n_bucket else (
                     self._asarray(pre_tok[:n_bucket]),
                     self._asarray(pre_pos[:n_bucket]),
-                    jax.tree.map(self._asarray, pre_tables),
+                    jax.tree.map(self._asarray, pre_tables  # one: a leaf
+                                 if len(pre_tables) > 1 else pre_tables[0]),
                     self._asarray(pre_index[:n_bucket]),
                     self._asarray(pre_hor[:n_bucket]))
                 tick_fn = self._tick_program(n_bucket)
@@ -2810,8 +2647,8 @@ class ContinuousBatchingEngine:
                 if self.spec_k:
                     (self.pool.kv, self.pool.draft_kv,
                      out_tok, out_lp, acc, cnt,
-                     new_pos, next_tok, new_steps) = tick_fn(
-                        self.params, self.draft_params,
+                     new_pos, next_tok, new_steps) = call_tick(
+                        tick_fn, self.params, self.draft_params,
                         self.pool.kv, self.pool.draft_kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         self._asarray(k_eff), *pre_args)
@@ -2819,8 +2656,8 @@ class ContinuousBatchingEngine:
                     del acc, cnt
                 else:
                     (self._kv, next_tok, out_lp,
-                     new_pos, new_steps, *moe) = tick_fn(
-                        self.params, self._kv,
+                     new_pos, new_steps, *moe) = call_tick(
+                        tick_fn, self.params, self._kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         *carry, *pre_args)
                     out_tok, spec = next_tok, None
@@ -2863,30 +2700,9 @@ class ContinuousBatchingEngine:
             if dry:
                 self._m_dry_ticks.inc()
         if self.state and obs_registry.publishing():
-            # the state sweep's rows and runs, by the program's own rule
-            # (ops/retention.tick_runs): a decode row is a run of one, a
-            # request's prompt rows of one tick are one run however many
-            # chunks they fill, and a run at position 0 starts on zero
-            runs = {id(r): start for r, start, _ in reversed(spans)}
-            self._m_state["rows"].inc(len(active) + n_pre)
-            self._m_state["touches"].inc(len(active) + len(runs))
-            self._m_state["resets"].inc(
-                sum(start == 0 for start in runs.values()) + starts)
-            steps = len(active) + n_pre
-            if self.spool.sweep_steps is not None:
-                # the tick's rows as the program lays them out: a decode
-                # row a slot, the prompt rows behind them, each request's
-                # at consecutive positions
-                pre = pre_index[:n_bucket]
-                row_slots = np.zeros((self.max_slots,), np.int32)
-                row_slots[active] = 1 + np.asarray(active, np.int32)
-                steps = self.spool.sweep_steps(
-                    np.concatenate([row_slots, np.where(
-                        pre >= 0, 1 + self.max_slots + pre, 0)]),
-                    np.concatenate([np.zeros_like(row_slots),
-                                    pre_pos[:n_bucket]]))
-            self._m_state["steps"].inc(steps)
-        if not self._state_only and obs_registry.publishing():
+            self._note_state_rows(active, spans, n_pre, starts,
+                                  pre_index[:n_bucket], pre_pos[:n_bucket])
+        if self._walked and obs_registry.publishing():
             # the tick's rows as the program lays them out, by the kernel's
             # own rules: the slots in the tick's order (a slot's verify
             # rows together), a request's prompt rows behind them, on the
@@ -2896,9 +2712,8 @@ class ContinuousBatchingEngine:
                 tables = [
                     np.concatenate([null, mine, packed])
                     for mine, packed, _ in zip(
-                        (self._block_tables, self._wtables),
-                        pre_tables if isinstance(pre_tables, tuple)
-                        else (pre_tables,), self._walked)]
+                        [cls.table for cls in self._classes], pre_tables,
+                        self._walked)]
                 pos = self._positions + ahead
             tables[0][1 + np.asarray(spent, np.int64)] = NULL_PAGE
             order = decode_order(tables[0][1:1 + self.max_slots])
@@ -2913,23 +2728,53 @@ class ContinuousBatchingEngine:
                 np.concatenate([(on * pos).ravel(), pre_pos[:n_bucket]]),
                 np.concatenate([(on * _bucket_up(pos + 1)).ravel(),
                                 pre_hor[:n_bucket]]))
-            self._m_paged_rows.inc(int((rows[2] > 0).sum()))
-            for k, (table, (layers, window, row)) in enumerate(
-                    zip(tables, self._walked)):
-                shares = tile_shares(
-                    table, *rows, window=window, page=self.page_size,
-                    row_bytes=row)
-                if k == 0:
-                    self._m_paged_walks.inc(int(shares.walks()))
-                seen, fetched = shares.blocks()
-                self._m_paged_seen.inc(layers * int(seen))
-                self._m_paged_fetched.inc(layers * int(fetched))
+            self._note_walks(tables, rows)
         # the tick before lands while the device runs this one; this one
         # too where the host cannot know its outcome's shape beforehand
         lag = 0 if self.spec_k or did_lp else 1
         while self._apply_tick(keep=lag) is not None:
             pass
         return len(active) + (1 if spans else 0) + did_lp
+
+    def _note_walks(self, tables, rows) -> None:
+        """The paged kernel's rows, walks and blocks of one launched tick, by
+        its own rule (``tile_shares``): ``tables`` one a page class, the
+        null row first; ``rows`` each row's table, position and horizon."""
+        self._m_paged_rows.inc(int((rows[2] > 0).sum()))
+        for k, (table, (layers, window, row)) in enumerate(
+                zip(tables, self._walked)):
+            shares = tile_shares(table, *rows, window=window,
+                                 page=self.page_size, row_bytes=row)
+            if k == 0:
+                self._m_paged_walks.inc(int(shares.walks()))
+            seen, fetched = shares.blocks()
+            self._m_paged_seen.inc(layers * int(seen))
+            self._m_paged_fetched.inc(layers * int(fetched))
+
+    def _note_state_rows(self, active, spans, n_pre: int, starts: int,
+                         pre, pre_pos) -> None:
+        """The state sweep's rows and runs of one launched tick, by the
+        program's own rule (ops/retention.tick_runs): a decode row is a run
+        of one, a request's prompt rows (``pre``, ``pre_pos``) one run
+        however many chunks they fill, and a run at position 0 starts on
+        zero (``starts``: the decode rows that do)."""
+        runs = {id(r): start for r, start, _ in reversed(spans)}
+        self._m_state["rows"].inc(len(active) + n_pre)
+        self._m_state["touches"].inc(len(active) + len(runs))
+        self._m_state["resets"].inc(
+            sum(start == 0 for start in runs.values()) + starts)
+        steps = len(active) + n_pre
+        if self.spool.sweep_steps is not None:
+            # the tick's rows as the program lays them out: a decode
+            # row a slot, the prompt rows behind them, each request's
+            # at consecutive positions
+            row_slots = np.zeros((self.max_slots,), np.int32)
+            row_slots[active] = 1 + np.asarray(active, np.int32)
+            steps = self.spool.sweep_steps(
+                np.concatenate([row_slots, np.where(
+                    pre >= 0, 1 + self.max_slots + pre, 0)]),
+                np.concatenate([np.zeros_like(row_slots), pre_pos]))
+        self._m_state["steps"].inc(steps)
 
     def _row_live(self, rec: _Launched, k: int) -> bool:  # holds _lock
         """Whether row ``k`` of a launch in flight is still its request's:
@@ -3045,22 +2890,8 @@ class ContinuousBatchingEngine:
             print(f"[engine] {obs_compiles.summary()}", flush=True)
         return emitted
 
-    # A program's first call traces, lowers and compiles (or loads) it on
-    # the scheduler thread while every open stream waits: a ``tick-program``
-    # start-up phase around that call alone, so a tick whose program exists
-    # pays one dictionary lookup.  The two stand BELOW the launch: the line
-    # of the tick's call there is in the compile-cache key of the cells
-    # whose kernel sits nine frames under it (tools/tick_digest.py,
-    # ``callers``), so nothing above that line is added to.
-
-    def _tick_program(self, pre_rows: int):
-        new = pre_rows not in self._ragged_fns
-        fn = self._ragged_tick(pre_rows)
-        return obs_compiles.startup_phase(
-            "tick-program", rows=pre_rows)(fn) if new else fn
-
-    # What a block model (generation/blocks.py) asks of admission, here for
-    # the same reason; a causal model takes the first branch of each.
+    # What a block model (generation/blocks.py) asks of admission; a causal
+    # model takes the first branch of each.
 
     def _new_request(self, prompt, max_new_tokens: int,
                      kw: dict) -> EngineRequest:
@@ -3089,12 +2920,6 @@ class ContinuousBatchingEngine:
             return self._blocks.parkable_pages(req, seq)
         return (len(seq) - 1) // self.page_size
 
-    def _chunk_program(self, rows: int, kv_pages: int):
-        new = (rows, kv_pages) not in self._chunk_fns
-        fn = self._score_chunk(rows, kv_pages)
-        return obs_compiles.startup_phase(
-            "tick-program", rows=rows, kv_pages=kv_pages)(fn) if new else fn
-
     def _note_seq_pages_locked(self) -> None:  # holds _lock
         """Once an applied tick: slide the decoding sequences' windows up
         to the position their next query stands at (the tick in flight
@@ -3102,7 +2927,7 @@ class ContinuousBatchingEngine:
         they then hold, a class, to ``mlt_engine_seq_pages_sum``."""
         live = [r for r in self._slots
                 if r is not None and r._phase == "decode"]
-        if self.wpool is not None:
+        if any(cls.window for cls in self._classes):
             released = sum(
                 self._slide_locked(r, len(r.prompt) + len(r.generated) - 1)
                 for r in live)
@@ -3111,13 +2936,9 @@ class ContinuousBatchingEngine:
                     pass
         if live and obs_registry.publishing():
             self._m_seq_ticks.inc(len(live))
-            self._m_seq_pages[self.pool.page_class or "full"].inc(
-                sum(len(r._pages) for r in live))
-            if self.wpool is not None:
-                self._m_seq_pages[self.wpool.page_class].inc(
-                    sum(len(r._wpages) - r._wfirst for r in live))
-            if self._stables is not None:
-                self._m_seq_pages[self.spool.page_class].inc(len(live))
+            for k, (cls, m) in enumerate(zip(self._classes,
+                                             self._m_seq_pages)):
+                m.inc(sum(cls.held(r._mem[k]) for r in live))
 
     def run_until_idle(self) -> None:
         """Drive ticks on the calling thread until queue and slots drain.
@@ -3418,7 +3239,7 @@ class ContinuousBatchingEngine:
         try:
             with self._drive_lock:
                 with self._lock:
-                    pages = list(req._pages[:n])
+                    pages = list(req._mem[0].pages[:n])
                 leaves = self.pool.export_pages(pages)
             blob = wire.encode_pages(ids[: len(pages) * ps], ps,
                                      self.kv_dtype, leaves)

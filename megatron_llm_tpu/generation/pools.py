@@ -10,18 +10,26 @@ and the prefix trie, below the scheduler (generation/engine.py).
   page pool of latent rows or K/V pages).
 * :class:`PrefixCache` — the host-side radix trie over page-aligned token
   chunks, and the eviction order of its idle pages.
+* :class:`ClassMemory` — what the engine holds of ONE class: the pool, the
+  host mirror of the tick's table, the commitment ledger, and admit, grant,
+  slide and release of what one sequence holds of it (:class:`SeqMemory`).
 * :func:`refuse_unserved` — what each kind of per-sequence memory does not
   carry yet, as ONE table (:data:`NOT_CARRIED`), said at start-up in a
   sentence.
 
-Nothing here schedules: which request gets a page when, the commitment
-ledger, sliding a window, preemption are the engine's.  This module imports
-nothing from ``generation/engine.py``, ``generation/server.py`` or
+A class owns HOW a sequence's memory is granted, slid and released, and
+its ledger; the engine owns WHEN (admission, preemption, the order of
+prefill, which row fails when a ledger is violated) and the lock: every
+method here is called with the engine's ``_lock`` held and takes none.  A
+further kind of memory is a pool class here and its rows of
+:data:`NOT_CARRIED`.  This module imports nothing from
+``generation/engine.py``, ``generation/server.py`` or
 ``generation/scheduling/`` (tests/test_pools_seam.py).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import heapq
 import itertools
 import time
@@ -826,6 +834,176 @@ class StatePool(PagedKVPool):
         return 0
 
 
+@dataclasses.dataclass
+class SeqMemory:
+    """What ONE sequence holds of ONE class.  ``pages``: a block's page at
+    the block's place, ``NULL_PAGE`` where a window has moved past it
+    (``first`` blocks so far); a state class's one slot.  Blocks below
+    ``keep`` are the prefix cache's, the rest the sequence's own:
+    ``private`` of them live, never more than ``max`` (what the ledger
+    holds for it).  ``row``: its row of the class's table, -1 while the
+    sequence waits or prefills."""
+
+    pages: List[int] = dataclasses.field(default_factory=list)
+    first: int = 0
+    keep: int = 0
+    private: int = 0
+    max: int = 0
+    row: int = -1
+
+
+class ClassMemory:
+    """What the engine holds of ONE class: ``committed`` is what the
+    admitted sequences may still take beyond their own pages (the sum of
+    ``max - private``), and admission keeps ``free + evictable >=
+    committed + watermark``, so a grant to a sequence in flight cannot
+    fail.  ``window``: the keys a query sees (0: every one); such a class
+    takes its prompt's pages a tick's rows at a time, never more of its
+    own than ``cap``, and gives a page back once the window has moved past
+    it.  A class without one takes the prompt's pages at admission and a
+    page a boundary a decode row crosses.  A state class takes ONE slot,
+    once: its table is one entry wide and its ledger keeps no slack."""
+
+    def __init__(self, pool: PagedKVPool, slots: int, width: int, *,
+                 window: int = 0, cap: Optional[int] = None,
+                 watermark: int = 0):
+        self.pool = pool
+        self.name = pool.page_class or "full"
+        self.state = isinstance(pool, StatePool)
+        self.window = window
+        self.width = 1 if self.state else width
+        self.cap = self.width if cap is None else cap
+        self.watermark = 0 if self.state else watermark
+        self.table = np.zeros((slots, self.width), np.int32)
+        self.committed = 0
+
+    # ---- admission ----
+
+    def demand(self, keep: int, cow: int, fill: int,
+               total: int) -> Tuple[int, int]:
+        """(pages granted at admission, the most of its own it ever holds)
+        for a sequence of ``total`` pages whose first ``keep`` are shared:
+        the copy-on-write page (``cow``, 0 or 1) and, without a window, the
+        ``fill`` pages of its prompt and first decode rows."""
+        if self.state:
+            return 1, 1
+        most = min(self.cap, total - keep)
+        return (cow if self.window else min(cow + fill, most)), most
+
+    def can_admit(self, keep: int, cow: int, fill: int, total: int) -> bool:
+        """Whether the pool and the ledger allow such a :meth:`demand`."""
+        need, most = self.demand(keep, cow, fill, total)
+        return (self.pool.num_available - need
+                >= self.committed + most - need + self.watermark)
+
+    def admit(self, matched: Sequence[int], keep: int, cow: int, fill: int,
+              total: int) -> SeqMemory:
+        """The ``matched`` pages (the prefix cache's, the caller holds their
+        references) and what :meth:`demand` grants now, fresh, the rest
+        booked in the ledger.  After :meth:`can_admit`."""
+        need, most = self.demand(keep, cow, fill, total)
+        fresh = self.pool.alloc(need)
+        assert fresh is not None, "can_admit() holds the ledger"
+        self.committed += most - need
+        first = 0       # the blocks a matched window had already left
+        while first < len(matched) and matched[first] == NULL_PAGE:
+            first += 1
+        return SeqMemory(list(matched) + fresh, first, keep, need, most)
+
+    def undo(self, matched: Sequence[int]) -> None:
+        """A refused admission gives its matched references back."""
+        self.pool.release([p for p in matched if p != NULL_PAGE])
+
+    def drop_shared(self, mem: SeqMemory) -> None:
+        """The copy-on-write copy (the page after it) has landed."""
+        self.pool.release([mem.pages.pop(mem.keep)])
+
+    # ---- while the sequence lives ----
+
+    def grant(self, mem: SeqMemory, last_block: int) -> Optional[int]:
+        """A page of the sequence's own, off the ledger, for every block up
+        to ``last_block`` it holds none for yet; how many those were, None
+        where the pool cannot (ledger-unreachable)."""
+        last, had = min(last_block, self.width - 1), len(mem.pages)
+        while len(mem.pages) <= last:
+            got = self.pool.alloc(1)
+            if got is None:
+                return None
+            mem.pages.append(got[0])
+            mem.private += 1
+            self.committed -= 1
+        if mem.row >= 0 and len(mem.pages) > had:
+            self.table[mem.row, had:len(mem.pages)] = mem.pages[had:]
+        return len(mem.pages) - had
+
+    def slide(self, mem: SeqMemory, qpos: int) -> int:
+        """No query at ``qpos`` or later sees a key of the blocks before the
+        one holding key ``qpos - window + 1``: their pages go back (those
+        the prefix cache registered stay cached-idle) and the ledger holds
+        a page again for each of the sequence's own.  A tick already
+        launched may still read them: it runs before any tick that writes
+        what a later grant makes of them.  Returns the pages released."""
+        if not self.window:
+            return 0
+        lo = mem.first
+        first = min(max(0, qpos - self.window + 1) // self.pool.page_size,
+                    len(mem.pages))
+        if first <= lo:
+            return 0
+        gone, own = [], 0
+        for i in range(lo, first):
+            p = mem.pages[i]
+            if p != NULL_PAGE:
+                gone.append(p)
+                own += i >= mem.keep
+                mem.pages[i] = NULL_PAGE
+        mem.first = first
+        mem.private -= own
+        self.committed += own
+        self.pool.release(gone)
+        if mem.row >= 0:
+            # the mirror only: the row's queries start behind these blocks,
+            # and the next upload anything else asks for carries the nulls
+            self.table[mem.row, lo:first] = NULL_PAGE
+        return len(gone)
+
+    def held(self, mem: SeqMemory) -> int:
+        """Pages the sequence holds, whole window."""
+        return len(mem.pages) - mem.first
+
+    def trim(self, mem: SeqMemory) -> None:
+        """It takes no further page: the ledger's rest for it returns."""
+        self.committed -= mem.max - mem.private
+        mem.max = mem.private
+
+    def release(self, mem: SeqMemory) -> int:
+        """Every page the sequence holds goes back, with what the ledger
+        still held for it; returns how many pages those were."""
+        pages = [p for p in mem.pages if p != NULL_PAGE]
+        self.trim(mem)
+        self.pool.release(pages)
+        mem.pages, mem.row = [], -1
+        mem.first = mem.keep = mem.private = mem.max = 0
+        return len(pages)
+
+    # ---- the slot's table row ----
+
+    def install(self, slot: int, mem: SeqMemory) -> None:
+        self.table[slot] = NULL_PAGE
+        self.table[slot, :len(mem.pages)] = mem.pages
+        mem.row = slot
+
+    def clear(self, slot: int) -> None:
+        self.table[slot] = NULL_PAGE
+
+    def snapshot(self, dead: Sequence[int] = ()) -> np.ndarray:
+        """A copy for a tick (the mirror moves again before the tick that
+        reads the upload has run), the ``dead`` rows null."""
+        table = self.table.copy()
+        table[list(dead)] = NULL_PAGE
+        return table
+
+
 class _TrieNode:
     __slots__ = ("key", "page", "wpage", "parent", "children", "last_use",
                  "depth")
@@ -999,6 +1177,18 @@ class PrefixCache:
         self.pool.incref(pages)
         self.wpool.incref(wpages)
         return pages, [NULL_PAGE] * first + wpages
+
+    def match_lists(self, tokens: Sequence[int],
+                    max_pages: int) -> List[List[int]]:
+        """:meth:`match` as ONE list a page class, in the pools' order."""
+        if self.wpool is None:
+            return [self.match(tokens, max_pages)]
+        return list(self.match_classes(tokens, max_pages))
+
+    def insert_lists(self, tokens: Sequence[int],
+                     lists: Sequence[Sequence[int]], n_pages: int) -> int:
+        """:meth:`insert` of one list a page class."""
+        return self.insert(tokens, lists[0], n_pages, *lists[1:])
 
     def insert(self, tokens: Sequence[int], pages: Sequence[int],
                n_pages: int, wpages: Optional[Sequence[int]] = None) -> int:

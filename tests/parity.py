@@ -129,3 +129,63 @@ def assert_greedy_match_dense(cfg, params, jobs, reqs) -> int:
                 ref_lp[:first], rtol=0, atol=DENSE_ATOL,
                 err_msg=f"request {i}: prompt log-probs")
     return n
+
+
+# ---- the engine's per-sequence memory, a class ------------------------------
+
+def held_pages(req) -> list:
+    """Every page or slot ``req`` holds, all classes together."""
+    return [p for m in req._mem for p in m.pages if p]
+
+
+def assert_memory(eng) -> None:
+    """Between two steps, for every class of per-sequence memory
+    (``zip(eng._classes, req._mem)``): a page is referenced once a holder,
+    free, cached-idle and referenced pages are disjoint and make up the
+    pool, the kept idle count is the walk's; a record's nulls lie behind
+    ``first``, ``private`` counts its own live pages and stays under
+    ``max``; the ledger is the sum of ``max - private`` and the pool can
+    serve it; an installed row of the class's table mirrors its record."""
+    from collections import Counter
+
+    for r in list(eng._queue):           # a queued request holds nothing
+        assert not held_pages(r)
+    live = [r for r in eng._slots if r is not None]
+    for k, cls in enumerate(eng._classes):
+        pool, mems = cls.pool, [r._mem[k] for r in live]
+        holders = Counter(p for m in mems for p in m.pages if p)
+        free = set(pool._free)
+        assert len(free) == pool.num_free and 0 not in free
+        for p in range(1, pool.num_pages):
+            assert pool.refcounts[p] == holders.get(p, 0), (cls.name, p)
+        referenced = set(holders)
+        idle = {p for p in pool.cached if pool.refcounts[p] == 0}
+        assert not free & referenced and not free & pool.cached
+        assert len(free) + len(referenced) + len(idle) == pool.num_pages - 1
+        assert pool.num_evictable == len(idle)     # the kept count is the walk
+        own = 0
+        for m in mems:
+            assert not any(m.pages[:m.first])
+            assert m.private == sum(p != 0 for p in m.pages[m.keep:])
+            assert m.private <= m.max <= cls.cap
+            assert len(m.pages) <= cls.width
+            own += m.max - m.private
+            if m.row >= 0:
+                assert eng._slots[m.row]._mem[k] is m
+                assert list(cls.table[m.row, :len(m.pages)]) == m.pages
+                assert not cls.table[m.row, len(m.pages):].any()
+        assert cls.committed == own          # the ledger is what it says
+        assert pool.num_available >= cls.committed
+        rows = {m.row for m in mems if m.row >= 0}
+        for slot in range(eng.max_slots):
+            assert slot in rows or not cls.table[slot].any()
+
+
+def assert_memory_idle(eng) -> None:
+    """Nothing in flight: every ledger at zero, every table null, every
+    page free or cached-idle."""
+    for cls in eng._classes:
+        assert cls.committed == 0 and not cls.table.any()
+        assert (cls.pool.num_free + cls.pool.num_evictable
+                == cls.pool.num_pages - 1)
+        assert not cls.pool.refcounts.any()

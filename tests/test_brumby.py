@@ -16,7 +16,6 @@ from benchmark.reference import common as ref_common
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation import generation as gen
 from megatron_llm_tpu.generation.pools import (
-    NULL_PAGE,
     StatePool,
     refuse_unserved,
 )
@@ -26,6 +25,7 @@ from megatron_llm_tpu.models.transformer import pool_classes
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.ops import retention as ret
 from megatron_llm_tpu.ops.pallas import retention as ret_kernel
+from tests.parity import assert_memory, assert_memory_idle, held_pages
 
 ATOL = 5e-5
 VOCAB = 256
@@ -216,7 +216,8 @@ def _assert_idle(eng):
     pool = eng.pool
     assert isinstance(pool, StatePool) and eng.cache is None
     assert pool.num_free == eng.max_slots and not pool.refcounts.any()
-    assert (eng._block_tables == NULL_PAGE).all() and eng._committed == 0
+    assert [cls.width for cls in eng._classes] == [1]
+    assert_memory_idle(eng)
 
 
 def test_engine_matches_reference_and_reuses_slots(model):
@@ -271,7 +272,9 @@ def test_preempted_and_resumed_matches_never_preempted(model):
     req = eng.submit(p, 30, top_k=1, termination_id=NEVER)
     while len(req.generated) < 11:
         eng.step()
-    assert eng.preempt(req) and req._phase == "queued" and not req._pages
+    assert eng.preempt(req) and req._phase == "queued"
+    assert not held_pages(req)
+    assert_memory(eng)
     done = len(req.generated)
     eng.run_until_idle()
     check(req, params)

@@ -6,8 +6,6 @@ sigmoid-routed experts with four averaged shared experts, served through
 the experts held.  Everything is compared with the plain reference
 (``benchmark/reference/commanda_block.py``) on the same weights."""
 
-from collections import Counter
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +24,7 @@ from megatron_llm_tpu.generation.pools import (
 from megatron_llm_tpu.models import init_model_params, make_config, moe
 from megatron_llm_tpu.models.language_model import model_forward
 from megatron_llm_tpu.models.transformer import pool_classes
+from tests.parity import assert_memory, assert_memory_idle, held_pages
 
 ATOL = 3e-5
 VOCAB = 256
@@ -245,25 +244,11 @@ def test_planted_faults_fail_the_reference(share, fault):
 
 # ---- the pool's invariants, a class -------------------------------------------
 
-def _assert_class(eng, pool, held_of):
-    holders = Counter(p for r in eng._slots if r is not None
-                      for p in held_of(r) if p != NULL_PAGE)
-    for r in list(eng._queue):           # a queued request holds nothing
-        assert not r._pages and not r._wpages
-    free = set(pool._free)
-    assert len(free) == pool.num_free and NULL_PAGE not in free
-    for p in range(1, pool.num_pages):
-        assert pool.refcounts[p] == holders.get(p, 0), (pool.page_class, p)
-    referenced = set(holders)
-    idle = {p for p in pool.cached if pool.refcounts[p] == 0}
-    assert not free & referenced and not free & pool.cached
-    assert len(free) + len(referenced) + len(idle) == pool.num_pages - 1
-    assert pool.num_evictable == len(idle)         # the kept count is the walk
-
-
 def _assert_classes(eng):
-    _assert_class(eng, eng.pool, lambda r: r._pages)
-    _assert_class(eng, eng.wpool, lambda r: r._wpages)
+    assert_memory(eng)            # every class: tests/parity.py
+    full, win = eng._classes
+    assert (full.pool, win.pool) == (eng.pool, eng.wpool)
+    assert win.window == WINDOW and win.cap == eng.window_pages_cap
     if eng.cache is not None:
         assert set(eng.cache._nodes) == eng.pool.cached
         assert set(eng.cache._wnodes) == eng.wpool.cached
@@ -272,28 +257,16 @@ def _assert_classes(eng):
             # a window page referenced: so is its block's full page
             assert (eng.wpool.refcounts[wp] == 0
                     or eng.pool.refcounts[node.page] > 0)
-    own = 0
     for r in eng._slots:
         if r is None:
             continue
-        live = [p for p in r._wpages if p != NULL_PAGE]
+        live = [p for p in r._mem[1].pages if p != NULL_PAGE]
         assert len(live) <= eng.window_pages_cap
         if r._phase == "decode":
             assert len(live) <= -(-WINDOW // PAGE) + 2
-        assert all(p == NULL_PAGE for p in r._wpages[:r._wfirst])
-        assert r._wprivate == sum(p != NULL_PAGE
-                                  for p in r._wpages[r._wkeep:])
-        own += r._wmax - r._wprivate
-        assert r._wprivate <= r._wmax
-    assert eng._wcommitted == own          # the ledger is what it says
-    assert eng.wpool.num_available >= eng._wcommitted
 
 
-def _assert_idle(eng):
-    assert eng._committed == 0 and eng._wcommitted == 0
-    for pool in (eng.pool, eng.wpool):
-        assert pool.num_free + pool.num_evictable == pool.num_pages - 1
-        assert not pool.refcounts.any()
+_assert_idle = assert_memory_idle
 
 
 @pytest.mark.parametrize("seed", [0, 1])
@@ -328,7 +301,7 @@ def test_pool_invariants_hold_step_by_step(share, seed):
             victim = next((r for r in eng._slots
                            if r is not None and r._phase == "decode"), None)
             if victim is not None and eng.preempt(victim):
-                assert not victim._pages and not victim._wpages
+                assert not held_pages(victim)
                 _assert_classes(eng)
         if n == 0 and not eng._queue and all(r is None for r in eng._slots):
             break
@@ -347,7 +320,7 @@ def test_preempted_and_resumed_matches_reference(share):
     req = eng.submit(p, 30, top_k=1, termination_id=NEVER)
     while len(req.generated) < 12:
         eng.step()
-    assert eng.preempt(req) and not req._wpages
+    assert eng.preempt(req) and not held_pages(req)
     eng.run_until_idle()
     tokens, lps = req.result(timeout=60)
     assert req._preemptions == 1 and eng.prefix_hit_tokens > 0
@@ -411,13 +384,13 @@ def test_a_uniform_model_keeps_one_class():
     eng = ContinuousBatchingEngine(
         cfg, init_model_params(cfg, jax.random.PRNGKey(0)), max_slots=2,
         page_size=PAGE, max_seq=128)
-    assert eng.wpool is None and eng._wtables is None
+    assert eng.wpool is None and len(eng._classes) == 1
     assert eng._class_statics == () and eng.pool.page_class is None
     assert eng._kv is eng.pool.kv and eng.pool.kv.shape[0] == 2
     req = eng.submit(prompts(70)[0], 8, top_k=1, termination_id=NEVER)
     eng.run_until_idle()
     assert req.result(timeout=60) and eng.window_pages_released == 0
-    assert not req._wpages and eng._wcommitted == 0
+    assert not held_pages(req) and eng._classes[0].committed == 0
 
 
 # ---- what two classes do not carry yet says so ----------------------------------

@@ -20,7 +20,6 @@ from benchmark.reference import common as ref_common
 from benchmark.reference import gigachat35_block as ref
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation.pools import (
-    NULL_PAGE,
     PagedKVPool,
     StatePool,
     memory_kind,
@@ -39,6 +38,7 @@ from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.ops import gated_delta as gd
 from megatron_llm_tpu.ops import norms, rope
 from megatron_llm_tpu.ops.retention import tick_runs
+from tests.parity import assert_memory, assert_memory_idle, held_pages
 
 # float32 rounding: the program sums a state's part and a run's part (the
 # chunked form, the tick's runs) where the reference walks token by token,
@@ -572,8 +572,8 @@ def _assert_idle(eng):
     assert eng.spool.num_free == eng.max_slots
     assert eng.pool.num_free == eng.pool.num_pages - 1
     assert not eng.pool.refcounts.any() and not eng.spool.refcounts.any()
-    assert (eng._block_tables == NULL_PAGE).all()
-    assert (eng._stables == NULL_PAGE).all() and eng._committed == 0
+    assert [cls.width for cls in eng._classes] == [eng.pages_per_seq, 1]
+    assert_memory_idle(eng)
 
 
 def test_engine_matches_reference_through_both_pools(model):
@@ -593,7 +593,10 @@ def test_engine_matches_reference_through_both_pools(model):
              for p in prompts(100, 37)]
     eng.step()
     held = [r for r in first if r._phase != "queued"]
-    assert held and all(len(r._state) == 1 and r._pages for r in held)
+    # pages AND a slot: one record a class, the slot's of one entry
+    assert held and all(r._mem[0].pages and len(r._mem[1].pages) == 1
+                        for r in held)
+    assert_memory(eng)
     eng.run_until_idle()
     later = [eng.submit(p, 12, top_k=1, termination_id=NEVER)
              for p in prompts(53, 1, 18, 70, seed=2)]
@@ -626,7 +629,8 @@ def test_preempted_and_recomputed_matches_never_preempted(model):
     while len(req.generated) < 11:
         eng.step()
     assert eng.preempt(req) and req._phase == "queued"
-    assert not req._pages and not req._state
+    assert not held_pages(req)
+    assert_memory(eng)
     assert eng.spool.num_free == eng.max_slots
     done = len(req.generated)
     eng.run_until_idle()

@@ -1012,7 +1012,7 @@ def test_lock_rule_verifies_engine_annotations():
                 and node.name == "ContinuousBatchingEngine":
             model = rule._build(ctx, node)
             assert model is not None
-            assert {"_queue", "_slots", "_committed",
+            assert {"_queue", "_slots", "_classes",
                     "_stopping"} <= set(model.guards)
             assert "_retire" in model.holds
             assert "_work" in model.groups.get("_lock", set())

@@ -21,7 +21,6 @@ from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation.pools import (
     KEEPS,
     NOT_CARRIED,
-    NULL_PAGE,
     PagedKVPool,
     StatePool,
     memory_kind,
@@ -38,6 +37,7 @@ from megatron_llm_tpu.models.transformer import (
 )
 from megatron_llm_tpu.observability import registry as obs_registry
 from megatron_llm_tpu.ops import gated_delta as gd
+from tests.parity import assert_memory, assert_memory_idle, held_pages
 
 # float32 rounding: the program's fused QKV and grouped expert GEMMs sum in
 # another order than the reference's plain products, at log-probs of
@@ -410,8 +410,8 @@ def _assert_idle(eng):
     assert eng.spool.num_free == eng.max_slots
     assert eng.pool.num_free == eng.pool.num_pages - 1
     assert not eng.pool.refcounts.any() and not eng.spool.refcounts.any()
-    assert (eng._block_tables == NULL_PAGE).all()
-    assert (eng._stables == NULL_PAGE).all() and eng._committed == 0
+    assert [cls.width for cls in eng._classes] == [eng.pages_per_seq, 1]
+    assert_memory_idle(eng)
 
 
 def test_engine_matches_reference_through_pages_and_tail_slots(model):
@@ -433,7 +433,10 @@ def test_engine_matches_reference_through_pages_and_tail_slots(model):
              for p in prompts(100, 37)]
     eng.step()
     held = [r for r in first if r._phase != "queued"]
-    assert held and all(len(r._state) == 1 and r._pages for r in held)
+    # pages AND a slot: one record a class, the slot's of one entry
+    assert held and all(r._mem[0].pages and len(r._mem[1].pages) == 1
+                        for r in held)
+    assert_memory(eng)
     eng.run_until_idle()
     later = [eng.submit(p, 12, top_k=1, termination_id=NEVER)
              for p in prompts(53, 1, 18, 70, seed=2)]
@@ -472,7 +475,8 @@ def test_preempted_and_recomputed_matches_never_preempted(model):
     while len(req.generated) < 11:
         eng.step()
     assert eng.preempt(req) and req._phase == "queued"
-    assert not req._pages and not req._state
+    assert not held_pages(req)
+    assert_memory(eng)
     assert eng.spool.num_free == eng.max_slots
     done = len(req.generated)
     eng.run_until_idle()

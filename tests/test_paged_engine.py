@@ -427,7 +427,7 @@ def test_block_table_alloc_free_stress(toy_model):
     while True:
         n = eng.step()
         steps += 1
-        held = [p for r in eng._slots if r is not None for p in r._pages]
+        held = [p for r in eng._slots if r is not None for p in r._mem[0].pages]
         assert all(p != 0 for p in held), "null page allocated"
         # refcount-exact accounting (the PR-5 three-state page model):
         # every page is free XOR referenced XOR cached-idle, and refcounts

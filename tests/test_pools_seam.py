@@ -208,3 +208,165 @@ def test_the_engine_re_exports_the_pools_own_classes():
     assert [n for n in dir(pools) if n.startswith("refuse_")] == [
         "refuse_unserved"]
     assert not [n for n in defined if n.startswith("refuse_")]
+
+
+# ---- one per-sequence memory a class (PR 61) --------------------------------
+
+def test_the_scheduler_names_no_kind_of_memory():
+    """The engine and the block driver hold ``req._mem`` and
+    ``engine._classes`` and loop over them: no list, table or ledger of one
+    kind of memory has a name there, and the pools' own names (``wpool``,
+    ``spool``) stay where they are built, in a compiled program's key and
+    in the state sweep's counters."""
+    from megatron_llm_tpu.generation import blocks, engine
+
+    gone = {"_pages", "_max_pages", "_wpages", "_wfirst", "_wkeep",
+            "_wprivate", "_wmax", "_wtables", "_stables", "_block_tables",
+            "_committed", "_wcommitted", "_state_only",
+            "_grant_window_locked"}
+    may_name_pools = {"__init__", "_class_statics", "_note_state_rows"}
+    for mod in (engine, blocks):
+        tree = ast.parse(open(mod.__file__).read())
+        names = {n.attr for n in ast.walk(tree)
+                 if isinstance(n, ast.Attribute)} | {
+            n.id for n in ast.walk(tree) if isinstance(n, ast.Name)} | {
+            n.name for n in ast.walk(tree)
+            if isinstance(n, (ast.FunctionDef, ast.ClassDef))}
+        assert {"_mem", "_classes"} <= names, "the walk is broken"
+        assert not names & gone, (mod.__name__, names & gone)
+        for fn in ast.walk(tree):
+            if not isinstance(fn, ast.FunctionDef) \
+                    or fn.name in may_name_pools:
+                continue
+            named = {n.attr for n in ast.walk(fn)
+                     if isinstance(n, ast.Attribute)} & {"wpool", "spool"}
+            assert not named, (mod.__name__, fn.name, named)
+    fields = {f.name for f in
+              __import__("dataclasses").fields(engine.EngineRequest)}
+    assert "_mem" in fields and not fields & (gone | {"_state"})
+
+
+def test_a_window_class_and_a_state_class_together_on_the_host():
+    """What no engine serves yet, at the pools' level: ONE sequence holds a
+    windowed page class AND a state class, through admission, grants past
+    the window, slides, a preemption's release and a re-admission, every
+    step under the invariants the engines' suites hold (tests/parity.py);
+    and a grant that one class refuses is refused whole."""
+    import types
+
+    from megatron_llm_tpu.generation.pools import (
+        ClassMemory,
+        PagedKVPool,
+        StatePool,
+    )
+    from tests.parity import assert_memory, assert_memory_idle
+
+    page, window, width, cap = 8, 16, 8, 5
+    win = ClassMemory(
+        PagedKVPool(KINDS["paged"](), 12, page, layers=1,
+                    page_class="window"),
+        2, width, window=window, cap=cap)
+    st = ClassMemory(StatePool(KINDS["state"](), 1, page, layers=1,
+                               page_class="state"), 2, 1)
+    classes = [win, st]
+    eng = types.SimpleNamespace(_classes=classes, _queue=[], max_slots=2,
+                                _slots=[None, None])
+
+    def admit(keep=0, cow=0, fill=4, total=width):
+        want = (keep, cow, fill, total)
+        if not all(cls.can_admit(*want) for cls in classes):
+            return None
+        return types.SimpleNamespace(_mem=[
+            cls.admit([], *want) for cls in classes])
+
+    for _ in range(2):          # admitted, preempted, admitted again
+        req = admit()
+        eng._slots[0] = req
+        wmem, smem = req._mem
+        # the window takes nothing at admission, the state its one slot
+        assert (wmem.pages, wmem.max, len(smem.pages)) == ([], cap, 1)
+        assert (win.committed, st.committed) == (cap, 0)
+        assert_memory(eng)
+        # a second sequence: the window class could, the state class has
+        # no slot left, so neither grants
+        assert win.can_admit(0, 0, 4, width)
+        assert admit() is None and win.committed == cap
+        # the prompt's rows, a tick's worth at a time, its rows not yet in
+        # the table
+        for last in (1, 2):
+            assert [cls.grant(m, last) for cls, m in zip(classes, req._mem)
+                    ] == [2 if last == 1 else 1, 0]
+            assert_memory(eng)
+        assert not win.table.any() and not st.table.any()
+        for cls, m in zip(classes, req._mem):
+            cls.install(0, m)
+        assert_memory(eng)
+        released = 0
+        for pos in range(3 * page, width * page):
+            released += sum(cls.slide(m, pos)
+                            for cls, m in zip(classes, req._mem))
+            granted = [cls.grant(m, pos // page)
+                       for cls, m in zip(classes, req._mem)]
+            assert granted == [int(pos % page == 0), 0]
+            assert_memory(eng)
+            assert win.held(wmem) <= -(-window // page) + 1 <= cap
+            assert st.held(smem) == 1
+            # the row mirrors the record: nulls behind the window
+            assert not win.table[0, :wmem.first].any()
+        assert released == wmem.first == (width * page - window) // page
+        assert win.grant(wmem, 10 ** 6) == 0       # never past the table
+        held = [cls.held(m) for cls, m in zip(classes, req._mem)]
+        assert held == [width - released, 1]
+        eng._slots[0] = None
+        for cls, m in zip(classes, req._mem):
+            cls.clear(0)
+        assert [cls.release(m)
+                for cls, m in zip(classes, req._mem)] == held
+        assert not any(m.pages for m in req._mem)
+        assert_memory(eng)
+        assert_memory_idle(eng)
+
+
+def test_a_state_slot_is_granted_whole_whatever_the_watermark():
+    """``--page_watermark`` is slack for pages a sequence in flight still
+    takes.  A state class takes ONE slot, once, at admission (an explicit
+    demand of ``(1, 1)``, whatever the prompt fills), so its ledger stays
+    at zero and it keeps no slack: every slot can be admitted to, alone or
+    beside a page class, which does keep its watermark."""
+    from megatron_llm_tpu.generation.pools import (
+        ClassMemory,
+        PagedKVPool,
+        StatePool,
+    )
+
+    slots, page, width = 3, 8, 4
+    st = ClassMemory(StatePool(KINDS["state"](), slots, page, layers=1,
+                               page_class="state"),
+                     slots, width, watermark=2)
+    assert (st.state, st.width, st.cap, st.watermark) == (True, 1, 1, 0)
+    assert st.table.shape == (slots, 1)
+    mems = []
+    for fill in (1, 4, 0):      # what the prompt fills is a page class's
+        assert st.demand(0, 0, fill, width) == (1, 1)
+        assert st.can_admit(0, 0, fill, width)
+        mems.append(st.admit([], 0, 0, fill, width))
+        assert (len(mems[-1].pages), mems[-1].private, mems[-1].max,
+                st.committed) == (1, 1, 1, 0)
+    assert len({m.pages[0] for m in mems}) == slots
+    assert not st.can_admit(0, 0, 1, width)         # every slot is taken
+    assert st.grant(mems[0], 10 ** 6) == 0          # and never a second
+    assert [st.release(m) for m in mems] == [1] * slots
+    assert st.can_admit(0, 0, 1, width) and st.committed == 0
+
+    # a page class under the same watermark refuses the sequence that
+    # would leave fewer than ``watermark`` pages beyond the ledger
+    pg = ClassMemory(PagedKVPool(KINDS["paged"](), 1 + 2 * width + 1, page,
+                                 layers=1, page_class="full"),
+                     slots, width, watermark=2)
+    assert (pg.state, pg.width, pg.watermark) == (False, width, 2)
+    a = pg.admit([], 0, 0, 2, width)
+    assert pg.committed == width - 2
+    assert pg.can_admit(0, 0, 2, width - 1)          # 7 - 2 >= 2 + 1 + 2
+    assert not pg.can_admit(0, 0, 2, width)          # 7 - 2 <  2 + 2 + 2
+    pg.release(a)
+    assert pg.can_admit(0, 0, 2, width) and pg.committed == 0

@@ -151,7 +151,7 @@ def test_engine_pp_preempt_resume(eight_devices, toy_params):
     while len(req.generated) < 9:
         eng.step()
     assert eng.preempt(req)
-    assert req._phase == "queued" and not req._pages
+    assert req._phase == "queued" and not req._mem[0].pages
     eng.run_until_idle()
     assert req.result()[0] == t_ref
     np.testing.assert_allclose(list(req.log_probs), lp_ref, atol=5e-6)

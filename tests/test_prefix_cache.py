@@ -189,7 +189,7 @@ def _assert_page_states(eng):
 
     pool = eng.pool
     holders = Counter(p for r in eng._slots if r is not None
-                      for p in r._pages)
+                      for p in r._mem[0].pages)
     free = set(pool._free)
     assert NULL_PAGE not in free and holders.get(NULL_PAGE, 0) == 0
     for p in range(1, pool.num_pages):
@@ -259,7 +259,7 @@ def test_refcount_invariants_under_shared_stress(toy_model):
         from collections import Counter
 
         holders = Counter(p for r in eng._slots if r is not None
-                          for p in r._pages)
+                          for p in r._mem[0].pages)
         if any(c > 1 for c in holders.values()):
             saw_sharing = True
         if n == 0 and not eng._queue:
@@ -293,8 +293,7 @@ def test_eviction_under_pressure_admits_instead_of_starving(toy_model):
     parked = set(eng.pool.cached)
     free_before = eng.pool.num_free
     # worst case 7 pages > free list, but free + evictable covers it
-    need = eng._max_pages_for(
-        type("R", (), {"prompt": [0] * 80, "max_new_tokens": 30})())
+    need = -(-(80 + 30) // eng.page_size)
     assert need > free_before
     prompt = [11 + (j * 13) % 50 for j in range(80)]
     (toks, _, _), = _run(eng, [(prompt, 30, dict(top_k=1,

@@ -156,7 +156,7 @@ def test_preempt_resume_bitwise(toy_model, cut, cache):
     while len(req.generated) < cut:
         eng.step()
     assert eng.preempt(req)
-    assert req._phase == "queued" and not req._pages
+    assert req._phase == "queued" and not req._mem[0].pages
     (t, lp), = _drain(eng, [req])
     assert t == t_ref
     assert_logprobs_close(lp, lp_ref)
@@ -193,7 +193,7 @@ def _assert_invariants(eng):
     worst case (the deadlock-freedom invariant, now under preemption)."""
     pool = eng.pool
     holders = Counter(p for r in eng._slots if r is not None
-                      for p in r._pages)
+                      for p in r._mem[0].pages)
     free = set(pool._free)
     assert NULL_PAGE not in free and holders.get(NULL_PAGE, 0) == 0
     for p in range(1, pool.num_pages):
@@ -202,10 +202,10 @@ def _assert_invariants(eng):
             assert pool.refcounts[p] == 0 and p not in pool.cached
     cached_idle = sum(1 for p in pool.cached if pool.refcounts[p] == 0)
     assert len(holders) + pool.num_free + cached_idle == pool.num_pages - 1
-    assert pool.num_available >= eng._committed + eng.page_watermark
+    assert pool.num_available >= eng._classes[0].committed + eng.page_watermark
     # queued requests (incl. preempted ones) hold nothing
     for r in eng._queue:
-        assert not r._pages and r._slot == -1
+        assert not any(m.pages for m in r._mem) and r._slot == -1
 
 
 def test_ledger_and_page_invariants_under_preemption_churn(toy_model):
@@ -240,7 +240,7 @@ def test_ledger_and_page_invariants_under_preemption_churn(toy_model):
         assert len(r.generated) == r.max_new_tokens
     assert eng.preemptions >= 1
     assert int(eng.pool.refcounts.sum()) == 0
-    assert eng._committed == 0
+    assert eng._classes[0].committed == 0
     assert eng.pool.num_free + len(eng.pool.cached) == eng.pool.num_pages - 1
 
 

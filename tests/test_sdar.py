@@ -18,6 +18,7 @@ from benchmark.reference import sdar_block as ref
 from megatron_llm_tpu.config.arguments import MODEL_SIZES, parse_args
 from megatron_llm_tpu.generation import ContinuousBatchingEngine
 from megatron_llm_tpu.generation import blocks as blocks_mod
+from megatron_llm_tpu.generation import engine as engine_mod
 from megatron_llm_tpu.generation import generation as gen
 from megatron_llm_tpu.generation.pools import (
     KEEPS,
@@ -29,6 +30,7 @@ from megatron_llm_tpu.models import init_model_params, make_config
 from megatron_llm_tpu.models import moe as moe_mod
 from megatron_llm_tpu.models.language_model import model_forward
 from megatron_llm_tpu.observability import registry as obs_registry
+from tests.parity import assert_memory, assert_memory_idle
 
 ATOL = 1e-4
 VOCAB, MASK = 96, 95
@@ -116,7 +118,7 @@ def check(req, params, strategy="sequential", steps=4, threshold=0.5,
 
 def _assert_idle(eng):
     assert all(r is None for r in eng._slots) and not eng._inflight
-    assert eng._committed == 0
+    assert_memory_idle(eng)
 
 
 # ---- the family ------------------------------------------------------------
@@ -397,9 +399,11 @@ def test_preempted_and_resumed_equals_uninterrupted(model, strategy):
         confidence_threshold=0.5)
     while len(req.generated) < 10:
         eng.step()
+        assert_memory(eng)
     before = obs_registry.get_registry().counter(
         "mlt_engine_block_recomputed_total").value
     assert eng.preempt(req)
+    assert_memory(eng)
     eng.run_until_idle()
     assert req.result() == want.result()
     assert req._preemptions == 1 and req._hit_tokens >= 24
@@ -473,7 +477,7 @@ def test_block_ticks_count_walks_and_blocks_by_the_kernels_rule(monkeypatch):
     read = lambda: np.array([reg.counter(  # noqa: E731
         f"mlt_engine_paged_{n}_total").value
         for n in ("rows", "walks", "blocks_seen", "blocks_fetched")])
-    given, rule = [], blocks_mod.tile_shares
+    given, rule = [], engine_mod.tile_shares    # the engine counts both ticks
 
     def spy(table, idx, *rest, **kw):
         shares = rule(table, idx, *rest, **kw)
@@ -482,7 +486,7 @@ def test_block_ticks_count_walks_and_blocks_by_the_kernels_rule(monkeypatch):
                       int(shares.walks()), seen, fetched))
         return shares
 
-    monkeypatch.setattr(blocks_mod, "tile_shares", spy)
+    monkeypatch.setattr(engine_mod, "tile_shares", spy)
     eng = engine(cfg, params, max_seq=512)
     before = read()
     prompt, = prompts(264, seed=4)

@@ -183,7 +183,7 @@ def test_greedy_spec_bitwise_under_preemption(models):
     while req._phase != "decode" or len(req.generated) < 5:
         eng.step()
     assert eng.preempt(req), "request should be preemptible"
-    assert req._phase == "queued" and not req._pages
+    assert req._phase == "queued" and not req._mem[0].pages
     eng.run_until_idle()
     toks, lps = req.result(timeout=60)
     assert_same_generations([(toks, lps)], res0)
@@ -300,7 +300,7 @@ def test_spec_pool_shares_page_ids_and_drains(models):
     _run(eng, _greedy_jobs())
     assert np.all(pool.refcounts == 0)
     assert pool.num_free == pool.num_pages - 1  # cache off: all pages back
-    assert eng._committed == 0
+    assert eng._classes[0].committed == 0
 
 
 def test_spec_requires_draft_and_chunked_prefill(models):

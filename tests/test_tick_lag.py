@@ -131,7 +131,7 @@ def _pages_accounted(eng):
     assert pool.num_free + referenced + pool.num_evictable == \
         pool.num_pages - 1
     holders = collections.Counter(
-        p for r in eng._slots if r is not None for p in r._pages)
+        p for r in eng._slots if r is not None for p in r._mem[0].pages)
     assert all(pool.refcounts[p] == n for p, n in holders.items()), holders
     assert referenced == len(holders)
     private = [p for p, n in holders.items() if p not in pool.cached]
@@ -290,7 +290,7 @@ def test_budget_end_is_launched_dead(models, n_prompt, asked, n_out):
     granted = []
     while not req.finished:
         eng.step()
-        granted.append(len(req._pages))
+        granted.append(len(req._mem[0].pages))
     eng.run_until_idle()
     toks, _ = req.result(timeout=60)
     ref, _ = dense_greedy(models["cfg"], models["params"], prompt, n_out)

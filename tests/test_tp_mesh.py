@@ -312,7 +312,7 @@ def test_engine_tp4_decode_parity(toy_model, eight_devices):
     assert shard[3] == eng4.pool.kv.shape[3] // 4 == (
         m.num_attention_heads_kv // 4 * 2 * m.kv_channels)
     # block tables stay host-side numpy
-    assert isinstance(eng4._block_tables, np.ndarray)
+    assert isinstance(eng4._classes[0].table, np.ndarray)
 
     for (t0, l0), (t1, l1) in zip(base, tp):
         # tokens bitwise; log-probs within the row-parallel reduction bound

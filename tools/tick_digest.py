@@ -22,17 +22,19 @@ A line that differs costs that cell one cold compile on the change's side
 
 The tool lowers ``make_ragged_tick_fn`` and ``make_jitted_train_step``
 itself, so no frame of ``generation/engine.py`` or ``training.py`` is on
-its stack: where the program's caller would stand, this file does.  The
-frames are kept from the kernel outwards, so a payload that names THIS file
-is one whose tenth frame is the caller of the tick (a kernel nine frames
-under ``tick``: the retention and the gated-delta sweeps), and in a serving
-process that frame is the engine's call of the tick program.  The last two
-lines say so: ``payload files: ...`` (every source file a payload names) and
-``callers: <cells whose payloads name the tick's caller>; engine.py calls
-tick_fn at <line:col-line:col> ...``.  They too have to be equal on parent
-and change: a line added above that call in ``generation/engine.py`` makes
-those cells' ticks new programs.  No train cell's payload reaches its
-caller.
+its stack.  The frames are kept from the kernel outwards, ten of them, so a
+kernel nine frames under ``tick`` (the retention and the gated-delta sweeps)
+carries the tick's CALLER as its tenth.  In a serving process that caller is
+``generation/launch.py`` ``call_tick`` (PR 61: the engine and the block
+driver launch through it, so that no line of ``generation/engine.py`` is in a
+payload), and the tool lowers a serving tick through the same call: such a
+payload names ``generation/launch.py`` here as it does there, and would name
+THIS file only if a frame beyond the helper's reached it.  The last two lines
+say so: ``payload files: ...`` (every source file a payload names) and
+``callers: <cells whose payloads name the tick's caller>; launch.py calls
+tick_fn at <line:col-line:col>``.  They too have to be equal on parent and
+change: a line added above that call in ``generation/launch.py`` makes those
+cells' ticks new programs.  No train cell's payload reaches its caller.
 
 ``--bodies`` answers another question, whether an edit changed a KERNEL: the
 programs are lowered with no frame in a location
@@ -56,11 +58,15 @@ import sys
 
 
 def lowered_text(tick, *operands) -> str:
-    """The tick as lowered with its pools donated: the text a compile
-    cache's key is made of."""
+    """The tick as lowered with its pools donated, through the call the
+    serving engine makes of it (``generation/launch.py``): the text a
+    compile cache's key is made of."""
     import jax
 
-    return jax.jit(tick, donate_argnums=(1,)).lower(*operands).as_text()
+    from megatron_llm_tpu.generation.launch import call_tick
+
+    return call_tick(jax.jit(tick, donate_argnums=(1,)).lower,
+                     *operands).as_text()
 
 
 def payloads(text: str) -> list:
@@ -83,10 +89,14 @@ def payload_digests(text: str) -> str:
         hashlib.sha256(body).hexdigest()[:16] for body in payloads(text)}))
 
 
-def engine_call_sites(root: str) -> list:
-    """Where ``generation/engine.py`` calls a tick program (``tick_fn(...)``),
-    as the location a payload would carry: ``line:col-line:col``."""
-    path = os.path.join(root, "megatron_llm_tpu", "generation", "engine.py")
+LAUNCH = os.path.join("megatron_llm_tpu", "generation", "launch.py")
+
+
+def launch_call_sites(root: str) -> list:
+    """Where ``generation/launch.py`` calls a tick program
+    (``tick_fn(...)``), as the location a payload would carry:
+    ``line:col-line:col``."""
+    path = os.path.join(root, LAUNCH)
     with open(path) as f:
         tree = ast.parse(f.read())
     return [f"{n.lineno}:{n.col_offset}-{n.end_lineno}:{n.end_col_offset}"
@@ -196,7 +206,8 @@ def main() -> int:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
     named, reach_caller = set(), []
-    me = os.path.abspath(__file__)
+    # the tick's caller in a serving process, and a train step's here
+    launch, me = os.path.join(root, LAUNCH), os.path.abspath(__file__)
     for entry in bench["workloads"]:
         name = entry["name"]
         if args.cells and name not in args.cells:
@@ -256,7 +267,8 @@ def main() -> int:
                         S((slots,), jnp.float32), S((slots,), jnp.int32),
                         S((slots,), jnp.bool_), *pre)
                 named |= payload_files(text)
-                if me in payload_files(text) and name not in reach_caller:
+                if (launch in payload_files(text)
+                        and name not in reach_caller):
                     reach_caller.append(name)
                 print(f"{name} rows={rows} "
                       f"{hashlib.sha256(text.encode()).hexdigest()}"
@@ -264,8 +276,8 @@ def main() -> int:
     print("payload files: " + " ".join(sorted(
         os.path.relpath(f, root) if os.path.isabs(f) else f for f in named)),
         flush=True)
-    print(f"callers: {' '.join(reach_caller) or 'none'}; engine.py calls "
-          f"tick_fn at {' '.join(engine_call_sites(root))}", flush=True)
+    print(f"callers: {' '.join(reach_caller) or 'none'}; launch.py calls "
+          f"tick_fn at {' '.join(launch_call_sites(root))}", flush=True)
     return 0
 
 
