@@ -25,8 +25,10 @@ a walk is partial: pages past the context are not fetched, their columns
 are masked and their value rows zeroed.  The softmax state (running max m,
 normalizer l, fp32 accumulator) of the tile's rows lives in VMEM scratch —
 the blockwise scheme of ops/pallas/flash_attention.py with blocks of pages
-as KV blocks.  GQA is native (q grouped [tiles, nkv, TILE*group, d], no
-K/V expansion).
+as KV blocks.  GQA is native, with no K/V expansion and no copy of the
+query: a program's query and output blocks are its tile's rows ROW-MAJOR, as
+the attention layer holds them (``[TILE, heads, d]``), and a kv head's query
+rows are a slice of the block inside the kernel (``_paged_kernel``).
 
 WHO SHARES A WALK is read from the call's own data, by ONE rule the engine
 counts by too (:func:`tile_shares`; a tile holds up to two SPANS, stretches
@@ -394,11 +396,19 @@ def _paged_kernel(
     quantized: bool,
     paired: bool,
 ):
-    """One program per TILE of rows: ``group`` query rows a kv head and
-    row (the heads of its group, padded to whole sublane tiles), UNSCALED
-    and in float32 — an exact copy of a bf16 query, whose rows are sliced
-    where a 32-bit tile starts and cast to ``operand``, the dtype both
-    matmuls take their operands in (``_operand_dtype``), beside the matmul.
+    """One program per TILE of rows.  The query and output blocks are the
+    tile's rows as the layer holds them, ``[TILE, nkv * group, w]``: a
+    row's heads one kv head after another, ``group`` query rows a kv head
+    and row (the heads of its group, padded to whole sublane tiles).  The
+    kv-head-major order the matmuls want is an INDEX here, not a copy
+    around the call: a row's own walk reads ``q_ref[t, heads(h)]``, a
+    span's walk ``q_ref[:, heads(h)]`` folded to ``[TILE * group, w]``,
+    which in float32 with ``group`` in whole tiles of 8 moves no data; the
+    softmax state stays ``[nkv, TILE * group, .]`` and the last lines write
+    ``acc / l`` back head by head.  The query is UNSCALED and in float32 —
+    an exact copy of a bf16 query, whose rows are sliced where a 32-bit
+    tile starts and cast to ``operand``, the dtype both matmuls take their
+    operands in (``_operand_dtype``), beside the matmul.
     ``paired``: a head's ``w`` key lanes are followed by its ``w`` value
     lanes; otherwise its ``w`` lanes are key and value at once (a latent
     row; a K|V pair of 64s)."""
@@ -408,7 +418,7 @@ def _paged_kernel(
     else:
         q_ref, kv_hbm, o_ref, kv_buf, sem, m_s, l_s, acc_s = refs
     i = pl.program_id(0)
-    nkv, _, w = q_ref.shape
+    nkv, _, w = acc_s.shape
     _, pps, page, _ = kv_buf.shape
     bk = pps * page
     # storage heads a page's scale row holds: a key and a value per head
@@ -426,10 +436,15 @@ def _paged_kernel(
                 s_buf[slot, j, (at[j] + h) // 128, (at[j] + h) % 128], vec)
         return jnp.where(live, vec, 0.0)
 
-    def walk(at, rows: int, tbl, blk0, blk1, kv_end, q_pos, q_end):
+    def heads(h):
+        """Kv head ``h``'s query heads in a row of the q and out blocks."""
+        return pl.ds(h * group, group)
+
+    def walk(at, rows: int, query, tbl, blk0, blk1, kv_end, q_pos, q_end):
         """ONE page walk over compute blocks ``[blk0, blk1)`` of table
         ``tbl``, keys below ``kv_end`` fetched, for the ``rows`` query rows
-        a kv head from ``at`` on.  ``q_pos`` is their position and
+        a kv head from ``at`` on, which ``query(h)`` reads out of the q
+        block as ``[rows, w]``.  ``q_pos`` is their position and
         ``q_end`` the end of the keys they may see — a scalar each for one
         row's group, ``[rows, 1]`` for a tile's rows: the causal (and
         window) mask is per ROW, and a block that lies outside one row's
@@ -488,7 +503,7 @@ def _paged_kernel(
             for h in range(nkv):
                 k_lanes = pl.ds((2 * h if paired else h) * w, w)
                 v_lanes = pl.ds((2 * h + 1) * w, w) if paired else k_lanes
-                q = q_ref[h, rows_at, :].astype(operand)        # [rows, w]
+                q = query(h).astype(operand)                    # [rows, w]
                 # the tile as it lies: a cast only where the page is not in
                 # the operands' dtype (a quantized page; float32 operands)
                 k = kv_buf[slot, :, :, k_lanes].astype(operand).reshape(
@@ -568,9 +583,13 @@ def _paged_kernel(
 
         @pl.when(blk1 > blk0)
         def _span():
-            # a run's rows need no look at each row (a chunk's tiles are
+            # the tile's rows at one kv head are whole (8, 128) tiles of
+            # float32: the fold to [TILE * group, w] moves nothing.  A
+            # run's rows need no look at each row (a chunk's tiles are
             # most of a prompt-heavy tick's)
-            walk(0, TILE * group, tbl, blk0, blk1, kv_end,
+            walk(0, TILE * group,
+                 lambda h: q_ref[:, heads(h), :].reshape(TILE * group, w),
+                 tbl, blk0, blk1, kv_end,
                  *jax.lax.cond(run != 0, of_a_run, of_each_row))
 
     def part(ph, _):
@@ -582,7 +601,8 @@ def _paged_kernel(
             r = row0 + t
             blk0 = rows_ref[r * 4 + 2 * ph]
             blk1 = rows_ref[r * 4 + 2 * ph + 1]
-            walk(pl.multiple_of(t * group, 8), group, idx_ref[r], blk0, blk1,
+            walk(pl.multiple_of(t * group, 8), group,
+                 lambda h: q_ref[t, heads(h), :], idx_ref[r], blk0, blk1,
                  row_end(r), pos_ref[r], row_end(r))
 
         # a part no row of the tile takes is not looped over: a tile of
@@ -599,7 +619,11 @@ def _paged_kernel(
 
     l = l_s[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = (acc_s[...] / l_safe).astype(o_ref.dtype)
+    out = acc_s[...] / l_safe
+    # back into the rows' own order, head by head
+    for h in range(nkv):
+        o_ref[:, heads(h), :] = out[h].reshape(TILE, group, w).astype(
+            o_ref.dtype)
 
 
 def _scale_rows(scale):
@@ -639,7 +663,16 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     """``q`` [R, n_heads, d], one query row a ragged row -> same shape.
     ``pool`` is the flat ``[pages, page, H*d]`` pool of every layer
     (ops/kv_quant.layer_view; quantized: with the calling layer's ``[P,
-    H]`` scales) and ``page_base`` the calling layer's first page in it."""
+    H]`` scales) and ``page_base`` the calling layer's first page in it.
+
+    The kernel takes the query and gives the output ROW-MAJOR, ``[rows,
+    heads, lanes]``, a program's block its ``TILE`` rows with all their
+    heads.  What stands around the call follows from ``g``, ``nkv``, ``d``
+    and the dtype alone: where a group is whole sublane tiles and a head
+    whole lanes (8 x 128, 16 x 128) the pad and the slice are empty and the
+    call's operand and result ARE ``[R, n, d]``; where a group or a head is
+    padded (4 -> 8, 71 -> 72, a pair of 64s, a latent row) one pad in front
+    and one slice behind stay, and no transposition anywhere."""
     quantized = kv_quant.is_quantized(pool)
     assert not (latent and quantized), "a latent pool is not quantized"
     arr = kv_quant.values_of(pool)
@@ -658,14 +691,17 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     table_index, positions, horizons = (
         jnp.pad(a.astype(jnp.int32), dead)
         for a in (table_index, positions, horizons))
-    # kv-head-major query rows, unscaled (the kernel scales the float32
-    # scores) and in float32, which holds a bf16 query exactly: one program
-    # sees all of a kv head's query rows of its tile as ONE matmul operand.
-    # A bf16 block would want a row's group in tiles of 16 rows: measured,
-    # the padded rows cost more than this copy (PERF.md section 6, PR 45)
+    # the query unscaled (the kernel scales the float32 scores) and in
+    # float32, which holds a bf16 query exactly and in which a kv head's
+    # ``gp`` rows of a row are whole (8, 128) tiles: one program slices all
+    # of a kv head's query rows of its tile out of the row-major block as
+    # ONE matmul operand.  The cast fuses into whatever produces ``q``; a
+    # block in the query's own dtype, cast once a program into a float32
+    # scratch, read the same in the cell and 0.5% slower alone (PERF.md
+    # section 6, PR 62)
     qg = jnp.pad(q.astype(jnp.float32).reshape(r, nkv, g, d),
                  (dead, (0, 0), (0, gp - g), (0, w - d)))
-    qg = qg.reshape(tiles, TILE, nkv, gp, w).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(tiles * TILE, nkv * gp, w)
 
     def lanes(n):
         return pl.cdiv(n, 128) * 128
@@ -681,11 +717,11 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     buf_shape = (2, pps, page_size, row)
     rows = TILE * gp
 
-    tile_spec = pl.BlockSpec((None, nkv, rows, w),
-                             lambda i, *prefetch: (i, 0, 0, 0))
+    tile_spec = pl.BlockSpec((TILE, nkv * gp, w),
+                             lambda i, *prefetch: (i, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [tile_spec, hbm_spec]
-    operands = [qg.reshape(tiles, nkv, rows, w), arr]
+    operands = [qg, arr]
     scratch = [
         pltpu.VMEM(buf_shape, arr.dtype),
         pltpu.SemaphoreType.DMA((2 if quantized else 1, 2)),
@@ -711,7 +747,9 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     )
 
     # VMEM, every last dim padded to 128 lanes: the float32 q and the out
-    # blocks (two of each, the pipeline's), the softmax state of the whole
+    # blocks (row-major, two of each, the pipeline's; the out block is
+    # stored into a kv head at a time and wants no scratch of its own), the
+    # softmax state of the whole
     # tile, both halves of the page buffer, and a step's [rows, block] fp32
     # temporaries (scores, probabilities, masks, and the probabilities' two
     # bf16 halves: one more) at a run's TILE * group rows.  A tile of 8
@@ -728,7 +766,7 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tiles, nkv, rows, w), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles * TILE, nkv * gp, w), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(2 * vmem, 16 << 20)),
         interpret=interpret,
@@ -737,8 +775,8 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
       shares.rows.reshape(-1), shares.spans.reshape(-1),
       shares.parts.reshape(-1),
       jnp.asarray(page_base, jnp.int32).reshape(1), *operands)
-    out = out.reshape(tiles, nkv, TILE, gp, w).transpose(0, 2, 1, 3, 4)
-    # the pair read whole: its value lanes are the output
+    # what was padded is sliced off again; the pair read whole: its value
+    # lanes are the output
     return out.reshape(tiles * TILE, nkv, gp, w)[
         :r, :, :g, (0 if w == d or latent else d):].reshape(r, n, -1)
 

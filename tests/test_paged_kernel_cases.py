@@ -65,9 +65,55 @@ def check_case(case) -> None:
     dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300,
          window=100, slid_head=True),
     dict(n=128, nkv=8, d=128, page=16, max_pages=24, context=300),
+    # SDAR-30B-A3B (32 query heads on 4 kv heads of 128: a group of 8 rows,
+    # HALF a packed bf16 tile, on several kv heads): a slot's tile holds the
+    # commit rows of the block before and the denoise rows of its block on
+    # one table (the ``blocks`` call shape), in both dtypes
+    dict(n=32, nkv=4, d=128, page=16, max_pages=24, context=300, block=4),
+    dict(n=32, nkv=4, d=128, page=16, dtype=jnp.float32, max_pages=24,
+         context=300, block=4),
+    # LFM2-24B-A2B (32 / 8 x 64): a group of 4 padded to 8 rows and a head
+    # of 64 read as its 128-lane pair, on several kv heads
+    dict(n=32, nkv=8, d=64, page=16, max_pages=24, context=300, block=4),
+    # rows that fill no whole number of tiles: 19, the wrapper pads dead
+    # ones behind them and slices them off again
+    dict(n=32, nkv=4, d=128, page=16, dtype=jnp.float32, max_pages=24,
+         context=300, block=4, tail=3),
 ], ids=case_id)
 def test_paged_kernels_interpret_match_jnp_path(case):
     """The Pallas decode / prefill / ragged kernel (interpret mode) == the
     jnp gather path on plain pools, with and without a sliding window —
     the scenarios tools/tpu_kernel_check.py compiles on the chip."""
     check_case(case)
+
+
+def test_paged_call_holds_no_transpose_where_nothing_is_padded():
+    """Where a group is whole sublane tiles and a head whole lanes (``g ==
+    gp``, ``w == d``: SDAR 8 x 128, Command A+ 16 x 128) the kernel takes
+    the query and gives the output as the layer holds them: the operand of
+    ``pallas_call`` and its result are ``[R, n, d]``, and no ``transpose``
+    stands outside it."""
+    import jax
+
+    from megatron_llm_tpu.ops.pallas import paged_attention as pk
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+
+    r, d, page = 24, 128, 16
+    S = jax.ShapeDtypeStruct
+    for n, nkv in ((32, 4), (128, 8)):
+        jaxpr = jax.make_jaxpr(lambda *a: pk.paged_ragged_kernel(
+            *a, scale=1.0))(
+                S((r, 1, n, d), jnp.bfloat16),
+                S((40, page, 2 * nkv * d), jnp.bfloat16),
+                S((3, 12), jnp.int32), *(S((r,), jnp.int32),) * 3)
+        eqns = list(primitives(jaxpr.jaxpr))
+        assert not [e for e in eqns if e.primitive.name == "transpose"]
+        call, = (e for e in eqns if e.primitive.name == "pallas_call")
+        assert (r, n, d) in [v.aval.shape for v in call.invars]
+        assert [v.aval.shape for v in call.outvars] == [(r, n, d)]

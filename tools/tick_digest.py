@@ -138,11 +138,12 @@ def train_step_text(cell, mesh) -> str:
                           jax.ShapeDtypeStruct((), jnp.int32)).as_text()
 
 
-def block_tick_text(cfg, rows: int, params, pool, tables, slots: int,
-                    chunk: int, S) -> str:
-    """The tick of a model that generates by diffusion over blocks
-    (``generation/blocks.py``, in the ragged tick's place), lowered on its
-    own operands."""
+def block_tick_program(cfg, rows: int, params, pool, tables, slots: int,
+                       chunk: int, S):
+    """``(tick, operands)``: the tick of a model that generates by
+    diffusion over blocks (``generation/blocks.py``, in the ragged tick's
+    place) and its own abstract operands (``tools/tick_hlo_copies.py``
+    compiles the same)."""
     import jax.numpy as jnp
 
     from megatron_llm_tpu.generation.blocks import (
@@ -161,9 +162,9 @@ def block_tick_text(cfg, rows: int, params, pool, tables, slots: int,
                    S((slots,), i32), S((slots,), i32), S((slots,), f32))
     pre = (S((rows,), i32), S((rows,), i32), tables(rows // chunk + 1),
            S((rows,), i32)) if rows else ()
-    return lowered_text(
-        make_block_tick_fn(cfg, rows), params, pool, tables(slots), state,
-        S((slots,), flag), state, S((slots, 2), jnp.uint32), un, *pre)
+    return make_block_tick_fn(cfg, rows), (
+        params, pool, tables(slots), state, S((slots,), flag), state,
+        S((slots, 2), jnp.uint32), un, *pre)
 
 
 def main() -> int:
@@ -252,8 +253,9 @@ def main() -> int:
             cap = -(-slots // chunk) * chunk
             for rows in (0, cap):
                 if cfg.model.diffusion_block_length:
-                    text = block_tick_text(cfg, rows, params, pool,
-                                           tables, slots, chunk, S)
+                    tick, operands = block_tick_program(
+                        cfg, rows, params, pool, tables, slots, chunk, S)
+                    text = lowered_text(tick, *operands)
                 else:
                     tick = make_ragged_tick_fn(cfg, None, 0, rows, mesh=mesh)
                     pre = (S((rows,), jnp.int32), S((rows,), jnp.int32),

@@ -1,18 +1,19 @@
 """Is a weight copied inside a serving tick?  Compile only, no chip.
 
-Compiles the ragged tick of one benchmark configuration (``--workload``,
-a serving cell of BENCHMARK.json) at the engine's own geometry, with
-abstract parameters and an abstract pool, for one TPU v5e (the chip if
-this process has one, else a virtual topology: libtpu compiles, nothing
-runs), and prints every operation of the compiled program that MOVES at
-least ``--min_mb`` and computes nothing: an XLA ``copy``, or a fusion
-whose body holds nothing but ``copy`` / ``slice`` / ``dynamic-slice`` /
-``bitcast`` / ``transpose`` / ``reshape`` / ``constant`` (what
-``benchmark/layer_metrics/copy_share.batch.py`` prices in a device
-trace).  A row an operation: its name, the parameter leaf it reads (by
-the operand's shape), operand and result shapes with their layouts
-(``{minor_to_major}``; the ``T(..)`` tiling as the compiler prints it),
-its consumers, and the ``op_name`` the trace would show.
+Compiles the tick of one benchmark configuration (``--workload``, a serving
+cell of BENCHMARK.json: the ragged tick, or the block tick of a model that
+generates by diffusion over blocks) at the engine's own geometry, with
+abstract parameters and an abstract pool, for one TPU v5e (the chip if this
+process has one, else a virtual topology: libtpu compiles, nothing runs),
+and prints every operation of the compiled program that MOVES at least
+``--min_mb`` and computes nothing: an XLA ``copy``, or a fusion whose body
+holds nothing but ``copy`` / ``slice`` / ``dynamic-slice`` / ``bitcast`` /
+``transpose`` / ``reshape`` / ``constant`` (what
+``benchmark/layer_metrics/copy_share.batch.py`` prices in a device trace). A
+row an operation: its name, the parameter leaf it reads (by the operand's
+shape), operand and result shapes with their layouts (``{minor_to_major}``;
+the ``T(..)`` tiling as the compiler prints it), its consumers, and the
+``op_name`` the trace would show.
 
     JAX_PLATFORMS=cpu python tools/tick_hlo_copies.py \
         --workload brumby14b_longgen_closed [--prefill_rows 64] \
@@ -351,7 +352,14 @@ def compile_tick(workload: str, prefill_rows: Optional[int] = 0):
             args += [S((pre,), jnp.int32), S((pre,), jnp.int32),
                      tables(cap // chunk + 1), S((pre,), jnp.int32),
                      S((pre,), jnp.int32)]
-        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        if m.diffusion_block_length:
+            # the tick such a cell runs (generation/blocks.py)
+            from tick_digest import block_tick_program
+
+            tick, args = block_tick_program(
+                cfg, pre, params, pool, tables, slots, chunk, S)
+        else:
+            tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
         compiled = jax.jit(tick, donate_argnums=(1,)).lower(*args).compile()
     hlo_name = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
     leaves = {

@@ -34,8 +34,9 @@ its decode rows' tables led by four shared prefixes (``SHARED_TICKS``: in
 slot order and in the order the tick runs them in) and at a block model's
 ticks (``BLOCK_TICKS``: the SDAR cell's 32 slots of 4 commit + 4 denoise
 rows, under the rule's plan and under the plan it gave until PR 60),
-beside the dtype its two matmuls take their operands in and what its KV
-bytes need at the HBM peak, and checks nothing.
+beside the dtype its two matmuls take their operands in, what its KV
+bytes need at the HBM peak and a digest of one call's output (equal on two
+trees exactly where the kernel's numbers are), and checks nothing.
 ``--brumby`` holds the retention state sweep (``ops/pallas/retention.py``)
 to its ``jnp`` form at the Brumby-14B tick shapes (40 decode rows; 39
 decode rows and one 64-row prompt run) on a small pool, and with ``--time``
@@ -178,7 +179,7 @@ def flash_numerics(quick: bool):
 def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
                kv_dtype: str = "bf16", window=None, dtype=jnp.bfloat16,
                max_pages: int = 12, context=None, poison_tail: bool = False,
-               slid_head: bool = False):
+               slid_head: bool = False, block: int = 0, tail: int = 0):
     """One random paged-attention scenario and its three call shapes.
 
     Returns ``{name: (pallas_fn, jnp_fn)}`` — thunks over the same pool and
@@ -272,7 +273,33 @@ def paged_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     r_pos, r_idx, r_hor = (jnp.asarray(a) for a in (r_pos, r_idx, r_hor))
     qr = jnp.asarray(rng.normal(size=(r_pos.shape[0], 1, n, d)), dtype)
 
+    made = {}
+    if block:
+        # a slot's block starts on the blocks' grid, inside the context
+        first = np.array([limit - 2 * block, limit // 2]) // block * block
+        b_pos, b_idx = [], []
+        for slot, (at, commits) in enumerate(zip(first, (True, False))):
+            b_pos += [at - 1 if commits else 0] * block + [
+                at + block - 1] * block
+            b_idx += [slot + 1 if commits else 0] * block + [slot + 1] * block
+        b_pos += list(range(first[0] // 2, first[0] // 2 + tail))
+        b_idx += [1] * tail
+        b_pos, b_idx = (np.asarray(a, np.int32) for a in (b_pos, b_idx))
+        b_hor = np.where(b_idx > 0, (b_pos // 64 + 1) * 64, 0).astype(
+            np.int32)
+        b_live = np.flatnonzero(b_idx)
+        b_pos, b_idx, b_hor = (jnp.asarray(a) for a in (b_pos, b_idx, b_hor))
+        qb = jnp.asarray(rng.normal(size=(b_pos.shape[0], 1, n, d)), dtype)
+        made["blocks"] = (
+            lambda interpret=False: pk.paged_ragged_kernel(
+                qb, pool_k, tables_k, b_idx, b_pos, b_hor,
+                interpret=interpret, **kw)[b_live],
+            lambda: pa.paged_attention_ragged(
+                qb, pool, tables, b_idx, b_pos, b_hor,
+                use_kernel=False, **kw)[b_live])
+
     return {
+        **made,
         "decode": (
             lambda interpret=False: pk.paged_decode_kernel(
                 q1, pool_k, bt_k, pos, interpret=interpret, **kw),
@@ -1012,8 +1039,13 @@ def paged_timing():
     bytes the tick needs.  One program makes 24 calls, as a tick's layers
     do; the kernel's own device time is read from a profiler trace of it
     (the host's clock around the program is printed beside it, and holds
-    whatever copy XLA puts in front of the kernel)."""
+    whatever copy XLA puts in front of the kernel).  ``digest``: sixteen
+    digits of the sha256 of ONE call's output bytes at the tool's fixed
+    seed, equal on two trees exactly where the kernel's numbers are."""
+    import hashlib
     import statistics
+
+    import numpy as np
 
     from megatron_llm_tpu.ops.pallas import paged_attention as pk
 
@@ -1058,13 +1090,18 @@ def paged_timing():
         least = need / 819e9
         # what the kernel reads off this call's dtypes
         operand = pk._operand_dtype(args[0].dtype, args[1].dtype, False)
+        # one program per shape, as above
+        once = jax.jit(functools.partial(  # graftcheck: noqa[recompile-hazard]
+            pk.paged_ragged_kernel, shares=plan, **kw))
+        digest = hashlib.sha256(
+            np.asarray(once(*args)).tobytes()).hexdigest()[:16]
         print(f"TIME paged tick {name} rows={args[0].shape[0]} "
               f"page_slots={width} operands={operand}: "
               f"{t * 1e3:.3f} ms a call on the device "
               f"({host * 1e3:.3f} by the host's clock over {calls} calls), "
               f"least {least * 1e3:.4f} ms for {need / 1e6:.2f} MB of K "
-              f"and V ({100 * least / t:.2f}% of the bandwidth roofline)",
-              flush=True)
+              f"and V ({100 * least / t:.2f}% of the bandwidth roofline), "
+              f"digest {digest}", flush=True)
 
 
 BRUMBY_TICKS = {
