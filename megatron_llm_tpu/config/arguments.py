@@ -265,12 +265,30 @@ class ModelConfig:
     # gpt-oss's clamp inside a SwiGLU: SiLU(min(gate, limit)) x clip(value,
     # -limit, limit), dense MLP and experts alike
     swiglu_limit: Optional[float] = None
+    # --- a LOOPED stack (Ouro's LoopLM: `total_ut_steps`) ---
+    # the whole stack runs `loop_steps` times over the SAME weights, the
+    # final norm after every pass and feeding the next; a pass's keys and
+    # values are its own (a cache holds `cache_layer_slots` rows a token).
+    # After every norm an exit gate (a hidden -> 1 linear with a bias, leaf
+    # `exit_gate`) gives the pass's exit probability; the logits are the
+    # LAST pass's, where the exit distribution's cumulative mass reaches the
+    # published `early_exit_threshold` of 1.0 (a checkpoint's config that
+    # says less is refused where it enters: weights_conversion/
+    # hf_to_native.py).  1: every other model, no loop and no gate
+    loop_steps: int = 1
 
     @property
     def depth(self) -> int:
-        """Layers a token passes: the dense prefix and the scanned stack
-        (the KV pool's layer axis)."""
+        """Layers of weights a token passes: the dense prefix and the
+        scanned stack."""
         return self.dense_prefix_layers + self.num_layers
+
+    @property
+    def cache_layer_slots(self) -> int:
+        """The KV pool's layer axis: a slot a layer and PASS, layer l of
+        pass t (from 0) at ``t * depth + l`` (``depth`` where nothing
+        loops)."""
+        return self.loop_steps * self.depth
 
     @property
     def mla(self) -> bool:
@@ -382,6 +400,16 @@ class ModelConfig:
                 "the block-causal mask is written for one class of K/V "
                 "pages: 'mha' layers with no window, no pattern and no "
                 "state class")
+        assert self.loop_steps >= 1, "loop_steps counts the passes: >= 1"
+        if self.loop_steps > 1:
+            assert (self.attention_type == "mha" and not self.bidirectional
+                    and not self.sliding_window_layout
+                    and not self.sublayer_pattern and not self.linear_layout
+                    and not self.dense_prefix_layers
+                    and self.diffusion_block_length is None), (
+                "a looped stack is written for one class of K/V pages over "
+                "ONE scanned stack: 'mha' layers, no pattern, no state "
+                "class, no dense prefix")
         if self.retention:
             assert (self.sliding_window_size is None
                     and not self.sliding_window_layout
@@ -1317,6 +1345,21 @@ ARCH_DEFAULTS = {
         moe_normalize_gates=True,
         diffusion_block_length=4,
     ),
+    # ByteDance Ouro (`ouro`, a LoopLM): a dense llama-like stack with FOUR
+    # norms a layer (before and after each sublayer), run `loop_steps`
+    # times over the same weights with the final norm and an exit gate
+    # after every pass; multi-head attention (a K/V head a query head)
+    "ouro": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=1_000_000.0,
+        post_sublayer_norms=True,
+        loop_steps=4,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1450,6 +1493,13 @@ MODEL_SIZES = {
         max_position_embeddings=32768, ffn_hidden_size=6144,
         num_experts=128, moe_router_topk=8, moe_ffn_hidden_size=768,
         vocab_size=151936, diffusion_block_length=4, mask_token_id=151669),
+    # Ouro-2.6B: 48 layers x 4 passes (`total_ut_steps`), 16 heads of 128
+    # each with a K/V head of its own, every published size
+    "ouro-2.6b": dict(
+        num_layers=48, hidden_size=2048,
+        num_attention_heads=16, num_attention_heads_kv=16, kv_channels=128,
+        max_position_embeddings=65536, ffn_hidden_size=5632,
+        vocab_size=49152, loop_steps=4),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,
@@ -1570,7 +1620,8 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     )
     parser.add_argument("--model_name", type=str, default=None,
                         help="gpt|llama|llama2|codellama|llama3|falcon|"
-                             "mistral|mixtral|qwen2|joyai|smallthinker|gigachat35|bert|t5 "
+                             "mistral|mixtral|qwen2|joyai|smallthinker|gigachat35|ouro|"
+                             "bert|t5 "
                              "or a canonical size like llama2-7b / "
                              "llama3-8b")
     seen = set()

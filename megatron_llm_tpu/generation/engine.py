@@ -306,6 +306,9 @@ class _Launched(NamedTuple):
     # an expert model's tick: what its router did, ``[2]`` on the device
     # (assignments, distinct experts touched; generation/ragged.py)
     moe: object = None
+    # a looped stack's tick: its sampled rows' exit masses, ``[slots,
+    # passes]`` on the device
+    loop_mass: object = None
 
 
 class ContinuousBatchingEngine:
@@ -652,6 +655,9 @@ class ContinuousBatchingEngine:
         # ... and of those, what fell to the experts this chip holds
         self.moe_held_assignments = 0
         self.moe_held_experts_touched = 0
+        # a looped stack (loop_steps > 1): the sampled rows' exit masses a
+        # pass (mlt_engine_loop_exit_mass_total; no other model has it)
+        self.loop_exit_mass = np.zeros((cfg.model.loop_steps,), np.float64)
         self.prefix_hit_tokens = 0
         self.prefix_miss_tokens = 0
         self.cow_copies = 0
@@ -757,6 +763,16 @@ class ContinuousBatchingEngine:
             help="distinct experts that received a row, summed over ticks "
                  "and expert layers: with the assignments, the rows an "
                  "expert's GEMM ran on")
+        self._m_loop_mass = None
+        if cfg.model.loop_steps > 1:
+            self._m_loop_mass = [
+                reg.counter(
+                    "mlt_engine_loop_exit_mass_total",
+                    help="a looped stack: the exit distribution's mass at "
+                         "this pass, summed over the sampled rows (the "
+                         "steps' series sum to the rows sampled)",
+                    labels={"step": str(t + 1)})
+                for t in range(cfg.model.loop_steps)]
         self._m_preempt = reg.counter(
             "mlt_engine_preemptions_total",
             help="decoding requests preempted by page release")
@@ -2643,7 +2659,7 @@ class ContinuousBatchingEngine:
                     self._asarray(pre_index[:n_bucket]),
                     self._asarray(pre_hor[:n_bucket]))
                 tick_fn = self._tick_program(n_bucket)
-                moe = ()
+                moe = loop_mass = None
                 if self.spec_k:
                     (self.pool.kv, self.pool.draft_kv,
                      out_tok, out_lp, acc, cnt,
@@ -2656,17 +2672,23 @@ class ContinuousBatchingEngine:
                     del acc, cnt
                 else:
                     (self._kv, next_tok, out_lp,
-                     new_pos, new_steps, *moe) = call_tick(
+                     new_pos, new_steps, *extra) = call_tick(
                         tick_fn, self.params, self._kv,
                         bt, pos, toks, keys, steps, temp, tk, tp,
                         *carry, *pre_args)
                     out_tok, spec = next_tok, None
+                    # generation/ragged.py: the router's first, a looped
+                    # stack's masses last
+                    if self.cfg.model.num_experts is not None:
+                        moe = extra[0]
+                    if self._m_loop_mass is not None:
+                        loop_mass = extra[-1]
                 self._last_dispatch_end = time.monotonic()
                 with self._lock:
                     self._inflight.append(_Launched(
                         active, reqs, out_tok, out_lp, t_tick, epochs,
                         no=no, spans=spans, n_bucket=n_bucket, spec=spec,
-                        moe=moe[0] if moe else None))
+                        moe=moe, loop_mass=loop_mass))
                     self._advance_fill_locked(spans)
                     if not self._dirty:
                         # steady state: the tick advanced the device mirror
@@ -2807,7 +2829,16 @@ class ContinuousBatchingEngine:
                 rec.spec[:2] if rec.spec else ())
             if rec.moe is not None:      # rides the same fetch
                 handles += (rec.moe,)
+            if rec.loop_mass is not None:    # and so do these
+                handles += (rec.loop_mass,)
             fetched = jax.device_get(handles)
+            if rec.loop_mass is not None:
+                *fetched, masses = fetched
+                mass = masses[rec.active].sum(0, dtype=np.float64)
+                self.loop_exit_mass += mass
+                if obs_registry.publishing():
+                    for m_step, at in zip(self._m_loop_mass, mass):
+                        m_step.inc(float(at))
             if rec.moe is not None:
                 *fetched, moe_stats = fetched
                 rows, touched = int(moe_stats[0]), int(moe_stats[1])

@@ -97,9 +97,11 @@ def clear_jit_cache() -> None:
 
 def init_kv_caches(cfg, batch_size: int, max_seq: int, dtype) -> Tuple[jax.Array, jax.Array]:
     """Pre-allocated stacked KV cache (InferenceParams.key_value_memory_dict
-    analog, forward_step.py:17-41)."""
+    analog, forward_step.py:17-41); a looped stack's holds a pair a layer
+    and pass (models/language_model.py ``looped_forward``)."""
     m = cfg.model
-    shape = (m.num_layers, batch_size, max_seq, m.num_attention_heads_kv, m.kv_channels)
+    shape = (m.loop_steps * m.num_layers, batch_size, max_seq,
+             m.num_attention_heads_kv, m.kv_channels)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 

@@ -61,8 +61,9 @@ NULL_PAGE = 0
 # slot and pages), ``tails`` (the same beside a state class of conv tails
 # alone: gated short convolutions, no recurrent state), ``blocks`` (one
 # class of K/V pages under a block-causal mask, generated from by diffusion
-# over blocks: generation/blocks.py); ``paged``, one
-# class of K/V pages, carries every
+# over blocks: generation/blocks.py), ``loop`` (one class of K/V pages whose
+# layer axis is a slot a layer and PASS of a looped stack: ``loop_steps``);
+# ``paged``, one class of K/V pages, carries every
 # feature and has no row.  The features are what a caller of :func:`refuse_unserved` may
 # ask for: ``kv_dtype`` other than bf16, a ``tp`` or ``pp`` mesh, a
 # ``draft`` model (--spec_k), the cross-replica ``handoff``, a request's
@@ -89,6 +90,9 @@ KEEPS = {
     "blocks": ("generation by diffusion over blocks (diffusion_block_length "
                "{block}) keeps its K/V pages under a block-causal mask and "
                "a block's ids and known flags a slot on the device"),
+    "loop": ("a looped stack (loop_steps {loops}) runs its layers {loops} "
+             "times over the same weights and keeps every pass's keys and "
+             "values in page slots of its own"),
     "tails": ("a stack of gated short convolutions ({stack}) keeps the "
               "conv's last inputs a sequence (a tail a layer, no recurrent "
               "state) beside pages of keys and values for its attention "
@@ -206,6 +210,24 @@ NOT_CARRIED = {
         "return_log_probs (prompt scoring): the scoring chunk scores "
         "token i + 1 from position i under a causal mask, and this model "
         "has no such distribution"),
+    ("loop", "kv_dtype"): (
+        "--kv_dtype {kv_dtype}: a page's scales are set by the page's "
+        "first write, a slot a pass, and no test holds {loops} passes' "
+        "scales against the reference"),
+    ("loop", "tp"): (
+        "tensor-parallel serving (tp {tp}): no sharding rule names the "
+        "exit gate, and no test holds the pool's {loops} x depth slots on "
+        "a shard of the heads"),
+    ("loop", "pp"): (
+        "pipeline-parallel serving (pp {pp}): a stage owns a slice of the "
+        "layers ONCE, and a looped stack's rows come back to the first "
+        "stage {loops} times"),
+    ("loop", "draft"): (
+        "--spec_k: the verify tick's rejected rows would have to be "
+        "rolled back in every pass's slots, and no test holds that"),
+    ("loop", "handoff"): (
+        "the cross-replica KV handoff: its wire format names a page's "
+        "rows by the model's depth, not by {loops} x depth slots"),
     ("tails", "kv_dtype"): (
         "--kv_dtype {kv_dtype}: no test holds a page's scales beside a "
         "tail slot, and the tail is the conv's inputs as they were fed"),
@@ -243,6 +265,8 @@ def memory_kind(cfg) -> str:
         return "classes"
     if cfg.model.diffusion_block_length:
         return "blocks"
+    if cfg.model.loop_steps > 1:
+        return "loop"
     return "latent" if cfg.model.mla else "paged"
 
 
@@ -280,7 +304,7 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
         return
     why = NOT_CARRIED[kind, feature].format(
         kv_dtype=kv_dtype, tp=tp, pp=pp, held=m.moe_experts_held,
-        experts=m.num_experts)
+        experts=m.num_experts, loops=m.loop_steps)
     if kind == "share":
         raise ValueError(why)
     keeps = KEEPS[kind].format(
@@ -288,7 +312,7 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
         stack=(f"sublayer_pattern {m.sublayer_pattern}"
                if m.sublayer_pattern else f"linear_layout {m.linear_layout}"),
         rows="latent rows" if m.mla else "keys and values",
-        block=m.diffusion_block_length)
+        block=m.diffusion_block_length, loops=m.loop_steps)
     raise ValueError(
         f"{keeps}, which {why} "
         "does not carry yet. Serve this model on one chip with --kv_dtype "
@@ -349,7 +373,8 @@ class PagedKVPool:
         # model's layers keep their keys here).  None: the one pool of a
         # uniform model, every layer's, its counters unlabelled as ever
         self.page_class = page_class
-        layers = m.depth if layers is None else layers
+        # (a looped stack: a slot a layer and pass, models/language_model.py)
+        layers = m.cache_layer_slots if layers is None else layers
         dtype = dtype or _compute_dtype(cfg)
         assert kv_dtype in kv_quant.KV_DTYPES, (
             f"kv_dtype must be one of {kv_quant.KV_DTYPES}, got {kv_dtype!r}")

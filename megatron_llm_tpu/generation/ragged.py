@@ -141,7 +141,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
          req_keys, steps, temperature, top_k, top_p, carry_tok, carried
          [, pre_tok, pre_pos, pre_tables, pre_index, pre_hor])
         -> (pool_kv, next_tok, logp, new_pos, new_steps
-            [, moe_stats])
+            [, moe_stats] [, loop_mass])
 
     ``pool_kv`` is the paged pool, ONE leaf over all layers whose row
     ops/kv_quant.py owns (K/V or latent); the engine donates it and every
@@ -152,9 +152,9 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
 
     ``moe_stats`` exists iff the model has experts: ``[2]`` float32, the
     router's assignments (rows x topk, every row the program ran, dead
-    padding rows too: the grouped GEMM runs them) and the distinct experts
-    that received a row, both summed over the expert layers.  It rides
-    the tick's one fetch to the engine's ``mlt_engine_moe_*`` counters.
+    padding rows too) and the distinct experts that received a row, summed
+    over the expert layers; on the tick's one fetch to ``mlt_engine_moe_*``.
+    A looped stack adds ``loop_mass`` LAST: the slots' exit masses a pass.
     Where the program holds a share of the experts (``moe_experts_held``)
     it is ``[5]``: then the held assignments that ran, those dropped for
     want of a row, and the distinct held experts that received one.
@@ -416,7 +416,14 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
             next_tok, logp = (jnp.zeros_like(a).at[order].set(a)
                               for a in (next_tok, logp))
         res = (pool_kv, next_tok, logp, positions + 1, steps + 1)
-        return res + (aux[moe_stats],) if moe else res
+        mass = ()
+        if cfg.model.loop_steps > 1:
+            # a looped stack's aux is a pair (models/language_model.py): the
+            # routers' vector and the rows' exit distributions, of which the
+            # sampled rows' [b, passes] ride the same fetch in slot order
+            aux, mass = aux
+            mass = (jnp.zeros_like(mass[:b, 0]).at[order].set(mass[:b, 0]),)
+        return res + ((aux[moe_stats],) if moe else ()) + mass
 
     base_fn = spec_tick if K else tick
     if ovl is None and ppc is None:

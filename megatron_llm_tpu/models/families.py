@@ -183,6 +183,19 @@ def validate_family(cfg: Config) -> Config:
                "over the chosen, with no shared expert")
         _check(not m.use_bias and not m.tie_embed_logits,
                "sdar_moe has no biases and an untied head")
+    elif name == "ouro":
+        _check(m.post_sublayer_norms and not m.zero_centered_gated_norm
+               and m.use_rms_norm,
+               "ouro norms before and after each sublayer with a plain "
+               "RMSNorm")
+        _check(m.glu_activation == "swiglu"
+               and m.position_embedding_type == "rotary"
+               and not m.qk_head_norm,
+               "ouro uses SwiGLU and rotates q and k with no norm a head")
+        _check(m.num_experts is None and not m.parallel_attn,
+               "ouro is a dense sequential block")
+        _check(not m.use_bias and not m.tie_embed_logits,
+               "ouro has no biases and an untied head")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

@@ -71,9 +71,9 @@ def init_model_params(cfg, key: jax.Array) -> Params:
         from megatron_llm_tpu.models.sublayers import init_mixers
 
         params["mixers"] = init_mixers(cfg, jax.random.fold_in(k_layers, 2))
-    if m.dense_prefix_layers:
-        # the leading dense layers: a stack of their own, so that the
-        # scanned stack keeps one parameter shape
+    if m.loop_steps > 1:   # a looped stack's exit gate (the file's end)
+        params["exit_gate"] = init_exit_gate(cfg, jax.random.fold_in(key, 3))
+    if m.dense_prefix_layers:   # a stack of their own: ONE scanned shape
         params["dense_layers"] = init_stacked_layers(
             cfg, jax.random.fold_in(k_layers, 1), m.dense_prefix_layers,
             dense_ffn=True)
@@ -274,19 +274,15 @@ def model_forward(
     logits_postprocess=True,
     return_aux=False,
 ):
-    """GPTModel.forward analog (gpt_model.py:45-124).
-
-    ``paged`` (ops/paged_attention.PagedState): ``kv_caches`` is the paged
-    pool, one leaf [L, num_pages, page_size, row] (ops/kv_quant.py owns the
-    row) that every layer updates in place, instead of a dense cache, and
-    every batch row decodes one token at its own ``paged.positions`` entry
-    (the serving engine's fused tick, generation/engine.py).
-
-    With ``labels``: returns per-token fp32 loss [b, s] (masked mean is the
-    caller's job, matching the reference loss_func split). Without: logits.
-    Returns (output, new_kv_caches), or (output, new_kv_caches, moe_aux[AUX_LEN])
-    when ``return_aux`` (MoE router losses, models/moe.py).
-    """
+    """GPTModel.forward analog (gpt_model.py:45-124).  ``paged``
+    (ops/paged_attention.PagedState): ``kv_caches`` is the paged pool, one
+    leaf [L, num_pages, page_size, row] (ops/kv_quant.py owns the row) that
+    every layer updates in place, instead of a dense cache, and every batch
+    row decodes one token at its own ``paged.positions`` entry (the serving
+    engine's fused tick, generation/engine.py).  With ``labels``: per-token
+    fp32 loss [b, s] (the masked mean is the caller's job, as the reference
+    loss_func splits it); without: logits.  Returns (output, new_kv_caches),
+    with ``return_aux`` also moe_aux[AUX_LEN] (MoE losses, models/moe.py)."""
     hidden = embed_tokens(cfg, params, tokens, position_ids)
     if dropout_key is not None and not deterministic:
         k_embed, dropout_key = jax.random.split(dropout_key)
@@ -296,7 +292,11 @@ def model_forward(
 
     if rope_cache is None:
         rope_cache = make_rope_cache(cfg)
-
+    if cfg.model.loop_steps > 1:    # a looped stack's passes: the file's end
+        assert deterministic and token_idx is None and sp_constraint is None
+        return looped_forward(cfg, params, hidden, rope_cache, position_ids,
+                              segment_ids, labels, kv_caches, cache_index,
+                              paged, logits_postprocess, return_aux)
     ppc = pp_serve_mod.current()
     if ppc is not None and paged is not None and kv_caches is not None:
         # Pipeline-parallel serving tick (parallel/pp_serve.py, ISSUE 20):
@@ -400,3 +400,110 @@ def loss_from_batch(cfg, params, batch: Dict[str, jax.Array], *,
             metrics["router z loss"] = z
         return total, metrics
     return loss, metrics
+
+
+# ---- a looped stack (``loop_steps`` > 1) ------------------------------------
+#
+# Appended here: the lines above keep their numbers, which the serving
+# tick's kernels carry in their payloads (tools/tick_digest.py).
+
+
+# what a looped stack refuses, a sentence each, raised where the request
+# enters: a checkpoint's config (weights_conversion/hf_to_native.py), the
+# trainer's start-up (training_step.py)
+EXIT_BELOW_ONE = (
+    "early_exit_threshold {threshold} (below 1): reading an earlier pass's "
+    "logits saves no compute without a K/V policy for the passes a token "
+    "skipped (later tokens' queries of those passes read its keys there), "
+    "which the config does not give; a looped stack reads the last pass's "
+    "logits, the published threshold of 1.0")
+LOOP_NOT_TRAINED = (
+    "a looped stack (loop_steps {loops}) is served and not trained: the "
+    "published objective weighs the passes' losses by the exit distribution "
+    "with an entropy term whose coefficient is no key of the config")
+
+
+def init_exit_gate(cfg, key: jax.Array) -> Params:
+    """The exit gate of a looped stack: a hidden -> 1 linear with a bias,
+    read after every pass's final norm."""
+    m = cfg.model
+    return {"kernel": m.init_method_std * jax.random.normal(
+                key, (m.hidden_size, 1), jnp.float32),
+            "bias": jnp.zeros((1,), jnp.float32)}
+
+
+def exit_pdf(gates: jax.Array) -> jax.Array:
+    """The exit distribution over the passes from the gates' logits
+    ``[T, ...]``: with lambda_t = sigmoid(g_t), p_t = lambda_t prod_{j<t}
+    (1 - lambda_j) for t < T and p_T = prod_{j<T} (1 - lambda_j), float32;
+    it sums to one, and the last gate is read by nothing."""
+    lam = jax.nn.sigmoid(gates.astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)      # prod_{j<=t}, t < T
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), stay[:-1]])
+    return jnp.concatenate([lam[:-1] * before, stay[-1:]])
+
+
+def looped_forward(cfg, params: Params, hidden, rope_cache, position_ids,
+                   segment_ids, labels, kv_caches, cache_index, paged,
+                   logits_postprocess, return_aux):
+    """:func:`model_forward` from the embedding on for a looped stack: the
+    stack ``loop_steps`` times over the SAME parameters, the final norm
+    after every pass (it feeds the next), the exit gate after every norm.
+
+    The passes are ONE traced body, a scan over t around the layer scan.
+    Pass t's layer l keeps its keys on slot ``t * depth + l`` of the cache
+    (t from 0): the paged pool rides both scans' carries and is written in
+    place, ``pool_first_layer`` the traced ``-t * depth``; the dense
+    incremental cache's ``[T * L, ...]`` pair is scanned a pass.  The
+    logits are the LAST pass's, which is where the exit distribution's
+    cumulative mass reaches the published threshold of 1
+    (:data:`EXIT_BELOW_ONE` says why no lower one is taken).  With
+    ``return_aux`` the third output is a PAIR: the routers' aux vector
+    summed over the passes, and the exit distribution ``[b, s, T]``
+    (:func:`exit_pdf`)."""
+    m = cfg.model
+    T, L = m.loop_steps, m.depth
+    assert pp_serve_mod.current() is None, "a looped stack has no stages"
+    (stack, first_layer), = layer_stacks(cfg, params)
+    in_pool = paged is not None and kv_caches is not None
+    per_pass = None if in_pool else jax.tree.map(
+        lambda a: a.reshape(T, L, *a.shape[1:]), kv_caches)
+    gate = params["exit_gate"]
+
+    def one_pass(carry, xs):
+        hidden, pool = carry
+        t, caches = xs
+        with jax.named_scope("loop_pass"):
+            hidden, new, aux = transformer_forward(
+                cfg, stack, hidden, rope=rope_cache,
+                position_ids=position_ids, segment_ids=segment_ids,
+                kv_caches=pool if in_pool else caches,
+                cache_index=cache_index, paged=paged,
+                layer_offset=first_layer, pool_first_layer=-t * L)
+        with jax.named_scope("loop_norm_gate"):
+            hidden = norm(hidden, params["final_norm"],
+                          m.layernorm_epsilon, m.use_rms_norm)
+            g = jnp.einsum("bsh,ho->bs", hidden,
+                           gate["kernel"].astype(hidden.dtype),
+                           preferred_element_type=jnp.float32)
+            g = g + gate["bias"].astype(jnp.float32)
+        return (hidden, new if in_pool else pool), (
+            g, aux, None if in_pool else new)
+
+    (hidden, pool), (gates, aux, caches) = jax.lax.scan(
+        one_pass, (hidden, kv_caches if in_pool else None),
+        (jnp.arange(T, dtype=jnp.int32), per_pass))
+    new_caches = pool if in_pool else jax.tree.map(
+        lambda a: a.reshape(T * L, *a.shape[2:]), caches)
+    pdf = jnp.moveaxis(exit_pdf(gates), 0, -1)              # [b, s, T]
+
+    def ret(out):
+        return ((out, new_caches, (aux.sum(0), pdf)) if return_aux
+                else (out, new_caches))
+
+    if not logits_postprocess:
+        return ret(hidden)
+    with jax.named_scope("lm_head_loss"):
+        logits = compute_logits(cfg, params, hidden)
+        return ret(logits if labels is None
+                   else softmax_cross_entropy(logits, labels))

@@ -29,7 +29,11 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from megatron_llm_tpu.core import rng as rng_mod
-from megatron_llm_tpu.models.language_model import loss_from_batch, make_rope_cache
+from megatron_llm_tpu.models.language_model import (
+    LOOP_NOT_TRAINED,
+    loss_from_batch,
+    make_rope_cache,
+)
 from megatron_llm_tpu.optimizer.optimizer import (
     get_optimizer,
     global_grad_norm,
@@ -92,6 +96,8 @@ def make_train_step(cfg, optimizer: Optional[optax.GradientTransformation] = Non
     batch, num_micro=, dropout_key=) -> (loss, metrics)``, differentiated
     GPipe-style.
     """
+    if cfg.model.loop_steps > 1:
+        raise ValueError(LOOP_NOT_TRAINED.format(loops=cfg.model.loop_steps))
     sp_constraint = make_sp_constraint(cfg)
     lr_fn = lr_schedule(cfg)
     if num_micro is None:

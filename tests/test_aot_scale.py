@@ -957,3 +957,63 @@ def test_aot_block_tick_compiles_at_published_widths_and_depth():
         assert scope in hlo, scope
     assert stats.alias_size_in_bytes >= pool_bytes      # in place
     assert stats.temp_size_in_bytes < 1 << 30           # and never copied
+
+
+@pytest.mark.parametrize("pre", (0, 64))
+def test_aot_looped_tick_compiles_in_place_at_published_widths(pre):
+    """The ragged tick of Ouro-2.6B as its cell runs it (the WHOLE model: 48
+    layers x 4 passes, every width, the whole vocabulary, 16 slots and 0 or
+    64 prompt rows, 321 pages of 24 MiB over 192 layer slots; abstract
+    parameters) compiles for one v5e in ONE program: the paged kernel at 16
+    K/V heads with a group of ONE query head each, ONE layer body under TWO
+    nested loops (passes around layers), the 8 GB pool riding both loops'
+    carries in place, and both named scopes where the cell's readers look."""
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    cfg = make_config("ouro-2.6b", params_dtype="bfloat16", seq_length=576)
+    m = cfg.model
+    assert (m.depth, m.loop_steps, m.cache_layer_slots) == (48, 4, 192)
+    slots, page, pages = 16, 16, 321
+    width = cfg.data.seq_length // page
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    pool = S((192, pages, page, 2 * 16 * 128), jnp.bfloat16)
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert pool_bytes == pages * 16 * 1_572_864
+    with global_mesh(mesh):
+        params = jax.eval_shape(
+            functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
+        assert sum(a.size for a in jax.tree.leaves(params)) == 2_667_974_657
+        params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), params)
+        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        pre_args = (S((pre,), jnp.int32), S((pre,), jnp.int32),
+                    S((pre // 64 + 1, width), jnp.int32),
+                    S((pre,), jnp.int32), S((pre,), jnp.int32)) if pre else ()
+        lowered = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, pool, S((slots, width), jnp.int32),
+            S((slots,), jnp.int32), S((slots,), jnp.int32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), *pre_args)
+        assert "paged_attention" in lowered.as_text()
+        compiled = lowered.compile()
+        stats = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    for scope in ("/loop_pass/", "/loop_norm_gate/", "lm_head_loss"):
+        assert scope in hlo, scope
+    # ONE layer body: two loops (the passes, the layers), two kernels (the
+    # paged one, the GLU fc1's), however many passes and layers run
+    assert hlo.count(" while(") == 2
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert stats.alias_size_in_bytes >= pool_bytes      # in place
+    assert stats.temp_size_in_bytes < 1 << 28           # and never copied
+    assert not _weights_moved(compiled, 16 << 20, "bf16")
+    # the exit masses ride out beside the tokens: [slots, passes]
+    assert lowered.out_info[-1].shape == (slots, 4)

@@ -54,6 +54,8 @@ KINDS = {
     "blocks": lambda: make_config(
         "sdar_moe", **TINY, kv_channels=16, ffn_hidden_size=32, num_experts=4,
         moe_router_topk=2, moe_ffn_hidden_size=32, mask_token_id=127),
+    "loop": lambda: make_config(
+        "ouro", **TINY, kv_channels=16, ffn_hidden_size=96, loop_steps=4),
     "tails": lambda: make_config(
         "lfm2", **{k: v for k, v in TINY.items() if k != "num_layers"},
         sublayer_pattern="CD*ECE", kv_channels=16, ffn_hidden_size=96,
@@ -125,7 +127,7 @@ def test_the_table_has_no_row_without_a_case():
         _case(*row)
     kinds = {k for k, _ in NOT_CARRIED}
     assert kinds == {"share", "classes", "latent", "state", "hybrid", "tails",
-                     "blocks"}
+                     "blocks", "loop"}
     # a state class beside a page class is SERVED (tests/test_gigachat35.py):
     # the row that refused it now says whose that is (the hybrid's, not
     # power retention's), and the hybrid has a row a feature
@@ -133,6 +135,9 @@ def test_the_table_has_no_row_without_a_case():
     assert {f for k, f in NOT_CARRIED if k == "hybrid"} == set(FEATURES)
     assert {f for k, f in NOT_CARRIED if k == "tails"} == set(FEATURES)
     assert {f for k, f in NOT_CARRIED if k == "blocks"} == set(FEATURES)
+    # a looped stack serves prompt scoring
+    assert {f for k, f in NOT_CARRIED if k == "loop"} == (
+        set(FEATURES) - {"log_probs"})
     assert {f for _, f in NOT_CARRIED} <= set(FEATURES) | {"mesh", "pattern"}
 
 

@@ -8,6 +8,7 @@ fail, at one seed and with no measured window.
     chiprun -- python tools/serve_faults.py --workload nemotron3_nano_chat_closed --seed N
     chiprun -- python tools/serve_faults.py --workload lfm2_24b_chat_closed --seed N
     chiprun -- python tools/serve_faults.py --workload sdar30b_chat_blocks_closed --seed N
+    chiprun -- python tools/serve_faults.py --workload ouro26b_shortqa_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -20,6 +21,13 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
 * ``control``: the reference computed in bfloat16 throughout against itself
   in float32 at the same prompts and tokens: the precision below the
   configuration's, which the limits must not pass;
+* ``control_fp8`` (``--fp8``): the float32 reference on the weights ROUNDED
+  to float8_e4m3 (every matrix under one scale of its own, its largest
+  magnitude at the format's 240; vectors as given) against itself on the
+  weights as given, at the same prompts and tokens: a precision truly below
+  the configuration's, for a cell whose bfloat16 control reads as its
+  program does (the K/V pool is freed first: the rounded copy of the
+  weights takes its room);
 * one entry a planted fault: the program against a reference that has the
   fault (the difference is the one an honest reference reads of a program
   with it).  The faults are the three choices the reference module exposes
@@ -50,12 +58,18 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   blocks read the K/V of mask inputs), the logits shifted by one, a block's
   keys read from its final tokens before they are known, the rotary
   embedding at a row's mask position, and (shared with LFM2's) the head
-  norms and the gates' normalisation left out.  A fault is tried where the
-  cell's reference has its choice.
+  norms and the gates' normalisation left out; and those of
+  ``benchmark/reference/ouro_block.py``: three passes of the stack for four,
+  the norm between passes left out (the final norm after the last pass
+  alone), a query of every pass reading the FIRST pass's keys (keys and
+  values shared between passes: the cache-slot fault), and (shared with
+  GigaChat's) the two norms after the sublayers left out.  A fault is tried
+  where the cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
 readings and the others over a dozen seeds; an entry other than ``program``
-that reads ``reference_ok`` true is a limit too wide.  Exits 2 off the TPU
+that reads ``reference_ok`` true is a limit too wide (the bfloat16
+``control`` of a cell that says so in its ``tolerance.why`` apart).  Exits 2 off the TPU
 (``--rehearsal 1``: the CPU at tiny widths, never a reading)."""
 
 from __future__ import annotations
@@ -118,7 +132,33 @@ FAULTS = {
     "rope_at_the_mask_position": ("rope_position", lambda pos, model: (
         pos // model["diffusion_block_length"] + 1)
         * model["diffusion_block_length"] - 1),
+    "three_passes_for_four": ("passes",
+                              lambda model: model["total_ut_steps"] - 1),
+    "norm_between_passes_left_out": ("normed_between", lambda model: False),
+    "queries_read_the_first_pass_keys": ("keys_pass", lambda t, model: 0),
 }
+
+
+def fp8_weights(params):
+    """``params`` with every matrix rounded to float8_e4m3 (4 bits of
+    exponent, 3 of mantissa to bfloat16's 8 and 7) under one scale a leaf,
+    its largest magnitude at the format's largest (240): what a weight-only
+    fp8 deployment holds.  Vectors (norm scales, biases) are left as given.
+    ``lax.reduce_precision`` and not a pair of ``astype``: the compiler
+    drops a conversion there and back (the first readings on the chip were
+    0.0 to the last digit)."""
+    import jax
+    import jax.numpy as jnp
+
+    def rounded(a):
+        if a.ndim < 2:
+            return a
+        wide = a.astype(jnp.float32)
+        scale = jnp.max(jnp.abs(wide)) / 240.0
+        low = jax.lax.reduce_precision(wide / scale, 4, 3)
+        return (low * scale).astype(a.dtype)
+
+    return jax.tree.map(jax.jit(rounded), params)
 
 
 def main() -> int:
@@ -128,6 +168,8 @@ def main() -> int:
     ap.add_argument("--rehearsal", type=int, default=0)
     ap.add_argument("--no-faults", action="store_true",
                     help="program and control only: a reading a seed")
+    ap.add_argument("--fp8", action="store_true",
+                    help="add control_fp8 (a cell with one pool of K/V pages)")
     args = ap.parse_args()
     args.rate, args.trace = None, 0
     if args.rehearsal:
@@ -176,6 +218,11 @@ def main() -> int:
         with mock.patch.object(ref, choice, fault):
             faulty = check.emitted_reference(cell, served.params, probes)
         line[name] = check.compare(cell, got, faulty)
+    if args.fp8:    # last: the pool's room goes to the rounded weights
+        for leaf in jax.tree.leaves(served.engine.pool.kv):
+            leaf.delete()
+        line["control_fp8"] = check.compare(cell, check.emitted_reference(
+            cell, fp8_weights(served.params), probes), want)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -628,6 +628,8 @@ WALK_CASES = [
 
 # Command A+: 128 query heads on 8 kv heads of 128
 COMMANDA = dict(n=128, nkv=8, d=128, page=16)
+# Ouro-2.6B: 16 query heads, each with a K/V head of its own (a group of ONE)
+OURO = dict(n=16, nkv=16, d=128, page=16)
 # JoyAI-LLM-Flash: 32 query heads on ONE latent row of 640 lanes
 LATENT = dict(n=32, nkv=1, d=640, page=16, latent=True)
 
@@ -861,6 +863,7 @@ def paged_numerics(quick: bool):
               for kvd in ("bf16", "int8")]
     cases += [dict(geo, **walk) for geo in (FALCON, MISTRAL)
               for walk in WALK_CASES]
+    cases += [OURO, dict(OURO, max_pages=24, context=300)]
     # Falcon-40B: 8 kv heads of 64, a head's 128-lane key|value pair read
     # as one operand
     cases += [dict(n=16, nkv=8, d=64, page=16, kv_dtype=kvd)
@@ -889,7 +892,8 @@ def paged_numerics(quick: bool):
     # the shared walk (run_case): every scenario's live rows against the
     # gather path, at the serving configurations' geometries, the latent
     # row's and on quantized pools
-    runs = [FALCON, MISTRAL, COMMANDA, LATENT, dict(MISTRAL, kv_dtype="int8")]
+    runs = [FALCON, MISTRAL, COMMANDA, LATENT, dict(MISTRAL, kv_dtype="int8"),
+            OURO]
     if not quick:
         runs += [dict(FALCON, kv_dtype="int8"), dict(COMMANDA, kv_dtype="fp8")]
     # and the walk that rows of different sequences share (share_case),

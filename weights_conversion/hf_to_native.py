@@ -309,6 +309,16 @@ def config_from_hf(hf_config, model_name: str):
         # contract forbids, rather than silently untying
         kw["tie_embed_logits"] = bool(
             getattr(hf_config, "tie_word_embeddings", False))
+        if model_name == "ouro":
+            # a LoopLM's config (no weight converter yet): the passes, and
+            # the one exit threshold the looped forward reads logits at
+            from megatron_llm_tpu.models.language_model import EXIT_BELOW_ONE
+
+            threshold = float(getattr(hf_config, "early_exit_threshold", 1.0))
+            if threshold < 1.0:
+                raise ValueError(EXIT_BELOW_ONE.format(threshold=threshold))
+            kw["loop_steps"] = hf_config.total_ut_steps
+            kw["kv_channels"] = hf_config.head_dim
         if model_name == "mistral":
             kw["sliding_window_size"] = getattr(hf_config, "sliding_window", 4096)
         if model_name == "qwen2":
