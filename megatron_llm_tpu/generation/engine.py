@@ -118,6 +118,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from megatron_llm_tpu.config.arguments import check_prefill_chunk
 from megatron_llm_tpu.core.parallel_state import PP_AXIS, TP_AXIS
 from megatron_llm_tpu.generation import generation as gen
+from megatron_llm_tpu.generation import placement
 from megatron_llm_tpu.generation.launch import call_tick
 from megatron_llm_tpu.generation.pools import (  # noqa: F401 — re-exported
     NULL_PAGE,
@@ -487,6 +488,18 @@ class ContinuousBatchingEngine:
                 draft_params = jax.device_put(
                     draft_params, param_shardings(mesh, draft_params))
             self.draft_cfg, self.draft_params = draft.cfg, draft_params
+        # the word-embedding tables in rows (generation/placement.py): where
+        # the device's default layout of one is not, every program that
+        # looks a token up would write the whole table out again
+        self.params, self.draft_params = placement.tables_in_rows(
+            self.params, self.draft_params)
+        if mesh is None:
+            # parameters COMMITTED to their device (a re-laid table is)
+            # commit every tick's outputs, the pool and the carried tokens
+            # among them: the pools and what the engine uploads are born
+            # there too, or jit compiles each tick program once a mixture
+            # of committed and uncommitted operands
+            self._repl = placement.committed_to(self.params)
         budget_cap = (prefill_budget if prefill_budget is not None
                       else getattr(inf, "prefill_budget", 0))
         # prompt tokens a tick may prefill; the policy's token budget is
@@ -567,6 +580,12 @@ class ContinuousBatchingEngine:
                 pl, self.max_slots, self.pages_per_seq, window=window,
                 cap=cap, watermark=self.page_watermark))
         self.pool = self._pools[0]
+        if mesh is None and self._repl is not None:
+            for pl in self._pools:
+                pl.kv = jax.device_put(pl.kv, self._repl)
+            if self.draft_cfg is not None:
+                self.pool.draft_kv = jax.device_put(self.pool.draft_kv,
+                                                    self._repl)
         if self.state:
             if use_cache:
                 print("[engine] the prefix cache is off for a state pool: "
@@ -1112,11 +1131,15 @@ class ContinuousBatchingEngine:
     def _asarray(self, x):
         """Host -> device for tick/prefill operands: mesh-replicated when a
         mesh is active (slot vectors, block tables, token rows are identical
-        on every shard), plain asarray otherwise."""
-        a = jnp.asarray(x)
-        if self._repl is not None:
-            a = jax.device_put(a, self._repl)
-        return a
+        on every shard), committed to the parameters' device where they
+        are, plain asarray otherwise."""
+        if self._repl is None:
+            return jnp.asarray(x)
+        # one transfer: an upload and then a placement is twice the host's
+        # work a tick (plan_upload_ms.batch 4.2 -> 5.2 ms, PERF.md, PR 64)
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return jax.device_put(x, self._repl)
 
     def _overlap_span(self):
         """Tracer span marking an overlapped forward dispatch

@@ -1017,3 +1017,100 @@ def test_aot_looped_tick_compiles_in_place_at_published_widths(pre):
     assert not _weights_moved(compiled, 16 << 20, "bf16")
     # the exit masses ride out beside the tokens: [slots, passes]
     assert lowered.out_info[-1].shape == (slots, 4)
+
+
+# ---------------------------------------------------------------------------
+# the word-embedding table's layout (generation/placement.py, PR 64)
+# ---------------------------------------------------------------------------
+
+_TABLE_COPY = r"= bf16\[65024,4544\]\S* copy\("
+
+
+def test_aot_table_rule_on_a_described_chip():
+    """What the chip's compiler makes of a 2-D bf16 array by default, asked
+    of a compile-only client, and what the rule answers: Falcon's table
+    (4,544 = 35.5 x 128 lanes) lies vocabulary-minor and is wanted in rows
+    with the tiling it had; every table whose hidden size is whole lanes
+    lies in rows and is left alone.  A lone ``take`` + tied head then
+    compiles with a copy of the whole table in the one layout and with none
+    in the other, the gather and the head's dot reading the parameter."""
+    import re
+
+    from megatron_llm_tpu.generation import placement
+
+    sh = jax.sharding.SingleDeviceSharding(_topo_devices("v5e:2x2")[0])
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    falcon = S((65024, 4544))
+    assert placement.device_layout(falcon).major_to_minor == (1, 0)
+    fmt = placement.rows_format(falcon)
+    assert fmt.layout.major_to_minor == (0, 1)
+    assert fmt.layout.tiling == ((8, 128), (2, 1)) and fmt.sharding == sh
+    for shape in ((32000, 4096), (131072, 2048), (151936, 5120),
+                  (65024, 4608)):
+        leaf = S(shape)
+        assert placement.in_rows(leaf) is leaf, shape
+    # the other leaves a v5e lays out minor-axis-first are untied heads
+    # ``[h, v]`` with v no whole number of lanes: nothing gathers from them
+    assert placement.device_layout(S((2048, 16032))).major_to_minor == (1, 0)
+
+    def lookup_and_head(table, ids, x):
+        return jnp.take(table, ids, axis=0).sum(), x @ table.T
+
+    program = jax.jit(lookup_and_head)
+    texts = {name: program.lower(
+        table, S((256,), jnp.int32), S((128, 4544))).compile().as_text()
+        for name, table in (("default", falcon),
+                            ("rows", placement.in_rows(falcon)))}
+    assert "bf16[65024,4544]{0,1:T(8,128)(2,1)}" in texts["default"].split(
+        "\n", 1)[0]
+    assert len(re.findall(_TABLE_COPY, texts["default"])) == 1
+    assert "bf16[65024,4544]{1,0:T(8,128)(2,1)}" in texts["rows"].split(
+        "\n", 1)[0]
+    assert not re.findall(_TABLE_COPY, texts["rows"])
+
+
+def test_aot_falcon_tick_reads_the_table_as_it_lies():
+    """The ragged tick at Falcon's widths (2 layers, 128 prompt rows) on
+    abstract parameters put through the engine's own placement call: the
+    table enters in rows and no operation of the compiled tick copies it
+    (the parent's tick wrote 564 MiB a launch, ``copy.222``)."""
+    import re
+
+    from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
+    from megatron_llm_tpu.generation.placement import tables_in_rows
+    from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
+    from megatron_llm_tpu.models import init_model_params, make_config
+
+    slots, page, pre, width = 128, 16, 128, 128
+    mesh = build_mesh(devices=_topo_devices("v5e:2x2")[:1])
+    repl = NamedSharding(mesh, P())
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    cfg = make_config("falcon-7b", num_layers=2, params_dtype="bfloat16",
+                      vocab_size=65024, seq_length=2048)
+    pool = _abstract_pool((2, slots * width + 1, page, 1, 64), "bf16", repl,
+                          None)
+    with global_mesh(mesh):
+        params = jax.eval_shape(functools.partial(
+            init_model_params, cfg), jax.random.PRNGKey(0))
+        params, = tables_in_rows(jax.tree.map(
+            lambda a: S(a.shape, jnp.bfloat16), params))
+        table = params["embedding"]["word_embeddings"]
+        assert table.format.layout.major_to_minor == (0, 1)
+        tick = make_ragged_tick_fn(cfg, None, 0, pre, mesh=mesh)
+        text = jax.jit(tick, donate_argnums=(1,)).lower(
+            params, pool, S((slots, width), jnp.int32),
+            S((slots,), jnp.int32), S((slots,), jnp.int32),
+            S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.float32), S((slots,), jnp.int32),
+            S((slots,), jnp.bool_), S((pre,), jnp.int32),
+            S((pre,), jnp.int32), S((pre // page + 1, width), jnp.int32),
+            S((pre,), jnp.int32), S((pre,), jnp.int32)).compile().as_text()
+    assert "bf16[65024,4544]{1,0:T(8,128)(2,1)}" in text.split("\n", 1)[0]
+    assert not re.findall(_TABLE_COPY, text)

@@ -93,3 +93,53 @@ def test_report_names_leaf_layouts_and_consumer(comps):
     assert thc.leaf_of(LEAVES, small["copy.3"].instr.shape) == "-"
     assert thc.leaf_of(LEAVES, "bf16[34816,5120]{1,0}") == (
         "layers/mlp/fc1/kernel (by size)")
+
+
+# ---------------------------------------------------------------------------
+# the word-embedding table (PR 64): a lone ``take`` + tied head at the Falcon
+# cell's shapes, compiled for a described v5e with the table as the device
+# lays it out by default (``tick_hlo_table_cols.txt``: the parent's form) and
+# in rows (``tick_hlo_table_rows.txt``), the index clamps cut by hand
+# ---------------------------------------------------------------------------
+
+TABLE = {"embedding/word_embeddings": ("bf16", (65024, 4544))}
+
+
+def _canned(name):
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           name)) as f:
+        return f.read()
+
+
+def test_a_table_copied_into_rows_for_the_gather_is_reported():
+    text = _canned("tick_hlo_table_cols.txt")
+    (row,) = thc.moved(thc.parse_hlo(text), 100 * MIB)
+    assert (row.comp, row.instr.name, row.kind) == ("main.4", "copy", "copy")
+    assert row.instr.nbytes == 65024 * 4544 * 2
+    # the parameter lies vocabulary-minor, the gather wants rows
+    assert row.sources == (("t.1", "bf16[65024,4544]{0,1:T(8,128)(2,1)}"),)
+    assert row.instr.shape == "bf16[65024,4544]{1,0:T(8,128)(2,1)}"
+    assert row.consumers == ("fusion fusion[kCustom]",)
+    said = "\n".join(thc.report(text, TABLE, 100 * MIB))
+    assert said.startswith("1 moving operations of at least 100 MiB, "
+                           "563.6 MiB written in all")
+    assert "563.6 MiB  leaf: embedding/word_embeddings\n" in said
+
+
+def test_a_table_held_in_rows_is_read_as_it_lies():
+    text = _canned("tick_hlo_table_rows.txt")
+    comps = thc.parse_hlo(text)
+    assert thc.moved(comps, 100 * MIB) == []
+    assert thc.report(text, TABLE, 100 * MIB)[0].startswith(
+        "0 moving operations of at least 100 MiB")
+    entry = {i.name: i for i in comps["main.4"]}
+    assert entry["t.1"].shape == "bf16[65024,4544]{1,0:T(8,128)(2,1)}"
+    # the gather and the head's dot read the parameter; the dot through a
+    # bitcast that moves nothing
+    assert entry["fusion"].operands == ("t.1", "ids.1")
+    assert entry["convolution_convert_fusion"].operands == ("copy-done",
+                                                            "t.1")
+    assert {i.opcode for i in comps["bitcast_fusion.1"]} == {"parameter",
+                                                             "bitcast"}
+    # the small copy left (the toy's output rows) is under any table's size
+    assert [r.instr.name for r in thc.moved(comps, MIB)] == ["copy"]

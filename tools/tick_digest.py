@@ -194,6 +194,14 @@ def main() -> int:
     from megatron_llm_tpu.models import init_model_params
     from megatron_llm_tpu.models.transformer import pool_classes
 
+    try:
+        from megatron_llm_tpu.generation.placement import tables_in_rows
+    except ImportError:
+        # ``--root`` a tree from before PR 64: its engine takes the
+        # tables as they lie
+        def tables_in_rows(*trees):
+            return trees
+
     if args.bodies:
         jax.config.update("jax_traceback_in_locations_limit", 0)
     bodies = payload_digests if args.bodies else lambda text: ""
@@ -232,7 +240,9 @@ def main() -> int:
         with global_mesh(mesh):
             params = jax.eval_shape(functools.partial(
                 init_model_params, cfg), jax.random.PRNGKey(0))
-            params = jax.tree.map(lambda a: S(a.shape, jnp.bfloat16), params)
+            # the table in the layout the engine keeps it in
+            params, = tables_in_rows(jax.tree.map(
+                lambda a: S(a.shape, jnp.bfloat16), params))
             pools, widths = [], []
             many = len(classes) > 1
             for cls in classes:

@@ -274,6 +274,7 @@ def compile_tick(workload: str, prefill_rows: Optional[int] = 0):
     from megatron_llm_tpu.config.arguments import parse_args
     from megatron_llm_tpu.core.parallel_state import build_mesh, global_mesh
     from megatron_llm_tpu.generation import engine as eng
+    from megatron_llm_tpu.generation.placement import tables_in_rows
     from megatron_llm_tpu.generation.ragged import make_ragged_tick_fn
     from megatron_llm_tpu.models import init_model_params
     from megatron_llm_tpu.models.transformer import pool_classes
@@ -342,7 +343,9 @@ def compile_tick(workload: str, prefill_rows: Optional[int] = 0):
             tables = lambda n: S((n, width), jnp.int32)  # noqa: E731
         params = jax.eval_shape(
             functools.partial(init_model_params, cfg), jax.random.PRNGKey(0))
-        params = jax.tree.map(lambda a: S(a.shape, dtype), params)
+        # the table in the layout the engine keeps it in
+        params, = tables_in_rows(
+            jax.tree.map(lambda a: S(a.shape, dtype), params))
         row = lambda dt, *tail: S((slots, *tail), dt)  # noqa: E731
         args = [params, pool, tables(slots), row(jnp.int32), row(jnp.int32),
                 row(jnp.uint32, 2), row(jnp.int32), row(jnp.float32),
