@@ -912,6 +912,14 @@ class ContinuousBatchingEngine:
                  "rows) are walked once together, any other row once "
                  "(ops/pallas/paged_attention.tile_shares, its walks()); "
                  "rows over walks is how often the shared walk engages")
+        self._m_paged_carried = reg.counter(
+            "mlt_engine_paged_carried_walks_total",
+            help="of those walks, the ones whose first block's copies the "
+                 "walk before them in the call starts while it computes its "
+                 "last block (paged_attention.walk_order; TileShares."
+                 "carried()): every walk of a call but its first. Carried "
+                 "over walks is the share of walks that start with their "
+                 "pages on the way")
         self._m_paged_seen = reg.counter(
             "mlt_engine_paged_blocks_seen_total",
             help="compute blocks (the kernel's step: several pages) under "
@@ -2792,6 +2800,7 @@ class ContinuousBatchingEngine:
                                  page=self.page_size, row_bytes=row)
             if k == 0:
                 self._m_paged_walks.inc(int(shares.walks()))
+                self._m_paged_carried.inc(int(shares.carried()))
             seen, fetched = shares.blocks()
             self._m_paged_seen.inc(layers * int(seen))
             self._m_paged_fetched.inc(layers * int(fetched))

@@ -20,15 +20,21 @@ writes zeros, and the cost of a call does not depend on the table's width
 pages (``page_id = tables[table_index[row], j]``, one copy a page, all kv
 heads of the page at once) into the other half of a double-buffered VMEM
 scratch, waits for the current half, and does one score matmul and one
-online-softmax update per kv head for the whole block.  The last block of
-a walk is partial: pages past the context are not fetched, their columns
-are masked and their value rows zeroed.  The softmax state (running max m,
-normalizer l, fp32 accumulator) of the tile's rows lives in VMEM scratch —
-the blockwise scheme of ops/pallas/flash_attention.py with blocks of pages
-as KV blocks.  GQA is native, with no K/V expansion and no copy of the
-query: a program's query and output blocks are its tile's rows ROW-MAJOR, as
-the attention layer holds them (``[TILE, heads, d]``), and a kv head's query
-rows are a slice of the block inside the kernel (``_paged_kernel``).
+online-softmax update per kv head for the whole block.  Behind a walk's
+LAST block the next block is the FIRST block of the walk the call takes
+next (:func:`walk_order`: the next walk of the tile's program, behind its
+last one the first walk of the next program that has any), started under
+that walk's table and last key: a walk finds its first block on the way
+and only a call's first walk starts a copy and waits for it with nothing
+to compute meanwhile.  The last block of a walk is partial: pages past
+the context are not fetched, their columns are masked and their value rows
+zeroed.  The softmax state (running max m, normalizer l, fp32 accumulator)
+of the tile's rows lives in VMEM scratch — the blockwise scheme of
+ops/pallas/flash_attention.py with blocks of pages as KV blocks.  GQA is
+native, with no K/V expansion and no copy of the query: a program's query
+and output blocks are its tile's rows ROW-MAJOR, as the attention layer
+holds them (``[TILE, heads, d]``), and a kv head's query rows are a slice
+of the block inside the kernel (``_paged_kernel``).
 
 WHO SHARES A WALK is read from the call's own data, by ONE rule the engine
 counts by too (:func:`tile_shares`; a tile holds up to two SPANS, stretches
@@ -56,10 +62,12 @@ query rows a matmul: every row meets the blocks it met alone, in the same
 order, with the same arithmetic a row.  Rows that agree with nobody —
 decode rows on a table each — walk one after another inside the program: a
 row alone costs what it cost when the grid ran over rows.  All of it is
-data (``rows_ref`` / ``span_ref`` / ``part_ref``: each row's own head and
-tail, each span's range, the parts of its program a tile takes at all), so
-a tick's composition never recompiles, and the kernel holds ONE traced
-one-row walk and ONE traced tile walk whatever a tile's program is.
+data (``walk_ref`` / ``count_ref`` / ``span_ref``: a tile's non-empty walks
+in the order its program takes them — each row's own head, each span's
+range, each row's own tail — and which rows a span holds), so a tick's
+composition never recompiles, and the kernel holds ONE loop over a tile's
+walks with ONE traced one-row walk and ONE traced tile walk in it,
+whatever a tile's program is.
 
 Layout rules (Mosaic).  A copy out of HBM moves whole 128-lane rows, and
 the pool is STORED in such rows (ops/kv_quant.py owns the row): ``[pages,
@@ -152,19 +160,34 @@ SHARE_ROWS = 3
 SHARE_BLOCKS = 2
 
 
+# A tile's page walks in the order its program takes them: each row's own
+# head, the two spans, each row's own tail (a SLOT each; the empty ones are
+# left out of the list the kernel loops over)
+SLOTS = 2 * TILE + 2
+# a walk's record in ``TileShares.order``: the table it reads, its compute
+# blocks [blk0, blk1), the token it fetches up to, its slot, the half of the
+# page buffer its first block lands in, whether a walk before it starts that
+# block's copies, and the walk whose first block IT starts (that walk's
+# place in the call's list, -1: none)
+WALK = 8
+TBL, BLK0, BLK1, KV_END, SLOT, HALF, CARRIED, SUCC = range(WALK)
+
+
 class TileShares(NamedTuple):
     """What :func:`tile_shares` reads off a call: every row's walk in
     compute blocks (``blk0`` .. ``blk1``, its mask's first and last), the
     part of it a span's shared walk serves (``lo`` .. ``hi``; empty, at
     ``blk0``, for a row that walks alone; the whole of it for a row of a
-    span of one table) and a tile's two spans."""
+    span of one table), a tile's two spans, and the tile's non-empty walks
+    as the kernel takes them, one after another (:func:`walk_order`)."""
 
     rows: object     # [R', 4] int32: blk0, lo, hi, blk1 (R' in whole tiles)
     spans: object    # [tiles, 2, 7]: table, rows from, to, blocks s0, s1,
     #                  the token its walk fetches up to, whether it is a run
     #                  (all zeros: a span with no range)
-    parts: object    # [tiles, 3]: whether any row of the tile walks a head
-    #                  of its own, any span has a range, any row a tail
+    order: object    # [tiles, SLOTS, WALK]: a tile's walks, the first
+    #                  ``count`` of its records
+    count: object    # [tiles]: the walks of a tile's program
 
     def blocks(self):
         """``(seen, fetched)``: the compute blocks under the live rows'
@@ -175,18 +198,100 @@ class TileShares(NamedTuple):
         shared = (self.spans[..., 4] - self.spans[..., 3]).clip(0)
         return (blk1 - blk0).clip(0).sum(), own.sum() + shared.sum()
 
-    def walks(self):
-        """The page walks the call costs: one a row that walks a head or a
-        tail of its own, one a span that serves a row's WHOLE walk (a run,
-        a span of one table; a span whose rows each walk a tail besides
-        costs no walk more than they do)."""
+    def _walkers(self):
+        """``(alone, whole, inside)``: the rows that walk a head or a tail
+        of their own ``[tiles, TILE]``, and by span ``[tiles, 2, TILE]``
+        the rows whose WHOLE walk the span serves and the rows it holds."""
         blk0, lo, hi, blk1 = (
             self.rows.reshape(-1, TILE, 4)[..., k] for k in range(4))
         alone = (lo > blk0) | (blk1 > hi)
         first, end = (self.spans[..., k, None] for k in (1, 2))
         r = np.arange(TILE)
-        whole = ((blk1 > blk0) & ~alone)[:, None] & (r >= first) & (r < end)
+        inside = (r >= first) & (r < end)
+        return alone, ((blk1 > blk0) & ~alone)[:, None] & inside, inside
+
+    def walks(self):
+        """The page walks the call costs: one a row that walks a head or a
+        tail of its own, one a span that serves a row's WHOLE walk (a run,
+        a span of one table; a span whose rows each walk a tail besides
+        costs no walk more than they do)."""
+        alone, whole, _ = self._walkers()
         return alone.sum() + whole.any(axis=2).sum()
+
+    def carried(self):
+        """Of those walks, the ones whose FIRST block's copies a walk
+        before them starts (``CARRIED`` of the record that fetches it: a
+        row's head, else the span that holds it where that has a range,
+        else its tail; a span's own).  A call's first walk starts its own,
+        so this is at most ``walks()`` less one a call with a walk."""
+        alone, whole, inside = self._walkers()
+        blk0, lo, hi, _ = (
+            self.rows.reshape(-1, TILE, 4)[..., k] for k in range(4))
+        # the records' flags back in their slots
+        flag = np.zeros(self.order.shape[:2], bool)
+        tile, at = np.nonzero(np.arange(SLOTS) < self.count[:, None])
+        flag[tile, self.order[tile, at, SLOT]] = (
+            self.order[tile, at, CARRIED] != 0)
+        r = np.arange(TILE)
+        opens = np.where(lo > blk0, r, np.where(
+            hi > lo, TILE + inside[:, 1], TILE + 2 + r))
+        return ((alone & np.take_along_axis(flag, opens, axis=1)).sum()
+                + (whole.any(axis=2) & flag[:, TILE:TILE + 2]).sum())
+
+
+def walk_order(rows, spans, table_index, kv_end):
+    """A call's page walks as records (``WALK``), tile by tile in the order
+    the kernel's program takes them, the empty ones left out: ``(order
+    [tiles, SLOTS, WALK], count [tiles])`` of the rule's ``rows`` and
+    ``spans`` and each row's table and last key + 1 (``[R']``, whole
+    tiles).
+
+    The list is what lets a walk START ITS SUCCESSOR'S FIRST BLOCK: a
+    walk's last step has no next block of its own to fetch while it
+    computes, so it starts the copies of the first block of the walk the
+    program takes next — the next record of its tile, behind a tile's last
+    the first of the next tile that has any (the grid is one sequential
+    dimension and the page buffer outlives a program) — and only a call's
+    first walk starts its own and waits with nothing to do.  A block lands
+    in the half of the page buffer the block before it, of whichever walk,
+    is not in: ``HALF`` counts the call's blocks, not a walk's."""
+    xp = jnp if any(isinstance(a, jax.Array) for a in (
+        rows, spans, table_index, kv_end)) else np
+    blk0, lo, hi, blk1 = (rows.reshape(-1, TILE, 4)[..., k] for k in range(4))
+    idx, kv_end = (a.reshape(-1, TILE) for a in (table_index, kv_end))
+    tiles = idx.shape[0]
+
+    def slots(heads, of_spans, tails):
+        return xp.concatenate([heads, of_spans, tails], axis=1)
+
+    first = slots(blk0, spans[..., 3], hi)
+    last = slots(lo, spans[..., 4], blk1)
+    steps = (last - first).clip(0)
+    live = steps > 0
+    # the call's blocks before a walk's first: its parity is the half
+    done = xp.cumsum(steps.reshape(-1)).reshape(tiles, SLOTS) - steps
+    at = xp.cumsum(live, axis=1)
+    count = at[:, -1]
+    # a tile's j-th walk lies in the first slot with j + 1 walks up to it
+    k = np.arange(SLOTS)
+    src = xp.minimum((at[:, None] <= k[:, None]).sum(axis=2), SLOTS - 1)
+    # the next tile with a walk (``tiles``: none)
+    tile = np.arange(tiles)
+    ahead = xp.where((count > 0) & (tile > tile[:, None]), tile, tiles).min(
+        axis=1)
+    succ = xp.where(k + 1 < count[:, None], tile[:, None] * SLOTS + k + 1,
+                    xp.where(ahead < tiles, ahead * SLOTS, -1)[:, None])
+    before = (xp.cumsum(count) - count)[:, None] + k
+    by_slot = xp.stack([
+        slots(idx, spans[..., 0], idx), first, last,
+        slots(kv_end, spans[..., 5], kv_end),
+        xp.broadcast_to(k, live.shape), done % 2], axis=2)
+    order = xp.concatenate([
+        xp.take_along_axis(by_slot, src[..., None], axis=1),
+        xp.stack([before > 0, succ], axis=2)], axis=2)
+    # behind a tile's walks: zeros
+    order = xp.where((k < count[:, None])[..., None], order, 0)
+    return order.astype(np.int32), count.astype(np.int32)
 
 
 def tile_shares(tables, table_index, positions, horizons, *,
@@ -317,13 +422,10 @@ def tile_shares(tables, table_index, positions, horizons, *,
         [table, first, end, s0, s1,
          xp.minimum(s1 * bk, over(kv_end, 0, False)),
          run[:, None] & pays], axis=2), 0).astype(np.int32)
-    rows = xp.stack([blk0, lo, hi, blk1], axis=2)
-    # the parts of a tile's program that any of its rows or spans takes
-    parts = xp.stack([(lo > blk0).any(axis=1),
-                      (spans[..., 4] > spans[..., 3]).any(axis=1),
-                      (blk1 > hi).any(axis=1)], axis=1)
-    return TileShares(rows.reshape(-1, 4).astype(np.int32), spans,
-                      parts.astype(np.int32))
+    rows = xp.stack([blk0, lo, hi, blk1], axis=2).reshape(-1, 4).astype(
+        np.int32)
+    return TileShares(rows, spans, *walk_order(
+        rows, spans, idx.reshape(-1), kv_end.reshape(-1)))
 
 
 def _operand_dtype(q_dtype, page_dtype, quantized: bool):
@@ -380,12 +482,12 @@ def _paged_kernel(
     # scalar prefetch — all traced data, so one compiled launch serves any
     # tick composition
     tbl_ref,     # [T, max_pages] int32 block tables
-    idx_ref,     # [b] int32 row -> table
     pos_ref,     # [b] int32 the row's position
     hor_ref,     # [b] int32 kv horizon in tokens (0 = dead row)
-    rows_ref,    # [b * 4] int32: a row's blk0, lo, hi, blk1 (tile_shares)
     span_ref,    # [b / TILE * 14] int32: a tile's two spans (tile_shares)
-    part_ref,    # [b / TILE * 3] int32: the parts of its program a tile takes
+    walk_ref,    # [b / TILE * SLOTS * WALK] int32: the call's walks in the
+    #              order the programs take them (walk_order)
+    count_ref,   # [b / TILE] int32: the walks of a tile's program
     base_ref,    # [1] int32 first page of the calling layer in the pool
     # q block, the pool in HBM [, its scales], out block, then scratch
     *refs,
@@ -440,32 +542,45 @@ def _paged_kernel(
         """Kv head ``h``'s query heads in a row of the q and out blocks."""
         return pl.ds(h * group, group)
 
-    def walk(at, rows: int, query, tbl, blk0, blk1, kv_end, q_pos, q_end):
-        """ONE page walk over compute blocks ``[blk0, blk1)`` of table
-        ``tbl``, keys below ``kv_end`` fetched, for the ``rows`` query rows
-        a kv head from ``at`` on, which ``query(h)`` reads out of the q
-        block as ``[rows, w]``.  ``q_pos`` is their position and
-        ``q_end`` the end of the keys they may see — a scalar each for one
-        row's group, ``[rows, 1]`` for a tile's rows: the causal (and
-        window) mask is per ROW, and a block that lies outside one row's
-        mask (every block, for a row with ``q_end`` 0) leaves that row's
-        state as it was."""
+    def walk(rec, at, rows: int, query, q_pos, q_end):
+        """ONE page walk, the call's record ``rec`` (walk_order): compute
+        blocks ``[blk0, blk1)`` of table ``tbl``, keys below ``kv_end``
+        fetched, for the ``rows`` query rows a kv head from ``at`` on,
+        which ``query(h)`` reads out of the q block as ``[rows, w]``.
+        ``q_pos`` is their position and ``q_end`` the end of the keys they
+        may see — a scalar each for one row's group, ``[rows, 1]`` for a
+        tile's rows: the causal (and window) mask is per ROW, and a block
+        that lies outside one row's mask (every block, for a row with
+        ``q_end`` 0) leaves that row's state as it was.
+
+        While a block is computed the copies of the NEXT one run: the
+        walk's own next block, and behind its last block the FIRST block of
+        the walk the call takes next (``SUCC``), which then finds its pages
+        started (``CARRIED``) and waits for them under its own ``kv_end``.
+        A block lands in the half the block before it is not in, whichever
+        walk that was of."""
+        tbl, blk0, blk1, kv_end, _, half, carried, succ = (
+            walk_ref[rec + k] for k in range(WALK))
+        nxt = jnp.maximum(succ, 0) * WALK
+        succ_tbl, succ_blk0, succ_end = (
+            walk_ref[nxt + k] for k in (TBL, BLK0, KV_END))
         rows_at = pl.ds(at, rows)
         last = jnp.minimum(q_pos, q_end - 1)
 
-        def page_id(blk, j):
+        def page_id(tbl, blk, j):
             # clamped: a block's last slots may lie past the table's width
             return tbl_ref[tbl, jnp.minimum(blk * pps + j,
                                             tbl_ref.shape[1] - 1)]
 
-        def pages_of(blk, slot, start: bool):
-            """Start (or wait for) the copies of block ``blk``'s pages into
-            half ``slot``.  Pages past ``kv_end`` are never looked up."""
+        def pages_of(tbl, blk, kv_end, slot, start: bool):
+            """Start (or wait for) the copies of block ``blk``'s pages of
+            table ``tbl`` into half ``slot``.  Pages past ``kv_end`` are
+            never looked up."""
             def page_j(j, _):
                 @pl.when((blk * pps + j) * page < kv_end)
                 def _page():
                     # a wait needs the copy's shape only, not its source
-                    pid = page_id(blk, j) if start else 0
+                    pid = page_id(tbl, blk, j) if start else 0
                     copies = [(kv_hbm.at[pid + base_ref[0]], kv_buf, 0)]
                     if quantized:
                         scale_rows = pl.ds(pid * n_scales // 128, 2)
@@ -480,13 +595,16 @@ def _paged_kernel(
             jax.lax.fori_loop(0, pps, page_j, None, unroll=True)
 
         def block(blk, _):
-            slot = blk % 2
+            slot = (half + blk - blk0) % 2
+            own = blk + 1 < blk1
 
-            @pl.when(blk + 1 < blk1)
+            @pl.when(jnp.logical_or(own, succ >= 0))
             def _prefetch():
-                pages_of(blk + 1, 1 - slot, True)
+                pages_of(jnp.where(own, tbl, succ_tbl),
+                         jnp.where(own, blk + 1, succ_blk0),
+                         jnp.where(own, kv_end, succ_end), 1 - slot, True)
 
-            pages_of(blk, slot, False)
+            pages_of(tbl, blk, kv_end, slot, False)
             first = blk * bk
             kv_pos = first + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
             # q_end lies at or below kv_end: the mask also covers the
@@ -498,7 +616,7 @@ def _paged_kernel(
             live_row = first + jax.lax.broadcasted_iota(
                 jnp.int32, (bk, 1), 0) < kv_end
             if quantized:
-                scales_at = [page_id(blk, j) * n_scales % 128
+                scales_at = [page_id(tbl, blk, j) * n_scales % 128
                              for j in range(pps)]
             for h in range(nkv):
                 k_lanes = pl.ds((2 * h if paired else h) * w, w)
@@ -543,10 +661,11 @@ def _paged_kernel(
                 acc_s[h, rows_at, :] = (
                     acc_s[h, rows_at, :] * alpha + _weighted_values(p, v))
 
-        @pl.when(blk1 > blk0)
-        def _walk():
-            pages_of(blk0, blk0 % 2, True)
-            jax.lax.fori_loop(blk0, blk1, block, None)
+        @pl.when(carried == 0)
+        def _first():
+            pages_of(tbl, blk0, kv_end, half, True)
+
+        jax.lax.fori_loop(blk0, blk1, block, None)
 
     # a row no walk reaches (horizon 0) keeps this state: it writes zeros
     m_s[...] = jnp.full_like(m_s, NEG_INF)
@@ -557,11 +676,10 @@ def _paged_kernel(
     def row_end(r):
         return jnp.minimum(hor_ref[r], pos_ref[r] + 1)
 
-    def span_walk(s, _):
+    def span_walk(rec, s):
         """One of the tile's spans (tile_shares): ONE walk, one matmul a kv
         head for all the tile's rows, the rows outside the span masked."""
-        tbl, first, end, blk0, blk1, kv_end, run = (
-            span_ref[(i * 2 + s) * 7 + k] for k in range(7))
+        first, end, run = (span_ref[(i * 2 + s) * 7 + k] for k in (1, 2, 6))
 
         def of_a_run():
             # consecutive positions from the first row's on, all live
@@ -581,41 +699,39 @@ def _paged_kernel(
                     t == k, jnp.where(inside, row_end(row0 + k), 0), q_end)
             return q_pos, q_end
 
-        @pl.when(blk1 > blk0)
+        # the tile's rows at one kv head are whole (8, 128) tiles of
+        # float32: the fold to [TILE * group, w] moves nothing.  A run's
+        # rows need no look at each row (a chunk's tiles are most of a
+        # prompt-heavy tick's)
+        walk(rec, 0, TILE * group,
+             lambda h: q_ref[:, heads(h), :].reshape(TILE * group, w),
+             *jax.lax.cond(run != 0, of_a_run, of_each_row))
+
+    def row_walk(rec, t):
+        """A row's own head or tail: ``group`` query rows a matmul.  A tile
+        with nothing to share is its rows' tails, their whole walks, one
+        after another."""
+        r = row0 + t
+        walk(rec, pl.multiple_of(t * group, 8), group,
+             lambda h: q_ref[t, heads(h), :], pos_ref[r], row_end(r))
+
+    def one(j, _):
+        """The tile's ``j``-th walk: the rows' own heads, the spans, then
+        the rows' own tails, so that a row meets its blocks in ascending
+        order; the empty ones are not in the list."""
+        rec = (i * SLOTS + j) * WALK
+        slot = walk_ref[rec + SLOT]
+        shared = jnp.logical_and(slot >= TILE, slot < TILE + 2)
+
+        @pl.when(shared)
         def _span():
-            # the tile's rows at one kv head are whole (8, 128) tiles of
-            # float32: the fold to [TILE * group, w] moves nothing.  A
-            # run's rows need no look at each row (a chunk's tiles are
-            # most of a prompt-heavy tick's)
-            walk(0, TILE * group,
-                 lambda h: q_ref[:, heads(h), :].reshape(TILE * group, w),
-                 tbl, blk0, blk1, kv_end,
-                 *jax.lax.cond(run != 0, of_a_run, of_each_row))
+            span_walk(rec, slot - TILE)
 
-    def part(ph, _):
-        """The rows' own heads, the spans, then the rows' own tails: a row
-        meets its blocks in ascending order.  A tile with nothing to share
-        has empty heads and spans, and its tails are the rows' whole
-        walks, one after another, ``group`` query rows a matmul."""
-        def row(t, _):
-            r = row0 + t
-            blk0 = rows_ref[r * 4 + 2 * ph]
-            blk1 = rows_ref[r * 4 + 2 * ph + 1]
-            walk(pl.multiple_of(t * group, 8), group,
-                 lambda h: q_ref[t, heads(h), :], idx_ref[r], blk0, blk1,
-                 row_end(r), pos_ref[r], row_end(r))
+        @pl.when(jnp.logical_not(shared))
+        def _row():
+            row_walk(rec, jnp.where(slot < TILE, slot, slot - TILE - 2))
 
-        # a part no row of the tile takes is not looped over: a tile of
-        # decode rows that share nothing is its rows' tails alone
-        @pl.when(part_ref[i * 3 + 2 * ph] != 0)
-        def _rows():
-            jax.lax.fori_loop(0, TILE, row, None)
-
-        @pl.when(jnp.logical_and(ph == 0, part_ref[i * 3 + 1] != 0))
-        def _spans():
-            jax.lax.fori_loop(0, 2, span_walk, None)
-
-    jax.lax.fori_loop(0, 2, part, None)
+    jax.lax.fori_loop(0, count_ref[i], one, None)
 
     l = l_s[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -713,7 +829,7 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         shares = tile_shares(tables, table_index, positions, horizons,
                              window=sliding_window, page=page_size,
                              row_bytes=row * arr.dtype.itemsize)
-    assert shares.rows.shape == (tiles * TILE, 4), (shares.rows.shape, r)
+    assert shares.order.shape == (tiles, SLOTS, WALK), (shares.order.shape, r)
     buf_shape = (2, pps, page_size, row)
     rows = TILE * gp
 
@@ -734,7 +850,7 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
         operands += [_scale_rows(pool.scale)]
         scratch += [pltpu.SMEM((2, pps, 2, 128), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=8,
+        num_scalar_prefetch=7,
         grid=(tiles,),
         in_specs=in_specs,
         out_specs=tile_spec,
@@ -771,9 +887,8 @@ def _paged_call(q, pool, tables, table_index, positions, horizons,
             vmem_limit_bytes=max(2 * vmem, 16 << 20)),
         interpret=interpret,
         name="paged_attention",
-    )(tables.astype(jnp.int32), table_index, positions, horizons,
-      shares.rows.reshape(-1), shares.spans.reshape(-1),
-      shares.parts.reshape(-1),
+    )(tables.astype(jnp.int32), positions, horizons,
+      shares.spans.reshape(-1), shares.order.reshape(-1), shares.count,
       jnp.asarray(page_base, jnp.int32).reshape(1), *operands)
     # what was padded is sliced off again; the pair read whole: its value
     # lanes are the output

@@ -75,6 +75,10 @@ def check_case(case) -> None:
     # LFM2-24B-A2B (32 / 8 x 64): a group of 4 padded to 8 rows and a head
     # of 64 read as its 128-lane pair, on several kv heads
     dict(n=32, nkv=8, d=64, page=16, max_pages=24, context=300, block=4),
+    # Ouro-2.6B (16 query heads, a K/V head each: a group of ONE padded to 8
+    # rows): decode rows of two and three blocks, each walk's first block
+    # started by the walk before it
+    dict(n=16, nkv=16, d=128, page=16, max_pages=24, context=300),
     # rows that fill no whole number of tiles: 19, the wrapper pads dead
     # ones behind them and slices them off again
     dict(n=32, nkv=4, d=128, page=16, dtype=jnp.float32, max_pages=24,

@@ -21,9 +21,11 @@ import pytest
 
 from megatron_llm_tpu.generation import ContinuousBatchingEngine, DraftModel
 from megatron_llm_tpu.generation import ragged
+from megatron_llm_tpu.ops.pallas import paged_attention as pk
 from megatron_llm_tpu.ops.pallas.paged_attention import (
     SHARE_BLOCKS,
     SHARE_ROWS,
+    SLOTS,
     TILE,
     tile_shares,
 )
@@ -54,6 +56,14 @@ RULE = {
               [(0, 0, 2, 6)] * 3 + [(0, 0, 2, 3)] + [(0, 0, 2, 6)] * 4),
     "verify": (False, (0, 8, 0, 5), None, [(0, 0, 5, 6)] * 8),
     "none": (False, None, None, [(0, 0, 0, 4)] * 8),
+    # rows that share nothing, dead rows between them: walks of four, two,
+    # one and three blocks, so that the first blocks of two successive walks
+    # land in the SAME half of the page buffer (behind an even walk) and in
+    # the other; and a tile of one block a walk
+    "gaps": (False, None, None,
+             [(0, 0, 0, 4), (0,) * 4, (0,) * 4, (0, 0, 0, 2), (0,) * 4,
+              (0, 0, 0, 1), (0, 0, 0, 3), (0,) * 4]),
+    "single": (False, None, None, [(0, 0, 0, 1)] * 8),
     # windows of four blocks: four rows' open in block 1, four in block 2,
     # whose slots behind the window name the null page: block 3 is the
     # first in which all eight agree
@@ -73,6 +83,16 @@ RULE = {
              [(0, 0, 3, 3)] * 5 + [(0, 0, 0, 0)] * 3),
     "window_commit": (True, (0, 8, 2, 8, 7 * BK + 2), None,
                       [(2, 2, 7, 7)] * 4 + [(3, 3, 8, 8)] * 4),
+    # under the window: rows that share nothing, whose walks start at an
+    # odd, an even and an odd block; and a span of five rows of which three
+    # walk a block of their own in front of it and two (their windows open
+    # where the span begins) have NO head, dead rows between them
+    "window_gaps": (True, None, None,
+                    [(1, 1, 1, 6), (0,) * 4, (2, 2, 2, 7), (0,) * 4,
+                     (0,) * 4, (1, 1, 1, 5), (0,) * 4, (0,) * 4]),
+    "window_heads": (True, (0, 8, 2, 5), None,
+                     [(1, 2, 5, 6), (0,) * 4, (1, 2, 5, 6), (2, 2, 5, 6),
+                      (0,) * 4, (1, 2, 5, 6), (2, 2, 5, 6), (0,) * 4]),
 }
 # the compute blocks under a scenario's masks, those its walks fetch, and
 # its walks, by hand: a row that walks a block alone is a walk, a span that
@@ -83,7 +103,27 @@ COUNTS = {
     "window": (40, 26, 8), "window_two": (40, 22, 8),
     "block": (32, 8, 1), "commit": (60, 8, 1), "tail": (15, 3, 1),
     "window_commit": (40, 6, 1),
+    "gaps": (10, 10, 4), "single": (8, 8, 8),
+    "window_gaps": (14, 14, 3), "window_heads": (23, 11, 5),
 }
+# the scenarios in which every walk has a first block of its own (no span of
+# several tables stands in front of rows with no head): alone in a call, all
+# of its walks but the first find their first block started
+EVERY_WALK_ITS_OWN_START = {
+    "none", "gaps", "single", "block", "commit", "tail", "window",
+    "window_two", "window_commit", "window_gaps", "window_heads"}
+
+
+def _walks_by_hand(tile: int, rows, spans):
+    """A tile's non-empty walks in the order its program takes them, as
+    ``(tile, slot, blk0, blk1)``: the rows' own heads, the spans, the rows'
+    own tails."""
+    heads = [(tile, t, b0, lo) for t, (b0, lo, _, _) in enumerate(rows)]
+    both = [(tile, TILE + s, span[3], span[4])
+            for s, span in enumerate(spans)]
+    tails = [(tile, TILE + 2 + t, hi, b1)
+             for t, (_, _, hi, b1) in enumerate(rows)]
+    return [w for w in heads + both + tails if w[3] > w[2]]
 
 
 @functools.lru_cache(maxsize=None)
@@ -124,11 +164,15 @@ def test_tile_shares_rule(scenario):
                 # the span's walk reads its first live row's table
                 live = scenarios[scenario] - TILE * at
                 assert table == plan[1][TILE * at + live[live >= first][0]]
-        # the parts of its program the tile takes: heads, spans, tails
-        assert np.asarray(shares.parts)[at].tolist() == [
-            int(any(lo > b0 for b0, lo, _, _ in rows)),
-            int(bool(span_a or span_b)),
-            int(any(b1 > hi for _, _, hi, b1 in rows))]
+        # the walks of its program: heads, spans, tails, the empty ones
+        # left out
+        count = int(np.asarray(shares.count)[at])
+        order = np.asarray(shares.order)[at]
+        assert [(at, *w) for w in order[:count, [
+            pk.SLOT, pk.BLK0, pk.BLK1]].tolist()] == _walks_by_hand(
+                at, rows, [(0, *s[:4]) if s else (0,) * 5
+                           for s in (span_a, span_b)])
+        assert not order[count:].any()
     assert isinstance(tile_shares(*plan[:4], **plan[4]).rows, np.ndarray)
     seen = sum(max(0, b1 - b0) for b0, _, _, b1 in rows)
     alone = sum(lo - b0 + b1 - hi for b0, lo, hi, b1 in rows)
@@ -137,6 +181,52 @@ def test_tile_shares_rule(scenario):
                         for i, a in enumerate(plan[:4])), **plan[4])
     assert tuple(int(n) for n in one.blocks()) == (seen, alone + spans)
     assert (seen, alone + spans, int(one.walks())) == COUNTS[scenario]
+    # a call's first walk starts its own first block
+    assert 0 <= one.carried() <= one.walks() - 1
+    if scenario in EVERY_WALK_ITS_OWN_START:
+        assert one.carried() == one.walks() - 1
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+def test_walk_order_names_every_walk_once_in_program_order(window):
+    """The successors (``SUCC``) from the call's first walk on: every
+    non-empty walk of every tile once, in the order the programs take them,
+    across tiles with none (the call's last tile is dead rows); each but
+    the first finds its first block started, in the half the call's blocks
+    before it leave free, by the table and the last key that are its own;
+    the call's last walk starts nothing."""
+    tables, idx, pos, hor, kw = _case("pair128", window)[3]
+    # a tile of dead rows behind the call's second tile too
+    idx, pos, hor = (np.insert(a, [2 * TILE] * TILE, 0)
+                     for a in (idx, pos, hor))
+    shares = tile_shares(tables, idx, pos, hor, **kw)
+    rows = shares.rows.reshape(-1, TILE, 4)
+    want = [w for tile in range(rows.shape[0]) for w in _walks_by_hand(
+        tile, rows[tile].tolist(), shares.spans[tile].tolist())]
+    assert shares.count.tolist() == [
+        sum(w[0] == tile for w in want) for tile in range(rows.shape[0])]
+    assert shares.count[-1] == 0 and shares.count[2] == 0
+    flat = shares.order.reshape(-1, pk.WALK)
+    kv_end = np.where(hor > 0, np.minimum(hor, pos + 1), 0)
+    at, got, blocks = int(np.flatnonzero(shares.count)[0]) * SLOTS, [], 0
+    while at >= 0:
+        tbl, blk0, blk1, end, slot, half, carried, succ = flat[at].tolist()
+        tile = at // SLOTS
+        assert at % SLOTS < shares.count[tile]
+        assert (half, carried) == (blocks % 2, int(bool(got)))
+        if TILE <= slot < TILE + 2:
+            assert (tbl, end) == tuple(
+                shares.spans[tile, slot - TILE, [0, 5]])
+        else:
+            row = tile * TILE + (slot if slot < TILE else slot - TILE - 2)
+            assert (tbl, end) == (idx[row], kv_end[row])
+        got.append((tile, slot, blk0, blk1))
+        blocks += blk1 - blk0
+        at = succ
+    assert got == want
+    # both parities of a walk's length, so both halves of a successor's
+    assert {(b1 - b0) % 2 for _, _, b0, b1 in want} == {0, 1}
+    assert 0 <= shares.carried() <= shares.walks() - 1
 
 
 def test_tile_shares_run_is_one_span_and_few_rows_walk_alone():
@@ -150,14 +240,18 @@ def test_tile_shares_run_is_one_span_and_few_rows_walk_alone():
                       np.full(8, 320), window=None, page=8, row_bytes=2048)
     assert run.spans[0].tolist() == [[1, 0, 8, 0, 3, 308, 1], [0] * 7]
     assert run.rows.tolist() == [[0, 0, 3, 3]] * 8
-    assert run.parts.tolist() == [[0, 1, 0]]
+    assert run.count.tolist() == [1]
+    assert run.order[0, 0].tolist() == [1, 0, 3, 308, TILE, 0, 0, -1]
+    assert (int(run.walks()), int(run.carried())) == (1, 0)
     assert tuple(int(n) for n in run.blocks()) == (24, 3)
     two = tile_shares(tables, np.array([1, 2, 0, 0, 0, 0, 0, 0]),
                       np.array([400, 410, 0, 0, 0, 0, 0, 0]),
                       np.array([448, 448, 0, 0, 0, 0, 0, 0]),
                       window=None, page=8, row_bytes=2048)
     assert tuple(int(n) for n in two.blocks()) == (8, 8)
-    assert two.parts.tolist() == [[0, 0, 1]]
+    assert two.order[0, :, pk.SLOT].tolist() == [
+        TILE + 2, TILE + 3] + [0] * (SLOTS - 2)
+    assert (int(two.walks()), int(two.carried())) == (2, 1)
 
 
 def test_plan_walks_is_the_kernels_own_reading_or_nothing(monkeypatch):
@@ -212,12 +306,15 @@ def test_shared_walk_is_the_one_row_walk(scenario):
 
 @pytest.mark.parametrize("geometry,scenario", [
     ("pair64", "two"), ("latent", "window_two"), ("int8", "two"),
-    ("pair64", "commit"), ("latent", "window_commit"), ("int8", "block")])
+    ("pair64", "commit"), ("latent", "window_commit"), ("int8", "block"),
+    ("pair64", "single"), ("latent", "window_heads"), ("int8", "gaps"),
+    ("latent", "gaps"), ("int8", "window_gaps")])
 def test_shared_walk_is_the_one_row_walk_at_every_row_kind(
         geometry, scenario):
-    """Two spans in one tile, and one table's rows at two positions, on the
-    pair of 64s read whole, on a latent row (under a window) and on int8
-    pages with their scales."""
+    """Two spans in one tile, one table's rows at two positions, and walks
+    that start one another's first block across dead rows, empty heads and
+    a dead tile, on the pair of 64s read whole, on a latent row (under a
+    window) and on int8 pages with their scales."""
     _check(geometry, scenario, only=(scenario,))
 
 
@@ -336,15 +433,16 @@ def _counters():
     reg = registry_mod.get_registry()
     return np.array([reg.counter(f"mlt_engine_paged_{name}_total").value
                      for name in ("blocks_seen", "blocks_fetched", "rows",
-                                  "walks")])
+                                  "walks", "carried_walks")])
 
 
 def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
     """``mlt_engine_paged_blocks_seen_total`` / ``_fetched_total`` rise by
     what ``tile_shares`` gives for each launched tick's plan times the
     layers, ``_rows_total`` / ``_walks_total`` by its live rows and its
-    ``walks()``; requests on one primed prefix of two compute blocks and
-    more fetch fewer blocks than their rows see, tick after tick."""
+    ``walks()``, ``_carried_walks_total`` by its ``carried()``; requests on
+    one primed prefix of two compute blocks and more fetch fewer blocks
+    than their rows see, tick after tick."""
     from megatron_llm_tpu.generation import engine as engine_mod
 
     cfg, params = models["cfg"], models["params"]
@@ -356,7 +454,8 @@ def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
     def spy(*args, **kw):
         shares = tile_shares(*args, **kw)
         given.append([layers * int(n) for n in shares.blocks()]
-                     + [int((args[3] > 0).sum()), int(shares.walks())])
+                     + [int((args[3] > 0).sum()), int(shares.walks()),
+                        int(shares.carried())])
         return shares
 
     monkeypatch.setattr(engine_mod, "tile_shares", spy)
@@ -371,11 +470,13 @@ def test_engine_counts_blocks_by_the_kernels_rule(models, monkeypatch):
     eng.run_until_idle()
     for r in reqs:
         r.result(timeout=5)
-    seen, fetched, rows, walks = _counters() - before
-    assert [seen, fetched, rows, walks] == np.sum(
+    seen, fetched, rows, walks, carried = _counters() - before
+    assert [seen, fetched, rows, walks, carried] == np.sum(
         given[ticks:], axis=0).tolist()
     # a prompt's rows of one table are walked together, a decode row alone
     assert 0 < walks < rows
+    # every tick's first walk starts its own first block
+    assert 0 < carried <= walks - (len(given) - ticks)
     # four decode rows on one prefix: two blocks walked once, not four
     # times, in every tick that held three of them or more
     decode = [g for g in given[ticks:] if g[0] - g[1] >= 2 * layers * 2]
@@ -395,7 +496,7 @@ def test_engine_unshared_plan_counts_the_runs_saving_alone(models):
     eng.run_until_idle()
     for r in reqs:
         r.result(timeout=5)
-    seen, fetched, rows, walks = _counters() - before
-    assert rows > walks > 0
+    seen, fetched, rows, walks, carried = _counters() - before
+    assert rows > walks > carried > 0
     assert seen == cfg.model.num_layers * rows
     assert seen - fetched == cfg.model.num_layers * (rows - walks)

@@ -502,6 +502,10 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
     * ``verify``: a verify block (four rows of one table at consecutive
       positions) and four decode rows, all on A;
     * ``none``: eight rows that share nothing;
+    * ``gaps``: four rows that share nothing, of four, two, one and three
+      blocks, dead rows between them: walks whose first blocks follow one
+      another in the SAME half of the page buffer and in the other;
+    * ``single``: eight rows of one block each;
 
     then tiles whose live rows name ONE table, walked whole whatever their
     positions (a context of eight blocks unless said):
@@ -522,7 +526,14 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
       their own before the span's;
     * ``window_two``: four rows on A, four on B;
     * ``window_commit``: ``commit`` with the first four rows' window
-      opening in the third block and the others' in the fourth.
+      opening in the third block and the others' in the fourth;
+    * ``window_gaps``: three rows that share nothing, dead rows between
+      them, their walks from the second, the third and the second block;
+    * ``window_heads``: five rows on A, dead rows between them; three
+      windows open inside the second block (a block of their own in front
+      of the span's), two where the third begins (none).
+
+    A tile of dead rows ends every call.
     """
     import numpy as np
 
@@ -547,6 +558,11 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             + [("B", end + bk // 2 + 9 * i) for i in range(4)],
             "window_commit": [("A", 7 * bk - 3, "c")] * 4
             + [("A", 7 * bk + 1, "c")] * 4,
+            "window_gaps": [(None, end + 3), dead, (None, end + bk + 7),
+                            dead, dead, (None, 5 * bk - 1), dead, dead],
+            "window_heads": [("A", end + 5), dead, ("A", end + 16),
+                             ("A", 2 * bk + w - 1), dead, ("A", end + 27),
+                             ("A", 2 * bk + w - 1), dead],
         }
     else:
         scenes = {
@@ -560,6 +576,9 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             "verify": [("A", end + 20 + i, "v") for i in range(4)]
             + [("A", end + 17 * i) for i in range(4)],
             "none": [(None, 3 * bk + 11 * i) for i in range(T)],
+            "gaps": [(None, 3 * bk + 5), dead, dead, (None, bk + 9), dead,
+                     (None, 40), (None, 2 * bk + 1), dead],
+            "single": [(None, 20 + 11 * i) for i in range(T)],
             "block": [dead] * 4 + [("A", 7 * bk + 19, "b")] * 4,
             "commit": [("A", 7 * bk - 1, "c")] * 4
             + [("A", 7 * bk + 3, "c")] * 4,
@@ -567,7 +586,7 @@ def share_case(seed: int, *, n: int, nkv: int, d: int, page: int,
             + [dead] * 3,
         }
     scenes = {k: v for k, v in scenes.items() if only is None or k in only}
-    rows = [r for scene in scenes.values() for r in scene]
+    rows = [r for scene in scenes.values() for r in scene] + [dead] * T
     at, scenarios = 0, {}
     for name, scene in scenes.items():
         scenarios[name] = np.array(
@@ -634,18 +653,26 @@ OURO = dict(n=16, nkv=16, d=128, page=16)
 LATENT = dict(n=32, nkv=1, d=640, page=16, latent=True)
 
 # name: geometry, slots, live decode rows, their contexts from .. to, where
-# the chunk starts, table width, pool pages, window
+# the chunk starts, table width, pool pages, window, the chunk's rows
 TICKS = {
-    "falcon": (FALCON, 128, 49, 300, 700, 192, 128, 128 * 128 + 1, None),
-    "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 32 * 256 + 1, 4096),
+    "falcon": (FALCON, 128, 49, 300, 700, 192, 128, 128 * 128 + 1, None, 64),
+    "mistral": (MISTRAL, 32, 24, 256, 1536, 512, 256, 32 * 256 + 1, 4096,
+                64),
     # the agent cell's tick (PERF.md section 5): every row past 16k tokens,
     # under the full layers' mask and under the window layers'
-    "commanda": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289, None),
+    "commanda": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289, None,
+                 64),
     "commanda_window": (COMMANDA, 64, 55, 16400, 17400, 16400, 1112, 12289,
-                        4096),
+                        4096, 64),
     # the JoyAI cell's tick: the Falcon cell's traffic on the latent row,
     # 103 of 128 slots live (`slot_occupancy.batch` 80.8)
-    "joyai": (LATENT, 128, 103, 300, 700, 192, 128, 128 * 128 + 1, None),
+    "joyai": (LATENT, 128, 103, 300, 700, 192, 128, 128 * 128 + 1, None, 64),
+    # the Ouro cell's tick: 16 slots, all live, on the pool's 320 pages; a
+    # row's walk is two to four blocks of 128 tokens, 1 MiB each
+    "ouro": (OURO, 16, 16, 160, 512, 128, 32, 320 + 1, None, 64),
+    # and its decode tick, the program of NO prompt rows: two tiles, no
+    # dead tile behind them (the cell's ticks are 70% such)
+    "ouro_decode": (OURO, 16, 16, 160, 512, 128, 32, 320 + 1, None, 0),
 }
 
 
@@ -720,16 +747,17 @@ def walks_until_pr60(args, kw):
     now = pk.tile_shares(tables, idx, pos, hor, window=kw["sliding_window"],
                          page=page, row_bytes=row)
     rows = now.rows.reshape(-1, pk.TILE, 4).copy()
-    spans, parts = now.spans.copy(), now.parts.copy()
+    spans = now.spans.copy()
     bk = pk._pages_per_step(page, row) * page
-    kv_end = np.where(hor > 0, np.minimum(hor, pos + 1), 1 << 30)
-    below = kv_end.reshape(-1, pk.TILE).min(axis=1) // bk
+    kv_end = np.where(hor > 0, np.minimum(hor, pos + 1), 0)
+    below = np.where(kv_end > 0, kv_end, 1 << 30).reshape(
+        -1, pk.TILE).min(axis=1) // bk
     assert (spans[:, 0, 3] == 0).all() and not spans[:, 1].any()
     rows[..., 2] = np.minimum(rows[..., 2], below[:, None])
     spans[:, 0, 4], spans[:, 0, 5] = below, below * bk
-    parts[:, 2] = (rows[..., 3] > rows[..., 2]).any(axis=1)
+    rows = rows.reshape(-1, 4)
     return pk.TileShares(*(jnp.asarray(a) for a in (
-        rows.reshape(-1, 4), spans, parts)))
+        rows, spans, *pk.walk_order(rows, spans, idx, kv_end))))
 
 
 @functools.lru_cache(maxsize=1)
@@ -774,9 +802,12 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True,
     of a window page class (the slots wholly behind a table's window name
     the null page).  ``joyai``: Falcon's slots and contexts on one latent
     row of 640 lanes, 103 decode rows live; its bytes are the 576 values of
-    a row read once.  ``width`` overrides the table width (same contexts);
-    ``chunk_live`` false leaves the chunk's rows dead: the decode rows
-    alone.  A name of ``BLOCK_TICKS``: :func:`block_tick_case`.
+    a row read once.  ``ouro``: 16 slots + 64 chunk rows over 32 page
+    slots, 16 kv heads of 128 with a query head each; 16 decode rows at
+    160-512; ``ouro_decode`` the same with no chunk's rows at all, the
+    program of the cell's decode ticks.  ``width`` overrides the table
+    width (same contexts); ``chunk_live`` false leaves the chunk's rows
+    dead: the decode rows alone.  A name of ``BLOCK_TICKS``: :func:`block_tick_case`.
     """
     import numpy as np
 
@@ -784,13 +815,12 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True,
         return block_tick_case(seed, name)
     name, prefixes, shared = SHARED_TICKS.get(name, (name, 0, 0))
     (geo, slots, live, lo, hi, chunk_at, slots_wide, num_pages,
-     window) = TICKS[name]
+     window, chunk) = TICKS[name]
     n, nkv, d, page = (geo[k] for k in ("n", "nkv", "d", "page"))
     latent = geo.get("latent", False)
     width = width or slots_wide
     rng = np.random.default_rng(seed)
     pool = _tick_pool(seed, num_pages, page, nkv, d, latent)
-    chunk = 64
     pos = np.zeros(slots + chunk, np.int64)
     idx = np.full(slots + chunk, slots + 1)
     rows = rng.permutation(slots)[:live]
@@ -826,7 +856,7 @@ def tick_case(seed: int, name: str, width=None, chunk_live: bool = True,
     hor = np.where(idx <= slots, (pos // 64 + 1) * 64, 0)
     q = jnp.asarray(rng.normal(size=(slots + chunk, 1, n, d)), jnp.bfloat16)
     visible = pos[at] + 1
-    keys = chunk_at + chunk if chunk_live else 0
+    keys = chunk_at + chunk if chunk_live and chunk else 0
     if window:
         visible = np.minimum(visible, window)
         keys = min(keys, window - 1 + chunk)
@@ -1058,7 +1088,8 @@ def paged_timing():
     # rows alone
     cases = [(name, width, True, False) for name in TICKS
              for width in ((128, 256) if name == "falcon" else (None,))]
-    cases += [(name, None, False, False) for name in TICKS]
+    cases += [(name, None, False, False) for name, tick in TICKS.items()
+              if tick[-1]]
     # the shared prefixes' ticks in slot order (no tile agrees) and in the
     # order the tick runs them in
     cases += [(name, None, chunk_live, ordered) for name in SHARED_TICKS
