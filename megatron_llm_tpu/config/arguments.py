@@ -149,6 +149,12 @@ class ModelConfig:
     moe_selection_bias: bool = False
     # ... and what multiplies the (renormalised) weights of the chosen
     moe_routed_scaling_factor: float = 1.0
+    # group-limited selection (DeepSeek-V3's `n_group` / `topk_group`): the
+    # experts stand in `moe_n_group` equal groups, a group's score is the
+    # sum of its two largest selection scores, and the top-k is taken among
+    # the experts of the `moe_topk_group` best groups.  1 / 1: no groups
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # what `moe_normalize_gates` adds to the chosen scores' sum (DeepSeek-V3's
     # modelling code: 1e-20; LFM2's: 1e-6)
     moe_gate_eps: float = 1e-20
@@ -213,6 +219,21 @@ class ModelConfig:
     # an elementwise sigmoid gate on the attention's output, read from the
     # layer's normed input (`gated_attention`; the leaf `attention/g_proj`)
     attention_output_gate: bool = False
+    # ... or ONE value a head (the "headwise" form of the same paper; the
+    # leaf is then [h, heads]): A.X-K2's head-specific gate
+    attention_gate_headwise: bool = False
+    # --- learned sparse attention (the DeepSeek-V3.2 lightning indexer,
+    # models/sparse_mla.py): `index_n_heads` index queries of
+    # `index_head_dim` a token score every earlier token's ONE cached index
+    # key, and a query attends the `index_topk` best-scored latent rows
+    # alone (all of them up to that context).  None: every key is read
+    index_topk: Optional[int] = None
+    index_n_heads: Optional[int] = None
+    index_head_dim: Optional[int] = None
+    # a rank-`gated_norm_rank` sigmoid gate on the output of a layer's two
+    # norms and of the final norm (ops/norms.py `gated_norm`)
+    gated_norm: bool = False
+    gated_norm_rank: int = 16
     # --- linear layers: the gated delta rule (ops/gated_delta.py) ---
     # one period of the scanned stack: entry j says whether the layers l
     # with l % period == j are LINEAR layers (1: a gated-delta mixer on a
@@ -1031,7 +1052,7 @@ class Config:
             assert self.model_name in (
                 "gpt", "llama", "llama2", "codellama", "llama3", "falcon",
                 "mistral", "mixtral", "joyai", "smallthinker", "commanda",
-                "gigachat35", "nemotron_h", "lfm2", "sdar_moe",
+                "gigachat35", "nemotron_h", "lfm2", "sdar_moe", "axk2",
             ), (
                 "MoE is supported for the GPT/Llama-family decoder models "
                 "only — the BERT/T5/biencoder loss paths do not consume the "
@@ -1360,6 +1381,40 @@ ARCH_DEFAULTS = {
         post_sublayer_norms=True,
         loop_steps=4,
     ),
+    # A.X-K2 (beyond-reference; skt's `axk2`): the DeepSeek-V3.2 block
+    # (latent attention whose every query attends the `index_topk` rows a
+    # learned indexer scores best; one leading dense layer; 256 routed
+    # experts top-8 by a bias-corrected sigmoid router limited to the best
+    # groups, one shared expert) under the model's own two sublayers: a
+    # sigmoid gate a head on the attention's output and a low-rank sigmoid
+    # gate on the layer's two norms and the final norm; YaRN (factor 2 over
+    # 131,072) on the 64 rope dims
+    "axk2": dict(
+        use_rms_norm=True,
+        glu_activation="swiglu",
+        use_bias=False,
+        tie_embed_logits=False,
+        position_embedding_type="rotary",
+        layernorm_epsilon=1e-6,
+        rope_theta=1_000_000.0,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=2.0,
+        rope_yarn_beta_fast=32.0,
+        rope_yarn_beta_slow=1.0,
+        rope_yarn_original_max_position=131072,
+        rope_yarn_mscale_all_dim=1.0,
+        attention_type="mla",
+        attention_output_gate=True,
+        attention_gate_headwise=True,
+        gated_norm=True,
+        gated_norm_rank=16,
+        moe_score_func="sigmoid",
+        moe_selection_bias=True,
+        moe_normalize_gates=True,
+        moe_routed_scaling_factor=2.5,
+        moe_shared_experts=1,
+        dense_prefix_layers=1,
+    ),
     # Qwen2/2.5 (beyond-reference): llama2 block + bias on the QKV
     # projection only + rope_theta 1e6; small checkpoints (<=1.5B) tie
     # embeddings, which config_from_hf passes through
@@ -1500,6 +1555,15 @@ MODEL_SIZES = {
         num_attention_heads=16, num_attention_heads_kv=16, kv_channels=128,
         max_position_embeddings=65536, ffn_hidden_size=5632,
         vocab_size=49152, loop_steps=4),
+    # A.X-K2 688B-A33B: 61 published layers = 1 dense + 60 of experts
+    "a.x-k2": dict(num_layers=60, hidden_size=7168, num_attention_heads=64,
+                   ffn_hidden_size=18432, max_position_embeddings=262144,
+                   q_lora_rank=1536, kv_lora_rank=512,
+                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                   index_n_heads=64, index_head_dim=128, index_topk=2048,
+                   num_experts=256, moe_router_topk=8,
+                   moe_ffn_hidden_size=2048, moe_n_group=8, moe_topk_group=4,
+                   vocab_size=163840),
     # 32 layers = 8 periods of (window, window, window, full NoPE)
     "commanda-plus": dict(num_layers=32, hidden_size=4096,
                           num_attention_heads=128, num_attention_heads_kv=8,
@@ -1511,7 +1575,7 @@ MODEL_SIZES = {
 
 
 # a canonical size whose family is not the name's first word
-SIZE_FAMILIES = {"sdar-30b-a3b-chat": "sdar_moe"}
+SIZE_FAMILIES = {"sdar-30b-a3b-chat": "sdar_moe", "a.x-k2": "axk2"}
 
 
 def apply_architecture(cfg: Config, model_name: str, size: Optional[str] = None) -> Config:
@@ -1620,7 +1684,7 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     )
     parser.add_argument("--model_name", type=str, default=None,
                         help="gpt|llama|llama2|codellama|llama3|falcon|"
-                             "mistral|mixtral|qwen2|joyai|smallthinker|gigachat35|ouro|"
+                             "mistral|mixtral|qwen2|joyai|smallthinker|gigachat35|ouro|axk2|"
                              "bert|t5 "
                              "or a canonical size like llama2-7b / "
                              "llama3-8b")

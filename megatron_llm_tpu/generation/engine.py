@@ -920,6 +920,24 @@ class ContinuousBatchingEngine:
                  "carried()): every walk of a call but its first. Carried "
                  "over walks is the share of walks that start with their "
                  "pages on the way")
+        # learned sparse attention (models/sparse_mla.py): what the indexer
+        # scored and what the attention then read, by the program's own rule
+        self._m_sparse = {
+            "rows": reg.counter(
+                "mlt_engine_sparse_rows_total",
+                help="live rows of the launched ticks that SELECTED: whose "
+                     "context is longer than index_topk (a shorter one "
+                     "attends all of it); 0 for a model with no indexer"),
+            "scored": reg.counter(
+                "mlt_engine_sparse_keys_scored_total",
+                help="index keys those rows' indexer scored (a row's whole "
+                     "context), times the attention layers"),
+            "attended": reg.counter(
+                "mlt_engine_sparse_keys_attended_total",
+                help="latent rows those rows then attended (index_topk a "
+                     "row), times the attention layers; over the keys "
+                     "scored, the share of its context a query reads"),
+        } if cfg.model.index_topk else None
         self._m_paged_seen = reg.counter(
             "mlt_engine_paged_blocks_seen_total",
             help="compute blocks (the kernel's step: several pages) under "
@@ -2794,6 +2812,14 @@ class ContinuousBatchingEngine:
         its own rule (``tile_shares``): ``tables`` one a page class, the
         null row first; ``rows`` each row's table, position and horizon."""
         self._m_paged_rows.inc(int((rows[2] > 0).sum()))
+        if self._m_sparse is not None:   # picked rows are gathered: no walk
+            topk = self.cfg.model.index_topk
+            ctx = (rows[1] + 1)[rows[2] > 0]
+            ctx, layers = ctx[ctx > topk], self._walked[0][0]
+            self._m_sparse["rows"].inc(int(ctx.size))
+            self._m_sparse["scored"].inc(layers * int(ctx.sum()))
+            self._m_sparse["attended"].inc(layers * topk * int(ctx.size))
+            return
         for k, (table, (layers, window, row)) in enumerate(
                 zip(tables, self._walked)):
             shares = tile_shares(table, *rows, window=window,

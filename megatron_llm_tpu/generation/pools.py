@@ -56,7 +56,9 @@ NULL_PAGE = 0
 # A row is (kind of per-sequence memory, feature) -> why the feature does
 # not carry that kind yet.  The kinds are what ``cfg``'s pool classes show
 # (:func:`memory_kind`): ``classes`` (a full and a window page class),
-# ``latent`` (MLA's one-leaf pool), ``state`` (power retention's state
+# ``latent`` (MLA's one-leaf pool), ``indexed`` (latent rows AND index
+# keys, two leaves under one page id: learned sparse attention), ``state``
+# (power retention's state
 # slots), ``hybrid`` (a state class BESIDE a page class: a sequence holds a
 # slot and pages), ``tails`` (the same beside a state class of conv tails
 # alone: gated short convolutions, no recurrent state), ``blocks`` (one
@@ -82,6 +84,9 @@ KEEPS = {
                 "classes"),
     "latent": ("latent attention (attention_type 'mla') keeps ONE latent "
                "row a token, key and value at once"),
+    "indexed": ("learned sparse attention (index_topk {topk}) keeps a "
+                "latent row AND an index key a token, two leaves under one "
+                "page id"),
     "state": ("power retention (attention_type 'retention') keeps a "
               "constant-size recurrent state a sequence"),
     "hybrid": ("a hybrid stack ({stack}) keeps a recurrent state a sequence "
@@ -143,6 +148,23 @@ NOT_CARRIED = {
     ("latent", "handoff"): (
         "the cross-replica KV handoff: its wire format names a K and a V "
         "leaf"),
+    ("indexed", "kv_dtype"): (
+        "--kv_dtype {kv_dtype}: the indexer's scores are made from the "
+        "index keys as they were written, a page's scales are kept per KV "
+        "head, and neither leaf has one"),
+    ("indexed", "tp"): (
+        "tensor-parallel serving (tp {tp}): the pool shards over KV heads, "
+        "and neither a latent row nor an index key has one (the indexer's "
+        "own heads are not sharded either)"),
+    ("indexed", "pp"): (
+        "pipeline-parallel serving (pp {pp}): the stage pipeline hands ONE "
+        "paged leaf from stage to stage, and this pool keeps two"),
+    ("indexed", "draft"): (
+        "--spec_k: the verify tick and the draft cache are built for a K/V "
+        "pool, and a draft model would need an indexer of its own"),
+    ("indexed", "handoff"): (
+        "the cross-replica KV handoff: its wire format names a K and a V "
+        "leaf, and an index key is neither"),
     ("state", "pattern"): (
         "a stack that mixes it with a page class (a window pattern, a dense "
         "prefix, latent attention): power retention's state is served "
@@ -267,6 +289,8 @@ def memory_kind(cfg) -> str:
         return "blocks"
     if cfg.model.loop_steps > 1:
         return "loop"
+    if cfg.model.mla and cfg.model.index_topk:
+        return "indexed"
     return "latent" if cfg.model.mla else "paged"
 
 
@@ -312,7 +336,8 @@ def refuse_unserved(cfg, *, kv_dtype: str = "bf16", mesh=None,
         stack=(f"sublayer_pattern {m.sublayer_pattern}"
                if m.sublayer_pattern else f"linear_layout {m.linear_layout}"),
         rows="latent rows" if m.mla else "keys and values",
-        block=m.diffusion_block_length, loops=m.loop_steps)
+        block=m.diffusion_block_length, loops=m.loop_steps,
+        topk=m.index_topk)
     raise ValueError(
         f"{keeps}, which {why} "
         "does not carry yet. Serve this model on one chip with --kv_dtype "
@@ -439,6 +464,13 @@ class PagedKVPool:
             kv = kv_quant.make_pool(
                 (layers, num_pages, page_size, 1, self.head_dim), kv_dtype,
                 dtype)
+            if m.index_topk:
+                # learned sparse attention: a token's index key in a leaf
+                # of its own under the same page ids (a sweep over a
+                # sequence's index keys then reads them alone).  One
+                # allocation, one reference count, one trie entry a page
+                kv = kv_quant.IndexedLatent(rows=kv, index=jnp.zeros(
+                    (layers, num_pages, page_size, m.index_head_dim), dtype))
         else:
             self.head_dim = m.kv_channels
             kv = kv_quant.make_kv_pool(
@@ -576,6 +608,9 @@ class PagedKVPool:
         if kv_quant.is_quantized(self.kv):
             return ("kv", self.kv_dtype, str(self.kv.q.dtype),
                     str(self.kv.scale.dtype))
+        if isinstance(self.kv, kv_quant.IndexedLatent):
+            return ("kv", "indexed", str(self.kv.rows.dtype),
+                    self.kv.rows.shape[-1], self.kv.index.shape[-1])
         if self.latent:
             return ("kv", "latent", str(self.kv.dtype), self.kv.shape[-1])
         return ("kv", self.kv_dtype, str(self.kv.dtype))
@@ -604,7 +639,8 @@ class PagedKVPool:
         both).  For tests, tools and debugging — not the tick."""
         _, pool, d = self._pools()[int(draft)]
         ids = np.asarray(list(pages), np.int32)
-        got = jax.tree.map(lambda a: a[:, ids], pool)
+        got = jax.tree.map(lambda a: a[:, ids], kv_quant.values_of(pool)
+                           if self.latent else pool)
         heads = np.asarray(
             kv_quant.dequantize_pages(got, jnp.float32)
             if kv_quant.is_quantized(got) else kv_quant.heads_view(got, d))

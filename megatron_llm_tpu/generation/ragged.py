@@ -207,7 +207,7 @@ def make_ragged_tick_fn(cfg, draft_cfg, spec_k: int, prefill_rows: int,
     # worked out once a class in front of them (on one chip; a shard of
     # the heads or a stage's microbatch reads it off its own call)
     latent = bool(cfg.model.mla)
-    planned = {} if tp > 1 or ppc is not None else {
+    planned = {} if tp > 1 or ppc is not None or cfg.model.index_topk else {
         c: layer_kinds(cfg)[cls.places[0]].window
         for c, cls in enumerate(classes) if not cls.state}
 

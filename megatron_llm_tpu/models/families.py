@@ -196,6 +196,37 @@ def validate_family(cfg: Config) -> Config:
                "ouro is a dense sequential block")
         _check(not m.use_bias and not m.tie_embed_logits,
                "ouro has no biases and an untied head")
+    elif name == "axk2":
+        _check(m.mla and m.index_topk and m.index_n_heads
+               and m.index_head_dim,
+               "axk2 requires attention_type 'mla' under an indexer: give "
+               "index_topk, index_n_heads and index_head_dim")
+        _check(m.index_head_dim >= m.qk_rope_head_dim,
+               "axk2's index vectors rotate their first qk_rope_head_dim "
+               "values")
+        _check(m.attention_output_gate and m.attention_gate_headwise
+               and m.gated_norm and m.gated_norm_rank > 0,
+               "axk2 gates its attention's output a head and its norms "
+               "through a low-rank pair")
+        _check(m.num_experts is not None and m.num_experts > 1
+               and m.moe_score_func == "sigmoid" and m.moe_selection_bias
+               and m.moe_normalize_gates,
+               "axk2 routes by bias-corrected sigmoid scores, normalised "
+               "over the chosen")
+        _check(m.num_experts % m.moe_n_group == 0
+               and 0 < m.moe_topk_group <= m.moe_n_group
+               and m.moe_router_topk
+               <= m.moe_topk_group * (m.num_experts // m.moe_n_group),
+               "axk2's router picks among moe_topk_group of moe_n_group "
+               "equal groups of experts")
+        _check(m.use_rms_norm and m.glu_activation == "swiglu"
+               and not m.use_bias and not m.parallel_attn
+               and not m.tie_embed_logits,
+               "axk2 is a sequential RMSNorm / SwiGLU block without "
+               "biases and an untied head")
+        _check(m.position_embedding_type == "rotary"
+               and m.rope_scaling_type == "yarn",
+               "axk2 rotates under YaRN")
     elif name == "qwen2":
         # beyond-reference: llama block + QKV-only bias
         _check(m.position_embedding_type == "rotary",

@@ -64,7 +64,7 @@ def init_model_params(cfg, key: jax.Array) -> Params:
         },
         "layers": init_stacked_layers(cfg, k_layers),
         "final_norm": init_norm_params(h, m.use_rms_norm, bias=m.norm_bias,
-                                       gain=m.norm_gain),
+                                       gain=m.norm_gain, **_gated(m, key)),
     }
     if m.linear_layout is not None or m.sublayer_pattern:
         # a hybrid's mixers, a stack a kind beside the uniform stack
@@ -507,3 +507,10 @@ def looped_forward(cfg, params: Params, hidden, rope_cache, position_ids,
         logits = compute_logits(cfg, params, hidden)
         return ret(logits if labels is None
                    else softmax_cross_entropy(logits, labels))
+
+
+def _gated(m, key: jax.Array) -> dict:
+    """The final norm's low-rank gate (``gated_norm``, ops/norms.py), where
+    the model has one; at the file's end so that the lines above stand."""
+    return dict(gated_rank=m.gated_norm_rank,
+                key=jax.random.fold_in(key, 4)) if m.gated_norm else {}

@@ -242,7 +242,7 @@ def route(cfg, p_router: Params, x: jax.Array
     pick = scores
     if "bias" in p_router:
         pick = scores + p_router["bias"].astype(jnp.float32)
-    _, idx = jax.lax.top_k(pick, k_)                        # [T, K]
+    _, idx = jax.lax.top_k(_group_limited(m, pick), k_)     # [T, K]
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if m.moe_normalize_gates:
         w = w / (w.sum(-1, keepdims=True) + m.moe_gate_eps)
@@ -776,3 +776,21 @@ def aux_loss_coeffs(cfg) -> Tuple[float, float]:
     m = cfg.model
     balance = 0.0 if m.moe_router_type == "expert_choice" else m.moe_aux_loss_coeff
     return balance, m.moe_z_loss_coeff
+
+
+def _group_limited(m, pick: jax.Array) -> jax.Array:
+    """``pick`` [T, E] with the experts outside a token's ``moe_topk_group``
+    best groups at ``-inf`` (DeepSeek-V3's ``n_group`` / ``topk_group``;
+    ``pick`` itself where there is one group): the experts stand in
+    ``moe_n_group`` equal runs, a group's score is the sum of its two
+    largest selection scores, and of equal groups the earlier stays.  At
+    the file's end, so that the lines above stand where they stood (the
+    grouped kernel's payload names its callers' lines)."""
+    groups, stay = m.moe_n_group, m.moe_topk_group
+    if groups <= 1:
+        return pick
+    t, e = pick.shape
+    score = jax.lax.top_k(pick.reshape(t, groups, e // groups), 2)[0].sum(-1)
+    _, best = jax.lax.top_k(score, stay)
+    alive = (best[:, :, None] == jnp.arange(groups)[None, None, :]).any(1)
+    return jnp.where(jnp.repeat(alive, e // groups, axis=1), pick, -jnp.inf)

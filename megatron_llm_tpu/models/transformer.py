@@ -118,8 +118,8 @@ def init_layer_params(cfg, key: jax.Array, cross_attention: bool = False,
     out_std = std / (2.0 * m.num_layers) ** 0.5 if m.use_scaled_init_method else std
 
     k = jax.random.split(key, 7)
-    new_norm = partial(init_norm_params, h, m.use_rms_norm, bias=m.norm_bias,
-                       gain=m.norm_gain)
+    # a plain norm's leaves, or a gated norm's (A.X-K2): the file's end
+    new_norm = _norm_maker(cfg, key)
     p: Params = {"input_norm": new_norm()}
     if m.sublayer_pattern:   # its ONE sublayer is ``params["mixers"]``'s
         return p
@@ -270,9 +270,9 @@ def _init_mla_params(cfg, k_down: jax.Array, k_out: jax.Array,
     h, n, std = m.hidden_size, m.num_attention_heads, m.init_method_std
     nope, rope, v = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     kq, kkv, kqu, kkvu = jax.random.split(k_down, 4)
-    gate = {"g_proj": {"kernel": _normal(jax.random.fold_in(k_down, 4),
-                                         (h, n * v), std)}} \
-        if m.attention_output_gate else {}
+    # the output gate's leaf (a value a head and channel, or a head) and
+    # the indexer's, where the model has them: the file's end
+    gate = _mla_extras(cfg, k_down)
     return {
         **gate,
         "q_down": {"kernel": _normal(kq, (h, m.q_lora_rank), std)},
@@ -857,7 +857,7 @@ def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
 
         new_pool = None
         if paged is not None:
-            ctx, new_pool = _mla_paged(
+            ctx, new_pool = _mla_rows(cfg, p, x, c_q, rope, position_ids)(
                 cfg, q_nope, q_rope, c_kv, k_rope[:, :, 0], w_ukv, kv_cache,
                 paged, scale)
         else:
@@ -871,18 +871,18 @@ def mla_sublayer(cfg, p: Params, x: jax.Array, rope, position_ids,
             qf = jnp.concatenate([q_nope, q_rope], axis=-1)
             # unequal qk / v widths: attention() keeps this off the flash
             # kernel and off the ring, and says so
-            ctx = attn_ops.attention(
-                qf, k, kv[..., nope:], causal=True, segment_ids=segment_ids,
+            ctx = attn_ops.attention(qf, k, kv[..., nope:], **_mla_mask(
+                cfg, p, x, c_q, rope, position_ids, segment_ids), causal=True,
                 scale=scale, use_flash=cfg.training.use_flash_attn)
     from jax.ad_checkpoint import checkpoint_name
 
     ctx = checkpoint_name(ctx, "attn_out").reshape(b, s, n * vd)
     if m.attention_output_gate:
-        # elementwise, a value a head and channel, read from the layer's
-        # normed input (the G1 form of arXiv:2505.06708)
+        # a value a head and channel (the G1 form of arXiv:2505.06708) or
+        # ONE a head (attention_gate_headwise), from the layer's normed input
         with jax.named_scope("mla"):
-            ctx = ctx * jax.nn.sigmoid(
-                linear(p["g_proj"], x).astype(jnp.float32)).astype(ctx.dtype)
+            ctx = ctx * _head_wide(m, vd, jax.nn.sigmoid(
+                linear(p["g_proj"], x).astype(jnp.float32)).astype(ctx.dtype))
     out = apply_row_parallel(cfg, p["dense"], ctx, linear)
     return out, new_pool
 
@@ -1676,3 +1676,73 @@ def _block_bias(m, attn_bias, s: int):
         q_pos, m.diffusion_block_length)[:, None]
     return jnp.where(allowed, 0.0, attn_ops.NEG_INF).astype(
         jnp.float32)[None, None]
+
+
+# ---- A.X-K2's additions (PR 67), at the file's end so that every line
+# above stands where it stood: a Pallas kernel's payload in a lowered tick
+# names the LINES of its callers (tools/tick_digest.py), and the accepted
+# cells' ticks stay the parent's programs -------------------------------------
+
+
+def _norm_maker(cfg, key: jax.Array):
+    """``() -> leaves`` of one of a layer's norms: plain, or with the
+    low-rank gate behind it (``gated_norm``), a key a call."""
+    m = cfg.model
+    if not m.gated_norm:
+        return partial(init_norm_params, m.hidden_size, m.use_rms_norm,
+                       bias=m.norm_bias, gain=m.norm_gain)
+    keys = iter(jax.random.split(jax.random.fold_in(key, 7), 2))
+    return lambda: init_norm_params(
+        m.hidden_size, True, gated_rank=m.gated_norm_rank, key=next(keys))
+
+
+def _mla_extras(cfg, key: jax.Array) -> Params:
+    """The latent attention's optional leaves: ``g_proj`` (the output gate:
+    a value a head and channel, or ONE a head) and the indexer's."""
+    m = cfg.model
+    n = m.num_attention_heads
+    out = {}
+    if m.attention_output_gate:
+        out["g_proj"] = {"kernel": _normal(
+            jax.random.fold_in(key, 4),
+            (m.hidden_size, n if m.attention_gate_headwise
+             else n * m.v_head_dim), m.init_method_std)}
+    if m.index_topk:
+        from megatron_llm_tpu.models.sparse_mla import init_index_params
+
+        out.update(init_index_params(cfg, jax.random.fold_in(key, 5)))
+    return out
+
+
+def _mla_rows(cfg, p: Params, x, c_q, rope, position_ids):
+    """What attends a tick's rows: :func:`_mla_paged`, or under a learned
+    indexer (``index_topk``) models/sparse_mla.py ``paged``, which also
+    needs the layer's leaves and input for the index queries and key."""
+    if not cfg.model.index_topk:
+        return _mla_paged
+    from megatron_llm_tpu.models.sparse_mla import paged
+
+    return partial(paged, p=p, x=x, c_q=c_q, rope=rope,
+                   position_ids=position_ids, linear=_linear_impl(cfg))
+
+
+def _mla_mask(cfg, p: Params, x, c_q, rope, position_ids, segment_ids):
+    """The no-cache forward's mask arguments: the packed segments, and under
+    a learned indexer the same selection as a bias (no gradient; every key
+    while the sequence is no longer than ``index_topk``)."""
+    m = cfg.model
+    if not m.index_topk or x.shape[1] <= m.index_topk:
+        return dict(segment_ids=segment_ids)
+    from megatron_llm_tpu.models import sparse_mla
+
+    return dict(segment_ids=segment_ids, bias=jax.lax.stop_gradient(
+        sparse_mla.dense_bias(cfg, *sparse_mla.index_inputs(
+            cfg, p, x, c_q, rope, position_ids, _linear_impl(cfg)),
+            segment_ids=segment_ids)))
+
+
+def _head_wide(m, vd: int, gate: jax.Array) -> jax.Array:
+    """A gate of one value a head ``[..., n]`` spread over its ``vd``
+    channels; an elementwise gate as it is."""
+    return jnp.repeat(gate, vd, axis=-1) if m.attention_gate_headwise \
+        else gate

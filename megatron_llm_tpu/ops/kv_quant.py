@@ -112,7 +112,16 @@ class QuantPagedKV(NamedTuple):
     scale: jax.Array   # [..., num_pages, H] float32
 
 
-PagedKV = Union[jax.Array, QuantPagedKV]
+class IndexedLatent(NamedTuple):
+    """A latent pool whose tokens also keep an INDEX KEY (learned sparse
+    attention, ops/sparse_attention.py): two leaves under one page id, so
+    that a sweep over a sequence's index keys reads their rows alone."""
+
+    rows: jax.Array    # [L, num_pages, page_size, latent lanes]
+    index: jax.Array   # [L, num_pages, page_size, index_head_dim]
+
+
+PagedKV = Union[jax.Array, QuantPagedKV, IndexedLatent]
 
 
 def is_quantized(pool: PagedKV) -> bool:
@@ -151,7 +160,10 @@ def make_kv_pool(layers: int, num_pages: int, page_size: int, nkv: int,
 
 
 def values_of(pool: PagedKV) -> jax.Array:
-    """The value leaf (``q`` of a quantized pool)."""
+    """The value leaf (``q`` of a quantized pool, the latent rows of an
+    indexed one)."""
+    if isinstance(pool, IndexedLatent):
+        return pool.rows
     return pool.q if is_quantized(pool) else pool
 
 
@@ -247,7 +259,9 @@ def pool_nbytes(pool: PagedKV) -> int:
     by :func:`scale_nbytes` — the capacity bench and /metrics report the
     split so the per-page overhead stays visible)."""
     arr = values_of(pool)
-    return arr.size * arr.dtype.itemsize
+    more = pool.index.size * pool.index.dtype.itemsize if isinstance(
+        pool, IndexedLatent) else 0
+    return arr.size * arr.dtype.itemsize + more
 
 
 def scale_nbytes(pool: PagedKV) -> int:

@@ -59,9 +59,26 @@ def gated_head_norm(x: jax.Array, z: jax.Array, weight: jax.Array,
     return out * (gate_scale * jax.nn.sigmoid(z.astype(jnp.float32)))
 
 
+def gated_norm(x: jax.Array, params: dict, eps: float = 1e-6) -> jax.Array:
+    """A.X-K2's GatedNorm: ``n * sigmoid((n W_down) W_up)`` with ``n =
+    RMSNorm(x)``; the gate reads the normalised vector through a rank-r
+    pair with no activation between (``gate_down`` [h, r], ``gate_up`` [r,
+    h]).  The pair multiplies in the input's dtype into float32, the gate
+    and the product are float32."""
+    n = rms_norm(x, params["scale"], eps)
+    low = jnp.dot(n, params["gate_down"].astype(n.dtype),
+                  preferred_element_type=jnp.float32)
+    gate = jnp.dot(low.astype(n.dtype), params["gate_up"].astype(n.dtype),
+                   preferred_element_type=jnp.float32)
+    return (n.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(x.dtype)
+
+
 def norm(x, params: dict, eps: float, use_rms: bool) -> jax.Array:
     """Dispatch on norm family given a params dict {'scale': ..., 'bias': ...?}
-    (or ``{'gate': w}``: an RMSNorm whose gain is :func:`sigmoid2_gain`)."""
+    (or ``{'gate': w}``: an RMSNorm whose gain is :func:`sigmoid2_gain`; or
+    with ``gate_down`` / ``gate_up``: :func:`gated_norm`)."""
+    if "gate_down" in params:
+        return gated_norm(x, params, eps)
     if "gate" in params:
         return rms_norm(x, sigmoid2_gain(params["gate"]), eps)
     if use_rms:
@@ -70,9 +87,23 @@ def norm(x, params: dict, eps: float, use_rms: bool) -> jax.Array:
 
 
 def init_norm_params(hidden_size: int, use_rms: bool, dtype=jnp.float32,
-                     bias: bool = True, gain: str = "scale") -> dict:
+                     bias: bool = True, gain: str = "scale",
+                     gated_rank: int = 0, key=None) -> dict:
     """``bias``: a LayerNorm's additive bias (``norm_bias``; RMSNorm has none).
-    ``gain`` 'sigmoid2': the leaf is ``gate``, zeros (a gain of 1)."""
+    ``gain`` 'sigmoid2': the leaf is ``gate``, zeros (a gain of 1).
+    ``gated_rank`` > 0 (with ``key``): :func:`gated_norm`'s pair, DRAWN at
+    1 / sqrt(fan-in) so that a gate's logit has unit variance (at the
+    weights' 0.02 every gate would sit at a half and a program that
+    dropped the gate would read as one that scaled its norms)."""
+    if gated_rank:
+        assert use_rms and gain == "scale" and key is not None
+        k_down, k_up = jax.random.split(key)
+        return {
+            "scale": jnp.ones((hidden_size,), dtype=dtype),
+            "gate_down": jax.random.normal(
+                k_down, (hidden_size, gated_rank), dtype) / hidden_size ** 0.5,
+            "gate_up": jax.random.normal(
+                k_up, (gated_rank, hidden_size), dtype) / gated_rank ** 0.5}
     if gain == "sigmoid2":
         assert use_rms
         return {"gate": jnp.zeros((hidden_size,), dtype=dtype)}

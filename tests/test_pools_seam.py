@@ -41,6 +41,12 @@ KINDS = {
         "joyai", ffn_hidden_size=160, q_lora_rank=48, kv_lora_rank=32,
         qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=16,
         num_experts=4, moe_router_topk=2, moe_ffn_hidden_size=40, **TINY),
+    "indexed": lambda: make_config(
+        "axk2", ffn_hidden_size=160, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=16,
+        index_n_heads=4, index_head_dim=16, index_topk=16, gated_norm_rank=4,
+        num_experts=4, moe_router_topk=2, moe_ffn_hidden_size=40,
+        moe_n_group=2, moe_topk_group=1, **TINY),
     "state": lambda: make_config(
         "brumby", kv_channels=16, ffn_hidden_size=96, **TINY),
     "hybrid": lambda: make_config(
@@ -126,8 +132,11 @@ def test_the_table_has_no_row_without_a_case():
     for row in NOT_CARRIED:
         _case(*row)
     kinds = {k for k, _ in NOT_CARRIED}
-    assert kinds == {"share", "classes", "latent", "state", "hybrid", "tails",
-                     "blocks", "loop"}
+    assert kinds == {"share", "classes", "latent", "indexed", "state",
+                     "hybrid", "tails", "blocks", "loop"}
+    # latent rows under an indexer serve prompt scoring, as plain ones do
+    assert {f for k, f in NOT_CARRIED if k == "indexed"} == (
+        set(FEATURES) - {"log_probs"})
     # a state class beside a page class is SERVED (tests/test_gigachat35.py):
     # the row that refused it now says whose that is (the hybrid's, not
     # power retention's), and the hybrid has a row a feature

@@ -9,6 +9,7 @@ fail, at one seed and with no measured window.
     chiprun -- python tools/serve_faults.py --workload lfm2_24b_chat_closed --seed N
     chiprun -- python tools/serve_faults.py --workload sdar30b_chat_blocks_closed --seed N
     chiprun -- python tools/serve_faults.py --workload ouro26b_shortqa_closed --seed N
+    chiprun -- python tools/serve_faults.py --workload axk2_docqa_32k_closed --seed N
 
 As ``benchmark/control.py`` (which it follows line by line and cannot be a
 part of: a ``model_config`` PR adds to the benchmark and edits none of its
@@ -63,7 +64,11 @@ the cell's probes through the HTTP API, and holds to the cell's own limits
   the norm between passes left out (the final norm after the last pass
   alone), a query of every pass reading the FIRST pass's keys (keys and
   values shared between passes: the cache-slot fault), and (shared with
-  GigaChat's) the two norms after the sublayers left out.  A fault is tried
+  GigaChat's) the two norms after the sublayers left out; and the six of
+  ``benchmark/reference/axk2_block.py``: half the keys picked (``index_topk``
+  1,024 for 2,048), the indexer's scores without the ReLU, the gate a head
+  left out, the norms' low-rank gates left out, the router's groups ignored,
+  the indexer's rope dims rotated in pairs for halves.  A fault is tried
   where the cell's reference has its choice.
 
 A limit of the configuration's ``tolerance`` lies between the ``program``
@@ -136,6 +141,17 @@ FAULTS = {
                               lambda model: model["total_ut_steps"] - 1),
     "norm_between_passes_left_out": ("normed_between", lambda model: False),
     "queries_read_the_first_pass_keys": ("keys_pass", lambda t, model: 0),
+    "half_the_keys_picked": ("index_topk_of",
+                             lambda model: int(model["index_topk"]) // 2),
+    "index_scores_without_relu": ("index_activation", lambda x: x),
+    "head_gate_left_out": ("head_gate", lambda p, u: 1.0 + 0.0 * (
+        u @ p["g_proj"]["kernel"])),
+    "norm_gates_left_out": ("gated_norm", lambda x, p, model: x * (
+        (x * x).mean(-1, keepdims=True) + model["rms_norm_eps"]) ** -0.5
+        * p["scale"]),
+    "router_groups_ignored": ("router_groups", lambda model: (1, 1)),
+    "index_rope_in_pairs": ("rope_halves", lambda x, model: sys.modules[
+        "benchmark.reference.axk2_block"].rope_pairs(x, model)),
 }
 
 
@@ -155,7 +171,9 @@ def fp8_weights(params):
             return a
         wide = a.astype(jnp.float32)
         scale = jnp.max(jnp.abs(wide)) / 240.0
-        low = jax.lax.reduce_precision(wide / scale, 4, 3)
+        # a stack of zero biases (a LayerNorm's, a layer a row) has no scale
+        low = jax.lax.reduce_precision(
+            wide / jnp.where(scale > 0, scale, 1.0), 4, 3)
         return (low * scale).astype(a.dtype)
 
     return jax.tree.map(jax.jit(rounded), params)
